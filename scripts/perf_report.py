@@ -32,7 +32,7 @@ the shape ``perf_ledger.py`` ingests as ``exchange_hop:*`` series.
 Outputs: ``roofline.json`` + ``roofline.md`` in the telemetry dir (or
 ``--out-json`` / ``--out-md``).
 
-    python scripts/perf_report.py /tmp/telem --chip "TPU v5e" --merge \\
+    python scripts/perf_report.py /tmp/telem --chip "TPU v5 lite" --merge \\
         --fabric fabric.json --json comms_roofline.json
 """
 
@@ -70,7 +70,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--chip",
         default=None,
-        help="device kind for the peak table (e.g. 'TPU v5e'; default: "
+        help="device kind for the peak table (e.g. 'TPU v5 lite'; default: "
         "the snapshot carries no chip — achieved rates only)",
     )
     p.add_argument(

@@ -22,9 +22,10 @@ from stencil_tpu.utils.config import (
     apply_compile_cache,
 )
 
-# Persistent XLA compilation cache (STENCIL_COMPILE_CACHE_DIR): applied at
-# package import so it lands before the first backend compile whichever
-# entry point the process came through (models, drivers, bench.py).
+# Persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR, else the
+# fixed <checkout>/.jax_cache): applied at package import so it lands before
+# the first backend compile whichever entry point the process came through
+# (models, drivers, bench.py, chip_smoke.py).
 apply_compile_cache()
 
 __version__ = "0.1.0"

@@ -29,9 +29,8 @@ Three tools, shared by every contract (``analysis/contracts.py``):
   ``telemetry.annotate``) stamped on eqn source info, the strings XProf
   device-time attribution and the overlap proofs key on.
 
-The ``Literal`` import shim below is THE one home for the jax-0.4.x
-core-type move (``jax.extend.core`` vs ``jax.core``); the overlap test's
-local copy moved here.
+``Literal`` is re-exported here (from ``jax.extend.core``, its public
+home) so the contracts and the overlap tests share one import.
 """
 
 from __future__ import annotations
@@ -39,10 +38,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Iterable, Iterator, List, Optional, Set, Tuple
 
-try:  # jax moved core types under jax.extend over the 0.4.x line
-    from jax.extend.core import Literal
-except ImportError:  # pragma: no cover - older toolchains
-    from jax.core import Literal
+from jax.extend.core import Literal
 
 #: primitives whose inner jaxpr is NOT array dataflow and is never
 #: descended into by default — the analyzer treats them as opaque nodes
@@ -210,12 +206,12 @@ def pallas_taint_rows(closed) -> List[Tuple[str, bool]]:
 
 
 def donated_operands(eqn) -> List[Tuple[object, str]]:
-    """``(var, kind)`` for the invars this eqn consumes in place: a pjit's
-    ``donated_invars`` (kind ``"donated"``) and a pallas call's
+    """``(var, kind)`` for the invars this eqn consumes in place: a nested
+    jit's ``donated_invars`` (kind ``"donated"``) and a pallas call's
     ``input_output_aliases`` (kind ``"aliased"``) — the jaxpr-level twins
     of ``donate_argnums`` and buffer aliasing.  Literals excluded."""
     out: List[Tuple[object, str]] = []
-    if eqn.primitive.name == "pjit":
+    if eqn.primitive.name == "jit":
         donated = eqn.params.get("donated_invars") or ()
         for v, d in zip(eqn.invars, donated):
             if d and not isinstance(v, Literal):
@@ -239,7 +235,7 @@ def donation_hazards(jaxpr) -> List[Tuple[object, object, str]]:
     write — the split schedule's blend chain relies on exactly this), so
     that is NOT flagged.  What cannot be scheduled away:
 
-    * a pjit-DONATED operand with any later use (or escaping as a jaxpr
+    * a jit-DONATED operand with any later use (or escaping as a jaxpr
       output): the donation silently cannot engage — the plan claims
       in-place, the compiler double-buffers (``other_use`` is the later
       eqn or the string ``"outvars"``);

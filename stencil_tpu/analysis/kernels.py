@@ -152,14 +152,13 @@ class KernelReport:
 
 
 def _dimension_semantics(params: dict) -> Tuple[str, ...]:
-    cp = params.get("compiler_params")
-    if isinstance(cp, dict):  # {'mosaic': {'dimension_semantics': ...}}
-        for sub in cp.values():
-            if isinstance(sub, dict) and sub.get("dimension_semantics"):
-                return tuple(sub["dimension_semantics"])
-        return ()
-    ds = getattr(cp, "dimension_semantics", None)
-    return tuple(ds) if ds else ()
+    # the eqn carries {backend name: CompilerParams} (pallas normalizes a
+    # bare ``pltpu.CompilerParams`` into that mapping at call time)
+    for cp in (params.get("compiler_params") or {}).values():
+        ds = getattr(cp, "dimension_semantics", None)
+        if ds:
+            return tuple(str(getattr(d, "value", d)) for d in ds)
+    return ()
 
 
 def _aval_of(var):
@@ -199,13 +198,11 @@ def _eval_index_map(bm, points) -> Optional[List[Tuple[int, ...]]]:
 
 
 def _block_use(role, idx, bm, points, note_sink) -> BlockUse:
-    sd = bm.array_shape_dtype
-    # block_shape entries are ints or the pallas ``Mapped`` sentinel (the
-    # user-facing ``None``: a size-1 dim squeezed out of the kernel ref)
-    block = tuple(
-        int(b) if isinstance(b, (int,)) or hasattr(b, "__index__") else 1
-        for b in bm.block_shape
-    )
+    sd = bm.array_aval
+    # block_shape entries are ``pl.Blocked(block_size)`` (the default) or
+    # ``pl.Squeezed()`` (the user-facing ``None``: a size-1 dim squeezed
+    # out of the kernel ref, which carries no ``block_size``)
+    block = tuple(int(getattr(b, "block_size", 1)) for b in bm.block_shape)
     footprint = None
     i64 = any(
         str(getattr(a, "dtype", "")) == "int64"
@@ -578,9 +575,9 @@ def _mosaic_target() -> bool:
     process-config fact and only matters where Mosaic actually runs — on
     the CPU/interpret tiers (which deliberately enable x64) it must not
     veto anything.  Tests monkeypatch this to simulate a TPU process."""
-    import jax
+    from stencil_tpu.utils.config import pallas_interpret
 
-    return jax.default_backend() == "tpu"
+    return not pallas_interpret()
 
 
 def check_kernel_legal(dd, plan: dict) -> Optional[str]:

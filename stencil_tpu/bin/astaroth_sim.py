@@ -64,6 +64,7 @@ def main(argv=None) -> int:
     _common.add_numerics_flag(p)
     _common.add_checkpoint_flags(p)
     args = p.parse_args(argv)
+    args.interpret = _common.require_platform("astaroth_sim")
     _common.telemetry_begin(args)
     _common.tune_begin(args)
     try:
@@ -106,7 +107,7 @@ def _run(args) -> int:
         tuner_sim = AstarothSim(
             x, y, z, num_quantities=args.quantities,
             strategy=_common.parse_strategy(args), kernel_impl="pallas",
-            interpret=jax.default_backend() == "cpu", schedule=args.schedule,
+            interpret=args.interpret, schedule=args.schedule,
         )
         if tune.best_config(tuner_sim.dd.tune_key("stream")) is not None:
             print("tune[stream]: source=cache (warm; zero trials)", file=sys.stderr)
@@ -114,7 +115,7 @@ def _run(args) -> int:
             tuner_sim.realize()
             report = tune_runners.autotune_stream(
                 tuner_sim.dd, tuner_sim._kernel, x_radius=1, separable=True,
-                interpret=jax.default_backend() == "cpu",
+                interpret=args.interpret,
                 mxu_kernel=tuner_sim._kernel_mxu,
             )
             _common.tune_report_stderr(report)
@@ -127,7 +128,7 @@ def _run(args) -> int:
         overlap=not args.no_overlap,
         strategy=_common.parse_strategy(args),
         kernel_impl=kernel_impl,
-        interpret=jax.default_backend() == "cpu",
+        interpret=args.interpret,
         schedule=args.schedule,
         stream_overlap=args.stream_overlap,
         stream_halo=args.stream_halo,

@@ -77,6 +77,7 @@ def main(argv=None) -> int:
     p.add_argument("--scale", type=float, default=1.0, help="scale all matrix sizes")
     _common.add_telemetry_flags(p)
     args = p.parse_args(argv)
+    _common.require_platform("bench-alltoallv")
     _common.telemetry_begin(args)
 
     devices = jax.devices()

@@ -73,6 +73,7 @@ def main(argv=None) -> int:
     p.add_argument("y", type=int, nargs="?", default=512)
     p.add_argument("z", type=int, nargs="?", default=512)
     args = p.parse_args(argv)
+    args.interpret = _common.require_platform("jacobi3d")
     _common.telemetry_begin(args)
     _common.tune_begin(args)
     try:
@@ -118,7 +119,6 @@ def _run(args) -> int:
         # would burn device work on an orphaned cache entry.
         from stencil_tpu.tune import runners as tune_runners
 
-        interp = jax.default_backend() == "cpu"
         single = len(jax.devices()) == 1
         if args.pallas_path == "wrap" or (args.pallas_path == "auto" and single):
             if not single:
@@ -130,11 +130,11 @@ def _run(args) -> int:
                 report = None
             else:
                 report = tune_runners.autotune_jacobi_wrap(
-                    x, y, z, dtype=jnp.dtype(args.dtype), interpret=interp
+                    x, y, z, dtype=jnp.dtype(args.dtype), interpret=args.interpret
                 )
         else:  # forced wavefront, or auto on a multi-device mesh
             report = tune_runners.autotune_jacobi_wavefront(
-                x, y, z, dtype=jnp.dtype(args.dtype), interpret=interp,
+                x, y, z, dtype=jnp.dtype(args.dtype), interpret=args.interpret,
                 # same placement as the model built below — a strategy
                 # mismatch would re-key the workload and orphan the search
                 strategy=_common.parse_strategy(args),
@@ -175,7 +175,7 @@ def _run(args) -> int:
         strategy=_common.parse_strategy(args),
         methods=_common.parse_methods(args),
         kernel_impl=kernel_impl,
-        interpret=jax.default_backend() == "cpu",
+        interpret=args.interpret,
         pallas_path=args.pallas_path,
         dtype=jnp.dtype(args.dtype),
         **_common.kernel_axis_kwargs(args),

@@ -46,6 +46,7 @@ def main(argv=None) -> int:
 
     _common.add_telemetry_flags(p)
     args = p.parse_args(argv)
+    _common.require_platform("measure-buf-exchange")
     _common.telemetry_begin(args)
 
     devices = jax.devices()

@@ -20,7 +20,7 @@ import time
 import jax
 
 from stencil_tpu.bin import _common
-from stencil_tpu.utils.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -72,6 +72,7 @@ def main(argv=None) -> int:
     p.add_argument("--iters", type=int, default=30)
     _common.add_telemetry_flags(p)
     args = p.parse_args(argv)
+    _common.require_platform("pingpong")
     _common.telemetry_begin(args)
 
     rows = pingpong_times(jax.devices(), args.min, args.max, args.iters)

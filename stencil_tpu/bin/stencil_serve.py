@@ -90,6 +90,10 @@ def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     import jax
 
+    from stencil_tpu.bin import _common
+
+    _common.require_platform("stencil-serve")
+
     from stencil_tpu import telemetry
     from stencil_tpu.telemetry import names as tm
     from stencil_tpu.models.jacobi import Jacobi3D

@@ -24,6 +24,7 @@ from stencil_tpu.core.radius import Radius
 
 def main(argv=None) -> int:
     args = build_parser("strong").parse_args(argv)
+    _common.require_platform("strong")
     args.trivial = args.naive
     _common.telemetry_begin(args)
     _common.tune_begin(args)

@@ -46,7 +46,7 @@ from stencil_tpu.bin import _common
 from stencil_tpu.core.radius import Radius
 from stencil_tpu.domain import DistributedDomain
 from stencil_tpu.models.jacobi import weak_scaled_size
-from stencil_tpu.utils.config import MethodFlags
+from stencil_tpu.utils.config import MethodFlags, pallas_interpret
 
 
 def run(x: int, y: int, z: int, n_iters: int, args, name: str = "weak") -> str:
@@ -192,10 +192,9 @@ def run_overlap(args, name: str = "weak", weak_scale: bool = True) -> dict:
     autotuner's trial pattern — the domain state never advances), alternate
     them with the bare exchange under the trial protocol, and return the
     per-mesh JSON document."""
-    from stencil_tpu.tune.runners import _force_done
     from stencil_tpu.tune.trial import measure_alternating
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     mesh = parse_mesh(args.mesh)
     devices = jax.devices()
     if mesh is not None:
@@ -288,8 +287,7 @@ def run_overlap(args, name: str = "weak", weak_scale: bool = True) -> dict:
 
     def make_step_run(step):
         def go(ninner):
-            out = step(dd._curr, ninner)
-            _force_done(next(iter(out.values())))
+            jax.block_until_ready(step(dd._curr, ninner))
 
         return go
 
@@ -304,8 +302,7 @@ def run_overlap(args, name: str = "weak", weak_scale: bool = True) -> dict:
         return lax.fori_loop(0, s, lambda _, a: exch_fn(a), arrays)
 
     def exch_run(ninner):
-        out = exch_many(dd._curr, ninner)
-        _force_done(next(iter(out.values())))
+        jax.block_until_ready(exch_many(dd._curr, ninner))
 
     rt = _common.host_round_trip_s()
     runs = [make_step_run(steps["off"]), make_step_run(steps["split"]), exch_run]
@@ -494,6 +491,7 @@ def build_parser(name: str, overlap_flags: bool = True) -> argparse.ArgumentPars
 
 def main(argv=None) -> int:
     args = build_parser("weak").parse_args(argv)
+    _common.require_platform("weak")
     args.trivial = args.naive
     _common.telemetry_begin(args)
     _common.tune_begin(args)

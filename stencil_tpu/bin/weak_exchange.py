@@ -26,6 +26,7 @@ from stencil_tpu.utils.config import MethodFlags
 
 def main(argv=None) -> int:
     args = build_parser("weak-exchange", overlap_flags=False).parse_args(argv)
+    _common.require_platform("weak-exchange")
     args.trivial = args.naive
     _common.telemetry_begin(args)
     devs = len(jax.devices())

@@ -61,8 +61,10 @@ def main(argv=None) -> int:
     import jax
     from stencil_tpu.core.radius import Radius
     from stencil_tpu.parallel.mesh import make_mesh, mesh_from_grid
+    from stencil_tpu.bin._common import require_platform
     from stencil_tpu.telemetry import fabric
 
+    require_platform("stencil_tpu.fabric")
     if args.cache is not None:
         fabric.set_dir_override(args.cache)
     devices = jax.devices()

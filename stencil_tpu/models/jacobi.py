@@ -19,7 +19,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from stencil_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from stencil_tpu.core.dim3 import Dim3
 from stencil_tpu.core.radius import Radius

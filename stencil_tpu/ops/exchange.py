@@ -54,7 +54,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from stencil_tpu.core.dim3 import Dim3
-from stencil_tpu.utils.compat import shard_map
+from jax import shard_map
 from stencil_tpu.core.radius import Radius
 from stencil_tpu.parallel.mesh import MESH_AXES
 from stencil_tpu.telemetry import names as tm

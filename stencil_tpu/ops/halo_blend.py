@@ -31,12 +31,14 @@ import os
 import jax
 import jax.numpy as jnp
 
+from stencil_tpu.utils.config import pallas_interpret
+
 
 def enabled() -> bool:
     """Use the blend kernels for y/z halo writes?  Auto: on for TPU only —
     the relayout trap these kernels dodge is a property of TPU tiled layouts,
-    and the tile geometry below is TPU's; any other backend (cpu, gpu, dev
-    tunnels) takes the plain-DUS path it has actually been validated on.  Env
+    and the tile geometry below is TPU's; any other backend (cpu, gpu) takes
+    the plain-DUS path.  Env
     override ``STENCIL_HALO_BLEND=0|1`` forces either path (tests force 1
     with interpret mode to pin blend semantics against DUS)."""
     from stencil_tpu.utils.config import env_choice
@@ -46,11 +48,12 @@ def enabled() -> bool:
         return False
     if env == "1":
         return True
-    return jax.default_backend() == "tpu"
+    return not pallas_interpret()
 
 
 def interpret_mode() -> bool:
-    return jax.default_backend() != "tpu"
+    return pallas_interpret()
+
 
 #: second-to-minor (sublane) tile extent per itemsize, minor is always 128
 _SUBLANE = {8: 4, 4: 8, 2: 16, 1: 32}

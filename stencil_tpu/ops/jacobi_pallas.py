@@ -632,10 +632,12 @@ def _tpu_compiler_params(interpret: bool):
     interpret mode (no Mosaic, nothing to budget)."""
     if interpret:
         return {}
-    from stencil_tpu.utils.compat import tpu_compiler_params
+    from jax.experimental.pallas import tpu as pltpu
 
     return {
-        "compiler_params": tpu_compiler_params(vmem_limit_bytes=_vmem_budget())
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_budget()
+        )
     }
 
 

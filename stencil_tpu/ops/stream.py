@@ -105,7 +105,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from stencil_tpu.core.dim3 import Dim3
-from stencil_tpu.utils.compat import shard_map
+from jax import shard_map
 from stencil_tpu import telemetry
 from stencil_tpu.telemetry import names as tm
 from stencil_tpu.ops.jacobi_pallas import (

@@ -1,10 +1,11 @@
 """ctypes bindings for the native QAP solvers (native/qap.cpp).
 
-Loads ``libstencil_native.so``, building it with ``make`` on first use when a
-toolchain is available.  Importing this module raises ImportError when the
-library can neither be found nor built — ``qap.solve_auto`` catches that and
-falls back to the pure-Python solvers.  Set ``STENCIL_NATIVE=0`` to force the
-fallback.
+Loads ``libstencil_native.so`` through ``make -C native`` — the library is
+git-ignored, so a checkout has none (or a stale one) until the first load;
+make rebuilds only when ``qap.cpp`` is newer.  Importing this module raises
+ImportError when the library cannot be built — ``qap.solve_auto`` catches
+that, falls back to the pure-Python solvers and logs which solver is in
+use.  Set ``STENCIL_NATIVE=0`` to force the fallback.
 """
 
 from __future__ import annotations
@@ -37,16 +38,15 @@ _LIB_PATH = os.path.join(_NATIVE_DIR, "libstencil_native.so")
 
 
 def _load() -> ctypes.CDLL:
-    if not os.path.exists(_LIB_PATH):
-        try:
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-        except (OSError, subprocess.SubprocessError) as e:
-            raise ImportError(f"cannot build native library: {e}") from e
+    try:
+        subprocess.run(
+            ["make", "-C", _NATIVE_DIR],
+            check=True,
+            capture_output=True,
+            timeout=120,
+        )
+    except (OSError, subprocess.SubprocessError) as e:
+        raise ImportError(f"cannot build native library: {e}") from e
     return ctypes.CDLL(_LIB_PATH)
 
 

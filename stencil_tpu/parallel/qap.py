@@ -19,6 +19,7 @@ versions are the always-available fallback and the semantic spec.
 
 from __future__ import annotations
 
+import functools
 import itertools
 from typing import List, Optional, Sequence, Tuple
 
@@ -117,16 +118,28 @@ def qap_solve_catch(w: np.ndarray, d: np.ndarray) -> Tuple[List[int], float]:
     return best_f, best_cost
 
 
+@functools.cache
+def _native():
+    """The native solver module, or None — resolved (built, loaded) once per
+    process, saying once which solver placement will use."""
+    from stencil_tpu.utils.logging import log_info
+
+    try:
+        from stencil_tpu.parallel import native_qap
+    except (ImportError, OSError) as e:
+        log_info(f"QAP solver: pure Python (native unavailable: {e})")
+        return None
+    log_info("QAP solver: native (native/libstencil_native.so)")
+    return native_qap
+
+
 def solve_auto(w: np.ndarray, d: np.ndarray, exact_limit: int = 8) -> Tuple[List[int], float]:
     """Exact for small n (like the reference's per-node exact solve for <=6
     GPUs, partition.hpp:802-803), 2-opt beyond.  Prefers the native C++
-    implementation when built."""
-    try:
-        from stencil_tpu.parallel import native_qap
-
-        return native_qap.solve_auto(w, d, exact_limit)
-    except (ImportError, OSError):
-        pass
+    implementation when it builds."""
+    native = _native()
+    if native is not None:
+        return native.solve_auto(w, d, exact_limit)
     n = np.asarray(w).shape[0]
     if n <= exact_limit:
         return qap_solve(w, d)

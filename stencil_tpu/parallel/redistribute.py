@@ -309,7 +309,7 @@ def build_redistribute_fn(plan: RedistributionPlan, components: Tuple[int, ...],
 
     from stencil_tpu import telemetry
     from stencil_tpu.telemetry import names as tm
-    from stencil_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _union_mesh(plan)

@@ -2,7 +2,7 @@
 
 Every failure mode the resilience layer handled so far announces itself —
 an exception to classify, a NaN to detect.  The one that doesn't is the
-hang: a tunneled backend whose remote side went away mid-collective, a
+hang: a runtime whose peer went away mid-collective, a
 device-side deadlock, a preempted neighbor stalling a ppermute.  The run
 burns its preemption deadline doing nothing, and no checkpoint gets taken.
 

@@ -18,9 +18,9 @@ Two warmth layers (docs/serving.md "Admission"):
 * **cross-process** — a JSON stamp per digest (tune/cache.py's schema +
   toolchain-stamp pattern: corrupt/stale = miss, never a crash) recording
   that this key compiled before.  A stamped key re-compiles WITHOUT the
-  budget refusal on a server restart: ``STENCIL_COMPILE_CACHE_DIR`` (the
-  persistent XLA executable cache, applied at package import) makes that
-  rebuild a cache read, so treating it as warm is honest — and when the
+  budget refusal on a server restart: the persistent XLA executable cache
+  (``utils/config.compile_cache_dir``, applied at package import) makes
+  that rebuild a cache read, so treating it as warm is honest — and when the
   XLA cache was wiped the stamp's recorded seconds tell admission what the
   rebuild will really cost.
 """
@@ -53,16 +53,13 @@ def _toolchain():
     return jax.__version__, jaxlib_v
 
 
-def default_stamp_dir() -> Optional[str]:
-    """``<STENCIL_COMPILE_CACHE_DIR>/serve_aot`` when the persistent XLA
-    cache is configured (the stamps describe ITS contents, so they live
-    beside it), else None — in-process warmth only."""
-    from stencil_tpu.utils.config import env_str
+def default_stamp_dir() -> str:
+    """``serve_aot/`` inside the persistent XLA cache directory — the
+    stamps describe ITS contents, so they live beside it.  (An empty
+    ``stamp_dir`` keeps an ``AOTCache`` in-process only.)"""
+    from stencil_tpu.utils.config import compile_cache_dir
 
-    root = env_str("STENCIL_COMPILE_CACHE_DIR", None)
-    if root is None:
-        return None
-    return os.path.join(os.path.abspath(os.path.expanduser(root)), "serve_aot")
+    return os.path.join(compile_cache_dir(), "serve_aot")
 
 
 class AOTCache:

@@ -348,7 +348,7 @@ class NumericsEngine:
 
         from stencil_tpu.domain import _qspec
         from stencil_tpu.parallel.mesh import MESH_AXES
-        from stencil_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         dd = self.dd

@@ -28,18 +28,20 @@ from typing import Dict, Optional
 
 from stencil_tpu.telemetry import names
 
-#: nominal per-chip peaks keyed by ``device_kind`` prefix (the same labels
-#: ``tune.key.chip_kind`` persists).  Numbers follow PERF_NOTES ("VPU
-#: wall": v5e-class ≈ 197 Tf32-FLOP/s MXU, 819 GB/s HBM); unknown chips
-#: (and CPU dryruns) carry None peaks — the report then shows achieved
-#: rates with a null roofline fraction instead of inventing a ceiling.
+#: published per-chip peaks keyed by the ``device_kind`` string the chip
+#: reports (``jax.devices()[0].device_kind`` — the label
+#: ``tune.key.chip_kind`` persists; a v5e says "TPU v5 lite", chip run of
+#: PR 21), matched by prefix.  Sources: Google Cloud TPU documentation,
+#: "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM), "TPU v5p" (459 TFLOP/s bf16,
+#: 2765 GB/s), "TPU v4" (275 TFLOP/s bf16, 1200 GB/s).  No f32 matrix peak
+#: is published, and the MXU rounds f32 operands to bf16 at default
+#: precision, so bf16 is the one matrix ceiling.  Unknown chips (and CPU
+#: dryruns) carry None peaks — the report then shows achieved rates with a
+#: null roofline fraction instead of inventing a ceiling.
 PEAKS: Dict[str, dict] = {
-    "TPU v5e": {"hbm_gbps": 819.0, "mxu_gflops_f32": 197_000.0,
-                "mxu_gflops_bf16": 394_000.0},
-    "TPU v5p": {"hbm_gbps": 2765.0, "mxu_gflops_f32": 229_500.0,
-                "mxu_gflops_bf16": 459_000.0},
-    "TPU v4": {"hbm_gbps": 1228.0, "mxu_gflops_f32": 137_500.0,
-               "mxu_gflops_bf16": 275_000.0},
+    "TPU v5 lite": {"hbm_gbps": 819.0, "mxu_gflops_bf16": 197_000.0},
+    "TPU v5p": {"hbm_gbps": 2765.0, "mxu_gflops_bf16": 459_000.0},
+    "TPU v4": {"hbm_gbps": 1200.0, "mxu_gflops_bf16": 275_000.0},
 }
 
 #: phase -> the analytic counter carrying its traffic/work (the join key)
@@ -56,11 +58,11 @@ def peaks_for(chip: Optional[str],
               measured_hbm_gbps: Optional[float] = None) -> dict:
     """The peak table for ``chip`` (prefix match over ``PEAKS``), with the
     MEASURED copy bandwidth substituted for the nominal HBM number when
-    available — a time-shared/throttled chip's honest ceiling is what it
-    measured, not the datasheet (the ``chip_copy_gbps`` rule bench.py
+    available — a throttled chip's honest ceiling is what it measured, not
+    the datasheet (the ``chip_copy_gbps`` rule bench.py
     already applies to its headline)."""
-    out = {"chip": chip, "hbm_gbps": None, "mxu_gflops_f32": None,
-           "mxu_gflops_bf16": None, "hbm_source": None}
+    out = {"chip": chip, "hbm_gbps": None, "mxu_gflops_bf16": None,
+           "hbm_source": None}
     if chip:
         for prefix, vals in PEAKS.items():
             if chip.startswith(prefix):
@@ -142,9 +144,9 @@ def roofline_report(
                 entry["flops"] = int(fl)
                 if s > 0:
                     entry["gflops"] = round(fl / s / 1e9, 3)
-                    if peaks["mxu_gflops_f32"]:
+                    if peaks["mxu_gflops_bf16"]:
                         entry["frac_of_roofline"] = round(
-                            entry["gflops"] / peaks["mxu_gflops_f32"], 4
+                            entry["gflops"] / peaks["mxu_gflops_bf16"], 4
                         )
         phases[phase] = entry
     return {
@@ -267,7 +269,7 @@ def render_markdown(report: dict) -> str:
         f"- chip: `{peaks.get('chip')}`  "
         f"(HBM peak {peaks.get('hbm_gbps')} GB/s "
         f"[{peaks.get('hbm_source') or 'unknown'}], "
-        f"MXU f32 peak {peaks.get('mxu_gflops_f32')} GFLOP/s)",
+        f"MXU bf16 peak {peaks.get('mxu_gflops_bf16')} GFLOP/s)",
         f"- timing source: **{report.get('source')}** "
         + ("(device truth)" if report.get("source") == "device"
            else "(host spans — async dispatch upper bound only)"),

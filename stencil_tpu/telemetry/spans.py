@@ -45,8 +45,7 @@ def trace(log_dir: Optional[str]):
 
     Degrades gracefully: the directory is created up front (a capture that
     dies mid-run must still leave the dir its tooling expects), and a
-    backend with no profiler support (CPU dryrun containers, tunneled dev
-    backends) WARNS once and runs the body unprofiled — a profiling knob
+    backend with no profiler support (CPU dryrun containers) WARNS once and runs the body unprofiled — a profiling knob
     must never crash the run it was meant to observe."""
     if not log_dir:
         yield

@@ -20,7 +20,7 @@ from typing import Tuple
 
 def chip_kind() -> str:
     """The device kind tuned configs are keyed by — ``device_kind`` when a
-    backend is up-able (e.g. "TPU v5e", "cpu"), else the platform name.
+    backend is up-able (e.g. "TPU v5 lite", "cpu"), else the platform name.
     Only called from tuning/plan paths that already initialized jax."""
     import jax
 

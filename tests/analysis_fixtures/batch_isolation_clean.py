@@ -10,7 +10,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from stencil_tpu import analysis
-from stencil_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def build():

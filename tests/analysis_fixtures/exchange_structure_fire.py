@@ -11,7 +11,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from stencil_tpu import analysis
 from stencil_tpu.telemetry import names as tm
-from stencil_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def build():

@@ -10,6 +10,7 @@ The same map on a sequential grid is the sanctioned last-write-wins replay
 import jax
 import jax.experimental.pallas as pl
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from stencil_tpu import analysis
 
@@ -26,8 +27,8 @@ def build():
             in_specs=[pl.BlockSpec((1, 8, 128), lambda i: (i, 0, 0))],
             out_specs=pl.BlockSpec((1, 8, 128), lambda i: (i // 2, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((2, 8, 128), jnp.float32),
-            compiler_params=dict(
-                mosaic=dict(dimension_semantics=("parallel",))
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)
             ),
             interpret=True,
         )(b)

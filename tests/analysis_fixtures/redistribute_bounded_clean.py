@@ -11,7 +11,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import numpy as np
 
 from stencil_tpu import analysis
-from stencil_tpu.utils.compat import shard_map
+from jax import shard_map
 
 N_DEV = 4
 BLOCK = (8, 8, 8)

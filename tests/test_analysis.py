@@ -182,7 +182,7 @@ def test_taint_flows_through_nested_scan_and_while():
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from stencil_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:8]), ("x",))
     perm = [(i, (i + 1) % 8) for i in range(8)]
@@ -236,7 +236,7 @@ def test_pallas_opacity_is_conservative():
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from stencil_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:8]), ("x",))
     perm = [(i, (i + 1) % 8) for i in range(8)]
@@ -471,7 +471,7 @@ def test_check_kernel_legal_verdicts(monkeypatch):
 
     dd = _mk_dd()
     plan = {"route": "wavefront", "m": 2, "z_slabs": False}
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         assert analysis.check_kernel_legal(dd, plan) is None  # CPU: no veto
         monkeypatch.setattr(akern, "_mosaic_target", lambda: True)
         reason = analysis.check_kernel_legal(dd, plan)
@@ -500,7 +500,7 @@ def test_stream_space_prunes_illegal_kernel_statically(monkeypatch, tune_dir):
                                             mxu_ok=True)
     assert len(cands) > 1, "control: the space is non-trivial on CPU"
     monkeypatch.setattr(akern, "_mosaic_target", lambda: True)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         cands64, prefiltered64 = space.stream_space(
             dd, 1, False, static_plan, mxu_ok=True
         )
@@ -539,7 +539,7 @@ def test_illegal_candidate_never_compiles(monkeypatch, tune_dir):
 
     monkeypatch.setattr(sm, "_build_stream_step", spy)
     monkeypatch.setattr(akern, "_mosaic_target", lambda: True)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         report = autotune_stream(
             dd, aprog.mean6_kernel, interpret=True, reps=1, rt=0.0,
         )

@@ -133,3 +133,172 @@ def test_compiled_astaroth_schedules_match():
     b.step(6)
     for i in range(2):
         np.testing.assert_allclose(a.field(i), b.field(i), rtol=0, atol=1e-6)
+
+
+# --- census: one compiled case per non-default axis value ---------------------
+#
+# Twins of the interpret-mode suites (test_exchange_routes, test_overlap_split,
+# test_stream_fused, test_kernel_axes), each forcing ONE non-default axis value
+# through Mosaic at <= 128^3.  ROADMAP S4-S6/D1 need to know which axes compile
+# at all before chip time goes into A/B-ing them; a case the compiler rejects
+# is marked xfail(strict=True) with the compiler's own message and listed under
+# ROADMAP D1 — repairing it is a separate change.  A ladder descent counts as a
+# failure here: a rejected axis must not pass by quietly running its default.
+
+
+def _mean6(views, info):
+    out = {}
+    for name, src in views.items():
+        out[name] = (
+            src.sh(-1, 0, 0) + src.sh(1, 0, 0)
+            + src.sh(0, -1, 0) + src.sh(0, 1, 0)
+            + src.sh(0, 0, -1) + src.sh(0, 0, 1)
+        ) / 6.0
+    return out
+
+
+def _mean6_mxu(views, info):
+    out = {}
+    for name, src in views.items():
+        out[name] = (
+            src.sh(-1, 0, 0) + src.sh(1, 0, 0) + src.plane_nbr_sum()
+        ) / 6.0
+    return out
+
+
+def _stream_run(n=128, mult=1, route=None, storage=None, steps=4, **step_kw):
+    """One compiled stream-engine run on one device; returns (plan, field).
+    Fails on any ladder descent."""
+    from stencil_tpu.core.radius import Radius
+    from stencil_tpu.domain import DistributedDomain
+
+    dd = DistributedDomain(n, n, n)
+    dd.set_radius(Radius.constant(1))
+    dd.set_devices(jax.devices()[:1])
+    if mult > 1:
+        dd.set_halo_multiplier(mult)
+    if route is not None:
+        dd.set_exchange_route(route)
+    if storage is not None:
+        dd.set_storage(storage)
+    h = dd.add_data("q0")
+    dd.realize()
+    dd.init_by_coords(h, lambda x, y, z: jnp.sin(0.13 * (x + 2 * y + 3 * z)))
+    step = dd.make_step(_mean6, engine="stream", mxu_kernel=_mean6_mxu, **step_kw)
+    dd.run_step(step, steps)
+    assert step._resilience.descents == [], step._resilience.descents
+    return step._stream_plan, dd.quantity_to_host(h)
+
+
+#: chip run of PR 21 (TPU v5 lite, jax 0.9.0 / libtpu 0.0.34) — ROADMAP D1
+_PACK_REJECT = (
+    "ValueError at realize(): The Pallas TPU lowering currently requires that "
+    "the last two dimensions of your block shape are divisible by 8 and 128 "
+    "respectively, or be equal to the respective dimensions of the overall "
+    "array. Block spec for outputs in pallas_call kernel at ops/pack.py:345 "
+    "has block shape (2, 132, 1), array shape (2, 132, 256) [z pack; the y "
+    "pack at ops/pack.py:434 likewise: block (2, 1, 132) of (2, 132, 132)]"
+)
+_BAND_REJECT = (
+    "INTERNAL: Mosaic failed to compile TPU kernel: infer-vector-layout: "
+    "unsupported shape cast — tpu.reshape vector<1x1x128x128xf32> -> "
+    "vector<128x16x8xf32> (the ladder then descends mxu_band -> mxu)"
+)
+_MXU_NUMERICS = (
+    "compiles and runs, but diverges 1.935e-03 from vpu against the analytic "
+    "bound 5.722e-06 (16 roundings * half-ulp at scale 6): the contraction "
+    "is not f32-accurate on the chip"
+)
+
+
+@pytest.mark.parametrize(
+    "route",
+    [
+        "zpack_xla",
+        pytest.param("zpack_pallas",
+                     marks=pytest.mark.xfail(strict=True, reason=_PACK_REJECT)),
+        "yzpack_xla",
+        pytest.param("yzpack_pallas",
+                     marks=pytest.mark.xfail(strict=True, reason=_PACK_REJECT)),
+    ],
+)
+def test_compiled_exchange_route(route):
+    """A radius-2 shell through each packed route equals the analytic
+    field in every raw cell (interior and shell)."""
+    from stencil_tpu.core.radius import Radius
+    from stencil_tpu.domain import DistributedDomain
+
+    n, r = 128, 2
+    dd = DistributedDomain(n, n, n)
+    dd.set_radius(Radius.constant(r))
+    dd.set_devices(jax.devices()[:1])
+    dd.set_exchange_route(route)
+    h = dd.add_data("q0")
+    dd.realize()
+    assert dd.exchange_route() == route  # no realize-time step-down to direct
+    dd.init_by_coords(h, lambda x, y, z: (x * 37 + y * 5 + z).astype(jnp.float32))
+    dd.exchange()
+    c = (np.arange(n + 2 * r) - r) % n
+    want = (
+        c[:, None, None] * 37 + c[None, :, None] * 5 + c[None, None, :]
+    ).astype(np.float32)
+    np.testing.assert_array_equal(dd.raw_to_host(h), want)
+
+
+def test_compiled_overlap_split():
+    plan_off, want = _stream_run(mult=3, stream_path="wavefront",
+                                 stream_overlap="off", steps=7)
+    plan, got = _stream_run(mult=3, stream_path="wavefront",
+                            stream_overlap="split", steps=7)
+    assert plan["overlap"] == "split" and plan["route"] == "wavefront", plan
+    np.testing.assert_array_equal(want, got)
+
+
+def test_compiled_halo_fused():
+    _, want = _stream_run(mult=3, route="yzpack_xla", stream_path="wavefront",
+                          stream_halo="array", steps=7)
+    plan, got = _stream_run(mult=3, route="yzpack_xla", stream_path="wavefront",
+                            stream_halo="fused", steps=7)
+    assert plan["halo"] == "fused" and plan["route"] == "wavefront", plan
+    np.testing.assert_array_equal(want, got)
+
+
+@pytest.mark.parametrize(
+    "unit",
+    [
+        pytest.param("mxu",
+                     marks=pytest.mark.xfail(strict=True, reason=_MXU_NUMERICS)),
+        pytest.param("mxu_band",
+                     marks=pytest.mark.xfail(strict=True, reason=_BAND_REJECT)),
+    ],
+)
+def test_compiled_compute_unit(unit):
+    from ulp import assert_reassociation_close
+
+    plan_v, want = _stream_run(compute_unit="vpu", stream_depth=4)
+    plan, got = _stream_run(compute_unit=unit, stream_depth=4)
+    assert plan["compute_unit"] == unit and plan["m"] == plan_v["m"], plan
+    # 4 reordered roundings per level x 4 levels at the six-sum's magnitude
+    assert_reassociation_close(got, want, rounds=16, scale=6.0,
+                               context=f"compiled {unit}")
+
+
+def test_compiled_mxu_input_bf16():
+    """On the dense unit — the band form does not compile (above), and this
+    case asks only whether the narrowed operands do."""
+    from ulp import assert_mxu_bf16_input_close
+
+    _, want = _stream_run(compute_unit="mxu", stream_depth=4)
+    plan, got = _stream_run(compute_unit="mxu", mxu_input="bf16",
+                            stream_depth=4)
+    assert plan["mxu_input"] == "bf16" and plan["compute_unit"] == "mxu", plan
+    assert_mxu_bf16_input_close(got, want, levels=4, context="compiled bf16in")
+
+
+def test_compiled_storage_bf16():
+    from ulp import assert_bf16_storage_close
+
+    _, want = _stream_run(stream_depth=4)
+    plan, got = _stream_run(storage="bf16", stream_depth=4)
+    # init quantizes the input (one extra rounding) + <= one downcast per step
+    assert_bf16_storage_close(got, want, passes=5, context="compiled bf16")

@@ -215,7 +215,7 @@ class TestRoofline:
         )
 
     def test_join_bytes_and_flops(self):
-        r = self._report(chip="TPU v5e")
+        r = self._report(chip="TPU v5 lite")
         ex = r["phases"]["exchange"]
         # 6291456 B over 640 us of collective time
         assert ex["bytes"] == 6_291_456
@@ -232,11 +232,18 @@ class TestRoofline:
         json.loads(json.dumps(r))  # strict-JSON-safe
 
     def test_measured_bandwidth_overrides_nominal(self):
-        r = self._report(chip="TPU v5e", measured_hbm_gbps=550.0)
+        r = self._report(chip="TPU v5 lite", measured_hbm_gbps=550.0)
         assert r["peaks"]["hbm_gbps"] == 550.0
         assert r["peaks"]["hbm_source"] == "measured"
-        nominal = peaks_for("TPU v5e")
+        nominal = peaks_for("TPU v5 lite")
         assert nominal["hbm_gbps"] == 819.0 and nominal["hbm_source"] == "nominal"
+
+    def test_v5e_is_keyed_by_its_reported_device_kind(self):
+        """A v5e reports ``device_kind`` "TPU v5 lite" (chip run, PR 21);
+        its published peaks are 819 GB/s HBM and 197 TFLOP/s bf16."""
+        v5e = peaks_for("TPU v5 lite")
+        assert v5e["hbm_gbps"] == 819.0 and v5e["mxu_gflops_bf16"] == 197_000.0
+        assert peaks_for("TPU v5e")["hbm_gbps"] is None  # no chip says this
 
     def test_unknown_chip_has_null_roofline(self):
         r = self._report(chip="cpu")
@@ -245,7 +252,7 @@ class TestRoofline:
         assert r["phases"]["exchange"]["gbps"] is not None  # achieved still shown
 
     def test_markdown_rendering(self):
-        md = render_markdown(self._report(chip="TPU v5e"))
+        md = render_markdown(self._report(chip="TPU v5 lite"))
         assert "| phase |" in md
         assert f"`{names.SPAN_OVERLAP_INTERIOR}`" in md
         assert "device truth" in md
@@ -291,7 +298,7 @@ class TestRoofline:
         assert zl["probed_gbps"] == 50.0
         assert zl["frac_of_link"] == pytest.approx(zl["gbps"] / 50.0, rel=1e-3)
         assert comms["fabric"] == "probed"
-        report = self._report(chip="TPU v5e")
+        report = self._report(chip="TPU v5 lite")
         report["comms"] = comms
         md = render_markdown(report)
         assert "Comms roofline" in md
@@ -310,7 +317,7 @@ class TestPerfReportScript:
         work = tmp_path / "telem"
         shutil.copytree(FIXTURE, work)
         mod = _load_script("perf_report")
-        rc = mod.main([str(work), "--chip", "TPU v5e", "--merge"])
+        rc = mod.main([str(work), "--chip", "TPU v5 lite", "--merge"])
         assert rc == 0
         report = json.load(open(work / "roofline.json"))
         assert report["source"] == "device"
@@ -336,7 +343,7 @@ class TestPerfReportScript:
         work = tmp_path / "telem"
         shutil.copytree(FIXTURE, work)
         fabric_doc = {
-            "schema": 1, "bench": "fabric_probe", "chip": "TPU v5e",
+            "schema": 1, "bench": "fabric_probe", "chip": "TPU v5 lite",
             "topology": [1, 2, 2], "nbytes": 4096, "lat_nbytes": None,
             "protocol": {"edges": 8}, "seconds": 0.5,
             "links": [
@@ -352,7 +359,7 @@ class TestPerfReportScript:
         comms_path = tmp_path / "comms_roofline.json"
         mod = _load_script("perf_report")
         rc = mod.main([
-            str(work), "--chip", "TPU v5e",
+            str(work), "--chip", "TPU v5 lite",
             "--fabric", str(fabric_path), "--json", str(comms_path),
         ])
         assert rc == 0

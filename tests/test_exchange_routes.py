@@ -5,8 +5,7 @@ pallas): packed and direct exchanges are BITWISE identical across radii,
 uneven shards, halo multipliers, and multi-dtype fused messages; route
 resolution follows explicit > env > tuned > static-direct with structural
 degradation; the compile-reject ladder steps a packed route down to direct;
-realize's eager compile retries classified transients (the BENCH_r05
-remote-compile class); ``autotune_exchange`` measures the route space and
+realize's eager compile retries classified transients; ``autotune_exchange`` measures the route space and
 persists a winner the next realize picks up.
 """
 
@@ -263,8 +262,8 @@ def test_compile_reject_steps_down_to_direct(tune_dir, route):
 
 
 def test_realize_compile_retries_transient(monkeypatch):
-    """The remote-compile tunnel class (BENCH_r05's rc=1) is TRANSIENT: the
-    eager exchange compile retries under the policy instead of dying."""
+    """A connection drop during compile is TRANSIENT: the eager exchange
+    compile retries under the policy instead of dying."""
     monkeypatch.setenv("STENCIL_RETRY_BACKOFF_S", "0")
     before = telemetry.snapshot()["counters"][tm.RETRY_ATTEMPTS]
     inject.set_plan("compile:transient:compile:exchange:direct")

@@ -1,10 +1,10 @@
 """Tier-1: the perf ledger (stencil_tpu/telemetry/ledger.py +
-scripts/perf_ledger.py) — artifact normalization over the committed
-BENCH_r* files, idempotent appends, and the trailing-median regression
-gate flagging a synthetic regression.  The CLI subprocess run is tier-2
-``slow``."""
+scripts/perf_ledger.py) — artifact normalization over small BENCH_r*
+documents of the three shapes a harness leaves (written by the fixture
+below: what is under test is the ingest, not any round's numbers),
+idempotent appends, and the trailing-median regression gate flagging a
+synthetic regression.  The CLI subprocess run is tier-2 ``slow``."""
 
-import glob
 import importlib.util
 import json
 import os
@@ -16,7 +16,48 @@ import pytest
 from stencil_tpu.telemetry import ledger
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_ARTIFACTS = sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json")))
+
+
+@pytest.fixture
+def bench_artifacts(tmp_path):
+    """A five-round BENCH_r* series in the three artifact shapes: harness
+    wrappers with a ``parsed`` field (r01-r04), a failed run with
+    ``parsed: null`` and no artifact line in its tail (r05), and a raw
+    one-line bench document (the rerun)."""
+    d = tmp_path / "artifacts"
+    d.mkdir()
+
+    def doc(value, **extra):
+        return dict({"metric": "jacobi3d_mcells_per_s_per_chip",
+                     "value": value, "unit": "Mcells/s",
+                     "chip_copy_gbps": 500.0}, **extra)
+
+    paths = []
+    for n, value in ((1, 100.0), (2, 200.0), (3, 210.0), (4, 300.0)):
+        body = doc(value)
+        p = d / f"BENCH_r0{n}.json"
+        p.write_text(json.dumps({
+            "n": n, "cmd": "bench", "rc": 0,
+            "tail": "log line\n" + json.dumps(body) + "\n", "parsed": body,
+        }))
+        paths.append(p)
+    p = d / "BENCH_r05.json"
+    p.write_text(json.dumps({
+        "n": 5, "cmd": "bench", "rc": 1,
+        "tail": "Traceback (most recent call last):\n  ...\nRuntimeError",
+        "parsed": None,
+    }))
+    paths.append(p)
+    p = d / "BENCH_r05_rerun.json"
+    p.write_text(json.dumps(doc(
+        400.0, exchange_path_mcells_per_s_per_chip=350.0,
+        astaroth_8q_mupdates_per_s=80.0,
+    )))
+    paths.append(p)
+    # distinct mtimes, in round order: the ledger's clock is the file's
+    for i, p in enumerate(paths):
+        os.utime(p, (1e9 + i, 1e9 + i))
+    return [str(p) for p in paths]
 
 
 def _load_script(name):
@@ -28,9 +69,9 @@ def _load_script(name):
     return mod
 
 
-def _ingest_all(path):
+def _ingest_all(path, artifacts):
     entries = []
-    for f in BENCH_ARTIFACTS:
+    for f in artifacts:
         entries.extend(ledger.entries_from_artifact(f))
     return ledger.append_entries(str(path), entries)
 
@@ -39,25 +80,24 @@ def _ingest_all(path):
 
 
 class TestIngest:
-    def test_bench_r_series(self, tmp_path):
-        """The acceptance pin: the existing BENCH_r01-r05 artifacts ingest
-        into the headline series (r05 proper died pre-artifact — its data
-        rides the judge rerun), newest value the r05 rerun's 143724.5."""
+    def test_bench_r_series(self, tmp_path, bench_artifacts):
+        """The acceptance pin: a BENCH_r01-r05 series ingests into the
+        headline series (r05 proper died pre-artifact and contributes
+        nothing — its data rides the rerun), newest value the rerun's."""
         led = tmp_path / "ledger.jsonl"
-        n = _ingest_all(led)
-        assert n >= 10
+        n = _ingest_all(led, bench_artifacts)
+        assert n == 12  # 5 headline + 5 chip_copy + the rerun's 2 companions
         entries = ledger.read_ledger(str(led))
         headline = [
             e for e in entries if e["key"] == "jacobi3d_mcells_per_s_per_chip"
         ]
-        assert len(headline) >= 5  # r01-r04 + the r05 judge rerun
-        assert {e["source"] for e in headline} >= {
-            "BENCH_r01.json", "BENCH_r04.json", "BENCH_r05_judge_rerun.json",
-        }
-        values = [e["value"] for e in headline]
-        assert min(values) == pytest.approx(15595.4)  # r01
+        assert [e["source"] for e in headline] == [
+            "BENCH_r01.json", "BENCH_r02.json", "BENCH_r03.json",
+            "BENCH_r04.json", "BENCH_r05_rerun.json",
+        ]
+        assert [e["value"] for e in headline] == [100.0, 200.0, 210.0, 300.0, 400.0]
         # re-ingesting is idempotent (dedupe on key+source)
-        assert _ingest_all(led) == 0
+        assert _ingest_all(led, bench_artifacts) == 0
         assert len(ledger.read_ledger(str(led))) == len(entries)
 
     def test_judge_wrapper_and_tail_fallback(self, tmp_path):
@@ -231,11 +271,11 @@ class TestIngest:
 
 
 class TestGate:
-    def test_synthetic_regression_flagged(self, tmp_path):
-        """THE acceptance pin: the real BENCH trajectory passes the gate;
+    def test_synthetic_regression_flagged(self, tmp_path, bench_artifacts):
+        """THE acceptance pin: a rising BENCH trajectory passes the gate;
         one synthetic 40%-down headline entry flips it."""
         led = tmp_path / "ledger.jsonl"
-        _ingest_all(led)
+        _ingest_all(led, bench_artifacts)
         rows, regressions = ledger.check_regressions(ledger.read_ledger(str(led)))
         assert regressions == []  # the r01->r05 trajectory only went up
         headline = next(
@@ -331,11 +371,12 @@ def test_repeat_source_grows_the_series(tmp_path):
 
 
 class TestCLI:
-    def test_ingest_then_check(self, tmp_path, capsys):
+    def test_ingest_then_check(self, tmp_path, capsys, bench_artifacts):
         mod = _load_script("perf_ledger")
         led = str(tmp_path / "ledger.jsonl")
         rc = mod.main(
-            ["--ledger", led, "ingest", os.path.join(REPO, "BENCH_r*.json")]
+            ["--ledger", led, "ingest",
+             os.path.join(os.path.dirname(bench_artifacts[0]), "BENCH_r*.json")]
         )
         assert rc == 0
         assert mod.main(["--ledger", led, "check"]) == 0
@@ -359,13 +400,13 @@ class TestCLI:
 
 
 @pytest.mark.slow
-def test_cli_subprocess_gate(tmp_path):
+def test_cli_subprocess_gate(tmp_path, bench_artifacts):
     """scripts/perf_ledger.py as a subprocess — the tier-2 check shape:
-    ingest the committed artifacts, run the gate, exit 0."""
+    ingest a series of artifacts, run the gate, exit 0."""
     led = str(tmp_path / "ledger.jsonl")
     script = os.path.join(REPO, "scripts", "perf_ledger.py")
     ing = subprocess.run(
-        [sys.executable, script, "--ledger", led, "ingest"] + BENCH_ARTIFACTS,
+        [sys.executable, script, "--ledger", led, "ingest"] + bench_artifacts,
         capture_output=True, text=True, timeout=120,
     )
     assert ing.returncode == 0, ing.stderr

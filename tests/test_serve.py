@@ -66,7 +66,7 @@ class FakeClock:
 def make_server(**kw) -> StencilServer:
     kw.setdefault("clock", FakeClock())
     kw.setdefault("sleep", lambda s: None)
-    kw.setdefault("aot", AOTCache(stamp_dir=None, clock=kw["clock"]))
+    kw.setdefault("aot", AOTCache(stamp_dir="", clock=kw["clock"]))
     return StencilServer(**kw)
 
 
@@ -168,13 +168,13 @@ class TestRetryJitterAndBudget:
         def flaky_once(state=[0]):
             state[0] += 1
             if state[0] == 1:
-                raise RuntimeError("unavailable: tunnel dropped")
+                raise RuntimeError("unavailable: connection dropped")
 
         execute_with_retry(flaky_once, policy=policy, budget=budget, sleep=lambda s: None)
         assert budget.remaining == 1
 
         def always_flaky():
-            raise RuntimeError("unavailable: tunnel dropped")
+            raise RuntimeError("unavailable: connection dropped")
 
         calls = []
         with pytest.raises(RuntimeError):
@@ -320,7 +320,7 @@ class TestAOTStamps:
     def test_stamp_survives_a_process_restart(self, tmp_path):
         """A key compiled by one cache instance is ``stamped`` for the
         next (new process): the re-compile runs WITHOUT the budget refusal
-        — with STENCIL_COMPILE_CACHE_DIR it is an XLA cache read."""
+        — a persistent-XLA-cache read."""
         d = str(tmp_path / "aot")
         clk = FakeClock()
         first = AOTCache(stamp_dir=d, clock=clk)

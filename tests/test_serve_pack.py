@@ -50,7 +50,7 @@ class FakeClock:
 def make_server(**kw) -> StencilServer:
     kw.setdefault("clock", FakeClock())
     kw.setdefault("sleep", lambda s: None)
-    kw.setdefault("aot", AOTCache(stamp_dir=None, clock=kw["clock"]))
+    kw.setdefault("aot", AOTCache(stamp_dir="", clock=kw["clock"]))
     return StencilServer(**kw)
 
 

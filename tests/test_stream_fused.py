@@ -323,10 +323,12 @@ def test_autotune_persists_halo_and_consult(tune_dir):
     # pin a fused winner and verify the next auto-mode build consults it
     # (pin the FULL wavefront shape — the search winner may be the plane
     # route, whose m=1 would make a bare route override structurally
-    # invalid and silently fall back to static)
+    # invalid and silently fall back to static — and overlap=off: the
+    # winner of a timing search on the CPU is as often the split twin,
+    # under which fused structurally degrades)
     key = dd.tune_key("stream")
-    win = dict(report.config, halo="fused", route="wavefront", m=2,
-               z_slabs=False, grouping="joint")
+    win = dict(report.config, halo="fused", overlap="off", route="wavefront",
+               m=2, z_slabs=False, grouping="joint")
     tune.record_config(key, win)
     tune.reset_memo()
     dd2, _ = _mk(mult=2)

@@ -261,7 +261,7 @@ def test_stream_alias_resolution_precedence(monkeypatch):
 
 
 def test_search_retries_transient_mid_measurement(monkeypatch):
-    """A tunnel drop during the timed rounds (not just at build) retries
+    """A connection drop during the timed rounds (not just at build) retries
     under the PR-1 policy instead of crashing the search."""
     monkeypatch.setenv("STENCIL_RETRY_MAX", "3")
     monkeypatch.setenv("STENCIL_RETRY_BACKOFF_S", "0.0")
@@ -273,7 +273,7 @@ def test_search_retries_transient_mid_measurement(monkeypatch):
             calls["n"] += 1
             if calls["n"] == 3:  # past build+warm: inside the timed protocol
                 raise RuntimeError(
-                    "UNAVAILABLE: connection reset by peer (remote compile tunnel)"
+                    "UNAVAILABLE: connection reset by peer (injected)"
                 )
         return run
 
@@ -447,37 +447,36 @@ def test_plan_stream_consults_and_validates(tune_dir):
 # --- compile cache + driver flags -------------------------------------------
 
 
-def test_compile_cache_knob(tmp_path, monkeypatch):
-    from stencil_tpu.utils.config import apply_compile_cache
+def test_compile_cache_rule(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set -> used untouched (nothing set in
+    code); unset -> the fixed in-checkout ``.jax_cache``, exported and
+    applied to the live config.  No other variable is consulted."""
+    import stencil_tpu
+    from stencil_tpu.utils import config
 
-    target = tmp_path / "xla-cache"
-    monkeypatch.setenv("STENCIL_COMPILE_CACHE_DIR", str(target))
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    live = jax.config.jax_compilation_cache_dir
     try:
-        path = apply_compile_cache()
-        assert path == str(target) and target.is_dir()
-        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(target)
-        assert jax.config.jax_compilation_cache_dir == str(target)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert config.apply_compile_cache() == "/elsewhere"
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == live  # left alone
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        checkout = os.path.dirname(os.path.dirname(stencil_tpu.__file__))
+        fixed = os.path.join(checkout, ".jax_cache")
+        assert config.compile_cache_dir() == fixed
+        assert config.apply_compile_cache() == fixed
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        # idempotent, and stable across calls (never a pid/time/temp name)
+        assert config.apply_compile_cache() == fixed
     finally:
-        jax.config.update("jax_compilation_cache_dir", None)
-    # a pre-existing jax-native knob wins deterministically (no
-    # import-order dependence): env and live config are left alone
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
-    assert apply_compile_cache() == "/elsewhere"
-    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "/elsewhere"
-    assert jax.config.jax_compilation_cache_dir is None
-    # unset -> no-op
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-    monkeypatch.delenv("STENCIL_COMPILE_CACHE_DIR")
-    assert apply_compile_cache() is None
-    # unusable path: the function runs at `import stencil_tpu`, so it must
-    # WARN (naming the knob) and run uncached, never crash the import
-    blocker = tmp_path / "a-file"
-    blocker.write_text("x")
-    monkeypatch.setenv("STENCIL_COMPILE_CACHE_DIR", str(blocker / "sub"))
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    assert apply_compile_cache() is None
-    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+        jax.config.update("jax_compilation_cache_dir", live)
+    # the rule reads jax's own variable only — no STENCIL_* name is left
+    import inspect
+
+    for fn in (config.compile_cache_dir, config.apply_compile_cache):
+        assert "STENCIL_" not in inspect.getsource(fn)
 
 
 def test_driver_tune_flags(tune_dir, tmp_path):

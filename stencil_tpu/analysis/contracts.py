@@ -41,9 +41,15 @@ walker or a source-level heuristic the tracer can defeat:
   traced shapes must fit the chip budget (``analysis/vmem.py``; the same
   verdict ``tune/space.py`` and the stream ladder consult statically).
 * ``span-registry``       — every dotted named-scope label in the traced
-  program is a registered span (``telemetry/names.py ALL_SPANS``): drift
+  program is a registered span or kernel name (``telemetry/names.py
+  ALL_SPANS`` / ``ALL_KERNELS``): drift
   the source-level ``span-name`` rule cannot see through f-strings or
   indirection falls out of device-time attribution silently.
+* ``kernel-name``         — every pallas call carries a registered kernel
+  name (``telemetry/names.py ALL_KERNELS``): its identity in a device trace.
+* ``exchange-scope``      — every ppermute sits under an ``exchange.<axis>``
+  sweep scope, a bare exchange program is >= 90% scoped, a program with no
+  ppermute carries no such scope: a trace tells exchange from glue by name.
 * ``kernel-race``         — the kernel verifier's deliberate descent
   (``analysis/kernels.py``): no two PARALLEL grid points of any pallas
   call write the same output block unless the writes are provably
@@ -987,14 +993,134 @@ class SpanRegistry(Contract):
         from stencil_tpu.telemetry import names as tm
 
         out: List[Finding] = []
+        # a named pallas call stamps its kernel name as the innermost scope
+        registered = tm.ALL_SPANS | tm.ALL_KERNELS
         for label in sorted(jx.scope_labels(art.closed)):
-            if label not in tm.ALL_SPANS:
+            if label not in registered:
                 out.append(
                     art.finding(
                         self.name,
                         f"named scope {label!r} is not a registered span — "
                         "add it to telemetry/names.py ALL_SPANS or rename "
                         "the scope",
+                    )
+                )
+        return out
+
+
+@register
+class KernelName(Contract):
+    name = "kernel-name"
+    why = (
+        "every pallas call carries a registered kernel name "
+        "(pl.pallas_call(name=...), telemetry/names.py ALL_KERNELS): the "
+        "name is the kernel's identity in a device trace — an unnamed call "
+        "is a custom-call told apart only by its result shape, and falls "
+        "out of every per-kernel metric"
+    )
+
+    def check(self, art: ProgramArtifact) -> List[Finding]:
+        from stencil_tpu.analysis import jaxpr as jx
+        from stencil_tpu.telemetry import names as tm
+
+        unnamed = sorted(
+            {
+                str(e.params.get("name"))
+                for e in jx.iter_eqns(art.closed)
+                if e.primitive.name == "pallas_call"
+                and e.params.get("name") not in tm.ALL_KERNELS
+            }
+        )
+        return [
+            art.finding(
+                self.name,
+                f"pallas call named {name!r} is not a registered kernel — "
+                "pass name=tm.KERNEL_* (telemetry/names.py ALL_KERNELS)",
+            )
+            for name in unnamed
+        ]
+
+
+@register
+class ExchangeScope(Contract):
+    name = "exchange-scope"
+    why = (
+        "the exchange is told from step glue BY NAME: every ppermute sits "
+        "under an exchange.<axis> sweep scope, a bare exchange program "
+        "carries one on >= 90% of its equations (slab cuts, reshapes and "
+        "blends included, not just the wire), and a program that moves "
+        "nothing between shards carries none"
+    )
+
+    #: share of a bare exchange program's leaf equations (containers -- jit,
+    #: shard_map, loops -- count through their bodies) under a sweep scope
+    MIN_COVERAGE = 0.9
+
+    def applies_to(self, art: ProgramArtifact) -> bool:
+        return art.kind in ("step", "exchange", "fn")
+
+    def check(self, art: ProgramArtifact) -> List[Finding]:
+        from stencil_tpu.analysis import jaxpr as jx
+        from stencil_tpu.telemetry import names as tm
+
+        sweeps = set(tm.EXCHANGE_AXIS_SPANS.values())
+        eqns, scoped = [], []
+
+        def visit(jaxpr, under: bool) -> None:
+            # a nested jit's equations (jnp.pad, ...) carry a stack relative
+            # to the call eqn: they inherit its scope, as their HLO op_name does
+            for e in jaxpr.eqns:
+                here = under or bool(
+                    sweeps & set(jx.name_stack_str(e).split("/"))
+                )
+                subs = (
+                    []
+                    if e.primitive.name in jx.OPAQUE_PRIMITIVES
+                    else list(jx.eqn_subjaxprs(e))
+                )
+                for sub in subs:
+                    visit(sub, here)
+                if subs:
+                    continue  # a container (jit, shard_map, loop): its body counts
+                eqns.append(e)
+                if here:
+                    scoped.append(e)
+
+        visit(getattr(art.closed, "jaxpr", art.closed), False)
+        scoped_ids = {id(e) for e in scoped}
+        permutes = [e for e in eqns if e.primitive.name == "ppermute"]
+        out: List[Finding] = []
+        bare = sum(1 for e in permutes if id(e) not in scoped_ids)
+        if bare:
+            out.append(
+                art.finding(
+                    self.name,
+                    f"{bare} of {len(permutes)} ppermute(s) sit under no "
+                    "exchange.<axis> sweep scope "
+                    "(names.exchange_axis_span) — a trace would read that "
+                    "wire time as step glue",
+                )
+            )
+        if not permutes and scoped:
+            out.append(
+                art.finding(
+                    self.name,
+                    f"{len(scoped)} equation(s) carry an exchange.<axis> "
+                    "scope in a program with no ppermute — the scope would "
+                    "bill step work to the exchange",
+                )
+            )
+        if art.kind == "exchange" and eqns:
+            share = len(scoped) / len(eqns)
+            if share < self.MIN_COVERAGE:
+                out.append(
+                    art.finding(
+                        self.name,
+                        f"only {len(scoped)} of {len(eqns)} equations of "
+                        "the exchange program sit under an exchange.<axis> "
+                        f"sweep scope ({share:.0%} < "
+                        f"{self.MIN_COVERAGE:.0%}): slab cuts, reshapes and "
+                        "blends must be scoped, not just the ppermute",
                     )
                 )
         return out

@@ -514,6 +514,10 @@ class DistributedDomain:
         topologies (``jax.experimental.topologies``), where ``make_step`` can
         then be lowered/compiled against abstract sharded shapes (used by the
         overlap-schedule proof, tests/test_overlap_schedule.py)."""
+        with telemetry.span(tm.SPAN_REALIZE):
+            self._realize(allocate)
+
+    def _realize(self, allocate: bool) -> None:
         self._radius.validate()
         if self._storage == "bf16":
             # the structural gate the model resolvers apply, repeated here
@@ -587,7 +591,8 @@ class DistributedDomain:
             # executable cache.
             if self._handles:
                 t0 = time.perf_counter()
-                self._exchange_fn.lower(self._curr).compile()
+                with telemetry.span(tm.EVENT_COMPILE, label="exchange:realize"):
+                    self._exchange_fn.lower(self._curr).compile()
                 self._record_exchange_compile(t0, "realize")
         else:
             self._exchange_route = self._resolve_exchange_route()
@@ -891,7 +896,8 @@ class DistributedDomain:
                 # dispatch() pattern) so injected connection drops exercise
                 # the same retry path real ones take
                 inject.maybe_fail("compile", label)
-                return fn.lower(self._curr).compile()
+                with telemetry.span(tm.EVENT_COMPILE, label=label):
+                    return fn.lower(self._curr).compile()
 
             execute_with_retry(compile_unit, label=label)
         return fn
@@ -1142,40 +1148,40 @@ class DistributedDomain:
             ].set(vals)
 
         spec = _qspec(h)
-        out = jax.jit(
-            shard_map(per_shard, mesh=self.mesh, in_specs=(spec,), out_specs=spec)
-        )(self._curr[h.name])
+        with telemetry.span(tm.SPAN_INIT, quantity=h.name):
+            out = jax.jit(
+                shard_map(per_shard, mesh=self.mesh, in_specs=(spec,), out_specs=spec)
+            )(self._curr[h.name])
         self._curr[h.name] = out
 
     # --- the hot path ---------------------------------------------------------
     @contextlib.contextmanager
-    def _phase_timer(self, attr: str, histogram: str, span_name: str = None,
-                     sync: bool = False):
-        """THE timing path for the per-call hot-loop phases: one
-        ``perf_counter`` pair feeds both the reference-parity ``DomainStats``
-        accumulator (``attr``) and the telemetry histogram/span.  Active when
-        exchange-stats (the reference's blocking per-call opt-in,
-        stencil.hpp:106-131) or telemetry is enabled; otherwise it yields
-        immediately — zero per-step formatting work.  ``sync=True`` adds the
-        honest device sync timing requires (see ``block_until_ready``)."""
-        if not (self._exchange_stats or telemetry.enabled()):
+    def _phase_timer(self, attr: str, histogram: str, span_name: str,
+                     sync: bool = False, **span_args):
+        """THE timing path for the per-call hot-loop phases.  The phase is
+        ALWAYS a ``telemetry.span`` (a profiler annotation: free without a
+        profiler session, never a sync).  When exchange-stats (the
+        reference's blocking per-call opt-in, stencil.hpp:106-131) or
+        telemetry is enabled, one ``perf_counter`` pair additionally feeds
+        the reference-parity ``DomainStats`` accumulator (``attr``) and the
+        telemetry histogram, and ``sync=True`` adds the honest device sync
+        that timing requires (see ``block_until_ready``) inside the span."""
+        with telemetry.span(span_name, **span_args):
+            if not (self._exchange_stats or telemetry.enabled()):
+                yield
+                return
+            t0 = time.perf_counter()
             yield
-            return
-        t0 = time.perf_counter()
-        yield
-        if sync:
-            self.block_until_ready()
-        dt = time.perf_counter() - t0
+            if sync:
+                self.block_until_ready()
+            dt = time.perf_counter() - t0
         setattr(self.stats, attr, getattr(self.stats, attr) + dt)
         telemetry.observe(histogram, dt)
-        if span_name is not None:
-            telemetry.record_span(span_name, t0, dt)
 
-    def _account_exchanges(self, n: int) -> None:
-        """Counter bookkeeping for ``n`` (possibly fused) halo exchanges:
-        analytic bytes via ``exchange_bytes_total`` (src/stencil.cu:6-25),
-        computed once and cached — counters are always live, so this must
-        stay a dict hit + two int adds on the hot path."""
+    def _model_exchange(self) -> int:
+        """Analytic bytes of ONE exchange via ``exchange_bytes_total``
+        (src/stencil.cu:6-25), modeled once with its per-hop and packed-route
+        decompositions and cached — the hot path is a None check."""
         if self._exchange_nbytes is None:
             self._exchange_nbytes = (
                 self.exchange_bytes_total() if self._handles else 0
@@ -1238,8 +1244,14 @@ class DistributedDomain:
                     kernels += nk
                 self._packed_nbytes = nbytes * self.num_subdomains()
                 self._packed_nkernels = kernels * self.num_subdomains()
+        return self._exchange_nbytes
+
+    def _account_exchanges(self, n: int) -> None:
+        """Counter bookkeeping for ``n`` (possibly fused) halo exchanges —
+        counters are always live, so this must stay a dict hit + two int
+        adds on the hot path."""
         telemetry.inc(tm.EXCHANGE_COUNT, n)
-        telemetry.inc(tm.EXCHANGE_BYTES, n * self._exchange_nbytes)
+        telemetry.inc(tm.EXCHANGE_BYTES, n * self._model_exchange())
         for counter, nb in self._hop_nbytes:
             telemetry.inc(counter, n * nb)
         if self._packed_nkernels:
@@ -1250,7 +1262,8 @@ class DistributedDomain:
         """Fill every quantity's halo shell (src/stencil.cu:670-864)."""
         assert self._realized
         with self._phase_timer(
-            "time_exchange", tm.EXCHANGE_SECONDS, tm.SPAN_EXCHANGE, sync=True
+            "time_exchange", tm.EXCHANGE_SECONDS, tm.SPAN_EXCHANGE, sync=True,
+            route=self._exchange_route, nbytes=self._model_exchange(), count=1,
         ):
             self._curr = self._watched_call(
                 "exchange", lambda: self._exchange_fn(self._curr)
@@ -1273,14 +1286,18 @@ class DistributedDomain:
                 return lax.fori_loop(0, s, lambda _, a: inner(a), arrays)
 
             self._exchange_many_fn = many
-        self._curr = self._exchange_many_fn(self._curr, steps)
+        with telemetry.span(
+            tm.SPAN_EXCHANGE, route=self._exchange_route,
+            nbytes=steps * self._model_exchange(), count=steps,
+        ):
+            self._curr = self._exchange_many_fn(self._curr, steps)
         self._shell_stale = False
         self._exchange_count += steps
         self._account_exchanges(steps)
 
     def swap(self) -> None:
         """Swap curr/next slots (src/stencil.cu:541-561)."""
-        with self._phase_timer("time_swap", tm.SWAP_SECONDS):
+        with self._phase_timer("time_swap", tm.SWAP_SECONDS, tm.SPAN_SWAP):
             self._curr, self._next = self._next, self._curr
 
     def block_until_ready(self) -> None:
@@ -1678,10 +1695,13 @@ class DistributedDomain:
 
         This is also the TELEMETRY boundary: the dispatch counters
         (``domain.step.*``) and analytic exchange bytes are always counted;
-        with telemetry enabled the dispatch is additionally honest-synced and
-        its wall time recorded as a span plus a per-raw-iteration histogram
-        sample (``domain.step.seconds``) — enabling telemetry therefore adds
-        one device sync per dispatch, exactly like exchange-stats.
+        the enqueue is always a ``domain.step`` span (a profiler annotation
+        [label, steps]: on the device's clock under a profiler session, free
+        without one, never a sync).  With ``STENCIL_TELEMETRY`` enabled the
+        dispatch is additionally honest-synced inside that span and a
+        per-raw-iteration histogram sample (``domain.step.seconds``) is taken
+        — enabling telemetry therefore adds one device sync per dispatch,
+        exactly like exchange-stats, and must stay off in a measured run.
         """
         from stencil_tpu.resilience import inject
         from stencil_tpu.resilience.retry import RetryPolicy, execute_with_retry
@@ -1701,16 +1721,19 @@ class DistributedDomain:
         raw = steps * getattr(step_fn, "_raw_steps_per_call", 1)
         timed = telemetry.enabled()
         t0 = time.perf_counter() if timed else 0.0
-        self._curr = execute_with_retry(
-            dispatch,
-            label=f"dispatch:{label}",
-            policy=self._retry_policy,
-            buffers=lambda: self._curr,
-        )
+        # the span is the ENQUEUE (a profiler annotation, never a sync);
+        # only STENCIL_TELEMETRY's honest timing waits inside it
+        with telemetry.span(tm.SPAN_STEP, label=label, steps=raw):
+            self._curr = execute_with_retry(
+                dispatch,
+                label=f"dispatch:{label}",
+                policy=self._retry_policy,
+                buffers=lambda: self._curr,
+            )
+            if timed:
+                self.block_until_ready()
         if timed:
-            self.block_until_ready()
             dt = time.perf_counter() - t0
-            telemetry.record_span(tm.SPAN_STEP, t0, dt, label=label, steps=raw)
             telemetry.observe(tm.STEP_SECONDS, dt / max(raw, 1))
         telemetry.inc(tm.STEP_DISPATCHES)
         telemetry.inc(tm.STEP_ITERATIONS, raw)

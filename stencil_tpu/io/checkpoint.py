@@ -141,7 +141,16 @@ def save_checkpoint(
     non-addressable shards), and process 0 alone writes the manifest —
     removed first, re-written after orbax completes, so it stays the
     commit marker.  Elastic (cross-mesh) restore is single-controller
-    only; multi-host restores require the same topology."""
+    only; multi-host restores require the same topology.
+
+    The whole commit runs under a ``checkpoint.save`` span [step]: the
+    gather + write + fsync is host work that stalls a dispatch pipeline, and
+    a profiler trace then shows it beside the device ops."""
+    with telemetry.span(tm.EVENT_CHECKPOINT_SAVE, step=int(step)):
+        return _save_checkpoint(dd, path, step, backend, run_state, reason, digests)
+
+
+def _save_checkpoint(dd, path, step, backend, run_state, reason, digests) -> str:
     import jax
 
     t0 = time.perf_counter()

@@ -32,16 +32,20 @@ def _ledger():
     return PALLAS_KERNELS
 
 
-def _issues_pallas_call(node: ast.FunctionDef) -> bool:
-    for sub in ast.walk(node):
+def _pallas_calls(tree: ast.AST):
+    """Every ``pallas_call(...)`` / ``pl.pallas_call(...)`` call under ``tree``."""
+    for sub in ast.walk(tree):
         if not isinstance(sub, ast.Call):
             continue
         fn = sub.func
-        if isinstance(fn, ast.Attribute) and fn.attr == "pallas_call":
-            return True
-        if isinstance(fn, ast.Name) and fn.id == "pallas_call":
-            return True
-    return False
+        if (isinstance(fn, ast.Attribute) and fn.attr == "pallas_call") or (
+            isinstance(fn, ast.Name) and fn.id == "pallas_call"
+        ):
+            yield sub
+
+
+def _issues_pallas_call(node: ast.FunctionDef) -> bool:
+    return next(_pallas_calls(node), None) is not None
 
 
 @register
@@ -78,6 +82,51 @@ class KernelLedgerRule(Rule):
                     "PALLAS_KERNELS in stencil_tpu/analysis/registry.py "
                     "(and reach it from the canonical matrix or the "
                     "fixture corpus) before shipping the kernel",
+                )
+            )
+        return out
+
+
+def _registered_kernel_name(node: ast.AST) -> bool:
+    """``tm.KERNEL_X`` / ``names.KERNEL_X`` naming an ``ALL_KERNELS`` entry,
+    or a string literal that is one."""
+    from stencil_tpu.telemetry import names
+
+    if isinstance(node, ast.Attribute) and isinstance(node.value, ast.Name):
+        if node.value.id in {"tm", "names"} and node.attr.startswith("KERNEL_"):
+            return getattr(names, node.attr, None) in names.ALL_KERNELS
+    if isinstance(node, ast.Constant) and isinstance(node.value, str):
+        return node.value in names.ALL_KERNELS
+    return False
+
+
+@register
+class KernelNameRule(Rule):
+    name = "kernel-name"
+    why = (
+        "every ops/ pallas_call passes name= from the kernel registry "
+        "(telemetry/names.py ALL_KERNELS): the name is the kernel's identity "
+        "in a device trace — without it the op is a custom-call told apart "
+        "only by its result shape, and falls out of every per-kernel metric"
+    )
+
+    def applies_to(self, rel: str) -> bool:
+        return rel.replace("\\", "/").startswith("stencil_tpu/ops/")
+
+    def check(self, ctx: FileContext) -> List[Violation]:
+        out: List[Violation] = []
+        for call in _pallas_calls(ctx.tree):
+            name = next((kw.value for kw in call.keywords if kw.arg == "name"), None)
+            if name is not None and _registered_kernel_name(name):
+                continue
+            out.append(
+                ctx.violation(
+                    self.name,
+                    call,
+                    "pallas_call without a registered name= — pass "
+                    "name=tm.KERNEL_* (add the constant to stencil_tpu/"
+                    "telemetry/names.py ALL_KERNELS: one name per kernel "
+                    "family, stable across depth, radius, shape and dtype)",
                 )
             )
         return out

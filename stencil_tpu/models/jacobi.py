@@ -846,6 +846,7 @@ class Jacobi3D:
         from jax.sharding import PartitionSpec as P
 
         from stencil_tpu.ops.exchange import _shift_from_high, _shift_from_low
+        from stencil_tpu.telemetry import names as tm
         from stencil_tpu.ops.jacobi_pallas import jacobi_slab_step, yz_dist2_plane
         from stencil_tpu.parallel.mesh import MESH_AXES
 
@@ -877,12 +878,17 @@ class Jacobi3D:
                 # -dir convention at radius 1 (packer.cuh:91-93); z-slabs
                 # travel transposed so lanes ride the x axis (see
                 # jacobi_slab_step's layout note)
-                xlo = _shift_from_low(b[n.x - 1], MESH_AXES[0], mesh_shape[0])
-                xhi = _shift_from_high(b[0], MESH_AXES[0], mesh_shape[0])
-                ylo = _shift_from_low(b[:, n.y - 1, :], MESH_AXES[1], mesh_shape[1])
-                yhi = _shift_from_high(b[:, 0, :], MESH_AXES[1], mesh_shape[1])
-                zlo = _shift_from_low(b[:, :, n.z - 1].T, MESH_AXES[2], mesh_shape[2])
-                zhi = _shift_from_high(b[:, :, 0].T, MESH_AXES[2], mesh_shape[2])
+                # (cut + wire of each axis under its exchange.<axis> scope,
+                # like the shell-carrying sweeps)
+                with jax.named_scope(tm.SPAN_EXCHANGE_X):
+                    xlo = _shift_from_low(b[n.x - 1], MESH_AXES[0], mesh_shape[0])
+                    xhi = _shift_from_high(b[0], MESH_AXES[0], mesh_shape[0])
+                with jax.named_scope(tm.SPAN_EXCHANGE_Y):
+                    ylo = _shift_from_low(b[:, n.y - 1, :], MESH_AXES[1], mesh_shape[1])
+                    yhi = _shift_from_high(b[:, 0, :], MESH_AXES[1], mesh_shape[1])
+                with jax.named_scope(tm.SPAN_EXCHANGE_Z):
+                    zlo = _shift_from_low(b[:, :, n.z - 1].T, MESH_AXES[2], mesh_shape[2])
+                    zhi = _shift_from_high(b[:, :, 0].T, MESH_AXES[2], mesh_shape[2])
                 return jacobi_slab_step(
                     b, xlo, xhi, ylo, yhi, zlo, zhi, origin, yz_d2, gsize,
                     interpret=interpret, f32_accumulate=f32_acc,

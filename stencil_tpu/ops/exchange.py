@@ -425,6 +425,129 @@ def _ypack_sweep(
     return out_blocks
 
 
+def _axis_sweep(
+    blocks: List[jax.Array],
+    axis: int,
+    r_lo: int,
+    r_hi: int,
+    name: str,
+    n_dev: int,
+    size: int,
+    v_last: Optional[int],
+    route: str,
+) -> List[jax.Array]:
+    """One axis sweep of ``halo_exchange_multi`` (which enters the
+    ``exchange.<axis>`` scope around it): ``size`` is the raw extent on this
+    axis, ``v_last`` the last shard's valid interior cells (None = even)."""
+    n_pad = size - r_lo - r_hi  # per-shard (padded) interior width
+    uneven = v_last is not None and v_last != n_pad
+
+    # a packed route engages per SWEEP: the y sweep packs on the
+    # yzpack_* routes, the z sweep on every packed route; a sweep that
+    # structurally cannot engage (uneven axis, unsupported dtype)
+    # silently runs direct, so a pinned route is always correct
+    if route in Y_PACK_ROUTES and axis == 1 and not uneven:
+        from stencil_tpu.ops import halo_blend
+
+        if all(halo_blend.supports(b.dtype) for b in blocks):
+            return _ypack_sweep(blocks, r_lo, r_hi, n_pad, name, n_dev, route)
+    if route != "direct" and axis == 2 and not uneven:
+        from stencil_tpu.ops import halo_blend
+
+        if all(halo_blend.supports(b.dtype) for b in blocks):
+            return _zpack_sweep(blocks, r_lo, r_hi, n_pad, name, n_dev, route)
+
+    def axslice(b, lo, hi):
+        idx = [slice(None)] * b.ndim
+        idx[b.ndim - 3 + axis] = slice(lo, hi)
+        return tuple(idx)
+
+    def dyn_starts(b, start):
+        s = [jnp.int32(0)] * b.ndim
+        s[b.ndim - 3 + axis] = start
+        return tuple(s)
+
+    def slab_sizes(b, w):
+        s = list(b.shape)
+        s[b.ndim - 3 + axis] = w
+        return tuple(s)
+
+    if uneven:
+        idx = lax.axis_index(name)
+        n_valid = jnp.where(idx == n_dev - 1, v_last, n_pad).astype(jnp.int32)
+
+    def through_permute(slabs, shift_fn):
+        if axis != 0:
+            return _fused_shift(slabs, shift_fn, name, n_dev)
+        # axis-0 slabs (r, Y, Z) travel as (1, r*Y, Z): the slice is
+        # contiguous, and the 2D-spatial buffer keeps XLA's layout
+        # assignment from giving the permute operand a transposed layout
+        # whose feeder is a full-domain relayout copy (seen as a ~3 ms
+        # {2,1,0}->{2,0,1} copy per macro step in the wavefront loop)
+        shapes = [s.shape for s in slabs]
+        flat = [
+            s.reshape(s.shape[:-3] + (1, s.shape[-3] * s.shape[-2], s.shape[-1]))
+            for s in slabs
+        ]
+        out = _fused_shift(flat, shift_fn, name, n_dev)
+        return [o.reshape(sh) for o, sh in zip(out, shapes)]
+
+    lo_recv = hi_recv = None
+    if r_lo > 0:
+        # my low halo [0, r_lo) <- -axis neighbor's top slab of VALID
+        # interior, width r_lo (message traveling +axis has extent
+        # radius(-axis)).  Uneven: top r_lo rows of my valid interior,
+        # [n_valid, n_valid + r_lo) in allocation coords.
+        slabs = [
+            lax.dynamic_slice(b, dyn_starts(b, n_valid), slab_sizes(b, r_lo))
+            if uneven
+            else b[axslice(b, n_pad, r_lo + n_pad)]
+            for b in blocks
+        ]
+        lo_recv = through_permute(slabs, _shift_from_low)
+    if r_hi > 0:
+        # my high halo <- +axis neighbor's interior bottom slab, width
+        # r_hi, written right after MY valid cells
+        slabs = [b[axslice(b, r_lo, r_lo + r_hi)] for b in blocks]
+        hi_recv = through_permute(slabs, _shift_from_high)
+    # y/z halo writes go through tile-local pallas blend kernels where
+    # possible: plain DUS slivers on those axes bait XLA's layout
+    # assignment into transposing the whole array (two full-domain
+    # relayout copies per exchange — see ops/halo_blend.py).
+    from stencil_tpu.ops import halo_blend
+
+    blend = halo_blend.enabled() and all(
+        b.ndim == 3 and halo_blend.supports(b.dtype) for b in blocks
+    )
+    interp = halo_blend.interpret_mode()
+    for j, b in enumerate(blocks):
+        if lo_recv is not None:
+            # the low halo sits at 0 even on padded axes, so the static
+            # kernel serves both cases
+            if blend:
+                b = halo_blend.blend_slab(b, lo_recv[j], axis, 0, interpret=interp)
+            else:
+                b = b.at[axslice(b, 0, r_lo)].set(lo_recv[j])
+        if hi_recv is not None:
+            if uneven and blend and axis != 0:
+                b = halo_blend.blend_slab_dynamic(
+                    b, hi_recv[j], axis, r_lo + n_valid, interpret=interp
+                )
+            elif uneven:
+                # stencil-lint: disable=sliver-dus axis-0 traced offset: an x-plane DUS is contiguous in the (8,128) tiling, no relayout bait
+                b = lax.dynamic_update_slice(
+                    b, hi_recv[j], dyn_starts(b, r_lo + n_valid)
+                )
+            elif blend:
+                b = halo_blend.blend_slab(
+                    b, hi_recv[j], axis, r_lo + n_pad, interpret=interp
+                )
+            else:
+                b = b.at[axslice(b, r_lo + n_pad, size)].set(hi_recv[j])
+        blocks[j] = b
+    return blocks
+
+
 def halo_exchange_multi(
     blocks: Sequence[jax.Array],
     radius: Radius,
@@ -478,117 +601,15 @@ def halo_exchange_multi(
         if r_lo == 0 and r_hi == 0:
             continue
         name = axis_names[axis]
-        n_dev = mesh_shape[axis]
-        size = spatial[axis]  # raw extent on this axis
-        n_pad = size - r_lo - r_hi  # per-shard (padded) interior width
-        v_last = valid_last[axis] if valid_last is not None else None
-        uneven = v_last is not None and v_last != n_pad
-
-        # a packed route engages per SWEEP: the y sweep packs on the
-        # yzpack_* routes, the z sweep on every packed route; a sweep that
-        # structurally cannot engage (uneven axis, unsupported dtype)
-        # silently runs direct, so a pinned route is always correct
-        if route in Y_PACK_ROUTES and axis == 1 and not uneven:
-            from stencil_tpu.ops import halo_blend
-
-            if all(halo_blend.supports(b.dtype) for b in blocks):
-                blocks = _ypack_sweep(blocks, r_lo, r_hi, n_pad, name, n_dev, route)
-                continue
-        if route != "direct" and axis == 2 and not uneven:
-            from stencil_tpu.ops import halo_blend
-
-            if all(halo_blend.supports(b.dtype) for b in blocks):
-                blocks = _zpack_sweep(blocks, r_lo, r_hi, n_pad, name, n_dev, route)
-                continue
-
-        def axslice(b, lo, hi):
-            idx = [slice(None)] * b.ndim
-            idx[b.ndim - 3 + axis] = slice(lo, hi)
-            return tuple(idx)
-
-        def dyn_starts(b, start):
-            s = [jnp.int32(0)] * b.ndim
-            s[b.ndim - 3 + axis] = start
-            return tuple(s)
-
-        def slab_sizes(b, w):
-            s = list(b.shape)
-            s[b.ndim - 3 + axis] = w
-            return tuple(s)
-
-        if uneven:
-            idx = lax.axis_index(name)
-            n_valid = jnp.where(idx == n_dev - 1, v_last, n_pad).astype(jnp.int32)
-
-        def through_permute(slabs, shift_fn):
-            if axis != 0:
-                return _fused_shift(slabs, shift_fn, name, n_dev)
-            # axis-0 slabs (r, Y, Z) travel as (1, r*Y, Z): the slice is
-            # contiguous, and the 2D-spatial buffer keeps XLA's layout
-            # assignment from giving the permute operand a transposed layout
-            # whose feeder is a full-domain relayout copy (seen as a ~3 ms
-            # {2,1,0}->{2,0,1} copy per macro step in the wavefront loop)
-            shapes = [s.shape for s in slabs]
-            flat = [
-                s.reshape(s.shape[:-3] + (1, s.shape[-3] * s.shape[-2], s.shape[-1]))
-                for s in slabs
-            ]
-            out = _fused_shift(flat, shift_fn, name, n_dev)
-            return [o.reshape(sh) for o, sh in zip(out, shapes)]
-
-        lo_recv = hi_recv = None
-        if r_lo > 0:
-            # my low halo [0, r_lo) <- -axis neighbor's top slab of VALID
-            # interior, width r_lo (message traveling +axis has extent
-            # radius(-axis)).  Uneven: top r_lo rows of my valid interior,
-            # [n_valid, n_valid + r_lo) in allocation coords.
-            slabs = [
-                lax.dynamic_slice(b, dyn_starts(b, n_valid), slab_sizes(b, r_lo))
-                if uneven
-                else b[axslice(b, n_pad, r_lo + n_pad)]
-                for b in blocks
-            ]
-            lo_recv = through_permute(slabs, _shift_from_low)
-        if r_hi > 0:
-            # my high halo <- +axis neighbor's interior bottom slab, width
-            # r_hi, written right after MY valid cells
-            slabs = [b[axslice(b, r_lo, r_lo + r_hi)] for b in blocks]
-            hi_recv = through_permute(slabs, _shift_from_high)
-        # y/z halo writes go through tile-local pallas blend kernels where
-        # possible: plain DUS slivers on those axes bait XLA's layout
-        # assignment into transposing the whole array (two full-domain
-        # relayout copies per exchange — see ops/halo_blend.py).
-        from stencil_tpu.ops import halo_blend
-
-        blend = halo_blend.enabled() and all(
-            b.ndim == 3 and halo_blend.supports(b.dtype) for b in blocks
-        )
-        interp = halo_blend.interpret_mode()
-        for j, b in enumerate(blocks):
-            if lo_recv is not None:
-                # the low halo sits at 0 even on padded axes, so the static
-                # kernel serves both cases
-                if blend:
-                    b = halo_blend.blend_slab(b, lo_recv[j], axis, 0, interpret=interp)
-                else:
-                    b = b.at[axslice(b, 0, r_lo)].set(lo_recv[j])
-            if hi_recv is not None:
-                if uneven and blend and axis != 0:
-                    b = halo_blend.blend_slab_dynamic(
-                        b, hi_recv[j], axis, r_lo + n_valid, interpret=interp
-                    )
-                elif uneven:
-                    # stencil-lint: disable=sliver-dus axis-0 traced offset: an x-plane DUS is contiguous in the (8,128) tiling, no relayout bait
-                    b = lax.dynamic_update_slice(
-                        b, hi_recv[j], dyn_starts(b, r_lo + n_valid)
-                    )
-                elif blend:
-                    b = halo_blend.blend_slab(
-                        b, hi_recv[j], axis, r_lo + n_pad, interpret=interp
-                    )
-                else:
-                    b = b.at[axslice(b, r_lo + n_pad, size)].set(hi_recv[j])
-            blocks[j] = b
+        # the whole sweep -- slab cut / pack, the wire, unpack / blend -- sits
+        # under ONE registered scope: every instruction the exchange adds
+        # carries ``exchange.<axis>`` in its HLO op_name (the per-direction
+        # wire scopes nest inside), so a trace tells it from step glue
+        with jax.named_scope(tm.exchange_axis_span(name)):
+            blocks = _axis_sweep(
+                blocks, axis, r_lo, r_hi, name, mesh_shape[axis], spatial[axis],
+                valid_last[axis] if valid_last is not None else None, route,
+            )
     return blocks
 
 
@@ -681,8 +702,15 @@ def fused_shell_exchange(
         out = _fused_shift(flat, shift_fn, axis_names[0], mesh_shape[0])
         return [o.reshape(sh) for o, sh in zip(out, shapes)]
 
-    xlo = permute_x([b[n[0] : n[0] + lo[0]] for b in blocks], _shift_from_low)
-    xhi = permute_x([b[lo[0] : lo[0] + hi[0]] for b in blocks], _shift_from_high)
+    # each sweep (cut / pack, corner patch, wire) and the final orientation of
+    # its buffers sit under that axis's ``exchange.<axis>`` scope, like the
+    # in-array sweeps of ``halo_exchange_multi``
+    scope_x, scope_y, scope_z = (
+        partial(jax.named_scope, tm.exchange_axis_span(a)) for a in axis_names
+    )
+    with scope_x():
+        xlo = permute_x([b[n[0] : n[0] + lo[0]] for b in blocks], _shift_from_low)
+        xhi = permute_x([b[lo[0] : lo[0] + hi[0]] for b in blocks], _shift_from_high)
 
     # --- y sweep: packed (2m, X, Z) buffers, x-corner-patched pre-permute ---
     def pack_y(y0, depth):
@@ -707,8 +735,9 @@ def fused_shell_exchange(
             out.append(buf)
         return out
 
-    ylo = _fused_shift(pack_y(n[1], lo[1]), _shift_from_low, axis_names[1], mesh_shape[1])
-    yhi = _fused_shift(pack_y(lo[1], hi[1]), _shift_from_high, axis_names[1], mesh_shape[1])
+    with scope_y():
+        ylo = _fused_shift(pack_y(n[1], lo[1]), _shift_from_low, axis_names[1], mesh_shape[1])
+        yhi = _fused_shift(pack_y(lo[1], hi[1]), _shift_from_high, axis_names[1], mesh_shape[1])
 
     # --- z sweep: packed (2m, Y, Xpad) buffers, x+y-corner-patched ----------
     def pack_z(z0, depth):
@@ -740,18 +769,22 @@ def fused_shell_exchange(
             out.append(buf)
         return out
 
-    zlo = _fused_shift(pack_z(n[2], lo[2]), _shift_from_low, axis_names[2], mesh_shape[2])
-    zhi = _fused_shift(pack_z(lo[2], hi[2]), _shift_from_high, axis_names[2], mesh_shape[2])
+    with scope_z():
+        zlo = _fused_shift(pack_z(n[2], lo[2]), _shift_from_low, axis_names[2], mesh_shape[2])
+        zhi = _fused_shift(pack_z(lo[2], hi[2]), _shift_from_high, axis_names[2], mesh_shape[2])
 
-    xbufs = [jnp.concatenate([xlo[q], xhi[q]], axis=0) for q in range(len(blocks))]
-    ybufs = [
-        jnp.transpose(jnp.concatenate([ylo[q], yhi[q]], axis=0), (1, 0, 2))
-        for q in range(len(blocks))
-    ]
-    zbufs = [
-        jnp.transpose(jnp.concatenate([zlo[q], zhi[q]], axis=0), (2, 0, 1))[:X]
-        for q in range(len(blocks))
-    ]
+    with scope_x():
+        xbufs = [jnp.concatenate([xlo[q], xhi[q]], axis=0) for q in range(len(blocks))]
+    with scope_y():
+        ybufs = [
+            jnp.transpose(jnp.concatenate([ylo[q], yhi[q]], axis=0), (1, 0, 2))
+            for q in range(len(blocks))
+        ]
+    with scope_z():
+        zbufs = [
+            jnp.transpose(jnp.concatenate([zlo[q], zhi[q]], axis=0), (2, 0, 1))[:X]
+            for q in range(len(blocks))
+        ]
     return xbufs, ybufs, zbufs
 
 

@@ -31,6 +31,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from stencil_tpu.telemetry import names as tm
 from stencil_tpu.utils.config import pallas_interpret
 
 
@@ -115,6 +116,7 @@ def blend_slab(
 
         return pl.pallas_call(
             kernel0,
+            name=tm.KERNEL_BLEND_PLANES,
             grid=(r,),
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -167,6 +169,7 @@ def blend_slab(
 
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_BLEND_SLAB,
         grid=(gx, nb),
         in_specs=[
             pl.BlockSpec(blk, index),
@@ -267,6 +270,7 @@ def blend_slab_dynamic(
     )
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_BLEND_SLAB_DYNAMIC,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(block.shape, block.dtype),
         input_output_aliases={1: 0},

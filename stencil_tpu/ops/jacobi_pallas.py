@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from stencil_tpu.core.dim3 import Dim3
+from stencil_tpu.telemetry import names as tm
 
 HOT_TEMP = 1.0
 COLD_TEMP = 0.0
@@ -164,7 +165,6 @@ def resolve_compute_unit(
         val, source = "vpu", source + "/degraded"
     if emit:
         from stencil_tpu import telemetry
-        from stencil_tpu.telemetry import names as tm
 
         telemetry.emit_event(
             tm.EVENT_KERNEL_COMPUTE_UNIT, unit=val, source=source, where=where
@@ -203,7 +203,6 @@ def resolve_storage_dtype(
         )
         val, source = "native", source + "/degraded"
     from stencil_tpu import telemetry
-    from stencil_tpu.telemetry import names as tm
 
     telemetry.emit_event(
         tm.EVENT_KERNEL_STORAGE_DTYPE, storage=val, source=source, where=where
@@ -238,7 +237,6 @@ def resolve_mxu_input(
         val, source = "f32", source + "/degraded"
     if emit:
         from stencil_tpu import telemetry
-        from stencil_tpu.telemetry import names as tm
 
         telemetry.emit_event(
             tm.EVENT_KERNEL_MXU_INPUT,
@@ -972,6 +970,7 @@ def jacobi_wrap_step(
         args += b_args
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_JACOBI_WRAP,
         grid=(X + 2 * k,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Y, Z), lambda i: ((i - k) % X, 0, 0)),
@@ -1164,6 +1163,7 @@ def jacobi_shell_wavefront_step(
         args += [z_slabs]
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_JACOBI_SHELL_WAVEFRONT,
         grid=(Xr,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -1329,6 +1329,7 @@ def jacobi_zring_wavefront_step(
         args += b_args
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_JACOBI_ZRING_WAVEFRONT,
         grid=(Xr,),
         in_specs=in_specs,
         out_specs=(
@@ -1458,6 +1459,7 @@ def jacobi_slab_step(
     const = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_JACOBI_SLAB,
         grid=(X + 1,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -1546,6 +1548,7 @@ def jacobi_plane_step(
 
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_JACOBI_PLANE,
         grid=(X + 1,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

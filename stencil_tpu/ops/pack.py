@@ -68,6 +68,7 @@ from jax import lax
 
 from stencil_tpu.core.dim3 import Dim3
 from stencil_tpu.core.geometry import LocalSpec
+from stencil_tpu.telemetry import names as tm
 
 
 def next_align_of(x: int, align: int) -> int:
@@ -211,6 +212,7 @@ def pallas_pack_slab(block: jax.Array, pos: Dim3, ext: Dim3, interpret: bool = F
 
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_PACK_SLAB,
         grid=(ext.x,),
         # one full x-plane per step: HBM->VMEM movement must be lane-tile
         # aligned, so the pipeline streams whole planes and the VPU cuts the
@@ -241,6 +243,7 @@ def pallas_unpack_slab(
     plane = pl.BlockSpec((1, raw_y, raw_z), lambda i: (pos.x + i, 0, 0))
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_UNPACK_SLAB,
         grid=(ext.x,),
         in_specs=[plane, pl.BlockSpec((1, ext.y, ext.z), lambda i: (i, 0, 0))],
         out_specs=plane,
@@ -347,6 +350,7 @@ def pack_zshell_pallas(
 
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_PACK_ZSHELL,
         grid=(X,),
         in_specs=[pl.BlockSpec((1, Y, Z), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((depth, Y, 1), lambda i: (0, 0, i)),
@@ -375,6 +379,7 @@ def unpack_zshell_pallas(
     plane = pl.BlockSpec((1, Y, Z), lambda i: (i, 0, 0))
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_UNPACK_ZSHELL,
         grid=(X,),
         in_specs=[plane, pl.BlockSpec((depth, Y, 1), lambda i: (0, 0, i))],
         out_specs=plane,
@@ -436,6 +441,7 @@ def pack_yshell_pallas(
 
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_PACK_YSHELL,
         grid=(X,),
         in_specs=[pl.BlockSpec((1, Y, Z), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((depth, 1, Z), lambda i: (0, i, 0)),
@@ -465,6 +471,7 @@ def unpack_yshell_pallas(
     plane = pl.BlockSpec((1, Y, Z), lambda i: (i, 0, 0))
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_UNPACK_YSHELL,
         grid=(X,),
         in_specs=[plane, pl.BlockSpec((depth, 1, Z), lambda i: (0, i, 0))],
         out_specs=plane,

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from stencil_tpu.core.dim3 import Dim3
+from stencil_tpu.telemetry import names as tm
 
 
 def mean6_shell_wavefront_step(
@@ -99,6 +100,7 @@ def mean6_shell_wavefront_step(
         args += b_args
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_MEAN6_SHELL_WAVEFRONT,
         grid=(Xr,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Yr, Zr), lambda i: (jnp.maximum(i - m, 0), 0, 0)),
@@ -212,6 +214,7 @@ def mean6_plane_step(
         args += b_args
     return pl.pallas_call(
         kernel,
+        name=tm.KERNEL_MEAN6_PLANE,
         grid=(X + 1,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Y, Z), lambda i: (jnp.clip(i - 1, 0, X - 1), 0, 0)),

@@ -465,6 +465,7 @@ def stream_plane_pass(
     )
     outs = pl.pallas_call(
         body,
+        name=tm.KERNEL_STREAM_PLANE_PASS,
         grid=(X + r,),
         in_specs=in_specs,
         out_specs=out_specs if nq > 1 else out_specs[0],
@@ -678,6 +679,7 @@ def stream_wavefront_pass(
     aliases = {1 + q: q for q in range(nq)} if alias else {}
     outs = pl.pallas_call(
         body,
+        name=tm.KERNEL_STREAM_WAVEFRONT_PASS,
         grid=(Xr,),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
@@ -781,6 +783,7 @@ def stream_wrap_pass(
         args += b_args
     outs = pl.pallas_call(
         body,
+        name=tm.KERNEL_STREAM_WRAP_PASS,
         grid=(X + 2 * k,),
         in_specs=in_specs,
         out_specs=tuple(
@@ -1087,14 +1090,16 @@ def prime_z_slabs(block: jax.Array, Zr: int, s: int) -> jax.Array:
     """The initial outgoing z-slab buffer for a macro chain: the block's
     interior z-boundary columns, packed [(-z)-bound | (+z)-bound] and
     transposed z-major (Xr, 2s, Yr) — the one strided read per dispatch;
-    every later slab is kernel-emitted."""
-    return jnp.concatenate(
-        [
-            jnp.swapaxes(block[:, :, Zr - 2 * s : Zr - s], 1, 2),
-            jnp.swapaxes(block[:, :, s : 2 * s], 1, 2),
-        ],
-        axis=1,
-    )
+    every later slab is kernel-emitted.  Exchange work (the z slab cut), so
+    it sits under the ``exchange.z`` scope like the sweep it primes."""
+    with telemetry.annotate(tm.SPAN_EXCHANGE_Z):
+        return jnp.concatenate(
+            [
+                jnp.swapaxes(block[:, :, Zr - 2 * s : Zr - s], 1, 2),
+                jnp.swapaxes(block[:, :, s : 2 * s], 1, 2),
+            ],
+            axis=1,
+        )
 
 
 def make_slab_extenders(Xr: int, Yr: int, s: int, mesh_shape, axis_names=None):
@@ -1126,13 +1131,16 @@ def make_slab_extenders(Xr: int, Yr: int, s: int, mesh_shape, axis_names=None):
 def permute_and_extend_z_slabs(zout, s: int, mesh_shape, yext, xext):
     """One macro's incoming z-slab buffer from the previous macro's outgoing
     one: ppermute the two direction halves along z, then extend with y- and
-    x-neighbor content (corner propagation)."""
+    x-neighbor content (corner propagation).  This IS the z sweep of the
+    z-slab routes: all of it sits under the ``exchange.z`` scope (the y/x
+    extension hops nest their own direction scopes inside)."""
     from stencil_tpu.ops.exchange import _shift_from_high, _shift_from_low
     from stencil_tpu.parallel.mesh import MESH_AXES
 
-    zlo = _shift_from_low(zout[:, 0:s, :], MESH_AXES[2], mesh_shape[2])
-    zhi = _shift_from_high(zout[:, s : 2 * s, :], MESH_AXES[2], mesh_shape[2])
-    return jnp.concatenate([xext(yext(zlo)), xext(yext(zhi))], axis=1)
+    with telemetry.annotate(tm.SPAN_EXCHANGE_Z):
+        zlo = _shift_from_low(zout[:, 0:s, :], MESH_AXES[2], mesh_shape[2])
+        zhi = _shift_from_high(zout[:, s : 2 * s, :], MESH_AXES[2], mesh_shape[2])
+        return jnp.concatenate([xext(yext(zlo)), xext(yext(zhi))], axis=1)
 
 
 def _resolve_stream_alias(plan: dict, n_fields: int) -> bool:

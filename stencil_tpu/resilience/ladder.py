@@ -145,7 +145,10 @@ class DegradationLadder:
             self._apply_prefilter()
             inject.maybe_fail("compile", f"{self.label}:{self.rung.name}")
             t0 = time.perf_counter()
-            self._impl = self.rung.build()
+            with telemetry.span(
+                tm.EVENT_COMPILE, label=f"{self.label}:{self.rung.name}"
+            ):
+                self._impl = self.rung.build()
             dt = time.perf_counter() - t0
             telemetry.observe(tm.LADDER_BUILD_SECONDS, dt)
             telemetry.emit_event(

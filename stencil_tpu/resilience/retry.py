@@ -194,4 +194,7 @@ def execute_with_retry(
                 f"(attempt {attempt}/{policy.max_retries}), retrying in "
                 f"{delay:.2f}s: {e}"
             )
-            sleep(delay)
+            # the back-off is host work that stalls the dispatch pipeline:
+            # a span, so a profiler trace shows what the idle device waited on
+            with telemetry.span(tm.EVENT_RETRY, label=label, attempt=attempt):
+                sleep(delay)

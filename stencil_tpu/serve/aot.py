@@ -107,7 +107,8 @@ class AOTCache:
         a previous process — AFTER caching, so the refusal can never
         repeat for this key."""
         t0 = self.clock()
-        exe = build()
+        with telemetry.span(tm.EVENT_COMPILE, label=f"serve:{label}"):
+            exe = build()
         seconds = self.clock() - t0
         telemetry.observe(tm.SERVE_COMPILE_SECONDS, seconds)
         self._exec[digest] = exe

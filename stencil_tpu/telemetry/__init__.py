@@ -9,7 +9,9 @@ same visibility is one process-local facade:
   backed by ``utils/statistics.Statistics`` (trimean and friends for free).
   ``snapshot()`` returns the JSON-safe dict ``bench.py`` embeds in the
   BENCH artifact and every ``bin/`` driver writes via ``--metrics-out``.
-* **spans** (``spans.py``) — nestable wall-clock spans dumped as Chrome
+* **spans** (``spans.py``) — nestable spans: always a
+  ``jax.profiler.TraceAnnotation`` (so a profiler trace holds them beside the
+  device ops), and with telemetry on wall-clock spans dumped as Chrome
   trace-event JSON (``chrome://tracing`` / Perfetto); also home of the
   ``annotate``/``trace`` jax wrappers that used to live in
   ``utils/profiling.py``.
@@ -24,7 +26,8 @@ Knobs (validated reads — ``utils/config.py`` pattern):
 
 Design rules (enforced here, asserted by tests):
 
-* **zero-cost when disabled** — ``span()`` yields immediately, ``observe``/
+* **near-zero cost when disabled** — ``span()`` opens one profiler
+  annotation (a no-op without a profiler session) and yields, ``observe``/
   ``emit_event`` return after one attribute check, no formatting happens.
   Counters/gauges stay live always (an int add; a post-mortem ``snapshot()``
   after a failed run still counts its retries).
@@ -52,6 +55,7 @@ from stencil_tpu.telemetry.metrics import MetricsRegistry
 from stencil_tpu.telemetry.spans import (  # noqa: F401  (annotate/trace re-export)
     SpanRecorder,
     _maybe_named_scope,
+    _profiler_annotation,
     annotate,
     trace,
 )
@@ -198,34 +202,41 @@ def snapshot() -> dict:
 
 @contextlib.contextmanager
 def span(name: str, histogram: Optional[str] = None, **args):
-    """Nestable wall-clock span.  When disabled: an immediate yield, nothing
-    recorded.  When enabled: records a Chrome-trace event (nested under the
-    enclosing span), optionally observes the duration into ``histogram``,
-    and labels the region in HLO/XProf if jax is already up."""
+    """Nestable span.  ALWAYS: a ``jax.profiler.TraceAnnotation`` of the
+    same name and args (if jax is already up) — a no-op without a profiler
+    session, and with one the span sits on the host plane of the profiler's
+    trace, on the device ops' clock.  When telemetry is enabled,
+    additionally: records a Chrome-trace event (nested under the enclosing
+    span), optionally observes the duration into ``histogram``, and labels
+    the region in HLO/XProf."""
     t = _cfg()
-    if not t.enabled:
-        yield
-        return
-    parent = t.spans.current()
-    t.spans.push(name)
-    t0 = time.perf_counter()
-    try:
-        with _maybe_named_scope(name):
+    with _profiler_annotation(name, args):
+        if not t.enabled:
             yield
-    finally:
-        dur = time.perf_counter() - t0
-        t.spans.pop()
-        t.spans.record(name, t0, dur, parent=parent, **args)
-        _sample_track_counters(t, t0 + dur)
-        if histogram is not None:
-            t.registry.histogram(histogram).observe(dur)
+            return
+        parent = t.spans.current()
+        t.spans.push(name)
+        t0 = time.perf_counter()
+        try:
+            with _maybe_named_scope(name):
+                yield
+        finally:
+            dur = time.perf_counter() - t0
+            t.spans.pop()
+            t.spans.record(name, t0, dur, parent=parent, **args)
+            _sample_track_counters(t, t0 + dur)
+            if histogram is not None:
+                t.registry.histogram(histogram).observe(dur)
 
 
 def record_span(
     name: str, t0: float, dur: float, histogram: Optional[str] = None, **args
 ) -> None:
     """Post-hoc span record for call sites that already timed themselves
-    (``t0`` from ``time.perf_counter``, ``dur`` seconds).  No-op disabled."""
+    (``t0`` from ``time.perf_counter``, ``dur`` seconds).  No-op disabled.
+    Recorder only: a finished interval cannot be back-dated onto the
+    profiler's clock, so a span that must sit beside the device ops is
+    opened with ``span()``."""
     t = _cfg()
     if not t.enabled:
         return

@@ -312,9 +312,28 @@ ALL_HISTOGRAMS = frozenset({
 
 # --- spans (Chrome-trace timeline entries) -----------------------------------
 
+# Host spans (``telemetry.span``) go two ways: into the perf_counter
+# recorder when STENCIL_TELEMETRY is on, and ALWAYS into the profiler's own
+# trace as a ``jax.profiler.TraceAnnotation`` of the same name -- under a
+# profiler session they sit on /host:CPU of the same xplane as the device
+# ops (docs/observability.md "One timeline").  Args in brackets.  Three
+# events double as the span around the work they report (one constant, one
+# name, like NUMERICS_DRIFT): EVENT_COMPILE [label], EVENT_RETRY (the
+# back-off sleep) [label, attempt], EVENT_CHECKPOINT_SAVE [step].
+
+#: the enqueue of one ``run_step`` dispatch [label, steps = raw iterations]
 SPAN_STEP = "domain.step"
+#: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
+#: bytes of the call, count = exchanges in it]
 SPAN_EXCHANGE = "domain.exchange"
 SPAN_SWAP = "domain.swap"
+#: ``realize()``: placement, allocation, exchange build + eager compile
+SPAN_REALIZE = "domain.realize"
+#: ``init_by_coords``: builds and traces a new jit per call [quantity]
+SPAN_INIT = "domain.init"
+#: one fused numerics snapshot's dispatch + scalar read-back (the divergence
+#: sentinel's check too): host work that drains the dispatch queue [step]
+SPAN_NUMERICS_SNAPSHOT = "numerics.snapshot"
 #: the split-step schedule's two halves (ops/stream.py overlap=split).  These
 #: are DEVICE-timeline spans: the split macro enters them as
 #: ``telemetry.annotate`` named scopes, so they label the interior stream
@@ -337,6 +356,21 @@ SPAN_EXCHANGE_Y_HIGH = "exchange.y.high"
 SPAN_EXCHANGE_Z_LOW = "exchange.z.low"
 SPAN_EXCHANGE_Z_HIGH = "exchange.z.high"
 
+#: one axis SWEEP of the halo exchange -- slab cut / pack, the wire, unpack /
+#: blend -- as a DEVICE-timeline scope: every instruction the exchange adds
+#: to a program sits under one of these (the direction scopes above nest
+#: inside), so a trace reader tells exchange work from step glue by name
+SPAN_EXCHANGE_X = "exchange.x"
+SPAN_EXCHANGE_Y = "exchange.y"
+SPAN_EXCHANGE_Z = "exchange.z"
+
+#: the sweep scope for one mesh axis
+EXCHANGE_AXIS_SPANS = {
+    "x": SPAN_EXCHANGE_X,
+    "y": SPAN_EXCHANGE_Y,
+    "z": SPAN_EXCHANGE_Z,
+}
+
 #: the direction span for one (mesh axis, receive side)
 EXCHANGE_DIRECTION_SPANS = {
     ("x", "low"): SPAN_EXCHANGE_X_LOW,
@@ -358,10 +392,24 @@ def exchange_direction_span(axis: str, side: str) -> str:
         raise ValueError(f"no exchange direction span for {axis!r}/{side!r}") from None
 
 
+def exchange_axis_span(axis: str) -> str:
+    """The registered sweep scope for one mesh axis (x/y/z)."""
+    try:
+        return EXCHANGE_AXIS_SPANS[axis]
+    except KeyError:
+        raise ValueError(f"no exchange sweep span for axis {axis!r}") from None
+
+
 ALL_SPANS = frozenset({
     SPAN_STEP,
     SPAN_EXCHANGE,
     SPAN_SWAP,
+    SPAN_REALIZE,
+    SPAN_INIT,
+    SPAN_NUMERICS_SNAPSHOT,
+    SPAN_EXCHANGE_X,
+    SPAN_EXCHANGE_Y,
+    SPAN_EXCHANGE_Z,
     SPAN_OVERLAP_INTERIOR,
     SPAN_OVERLAP_EXTERIOR,
     SPAN_RESHARD,
@@ -499,5 +547,62 @@ ALL_EVENTS = frozenset({
     NUMERICS_DRIFT,
 })
 
+# the three events that double as host spans (see "spans" above)
+ALL_SPANS = ALL_SPANS | {EVENT_COMPILE, EVENT_RETRY, EVENT_CHECKPOINT_SAVE}
+
+# --- Pallas kernel names (``pl.pallas_call(name=...)``) ------------------------
+#
+# One name per kernel FAMILY, stable across depth, radius, shape and dtype
+# (those are in the op's shape already).  The name is the kernel's
+# ``kernel_name`` in the TPU custom call and the last scope of its HLO
+# ``op_name`` (``.../exchange.z/blend_slab/pallas_call``), which is how the
+# benchmark's named per-layer metrics find it.  Identifiers (``[a-z0-9_]+``):
+# ``mlir.sanitize_name`` leaves them alone.
+
+KERNEL_JACOBI_WRAP = "jacobi_wrap_step"
+KERNEL_JACOBI_SHELL_WAVEFRONT = "jacobi_shell_wavefront_step"
+KERNEL_JACOBI_ZRING_WAVEFRONT = "jacobi_zring_wavefront_step"
+KERNEL_JACOBI_SLAB = "jacobi_slab_step"
+KERNEL_JACOBI_PLANE = "jacobi_plane_step"
+KERNEL_STREAM_PLANE_PASS = "stream_plane_pass"
+KERNEL_STREAM_WAVEFRONT_PASS = "stream_wavefront_pass"
+KERNEL_STREAM_WRAP_PASS = "stream_wrap_pass"
+KERNEL_MEAN6_SHELL_WAVEFRONT = "mean6_shell_wavefront_step"
+KERNEL_MEAN6_PLANE = "mean6_plane_step"
+#: the halo writes: whole x planes, a static y/z sliver, a traced-offset one
+KERNEL_BLEND_PLANES = "blend_planes"
+KERNEL_BLEND_SLAB = "blend_slab"
+KERNEL_BLEND_SLAB_DYNAMIC = "blend_slab_dynamic"
+KERNEL_PACK_SLAB = "pack_slab"
+KERNEL_UNPACK_SLAB = "unpack_slab"
+KERNEL_PACK_ZSHELL = "pack_zshell"
+KERNEL_UNPACK_ZSHELL = "unpack_zshell"
+KERNEL_PACK_YSHELL = "pack_yshell"
+KERNEL_UNPACK_YSHELL = "unpack_yshell"
+
+ALL_KERNELS = frozenset({
+    KERNEL_JACOBI_WRAP,
+    KERNEL_JACOBI_SHELL_WAVEFRONT,
+    KERNEL_JACOBI_ZRING_WAVEFRONT,
+    KERNEL_JACOBI_SLAB,
+    KERNEL_JACOBI_PLANE,
+    KERNEL_STREAM_PLANE_PASS,
+    KERNEL_STREAM_WAVEFRONT_PASS,
+    KERNEL_STREAM_WRAP_PASS,
+    KERNEL_MEAN6_SHELL_WAVEFRONT,
+    KERNEL_MEAN6_PLANE,
+    KERNEL_BLEND_PLANES,
+    KERNEL_BLEND_SLAB,
+    KERNEL_BLEND_SLAB_DYNAMIC,
+    KERNEL_PACK_SLAB,
+    KERNEL_UNPACK_SLAB,
+    KERNEL_PACK_ZSHELL,
+    KERNEL_UNPACK_ZSHELL,
+    KERNEL_PACK_YSHELL,
+    KERNEL_UNPACK_YSHELL,
+})
+
 #: every registered name, any kind — what the lint checks literals against
-ALL_NAMES = ALL_COUNTERS | ALL_GAUGES | ALL_HISTOGRAMS | ALL_SPANS | ALL_EVENTS
+ALL_NAMES = (
+    ALL_COUNTERS | ALL_GAUGES | ALL_HISTOGRAMS | ALL_SPANS | ALL_EVENTS | ALL_KERNELS
+)

@@ -489,8 +489,11 @@ class NumericsEngine:
         from stencil_tpu.telemetry import names as tm
 
         t0 = time.perf_counter()
-        fn, args, names = self.program()
-        raw = [np.asarray(v) for v in fn(*args)]  # the O(#q)-scalar transfer
+        # the read-back drains the dispatch queue: a span on the profiler's
+        # timeline (the divergence sentinel's check comes through here too)
+        with telemetry.span(tm.SPAN_NUMERICS_SNAPSHOT, step=step):
+            fn, args, names = self.program()
+            raw = [np.asarray(v) for v in fn(*args)]  # the O(#q)-scalar transfer
         dd = self.dd
         size = dd._size
         stats = []

@@ -5,6 +5,10 @@ Subsumes ``utils/profiling.py``: ``annotate`` (the NVTX-range analog —
 and ``trace`` (a ``jax.profiler`` capture) live here now, alongside the
 host-side span recorder.
 
+Every span is also a ``jax.profiler.TraceAnnotation`` (``_profiler_annotation``):
+under a profiler session it sits in the profiler's own trace beside the device
+ops, whether or not the recorder below is on.
+
 Spans record (name, start, duration, thread, parent, args) tuples that
 ``chrome_trace_events`` renders as Chrome trace-event JSON — complete
 ("ph":"X") events with microsecond timestamps — viewable in
@@ -88,6 +92,20 @@ def _maybe_named_scope(name: str):
     if jax is None:
         return contextlib.nullcontext()
     return jax.named_scope(name)
+
+
+def _profiler_annotation(name: str, args: dict):
+    """The span on the PROFILER's timeline: a ``jax.profiler.TraceAnnotation``
+    (name + args) when jax is already imported, else a null context — the
+    same fail-closed rule as ``_maybe_named_scope``.  The profiler session
+    is the switch: with none running this is a ~0.5 us no-op; with one
+    (``--profile-dir`` / ``STENCIL_PROFILE_DIR``, the benchmark's
+    ``--trace 1``) the span lands on ``/host:CPU`` of the same xplane as
+    the device ops, on the same clock.  It never syncs the device."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 class SpanRecorder:

@@ -1,0 +1,67 @@
+"""The benchmark's named-timeline readers (``benchmark/harness/timeline.py``
+and the reducers over it), case by case from ``benchmark/selftest_timeline.py``
+— plus what keeps the benchmark's copy of the program's registry, and the
+new per-layer metrics' declarations, true."""
+
+import importlib.util
+import json
+import os
+
+import pytest
+
+from stencil_tpu.telemetry import names as tm
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _selftest():
+    spec = importlib.util.spec_from_file_location(
+        "bench_selftest_timeline", os.path.join(ROOT, "benchmark", "selftest_timeline.py")
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("part", list("abcde"))
+def test_selftest_timeline(part, capsys):
+    getattr(_selftest(), "part_" + part)()
+    assert f"{part} " in capsys.readouterr().out
+
+
+def test_program_names_file_equals_the_registry():
+    """``harness/timeline.py`` imports nothing of the program; its data file
+    has to say what the registry says."""
+    with open(os.path.join(ROOT, "benchmark", "harness", "program_names.json")) as f:
+        names = json.load(f)
+    assert set(names["kernels"]) == set(tm.ALL_KERNELS)
+    assert set(names["sweep_scopes"]) == set(tm.EXCHANGE_AXIS_SPANS.values())
+    host_spans = {
+        tm.SPAN_STEP, tm.SPAN_EXCHANGE, tm.SPAN_SWAP, tm.SPAN_REALIZE, tm.SPAN_INIT,
+        tm.SPAN_NUMERICS_SNAPSHOT, tm.EVENT_COMPILE, tm.EVENT_RETRY, tm.EVENT_CHECKPOINT_SAVE,
+    }
+    assert set(names["spans"]) == host_spans and host_spans <= tm.ALL_SPANS
+
+
+def test_new_metrics_are_declared_and_read_names_not_shapes():
+    """Every per-layer metric this PR adds sits in BENCHMARK.json with its
+    cells listed, and the old metrics' files are as PR 24 left them (their
+    reducers read opcode + shape only, which names and scopes do not move)."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        declared = {m["name"]: m for m in json.load(f)["per_layer"]}
+    new = {
+        "kernel_named_pct.bulk", "kernel_named_pct.exchange", "stencil_kernel_pct",
+        "exchange_dev_pct.bulk", "exchange_dev_pct.exchange", "step_glue_pct",
+        "enqueue_ms_p90.bulk", "enqueue_ms_p90.exchange", "exchange_z_pct.exchange",
+        "compiles_in_window.bulk", "compiles_in_window.exchange", "idle_in_program_pct.exchange",
+    }
+    assert new <= set(declared)
+    for name in new:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["reducer"] in ("named_share", "span_percentile", "span_count", "idle_by_span"), name
+        assert m["source"] in ("device_trace", "program_span") and m["cells"] == declared[name]["workloads"]
+    for name in set(declared) - new:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
+                                               "trace_roofline_hbm", "trace_idle"), name

@@ -47,9 +47,11 @@ walker or a source-level heuristic the tracer can defeat:
   indirection falls out of device-time attribution silently.
 * ``kernel-name``         — every pallas call carries a registered kernel
   name (``telemetry/names.py ALL_KERNELS``): its identity in a device trace.
-* ``exchange-scope``      — every ppermute sits under an ``exchange.<axis>``
-  sweep scope, a bare exchange program is >= 90% scoped, a program with no
-  ppermute carries no such scope: a trace tells exchange from glue by name.
+* ``exchange-scope``      — every ppermute and every self-wrap kernel
+  (``exchange.<axis>.wrap``) sits under an ``exchange.<axis>`` sweep scope,
+  a bare exchange program is >= 90% scoped, a program with neither a
+  ppermute nor a wrap kernel carries no such scope: a trace tells exchange
+  from glue by name.
 * ``kernel-race``         — the kernel verifier's deliberate descent
   (``analysis/kernels.py``): no two PARALLEL grid points of any pallas
   call write the same output block unless the writes are provably
@@ -1045,11 +1047,13 @@ class KernelName(Contract):
 class ExchangeScope(Contract):
     name = "exchange-scope"
     why = (
-        "the exchange is told from step glue BY NAME: every ppermute sits "
-        "under an exchange.<axis> sweep scope, a bare exchange program "
-        "carries one on >= 90% of its equations (slab cuts, reshapes and "
-        "blends included, not just the wire), and a program that moves "
-        "nothing between shards carries none"
+        "the exchange is told from step glue BY NAME: every ppermute and "
+        "every self-wrap kernel (the sweep of an axis the mesh does not "
+        "split: exchange.<axis>.wrap, no wire) sits under an "
+        "exchange.<axis> sweep scope, a bare exchange program carries one "
+        "on >= 90% of its equations (slab cuts, reshapes and blends "
+        "included, not just the wire), and a program that fills no halo -- "
+        "neither a ppermute nor a wrap kernel -- carries none"
     )
 
     #: share of a bare exchange program's leaf equations (containers -- jit,
@@ -1064,6 +1068,7 @@ class ExchangeScope(Contract):
         from stencil_tpu.telemetry import names as tm
 
         sweeps = set(tm.EXCHANGE_AXIS_SPANS.values())
+        wrap_scopes = set(tm.EXCHANGE_WRAP_SPANS.values())
         eqns, scoped = [], []
 
         def visit(jaxpr, under: bool) -> None:
@@ -1101,13 +1106,30 @@ class ExchangeScope(Contract):
                     "wire time as step glue",
                 )
             )
-        if not permutes and scoped:
+        wraps = [
+            e
+            for e in eqns
+            if e.primitive.name == "pallas_call"
+            and wrap_scopes & set(jx.name_stack_str(e).split("/"))
+        ]
+        bare_wraps = sum(1 for e in wraps if id(e) not in scoped_ids)
+        if bare_wraps:
+            out.append(
+                art.finding(
+                    self.name,
+                    f"{bare_wraps} of {len(wraps)} self-wrap kernel(s) sit under "
+                    "an exchange.<axis>.wrap scope but no exchange.<axis> "
+                    "sweep scope — a trace would read that halo fill as "
+                    "step glue",
+                )
+            )
+        if not permutes and not wraps and scoped:
             out.append(
                 art.finding(
                     self.name,
                     f"{len(scoped)} equation(s) carry an exchange.<axis> "
-                    "scope in a program with no ppermute — the scope would "
-                    "bill step work to the exchange",
+                    "scope in a program with no ppermute and no self-wrap "
+                    "kernel — the scope would bill step work to the exchange",
                 )
             )
         if art.kind == "exchange" and eqns:

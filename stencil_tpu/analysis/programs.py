@@ -164,6 +164,17 @@ CANONICAL_PROGRAMS: List[ProgramSpec] = [
         n_fields=2,
     ),
     ProgramSpec("exchange:direct", kind="exchange", halo_mult=2, n_fields=2),
+    # axes the mesh does not split sweep by the self-wrap kernel
+    # (ops/halo_blend.py wrap_halo): z on the 4-chip [2,2,1] mesh beside real
+    # x/y wires, and all three on one chip -- an exchange with no ppermute
+    ProgramSpec(
+        "exchange:direct/unsplit-z",
+        kind="exchange",
+        n_devices=4,
+        halo_mult=2,
+        n_fields=2,
+    ),
+    ProgramSpec("exchange:direct/one-chip", kind="exchange", n_devices=1),
     ProgramSpec(
         "exchange:zpack_xla",
         kind="exchange",
@@ -451,7 +462,7 @@ def _serve_artifact(spec: ProgramSpec, dd) -> ProgramArtifact:
     )
 
 
-#: traced canonical programs memoized by label — tracing the 21-program
+#: traced canonical programs memoized by label — tracing the 23-program
 #: matrix costs ~tens of seconds and every per-contract consumer
 #: (tests/test_analysis.py's contract tests, repeated in-process CLI
 #: calls, the kernel verifier's report sweep) hits the same specs; an

@@ -74,6 +74,7 @@ CANONICAL_AXES = {
 PALLAS_KERNELS = {
     "stencil_tpu/ops/halo_blend.py": (
         "blend_slab",
+        "wrap_halo",
         "blend_slab_dynamic",
     ),
     "stencil_tpu/ops/jacobi_pallas.py": (

@@ -181,6 +181,7 @@ class DistributedDomain:
         # config > static "direct"; packed-route analytic accounting rides it
         self._exchange_route_req: Optional[str] = None
         self._exchange_route = "direct"
+        self._wrap_axes = ""  # mesh axes swept by the self-wrap kernel
         # storage-dtype axis (ops/jacobi_pallas STORAGE_DTYPES): models
         # resolve the axis (explicit > STENCIL_STORAGE_DTYPE > tuned >
         # static native) and pin the RESOLVED value here before realize();
@@ -584,6 +585,7 @@ class DistributedDomain:
             )
             self._exchange_fn = maker(self.mesh, self._shell_radius, self._spec, dim)
             self._exchange_route = "direct"  # the debug oracles have no z route
+            self._wrap_axes = ""  # ...and no axis sweeps
             self.stats.time_plan = time.perf_counter() - t0
             # eager trace+compile of the exchange — the analog of the
             # reference's sender/recver creation + CUDA-Graph capture
@@ -862,8 +864,30 @@ class DistributedDomain:
                 "'direct'"
             )
             route, source = "direct", source + "/degraded"
-        telemetry.emit_event(tm.EVENT_EXCHANGE_ROUTE, route=route, source=source)
+        telemetry.emit_event(
+            tm.EVENT_EXCHANGE_ROUTE, route=route, source=source,
+            wrap_axes=self._plan_wrap_axes(route),
+        )
         return route
+
+    def _plan_wrap_axes(self, route: str) -> str:
+        """Record (and return) the mesh axes whose sweep under ``route`` is
+        the self-wrap kernel (``ops/exchange.py wrap_axes``): called wherever
+        the route is settled, so ``self._wrap_axes`` — the ``wrap_axes`` field
+        of every ``domain.exchange`` span — follows ``self._exchange_route``."""
+        from stencil_tpu.ops.exchange import wrap_axes
+
+        raw = self._spec.raw_size()
+        self._wrap_axes = wrap_axes(
+            tuple(self.mesh.shape[a] for a in MESH_AXES),
+            self._shell_radius,
+            (raw.x, raw.y, raw.z),
+            [self.field_dtype(h) for h in self._handles],
+            all_3d=not any(h.components for h in self._handles),
+            valid_last=self._valid_last,
+            route=route,
+        )
+        return self._wrap_axes
 
     def make_exchange_route_fn(
         self,
@@ -924,7 +948,8 @@ class DistributedDomain:
         if ladder.rung.name != route:
             self._exchange_route = ladder.rung.name
             telemetry.emit_event(
-                tm.EVENT_EXCHANGE_ROUTE, route=ladder.rung.name, source="ladder"
+                tm.EVENT_EXCHANGE_ROUTE, route=ladder.rung.name, source="ladder",
+                wrap_axes=self._plan_wrap_axes(ladder.rung.name),
             )
         return fn
 
@@ -1264,6 +1289,7 @@ class DistributedDomain:
         with self._phase_timer(
             "time_exchange", tm.EXCHANGE_SECONDS, tm.SPAN_EXCHANGE, sync=True,
             route=self._exchange_route, nbytes=self._model_exchange(), count=1,
+            wrap_axes=self._wrap_axes,
         ):
             self._curr = self._watched_call(
                 "exchange", lambda: self._exchange_fn(self._curr)
@@ -1289,6 +1315,7 @@ class DistributedDomain:
         with telemetry.span(
             tm.SPAN_EXCHANGE, route=self._exchange_route,
             nbytes=steps * self._model_exchange(), count=steps,
+            wrap_axes=self._wrap_axes,
         ):
             self._curr = self._exchange_many_fn(self._curr, steps)
         self._shell_stale = False

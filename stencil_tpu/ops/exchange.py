@@ -19,9 +19,14 @@ The ``-dir`` extent convention holds by construction: the slab sent in
 direction ``+a`` has width ``radius(-a)`` (the receiver's ``-a`` halo width),
 and the slab sent in ``-a`` has width ``radius(+a)`` (packer.cuh:91-93).
 
-A mesh axis of size 1 still ppermutes to itself — that self-wrap implements
-periodic boundaries within one shard, the collapse of the reference's
-same-GPU ``PeerAccessSender`` kernels (tx_cuda.cuh:39-104).
+A mesh axis of size 1 has no neighbor but the shard itself: its sweep is
+the SELF-WRAP (``halo_blend.wrap_halo`` under ``exchange.<axis>.wrap``) — one
+in-place kernel per quantity copies the shard's own interior cells onto its
+halos, no slab cut, no message and no unpack, like the reference's same-GPU
+``PeerAccessSender`` copy kernels (tx_cuda.cuh:39-104).  Where the blend
+kernels are off (CPU, ``STENCIL_HALO_BLEND=0``) or cannot engage (N-D blocks,
+exotic dtypes) such an axis still ppermutes to itself, which is the same
+periodic boundary by the general path.
 
 The y and z sweeps have selectable ROUTES (``EXCHANGE_ROUTES``, a tuner
 axis — docs/tuning.md "Exchange routes"): ``direct`` sends the thin sliver
@@ -425,6 +430,74 @@ def _ypack_sweep(
     return out_blocks
 
 
+def _sweep_kind(
+    axis: int,
+    r_lo: int,
+    r_hi: int,
+    n_dev: int,
+    size: int,
+    v_last: Optional[int],
+    route: str,
+    dtypes,
+    all_3d: bool,
+) -> str:
+    """Which implementation one axis sweep takes — ``ypack`` / ``zpack``
+    (the route's packed pipelines), ``wrap`` (the self-wrap kernel) or
+    ``direct`` (cut, ppermute, blend / DUS) — from what the sweep can observe:
+    the route, the mesh extent, the axis padding, block rank and dtype.
+
+    A packed route engages per SWEEP: the y sweep packs on the yzpack_*
+    routes, the z sweep on every packed route; a sweep that structurally
+    cannot engage (uneven axis, unsupported dtype) runs ``direct``, so a
+    pinned route is always correct.  An axis the mesh does not split takes
+    the self-wrap wherever the blend kernels can engage: its padding offset is
+    static there (one shard is the last shard)."""
+    from stencil_tpu.ops import halo_blend
+
+    n_pad = size - r_lo - r_hi
+    n_last = n_pad if v_last is None else v_last  # the last shard's valid width
+    known = all(halo_blend.supports(dt) for dt in dtypes)
+    if route in Y_PACK_ROUTES and axis == 1 and n_last == n_pad and known:
+        return "ypack"
+    if route != "direct" and axis == 2 and n_last == n_pad and known:
+        return "zpack"
+    if (
+        n_dev == 1
+        and known
+        and all_3d
+        and halo_blend.enabled()
+        # narrower interiors than halos read halo cells as sources: the
+        # general path's cut-before-write order is the semantics there
+        and n_last >= max(r_lo, r_hi)
+    ):
+        return "wrap"
+    return "direct"
+
+
+def wrap_axes(
+    mesh_shape: Tuple[int, int, int],
+    radius: Radius,
+    raw_spatial: Tuple[int, int, int],
+    dtypes,
+    all_3d: bool = True,
+    valid_last: Optional[Tuple[Optional[int], Optional[int], Optional[int]]] = None,
+    route: str = "direct",
+) -> str:
+    """The mesh axes (a substring of ``"xyz"``) whose sweep of this exchange
+    is the self-wrap kernel — what ``exchange.route`` and ``domain.exchange``
+    report as ``wrap_axes``."""
+    return "".join(
+        MESH_AXES[a]
+        for a in range(3)
+        if radius.axis(a, -1) + radius.axis(a, +1) > 0
+        and _sweep_kind(
+            a, radius.axis(a, -1), radius.axis(a, +1), mesh_shape[a],
+            raw_spatial[a], valid_last[a] if valid_last is not None else None,
+            route, dtypes, all_3d,
+        ) == "wrap"
+    )
+
+
 def _axis_sweep(
     blocks: List[jax.Array],
     axis: int,
@@ -439,23 +512,27 @@ def _axis_sweep(
     """One axis sweep of ``halo_exchange_multi`` (which enters the
     ``exchange.<axis>`` scope around it): ``size`` is the raw extent on this
     axis, ``v_last`` the last shard's valid interior cells (None = even)."""
+    from stencil_tpu.ops import halo_blend
+
     n_pad = size - r_lo - r_hi  # per-shard (padded) interior width
     uneven = v_last is not None and v_last != n_pad
-
-    # a packed route engages per SWEEP: the y sweep packs on the
-    # yzpack_* routes, the z sweep on every packed route; a sweep that
-    # structurally cannot engage (uneven axis, unsupported dtype)
-    # silently runs direct, so a pinned route is always correct
-    if route in Y_PACK_ROUTES and axis == 1 and not uneven:
-        from stencil_tpu.ops import halo_blend
-
-        if all(halo_blend.supports(b.dtype) for b in blocks):
-            return _ypack_sweep(blocks, r_lo, r_hi, n_pad, name, n_dev, route)
-    if route != "direct" and axis == 2 and not uneven:
-        from stencil_tpu.ops import halo_blend
-
-        if all(halo_blend.supports(b.dtype) for b in blocks):
-            return _zpack_sweep(blocks, r_lo, r_hi, n_pad, name, n_dev, route)
+    interp = halo_blend.interpret_mode()
+    kind = _sweep_kind(
+        axis, r_lo, r_hi, n_dev, size, v_last, route,
+        [b.dtype for b in blocks], all(b.ndim == 3 for b in blocks),
+    )
+    if kind == "ypack":
+        return _ypack_sweep(blocks, r_lo, r_hi, n_pad, name, n_dev, route)
+    if kind == "zpack":
+        return _zpack_sweep(blocks, r_lo, r_hi, n_pad, name, n_dev, route)
+    if kind == "wrap":
+        # one shard is the last shard: its valid width is static
+        n_last = n_pad if v_last is None else v_last
+        with jax.named_scope(tm.exchange_wrap_span(name)):
+            return [
+                halo_blend.wrap_halo(b, axis, r_lo, r_hi, n_last, interpret=interp)
+                for b in blocks
+            ]
 
     def axslice(b, lo, hi):
         idx = [slice(None)] * b.ndim
@@ -514,12 +591,9 @@ def _axis_sweep(
     # possible: plain DUS slivers on those axes bait XLA's layout
     # assignment into transposing the whole array (two full-domain
     # relayout copies per exchange — see ops/halo_blend.py).
-    from stencil_tpu.ops import halo_blend
-
     blend = halo_blend.enabled() and all(
         b.ndim == 3 and halo_blend.supports(b.dtype) for b in blocks
     )
-    interp = halo_blend.interpret_mode()
     for j, b in enumerate(blocks):
         if lo_recv is not None:
             # the low halo sits at 0 even on padded axes, so the static

@@ -22,6 +22,14 @@ expressed per-tile to avoid the relayout trap; this file is that expression.
 
 The x axis never needs this: x-slabs are whole contiguous planes, which DUS
 handles at slab cost in the native layout.
+
+``wrap_halo`` is the same tile-local write for an axis the mesh does not
+split, where the "received slab" is the block's own interior: one aliased
+call fills both halos from cells it reads through the aliased operand itself
+— no slab is cut, sent to oneself or blended (each of those re-walked a whole
+128-lane tile column for 3 lanes of data: 86% of an exchange on mesh [2,2,1]
+— PERF.md §6, PR 26).  Reference analog: the same-GPU ``PeerAccessSender``
+copy kernel (tx_cuda.cuh:39-104).
 """
 
 from __future__ import annotations
@@ -180,6 +188,164 @@ def blend_slab(
         input_output_aliases={0: 0},
         interpret=interpret,
     )(block, slab)
+
+
+#: VMEM the self-wrap kernel sizes its x-block for (pipeline buffers plus the
+#: tile scratch) and the scoped limit it requests to hold them.  Deeper
+#: x-blocks amortize the per-grid-step cost over longer DMAs: the z wrap of a
+#: 518^3 f32 block took 1.112 / 1.036 / 1.009 / 0.996 / 0.993 ms at 6 / 12 /
+#: 24 / 48 / 96 MB on a v5e (PERF.md §6, PR 26), HBM-bound from 24 MB on
+_WRAP_VMEM_BLOCKS = 24 * 1024 * 1024
+_WRAP_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def wrap_halo(
+    block: jax.Array,
+    axis: int,
+    r_lo: int,
+    r_hi: int,
+    n: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """Return ``block`` with both ``axis`` halos filled from its OWN interior
+    — the periodic self-wrap of an axis the mesh does not split: low halo
+    ``[0, r_lo)`` <- ``[n, n + r_lo)``, high halo ``[r_lo + n, r_lo + n +
+    r_hi)`` <- ``[r_lo, r_lo + r_hi)``, over the full extent of the other two
+    axes.  ``n`` is the valid interior width (static: one shard owns the whole
+    axis).  ``block`` is consumed (aliased to the output) and is the ONLY
+    operand: a second, plain view of it would make XLA copy the whole array.
+
+    Axis 0 streams the source planes through VMEM onto the halo planes.  On
+    axes 1/2 the grid visits only the (sublane/lane) tiles holding one of the
+    four ranges — ``k`` of them, statically known: per x-block, visits
+    ``0..k-1`` gather the tiles into one VMEM scratch (consecutive tiles stay
+    adjacent, so a range straddling a tile boundary stays contiguous), visit
+    ``k-1`` shuffles the halo cells inside the scratch, and visits
+    ``k-1..2k-2`` emit the tiles.  The index maps hold the input on the last
+    tile while the rest are emitted and the output on the first while they
+    are gathered, and the pipeline moves a block only when its index changes:
+    every touched tile column is read once and written once, and nothing else
+    of the block is touched.  Sources are never written (halo and interior are
+    disjoint), so reading them through the aliased operand is race-free."""
+    from jax.experimental import pallas as pl
+
+    assert axis in (0, 1, 2), axis
+    assert n >= max(r_lo, r_hi) and r_lo + r_hi > 0, (n, r_lo, r_hi)
+    X, Y, Z = block.shape
+    # (destination, source, width) of the two halo fills
+    fills = [
+        (d, s, w) for d, s, w in ((0, n, r_lo), (r_lo + n, r_lo, r_hi)) if w
+    ]
+    out_shape = jax.ShapeDtypeStruct(block.shape, block.dtype)
+    if axis == 0:
+
+        def plane_of(which):
+            # grid step g -> the g-th plane of the fills' destinations
+            # (which=0) or sources (which=1): one run per fill
+            (a, wa), *rest = [(f[which], f[2]) for f in fills]
+            if not rest:
+                return lambda g: (a + g, 0, 0)
+            b = rest[0][0]
+            return lambda g: (jnp.where(g < wa, a + g, b + g - wa), 0, 0)
+
+        def kernel0(in_ref, out_ref):
+            out_ref[...] = in_ref[...]
+
+        return pl.pallas_call(
+            kernel0,
+            name=tm.KERNEL_BLEND_PLANES,
+            grid=(r_lo + r_hi,),
+            in_specs=[pl.BlockSpec((1, Y, Z), plane_of(1))],
+            out_specs=pl.BlockSpec((1, Y, Z), plane_of(0)),
+            out_shape=out_shape,
+            input_output_aliases={0: 0},
+            interpret=interpret,
+        )(block)
+
+    tile = _sublane(block.dtype) if axis == 1 else 128
+    tiles = sorted(
+        {t for d, s, w in fills for p in (d, s) for t in range(p // tile, (p + w - 1) // tile + 1)}
+    )
+    k = len(tiles)
+
+    def spot(p: int) -> int:
+        """Scratch coordinate of axis position ``p`` (tile j of the touched
+        list sits at ``[j * tile, (j + 1) * tile)``)."""
+        return tiles.index(p // tile) * tile + p % tile
+
+    rows, lanes = (tile, Z) if axis == 1 else (Y, tile)  # one tile of one x-row
+    sub = _sublane(block.dtype)
+    row_bytes = (-(-rows // sub) * sub) * (-(-lanes // 128) * 128) * block.dtype.itemsize
+    # 2 input + 2 output pipeline buffers and the k-tile scratch
+    bx = max(1, min(X, _WRAP_VMEM_BLOCKS // ((4 + k) * row_bytes)))
+    gx = -(-X // bx)
+
+    def cut(lo, hi):
+        span = slice(lo, hi)
+        return (slice(None), span, slice(None)) if axis == 1 else (slice(None), slice(None), span)
+
+    def kernel(in_ref, out_ref, scratch):
+        v = pl.program_id(1)
+
+        def at_visit(j, body):
+            body() if k == 1 else pl.when(v == j)(body)
+
+        for j in range(k):
+
+            def gather(j=j):
+                scratch[cut(j * tile, (j + 1) * tile)] = in_ref[...]
+
+            at_visit(j, gather)
+
+        def shuffle():
+            # both sources are read before either halo is written, as the
+            # slab-cutting sweep does
+            cells = [scratch[cut(spot(s), spot(s) + w)] for _, s, w in fills]
+            for (d, _, w), c in zip(fills, cells):
+                scratch[cut(spot(d), spot(d) + w)] = c
+
+        at_visit(k - 1, shuffle)
+        for j in range(k):
+
+            def emit(j=j):
+                out_ref[...] = scratch[cut(j * tile, (j + 1) * tile)]
+
+            at_visit(k - 1 + j, emit)
+
+    def tile_index(table):
+        # a static tile list as a select chain over the visit index
+        def index(i, v):
+            t = jnp.int32(table[-1])
+            for j in range(len(table) - 2, -1, -1):
+                t = jnp.where(v <= j, table[j], t)
+            return (i, t, 0) if axis == 1 else (i, 0, t)
+
+        return index
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    blk = (bx, rows, lanes)
+    sshape = (bx, k * tile, Z) if axis == 1 else (bx, Y, k * tile)
+    params = {}
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=_WRAP_VMEM_LIMIT
+        )
+    return pl.pallas_call(
+        kernel,
+        name=tm.KERNEL_BLEND_SLAB,
+        grid=(gx, 2 * k - 1),
+        # visits 0..k-1 read tile v, then the input rests on the last tile
+        in_specs=[pl.BlockSpec(blk, tile_index(tiles + [tiles[-1]] * (k - 1)))],
+        # the output rests on the first tile until visit k-1 writes it, then
+        # visit k-1+j writes tile j
+        out_specs=pl.BlockSpec(blk, tile_index([tiles[0]] * (k - 1) + tiles)),
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(sshape, block.dtype)],
+        input_output_aliases={0: 0},
+        interpret=interpret,
+        **params,
+    )(block)
 
 
 def blend_slab_dynamic(

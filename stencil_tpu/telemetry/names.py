@@ -324,7 +324,8 @@ ALL_HISTOGRAMS = frozenset({
 #: the enqueue of one ``run_step`` dispatch [label, steps = raw iterations]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
-#: bytes of the call, count = exchanges in it]
+#: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes
+#: whose sweep is the self-wrap kernel, e.g. "z" on mesh [2,2,1]]
 SPAN_EXCHANGE = "domain.exchange"
 SPAN_SWAP = "domain.swap"
 #: ``realize()``: placement, allocation, exchange build + eager compile
@@ -355,6 +356,13 @@ SPAN_EXCHANGE_Y_LOW = "exchange.y.low"
 SPAN_EXCHANGE_Y_HIGH = "exchange.y.high"
 SPAN_EXCHANGE_Z_LOW = "exchange.z.low"
 SPAN_EXCHANGE_Z_HIGH = "exchange.z.high"
+#: the SELF-WRAP of an axis the mesh does not split: no wire, one in-place
+#: kernel per quantity filling both halos from the shard's own interior
+#: (ops/halo_blend.py ``wrap_halo``) — the scope that tells those kernels
+#: from the blends of a received slab, whose registered names they share
+SPAN_EXCHANGE_X_WRAP = "exchange.x.wrap"
+SPAN_EXCHANGE_Y_WRAP = "exchange.y.wrap"
+SPAN_EXCHANGE_Z_WRAP = "exchange.z.wrap"
 
 #: one axis SWEEP of the halo exchange -- slab cut / pack, the wire, unpack /
 #: blend -- as a DEVICE-timeline scope: every instruction the exchange adds
@@ -381,6 +389,13 @@ EXCHANGE_DIRECTION_SPANS = {
     ("z", "high"): SPAN_EXCHANGE_Z_HIGH,
 }
 
+#: the self-wrap scope for one mesh axis
+EXCHANGE_WRAP_SPANS = {
+    "x": SPAN_EXCHANGE_X_WRAP,
+    "y": SPAN_EXCHANGE_Y_WRAP,
+    "z": SPAN_EXCHANGE_Z_WRAP,
+}
+
 
 def exchange_direction_span(axis: str, side: str) -> str:
     """The registered span name for one exchange hop (axis in x/y/z, side in
@@ -398,6 +413,14 @@ def exchange_axis_span(axis: str) -> str:
         return EXCHANGE_AXIS_SPANS[axis]
     except KeyError:
         raise ValueError(f"no exchange sweep span for axis {axis!r}") from None
+
+
+def exchange_wrap_span(axis: str) -> str:
+    """The registered self-wrap scope for one mesh axis (x/y/z)."""
+    try:
+        return EXCHANGE_WRAP_SPANS[axis]
+    except KeyError:
+        raise ValueError(f"no exchange self-wrap span for axis {axis!r}") from None
 
 
 ALL_SPANS = frozenset({
@@ -419,6 +442,9 @@ ALL_SPANS = frozenset({
     SPAN_EXCHANGE_Y_HIGH,
     SPAN_EXCHANGE_Z_LOW,
     SPAN_EXCHANGE_Z_HIGH,
+    SPAN_EXCHANGE_X_WRAP,
+    SPAN_EXCHANGE_Y_WRAP,
+    SPAN_EXCHANGE_Z_WRAP,
 })
 
 # --- structured events (JSONL sink) ------------------------------------------
@@ -448,7 +474,8 @@ EVENT_TUNE_DECISION = "tune.decision"
 EVENT_TUNE_TRIAL = "tune.trial"
 #: the exchange planner resolved its z-sweep route (fields: route,
 #: source=explicit|env|tuned|static|ladder — or "<orig>/degraded" when a
-#: packed pick structurally could not engage)
+#: packed pick structurally could not engage —, wrap_axes = the mesh axes
+#: whose sweep under that route is the self-wrap kernel, "" when none)
 EVENT_EXCHANGE_ROUTE = "exchange.route"
 #: a stream-engine step build resolved its overlap schedule (fields:
 #: overlap=off|split, source=explicit|env|tuned|static|ladder or

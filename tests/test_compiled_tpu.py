@@ -302,3 +302,26 @@ def test_compiled_storage_bf16():
     plan, got = _stream_run(storage="bf16", stream_depth=4)
     # init quantizes the input (one extra rounding) + <= one downcast per step
     assert_bf16_storage_close(got, want, passes=5, context="compiled bf16")
+
+
+@pytest.mark.parametrize("axis", [1, 2], ids=["y", "z"])
+def test_compiled_self_wrap_518(axis):
+    """The self-wrap kernel at the weak cell's own block — 518^3 f32, radius
+    3: sublane tiles 0 and 64 (y), lane tiles 0 and 4 with the last one
+    ragged (z) — equals the plain-DUS fill in every cell."""
+    from stencil_tpu.ops.halo_blend import wrap_halo
+
+    n, r = 512, 3
+    size = n + 2 * r
+    cells = jax.lax.iota(jnp.int32, size**3).reshape(size, size, size)
+    block = (cells % 1000003).astype(jnp.float32)
+
+    def cut(lo, hi):
+        idx = [slice(None)] * 3
+        idx[axis] = slice(lo, hi)
+        return tuple(idx)
+
+    want = block.at[cut(0, r)].set(block[cut(n, n + r)])
+    want = want.at[cut(r + n, size)].set(block[cut(r, 2 * r)])
+    got = jax.jit(lambda b: wrap_halo(b, axis, r, r, n))(block)
+    assert bool(jnp.array_equal(got, want))

@@ -211,3 +211,59 @@ def test_exchange_int8_and_bool_quantities():
         gx = (ix * n.x - lo.x) % 16  # -x halo cell's global x
         assert blk[0, 1, 1] == (gx + 0 + 0) % 100
         assert blkb[0, 1, 1] == ((gx + 0 + 0) % 2 == 0)
+
+
+@pytest.mark.parametrize(
+    "mesh_shape,wired,wrapped",
+    [((1, 1, 8), "z", "xy"), ((2, 2, 1), "xy", "z"), ((1, 1, 1), "", "xyz")],
+    ids=["1x1x8", "2x2x1", "1x1x1"],
+)
+def test_unsplit_axes_trace_wrap_kernels_not_ppermutes(
+    mesh_shape, wired, wrapped, monkeypatch
+):
+    """What the exchange traces per axis with the blend kernels engaged: a
+    split axis its two ``ppermute``s (one per direction scope), an axis the
+    mesh does not split NO ``ppermute`` and one in-place kernel per quantity
+    under ``exchange.<axis>.wrap`` — carrying a registered kernel name, its
+    only operand the block itself."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from stencil_tpu.analysis import jaxpr as jx
+    from stencil_tpu.ops.exchange import make_exchange_fn
+    from stencil_tpu.parallel.mesh import MESH_AXES
+    from stencil_tpu.telemetry import names as tm
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    n_dev = int(np.prod(mesh_shape))
+    mesh = Mesh(np.array(jax.devices()[:n_dev]).reshape(mesh_shape), MESH_AXES)
+    fn = make_exchange_fn(mesh, Radius.constant(2), donate=False)
+    shape = tuple(12 * d for d in mesh_shape)
+    quantities = [jnp.zeros(shape, jnp.float32) for _ in range(3)]
+    eqns = list(jx.iter_eqns(jax.make_jaxpr(fn)(quantities)))
+    for axis in "xyz":
+        sweep, wrap = tm.exchange_axis_span(axis), tm.exchange_wrap_span(axis)
+        under = [e for e in eqns if sweep in jx.name_stack_str(e).split("/")]
+        permutes = [e for e in under if e.primitive.name == "ppermute"]
+        wraps = [
+            e
+            for e in under
+            if e.primitive.name == "pallas_call"
+            and wrap in jx.name_stack_str(e).split("/")
+        ]
+        if axis in wired:
+            assert len(permutes) == 2 and not wraps, (axis, len(permutes), len(wraps))
+            assert {jx.name_stack_str(e).split("/")[-1] for e in permutes} == {
+                tm.exchange_direction_span(axis, "low"),
+                tm.exchange_direction_span(axis, "high"),
+            }
+        else:
+            assert axis in wrapped
+            assert not permutes and len(wraps) == len(quantities), (axis, len(wraps))
+            kernel = tm.KERNEL_BLEND_PLANES if axis == "x" else tm.KERNEL_BLEND_SLAB
+            for e in wraps:
+                assert e.params["name"] == kernel
+                assert len(e.invars) == 1 and e.invars[0].aval.shape == (12, 12, 12)
+            # the sweep is the kernels and nothing else: no slab cut survives
+            assert len(under) == len(wraps), [e.primitive.name for e in under]
+    assert len([e for e in eqns if e.primitive.name == "ppermute"]) == 2 * len(wired)

@@ -133,3 +133,113 @@ def test_exchange_with_blend_forced_matches_dus(monkeypatch):
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
     got = run()
     np.testing.assert_array_equal(ref, got)
+
+
+# --- the self-wrap sweep (an axis the mesh does not split) --------------------
+
+def _one_axis_exchange(block, axis, r_lo, r_hi, monkeypatch, blend):
+    """One axis sweep of the real exchange on a one-device mesh: the
+    self-wrap kernel when the blend kernels are forced on, the slab cut +
+    self-``ppermute`` + plain DUS when they are off."""
+    from jax.sharding import Mesh
+
+    from stencil_tpu.core.radius import Radius
+    from stencil_tpu.ops.exchange import make_exchange_fn
+    from stencil_tpu.parallel.mesh import MESH_AXES
+
+    d = [0, 0, 0]
+    entries = {}
+    for sign, r in ((-1, r_lo), (+1, r_hi)):
+        d[axis] = sign
+        entries[tuple(d)] = r
+    monkeypatch.setenv("STENCIL_HALO_BLEND", blend)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1), MESH_AXES)
+    fn = make_exchange_fn(mesh, Radius.from_dict(entries), axes=(axis,), donate=False)
+    return np.asarray(fn([block])[0])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float64])
+@pytest.mark.parametrize("n", [8, 126, 250, 512])
+@pytest.mark.parametrize("radii", [(1, 1), (3, 3), (2, 5), (16, 16)])
+@pytest.mark.parametrize("axis", [0, 1, 2])
+def test_self_wrap_sweep_equals_dus_sweep(axis, radii, n, dtype, monkeypatch):
+    """Bit for bit, over extents whose four ranges (two halos, two sources)
+    share one tile (interior 8), straddle a tile boundary (126: the high
+    ranges cross lane 128; 250 with radius 16), and sit in distinct tiles
+    (250, 512 — the weak cell's own geometry on a thin block)."""
+    r_lo, r_hi = radii
+    if n < max(radii):
+        pytest.skip("interior narrower than the halo: the sweep stays on the slab path")
+    shape = [5, 11, 13]
+    shape[axis] = n + r_lo + r_hi
+    rng = np.random.default_rng(7)
+    block = jnp.asarray(rng.integers(0, 1 << 7, size=shape), dtype=dtype)
+    want = _one_axis_exchange(block, axis, r_lo, r_hi, monkeypatch, "0")
+    got = _one_axis_exchange(block, axis, r_lo, r_hi, monkeypatch, "1")
+    np.testing.assert_array_equal(got, want)
+    # the halos really moved (a sweep that wrote nothing would also "agree"
+    # with itself, not with the roll truth)
+    idx = [slice(None)] * 3
+    idx[axis] = slice(r_lo, r_lo + n)
+    interior = np.asarray(block)[tuple(idx)]
+    truth = np.concatenate(
+        [np.take(interior, range(n - r_lo, n), axis), interior,
+         np.take(interior, range(r_hi), axis)],
+        axis=axis,
+    )
+    np.testing.assert_array_equal(got, truth)
+
+
+@pytest.mark.parametrize("radius", [1, 3])
+def test_one_chip_exchange_wraps_all_axes_against_roll(radius, monkeypatch):
+    """The full three-axis exchange on mesh [1,1,1], every sweep a self-wrap:
+    every raw cell — faces, edges and corners — equals the periodic
+    ``jnp.roll`` truth of the interior."""
+    from stencil_tpu.core.radius import Radius
+    from stencil_tpu.domain import DistributedDomain
+    from stencil_tpu.ops.exchange import wrap_axes
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    n, r = (12, 10, 14), radius
+    dd = DistributedDomain(*n)
+    dd.set_radius(Radius.constant(r))
+    dd.set_devices(jax.devices()[:1])
+    h = dd.add_data("q")
+    dd.realize()
+    assert dd.exchange_route() == "direct" and dd._wrap_axes == "xyz"
+    dd.init_by_coords(h, lambda x, y, z: x * 10000.0 + y * 100.0 + z)
+    dd.exchange()
+    interior = dd.quantity_to_host(h)
+    want = np.pad(interior, r, mode="wrap")
+    np.testing.assert_array_equal(dd.raw_to_host(h), want)
+    assert wrap_axes((2, 2, 1), Radius.constant(r), (18, 18, 18), [jnp.float32]) == "z"
+    assert wrap_axes((2, 2, 1), Radius.constant(r), (18, 18, 18), [jnp.float32],
+                     route="zpack_xla") == ""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "0")
+    assert wrap_axes((1, 1, 1), Radius.constant(r), (18, 18, 18), [jnp.float32]) == ""
+
+
+def test_uneven_unsplit_axis_wraps_at_static_offset(monkeypatch):
+    """A padded axis the mesh does not split: its one shard is the last
+    shard, the valid width is static, and the wrap kernel serves it (no
+    ``blend_slab_dynamic``) — equal to the DUS path."""
+    from stencil_tpu.ops import exchange
+    from stencil_tpu.core.radius import Radius
+
+    assert exchange._sweep_kind(2, 2, 2, 1, 24, 17, "direct", [jnp.float32], True) == "direct"
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    assert exchange._sweep_kind(2, 2, 2, 1, 24, 17, "direct", [jnp.float32], True) == "wrap"
+    assert exchange._sweep_kind(2, 2, 2, 2, 24, 17, "direct", [jnp.float32], True) == "direct"
+    assert exchange._sweep_kind(2, 2, 2, 1, 24, 17, "direct", [jnp.complex128], True) == "direct"
+    assert exchange._sweep_kind(2, 2, 2, 1, 24, 17, "direct", [jnp.float32], False) == "direct"
+    assert exchange._sweep_kind(2, 2, 2, 1, 5, None, "direct", [jnp.float32], True) == "direct"
+
+    rng = np.random.default_rng(11)
+    block = jnp.asarray(rng.random((6, 9, 24)), jnp.float32)
+    blocks = exchange._axis_sweep(
+        [block], 2, 2, 2, "z", 1, 24, 17, "direct"
+    )
+    want = np.asarray(block).copy()
+    want[:, :, 0:2] = want[:, :, 17:19]
+    want[:, :, 19:21] = want[:, :, 2:4]
+    np.testing.assert_array_equal(np.asarray(blocks[0]), want)

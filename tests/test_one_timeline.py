@@ -232,8 +232,9 @@ def test_hot_path_opens_spans_and_never_syncs(session, monkeypatch, request):
     assert all(a == {"label": "unit", "steps": 4} for a in step_args), step_args
     exchange_args = [a for n, a in opened if n == tm.SPAN_EXCHANGE]
     assert all(
-        a == {"route": "direct", "nbytes": dd.exchange_bytes_total(), "count": 1} for a in exchange_args
-    ), exchange_args
+        a == {"route": "direct", "nbytes": dd.exchange_bytes_total(), "count": 1, "wrap_axes": ""}
+        for a in exchange_args
+    ), exchange_args  # wrap_axes "": on the CPU the blend kernels are off, so z self-ppermutes
     if stop is not None:
         events = stop()
         assert sum(int(s["steps"]) for n, s in events if n == tm.SPAN_STEP) == 12
@@ -285,6 +286,7 @@ def test_every_registered_name_has_a_call_site(group):
     for helper, table in (
         ("exchange_direction_span", tm.EXCHANGE_DIRECTION_SPANS),
         ("exchange_axis_span", tm.EXCHANGE_AXIS_SPANS),
+        ("exchange_wrap_span", tm.EXCHANGE_WRAP_SPANS),
     ):
         for value in table.values():
             through_helper[value] = helper

@@ -1142,10 +1142,17 @@ class DistributedDomain:
         arr = (self._curr if slot == "curr" else self._next)[h.name]
         return np.asarray(jax.device_get(arr)).astype(h.dtype, copy=False)
 
-    def init_by_coords(self, h: DataHandle, fn, include_halo: bool = False) -> None:
+    def init_by_coords(self, h: DataHandle, fn, include_halo: bool = False,
+                       args: tuple = ()) -> None:
         """Device-side init: ``fn(cx, cy, cz)`` maps broadcastable global
         coordinate arrays to values.  Fills the interior (and optionally the
-        shell, for analytic whole-domain fields)."""
+        shell, for analytic whole-domain fields).
+
+        ``args`` are handed to ``fn`` after the coordinates as TRACED
+        arguments of the fill program: parameters that change from fill to
+        fill (a seed's words) then leave the program's text alone, so the
+        compile cache serves every later fill instead of compiling a new
+        program per value."""
         n = self._spec.sz
         raw = self._spec.raw_size()
         lo = self._shell_radius.lo()
@@ -1153,7 +1160,7 @@ class DistributedDomain:
 
         comps = h.components
 
-        def per_shard(block):
+        def per_shard(block, *extra):
             ox = lax.axis_index(MESH_AXES[0]) * n.x
             oy = lax.axis_index(MESH_AXES[1]) * n.y
             oz = lax.axis_index(MESH_AXES[2]) * n.z
@@ -1161,12 +1168,12 @@ class DistributedDomain:
                 cx = ox - lo.x + jnp.arange(raw.x)
                 cy = oy - lo.y + jnp.arange(raw.y)
                 cz = oz - lo.z + jnp.arange(raw.z)
-                vals = fn(cx[:, None, None], cy[None, :, None], cz[None, None, :])
+                vals = fn(cx[:, None, None], cy[None, :, None], cz[None, None, :], *extra)
                 return jnp.broadcast_to(vals, comps + tuple(raw)).astype(block.dtype)
             cx = ox + jnp.arange(n.x)
             cy = oy + jnp.arange(n.y)
             cz = oz + jnp.arange(n.z)
-            vals = fn(cx[:, None, None], cy[None, :, None], cz[None, None, :])
+            vals = fn(cx[:, None, None], cy[None, :, None], cz[None, None, :], *extra)
             vals = jnp.broadcast_to(vals, comps + tuple(n)).astype(block.dtype)
             return block.at[
                 ..., lo.x : lo.x + n.x, lo.y : lo.y + n.y, lo.z : lo.z + n.z
@@ -1175,8 +1182,11 @@ class DistributedDomain:
         spec = _qspec(h)
         with telemetry.span(tm.SPAN_INIT, quantity=h.name):
             out = jax.jit(
-                shard_map(per_shard, mesh=self.mesh, in_specs=(spec,), out_specs=spec)
-            )(self._curr[h.name])
+                shard_map(
+                    per_shard, mesh=self.mesh,
+                    in_specs=(spec,) + (P(),) * len(args), out_specs=spec,
+                )
+            )(self._curr[h.name], *args)
         self._curr[h.name] = out
 
     # --- the hot path ---------------------------------------------------------
@@ -1750,7 +1760,8 @@ class DistributedDomain:
         t0 = time.perf_counter() if timed else 0.0
         # the span is the ENQUEUE (a profiler annotation, never a sync);
         # only STENCIL_TELEMETRY's honest timing waits inside it
-        with telemetry.span(tm.SPAN_STEP, label=label, steps=raw):
+        plan_args = getattr(step_fn, "_span_args", dict)()  # a stream step's plan
+        with telemetry.span(tm.SPAN_STEP, label=label, steps=raw, **plan_args):
             self._curr = execute_with_retry(
                 dispatch,
                 label=f"dispatch:{label}",

@@ -4,9 +4,13 @@
   (reference bin/jacobi3d.cu), the flagship app.
 * ``astaroth`` — radius-3 multi-quantity MHD proxy (reference
   bin/astaroth_sim.cu).
+* ``acoustic`` — isotropic acoustic wave propagation at space order 8
+  (Devito ``examples/seismic/acoustic``; Minimod), the 25-point radius-4
+  star, beside its plain reference ``acoustic_reference``.
 """
 
 from stencil_tpu.models.jacobi import Jacobi3D
 from stencil_tpu.models.astaroth import AstarothSim
+from stencil_tpu.models.acoustic import AcousticWave
 
-__all__ = ["Jacobi3D", "AstarothSim"]
+__all__ = ["Jacobi3D", "AstarothSim", "AcousticWave"]

@@ -1611,12 +1611,13 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
                         [yb[q] for q in g],
                         [zb[q] for q in g],
                     )
-                outs = stream_plane_pass(
-                    kernel, [names[q] for q in g], [bs[q] for q in g],
-                    lo, hi, x_radius, origin, gsize, interpret=interpret,
-                    fused_shell=fs,
-                    **unit_kw,
-                )
+                with telemetry.annotate(tm.SPAN_STEP_PASS):
+                    outs = stream_plane_pass(
+                        kernel, [names[q] for q in g], [bs[q] for q in g],
+                        lo, hi, x_radius, origin, gsize, interpret=interpret,
+                        fused_shell=fs,
+                        **unit_kw,
+                    )
                 for q, o in zip(g, outs):
                     out[q] = o
             return out
@@ -2240,6 +2241,22 @@ def make_stream_step(
     # the eager build may already have descended (compile-phase rejection),
     # so expose the LADDER's plan, not the initial one
     step._stream_plan = ladder.rung.state["plan"]
+
+    def span_args() -> dict:
+        """What this step's ``domain.step`` span says of the plan it runs
+        NOW (``telemetry/names.py SPAN_STEP``; the ladder may have moved it)."""
+        plan_now = step._stream_plan
+        nq = len(dd._handles)
+        return {
+            "route": plan_now["route"],
+            "x_radius": x_radius,
+            "grouping": plan_now.get("grouping", "joint"),
+            "streamed": nq,
+            # every quantity rides halo_exchange_multi on the exchanging routes
+            "exchanged": 0 if plan_now["route"] == "wrap" else nq,
+        }
+
+    step._span_args = span_args
     step._resilience = ladder
     step._resilience_label = "stream"
     return step

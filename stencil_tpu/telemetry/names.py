@@ -321,7 +321,10 @@ ALL_HISTOGRAMS = frozenset({
 # name, like NUMERICS_DRIFT): EVENT_COMPILE [label], EVENT_RETRY (the
 # back-off sleep) [label, attempt], EVENT_CHECKPOINT_SAVE [step].
 
-#: the enqueue of one ``run_step`` dispatch [label, steps = raw iterations]
+#: the enqueue of one ``run_step`` dispatch [label, steps = raw iterations;
+#: a stream-engine step adds the plan it ran: route, x_radius, grouping,
+#: streamed = quantities in the pass, exchanged = quantities riding the halo
+#: exchange (0 on the exchange-free wrap route)]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes
@@ -342,6 +345,11 @@ SPAN_NUMERICS_SNAPSHOT = "numerics.snapshot"
 #: the tier-1/tier-2 overlap proofs key on the interior scope name.
 SPAN_OVERLAP_INTERIOR = "step.overlap.interior"
 SPAN_OVERLAP_EXTERIOR = "step.overlap.exterior"
+#: the plane route's streaming pass (ops/stream.py), a DEVICE-timeline scope
+#: like the two above: the pass and the copies the compiler adds to feed it
+#: carry it, so a trace splits a plane step into pass, exchange sweeps
+#: (``exchange.<axis>``) and whatever is left
+SPAN_STEP_PASS = "step.pass"
 #: the redistribution collective schedule (parallel/redistribute.py): a
 #: named scope entered around the per-round slice/permute/blend body, so
 #: device-time attribution can price a live mesh transition
@@ -435,6 +443,7 @@ ALL_SPANS = frozenset({
     SPAN_EXCHANGE_Z,
     SPAN_OVERLAP_INTERIOR,
     SPAN_OVERLAP_EXTERIOR,
+    SPAN_STEP_PASS,
     SPAN_RESHARD,
     SPAN_EXCHANGE_X_LOW,
     SPAN_EXCHANGE_X_HIGH,

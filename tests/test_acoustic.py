@@ -1,0 +1,178 @@
+"""``AcousticWave`` (models/acoustic.py) against its plain reference
+(models/acoustic_reference.py): the so-8 acoustic propagator on the stream
+engine's plane route and on the XLA engine, in process, interpreted, small
+(24^3 with ``nbl`` 4: 8 physical cells, a 4-cell sponge and the 4-cell zero
+frame on every side).
+
+Tolerance.  The model and the reference sum the 25 points in the same order,
+so they differ by the two compilers' roundings only (fused multiply-adds, the
+seeded fill evaluated per shard against globally): a few ulp of the
+wavefield's amplitude per step, 2e-7 measured after six steps at amplitude
+0.25.  ``ATOL`` 2e-6 leaves ten times that and lies 500 times under what bf16
+storage does to the same run (1e-3, 2^-9 of the amplitude per store), which
+``test_bf16_storage_fails_the_tolerance`` holds it to.
+"""
+
+import jax
+import numpy as np
+import pytest
+
+from stencil_tpu import telemetry
+from stencil_tpu.models import acoustic_reference as ref
+from stencil_tpu.models.acoustic import QUANTITIES, AcousticWave
+from stencil_tpu.telemetry import names as tm
+
+N, NBL, DISPATCH = 24, 4, 3
+ATOL = 2e-6
+WORDS = np.asarray([0x1234ABCD, 77, 0xDEADBEEF, 2024], dtype=np.uint32)
+
+
+def _sim(impl="pallas", devices=None, **kw):
+    sim = AcousticWave(N, N, N, nbl=NBL, kernel_impl=impl, interpret=True,
+                       devices=devices or jax.devices()[:1], seed_words=WORDS, **kw)
+    sim.realize()
+    return sim
+
+
+def _reference(grid, steps):
+    f = ref.global_fields(grid, WORDS)
+    u, u_prev = ref.steps_framed(grid, f["u"], f["u_prev"], f["m"], f["damp"], steps)
+    return np.asarray(u), np.asarray(u_prev), f
+
+
+def _errors(sim, dispatches):
+    for _ in range(dispatches):
+        sim.step(DISPATCH)
+    u, u_prev, _ = _reference(sim.grid, dispatches * DISPATCH)
+    assert np.max(np.abs(u)) > 0.05  # the wave is there: the comparison means something
+    return (float(np.max(np.abs(sim.field("u") - u))),
+            float(np.max(np.abs(sim.field("u_prev") - u_prev))))
+
+
+@pytest.mark.parametrize("dispatches", [1, 2])
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
+def test_model_matches_the_reference(impl, dispatches):
+    err_u, err_prev = _errors(_sim(impl), dispatches)
+    assert err_u <= ATOL and err_prev <= ATOL, (err_u, err_prev)
+
+
+def test_bf16_storage_fails_the_tolerance():
+    """The control: the same run on the program's bf16 storage axis lies far
+    outside ``ATOL`` -- the tolerance would catch a lower precision."""
+    sim = _sim("pallas", storage_dtype="bf16")
+    assert sim.dd.storage_dtype() == "bf16"
+    err_u, _ = _errors(sim, 1)
+    assert err_u > 100 * ATOL, err_u
+
+
+def test_model_matches_the_reference_across_devices():
+    """Mesh [2,2,2]: every sweep crosses a wire, the frame is found from
+    wrapped global coordinates on every shard."""
+    sim = _sim("pallas", devices=jax.devices()[:8])
+    assert sim.dd.num_subdomains() == 8
+    err_u, err_prev = _errors(sim, 1)
+    assert err_u <= ATOL and err_prev <= ATOL, (err_u, err_prev)
+
+
+def test_one_chip_sweeps_are_self_wraps_at_radius_4(monkeypatch):
+    """On one device every axis is unsplit: with the blend kernels on (as on
+    the chip) all three sweeps take ``wrap_halo`` at radius 4, every step."""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    sim = _sim("pallas")
+    assert set(sim.dd._wrap_axes) == {"x", "y", "z"}, sim.dd._wrap_axes
+    err_u, err_prev = _errors(sim, 1)
+    assert err_u <= ATOL and err_prev <= ATOL, (err_u, err_prev)
+
+
+def test_route_is_plane_and_the_span_says_so():
+    sim = _sim("pallas")
+    plan = sim._step._stream_plan
+    assert plan["route"] == "plane" and plan["m"] == 1 and plan["grouping"] == "joint", plan
+    assert sim._step._span_args() == {
+        "route": "plane", "x_radius": 4, "grouping": "joint", "streamed": 4, "exchanged": 4,
+    }
+    seen = []
+    real = telemetry.span
+
+    def spy(name, *a, **kw):
+        seen.append((name, kw))
+        return real(name, *a, **kw)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(telemetry, "span", spy)
+        sim.step(2)
+    (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
+    assert kw["label"] == "acoustic" and kw["steps"] == 2 and kw["route"] == "plane"
+
+
+def test_plane_pass_sits_under_its_scope():
+    """``step.pass`` around the plane pass, in the traced program's name
+    stacks (what the profiler's ``op_name`` is made of)."""
+    sim = _sim("pallas")
+    impl = sim._step._resilience.built()
+    lowered = impl.lower(sim.dd._curr, 1).as_text(debug_info=True)
+    assert f"{tm.SPAN_STEP_PASS}/{tm.KERNEL_STREAM_PLANE_PASS}" in lowered
+
+
+def test_frame_equals_devitos_zero_halo_exactly():
+    """The pinned frame on the periodic array and the zero-padded frameless
+    array (Devito's arrangement) give the same cells, bit for bit."""
+    grid = ref.AcousticGrid((N, N, N), nbl=NBL)
+    u, u_prev, f = _reference(grid, 2 * DISPATCH)
+    inner = [ref.interior(f[k]) for k in QUANTITIES]
+    pu, pu_prev = ref.steps_padded(grid, *inner, 2 * DISPATCH)
+    np.testing.assert_array_equal(np.asarray(pu), ref.interior(u))
+    np.testing.assert_array_equal(np.asarray(pu_prev), ref.interior(u_prev))
+
+
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
+def test_frame_stays_exactly_zero(impl):
+    sim = _sim(impl)
+    sim.step(2 * DISPATCH)
+    frame = np.broadcast_to(np.asarray(ref.frame_mask((N, N, N))), (N, N, N))
+    for q in ("u", "u_prev"):
+        a = sim.field(q)
+        assert np.all(a[frame] == 0.0) and np.any(a[~frame] != 0.0), q
+    # the model fields are read, never written
+    f = ref.global_fields(sim.grid, WORDS)
+    for q in ("m", "damp"):
+        np.testing.assert_allclose(sim.field(q), np.asarray(f[q]), rtol=0, atol=1e-6)
+
+
+def test_seeded_fields_are_what_the_configuration_says():
+    grid = ref.AcousticGrid((N, N, N), nbl=NBL)
+    f = {k: np.asarray(v) for k, v in ref.global_fields(grid, WORDS).items()}
+    lo, hi = ref.FRAME + NBL, N - ref.FRAME - NBL
+    vp = 1.0 / np.sqrt(f["m"])
+    assert vp.min() >= 1.5 - 1e-5 and vp.max() <= 3.5 + 1e-5
+    assert np.all(np.diff(vp[0, 0, :]) >= 0)  # layers get faster with depth
+    assert np.all(f["damp"][lo:hi, lo:hi, lo:hi] == 0.0) and f["damp"].max() > 0
+    outside = np.ones((N, N, N), bool)
+    outside[lo:hi, lo:hi, lo:hi] = False
+    assert np.all(f["u"][outside] == 0.0) and np.all(f["u_prev"][outside] == 0.0)
+    assert np.max(np.abs(f["u"])) < ref.AMPLITUDE_BOUND
+    assert abs(grid.dt - 0.38 * 20.0 / 3.5) < 1e-12
+    other = ref.global_fields(grid, WORDS + 1)
+    assert np.max(np.abs(np.asarray(other["u"]) - f["u"])) > 1e-3  # the seed matters
+
+
+def test_fill_takes_the_seed_as_an_argument():
+    """A second seed reuses the fill program: the words are traced arguments,
+    not constants baked into a new program (PERF.md: `setup_s` is seed-bound)."""
+    sim = _sim("pallas")
+    u0 = ref.seeded_fields(sim.grid)["u"]
+    c = (np.arange(4)[:, None, None], np.arange(4)[None, :, None], np.arange(4)[None, None, :])
+    programs = {jax.jit(u0).lower(*c, w).as_text() for w in (WORDS, WORDS + 5)}
+    assert len(programs) == 1
+    sim.fill({"u": u0}, (WORDS + 5,))
+    want = np.asarray(ref.global_fields(sim.grid, WORDS + 5)["u"])
+    np.testing.assert_allclose(sim.field("u"), want, rtol=0, atol=1e-6)
+
+
+def test_driver_runs_on_the_cpu(capsys):
+    from stencil_tpu.bin import acoustic
+
+    rc = acoustic.main(["8", "8", "8", "--nbl", "4", "--iters", "1", "--steps", "2"])
+    assert rc == 0
+    row = capsys.readouterr().out.strip().splitlines()[-1].split(",")
+    assert row[0] == "acoustic" and row[3:7] == ["8", "8", "8", "4"]

@@ -61,7 +61,19 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
             m = json.load(f)
         assert m["reducer"] in ("named_share", "span_percentile", "span_count", "idle_by_span"), name
         assert m["source"] in ("device_trace", "program_span") and m["cells"] == declared[name]["workloads"]
-    for name in set(declared) - new:
+    # PR 27's: the plane route's shares for the acoustic cells, by name too,
+    # applied by pattern so that a later acoustic cell needs no edit
+    plane = {
+        "plane_pass_pct", "plane_pass_hbm_pct", "exchange_dev_pct.plane",
+        "step_glue_pct.plane", "kernel_named_pct.plane",
+    }
+    assert plane <= set(declared)
+    for name in plane:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["reducer"] in ("named_share", "named_roofline_hbm") and m["cells"] == ["acoustic-*"], name
+        assert declared[name]["workloads"] == ["acoustic-so8-600.bulk"], name
+    for name in set(declared) - new - plane:
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
                                                "trace_roofline_hbm", "trace_idle"), name

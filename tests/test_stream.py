@@ -559,3 +559,40 @@ def test_stream_forced_paths_and_rejects():
     dd2.realize()
     with pytest.raises(ValueError):
         dd2.make_step(mean6_kernel, engine="stream", interpret=True)
+
+
+# --- the plane route beyond radius 1 (x_radius 2..4, ISSUE 27) ----------------
+
+
+def star_kernel(r):
+    """A (6r+1)-point star reading every distance 1..r on every axis, with
+    distinct weights so a wrong offset or ring slot cannot cancel."""
+
+    def kernel(views, info):
+        src = views["u"]
+        acc = 0.5 * src.center()
+        for k in range(1, r + 1):
+            w = 1.0 / (12.0 * k)
+            acc = acc + w * (
+                (src.sh(k, 0, 0) + 0.9 * src.sh(-k, 0, 0))
+                + (src.sh(0, k, 0) + 0.8 * src.sh(0, -k, 0))
+                + (src.sh(0, 0, k) + 0.7 * src.sh(0, 0, -k))
+            )
+        return {"u": acc}
+
+    return kernel
+
+
+@pytest.mark.parametrize("n_dev", [1, 8])
+@pytest.mark.parametrize("r", [2, 3, 4])
+def test_plane_route_reads_its_whole_halo(r, n_dev):
+    """A kernel that reads as far as it exchanges (``x_radius == radius``):
+    the plane route's 2r-deep ring, against the XLA engine, on one device
+    (every sweep a self-wrap) and on eight (every sweep over the mesh)."""
+    devices = jax.devices()[:n_dev]
+    mk = lambda: _mk(16, 16, 16, Radius.constant(r), ["u"], devices)  # noqa: E731
+    outs, step = _run_both(mk, mk, star_kernel(r), 3, x_radius=r)
+    assert step._stream_plan["route"] == "plane", step._stream_plan
+    assert step._span_args()["x_radius"] == r
+    for a, b in outs:
+        np.testing.assert_allclose(a, b, **TOL)

@@ -61,6 +61,10 @@ walker or a source-level heuristic the tracer can defeat:
   consistent ``input_output_aliases`` entry (the donation-soundness
   analog one level down); boundary shells up to the plan's depth margin
   are the one sanctioned gap.
+* ``inplace-order``       — for every output a pallas call aliases onto
+  an input, on a sequential grid, no input block is fetched after the
+  output block over the same cells was flushed: writes trail reads, or in
+  place the kernel reads its own result (``analysis/kernels.py``).
 * ``tiling-legal``        — the Mosaic tiling-legality model over the
   traced kernels: no rotate on unaligned or non-32-bit planes, no
   blocked windows at sub-granule offsets, no int64 index arithmetic —
@@ -952,6 +956,29 @@ class KernelCoverage(Contract):
         return [
             art.finding(self.name, msg)
             for msg in kernels.check_coverage(art)
+        ]
+
+
+@register
+class InplaceOrder(Contract):
+    name = "inplace-order"
+    why = (
+        "an output aliased onto an input (input_output_aliases) shares its "
+        "HBM buffer: on a sequential grid no input block may be fetched "
+        "after the output block over the same cells was flushed, or the "
+        "kernel reads its own result — CPU interpret mode, which runs an "
+        "aliased call functionally, can never show it (analysis/kernels.py)"
+    )
+
+    def applies_to(self, art: ProgramArtifact) -> bool:
+        return art.closed is not None
+
+    def check(self, art: ProgramArtifact) -> List[Finding]:
+        from stencil_tpu.analysis import kernels
+
+        return [
+            art.finding(self.name, msg)
+            for msg in kernels.check_inplace_order(art)
         ]
 
 

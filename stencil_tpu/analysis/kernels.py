@@ -37,6 +37,23 @@ classes into static verdicts:
   visit the dead pad columns, so a trailing minor-dim run of uncovered
   blocks shorter than one lane tile — on an output whose minor extent is
   a 128-multiple — is tolerated too.  Any other gap fires.
+* **In-place order** (:func:`check_inplace_order`, contract
+  ``inplace-order``).  An output that aliases an input
+  (``input_output_aliases``) shares its HBM buffer, so on a sequential
+  grid a block the kernel has already FLUSHED must never be FETCHED again
+  as input — the kernel would read its own result (and, one step apart,
+  race its own write-back DMA).  The pipeline fetches an input block when
+  its index changes (and at step 0) and flushes an output block after the
+  last step that holds it (the step before its index changes, or the last
+  step): for every aliased pair, no input block fetched at a step ``> i``
+  may overlap an output block flushed at a step ``<= i``.  The streaming
+  passes satisfy it by construction — the plane pass writes ``clip(i - r,
+  0, X - 1)`` while reading ``min(i, X - 1)``, the wavefront ``max(i - m,
+  0)`` while reading ``i``: writes trail reads — and until this contract
+  that was a comment.  CPU interpret mode runs an aliased call
+  functionally and can never show the hazard; this check and a chip run
+  are what can.  Grids with a ``parallel`` dim have no order to check
+  (``kernel-race`` owns them).
 * **Mosaic tiling legality** (:func:`check_tiling`, contract
   ``tiling-legal``; :func:`check_kernel_legal` is the pre-build plan
   surface).  The shape/op legality model for the lowering failures PR 6
@@ -302,8 +319,8 @@ def kernel_reports(closed, grid_bound: int = GRID_EVAL_BOUND) -> List[KernelRepo
                 aval = _aval_of(v)
                 shape = tuple(getattr(aval, "shape", ()) or ())
                 scratch.append((shape, getattr(aval, "dtype", None)))
-        nsi = params.get("name_and_src_info")
-        label = getattr(nsi, "name", None) or eqn.primitive.name
+        # the kernel's registered name (``pl.pallas_call(name=...)``)
+        label = params.get("name") or eqn.primitive.name
         reports.append(
             KernelReport(
                 label=str(label),
@@ -441,6 +458,63 @@ def check_coverage(art) -> List[str]:
                     f"{margin}-block shell margin (first: {bad[0]}) and is "
                     "not carried in via input_output_aliases"
                 )
+    return out
+
+
+def _block_box(use: BlockUse, blk) -> Tuple[Tuple[int, int], ...]:
+    """Element range ``[lo, hi)`` per dim of one block of ``use``."""
+    return tuple(
+        (i * b, min((i + 1) * b, n))
+        for i, b, n in zip(blk, use.block_shape, use.array_shape)
+    )
+
+
+def _boxes_overlap(a, b) -> bool:
+    return all(lo < bhi and blo < hi for (lo, hi), (blo, bhi) in zip(a, b))
+
+
+def check_inplace_order(art) -> List[str]:
+    """``inplace-order``: on a sequential grid, no aliased input block is
+    fetched after the output block over the same cells was flushed (module
+    docstring).  One finding per aliased pair, naming the first hazard."""
+    out: List[str] = []
+    for rep in kernel_reports(art.closed):
+        if rep.parallel_dims:
+            continue  # no order to check: kernel-race owns parallel grids
+        for out_i, src in sorted(rep.aliases.items()):
+            dst = rep.outputs[out_i]
+            if not src.footprint or not dst.footprint:
+                continue
+            last = len(dst.footprint) - 1
+            # last step at which each distinct input block is FETCHED (the
+            # pipeline skips the DMA while the block index stands still)
+            last_fetch: Dict[Tuple[int, ...], int] = {}
+            for j, blk in enumerate(src.footprint):
+                if j == 0 or blk != src.footprint[j - 1]:
+                    last_fetch[blk] = j
+            fetched = [(_block_box(src, b), b, j) for b, j in last_fetch.items()]
+            for i, blk in enumerate(dst.footprint):
+                if i != last and dst.footprint[i + 1] == blk:
+                    continue  # still held: flushed after a later step
+                box = _block_box(dst, blk)
+                hit = next(
+                    (
+                        (b, j)
+                        for fbox, b, j in fetched
+                        if j > i and _boxes_overlap(fbox, box)
+                    ),
+                    None,
+                )
+                if hit is not None:
+                    out.append(
+                        f"{rep.label}: output {out_i} aliases in[{src.index}] "
+                        f"and flushes block {blk} after grid step {i}, but "
+                        f"input block {hit[0]} over the same cells is fetched "
+                        f"at step {hit[1]} — in place the kernel reads its "
+                        "own result (writes must trail reads on a "
+                        "sequential grid)"
+                    )
+                    break
     return out
 
 

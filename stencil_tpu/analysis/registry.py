@@ -102,3 +102,19 @@ PALLAS_KERNELS = {
         "stream_wrap_pass",
     ),
 }
+
+#: in-place streaming passes — kernels whose ``alias=`` lands an output on
+#: the buffer the sequential grid is still reading from, some planes behind
+#: — and the fixtures under ``tests/analysis_fixtures`` that trace the REAL
+#: pass aliased and hold it to the ``inplace-order`` contract
+#: (``analysis/kernels.py``).  ``tests/test_analysis.py::
+#: test_inplace_passes_are_proven_aliased`` pins that each fixture carries
+#: that kernel aliased and comes out clean: a fixture that silently lost
+#: its alias would prove nothing.
+INPLACE_PASSES = {
+    "stream_plane_pass": (
+        "inplace_order_plane_r1_clean.py",
+        "inplace_order_plane_r4_clean.py",
+    ),
+    "stream_wavefront_pass": ("inplace_order_wavefront_clean.py",),
+}

@@ -285,6 +285,7 @@ def stream_plane_pass(
     x_radius: int,  # kernel x read distance r; ring depth is 2r
     origin: jax.Array,  # (3,) int32 global coords of the interior start
     global_size: Dim3,
+    alias: bool = False,  # out q aliases raw q (in place; see below)
     interpret: bool = False,
     compute_unit: str = "vpu",  # "mxu"/"mxu_band": band constants ride in
     # as resident inputs and the views' plane_nbr_sum contracts on the
@@ -308,7 +309,23 @@ def stream_plane_pass(
     loaded plane is patched in VMEM — x-shell planes replaced from the x
     slabs, then y rows, then z columns, replaying the exchange's sweep
     order — before it feeds the ring, the kernel, or the pass-through, so
-    the pass is bitwise-identical to running over exchanged blocks."""
+    the pass is bitwise-identical to running over exchanged blocks.
+
+    With ``alias`` output ``q`` IS raw ``q`` (``input_output_aliases``): a
+    step loop that carries its blocks in place then needs no whole-array
+    copy per quantity per step to put a fresh result where the carry lives.
+    In place is safe because writes trail reads by ``r >= 1`` planes on the
+    sequential grid ``(X + r,)``: step ``i`` fetches in plane ``min(i, X-1)``
+    and holds out plane ``clip(i - r, 0, X-1)``.  The out plane flushed
+    after step ``i`` is ``i - r <= i - 1``; every in plane fetched after
+    step ``i`` is ``>= i + 1`` (or the clamped ``X-1``, which is written
+    last, after the final step).  Out plane 0 is held for steps ``0..r``
+    and flushed once, after plane 0 was read at step 0.  All the kernel
+    needs of planes ``i-2r..i`` sits in the VMEM rings by the time plane
+    ``i - r`` is written.  The ``inplace-order`` contract
+    (``analysis/kernels.py check_inplace_order``) proves this from the
+    traced block maps; CPU interpret mode runs an aliased call
+    functionally and cannot."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -470,6 +487,9 @@ def stream_plane_pass(
         in_specs=in_specs,
         out_specs=out_specs if nq > 1 else out_specs[0],
         out_shape=out_shape if nq > 1 else out_shape[0],
+        # operand 0 is origin; band constants and fused-shell side inputs
+        # sit after the raws, so the map is raw-q -> out-q whatever rides in
+        input_output_aliases={1 + q: q for q in range(nq)} if alias else {},
         scratch_shapes=[
             pltpu.VMEM((2 * r, Y, Z), b.dtype) for b in raws
         ],
@@ -672,10 +692,10 @@ def stream_wavefront_pass(
             jax.ShapeDtypeStruct((Xr, 2 * s_off, Yr), b.dtype) for b in raws
         ]
         args += list(z_slabs)
-    # in-place safe (write trails read by m+1 planes); un-aliased is ~20%
-    # faster at deep m (probe21b) at the cost of fresh output buffers.
-    # (Band-matrix inputs sit between the raws and the slabs, so the alias
-    # map stays raw-q -> out-q regardless.)
+    # in-place safe: out plane max(i - m, 0) trails in plane i by m >= 1
+    # (the inplace-order contract, analysis/kernels.py, proves it from the
+    # block maps).  Band-matrix inputs sit between the raws and the slabs,
+    # so the alias map stays raw-q -> out-q regardless.
     aliases = {1 + q: q for q in range(nq)} if alias else {}
     outs = pl.pallas_call(
         body,
@@ -1143,12 +1163,19 @@ def permute_and_extend_z_slabs(zout, s: int, mesh_shape, yext, xext):
         return jnp.concatenate([xext(yext(zlo)), xext(yext(zhi))], axis=1)
 
 
+def static_stream_alias(route: str, n_fields: int) -> bool:
+    """The no-tune alias rule, read from what the plan says of itself: the
+    plane route always, any route from 4 fields up (``_build_stream_step``
+    has the account: what is measured, what is round-5 hearsay)."""
+    return route == "plane" or n_fields >= 4
+
+
 def _resolve_stream_alias(plan: dict, n_fields: int) -> bool:
     """input_output_aliases decision for a stream plan.  Precedence mirrors
     the bespoke wavefront path (models/jacobi.py): an autotuner CANDIDATE
     build (``alias_forced`` — its A/B trials must actually differ, whatever
     the environment says) > ``STENCIL_STREAM_ALIAS=0/1`` (validated read) >
-    the plan's persisted tuned ``alias`` > the >= 4-fields static rule."""
+    the plan's persisted tuned ``alias`` > ``static_stream_alias``."""
     from stencil_tpu.utils.config import env_choice
 
     if plan.get("alias_forced") and plan.get("alias") is not None:
@@ -1158,7 +1185,20 @@ def _resolve_stream_alias(plan: dict, n_fields: int) -> bool:
         return env == "1"
     if plan.get("alias") is not None:
         return bool(plan["alias"])
-    return n_fields >= 4
+    return static_stream_alias(plan.get("route"), n_fields)
+
+
+def _plan_passes_in_place(plan: dict) -> bool:
+    """Do the main passes of a BUILT plan write onto their inputs?  The
+    resolved ``plan["alias"]`` — except on the plane route under
+    ``overlap="split"``, which keeps fresh outputs: the interior pass and
+    the exchange both read the pre-exchange blocks, so XLA copies each block
+    once a step either way (compiled for a described v5e 2x2, 260^3 shards:
+    one whole-array copy per quantity per step aliased or not, and 73 MB
+    more temporaries aliased)."""
+    return bool(plan.get("alias")) and not (
+        plan["route"] == "plane" and plan.get("overlap") == "split"
+    )
 
 
 def _overlap_request(plan: dict) -> Tuple[str, str]:
@@ -1378,14 +1418,24 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
     # resolved route (packed z-shell vs direct — ops/exchange.py), so stream
     # steps escape the 64×-amplified thin-z path exactly like exchange()
     exch_route = getattr(dd, "_exchange_route", "direct")
-    # Un-aliased wavefront passes are ~10-20% faster for FEW fields
-    # (probe21b: the in-place alias serializes the deep-m pipeline) but cost
-    # one fresh raw-sized buffer per pass.  From 4 fields up, alias: a joint
-    # pass would double a multi-GB working set (8 x ~700 MB exhausted HBM in
-    # bench), and even per-field grouped passes measured ~50% SLOWER
-    # un-aliased at 8x512^3 (19.1 vs 12.8 ms/iter, r5 bench) — the per-pass
-    # allocate/free churn costs more than the aliasing serialization saves.
-    alias = _resolve_stream_alias(plan, len(names))
+    # Pass outputs alias their inputs or not (_resolve_stream_alias), written
+    # back into the plan like overlap / halo / compute_unit (the ladder,
+    # step._stream_plan and domain.step's ``aliased`` read it).  The wrap
+    # pass has no in-place form.
+    # MEASURED on the v5e (PERF.md §6, PR 28): the plane route un-aliased
+    # pays one whole-array copy per quantity per step — its pass runs inside
+    # the step loop, whose carry lives in place.  Acoustic, four quantities
+    # at 608^3: 29.35 -> 17.77 ms a step aliased, the pass itself unchanged
+    # at 11.48 ms; one quantity at radius 2, 512^3: 5.44 -> 3.35 ms.  So the
+    # plane route always aliases (static_stream_alias).
+    # Round-5 HEARSAY, never re-measured on this chip: un-aliased WAVEFRONT
+    # passes ~10-20% faster for few fields (probe21b: the in-place alias
+    # serializes the deep-m pipeline), aliased ahead from 4 fields up (8 x
+    # ~700 MB of fresh results exhausted HBM; per-field passes at 8x512^3
+    # read 19.1 ms/iter un-aliased against 12.8).  The wavefront rule rests
+    # on that and stays as it was.
+    alias = _resolve_stream_alias(plan, len(names)) and plan["route"] != "wrap"
+    plan["alias"] = alias
     # split-step overlap schedule (module docstring): resolve, write the
     # decision back into the plan (the ladder and step._stream_plan read it),
     # and record it — the stream-engine twin of the exchange.route event
@@ -1599,6 +1649,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
             )
 
     elif plan["route"] == "plane":
+        in_place = _plan_passes_in_place(plan)
 
         def plane_groups(bs, origin, fused_bufs=None):
             out = list(bs)
@@ -1614,9 +1665,8 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
                 with telemetry.annotate(tm.SPAN_STEP_PASS):
                     outs = stream_plane_pass(
                         kernel, [names[q] for q in g], [bs[q] for q in g],
-                        lo, hi, x_radius, origin, gsize, interpret=interpret,
-                        fused_shell=fs,
-                        **unit_kw,
+                        lo, hi, x_radius, origin, gsize, alias=in_place,
+                        interpret=interpret, fused_shell=fs, **unit_kw,
                     )
                 for q, o in zip(g, outs):
                     out[q] = o
@@ -2252,6 +2302,8 @@ def make_stream_step(
             "x_radius": x_radius,
             "grouping": plan_now.get("grouping", "joint"),
             "streamed": nq,
+            # quantities whose pass output aliases its input (all or none)
+            "aliased": nq if _plan_passes_in_place(plan_now) else 0,
             # every quantity rides halo_exchange_multi on the exchanging routes
             "exchanged": 0 if plan_now["route"] == "wrap" else nq,
         }

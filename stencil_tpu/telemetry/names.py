@@ -323,8 +323,9 @@ ALL_HISTOGRAMS = frozenset({
 
 #: the enqueue of one ``run_step`` dispatch [label, steps = raw iterations;
 #: a stream-engine step adds the plan it ran: route, x_radius, grouping,
-#: streamed = quantities in the pass, exchanged = quantities riding the halo
-#: exchange (0 on the exchange-free wrap route)]
+#: streamed = quantities in the pass, aliased = quantities whose pass output
+#: aliases its input (all or none: ``ops/stream._plan_passes_in_place``), exchanged
+#: = quantities riding the halo exchange (0 on the exchange-free wrap route)]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes

@@ -325,6 +325,7 @@ def stream_space(dd, x_radius: int, separable: bool, static_plan: dict,
         fused_halo_ineligible,
         plain_wavefront_plan,
         plan_stream,
+        static_stream_alias,
     )
 
     cands: List[dict] = []
@@ -342,7 +343,7 @@ def stream_space(dd, x_radius: int, separable: bool, static_plan: dict,
             cands.append(c)
 
     nq = len(dd._handles)
-    static_alias = nq >= 4  # the _build_stream_step auto rule
+    static_alias = static_stream_alias(static_plan["route"], nq)
     add(static_plan, static_alias if static_plan["route"] != "wrap" else None)
     if static_plan["route"] in ("wavefront", "wrap"):
         m = static_plan["m"]

@@ -88,8 +88,10 @@ def test_route_is_plane_and_the_span_says_so():
     sim = _sim("pallas")
     plan = sim._step._stream_plan
     assert plan["route"] == "plane" and plan["m"] == 1 and plan["grouping"] == "joint", plan
+    assert plan["alias"] is True, plan  # the plane route writes in place (ISSUE 28)
     assert sim._step._span_args() == {
-        "route": "plane", "x_radius": 4, "grouping": "joint", "streamed": 4, "exchanged": 4,
+        "route": "plane", "x_radius": 4, "grouping": "joint", "streamed": 4,
+        "aliased": 4, "exchanged": 4,
     }
     seen = []
     real = telemetry.span
@@ -103,6 +105,44 @@ def test_route_is_plane_and_the_span_says_so():
         sim.step(2)
     (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
     assert kw["label"] == "acoustic" and kw["steps"] == 2 and kw["route"] == "plane"
+    assert kw["aliased"] == 4
+
+
+def _plane_pass_aliases(fn, curr):
+    """``input_output_aliases`` of every ``stream_plane_pass`` in the traced step."""
+    from stencil_tpu.analysis import jaxpr as jx
+
+    closed = jax.make_jaxpr(fn, static_argnums=1)(curr, 1)
+    return [
+        tuple(tuple(int(v) for v in pair) for pair in e.params["input_output_aliases"])
+        for e in jx.iter_eqns(closed)
+        if e.primitive.name == "pallas_call"
+        and e.params.get("name") == tm.KERNEL_STREAM_PLANE_PASS
+    ]
+
+
+def test_the_step_program_says_the_pass_is_in_place():
+    """The step as built: ONE plane pass whose four outputs alias the four raw
+    blocks (operand 0 is ``origin``); the same plan forced off carries none,
+    and says so in the plan and on the span."""
+    from stencil_tpu.ops import stream as sm
+
+    sim = _sim("pallas")
+    assert _plane_pass_aliases(sim._step._resilience.built(), sim.dd._curr) == [
+        ((1, 0), (2, 1), (3, 2), (4, 3))
+    ]
+    plan = dict(sim._step._stream_plan, alias=False, alias_forced=True)
+    off = sm._build_stream_step(sim.dd, sim._kernel, ref.RADIUS, plan, interpret=True)
+    assert _plane_pass_aliases(off, sim.dd._curr) == [()]
+    assert plan["alias"] is False
+    # the split schedule keeps fresh outputs whatever the plan resolves: the
+    # interior pass and the exchange both read the pre-exchange blocks
+    plan = dict(sim._step._stream_plan, overlap="split", overlap_forced=True)
+    split = sm._build_stream_step(sim.dd, sim._kernel, ref.RADIUS, plan, interpret=True)
+    passes = _plane_pass_aliases(split, sim.dd._curr)
+    assert len(passes) == 7 and set(passes) == {()}, passes  # interior + six bands
+    assert plan["overlap"] == "split" and plan["alias"] is True
+    assert sm._plan_passes_in_place(plan) is False
 
 
 def test_plane_pass_sits_under_its_scope():

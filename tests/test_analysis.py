@@ -619,6 +619,45 @@ def test_kernel_ledger_matches_tree():
     assert found == dict(aregistry.PALLAS_KERNELS)
 
 
+def _inplace_cases():
+    return [
+        (kernel, fixture)
+        for kernel, fixtures in sorted(aregistry.INPLACE_PASSES.items())
+        for fixture in fixtures
+    ]
+
+
+@pytest.mark.parametrize("kernel,fixture", _inplace_cases())
+def test_inplace_passes_are_proven_aliased(kernel, fixture):
+    """Every pass the registry names as running in place (``INPLACE_PASSES``)
+    has its fixtures: each traces the REAL kernel with every streamed output
+    aliased onto its input — so ``inplace-order`` has pairs to judge — and
+    comes out clean.  A fixture that lost its alias would pass vacuously."""
+    from stencil_tpu.analysis import kernels
+    from stencil_tpu.telemetry import names as tm
+
+    assert kernel in tm.ALL_KERNELS
+    art = _load(os.path.join(FIXTURE_DIR, fixture))
+    reps = [r for r in kernels.kernel_reports(art.closed) if r.label == kernel]
+    assert reps, f"{fixture} traces no {kernel}"
+    for rep in reps:
+        assert rep.aliases and not rep.parallel_dims, rep
+        assert all(a.footprint for a in rep.aliases.values()), rep.notes
+    assert not kernels.check_inplace_order(art)
+
+
+def test_inplace_order_reads_fetch_and_flush_steps():
+    """The contract's model on the fire fixture: the hazard it names is the
+    first flushed block that a LATER step fetches (plane 0 is read before it
+    is flushed; plane 1, flushed after step 1, is fetched at step 2)."""
+    from stencil_tpu.analysis import kernels
+
+    (msg,) = kernels.check_inplace_order(
+        _load(os.path.join(FIXTURE_DIR, "inplace_order_fire.py"))
+    )
+    assert "flushes block (1, 0, 0) after grid step 1" in msg and "step 2" in msg
+
+
 # --- tier-2: the real CLI end to end -----------------------------------------
 
 

@@ -211,3 +211,45 @@ def test_no_overlap_step_schedule_serializes():
     step = dd.make_step(_jacobi_kernel, overlap=False, donate=False)
     text = step.lower(dd.abstract_arrays(), 1).compile().as_text()
     assert "step.overlap.interior" not in text
+
+
+@pytest.mark.slow  # tier-2 with its siblings: a real-TPU-compiler AOT
+# compile at the benchmark's size (15 s alone, and it loads libtpu into the
+# worker that runs it)
+def test_acoustic_step_carries_its_blocks_in_place(monkeypatch):
+    """The acoustic cell's step (600^3, four quantities, plane route) as the
+    chip's compiler leaves it: the pass's custom call aliases all four
+    results onto its operands and the ``while`` body holds NO whole-array
+    copy — un-aliased, XLA copies every 608^3 block every step to put the
+    fresh result where the loop's carry lives (PERF.md §6, PR 28: 11.5 of
+    29.35 ms).  The check ISSUE 28 asks for before any chip call."""
+    from stencil_tpu.models.acoustic import RADIUS, AcousticWave
+    from stencil_tpu.ops import halo_blend
+    from stencil_tpu.ops import stream as sm
+
+    devices = _topology_devices()
+    monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        texts = {}
+        for alias in (None, False):
+            sim = AcousticWave(600, 600, 600, devices=devices[:1], seed_words=None)
+            sim.dd.realize(allocate=False)
+            plan = sm.plan_stream(sim.dd, RADIUS, "auto", False)
+            if alias is not None:
+                plan = dict(plan, alias=alias, alias_forced=True)
+            step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
+            compiled = step.lower(sim.dd.abstract_arrays(), 8).compile()
+            texts[alias] = (compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes)
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    big_copy = re.compile(r"=\s+f32\[608,608,608\]\S*\s+copy\(")
+    text, temp = texts[None]
+    (pass_line,) = [
+        l for l in text.splitlines() if "stream_plane_pass" in l and "custom-call(" in l
+    ]
+    assert "output_to_operand_aliasing={{0}: (1, {}), {1}: (2, {}), {2}: (3, {}), {3}: (4, {})}" in pass_line
+    assert not big_copy.findall(text) and temp == 0
+    text_off, temp_off = texts[False]
+    assert len(big_copy.findall(text_off)) == 4 and temp_off > 3.7e9

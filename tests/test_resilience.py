@@ -485,12 +485,17 @@ class TestLadderEngines:
         step = dd.make_step(mean6_kernel, engine="stream", interpret=True)
         assert step._stream_plan == {
             "route": "wavefront", "m": 3, "z_slabs": True, "grouping": "joint",
+            "alias": False,  # one field on the wavefront route: fresh outputs
             "overlap": "off", "halo": "array", "compute_unit": "vpu",
             "mxu_input": "f32",
         }
         inject.set_plan("execute:vmem_oom:stream*2")
         dd.run_step(step, 4)
         assert step._stream_plan["route"] == "plane"
+        # a depth descent re-plans: the plane rung resolves its OWN alias
+        # (in place) instead of inheriting the wavefront rung's
+        assert step._stream_plan["alias"] is True
+        assert step._span_args()["aliased"] == 1
         assert [d[0] for d in step._resilience.descents] == [
             "wavefront[m=3]", "wavefront[m=2]",
         ]

@@ -363,6 +363,7 @@ def test_stream_separable_per_field_grouping(monkeypatch):
     )
     assert step._stream_plan == {
         "route": "wavefront", "m": 3, "z_slabs": True, "grouping": "per-field",
+        "alias": True,  # four fields: the wavefront's static rule, written back
         "overlap": "off", "halo": "array", "compute_unit": "vpu",
         "mxu_input": "f32",
     }
@@ -430,6 +431,7 @@ def test_stream_depth_cap():
     )
     assert step._stream_plan == {
         "route": "wrap", "m": 8, "z_slabs": False, "grouping": "joint",
+        "alias": False,  # the wrap pass has no in-place form
         "overlap": "off", "halo": "array", "compute_unit": "vpu",
         "mxu_input": "f32",
     }
@@ -596,3 +598,106 @@ def test_plane_route_reads_its_whole_halo(r, n_dev):
     assert step._span_args()["x_radius"] == r
     for a, b in outs:
         np.testing.assert_allclose(a, b, **TOL)
+
+
+# --- the plane pass in place (ISSUE 28) ---------------------------------------
+
+
+def coupled_kernel(r):
+    """Four coupled quantities in acoustic's shape: ``u`` reads its whole
+    halo and every other field's centre, ``v`` takes the old ``u`` (a second
+    time level), ``d`` is rewritten from itself, and ``c`` is a coefficient
+    the kernel does not return (a pass-through, as ``m`` and ``damp``)."""
+    star = star_kernel(r)
+
+    def kernel(views, info):
+        u = views["u"]
+        new = star(views, info)["u"] * (1.0 + 0.1 * views["c"].center())
+        new = new - 0.05 * views["v"].center() + 0.01 * views["d"].center()
+        return {"u": new, "v": u.center(), "d": 0.5 * views["d"].center()}
+
+    return kernel
+
+
+#: (id, radius, quantities, devices, extent, plan overrides, domain set-up)
+_INPLACE_CASES = [
+    pytest.param(r, nq, n_dev, (16, 16, 16), {}, {}, id=f"r{r}-q{nq}-dev{n_dev}")
+    for r in (1, 2, 4)
+    for nq in (1, 4)
+    for n_dev in (1, 8)
+] + [
+    pytest.param(1, 1, 8, (15, 13, 15), {}, {}, id="padded"),
+    pytest.param(2, 4, 8, (16, 16, 16), {"overlap": "split", "overlap_forced": True}, {},
+                 id="split"),
+    pytest.param(1, 4, 8, (16, 16, 16), {"halo": "fused", "halo_forced": True},
+                 {"exchange_route": "yzpack_xla"}, id="fused"),
+    pytest.param(2, 4, 1, (16, 16, 16), {}, {"storage": "bf16"}, id="bf16-storage"),
+]
+
+
+@pytest.mark.parametrize("r,nq,n_dev,extent,plan_kw,dom_kw", _INPLACE_CASES)
+def test_plane_pass_in_place_is_bitwise_the_fresh_pass(r, nq, n_dev, extent, plan_kw, dom_kw):
+    """The plane route with every pass output aliased onto its input
+    (``plan["alias"]``, forced through the plan as the autotuner's candidate
+    builds force it) against the same plan un-aliased: bitwise equal on every
+    quantity after three steps, over radius, quantity count (one of four a
+    pass-through), devices, padded extents, the split schedule, the fused
+    halo and bf16 storage.
+
+    What this cannot show: CPU interpret mode runs an aliased ``pallas_call``
+    FUNCTIONALLY (the input is copied, never overwritten under the kernel),
+    so a read-after-write hazard of the in-place order cannot appear here.
+    The ``inplace-order`` contract (``analysis/kernels.py``; fixtures
+    ``inplace_order_*``) proves the order from the traced block maps, and the
+    benchmark cell ``acoustic-so8-600.bulk``'s ``correct`` holds the compiled
+    kernel to the plain reference on the chip."""
+    from stencil_tpu.ops import stream as sm
+
+    names = ["u", "v", "c", "d"][:nq]
+    kernel = coupled_kernel(r) if nq == 4 else star_kernel(r)
+    fields, plans = [], []
+    for alias in (True, False):
+        dd = DistributedDomain(*extent)
+        dd.set_radius(Radius.constant(r))
+        dd.set_devices(jax.devices()[:n_dev])
+        if "exchange_route" in dom_kw:
+            dd.set_exchange_route(dom_kw["exchange_route"])
+        if "storage" in dom_kw:
+            dd.set_storage(dom_kw["storage"])
+        hs = [dd.add_data(n) for n in names]
+        dd.realize()
+        for i, h in enumerate(hs):
+            dd.init_by_coords(
+                h, lambda x, y, z, i=i: jnp.sin(0.13 * (x + 2 * y + 3 * z) + i)
+            )
+        plan = dict(
+            sm.plan_stream(dd, r, "plane", False),
+            alias=alias, alias_forced=True, **plan_kw,
+        )
+        step = sm._build_stream_step(dd, kernel, r, plan, interpret=True)
+        dd.run_step(step, 3)
+        plans.append(plan)
+        fields.append([dd.quantity_to_host(h) for h in hs])
+    assert [p["route"] for p in plans] == ["plane", "plane"]
+    assert [p["alias"] for p in plans] == [True, False]  # written back as resolved
+    for key in ("overlap", "halo"):
+        if key in plan_kw:  # the variant engaged, it did not degrade
+            assert plans[0][key] == plans[1][key] == plan_kw[key]
+    for name, a, b in zip(names, *fields):
+        assert np.isfinite(a).all(), name
+        assert np.array_equal(a, b), name
+
+
+@pytest.mark.parametrize("nq,want", [(8, 8), (2, 0)])
+def test_wavefront_span_counts_its_in_place_passes(nq, want):
+    """``domain.step``'s ``aliased`` on the wavefront route: real Astaroth's 8
+    fields run in place (the static rule from 4 fields up, as before ISSUE
+    28), two fields run fresh; the resolved value is in the plan either way."""
+    sim = AstarothSim(24, 24, 24, num_quantities=nq, kernel_impl="pallas",
+                      schedule="wavefront", devices=jax.devices()[:1], interpret=True)
+    sim.realize()
+    plan = sim._step._stream_plan
+    assert plan["route"] == "wavefront", plan
+    assert plan["alias"] is (want > 0), plan
+    args = sim._step._span_args()
+    assert (args["streamed"], args["aliased"]) == (nq, want), args

@@ -247,6 +247,14 @@ def test_stream_alias_resolution_precedence(monkeypatch):
     # static rule: >= 4 fields alias
     assert _resolve_stream_alias({}, 1) is False
     assert _resolve_stream_alias({}, 4) is True
+    # ... read from the plan's route too: the plane route always aliases (its
+    # pass runs inside the step loop, ISSUE 28); every other route is as it was
+    for nq in range(1, 9):
+        assert _resolve_stream_alias({"route": "plane"}, nq) is True
+        for route in ("wavefront", "wrap"):
+            assert _resolve_stream_alias({"route": route}, nq) is (nq >= 4)
+    # the tuned plan and the environment still beat it
+    assert _resolve_stream_alias({"route": "plane", "alias": False}, 1) is False
     # tuned plan beats the static rule
     assert _resolve_stream_alias({"alias": True}, 1) is True
     # env beats the tuned plan

@@ -25,7 +25,7 @@ one write per plane per iteration — ~2.6x the throughput of the XLA
 shifted-slice formulation on the same chip.
 
 RESILIENCE: the headline jacobi fields are fully measured BEFORE the side
-sections, and a failing section (autotune, mxu A/B, numerics A/B, roofline,
+sections, and a failing section (autotune, numerics A/B, roofline,
 astaroth) records its fields as null, lets the artifact line print, and THEN
 makes the exit non-zero — a failure in a late section never discards
 already-measured results and never passes silently.  Transient dispatch
@@ -99,98 +99,6 @@ def measured_copy_gbps(rt: float, n: int = 514, steps: int = 50) -> float:
         float(jnp.sum(a[0, 0, 0:1]))  # force completion
         best = min(best, (time.perf_counter() - t0 - rt) / steps)
     return 2 * a.size * 4 / best / 1e9
-
-
-def mxu_vs_vpu_ab(size: int, k: int, interpret: bool, rt: float,
-                  reps: int = 3, inner: int = None) -> dict:
-    """Steady-state compute-unit A/B on the headline wrap workload: the
-    SAME k-level kernel under ``vpu`` (roll+add chain), ``mxu`` (dense
-    banded contraction, ops/jacobi_pallas ``band_matrix``), ``mxu_band``
-    (the blocked (2r+1)-band tiling), and the band variant's bf16-INPUT
-    leg (``mxu_band+bf16in`` — the doubled-ratio arm of the "VPU wall"
-    break-even model), alternating in ONE process under the trial protocol
-    (rep-0 drop, steady-state median) — the ``route_ab`` shape from the
-    exchange bench, applied to the "Break the VPU wall" lever so the
-    win/loss lands in the BENCH artifact next to the headline it would
-    move.  ``scripts/perf_ledger.py`` ingests every leg as a
-    regression-gated ``mxu_ab:*`` series.  Returns the JSON section."""
-    import statistics as _stats
-    from functools import partial
-
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    from stencil_tpu.ops.jacobi_pallas import (
-        band_tile_plan,
-        jacobi_wrap_step,
-        mxu_supported,
-    )
-    from stencil_tpu.tune.trial import measure_alternating
-
-    cells = float(size) ** 3
-    eligible = bool(mxu_supported([jnp.float32]))
-    band_ok = eligible and band_tile_plan(size, size) is not None
-    section = {
-        "eligible": eligible,
-        "band_eligible": band_ok,
-        "k": k,
-        "measurement_protocol": {
-            "alternating": True, "drop_rep0": True, "stat": "median",
-        },
-        "units": {},
-        "speedup_vs_vpu": None,
-        "speedups_vs_vpu": {},
-    }
-    legs = [("vpu", "vpu", "f32")]
-    if eligible:
-        legs.append(("mxu", "mxu", "f32"))
-    if band_ok:
-        legs.append(("mxu_band", "mxu_band", "f32"))
-        legs.append(("mxu_band+bf16in", "mxu_band", "bf16"))
-    block = jnp.full((size, size, size), 0.5, jnp.float32)
-
-    def make_run(unit, mxu_input):
-        @partial(jax.jit, static_argnums=1)
-        def steps(b, n):
-            return lax.fori_loop(
-                0, n,
-                lambda _, bb: jacobi_wrap_step(
-                    bb, interpret=interpret, k=k, compute_unit=unit,
-                    mxu_input=mxu_input,
-                ),
-                b,
-            )
-
-        def run(n):
-            steps(block, n).block_until_ready()
-
-        return run
-
-    if inner is None:
-        inner = 25 if size >= 256 else 2
-    runs = [make_run(unit, mi) for _, unit, mi in legs]
-    inners = [inner] * len(runs)
-    for run, n in zip(runs, inners):
-        run(n)  # warm + compile at the timed count
-    rounds = measure_alternating(runs, inners, rt, reps)
-    for (key, _, _), per_rep in zip(legs, rounds):
-        dt = _stats.median(per_rep)  # seconds per k-level dispatch
-        section["units"][key] = {
-            "ms_per_dispatch": round(dt * 1e3, 3),
-            "mcells_per_s": round(cells * k / dt / 1e6, 1),
-        }
-    vpu_ms = section["units"]["vpu"]["ms_per_dispatch"]
-    for key in section["units"]:
-        if key != "vpu":
-            section["speedups_vs_vpu"][key] = round(
-                vpu_ms
-                / max(section["units"][key]["ms_per_dispatch"], 1e-12),
-                3,
-            )
-    # legacy scalar (pre-band artifacts carried only the dense ratio)
-    section["speedup_vs_vpu"] = section["speedups_vs_vpu"].get("mxu")
-    return section
 
 
 def numerics_overhead_ab(size: int, interpret: bool, rt: float,
@@ -414,24 +322,12 @@ def main(argv=None) -> None:
 
     # free the jacobi models' HBM before the 8-field astaroth run (~6 GB)
     wrap_k = model._wrap_k
-    headline_unit = model._compute_unit
     headline_storage = model.dd.storage_dtype()
     del model, ex_model
 
-    # the compute-unit A/B on the headline workload ("Break the VPU wall"):
-    # failures must never cost the headline fields — record null, keep going
-    mxu_ab = None
-    try:
-        mxu_ab = mxu_vs_vpu_ab(size, wrap_k, interpret, rt,
-                               reps=3 if full else 1)
-    except Exception as e:  # noqa: BLE001 — an A/B accelerator, not a dep
-        failed_sections.append("mxu_vs_vpu")
-        print(f"mxu_vs_vpu section failed (recorded null): {e!r}",
-              file=sys.stderr)
-
     # the numerics-observatory on/off A/B ("cheap enough to leave on" —
-    # docs/observability.md 'Numerics observatory'): same rule, a failure
-    # records null and never costs the headline fields
+    # docs/observability.md 'Numerics observatory'): a failure records null
+    # and never costs the headline fields
     numerics_ab = None
     try:
         numerics_ab = numerics_overhead_ab(size, interpret, rt,
@@ -465,12 +361,9 @@ def main(argv=None) -> None:
         # pushes this past 1.0
         "frac_of_chip_roofline": round(mcells_per_s / chip_roofline_mcells, 3),
         "temporal_k": wrap_k,
-        # the headline model's RESOLVED kernel axes (docs/tuning.md
-        # "Compute unit and storage dtype") and the steady-state
-        # compute-unit A/B at the headline depth (route_ab's shape)
-        "compute_unit": headline_unit,
+        # the headline model's RESOLVED storage axis (docs/tuning.md
+        # "Storage dtype")
         "storage_dtype": headline_storage,
-        "mxu_vs_vpu": mxu_ab,
         # the numerics observatory's on/off A/B: per-snapshot cost of the
         # fused on-device field-health dispatch, regression-gated by the
         # ledger's LOWER-is-better numerics:overhead series

@@ -7,8 +7,8 @@ that descend into pjit/scan/while subjaxprs (pallas calls and custom calls
 stay opaque to the TAINT analysis, conservatively — the kernel verifier
 ``analysis/kernels.py`` descends into pallas bodies deliberately), and a
 registry of program contracts checked
-against REAL built artifacts — the canonical route × overlap ×
-compute-unit × storage-dtype matrix (``analysis/programs.py``).
+against REAL built artifacts — the canonical route × overlap × halo ×
+storage-dtype matrix (``analysis/programs.py``).
 
 Entry points:
 

@@ -52,7 +52,7 @@ class ProgramArtifact:
                  fixture corpus's synthetic programs).
     ``closed`` — the ClosedJaxpr of the program.
     ``axes``   — the axis values this program claims to exercise
-                 (``route``/``overlap``/``exchange_route``/``compute_unit``/
+                 (``route``/``overlap``/``halo``/``exchange_route``/
                  ``storage_dtype``); contracts scope their pins on these.
     ``plan``   — the stream plan dict (steps only; None otherwise).
     ``dd``     — the realized domain (when available: vmem re-derivation).

@@ -1,5 +1,5 @@
 """The canonical program matrix — the REAL built artifacts the contracts
-verify, swept over route × overlap × compute-unit × storage-dtype in
+verify, swept over route × overlap × halo × storage-dtype in
 interpret/CPU mode (the tier-1 gate
 ``tests/test_analysis.py::test_canonical_programs_verify`` and the CLI both
 run exactly this list).
@@ -48,17 +48,6 @@ def mean6_kernel(views, info):
     return out
 
 
-def mean6_kernel_mxu(views, info):
-    """The declared contraction form: in-plane taps through
-    ``PlaneView.plane_nbr_sum`` (the banded-matmul lowering)."""
-    out = {}
-    for name, src in views.items():
-        out[name] = (
-            src.sh(-1, 0, 0) + src.sh(1, 0, 0) + src.plane_nbr_sum()
-        ) / 6.0
-    return out
-
-
 @dataclasses.dataclass(frozen=True)
 class ProgramSpec:
     """One canonical program: what to build and which axes it exercises."""
@@ -73,8 +62,6 @@ class ProgramSpec:
     stream_path: str = "auto"
     overlap: str = "off"
     halo: str = "array"
-    compute_unit: str = "vpu"
-    mxu_input: str = "f32"
     storage_dtype: str = "native"
     reshard_to: tuple = ()  # redistribute only: the target mesh dim
     serve_mode: str = ""  # serve only: "batched" | "subslice" (pack.SERVE_MODES)
@@ -86,8 +73,6 @@ class ProgramSpec:
             "overlap": self.overlap,
             "halo": self.halo,
             "exchange_route": self.exchange_route,
-            "compute_unit": self.compute_unit,
-            "mxu_input": self.mxu_input,
             "storage_dtype": self.storage_dtype,
         }
 
@@ -102,12 +87,11 @@ class ProgramSpec:
 CANONICAL_PROGRAMS: List[ProgramSpec] = [
     ProgramSpec("step:wrap/off", n_devices=1),
     ProgramSpec("step:plane/off/direct", stream_path="plane"),
-    # (The former plane/split program was deduped when the mxu_band entry
-    # landed: both wavefront/split programs exercise every split-schedule
-    # contract clause — interior independence, exterior taint, band-blend
-    # sliver hygiene — and the plane route stays covered at overlap=off by
-    # two programs; no contract discriminates plane×split from
-    # wavefront×split, so the build-time budget goes to the new axis.)
+    # (No plane/split program: both wavefront/split programs exercise every
+    # split-schedule contract clause — interior independence, exterior
+    # taint, band-blend sliver hygiene — and the plane route stays covered
+    # at overlap=off by two programs; no contract discriminates plane×split
+    # from wavefront×split.)
     ProgramSpec(
         "step:plane/off/zpack_pallas",
         stream_path="plane",
@@ -126,24 +110,10 @@ CANONICAL_PROGRAMS: List[ProgramSpec] = [
         exchange_route="zpack_xla",
         n_fields=2,
     ),
-    ProgramSpec(
-        "step:wavefront/split/direct/mxu",
-        halo_mult=2,
-        overlap="split",
-        compute_unit="mxu",
-    ),
-    # the band-tiled contraction variant with bf16 MXU inputs: one program
-    # covers both new axis values (the accum-dtype contract verifies every
-    # bf16-operand dot_general still pins the f32 accumulator, and the
-    # vmem-budget contract prices the band tiles instead of the dense
-    # circulants).  16³ at mult 2 shards to 12-wide raw planes — band
-    # granule 3 — so the traced program really runs the blocked form.
-    ProgramSpec(
-        "step:wavefront/off/direct/mxu_band/bf16in",
-        halo_mult=2,
-        compute_unit="mxu_band",
-        mxu_input="bf16",
-    ),
+    # the one-field even wavefront at overlap=off (auto-planned to the
+    # z-slab form like the two-field entry above; 16³ at mult 2 shards to
+    # 12-wide raw planes)
+    ProgramSpec("step:wavefront/off/direct", halo_mult=2),
     ProgramSpec(
         "step:wavefront/off/direct/bf16/uneven",
         size=(17, 17, 17),
@@ -250,8 +220,6 @@ def covered_axis_values() -> dict:
         "EXCHANGE_ROUTES": set(),
         "STREAM_OVERLAP": set(),
         "STREAM_HALO": set(),
-        "COMPUTE_UNITS": set(),
-        "MXU_INPUTS": set(),
         "STORAGE_DTYPES": set(),
     }
     out["SERVE_MODES"] = set()
@@ -264,8 +232,6 @@ def covered_axis_values() -> dict:
         out["EXCHANGE_ROUTES"].add(s.exchange_route)
         out["STREAM_OVERLAP"].add(s.overlap)
         out["STREAM_HALO"].add(s.halo)
-        out["COMPUTE_UNITS"].add(s.compute_unit)
-        out["MXU_INPUTS"].add(s.mxu_input)
         out["STORAGE_DTYPES"].add(s.storage_dtype)
     return out
 
@@ -462,7 +428,7 @@ def _serve_artifact(spec: ProgramSpec, dd) -> ProgramArtifact:
     )
 
 
-#: traced canonical programs memoized by label — tracing the 23-program
+#: traced canonical programs memoized by label — tracing the 22-program
 #: matrix costs ~tens of seconds and every per-contract consumer
 #: (tests/test_analysis.py's contract tests, repeated in-process CLI
 #: calls, the kernel verifier's report sweep) hits the same specs; an
@@ -512,13 +478,7 @@ def _build_program_uncached(spec: ProgramSpec) -> ProgramArtifact:
             stream_path=spec.stream_path,
             stream_overlap=spec.overlap,
             stream_halo=spec.halo,
-            compute_unit=spec.compute_unit,
-            mxu_input=spec.mxu_input,
         )
-        from stencil_tpu.ops.jacobi_pallas import unit_uses_mxu
-
-        if unit_uses_mxu(spec.compute_unit):
-            kw["mxu_kernel"] = mean6_kernel_mxu
         step = dd.make_step(mean6_kernel, **kw)
         return step_artifact(dd, step, label=spec.label, axes=spec.axes)
 

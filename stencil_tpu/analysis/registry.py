@@ -43,14 +43,6 @@ CANONICAL_AXES = {
         "module": "stencil_tpu/ops/stream.py",
         "covered": ("array", "fused"),
     },
-    "COMPUTE_UNITS": {
-        "module": "stencil_tpu/ops/jacobi_pallas.py",
-        "covered": ("vpu", "mxu", "mxu_band"),
-    },
-    "MXU_INPUTS": {
-        "module": "stencil_tpu/ops/jacobi_pallas.py",
-        "covered": ("f32", "bf16"),
-    },
     "STORAGE_DTYPES": {
         "module": "stencil_tpu/ops/jacobi_pallas.py",
         "covered": ("native", "bf16"),

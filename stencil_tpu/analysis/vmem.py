@@ -16,16 +16,6 @@ Two entry points over the SAME models the planners use
 
 Both return ``None`` for "fits" or a human reason string — never raise on a
 fit question (a malformed plan is the caller's bug and does raise).
-
-The mxu accounting is the piece the stream planner historically did NOT
-model (its ``stream_vmem_fits`` has no band-matrix term — mxu twins were
-compile-and-catch until this module): the DENSE contraction form parks two
-f32 band matrices per kernel resident in VMEM (``band_matrix``: (y, y) and
-(z, z), tile-padded).  The ``mxu_band`` variant parks only the KB-scale
-wide tiles (``band_wide_tile``) — the footprint cut that makes previously
-VMEM-pruned mxu candidates admissible — and ``mxu_input="bf16"`` halves
-the constants either way (``ops/jacobi_pallas.mxu_vmem_extra_bytes`` is
-the shared term).
 """
 
 from __future__ import annotations
@@ -40,22 +30,15 @@ def stream_plan_vmem_bytes(
     itemsizes: Sequence[int],
     z_slabs: bool = False,
     ring_itemsizes: Optional[Sequence[int]] = None,
-    mxu=False,
     fused: bool = False,
-    mxu_input: str = "f32",
 ) -> int:
     """Modeled VMEM block bytes of a stream plan (stack margin excluded —
     compare against :func:`budget_and_margin`).  The generic-engine model
-    (``stream_vmem_fits``'s accounting) plus the MXU constants term for
-    the resolved variant (``mxu`` — a bool for the dense form, or the
-    compute-unit string) and, under ``halo="fused"``, the double-buffered
-    fused-shell side blocks: per field, one (1, y, z) x-slab plane plus
-    the (1, 2m, z) y and (1, 2m, y) z message blocks per grid step."""
-    from stencil_tpu.ops.jacobi_pallas import (
-        _mxu_unit_of,
-        _padded_plane_bytes,
-        mxu_vmem_extra_bytes,
-    )
+    (``stream_vmem_fits``'s accounting) plus, under ``halo="fused"``, the
+    double-buffered fused-shell side blocks: per field, one (1, y, z) x-slab
+    plane plus the (1, 2m, z) y and (1, 2m, y) z message blocks per grid
+    step."""
+    from stencil_tpu.ops.jacobi_pallas import _padded_plane_bytes
 
     ring = list(itemsizes) if ring_itemsizes is None else list(ring_itemsizes)
     est = 0
@@ -68,9 +51,6 @@ def stream_plan_vmem_bytes(
             est += 2 * _padded_plane_bytes(plane_y, plane_z, it)
             est += 2 * _padded_plane_bytes(2 * m, plane_z, it)
             est += 2 * _padded_plane_bytes(2 * m, plane_y, it)
-    unit = _mxu_unit_of(mxu)
-    if unit:
-        est += mxu_vmem_extra_bytes(plane_y, plane_z, unit, mxu_input)
     return est
 
 
@@ -89,12 +69,7 @@ def check_vmem(dd, plan: dict, budget: Optional[int] = None) -> Optional[str]:
     on this realized domain?  ``None`` = fits; otherwise a reason string
     naming the estimate and the budget.  The per-field itemsizes honor the
     storage axis (bf16 buffers stream 2 B planes but carry f32 level
-    rings — the ``f32_accumulate`` contract), and an MXU ``compute_unit``
-    folds the resident contraction constants of the resolved variant in
-    (dense circulants vs the band variant's small tiles, narrowed under
-    ``mxu_input="bf16"``)."""
-    from stencil_tpu.ops.jacobi_pallas import unit_uses_mxu
-
+    rings — the ``f32_accumulate`` contract)."""
     route = plan.get("route")
     if route not in ("wrap", "wavefront", "plane"):
         raise ValueError(f"not a stream plan: {plan!r}")
@@ -105,7 +80,6 @@ def check_vmem(dd, plan: dict, budget: Optional[int] = None) -> Optional[str]:
     if plan.get("grouping") == "per-field" and len(itemsizes) > 1:
         itemsizes = [max(itemsizes)]
         ring_sizes = [max(ring_sizes)]
-    unit = plan.get("compute_unit", "vpu")
     est = stream_plan_vmem_bytes(
         m,
         raw.y,
@@ -113,20 +87,11 @@ def check_vmem(dd, plan: dict, budget: Optional[int] = None) -> Optional[str]:
         itemsizes,
         z_slabs=bool(plan.get("z_slabs")),
         ring_itemsizes=ring_sizes,
-        mxu=unit if unit_uses_mxu(unit) else False,
         fused=plan.get("halo") == "fused",
-        mxu_input=plan.get("mxu_input", "f32"),
     )
     cap, margin = budget_and_margin(len(itemsizes), budget)
     if est + margin > cap:
-        tags = "".join(
-            t
-            for t, on in (
-                (f",{unit}", unit_uses_mxu(unit)),
-                (",fused", plan.get("halo") == "fused"),
-            )
-            if on
-        )
+        tags = ",fused" if plan.get("halo") == "fused" else ""
         return (
             f"plan {plan.get('route')}[m={m}{tags}] models "
             f"{est / 1e6:.1f} MB of VMEM blocks (+{margin / 1e6:.1f} MB "
@@ -179,9 +144,6 @@ def check_traced(art, budget: Optional[int] = None) -> Optional[str]:
             best = (weight, tuple(big.shape[-2:]), sizes, rings)
     if best is None:
         return None
-    from stencil_tpu.ops.jacobi_pallas import unit_uses_mxu
-
-    unit = plan.get("compute_unit", "vpu")
     _, (py, pz), itemsizes, ring_itemsizes = best
     est = stream_plan_vmem_bytes(
         int(plan.get("m", 1)),
@@ -190,9 +152,7 @@ def check_traced(art, budget: Optional[int] = None) -> Optional[str]:
         itemsizes,
         z_slabs=bool(plan.get("z_slabs")),
         ring_itemsizes=ring_itemsizes,
-        mxu=unit if unit_uses_mxu(unit) else False,
         fused=plan.get("halo") == "fused",
-        mxu_input=plan.get("mxu_input", "f32"),
     )
     cap, margin = budget_and_margin(
         len(itemsizes), budget if budget is not None else art.vmem_budget

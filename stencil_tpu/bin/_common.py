@@ -228,30 +228,11 @@ def apply_exchange_route(args, dd) -> None:
 
 
 def add_kernel_axis_flags(p: argparse.ArgumentParser) -> None:
-    """``--compute-unit`` / ``--storage-dtype``: pin the level kernels'
-    execution unit and the field buffers' storage dtype for this run
-    (docs/tuning.md "Compute unit and storage dtype").  ``auto`` (default)
-    keeps the planner resolution: ``STENCIL_COMPUTE_UNIT`` /
-    ``STENCIL_STORAGE_DTYPE`` > tuned config > the static ``vpu`` /
-    ``native`` fallbacks; structural guards (non-f32 fields, routes with no
-    contraction/f32-accumulate kernels) degrade with a warning."""
-    p.add_argument(
-        "--compute-unit",
-        default="auto",
-        choices=("auto", "vpu", "mxu", "mxu_band"),
-        help="level-kernel execution unit: vpu roll+add chain vs one banded "
-        "contraction per axis on the MXU — dense circulant (mxu) or the "
-        "blocked (2r+1)-band tiling (mxu_band, ~n/(2r+1)x fewer FLOPs) "
-        "(auto = env > tuned config > vpu)",
-    )
-    p.add_argument(
-        "--mxu-input",
-        default="auto",
-        choices=("auto", "f32", "bf16"),
-        help="MXU contraction operand precision: bf16 inputs double the "
-        "matrix unit's FLOP ratio under the unchanged f32-accumulate "
-        "contract (auto = env > tuned config > f32; inert under vpu)",
-    )
+    """``--storage-dtype``: pin the field buffers' storage dtype for this
+    run (docs/tuning.md "Storage dtype").  ``auto`` (default) keeps the
+    planner resolution: ``STENCIL_STORAGE_DTYPE`` > tuned config > the
+    static ``native`` fallback; structural guards (non-f32 fields, routes
+    with no f32-accumulate kernels) degrade with a warning."""
     p.add_argument(
         "--storage-dtype",
         default="auto",
@@ -265,17 +246,8 @@ def add_kernel_axis_flags(p: argparse.ArgumentParser) -> None:
 def kernel_axis_kwargs(args) -> dict:
     """Model ctor kwargs from ``add_kernel_axis_flags``'s choices (``auto``
     maps to None = consult the resolution chain)."""
-    out = {}
-    cu = getattr(args, "compute_unit", "auto")
-    mi = getattr(args, "mxu_input", "auto")
     sd = getattr(args, "storage_dtype", "auto")
-    if cu != "auto":
-        out["compute_unit"] = cu
-    if mi != "auto":
-        out["mxu_input"] = mi
-    if sd != "auto":
-        out["storage_dtype"] = sd
-    return out
+    return {} if sd == "auto" else {"storage_dtype": sd}
 
 
 def add_stream_overlap_flag(p: argparse.ArgumentParser) -> None:

@@ -116,7 +116,6 @@ def _run(args) -> int:
             report = tune_runners.autotune_stream(
                 tuner_sim.dd, tuner_sim._kernel, x_radius=1, separable=True,
                 interpret=args.interpret,
-                mxu_kernel=tuner_sim._kernel_mxu,
             )
             _common.tune_report_stderr(report)
         del tuner_sim

@@ -197,7 +197,6 @@ def _run(args) -> int:
         run_state=lambda: {
             "model": "jacobi3d",
             "kernel_impl": kernel_impl,
-            "compute_unit": model._compute_unit,
             "iters": args.iters,
         },
         # elastic capacity: a drain-and-reshard (or cross-mesh restore)

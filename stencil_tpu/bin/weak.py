@@ -117,18 +117,6 @@ def _mean6_kernel(views, info):
     return out
 
 
-def _mean6_kernel_mxu(views, info):
-    """``_mean6_kernel``'s declared axis-separable contraction form
-    (PlaneView.plane_nbr_sum; ≤1 ulp/level) — lets the stream tuner's
-    compute-unit A/B engage on this workload."""
-    out = {}
-    for name, src in views.items():
-        out[name] = (
-            src.sh(-1, 0, 0) + src.sh(1, 0, 0) + src.plane_nbr_sum()
-        ) / 6.0
-    return out
-
-
 def parse_mesh(spec):
     """``"MX,MY,MZ"`` -> (mx, my, mz), or None."""
     if spec is None:
@@ -233,7 +221,6 @@ def run_overlap(args, name: str = "weak", weak_scale: bool = True) -> dict:
         _common.tune_report_stderr(ex_report)
         st_report = autotune_stream(
             dd, _mean6_kernel, x_radius=1, interpret=interpret,
-            mxu_kernel=_mean6_kernel_mxu,
         )
         _common.tune_report_stderr(st_report)
         tune_section = {
@@ -248,7 +235,6 @@ def run_overlap(args, name: str = "weak", weak_scale: bool = True) -> dict:
             engine="stream",
             donate=False,
             interpret=interpret,
-            mxu_kernel=_mean6_kernel_mxu,
             stream_overlap=ov,
         )
 

@@ -1469,21 +1469,6 @@ class DistributedDomain:
         # big-array halo write at all) — bitwise-identical to "array";
         # "auto" resolves env > tuned > array (docs/tuning.md "Fused halo
         # consumption")
-        compute_unit: str = "auto",  # stream engine: the level kernels'
-        # execution unit (ops/jacobi_pallas COMPUTE_UNITS): "mxu" routes
-        # the separable in-plane taps through banded contractions on the
-        # matrix unit — needs `mxu_kernel`; "mxu_band" runs the blocked
-        # (2r+1)-band form of the same contraction; "auto" resolves env >
-        # tuned > the static vpu (docs/tuning.md "Compute unit and
-        # storage dtype")
-        mxu_input: str = "auto",  # stream engine: MXU contraction operand
-        # precision (ops/jacobi_pallas MXU_INPUTS): "bf16" narrows the
-        # operands under the unchanged f32-accumulate contract; "auto"
-        # resolves env > tuned > the static f32; inert under vpu
-        mxu_kernel=None,  # stream engine: the kernel's DECLARED
-        # axis-separable contraction form, written against
-        # PlaneView.plane_nbr_sum (≤1 ulp/level vs `kernel`); None =
-        # no mxu form, compute_unit=mxu structurally degrades to vpu
         interpret: bool = False,  # stream engine only: pallas interpret mode
     ):
         """Build ``step(curr) -> next`` fusing exchange + compute.
@@ -1533,25 +1518,10 @@ class DistributedDomain:
                 self, kernel, x_radius=x_radius, path=stream_path,
                 separable=separable, interpret=interpret, donate=donate,
                 max_depth=stream_depth, overlap=stream_overlap,
-                halo=stream_halo, compute_unit=compute_unit,
-                mxu_input=mxu_input,
-                mxu_kernel=mxu_kernel,
+                halo=stream_halo,
             )
         if engine != "xla":
             raise ValueError(f"unknown engine {engine!r}")
-        if compute_unit not in (None, "auto"):
-            # the XLA slice engine has no pallas level kernels — resolve
-            # through the shared chain so an explicit mxu request degrades
-            # with the standard warning + kernel.compute_unit event instead
-            # of being silently dropped (env/tuned stay un-consulted here:
-            # there is no unit to switch)
-            from stencil_tpu.ops.jacobi_pallas import resolve_compute_unit
-
-            resolve_compute_unit(
-                compute_unit, None, [h.dtype for h in self._handles],
-                where="xla", engine_ok=False,
-                engine_why="the XLA slice engine has no pallas level kernels",
-            )
         from stencil_tpu.core.geometry import exterior_of, shrink_by_radius
 
         n = self._spec.sz

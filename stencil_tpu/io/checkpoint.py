@@ -13,7 +13,7 @@ preemption-tolerant long runs (docs/resilience.md "Long-run operation"):
   interrupted save.
 * **Versioned manifest with per-array digests** — ``MANIFEST.json`` carries
   a schema number, the domain geometry at save time, the full run state
-  (step counter, ``storage_dtype``/``compute_unit`` axes, tuned decisions in
+  (step counter, the ``storage_dtype`` axis, tuned decisions in
   effect — whatever the caller passes), and one sha256 per quantity over the
   PORTABLE interior representation (interior cells at the native dtype —
   bf16-stored fields upcast exactly per the PR-7 f32-accumulate contract).

@@ -2,15 +2,13 @@
 (``dot_general`` / ``jnp.dot`` / ``jnp.matmul`` / ``jnp.einsum``) passes an
 explicit ``preferred_element_type``.
 
-Why: the MXU offload (ops/jacobi_pallas ``band_matrix`` + the contraction
-level kernels) exists precisely to run reduced-precision storage through
-full-precision accumulation — a ``dot_general`` over bf16 operands WITHOUT
-``preferred_element_type`` silently accumulates at bf16 (bf16x bf16 -> bf16),
+Why: bf16 storage runs reduced-precision fields through full-precision
+accumulation — a ``dot_general`` over bf16 operands WITHOUT
+``preferred_element_type`` silently accumulates at bf16 (bf16 x bf16 -> bf16),
 which is exactly the bug class the bf16-storage/f32-accumulate contract
-forbids (docs/tuning.md "Compute unit and storage dtype"; PERF_NOTES "VPU
-wall").  Making the accumulator explicit at every contraction site keeps the
-contract checkable instead of hoping each kernel author remembers the XLA
-default.
+forbids (docs/tuning.md "Storage dtype").  Making the accumulator explicit at
+every contraction site keeps the contract checkable instead of hoping each
+kernel author remembers the XLA default.
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
 """Rule ``contract-coverage``: an ops/ module that grows a tuner-axis
-vocabulary (``EXCHANGE_ROUTES``, ``STREAM_OVERLAP``, ``COMPUTE_UNITS``,
+vocabulary (``EXCHANGE_ROUTES``, ``STREAM_OVERLAP``, ``STREAM_HALO``,
 ``STORAGE_DTYPES``) must grow the program-contract verifier's canonical
 matrix with it.
 

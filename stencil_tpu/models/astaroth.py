@@ -75,15 +75,6 @@ class AstarothSim:
         exchange_route: str = None,  # pin the halo exchange's y/z-sweep
         # route (ops/exchange.py EXCHANGE_ROUTES; None/"auto" = env >
         # tuned > static direct)
-        compute_unit: str = "auto",  # pallas engine only: the level
-        # kernels' execution unit ("vpu" | "mxu" | "mxu_band" | "auto" =
-        # env > tuned > static vpu).  The mxu units run ``_kernel_mxu`` —
-        # the same mean-of-6 written through the views' banded-contraction
-        # seam (PlaneView.plane_nbr_sum; ≤1 ulp/level vs vpu; mxu_band =
-        # the blocked band form)
-        mxu_input: str = "auto",  # pallas engine only: MXU contraction
-        # operand precision ("f32" | "bf16" | "auto" = env > tuned >
-        # static f32); inert under vpu
         storage_dtype: str = None,  # field buffers' storage axis ("native"
         # | "bf16" | None/"auto" = env > tuned > static native): bf16
         # stores f32 fields at 2 B/cell end-to-end while the stream kernels
@@ -108,8 +99,6 @@ class AstarothSim:
         self.stream_halo = stream_halo
         if exchange_route not in (None, "auto"):
             self.dd.set_exchange_route(exchange_route)
-        self.compute_unit = compute_unit
-        self.mxu_input = mxu_input
         self.storage_dtype_request = storage_dtype
         self._storage_dtype = "native"
         if check_divergence_every:
@@ -196,11 +185,6 @@ class AstarothSim:
                 interpret=self.interpret,
                 stream_overlap=self.stream_overlap,
                 stream_halo=self.stream_halo,
-                compute_unit=self.compute_unit,
-                mxu_input=self.mxu_input,
-                # the declared axis-separable contraction form — what lets
-                # compute_unit=mxu engage on this kernel
-                mxu_kernel=self._kernel_mxu,
             )
         return self.dd.make_step(self._kernel, overlap=self.overlap)
 
@@ -234,20 +218,6 @@ class AstarothSim:
                 + src.sh(1, 0, 0)
                 + src.sh(0, 1, 0)
                 + src.sh(0, 0, 1)
-            ) / 6.0
-        return out
-
-    def _kernel_mxu(self, views, info):
-        # the SAME mean-of-6 with its four in-plane taps written through the
-        # banded-contraction seam (PlaneView.plane_nbr_sum) — on the MXU
-        # when the engine hands the views band matrices, and ≤1 ulp/level
-        # from `_kernel` either way (the in-plane pair sums regroup); the
-        # x taps stay plane reads.  The vpu `_kernel` above is untouched,
-        # so the default path stays bitwise-identical to pre-axis builds.
-        out = {}
-        for name, src in views.items():
-            out[name] = (
-                src.sh(-1, 0, 0) + src.sh(1, 0, 0) + src.plane_nbr_sum()
             ) / 6.0
         return out
 

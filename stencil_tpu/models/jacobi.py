@@ -54,20 +54,6 @@ class Jacobi3D:
         z_ring: bool = None,  # z-RING vs padded layout preference: None =
         # env (STENCIL_Z_RING) > tuned config > ring default; structural
         # gates (lane alignment, slab mode) still apply either way
-        compute_unit: str = None,  # level kernels' execution unit ("vpu" |
-        # "mxu" | "mxu_band" | None/"auto"): mxu contracts the in-plane
-        # taps against banded coefficient matrices on the matrix unit (≤1
-        # ulp/level vs vpu); mxu_band runs the blocked (2r+1)-band form of
-        # the same contraction (ulp-pinned vs dense, ~n/(2r+1)× fewer
-        # FLOPs).  None/"auto" = STENCIL_COMPUTE_UNIT > tuned config >
-        # static vpu; structural guards (non-f32 compute, routes with no
-        # contraction kernel, untilable plane geometry for the band form)
-        # degrade with a warning
-        mxu_input: str = None,  # MXU contraction operand precision ("f32"
-        # | "bf16" | None/"auto" = STENCIL_MXU_INPUT > tuned config >
-        # static f32): bf16 narrows the operands (~2× MXU ratio) under the
-        # unchanged f32-accumulate contract — analytic bound
-        # tests/ulp.mxu_bf16_input_atol; inert under vpu
         storage_dtype: str = None,  # field buffers' storage axis ("native"
         # | "bf16" | None/"auto"): bf16 stores f32 fields at 2 B/cell
         # end-to-end (HBM, VMEM pipeline, exchange messages) while the
@@ -94,14 +80,8 @@ class Jacobi3D:
         self.pallas_path_request = pallas_path
         self.wavefront_alias_request = wavefront_alias
         self.z_ring_request = z_ring
-        self.compute_unit_request = compute_unit
-        self.mxu_input_request = mxu_input
         self.storage_dtype_request = storage_dtype
-        # resolved axes (realize() / the step builders fill these in)
-        self._compute_unit = "vpu"
-        self._mxu_input = "f32"
-        self._storage_dtype = "native"
-        self._mxu_flops_iter = 0  # analytic MXU FLOPs per raw iteration
+        self._storage_dtype = "native"  # resolved by realize()
         if check_divergence_every:
             self.dd.set_divergence_check(check_divergence_every)
         # tuned config applied by _plan_wavefront (auto mode only)
@@ -184,8 +164,8 @@ class Jacobi3D:
 
     def _prospective_tune_route(self):
         """The workload-key route the build WILL consult (pre-realize
-        mirror of the route choice) — where the tuned compute-unit/
-        storage-dtype fields live; None when no tunable pallas route can be
+        mirror of the route choice) — where the tuned storage-dtype field
+        lives; None when no tunable pallas route can be
         reached (jnp engine, forced slab/shell)."""
         if self.kernel_impl != "pallas":
             return None
@@ -271,27 +251,6 @@ class Jacobi3D:
         # carries the f32_accumulate working precision (native itemsize)
         itemsize = self.dd.field_dtype(self.h).itemsize
         ring_itemsize = self.h.dtype.itemsize
-        # PROSPECTIVE compute unit (emit=False — the authoritative
-        # resolution with its telemetry event happens at build time in
-        # _make_wavefront_step): folds the contraction form's resident
-        # band-matrix constants into the depth gate below
-        from stencil_tpu import tune
-        from stencil_tpu.ops.jacobi_pallas import (
-            mxu_supported,
-            resolve_compute_unit,
-        )
-
-        p_mxu = False  # False or the prospective unit string — the VMEM
-        # model prices the resolved variant (dense constants vs band tiles)
-        if mxu_supported([self.h.dtype]):  # else build-time warns once
-            cfg0 = tune.best_config(dd.tune_key("jacobi-wavefront")) or {}
-            p_unit, _ = resolve_compute_unit(
-                self.compute_unit_request, cfg0.get("compute_unit"),
-                [self.h.dtype], where="jacobi-wavefront", emit=False,
-            )
-            from stencil_tpu.ops.jacobi_pallas import unit_uses_mxu
-
-            p_mxu = p_unit if unit_uses_mxu(p_unit) else False
         # planning diagnostics for the autotuner's candidate-space builder
         # (tune/runners.autotune_jacobi_wavefront)
         self._wavefront_plan_info = {
@@ -301,7 +260,7 @@ class Jacobi3D:
         def fits(m, z):
             return wavefront_vmem_fits(
                 m, n[1] + 2 * m, n[2] + 2 * m, itemsize, z_slabs=z,
-                ring_itemsize=ring_itemsize, mxu=p_mxu,
+                ring_itemsize=ring_itemsize,
             )
 
         if self.temporal_k != "auto":
@@ -311,7 +270,7 @@ class Jacobi3D:
                     f"wavefront temporal_k={m} needs 1 <= m <= min(shard/valid)={n_min}"
                 )
             warn_if_over_vmem_budget(m, n[1] + 2 * m, n[2] + 2 * m, itemsize,
-                                     ring_itemsize, mxu=p_mxu)
+                                     ring_itemsize)
             self._wavefront_z_planned = fits(m, True) and not padded
             return m
         # the autotuner's persisted on-device measurement beats the static
@@ -406,31 +365,7 @@ class Jacobi3D:
         from stencil_tpu.utils.config import env_bool
 
         tuned = self._tuned_wavefront or {}
-        # compute-unit axis: explicit ctor knob > STENCIL_COMPUTE_UNIT >
-        # tuned config > static vpu; non-f32 compute dtypes degrade
-        from stencil_tpu.ops.jacobi_pallas import (
-            mxu_flops_per_plane,
-            resolve_compute_unit,
-            resolve_mxu_input,
-            unit_uses_mxu,
-        )
-
-        unit, _unit_src = resolve_compute_unit(
-            self.compute_unit_request,
-            tuned.get("compute_unit"),
-            [self.h.dtype],
-            where="jacobi-wavefront",
-        )
-        self._compute_unit = unit
-        mi, _mi_src = resolve_mxu_input(
-            self.mxu_input_request, tuned.get("mxu_input"), unit,
-            where="jacobi-wavefront",
-        )
-        self._mxu_input = mi
         f32_acc = dd.field_dtype(self.h) != self.h.dtype
-        kern_kw = {
-            "compute_unit": unit, "f32_accumulate": f32_acc, "mxu_input": mi,
-        }
         z_slab_mode = env_bool("STENCIL_Z_SLABS", True) and getattr(
             self, "_wavefront_z_planned", False
         )
@@ -490,22 +425,6 @@ class Jacobi3D:
         # (z_valid).  Padding/unpadding happens once per step() dispatch,
         # amortized over the device-side macro loop.
         Zp = lane_pad_width(Zr) if z_slab_mode else Zr
-        # analytic MXU FLOPs per raw iteration (all shards): one band
-        # contraction pair per streamed plane per level, counted for the
-        # RESOLVED variant (the dense model over-reports a band-tiled run
-        # by ~n/(2r+1)) on the plane geometry the kernel actually
-        # CONTRACTS — the z-ring kernel works over the (Yr, OFF + Zi)
-        # ring plane, the padded-shell kernel over (Yr, Zp); the variant
-        # a geometry admits (band_tile_plan) differs with the width, so
-        # pricing the wrong plane could count the wrong variant — the
-        # kernel.mxu.flops per-step increment (step())
-        _flops_pz = (_ZRING_OFF + n.z) if z_ring_mode else Zp
-        self._mxu_flops_iter = (
-            mxu_flops_per_plane(Yr, _flops_pz, unit) * Xr * dd.num_subdomains()
-            if unit_uses_mxu(unit)
-            else 0
-        )
-
         def per_shard(steps, raw_block):
             # origin (and everything derived from it, like the d2 planes)
             # must be computed INSIDE each loop body: axis_index lowers to
@@ -534,7 +453,8 @@ class Jacobi3D:
                     )
                     return jacobi_shell_wavefront_step(
                         b, depth, origin, yz_d2, gsize, interior_offset=m,
-                        alias=alias, interpret=interpret, **kern_kw,
+                        alias=alias, interpret=interpret,
+                        f32_accumulate=f32_acc,
                     )
 
                 macros, rem = divmod(steps, depth_run)
@@ -567,7 +487,7 @@ class Jacobi3D:
                     return jacobi_zring_wavefront_step(
                         b, depth, origin, ring_d2, gsize, z_slabs=zs,
                         interior_offset=m, alias=alias, interpret=interpret,
-                        **kern_kw,
+                        f32_accumulate=f32_acc,
                     )
 
                 b0 = lax.slice(
@@ -597,7 +517,7 @@ class Jacobi3D:
                 return jacobi_shell_wavefront_step(
                     b, depth, origin, yz_d2, gsize, interior_offset=m,
                     z_slabs=zs, z_valid=Zr, alias=alias, interpret=interpret,
-                    **kern_kw,
+                    f32_accumulate=f32_acc,
                 )
 
             # prime the slab carry from the block's interior z boundaries
@@ -700,53 +620,17 @@ class Jacobi3D:
             self._marks_shell_stale = True
             self._pallas_path = "wrap"
             # pipeline planes stream at the STORAGE itemsize; the level
-            # compute-unit axis: explicit ctor knob > STENCIL_COMPUTE_UNIT >
-            # tuned config > static vpu — resolved BEFORE the depth choice
-            # so the VMEM model can fold in the contraction form's resident
-            # band matrices (choose_temporal_k's mxu= term)
-            from stencil_tpu import tune
-            from stencil_tpu.ops.jacobi_pallas import (
-                mxu_flops_per_plane,
-                resolve_compute_unit,
-                resolve_mxu_input,
-                unit_uses_mxu,
-            )
-
-            cfg = tune.best_config(dd.tune_key("jacobi-wrap")) or {}
-            unit, _unit_src = resolve_compute_unit(
-                self.compute_unit_request,
-                cfg.get("compute_unit"),
-                [self.h.dtype],
-                where="jacobi-wrap",
-            )
-            self._compute_unit = unit
-            mi, _mi_src = resolve_mxu_input(
-                self.mxu_input_request, cfg.get("mxu_input"), unit,
-                where="jacobi-wrap",
-            )
-            self._mxu_input = mi
             # ring carries the f32_accumulate working precision, so the
             # VMEM model takes both (a storage-only model under bf16 would
-            # admit depths whose f32 ring blows the budget); the mxu term
-            # prices the RESOLVED variant (dense constants vs band tiles)
+            # admit depths whose f32 ring blows the budget)
             k = choose_temporal_k(
                 (n.x, n.y, n.z), dd.field_dtype(self.h).itemsize,
                 self.temporal_k,
                 tune_key=dd.tune_key("jacobi-wrap"),
                 ring_itemsize=self.h.dtype.itemsize,
-                mxu=unit if unit_uses_mxu(unit) else False,
             )
             self._wrap_k = k
             f32_acc = dd.field_dtype(self.h) != self.h.dtype
-            kern_kw = {
-                "compute_unit": unit, "f32_accumulate": f32_acc,
-                "mxu_input": mi,
-            }
-            self._mxu_flops_iter = (
-                mxu_flops_per_plane(n.y, n.z, unit) * n.x
-                if unit_uses_mxu(unit)
-                else 0
-            )
 
             @partial(jax.jit, static_argnums=1, donate_argnums=0)
             def step(curr, steps: int = 1):
@@ -764,7 +648,7 @@ class Jacobi3D:
                         0,
                         blocked,
                         lambda _, b: jacobi_wrap_step(
-                            b, interpret=interpret, k=k, **kern_kw
+                            b, interpret=interpret, k=k, f32_accumulate=f32_acc
                         ),
                         block,
                     )
@@ -772,7 +656,7 @@ class Jacobi3D:
                     # one k=rem wavefront (rem < k <= X//2 so always valid);
                     # bit-exact and one HBM pass instead of rem
                     block = jacobi_wrap_step(
-                        block, interpret=interpret, k=rem, **kern_kw
+                        block, interpret=interpret, k=rem, f32_accumulate=f32_acc
                     )
                 # stencil-lint: disable=sliver-dus whole-interior write-back into the shell-carrying array after the k-loop — block spans the full interior, not a y/z sliver
                 return {name: lax.dynamic_update_slice(arr, block, (lo.x, lo.y, lo.z))}
@@ -785,7 +669,6 @@ class Jacobi3D:
         ):
             return self._make_slab_step()
         self._pallas_path = "shell"
-        self._resolve_unit_no_contraction("jacobi-shell")
         n = dd.local_spec().sz
         shell = dd._shell_radius
         mesh_shape = tuple(dd.mesh.shape[a] for a in MESH_AXES)
@@ -859,7 +742,6 @@ class Jacobi3D:
         name = self.h.name
         self._marks_shell_stale = True
         self._pallas_path = "slab"
-        self._resolve_unit_no_contraction("jacobi-slab")
         f32_acc = dd.field_dtype(self.h) != self.h.dtype
 
         def per_shard(steps, raw_block):
@@ -913,23 +795,6 @@ class Jacobi3D:
             return {name: fn(curr[name])}
 
         return step
-
-    def _resolve_unit_no_contraction(self, where: str) -> None:
-        """Compute-unit resolution for routes WITHOUT a contraction kernel
-        (slab/shell): any mxu request — explicit, env, or tuned — degrades
-        to vpu with a warning instead of crashing or silently engaging."""
-        from stencil_tpu.ops.jacobi_pallas import resolve_compute_unit
-
-        unit, _src = resolve_compute_unit(
-            self.compute_unit_request,
-            None,
-            [self.h.dtype],
-            where=where,
-            engine_ok=False,
-            engine_why="the slab/shell routes have no contraction kernels",
-        )
-        self._compute_unit = unit
-        self._mxu_flops_iter = 0
 
     def _kernel(self, views, info):
         size = info.global_size
@@ -992,26 +857,14 @@ class Jacobi3D:
                     f"multiplier {mult} on the jnp engine (macro steps)"
                 )
             steps //= mult
-        # analytic, from the plan the run STARTS on (a mid-run ladder
-        # step-down keeps the pre-degrade count for this call)
-        mxu_flops = steps * self._mxu_flops_iter
         self._ladder.step(steps)
-        if mxu_flops:
-            from stencil_tpu import telemetry
-            from stencil_tpu.telemetry import names as tm
-
-            telemetry.inc(tm.KERNEL_MXU_FLOPS, mxu_flops)
         if self._marks_shell_stale:
             self.dd.mark_shell_stale()
 
     def _rung_name(self) -> str:
         if self.kernel_impl != "pallas":
             return "xla"
-        suffix = (
-            f",{self._compute_unit}" if self._compute_unit != "vpu" else ""
-        )
-        if self.dd.storage_dtype() == "bf16":
-            suffix += ",bf16"
+        suffix = ",bf16" if self.dd.storage_dtype() == "bf16" else ""
         if self._pallas_path == "wrap":
             return f"wrap[k={self._wrap_k}{suffix}]"
         if self._pallas_path == "wavefront":
@@ -1056,31 +909,10 @@ class Jacobi3D:
 
         if self.kernel_impl != "pallas":
             return False
-        # the new-axis rungs come BEFORE any depth descent: an mxu or bf16
-        # build carries its own extra compiler surface (band matmuls /
-        # mixed-dtype pipelines), so the failure may be the axis's fault,
-        # not the depth's — step the axis down at the SAME depth first.
-        # The contraction walks band → dense → vpu: the blocked form's
-        # reshape/batched-dot lowering may be what the compiler rejected
-        # while the dense contraction still serves the matrix unit.
-        if self._compute_unit == "mxu_band":
-            log_warn(
-                f"compute_unit=mxu_band on the {self._pallas_path} route "
-                f"exceeded the compiler's capability ({cls.value}); stepping "
-                "down to the dense mxu form at the same depth"
-            )
-            self.compute_unit_request = "mxu"  # forced for the rebuild
-            self._rebuild_current_route()
-            return True
-        if self._compute_unit == "mxu":
-            log_warn(
-                f"compute_unit=mxu on the {self._pallas_path} route exceeded "
-                f"the compiler's capability ({cls.value}); stepping down to "
-                "vpu at the same depth"
-            )
-            self.compute_unit_request = "vpu"  # forced for the rebuild
-            self._rebuild_current_route()
-            return True
+        # the storage rung comes BEFORE any depth descent: a bf16 build
+        # carries its own extra compiler surface (mixed-dtype pipelines), so
+        # the failure may be the axis's fault, not the depth's — step the
+        # axis down at the SAME depth first
         if self.dd.storage_dtype() == "bf16":
             log_warn(
                 f"storage_dtype=bf16 on the {self._pallas_path} route "
@@ -1118,7 +950,7 @@ class Jacobi3D:
 
     def _rebuild_current_route(self) -> None:
         """Rebuild the installed step for the CURRENT route after an axis
-        step-down (mxu->vpu / bf16->native) — same depth, same allocation.
+        step-down (bf16->native) — same depth, same allocation.
         The wrap rebuild re-runs ``choose_temporal_k`` (whose auto/tuned
         resolution could shift under the changed storage itemsize), so pin
         the depth explicitly: the axis steps down FIRST, depth only through

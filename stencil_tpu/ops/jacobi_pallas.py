@@ -37,64 +37,12 @@ from stencil_tpu.telemetry import names as tm
 HOT_TEMP = 1.0
 COLD_TEMP = 0.0
 
-#: compute-unit axis for the streaming level kernels — a first-class tuner
-#: candidate (tune/space.py; docs/tuning.md "Compute unit and storage
-#: dtype"): ``vpu`` = the measured roll+add chain (the static cold-cache
-#: fallback, bitwise-pinned by tier-1), ``mxu`` = the per-axis stencil
-#: application as ONE banded contraction per axis on the matrix unit
-#: against the dense ``(n, n)`` circulant — the wafer-scale stencil
-#: mapping (PAPERS.md arxiv 2605.07954 / 2601.17754) aimed at the
-#: measured VPU wall (PERF_NOTES "VPU wall": the k≈12-24 plateau is
-#: roll+add-bound, not DMA), ``mxu_band`` = the same contraction TILED to
-#: the band's nonzeros (blocked ``(2r+1)``-band matmul: each output block
-#: contracts against only its ≤3 neighbor input blocks via small shifted
-#: dense tiles, ``band_wide_tile``), cutting the per-level FLOPs from
-#: ``2·Y²·Z + 2·Y·Z²`` to ``6·g·Y·Z`` per axis — the mechanism step the
-#: "VPU wall" break-even model asks for.
-COMPUTE_UNITS = ("vpu", "mxu", "mxu_band")
-
-#: the units that contract on the matrix unit — every ``== "mxu"`` gate in
-#: the tree routes through :func:`unit_uses_mxu` so both variants ride the
-#: same structural guards, VMEM/FLOP accounting hooks, and ladder rungs
-MXU_UNITS = ("mxu", "mxu_band")
-
-#: input-precision axis for the MXU contraction operands (independent of
-#: the compute-unit variant and of STORAGE dtype): ``f32`` feeds the plane
-#: and the band constants at f32, ``bf16`` narrows BOTH contraction
-#: operands to bfloat16 — the 0/1 band constants are exact in bfloat16,
-#: the plane pays one round-to-nearest per read — while
-#: ``preferred_element_type=f32`` keeps the accumulator (the
-#: ``accum-dtype`` contract still machine-checks every traced
-#: ``dot_general``).  The MXU's bf16 ratio is ~2× its f32 ratio, which is
-#: the doubling the "VPU wall" break-even model needs; the analytic error
-#: bound is ``tests/ulp.mxu_bf16_input_atol``.
-MXU_INPUTS = ("f32", "bf16")
-
-
-def unit_uses_mxu(compute_unit: str) -> bool:
-    """True for every compute-unit value that contracts on the matrix unit
-    (dense or band-tiled) — the one predicate the rest of the tree keys
-    structural gates, flop counters, and VMEM terms on."""
-    return compute_unit in MXU_UNITS
-
 #: storage-dtype axis for field buffers — ``native`` keeps the user dtype
 #: end to end; ``bf16`` stores f32 fields as bfloat16 (HBM planes, VMEM
 #: pipeline blocks, exchange messages all narrow to 2 B/cell) while the
 #: level kernels accumulate at f32 (load → upcast → compute → downcast on
 #: the final store; the ``f32_accumulate`` kernel contract).
 STORAGE_DTYPES = ("native", "bf16")
-
-
-def mxu_supported(compute_dtypes) -> bool:
-    """Structural gate for the MXU contraction form: every field must
-    COMPUTE at f32 — the banded ``dot_general`` accumulates at f32
-    (``preferred_element_type``), so an f64 field would silently lose
-    precision through the matrix unit (violating the ≤1-ulp-per-level
-    contract) and integer/bool fields have no matmul form at all.  A bf16
-    STORAGE field computes at f32 (``f32_accumulate``) and qualifies; a
-    native-bf16 field does not (its vpu path computes at bf16 in interpret
-    mode, so no cross-unit ulp contract could be pinned)."""
-    return all(jnp.dtype(dt) == jnp.float32 for dt in compute_dtypes)
 
 
 def bf16_supported(native_dtypes) -> bool:
@@ -107,8 +55,8 @@ def bf16_supported(native_dtypes) -> bool:
 
 
 def _resolve_axis_value(request, tuned, env_name: str, choices, static: str):
-    """Shared precedence chain for the compute-unit / storage-dtype axes
-    (mirrors the exchange-route and stream-overlap rules): an explicit
+    """The storage-dtype axis's precedence chain (mirrors the
+    exchange-route and stream-overlap rules): an explicit
     request wins and never consults further; then the validated env knob;
     then the tuned config's field (garbage warns and falls through); then
     the static fallback.  Returns ``(value, source)`` pre-structural."""
@@ -131,45 +79,6 @@ def _resolve_axis_value(request, tuned, env_name: str, choices, static: str):
             f"{choices}; using the static {static!r} fallback"
         )
     return static, "static"
-
-
-def resolve_compute_unit(
-    request, tuned, compute_dtypes, where: str = "kernel",
-    engine_ok: bool = True,
-    engine_why: str = "this engine has no pallas level kernel",
-    emit: bool = True,
-):
-    """Resolve the compute-unit axis for one kernel build: precedence
-    explicit > ``STENCIL_COMPUTE_UNIT`` > tuned > static ``vpu``, then the
-    structural guard — an ``mxu`` the kernels cannot serve (non-f32 compute
-    dtypes, or an engine with no pallas level kernel at all) degrades to
-    ``vpu`` with a warning, never an error.  Every resolution is a
-    ``kernel.compute_unit`` telemetry event (``emit=False`` for PROSPECTIVE
-    resolutions — a planner peeking at the unit before the authoritative
-    build-time resolve emits the one real event).  Returns ``(unit, source)``."""
-    val, source = _resolve_axis_value(
-        request, tuned, "STENCIL_COMPUTE_UNIT", COMPUTE_UNITS, "vpu"
-    )
-    if unit_uses_mxu(val) and not (engine_ok and mxu_supported(compute_dtypes)):
-        from stencil_tpu.utils.logging import log_warn
-
-        why = (
-            engine_why
-            if not engine_ok
-            else f"fields compute at {[jnp.dtype(d).name for d in compute_dtypes]}, not f32"
-        )
-        log_warn(
-            f"compute_unit={val} ({source}) cannot engage for {where} ({why}); "
-            "degrading to vpu"
-        )
-        val, source = "vpu", source + "/degraded"
-    if emit:
-        from stencil_tpu import telemetry
-
-        telemetry.emit_event(
-            tm.EVENT_KERNEL_COMPUTE_UNIT, unit=val, source=source, where=where
-        )
-    return val, source
 
 
 def resolve_storage_dtype(
@@ -210,356 +119,17 @@ def resolve_storage_dtype(
     return val, source
 
 
-def resolve_mxu_input(
-    request, tuned, compute_unit: str, where: str = "kernel", emit: bool = True
-):
-    """Resolve the MXU input-precision axis for one kernel build: precedence
-    explicit > ``STENCIL_MXU_INPUT`` > tuned > static ``f32``, then the
-    structural guard — ``bf16`` inputs only exist under an engaged MXU unit
-    (the vpu chain has no contraction to feed), so a vpu resolution pins
-    ``f32``; the degrade warns only for explicit/env requests (a persisted
-    tuned ``bf16`` consulted by a vpu build is routine, not drift).  Every
-    resolution is a ``kernel.mxu_input`` telemetry event (``emit=False``
-    for prospective resolutions, like the compute-unit resolver).  Returns
-    ``(value, source)``."""
-    val, source = _resolve_axis_value(
-        request, tuned, "STENCIL_MXU_INPUT", MXU_INPUTS, "f32"
+def _level_sum(roll, prev, vals, cent):
+    """The per-level 6-neighbor numerator: the roll+add chain, in the
+    left-fold order tier-1 pins bitwise."""
+    return (
+        prev
+        + vals
+        + roll(cent, 1, 0)
+        + roll(cent, -1, 0)
+        + roll(cent, 1, 1)
+        + roll(cent, -1, 1)
     )
-    if val == "bf16" and not unit_uses_mxu(compute_unit):
-        if source in ("explicit", "env"):
-            from stencil_tpu.utils.logging import log_warn
-
-            log_warn(
-                f"mxu_input=bf16 ({source}) has no effect for {where}: the "
-                f"resolved compute unit is {compute_unit!r} (no contraction "
-                "to feed); using f32"
-            )
-        val, source = "f32", source + "/degraded"
-    if emit:
-        from stencil_tpu import telemetry
-
-        telemetry.emit_event(
-            tm.EVENT_KERNEL_MXU_INPUT,
-            input=val,
-            source=source,
-            unit=compute_unit,
-            where=where,
-        )
-    return val, source
-
-
-def band_matrix(n: int, dtype=jnp.float32, r: int = 1) -> jax.Array:
-    """The ``(n, n)`` circulant ``(2r+1)``-band for the dense MXU
-    contraction form: ``(B @ v)[i] == Σ_{d=1..r} v[(i-d) % n] + v[(i+d) % n]``
-    — exactly the ``roll(v, d) + roll(v, -d)`` chain of the vpu form, as ONE
-    banded matmul (the wafer-scale stencil mapping: a (2r+1)-diagonal
-    coefficient band contracted against the plane, with the periodic wrap —
-    the same wrap the vpu rotate has, so shell/garbage cells keep the
-    identical dependency structure and the ≤1-ulp-per-level contract is a
-    pure summation-order statement).  Symmetric, so the same matrix serves
-    both orientations (``B @ plane`` for the sublane axis, ``plane @ B``
-    for the lane axis).  Materialized ONCE per plan as a constant-index-map
-    pallas input — resident in VMEM at (sublane, 128)-tile-padded size,
-    like the d2 plane.  Built as a SUM of the per-offset shift matrices
-    (not a membership predicate) so degenerate extents stay value-exact:
-    at n=2, r=1 both offsets land on the same neighbor and the entry is
-    2.0, matching the vpu chain's double-counted roll."""
-    i = jnp.arange(n)
-    d = (i[:, None] - i[None, :]) % n
-    out = jnp.zeros((n, n), dtype)
-    for off in range(1, r + 1):
-        out = out + (d == off % n).astype(dtype) + (d == (n - off) % n).astype(dtype)
-    return out
-
-
-def band_tile_size(n: int, r: int = 1):
-    """The band-tile granule for one plane axis of extent ``n`` under the
-    ``mxu_band`` variant, or None when no admissible tiling exists (the
-    kernel then runs the dense form — ``plane_band_unit``).
-
-    A granule ``g`` must divide ``n`` (the blocked form reshapes the axis
-    into ``n/g`` whole blocks), must cover the band half-width
-    (``g >= 2r+1`` keeps every neighbor read within the adjacent block, so
-    each output block contracts against ≤3 input blocks), and must
-    actually CUT FLOPs vs the dense circulant (``6·g`` per element per
-    axis < the dense ``2·n`` ⟺ ``3·g < n`` — a near-``n/2`` granule would
-    dispatch MORE dense-tile FLOPs than the circulant it replaces, so
-    such geometries run dense instead).  Preference among admissible
-    divisors: the smallest sublane-granule multiple (8 — keeps the
-    (8, 128)-tiled layout native for the reshape and the tile operands),
-    else the smallest: smaller granules mean fewer dispatched FLOPs
-    (``mxu_flops_per_plane``)."""
-    divs = [
-        d
-        for d in range(max(2 * r + 1, 2), n)
-        if n % d == 0 and 3 * d < n
-    ]
-    for d in divs:
-        if d % 8 == 0:
-            return d
-    return divs[0] if divs else None
-
-
-def band_tile_plan(plane_y: int, plane_z: int, r: int = 1):
-    """``(gy, gz)`` band-tile granules for one (Y, Z) plane geometry, or
-    None when EITHER in-plane axis admits no tiling — the ``mxu_band``
-    variant engages whole-plane or not at all (a mixed band/dense plane
-    would split the ulp pin and the FLOP model per axis for no modeled
-    win)."""
-    gy = band_tile_size(plane_y, r)
-    gz = band_tile_size(plane_z, r)
-    if gy is None or gz is None:
-        return None
-    return gy, gz
-
-
-def band_wide_tile(g: int, r: int = 1, dtype=jnp.float32) -> jax.Array:
-    """The ``(g, 3g)`` wide tile ``[L | D | U]`` of the blocked
-    ``(2r+1)``-band matmul: column ``j`` of the tile addresses position
-    ``j - g`` relative to the output block's start (the previous block's
-    rows, the block itself, the next block's rows, concatenated), so
-    ``W[p, j] = 1  iff  1 <= |p + g - j| <= r`` — the band's nonzeros and
-    nothing else.  ``out_block_i = W @ [c_{i-1}; c_i; c_{i+1}]`` then
-    reproduces the dense circulant contraction exactly (each output element
-    sums the same ``2r`` neighbor values; zeros add exactly), at
-    ``2·(3g)·g`` FLOPs per block instead of ``2·n·g``.  Transpose for the
-    lane-axis (right-multiplication) orientation."""
-    p = jnp.arange(g)[:, None]
-    j = jnp.arange(3 * g)[None, :]
-    d = jnp.abs(p + g - j)
-    return ((d >= 1) & (d <= r)).astype(dtype)
-
-
-def plane_band_unit(compute_unit: str, plane_y: int, plane_z: int,
-                    r: int = 1, where: str = "kernel") -> str:
-    """The EFFECTIVE contraction variant for one concrete plane geometry:
-    ``mxu_band`` on a plane either of whose in-plane axes admits no band
-    tile (``band_tile_plan`` — prime extents foremost) degrades to the
-    dense ``mxu`` form with a warning.  The resolve-time chain cannot see
-    per-kernel plane dims (the split schedule's narrow band sub-blocks run
-    the same ``compute_unit`` over different geometry), so this is the last
-    structural gate, applied by every kernel builder."""
-    if compute_unit == "mxu_band" and band_tile_plan(plane_y, plane_z, r) is None:
-        from stencil_tpu.utils.logging import log_warn
-
-        log_warn(
-            f"compute_unit=mxu_band cannot tile a ({plane_y}, {plane_z}) "
-            f"plane at r={r} for {where} (no admissible granule divides "
-            "both extents); running the dense mxu form"
-        )
-        return "mxu"
-    return compute_unit
-
-
-def band_operands(plane_y: int, plane_z: int, compute_unit: str,
-                  mxu_input: str = "f32", r: int = 1):
-    """``(args, in_specs)`` of the resident contraction constants for one
-    (Y, Z) plane geometry — the two arrays every MXU kernel parks in VMEM
-    via constant index maps (like the d2 plane).  Dense: the two circulants
-    (``band_matrix``, (Y, Y) + (Z, Z)); band: the two wide tiles
-    (``band_wide_tile``, (gy, 3gy) + the transposed (3gz, gz)) — a
-    few-KB footprint where the dense constants cost plane-squared bytes
-    (the VMEM-model term that makes previously-pruned mxu candidates
-    admissible).  ``mxu_input="bf16"`` materializes the constants narrow
-    (0/1/2 band entries are exact in bfloat16), halving their residency."""
-    from jax.experimental import pallas as pl
-
-    assert unit_uses_mxu(compute_unit), compute_unit
-    dt = jnp.bfloat16 if mxu_input == "bf16" else jnp.float32
-    if compute_unit == "mxu_band":
-        gy, gz = band_tile_plan(plane_y, plane_z, r)  # gated by the builder
-        args = [band_wide_tile(gy, r, dt), jnp.transpose(band_wide_tile(gz, r, dt))]
-        specs = [
-            pl.BlockSpec((gy, 3 * gy), lambda i: (0, 0)),
-            pl.BlockSpec((3 * gz, gz), lambda i: (0, 0)),
-        ]
-        return args, specs
-    args = [band_matrix(plane_y, dt, r), band_matrix(plane_z, dt, r)]
-    specs = [
-        pl.BlockSpec((plane_y, plane_y), lambda i: (0, 0)),
-        pl.BlockSpec((plane_z, plane_z), lambda i: (0, 0)),
-    ]
-    return args, specs
-
-
-def _block_roll(c3, amt: int, axis: int):
-    """Roll by WHOLE blocks along a non-minor axis, as two static slices +
-    a concatenate (the unaligned-plane lowering ``_make_roll`` uses —
-    block-granular major/second-minor slices are tile-aligned by
-    construction, so Mosaic accepts them at any granule)."""
-    n = c3.shape[axis]
-    k = amt % n
-    if k == 0:
-        return c3
-    return jax.lax.concatenate(
-        [
-            jax.lax.slice_in_dim(c3, n - k, n, axis=axis),
-            jax.lax.slice_in_dim(c3, 0, n - k, axis=axis),
-        ],
-        dimension=axis,
-    )
-
-
-def make_plane_nbr_sum(plane_y: int, plane_z: int, compute_unit: str,
-                       mxu_input: str = "f32", r: int = 1):
-    """The in-kernel ``(2r+1)``-band in-plane neighbor sum for one (Y, Z)
-    plane geometry under an MXU compute unit: returns
-    ``nbr_sum(c, b1, b2) -> (Y, Z)`` where ``b1``/``b2`` are the VALUES of
-    the resident constants ``band_operands`` built for the same geometry
-    (the kernels read them out of their refs once per invocation).
-
-    ``mxu`` contracts the dense circulants; ``mxu_band`` runs the blocked
-    band form: the tiled axis reshapes into granule blocks, each output
-    block contracts against its ≤3 neighbor blocks through the wide tile —
-    one batched ``dot_general`` for the sublane (y) axis (the tile
-    broadcast over blocks keeps the output layout transpose-free) and one
-    free-dims ``dot_general`` for the lane (z) axis.  Both variants sum the
-    same ``2r`` neighbor values per element per axis (zeros add exactly),
-    so band-vs-dense divergence is pure summation order — the same ulp
-    regime as the mxu-vs-vpu pin.  ``mxu_input="bf16"`` rounds the plane
-    operand to bfloat16 once per read (constants are exact);
-    ``preferred_element_type`` pins the f32 accumulator either way."""
-    assert unit_uses_mxu(compute_unit), compute_unit
-    cast = (
-        (lambda v: v.astype(jnp.bfloat16))
-        if mxu_input == "bf16"
-        else (lambda v: v)
-    )
-    if compute_unit == "mxu":
-
-        def nbr_sum(c, by, bz):
-            dn = (((1,), (0,)), ((), ()))
-            cc = cast(c)
-            return jax.lax.dot_general(
-                by, cc, dn, preferred_element_type=jnp.float32
-            ) + jax.lax.dot_general(
-                cc, bz, dn, preferred_element_type=jnp.float32
-            )
-
-        return nbr_sum
-
-    gy, gz = band_tile_plan(plane_y, plane_z, r)  # gated by plane_band_unit
-    nby, nbz = plane_y // gy, plane_z // gz
-
-    def nbr_sum(c, wy, wz):
-        cc = cast(c)
-        # y axis: granule blocks of rows against the (gy, 3gy) wide tile,
-        # batched over blocks (the broadcast tile is KBs; batching keeps
-        # the (block, row, lane) output layout transpose-free)
-        c3 = cc.reshape(nby, gy, plane_z)
-        ext = jnp.concatenate(
-            [_block_roll(c3, 1, 0), c3, _block_roll(c3, -1, 0)], axis=1
-        )  # (nby, 3gy, Z)
-        wyb = jnp.broadcast_to(wy, (nby,) + wy.shape)
-        ysum = jax.lax.dot_general(
-            wyb, ext, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ).reshape(plane_y, plane_z)
-        # z axis: granule blocks of lanes against the transposed (3gz, gz)
-        # tile — the lhs free dims (Y, block) keep the layout in place
-        c3z = cc.reshape(plane_y, nbz, gz)
-        extz = jnp.concatenate(
-            [_block_roll(c3z, 1, 1), c3z, _block_roll(c3z, -1, 1)], axis=2
-        )  # (Y, nbz, 3gz)
-        zsum = jax.lax.dot_general(
-            extz, wz, (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).reshape(plane_y, plane_z)
-        return ysum + zsum
-
-    return nbr_sum
-
-
-def plane_nbr_sum_host(c: jax.Array, compute_unit: str, r: int = 1,
-                       mxu_input: str = "f32") -> jax.Array:
-    """Host-level (non-pallas) evaluation of the in-plane ``(2r+1)``-band
-    neighbor sum under one compute unit — the shared harness for the
-    contraction-form probes and the radii-{1,2} equivalence pins
-    (``scripts/probes/probe_mxu_band.py``, tests/test_kernel_axes.py).
-    ``vpu`` is the roll chain; the MXU units build their own resident
-    constants for this plane and contract exactly as the kernels do."""
-    Y, Z = c.shape
-    if compute_unit == "vpu":
-        out = jnp.zeros_like(c)
-        for off in range(1, r + 1):
-            out = (
-                out
-                + jnp.roll(c, off, 0) + jnp.roll(c, -off, 0)
-                + jnp.roll(c, off, 1) + jnp.roll(c, -off, 1)
-            )
-        return out
-    unit = plane_band_unit(compute_unit, Y, Z, r, where="host")
-    args, _ = band_operands(Y, Z, unit, mxu_input, r)
-    return make_plane_nbr_sum(Y, Z, unit, mxu_input, r)(c, *args)
-
-
-def _make_level_sum(roll, compute_unit: str, nbr_sum=None):
-    """The per-level 6-neighbor numerator, per compute unit.  ``vpu`` is
-    the historical roll+add chain VERBATIM (same left-fold order — tier-1
-    pins it bitwise); the MXU units replace the four in-plane rolls with
-    ``nbr_sum`` (``make_plane_nbr_sum`` — one banded contraction per axis,
-    dense or band-tiled; ``preferred_element_type=f32`` pins the
-    accumulator, which the ``accum-dtype`` lint rule makes mandatory in
-    ops/).  The forms differ only in summation order, hence the
-    ulps-per-level contract."""
-    if unit_uses_mxu(compute_unit):
-        assert nbr_sum is not None
-
-        def level_sum(prev, vals, cent, b1, b2):
-            return prev + vals + nbr_sum(cent, b1, b2)
-
-    else:
-
-        def level_sum(prev, vals, cent, b1, b2):
-            del b1, b2
-            return (
-                prev
-                + vals
-                + roll(cent, 1, 0)
-                + roll(cent, -1, 0)
-                + roll(cent, 1, 1)
-                + roll(cent, -1, 1)
-            )
-
-    return level_sum
-
-
-def _check_compute_unit(compute_unit: str, acc_dtype) -> None:
-    """Build-time guard: the resolvers degrade structurally-impossible
-    requests BEFORE a kernel build, so reaching a kernel with an MXU unit
-    on a non-f32 accumulator is a wiring bug, not a user error."""
-    assert compute_unit in COMPUTE_UNITS, compute_unit
-    if unit_uses_mxu(compute_unit):
-        assert jnp.dtype(acc_dtype) == jnp.float32, (
-            "mxu contraction requires an f32 accumulator; the resolver "
-            f"should have degraded this build (got {jnp.dtype(acc_dtype)})"
-        )
-
-
-def mxu_flops_per_plane(plane_y: int, plane_z: int,
-                        compute_unit: str = "mxu", r: int = 1) -> int:
-    """Analytic MXU FLOPs of ONE level over one (Y, Z) plane, for the
-    RESOLVED contraction variant — the ``kernel.mxu.flops`` counter and
-    every roofline/perf-ledger series built on it would be poisoned by
-    ~``n/(2r+1)`` if the dense model kept reporting for a band-tiled run.
-
-    * ``mxu`` (dense): the y-axis band matmul is (Y,Y)x(Y,Z) = 2·Y²·Z
-      FLOPs and the z-axis (Y,Z)x(Z,Z) = 2·Y·Z² — dense FLOPs over a
-      mostly-zero band, the deliberate wafer-scale trade.
-    * ``mxu_band``: per axis, each output granule block contracts one
-      (g, 3g)-tile matmul — ``2·(3g)·g`` FLOPs per block × ``n/g`` blocks
-      × the other extent = ``6·g·Y·Z`` per axis (``band_tile_plan`` picks
-      the granules).  A geometry with no admissible tiling runs (and is
-      counted as) the dense form.
-
-    Feeds the ``kernel.mxu.flops`` telemetry counter — modeled, like the
-    exchange bytes, so the hot path stays an int multiply."""
-    if compute_unit == "mxu_band":
-        plan = band_tile_plan(plane_y, plane_z, r)
-        if plan is not None:
-            gy, gz = plan
-            return 6 * gy * plane_y * plane_z + 6 * gz * plane_y * plane_z
-    return 2 * plane_y * plane_y * plane_z + 2 * plane_y * plane_z * plane_z
 
 
 def sphere_params(gx: int):
@@ -647,42 +217,6 @@ def _padded_plane_bytes(plane_y: int, plane_z: int, itemsize: int) -> int:
     return (-(-plane_y // sub) * sub) * (-(-plane_z // 128) * 128) * itemsize
 
 
-def mxu_vmem_extra_bytes(plane_y: int, plane_z: int, compute_unit="mxu",
-                         mxu_input: str = "f32", r: int = 1) -> int:
-    """Resident VMEM bytes of the contraction constants for one (Y, Z)
-    plane geometry — the per-variant term every depth gate folds in.
-    Dense parks the two full circulants (plane-squared bytes, the term
-    that historically pruned mxu candidates); the band variant parks only
-    the two wide tiles (KBs — which is why previously VMEM-pruned mxu
-    candidates become admissible under ``mxu_band``; its ext/block
-    temporaries are transient and live in the same stack margin the vpu
-    chain's roll temporaries do).  ``mxu_input="bf16"`` halves the
-    constants (they materialize narrow).  An untilable band geometry is
-    priced as the dense form it will actually run."""
-    it = 2 if mxu_input == "bf16" else 4
-    if compute_unit == "mxu_band":
-        plan = band_tile_plan(plane_y, plane_z, r)
-        if plan is not None:
-            gy, gz = plan
-            return _padded_plane_bytes(gy, 3 * gy, it) + _padded_plane_bytes(
-                3 * gz, gz, it
-            )
-    return _padded_plane_bytes(plane_y, plane_y, it) + _padded_plane_bytes(
-        plane_z, plane_z, it
-    )
-
-
-def _mxu_unit_of(mxu) -> str:
-    """Normalize the VMEM models' ``mxu`` parameter: historically a bool
-    (True = the dense form), now also the compute-unit string so the
-    models price the RESOLVED variant.  Falsy -> no MXU term."""
-    if mxu is True:
-        return "mxu"
-    if isinstance(mxu, str) and unit_uses_mxu(mxu):
-        return mxu
-    return ""
-
-
 def wavefront_vmem_bytes(
     k: int,
     plane_y: int,
@@ -691,8 +225,6 @@ def wavefront_vmem_bytes(
     z_slabs: bool = False,
     d2_itemsize: int = 4,
     ring_itemsize: int = None,
-    mxu=False,
-    mxu_input: str = "f32",
 ) -> int:
     """Modeled VMEM footprint of a k-level plane wavefront: 2k ring planes,
     4 pipeline (in/out double-buffer) planes, the resident d2 plane
@@ -700,11 +232,7 @@ def wavefront_vmem_bytes(
     variant) 4 double-buffered packed-slab blocks.  ``ring_itemsize``
     overrides the ring planes' itemsize: bf16 STORAGE (``f32_accumulate``)
     streams 2-byte pipeline planes but carries its level ring at f32, so
-    the ring must be modeled at 4 bytes or the gate lies.  ``mxu`` (a bool
-    for the dense form, or the compute-unit string) adds the resident
-    contraction constants of the resolved variant — the dense circulants
-    or the band variant's small wide tiles (``mxu_vmem_extra_bytes``);
-    ``mxu_input`` narrows them."""
+    the ring must be modeled at 4 bytes or the gate lies."""
     ring_it = itemsize if ring_itemsize is None else ring_itemsize
     plane = _padded_plane_bytes(plane_y, plane_z, itemsize)
     est = 2 * k * _padded_plane_bytes(plane_y, plane_z, ring_it) + 4 * plane
@@ -713,9 +241,6 @@ def wavefront_vmem_bytes(
     if z_slabs:
         # z-major (1, 2k, plane_y) blocks: sublane-pad the 2k rows
         est += 4 * _padded_plane_bytes(2 * k, plane_y, itemsize)
-    unit = _mxu_unit_of(mxu)
-    if unit:
-        est += mxu_vmem_extra_bytes(plane_y, plane_z, unit, mxu_input)
     return est
 
 
@@ -727,12 +252,9 @@ def wavefront_vmem_fits(
     z_slabs: bool = False,
     d2_itemsize: int = 4,
     ring_itemsize: int = None,
-    mxu=False,
-    mxu_input: str = "f32",
 ) -> bool:
     est = wavefront_vmem_bytes(
-        k, plane_y, plane_z, itemsize, z_slabs, d2_itemsize, ring_itemsize,
-        mxu, mxu_input,
+        k, plane_y, plane_z, itemsize, z_slabs, d2_itemsize, ring_itemsize
     )
     return est + _VMEM_STACK_MARGIN <= _vmem_budget()
 
@@ -747,12 +269,11 @@ def pack_d2(yz_d2: jax.Array, global_size) -> jax.Array:
 
 
 def warn_if_over_vmem_budget(k: int, plane_y: int, plane_z: int, itemsize: int,
-                             ring_itemsize: int = None,
-                             mxu=False) -> None:
+                             ring_itemsize: int = None) -> None:
     if not wavefront_vmem_fits(k, plane_y, plane_z, itemsize,
-                               ring_itemsize=ring_itemsize, mxu=mxu):
+                               ring_itemsize=ring_itemsize):
         est = wavefront_vmem_bytes(k, plane_y, plane_z, itemsize,
-                                   ring_itemsize=ring_itemsize, mxu=mxu)
+                                   ring_itemsize=ring_itemsize)
         from stencil_tpu.utils.logging import log_warn
 
         log_warn(
@@ -764,7 +285,7 @@ def warn_if_over_vmem_budget(k: int, plane_y: int, plane_z: int, itemsize: int,
 
 def choose_temporal_k(
     shape: Tuple[int, int, int], itemsize: int, requested="auto",
-    tune_key=None, ring_itemsize: int = None, mxu=False,
+    tune_key=None, ring_itemsize: int = None,
 ) -> int:
     """Pick the wrap kernel's temporal blocking depth: the deepest k whose
     VMEM footprint fits the calibrated budget (``auto``), or a validated
@@ -782,16 +303,13 @@ def choose_temporal_k(
     model: under bf16 STORAGE the pipeline planes stream at 2 B but the
     ring carries the f32 accumulator (the ``f32_accumulate`` contract), so
     a storage-itemsize-only model would admit depths whose f32 ring blows
-    the budget.  ``mxu`` (bool for the dense form, or the compute-unit
-    string) folds the resolved variant's resident contraction constants
-    into the model the same way — the dense circulants, or the band
-    variant's KB tiles."""
+    the budget."""
     X, Y, Z = shape
     if requested != "auto":
         k = int(requested)
         if not 1 <= k <= max(1, X // 2):
             raise ValueError(f"temporal_k={k} needs 1 <= k <= X//2 = {X // 2}")
-        warn_if_over_vmem_budget(k, Y, Z, itemsize, ring_itemsize, mxu=mxu)
+        warn_if_over_vmem_budget(k, Y, Z, itemsize, ring_itemsize)
         return k
     if tune_key is not None:
         from stencil_tpu import tune
@@ -811,7 +329,7 @@ def choose_temporal_k(
     k = 1
     for cand in range(2, _WRAP_MAX_K + 1):
         if cand <= X // 2 and wavefront_vmem_fits(
-            cand, Y, Z, itemsize, ring_itemsize=ring_itemsize, mxu=mxu
+            cand, Y, Z, itemsize, ring_itemsize=ring_itemsize
         ):
             k = cand
     return k
@@ -870,18 +388,10 @@ def jacobi_wrap_step(
     block: jax.Array,
     interpret: bool = False,
     k: int = 1,
-    compute_unit: str = "vpu",  # "vpu" = the historical roll+add chain
-    # (bitwise-pinned); "mxu" = one banded contraction per in-plane axis on
-    # the matrix unit (band_matrix + _make_level_sum; ≤1 ulp/level vs vpu);
-    # "mxu_band" = the blocked (2r+1)-band form of the same contraction
-    # (band_wide_tile — ulp-pinned vs dense, O(g)-per-element FLOPs)
     f32_accumulate: bool = False,  # bf16-STORAGE variant: the block streams
     # at its (narrow) dtype but the kernel upcasts at load, carries the
     # level ring and all arithmetic at f32, and downcasts ONCE at the final
     # store — one round-to-nearest per k levels instead of one per level
-    mxu_input: str = "f32",  # MXU operand precision: "bf16" narrows the
-    # contraction operands (f32 accumulator pinned) — analytic bound in
-    # tests/ulp.mxu_bf16_input_atol; ignored under vpu
 ) -> jax.Array:
     """``k`` Jacobi iterations over the WHOLE (unsharded) domain with the
     periodic wrap folded into the kernel — the single-device fast path.
@@ -918,22 +428,8 @@ def jacobi_wrap_step(
 
     roll = _make_roll(interpret)
     acc_dtype = jnp.float32 if f32_accumulate else block.dtype
-    _check_compute_unit(compute_unit, acc_dtype)
-    mxu = unit_uses_mxu(compute_unit)
-    if mxu:
-        compute_unit = plane_band_unit(compute_unit, Y, Z, where="wrap")
-    nbr_sum = (
-        make_plane_nbr_sum(Y, Z, compute_unit, mxu_input) if mxu else None
-    )
-    level_sum = _make_level_sum(roll, compute_unit, nbr_sum)
 
-    def kernel(in_ref, d2_ref, *rest):
-        if mxu:
-            by_ref, bz_ref, out_ref, ring = rest
-            by, bz = by_ref[...], bz_ref[...]
-        else:
-            out_ref, ring = rest
-            by = bz = None
+    def kernel(in_ref, d2_ref, out_ref, ring):
         # ring[s] holds the two most recent level-s planes (level 0 = input)
         i = pl.program_id(0)
         d2 = d2_ref[...]
@@ -944,7 +440,7 @@ def jacobi_wrap_step(
             prev = ring[s - 1, i % 2]  # plane i-s-1
             cent = ring[s - 1, (i + 1) % 2]  # plane i-s
             ring[s - 1, i % 2] = vals  # push plane i-s+1 (after prev read)
-            val = level_sum(prev, vals, cent, by, bz) / 6.0
+            val = _level_sum(roll, prev, vals, cent) / 6.0
             x_g = (i - s) % X
             val = jnp.where(d2 < in_r2 - (x_g - hot_x) ** 2, HOT_TEMP, val)
             val = jnp.where(d2 < in_r2 - (x_g - cold_x) ** 2, COLD_TEMP, val)
@@ -962,12 +458,6 @@ def jacobi_wrap_step(
         const(Y, Z),
     ]
     args = [block, d2.astype(jnp.int32)]
-    if mxu:
-        # resident contraction constants (dense circulants or band tiles),
-        # fetched once like the d2 plane
-        b_args, b_specs = band_operands(Y, Z, compute_unit, mxu_input)
-        in_specs += b_specs
-        args += b_args
     return pl.pallas_call(
         kernel,
         name=tm.KERNEL_JACOBI_WRAP,
@@ -1017,12 +507,8 @@ def jacobi_shell_wavefront_step(
     # garbage rolls into halo column 0 / z_valid-1 at level 1 — columns that
     # are only valid at level 0 anyway, so the shrinking-validity argument is
     # unchanged: level s remains valid on [s, z_valid - s).
-    compute_unit: str = "vpu",  # "mxu" = one banded in-plane contraction
-    # per axis on the matrix unit (see jacobi_wrap_step); ≤1 ulp/level vs
-    # vpu; "mxu_band" = its blocked (2r+1)-band form
     f32_accumulate: bool = False,  # bf16-storage variant: upcast at load,
     # f32 level ring + arithmetic, ONE downcast at the final store/emit
-    mxu_input: str = "f32",  # MXU operand precision (see jacobi_wrap_step)
 ) -> jax.Array:
     """``m`` Jacobi levels over an m-shell-carrying shard in ONE pass — the
     multi-device temporal-blocking path.
@@ -1067,21 +553,8 @@ def jacobi_shell_wavefront_step(
 
     roll = _make_roll(interpret)
     acc_dtype = jnp.float32 if f32_accumulate else raw.dtype
-    _check_compute_unit(compute_unit, acc_dtype)
-    mxu = unit_uses_mxu(compute_unit)
-    if mxu:
-        compute_unit = plane_band_unit(compute_unit, Yr, Zr, where="wavefront")
-    nbr_sum = (
-        make_plane_nbr_sum(Yr, Zr, compute_unit, mxu_input) if mxu else None
-    )
-    level_sum = _make_level_sum(roll, compute_unit, nbr_sum)
 
     def kernel(origin_ref, in_ref, d2_ref, *rest):
-        if mxu:
-            by_ref, bz_ref, rest = rest[0], rest[1], rest[2:]
-            by, bz = by_ref[...], bz_ref[...]
-        else:
-            by = bz = None
         if z_slabs is not None:
             zs_ref, out_ref, zout_ref, ring = rest
         else:
@@ -1105,7 +578,7 @@ def jacobi_shell_wavefront_step(
             prev = ring[s - 1, i % 2]  # level-(s-1) plane i-s-1
             cent = ring[s - 1, (i + 1) % 2]  # level-(s-1) plane i-s
             ring[s - 1, i % 2] = vals  # push plane i-s+1 (after prev read)
-            val = level_sum(prev, vals, cent, by, bz) / 6.0
+            val = _level_sum(roll, prev, vals, cent) / 6.0
             # global x of level-s plane i-s (raw index -> interior-origin
             # coords; + gx keeps lax.rem's operand non-negative:
             # i-s-s_off >= -2*s_off > -gx).  Shell planes matter too: their
@@ -1143,12 +616,6 @@ def jacobi_shell_wavefront_step(
     out_specs = pl.BlockSpec((1, Yr, Zr), out_idx)
     out_shape = jax.ShapeDtypeStruct((Xr, Yr, Zr), raw.dtype)
     args = [origin.astype(jnp.int32), raw, d2]
-    if mxu:
-        # resident contraction constants of the resolved variant, fetched
-        # once like the d2 plane
-        b_args, b_specs = band_operands(Yr, Zr, compute_unit, mxu_input)
-        in_specs += b_specs
-        args += b_args
     if z_slabs is not None:
         assert z_slabs.shape == (Xr, 2 * s_off, Yr), (z_slabs.shape, raw.shape)
         in_specs += [pl.BlockSpec((1, 2 * s_off, Yr), lambda i: (i, 0, 0))]
@@ -1216,14 +683,8 @@ def jacobi_zring_wavefront_step(
     interior_offset: int = None,
     alias: bool = False,
     interpret: bool = False,
-    compute_unit: str = "vpu",  # "mxu" = banded in-plane contraction over
-    # the RING-layout working plane (the circulant wrap of band_matrix is
-    # exactly the ring seam's lane wrap); ≤1 ulp/level vs "vpu";
-    # "mxu_band" = its blocked form (the block-granular wrap of the tiled
-    # z contraction is the same ring seam)
     f32_accumulate: bool = False,  # bf16-storage variant (see
     # jacobi_shell_wavefront_step)
-    mxu_input: str = "f32",  # MXU operand precision (see jacobi_wrap_step)
 ):
     """``m`` Jacobi levels per pass with the z halo in a RING-layout VMEM
     working plane — the deep-wavefront path that streams NO z padding.
@@ -1267,24 +728,8 @@ def jacobi_zring_wavefront_step(
     hot_x, cold_x, in_r2 = sphere_params(gx)
     roll = _make_roll(interpret)
     acc_dtype = jnp.float32 if f32_accumulate else raw.dtype
-    _check_compute_unit(compute_unit, acc_dtype)
-    mxu = unit_uses_mxu(compute_unit)
-    if mxu:
-        # the contraction spans the WORKING plane width W: its wrap at
-        # lanes 0/W-1 is exactly the ring layout's periodic-consistent seam
-        compute_unit = plane_band_unit(compute_unit, Yr, W, where="zring")
-    nbr_sum = (
-        make_plane_nbr_sum(Yr, W, compute_unit, mxu_input) if mxu else None
-    )
-    level_sum = _make_level_sum(roll, compute_unit, nbr_sum)
 
-    def kernel(origin_ref, in_ref, d2_ref, zs_ref, *rest):
-        if mxu:
-            by_ref, bz_ref, out_ref, zout_ref, ring = rest
-            by, bz = by_ref[...], bz_ref[...]
-        else:
-            out_ref, zout_ref, ring = rest
-            by = bz = None
+    def kernel(origin_ref, in_ref, d2_ref, zs_ref, out_ref, zout_ref, ring):
         i = pl.program_id(0)
         d2v = d2_ref[...]
         # stage the interior plane at lane offset OFF and patch the halo
@@ -1299,7 +744,7 @@ def jacobi_zring_wavefront_step(
             prev = ring[s - 1, i % 2]
             cent = ring[s - 1, (i + 1) % 2]
             ring[s - 1, i % 2] = vals
-            val = level_sum(prev, vals, cent, by, bz) / 6.0
+            val = _level_sum(roll, prev, vals, cent) / 6.0
             x_g = jax.lax.rem(
                 origin_ref[0] + jnp.int32(gx) + i - jnp.int32(s + s_off), jnp.int32(gx)
             )
@@ -1323,10 +768,6 @@ def jacobi_zring_wavefront_step(
         pl.BlockSpec((1, 2 * s_off, Yr), lambda i: (i, 0, 0)),
     ]
     args = [origin.astype(jnp.int32), raw, d2, z_slabs]
-    if mxu:
-        b_args, b_specs = band_operands(Yr, W, compute_unit, mxu_input)
-        in_specs += b_specs
-        args += b_args
     return pl.pallas_call(
         kernel,
         name=tm.KERNEL_JACOBI_ZRING_WAVEFRONT,

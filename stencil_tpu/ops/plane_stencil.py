@@ -23,13 +23,8 @@ def mean6_shell_wavefront_step(
     m: int,  # levels to advance, <= the shell width s
     shell_width: int,
     interpret: bool = False,
-    compute_unit: str = "vpu",  # "mxu" = one banded in-plane contraction
-    # per axis on the matrix unit (ops/jacobi_pallas.band_matrix); ≤1
-    # ulp/level vs the "vpu" roll+add chain; "mxu_band" = its blocked
-    # (2r+1)-band form (ops/jacobi_pallas.band_wide_tile)
     f32_accumulate: bool = False,  # bf16-storage variant: upcast at load,
     # f32 level ring + arithmetic, one downcast at the final store
-    mxu_input: str = "f32",  # MXU operand precision (jacobi_wrap_step)
 ) -> jax.Array:
     """``m`` mean-of-6 levels in ONE pass over an s-shell-carrying shard —
     the Astaroth proxy's temporal wavefront (opt-in ``schedule="wavefront"``).
@@ -47,14 +42,9 @@ def mean6_shell_wavefront_step(
     from jax.experimental.pallas import tpu as pltpu
 
     from stencil_tpu.ops.jacobi_pallas import (
-        _check_compute_unit,
-        _make_level_sum,
+        _level_sum,
         _make_roll,
         _tpu_compiler_params,
-        band_operands,
-        make_plane_nbr_sum,
-        plane_band_unit,
-        unit_uses_mxu,
     )
 
     Xr, Yr, Zr = raw.shape
@@ -63,22 +53,8 @@ def mean6_shell_wavefront_step(
     )
     roll = _make_roll(interpret)
     acc_dtype = jnp.float32 if f32_accumulate else raw.dtype
-    _check_compute_unit(compute_unit, acc_dtype)
-    mxu = unit_uses_mxu(compute_unit)
-    if mxu:
-        compute_unit = plane_band_unit(compute_unit, Yr, Zr, where="mean6-wavefront")
-    nbr_sum = (
-        make_plane_nbr_sum(Yr, Zr, compute_unit, mxu_input) if mxu else None
-    )
-    level_sum = _make_level_sum(roll, compute_unit, nbr_sum)
 
-    def kernel(in_ref, *rest):
-        if mxu:
-            by_ref, bz_ref, out_ref, ring = rest
-            by, bz = by_ref[...], bz_ref[...]
-        else:
-            out_ref, ring = rest
-            by = bz = None
+    def kernel(in_ref, out_ref, ring):
         # ring[s] holds the two most recent level-s planes (level 0 = input)
         i = pl.program_id(0)
         vals = in_ref[0].astype(acc_dtype)  # level-0 raw plane i
@@ -86,23 +62,17 @@ def mean6_shell_wavefront_step(
             prev = ring[s - 1, i % 2]  # level-(s-1) plane i-s-1
             cent = ring[s - 1, (i + 1) % 2]  # level-(s-1) plane i-s
             ring[s - 1, i % 2] = vals  # push plane i-s+1 (after prev read)
-            val = level_sum(prev, vals, cent, by, bz) / 6.0
+            val = _level_sum(roll, prev, vals, cent) / 6.0
             vals = val.astype(acc_dtype)
         # level-m plane i-m; valid for the interior (the one f32_accumulate
         # downcast)
         out_ref[0] = vals.astype(raw.dtype)
 
-    in_specs = [pl.BlockSpec((1, Yr, Zr), lambda i: (i, 0, 0))]
-    args = [raw]
-    if mxu:
-        b_args, b_specs = band_operands(Yr, Zr, compute_unit, mxu_input)
-        in_specs += b_specs
-        args += b_args
     return pl.pallas_call(
         kernel,
         name=tm.KERNEL_MEAN6_SHELL_WAVEFRONT,
         grid=(Xr,),
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((1, Yr, Zr), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((1, Yr, Zr), lambda i: (jnp.maximum(i - m, 0), 0, 0)),
         out_shape=jax.ShapeDtypeStruct((Xr, Yr, Zr), raw.dtype),
         # write of plane i-m trails the fetch of plane i+1: in-place safe
@@ -110,36 +80,22 @@ def mean6_shell_wavefront_step(
         scratch_shapes=[pltpu.VMEM((m, 2, Yr, Zr), acc_dtype)],
         interpret=interpret,
         **_tpu_compiler_params(interpret),
-    )(*args)
+    )(raw)
 
 
 def mean6_plane_step(
     block: jax.Array, lo: Dim3, hi: Dim3, interpret: bool = False,
-    compute_unit: str = "vpu", f32_accumulate: bool = False,
-    mxu_input: str = "f32",
+    f32_accumulate: bool = False,
 ) -> jax.Array:
     """One mean-of-6-face-neighbors iteration over a shell-carrying block.
 
-    ``compute_unit="mxu"`` computes the in-plane neighbor pair sums as one
-    banded contraction per axis (``band_matrix``; ``"mxu_band"`` runs the
-    blocked form); the interior window ``[y0, y1) x [z0, z1)`` sits at
-    least one cell inside the plane, so the circulant wrap rows/columns
-    never enter the sliced result and the contraction is exactly the
-    shifted-slice sum up to summation order (≤1 ulp).  ``f32_accumulate``
-    is the bf16-storage variant: the mean is computed at f32 and rounded
-    once at the interior store (pass-through shell planes keep their
-    storage bytes untouched)."""
+    ``f32_accumulate`` is the bf16-storage variant: the mean is computed at
+    f32 and rounded once at the interior store (pass-through shell planes
+    keep their storage bytes untouched)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from stencil_tpu.ops.jacobi_pallas import (
-        _check_compute_unit,
-        _tpu_compiler_params,
-        band_operands,
-        make_plane_nbr_sum,
-        plane_band_unit,
-        unit_uses_mxu,
-    )
+    from stencil_tpu.ops.jacobi_pallas import _tpu_compiler_params
 
     X, Y, Z = block.shape
     # every side needs >= 1 shell cell: the distance-1 reads and the
@@ -147,21 +103,9 @@ def mean6_plane_step(
     assert lo.all_ge(1) and hi.all_ge(1), (lo, hi)
     y0, y1 = lo.y, Y - hi.y
     z0, z1 = lo.z, Z - hi.z
-    acc_dtype = jnp.float32 if f32_accumulate else block.dtype
-    _check_compute_unit(compute_unit, acc_dtype)
-    mxu = unit_uses_mxu(compute_unit)
-    if mxu:
-        compute_unit = plane_band_unit(compute_unit, Y, Z, where="mean6-plane")
-    nbr_sum = (
-        make_plane_nbr_sum(Y, Z, compute_unit, mxu_input) if mxu else None
-    )
     up = (lambda v: v.astype(jnp.float32)) if f32_accumulate else (lambda v: v)
 
-    def kernel(in_ref, *rest):
-        if mxu:
-            by_ref, bz_ref, out_ref, ring = rest
-        else:
-            out_ref, ring = rest
+    def kernel(in_ref, out_ref, ring):
         i = pl.program_id(0)
         cur = in_ref[0]
 
@@ -178,23 +122,14 @@ def mean6_plane_step(
             @pl.when(in_window)
             def _():
                 prev = ring[i % 2]  # plane i-2
-                if mxu:
-                    c = up(cent)
-                    nbr = nbr_sum(c, by_ref[...], bz_ref[...])
-                    mean = (
-                        up(prev[y0:y1, z0:z1])
-                        + up(cur[y0:y1, z0:z1])
-                        + nbr[y0:y1, z0:z1]
-                    ) / 6.0
-                else:
-                    mean = (
-                        up(prev[y0:y1, z0:z1])
-                        + up(cur[y0:y1, z0:z1])
-                        + up(cent[y0 - 1 : y1 - 1, z0:z1])
-                        + up(cent[y0 + 1 : y1 + 1, z0:z1])
-                        + up(cent[y0:y1, z0 - 1 : z1 - 1])
-                        + up(cent[y0:y1, z0 + 1 : z1 + 1])
-                    ) / 6.0
+                mean = (
+                    up(prev[y0:y1, z0:z1])
+                    + up(cur[y0:y1, z0:z1])
+                    + up(cent[y0 - 1 : y1 - 1, z0:z1])
+                    + up(cent[y0 + 1 : y1 + 1, z0:z1])
+                    + up(cent[y0:y1, z0 - 1 : z1 - 1])
+                    + up(cent[y0:y1, z0 + 1 : z1 + 1])
+                ) / 6.0
                 out_ref[0] = cent  # keep the y/z shell
                 out_ref[0, y0:y1, z0:z1] = mean.astype(cur.dtype)
 
@@ -206,20 +141,14 @@ def mean6_plane_step(
         def _():
             ring[i % 2] = cur
 
-    in_specs = [pl.BlockSpec((1, Y, Z), lambda i: (jnp.minimum(i, X - 1), 0, 0))]
-    args = [block]
-    if mxu:
-        b_args, b_specs = band_operands(Y, Z, compute_unit, mxu_input)
-        in_specs += b_specs
-        args += b_args
     return pl.pallas_call(
         kernel,
         name=tm.KERNEL_MEAN6_PLANE,
         grid=(X + 1,),
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((1, Y, Z), lambda i: (jnp.minimum(i, X - 1), 0, 0))],
         out_specs=pl.BlockSpec((1, Y, Z), lambda i: (jnp.clip(i - 1, 0, X - 1), 0, 0)),
         out_shape=jax.ShapeDtypeStruct((X, Y, Z), block.dtype),
         scratch_shapes=[pltpu.VMEM((2, Y, Z), block.dtype)],
         interpret=interpret,
         **_tpu_compiler_params(interpret),
-    )(*args)
+    )(block)

@@ -109,21 +109,12 @@ from jax import shard_map
 from stencil_tpu import telemetry
 from stencil_tpu.telemetry import names as tm
 from stencil_tpu.ops.jacobi_pallas import (
-    COMPUTE_UNITS,
-    MXU_INPUTS,
     _make_roll,
     _padded_plane_bytes,
     _tpu_compiler_params,
     _vmem_budget,
     _VMEM_STACK_MARGIN,
     _WRAP_MAX_K,
-    band_operands,
-    make_plane_nbr_sum,
-    mxu_flops_per_plane,
-    plane_band_unit,
-    resolve_compute_unit,
-    resolve_mxu_input,
-    unit_uses_mxu,
 )
 
 
@@ -150,26 +141,12 @@ class PlaneView:
     x offset selects one of the ``2r+1`` VMEM-resident planes, the y/z
     offsets are in-plane rotates.  Rotate wraparound at the plane edges only
     contaminates shell cells the validity contract already sacrifices.
-
-    ``plane_nbr_sum()`` is the compute-unit seam for AXIS-SEPARABLE
-    kernels: the sum of the four in-plane face neighbors of the center
-    plane, lowered as the historical roll+add chain under ``vpu`` or as ONE
-    banded contraction per axis on the matrix unit under the MXU units
-    (``bands`` set — the dense ``band_matrix`` circulants under ``mxu``,
-    the blocked ``band_wide_tile`` form under ``mxu_band``; ulp-pinned vs
-    the chain, a pure summation-order difference).  A kernel's ``mxu``
-    form (``make_stream_step(mxu_kernel=...)``) writes its separable
-    in-plane taps through this helper; kernels with no such form never see
-    bands and structurally degrade to ``vpu``.
     """
 
-    def __init__(self, window: Tuple[jax.Array, ...], roll, bands=None):
+    def __init__(self, window: Tuple[jax.Array, ...], roll):
         self._window = window
         self._r = (len(window) - 1) // 2
         self._roll = roll
-        self._bands = bands  # nbr_sum(center) closure over the resident
-        # contraction constants (ops/jacobi_pallas.make_plane_nbr_sum
-        # bound to this pass's refs), or None (= vpu)
 
     def sh(self, dx: int = 0, dy: int = 0, dz: int = 0) -> jax.Array:
         # ALL axes are bounded by the declared read radius: an in-plane
@@ -185,19 +162,6 @@ class PlaneView:
         if dz:
             v = self._roll(v, -dz, 1)
         return v
-
-    def plane_nbr_sum(self) -> jax.Array:
-        """``sh(0,1,0) + sh(0,-1,0) + sh(0,0,1) + sh(0,0,-1)`` — on the MXU
-        as banded contractions when this view carries band constants."""
-        c = self.center()
-        if self._bands is not None:
-            return self._bands(c)
-        return (
-            self.sh(0, 1, 0)
-            + self.sh(0, -1, 0)
-            + self.sh(0, 0, 1)
-            + self.sh(0, 0, -1)
-        )
 
     def center(self) -> jax.Array:
         return self._window[self._r]
@@ -259,23 +223,6 @@ def _fused_plane_patch(v, xplane, yst, zst, t, lo_y, hi_y, lo_z, hi_z):
     return v
 
 
-def _pass_band_setup(compute_unit: str, mxu_input: str, plane_y: int,
-                     plane_z: int, where: str):
-    """``(effective unit, band args, band in_specs, nbr_sum)`` for one
-    streaming pass's plane geometry — empty/None pieces under ``vpu``.
-    Each pass tiles its OWN geometry (the split schedule's narrow band
-    sub-blocks differ from the interior pass), so the band→dense
-    structural degrade (``plane_band_unit``) is per pass; the contraction
-    VALUES stay identical across variants up to summation order, so the
-    pass outputs keep the documented ulp pins either way."""
-    if not unit_uses_mxu(compute_unit):
-        return compute_unit, [], [], None
-    unit = plane_band_unit(compute_unit, plane_y, plane_z, where=where)
-    args, specs = band_operands(plane_y, plane_z, unit, mxu_input)
-    nbr = make_plane_nbr_sum(plane_y, plane_z, unit, mxu_input)
-    return unit, args, specs, nbr
-
-
 def stream_plane_pass(
     kernel: PlaneKernel,
     names: Sequence[str],
@@ -287,10 +234,6 @@ def stream_plane_pass(
     global_size: Dim3,
     alias: bool = False,  # out q aliases raw q (in place; see below)
     interpret: bool = False,
-    compute_unit: str = "vpu",  # "mxu"/"mxu_band": band constants ride in
-    # as resident inputs and the views' plane_nbr_sum contracts on the
-    # matrix unit (dense circulants vs blocked band tiles)
-    mxu_input: str = "f32",  # MXU operand precision (jacobi_wrap_step)
     f32_accumulate: bool = False,  # bf16-storage variant: planes upcast to
     # f32 for the kernel, one downcast at the interior store (pass-through
     # shell planes keep their storage bytes bit-exact)
@@ -338,20 +281,10 @@ def stream_plane_pass(
     z0, z1 = lo.z, Z - hi.z
     roll = _make_roll(interpret)
     gsize = global_size
-    compute_unit, b_args, b_specs, nbr = _pass_band_setup(
-        compute_unit, mxu_input, Y, Z, "stream-plane"
-    )
-    mxu = unit_uses_mxu(compute_unit)
     up = (lambda v: v.astype(jnp.float32)) if f32_accumulate else (lambda v: v)
 
     def body(origin_ref, *refs):
         in_refs = refs[:nq]
-        if mxu:
-            b1, b2 = refs[nq][...], refs[nq + 1][...]
-            bands = lambda c: nbr(c, b1, b2)
-            refs = refs[: nq] + refs[nq + 2 :]
-        else:
-            bands = None
         if fused_shell is not None:
             xs_refs = refs[nq : 2 * nq]
             ys_refs = refs[2 * nq : 3 * nq]
@@ -392,7 +325,6 @@ def stream_plane_pass(
                     names[q]: PlaneView(
                         tuple(up(plane(q, 2 * r - d)) for d in range(2 * r + 1)),
                         roll,
-                        bands=bands,
                     )
                     for q in range(nq)
                 }
@@ -435,10 +367,6 @@ def stream_plane_pass(
         for _ in range(nq)
     ]
     args = [origin.astype(jnp.int32), *raws]
-    if mxu:
-        # resident contraction constants, fetched once like the d2 plane
-        in_specs += b_specs
-        args += b_args
     if fused_shell is not None:
         xs_list, ys_list, zs_list = fused_shell
         assert all(b.shape == (lo.x + hi.x, Y, Z) for b in xs_list)
@@ -487,8 +415,8 @@ def stream_plane_pass(
         in_specs=in_specs,
         out_specs=out_specs if nq > 1 else out_specs[0],
         out_shape=out_shape if nq > 1 else out_shape[0],
-        # operand 0 is origin; band constants and fused-shell side inputs
-        # sit after the raws, so the map is raw-q -> out-q whatever rides in
+        # operand 0 is origin; fused-shell side inputs sit after the raws,
+        # so the map is raw-q -> out-q whatever rides in
         input_output_aliases={1 + q: q for q in range(nq)} if alias else {},
         scratch_shapes=[
             pltpu.VMEM((2 * r, Y, Z), b.dtype) for b in raws
@@ -511,9 +439,6 @@ def stream_wavefront_pass(
     z_valid: int = None,  # logical plane width; [z_valid, Zr) is lane padding
     alias: bool = False,
     interpret: bool = False,
-    compute_unit: str = "vpu",  # "mxu"/"mxu_band": resident band constants
-    # + contraction via the views' plane_nbr_sum (see stream_plane_pass)
-    mxu_input: str = "f32",  # MXU operand precision (jacobi_wrap_step)
     f32_accumulate: bool = False,  # bf16-storage variant: upcast at load,
     # f32 level rings + arithmetic, one downcast at the final store/emit
     fused_shell=None,  # (xbufs, ybufs, zbufs) per quantity — the packed
@@ -544,10 +469,6 @@ def stream_wavefront_pass(
     gsize = global_size
     assert 2 * s_off < gsize.x, (s_off, gsize)  # non-negative lax.rem operand
     roll = _make_roll(interpret)
-    compute_unit, b_args, b_specs, nbr = _pass_band_setup(
-        compute_unit, mxu_input, Yr, Zr, "stream-wavefront"
-    )
-    mxu = unit_uses_mxu(compute_unit)
     acc_dtypes = [
         jnp.float32 if f32_accumulate else b.dtype for b in raws
     ]
@@ -556,12 +477,6 @@ def stream_wavefront_pass(
     def body(origin_ref, *refs):
         in_refs = refs[:nq]
         refs = refs[nq:]
-        if mxu:
-            b1, b2 = refs[0][...], refs[1][...]
-            bands = lambda c: nbr(c, b1, b2)
-            refs = refs[2:]
-        else:
-            bands = None
         if fused_shell is not None:
             xs_refs = refs[:nq]
             ys_refs = refs[nq : 2 * nq]
@@ -611,8 +526,7 @@ def stream_wavefront_pass(
             for q in range(nq):
                 rings[q][s - 1, i % 2] = vals[q]  # push plane i-s+1
             views = {
-                names[q]: PlaneView((prevs[q], cents[q], vals[q]), roll,
-                                    bands=bands)
+                names[q]: PlaneView((prevs[q], cents[q], vals[q]), roll)
                 for q in range(nq)
             }
             x_g = lax.rem(
@@ -649,9 +563,6 @@ def stream_wavefront_pass(
         jax.ShapeDtypeStruct((Xr, Yr, Zr), b.dtype) for b in raws
     ]
     args = [origin.astype(jnp.int32), *raws]
-    if mxu:
-        in_specs += b_specs
-        args += b_args
     if fused_shell is not None:
         xs_list, ys_list, zs_list = fused_shell
         s = s_off
@@ -725,9 +636,6 @@ def stream_wrap_pass(
     origin: jax.Array,  # (3,) int32 — global coords of the block start
     global_size: Dim3,
     interpret: bool = False,
-    compute_unit: str = "vpu",  # "mxu"/"mxu_band": resident band constants
-    # + contraction via the views' plane_nbr_sum (see stream_plane_pass)
-    mxu_input: str = "f32",  # MXU operand precision (jacobi_wrap_step)
     f32_accumulate: bool = False,  # bf16-storage variant (see
     # stream_wavefront_pass)
 ) -> List[jax.Array]:
@@ -745,10 +653,6 @@ def stream_wrap_pass(
     assert 1 <= k <= X // 2, (k, X)
     roll = _make_roll(interpret)
     gsize = global_size
-    compute_unit, b_args, b_specs, nbr = _pass_band_setup(
-        compute_unit, mxu_input, Y, Z, "stream-wrap"
-    )
-    mxu = unit_uses_mxu(compute_unit)
     acc_dtypes = [
         jnp.float32 if f32_accumulate else b.dtype for b in blocks
     ]
@@ -757,12 +661,6 @@ def stream_wrap_pass(
     def body(origin_ref, *refs):
         in_refs = refs[:nq]
         refs = refs[nq:]
-        if mxu:
-            b1, b2 = refs[0][...], refs[1][...]
-            bands = lambda c: nbr(c, b1, b2)
-            refs = refs[2:]
-        else:
-            bands = None
         out_refs = refs[:nq]
         rings = refs[nq:]
         i = pl.program_id(0)
@@ -774,8 +672,7 @@ def stream_wrap_pass(
             for q in range(nq):
                 rings[q][s - 1, i % 2] = vals[q]
             views = {
-                names[q]: PlaneView((prevs[q], cents[q], vals[q]), roll,
-                                    bands=bands)
+                names[q]: PlaneView((prevs[q], cents[q], vals[q]), roll)
                 for q in range(nq)
             }
             x_g = lax.rem(
@@ -798,9 +695,6 @@ def stream_wrap_pass(
         pl.BlockSpec((1, Y, Z), lambda i: (i % X, 0, 0)) for _ in range(nq)
     ]
     args = [origin.astype(jnp.int32), *blocks]
-    if mxu:
-        in_specs += b_specs
-        args += b_args
     outs = pl.pallas_call(
         body,
         name=tm.KERNEL_STREAM_WRAP_PASS,
@@ -872,15 +766,6 @@ def _tuned_stream_plan(dd, x_radius: int, separable: bool) -> dict:
     # never a crash), like any other hand-edited field.
     if cfg.get("overlap") is not None:
         plan["overlap"] = cfg["overlap"]
-    # the compute-unit axis rides the same no-schema-bump rule: absent =
-    # the static vpu, garbage invalidates the plan below.  Pre-variant
-    # entries (``mxu`` from before the band form existed) stay warm: the
-    # value is still in the vocabulary
-    if cfg.get("compute_unit") is not None:
-        plan["compute_unit"] = cfg["compute_unit"]
-    # ...and the MXU input-precision axis: absent = the static f32
-    if cfg.get("mxu_input") is not None:
-        plan["mxu_input"] = cfg["mxu_input"]
     # ...and so does the fused-halo axis: pre-halo entries lack the key and
     # resolve to the static "array"; garbage invalidates to static
     if cfg.get("halo") is not None:
@@ -894,10 +779,6 @@ def _tuned_stream_plan(dd, x_radius: int, separable: bool) -> dict:
         ok = plan["overlap"] in STREAM_OVERLAP
     if ok and plan.get("halo") is not None:
         ok = plan["halo"] in STREAM_HALO
-    if ok and plan.get("compute_unit") is not None:
-        ok = plan["compute_unit"] in COMPUTE_UNITS
-    if ok and plan.get("mxu_input") is not None:
-        ok = plan["mxu_input"] in MXU_INPUTS
     if ok and plan["grouping"] == "per-field":
         ok = separable and len(dd._handles) > 1
     elif ok and plan["grouping"] != "joint":
@@ -1388,8 +1269,7 @@ def plain_wavefront_plan(dd, plan: dict, max_depth: Optional[int] = None) -> Opt
     return out
 
 
-def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
-                       mxu_kernel=None):
+def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     from jax.sharding import PartitionSpec as P
 
     from stencil_tpu.ops.exchange import (
@@ -1419,7 +1299,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
     # steps escape the 64×-amplified thin-z path exactly like exchange()
     exch_route = getattr(dd, "_exchange_route", "direct")
     # Pass outputs alias their inputs or not (_resolve_stream_alias), written
-    # back into the plan like overlap / halo / compute_unit (the ladder,
+    # back into the plan like overlap / halo (the ladder,
     # step._stream_plan and domain.step's ``aliased`` read it).  The wrap
     # pass has no in-place form.
     # MEASURED on the v5e (PERF.md §6, PR 28): the plane route un-aliased
@@ -1464,49 +1344,8 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
         exchange_route=exch_route,
     )
     fused = halo == "fused"
-    # compute-unit axis (ops/jacobi_pallas COMPUTE_UNITS): shared precedence
-    # chain (forced plan value = explicit requests / autotuner candidates /
-    # ladder step-downs > STENCIL_COMPUTE_UNIT > tuned plan > static vpu)
-    # plus the stream engine's structural gate — mxu needs a DECLARED
-    # axis-separable contraction form (``mxu_kernel``; opaque user kernels
-    # have none and degrade with a warning) and f32 compute dtypes.  bf16
-    # STORAGE (``f32_accumulate``) computes at the native f32 and qualifies.
+    # bf16 STORAGE: the passes upcast at load and accumulate at the native f32
     f32_acc = any(dd.field_dtype(h) != h.dtype for h in dd._handles)
-    unit_req = (
-        plan.get("compute_unit") if plan.get("compute_unit_forced") else None
-    )
-    unit_tuned = None if unit_req is not None else plan.get("compute_unit")
-    compute_unit, _unit_src = resolve_compute_unit(
-        unit_req,
-        unit_tuned,
-        [h.dtype for h in dd._handles],
-        where=f"stream:{plan['route']}",
-        engine_ok=mxu_kernel is not None,
-        engine_why=(
-            "the kernel declares no axis-separable contraction form "
-            "(make_stream_step mxu_kernel=...)"
-        ),
-    )
-    plan["compute_unit"] = compute_unit
-    # MXU input precision (ops/jacobi_pallas MXU_INPUTS): resolved AFTER
-    # the unit (bf16 inputs only exist under an engaged MXU unit) through
-    # the same forced > env > tuned > static chain
-    mi_req = plan.get("mxu_input") if plan.get("mxu_input_forced") else None
-    mi_tuned = None if mi_req is not None else plan.get("mxu_input")
-    mxu_input, _mi_src = resolve_mxu_input(
-        mi_req, mi_tuned, compute_unit, where=f"stream:{plan['route']}"
-    )
-    plan["mxu_input"] = mxu_input
-    if unit_uses_mxu(compute_unit):
-        # the mxu form is the SAME stencil written through the views'
-        # plane_nbr_sum seam; every pass (interior, exterior bands, wrap)
-        # runs it, so the split-schedule bitwise argument holds per unit
-        kernel = mxu_kernel
-    unit_kw = {
-        "compute_unit": compute_unit,
-        "f32_accumulate": f32_acc,
-        "mxu_input": mxu_input,
-    }
 
     if split:
         from stencil_tpu.ops import halo_blend
@@ -1632,7 +1471,8 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
                 for g in groups:
                     outs = stream_wrap_pass(
                         kernel, [names[q] for q in g], [bs[q] for q in g],
-                        depth, origin, gsize, interpret=interpret, **unit_kw,
+                        depth, origin, gsize, interpret=interpret,
+                        f32_accumulate=f32_acc,
                     )
                     for q, o in zip(g, outs):
                         out[q] = o
@@ -1666,7 +1506,8 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
                     outs = stream_plane_pass(
                         kernel, [names[q] for q in g], [bs[q] for q in g],
                         lo, hi, x_radius, origin, gsize, alias=in_place,
-                        interpret=interpret, fused_shell=fs, **unit_kw,
+                        interpret=interpret, fused_shell=fs,
+                        f32_accumulate=f32_acc,
                     )
                 for q, o in zip(g, outs):
                     out[q] = o
@@ -1708,7 +1549,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
                     outs = stream_plane_pass(
                         kernel, [names[q] for q in g], [subs[q] for q in g],
                         lo2, hi2, x_radius, origin_sub, gsize,
-                        interpret=interpret, **unit_kw,
+                        interpret=interpret, f32_accumulate=f32_acc,
                     )
                     for q, o in zip(g, outs):
                         out[q] = o
@@ -1780,7 +1621,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
                     alias=alias,
                     interpret=interpret,
                     fused_shell=fs,
-                    **unit_kw,
+                    f32_accumulate=f32_acc,
                 )
                 for j, q in enumerate(g):
                     outs[q] = o[j]
@@ -1805,7 +1646,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True,
                 o, _ = stream_wavefront_pass(
                     kernel, [names[q] for q in g], [subs[q] for q in g],
                     w, w, origin_sub, gsize, alias=False, interpret=interpret,
-                    **unit_kw,
+                    f32_accumulate=f32_acc,
                 )
                 for q, oo in zip(g, o):
                     out[q] = oo
@@ -1928,9 +1769,6 @@ def make_stream_step(
     max_depth: int = None,
     overlap: str = "auto",
     halo: str = "auto",
-    compute_unit: str = "auto",
-    mxu_input: str = "auto",
-    mxu_kernel: PlaneKernel = None,
 ):
     """Build a ``step(curr, steps) -> curr`` running ``kernel`` under the
     plane-streaming engine — the fast-by-default path for user stencils
@@ -1971,29 +1809,6 @@ def make_stream_step(
     a compile-rejected fused build steps down to ``"array"`` at the same
     depth through the ladder before any depth descent.
 
-    ``compute_unit`` selects the level kernels' execution unit (a tuner
-    axis — docs/tuning.md "Compute unit and storage dtype"): ``"auto"``
-    resolves ``STENCIL_COMPUTE_UNIT`` > the tuned config > the static
-    ``vpu``; ``"mxu"`` routes the separable in-plane taps through one
-    banded contraction per axis on the matrix unit, which requires the
-    kernel's declared contraction form ``mxu_kernel`` — the SAME stencil
-    written against ``PlaneView.plane_nbr_sum`` (pinned ≤1 ulp/level
-    against the vpu form); ``"mxu_band"`` tiles that contraction to the
-    band's nonzeros (blocked ``(2r+1)``-band matmul — ulp-pinned against
-    the dense form, ~``n/(2r+1)``× fewer FLOPs, KB-scale resident
-    constants).  A kernel with no mxu form, or non-f32 compute dtypes,
-    degrades to ``vpu`` with a warning; an untilable plane geometry
-    degrades ``mxu_band`` to the dense form per pass; a compile-rejected
-    build steps down band → dense → vpu at the same depth through the
-    ladder before any depth descent.
-
-    ``mxu_input`` selects the contraction operand precision (a tuner
-    axis): ``"auto"`` resolves ``STENCIL_MXU_INPUT`` > the tuned config >
-    the static ``"f32"``; ``"bf16"`` feeds bfloat16 operands to the MXU
-    under the unchanged f32-accumulate contract (analytic bound
-    ``tests/ulp.mxu_bf16_input_atol``) — the ~2× ratio leg of the "VPU
-    wall" break-even model.  Structurally inert under ``vpu``.
-
     The returned step rides the resilience DEGRADATION LADDER
     (``resilience/ladder.py``): if Mosaic rejects the planned wavefront depth
     (scoped-VMEM OOM, or any other classified compile reject), the ladder
@@ -2033,19 +1848,8 @@ def make_stream_step(
             f"unknown stream halo mode {halo!r} (one of "
             f"{('auto',) + STREAM_HALO})"
         )
-    if compute_unit not in ("auto",) + COMPUTE_UNITS:
-        raise ValueError(
-            f"unknown compute unit {compute_unit!r} (one of "
-            f"{('auto',) + COMPUTE_UNITS})"
-        )
-    if mxu_input not in ("auto",) + MXU_INPUTS:
-        raise ValueError(
-            f"unknown mxu input {mxu_input!r} (one of "
-            f"{('auto',) + MXU_INPUTS})"
-        )
     plan = plan_stream(dd, x_radius, path, separable, max_m=max_depth)
-    if (overlap != "auto" or halo != "auto" or compute_unit != "auto"
-            or mxu_input != "auto"):
+    if overlap != "auto" or halo != "auto":
         plan = dict(plan)
     if overlap != "auto":
         plan["overlap"] = overlap
@@ -2053,12 +1857,6 @@ def make_stream_step(
     if halo != "auto":
         plan["halo"] = halo
         plan["halo_forced"] = True
-    if compute_unit != "auto":
-        plan["compute_unit"] = compute_unit
-        plan["compute_unit_forced"] = True
-    if mxu_input != "auto":
-        plan["mxu_input"] = mxu_input
-        plan["mxu_input_forced"] = True
     # a split request (explicit/env/tuned) against a z-slab wavefront plan
     # re-plans to the PLAIN form when it fits: split needs z halos in the
     # big array for the exchange it overlaps, and the packed zpack_* routes
@@ -2073,40 +1871,16 @@ def make_stream_step(
         if plain is not None:
             plan = plain
 
-    from stencil_tpu.ops.jacobi_pallas import mxu_supported
-
-    def _prospective_unit(p) -> str:
-        """The unit the build WILL resolve (same chain as
-        _build_stream_step, emit=False) — rung names must show an
-        env/tuned-sourced mxu, not just an explicit one.  Skipped when mxu
-        cannot engage (no declared form / non-f32), where the build's own
-        resolve owns the single degrade warning."""
-        if mxu_kernel is None or not mxu_supported(
-            [h.dtype for h in dd._handles]
-        ):
-            return "vpu"
-        u_req = p.get("compute_unit") if p.get("compute_unit_forced") else None
-        u_tuned = None if u_req is not None else p.get("compute_unit")
-        unit, _ = resolve_compute_unit(
-            u_req, u_tuned, [h.dtype for h in dd._handles],
-            where=f"stream:{p['route']}", emit=False,
-        )
-        return unit
-
     def rung_for(p):
         # build() resolves _build_stream_step through module globals at call
         # time, so tests may monkeypatch it
         suffix = ",split" if p.get("overlap") == "split" else ""
         if p.get("halo") == "fused":
             suffix += ",fused"
-        unit = _prospective_unit(p)
-        if unit != "vpu":
-            suffix += f",{unit}"
         return Rung(
             name=f"{p['route']}[m={p['m']}{suffix}]",
             build=lambda: _build_stream_step(
-                dd, kernel, x_radius, p, interpret, donate,
-                mxu_kernel=mxu_kernel,
+                dd, kernel, x_radius, p, interpret, donate
             ),
             state={"plan": p},
         )
@@ -2115,47 +1889,8 @@ def make_stream_step(
         plan_now = rung.state["plan"]
         from stencil_tpu.utils.logging import log_warn
 
-        # key the axis step-down on the unit the rung actually RESOLVES
-        # (the build's chain, mirrored by _prospective_unit) — an env/tuned-
-        # sourced mxu leaves the plan dict unset, and keying on the dict
-        # alone would wrongly descend DEPTH for a reject that is the
-        # contraction's fault (incl. the prefilter's static band-matrix
-        # reject), violating the axis-drops-first-at-same-depth rule
-        unit_now = _prospective_unit(plan_now)
-        if unit_now == "mxu_band":
-            # first rung down: band → DENSE at the SAME depth/schedule —
-            # the blocked form carries its own reshape/batched-dot lowering
-            # surface, so a reject may be the tiling's fault while the
-            # dense contraction still compiles
-            log_warn(
-                f"compute_unit=mxu_band on {plan_now['route']}"
-                f"[m={plan_now['m']}] exceeded the compiler's capability "
-                f"({cls.value}); stepping down to the dense mxu form at the "
-                "same depth"
-            )
-            p2 = dict(plan_now)
-            p2["compute_unit"] = "mxu"
-            p2["compute_unit_forced"] = True
-            return rung_for(p2)
-        if unit_now == "mxu":
-            # next rung down: drop the MXU contraction form at the SAME
-            # depth/schedule — the band matmuls carry their own resident
-            # constants and matrix-unit lowering, so a VMEM_OOM or compile
-            # reject may be the contraction's fault, not the depth's
-            log_warn(
-                f"compute_unit=mxu on {plan_now['route']}[m={plan_now['m']}] "
-                f"exceeded the compiler's capability ({cls.value}); stepping "
-                "down to vpu at the same depth"
-            )
-            p2 = dict(plan_now)
-            p2["compute_unit"] = "vpu"
-            p2["compute_unit_forced"] = True
-            # moot without a contraction — pin f32 so the resolve stays quiet
-            p2["mxu_input"] = "f32"
-            p2["mxu_input_forced"] = True
-            return rung_for(p2)
         if plan_now.get("halo") == "fused":
-            # next rung down: drop the fused halo mode at the SAME depth —
+            # first rung down: drop the fused halo mode at the SAME depth —
             # the fused pass carries extra side-buffer blocks and per-plane
             # patch selects, so a VMEM_OOM or compile reject may be the
             # fused form's fault, not the depth's
@@ -2169,7 +1904,7 @@ def make_stream_step(
             p2["halo_forced"] = True
             return rung_for(p2)
         if plan_now.get("overlap") == "split":
-            # first rung down: drop the split schedule at the SAME depth —
+            # next rung down: drop the split schedule at the SAME depth —
             # the exterior passes carry their own scratch, so a VMEM_OOM or
             # compile reject may be the overlap's fault, not the depth's
             log_warn(
@@ -2192,22 +1927,16 @@ def make_stream_step(
             "STENCIL_VMEM_LIMIT_BYTES)"
         )
         p2 = dict(plan_stream(dd, x_radius, path, separable, max_m=new_max))
-        # a descent never re-enables split, fused, or mxu: carry the
+        # a descent never re-enables split or fused: carry the
         # (post-step-down) axis state into the shallower plan as forced
         p2["overlap"] = plan_now.get("overlap", "off")
         p2["overlap_forced"] = True
         p2["halo"] = plan_now.get("halo", "array")
         p2["halo_forced"] = True
-        p2["compute_unit"] = plan_now.get("compute_unit", "vpu")
-        p2["compute_unit_forced"] = True
-        p2["mxu_input"] = plan_now.get("mxu_input", "f32")
-        p2["mxu_input_forced"] = True
         return rung_for(p2)
 
     # static prefilters on real backends: a rung the VMEM model
-    # (analysis/vmem.py) already rejects descends WITHOUT compiling — the
-    # mxu twin's resident band matrices are the case plan_stream's depth
-    # gate never modeled, previously a compile-and-catch VMEM_OOM — and a
+    # (analysis/vmem.py) already rejects descends WITHOUT compiling, and a
     # rung the Mosaic legality model (analysis/kernels.py) rejects
     # descends as a recorded COMPILE_REJECT the same way (the tuple
     # verdict names the class).  Interpret mode has no Mosaic: nothing to
@@ -2218,12 +1947,7 @@ def make_stream_step(
             from stencil_tpu.analysis import check_kernel_legal, check_vmem
             from stencil_tpu.resilience.taxonomy import FailureClass
 
-            # model what build() will actually compile: the unit resolves
-            # through the same chain the build uses (_prospective_unit —
-            # env/tuned mxu folds the band matrices in, a request that
-            # structurally degrades to vpu must NOT be priced as mxu)
-            p = dict(rung.state["plan"])
-            p["compute_unit"] = _prospective_unit(p)
+            p = rung.state["plan"]
             reason = check_vmem(dd, p)
             if reason is not None:
                 return reason
@@ -2241,28 +1965,6 @@ def make_stream_step(
     band_area = 2 * (raw.y * raw.z + raw.x * raw.z + raw.x * raw.y) * len(
         dd._handles
     ) * n_doms
-    # analytic MXU FLOPs of ONE raw iteration under the RESOLVED
-    # contraction variant (all shards, all fields) — the dense model
-    # over-reports a band-tiled run by ~n/(2r+1), which would poison every
-    # roofline and perf-ledger series built on kernel.mxu.flops.  Modeled
-    # on the plane geometry the pass actually CONTRACTS, not the raw
-    # dims: the wrap route slices the bare interior, and the z-slab
-    # wavefront lane-pads its planes — both change which band tiling (if
-    # any) engages, so raw-dims pricing could count the wrong variant
-    n_int = dd.local_spec().sz
-
-    def _mxu_flops_iter(plan_now: dict) -> int:
-        unit = plan_now.get("compute_unit", "vpu")
-        if plan_now.get("route") == "wrap":
-            py, pz, px = n_int.y, n_int.z, n_int.x
-        else:
-            py, px = raw.y, raw.x
-            pz = lane_pad_width(raw.z) if plan_now.get("z_slabs") else raw.z
-        return (
-            mxu_flops_per_plane(py, pz, unit)
-            * px * len(dd._handles) * n_doms
-        )
-
     def _exterior_cells(plan_now, steps: int) -> int:
         """Analytic cells recomputed by the exterior band passes for this
         dispatch (all shards, all fields) — 0 under ``overlap=off``."""
@@ -2281,10 +1983,6 @@ def make_stream_step(
         cells = _exterior_cells(plan_now, steps)
         if cells:
             telemetry.inc(tm.STEP_OVERLAP_EXTERIOR_CELLS, cells)
-        if unit_uses_mxu(plan_now.get("compute_unit", "vpu")):
-            telemetry.inc(
-                tm.KERNEL_MXU_FLOPS, steps * _mxu_flops_iter(plan_now)
-            )
         return out
 
     step._marks_shell_stale = True

@@ -67,11 +67,10 @@ RING_SIZE = 256
 
 #: counters sampled onto Chrome counter tracks at every span record — the
 #: cumulative series whose slope IS the throughput Perfetto shows next to
-#: the spans (exchange/packed bytes, MXU flops)
+#: the spans (exchange/packed bytes)
 _TRACK_COUNTERS = (
     names.EXCHANGE_BYTES,
     names.EXCHANGE_PACKED_BYTES,
-    names.KERNEL_MXU_FLOPS,
 )
 
 

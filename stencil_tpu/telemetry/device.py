@@ -4,7 +4,7 @@ metadata.
 
 The host span tracer (``spans.py``) sees wall-clock only — a dispatch that
 returns at enqueue looks free, and the split-step overlap win/loss, the
-exchange's real cost, and the MXU contraction's share of a step are only
+exchange's real cost and the pack kernels' share of a step are only
 knowable from the device timeline (T3, arxiv 2401.16677: overlap efficiency
 comes from fine-grained attribution of compute vs collectives).  This module
 closes that gap without any online dependency on the profiler:
@@ -49,8 +49,7 @@ from stencil_tpu.telemetry import names
 #: the named-scope/kernel families device time is attributed to.  The two
 #: ``step.overlap.*`` entries are the annotate() scopes the split schedule
 #: enters (names.py); ``exchange``/``pack`` match the collective and pack
-#: kernel families by their stable substrings; ``mxu`` matches the banded
-#: contraction's dot/matmul kernels.  Matching is case-insensitive
+#: kernel families by their stable substrings.  Matching is case-insensitive
 #: substring over the event name and its args values.
 PHASE_PATTERNS: Dict[str, Tuple[str, ...]] = {
     names.SPAN_OVERLAP_INTERIOR: (names.SPAN_OVERLAP_INTERIOR,),
@@ -68,7 +67,6 @@ PHASE_PATTERNS: Dict[str, Tuple[str, ...]] = {
         names.SPAN_EXCHANGE,
     ),
     "pack": ("zpack", "halo_pack", "shell_pack", "unpack"),
-    "mxu": ("band_matrix", "dot_general", "matmul", "convolution"),
     "step": (names.SPAN_STEP,),
 }
 
@@ -97,7 +95,6 @@ DEVICE_PID_BASE = 1000
 CAPTURE_COUNTERS = (
     names.EXCHANGE_BYTES,
     names.EXCHANGE_PACKED_BYTES,
-    names.KERNEL_MXU_FLOPS,
 ) + tuple(sorted(names.EXCHANGE_HOP_BYTES.values()))
 
 
@@ -167,8 +164,8 @@ def attribute_device_time(
     Returns ``{phase: {"device_us": float, "events": int}}`` plus two
     synthetic rows: ``_total`` (all device complete-events) and
     ``_unattributed`` (device time matching no phase).  An event matching
-    several phases counts toward each (an interior-scope matmul is both
-    ``step.overlap.interior`` and ``mxu`` time), so rows are VIEWS of the
+    several phases counts toward each (an interior-scope pack kernel is both
+    ``step.overlap.interior`` and ``pack`` time), so rows are VIEWS of the
     device timeline, not a partition — only ``_total`` is additive.
 
     Row selection: when the dump carries process metadata, only events on

@@ -114,22 +114,6 @@ def entries_from_artifact(path: str) -> List[dict]:
             ("chip_copy_gbps", "GB/s"),
         ):
             out.append(_entry(ts, f"bench.{field}", bench.get(field), unit, source))
-        # the compute-unit A/B legs (bench.py mxu_vs_vpu: vpu / mxu /
-        # mxu_band / mxu_band+bf16in) as their own series — higher is
-        # better, so the trailing-median gate catches a contraction-leg
-        # regression exactly like a headline drop
-        mxu_ab = bench.get("mxu_vs_vpu") or {}
-        for leg, d in sorted((mxu_ab.get("units") or {}).items()):
-            out.append(
-                _entry(
-                    ts,
-                    f"mxu_ab:{leg}:mcells_per_s",
-                    (d or {}).get("mcells_per_s"),
-                    "Mcells/s",
-                    source,
-                    k=mxu_ab.get("k"),
-                )
-            )
         # the numerics observatory's on/off A/B (bench.py
         # numerics_overhead): per-snapshot cost of the fused on-device
         # field-health dispatch — LOWER-is-better (the gate flags a rise),

@@ -63,13 +63,6 @@ EXCHANGE_PACKED_KERNELS = "exchange.packed.kernels"
 #: surface work the overlapped schedule pays to free the interior pass from
 #: any ppermute dependency; 0 under ``overlap=off``
 STEP_OVERLAP_EXTERIOR_CELLS = "step.overlap.exterior_cells"
-#: analytic MXU FLOPs issued by the banded-contraction level kernels
-#: (``compute_unit=mxu|mxu_band`` — ops/jacobi_pallas.py
-#: ``mxu_flops_per_plane``): FLOPs per level per plane for the RESOLVED
-#: variant (dense circulant vs blocked band tiles — the dense model
-#: over-reports a band-tiled run by ~n/(2r+1)), modeled once per build
-#: like the exchange bytes; 0 under ``compute_unit=vpu``
-KERNEL_MXU_FLOPS = "kernel.mxu.flops"
 #: checkpoints committed (atomic rename completed — io/checkpoint.py)
 CHECKPOINT_SAVES = "checkpoint.saves"
 #: bytes of quantity data written by those checkpoints (interior cells at
@@ -188,7 +181,6 @@ ALL_COUNTERS = frozenset({
     TUNE_PRUNED,
     TUNE_SELECTED,
     STEP_OVERLAP_EXTERIOR_CELLS,
-    KERNEL_MXU_FLOPS,
     CHECKPOINT_SAVES,
     CHECKPOINT_SAVE_BYTES,
     CHECKPOINT_RESTORES,
@@ -495,19 +487,9 @@ EVENT_STEP_OVERLAP = "step.overlap"
 #: halo=array|fused, source=explicit|env|tuned|static|ladder or
 #: "<orig>/degraded" on a structural step-down, route, m, exchange_route)
 EVENT_STEP_HALO = "step.halo"
-#: a kernel build resolved its compute-unit axis (fields:
-#: unit=vpu|mxu|mxu_band, source=explicit|env|tuned|static|ladder or
-#: "<orig>/degraded" when a structural guard stepped an mxu request down,
-#: where)
-EVENT_KERNEL_COMPUTE_UNIT = "kernel.compute_unit"
-#: a kernel build resolved its MXU input-precision axis (fields:
-#: input=f32|bf16, source — same vocabulary as kernel.compute_unit plus
-#: "<orig>/degraded" when the resolved unit has no contraction to feed,
-#: unit, where)
-EVENT_KERNEL_MXU_INPUT = "kernel.mxu_input"
 #: a model build resolved its storage-dtype axis (fields:
-#: storage=native|bf16, source — same vocabulary as kernel.compute_unit,
-#: where)
+#: storage=native|bf16, source=explicit|env|tuned|static or
+#: "<orig>/degraded" on a structural step-down, where)
 EVENT_KERNEL_STORAGE_DTYPE = "kernel.storage_dtype"
 #: a checkpoint committed (fields: path, step, backend, bytes, seconds,
 #: reason=cadence|final|preempt)
@@ -564,8 +546,6 @@ ALL_EVENTS = frozenset({
     EVENT_EXCHANGE_ROUTE,
     EVENT_STEP_OVERLAP,
     EVENT_STEP_HALO,
-    EVENT_KERNEL_COMPUTE_UNIT,
-    EVENT_KERNEL_MXU_INPUT,
     EVENT_KERNEL_STORAGE_DTYPE,
     EVENT_CHECKPOINT_SAVE,
     EVENT_CHECKPOINT_RESTORE,

@@ -1,14 +1,14 @@
 """Roofline reports: join measured device time per phase with the analytic
 counters the tree already records.
 
-The PERF_NOTES break-even models (VPU wall, split-step overlap, zpack) all
-end in the same table a human currently assembles by hand: achieved GB/s /
-GFLOP/s per phase vs the chip's peak.  This module builds that table from
+The PERF_NOTES break-even models (split-step overlap, zpack) all end in the
+same table a human currently assembles by hand: achieved GB/s per phase vs
+the chip's peak.  This module builds that table from
 two inputs this repo already produces —
 
 * a **metrics snapshot** (``telemetry.snapshot()`` /
   ``metrics_<rank>.json``): the analytic counters ``domain.exchange.bytes``,
-  ``exchange.packed.bytes``, ``kernel.mxu.flops``;
+  ``exchange.packed.bytes``;
 * a **device-time attribution** (``telemetry/device.py``): measured device
   microseconds per phase from a ``jax.profiler`` capture, or — when no
   profiler backend exists — host span durations as a degraded stand-in
@@ -48,9 +48,6 @@ PEAKS: Dict[str, dict] = {
 PHASE_BYTES_COUNTERS = {
     "exchange": names.EXCHANGE_BYTES,
     "pack": names.EXCHANGE_PACKED_BYTES,
-}
-PHASE_FLOPS_COUNTERS = {
-    "mxu": names.KERNEL_MXU_FLOPS,
 }
 
 
@@ -93,9 +90,8 @@ def roofline_report(
     (``telemetry.device.attribute_device_time``; a host-span fallback uses
     the same shape with ``source="host"``).  Phases carrying an analytic
     bytes counter report achieved GB/s and their fraction of the HBM
-    roofline; the ``mxu`` phase reports GFLOP/s vs the MXU peak; scope
-    phases with no counter (interior/exterior) report time and their share
-    of total device time — the overlap-efficiency inputs.
+    roofline; scope phases with no counter (interior/exterior) report time
+    and their share of total device time — the overlap-efficiency inputs.
 
     ``counters_scope`` records what window the counters cover, because the
     join is only honest when numerator and denominator cover the SAME
@@ -122,8 +118,6 @@ def roofline_report(
             "share_of_device": round(us / total_us, 4) if total_us else None,
             "bytes": None,
             "gbps": None,
-            "flops": None,
-            "gflops": None,
             "frac_of_roofline": None,
         }
         bc = PHASE_BYTES_COUNTERS.get(phase)
@@ -136,17 +130,6 @@ def roofline_report(
                     if peaks["hbm_gbps"]:
                         entry["frac_of_roofline"] = round(
                             entry["gbps"] / peaks["hbm_gbps"], 4
-                        )
-        fc = PHASE_FLOPS_COUNTERS.get(phase)
-        if fc is not None:
-            fl = counters.get(fc)
-            if fl:
-                entry["flops"] = int(fl)
-                if s > 0:
-                    entry["gflops"] = round(fl / s / 1e9, 3)
-                    if peaks["mxu_gflops_bf16"]:
-                        entry["frac_of_roofline"] = round(
-                            entry["gflops"] / peaks["mxu_gflops_bf16"], 4
                         )
         phases[phase] = entry
     return {
@@ -281,8 +264,8 @@ def render_markdown(report: dict) -> str:
         f"- total device time: {report.get('total_device_ms')} ms "
         f"(unattributed {report.get('unattributed_device_ms')} ms)",
         "",
-        "| phase | device ms | events | share | GB/s | GFLOP/s | % of roofline |",
-        "|---|---|---|---|---|---|---|",
+        "| phase | device ms | events | share | GB/s | % of roofline |",
+        "|---|---|---|---|---|---|",
     ]
     for phase in sorted(report.get("phases", {})):
         e = report["phases"][phase]
@@ -290,7 +273,6 @@ def render_markdown(report: dict) -> str:
         lines.append(
             f"| `{phase}` | {e['device_ms']} | {e['events']} | "
             f"{e.get('share_of_device')} | {e.get('gbps') or ''} | "
-            f"{e.get('gflops') or ''} | "
             f"{f'{100 * frac:.1f}%' if frac is not None else ''} |"
         )
     lines.append("")

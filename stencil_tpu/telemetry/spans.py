@@ -118,7 +118,7 @@ class SpanRecorder:
         self._events: List[dict] = []
         #: (ts_us, series, value) counter samples — rendered as Chrome
         #: counter-track ("ph":"C") events so Perfetto shows cumulative
-        #: exchange bytes / MXU flops as a throughput track under the spans
+        #: exchange bytes as a throughput track under the spans
         self._counter_samples: List[tuple] = []
         self._counter_last: dict = {}
         self._tls = threading.local()

@@ -11,7 +11,8 @@ Layout: ``<cache_dir>/<key.digest()>.json`` with
 ``load`` returns ``(config, meta)`` only when the schema AND the jax/jaxlib
 versions match the running process — a toolchain upgrade silently
 invalidates every persisted config (PERF_NOTES.md: "re-qualify them when
-the toolchain or chip generation changes"), exactly like a cold cache.  A
+the toolchain or chip generation changes"), exactly like a cold cache —
+and the config names no kernel form that has since been removed.  A
 corrupt or truncated file is treated as a miss (warn, never crash): the
 cache is an accelerator, not a dependency.
 
@@ -98,7 +99,21 @@ def load(key: WorkloadKey) -> Optional[Tuple[dict, dict]]:
             "configs must be re-qualified on this toolchain — treating as a miss"
         )
         return None
-    return doc["config"], doc.get("meta") or {}
+    config = doc["config"]
+    # the stale-record rule of the removed matrix-unit axis pair: a depth
+    # measured under another kernel form is not this kernel's depth.  Entries
+    # naming the one value that stayed (vpu / f32), or none, stay warm.
+    unit, operand = config.get("compute_unit"), config.get("mxu_input")
+    if unit not in (None, "vpu") or operand not in (None, "f32"):
+        from stencil_tpu.utils.logging import log_warn
+
+        log_warn(
+            f"tune cache {path} was measured on a kernel form that no longer "
+            f"exists (compute_unit={unit!r}, mxu_input={operand!r}); "
+            "treating as a miss"
+        )
+        return None
+    return config, doc.get("meta") or {}
 
 
 def store(key: WorkloadKey, config: dict, meta: Optional[dict] = None) -> str:

@@ -62,17 +62,12 @@ def autotune_jacobi_wrap(
 
     def build_run(cand):
         storage = cand.get("storage_dtype", "native")
-        unit = cand.get("compute_unit", "vpu")
         bdt = jnp.bfloat16 if storage == "bf16" else dtype
         if storage not in state:
             state[storage] = jnp.full((x, y, z), 0.5, bdt)
         block = state[storage]
         k = cand["k"]
-        kern_kw = {
-            "compute_unit": unit,
-            "f32_accumulate": storage == "bf16",
-            "mxu_input": cand.get("mxu_input", "f32"),
-        }
+        f32_acc = storage == "bf16"
 
         @partial(jax.jit, static_argnums=1)
         def steps(b, n):
@@ -82,12 +77,14 @@ def autotune_jacobi_wrap(
                     0,
                     blocked,
                     lambda _, bb: jacobi_wrap_step(
-                        bb, interpret=interpret, k=k, **kern_kw
+                        bb, interpret=interpret, k=k, f32_accumulate=f32_acc
                     ),
                     b,
                 )
             if rem:
-                b = jacobi_wrap_step(b, interpret=interpret, k=rem, **kern_kw)
+                b = jacobi_wrap_step(
+                    b, interpret=interpret, k=rem, f32_accumulate=f32_acc
+                )
             return b
 
         def run(n):
@@ -100,11 +97,7 @@ def autotune_jacobi_wrap(
         candidates,
         build_run,
         depth_key="k",
-        static={
-            "k": static_k,
-            "compute_unit": "vpu",
-            "storage_dtype": "native",
-        },
+        static={"k": static_k, "storage_dtype": "native"},
         reps=reps,
         rt=rt,
         prefiltered=prefiltered,
@@ -138,7 +131,7 @@ def autotune_jacobi_wavefront(
     dtype = jnp.dtype(dtype or jnp.float32)
 
     def make_model(temporal_k="auto", alias=None, z_ring=None,
-                   compute_unit=None, storage_dtype=None, mxu_input=None):
+                   storage_dtype=None):
         kwargs = {} if strategy is None else {"strategy": strategy}
         return Jacobi3D(
             x,
@@ -152,9 +145,7 @@ def autotune_jacobi_wavefront(
             interpret=interpret,
             wavefront_alias=alias,
             z_ring=z_ring,
-            compute_unit=compute_unit,
             storage_dtype=storage_dtype,
-            mxu_input=mxu_input,
             **kwargs,
         )
 
@@ -168,23 +159,8 @@ def autotune_jacobi_wavefront(
         getattr(probe, "_wavefront_z_planned", False)
         and info["n"][2] % 128 == 0
     )
-    from stencil_tpu.ops.jacobi_pallas import (
-        band_tile_plan,
-        bf16_supported,
-        mxu_supported,
-    )
+    from stencil_tpu.ops.jacobi_pallas import bf16_supported
 
-    # the band variant needs a tilable plane geometry — the geometry the
-    # kernel CONTRACTS (lane-padded under the z-slab route), not the bare
-    # raw extent: a ragged raw width that pads to a 128 multiple tiles
-    # fine, and prefiltering on the raw dims would drop the band twins
-    # from exactly the large padded geometries they were built to win on
-    from stencil_tpu.ops.stream import lane_pad_width
-
-    n = info["n"]
-    _band_pz = n[2] + 2 * static_m
-    if getattr(probe, "_wavefront_z_planned", False):
-        _band_pz = lane_pad_width(_band_pz)
     candidates, prefiltered = space.jacobi_wavefront_space(
         static_m,
         # structural caps only (a shard must fill an m-wide halo from valid
@@ -195,18 +171,14 @@ def autotune_jacobi_wavefront(
         z_ring_eligible=z_ring_eligible,
         static_z_ring=True,
         ms=ms,
-        mxu_ok=mxu_supported([dtype]),
         bf16_ok=bf16_supported([dtype]),
-        band_ok=band_tile_plan(n[1] + 2 * static_m, _band_pz) is not None,
     )
     models = {}
 
     def build_run(cand):
         model = make_model(
             temporal_k=cand["m"], alias=cand["alias"], z_ring=cand.get("z_ring"),
-            compute_unit=cand.get("compute_unit"),
             storage_dtype=cand.get("storage_dtype"),
-            mxu_input=cand.get("mxu_input"),
         )
         model.realize()
         models[space.candidate_label(cand)] = model  # keep resident
@@ -227,7 +199,6 @@ def autotune_jacobi_wavefront(
             "halo_multiplier": static_m,
             "alias": False,
             "z_ring": z_ring_eligible,
-            "compute_unit": "vpu",
             "storage_dtype": "native",
         },
         reps=reps,
@@ -297,30 +268,22 @@ def autotune_stream(
     interpret: bool = False,
     reps: int = 3,
     rt: Optional[float] = None,
-    mxu_kernel=None,
 ) -> TuneReport:
     """Tune the generic stream engine's plan (route, depth, alias, overlap,
-    fused halo, compute unit) for a REALIZED domain + user kernel.  Trials run
+    fused halo) for a REALIZED domain + user kernel.  Trials run
     non-donating steps over the
     domain's live buffers (the domain state is never advanced), so the
     tuned plan feeds the very next ``make_step(engine="stream")`` on the
-    same process via the cache.  ``mxu_kernel`` is the kernel's declared
-    contraction form — without it the compute-unit A/B is structurally
-    prefiltered (an mxu candidate could only degrade to vpu and measure a
-    duplicate)."""
+    same process via the cache."""
     import jax
 
-    from stencil_tpu.ops.jacobi_pallas import mxu_supported
     from stencil_tpu.ops.stream import _build_stream_step, plan_stream
 
     key = dd.tune_key("stream")
     with tune.disabled():
         static_plan = plan_stream(dd, x_radius, "auto", separable)
-    mxu_ok = mxu_kernel is not None and mxu_supported(
-        [h.dtype for h in dd._handles]
-    )
     candidates, prefiltered = space.stream_space(
-        dd, x_radius, separable, static_plan, mxu_ok=mxu_ok
+        dd, x_radius, separable, static_plan
     )
 
     def build_run(cand):
@@ -338,12 +301,8 @@ def autotune_stream(
         if "halo" in plan:
             # and for the fused-halo A/B under STENCIL_STREAM_HALO
             plan["halo_forced"] = True
-        if "compute_unit" in plan:
-            # and for the compute-unit A/B under STENCIL_COMPUTE_UNIT
-            plan["compute_unit_forced"] = True
         step = _build_stream_step(
-            dd, kernel, x_radius, plan, interpret, donate=False,
-            mxu_kernel=mxu_kernel,
+            dd, kernel, x_radius, plan, interpret, donate=False
         )
 
         def run(n):
@@ -355,7 +314,6 @@ def autotune_stream(
     static.setdefault("halo_multiplier", static.get("m", 1))
     static.setdefault("overlap", "off")
     static.setdefault("halo", "array")
-    static.setdefault("compute_unit", "vpu")
     return tune.ensure(
         key,
         candidates,

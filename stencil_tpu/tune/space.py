@@ -31,12 +31,6 @@ Axes (ISSUE: the constants PERF_NOTES.md says to re-qualify per chip):
   working planes and the big array never sees a halo write; ``array`` is
   the static fallback — the win trades the saved unpack/blend dispatches
   against per-plane patch selects, so it is measured, not assumed.
-* **compute unit** (vpu/mxu) — the level kernels' execution unit
-  (ops/jacobi_pallas ``COMPUTE_UNITS``): the roll+add chain on the vector
-  lanes vs one banded contraction per in-plane axis on the matrix unit —
-  the "Break the VPU wall" lever (PERF_NOTES "VPU wall": the k≈12-24
-  plateau is roll+add-bound, not DMA).  ``vpu`` is the static fallback;
-  mxu candidates are structurally prefiltered to f32-compute plans.
 * **storage dtype** (native/bf16) — bf16 field buffers with f32
   accumulation in-kernel, halving bytes/cell on the DMA-bound shallow-k
   paths; ``native`` is the static fallback, bf16 prefiltered to f32
@@ -117,22 +111,16 @@ def jacobi_wrap_space(
     dtype=None,
 ) -> Tuple[List[dict], int]:
     """(candidates, prefiltered_count) over the wrap kernel's temporal depth
-    ``k`` plus, at the static depth, the compute-unit and storage-dtype
-    A/Bs (one twin each, like the wavefront space's z-ring pair — the axes
-    are independent of depth to first order, so one pair per search
-    re-qualifies them cheaply).  Structural prefilters: ``mxu`` only for
-    f32-compute plans, ``bf16`` only for f32 fields — filtered twins count
-    into ``tune.pruned`` without burning a trial.  ``ks`` overrides the
-    depth grid (tests / narrow re-qualification); ``dtype`` (default f32)
-    drives the axis prefilters."""
+    ``k`` plus, at the static depth, the storage-dtype A/B (one twin, like
+    the wavefront space's z-ring pair — the axis is independent of depth to
+    first order, so one pair per search re-qualifies it cheaply).
+    Structural prefilter: ``bf16`` only for f32 fields — a filtered twin
+    counts into ``tune.pruned`` without burning a trial.  ``ks`` overrides
+    the depth grid (tests / narrow re-qualification); ``dtype`` (default
+    f32) drives the axis prefilter."""
     import jax.numpy as jnp
 
-    from stencil_tpu.ops.jacobi_pallas import (
-        band_tile_plan,
-        bf16_supported,
-        mxu_supported,
-        wavefront_vmem_fits,
-    )
+    from stencil_tpu.ops.jacobi_pallas import bf16_supported, wavefront_vmem_fits
 
     dtype = jnp.dtype(dtype or jnp.float32)
     X, Y, Z = shape
@@ -143,51 +131,20 @@ def jacobi_wrap_space(
         # the static pick always runs (it IS the fallback being defended);
         # other depths must pass the VMEM model to be worth a compile
         if k == static_k or wavefront_vmem_fits(k, Y, Z, itemsize):
-            kept.append(
-                {"k": k, "compute_unit": "vpu", "storage_dtype": "native"}
-            )
+            kept.append({"k": k, "storage_dtype": "native"})
         else:
             prefiltered += 1
-    # the axis A/Bs at the static depth (persisted winners carry the axes
-    # explicitly; pre-axis cache entries without the fields stay warm —
-    # absent = the static vpu/native/f32, no schema bump).  Unlike the
-    # static pick itself the twins are NOT the defended fallback, so they
-    # must pass the VMEM model — with the resolved variant's resident
-    # contraction constants / bf16's narrow pipeline planes over an f32
-    # level ring folded in.
-    if mxu_supported([dtype]) and wavefront_vmem_fits(
-        static_k, Y, Z, itemsize, mxu=True
-    ):
-        kept.append(
-            {"k": static_k, "compute_unit": "mxu", "storage_dtype": "native"}
-        )
-    else:
-        prefiltered += 1
-    # the band-tiled variant twin + its bf16-INPUT leg (the doubled-ratio
-    # arm of the "VPU wall" break-even model) — prefiltered when the plane
-    # geometry admits no tiling (the kernel would just re-measure dense)
-    if (
-        mxu_supported([dtype])
-        and band_tile_plan(Y, Z) is not None
-        and wavefront_vmem_fits(static_k, Y, Z, itemsize, mxu="mxu_band")
-    ):
-        kept.append(
-            {"k": static_k, "compute_unit": "mxu_band",
-             "storage_dtype": "native"}
-        )
-        kept.append(
-            {"k": static_k, "compute_unit": "mxu_band",
-             "storage_dtype": "native", "mxu_input": "bf16"}
-        )
-    else:
-        prefiltered += 2
+    # the storage A/B at the static depth (persisted winners carry the axis
+    # explicitly; pre-axis cache entries without the field stay warm —
+    # absent = the static native, no schema bump).  Unlike the static pick
+    # itself the twin is NOT the defended fallback, so it must pass the VMEM
+    # model — with bf16's narrow pipeline planes over an f32 level ring
+    # folded in.
     if bf16_supported([dtype]) and wavefront_vmem_fits(
         static_k, Y, Z, jnp.dtype(jnp.bfloat16).itemsize,
         ring_itemsize=itemsize,
     ):
-        kept.append(
-            {"k": static_k, "compute_unit": "vpu", "storage_dtype": "bf16"}
-        )
+        kept.append({"k": static_k, "storage_dtype": "bf16"})
     else:
         prefiltered += 1
     return kept, prefiltered
@@ -199,28 +156,24 @@ def jacobi_wavefront_space(
     z_ring_eligible: bool,
     static_z_ring: bool,
     ms=None,
-    mxu_ok: bool = False,
     bf16_ok: bool = False,
-    band_ok: bool = False,
 ) -> Tuple[List[dict], int]:
     """(candidates, prefiltered) over the multi-device wavefront: depth ``m``
     (== the halo multiplier: the m-wide shell is exchanged every m steps),
     alias on/off, and — at the static depth — z-ring vs padded layout plus
-    the compute-unit / storage-dtype A/Bs (``mxu_ok`` / ``bf16_ok`` /
-    ``band_ok`` are the structural prefilters the caller evaluates: f32
-    compute / f32 fields / a band-tilable raw plane geometry).
+    the storage-dtype A/B (``bf16_ok`` is the structural prefilter the
+    caller evaluates: f32 fields).
     ``depth_cap`` is the structural bound (shard/valid extents)."""
     grid = sorted({static_m, *(ms if ms is not None else _DEPTH_GRID)})
     grid = [m for m in grid if 1 <= m <= depth_cap]
     cands: List[dict] = []
 
-    def cand(m, alias, z_ring, unit="vpu", storage="native"):
+    def cand(m, alias, z_ring, storage="native"):
         return {
             "m": m,
             "halo_multiplier": m,
             "alias": alias,
             "z_ring": z_ring,
-            "compute_unit": unit,
             "storage_dtype": storage,
         }
 
@@ -233,19 +186,7 @@ def jacobi_wavefront_space(
         cands.append(cand(static_m, False, not static_z_ring))
     static_ring = static_z_ring and z_ring_eligible
     prefiltered = 0
-    # the new-axis A/Bs at the static depth (one twin each, like z-ring)
-    if mxu_ok:
-        cands.append(cand(static_m, False, static_ring, unit="mxu"))
-    else:
-        prefiltered += 1
-    # the band-tiled variant twin + its bf16-input leg
-    if mxu_ok and band_ok:
-        cands.append(cand(static_m, False, static_ring, unit="mxu_band"))
-        c = cand(static_m, False, static_ring, unit="mxu_band")
-        c["mxu_input"] = "bf16"
-        cands.append(c)
-    else:
-        prefiltered += 2
+    # the storage A/B at the static depth (one twin, like z-ring)
     if bf16_ok:
         cands.append(cand(static_m, False, static_ring, storage="bf16"))
     else:
@@ -296,25 +237,22 @@ def exchange_space(dd) -> Tuple[List[dict], int]:
     return cands, prefiltered
 
 
-def stream_space(dd, x_radius: int, separable: bool, static_plan: dict,
-                 mxu_ok: bool = False) -> Tuple[List[dict], int]:
+def stream_space(dd, x_radius: int, separable: bool,
+                 static_plan: dict) -> Tuple[List[dict], int]:
     """(candidates, prefiltered) of full stream-engine plans around the
     static pick: the static plan, its shallower depths, the alias flip, the
-    plane route as the m=1 structural baseline, the split-step overlap
+    plane route as the m=1 structural baseline, and the split-step overlap
     A/B (``overlap ∈ {off, split}``, ops/stream.py — the interior pass
-    dispatched with no ppermute dependency), and the compute-unit A/B
-    (``compute_unit ∈ {vpu, mxu}`` — the banded-contraction form; only when
-    ``mxu_ok``: the kernel declares an mxu form AND computes at f32).
+    dispatched with no ppermute dependency).
     Every candidate is a plan dict ``_build_stream_step`` accepts verbatim
-    (+ ``alias``/``overlap``/``compute_unit``).
+    (+ ``alias``/``overlap``).
 
-    Every candidate carries explicit ``overlap``, ``halo``, and
-    ``compute_unit`` fields ("off"/"array"/"vpu" unless it IS that axis's
-    twin) so persisted winners record the axes — while older entries
-    WITHOUT the fields stay consultable (absent = the static
-    off/array/vpu, ops/stream.py ``_overlap_request`` /
-    ``_halo_request`` / the compute-unit resolver); no cache schema
-    bump.  The split twin of a z-slab wavefront re-plans to the plain form
+    Every candidate carries explicit ``overlap`` and ``halo`` fields
+    ("off"/"array" unless it IS that axis's twin) so persisted winners
+    record the axes — while older entries WITHOUT the fields stay
+    consultable (absent = the static off/array, ops/stream.py
+    ``_overlap_request`` / ``_halo_request``); no cache schema bump.  The
+    split twin of a z-slab wavefront re-plans to the plain form
     (``plain_wavefront_plan``): split needs z halos in the big array for
     the exchange it overlaps.  The fused-halo twin (``halo="fused"`` —
     the packed messages land in the pass's level-0 VMEM planes,
@@ -331,13 +269,12 @@ def stream_space(dd, x_radius: int, separable: bool, static_plan: dict,
     cands: List[dict] = []
 
     def add(plan: dict, alias: Optional[bool], overlap: str = "off",
-            unit: str = "vpu", halo: str = "array") -> None:
+            halo: str = "array") -> None:
         c = dict(plan)
         if alias is not None:
             c["alias"] = alias
         c["overlap"] = overlap
         c["halo"] = halo
-        c["compute_unit"] = unit
         c.setdefault("halo_multiplier", c.get("m", 1))
         if c not in cands:
             cands.append(c)
@@ -400,40 +337,14 @@ def stream_space(dd, x_radius: int, separable: bool, static_plan: dict,
         add(b, static_alias, halo="fused")
     else:
         prefiltered += 1
-    # the compute-unit A/B: an mxu twin of the static plan, measured against
-    # its vpu sibling under the same protocol (the "Break the VPU wall"
-    # lever — the win depends on where the plan sits relative to the
-    # roll+add wall, so it is measured, not assumed), plus the band-tiled
-    # variant twin when the raw plane geometry tiles (band_tile_plan) —
-    # pre-variant cache entries (compute_unit="mxu" winners) stay warm:
-    # the value keeps its meaning and absent mxu_input = the static f32
-    if mxu_ok:
-        from stencil_tpu.ops.jacobi_pallas import band_tile_plan
-
-        b = {
-            k: v
-            for k, v in static_plan.items()
-            if k not in ("overlap", "halo_multiplier", "compute_unit")
-        }
-        add(b, static_alias if static_plan["route"] != "wrap" else None,
-            unit="mxu")
-        raw = dd.local_spec().raw_size()
-        if band_tile_plan(raw.y, raw.z) is not None:
-            add(b, static_alias if static_plan["route"] != "wrap" else None,
-                unit="mxu_band")
-        else:
-            prefiltered += 1
-    else:
-        prefiltered += 2
     # static verdicts: candidates whose MODELED footprint busts the
     # scoped-VMEM budget (analysis/vmem.py), or whose kernels the Mosaic
     # legality model rejects (analysis/kernels.py — x64 index arithmetic,
     # rotate operand width, sub-granule block windows), are pruned here,
     # before the search pays a compile-and-catch VMEM_OOM/COMPILE_REJECT
-    # for them.  plan_stream already depth-gates the vpu plans through the
+    # for them.  plan_stream already depth-gates its plans through the
     # VMEM model, so that leg mostly catches the twins the planner never
-    # modeled — the mxu twin's resident band matrices foremost.  The
-    # static pick always survives (it IS the no-tune fallback being
+    # modeled.  The static pick always survives (it IS the no-tune fallback being
     # defended), matching the wrap space's rule.
     from stencil_tpu.analysis import check_kernel_legal, check_vmem
 
@@ -444,7 +355,6 @@ def stream_space(dd, x_radius: int, separable: bool, static_plan: dict,
                 if k not in ("halo_multiplier", "alias"))
             and c.get("overlap", "off") == "off"
             and c.get("halo", "array") == "array"
-            and c.get("compute_unit", "vpu") == "vpu"
         )
         if not is_static and (
             check_vmem(dd, c) is not None

@@ -1,6 +1,6 @@
 # analysis-fixture: contract=accum-dtype expect=clean
 """The sanctioned contraction: bf16 storage, explicit f32 accumulation
-(the MXU band-contraction contract)."""
+(the bf16-storage / f32-accumulate contract)."""
 
 import jax
 import jax.experimental.pallas as pl

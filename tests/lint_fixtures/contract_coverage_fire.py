@@ -13,5 +13,5 @@ def _experimental():
 
 
 # stencil-lint: disable=contract-coverage fixture: prototype vocabulary behind a feature gate, matrix entry lands with the route PR
-COMPUTE_UNITS = ("vpu", "mxu", "sc")
+STORAGE_DTYPES = ("native", "bf16", "fp8")
 # stencil-lint: disable=contract-coverage

@@ -1,0 +1,217 @@
+"""Program fingerprints: a hash of what a traced program IS, blind to where
+its source lines sit.
+
+The text that is hashed holds the whole closed jaxpr (every equation with
+its name stack, so every ``exchange.*`` / ``step.*`` scope and every inner
+jaxpr of a ``pallas_call``) and, for each ``pallas_call`` in program order,
+what the pretty-printer abbreviates: the kernel name, the grid, every
+``BlockSpec`` (block shape, array, index map, pipeline mode), the scratch
+shapes, the aliases and the compiler parameters.  File paths, line numbers
+and object addresses are dropped, so moving code moves no fingerprint; a
+changed kernel body, grid, block, alias, name or scope does.
+
+Goldens live in ``tests/data/program_fingerprints.json``; the one command
+that rewrites them, after a change that is MEANT to change a program:
+
+    python tests/program_fingerprint.py --write
+
+(``tests/test_analysis.py::test_program_fingerprint`` holds every entry.)
+"""
+
+from __future__ import annotations
+
+import hashlib
+import json
+import os
+import re
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+GOLDEN_PATH = os.path.join(HERE, "data", "program_fingerprints.json")
+
+#: raw steps a model's step program is traced for: two depth-3 macros and a
+#: remainder on astaroth, a remainder under every temporal depth
+MODEL_STEPS = 7
+
+_PATH = re.compile(r"(?:[\w.\-]*/)+[\w.\-]+\.py(?::\d+)*")
+_ADDR = re.compile(r"0x[0-9a-fA-F]+")
+_SPACE = re.compile(r"\s+")
+_SET = re.compile(r"frozenset\(\{([^{}]*)\}\)")  # printed in hash order: sort
+
+
+def _sub_jaxprs(value):
+    items = value if isinstance(value, (list, tuple)) else (value,)
+    for item in items:
+        inner = getattr(item, "jaxpr", item)  # ClosedJaxpr -> Jaxpr
+        if hasattr(inner, "eqns"):
+            yield inner
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            for inner in _sub_jaxprs(value):
+                yield from _pallas_calls(inner)
+
+
+def _pallas_detail(eqn) -> str:
+    p = eqn.params
+    gm = p["grid_mapping"]
+    lines = [
+        f"pallas_call name={p.get('name')} scope={eqn.source_info.name_stack}",
+        f" grid={gm.grid} grid_names={gm.grid_names} vmapped={gm.vmapped_dims}",
+        f" inputs={gm.num_inputs} outputs={gm.num_outputs} "
+        f"index_operands={gm.num_index_operands} scratch={gm.scratch_avals}",
+        f" aliases={p.get('input_output_aliases')} "
+        f"compiler_params={p.get('compiler_params')} out={p.get('out_avals')}",
+    ]
+    for bm in gm.block_mappings:
+        lines.append(
+            f" block {bm.origin} {bm.block_shape} of {bm.array_aval} "
+            f"pipeline={bm.pipeline_mode} transforms={bm.transforms} index_map="
+            + str(bm.index_map_jaxpr.pretty_print(source_info=False))
+        )
+    return "\n".join(lines)
+
+
+def fingerprint_text(closed) -> str:
+    """The normalised text of a ClosedJaxpr that the fingerprint hashes."""
+    jaxpr = closed.jaxpr
+    parts = [str(jaxpr.pretty_print(source_info=False, name_stack=True))]
+    parts += [_pallas_detail(e) for e in _pallas_calls(jaxpr)]
+    text = _ADDR.sub("0x", _PATH.sub("<src>", "\n".join(parts)))
+    text = _SET.sub(lambda m: "{" + ", ".join(sorted(m.group(1).split(", "))) + "}", text)
+    return _SPACE.sub(" ", text)
+
+
+def fingerprint(closed) -> str:
+    return hashlib.sha256(fingerprint_text(closed).encode()).hexdigest()
+
+
+# --- the five benchmark configurations, as their models build them ----------
+
+
+def _trace_step(dd, step):
+    """The program ``dd.run_step(step, MODEL_STEPS)`` dispatches: the current
+    rung of a ladder-wrapped step, else the step itself."""
+    import jax
+
+    ladder = getattr(step, "_resilience", None)
+    fn = ladder.built() if ladder is not None else step
+    return jax.make_jaxpr(fn, static_argnums=1)(dd._curr, MODEL_STEPS)
+
+
+def _jacobi_wrap():
+    import jax
+
+    from stencil_tpu.models.jacobi import Jacobi3D
+
+    m = Jacobi3D(16, 16, 16, kernel_impl="pallas", interpret=True,
+                 devices=jax.devices()[:1])
+    m.realize()
+    assert m._pallas_path == "wrap", m._pallas_path
+    return _trace_step(m.dd, m._step)
+
+
+def _jacobi_zring():
+    import jax
+
+    from stencil_tpu.models.jacobi import Jacobi3D
+
+    m = Jacobi3D(32, 32, 128, kernel_impl="pallas", interpret=True,
+                 devices=jax.devices()[:4])
+    m.dd.set_partition(2, 2, 1)
+    m.realize()
+    assert m._pallas_path == "wavefront" and m._wavefront_z_ring
+    return _trace_step(m.dd, m._step)
+
+
+def _astaroth():
+    import jax
+
+    from stencil_tpu.models.astaroth import AstarothSim
+
+    s = AstarothSim(16, 16, 16, num_quantities=8, kernel_impl="pallas",
+                    schedule="wavefront", interpret=True,
+                    devices=jax.devices()[:1])
+    s.realize()
+    plan = s._step._stream_plan
+    assert (plan["route"], plan["m"]) == ("wavefront", 3), plan
+    return _trace_step(s.dd, s._step)
+
+
+def _weak_exchange():
+    import jax
+
+    from stencil_tpu import DistributedDomain, Radius
+
+    dd = DistributedDomain(32, 32, 16)
+    dd.set_radius(Radius.constant(3))
+    dd.set_devices(jax.devices()[:4])
+    dd.set_partition(2, 2, 1)
+    for i in range(4):
+        dd.add_data(f"q{i}")
+    dd.realize()
+    assert dd.exchange_route() == "direct"
+    return jax.make_jaxpr(dd.make_exchange_route_fn("direct", donate=False))(dd._curr)
+
+
+def _acoustic():
+    import jax
+
+    from stencil_tpu.models.acoustic import AcousticWave
+
+    s = AcousticWave(24, 24, 24, nbl=4, interpret=True, devices=jax.devices()[:1])
+    s.realize()
+    args = s._step._span_args()
+    assert (args["route"], args["x_radius"]) == ("plane", 4), args
+    return _trace_step(s.dd, s._step)
+
+
+#: label -> builder of the ClosedJaxpr, at a CPU size under interpret
+MODEL_PROGRAMS = {
+    "model:jacobi3d-512/wrap": _jacobi_wrap,
+    "model:jacobi3d-512x4/zring-wavefront": _jacobi_zring,
+    "model:astaroth-8q-512/wavefront-m3": _astaroth,
+    "model:weak-r3-512x4/exchange-direct": _weak_exchange,
+    "model:acoustic-so8-600/plane-r4": _acoustic,
+}
+
+
+def labels() -> list:
+    from stencil_tpu.analysis import programs as aprog
+
+    return [s.label for s in aprog.CANONICAL_PROGRAMS] + list(MODEL_PROGRAMS)
+
+
+def program_fingerprint(label: str) -> str:
+    from stencil_tpu.analysis import programs as aprog
+
+    if label in MODEL_PROGRAMS:
+        with aprog.tpu_shaped_trace():
+            return fingerprint(MODEL_PROGRAMS[label]())
+    return fingerprint(aprog.build_matrix([label])[0].closed)
+
+
+def load_goldens() -> dict:
+    with open(GOLDEN_PATH) as f:
+        return json.load(f)
+
+
+if __name__ == "__main__":
+    sys.path[:0] = [HERE, os.path.dirname(HERE)]
+    import conftest  # noqa: F401  the fake 8-chip CPU fleet, x64 as the tests have it
+
+    got = {label: program_fingerprint(label) for label in labels()}
+    if "--write" in sys.argv[1:]:
+        with open(GOLDEN_PATH, "w") as f:
+            json.dump(got, f, indent=1, sort_keys=True)
+            f.write("\n")
+        print(f"wrote {len(got)} fingerprints to {GOLDEN_PATH}")
+    else:
+        want = load_goldens()
+        bad = sorted(k for k in set(got) | set(want) if got.get(k) != want.get(k))
+        print(f"{len(got) - len(bad)} of {len(got)} hold" + (f"; differ: {bad}" if bad else ""))
+        sys.exit(1 if bad else 0)

@@ -1,7 +1,7 @@
 """Tier-1: the program-contract verifier (``stencil_tpu.analysis``).
 
 The tentpole gate: every registered contract over the whole canonical
-route × overlap × compute-unit × storage-dtype matrix of REALLY built
+route × overlap × halo × storage-dtype matrix of REALLY built
 programs (interpret/CPU mode) — plus the fixture corpus proving each
 contract fires on a seeded violation and stays quiet on the sanctioned
 pattern, the coverage-ledger pins (axis matrix AND pallas-kernel ledger),
@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import program_fingerprint as pfp
 from stencil_tpu import analysis
 from stencil_tpu.analysis import jaxpr as jx
 from stencil_tpu.analysis import programs as aprog
@@ -62,6 +63,20 @@ def test_canonical_programs_verify():
     assert len(artifacts) == len(aprog.CANONICAL_PROGRAMS)
     findings = analysis.check_artifacts(artifacts)
     assert not findings, "\n".join(f.render() for f in findings)
+
+
+@pytest.mark.parametrize("label", pfp.labels())
+def test_program_fingerprint(label):
+    """Every canonical program (the matrix the gate above built once, shared
+    through ``build_program``'s memo) and the step or exchange program of each
+    benchmark configuration, as its model builds it at a CPU size: the traced
+    program is the recorded one — kernel bodies, grids, BlockSpecs, aliases,
+    kernel names and scopes — wherever its source lines now sit.  A change
+    that MEANS to change a program regenerates the goldens
+    (``python tests/program_fingerprint.py --write``) and says which."""
+    assert pfp.program_fingerprint(label) == pfp.load_goldens().get(label), (
+        f"{label}: the traced program is not the recorded one"
+    )
 
 
 def test_registry_matches_matrix():
@@ -307,7 +322,7 @@ def tune_dir(tmp_path, monkeypatch):
     tune.reset_memo()
 
 
-def _mk_dd(nq=1):
+def _mk_dd(nq=1, exchange_route=None):
     from stencil_tpu.core.radius import Radius
     from stencil_tpu.domain import DistributedDomain
 
@@ -315,6 +330,8 @@ def _mk_dd(nq=1):
     dd.set_radius(Radius.constant(1))
     dd.set_devices(jax.devices()[:8])
     dd.set_halo_multiplier(2)
+    if exchange_route is not None:
+        dd.set_exchange_route(exchange_route)
     hs = [dd.add_data(f"q{i}") for i in range(nq)]
     dd.realize()
     for i, h in enumerate(hs):
@@ -324,52 +341,26 @@ def _mk_dd(nq=1):
     return dd
 
 
-def _mxu_straddling_budget(dd, static_plan):
-    """A scoped-VMEM budget that admits every vpu-plan footprint of the
-    space but rejects the mxu twin (whose resident band matrices the
-    stream planner never modeled) — computed from the same model, so the
-    pin cannot rot with recalibration."""
-    base = {k: v for k, v in static_plan.items() if k != "halo_multiplier"}
-    vpu = dict(base)
-    mxu = dict(base, compute_unit="mxu")
-    est_vpu = avmem.check_vmem  # noqa: F841  (documented entry point)
+def _fused_straddling_budget(dd, static_plan):
+    """A scoped-VMEM budget that admits every array-halo footprint of the
+    space but rejects the fused-halo twin (whose side-buffer blocks the
+    stream planner's depth gate never modeled) — computed from the same
+    model, so the pin cannot rot with recalibration."""
+    from stencil_tpu.ops.stream import plain_wavefront_plan
+
     raw = dd.local_spec().raw_size()
     sizes = [dd.field_dtype(h).itemsize for h in dd._handles]
-    e_vpu = avmem.stream_plan_vmem_bytes(
-        base["m"], raw.y, raw.z, sizes, z_slabs=bool(base.get("z_slabs"))
+    e_static = avmem.stream_plan_vmem_bytes(
+        static_plan["m"], raw.y, raw.z, sizes,
+        z_slabs=bool(static_plan.get("z_slabs")),
     )
-    e_mxu = avmem.stream_plan_vmem_bytes(
-        base["m"], raw.y, raw.z, sizes, z_slabs=bool(base.get("z_slabs")),
-        mxu=True,
+    plain = plain_wavefront_plan(dd, static_plan) or static_plan
+    e_fused = avmem.stream_plan_vmem_bytes(
+        plain["m"], raw.y, raw.z, sizes, fused=True
     )
-    assert e_mxu > e_vpu
+    assert e_fused > e_static
     _, margin = avmem.budget_and_margin(len(sizes))
-    return (e_vpu + e_mxu) // 2 + margin, vpu, mxu
-
-
-def test_stream_space_prunes_mxu_twin_statically(monkeypatch, tune_dir):
-    """tune/space.py consults analysis.check_vmem: the over-budget mxu twin
-    never enters the candidate list (it counts into ``prefiltered``), while
-    the static plan and its vpu siblings survive."""
-    from stencil_tpu import tune
-    from stencil_tpu.ops.stream import plan_stream
-    from stencil_tpu.tune import space
-
-    dd = _mk_dd()
-    with tune.disabled():
-        static_plan = plan_stream(dd, 1, "auto", False)
-    budget, _, mxu_plan = _mxu_straddling_budget(dd, static_plan)
-    assert analysis.check_vmem(dd, mxu_plan, budget=budget) is not None
-    monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(budget))
-    cands, prefiltered = space.stream_space(dd, 1, False, static_plan,
-                                            mxu_ok=True)
-    assert cands, "the static plan must always survive"
-    assert all(c.get("compute_unit", "vpu") != "mxu" for c in cands), cands
-    assert prefiltered >= 1
-    # control: under the calibrated default budget the twin IS a candidate
-    monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
-    cands2, _ = space.stream_space(dd, 1, False, static_plan, mxu_ok=True)
-    assert any(c.get("compute_unit") == "mxu" for c in cands2), cands2
+    return (e_static + e_fused) // 2 + margin
 
 
 def test_pruned_candidate_never_compiles(monkeypatch, tune_dir):
@@ -379,31 +370,30 @@ def test_pruned_candidate_never_compiles(monkeypatch, tune_dir):
     time)."""
     from stencil_tpu import tune
     from stencil_tpu.ops import stream as sm
+    from stencil_tpu.tune import space as tune_space
     from stencil_tpu.tune.runners import autotune_stream
 
-    dd = _mk_dd()
+    dd = _mk_dd(exchange_route="yzpack_xla")  # the fused twin is eligible
     with tune.disabled():
         static_plan = sm.plan_stream(dd, 1, "auto", False)
-    budget, _, _ = _mxu_straddling_budget(dd, static_plan)
-    monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(budget))
+    budget = _fused_straddling_budget(dd, static_plan)
     built_plans = []
     real_build = sm._build_stream_step
 
-    def spy(dd_, kernel, x_radius, plan, interpret, donate=True,
-            mxu_kernel=None):
+    def spy(dd_, kernel, x_radius, plan, interpret, donate=True):
         built_plans.append(dict(plan))
-        return real_build(dd_, kernel, x_radius, plan, interpret,
-                          donate=donate, mxu_kernel=mxu_kernel)
+        return real_build(dd_, kernel, x_radius, plan, interpret, donate=donate)
 
     monkeypatch.setattr(sm, "_build_stream_step", spy)
+    # control: under the calibrated default budget the twin IS a candidate
+    cands, _ = tune_space.stream_space(dd, 1, False, static_plan)
+    assert any(c["halo"] == "fused" for c in cands), cands
+    monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(budget))
     report = autotune_stream(
         dd, aprog.mean6_kernel, interpret=True, reps=1, rt=0.0,
-        mxu_kernel=aprog.mean6_kernel_mxu,
     )
     assert built_plans, "the surviving candidates must still compile"
-    assert all(
-        p.get("compute_unit", "vpu") != "mxu" for p in built_plans
-    ), built_plans
+    assert all(p.get("halo") != "fused" for p in built_plans), built_plans
     assert report.pruned >= 1
 
 
@@ -496,13 +486,12 @@ def test_stream_space_prunes_illegal_kernel_statically(monkeypatch, tune_dir):
     dd = _mk_dd()
     with tune.disabled():
         static_plan = plan_stream(dd, 1, "auto", False)
-    cands, prefiltered = space.stream_space(dd, 1, False, static_plan,
-                                            mxu_ok=True)
+    cands, prefiltered = space.stream_space(dd, 1, False, static_plan)
     assert len(cands) > 1, "control: the space is non-trivial on CPU"
     monkeypatch.setattr(akern, "_mosaic_target", lambda: True)
     with jax.enable_x64(True):
         cands64, prefiltered64 = space.stream_space(
-            dd, 1, False, static_plan, mxu_ok=True
+            dd, 1, False, static_plan
         )
     # only the static pick survives (both its alias twins count as static
     # — alias is excluded from the static-identity comparison)
@@ -531,11 +520,9 @@ def test_illegal_candidate_never_compiles(monkeypatch, tune_dir):
     built_plans = []
     real_build = sm._build_stream_step
 
-    def spy(dd_, kernel, x_radius, plan, interpret, donate=True,
-            mxu_kernel=None):
+    def spy(dd_, kernel, x_radius, plan, interpret, donate=True):
         built_plans.append(dict(plan))
-        return real_build(dd_, kernel, x_radius, plan, interpret,
-                          donate=donate, mxu_kernel=mxu_kernel)
+        return real_build(dd_, kernel, x_radius, plan, interpret, donate=donate)
 
     monkeypatch.setattr(sm, "_build_stream_step", spy)
     monkeypatch.setattr(akern, "_mosaic_target", lambda: True)
@@ -544,17 +531,8 @@ def test_illegal_candidate_never_compiles(monkeypatch, tune_dir):
             dd, aprog.mean6_kernel, interpret=True, reps=1, rt=0.0,
         )
     assert report.pruned >= 1
-    survivors = {
-        (p["route"], p.get("m"), p.get("compute_unit", "vpu"))
-        for p in built_plans
-    }
-    assert survivors <= {
-        (
-            static_plan["route"],
-            static_plan.get("m"),
-            static_plan.get("compute_unit", "vpu"),
-        )
-    }, built_plans
+    survivors = {(p["route"], p.get("m")) for p in built_plans}
+    assert survivors <= {(static_plan["route"], static_plan.get("m"))}, built_plans
 
 
 def test_ladder_prefilter_tuple_descends_compile_reject():

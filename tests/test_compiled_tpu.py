@@ -157,15 +157,6 @@ def _mean6(views, info):
     return out
 
 
-def _mean6_mxu(views, info):
-    out = {}
-    for name, src in views.items():
-        out[name] = (
-            src.sh(-1, 0, 0) + src.sh(1, 0, 0) + src.plane_nbr_sum()
-        ) / 6.0
-    return out
-
-
 def _stream_run(n=128, mult=1, route=None, storage=None, steps=4, **step_kw):
     """One compiled stream-engine run on one device; returns (plan, field).
     Fails on any ladder descent."""
@@ -184,7 +175,7 @@ def _stream_run(n=128, mult=1, route=None, storage=None, steps=4, **step_kw):
     h = dd.add_data("q0")
     dd.realize()
     dd.init_by_coords(h, lambda x, y, z: jnp.sin(0.13 * (x + 2 * y + 3 * z)))
-    step = dd.make_step(_mean6, engine="stream", mxu_kernel=_mean6_mxu, **step_kw)
+    step = dd.make_step(_mean6, engine="stream", **step_kw)
     dd.run_step(step, steps)
     assert step._resilience.descents == [], step._resilience.descents
     return step._stream_plan, dd.quantity_to_host(h)
@@ -199,18 +190,6 @@ _PACK_REJECT = (
     "has block shape (2, 132, 1), array shape (2, 132, 256) [z pack; the y "
     "pack at ops/pack.py:434 likewise: block (2, 1, 132) of (2, 132, 132)]"
 )
-_BAND_REJECT = (
-    "INTERNAL: Mosaic failed to compile TPU kernel: infer-vector-layout: "
-    "unsupported shape cast — tpu.reshape vector<1x1x128x128xf32> -> "
-    "vector<128x16x8xf32> (the ladder then descends mxu_band -> mxu)"
-)
-_MXU_NUMERICS = (
-    "compiles and runs, but diverges 1.935e-03 from vpu against the analytic "
-    "bound 5.722e-06 (16 roundings * half-ulp at scale 6): the contraction "
-    "is not f32-accurate on the chip"
-)
-
-
 @pytest.mark.parametrize(
     "route",
     [
@@ -261,38 +240,6 @@ def test_compiled_halo_fused():
                             stream_halo="fused", steps=7)
     assert plan["halo"] == "fused" and plan["route"] == "wavefront", plan
     np.testing.assert_array_equal(want, got)
-
-
-@pytest.mark.parametrize(
-    "unit",
-    [
-        pytest.param("mxu",
-                     marks=pytest.mark.xfail(strict=True, reason=_MXU_NUMERICS)),
-        pytest.param("mxu_band",
-                     marks=pytest.mark.xfail(strict=True, reason=_BAND_REJECT)),
-    ],
-)
-def test_compiled_compute_unit(unit):
-    from ulp import assert_reassociation_close
-
-    plan_v, want = _stream_run(compute_unit="vpu", stream_depth=4)
-    plan, got = _stream_run(compute_unit=unit, stream_depth=4)
-    assert plan["compute_unit"] == unit and plan["m"] == plan_v["m"], plan
-    # 4 reordered roundings per level x 4 levels at the six-sum's magnitude
-    assert_reassociation_close(got, want, rounds=16, scale=6.0,
-                               context=f"compiled {unit}")
-
-
-def test_compiled_mxu_input_bf16():
-    """On the dense unit — the band form does not compile (above), and this
-    case asks only whether the narrowed operands do."""
-    from ulp import assert_mxu_bf16_input_close
-
-    _, want = _stream_run(compute_unit="mxu", stream_depth=4)
-    plan, got = _stream_run(compute_unit="mxu", mxu_input="bf16",
-                            stream_depth=4)
-    assert plan["mxu_input"] == "bf16" and plan["compute_unit"] == "mxu", plan
-    assert_mxu_bf16_input_close(got, want, levels=4, context="compiled bf16in")
 
 
 def test_compiled_storage_bf16():

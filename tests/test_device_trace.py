@@ -80,9 +80,8 @@ class TestParse:
 class TestAttribution:
     def test_named_scopes_and_kernel_families(self):
         """THE parser/join pin: device time lands on the overlap scopes the
-        split schedule annotates, the exchange collectives, the pack
-        kernels, and the MXU contraction — host rows in the dump count
-        toward nothing."""
+        split schedule annotates, the exchange collectives and the pack
+        kernels — host rows in the dump count toward nothing."""
         att = attribute_device_time(_fixture_events())
         assert att[names.SPAN_OVERLAP_INTERIOR]["device_us"] == pytest.approx(
             800 + 700 + 150  # the interior-scope dot also carries the scope
@@ -91,7 +90,6 @@ class TestAttribution:
         # six direction-scoped collective rows + one legacy halo_ppermute row
         assert att["exchange"]["device_us"] == pytest.approx(640)
         assert att["pack"]["device_us"] == pytest.approx(120 + 90)
-        assert att["mxu"]["device_us"] == pytest.approx(150)
         # total is device-only: the 5000us host enqueue row is excluded
         assert att["_total"]["device_us"] == pytest.approx(
             800 + 700 + 640 + 120 + 90 + 400 + 150
@@ -214,18 +212,13 @@ class TestRoofline:
             snap, attribute_device_time(_fixture_events()), **kw
         )
 
-    def test_join_bytes_and_flops(self):
+    def test_join_bytes(self):
         r = self._report(chip="TPU v5 lite")
         ex = r["phases"]["exchange"]
         # 6291456 B over 640 us of collective time
         assert ex["bytes"] == 6_291_456
         assert ex["gbps"] == pytest.approx(6_291_456 / 640e-6 / 1e9, rel=1e-3)
         assert ex["frac_of_roofline"] == pytest.approx(ex["gbps"] / 819.0, rel=1e-2)
-        mxu = r["phases"]["mxu"]
-        assert mxu["flops"] == 4_194_304_000
-        assert mxu["gflops"] == pytest.approx(
-            4_194_304_000 / 150e-6 / 1e9, rel=1e-3
-        )
         assert r["phases"][names.SPAN_OVERLAP_INTERIOR]["share_of_device"] > 0.5
         assert r["total_device_ms"] == pytest.approx(2.90)
         assert r["source"] == "device"
@@ -476,12 +469,11 @@ class TestProfileCapture:
         telemetry.inc(names.EXCHANGE_BYTES, 7000)  # pre-window: excluded
         with prof.maybe(0):
             telemetry.inc(names.EXCHANGE_BYTES, 512)
-            telemetry.inc(names.KERNEL_MXU_FLOPS, 300)
+            telemetry.inc(names.EXCHANGE_PACKED_BYTES, 300)
         telemetry.inc(names.EXCHANGE_BYTES, 9000)  # post-window: excluded
         snap = prof.counters_snapshot()
         assert snap["counters"][names.EXCHANGE_BYTES] == 512
-        assert snap["counters"][names.KERNEL_MXU_FLOPS] == 300
-        assert snap["counters"][names.EXCHANGE_PACKED_BYTES] == 0
+        assert snap["counters"][names.EXCHANGE_PACKED_BYTES] == 300
 
 
 # --- tier-2: live capture on a real profiler backend -------------------------

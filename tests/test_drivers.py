@@ -192,44 +192,6 @@ def test_bench_exchange(capsys):
         }
 
 
-# stencil-lint: disable=slow-marker imports bench.py as a module and calls one tiny in-process interpret-mode A/B (~3 s measured); nothing is spawned
-def test_bench_mxu_vs_vpu_section_schema():
-    """bench.py's compute-unit A/B section (in-process, tiny interpret-mode
-    workload — the subprocess bench acceptance stays tier-2): route_ab's
-    shape, both units measured, and the speedup ratio derived from them."""
-    import importlib.util
-    import os
-
-    from stencil_tpu.lint.framework import REPO
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_module", os.path.join(REPO, "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    ab = bench.mxu_vs_vpu_ab(size=12, k=2, interpret=True, rt=0.0,
-                             reps=1, inner=1)
-    assert ab["eligible"] is True and ab["k"] == 2
-    assert ab["band_eligible"] is True  # 12 tiles at granule 3
-    assert ab["measurement_protocol"]["drop_rep0"] is True
-    assert set(ab["units"]) == {"vpu", "mxu", "mxu_band", "mxu_band+bf16in"}
-    for entry in ab["units"].values():
-        assert entry["ms_per_dispatch"] > 0
-        assert entry["mcells_per_s"] > 0
-    assert set(ab["speedups_vs_vpu"]) == {
-        "mxu", "mxu_band", "mxu_band+bf16in",
-    }
-    for leg, sp in ab["speedups_vs_vpu"].items():
-        # both sides are independently rounded artifact fields
-        assert sp == pytest.approx(
-            ab["units"]["vpu"]["ms_per_dispatch"]
-            / ab["units"][leg]["ms_per_dispatch"],
-            abs=2e-3,
-        )
-    # the legacy scalar keeps reporting the dense ratio
-    assert ab["speedup_vs_vpu"] == ab["speedups_vs_vpu"]["mxu"]
-
-
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 def test_bench_pack(capsys, backend):
     from stencil_tpu.bin.bench_pack import main

@@ -196,7 +196,9 @@ class TestLinkModel:
 
     def test_ledger_ingests_probe_artifact(self, tmp_path):
         mesh = _mesh222()
-        doc = fabric.ensure(mesh, nbytes=4096, reps=1)
+        # 1 MiB, not 4 KiB: links round to 3 decimals of a GB/s, and one
+        # 8 ms stall of a loaded worker reads a 4 KiB message as 0.000
+        doc = fabric.ensure(mesh, nbytes=1 << 20, reps=1)
         path = tmp_path / "fabric.json"
         path.write_text(json.dumps(doc))
         entries = entries_from_artifact(str(path))

@@ -38,22 +38,63 @@ def test_pallas_single_device_spheres_active():
     assert t[20, 15, 15] == pytest.approx(0.0)
 
 
-@pytest.mark.parametrize("k", [2, 3])
-def test_wrap_temporal_blocking_bit_exact(k):
+def _jacobi_roll_reference(b, levels):
+    """``levels`` jacobi updates of the whole periodic domain by ``jnp.roll``
+    on the array: the mean of the six face neighbours in the kernels' own
+    order, then the hot and cold spheres — no plane ring, no kernel."""
+    import jax.numpy as jnp
+
+    X, Y, Z = b.shape
+    x, y, z = jnp.meshgrid(jnp.arange(X), jnp.arange(Y), jnp.arange(Z), indexing="ij")
+    in_r2 = (X // 10 + 1) ** 2
+
+    def d2(cx):
+        return (x - cx) ** 2 + (y - Y // 2) ** 2 + (z - Z // 2) ** 2
+
+    for _ in range(levels):
+        b = (
+            jnp.roll(b, 1, 0) + jnp.roll(b, -1, 0)
+            + jnp.roll(b, 1, 1) + jnp.roll(b, -1, 1)
+            + jnp.roll(b, 1, 2) + jnp.roll(b, -1, 2)
+        ) / 6.0
+        b = jnp.where(d2(X // 3) < in_r2, 1.0, b)
+        b = jnp.where(d2(X * 2 // 3) < in_r2, 0.0, b).astype(b.dtype)
+    return b
+
+
+@pytest.mark.parametrize(
+    "shape,k,seed",
+    [
+        ((12, 16, 16), 2, 7),
+        ((12, 16, 16), 3, 7),
+        # the shapes, depths and seeds the matrix-unit pins ran their roll +
+        # add side at (PR 29): never held against ground truth before
+        ((12, 16, 16), 1, 7),
+        ((12, 16, 16), 1, 9),
+        ((12, 16, 16), 3, 9),
+        ((12, 13, 13), 2, 5),  # prime plane extents
+    ],
+)
+def test_wrap_temporal_blocking_bit_exact(shape, k, seed):
     """k temporally-blocked levels == k plain applications, bitwise: each
     level's arithmetic (summation order, forcing selects) is identical to a
-    k=1 pass, so the wavefront must not change a single ulp."""
+    k=1 pass, so the wavefront must not change a single ulp.  And both equal
+    the ``jnp.roll`` formulation on the whole array, bitwise too: the sums
+    run in the same order (the reference is jitted, as the interpreted
+    kernel is, so XLA lowers the division by 6 alike on both sides)."""
     import jax.numpy as jnp
 
     from stencil_tpu.ops.jacobi_pallas import jacobi_wrap_step
 
-    rng = np.random.default_rng(7)
-    b0 = jnp.asarray(rng.random((12, 16, 16)), jnp.float32)
+    rng = np.random.default_rng(seed)
+    b0 = jnp.asarray(rng.random(shape), jnp.float32)
     ref = b0
     for _ in range(k):
         ref = jacobi_wrap_step(ref, interpret=True)
     got = jacobi_wrap_step(b0, interpret=True, k=k)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    want = jax.jit(_jacobi_roll_reference, static_argnums=1)(b0, k)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_wrap_temporal_blocking_model_with_remainder():
@@ -72,7 +113,10 @@ def test_wrap_temporal_blocking_model_with_remainder():
     np.testing.assert_array_equal(a.temperature(), b.temperature())
 
 
-@pytest.mark.parametrize("size", [(24, 24, 24), (16, 24, 32)])
+@pytest.mark.parametrize(
+    "size",
+    [(24, 24, 24), (16, 24, 32), (21, 21, 21)],  # 21: padded last shards
+)
 def test_wavefront_matches_jnp_multidevice(size):
     """The temporally-blocked multi-device path (m-shell exchange + m-level
     wavefront kernel) equals the generic jnp formulation, including a

@@ -1,19 +1,14 @@
-"""Tier-1: the compute-unit (vpu/mxu) and storage-dtype (native/bf16) axes.
+"""Tier-1: the storage-dtype axis (native/bf16) of the level kernels.
 
-The ISSUE-7 tentpole claims, in-process on the fake 8-chip CPU mesh
-(interpret-mode pallas): the MXU banded-contraction form of every level
-kernel matches the VPU roll+add chain within the documented reassociation
-bound (the two orders share ``prev + vals`` and differ in the remaining
-four in-plane additions — ≤ 4 reordered roundings per level, so ≤ 4 ulps
-of the f32 result per level); bf16 storage with f32 accumulation tracks
-the f32 ground truth within the analytic one-rounding-per-downcast bound
-(``tests/ulp.bf16_storage_atol``); the default ``vpu``/``native`` path
-stays BITWISE identical to an axis-free build; resolution follows
-explicit > env > tuned > static with structural degradation (non-f32
-fields, engines without a contraction / f32-accumulate form); the ladder
-steps ``mxu -> vpu`` and ``bf16 -> native`` at the SAME depth before any
-depth descent; and both axes search, persist, and consult through
-``tune.best_config`` with pre-axis cache entries still warm.
+In-process on the fake 8-chip CPU mesh (interpret-mode pallas): bf16 storage
+with f32 accumulation tracks the f32 ground truth within the analytic
+one-rounding-per-downcast bound (``tests/ulp.bf16_storage_atol``); the
+default ``native`` path stays BITWISE identical to an explicit one;
+resolution follows explicit > env > tuned > static with structural
+degradation (non-f32 fields, engines without an f32-accumulate form); the
+ladder steps ``bf16 -> native`` at the SAME depth before any depth descent;
+and the axis persists and consults through ``tune.best_config`` with
+pre-axis cache entries still warm.
 """
 
 import numpy as np
@@ -21,42 +16,19 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ulp import (
-    assert_bf16_storage_close,
-    assert_mxu_bf16_input_close,
-    assert_reassociation_close,
-    assert_ulp_close,
-)
+from ulp import assert_bf16_storage_close
 
 from stencil_tpu import telemetry, tune
 from stencil_tpu.core.radius import Radius
 from stencil_tpu.domain import DistributedDomain
 from stencil_tpu.models.jacobi import Jacobi3D
-from stencil_tpu.ops import stream as sm
 from stencil_tpu.ops.jacobi_pallas import (
-    band_tile_plan,
-    band_tile_size,
     bf16_supported,
     jacobi_wrap_step,
-    mxu_flops_per_plane,
-    mxu_supported,
-    band_matrix,
-    plane_band_unit,
-    plane_nbr_sum_host,
-    resolve_compute_unit,
-    resolve_mxu_input,
     resolve_storage_dtype,
 )
 from stencil_tpu.resilience import inject
 from stencil_tpu.telemetry import names as tm
-
-#: per-level ulp bound for the mxu-vs-vpu contract: the two summation
-#: orders share ``prev + vals`` and differ in the remaining FOUR in-plane
-#: additions, each contributing at most one reordered rounding — measured
-#: 3 ulps at a single level, 4 at k=4 (docs/tuning.md "Compute unit and
-#: storage dtype"; PERF_NOTES "VPU wall")
-MXU_ULPS_PER_LEVEL = 4
-
 
 @pytest.fixture
 def tune_dir(tmp_path, monkeypatch):
@@ -93,44 +65,7 @@ def mean6_kernel(views, info):
     return out
 
 
-def mean6_kernel_mxu(views, info):
-    """The declared contraction form: the same mean-of-6 with the four
-    in-plane taps through ``PlaneView.plane_nbr_sum``."""
-    out = {}
-    for name, src in views.items():
-        out[name] = (
-            src.sh(-1, 0, 0) + src.sh(1, 0, 0) + src.plane_nbr_sum()
-        ) / 6.0
-    return out
-
-
-# --- the band matrix ---------------------------------------------------------
-
-
-def test_band_matrix_is_the_roll_pair():
-    """(B @ v)[i] == v[i-1] + v[i+1] with the periodic wrap, exactly —
-    including the degenerate n=2 double-count the vpu rolls produce."""
-    for n in (2, 3, 8, 128):
-        B = np.asarray(band_matrix(n))
-        v = np.arange(1.0, n + 1.0, dtype=np.float32)
-        want = np.roll(v, 1) + np.roll(v, -1)
-        np.testing.assert_array_equal(B @ v, want)
-    assert np.asarray(band_matrix(2)).tolist() == [[0.0, 2.0], [2.0, 0.0]]
-
-
 # --- kernel-level equivalence ------------------------------------------------
-
-
-@pytest.mark.parametrize("k", [1, 3])
-def test_wrap_mxu_matches_vpu_per_level_bound(k):
-    rng = np.random.default_rng(7)
-    b0 = jnp.asarray(rng.random((12, 16, 16)), jnp.float32)
-    v = jacobi_wrap_step(b0, interpret=True, k=k)
-    m = jacobi_wrap_step(b0, interpret=True, k=k, compute_unit="mxu")
-    assert_ulp_close(
-        np.asarray(m), np.asarray(v), ulps=MXU_ULPS_PER_LEVEL * k,
-        context=f"wrap mxu k={k}",
-    )
 
 
 @pytest.mark.parametrize("k", [1, 3])
@@ -149,29 +84,7 @@ def test_wrap_bf16_storage_analytic_bound(k):
     )
 
 
-def test_wrap_mxu_requires_f32_accumulator():
-    b = jnp.zeros((8, 8, 8), jnp.float64)
-    with pytest.raises(AssertionError, match="f32 accumulator"):
-        jacobi_wrap_step(b, interpret=True, compute_unit="mxu")
-
-
 # --- model-level equivalence -------------------------------------------------
-
-
-def test_jacobi_wavefront_mxu_matches_vpu():
-    a = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                 compute_unit="vpu")
-    a.realize()
-    b = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                 compute_unit="mxu")
-    b.realize()
-    assert a._pallas_path == b._pallas_path == "wavefront"
-    assert b._compute_unit == "mxu" and a._compute_unit == "vpu"
-    a.step(4)
-    b.step(4)
-    # 4 raw iterations = 4 levels of carried per-level divergence
-    assert_ulp_close(b.temperature(), a.temperature(),
-                     ulps=MXU_ULPS_PER_LEVEL * 4, context="wavefront mxu")
 
 
 def test_jacobi_bf16_storage_matches_f32_ground_truth():
@@ -202,49 +115,19 @@ def test_jacobi_bf16_halves_exchange_bytes():
 
 
 def test_default_path_bitwise_vs_explicit_vpu_native():
-    """The axes' static fallbacks ARE today's kernels: an explicit
-    vpu/native build is bit-identical to an axis-free one."""
+    """The axis' static fallback IS today's kernel: an explicit native
+    build is bit-identical to an axis-free one."""
     a = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True)
     a.realize()
     b = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                 compute_unit="vpu", storage_dtype="native")
+                 storage_dtype="native")
     b.realize()
     a.step(3)
     b.step(3)
     np.testing.assert_array_equal(a.temperature(), b.temperature())
 
 
-def test_combined_mxu_bf16():
-    """bf16 storage COMPUTES at f32, so mxu qualifies on top of it; the
-    divergence is the bf16 bound plus the mxu reassociation term (strictly
-    smaller than one extra downcast per step)."""
-    a = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True)
-    a.realize()
-    b = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                 compute_unit="mxu", storage_dtype="bf16")
-    b.realize()
-    assert b._compute_unit == "mxu" and b.dd.storage_dtype() == "bf16"
-    a.step(4)
-    b.step(4)
-    assert_bf16_storage_close(b.temperature(), a.temperature(), passes=5,
-                              scale=1.0, context="mxu+bf16")
-
-
 # --- structural degradation --------------------------------------------------
-
-
-def test_mxu_degrades_on_f64_fields():
-    assert not mxu_supported([jnp.float64])
-    m = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                 compute_unit="mxu", dtype=jnp.float64)
-    m.realize()
-    assert m._compute_unit == "vpu"  # degraded, not crashed
-    r = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                 dtype=jnp.float64)
-    r.realize()
-    m.step(2)
-    r.step(2)
-    np.testing.assert_array_equal(m.temperature(), r.temperature())
 
 
 def test_bf16_degrades_on_f64_fields_and_xla_engine():
@@ -258,27 +141,14 @@ def test_bf16_degrades_on_f64_fields_and_xla_engine():
     assert x.dd.storage_dtype() == "native"
 
 
-def test_stream_mxu_degrades_without_contraction_form():
-    """A kernel with no declared mxu form structurally degrades — the plan
-    lands on vpu with a warning, never a crash."""
-    dd, _ = _mk(mult=2)
-    step = dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                        compute_unit="mxu")  # no mxu_kernel=
-    assert step._stream_plan["compute_unit"] == "vpu"
-    dd.run_step(step, 2)
-
-
 def test_unknown_axis_values_rejected():
-    dd, _ = _mk()
-    with pytest.raises(ValueError, match="unknown compute unit"):
-        dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                     compute_unit="gpu")
     with pytest.raises(ValueError, match="unknown storage dtype"):
         DistributedDomain(8, 8, 8).set_storage("fp8")
     with pytest.raises(ValueError, match="unknown value"):
-        resolve_compute_unit("tpu", None, [jnp.float32])
-    with pytest.raises(ValueError, match="unknown value"):
         resolve_storage_dtype("fp4", None, [jnp.float32])
+    with pytest.raises(ValueError, match="unknown value"):
+        Jacobi3D(16, 16, 16, kernel_impl="pallas", interpret=True,
+                 storage_dtype="fp8").realize()
 
 
 def test_wrap_temporal_k_models_f32_ring_under_bf16(monkeypatch):
@@ -319,52 +189,7 @@ def test_set_storage_bf16_degrades_on_mixed_dtype_domain():
     assert dd._curr["d"].dtype == jnp.float64
 
 
-def test_xla_engine_degrades_explicit_mxu_with_event(tmp_path):
-    """engine="xla" has no pallas level kernels: an explicit mxu request
-    degrades through the shared resolver (warning + kernel.compute_unit
-    event), never silently dropped."""
-    import json
-
-    telemetry.enable(dir=str(tmp_path))
-    telemetry.reset()
-    try:
-        dd, hs = _mk()
-        step = dd.make_step(mean6_kernel, engine="xla", compute_unit="mxu")
-        dd.run_step(step, 1)
-        events = [
-            json.loads(line) for line in open(telemetry.event_log_path())
-        ]
-        cu = [e for e in events if e["event"] == tm.EVENT_KERNEL_COMPUTE_UNIT]
-        assert cu and cu[-1]["where"] == "xla"
-        assert cu[-1]["unit"] == "vpu"
-        assert cu[-1]["source"] == "explicit/degraded"
-    finally:
-        telemetry.disable()
-
-
 # --- stream engine -----------------------------------------------------------
-
-
-def test_stream_mxu_matches_vpu():
-    dd_a, hs_a = _mk(mult=3)
-    dd_b, hs_b = _mk(mult=3)
-    sa = dd_a.make_step(mean6_kernel, engine="stream", interpret=True,
-                        compute_unit="vpu", mxu_kernel=mean6_kernel_mxu)
-    sb = dd_b.make_step(mean6_kernel, engine="stream", interpret=True,
-                        compute_unit="mxu", mxu_kernel=mean6_kernel_mxu)
-    assert sa._stream_plan["compute_unit"] == "vpu"
-    assert sb._stream_plan["compute_unit"] == "mxu"
-    assert sb._stream_plan["m"] == sa._stream_plan["m"]  # same depth
-    dd_a.run_step(sa, 4)
-    dd_b.run_step(sb, 4)
-    # sin-initialized fields CROSS zero, where result-relative ulps blow up
-    # on operand-scale divergence — bound at the intermediates' magnitude
-    # instead (the six-sum reaches |6·field| before the division): 4
-    # reordered roundings per level x 4 levels
-    assert_reassociation_close(
-        dd_b.quantity_to_host(hs_b[0]), dd_a.quantity_to_host(hs_a[0]),
-        rounds=MXU_ULPS_PER_LEVEL * 4, scale=6.0, context="stream mxu",
-    )
 
 
 def test_stream_bf16_storage_via_domain():
@@ -417,25 +242,6 @@ def test_bf16_packed_exchange_matches_direct():
 # --- precedence: explicit > env > tuned > static -----------------------------
 
 
-def test_compute_unit_resolution_precedence(tune_dir, monkeypatch):
-    # static fallback: cold cache, no env, no request -> vpu
-    dd, _ = _mk(mult=2)
-    step = dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                        mxu_kernel=mean6_kernel_mxu)
-    assert step._stream_plan["compute_unit"] == "vpu"
-    # env beats static
-    monkeypatch.setenv("STENCIL_COMPUTE_UNIT", "mxu")
-    dd, _ = _mk(mult=2)
-    step = dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                        mxu_kernel=mean6_kernel_mxu)
-    assert step._stream_plan["compute_unit"] == "mxu"
-    # explicit beats env
-    dd, _ = _mk(mult=2)
-    step = dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                        compute_unit="vpu", mxu_kernel=mean6_kernel_mxu)
-    assert step._stream_plan["compute_unit"] == "vpu"
-
-
 def test_storage_dtype_resolution_precedence(tune_dir, monkeypatch):
     mk = lambda **kw: Jacobi3D(16, 16, 16, kernel_impl="pallas",
                                interpret=True, **kw)
@@ -452,60 +258,19 @@ def test_storage_dtype_resolution_precedence(tune_dir, monkeypatch):
 
 
 def test_axis_env_invalid_rejected(monkeypatch):
-    monkeypatch.setenv("STENCIL_COMPUTE_UNIT", "abacus")
-    with pytest.raises(ValueError, match="STENCIL_COMPUTE_UNIT"):
-        resolve_compute_unit(None, None, [jnp.float32])
-    monkeypatch.delenv("STENCIL_COMPUTE_UNIT")
     monkeypatch.setenv("STENCIL_STORAGE_DTYPE", "fp8")
     with pytest.raises(ValueError, match="STENCIL_STORAGE_DTYPE"):
         resolve_storage_dtype(None, None, [jnp.float32])
+    with pytest.raises(ValueError, match="STENCIL_STORAGE_DTYPE"):
+        Jacobi3D(16, 16, 16, kernel_impl="pallas", interpret=True).realize()
 
 
 # --- tuner: search, persist, consult -----------------------------------------
 
 
-def test_stream_space_grows_mxu_twin_candidates(tune_dir):
-    from stencil_tpu.tune import space as tune_space
-
-    dd, _ = _mk(mult=2)
-    with tune.disabled():
-        static = sm.plan_stream(dd, 1, "auto", False)
-    cands, _ = tune_space.stream_space(dd, 1, False, static, mxu_ok=True)
-    assert all("compute_unit" in c for c in cands)
-    mxu_cands = [c for c in cands if c["compute_unit"] == "mxu"]
-    assert len(mxu_cands) == 1 and mxu_cands[0]["m"] == static["m"]
-    # without a declared contraction form the twin is prefiltered
-    cands2, pre2 = tune_space.stream_space(dd, 1, False, static, mxu_ok=False)
-    assert not [c for c in cands2 if c["compute_unit"] == "mxu"]
-    assert pre2 >= 1
-
-
-def test_autotune_stream_persists_compute_unit_and_consult(tune_dir):
-    from stencil_tpu.tune.runners import autotune_stream
-
-    dd, _ = _mk(mult=2)
-    report = autotune_stream(dd, mean6_kernel, x_radius=1, interpret=True,
-                             reps=1, rt=0.0, mxu_kernel=mean6_kernel_mxu)
-    assert report.source == "search"
-    assert "compute_unit" in report.config
-    # pin an mxu winner; the next auto-mode build consults it — but only a
-    # build DECLARING the contraction form may engage it
-    key = dd.tune_key("stream")
-    tune.record_config(key, dict(report.config, compute_unit="mxu"))
-    tune.reset_memo()
-    dd2, _ = _mk(mult=2)
-    step = dd2.make_step(mean6_kernel, engine="stream", interpret=True,
-                         mxu_kernel=mean6_kernel_mxu)
-    assert step._stream_plan["compute_unit"] == "mxu"
-    tune.reset_memo()
-    dd3, _ = _mk(mult=2)
-    step3 = dd3.make_step(mean6_kernel, engine="stream", interpret=True)
-    assert step3._stream_plan["compute_unit"] == "vpu"  # degraded structurally
-
-
 def test_pre_axis_cache_entry_without_fields_still_hits(tune_dir):
-    """Pre-axis entries (no compute_unit/storage_dtype) stay consultable —
-    no schema bump; absent = the static vpu/native."""
+    """Pre-axis entries (no storage_dtype) stay consultable — no schema
+    bump; absent = the static native."""
     dd, _ = _mk(mult=2)
     key = dd.tune_key("stream")
     tune.record_config(
@@ -515,27 +280,9 @@ def test_pre_axis_cache_entry_without_fields_still_hits(tune_dir):
     )
     tune.reset_memo()
     dd2, _ = _mk(mult=2)
-    step = dd2.make_step(mean6_kernel, engine="stream", interpret=True,
-                         mxu_kernel=mean6_kernel_mxu)
-    assert step._stream_plan["m"] == 2
-    assert step._stream_plan["compute_unit"] == "vpu"
-
-
-def test_garbage_compute_unit_cache_entry_degrades_to_static(tune_dir):
-    dd, _ = _mk(mult=2)
-    key = dd.tune_key("stream")
-    tune.record_config(
-        key,
-        {"route": "wavefront", "m": 2, "z_slabs": False, "grouping": "joint",
-         "compute_unit": "abacus", "halo_multiplier": 2},
-    )
-    tune.reset_memo()
-    dd2, _ = _mk(mult=2)
-    step = dd2.make_step(mean6_kernel, engine="stream", interpret=True,
-                         mxu_kernel=mean6_kernel_mxu)
-    assert step._stream_plan["z_slabs"]  # the static plan applied
-    assert step._stream_plan["compute_unit"] == "vpu"
-    dd2.run_step(step, 2)
+    step = dd2.make_step(mean6_kernel, engine="stream", interpret=True)
+    assert step._stream_plan["m"] == 2 and not step._stream_plan["z_slabs"]
+    assert dd2.storage_dtype() == "native"
 
 
 def test_tuned_storage_dtype_consulted_by_jacobi(tune_dir):
@@ -555,33 +302,6 @@ def test_tuned_storage_dtype_consulted_by_jacobi(tune_dir):
 
 
 # --- resilience ladder -------------------------------------------------------
-
-
-def test_ladder_steps_mxu_down_to_vpu_same_depth(tune_dir):
-    """A runtime failure on an mxu stream rung drops the UNIT at the same
-    depth (mxu -> vpu) before any depth descent, and the stepped-down rung
-    matches the vpu ground truth bitwise."""
-    dd, hs = _mk(mult=3)
-    step = dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                        compute_unit="mxu", mxu_kernel=mean6_kernel_mxu)
-    plan0 = dict(step._stream_plan)
-    assert plan0["compute_unit"] == "mxu"
-    inject.set_plan("execute:vmem_oom:stream*1")
-    try:
-        dd.run_step(step, 4)
-    finally:
-        inject.set_plan(None)
-    assert step._stream_plan["compute_unit"] == "vpu"
-    assert step._stream_plan["m"] == plan0["m"]  # SAME depth
-    assert [d[0] for d in step._resilience.descents] == [
-        f"{plan0['route']}[m={plan0['m']},mxu]",
-    ]
-    ref_dd, ref_hs = _mk(mult=3)
-    ref = ref_dd.make_step(mean6_kernel, engine="stream", interpret=True)
-    ref_dd.run_step(ref, 4)
-    np.testing.assert_array_equal(
-        ref_dd.quantity_to_host(ref_hs[0]), dd.quantity_to_host(hs[0])
-    )
 
 
 def test_jacobi_ladder_steps_bf16_down_to_native(tune_dir):
@@ -611,82 +331,7 @@ def test_jacobi_ladder_steps_bf16_down_to_native(tune_dir):
                               context="post-step-down")
 
 
-def test_jacobi_ladder_steps_mxu_down_before_depth(tune_dir):
-    m = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                 compute_unit="mxu", temporal_k=3,
-                 devices=jax.devices()[:1])
-    m.realize()
-    assert m._compute_unit == "mxu" and m._wrap_k == 3
-    inject.set_plan("execute:vmem_oom:jacobi*1")
-    try:
-        m.step(3)
-    finally:
-        inject.set_plan(None)
-    assert m._compute_unit == "vpu"
-    assert m._wrap_k == 3  # depth untouched
-    ref = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                   temporal_k=3, devices=jax.devices()[:1])
-    ref.realize()
-    ref.step(3)
-    np.testing.assert_array_equal(m.temperature(), ref.temperature())
-
-
 # --- telemetry ---------------------------------------------------------------
-
-
-def test_axis_events_and_mxu_flops_counter(tmp_path, tune_dir):
-    telemetry.enable(dir=str(tmp_path))
-    telemetry.reset()
-    try:
-        dd, _ = _mk(mult=2)
-        step = dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                            compute_unit="mxu", mxu_kernel=mean6_kernel_mxu)
-        f0 = telemetry.snapshot()["counters"][tm.KERNEL_MXU_FLOPS]
-        assert f0 == 0
-        dd.run_step(step, 2)
-        f1 = telemetry.snapshot()["counters"][tm.KERNEL_MXU_FLOPS]
-        raw = dd.local_spec().raw_size()
-        # the counter models the plane the pass CONTRACTS: the z-slab
-        # wavefront lane-pads its planes to a 128 multiple
-        pz = sm.lane_pad_width(raw.z) if step._stream_plan["z_slabs"] else raw.z
-        per_plane = 2 * raw.y * raw.y * pz + 2 * raw.y * pz * pz
-        assert f1 - f0 == per_plane * raw.x * 8 * 2  # shards x steps
-        import json
-
-        events = [
-            json.loads(line) for line in open(telemetry.event_log_path())
-        ]
-        cu = [e for e in events if e["event"] == tm.EVENT_KERNEL_COMPUTE_UNIT]
-        assert cu and cu[-1]["unit"] == "mxu" and cu[-1]["source"] == "explicit"
-    finally:
-        telemetry.disable()
-
-
-def test_band_event_and_flops_counter_model_the_variant(tmp_path, tune_dir):
-    """kernel.mxu.flops under mxu_band counts the band-tiled analytic
-    model (6·g·Y·Z per axis), NOT the dense one — the dense model would
-    over-report by ~n/(2r+1) and poison every roofline/ledger series."""
-    telemetry.enable(dir=str(tmp_path))
-    telemetry.reset()
-    try:
-        dd, _ = _mk(mult=2)
-        step = dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                            compute_unit="mxu_band",
-                            mxu_kernel=mean6_kernel_mxu)
-        assert step._stream_plan["compute_unit"] == "mxu_band"
-        dd.run_step(step, 2)
-        f = telemetry.snapshot()["counters"][tm.KERNEL_MXU_FLOPS]
-        raw = dd.local_spec().raw_size()
-        # modeled on the plane the pass CONTRACTS (lane-padded under the
-        # z-slab route — the padded width decides which tiling engages)
-        pz = sm.lane_pad_width(raw.z) if step._stream_plan["z_slabs"] else raw.z
-        gy, gz = band_tile_plan(raw.y, pz)
-        per_plane = 6 * gy * raw.y * pz + 6 * gz * raw.y * pz
-        assert per_plane == mxu_flops_per_plane(raw.y, pz, "mxu_band")
-        assert per_plane < mxu_flops_per_plane(raw.y, pz, "mxu")
-        assert f == per_plane * raw.x * 8 * 2  # shards x steps
-    finally:
-        telemetry.disable()
 
 
 def test_storage_event_emitted(tmp_path):
@@ -706,325 +351,3 @@ def test_storage_event_emitted(tmp_path):
         assert sd[-1]["source"] == "explicit"
     finally:
         telemetry.disable()
-
-
-# --- the band-tiled contraction variant (ISSUE 13) ---------------------------
-
-
-@pytest.mark.parametrize("r", [1, 2])
-def test_band_tile_contraction_matches_dense_and_vpu(r):
-    """The blocked (2r+1)-band form computes the SAME neighbor sum as the
-    dense circulant contraction and the roll chain, across geometries that
-    exercise sublane-granule tiles, non-8-multiple granules, and uneven
-    y/z extents — band-vs-dense is pure summation order (each element sums
-    the same 2r values per axis; zeros add exactly), so it pins in the
-    same ulp regime as the dense-vs-vpu contract."""
-    rng = np.random.default_rng(11)
-    for (Y, Z) in ((32, 256), (24, 48), (40, 120)):
-        assert band_tile_plan(Y, Z, r) is not None, (Y, Z, r)
-        c = jnp.asarray(rng.standard_normal((Y, Z)), jnp.float32)
-        vpu = np.asarray(plane_nbr_sum_host(c, "vpu", r=r))
-        dense = np.asarray(plane_nbr_sum_host(c, "mxu", r=r))
-        band = np.asarray(plane_nbr_sum_host(c, "mxu_band", r=r))
-        # operand-scale-aware bounds: the (2r+1)-band sums cross zero on
-        # this data, where result-relative ulps blow up on operand-scale
-        # reassociation divergence (the assert_reassociation_close regime)
-        scale = float(np.abs(np.asarray(c)).max()) * 4 * r
-        assert_reassociation_close(dense, vpu, rounds=4 * r, scale=scale,
-                                   context=f"dense r={r} ({Y},{Z})")
-        assert_reassociation_close(band, dense, rounds=2 * r, scale=scale,
-                                   context=f"band-vs-dense r={r} ({Y},{Z})")
-        if r == 1:
-            # sums of two values are order-independent: the band form is
-            # BITWISE the dense contraction at the face-stencil radius
-            assert_ulp_close(band, dense, ulps=0,
-                             context=f"band bitwise r=1 ({Y},{Z})")
-
-
-def test_band_tile_plan_selection_and_structural_degrade():
-    """Granule preference (smallest 8-multiple divisor, else smallest
-    admissible), prime extents degrade band->dense per plane geometry, and
-    the degraded kernel still matches vpu."""
-    assert band_tile_size(512) == 8
-    assert band_tile_size(512, r=2) == 8  # 8 >= 2r+1 = 5
-    assert band_tile_size(12) == 3  # no admissible 8-multiple; smallest >= 3
-    assert band_tile_size(24, r=2) == 6  # smallest divisor >= 5 with 3g < n
-    assert band_tile_size(14) is None  # g=7 would COST more than dense
-    assert band_tile_size(13) is None  # prime: only n itself divides
-    assert band_tile_plan(16, 13) is None  # one untilable axis kills both
-    assert plane_band_unit("mxu_band", 16, 13) == "mxu"  # degrade, not crash
-    assert plane_band_unit("mxu_band", 16, 16) == "mxu_band"
-    assert plane_band_unit("vpu", 16, 13) == "vpu"
-    # the degraded geometry still runs (dense form) and matches vpu
-    rng = np.random.default_rng(3)
-    b0 = jnp.asarray(rng.random((12, 13, 13)), jnp.float32)
-    v = jacobi_wrap_step(b0, interpret=True, k=2)
-    m = jacobi_wrap_step(b0, interpret=True, k=2, compute_unit="mxu_band")
-    assert_ulp_close(np.asarray(m), np.asarray(v),
-                     ulps=MXU_ULPS_PER_LEVEL * 2, context="degraded band")
-    # an untilable-geometry band FLOP model prices the dense form it runs
-    assert mxu_flops_per_plane(13, 13, "mxu_band") == mxu_flops_per_plane(13, 13)
-
-
-@pytest.mark.parametrize("k", [1, 3])
-def test_wrap_mxu_band_matches_dense_and_vpu(k):
-    rng = np.random.default_rng(7)
-    b0 = jnp.asarray(rng.random((12, 16, 16)), jnp.float32)
-    v = jacobi_wrap_step(b0, interpret=True, k=k)
-    d = jacobi_wrap_step(b0, interpret=True, k=k, compute_unit="mxu")
-    b = jacobi_wrap_step(b0, interpret=True, k=k, compute_unit="mxu_band")
-    assert_ulp_close(np.asarray(b), np.asarray(v),
-                     ulps=MXU_ULPS_PER_LEVEL * k, context=f"band-vs-vpu k={k}")
-    # band-vs-dense differs only by the blocked summation order: ≤1
-    # reordered rounding per level
-    assert_ulp_close(np.asarray(b), np.asarray(d), ulps=k,
-                     context=f"band-vs-dense k={k}")
-
-
-@pytest.mark.parametrize("unit", ["mxu", "mxu_band"])
-def test_wrap_bf16_input_analytic_bound(unit):
-    """bf16 MXU inputs track the f32-input form of the SAME unit within
-    the analytic operand-rounding bound (tests/ulp.mxu_bf16_input_atol) —
-    per level: 4 in-plane operand reads x one bf16 rounding each."""
-    rng = np.random.default_rng(9)
-    b0 = jnp.asarray(rng.random((12, 16, 16)), jnp.float32)
-    for k in (1, 3):
-        f32 = jacobi_wrap_step(b0, interpret=True, k=k, compute_unit=unit)
-        nar = jacobi_wrap_step(b0, interpret=True, k=k, compute_unit=unit,
-                               mxu_input="bf16")
-        assert_mxu_bf16_input_close(
-            np.asarray(nar), np.asarray(f32), levels=k, scale=1.0,
-            context=f"{unit} bf16in k={k}",
-        )
-
-
-def test_jacobi_wavefront_mxu_band_matches_vpu_uneven():
-    """The band variant on the multi-device wavefront over UNEVEN shards
-    (21³ over 8 chips pads the last shard): the plain wavefront's raw
-    planes tile at a non-8-multiple granule and the run pins against vpu;
-    the flops ledger counts the band model."""
-    a = Jacobi3D(21, 21, 21, kernel_impl="pallas", interpret=True,
-                 compute_unit="vpu")
-    a.realize()
-    b = Jacobi3D(21, 21, 21, kernel_impl="pallas", interpret=True,
-                 compute_unit="mxu_band")
-    b.realize()
-    assert a._pallas_path == b._pallas_path == "wavefront"
-    assert b._compute_unit == "mxu_band"
-    raw = b.dd.local_spec().raw_size()
-    assert band_tile_plan(raw.y, raw.z) is not None  # really band-tiled
-    assert b._mxu_flops_iter > 0
-    assert b._mxu_flops_iter < (
-        mxu_flops_per_plane(raw.y, raw.z, "mxu") * raw.x
-        * b.dd.num_subdomains()
-    )
-    a.step(4)
-    b.step(4)
-    assert_ulp_close(b.temperature(), a.temperature(),
-                     ulps=MXU_ULPS_PER_LEVEL * 4, context="wavefront band")
-
-
-def test_jacobi_wavefront_band_vs_dense_pin():
-    a = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                 compute_unit="mxu")
-    a.realize()
-    b = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                 compute_unit="mxu_band")
-    b.realize()
-    assert a._compute_unit == "mxu" and b._compute_unit == "mxu_band"
-    a.step(4)
-    b.step(4)
-    assert_ulp_close(b.temperature(), a.temperature(), ulps=4,
-                     context="band-vs-dense wavefront")
-
-
-def test_stream_mxu_band_matches_vpu_and_dense():
-    outs = {}
-    for unit in ("vpu", "mxu", "mxu_band"):
-        dd, hs = _mk(mult=2)
-        s = dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                         compute_unit=unit, mxu_kernel=mean6_kernel_mxu)
-        assert s._stream_plan["compute_unit"] == unit
-        dd.run_step(s, 4)
-        outs[unit] = dd.quantity_to_host(hs[0])
-    assert_reassociation_close(
-        outs["mxu_band"], outs["vpu"], rounds=MXU_ULPS_PER_LEVEL * 4,
-        scale=6.0, context="stream band-vs-vpu",
-    )
-    assert_reassociation_close(
-        outs["mxu_band"], outs["mxu"], rounds=4, scale=6.0,
-        context="stream band-vs-dense",
-    )
-
-
-def test_stream_mxu_band_bf16_input_via_domain():
-    dd_a, hs_a = _mk(mult=2)
-    dd_b, hs_b = _mk(mult=2)
-    sa = dd_a.make_step(mean6_kernel, engine="stream", interpret=True,
-                        compute_unit="mxu_band",
-                        mxu_kernel=mean6_kernel_mxu)
-    sb = dd_b.make_step(mean6_kernel, engine="stream", interpret=True,
-                        compute_unit="mxu_band", mxu_input="bf16",
-                        mxu_kernel=mean6_kernel_mxu)
-    assert sa._stream_plan["mxu_input"] == "f32"
-    assert sb._stream_plan["mxu_input"] == "bf16"
-    dd_a.run_step(sa, 3)
-    dd_b.run_step(sb, 3)
-    assert_mxu_bf16_input_close(
-        dd_b.quantity_to_host(hs_b[0]), dd_a.quantity_to_host(hs_a[0]),
-        levels=3, context="stream band bf16in",
-    )
-
-
-def test_mxu_input_resolution_precedence_and_guards(monkeypatch):
-    # static
-    assert resolve_mxu_input(None, None, "mxu")[0] == "f32"
-    # env beats static; engages only under an MXU unit
-    monkeypatch.setenv("STENCIL_MXU_INPUT", "bf16")
-    assert resolve_mxu_input(None, None, "mxu_band")[0] == "bf16"
-    val, src = resolve_mxu_input(None, None, "vpu")
-    assert val == "f32" and src.endswith("/degraded")
-    # explicit beats env
-    assert resolve_mxu_input("f32", None, "mxu")[0] == "f32"
-    monkeypatch.setenv("STENCIL_MXU_INPUT", "fp8")
-    with pytest.raises(ValueError, match="STENCIL_MXU_INPUT"):
-        resolve_mxu_input(None, None, "mxu")
-    monkeypatch.delenv("STENCIL_MXU_INPUT")
-    # tuned consulted, garbage falls through to static
-    assert resolve_mxu_input(None, "bf16", "mxu")[0] == "bf16"
-    assert resolve_mxu_input(None, "fp8", "mxu")[0] == "f32"
-    with pytest.raises(ValueError, match="unknown mxu input"):
-        dd, _ = _mk()
-        dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                     mxu_input="fp8")
-
-
-def test_ladder_steps_band_to_dense_to_vpu_same_depth(tune_dir):
-    """Two classified failures on an mxu_band stream rung walk the
-    contraction ladder band -> dense -> vpu at the SAME depth before any
-    depth descent, and the floor matches the vpu ground truth bitwise."""
-    dd, hs = _mk(mult=2)
-    step = dd.make_step(mean6_kernel, engine="stream", interpret=True,
-                        compute_unit="mxu_band",
-                        mxu_kernel=mean6_kernel_mxu)
-    plan0 = dict(step._stream_plan)
-    assert plan0["compute_unit"] == "mxu_band"
-    inject.set_plan("execute:vmem_oom:stream*2")
-    try:
-        dd.run_step(step, 4)
-    finally:
-        inject.set_plan(None)
-    assert step._stream_plan["compute_unit"] == "vpu"
-    assert step._stream_plan["m"] == plan0["m"]  # SAME depth throughout
-    assert [d[0] for d in step._resilience.descents] == [
-        f"{plan0['route']}[m={plan0['m']},mxu_band]",
-        f"{plan0['route']}[m={plan0['m']},mxu]",
-    ]
-    ref_dd, ref_hs = _mk(mult=2)
-    ref = ref_dd.make_step(mean6_kernel, engine="stream", interpret=True)
-    ref_dd.run_step(ref, 4)
-    np.testing.assert_array_equal(
-        ref_dd.quantity_to_host(ref_hs[0]), dd.quantity_to_host(hs[0])
-    )
-
-
-def test_jacobi_ladder_steps_band_down_to_dense(tune_dir):
-    m = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                 compute_unit="mxu_band", temporal_k=3,
-                 devices=jax.devices()[:1])
-    m.realize()
-    assert m._compute_unit == "mxu_band" and m._wrap_k == 3
-    inject.set_plan("execute:vmem_oom:jacobi*1")
-    try:
-        m.step(3)
-    finally:
-        inject.set_plan(None)
-    assert m._compute_unit == "mxu"  # band -> dense, not straight to vpu
-    assert m._wrap_k == 3  # depth untouched
-    ref = Jacobi3D(24, 24, 24, kernel_impl="pallas", interpret=True,
-                   temporal_k=3, devices=jax.devices()[:1])
-    ref.realize()
-    ref.step(3)
-    assert_ulp_close(m.temperature(), ref.temperature(),
-                     ulps=MXU_ULPS_PER_LEVEL * 3, context="post-band-descent")
-
-
-def test_spaces_grow_band_twins_no_schema_bump(tune_dir):
-    from stencil_tpu.tune import space as tune_space
-
-    # wrap space: band twin + its bf16-input leg at the static depth
-    cands, _ = tune_space.jacobi_wrap_space((64, 64, 64), 4, 4)
-    band = [c for c in cands if c["compute_unit"] == "mxu_band"]
-    assert len(band) == 2
-    assert {c.get("mxu_input", "f32") for c in band} == {"f32", "bf16"}
-    # wavefront space: gated by band_ok
-    cands, pre = tune_space.jacobi_wavefront_space(
-        2, 4, False, False, mxu_ok=True, bf16_ok=True, band_ok=True)
-    assert [c for c in cands if c["compute_unit"] == "mxu_band"]
-    cands2, pre2 = tune_space.jacobi_wavefront_space(
-        2, 4, False, False, mxu_ok=True, bf16_ok=True, band_ok=False)
-    assert not [c for c in cands2 if c["compute_unit"] == "mxu_band"]
-    assert pre2 >= pre + 2
-    # stream space: the band twin of the static plan
-    dd, _ = _mk(mult=2)
-    with tune.disabled():
-        static = sm.plan_stream(dd, 1, "auto", False)
-    scands, _ = tune_space.stream_space(dd, 1, False, static, mxu_ok=True)
-    assert [c for c in scands if c["compute_unit"] == "mxu_band"]
-
-
-def test_tuned_mxu_band_and_input_consulted_no_schema_bump(tune_dir):
-    """A persisted compute_unit=mxu_band / mxu_input=bf16 winner is
-    consulted by the next auto build; garbage mxu_input invalidates to
-    the static plan; pre-variant entries stay warm (covered by
-    test_pre_axis_cache_entry_without_fields_still_hits)."""
-    dd, _ = _mk(mult=2)
-    key = dd.tune_key("stream")
-    tune.record_config(
-        key,
-        {"route": "wavefront", "m": 2, "z_slabs": False, "grouping": "joint",
-         "compute_unit": "mxu_band", "mxu_input": "bf16",
-         "halo_multiplier": 2},
-    )
-    tune.reset_memo()
-    dd2, _ = _mk(mult=2)
-    step = dd2.make_step(mean6_kernel, engine="stream", interpret=True,
-                         mxu_kernel=mean6_kernel_mxu)
-    assert step._stream_plan["compute_unit"] == "mxu_band"
-    assert step._stream_plan["mxu_input"] == "bf16"
-    dd2.run_step(step, 2)
-    # garbage mxu_input -> the static plan, never a crash
-    tune.record_config(
-        key,
-        {"route": "wavefront", "m": 2, "z_slabs": False, "grouping": "joint",
-         "mxu_input": "fp8", "halo_multiplier": 2},
-    )
-    tune.reset_memo()
-    dd3, _ = _mk(mult=2)
-    step3 = dd3.make_step(mean6_kernel, engine="stream", interpret=True,
-                          mxu_kernel=mean6_kernel_mxu)
-    assert step3._stream_plan["z_slabs"]  # the static plan applied
-    assert step3._stream_plan["mxu_input"] == "f32"
-
-
-def test_band_vmem_model_prices_tiles_not_circulants():
-    """The band variant's VMEM term is the KB-scale wide tiles: a budget
-    that rejects the dense mxu twin admits the band twin at the same
-    depth — the 'previously VMEM-pruned mxu candidates become admissible'
-    claim, checked through the shared models."""
-    from stencil_tpu.analysis import vmem as avmem
-    from stencil_tpu.ops.jacobi_pallas import (
-        mxu_vmem_extra_bytes,
-        wavefront_vmem_bytes,
-    )
-
-    Y = Z = 512
-    dense = mxu_vmem_extra_bytes(Y, Z, "mxu")
-    band = mxu_vmem_extra_bytes(Y, Z, "mxu_band")
-    assert band < dense // 100  # KBs vs MBs
-    assert mxu_vmem_extra_bytes(Y, Z, "mxu", "bf16") < dense
-    assert wavefront_vmem_bytes(8, Y, Z, 4, mxu="mxu_band") < \
-        wavefront_vmem_bytes(8, Y, Z, 4, mxu=True)
-    e_band = avmem.stream_plan_vmem_bytes(4, Y, Z, [4], mxu="mxu_band")
-    e_dense = avmem.stream_plan_vmem_bytes(4, Y, Z, [4], mxu=True)
-    assert e_band < e_dense

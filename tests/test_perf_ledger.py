@@ -212,46 +212,6 @@ class TestIngest:
         p2.write_text(json.dumps(bad))
         assert ledger.entries_from_artifact(str(p2)) == []
 
-    def test_bench_mxu_ab_legs(self, tmp_path):
-        """bench.py's mxu_vs_vpu section lands each compute-unit leg as a
-        regression-gated mxu_ab:* series (vpu / mxu / mxu_band /
-        mxu_band+bf16in) — higher-is-better Mcells/s, so a contraction-leg
-        regression trips the trailing-median gate like a headline drop."""
-        doc = {
-            "metric": "jacobi3d_mcells_per_s_per_chip",
-            "value": 100.0,
-            "unit": "Mcells/s",
-            "mxu_vs_vpu": {
-                "eligible": True,
-                "band_eligible": True,
-                "k": 16,
-                "units": {
-                    "vpu": {"ms_per_dispatch": 1.0, "mcells_per_s": 400.0},
-                    "mxu": {"ms_per_dispatch": 2.0, "mcells_per_s": 200.0},
-                    "mxu_band": {"ms_per_dispatch": 0.8,
-                                 "mcells_per_s": 500.0},
-                    "mxu_band+bf16in": {"ms_per_dispatch": 0.5,
-                                        "mcells_per_s": 800.0},
-                },
-                "speedups_vs_vpu": {"mxu": 0.5, "mxu_band": 1.25,
-                                    "mxu_band+bf16in": 2.0},
-            },
-        }
-        p = tmp_path / "BENCH_mxu.json"
-        p.write_text(json.dumps(doc))
-        entries = ledger.entries_from_artifact(str(p))
-        keys = {e["key"]: e["value"] for e in entries}
-        assert keys["mxu_ab:vpu:mcells_per_s"] == 400.0
-        assert keys["mxu_ab:mxu:mcells_per_s"] == 200.0
-        assert keys["mxu_ab:mxu_band:mcells_per_s"] == 500.0
-        assert keys["mxu_ab:mxu_band+bf16in:mcells_per_s"] == 800.0
-        mxu_entries = [e for e in entries if e["key"].startswith("mxu_ab:")]
-        assert all(e["k"] == 16 for e in mxu_entries)
-        # pre-band artifacts (no mxu_vs_vpu section) still ingest cleanly
-        q = tmp_path / "BENCH_old.json"
-        q.write_text(json.dumps({"metric": "m", "value": 1.0, "unit": "u"}))
-        assert ledger.entries_from_artifact(str(q))
-
     def test_unknown_shapes_are_skipped(self, tmp_path):
         p = tmp_path / "x.json"
         p.write_text(json.dumps({"something": "else"}))

@@ -97,14 +97,14 @@ def test_astaroth_wavefront_uneven_and_jnp_guard():
 
 
 def test_mean6_kernel_axes_variants():
-    """The bespoke mean6 kernels' compute-unit / storage-dtype variants
-    (ISSUE 7): nothing in the shipped models calls these two directly (the
-    astaroth wavefront rides ops/stream.py), so pin the mxu and
-    f32-accumulate forms HERE against their vpu/native siblings or they
-    rot as the shared helpers (_make_level_sum, band_matrix) evolve."""
+    """The bespoke mean6 kernels' storage-dtype variants (ISSUE 7): nothing
+    in the shipped models calls these two directly (the astaroth wavefront
+    rides ops/stream.py), so pin the f32-accumulate forms HERE against
+    their native siblings or they rot as the shared helper
+    (_level_sum) evolves."""
     import jax.numpy as jnp
 
-    from ulp import assert_bf16_storage_close, assert_ulp_close
+    from ulp import assert_bf16_storage_close
 
     from stencil_tpu.core.dim3 import Dim3
     from stencil_tpu.ops.plane_stencil import (
@@ -119,15 +119,11 @@ def test_mean6_kernel_axes_variants():
     fresh = lambda dt=jnp.float32: jnp.asarray(src, dt)
     raw = fresh()
 
-    # temporal wavefront: mxu ≤4 ulps/level; bf16 one downcast per pass.
+    # temporal wavefront: bf16 one downcast per pass.
     # Only the interior is valid at level m (the shell carries garbage by
     # the validity contract), so compare inside the shell_width=3 ring.
     core = (slice(3, 13),) * 3
     v = mean6_shell_wavefront_step(fresh(), m=2, shell_width=3, interpret=True)
-    m = mean6_shell_wavefront_step(fresh(), m=2, shell_width=3, interpret=True,
-                                   compute_unit="mxu")
-    assert_ulp_close(np.asarray(m)[core], np.asarray(v)[core], ulps=4 * 2,
-                     context="mean6 wavefront mxu")
     b = mean6_shell_wavefront_step(fresh(jnp.bfloat16), m=2,
                                    shell_width=3, interpret=True,
                                    f32_accumulate=True)
@@ -136,13 +132,10 @@ def test_mean6_kernel_axes_variants():
                               passes=1, scale=1.0,
                               context="mean6 wavefront bf16")
 
-    # single-level plane pass: same contracts (interior window only — the
+    # single-level plane pass: same contract (interior window only — the
     # pass-through shell keeps its input bytes in every variant)
     one = Dim3(1, 1, 1)
     pv = mean6_plane_step(raw, one, one, interpret=True)
-    pm = mean6_plane_step(raw, one, one, interpret=True, compute_unit="mxu")
-    assert_ulp_close(np.asarray(pm), np.asarray(pv), ulps=4,
-                     context="mean6 plane mxu")
     pb = mean6_plane_step(raw.astype(jnp.bfloat16), one, one, interpret=True,
                           f32_accumulate=True)
     assert_bf16_storage_close(pb, pv, passes=1, scale=1.0,

@@ -191,6 +191,77 @@ def test_stream_wavefront_route():
         np.testing.assert_allclose(a, b, **TOL)
 
 
+def _mean6_roll_reference(a, steps):
+    """``steps`` mean-of-6 updates of the whole periodic array by ``jnp.roll``,
+    in ``mean6_kernel``'s own order of taps."""
+    for _ in range(steps):
+        a = (
+            jnp.roll(a, 1, 0) + jnp.roll(a, 1, 1) + jnp.roll(a, 1, 2)
+            + jnp.roll(a, -1, 0) + jnp.roll(a, -1, 1) + jnp.roll(a, -1, 2)
+        ) / 6.0
+    return a
+
+
+#: (id, extent, devices, halo multiplier, stream_path, route, depth): every
+#: geometry x route the removed matrix-unit pins ran their roll + add side at
+#: (16^3 and 24^3 over 8 devices at multipliers 2 and 3, one device), plus
+#: the plane route and a padded last shard
+_MEAN6_ROUTES = [
+    ("plane-8dev", (16, 16, 16), 8, 1, "auto", "plane", 1),
+    ("plane-forced-1dev", (16, 16, 16), 1, 1, "plane", "plane", 1),
+    ("wavefront-m2", (16, 16, 16), 8, 2, "auto", "wavefront", 2),
+    ("wavefront-m3", (16, 16, 16), 8, 3, "auto", "wavefront", 3),
+    ("wavefront-m3-24", (24, 24, 24), 8, 3, "auto", "wavefront", 3),
+    ("wavefront-m2-uneven", (17, 17, 17), 8, 2, "auto", "wavefront", 2),
+    ("wrap-1dev", (16, 16, 16), 1, 1, "auto", "wrap", 8),
+]
+
+
+@pytest.mark.parametrize("storage", ["native", "bf16"])
+@pytest.mark.parametrize(
+    "extent,n_dev,mult,path,route,depth",
+    [c[1:] for c in _MEAN6_ROUTES], ids=[c[0] for c in _MEAN6_ROUTES],
+)
+def test_stream_mean6_routes_match_roll_reference(
+    extent, n_dev, mult, path, route, depth, storage
+):
+    """The mean-of-6 kernel on every stream route, native and bf16 storage,
+    against ``jnp.roll`` on the global array: native to a few reordered
+    roundings (a fused m-level graph may differ from m dispatches in the last
+    ulp per level under interpret), bf16 storage to its analytic bound of one
+    rounding per stored pass."""
+    from ulp import assert_bf16_storage_close, assert_reassociation_close
+
+    steps = 4
+    dd = DistributedDomain(*extent)
+    dd.set_radius(Radius.constant(1))
+    dd.set_devices(jax.devices()[:n_dev])
+    if mult != 1:
+        dd.set_halo_multiplier(mult)
+    h = dd.add_data("u")
+    if storage == "bf16":
+        dd.set_storage("bf16")
+    dd.realize()
+    dd.init_by_coords(h, lambda x, y, z: jnp.sin(0.13 * (x + 2 * y + 3 * z)))
+    x, y, z = np.meshgrid(*(np.arange(n) for n in extent), indexing="ij")
+    start = jnp.sin(0.13 * jnp.asarray(x + 2 * y + 3 * z)).astype(jnp.float32)
+    step = dd.make_step(mean6_kernel, engine="stream", stream_path=path, interpret=True)
+    plan = step._stream_plan
+    assert (plan["route"], plan["m"]) == (route, depth), plan
+    assert dd.storage_dtype() == storage
+    dd.run_step(step, steps)
+    assert step._resilience.descents == []
+    got = dd.quantity_to_host(h)
+    want = np.asarray(_mean6_roll_reference(start, steps))
+    if storage == "bf16":
+        # the fill quantizes once, then one downcast per stored pass at most
+        assert_bf16_storage_close(got, want, passes=steps + 1, context=route)
+    else:
+        assert_reassociation_close(
+            got, want, rounds=2 * steps, scale=6.0, context=route
+        )
+
+
 def test_stream_wavefront_wide_radius_narrow_reads():
     """Astaroth's pattern: radius-3 shell, distance-1 reads — the engine
     wavefronts m=3 against ONE exchange without a halo multiplier."""
@@ -364,8 +435,7 @@ def test_stream_separable_per_field_grouping(monkeypatch):
     assert step._stream_plan == {
         "route": "wavefront", "m": 3, "z_slabs": True, "grouping": "per-field",
         "alias": True,  # four fields: the wavefront's static rule, written back
-        "overlap": "off", "halo": "array", "compute_unit": "vpu",
-        "mxu_input": "f32",
+        "overlap": "off", "halo": "array",
     }
     monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
     ref_dd, ref_hs = _mk(24, 24, 24, Radius.constant(1), names, devs)
@@ -432,8 +502,7 @@ def test_stream_depth_cap():
     assert step._stream_plan == {
         "route": "wrap", "m": 8, "z_slabs": False, "grouping": "joint",
         "alias": False,  # the wrap pass has no in-place form
-        "overlap": "off", "halo": "array", "compute_unit": "vpu",
-        "mxu_input": "f32",
+        "overlap": "off", "halo": "array",
     }
     for a, b in outs:  # uncapped wrap vs the XLA ground truth
         np.testing.assert_allclose(a, b, **TOL)

@@ -216,7 +216,7 @@ class TestSpans:
     def test_counter_tracks_in_chrome_trace(self, tmp_path):
         """The metrics registry rides the trace as Chrome counter-track
         ("ph":"C") events sampled at span records — Perfetto shows
-        cumulative exchange bytes / MXU flops as a throughput track under
+        cumulative exchange / packed bytes as a throughput track under
         the spans.  Identical consecutive values are deduped."""
         telemetry.enable(dir=str(tmp_path))
         telemetry.inc(names.EXCHANGE_BYTES, 1024)
@@ -225,7 +225,7 @@ class TestSpans:
         with telemetry.span(names.SPAN_SWAP):
             pass  # bytes unchanged: no second sample
         telemetry.inc(names.EXCHANGE_BYTES, 1024)
-        telemetry.inc(names.KERNEL_MXU_FLOPS, 500)
+        telemetry.inc(names.EXCHANGE_PACKED_BYTES, 500)
         with telemetry.span(names.SPAN_STEP):
             pass
         doc = json.loads(open(telemetry.dump_chrome_trace()).read())
@@ -235,8 +235,10 @@ class TestSpans:
         ]
         assert [e["args"]["value"] for e in bytes_track] == [1024, 2048]
         assert all(e["ts"] >= 0 for e in tracks)
-        mxu_track = [e for e in tracks if e["name"] == names.KERNEL_MXU_FLOPS]
-        assert [e["args"]["value"] for e in mxu_track] == [0, 500]
+        packed_track = [
+            e for e in tracks if e["name"] == names.EXCHANGE_PACKED_BYTES
+        ]
+        assert [e["args"]["value"] for e in packed_track] == [0, 500]
         # spans still render as complete events alongside the tracks
         assert any(e["ph"] == "X" for e in doc["traceEvents"])
 

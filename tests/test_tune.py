@@ -222,7 +222,7 @@ def test_search_vmem_oom_prunes_deeper_wavefront_style_candidates():
 
     inject.set_plan(
         "compile:vmem_oom:tune:synthetic:"
-        "alias=0/compute_unit=vpu/halo_multiplier=8/m=8"
+        "alias=0/halo_multiplier=8/m=8"
     )
     try:
         report = search(key, cands, build_run, depth_key="m", reps=1, rt=0.0)
@@ -231,7 +231,7 @@ def test_search_vmem_oom_prunes_deeper_wavefront_style_candidates():
     # the alias=False m=8 OOM prunes alias=False m=12 untried; the alias=True
     # family is untouched
     assert (12, False) not in built
-    axes = {"compute_unit": "vpu", "storage_dtype": "native"}
+    axes = {"storage_dtype": "native"}
     assert report.result_for(
         {"m": 12, "halo_multiplier": 12, "alias": False, "z_ring": False, **axes}
     ).pruned
@@ -355,10 +355,8 @@ def test_forced_small_vmem_budget_prunes_deep_k(tune_dir, monkeypatch):
         16, 16, 16, interpret=True, reps=1, ks=[1, 2, 4], rt=0.0
     )
     # nothing beyond the static k=1 fits a 1-byte model budget (the
-    # mxu/bf16 twins are VMEM-gated too; winners carry the axes explicitly)
-    assert report.config == {
-        "k": 1, "compute_unit": "vpu", "storage_dtype": "native"
-    }
+    # bf16 twin is VMEM-gated too; winners carry the axis explicitly)
+    assert report.config == {"k": 1, "storage_dtype": "native"}
     assert report.pruned >= 2
     assert _counter(tm.TUNE_PRUNED) >= p0 + 2
 
@@ -450,6 +448,77 @@ def test_plan_stream_consults_and_validates(tune_dir):
     )
     tune.reset_memo()
     assert plan_stream(dd, 1) == static
+
+
+def _consult_stream(fields):
+    """(what the stream planner took from a cache entry carrying ``fields``,
+    the tuned plan, the static plan)."""
+    from stencil_tpu.core.radius import Radius
+    from stencil_tpu.domain import DistributedDomain
+    from stencil_tpu.ops.stream import plan_stream
+
+    dd = DistributedDomain(16, 16, 16)
+    dd.set_radius(Radius.constant(1))
+    dd.set_devices([jax.devices()[0]])
+    dd.add_data("q")
+    dd.realize()
+    static = plan_stream(dd, 1)
+    tuned = {"route": "wrap", "m": 2, "z_slabs": False, "grouping": "joint"}
+    assert static != tuned
+    tune.record_config(dd.tune_key("stream"), dict(tuned, **fields))
+    tune.reset_memo()  # consult the FILE, as the next process would
+    return plan_stream(dd, 1), tuned, static
+
+
+def _consult_jacobi(fields):
+    """The same for the bespoke wavefront's depth (static 2, tuned 3)."""
+    from stencil_tpu.models.jacobi import Jacobi3D
+
+    def model():
+        return Jacobi3D(16, 16, 16, kernel_impl="pallas",
+                        pallas_path="wavefront", interpret=True)
+
+    with tune.disabled():
+        static = model()._plan_wavefront()
+    assert static != 3
+    probe = model()
+    tune.record_config(
+        probe.dd.tune_key("jacobi-wavefront"),
+        dict({"m": 3, "halo_multiplier": 3, "alias": False, "z_ring": False}, **fields),
+    )
+    tune.reset_memo()
+    return probe._plan_wavefront(), 3, static
+
+
+@pytest.mark.parametrize(
+    "fields,warm",
+    [
+        ({"compute_unit": "mxu"}, False),
+        ({"compute_unit": "mxu_band"}, False),
+        ({"compute_unit": "mxu_band", "mxu_input": "bf16"}, False),
+        ({"compute_unit": "vpu", "mxu_input": "f32"}, True),
+    ],
+    ids=["mxu", "mxu_band", "mxu_band+bf16in", "vpu+f32"],
+)
+@pytest.mark.parametrize("consult", [_consult_stream, _consult_jacobi],
+                         ids=["stream", "jacobi"])
+def test_record_of_a_removed_kernel_form_is_a_logged_miss(
+    tune_dir, capsys, consult, fields, warm
+):
+    """A cache entry written before the matrix-unit axis pair went (PR 29):
+    one that names ``mxu`` / ``mxu_band`` / ``bf16`` operands holds a depth
+    measured for another kernel — a logged miss, never a crash, never a warm
+    hit; one that names ``vpu`` / ``f32`` is what every cache written on a
+    chip holds — it stays warm and the two fields are ignored."""
+    h0, m0 = _counter(tm.TUNE_CACHE_HIT), _counter(tm.TUNE_CACHE_MISS)
+    got, tuned, static = consult(fields)
+    logged = "no longer exists" in capsys.readouterr().err
+    if warm:
+        assert got == tuned and not logged  # no axis field rides the plan
+        assert _counter(tm.TUNE_CACHE_HIT) > h0
+    else:
+        assert got == static and logged
+        assert _counter(tm.TUNE_CACHE_MISS) > m0
 
 
 # --- compile cache + driver flags -------------------------------------------

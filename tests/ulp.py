@@ -1,30 +1,19 @@
 """Shared tolerance-aware equivalence helpers: ULP distances instead of
 ad-hoc ``atol`` constants.
 
-Two formulations of the same stencil arithmetic (the MXU banded contraction
-vs the VPU roll+add chain, a fused m-level graph vs m separate dispatches)
-differ only in summation order / excess precision, so the principled
-equivalence statement is a bound in UNITS IN THE LAST PLACE of the result's
-own dtype — one rounding's worth of divergence per reassociated operation —
-not an absolute epsilon picked to make the test pass.  These helpers back:
+Two formulations of the same stencil arithmetic (a fused m-level graph vs m
+separate dispatches, a kernel vs its XLA twin) differ only in summation
+order / excess precision, so the principled equivalence statement is a bound
+in UNITS IN THE LAST PLACE of the result's own dtype — one rounding's worth
+of divergence per reassociated operation — not an absolute epsilon picked to
+make the test pass.  These helpers back:
 
-* the ``compute_unit=mxu`` contract (ISSUE 7): ≤ 1 ulp PER LEVEL against
-  the vpu form at f32 — a pure summation-order statement (the contraction
-  accumulates the four in-plane taps in a different order), compounding to
-  ≤ ``levels`` ulps over a fused multi-level pass (each level adds at most
-  one rounding on top of the carried divergence; the mean-of-6 averages,
-  never amplifies, the carried term).
 * the wavefront excess-precision caveat (PERF_NOTES "Equivalence": a fused
   m-level graph vs m separate dispatches may differ in the LAST ulp per
   level through the division — interpret mode only, bitwise on hardware).
-* the bf16-storage analytic bound (docs/tuning.md "Compute unit and
-  storage dtype"): f32 accumulate with ONE round-to-nearest-bf16 per pass
-  — see :func:`bf16_storage_atol`.
-* the ``mxu_input=bf16`` analytic bound (ISSUE 13): bfloat16 contraction
-  OPERANDS under the unchanged f32 accumulator — one rounding per in-plane
-  operand read per level, see :func:`mxu_bf16_input_atol`; and the
-  band-vs-dense variant pin (``mxu_band``), which is pure summation order
-  and rides :func:`assert_ulp_close` in the same regime as mxu-vs-vpu.
+* the bf16-storage analytic bound (docs/tuning.md "Storage dtype"): f32
+  accumulate with ONE round-to-nearest-bf16 per pass — see
+  :func:`bf16_storage_atol`.
 """
 
 import numpy as np
@@ -120,46 +109,6 @@ def assert_reassociation_close(actual, desired, rounds: int,
         f"{context or 'reassociated forms'} diverged {err:.3e} "
         f"(analytic bound {atol:.3e} = {rounds} roundings * half-ulp at "
         f"scale {scale:.3g}, dtype {d.dtype})"
-    )
-
-
-def mxu_bf16_input_atol(levels: int, scale: float, taps: int = 4) -> float:
-    """Analytic absolute bound for ``mxu_input=bf16`` (bfloat16 contraction
-    OPERANDS, f32 accumulator) against the f32-input form of the SAME
-    kernel after ``levels`` fused levels.
-
-    Per level, the in-plane contraction reads ``taps`` neighbor values
-    (4 for the face stencil: ±1 on each in-plane axis) through one
-    round-to-nearest-bfloat16 each — relative error ≤ 2^-9 per operand
-    (8 significand bits) AT THE OPERAND'S OWN MAGNITUDE, bounded by
-    ``scale`` — while the 0/1/2 band constants are exact in bfloat16 and
-    the f32 accumulator adds no new error class.  The mean-of-N is convex,
-    so carried error passes through undamaged but unamplified and each
-    level adds at most ``taps · 2^-9 · scale`` BEFORE its division:
-    ``levels · taps · 2^-9 · scale`` total, conservative by the ~/N of
-    each mean.  Operand-scale-aware like ``reassociation_atol`` — the
-    right yardstick where results cross zero and result-relative ulps
-    blow up on operand-scale divergence."""
-    return levels * taps * 2.0 ** -9 * scale
-
-
-def assert_mxu_bf16_input_close(actual, desired_f32, levels: int,
-                                scale: float = None, taps: int = 4,
-                                context: str = "") -> None:
-    """Pin a bf16-INPUT mxu run against its f32-input ground truth to the
-    analytic bound above.  ``scale`` defaults to the ground truth's max
-    magnitude (the contraction operands are field values, so that is the
-    operand bound for jacobi/mean6-class kernels)."""
-    a = np.asarray(actual, np.float32)
-    d = np.asarray(desired_f32, np.float32)
-    if scale is None:
-        scale = float(np.abs(d).max()) or 1.0
-    atol = mxu_bf16_input_atol(levels, scale, taps)
-    err = float(np.abs(a - d).max()) if a.size else 0.0
-    assert err <= atol, (
-        f"{context or 'bf16-input mxu'} diverged {err:.3e} from the "
-        f"f32-input ground truth (analytic bound {atol:.3e} = {levels} "
-        f"levels * {taps} operand roundings * 2^-9 * scale {scale:.3g})"
     )
 
 

@@ -18,9 +18,11 @@ What it asks of the runtime, unlike ``Jacobi3D`` and ``AstarothSim``:
   per pass over a ``2r``-deep ring, an exchange every step);
 * two time levels through a contract that knows one: the kernel returns
   ``u <- u+`` and ``u_prev <- u`` in the same pass;
-* model fields ``m`` and ``damp`` that are read and never written (the
-  engine still carries them through the pass and the exchange: docs/acoustic.md
-  says what that costs);
+* model fields ``m`` and ``damp`` that are read and never written, and at
+  the centre only: like ``u_prev`` they stay out of the step's exchange
+  (the engine exchanges what the kernel reads off-centre, which is ``u``
+  alone: ``ops/stream.py plane_halo_readers``), but still ride through the
+  pass (docs/acoustic.md says what that costs);
 * a Dirichlet edge on a periodic runtime: the ``FRAME`` outer cells are
   pinned to zero BY THE KERNEL from ``info.coords()`` -- no model field
   could do it (``u+`` has no coefficient that a zero would null), and no

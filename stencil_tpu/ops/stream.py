@@ -14,9 +14,11 @@ vs ~40+ for the streamed form).
 
 Two routes, chosen by ``make_stream_step``:
 
-* **plane** — one level per pass: exchange the shell, then stream planes
-  with a ``2r``-deep ring (``r`` = the kernel's declared x read distance).
-  Works for any per-axis shell widths and any ``r >= 1``.
+* **plane** — one level per pass: exchange the shell of every quantity the
+  kernel reads off-centre (``plane_halo_readers``; the others' shells are
+  read by nothing), then stream planes with a ``2r``-deep ring (``r`` = the
+  kernel's declared x read distance).  Works for any per-axis shell widths
+  and any ``r >= 1``.
 * **wavefront** — ``m`` levels per pass over an ``s``-wide-shell shard
   (``m <= s // r``, ``r == 1`` only): each HBM plane is read and written
   once per ``m`` iterations (~``8/m`` B/cell), the temporal blocking that
@@ -141,12 +143,18 @@ class PlaneView:
     x offset selects one of the ``2r+1`` VMEM-resident planes, the y/z
     offsets are in-plane rotates.  Rotate wraparound at the plane edges only
     contaminates shell cells the validity contract already sacrifices.
+
+    ``off_centre`` is called, at trace time, on every read with a non-zero
+    offset (``center()`` and ``sh(0, 0, 0)`` never call it): the plane
+    route's footprint trace records the quantity there, and its pass raises
+    there for a quantity whose halo was not filled (``plane_halo_readers``).
     """
 
-    def __init__(self, window: Tuple[jax.Array, ...], roll):
+    def __init__(self, window: Tuple[jax.Array, ...], roll, off_centre=None):
         self._window = window
         self._r = (len(window) - 1) // 2
         self._roll = roll
+        self._off_centre = off_centre
 
     def sh(self, dx: int = 0, dy: int = 0, dz: int = 0) -> jax.Array:
         # ALL axes are bounded by the declared read radius: an in-plane
@@ -156,6 +164,8 @@ class PlaneView:
         assert all(-self._r <= d <= self._r for d in (dx, dy, dz)), (
             (dx, dy, dz), self._r,
         )
+        if self._off_centre is not None and (dx or dy or dz):
+            self._off_centre()
         v = self._window[self._r + dx]
         if dy:
             v = self._roll(v, -dy, 0)
@@ -240,6 +250,8 @@ def stream_plane_pass(
     fused_shell=None,  # (xbufs, ybufs, zbufs) per quantity — the packed
     # halo messages land in the level-0 planes in VMEM instead of having
     # been unpacked into the blocks (halo="fused"; see module docstring)
+    halo_readers: Optional[Sequence[str]] = None,  # the quantities whose
+    # shell was filled (plane_halo_readers); None = every one
 ) -> List[jax.Array]:
     """ONE kernel level over shell-carrying blocks, streaming x-planes with a
     ``2r``-deep ring per quantity; shell planes and the in-plane shell ring
@@ -268,7 +280,13 @@ def stream_plane_pass(
     ``i - r`` is written.  The ``inplace-order`` contract
     (``analysis/kernels.py check_inplace_order``) proves this from the
     traced block maps; CPU interpret mode runs an aliased call
-    functionally and cannot."""
+    functionally and cannot.
+
+    With ``halo_readers`` the shells of the OTHER quantities are stale (the
+    step exchanged only what the kernel's footprint trace saw read
+    off-centre): an off-centre ``sh`` on one of them in THIS trace raises
+    and names it, so a kernel that traces differently the second time can
+    never read a stale cell silently."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -282,6 +300,20 @@ def stream_plane_pass(
     roll = _make_roll(interpret)
     gsize = global_size
     up = (lambda v: v.astype(jnp.float32)) if f32_accumulate else (lambda v: v)
+
+    def stale_read(name):
+        if halo_readers is None or name in halo_readers:
+            return None
+
+        def fail():
+            raise ValueError(
+                f"the kernel reads {name!r} off-centre, but its footprint "
+                f"trace did not (it saw {tuple(halo_readers)}), so the halo "
+                f"of {name!r} was not exchanged: a kernel must read the same "
+                "offsets every time it is traced"
+            )
+
+        return fail
 
     def body(origin_ref, *refs):
         in_refs = refs[:nq]
@@ -325,6 +357,7 @@ def stream_plane_pass(
                     names[q]: PlaneView(
                         tuple(up(plane(q, 2 * r - d)) for d in range(2 * r + 1)),
                         roll,
+                        stale_read(names[q]),
                     )
                     for q in range(nq)
                 }
@@ -1044,6 +1077,73 @@ def permute_and_extend_z_slabs(zout, s: int, mesh_shape, yext, xext):
         return jnp.concatenate([xext(yext(zlo)), xext(yext(zhi))], axis=1)
 
 
+def plane_halo_readers(
+    kernel: PlaneKernel,
+    names: Sequence[str],
+    groups: Sequence[Sequence[int]],  # the passes' quantity indices
+    planes: Sequence[jax.ShapeDtypeStruct],  # per quantity, as the kernel sees it
+    x_radius: int,
+    global_size: Dim3,
+) -> Tuple[str, ...]:
+    """The quantities a PLANE-route step exchanges: those ``kernel`` reads
+    off-centre, in ``names``' order — learnt from the kernel itself by one
+    abstract trace (``jax.eval_shape``, nothing runs) over ``PlaneView``s
+    that record every ``sh`` with a non-zero offset, called group by group
+    as the passes call it.  A function of the kernel, as ``_sweep_kind`` is
+    a function of the mesh: no option, no plan value a user sets.
+
+    Why the others keep a stale shell and the result is the same.  The plane
+    pass is ONE level and writes interior cells only (shell planes and the
+    in-plane shell ring pass through), so an interior cell's new value
+    depends on a quantity's shell only through an off-centre read: a centre
+    read of an interior cell is an interior cell.  A quantity outside the
+    readers has its shell read by nothing, and every interior cell of every
+    quantity is bitwise what exchanging all of them gives.  The step marks
+    its shells stale (``step._marks_shell_stale``), so every reader of a
+    shell re-exchanges every quantity, as before.
+
+    Where the rule does NOT hold, and is not applied: the wavefront route —
+    level >= 2 computes cells inside the shell, whose CENTRE reads need the
+    shell of every quantity; ``halo="fused"`` — the side buffers are
+    per-quantity operands of the pass; the wrap route has no exchange.  The
+    plane route's split schedule takes it: its exterior bands are interior
+    cells too.
+
+    Fail closed, twice: a footprint trace that raises exchanges every
+    quantity, and the pass itself raises at trace time on an off-centre read
+    this trace did not see (``stream_plane_pass(halo_readers=)``)."""
+    seen = set()
+    roll = _make_roll(True)  # jnp.roll: the trace runs outside any kernel
+    Y, Z = planes[0].shape
+
+    def footprint(x_g, y_g, z_g, *vs):
+        info = PlaneInfo(x_g, y_g, z_g, global_size, 1)
+        for g in groups:
+            kernel(
+                {
+                    names[q]: PlaneView(
+                        (vs[q],) * (2 * x_radius + 1), roll,
+                        partial(seen.add, names[q]),
+                    )
+                    for q in g
+                },
+                info,
+            )
+
+    i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    try:
+        jax.eval_shape(footprint, i32(()), i32((Y, 1)), i32((1, Z)), *planes)
+    except Exception as exc:  # noqa: BLE001 — whatever the user's kernel raises
+        from stencil_tpu.utils.logging import log_warn
+
+        log_warn(
+            f"the stream kernel's footprint trace raised ({exc!r}); "
+            "exchanging every quantity"
+        )
+        return tuple(names)
+    return tuple(nm for nm in names if nm in seen)
+
+
 def static_stream_alias(route: str, n_fields: int) -> bool:
     """The no-tune alias rule, read from what the plan says of itself: the
     plane route always, any route from 4 fields up (``_build_stream_step``
@@ -1456,6 +1556,26 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
             [lax.axis_index(MESH_AXES[ax]) * n[ax] for ax in range(3)]
         )
 
+    # the quantities that ride the step's exchange, written back like alias
+    # (domain.step's ``exchanged`` counts them): none on the wrap route, on
+    # the plane route those the kernel reads off-centre, every one wherever
+    # the rule does not hold (plane_halo_readers says where and why)
+    if plan["route"] == "wrap":
+        plan["halo_readers"] = ()
+    elif plan["route"] == "plane" and not fused:
+        plan["halo_readers"] = plane_halo_readers(
+            kernel, names, groups,
+            [
+                jax.ShapeDtypeStruct(
+                    (raw.y, raw.z), jnp.float32 if f32_acc else dd.field_dtype(h)
+                )
+                for h in dd._handles
+            ],
+            x_radius, gsize,
+        )
+    else:
+        plan["halo_readers"] = tuple(names)
+
     if plan["route"] == "wrap":
         k = plan["m"]
 
@@ -1490,6 +1610,20 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
 
     elif plan["route"] == "plane":
         in_place = _plan_passes_in_place(plan)
+        readers = plan["halo_readers"]
+        riders = [q for q, name in enumerate(names) if name in readers]
+
+        def exchange_readers(bs):
+            """``bs`` with the halo readers' shells filled — one joint
+            exchange of those blocks alone; the others ride on untouched."""
+            out = list(bs)
+            filled = halo_exchange_multi(
+                [bs[q] for q in riders], shell, mesh_shape,
+                valid_last=valid_last, route=exch_route,
+            )
+            for q, b in zip(riders, filled):
+                out[q] = b
+            return out
 
         def plane_groups(bs, origin, fused_bufs=None):
             out = list(bs)
@@ -1507,7 +1641,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                         kernel, [names[q] for q in g], [bs[q] for q in g],
                         lo, hi, x_radius, origin, gsize, alias=in_place,
                         interpret=interpret, fused_shell=fs,
-                        f32_accumulate=f32_acc,
+                        f32_accumulate=f32_acc, halo_readers=readers,
                     )
                 for q, o in zip(g, outs):
                     out[q] = o
@@ -1550,6 +1684,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                         kernel, [names[q] for q in g], [subs[q] for q in g],
                         lo2, hi2, x_radius, origin_sub, gsize,
                         interpret=interpret, f32_accumulate=f32_acc,
+                        halo_readers=readers,
                     )
                     for q, o in zip(g, outs):
                         out[q] = o
@@ -1563,12 +1698,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                     # the interior pass below also reads those blocks — no
                     # data dependency between them, so XLA's latency-hiding
                     # scheduler flies the collectives behind the pass
-                    ex = list(
-                        halo_exchange_multi(
-                            bs, shell, mesh_shape, valid_last=valid_last,
-                            route=exch_route,
-                        )
-                    )
+                    ex = exchange_readers(bs)
                     with telemetry.annotate(tm.SPAN_OVERLAP_INTERIOR):
                         out = plane_groups(bs, origin)
                     with telemetry.annotate(tm.SPAN_OVERLAP_EXTERIOR):
@@ -1582,13 +1712,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
             def per_shard(steps, *blocks):
                 def body(_, bs):
                     origin = origin_of()
-                    bs = list(
-                        halo_exchange_multi(
-                            bs, shell, mesh_shape, valid_last=valid_last,
-                            route=exch_route,
-                        )
-                    )
-                    return tuple(plane_groups(bs, origin))
+                    return tuple(plane_groups(exchange_readers(bs), origin))
 
                 return lax.fori_loop(0, steps, body, tuple(blocks))
 
@@ -1782,6 +1906,15 @@ def make_stream_step(
     ``separable=True`` additionally declares the kernel correct on arbitrary
     view subsets, letting many-field domains stream per-field (see
     ``plan_stream``).
+
+    On the plane route the step exchanges only the quantities the kernel
+    reads OFF-CENTRE (``plane_halo_readers``: one abstract trace of the
+    kernel at build time; the resolved set is ``plan["halo_readers"]``, its
+    size ``domain.step``'s ``exchanged``).  A quantity read through
+    ``center()`` alone — a coefficient, an older time level — keeps a stale
+    shell that nothing reads; every interior cell is bitwise what exchanging
+    all of them gives.  So a kernel must read the same offsets every time it
+    is traced: the pass raises, naming the quantity, if it does not.
 
     ``max_depth`` caps the temporal depth (wrap k / wavefront m).  The auto
     planner maximizes depth because depth is the HBM-traffic lever
@@ -2002,8 +2135,11 @@ def make_stream_step(
             "streamed": nq,
             # quantities whose pass output aliases its input (all or none)
             "aliased": nq if _plan_passes_in_place(plan_now) else 0,
-            # every quantity rides halo_exchange_multi on the exchanging routes
-            "exchanged": 0 if plan_now["route"] == "wrap" else nq,
+            # quantities riding the step's exchange: what the kernel reads
+            # off-centre on the plane route (plane_halo_readers), every one
+            # on the wavefront route, none on the wrap route (and, like
+            # ``aliased``, none while the plan is not built yet)
+            "exchanged": len(plan_now.get("halo_readers", ())),
         }
 
     step._span_args = span_args

@@ -317,7 +317,9 @@ ALL_HISTOGRAMS = frozenset({
 #: a stream-engine step adds the plan it ran: route, x_radius, grouping,
 #: streamed = quantities in the pass, aliased = quantities whose pass output
 #: aliases its input (all or none: ``ops/stream._plan_passes_in_place``), exchanged
-#: = quantities riding the halo exchange (0 on the exchange-free wrap route)]
+#: = quantities riding the step's halo exchange: on the plane route those the
+#: kernel reads off-centre (``ops/stream.plane_halo_readers``; all of them under
+#: ``halo="fused"``), every one on the wavefront route, 0 on the wrap route]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes

@@ -91,8 +91,9 @@ def test_route_is_plane_and_the_span_says_so():
     assert plan["alias"] is True, plan  # the plane route writes in place (ISSUE 28)
     assert sim._step._span_args() == {
         "route": "plane", "x_radius": 4, "grouping": "joint", "streamed": 4,
-        "aliased": 4, "exchanged": 4,
+        "aliased": 4, "exchanged": 1,  # u alone is read off-centre (ISSUE 30)
     }
+    assert plan["halo_readers"] == ("u",), plan
     seen = []
     real = telemetry.span
 
@@ -105,7 +106,45 @@ def test_route_is_plane_and_the_span_says_so():
         sim.step(2)
     (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
     assert kw["label"] == "acoustic" and kw["steps"] == 2 and kw["route"] == "plane"
-    assert kw["aliased"] == 4
+    assert (kw["streamed"], kw["aliased"], kw["exchanged"]) == (4, 4, 1)
+
+
+@pytest.mark.parametrize("devices", [1, 2, 8])
+def test_plane_route_is_bitwise_the_xla_engine_on_every_quantity(devices):
+    """The plane route exchanges ``u`` alone; the XLA slice engine exchanges
+    all four.  Three dispatches on, every interior cell of every quantity is
+    bitwise the same -- on one device, on a mesh that splits one axis and on
+    one that splits all three."""
+    sims = [_sim(impl, devices=jax.devices()[:devices]) for impl in ("pallas", "jnp")]
+    assert sims[0]._step._stream_plan["halo_readers"] == ("u",)
+    for sim in sims:
+        for _ in range(3):
+            sim.step(DISPATCH)
+    for q in QUANTITIES:
+        a, b = (sim.field(q) for sim in sims)
+        assert np.any(a != 0.0), q
+        np.testing.assert_array_equal(a, b, err_msg=q)
+
+
+def test_the_step_program_exchanges_u_alone(monkeypatch):
+    """The step as the chip runs it (blend kernels on: every sweep of one
+    device is the self-wrap kernel): per step ONE ``stream_plane_pass`` and
+    exactly three Pallas calls under ``exchange.*`` scopes, one per axis, all
+    on ``u`` -- twelve before ISSUE 30, nine of them for halos nobody reads."""
+    from stencil_tpu.analysis import jaxpr as jx
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    sim = _sim("pallas")
+    closed = jax.make_jaxpr(sim._step._resilience.built(), static_argnums=1)(sim.dd._curr, 5)
+    calls = [e for e in jx.iter_eqns(closed) if e.primitive.name == "pallas_call"]
+    passes = [e for e in calls if e.params.get("name") == tm.KERNEL_STREAM_PLANE_PASS]
+    wraps = [jx.name_stack_str(e) for e in calls if "exchange." in jx.name_stack_str(e)]
+    assert len(passes) == 1 and len(calls) == 4, [e.params.get("name") for e in calls]
+    assert wraps == [
+        f"exchange.{ax}/exchange.{ax}.wrap/{kernel}"
+        for ax, kernel in zip("xyz", ("blend_planes", "blend_slab", "blend_slab"))
+    ], wraps
+    assert not [e for e in jx.iter_eqns(closed) if e.primitive.name == "ppermute"]
 
 
 def _plane_pass_aliases(fn, curr):

@@ -140,3 +140,229 @@ def test_mean6_kernel_axes_variants():
                           f32_accumulate=True)
     assert_bf16_storage_close(pb, pv, passes=1, scale=1.0,
                               context="mean6 plane bf16")
+
+
+# --- exchange only what the kernel reads (ISSUE 30) ---------------------------
+#
+# On the plane route the step exchanges the quantities the kernel reads
+# off-centre and no others (ops/stream.py plane_halo_readers).  Every case
+# runs the same kernel three ways -- the XLA slice engine (which exchanges
+# everything), the plane route as built, and the plane route with the rule
+# switched off (the parent's program: every quantity exchanged) -- and holds
+# every interior cell of every quantity BITWISE equal across the three.
+# (The cellwise updates multiply by powers of two only: the CPU compiler
+# contracts a multiply-add into one rounding in one engine's fusion and not in
+# the other's, and an exact product rounds the same either way.)
+
+
+def _star(v, r):
+    """``test_stream.star_kernel``'s radius-``r`` star (distinct weights per
+    direction and distance, so a wrong offset, ring slot or stale halo cell
+    cannot cancel) over any one view."""
+    from test_stream import star_kernel
+
+    return star_kernel(r)({"u": v}, None)["u"]
+
+
+def two_of_three_kernel(r):
+    """``a`` and ``b`` are read off-centre; ``c`` is a coefficient read at the
+    centre and never returned."""
+
+    def kernel(views, info):
+        a, b, c = views["a"], views["b"], views["c"]
+        return {
+            "a": _star(a, r) * (1.0 + 0.1 * c.center()) - 0.05 * b.sh(0, -r, 0),
+            "b": 0.5 * _star(b, r) + 0.1 * a.center(),
+        }
+
+    return kernel
+
+
+def centre_only_kernel(views, info):
+    """Reads nothing off-centre: a cellwise update of two time levels."""
+    u, v = views["u"].center(), views["v"].center()
+    return {"u": 0.5 * u + 0.25 * v, "v": u}
+
+
+def separable_kernel(r):
+    """Correct on any subset of views: ``a`` diffuses, ``b`` decays in place."""
+
+    def kernel(views, info):
+        return {
+            name: _star(v, r) if name == "a" else 0.5 * v.center() + 0.01
+            for name, v in views.items()
+        }
+
+    return kernel
+
+
+def _plane_domain(names, r, n_dev, extent=(16, 16, 16)):
+    import jax
+
+    from test_stream import _mk
+
+    from stencil_tpu.core.radius import Radius
+
+    return _mk(*extent, Radius.constant(r), names, jax.devices()[:n_dev])
+
+
+def _plane_step(dd, kernel, r, plan_kw):
+    """The plane route's step as ``_build_stream_step`` builds it, with the
+    plan it resolved."""
+    from stencil_tpu.ops import stream as sm
+
+    plan = dict(sm.plan_stream(dd, r, "plane", False), **plan_kw)
+    return sm._build_stream_step(dd, kernel, r, plan, interpret=True), plan
+
+
+def _exchange_everything(monkeypatch):
+    from stencil_tpu.ops import stream as sm
+
+    monkeypatch.setattr(sm, "plane_halo_readers", lambda kernel, names, *a: tuple(names))
+
+
+def _ppermute_cells(fn, curr) -> int:
+    """Cells sent through ``ppermute`` by one step of the traced program."""
+    import jax
+
+    from stencil_tpu.analysis import jaxpr as jx
+
+    closed = jax.make_jaxpr(fn, static_argnums=1)(curr, 1)
+    return sum(
+        int(np.prod(v.aval.shape))
+        for e in jx.iter_eqns(closed)
+        if e.primitive.name == "ppermute"
+        for v in e.invars
+    )
+
+
+SPLIT = {"overlap": "split", "overlap_forced": True}
+
+
+def _case(id, kernel, names, r, n_dev, readers, plan_kw=None, extent=(16, 16, 16)):
+    return pytest.param(kernel, names, r, n_dev, readers, plan_kw or {}, extent, id=id)
+
+
+_READER_CASES = [
+    _case("two-of-three", two_of_three_kernel(2), ["a", "b", "c"], 2, 1, ("a", "b")),
+    _case("order-is-names", two_of_three_kernel(1), ["c", "b", "a"], 1, 1, ("b", "a")),
+    _case("nothing-off-centre", centre_only_kernel, ["u", "v"], 1, 1, ()),
+    _case("nothing-off-centre-dev8", centre_only_kernel, ["u", "v"], 1, 8, ()),
+    _case("separable-per-field", separable_kernel(2), ["a", "b"], 2, 1, ("a",),
+          {"grouping": "per-field"}),
+    _case("split", two_of_three_kernel(2), ["a", "b", "c"], 2, 8, ("a", "b"), SPLIT),
+    _case("split-per-field", separable_kernel(1), ["a", "b"], 1, 8, ("a",),
+          dict(SPLIT, grouping="per-field")),
+    _case("mesh-2x2x2", two_of_three_kernel(2), ["a", "b", "c"], 2, 8, ("a", "b")),
+    _case("mesh-splits-one-axis", separable_kernel(3), ["a", "b"], 3, 2, ("a",)),
+    _case("padded", two_of_three_kernel(1), ["a", "b", "c"], 1, 8, ("a", "b"),
+          extent=(15, 13, 15)),
+]
+
+
+@pytest.mark.parametrize("kernel,names,r,n_dev,readers,plan_kw,extent", _READER_CASES)
+def test_plane_route_exchanges_only_what_the_kernel_reads(
+    kernel, names, r, n_dev, readers, plan_kw, extent, monkeypatch
+):
+    def run_plane():
+        dd, hs = _plane_domain(names, r, n_dev, extent)
+        step, plan = _plane_step(dd, kernel, r, plan_kw)
+        wires = _ppermute_cells(step, dd._curr)
+        dd.run_step(step, 3)
+        assert plan["route"] == "plane", plan
+        for key, want in plan_kw.items():  # the variant engaged, it did not degrade
+            assert plan[key] == want, plan
+        return [dd.quantity_to_host(h) for h in hs], plan["halo_readers"], wires
+
+    fields = {}
+    dd, hs = _plane_domain(names, r, n_dev, extent)
+    dd.run_step(dd.make_step(kernel, overlap=False), 3)
+    fields["xla"] = [dd.quantity_to_host(h) for h in hs]
+    fields["readers"], got, wires = run_plane()
+    assert got == readers
+    _exchange_everything(monkeypatch)
+    fields["all"], got, wires_all = run_plane()
+    assert got == tuple(names)
+
+    for i, name in enumerate(names):
+        assert np.isfinite(fields["xla"][i]).all() and np.ptp(fields["xla"][i]) > 0, name
+        assert np.array_equal(fields["readers"][i], fields["all"][i]), name
+        assert np.array_equal(fields["readers"][i], fields["xla"][i]), name
+    # the joint message carries the readers' slabs and nothing else (on one
+    # CPU device too: without the blend kernels an unsplit axis sends to itself)
+    assert wires_all > 0 and wires * len(names) == wires_all * len(readers), (wires, wires_all)
+
+
+def test_a_kernel_that_reads_nothing_off_centre_exchanges_nothing():
+    """``exchanged`` 0 through ``make_step`` and no ``exchange.*`` scope (nor
+    any collective) anywhere in the step's program."""
+    import jax
+
+    from stencil_tpu.analysis import jaxpr as jx
+    from stencil_tpu.analysis.programs import tpu_shaped_trace
+
+    with tpu_shaped_trace():
+        dd, _ = _plane_domain(["u", "v"], 1, 8)
+        step = dd.make_step(centre_only_kernel, engine="stream", stream_path="plane",
+                            interpret=True)
+        args = step._span_args()
+        assert (args["route"], args["streamed"], args["exchanged"]) == ("plane", 2, 0), args
+        closed = jax.make_jaxpr(step._resilience.built(), static_argnums=1)(dd._curr, 2)
+    eqns = list(jx.iter_eqns(closed))
+    assert not [e for e in eqns if "exchange." in jx.name_stack_str(e)]
+    assert not [e for e in eqns if e.primitive.name == "ppermute"]
+    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 1
+
+
+def test_a_footprint_trace_that_raises_exchanges_everything(monkeypatch):
+    """Fail closed: the abstract trace could not be made, so every quantity
+    rides the exchange as before -- and the step still runs and is right."""
+    from stencil_tpu.ops import stream as sm
+
+    real = sm.stream_plane_pass
+    in_pass = []
+
+    def spy(*a, **kw):
+        in_pass.append(True)
+        try:
+            return real(*a, **kw)
+        finally:
+            in_pass.pop()
+
+    monkeypatch.setattr(sm, "stream_plane_pass", spy)
+    inner = two_of_three_kernel(2)
+
+    def kernel(views, info):
+        if not in_pass:
+            raise RuntimeError("not traceable outside a pass")
+        return inner(views, info)
+
+    names = ["a", "b", "c"]
+    dd, hs = _plane_domain(names, 2, 1)
+    step, plan = _plane_step(dd, kernel, 2, {})
+    assert plan["halo_readers"] == ("a", "b", "c"), plan
+    dd.run_step(step, 2)
+    got = [dd.quantity_to_host(h) for h in hs]
+    dd, hs = _plane_domain(names, 2, 1)
+    dd.run_step(dd.make_step(inner, overlap=False), 2)
+    for name, a, h in zip(names, got, hs):
+        assert np.array_equal(a, dd.quantity_to_host(h)), name
+
+
+def test_an_off_centre_read_the_footprint_trace_missed_raises_by_name():
+    """The real trace checks the abstract one: a kernel that reads ``c``
+    off-centre only the SECOND time it is traced meets a ``c`` whose halo was
+    not exchanged, and the pass says so at trace time -- never a stale read."""
+    calls = []
+
+    def kernel(views, info):
+        calls.append(1)
+        c = views["c"]
+        coeff = c.center() if len(calls) == 1 else c.sh(0, 0, 1)
+        return {"a": _star(views["a"], 1) * coeff}
+
+    dd, _ = _plane_domain(["a", "c"], 1, 1)
+    step, plan = _plane_step(dd, kernel, 1, {})
+    assert plan["halo_readers"] == ("a",)
+    with pytest.raises(ValueError, match=r"reads 'c' off-centre.*halo of 'c' was not exchanged"):
+        step.lower(dd._curr, 1)

@@ -486,7 +486,7 @@ class TestLadderEngines:
         assert step._stream_plan == {
             "route": "wavefront", "m": 3, "z_slabs": True, "grouping": "joint",
             "alias": False,  # one field on the wavefront route: fresh outputs
-            "overlap": "off", "halo": "array",
+            "overlap": "off", "halo": "array", "halo_readers": ("u",),
         }
         inject.set_plan("execute:vmem_oom:stream*2")
         dd.run_step(step, 4)

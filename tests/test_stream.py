@@ -436,6 +436,7 @@ def test_stream_separable_per_field_grouping(monkeypatch):
         "route": "wavefront", "m": 3, "z_slabs": True, "grouping": "per-field",
         "alias": True,  # four fields: the wavefront's static rule, written back
         "overlap": "off", "halo": "array",
+        "halo_readers": ("a", "b", "c", "d"),  # the wavefront exchanges every quantity
     }
     monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
     ref_dd, ref_hs = _mk(24, 24, 24, Radius.constant(1), names, devs)
@@ -503,6 +504,7 @@ def test_stream_depth_cap():
         "route": "wrap", "m": 8, "z_slabs": False, "grouping": "joint",
         "alias": False,  # the wrap pass has no in-place form
         "overlap": "off", "halo": "array",
+        "halo_readers": (),  # and no exchange
     }
     for a, b in outs:  # uncapped wrap vs the XLA ground truth
         np.testing.assert_allclose(a, b, **TOL)

@@ -487,11 +487,13 @@ def main(argv=None) -> int:
             _common.telemetry_end(args)
             return 0
         devs = len(jax.devices())
-        # weak.cu:63-65 round-to-nearest scaling
+        # weak.cu:63-65 round-to-nearest scaling, and the extent stays as
+        # the reference rounds it (750 on four chips is 1191): a mesh that
+        # does not divide it gets padded shards whose last one owns the
+        # remainder (domain.py), where the reference's differ by one cell
         x = weak_scaled_size(args.x, devs)
         y = weak_scaled_size(args.y, devs)
         z = weak_scaled_size(args.z, devs)
-        x, y, z = _common.fit_to_mesh(x, y, z, Radius.constant(3))
         print(
             f"{devs} subdomains: {x},{y},{z}={x * y * z}",
             file=sys.stderr,

@@ -157,6 +157,61 @@ def _qspec(h: DataHandle) -> P:
     return P(*([None] * len(h.components)), *MESH_AXES)
 
 
+def _row_major_format(sharding: NamedSharding, shape, dtype):
+    """A ``Format`` that pins an array's shards row-major -- z in the lanes,
+    y in the sublanes, what every kernel of ``ops/`` is written for -- where
+    the backend's own default layout for the shard's shape is another one,
+    else None (nothing to pin: the programs stay as they are).
+
+    A TPU stores an array in the dimension order that wastes least on its
+    (8, 128) tiles: a 602 x 602 x 1197 f32 shard (the reference's weak run at
+    750^3 per chip) goes y-minor -- 640 lanes x 1200 sublanes beats 1280 x
+    608 -- and every Pallas call, which takes its operands row-major, then
+    pays a whole-array relayout on the way in and on the way out (PERF.md §6,
+    PR 31).  Cubes, and z extents close under a lane multiple, default to
+    row-major, so no other shape the benchmark runs is pinned."""
+    from jax.experimental.layout import Format, Layout
+
+    device = next(iter(sharding.device_set))
+    try:
+        default = Layout.from_pjrt_layout(
+            device.client.get_default_layout(
+                jnp.dtype(dtype), sharding.shard_shape(tuple(shape)), device
+            )
+        )
+    except Exception as e:  # noqa: BLE001 -- a backend without layouts pins nothing
+        log_debug(f"no default layout to read ({type(e).__name__}); pinning nothing")
+        return None
+    row_major = tuple(range(len(shape)))
+    if tuple(default.major_to_minor) == row_major:
+        return None
+    return Format(Layout(major_to_minor=row_major), sharding)
+
+
+def _persistent_cache_off() -> None:
+    """Turn jax's persistent compilation cache off for this process -- what a
+    domain does once it pins a layout.  jax 0.9 serves an executable from the
+    persistent cache WITHOUT its non-default entry layouts: the second fill of
+    a pinned quantity (same program, a cache hit) then expects the backend's
+    default layout and is handed the pinned buffer (`INVALID_ARGUMENT: expected
+    parameter 0 of size 1849344000 ... {1,2,0} but got ... {2,1,0}` on the
+    chip; silently transposed data on the CPU).  It holds for EVERY program
+    that takes or returns a pinned array, the caller's own included, so the
+    switch is the process's, not a program's.  Every aligned extent pins
+    nothing and keeps the cache."""
+    if not jax.config.jax_enable_compilation_cache:
+        return
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    log_warn(
+        "this domain pins its arrays row-major against the backend's default "
+        "layout; jax's persistent compilation cache drops such layouts, so it "
+        "is off for this process (every program compiles once per run)"
+    )
+
+
 class DistributedDomain:
     def __init__(self, x: int, y: int, z: int):
         self._size = Dim3(x, y, z)
@@ -173,6 +228,9 @@ class DistributedDomain:
         self._valid_last: Tuple[Optional[int], Optional[int], Optional[int]] = (None, None, None)
         self._curr: Dict[str, jax.Array] = {}
         self._next: Dict[str, jax.Array] = {}
+        # quantity name -> Format, for the quantities whose arrays are pinned
+        # row-major (``_row_major_format``); empty on every aligned extent
+        self._pinned: Dict[str, object] = {}
         self._exchange_fn = None
         self._exchange_many_fn = None
         self._exchange_count = 0
@@ -182,6 +240,7 @@ class DistributedDomain:
         self._exchange_route_req: Optional[str] = None
         self._exchange_route = "direct"
         self._wrap_axes = ""  # mesh axes swept by the self-wrap kernel
+        self._uneven_axes = ""  # mesh axes swept at per-shard traced offsets
         # storage-dtype axis (ops/jacobi_pallas STORAGE_DTYPES): models
         # resolve the axis (explicit > STENCIL_STORAGE_DTYPE > tuned >
         # static native) and pin the RESOLVED value here before realize();
@@ -515,10 +574,6 @@ class DistributedDomain:
         topologies (``jax.experimental.topologies``), where ``make_step`` can
         then be lowered/compiled against abstract sharded shapes (used by the
         overlap-schedule proof, tests/test_overlap_schedule.py)."""
-        with telemetry.span(tm.SPAN_REALIZE):
-            self._realize(allocate)
-
-    def _realize(self, allocate: bool) -> None:
         self._radius.validate()
         if self._storage == "bf16":
             # the structural gate the model resolvers apply, repeated here
@@ -548,20 +603,37 @@ class DistributedDomain:
             self._shell_radius,
         ) = self._derive_geometry(devices)
         self.stats.time_placement = time.perf_counter() - t0
+        # the span opens once the geometry is known, so it can say which
+        # shards are padded; it holds what takes the time (allocation, the
+        # exchange's build and eager compile)
+        with telemetry.span(
+            tm.SPAN_REALIZE,
+            valid_last=",".join("-" if v is None else str(v) for v in self._valid_last),
+        ):
+            self._allocate_and_plan(allocate)
+
+    def _allocate_and_plan(self, allocate: bool) -> None:
         dim = self.placement.dim()
         raw = self._spec.raw_size()
-        sharding = NamedSharding(self.mesh, P(*MESH_AXES))
         gshape = (dim.x * raw.x, dim.y * raw.y, dim.z * raw.z)
+        self._pinned = {}
+        for h in self._handles:
+            pin = _row_major_format(
+                NamedSharding(self.mesh, _qspec(h)), h.components + gshape,
+                self.field_dtype(h),
+            )
+            if pin is not None:
+                self._pinned[h.name] = pin
+        if self._pinned:
+            _persistent_cache_off()
         if not allocate:
             self._realized = True
             log_info(f"realized (abstract) {self._size} over mesh {dim} (raw shard {raw})")
             return
         t0 = time.perf_counter()
         for h in self._handles:
-            hsharding = NamedSharding(self.mesh, _qspec(h))
-            fdt = self.field_dtype(h)
-            self._curr[h.name] = jnp.zeros(h.components + gshape, dtype=fdt, device=hsharding)
-            self._next[h.name] = jnp.zeros(h.components + gshape, dtype=fdt, device=hsharding)
+            self._curr[h.name] = self._zeros(h, gshape)
+            self._next[h.name] = self._zeros(h, gshape)
         self.stats.time_realize = time.perf_counter() - t0
         t0 = time.perf_counter()
         if self._methods in (MethodFlags.AllGather, MethodFlags.RollCompare):
@@ -585,7 +657,7 @@ class DistributedDomain:
             )
             self._exchange_fn = maker(self.mesh, self._shell_radius, self._spec, dim)
             self._exchange_route = "direct"  # the debug oracles have no z route
-            self._wrap_axes = ""  # ...and no axis sweeps
+            self._wrap_axes = self._uneven_axes = ""  # ...and no axis sweeps
             self.stats.time_plan = time.perf_counter() - t0
             # eager trace+compile of the exchange — the analog of the
             # reference's sender/recver creation + CUDA-Graph capture
@@ -609,6 +681,15 @@ class DistributedDomain:
                 self._record_exchange_compile(t0, f"realize:{self._exchange_route}")
         self._realized = True
         log_info(f"realized {self._size} over mesh {dim} (raw shard {raw})")
+
+    def _zeros(self, h: DataHandle, gshape) -> jax.Array:
+        """A zeroed array of quantity ``h`` on the mesh, in its pinned layout
+        where it has one."""
+        shape, fdt = h.components + tuple(gshape), self.field_dtype(h)
+        pin = self._pinned.get(h.name)
+        if pin is None:
+            return jnp.zeros(shape, dtype=fdt, device=NamedSharding(self.mesh, _qspec(h)))
+        return jax.jit(partial(jnp.zeros, shape, dtype=fdt), out_shardings=pin)()
 
     def _record_exchange_compile(self, t0: float, label: str) -> None:
         self.stats.time_create = time.perf_counter() - t0
@@ -739,13 +820,10 @@ class DistributedDomain:
         self.mesh, self.placement = mesh, placement
         self._spec, self._valid_last, self._shell_radius = spec, vlast, shell
         self._curr = new_curr
+        # the redistributed arrays come in the backend's default layout
+        self._pinned = {}
         gshape = (dim.x * raw.x, dim.y * raw.y, dim.z * raw.z)
-        self._next = {}
-        for h in self._handles:
-            hsharding = NamedSharding(self.mesh, _qspec(h))
-            self._next[h.name] = jnp.zeros(
-                h.components + gshape, dtype=self.field_dtype(h), device=hsharding
-            )
+        self._next = {h.name: self._zeros(h, gshape) for h in self._handles}
         # re-realize the exchange plan/executable for the new geometry:
         # the route re-resolves (explicit pin > env > tuned — the tuner is
         # re-keyed automatically, tune_key reads the new placement) and the
@@ -875,11 +953,15 @@ class DistributedDomain:
         the self-wrap kernel (``ops/exchange.py wrap_axes``): called wherever
         the route is settled, so ``self._wrap_axes`` — the ``wrap_axes`` field
         of every ``domain.exchange`` span — follows ``self._exchange_route``."""
-        from stencil_tpu.ops.exchange import wrap_axes
+        from stencil_tpu.ops.exchange import uneven_axes, wrap_axes
 
         raw = self._spec.raw_size()
+        mesh_shape = tuple(self.mesh.shape[a] for a in MESH_AXES)
+        self._uneven_axes = uneven_axes(
+            mesh_shape, self._shell_radius, (raw.x, raw.y, raw.z), self._valid_last
+        )
         self._wrap_axes = wrap_axes(
-            tuple(self.mesh.shape[a] for a in MESH_AXES),
+            mesh_shape,
             self._shell_radius,
             (raw.x, raw.y, raw.z),
             [self.field_dtype(h) for h in self._handles],
@@ -911,6 +993,7 @@ class DistributedDomain:
             route=route,
             axes=axes,
             donate=donate,
+            out_shardings=self._out_formats(),
         )
         if self._handles:
             label = f"compile:exchange:{route}"
@@ -925,6 +1008,19 @@ class DistributedDomain:
 
             execute_with_retry(compile_unit, label=label)
         return fn
+
+    def _out_formats(self):
+        """``out_shardings`` of a program that returns this domain's
+        quantities as a dict: None -- leave it to jit, the program's text
+        stays as it is -- unless a quantity is pinned row-major
+        (``_row_major_format``); then each result keeps its array's own
+        layout, so the next program finds what it was built for."""
+        if not self._pinned:
+            return None
+        return {
+            h.name: self._pinned.get(h.name, NamedSharding(self.mesh, _qspec(h)))
+            for h in self._handles
+        }
 
     def _build_exchange_with_ladder(self):
         """Build (and compile) the production exchange for the resolved
@@ -963,7 +1059,7 @@ class DistributedDomain:
             h.name: jax.ShapeDtypeStruct(
                 h.components + gshape,
                 self.field_dtype(h),
-                sharding=NamedSharding(self.mesh, _qspec(h)),
+                sharding=self._pinned.get(h.name, NamedSharding(self.mesh, _qspec(h))),
             )
             for h in self._handles
         }
@@ -984,6 +1080,13 @@ class DistributedDomain:
 
     def num_subdomains(self) -> int:
         return self.placement.dim().flatten()
+
+    def valid_last(self) -> Tuple[Optional[int], Optional[int], Optional[int]]:
+        """Per axis, the valid interior cells of the LAST shard where the
+        mesh does not divide the extent (every other shard owns
+        ``subdomain_size()``; the rest of the last one is padding that no one
+        owns), None where it does."""
+        return self._valid_last
 
     def shard_valid(self, idx) -> Dim3:
         """Valid (unpadded) interior extent of the shard at mesh index ``idx``
@@ -1057,8 +1160,8 @@ class DistributedDomain:
         want = h.components + tuple(self._size)
         assert interior.shape == want, (interior.shape, want)
         raw = self._to_raw_global(np.asarray(interior), self.field_dtype(h))
-        sharding = NamedSharding(self.mesh, _qspec(h))
-        arr = jax.device_put(jnp.asarray(raw), sharding)
+        where = self._pinned.get(h.name, NamedSharding(self.mesh, _qspec(h)))
+        arr = jax.device_put(jnp.asarray(raw), where)
         (self._curr if slot == "curr" else self._next)[h.name] = arr
 
     def quantity_to_host(self, h: DataHandle, slot: str = "curr") -> np.ndarray:
@@ -1153,41 +1256,46 @@ class DistributedDomain:
         fill (a seed's words) then leave the program's text alone, so the
         compile cache serves every later fill instead of compiling a new
         program per value."""
+        with telemetry.span(tm.SPAN_INIT, quantity=h.name):
+            fill = self._init_program(h, fn, include_halo, len(args))
+            self._curr[h.name] = fill(self._curr[h.name], *args)
+
+    def _init_program(self, h: DataHandle, fn, include_halo: bool, n_args: int):
+        """The jitted fill of ``init_by_coords``: ``(array, *args) -> array``.
+        The old buffer is donated and the values are computed over the raw
+        block and selected in under the interior's mask -- one fused pass that
+        reads and writes the block in place, so a fill holds one array, not
+        the old one, the values and the new one (at 1.87 GB an array and
+        14.99 GB of fields on a 16 GB chip the difference is the fill)."""
         n = self._spec.sz
         raw = self._spec.raw_size()
         lo = self._shell_radius.lo()
-        mesh_shape = tuple(self.mesh.shape[a] for a in MESH_AXES)
-
         comps = h.components
 
         def per_shard(block, *extra):
-            ox = lax.axis_index(MESH_AXES[0]) * n.x
-            oy = lax.axis_index(MESH_AXES[1]) * n.y
-            oz = lax.axis_index(MESH_AXES[2]) * n.z
+            # raw coordinate k of this shard holds global cell o - lo + k
+            k = [jnp.arange(raw[a]) for a in range(3)]
+            c = [
+                lax.axis_index(MESH_AXES[a]) * n[a] - lo[a] + k[a] for a in range(3)
+            ]
+            vals = fn(c[0][:, None, None], c[1][None, :, None], c[2][None, None, :], *extra)
+            vals = jnp.broadcast_to(vals, comps + tuple(raw)).astype(block.dtype)
             if include_halo:
-                cx = ox - lo.x + jnp.arange(raw.x)
-                cy = oy - lo.y + jnp.arange(raw.y)
-                cz = oz - lo.z + jnp.arange(raw.z)
-                vals = fn(cx[:, None, None], cy[None, :, None], cz[None, None, :], *extra)
-                return jnp.broadcast_to(vals, comps + tuple(raw)).astype(block.dtype)
-            cx = ox + jnp.arange(n.x)
-            cy = oy + jnp.arange(n.y)
-            cz = oz + jnp.arange(n.z)
-            vals = fn(cx[:, None, None], cy[None, :, None], cz[None, None, :], *extra)
-            vals = jnp.broadcast_to(vals, comps + tuple(n)).astype(block.dtype)
-            return block.at[
-                ..., lo.x : lo.x + n.x, lo.y : lo.y + n.y, lo.z : lo.z + n.z
-            ].set(vals)
+                return vals
+            inside = [(k[a] >= lo[a]) & (k[a] < lo[a] + n[a]) for a in range(3)]
+            mask = inside[0][:, None, None] & inside[1][None, :, None] & inside[2][None, None, :]
+            return jnp.where(mask, vals, block)
 
         spec = _qspec(h)
-        with telemetry.span(tm.SPAN_INIT, quantity=h.name):
-            out = jax.jit(
-                shard_map(
-                    per_shard, mesh=self.mesh,
-                    in_specs=(spec,) + (P(),) * len(args), out_specs=spec,
-                )
-            )(self._curr[h.name], *args)
-        self._curr[h.name] = out
+        pin = self._pinned.get(h.name)
+        return jax.jit(
+            shard_map(
+                per_shard, mesh=self.mesh,
+                in_specs=(spec,) + (P(),) * n_args, out_specs=spec,
+            ),
+            donate_argnums=0,
+            **({} if pin is None else {"out_shardings": pin}),
+        )
 
     # --- the hot path ---------------------------------------------------------
     @contextlib.contextmanager
@@ -1299,7 +1407,7 @@ class DistributedDomain:
         with self._phase_timer(
             "time_exchange", tm.EXCHANGE_SECONDS, tm.SPAN_EXCHANGE, sync=True,
             route=self._exchange_route, nbytes=self._model_exchange(), count=1,
-            wrap_axes=self._wrap_axes,
+            wrap_axes=self._wrap_axes, uneven_axes=self._uneven_axes,
         ):
             self._curr = self._watched_call(
                 "exchange", lambda: self._exchange_fn(self._curr)
@@ -1325,7 +1433,7 @@ class DistributedDomain:
         with telemetry.span(
             tm.SPAN_EXCHANGE, route=self._exchange_route,
             nbytes=steps * self._model_exchange(), count=steps,
-            wrap_axes=self._wrap_axes,
+            wrap_axes=self._wrap_axes, uneven_axes=self._uneven_axes,
         ):
             self._curr = self._exchange_many_fn(self._curr, steps)
         self._shell_stale = False

@@ -498,6 +498,29 @@ def wrap_axes(
     )
 
 
+def uneven_axes(
+    mesh_shape: Tuple[int, int, int],
+    radius: Radius,
+    raw_spatial: Tuple[int, int, int],
+    valid_last: Optional[Tuple[Optional[int], Optional[int], Optional[int]]],
+) -> str:
+    """The mesh axes (a substring of ``"xyz"``) whose sweep runs at per-shard
+    TRACED offsets -- the mesh splits the axis and does not divide the global
+    extent, so the last shard owns fewer cells than it is padded to (what
+    ``domain.exchange`` reports as ``uneven_axes``; "" on every aligned
+    extent).  An unsplit axis is never padded: one shard is the last shard."""
+    if valid_last is None:
+        return ""
+    return "".join(
+        MESH_AXES[a]
+        for a in range(3)
+        if mesh_shape[a] > 1
+        and radius.axis(a, -1) + radius.axis(a, +1) > 0
+        and valid_last[a] is not None
+        and valid_last[a] != raw_spatial[a] - radius.axis(a, -1) - radius.axis(a, +1)
+    )
+
+
 def _axis_sweep(
     blocks: List[jax.Array],
     axis: int,
@@ -603,12 +626,15 @@ def _axis_sweep(
             else:
                 b = b.at[axslice(b, 0, r_lo)].set(lo_recv[j])
         if hi_recv is not None:
-            if uneven and blend and axis != 0:
+            if uneven and blend:
+                # every axis, x included: a traced x-plane DUS is no relayout
+                # bait but compiles to a whole-array fusion with a fresh
+                # result (halo_blend.blend_slab_dynamic)
                 b = halo_blend.blend_slab_dynamic(
                     b, hi_recv[j], axis, r_lo + n_valid, interpret=interp
                 )
             elif uneven:
-                # stencil-lint: disable=sliver-dus axis-0 traced offset: an x-plane DUS is contiguous in the (8,128) tiling, no relayout bait
+                # stencil-lint: disable=sliver-dus the no-kernel path (CPU, N-D quantities, exotic dtypes) at a traced offset
                 b = lax.dynamic_update_slice(
                     b, hi_recv[j], dyn_starts(b, r_lo + n_valid)
                 )
@@ -954,6 +980,7 @@ def make_exchange_fn(
     route: str = "direct",
     axes: Tuple[int, ...] = (0, 1, 2),
     donate: bool = True,
+    out_shardings=None,
 ):
     """Build a jitted exchange over a pytree of shell-carrying global arrays.
 
@@ -967,7 +994,10 @@ def make_exchange_fn(
     in-place in HBM, like the reference filling halos inside the existing
     allocation.  ``valid_last`` — see ``halo_exchange_shard``; ``route`` —
     see ``EXCHANGE_ROUTES``; ``axes`` restricts the sweeps (bench-exchange's
-    per-axis breakdown).
+    per-axis breakdown).  ``out_shardings`` (a pytree like ``arrays``, of
+    shardings or ``Format``s) pins where and in which layout the results
+    live -- a domain whose arrays are not in the backend's default layout
+    hands its own (``domain._row_major_format``); None leaves it to jit.
     """
     if route not in EXCHANGE_ROUTES:
         raise ValueError(f"unknown exchange route {route!r} (one of {EXCHANGE_ROUTES})")
@@ -977,9 +1007,11 @@ def make_exchange_fn(
         assert leaf.ndim >= 3, leaf.shape
         return P(*([None] * (leaf.ndim - 3)), *MESH_AXES)
 
-    donate_kw = {"donate_argnums": 0} if donate else {}
+    jit_kw = {"donate_argnums": 0} if donate else {}
+    if out_shardings is not None:
+        jit_kw["out_shardings"] = out_shardings
 
-    @partial(jax.jit, **donate_kw)
+    @partial(jax.jit, **jit_kw)
     def exchange(arrays):
         def per_shard(*blocks):
             # ALL quantities (and any leading batch dims) ride one fused

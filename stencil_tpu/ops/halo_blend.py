@@ -376,9 +376,38 @@ def blend_slab_dynamic(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    assert axis in (1, 2), axis
+    assert axis in (0, 1, 2), axis
     X, Y, Z = block.shape
     r = slab.shape[axis]
+    pos = jnp.asarray(pos, jnp.int32).reshape((1,))
+    if axis == 0:
+        # whole planes at a traced plane offset: ``blend_slab``'s axis-0 form
+        # with the offset in the output's index map.  A traced
+        # ``dynamic_update_slice`` here compiled to a whole-array loop fusion
+        # with a fresh result (3.8 GB of temporaries for four 602 x 602 x 1197
+        # quantities, cross-compiled for a v5e: PERF.md §6, PR 31)
+        def kernel0(pos_ref, in_ref, slab_ref, out_ref):
+            del pos_ref, in_ref
+            out_ref[...] = slab_ref[...]
+
+        return pl.pallas_call(
+            kernel0,
+            name=tm.KERNEL_BLEND_SLAB_DYNAMIC,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(r,),
+                in_specs=[
+                    pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec((1, Y, Z), lambda g, pos_ref: (g, 0, 0)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (1, Y, Z), lambda g, pos_ref: (pos_ref[0] + g, 0, 0)
+                ),
+            ),
+            out_shape=jax.ShapeDtypeStruct(block.shape, block.dtype),
+            input_output_aliases={1: 0},
+            interpret=interpret,
+        )(pos, block, slab)
     tile = _sublane(block.dtype) if axis == 1 else 128
     ext = (Y, Z)[axis - 1]
     ntiles = -(-ext // tile)
@@ -386,7 +415,6 @@ def blend_slab_dynamic(
     nb = min((r - 1) // tile + 2, ntiles)
     bx = min(8, X)
     gx = -(-X // bx)
-    pos = jnp.asarray(pos, jnp.int32).reshape((1,))
 
     def kernel(pos_ref, in_ref, slab_ref, out_ref):
         g = pl.program_id(1)

@@ -323,10 +323,15 @@ ALL_HISTOGRAMS = frozenset({
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes
-#: whose sweep is the self-wrap kernel, e.g. "z" on mesh [2,2,1]]
+#: whose sweep is the self-wrap kernel, e.g. "z" on mesh [2,2,1], uneven_axes
+#: = the mesh axes swept at per-shard traced offsets because the mesh does
+#: not divide the extent (``ops/exchange.uneven_axes``), e.g. "xy" for 1191^3
+#: on that mesh, "" on every aligned extent]
 SPAN_EXCHANGE = "domain.exchange"
 SPAN_SWAP = "domain.swap"
-#: ``realize()``: placement, allocation, exchange build + eager compile
+#: ``realize()`` once the geometry is known: allocation, exchange build +
+#: eager compile [valid_last = the last shard's valid cells per axis, "-" where
+#: the mesh divides the extent, e.g. "595,595,-"]
 SPAN_REALIZE = "domain.realize"
 #: ``init_by_coords``: builds and traces a new jit per call [quantity]
 SPAN_INIT = "domain.init"

@@ -73,7 +73,19 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
             m = json.load(f)
         assert m["reducer"] in ("named_share", "named_roofline_hbm") and m["cells"] == ["acoustic-*"], name
         assert declared[name]["workloads"] == ["acoustic-so8-600.bulk"], name
-    for name in set(declared) - new - plane:
+    # PR 31's: the ragged weak cell's shares, by pattern too
+    ragged = {
+        "exchange_z_pct.ragged", "collective_pct.ragged", "kernel_named_pct.ragged", "enqueue_ms_p90.ragged",
+        "compiles_in_window.ragged", "idle_in_program_pct.ragged", "blend_dynamic_pct",
+        "blend_dynamic_hbm_pct", "uneven_cut_pct",
+    }
+    assert ragged <= set(declared)
+    for name in ragged:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["cells"] == ["weak-r3-750x4*"] and m["moves"] == "halo_gbps_chip", name
+        assert declared[name]["workloads"] == ["weak-r3-750x4.exchange-only"], name
+    for name in set(declared) - new - plane - (ragged - {"collective_pct.ragged"}):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
                                                "trace_roofline_hbm", "trace_idle"), name

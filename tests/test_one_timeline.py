@@ -232,7 +232,8 @@ def test_hot_path_opens_spans_and_never_syncs(session, monkeypatch, request):
     assert all(a == {"label": "unit", "steps": 4} for a in step_args), step_args
     exchange_args = [a for n, a in opened if n == tm.SPAN_EXCHANGE]
     assert all(
-        a == {"route": "direct", "nbytes": dd.exchange_bytes_total(), "count": 1, "wrap_axes": ""}
+        a == {"route": "direct", "nbytes": dd.exchange_bytes_total(), "count": 1, "wrap_axes": "",
+              "uneven_axes": ""}
         for a in exchange_args
     ), exchange_args  # wrap_axes "": on the CPU the blend kernels are off, so z self-ppermutes
     if stop is not None:

@@ -21,8 +21,10 @@ What it asks of the runtime, unlike ``Jacobi3D`` and ``AstarothSim``:
 * model fields ``m`` and ``damp`` that are read and never written, and at
   the centre only: like ``u_prev`` they stay out of the step's exchange
   (the engine exchanges what the kernel reads off-centre, which is ``u``
-  alone: ``ops/stream.py plane_halo_readers``), but still ride through the
-  pass (docs/acoustic.md says what that costs);
+  alone), and they are inputs of the pass and nothing else (it writes what
+  the kernel returns, ``u`` and ``u_prev``) -- ``ops/stream.py
+  plane_footprint`` learns both from this kernel, docs/acoustic.md says
+  what a step moves;
 * a Dirichlet edge on a periodic runtime: the ``FRAME`` outer cells are
   pinned to zero BY THE KERNEL from ``info.coords()`` -- no model field
   could do it (``u+`` has no coefficient that a zero would null), and no

@@ -15,10 +15,10 @@ vs ~40+ for the streamed form).
 Two routes, chosen by ``make_stream_step``:
 
 * **plane** — one level per pass: exchange the shell of every quantity the
-  kernel reads off-centre (``plane_halo_readers``; the others' shells are
+  kernel reads off-centre (``plane_footprint``; the others' shells are
   read by nothing), then stream planes with a ``2r``-deep ring (``r`` = the
-  kernel's declared x read distance).  Works for any per-axis shell widths
-  and any ``r >= 1``.
+  kernel's declared x read distance), writing back only the quantities the
+  kernel returns.  Works for any per-axis shell widths and any ``r >= 1``.
 * **wavefront** — ``m`` levels per pass over an ``s``-wide-shell shard
   (``m <= s // r``, ``r == 1`` only): each HBM plane is read and written
   once per ``m`` iterations (~``8/m`` B/cell), the temporal blocking that
@@ -147,7 +147,7 @@ class PlaneView:
     ``off_centre`` is called, at trace time, on every read with a non-zero
     offset (``center()`` and ``sh(0, 0, 0)`` never call it): the plane
     route's footprint trace records the quantity there, and its pass raises
-    there for a quantity whose halo was not filled (``plane_halo_readers``).
+    there for a quantity whose halo was not filled (``plane_footprint``).
     """
 
     def __init__(self, window: Tuple[jax.Array, ...], roll, off_centre=None):
@@ -251,7 +251,9 @@ def stream_plane_pass(
     # halo messages land in the level-0 planes in VMEM instead of having
     # been unpacked into the blocks (halo="fused"; see module docstring)
     halo_readers: Optional[Sequence[str]] = None,  # the quantities whose
-    # shell was filled (plane_halo_readers); None = every one
+    # shell was filled (plane_footprint); None = every one
+    writers: Optional[Sequence[str]] = None,  # the quantities the kernel
+    # returns (plane_footprint): the pass's only outputs; None = every one
 ) -> List[jax.Array]:
     """ONE kernel level over shell-carrying blocks, streaming x-planes with a
     ``2r``-deep ring per quantity; shell planes and the in-plane shell ring
@@ -266,7 +268,22 @@ def stream_plane_pass(
     order — before it feeds the ring, the kernel, or the pass-through, so
     the pass is bitwise-identical to running over exchanged blocks.
 
-    With ``alias`` output ``q`` IS raw ``q`` (``input_output_aliases``): a
+    Returns one array per quantity, but only the ``writers`` are OUTPUTS of
+    the Pallas call: every quantity is an input with its ring and its view,
+    and a quantity the kernel never returns is nothing else — its every raw
+    cell, shell included, would be written back as it was read, so the pass
+    returns ``raws[q]`` itself and moves a plane in where it moved one in and
+    one out (acoustic: ``m`` and ``damp``, 8 arrays through HBM a step -> 6).
+    A kernel that returns a name outside ``writers`` in THIS trace raises and
+    names it (its values would otherwise be dropped silently); with no
+    writer at all there is no call to make.  Not under ``fused_shell``:
+    there the written planes are where the fresh shell lands, so every
+    quantity stays an output whatever ``writers`` says (the same exception
+    ``plane_footprint``'s caller makes for the readers).
+
+    With ``alias`` a writer's output IS its raw block
+    (``input_output_aliases`` maps operand ``1 + q`` — operand 0 is
+    ``origin`` — to the writer's position among the outputs): a
     step loop that carries its blocks in place then needs no whole-array
     copy per quantity per step to put a fresh result where the carry lives.
     In place is safe because writes trail reads by ``r >= 1`` planes on the
@@ -300,6 +317,12 @@ def stream_plane_pass(
     roll = _make_roll(interpret)
     gsize = global_size
     up = (lambda v: v.astype(jnp.float32)) if f32_accumulate else (lambda v: v)
+    if writers is None or fused_shell is not None:
+        wq = list(range(nq))
+    else:
+        wq = [q for q in range(nq) if names[q] in writers]
+    if not wq:
+        return list(raws)
 
     def stale_read(name):
         if halo_readers is None or name in halo_readers:
@@ -322,8 +345,8 @@ def stream_plane_pass(
             ys_refs = refs[2 * nq : 3 * nq]
             zs_refs = refs[3 * nq : 4 * nq]
             refs = refs[:nq] + refs[4 * nq :]
-        out_refs = refs[nq : 2 * nq]
-        rings = refs[2 * nq :]
+        out_refs = dict(zip(wq, refs[nq : nq + len(wq)]))  # writers only
+        rings = refs[nq + len(wq) :]
         i = pl.program_id(0)
         curs = [ref[0] for ref in in_refs]
         if fused_shell is not None:
@@ -368,26 +391,34 @@ def stream_plane_pass(
                 info = PlaneInfo(x_g, y_g, z_g, gsize, 1)
                 vals = kernel(views, info)
                 for q, name in enumerate(names):
+                    if name in vals and q not in out_refs:
+                        raise ValueError(
+                            f"the kernel returns {name!r}, but its footprint "
+                            f"trace did not (it saw {tuple(writers)}), so "
+                            f"{name!r} is not an output of the pass: a kernel "
+                            "must return the same names every time it is traced"
+                        )
+                for q, out in out_refs.items():
                     cent = plane(q, r)
-                    out_refs[q][0] = cent  # keep the y/z shell ring
-                    if name in vals:
-                        out_refs[q][0, y0:y1, z0:z1] = vals[name][
+                    out[0] = cent  # keep the y/z shell ring
+                    if names[q] in vals:
+                        out[0, y0:y1, z0:z1] = vals[names[q]][
                             y0:y1, z0:z1
                         ].astype(cent.dtype)
 
             @pl.when(jnp.logical_not(in_window))
             def _():
-                for q in range(nq):
+                for q, out in out_refs.items():
                     # shell plane j = i - r passes through from the ring
                     # (slot is garbage for i < r, where plane j < 0 doesn't
                     # exist — those writes land on out plane 0, which step
                     # i == r rewrites with the real pass-through)
-                    out_refs[q][0] = plane(q, r)
+                    out[0] = plane(q, r)
 
         @pl.when(i == 0)
         def _():
-            for q in range(nq):
-                out_refs[q][0] = curs[q]  # first plane passes through
+            for q, out in out_refs.items():
+                out[0] = curs[q]  # first plane passes through
 
         # push the fetched plane (skip replayed last-plane refetches)
         @pl.when(i <= X - 1)
@@ -436,28 +467,34 @@ def stream_plane_pass(
         args += list(xs_list) + list(ys_list) + list(zs_list)
     out_specs = tuple(
         pl.BlockSpec((1, Y, Z), lambda i: (jnp.clip(i - r, 0, X - 1), 0, 0))
-        for _ in range(nq)
+        for _ in wq
     )
     out_shape = tuple(
-        jax.ShapeDtypeStruct((X, Y, Z), b.dtype) for b in raws
+        jax.ShapeDtypeStruct((X, Y, Z), raws[q].dtype) for q in wq
     )
     outs = pl.pallas_call(
         body,
         name=tm.KERNEL_STREAM_PLANE_PASS,
         grid=(X + r,),
         in_specs=in_specs,
-        out_specs=out_specs if nq > 1 else out_specs[0],
-        out_shape=out_shape if nq > 1 else out_shape[0],
+        out_specs=out_specs if len(wq) > 1 else out_specs[0],
+        out_shape=out_shape if len(wq) > 1 else out_shape[0],
         # operand 0 is origin; fused-shell side inputs sit after the raws,
-        # so the map is raw-q -> out-q whatever rides in
-        input_output_aliases={1 + q: q for q in range(nq)} if alias else {},
+        # so the map is raw-q -> its place among the writers' outputs
+        # whatever rides in
+        input_output_aliases=(
+            {1 + q: k for k, q in enumerate(wq)} if alias else {}
+        ),
         scratch_shapes=[
             pltpu.VMEM((2 * r, Y, Z), b.dtype) for b in raws
         ],
         interpret=interpret,
         **_tpu_compiler_params(interpret),
     )(*args)
-    return list(outs) if nq > 1 else [outs]
+    result = list(raws)  # a non-writer comes back as the array that went in
+    for q, o in zip(wq, outs if len(wq) > 1 else [outs]):
+        result[q] = o
+    return result
 
 
 def stream_wavefront_pass(
@@ -1077,20 +1114,23 @@ def permute_and_extend_z_slabs(zout, s: int, mesh_shape, yext, xext):
         return jnp.concatenate([xext(yext(zlo)), xext(yext(zhi))], axis=1)
 
 
-def plane_halo_readers(
+def plane_footprint(
     kernel: PlaneKernel,
     names: Sequence[str],
     groups: Sequence[Sequence[int]],  # the passes' quantity indices
     planes: Sequence[jax.ShapeDtypeStruct],  # per quantity, as the kernel sees it
     x_radius: int,
     global_size: Dim3,
-) -> Tuple[str, ...]:
-    """The quantities a PLANE-route step exchanges: those ``kernel`` reads
-    off-centre, in ``names``' order — learnt from the kernel itself by one
-    abstract trace (``jax.eval_shape``, nothing runs) over ``PlaneView``s
-    that record every ``sh`` with a non-zero offset, called group by group
-    as the passes call it.  A function of the kernel, as ``_sweep_kind`` is
-    a function of the mesh: no option, no plan value a user sets.
+) -> Tuple[Tuple[str, ...], Tuple[str, ...]]:
+    """``(halo_readers, writers)`` of a PLANE-route step, each in ``names``'
+    order: the quantities ``kernel`` reads off-centre — the ones the step
+    exchanges — and the quantities it returns — the ones the pass writes.
+    Both are learnt from the kernel itself by one abstract trace
+    (``jax.eval_shape``, nothing runs) over ``PlaneView``s that record every
+    ``sh`` with a non-zero offset, called group by group as the passes call
+    it, keeping the keys of the dict each call returns.  A function of the
+    kernel, as ``_sweep_kind`` is a function of the mesh: no option, no plan
+    value a user sets.
 
     Why the others keep a stale shell and the result is the same.  The plane
     pass is ONE level and writes interior cells only (shell planes and the
@@ -1109,17 +1149,25 @@ def plane_halo_readers(
     plane route's split schedule takes it: its exterior bands are interior
     cells too.
 
-    Fail closed, twice: a footprint trace that raises exchanges every
-    quantity, and the pass itself raises at trace time on an off-centre read
-    this trace did not see (``stream_plane_pass(halo_readers=)``)."""
-    seen = set()
+    Why a quantity outside the writers need not be written.  The pass
+    writes a quantity's centre plane back unchanged unless the kernel
+    returned a value for it (``stream_plane_pass``): for a name the kernel
+    never returns, every raw cell out is the raw cell in, so the step keeps
+    the input array and moves nothing — a coefficient or an older time level
+    is then read once a step, not read and written.
+
+    Fail closed, twice for each: a footprint trace that raises exchanges AND
+    writes every quantity, and the pass itself raises at trace time on an
+    off-centre read or a returned name this trace did not see
+    (``stream_plane_pass(halo_readers=, writers=)``)."""
+    seen, returned = set(), set()
     roll = _make_roll(True)  # jnp.roll: the trace runs outside any kernel
     Y, Z = planes[0].shape
 
     def footprint(x_g, y_g, z_g, *vs):
         info = PlaneInfo(x_g, y_g, z_g, global_size, 1)
         for g in groups:
-            kernel(
+            vals = kernel(
                 {
                     names[q]: PlaneView(
                         (vs[q],) * (2 * x_radius + 1), roll,
@@ -1129,6 +1177,8 @@ def plane_halo_readers(
                 },
                 info,
             )
+            # a group's pass stores only its own quantities
+            returned.update(names[q] for q in g if names[q] in vals)
 
     i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
     try:
@@ -1138,10 +1188,13 @@ def plane_halo_readers(
 
         log_warn(
             f"the stream kernel's footprint trace raised ({exc!r}); "
-            "exchanging every quantity"
+            "exchanging and writing every quantity"
         )
-        return tuple(names)
-    return tuple(nm for nm in names if nm in seen)
+        return tuple(names), tuple(names)
+    return (
+        tuple(nm for nm in names if nm in seen),
+        tuple(nm for nm in names if nm in returned),
+    )
 
 
 def static_stream_alias(route: str, n_fields: int) -> bool:
@@ -1556,14 +1609,16 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
             [lax.axis_index(MESH_AXES[ax]) * n[ax] for ax in range(3)]
         )
 
-    # the quantities that ride the step's exchange, written back like alias
-    # (domain.step's ``exchanged`` counts them): none on the wrap route, on
-    # the plane route those the kernel reads off-centre, every one wherever
-    # the rule does not hold (plane_halo_readers says where and why)
+    # the quantities that ride the step's exchange and those its pass
+    # writes, written back like alias (domain.step's ``exchanged`` and
+    # ``written`` count them): on the plane route those the kernel reads
+    # off-centre and those it returns, every one wherever the rules do not
+    # hold (plane_footprint says where and why); the wrap route exchanges none
+    plan["writers"] = tuple(names)
     if plan["route"] == "wrap":
         plan["halo_readers"] = ()
     elif plan["route"] == "plane" and not fused:
-        plan["halo_readers"] = plane_halo_readers(
+        plan["halo_readers"], plan["writers"] = plane_footprint(
             kernel, names, groups,
             [
                 jax.ShapeDtypeStruct(
@@ -1610,7 +1665,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
 
     elif plan["route"] == "plane":
         in_place = _plan_passes_in_place(plan)
-        readers = plan["halo_readers"]
+        readers, writers = plan["halo_readers"], plan["writers"]
         riders = [q for q, name in enumerate(names) if name in readers]
 
         def exchange_readers(bs):
@@ -1642,6 +1697,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                         lo, hi, x_radius, origin, gsize, alias=in_place,
                         interpret=interpret, fused_shell=fs,
                         f32_accumulate=f32_acc, halo_readers=readers,
+                        writers=writers,
                     )
                 for q, o in zip(g, outs):
                     out[q] = o
@@ -1684,7 +1740,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                         kernel, [names[q] for q in g], [subs[q] for q in g],
                         lo2, hi2, x_radius, origin_sub, gsize,
                         interpret=interpret, f32_accumulate=f32_acc,
-                        halo_readers=readers,
+                        halo_readers=readers, writers=writers,
                     )
                     for q, o in zip(g, outs):
                         out[q] = o
@@ -1908,13 +1964,17 @@ def make_stream_step(
     ``plan_stream``).
 
     On the plane route the step exchanges only the quantities the kernel
-    reads OFF-CENTRE (``plane_halo_readers``: one abstract trace of the
+    reads OFF-CENTRE (``plane_footprint``: one abstract trace of the
     kernel at build time; the resolved set is ``plan["halo_readers"]``, its
     size ``domain.step``'s ``exchanged``).  A quantity read through
     ``center()`` alone — a coefficient, an older time level — keeps a stale
     shell that nothing reads; every interior cell is bitwise what exchanging
-    all of them gives.  So a kernel must read the same offsets every time it
-    is traced: the pass raises, naming the quantity, if it does not.
+    all of them gives.  The same trace learns which quantities the kernel
+    RETURNS (``plan["writers"]``, ``domain.step``'s ``written``): the others
+    are inputs of the pass and nothing else, read once a step and never
+    written back.  So a kernel must read the same offsets and return the
+    same names every time it is traced: the pass raises, naming the
+    quantity, if it does not.
 
     ``max_depth`` caps the temporal depth (wrap k / wavefront m).  The auto
     planner maximizes depth because depth is the HBM-traffic lever
@@ -2133,13 +2193,17 @@ def make_stream_step(
             "x_radius": x_radius,
             "grouping": plan_now.get("grouping", "joint"),
             "streamed": nq,
-            # quantities whose pass output aliases its input (all or none)
+            # quantities the passes carry in place (all or none): a written
+            # one's output aliases its input, an unwritten one IS its input
             "aliased": nq if _plan_passes_in_place(plan_now) else 0,
             # quantities riding the step's exchange: what the kernel reads
-            # off-centre on the plane route (plane_halo_readers), every one
+            # off-centre on the plane route (plane_footprint), every one
             # on the wavefront route, none on the wrap route (and, like
             # ``aliased``, none while the plan is not built yet)
             "exchanged": len(plan_now.get("halo_readers", ())),
+            # quantities the passes write: what the kernel returns on the
+            # plane route (plane_footprint), every one elsewhere
+            "written": len(plan_now.get("writers", ())),
         }
 
     step._span_args = span_args

@@ -315,11 +315,15 @@ ALL_HISTOGRAMS = frozenset({
 
 #: the enqueue of one ``run_step`` dispatch [label, steps = raw iterations;
 #: a stream-engine step adds the plan it ran: route, x_radius, grouping,
-#: streamed = quantities in the pass, aliased = quantities whose pass output
-#: aliases its input (all or none: ``ops/stream._plan_passes_in_place``), exchanged
+#: streamed = quantities in the pass, aliased = quantities the passes carry in
+#: place (all or none: ``ops/stream._plan_passes_in_place``; a written one's
+#: output aliases its input, an unwritten one is its input), exchanged
 #: = quantities riding the step's halo exchange: on the plane route those the
-#: kernel reads off-centre (``ops/stream.plane_halo_readers``; all of them under
-#: ``halo="fused"``), every one on the wavefront route, 0 on the wrap route]
+#: kernel reads off-centre (``ops/stream.plane_footprint``; all of them under
+#: ``halo="fused"``), every one on the wavefront route, 0 on the wrap route,
+#: written = quantities that are outputs of the passes: on the plane route
+#: those the kernel returns (the same trace, ``plan["writers"]``; all of them
+#: under ``halo="fused"``), every one on the other routes]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes

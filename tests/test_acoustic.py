@@ -92,8 +92,10 @@ def test_route_is_plane_and_the_span_says_so():
     assert sim._step._span_args() == {
         "route": "plane", "x_radius": 4, "grouping": "joint", "streamed": 4,
         "aliased": 4, "exchanged": 1,  # u alone is read off-centre (ISSUE 30)
+        "written": 2,  # m and damp are never returned: inputs only (ISSUE 32)
     }
     assert plan["halo_readers"] == ("u",), plan
+    assert plan["writers"] == ("u", "u_prev"), plan
     seen = []
     real = telemetry.span
 
@@ -106,7 +108,7 @@ def test_route_is_plane_and_the_span_says_so():
         sim.step(2)
     (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
     assert kw["label"] == "acoustic" and kw["steps"] == 2 and kw["route"] == "plane"
-    assert (kw["streamed"], kw["aliased"], kw["exchanged"]) == (4, 4, 1)
+    assert (kw["streamed"], kw["aliased"], kw["exchanged"], kw["written"]) == (4, 4, 1, 2)
 
 
 @pytest.mark.parametrize("devices", [1, 2, 8])
@@ -147,28 +149,33 @@ def test_the_step_program_exchanges_u_alone(monkeypatch):
     assert not [e for e in jx.iter_eqns(closed) if e.primitive.name == "ppermute"]
 
 
+def _plane_passes(fn, curr):
+    """Every ``stream_plane_pass`` equation of the traced step."""
+    from test_plane_stencil import _pass_calls
+
+    return _pass_calls(jax.make_jaxpr(fn, static_argnums=1)(curr, 1))
+
+
 def _plane_pass_aliases(fn, curr):
     """``input_output_aliases`` of every ``stream_plane_pass`` in the traced step."""
-    from stencil_tpu.analysis import jaxpr as jx
+    from test_plane_stencil import _alias_pairs
 
-    closed = jax.make_jaxpr(fn, static_argnums=1)(curr, 1)
-    return [
-        tuple(tuple(int(v) for v in pair) for pair in e.params["input_output_aliases"])
-        for e in jx.iter_eqns(closed)
-        if e.primitive.name == "pallas_call"
-        and e.params.get("name") == tm.KERNEL_STREAM_PLANE_PASS
-    ]
+    return [_alias_pairs(e) for e in _plane_passes(fn, curr)]
 
 
 def test_the_step_program_says_the_pass_is_in_place():
-    """The step as built: ONE plane pass whose four outputs alias the four raw
-    blocks (operand 0 is ``origin``); the same plan forced off carries none,
-    and says so in the plan and on the span."""
+    """The step as built: ONE plane pass over the four raw blocks with TWO
+    outputs, ``u`` and ``u_prev``, each aliased onto its raw block (operand 0
+    is ``origin``) -- ``m`` and ``damp`` are inputs and nothing else (ISSUE
+    32); the same plan forced off carries no alias, and says so in the plan
+    and on the span."""
     from stencil_tpu.ops import stream as sm
 
     sim = _sim("pallas")
+    (call,) = _plane_passes(sim._step._resilience.built(), sim.dd._curr)
+    assert len(call.outvars) == 2 and len(call.invars) == 1 + 4, call
     assert _plane_pass_aliases(sim._step._resilience.built(), sim.dd._curr) == [
-        ((1, 0), (2, 1), (3, 2), (4, 3))
+        ((1, 0), (2, 1))
     ]
     plan = dict(sim._step._stream_plan, alias=False, alias_forced=True)
     off = sm._build_stream_step(sim.dd, sim._kernel, ref.RADIUS, plan, interpret=True)
@@ -180,6 +187,7 @@ def test_the_step_program_says_the_pass_is_in_place():
     split = sm._build_stream_step(sim.dd, sim._kernel, ref.RADIUS, plan, interpret=True)
     passes = _plane_pass_aliases(split, sim.dd._curr)
     assert len(passes) == 7 and set(passes) == {()}, passes  # interior + six bands
+    assert {len(e.outvars) for e in _plane_passes(split, sim.dd._curr)} == {2}
     assert plan["overlap"] == "split" and plan["alias"] is True
     assert sm._plan_passes_in_place(plan) is False
 

@@ -145,7 +145,7 @@ def test_mean6_kernel_axes_variants():
 # --- exchange only what the kernel reads (ISSUE 30) ---------------------------
 #
 # On the plane route the step exchanges the quantities the kernel reads
-# off-centre and no others (ops/stream.py plane_halo_readers).  Every case
+# off-centre and no others (ops/stream.py plane_footprint).  Every case
 # runs the same kernel three ways -- the XLA slice engine (which exchanges
 # everything), the plane route as built, and the plane route with the rule
 # switched off (the parent's program: every quantity exchanged) -- and holds
@@ -218,7 +218,7 @@ def _plane_step(dd, kernel, r, plan_kw):
 def _exchange_everything(monkeypatch):
     from stencil_tpu.ops import stream as sm
 
-    monkeypatch.setattr(sm, "plane_halo_readers", lambda kernel, names, *a: tuple(names))
+    monkeypatch.setattr(sm, "plane_footprint", lambda kernel, names, *a: (tuple(names),) * 2)
 
 
 def _ppermute_cells(fn, curr) -> int:
@@ -366,3 +366,286 @@ def test_an_off_centre_read_the_footprint_trace_missed_raises_by_name():
     assert plan["halo_readers"] == ("a",)
     with pytest.raises(ValueError, match=r"reads 'c' off-centre.*halo of 'c' was not exchanged"):
         step.lower(dd._curr, 1)
+
+
+# --- write only what the kernel writes (ISSUE 32) -----------------------------
+#
+# The same trace learns which quantities the kernel RETURNS; the others are
+# inputs of the plane pass and nothing else (ops/stream.py plane_footprint,
+# stream_plane_pass(writers=)).  A pass with the rule off wrote every such
+# quantity back cell for cell, so the two must agree on every RAW cell of
+# every quantity, shell included.
+
+
+def two_of_four_kernel(r):
+    """``a`` and ``b`` are written; ``c`` is read off-centre (a coefficient
+    with a halo) and ``d`` at the centre, and neither is returned."""
+
+    def kernel(views, info):
+        a, b, c, d = (views[n] for n in "abcd")
+        return {
+            "a": _star(a, r) * (1.0 + 0.5 * d.center()) - 0.25 * c.sh(0, 0, -r),
+            "b": 0.5 * _star(b, r) + 0.125 * a.center() * c.sh(r, 0, 0),
+        }
+
+    return kernel
+
+
+def separable_two_of_three(r):
+    """Correct on any subset of views: ``a`` diffuses, ``b`` decays, ``c`` is
+    carried and never returned -- under per-field grouping its pass has
+    nothing to write and is not made."""
+
+    def kernel(views, info):
+        return {
+            name: _star(v, r) if name == "a" else 0.5 * v.center() + 0.25
+            for name, v in views.items()
+            if name != "c"
+        }
+
+    return kernel
+
+
+def _raising_kernel(views, info):
+    raise RuntimeError("not traceable")
+
+
+def _footprint(kernel, names, r, groups=None):
+    import jax
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops import stream as sm
+
+    plane = jax.ShapeDtypeStruct((16 + 2 * r, 16 + 2 * r), jnp.float32)
+    return sm.plane_footprint(
+        kernel, names, groups or [list(range(len(names)))], [plane] * len(names),
+        r, Dim3(16, 16, 16),
+    )
+
+
+@pytest.mark.parametrize(
+    "kernel,names,r,groups,readers,writers",
+    [
+        pytest.param(two_of_four_kernel(2), list("abcd"), 2, None,
+                     ("a", "b", "c"), ("a", "b"), id="two-of-four"),
+        pytest.param(two_of_four_kernel(1), list("dcba"), 1, None,
+                     ("c", "b", "a"), ("b", "a"), id="order-is-names"),
+        pytest.param(centre_only_kernel, ["u", "v"], 1, None,
+                     (), ("u", "v"), id="returns-every-name"),
+        pytest.param(separable_kernel(2), ["a", "b"], 2, [[0], [1]],
+                     ("a",), ("a", "b"), id="per-field-groups"),
+        pytest.param(_raising_kernel, list("abcd"), 2, None,
+                     tuple("abcd"), tuple("abcd"), id="a-trace-that-raises-writes-everything"),
+    ],
+)
+def test_the_footprint_trace_learns_what_the_kernel_returns(
+    kernel, names, r, groups, readers, writers
+):
+    assert _footprint(kernel, names, r, groups) == (readers, writers)
+
+
+def _pass_calls(closed):
+    from stencil_tpu.analysis import jaxpr as jx
+    from stencil_tpu.telemetry import names as tm
+
+    return [
+        e for e in jx.iter_eqns(closed)
+        if e.primitive.name == "pallas_call"
+        and e.params.get("name") == tm.KERNEL_STREAM_PLANE_PASS
+    ]
+
+
+def _alias_pairs(eqn):
+    return tuple(tuple(int(v) for v in p) for p in eqn.params["input_output_aliases"])
+
+
+@pytest.mark.parametrize("alias", [False, True], ids=["fresh", "aliased"])
+def test_the_pass_has_one_output_per_writer_and_is_bitwise_the_full_pass(alias):
+    """``stream_plane_pass`` itself, four quantities, two of them read-only:
+    the Pallas call has two outputs (and, aliased, two alias pairs from raw
+    ``1 + q`` to the writer's place among the outputs), a read-only quantity
+    comes back as the very array that went in, and all four results equal the
+    every-quantity-written pass on every raw cell."""
+    import jax
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops.stream import stream_plane_pass
+
+    r, n = 2, 12
+    names = list("dabc")  # the writers sit at positions 1 and 2
+    raw = n + 2 * r
+    rng = np.random.default_rng(32)
+    raws = [jnp.asarray(rng.standard_normal((raw,) * 3), jnp.float32) for _ in names]
+    origin = jnp.zeros((3,), jnp.int32)
+
+    def run(writers):
+        def fn(origin, *raws):
+            return stream_plane_pass(
+                two_of_four_kernel(r), names, list(raws), Dim3(r, r, r), Dim3(r, r, r),
+                r, origin, Dim3(n, n, n), alias=alias, interpret=True, writers=writers,
+            )
+
+        (call,) = _pass_calls(jax.make_jaxpr(fn)(origin, *raws))
+        return call, fn(origin, *raws)
+
+    call, got = run(("a", "b"))
+    full_call, want = run(None)
+    assert len(call.invars) == len(full_call.invars) == 1 + 4
+    assert (len(call.outvars), len(full_call.outvars)) == (2, 4)
+    assert _alias_pairs(call) == (((2, 0), (3, 1)) if alias else ())
+    assert _alias_pairs(full_call) == (((1, 0), (2, 1), (3, 2), (4, 3)) if alias else ())
+    assert got[0] is raws[0] and got[3] is raws[3]
+    for name, a, b, src in zip(names, got, want, raws):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+        assert (name in "ab") == bool(np.any(np.asarray(a) != np.asarray(src))), name
+
+
+def _write_everything(monkeypatch):
+    """The rule off: the parent's pass, every quantity an output (the readers
+    stay as the trace found them)."""
+    from stencil_tpu.ops import stream as sm
+
+    real = sm.plane_footprint
+    monkeypatch.setattr(
+        sm, "plane_footprint",
+        lambda kernel, names, *a: (real(kernel, names, *a)[0], tuple(names)),
+    )
+
+
+_FOUR = (two_of_four_kernel(2), list("abcd"))
+_WRITER_CASES = [
+    # kernel, names, n_dev, plan_kw, Pallas outputs of each plane pass of one
+    # step with the rule on and with it off
+    pytest.param(*_FOUR, 1, {}, [2], [4], id="in-place"),
+    pytest.param(*_FOUR, 1, {"alias": False, "alias_forced": True}, [2], [4],
+                 id="fresh-output"),
+    pytest.param(*_FOUR, 8, {}, [2], [4], id="mesh-2x2x2"),
+    pytest.param(*_FOUR, 8, SPLIT, [2] * 7, [4] * 7, id="split"),  # interior + six bands
+    # c's own pass has nothing to write and is not made
+    pytest.param(separable_two_of_three(2), ["a", "b", "c"], 1, {"grouping": "per-field"},
+                 [1, 1], [1, 1, 1], id="per-field"),
+]
+
+
+@pytest.mark.parametrize("kernel,names,n_dev,plan_kw,outputs,outputs_all", _WRITER_CASES)
+def test_plane_route_writes_only_what_the_kernel_returns(
+    kernel, names, n_dev, plan_kw, outputs, outputs_all, monkeypatch
+):
+    """The step as built, rule on against rule off, three steps: every raw
+    cell of every quantity bitwise equal (no exchange in between: the arrays
+    as the step left them), the interior equal to the XLA engine's."""
+    import jax
+
+    from stencil_tpu.ops import stream as sm
+
+    r = 2
+
+    def run_plane():
+        dd, hs = _plane_domain(names, r, n_dev)
+        step, plan = _plane_step(dd, kernel, r, plan_kw)
+        calls = _pass_calls(jax.make_jaxpr(step, static_argnums=1)(dd._curr, 1))
+        dd.run_step(step, 3)
+        for key, want in plan_kw.items():
+            assert plan[key] == want, plan
+        raws = {h.name: np.asarray(dd._curr[h.name]) for h in hs}
+        return raws, [dd.quantity_to_host(h) for h in hs], plan, calls
+
+    raws, fields, plan, calls = run_plane()
+    assert plan["writers"] == ("a", "b"), plan
+    assert [len(e.outvars) for e in calls] == outputs
+    in_place = sm._plan_passes_in_place(plan)
+    for e in calls:
+        assert len(_alias_pairs(e)) == (len(e.outvars) if in_place else 0)
+    _write_everything(monkeypatch)
+    raws_all, _, plan_all, calls_all = run_plane()
+    assert plan_all["writers"] == tuple(names) and plan_all["halo_readers"] == plan["halo_readers"]
+    assert [len(e.outvars) for e in calls_all] == outputs_all
+    dd, hs = _plane_domain(names, r, n_dev)
+    dd.run_step(dd.make_step(kernel, overlap=False), 3)
+    for i, (name, h) in enumerate(zip(names, hs)):
+        assert np.array_equal(raws[name], raws_all[name]), name
+        assert np.array_equal(fields[i], dd.quantity_to_host(h)), name
+
+
+def test_the_span_counts_the_written_quantities():
+    from stencil_tpu.analysis.programs import tpu_shaped_trace
+
+    with tpu_shaped_trace():
+        dd, _ = _plane_domain(list("abcd"), 2, 1)
+        step = dd.make_step(two_of_four_kernel(2), engine="stream", x_radius=2,
+                            interpret=True)
+        args = step._span_args()
+    assert (args["route"], args["streamed"], args["aliased"], args["exchanged"],
+            args["written"]) == ("plane", 4, 4, 3, 2), args
+
+
+def test_a_name_the_footprint_trace_never_saw_returned_raises_by_name():
+    """The real trace checks the abstract one: a kernel that returns ``c``
+    only the SECOND time it is traced meets a pass in which ``c`` is no
+    output, and the pass says so at trace time -- never a dropped result."""
+    calls = []
+
+    def kernel(views, info):
+        calls.append(1)
+        out = {"a": _star(views["a"], 1) * views["c"].center()}
+        if len(calls) > 1:
+            out["c"] = views["c"].center() + 1.0
+        return out
+
+    dd, _ = _plane_domain(["a", "c"], 1, 1)
+    step, plan = _plane_step(dd, kernel, 1, {})
+    assert plan["writers"] == ("a",)
+    with pytest.raises(ValueError, match=r"returns 'c'.*'c' is not an output of the pass"):
+        step.lower(dd._curr, 1)
+
+
+def test_fused_shell_keeps_every_quantity_an_output():
+    """Under ``halo="fused"`` the written planes are where the fresh shell
+    lands: the build names every quantity a writer, and the pass itself keeps
+    every output whatever ``writers`` it is handed."""
+    import jax
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops.stream import stream_plane_pass
+
+    r, n = 1, 8
+    raw = n + 2 * r
+    blk = jax.ShapeDtypeStruct((raw,) * 3, jnp.float32)
+    xs = jax.ShapeDtypeStruct((2 * r, raw, raw), jnp.float32)
+    ys = jax.ShapeDtypeStruct((raw, 2 * r, raw), jnp.float32)
+
+    def fn(origin, a, c, xa, xc, ya, yc, za, zc):
+        return stream_plane_pass(
+            lambda views, info: {"a": _star(views["a"], r) * views["c"].center()},
+            ["a", "c"], [a, c], Dim3(r, r, r), Dim3(r, r, r), r, origin,
+            Dim3(n, n, n), alias=True, interpret=True,
+            fused_shell=([xa, xc], [ya, yc], [za, zc]), writers=("a",),
+        )
+
+    origin = jax.ShapeDtypeStruct((3,), jnp.int32)
+    (call,) = _pass_calls(jax.make_jaxpr(fn)(origin, blk, blk, xs, xs, ys, ys, ys, ys))
+    assert len(call.outvars) == 2 and _alias_pairs(call) == ((1, 0), (2, 1))
+
+
+def test_a_fused_plane_step_writes_every_quantity():
+    import jax
+
+    from test_stream_fused import _mk
+
+    dd, hs = _mk(dtypes=(np.float32, np.float32))
+
+    def kernel(views, info):  # q1 is a coefficient: read, never returned
+        return {"q0": _star(views["q0"], 1) * (1.0 + views["q1"].center())}
+
+    step = dd.make_step(kernel, engine="stream", interpret=True, stream_halo="fused",
+                        stream_path="plane")
+    plan = step._stream_plan
+    assert (plan["route"], plan["halo"]) == ("plane", "fused"), plan
+    assert plan["writers"] == ("q0", "q1") and step._span_args()["written"] == 2
+    (call,) = _pass_calls(
+        jax.make_jaxpr(step._resilience.built(), static_argnums=1)(dd._curr, 1)
+    )
+    assert len(call.outvars) == 2

@@ -437,6 +437,7 @@ def test_stream_separable_per_field_grouping(monkeypatch):
         "alias": True,  # four fields: the wavefront's static rule, written back
         "overlap": "off", "halo": "array",
         "halo_readers": ("a", "b", "c", "d"),  # the wavefront exchanges every quantity
+        "writers": ("a", "b", "c", "d"),  # and writes every one
     }
     monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
     ref_dd, ref_hs = _mk(24, 24, 24, Radius.constant(1), names, devs)
@@ -505,6 +506,7 @@ def test_stream_depth_cap():
         "alias": False,  # the wrap pass has no in-place form
         "overlap": "off", "halo": "array",
         "halo_readers": (),  # and no exchange
+        "writers": ("u",),
     }
     for a, b in outs:  # uncapped wrap vs the XLA ground truth
         np.testing.assert_allclose(a, b, **TOL)

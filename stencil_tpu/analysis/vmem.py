@@ -74,6 +74,23 @@ def check_vmem(dd, plan: dict, budget: Optional[int] = None) -> Optional[str]:
     if route not in ("wrap", "wavefront", "plane"):
         raise ValueError(f"not a stream plan: {plan!r}")
     m = int(plan.get("m", 1))
+    if route == "plane" and plan.get("stages"):
+        # a planned plane step: its passes carry their own modeled bytes
+        # (ops/stream.py plan_plane_passes, stack margin included)
+        from stencil_tpu.ops.jacobi_pallas import _vmem_budget
+
+        cap = budget if budget is not None else _vmem_budget()
+        worst = max(
+            (p for st in plan["stages"] for p in st["passes"]),
+            key=lambda p: p["vmem_bytes"], default=None,
+        )
+        if worst is not None and worst["vmem_bytes"] > cap:
+            return (
+                f"plan plane[m=1]: the pass that writes {worst['writes']} models "
+                f"{worst['vmem_bytes'] / 1e6:.1f} MB of VMEM (stack included) "
+                f"against the {cap / 1e6:.1f} MB budget"
+            )
+        return None
     raw = dd.local_spec().raw_size()
     itemsizes: List[int] = [dd.field_dtype(h).itemsize for h in dd._handles]
     ring_sizes: List[int] = [h.dtype.itemsize for h in dd._handles]

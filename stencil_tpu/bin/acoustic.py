@@ -27,14 +27,17 @@ from stencil_tpu.models.acoustic_reference import FRAME, RADIUS
 from stencil_tpu.utils.statistics import Statistics
 
 
-def main(argv=None) -> int:
-    p = argparse.ArgumentParser("acoustic")
+def run(argv, name: str, model, steps: int) -> int:
+    """The driver of a wave propagator on ``AcousticGrid`` (``model`` is
+    ``AcousticWave`` or ``ElasticWave``: same grid, frame and sponge
+    geometry, same constructor); ``name`` labels the row and the run."""
+    p = argparse.ArgumentParser(name)
     p.add_argument("x", type=int, nargs="?", default=512, help="physical extent (Devito's -d)")
     p.add_argument("y", type=int, nargs="?", default=512)
     p.add_argument("z", type=int, nargs="?", default=512)
     p.add_argument("--nbl", type=int, default=40, help="sponge cells per side")
     p.add_argument("--iters", "-n", type=int, default=5, help="timed dispatches")
-    p.add_argument("--steps", type=int, default=8, help="time steps per dispatch")
+    p.add_argument("--steps", type=int, default=steps, help="time steps per dispatch")
     p.add_argument("--seed", type=int, default=0, help="seed of vp's layers and the wave packet")
     p.add_argument(
         "--kernel-impl",
@@ -46,7 +49,7 @@ def main(argv=None) -> int:
     _common.add_numerics_flag(p)
     _common.add_checkpoint_flags(p)
     args = p.parse_args(argv)
-    args.interpret = _common.require_platform("acoustic")
+    args.interpret = _common.require_platform(name)
     _common.telemetry_begin(args)
 
     pad = 2 * (args.nbl + FRAME)
@@ -55,7 +58,7 @@ def main(argv=None) -> int:
     )
     print(f"domain: {x},{y},{z} ({x - pad},{y - pad},{z - pad} physical)", file=sys.stderr)
     words = [int(w) for w in jax.random.bits(jax.random.key(args.seed), (4,), "uint32")]
-    sim = AcousticWave(
+    sim = model(
         x, y, z, nbl=args.nbl, kernel_impl=args.kernel_impl,
         interpret=args.interpret, seed_words=words,
     )
@@ -71,8 +74,8 @@ def main(argv=None) -> int:
         iter_time.insert(time.perf_counter() - t0)
 
     sup = _common.supervisor_for(
-        args, sim.dd, label="acoustic",
-        run_state=lambda: {"model": "acoustic", "nbl": args.nbl, "seed": args.seed},
+        args, sim.dd, label=name,
+        run_state=lambda: {"model": name, "nbl": args.nbl, "seed": args.seed},
         on_mesh_change=sim.rebuild_after_reshard,
     )
     rc = 0
@@ -95,11 +98,15 @@ def main(argv=None) -> int:
         ranks, dev_count = _common.ranks_and_devcount()
         gpts = x * y * z * args.steps / iter_time.trimean() / 1e9
         print(
-            f"acoustic,{ranks},{dev_count},{x - pad},{y - pad},{z - pad},{args.nbl},"
+            f"{name},{ranks},{dev_count},{x - pad},{y - pad},{z - pad},{args.nbl},"
             f"{iter_time.min()},{iter_time.trimean()},{gpts}"
         )
     _common.telemetry_end(args)
     return rc
+
+
+def main(argv=None) -> int:
+    return run(argv, "acoustic", AcousticWave, steps=8)
 
 
 if __name__ == "__main__":

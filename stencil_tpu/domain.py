@@ -631,9 +631,11 @@ class DistributedDomain:
             log_info(f"realized (abstract) {self._size} over mesh {dim} (raw shard {raw})")
             return
         t0 = time.perf_counter()
+        both = self._both_slots_fit()
         for h in self._handles:
             self._curr[h.name] = self._zeros(h, gshape)
-            self._next[h.name] = self._zeros(h, gshape)
+            if both:
+                self._next[h.name] = self._zeros(h, gshape)
         self.stats.time_realize = time.perf_counter() - t0
         t0 = time.perf_counter()
         if self._methods in (MethodFlags.AllGather, MethodFlags.RollCompare):
@@ -681,6 +683,38 @@ class DistributedDomain:
                 self._record_exchange_compile(t0, f"realize:{self._exchange_route}")
         self._realized = True
         log_info(f"realized {self._size} over mesh {dim} (raw shard {raw})")
+
+    def _both_slots_fit(self) -> bool:
+        """Do ``curr`` AND ``next`` of every quantity fit one device's memory?
+        Where they do, ``realize()`` allocates both, as the reference does
+        (its double buffer, ``swap()``); where they do not -- elastic's
+        thirteen 608^3 quantities are 12.3 GB of a 16.9 GB chip, and a built
+        step carries ``curr`` in place and never touches ``next`` -- the
+        ``next`` slot is allocated on first use (``_next_slot``).  A backend
+        that does not say how much memory it has (the CPU) gets both."""
+        stats = self.mesh.devices.flat[0].memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        if not limit:
+            return True
+        from stencil_tpu.ops.jacobi_pallas import _padded_plane_bytes
+
+        raw = self._spec.raw_size()
+        per_device = sum(
+            int(np.prod(h.components, dtype=np.int64)) * raw.x
+            * _padded_plane_bytes(raw.y, raw.z, self.field_dtype(h).itemsize)
+            for h in self._handles
+        )
+        return 2 * per_device <= limit
+
+    def _next_slot(self) -> Dict[str, jax.Array]:
+        """The ``next`` slot, every quantity allocated (zeroed) by now."""
+        missing = [h for h in self._handles if h.name not in self._next]
+        if missing:
+            dim, raw = self.placement.dim(), self._spec.raw_size()
+            gshape = (dim.x * raw.x, dim.y * raw.y, dim.z * raw.z)
+            for h in missing:
+                self._next[h.name] = self._zeros(h, gshape)
+        return self._next
 
     def _zeros(self, h: DataHandle, gshape) -> jax.Array:
         """A zeroed array of quantity ``h`` on the mesh, in its pinned layout
@@ -823,7 +857,10 @@ class DistributedDomain:
         # the redistributed arrays come in the backend's default layout
         self._pinned = {}
         gshape = (dim.x * raw.x, dim.y * raw.y, dim.z * raw.z)
-        self._next = {h.name: self._zeros(h, gshape) for h in self._handles}
+        self._next = (
+            {h.name: self._zeros(h, gshape) for h in self._handles}
+            if self._both_slots_fit() else {}
+        )
         # re-realize the exchange plan/executable for the new geometry:
         # the route re-resolves (explicit pin > env > tuned — the tuner is
         # re-keyed automatically, tune_key reads the new placement) and the
@@ -1162,12 +1199,12 @@ class DistributedDomain:
         raw = self._to_raw_global(np.asarray(interior), self.field_dtype(h))
         where = self._pinned.get(h.name, NamedSharding(self.mesh, _qspec(h)))
         arr = jax.device_put(jnp.asarray(raw), where)
-        (self._curr if slot == "curr" else self._next)[h.name] = arr
+        (self._curr if slot == "curr" else self._next_slot())[h.name] = arr
 
     def quantity_to_host(self, h: DataHandle, slot: str = "curr") -> np.ndarray:
         """Gather a quantity's interior to a (X,Y,Z) host array (analog of
         reference quantity_to_host, local_domain.cuh:329-346)."""
-        arr = (self._curr if slot == "curr" else self._next)[h.name]
+        arr = (self._curr if slot == "curr" else self._next_slot())[h.name]
         # bf16-storage buffers upcast back to the native dtype at readback
         # (exact: every bfloat16 is an f32)
         return self._from_raw_global(np.asarray(jax.device_get(arr))).astype(
@@ -1186,7 +1223,7 @@ class DistributedDomain:
         n = self._spec.sz
         raw = self._spec.raw_size()
         lo = self._shell_radius.lo()
-        arr = (self._curr if slot == "curr" else self._next)[h.name]
+        arr = (self._curr if slot == "curr" else self._next_slot())[h.name]
         ext = r.extent()
         out = np.zeros(h.components + (ext.x, ext.y, ext.z), dtype=h.dtype)
         shard_lo = Dim3(*(r.lo[a] // n[a] for a in range(3)))
@@ -1242,7 +1279,7 @@ class DistributedDomain:
         if self._shell_stale and slot == "curr":
             self._curr = self._exchange_fn(self._curr)
             self._shell_stale = False
-        arr = (self._curr if slot == "curr" else self._next)[h.name]
+        arr = (self._curr if slot == "curr" else self._next_slot())[h.name]
         return np.asarray(jax.device_get(arr)).astype(h.dtype, copy=False)
 
     def init_by_coords(self, h: DataHandle, fn, include_halo: bool = False,
@@ -1443,7 +1480,7 @@ class DistributedDomain:
     def swap(self) -> None:
         """Swap curr/next slots (src/stencil.cu:541-561)."""
         with self._phase_timer("time_swap", tm.SWAP_SECONDS, tm.SPAN_SWAP):
-            self._curr, self._next = self._next, self._curr
+            self._curr, self._next = self._next_slot(), self._curr
 
     def block_until_ready(self) -> None:
         """Wait for all in-flight device work on the current buffers —
@@ -1455,7 +1492,7 @@ class DistributedDomain:
         return self._curr[h.name]
 
     def get_next(self, h: DataHandle) -> jax.Array:
-        return self._next[h.name]
+        return self._next_slot()[h.name]
 
     def exchange_bytes_total(self) -> int:
         """Analytic bytes-per-exchange across all subdomains
@@ -1581,6 +1618,12 @@ class DistributedDomain:
     ):
         """Build ``step(curr) -> next`` fusing exchange + compute.
 
+        ``kernel`` is one ``StepKernel`` or a SEQUENCE of them: the STAGES of
+        a time step, run in order inside one device program, each behind its
+        own exchange -- a later stage reads what an earlier one wrote, its
+        halo included (``models/elastic.py``: velocities, then stresses from
+        the new velocities).  ``step(curr, s)`` advances ``s`` whole steps.
+
         With a halo multiplier ``k`` (``set_halo_multiplier``) each built step
         is a MACRO step: one exchange of ``k*r``-wide shells followed by ``k``
         compute sub-steps over shrinking valid regions — ``step(curr, s)``
@@ -1614,6 +1657,9 @@ class DistributedDomain:
           the temporal depth for compute-heavy kernels.
         """
         assert self._realized
+        stages = tuple(kernel) if isinstance(kernel, (list, tuple)) else (kernel,)
+        if not stages:
+            raise ValueError("make_step needs at least one kernel")
         if engine == "stream":
             from stencil_tpu.ops.stream import make_stream_step
 
@@ -1623,7 +1669,7 @@ class DistributedDomain:
                     for ax in range(3)
                 )
             return make_stream_step(
-                self, kernel, x_radius=x_radius, path=stream_path,
+                self, stages, x_radius=x_radius, path=stream_path,
                 separable=separable, interpret=interpret, donate=donate,
                 max_depth=stream_depth, overlap=stream_overlap,
                 halo=stream_halo,
@@ -1636,6 +1682,12 @@ class DistributedDomain:
         r_user = self._radius
         shell = self._shell_radius
         mult = self._halo_mult
+        if mult > 1 and len(stages) > 1:
+            raise ValueError(
+                "a step of several stages exchanges before every stage; a halo "
+                f"multiplier of {mult} (one exchange per {mult} sub-steps) has "
+                "no meaning for it"
+            )
         lo = shell.lo()  # allocation offset of the interior
         mesh_shape = tuple(self.mesh.shape[a] for a in MESH_AXES)
         names = [h.name for h in self._handles]
@@ -1676,7 +1728,7 @@ class DistributedDomain:
         def rect_to_slices(rect: Rect3):
             return tuple(slice(rect.lo[ax], rect.hi[ax]) for ax in range(3))
 
-        def region_update(blocks, region, origin):
+        def region_update(kernel, blocks, region, origin):
             views = {k: ShardView(b, lo, region) for k, b in blocks.items()}
             info = BlockInfo(origin, n, self._size, r_user, region)
             return kernel(views, info)
@@ -1690,7 +1742,13 @@ class DistributedDomain:
             return new_block.at[(Ellipsis,) + idx].set(vals)
 
         def one_step(blocks):
-            """One macro step: exchange + ``mult`` compute sub-steps."""
+            for kernel in stages:
+                blocks = one_stage(kernel, blocks)
+            return blocks
+
+        def one_stage(kernel, blocks):
+            """One macro step of one stage: exchange + ``mult`` compute
+            sub-steps."""
             origin = tuple(
                 lax.axis_index(MESH_AXES[ax]) * n[ax] for ax in range(3)
             )
@@ -1699,7 +1757,7 @@ class DistributedDomain:
                 # schedules it concurrently with the collective
                 with jax.named_scope(tm.SPAN_OVERLAP_INTERIOR):
                     int_region = rect_to_slices(interior_rect)
-                    int_vals = region_update(blocks, int_region, origin)
+                    int_vals = region_update(kernel, blocks, int_region, origin)
             # joint multi-quantity exchange: all fields fuse into one message
             # per direction (reference packer.cuh:52-69), ≤6 permutes total;
             # the z sweep runs the realize-resolved route, so fused steps
@@ -1727,12 +1785,12 @@ class DistributedDomain:
                     # exterior slabs (incl. shell extensions) read fresh halos
                     for ext_rect in exterior_of(rect, interior_rect):
                         ext_region = rect_to_slices(ext_rect)
-                        vals = region_update(cur, ext_region, origin)
+                        vals = region_update(kernel, cur, ext_region, origin)
                         for k in names:
                             if k in vals:
                                 new_blocks[k] = write_region(new_blocks[k], ext_region, vals[k])
                 else:
-                    vals = region_update(cur, region, origin)
+                    vals = region_update(kernel, cur, region, origin)
                     for k in names:
                         if k in vals:
                             new_blocks[k] = write_region(new_blocks[k], region, vals[k])

@@ -7,10 +7,15 @@
 * ``acoustic`` — isotropic acoustic wave propagation at space order 8
   (Devito ``examples/seismic/acoustic``; Minimod), the 25-point radius-4
   star, beside its plain reference ``acoustic_reference``.
+* ``elastic`` — isotropic elastic wave propagation at space order 8 (Devito
+  ``examples/seismic/elastic``; Minimod), velocity-stress on a staggered
+  grid: thirteen quantities, two stages a time step, beside its plain
+  reference ``elastic_reference``.
 """
 
 from stencil_tpu.models.jacobi import Jacobi3D
 from stencil_tpu.models.astaroth import AstarothSim
 from stencil_tpu.models.acoustic import AcousticWave
+from stencil_tpu.models.elastic import ElasticWave
 
-__all__ = ["Jacobi3D", "AstarothSim", "AcousticWave"]
+__all__ = ["Jacobi3D", "AstarothSim", "AcousticWave", "ElasticWave"]

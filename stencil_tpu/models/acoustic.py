@@ -23,7 +23,7 @@ What it asks of the runtime, unlike ``Jacobi3D`` and ``AstarothSim``:
   (the engine exchanges what the kernel reads off-centre, which is ``u``
   alone), and they are inputs of the pass and nothing else (it writes what
   the kernel returns, ``u`` and ``u_prev``) -- ``ops/stream.py
-  plane_footprint`` learns both from this kernel, docs/acoustic.md says
+  trace_plane_kernel`` learns both from this kernel, docs/acoustic.md says
   what a step moves;
 * a Dirichlet edge on a periodic runtime: the ``FRAME`` outer cells are
   pinned to zero BY THE KERNEL from ``info.coords()`` -- no model field

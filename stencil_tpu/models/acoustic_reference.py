@@ -101,23 +101,28 @@ def _unit(words, i: int):
     return (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
 
 
+def layered_vp(grid: AcousticGrid, z, words):
+    """``vp`` (km/s) of the layered model at integer depth ``z``: ``nlayers``
+    flat layers along z, ``vp_min`` at the top to ``vp_max`` at the bottom,
+    each interface at a seeded depth; edge-extended through the sponge and
+    the frame (Devito pads its model fields the same way)."""
+    import jax.numpy as jnp
+
+    nz, L = grid.physical[2], grid.nlayers
+    zc = jnp.clip(z - (FRAME + grid.nbl), 0, nz - 1)
+    layer = jnp.zeros_like(zc)
+    for i in range(1, L):
+        depth = jnp.round(nz * (i + 0.7 * (_unit(words, i) - 0.5)) / L).astype(zc.dtype)
+        layer = layer + (zc >= depth).astype(zc.dtype)
+    return grid.vp_min + (grid.vp_max - grid.vp_min) * layer.astype(jnp.float32) / (L - 1)
+
+
 def m_field(grid: AcousticGrid):
-    """Squared slowness ``1/vp^2`` of the layered model: ``nlayers`` flat
-    layers along z, ``vp_min`` at the top to ``vp_max`` at the bottom, each
-    interface at a seeded depth; edge-extended through the sponge and the
-    frame (Devito pads its model fields the same way)."""
+    """Squared slowness ``1/vp^2`` of the layered model (``layered_vp``)."""
 
     def f(x, y, z, words):
-        import jax.numpy as jnp
-
         del x, y
-        nz, L = grid.physical[2], grid.nlayers
-        zc = jnp.clip(z - (FRAME + grid.nbl), 0, nz - 1)
-        layer = jnp.zeros_like(zc)
-        for i in range(1, L):
-            depth = jnp.round(nz * (i + 0.7 * (_unit(words, i) - 0.5)) / L).astype(zc.dtype)
-            layer = layer + (zc >= depth).astype(zc.dtype)
-        vp = grid.vp_min + (grid.vp_max - grid.vp_min) * layer.astype(jnp.float32) / (L - 1)
+        vp = layered_vp(grid, z, words)
         return 1.0 / (vp * vp)
 
     return f

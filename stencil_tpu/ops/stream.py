@@ -15,10 +15,14 @@ vs ~40+ for the streamed form).
 Two routes, chosen by ``make_stream_step``:
 
 * **plane** — one level per pass: exchange the shell of every quantity the
-  kernel reads off-centre (``plane_footprint``; the others' shells are
-  read by nothing), then stream planes with a ``2r``-deep ring (``r`` = the
-  kernel's declared x read distance), writing back only the quantities the
-  kernel returns.  Works for any per-axis shell widths and any ``r >= 1``.
+  kernel reads off-centre (the others' shells are read by nothing), then
+  stream planes, a ``2r``-deep ring (``r`` = the kernel's declared x read
+  distance) for every quantity read off-centre ALONG X and a lagged fetch
+  for the others, writing back only the quantities the kernel returns.
+  Works for any per-axis shell widths and any ``r >= 1``.  A step may be
+  several STAGES (a sequence of kernels, each behind its own exchange) and
+  a stage several PASSES, each over the quantities its outputs touch: all
+  planned from one abstract trace of each kernel (``plan_plane_stages``).
 * **wavefront** — ``m`` levels per pass over an ``s``-wide-shell shard
   (``m <= s // r``, ``r == 1`` only): each HBM plane is read and written
   once per ``m`` iterations (~``8/m`` B/cell), the temporal blocking that
@@ -147,14 +151,19 @@ class PlaneView:
     ``off_centre`` is called, at trace time, on every read with a non-zero
     offset (``center()`` and ``sh(0, 0, 0)`` never call it): the plane
     route's footprint trace records the quantity there, and its pass raises
-    there for a quantity whose halo was not filled (``plane_footprint``).
+    there for a quantity whose halo was not filled (``trace_plane_kernel``).
+    A window plane may be ``None``: the pass holds no ring for a quantity
+    its kernel reads at ``dx == 0`` only, and ``no_ring`` is called on a read
+    of such a plane (it raises, naming the quantity).
     """
 
-    def __init__(self, window: Tuple[jax.Array, ...], roll, off_centre=None):
+    def __init__(self, window: Tuple[jax.Array, ...], roll, off_centre=None,
+                 no_ring=None):
         self._window = window
         self._r = (len(window) - 1) // 2
         self._roll = roll
         self._off_centre = off_centre
+        self._no_ring = no_ring
 
     def sh(self, dx: int = 0, dy: int = 0, dz: int = 0) -> jax.Array:
         # ALL axes are bounded by the declared read radius: an in-plane
@@ -167,6 +176,8 @@ class PlaneView:
         if self._off_centre is not None and (dx or dy or dz):
             self._off_centre()
         v = self._window[self._r + dx]
+        if v is None:
+            self._no_ring()
         if dy:
             v = self._roll(v, -dy, 0)
         if dz:
@@ -251,15 +262,31 @@ def stream_plane_pass(
     # halo messages land in the level-0 planes in VMEM instead of having
     # been unpacked into the blocks (halo="fused"; see module docstring)
     halo_readers: Optional[Sequence[str]] = None,  # the quantities whose
-    # shell was filled (plane_footprint); None = every one
+    # shell was filled (trace_plane_kernel); None = every one
     writers: Optional[Sequence[str]] = None,  # the quantities the kernel
-    # returns (plane_footprint): the pass's only outputs; None = every one
+    # returns (trace_plane_kernel): the pass's only outputs; None = every one
+    rings: Optional[Sequence[str]] = None,  # the quantities the kernel reads
+    # at dx != 0 (PlaneTrace.pruned): the only ones with a ring; None = all
 ) -> List[jax.Array]:
     """ONE kernel level over shell-carrying blocks, streaming x-planes with a
-    ``2r``-deep ring per quantity; shell planes and the in-plane shell ring
-    pass through unchanged (the exchange owns halo cells).  Generalizes
-    ``mean6_plane_step``/``jacobi_plane_step`` to user kernels, any field
-    count, and any ``r >= 1``.
+    ``2r``-deep ring per quantity read off-centre along x; shell planes and
+    the in-plane shell ring pass through unchanged (the exchange owns halo
+    cells).  Generalizes ``mean6_plane_step``/``jacobi_plane_step`` to user
+    kernels, any field count, and any ``r >= 1``.
+
+    A quantity outside ``rings`` is read at ``dx == 0`` only -- a coefficient,
+    an older time level, a quantity differenced along y or z alone -- and
+    needs no window along x: its plane is FETCHED LAGGED, at the output
+    plane ``clip(i - r, 0, X - 1)`` instead of ``min(i, X - 1)``, so the
+    fetched block IS the centre plane, and it has no ring scratch and no
+    push.  (VMEM per such quantity: two pipeline planes instead of ``2r +
+    2`` -- what lets a pass carry nine quantities at 608 x 608, ``plan_plane
+    _passes``.)  In place stays safe: a lagged input's plane ``j`` is fetched
+    before grid step ``j + r`` and the aliased output's plane ``j`` is
+    flushed after it, and no later fetch goes back (``check_inplace_order``
+    proves it from the block maps, as for the ringed form below).  Not under
+    ``fused_shell`` (the patch replays the sweep on the plane fetched at
+    ``i``): every quantity keeps its ring there.
 
     With ``fused_shell`` the blocks' shell cells are STALE and the fresh
     halos ride as side inputs (``fused_shell_exchange``'s buffers): every
@@ -279,7 +306,7 @@ def stream_plane_pass(
     writer at all there is no call to make.  Not under ``fused_shell``:
     there the written planes are where the fresh shell lands, so every
     quantity stays an output whatever ``writers`` says (the same exception
-    ``plane_footprint``'s caller makes for the readers).
+    ``plan_plane_stages`` makes for the readers).
 
     With ``alias`` a writer's output IS its raw block
     (``input_output_aliases`` maps operand ``1 + q`` — operand 0 is
@@ -323,6 +350,21 @@ def stream_plane_pass(
         wq = [q for q in range(nq) if names[q] in writers]
     if not wq:
         return list(raws)
+    if rings is None or fused_shell is not None:
+        ringed = list(range(nq))
+    else:
+        ringed = [q for q in range(nq) if names[q] in rings]
+
+    def no_ring(name):
+        def fail():
+            raise ValueError(
+                f"the kernel reads {name!r} off-centre along x, but its "
+                f"footprint trace did not (it saw {tuple(rings)}), so the pass "
+                f"holds no ring for {name!r}: a kernel must read the same "
+                "offsets every time it is traced"
+            )
+
+        return fail
 
     def stale_read(name):
         if halo_readers is None or name in halo_readers:
@@ -346,7 +388,7 @@ def stream_plane_pass(
             zs_refs = refs[3 * nq : 4 * nq]
             refs = refs[:nq] + refs[4 * nq :]
         out_refs = dict(zip(wq, refs[nq : nq + len(wq)]))  # writers only
-        rings = refs[nq + len(wq) :]
+        ring_refs = dict(zip(ringed, refs[nq + len(wq) :]))  # x readers only
         i = pl.program_id(0)
         curs = [ref[0] for ref in in_refs]
         if fused_shell is not None:
@@ -370,7 +412,15 @@ def stream_plane_pass(
         in_window = jnp.logical_and(j >= lo.x, j <= X - hi.x - 1)
 
         def plane(q, t):  # raw plane i - t for quantity q (t in [0, 2r])
-            return curs[q] if t == 0 else rings[q][(i - t) % (2 * r)]
+            if q not in ring_refs:  # fetched lagged: the centre plane alone
+                return curs[q] if t == r else None
+            return curs[q] if t == 0 else ring_refs[q][(i - t) % (2 * r)]
+
+        def window(q):
+            return tuple(
+                None if (v := plane(q, 2 * r - d)) is None else up(v)
+                for d in range(2 * r + 1)
+            )
 
         @pl.when(jnp.logical_and(i >= 1, i <= X + r - 1))
         def _():
@@ -378,9 +428,7 @@ def stream_plane_pass(
             def _():
                 views = {
                     names[q]: PlaneView(
-                        tuple(up(plane(q, 2 * r - d)) for d in range(2 * r + 1)),
-                        roll,
-                        stale_read(names[q]),
+                        window(q), roll, stale_read(names[q]), no_ring(names[q])
                     )
                     for q in range(nq)
                 }
@@ -421,14 +469,18 @@ def stream_plane_pass(
                 out[0] = curs[q]  # first plane passes through
 
         # push the fetched plane (skip replayed last-plane refetches)
-        @pl.when(i <= X - 1)
-        def _():
-            for q in range(nq):
-                rings[q][i % (2 * r)] = curs[q]
+        if ring_refs:
+
+            @pl.when(i <= X - 1)
+            def _():
+                for q, ring in ring_refs.items():
+                    ring[i % (2 * r)] = curs[q]
 
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + [
         pl.BlockSpec((1, Y, Z), lambda i: (jnp.minimum(i, X - 1), 0, 0))
-        for _ in range(nq)
+        if q in ringed
+        else pl.BlockSpec((1, Y, Z), lambda i: (jnp.clip(i - r, 0, X - 1), 0, 0))
+        for q in range(nq)
     ]
     args = [origin.astype(jnp.int32), *raws]
     if fused_shell is not None:
@@ -486,7 +538,7 @@ def stream_plane_pass(
             {1 + q: k for k, q in enumerate(wq)} if alias else {}
         ),
         scratch_shapes=[
-            pltpu.VMEM((2 * r, Y, Z), b.dtype) for b in raws
+            pltpu.VMEM((2 * r, Y, Z), raws[q].dtype) for q in ringed
         ],
         interpret=interpret,
         **_tpu_compiler_params(interpret),
@@ -1114,23 +1166,77 @@ def permute_and_extend_z_slabs(zout, s: int, mesh_shape, yext, xext):
         return jnp.concatenate([xext(yext(zlo)), xext(yext(zhi))], axis=1)
 
 
-def plane_footprint(
+@dataclasses.dataclass(frozen=True)
+class PlaneTrace:
+    """What ONE abstract trace of a plane-route kernel over one group of
+    quantities learnt (``trace_plane_kernel``): who is read off-centre, who
+    is returned, and the kernel itself as a jaxpr over ``x_g, y_g, z_g`` and
+    every quantity's ``2r + 1`` window planes -- from which ``pruned`` cuts
+    the kernel of any subset of the outputs."""
+
+    names: Tuple[str, ...]  # the group's quantities, in the domain's order
+    readers: Tuple[str, ...]  # read off-centre on any axis: the stage's exchange
+    writers: Tuple[str, ...]  # returned: the outputs, in ``names``' order
+    x_radius: int
+    closed: Optional[object]  # the ClosedJaxpr; None = the trace raised
+    kernel: PlaneKernel  # the user's callable (run as is when ``closed`` is None)
+
+    def pruned(self, outputs: Sequence[str]):
+        """``(kernel, reads, rings)`` of the pass that writes ``outputs``:
+        the kernel with everything those outputs do not need cut away
+        (``dce_jaxpr``), the quantities it still reads (the outputs
+        themselves included: the pass carries their shell through), and the
+        ones among them it reads at ``dx != 0``.  No second trace of the
+        user's callable is made: what the footprint saw IS what runs."""
+        if self.closed is None:  # fail closed: the whole kernel, every ring
+            return self.kernel, self.names, self.names
+        from jax.extend import core as jex
+        from jax.interpreters import partial_eval as pe
+
+        r, w = self.x_radius, 2 * self.x_radius + 1
+        kept = [nm for nm in self.writers if nm in outputs]
+        jaxpr, used = pe.dce_jaxpr(
+            self.closed.jaxpr, [nm in outputs for nm in self.writers], instantiate=False
+        )
+        run = jex.jaxpr_as_fun(jex.ClosedJaxpr(jaxpr, self.closed.consts))
+        planes = [
+            (nm, d)
+            for q, nm in enumerate(self.names)
+            for d in range(w)
+            if used[3 + q * w + d]
+        ]
+
+        def kernel(views, info):
+            args = [c for c, u in zip(info.coords(), used[:3]) if u]
+            args += [views[nm].sh(d - r, 0, 0) for nm, d in planes]
+            return dict(zip(kept, run(*args)))
+
+        touched = {nm for nm, _ in planes} | set(kept)
+        ringed = {nm for nm, d in planes if d != r}
+        return (
+            kernel,
+            tuple(nm for nm in self.names if nm in touched),
+            tuple(nm for nm in self.names if nm in ringed),
+        )
+
+
+def trace_plane_kernel(
     kernel: PlaneKernel,
     names: Sequence[str],
-    groups: Sequence[Sequence[int]],  # the passes' quantity indices
     planes: Sequence[jax.ShapeDtypeStruct],  # per quantity, as the kernel sees it
     x_radius: int,
     global_size: Dim3,
-) -> Tuple[Tuple[str, ...], Tuple[str, ...]]:
-    """``(halo_readers, writers)`` of a PLANE-route step, each in ``names``'
-    order: the quantities ``kernel`` reads off-centre — the ones the step
-    exchanges — and the quantities it returns — the ones the pass writes.
-    Both are learnt from the kernel itself by one abstract trace
-    (``jax.eval_shape``, nothing runs) over ``PlaneView``s that record every
-    ``sh`` with a non-zero offset, called group by group as the passes call
-    it, keeping the keys of the dict each call returns.  A function of the
-    kernel, as ``_sweep_kind`` is a function of the mesh: no option, no plan
-    value a user sets.
+    interpret: bool = True,
+) -> PlaneTrace:
+    """The footprint of a PLANE-route kernel: trace it ONCE, abstractly
+    (``jax.make_jaxpr``, nothing runs), over ``PlaneView``s that record
+    every ``sh`` with a non-zero offset, and keep the keys of the dict it
+    returns.  The quantities it reads off-centre are the ones the step
+    exchanges, the ones it returns are the ones its passes write, and the
+    jaxpr says which quantities each output touches and at which ``dx``
+    (``PlaneTrace.pruned``).  A function of the kernel, as ``_sweep_kind``
+    is a function of the mesh: no option, no plan value a user sets.
+    ``interpret`` picks the rotate the passes will lower (``_make_roll``).
 
     Why the others keep a stale shell and the result is the same.  The plane
     pass is ONE level and writes interior cells only (shell planes and the
@@ -1156,33 +1262,36 @@ def plane_footprint(
     the input array and moves nothing — a coefficient or an older time level
     is then read once a step, not read and written.
 
-    Fail closed, twice for each: a footprint trace that raises exchanges AND
-    writes every quantity, and the pass itself raises at trace time on an
-    off-centre read or a returned name this trace did not see
-    (``stream_plane_pass(halo_readers=, writers=)``)."""
-    seen, returned = set(), set()
-    roll = _make_roll(True)  # jnp.roll: the trace runs outside any kernel
+    Fail closed: a trace that raises exchanges AND writes every quantity and
+    runs the kernel as the user wrote it, every quantity ringed
+    (``PlaneTrace.closed is None``).  The build runs the jaxpr THIS trace
+    made, so its passes cannot see the kernel read or return anything the
+    footprint did not; a pass handed a callable directly still raises, at
+    trace time, on an off-centre read or a returned name it was not told of
+    (``stream_plane_pass(halo_readers=, writers=, rings=)``)."""
+    names = tuple(names)
+    seen, returned = set(), []
+    roll = _make_roll(interpret)
+    r, w = x_radius, 2 * x_radius + 1
     Y, Z = planes[0].shape
 
     def footprint(x_g, y_g, z_g, *vs):
         info = PlaneInfo(x_g, y_g, z_g, global_size, 1)
-        for g in groups:
-            vals = kernel(
-                {
-                    names[q]: PlaneView(
-                        (vs[q],) * (2 * x_radius + 1), roll,
-                        partial(seen.add, names[q]),
-                    )
-                    for q in g
-                },
-                info,
-            )
-            # a group's pass stores only its own quantities
-            returned.update(names[q] for q in g if names[q] in vals)
+        vals = kernel(
+            {
+                nm: PlaneView(tuple(vs[q * w : (q + 1) * w]), roll, partial(seen.add, nm))
+                for q, nm in enumerate(names)
+            },
+            info,
+        )
+        returned[:] = [nm for nm in names if nm in vals]
+        return [vals[nm] for nm in returned]
 
     i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
     try:
-        jax.eval_shape(footprint, i32(()), i32((Y, 1)), i32((1, Z)), *planes)
+        closed = jax.make_jaxpr(footprint)(
+            i32(()), i32((Y, 1)), i32((1, Z)), *[p for p in planes for _ in range(w)]
+        )
     except Exception as exc:  # noqa: BLE001 — whatever the user's kernel raises
         from stencil_tpu.utils.logging import log_warn
 
@@ -1190,11 +1299,118 @@ def plane_footprint(
             f"the stream kernel's footprint trace raised ({exc!r}); "
             "exchanging and writing every quantity"
         )
-        return tuple(names), tuple(names)
-    return (
+        return PlaneTrace(names, names, names, r, None, kernel)
+    return PlaneTrace(
+        names,
         tuple(nm for nm in names if nm in seen),
-        tuple(nm for nm in names if nm in returned),
+        tuple(returned),
+        r,
+        closed,
+        kernel,
     )
+
+
+def plane_pass_vmem_bytes(
+    plane_bytes: Dict[str, int], x_radius: int, reads, rings, writes
+) -> int:
+    """VMEM model of one plane pass, ``stream_vmem_fits``' accounting cut to
+    what the pass holds: two pipeline planes per quantity read, two more per
+    quantity written, a ``2r``-deep ring per quantity read at ``dx != 0``,
+    and the per-quantity stack margin (the kernel's roll / select
+    temporaries).  ``plane_bytes`` is the tile-padded plane of each quantity
+    at its STORAGE itemsize (the plane pass rings hold raw planes)."""
+    est = sum(2 * plane_bytes[q] for q in reads)
+    est += sum(2 * plane_bytes[q] for q in writes)
+    est += sum(2 * x_radius * plane_bytes[q] for q in rings)
+    return est + _VMEM_STACK_MARGIN * len(reads)
+
+
+def plan_plane_passes(
+    trace: PlaneTrace, plane_bytes: Dict[str, int], whole: bool = False
+) -> List[dict]:
+    """The passes of one stage over one group: ``[{"writes", "reads",
+    "rings", "vmem_bytes"}, ...]``, each a subset of the kernel's outputs
+    with the quantities THOSE outputs touch (``PlaneTrace.pruned``).
+
+    Outputs join the current pass, in the order the kernel returns them,
+    while the pass still fits the VMEM budget (``plane_pass_vmem_bytes``
+    against ``_vmem_budget``); the first that does not opens the next pass.
+    Fewer passes move fewer arrays -- a quantity two outputs share is read
+    once -- so a pass is as wide as the model allows (acoustic: one pass;
+    elastic at 608 x 608: two a stage).  An output that fits no pass alone
+    raises here, at plan time, naming its quantities and the bytes, instead
+    of handing Mosaic a kernel it must refuse -- unless the pass carries
+    that ONE quantity and nothing else: that is the engine's floor, nothing
+    smaller exists and no restructuring of the kernel helps, so it is built
+    whatever the model says (an over-tight ``STENCIL_VMEM_LIMIT_BYTES``
+    degrades to it and never crashes; the model errs on the safe side).
+
+    Passes run one after the other ON THE SAME ARRAYS (in place), while a
+    kernel means all its outputs to come from the values it was called with:
+    a pass that reads what an EARLIER pass of the stage has written would
+    read the new value.  That raises too (make it a stage of its own).
+
+    ``whole`` keeps the stage in one pass over every quantity, every one
+    ringed and written (``halo="fused"``, whose side buffers are
+    per-quantity operands of the pass)."""
+    budget = _vmem_budget()
+
+    def describe(outputs, whole=False):
+        if whole or trace.closed is None:
+            reads = rings = writes = trace.names
+        else:
+            _, reads, rings = trace.pruned(outputs)
+            writes = tuple(outputs)
+        return {
+            "writes": writes,
+            "reads": reads,
+            "rings": rings,
+            "vmem_bytes": plane_pass_vmem_bytes(
+                plane_bytes, trace.x_radius, reads, rings, writes
+            ),
+        }
+
+    def refuse(p):
+        if len(p["reads"]) == 1:
+            return  # the floor: one quantity, nothing to split
+        raise ValueError(
+            f"the plane pass that writes {p['writes']} reads {len(p['reads'])} "
+            f"quantities {p['reads']}, {len(p['rings'])} of them off-centre "
+            f"along x {p['rings']}: {p['vmem_bytes']} bytes of VMEM by the "
+            f"model against a budget of {budget} -- it fits no pass; split "
+            "the kernel into stages that touch fewer quantities each"
+        )
+
+    if not trace.writers:
+        return []
+    if whole or trace.closed is None:
+        p = describe(trace.writers, whole=True)
+        if p["vmem_bytes"] > budget:
+            refuse(p)
+        return [p]
+    passes, current = [], []
+    for out in trace.writers:
+        p = describe(current + [out])
+        if current and p["vmem_bytes"] > budget:  # close the pass, open the next
+            passes.append(describe(current))
+            current, p = [], describe([out])
+        if p["vmem_bytes"] > budget:
+            refuse(p)  # alone and too wide: raises, unless it is the floor
+        current.append(out)
+    passes.append(describe(current))
+    written = set()
+    for p in passes:
+        clash = written & (set(p["reads"]) - set(p["writes"]))
+        if clash:
+            raise ValueError(
+                f"the plane pass that writes {p['writes']} reads "
+                f"{tuple(sorted(clash))}, which an earlier pass of the same "
+                "stage has already written in place: the stage does not fit "
+                "one pass and cannot be split; make the later update a stage "
+                "of its own"
+            )
+        written |= set(p["writes"])
+    return passes
 
 
 def static_stream_alias(route: str, n_fields: int) -> bool:
@@ -1422,6 +1638,74 @@ def plain_wavefront_plan(dd, plan: dict, max_depth: Optional[int] = None) -> Opt
     return out
 
 
+def _stream_groups(plan: dict, n_fields: int) -> List[List[int]]:
+    """per-field grouping: one streaming pass per group per macro (valid only
+    for kernels declared separable); the exchange stays JOINT (<= 6 permutes
+    for any field count) either way"""
+    if plan.get("grouping") == "per-field":
+        return [[q] for q in range(n_fields)]
+    return [list(range(n_fields))]
+
+
+def _as_stages(kernel) -> Tuple[PlaneKernel, ...]:
+    """A step's kernel is one callable or the sequence of its STAGES."""
+    return tuple(kernel) if isinstance(kernel, (list, tuple)) else (kernel,)
+
+
+def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
+                      fused: bool = False) -> List[List[tuple]]:
+    """Plan a PLANE-route step from its kernels' own footprints and write the
+    plan back: ``plan["stages"]`` -- per stage its ``readers`` (the
+    quantities its exchange fills) and its ``passes`` (``plan_plane_passes``:
+    writes, reads, rings, modeled VMEM bytes) -- and the step-wide unions
+    ``plan["halo_readers"]`` / ``plan["writers"]``.  Raises ``ValueError``
+    for a step that fits in no pass.  Returns, per stage, what the build
+    runs: ``[(pass kernel, reads, rings, writes), ...]`` (names).
+
+    Every stage is traced once per group (``trace_plane_kernel``); a function
+    of the kernels, as ``_sweep_kind`` is a function of the mesh: no option.
+    Under ``fused`` every quantity rides the exchange and every pass is
+    whole (``plan_plane_passes``)."""
+    names = [h.name for h in dd._handles]
+    raw = dd.local_spec().raw_size()
+    f32_acc = any(dd.field_dtype(h) != h.dtype for h in dd._handles)
+    planes = [
+        jax.ShapeDtypeStruct(
+            (raw.y, raw.z), jnp.float32 if f32_acc else dd.field_dtype(h)
+        )
+        for h in dd._handles
+    ]
+    plane_bytes = {
+        h.name: _padded_plane_bytes(raw.y, raw.z, dd.field_dtype(h).itemsize)
+        for h in dd._handles
+    }
+    described, built = [], []
+    for stage in _as_stages(kernel):
+        readers, passes, runs = set(), [], []
+        for g in _stream_groups(plan, len(names)):
+            trace = trace_plane_kernel(
+                stage, [names[q] for q in g], [planes[q] for q in g], x_radius,
+                dd._size, interpret,
+            )
+            readers |= set(names) if fused else set(trace.readers)
+            for p in plan_plane_passes(trace, plane_bytes, whole=fused):
+                passes.append(p)
+                runs.append((trace.pruned(p["writes"])[0], p["reads"], p["rings"], p["writes"]))
+        described.append({
+            "readers": tuple(nm for nm in names if nm in readers),
+            "passes": tuple(passes),
+        })
+        built.append(runs)
+    plan["stages"] = tuple(described)
+    for key, of in (
+        ("halo_readers", lambda st: st["readers"]),
+        ("writers", lambda st: [w for p in st["passes"] for w in p["writes"]]),
+    ):
+        union = {nm for st in described for nm in of(st)}
+        plan[key] = tuple(nm for nm in names if nm in union)
+    return built
+
+
 def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     from jax.sharding import PartitionSpec as P
 
@@ -1440,13 +1724,14 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     gsize = dd._size
     raw = dd.local_spec().raw_size()
     spec = P(*MESH_AXES)
-    # per-field grouping: one streaming pass per group per macro (valid only
-    # for kernels declared separable); the exchange stays JOINT (<= 6
-    # permutes for any field count) either way
-    if plan.get("grouping") == "per-field":
-        groups = [[q] for q in range(len(names))]
-    else:
-        groups = [list(range(len(names)))]
+    groups = _stream_groups(plan, len(names))
+    stages = _as_stages(kernel)
+    if len(stages) > 1 and plan["route"] != "plane":
+        raise ValueError(
+            f"a step of {len(stages)} stages runs the plane route (an exchange "
+            f"before every stage); the plan says {plan['route']!r}"
+        )
+    kernel = stages[0]  # the wrap and wavefront routes run one kernel
     # the z sweep of every in-step exchange runs the domain's realize-
     # resolved route (packed z-shell vs direct — ops/exchange.py), so stream
     # steps escape the 64×-amplified thin-z path exactly like exchange()
@@ -1609,25 +1894,17 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
             [lax.axis_index(MESH_AXES[ax]) * n[ax] for ax in range(3)]
         )
 
-    # the quantities that ride the step's exchange and those its pass
-    # writes, written back like alias (domain.step's ``exchanged`` and
-    # ``written`` count them): on the plane route those the kernel reads
-    # off-centre and those it returns, every one wherever the rules do not
-    # hold (plane_footprint says where and why); the wrap route exchanges none
+    # the quantities that ride the step's exchange and those its passes
+    # write, written back like alias (domain.step's ``exchanged`` and
+    # ``written`` count them): on the plane route what each stage's kernel
+    # reads off-centre and returns (plan_plane_stages), every one wherever
+    # the rules do not hold (trace_plane_kernel says where and why); the wrap
+    # route exchanges none
     plan["writers"] = tuple(names)
     if plan["route"] == "wrap":
         plan["halo_readers"] = ()
-    elif plan["route"] == "plane" and not fused:
-        plan["halo_readers"], plan["writers"] = plane_footprint(
-            kernel, names, groups,
-            [
-                jax.ShapeDtypeStruct(
-                    (raw.y, raw.z), jnp.float32 if f32_acc else dd.field_dtype(h)
-                )
-                for h in dd._handles
-            ],
-            x_radius, gsize,
-        )
+    elif plan["route"] == "plane":
+        stage_runs = plan_plane_stages(dd, stages, x_radius, plan, interpret, fused)
     else:
         plan["halo_readers"] = tuple(names)
 
@@ -1664,13 +1941,25 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
             )
 
     elif plan["route"] == "plane":
-        in_place = _plan_passes_in_place(plan)
-        readers, writers = plan["halo_readers"], plan["writers"]
-        riders = [q for q, name in enumerate(names) if name in readers]
+        import contextlib
 
-        def exchange_readers(bs):
-            """``bs`` with the halo readers' shells filled — one joint
-            exchange of those blocks alone; the others ride on untouched."""
+        in_place = _plan_passes_in_place(plan)
+        index = {name: q for q, name in enumerate(names)}
+        stage_readers = [st["readers"] for st in plan["stages"]]
+
+        def stage_scope(k):
+            """``step.stage.<k>`` around a stage's exchange and passes, for a
+            step of more than one (a one-stage step's scopes stay as they
+            were)."""
+            if len(stages) == 1:
+                return contextlib.nullcontext()
+            return telemetry.annotate(tm.step_stage_span(k))
+
+        def exchange_readers(bs, k):
+            """``bs`` with the shells of stage ``k``'s halo readers filled --
+            one joint exchange of those blocks alone; the others ride on
+            untouched."""
+            riders = [index[name] for name in stage_readers[k]]
             out = list(bs)
             filled = halo_exchange_multi(
                 [bs[q] for q in riders], shell, mesh_shape,
@@ -1680,9 +1969,14 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                 out[q] = b
             return out
 
-        def plane_groups(bs, origin, fused_bufs=None):
+        def plane_passes(k, bs, origin, fused_bufs=None, lo=lo, hi=hi,
+                         alias=in_place,
+                         scope=partial(telemetry.annotate, tm.SPAN_STEP_PASS)):
+            """Stage ``k``'s passes in order, each over the quantities it
+            touches; a later pass sees what an earlier one wrote."""
             out = list(bs)
-            for g in groups:
+            for pass_kernel, reads, rings, writes in stage_runs[k]:
+                g = [index[name] for name in reads]
                 fs = None
                 if fused_bufs is not None:
                     xb, yb, zb = fused_bufs
@@ -1691,13 +1985,13 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                         [yb[q] for q in g],
                         [zb[q] for q in g],
                     )
-                with telemetry.annotate(tm.SPAN_STEP_PASS):
+                with scope():
                     outs = stream_plane_pass(
-                        kernel, [names[q] for q in g], [bs[q] for q in g],
-                        lo, hi, x_radius, origin, gsize, alias=in_place,
+                        pass_kernel, reads, [out[q] for q in g],
+                        lo, hi, x_radius, origin, gsize, alias=alias,
                         interpret=interpret, fused_shell=fs,
-                        f32_accumulate=f32_acc, halo_readers=readers,
-                        writers=writers,
+                        f32_accumulate=f32_acc, halo_readers=stage_readers[k],
+                        writers=writes, rings=rings,
                     )
                 for q, o in zip(g, outs):
                     out[q] = o
@@ -1705,23 +1999,16 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
 
         if fused:
 
-            def per_shard(steps, *blocks):
-                def body(_, bs):
-                    origin = origin_of()
-                    bs = list(bs)
-                    # the packed messages never unpack into the blocks: the
-                    # received shell buffers ride into the pass and land in
-                    # the level-0 VMEM planes — no big-array halo write
-                    bufs = fused_shell_exchange(
-                        bs, shell, mesh_shape, route=exch_route
-                    )
-                    return tuple(plane_groups(bs, origin, bufs))
-
-                return lax.fori_loop(0, steps, body, tuple(blocks))
+            def stage(k, bs, origin):
+                # the packed messages never unpack into the blocks: the
+                # received shell buffers ride into the pass and land in
+                # the level-0 VMEM planes — no big-array halo write
+                bufs = fused_shell_exchange(bs, shell, mesh_shape, route=exch_route)
+                return plane_passes(k, bs, origin, bufs)
 
         elif split:
 
-            def narrow_plane(subs, ax, start, w, origin):
+            def narrow_plane(k, subs, ax, start, w, origin):
                 """One kernel level over ``3w``-wide face sub-blocks (``w ==
                 x_radius``): the sliced axis carries a ``w``-deep pseudo
                 shell, the other axes keep the true shell widths, and the
@@ -1733,44 +2020,39 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                     jnp.asarray(start - lo_t[b] + w if b == ax else 0, jnp.int32)
                     for b in range(3)
                 ]
-                origin_sub = origin + jnp.stack(delta)
-                out = list(subs)
-                for g in groups:
-                    outs = stream_plane_pass(
-                        kernel, [names[q] for q in g], [subs[q] for q in g],
-                        lo2, hi2, x_radius, origin_sub, gsize,
-                        interpret=interpret, f32_accumulate=f32_acc,
-                        halo_readers=readers, writers=writers,
+                return plane_passes(
+                    k, subs, origin + jnp.stack(delta), lo=lo2, hi=hi2,
+                    alias=False, scope=contextlib.nullcontext,
+                )
+
+            def stage(k, bs, origin):
+                # the ppermutes read slabs of the PRE-exchange blocks;
+                # the interior pass below also reads those blocks — no
+                # data dependency between them, so XLA's latency-hiding
+                # scheduler flies the collectives behind the pass
+                ex = exchange_readers(bs, k)
+                with telemetry.annotate(tm.SPAN_OVERLAP_INTERIOR):
+                    out = plane_passes(k, bs, origin)
+                with telemetry.annotate(tm.SPAN_OVERLAP_EXTERIOR):
+                    return _exterior_fix(
+                        out, ex, x_radius, origin, partial(narrow_plane, k)
                     )
-                    for q, o in zip(g, outs):
-                        out[q] = o
-                return out
-
-            def per_shard(steps, *blocks):
-                def body(_, bs):
-                    origin = origin_of()
-                    bs = list(bs)
-                    # the ppermutes read slabs of the PRE-exchange blocks;
-                    # the interior pass below also reads those blocks — no
-                    # data dependency between them, so XLA's latency-hiding
-                    # scheduler flies the collectives behind the pass
-                    ex = exchange_readers(bs)
-                    with telemetry.annotate(tm.SPAN_OVERLAP_INTERIOR):
-                        out = plane_groups(bs, origin)
-                    with telemetry.annotate(tm.SPAN_OVERLAP_EXTERIOR):
-                        out = _exterior_fix(out, ex, x_radius, origin, narrow_plane)
-                    return tuple(out)
-
-                return lax.fori_loop(0, steps, body, tuple(blocks))
 
         else:
 
-            def per_shard(steps, *blocks):
-                def body(_, bs):
-                    origin = origin_of()
-                    return tuple(plane_groups(exchange_readers(bs), origin))
+            def stage(k, bs, origin):
+                return plane_passes(k, exchange_readers(bs, k), origin)
 
-                return lax.fori_loop(0, steps, body, tuple(blocks))
+        def per_shard(steps, *blocks):
+            def body(_, bs):
+                origin = origin_of()
+                bs = list(bs)
+                for k in range(len(stages)):
+                    with stage_scope(k):
+                        bs = stage(k, bs, origin)
+                return tuple(bs)
+
+            return lax.fori_loop(0, steps, body, tuple(blocks))
 
     else:
         m = plan["m"]
@@ -1963,18 +2245,25 @@ def make_stream_step(
     view subsets, letting many-field domains stream per-field (see
     ``plan_stream``).
 
-    On the plane route the step exchanges only the quantities the kernel
-    reads OFF-CENTRE (``plane_footprint``: one abstract trace of the
-    kernel at build time; the resolved set is ``plan["halo_readers"]``, its
-    size ``domain.step``'s ``exchanged``).  A quantity read through
+    ``kernel`` may be a SEQUENCE of such callables: the STAGES of a time
+    step, run in order inside one device program, each behind its own
+    exchange (a later stage reads what an earlier one wrote, halo included).
+    A staged step runs the plane route.
+
+    On the plane route the step exchanges only the quantities a stage's
+    kernel reads OFF-CENTRE (``plan_plane_stages``: one abstract trace of
+    each kernel at build time; the resolved set is ``plan["halo_readers"]``,
+    its size ``domain.step``'s ``exchanged``).  A quantity read through
     ``center()`` alone — a coefficient, an older time level — keeps a stale
     shell that nothing reads; every interior cell is bitwise what exchanging
     all of them gives.  The same trace learns which quantities the kernel
     RETURNS (``plan["writers"]``, ``domain.step``'s ``written``): the others
     are inputs of the pass and nothing else, read once a step and never
-    written back.  So a kernel must read the same offsets and return the
-    same names every time it is traced: the pass raises, naming the
-    quantity, if it does not.
+    written back; which quantities each output TOUCHES, so that a stage too
+    wide for one pass's VMEM runs as several, each over its own quantities
+    (``plan["stages"]``; a step that fits in no pass raises here); and which
+    are read off-centre ALONG X — the only ones that keep a VMEM ring.  The
+    passes run the jaxpr that trace made: the callable is traced once.
 
     ``max_depth`` caps the temporal depth (wrap k / wavefront m).  The auto
     planner maximizes depth because depth is the HBM-traffic lever
@@ -2041,6 +2330,15 @@ def make_stream_step(
             f"unknown stream halo mode {halo!r} (one of "
             f"{('auto',) + STREAM_HALO})"
         )
+    stages = _as_stages(kernel)
+    if len(stages) > 1:
+        # an exchange before every stage: the plane route's schedule
+        if path not in ("auto", "plane"):
+            raise ValueError(
+                f"a step of {len(stages)} stages runs the plane route; "
+                f"stream_path={path!r} cannot"
+            )
+        path = "plane"
     plan = plan_stream(dd, x_radius, path, separable, max_m=max_depth)
     if overlap != "auto" or halo != "auto":
         plan = dict(plan)
@@ -2067,6 +2365,10 @@ def make_stream_step(
     def rung_for(p):
         # build() resolves _build_stream_step through module globals at call
         # time, so tests may monkeypatch it
+        if p["route"] == "plane" and "stages" not in p:
+            # the passes, before anything is built: a step that fits in no
+            # pass raises HERE, and the ladder's VMEM prefilter reads them
+            plan_plane_stages(dd, kernel, x_radius, p, interpret)
         suffix = ",split" if p.get("overlap") == "split" else ""
         if p.get("halo") == "fused":
             suffix += ",fused"
@@ -2188,23 +2490,40 @@ def make_stream_step(
         NOW (``telemetry/names.py SPAN_STEP``; the ladder may have moved it)."""
         plan_now = step._stream_plan
         nq = len(dd._handles)
-        return {
+        in_place = _plan_passes_in_place(plan_now)
+        args = {
             "route": plan_now["route"],
             "x_radius": x_radius,
             "grouping": plan_now.get("grouping", "joint"),
             "streamed": nq,
             # quantities the passes carry in place (all or none): a written
             # one's output aliases its input, an unwritten one IS its input
-            "aliased": nq if _plan_passes_in_place(plan_now) else 0,
+            "aliased": nq if in_place else 0,
             # quantities riding the step's exchange: what the kernel reads
-            # off-centre on the plane route (plane_footprint), every one
+            # off-centre on the plane route (plan_plane_stages), every one
             # on the wavefront route, none on the wrap route (and, like
             # ``aliased``, none while the plan is not built yet)
             "exchanged": len(plan_now.get("halo_readers", ())),
             # quantities the passes write: what the kernel returns on the
-            # plane route (plane_footprint), every one elsewhere
+            # plane route (plan_plane_stages), every one elsewhere
             "written": len(plan_now.get("writers", ())),
         }
+        per_stage = plan_now.get("stages", ())
+        if len(per_stage) > 1:
+            # a staged step says the three PER STAGE, in order ("6/3"): each
+            # stage exchanges, writes and carries its own subset ("/" because
+            # a profiler annotation splits its arguments at "," and "=")
+            def each(count):
+                return "/".join(str(count(st)) for st in per_stage)
+
+            args["stages"] = len(per_stage)
+            args["passes"] = sum(len(st["passes"]) for st in per_stage)
+            args["exchanged"] = each(lambda st: len(st["readers"]))
+            args["written"] = each(lambda st: sum(len(p["writes"]) for p in st["passes"]))
+            args["aliased"] = each(
+                lambda st: len({q for p in st["passes"] for q in p["reads"]}) if in_place else 0
+            )
+        return args
 
     step._span_args = span_args
     step._resilience = ladder

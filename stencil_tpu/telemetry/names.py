@@ -319,11 +319,13 @@ ALL_HISTOGRAMS = frozenset({
 #: place (all or none: ``ops/stream._plan_passes_in_place``; a written one's
 #: output aliases its input, an unwritten one is its input), exchanged
 #: = quantities riding the step's halo exchange: on the plane route those the
-#: kernel reads off-centre (``ops/stream.plane_footprint``; all of them under
+#: kernel reads off-centre (``ops/stream.trace_plane_kernel``; all of them under
 #: ``halo="fused"``), every one on the wavefront route, 0 on the wrap route,
 #: written = quantities that are outputs of the passes: on the plane route
 #: those the kernel returns (the same trace, ``plan["writers"]``; all of them
-#: under ``halo="fused"``), every one on the other routes]
+#: under ``halo="fused"``), every one on the other routes; a STAGED step
+#: (``make_step`` with a sequence of kernels) adds stages and passes, and says
+#: exchanged / written / aliased PER STAGE, in order: "6/3", "3/6", "11/12"]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes
@@ -354,6 +356,11 @@ SPAN_OVERLAP_EXTERIOR = "step.overlap.exterior"
 #: carry it, so a trace splits a plane step into pass, exchange sweeps
 #: (``exchange.<axis>``) and whatever is left
 SPAN_STEP_PASS = "step.pass"
+#: one STAGE of a plane-route step of several (``make_step`` with a sequence
+#: of kernels: elastic's velocities, then its stresses): a DEVICE-timeline
+#: scope around the stage's exchange sweeps and its passes, ``step.stage.0``,
+#: ``step.stage.1``, ... (``step_stage_span``); a one-stage step has none
+SPAN_STEP_STAGE = "step.stage"
 #: the redistribution collective schedule (parallel/redistribute.py): a
 #: named scope entered around the per-round slice/permute/blend body, so
 #: device-time attribution can price a live mesh transition
@@ -409,6 +416,11 @@ EXCHANGE_WRAP_SPANS = {
 }
 
 
+def step_stage_span(k: int) -> str:
+    """The device scope of stage ``k`` of a staged step."""
+    return f"{SPAN_STEP_STAGE}.{int(k)}"
+
+
 def exchange_direction_span(axis: str, side: str) -> str:
     """The registered span name for one exchange hop (axis in x/y/z, side in
     low/high).  In-kernel scopes must come through here (or the constants
@@ -448,6 +460,7 @@ ALL_SPANS = frozenset({
     SPAN_OVERLAP_INTERIOR,
     SPAN_OVERLAP_EXTERIOR,
     SPAN_STEP_PASS,
+    SPAN_STEP_STAGE,
     SPAN_RESHARD,
     SPAN_EXCHANGE_X_LOW,
     SPAN_EXCHANGE_X_HIGH,

@@ -170,6 +170,18 @@ def _acoustic():
     return _trace_step(s.dd, s._step)
 
 
+def _elastic():
+    import jax
+
+    from stencil_tpu.models.elastic import ElasticWave
+
+    s = ElasticWave(24, 24, 24, nbl=4, interpret=True, devices=jax.devices()[:1])
+    s.realize()
+    args = s._step._span_args()
+    assert (args["route"], args["x_radius"], args["stages"]) == ("plane", 4, 2), args
+    return _trace_step(s.dd, s._step)
+
+
 #: label -> builder of the ClosedJaxpr, at a CPU size under interpret
 MODEL_PROGRAMS = {
     "model:jacobi3d-512/wrap": _jacobi_wrap,
@@ -177,6 +189,7 @@ MODEL_PROGRAMS = {
     "model:astaroth-8q-512/wavefront-m3": _astaroth,
     "model:weak-r3-512x4/exchange-direct": _weak_exchange,
     "model:acoustic-so8-600/plane-r4": _acoustic,
+    "model:elastic-so8-600/plane-r4": _elastic,
 }
 
 

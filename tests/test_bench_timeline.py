@@ -85,7 +85,20 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
             m = json.load(f)
         assert m["cells"] == ["weak-r3-750x4*"] and m["moves"] == "halo_gbps_chip", name
         assert declared[name]["workloads"] == ["weak-r3-750x4.exchange-only"], name
-    for name in set(declared) - new - plane - (ragged - {"collective_pct.ragged"}):
+    # PR 33's: the staged plane step's shares for the elastic cells, by pattern
+    staged = {
+        "plane_pass_pct.staged", "plane_pass_hbm_pct.staged", "exchange_dev_pct.staged",
+        "step_glue_pct.staged", "kernel_named_pct.staged", "stage_pct.v", "stage_pct.t",
+        "enqueue_ms_p90.staged", "compiles_in_window.staged",
+    }
+    assert staged <= set(declared)
+    for name in staged:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["reducer"] in ("named_share", "named_roofline_hbm", "span_percentile", "span_count"), name
+        assert m["cells"] == ["elastic-*"] and m["moves"] == "mcells_per_s_chip", name
+        assert declared[name]["workloads"] == ["elastic-so8-600.bulk"], name
+    for name in set(declared) - new - plane - staged - (ragged - {"collective_pct.ragged"}):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
                                                "trace_roofline_hbm", "trace_idle"), name

@@ -261,3 +261,16 @@ def test_measure_buf_exchange(capsys):
     vals = [float(v) for line in final for v in line.split()]
     assert any(v > 0 for v in vals)
     assert all(not math.isnan(v) for v in vals)
+
+
+def test_elastic(capsys):
+    """``stencil-elastic``: Devito's ``-P elastic -so 8`` command line, the
+    physical extents given and sponge + frame added, on the fake 8-device
+    mesh (13 quantities, a staged step)."""
+    from stencil_tpu.bin.elastic import main
+
+    assert main(["16", "16", "16", "--nbl", "4", "--iters", "2", "--steps", "2"]) == 0
+    row = _capture(capsys)[-1].split(",")
+    # elastic,ranks,devCount,x,y,z,nbl,min,trimean,gpts_per_s
+    assert row[0] == "elastic" and row[3:7] == ["16", "16", "16", "4"]
+    assert float(row[7]) > 0 and float(row[8]) > 0 and float(row[9]) > 0

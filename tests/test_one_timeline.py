@@ -288,6 +288,7 @@ def test_every_registered_name_has_a_call_site(group):
         ("exchange_direction_span", tm.EXCHANGE_DIRECTION_SPANS),
         ("exchange_axis_span", tm.EXCHANGE_AXIS_SPANS),
         ("exchange_wrap_span", tm.EXCHANGE_WRAP_SPANS),
+        ("step_stage_span", {"k": tm.SPAN_STEP_STAGE}),  # step.stage.<k>
     ):
         for value in table.values():
             through_helper[value] = helper

@@ -256,3 +256,44 @@ def test_acoustic_step_carries_its_blocks_in_place(monkeypatch):
     assert not big_copy.findall(text) and temp == 0
     text_off, temp_off = texts[False]
     assert len(big_copy.findall(text_off)) == 2 and temp_off > 1.8e9
+
+
+@pytest.mark.slow  # tier-2 with its siblings: a real-TPU-compiler AOT
+# compile at the benchmark's size (12 s alone)
+def test_elastic_step_carries_its_blocks_in_place(monkeypatch):
+    """The elastic cell's step (600^3, thirteen quantities, two stages) as
+    the chip's compiler leaves it: four ``stream_plane_pass`` custom calls of
+    2, 1, 4 and 2 results -- the passes the planner forms at 608 x 608 planes
+    -- every result aliased onto its operand, 27 self-wrap kernels, NO
+    whole-array copy in the ``while`` body and no temporary: a step holds its
+    thirteen arrays and nothing else.  The check ISSUE 33 asks for before any
+    chip call."""
+    from stencil_tpu.models.elastic import RADIUS, ElasticWave
+    from stencil_tpu.ops import halo_blend
+    from stencil_tpu.ops import stream as sm
+
+    devices = _topology_devices()
+    monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        sim = ElasticWave(600, 600, 600, devices=devices[:1], seed_words=None)
+        sim.dd.realize(allocate=False)
+        plan = sm.plan_stream(sim.dd, RADIUS, "plane", False)
+        step = sm._build_stream_step(
+            sim.dd, (sim._stage_v, sim._stage_t), RADIUS, plan, interpret=False
+        )
+        compiled = step.lower(sim.dd.abstract_arrays(), 4).compile()
+        text, temp = compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    assert [len(p["writes"]) for st in plan["stages"] for p in st["passes"]] == [2, 1, 4, 2]
+    calls = [l for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
+    passes = [l for l in calls if l.lstrip().startswith("%stream_plane_pass")]
+    results = sorted(l.lstrip().split(" custom-call(")[0].count("f32[608,608,608]") for l in passes)
+    assert results == [1, 2, 2, 4] and len(calls) - len(passes) == 27
+    for l in passes:
+        n = l.lstrip().split(" custom-call(")[0].count("f32[608,608,608]")
+        aliasing = l[l.index("output_to_operand_aliasing="):].split("}, ")[0]
+        assert aliasing.count("(") == n, aliasing
+    assert not re.findall(r"=\s+f32\[608,608,608\]\S*\s+copy\(", text) and temp == 0

@@ -145,7 +145,7 @@ def test_mean6_kernel_axes_variants():
 # --- exchange only what the kernel reads (ISSUE 30) ---------------------------
 #
 # On the plane route the step exchanges the quantities the kernel reads
-# off-centre and no others (ops/stream.py plane_footprint).  Every case
+# off-centre and no others (ops/stream.py trace_plane_kernel).  Every case
 # runs the same kernel three ways -- the XLA slice engine (which exchanges
 # everything), the plane route as built, and the plane route with the rule
 # switched off (the parent's program: every quantity exchanged) -- and holds
@@ -216,9 +216,16 @@ def _plane_step(dd, kernel, r, plan_kw):
 
 
 def _exchange_everything(monkeypatch):
+    """The rules off: the trace every build makes fails closed, so every
+    quantity is exchanged, ringed and written (PR 29's program)."""
     from stencil_tpu.ops import stream as sm
 
-    monkeypatch.setattr(sm, "plane_footprint", lambda kernel, names, *a: (tuple(names),) * 2)
+    monkeypatch.setattr(
+        sm, "trace_plane_kernel",
+        lambda kernel, names, planes, r, *a: sm.PlaneTrace(
+            tuple(names), tuple(names), tuple(names), r, None, kernel
+        ),
+    )
 
 
 def _ppermute_cells(fn, curr) -> int:
@@ -349,29 +356,76 @@ def test_a_footprint_trace_that_raises_exchanges_everything(monkeypatch):
         assert np.array_equal(a, dd.quantity_to_host(h)), name
 
 
-def test_an_off_centre_read_the_footprint_trace_missed_raises_by_name():
-    """The real trace checks the abstract one: a kernel that reads ``c``
-    off-centre only the SECOND time it is traced meets a ``c`` whose halo was
-    not exchanged, and the pass says so at trace time -- never a stale read."""
+def _one_pass(kernel, **kw):
+    """``stream_plane_pass`` itself over ``a`` and ``c``, abstractly."""
+    import jax
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops.stream import stream_plane_pass
+
+    r, n = 1, 8
+    blk = jax.ShapeDtypeStruct((n + 2 * r,) * 3, jnp.float32)
+
+    def fn(origin, a, c):
+        return stream_plane_pass(
+            kernel, ["a", "c"], [a, c], Dim3(r, r, r), Dim3(r, r, r), r, origin,
+            Dim3(n, n, n), interpret=True, **kw,
+        )
+
+    return jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((3,), jnp.int32), blk, blk)
+
+
+def test_an_off_centre_read_the_pass_was_not_told_of_raises_by_name():
+    """The pass checks what it is told: a kernel that reads ``c`` off-centre
+    in a pass whose ``halo_readers`` leave ``c`` out meets a ``c`` whose halo
+    was not exchanged, and the pass says so at trace time -- never a stale
+    read; off-centre ALONG X in a pass that holds no ring for ``c`` likewise."""
+
+    def kernel(dx, dz):
+        return lambda views, info: {"a": _star(views["a"], 1) * views["c"].sh(dx, 0, dz)}
+
+    _one_pass(kernel(0, 1), halo_readers=("a", "c"), rings=("a",))  # told: fine
+    with pytest.raises(ValueError, match=r"reads 'c' off-centre.*halo of 'c' was not exchanged"):
+        _one_pass(kernel(0, 1), halo_readers=("a",))
+    with pytest.raises(ValueError, match=r"reads 'c' off-centre along x.*no ring for 'c'"):
+        _one_pass(kernel(1, 0), rings=("a",))
+
+
+def test_a_step_traces_its_kernel_once_and_runs_what_that_trace_saw():
+    """The build traces the user's callable ONCE; the passes run that trace
+    (``PlaneTrace.pruned``), so a callable that would read or return
+    something else the second time is never asked a second time: what the
+    footprint saw -- ``c`` at the centre, ``a`` alone returned -- IS what
+    runs, and lowering and running the step trace nothing again."""
     calls = []
 
     def kernel(views, info):
         calls.append(1)
         c = views["c"]
-        coeff = c.center() if len(calls) == 1 else c.sh(0, 0, 1)
-        return {"a": _star(views["a"], 1) * coeff}
+        out = {"a": _star(views["a"], 1) * (c.center() if len(calls) == 1 else c.sh(0, 0, 1))}
+        if len(calls) > 1:
+            out["c"] = c.center() + 1.0
+        return out
 
-    dd, _ = _plane_domain(["a", "c"], 1, 1)
+    dd, hs = _plane_domain(["a", "c"], 1, 1)
     step, plan = _plane_step(dd, kernel, 1, {})
-    assert plan["halo_readers"] == ("a",)
-    with pytest.raises(ValueError, match=r"reads 'c' off-centre.*halo of 'c' was not exchanged"):
-        step.lower(dd._curr, 1)
+    assert plan["halo_readers"] == ("a",) and plan["writers"] == ("a",)
+    step.lower(dd._curr, 1)
+    dd.run_step(step, 2)
+    assert len(calls) == 1
+    got = [dd.quantity_to_host(h) for h in hs]
+    dd, hs = _plane_domain(["a", "c"], 1, 1)
+    first = lambda views, info: {"a": _star(views["a"], 1) * views["c"].center()}  # noqa: E731
+    dd.run_step(dd.make_step(first, overlap=False), 2)
+    for a, h in zip(got, hs):
+        assert np.array_equal(a, dd.quantity_to_host(h)), h.name
 
 
 # --- write only what the kernel writes (ISSUE 32) -----------------------------
 #
 # The same trace learns which quantities the kernel RETURNS; the others are
-# inputs of the plane pass and nothing else (ops/stream.py plane_footprint,
+# inputs of the plane pass and nothing else (ops/stream.py trace_plane_kernel,
 # stream_plane_pass(writers=)).  A pass with the rule off wrote every such
 # quantity back cell for cell, so the two must agree on every RAW cell of
 # every quantity, shell included.
@@ -418,9 +472,13 @@ def _footprint(kernel, names, r, groups=None):
     from stencil_tpu.ops import stream as sm
 
     plane = jax.ShapeDtypeStruct((16 + 2 * r, 16 + 2 * r), jnp.float32)
-    return sm.plane_footprint(
-        kernel, names, groups or [list(range(len(names)))], [plane] * len(names),
-        r, Dim3(16, 16, 16),
+    traces = [  # one trace per group, as plan_plane_stages makes them
+        sm.trace_plane_kernel(kernel, [names[q] for q in g], [plane] * len(g), r, Dim3(16, 16, 16))
+        for g in groups or [list(range(len(names)))]
+    ]
+    return tuple(
+        tuple(nm for nm in names if any(nm in got for got in of))
+        for of in ([t.readers for t in traces], [t.writers for t in traces])
     )
 
 
@@ -505,12 +563,16 @@ def test_the_pass_has_one_output_per_writer_and_is_bitwise_the_full_pass(alias):
 def _write_everything(monkeypatch):
     """The rule off: the parent's pass, every quantity an output (the readers
     stay as the trace found them)."""
+    import dataclasses
+
     from stencil_tpu.ops import stream as sm
 
-    real = sm.plane_footprint
+    real = sm.trace_plane_kernel
     monkeypatch.setattr(
-        sm, "plane_footprint",
-        lambda kernel, names, *a: (real(kernel, names, *a)[0], tuple(names)),
+        sm, "trace_plane_kernel",
+        lambda kernel, names, *a: dataclasses.replace(
+            real(kernel, names, *a), writers=tuple(names), closed=None
+        ),
     )
 
 
@@ -581,24 +643,18 @@ def test_the_span_counts_the_written_quantities():
             args["written"]) == ("plane", 4, 4, 3, 2), args
 
 
-def test_a_name_the_footprint_trace_never_saw_returned_raises_by_name():
-    """The real trace checks the abstract one: a kernel that returns ``c``
-    only the SECOND time it is traced meets a pass in which ``c`` is no
+def test_a_name_the_pass_was_not_told_of_returned_raises_by_name():
+    """The pass checks what it is told: a kernel that returns ``c`` in a pass
+    whose ``writers`` leave ``c`` out meets a pass in which ``c`` is no
     output, and the pass says so at trace time -- never a dropped result."""
-    calls = []
 
     def kernel(views, info):
-        calls.append(1)
-        out = {"a": _star(views["a"], 1) * views["c"].center()}
-        if len(calls) > 1:
-            out["c"] = views["c"].center() + 1.0
-        return out
+        return {"a": _star(views["a"], 1) * views["c"].center(),
+                "c": views["c"].center() + 1.0}
 
-    dd, _ = _plane_domain(["a", "c"], 1, 1)
-    step, plan = _plane_step(dd, kernel, 1, {})
-    assert plan["writers"] == ("a",)
+    _one_pass(kernel, writers=("a", "c"))  # told: fine
     with pytest.raises(ValueError, match=r"returns 'c'.*'c' is not an output of the pass"):
-        step.lower(dd._curr, 1)
+        _one_pass(kernel, writers=("a",))
 
 
 def test_fused_shell_keeps_every_quantity_an_output():

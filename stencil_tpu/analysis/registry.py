@@ -109,6 +109,7 @@ INPLACE_PASSES = {
         "inplace_order_plane_r4_clean.py",
         "inplace_order_plane_writers_clean.py",
         "inplace_order_plane_lagged_clean.py",
+        "inplace_order_plane_wrapped_clean.py",
     ),
     "stream_wavefront_pass": ("inplace_order_wavefront_clean.py",),
 }

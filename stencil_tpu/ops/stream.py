@@ -19,6 +19,9 @@ Two routes, chosen by ``make_stream_step``:
   stream planes, a ``2r``-deep ring (``r`` = the kernel's declared x read
   distance) for every quantity read off-centre ALONG X and a lagged fetch
   for the others, writing back only the quantities the kernel returns.
+  On a y or z axis the mesh does not split there is nothing to exchange:
+  the pass fills that halo of every plane it loads from the plane itself,
+  in VMEM (``pass_wrap_fills``), and the exchange sweeps the other axes.
   Works for any per-axis shell widths and any ``r >= 1``.  A step may be
   several STAGES (a sequence of kernels, each behind its own exchange) and
   a stage several PASSES, each over the quantities its outputs touch: all
@@ -267,6 +270,9 @@ def stream_plane_pass(
     # returns (trace_plane_kernel): the pass's only outputs; None = every one
     rings: Optional[Sequence[str]] = None,  # the quantities the kernel reads
     # at dx != 0 (PlaneTrace.pruned): the only ones with a ring; None = all
+    wrap_fills: Sequence[Tuple[int, int, int, int]] = (),  # (axis, destination,
+    # source, width) of the y / z halo fills the pass makes itself, in VMEM
+    # (pass_wrap_fills): the self-wrap of an axis the mesh does not split
 ) -> List[jax.Array]:
     """ONE kernel level over shell-carrying blocks, streaming x-planes with a
     ``2r``-deep ring per quantity read off-centre along x; shell planes and
@@ -294,6 +300,28 @@ def stream_plane_pass(
     slabs, then y rows, then z columns, replaying the exchange's sweep
     order — before it feeds the ring, the kernel, or the pass-through, so
     the pass is bitwise-identical to running over exchanged blocks.
+
+    With ``wrap_fills`` the y / z shell of the blocks is STALE on the axes
+    the fills name and there is no message at all: on an axis the mesh does
+    not split the halo of a plane is a copy of cells of that same plane, so
+    every loaded plane of every halo reader -- ringed or fetched lagged,
+    x-shell planes included -- has its halo rows (y) and then its halo
+    columns (z) copied from its own interior, each over the full extent of
+    the other axis, before it feeds the ring, the kernel or the
+    pass-through.  The step's exchange then sweeps the remaining axes only
+    (x always: in place, the pass has overwritten the source planes of the
+    high x shell long before it reaches it), and after that sweep the fills
+    replay the exchange's order x -> y -> z cell for cell: every window is
+    bitwise the one the kernel saw over exchanged blocks, and a writer that
+    is also a reader leaves the same raw array in HBM, halo included (the
+    pass-through writes the patched centre plane).  A reader no pass writes
+    keeps a stale y / z shell in HBM, which the contract allows (the
+    exchange owns halo cells and refills them before every read).  The
+    copies are made in the pipeline's own input buffer, on the few sublane
+    rows and the two lane tiles that hold the four ranges (as
+    ``halo_blend.wrap_halo``'s shuffle does in its scratch): no VMEM of
+    their own, and idempotent, so a plane the pipeline does not refetch is
+    patched again to the same cells.  Not with ``fused_shell``.
 
     Returns one array per quantity, but only the ``writers`` are OUTPUTS of
     the Pallas call: every quantity is an input with its ring and its view,
@@ -354,6 +382,12 @@ def stream_plane_pass(
         ringed = list(range(nq))
     else:
         ringed = [q for q in range(nq) if names[q] in rings]
+    assert not wrap_fills or fused_shell is None
+    assert all(a in (1, 2) for a, _, _, _ in wrap_fills), wrap_fills
+    wrapped = [
+        q for q in range(nq)
+        if wrap_fills and (halo_readers is None or names[q] in halo_readers)
+    ]
 
     def no_ring(name):
         def fail():
@@ -390,6 +424,12 @@ def stream_plane_pass(
         out_refs = dict(zip(wq, refs[nq : nq + len(wq)]))  # writers only
         ring_refs = dict(zip(ringed, refs[nq + len(wq) :]))  # x readers only
         i = pl.program_id(0)
+        for q in wrapped:
+            for axis, dst, src, w in wrap_fills:  # y before z
+                if axis == 1:
+                    in_refs[q][0, dst : dst + w, :] = in_refs[q][0, src : src + w, :]
+                else:
+                    in_refs[q][0, :, dst : dst + w] = in_refs[q][0, :, src : src + w]
         curs = [ref[0] for ref in in_refs]
         if fused_shell is not None:
             # level-0 VMEM patch (module docstring; _fused_plane_patch)
@@ -1706,6 +1746,52 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     return built
 
 
+def pass_wrap_fills(dd, exch_route: str) -> Tuple[str, tuple]:
+    """Which of the y and z sweeps of this domain's exchange the plane passes
+    make themselves, and how: ``(axes, fills)`` -- ``axes`` a substring of
+    ``"yz"`` (``plan["pass_wrap_axes"]``), ``fills`` the ``(axis,
+    destination, source, width)`` of each halo fill, y before z
+    (``stream_plane_pass(wrap_fills=)``).
+
+    An axis rides in the pass exactly where its sweep IS the self-wrap
+    (``ops/exchange.py wrap_axes``, i.e. ``_sweep_kind``: mesh extent 1 on the
+    axis, 3-D blocks, a supported dtype, the blend kernels enabled, an
+    interior no narrower than the halo, no packed route on the axis): the halo
+    is then a copy of cells of the same plane, at the static offsets
+    ``halo_blend.wrap_halo`` computes -- low halo ``[0, r_lo)`` <- ``[n, n +
+    r_lo)``, high halo ``[r_lo + n, r_lo + n + r_hi)`` <- ``[r_lo, r_lo +
+    r_hi)``.  A function of the mesh and the domain, as ``_sweep_kind`` is:
+    no option.  Never x: in place, the pass has overwritten the source planes
+    of the high x shell before it reaches it."""
+    from stencil_tpu.ops.exchange import wrap_axes
+    from stencil_tpu.parallel.mesh import MESH_AXES
+
+    raw = dd.local_spec().raw_size()
+    shell = dd._shell_radius
+    swept = wrap_axes(
+        tuple(dd.mesh.shape[a] for a in MESH_AXES),
+        shell,
+        (raw.x, raw.y, raw.z),
+        [dd.field_dtype(h) for h in dd._handles],
+        all_3d=not any(h.components for h in dd._handles),
+        valid_last=dd._valid_last,
+        route=exch_route,
+    )
+    axes, fills = "", []
+    for a in (1, 2):
+        if MESH_AXES[a] not in swept:
+            continue
+        r_lo, r_hi = shell.axis(a, -1), shell.axis(a, +1)
+        n = dd._valid_last[a]  # one shard is the last shard
+        if n is None:
+            n = raw[a] - r_lo - r_hi
+        axes += MESH_AXES[a]
+        fills += [
+            (a, d, s, w) for d, s, w in ((0, n, r_lo), (r_lo + n, r_lo, r_hi)) if w
+        ]
+    return axes, tuple(fills)
+
+
 def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     from jax.sharding import PartitionSpec as P
 
@@ -1901,10 +1987,18 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     # the rules do not hold (trace_plane_kernel says where and why); the wrap
     # route exchanges none
     plan["writers"] = tuple(names)
+    # the y / z sweeps the plane passes make themselves in VMEM
+    # (pass_wrap_fills), written back like the two above (domain.step's
+    # ``wrapped``): on the plane route's default schedule only -- the fused
+    # mode's side buffers and the split schedule's exterior bands keep the
+    # exchange they have
+    plan["pass_wrap_axes"], wrap_fills = "", ()
     if plan["route"] == "wrap":
         plan["halo_readers"] = ()
     elif plan["route"] == "plane":
         stage_runs = plan_plane_stages(dd, stages, x_radius, plan, interpret, fused)
+        if not fused and not split:
+            plan["pass_wrap_axes"], wrap_fills = pass_wrap_fills(dd, exch_route)
     else:
         plan["halo_readers"] = tuple(names)
 
@@ -1955,15 +2049,19 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                 return contextlib.nullcontext()
             return telemetry.annotate(tm.step_stage_span(k))
 
+        swept_axes = tuple(
+            a for a in range(3) if MESH_AXES[a] not in plan["pass_wrap_axes"]
+        )
+
         def exchange_readers(bs, k):
             """``bs`` with the shells of stage ``k``'s halo readers filled --
             one joint exchange of those blocks alone; the others ride on
-            untouched."""
+            untouched -- on the axes the passes do not wrap themselves."""
             riders = [index[name] for name in stage_readers[k]]
             out = list(bs)
             filled = halo_exchange_multi(
                 [bs[q] for q in riders], shell, mesh_shape,
-                valid_last=valid_last, route=exch_route,
+                valid_last=valid_last, axes=swept_axes, route=exch_route,
             )
             for q, b in zip(riders, filled):
                 out[q] = b
@@ -1991,7 +2089,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                         lo, hi, x_radius, origin, gsize, alias=alias,
                         interpret=interpret, fused_shell=fs,
                         f32_accumulate=f32_acc, halo_readers=stage_readers[k],
-                        writers=writes, rings=rings,
+                        writers=writes, rings=rings, wrap_fills=wrap_fills,
                     )
                 for q, o in zip(g, outs):
                     out[q] = o
@@ -2265,6 +2363,17 @@ def make_stream_step(
     are read off-centre ALONG X — the only ones that keep a VMEM ring.  The
     passes run the jaxpr that trace made: the callable is traced once.
 
+    What the exchange still sweeps and what the passes wrap.  On the plane
+    route's default schedule (not ``halo="fused"``, not ``overlap="split"``)
+    a y or z axis whose sweep would be the self-wrap -- the mesh does not
+    split it, and the blend kernels can engage (``pass_wrap_fills``, the
+    resolved ``plan["pass_wrap_axes"]``, ``domain.step``'s ``wrapped``) --
+    is not swept at all: ``stream_plane_pass`` fills that halo of every
+    reader plane it loads from the plane's own interior, in VMEM.  The
+    exchange keeps x (always a sweep of its own) and every axis the mesh
+    splits; the result is bitwise the full exchange's on every cell a kernel
+    can read.  A function of the mesh: no option.
+
     ``max_depth`` caps the temporal depth (wrap k / wavefront m).  The auto
     planner maximizes depth because depth is the HBM-traffic lever
     (~bytes/k per cell) — correct for bandwidth-bound kernels, but a
@@ -2507,6 +2616,10 @@ def make_stream_step(
             # quantities the passes write: what the kernel returns on the
             # plane route (plan_plane_stages), every one elsewhere
             "written": len(plan_now.get("writers", ())),
+            # the axes whose halo the plane passes fill themselves in VMEM,
+            # so that the exchange does not sweep them (pass_wrap_fills): one
+            # value for every stage, a function of the mesh and the domain
+            "wrapped": plan_now.get("pass_wrap_axes", ""),
         }
         per_stage = plan_now.get("stages", ())
         if len(per_stage) > 1:

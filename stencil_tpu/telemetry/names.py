@@ -323,9 +323,15 @@ ALL_HISTOGRAMS = frozenset({
 #: ``halo="fused"``), every one on the wavefront route, 0 on the wrap route,
 #: written = quantities that are outputs of the passes: on the plane route
 #: those the kernel returns (the same trace, ``plan["writers"]``; all of them
-#: under ``halo="fused"``), every one on the other routes; a STAGED step
-#: (``make_step`` with a sequence of kernels) adds stages and passes, and says
-#: exchanged / written / aliased PER STAGE, in order: "6/3", "3/6", "11/12"]
+#: under ``halo="fused"``), every one on the other routes, wrapped = the axes
+#: whose halo the plane passes fill themselves in VMEM, so that the step's
+#: exchange does not sweep them (``ops/stream.pass_wrap_fills``: the y / z
+#: axes the mesh does not split, "yz" on one chip, "z" on mesh [2,2,1], ""
+#: off the plane route's default schedule and wherever that axis's sweep is
+#: not the self-wrap); a STAGED step (``make_step`` with a sequence of
+#: kernels) adds stages and passes, and says exchanged / written / aliased
+#: PER STAGE, in order: "6/3", "3/6", "11/12" (``wrapped`` is one value: a
+#: function of the mesh, the same for every stage)]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes

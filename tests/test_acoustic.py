@@ -84,7 +84,12 @@ def test_one_chip_sweeps_are_self_wraps_at_radius_4(monkeypatch):
     assert err_u <= ATOL and err_prev <= ATOL, (err_u, err_prev)
 
 
-def test_route_is_plane_and_the_span_says_so():
+@pytest.mark.parametrize("blend,wrapped", [("0", ""), ("1", "yz")], ids=["cpu", "as-on-the-chip"])
+def test_route_is_plane_and_the_span_says_so(blend, wrapped, monkeypatch):
+    """``wrapped`` (ISSUE 34): with the blend kernels on, as on the chip, the
+    y and z self-wraps of the one device ride in the pass; a plain CPU run
+    keeps the program it had."""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", blend)
     sim = _sim("pallas")
     plan = sim._step._stream_plan
     assert plan["route"] == "plane" and plan["m"] == 1 and plan["grouping"] == "joint", plan
@@ -93,9 +98,11 @@ def test_route_is_plane_and_the_span_says_so():
         "route": "plane", "x_radius": 4, "grouping": "joint", "streamed": 4,
         "aliased": 4, "exchanged": 1,  # u alone is read off-centre (ISSUE 30)
         "written": 2,  # m and damp are never returned: inputs only (ISSUE 32)
+        "wrapped": wrapped,
     }
     assert plan["halo_readers"] == ("u",), plan
     assert plan["writers"] == ("u", "u_prev"), plan
+    assert plan["pass_wrap_axes"] == wrapped, plan
     seen = []
     real = telemetry.span
 
@@ -109,6 +116,7 @@ def test_route_is_plane_and_the_span_says_so():
     (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
     assert kw["label"] == "acoustic" and kw["steps"] == 2 and kw["route"] == "plane"
     assert (kw["streamed"], kw["aliased"], kw["exchanged"], kw["written"]) == (4, 4, 1, 2)
+    assert kw["wrapped"] == wrapped
 
 
 @pytest.mark.parametrize("devices", [1, 2, 8])
@@ -130,9 +138,10 @@ def test_plane_route_is_bitwise_the_xla_engine_on_every_quantity(devices):
 
 def test_the_step_program_exchanges_u_alone(monkeypatch):
     """The step as the chip runs it (blend kernels on: every sweep of one
-    device is the self-wrap kernel): per step ONE ``stream_plane_pass`` and
-    exactly three Pallas calls under ``exchange.*`` scopes, one per axis, all
-    on ``u`` -- twelve before ISSUE 30, nine of them for halos nobody reads."""
+    device is a self-wrap): per step ONE ``stream_plane_pass`` and ONE Pallas
+    call under an ``exchange.*`` scope, the x wrap of ``u`` -- the y and z
+    wraps ride in the pass (ISSUE 34: 4 Pallas calls a step -> 2, no
+    ``blend_slab`` left; three wraps before, twelve before ISSUE 30)."""
     from stencil_tpu.analysis import jaxpr as jx
 
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
@@ -141,12 +150,10 @@ def test_the_step_program_exchanges_u_alone(monkeypatch):
     calls = [e for e in jx.iter_eqns(closed) if e.primitive.name == "pallas_call"]
     passes = [e for e in calls if e.params.get("name") == tm.KERNEL_STREAM_PLANE_PASS]
     wraps = [jx.name_stack_str(e) for e in calls if "exchange." in jx.name_stack_str(e)]
-    assert len(passes) == 1 and len(calls) == 4, [e.params.get("name") for e in calls]
-    assert wraps == [
-        f"exchange.{ax}/exchange.{ax}.wrap/{kernel}"
-        for ax, kernel in zip("xyz", ("blend_planes", "blend_slab", "blend_slab"))
-    ], wraps
+    assert len(passes) == 1 and len(calls) == 2, [e.params.get("name") for e in calls]
+    assert wraps == ["exchange.x/exchange.x.wrap/blend_planes"], wraps
     assert not [e for e in jx.iter_eqns(closed) if e.primitive.name == "ppermute"]
+    assert sim._step._stream_plan["pass_wrap_axes"] == "yz"
 
 
 def _plane_passes(fn, curr):

@@ -272,3 +272,55 @@ def test_compiled_self_wrap_518(axis):
     want = want.at[cut(r + n, size)].set(block[cut(r, 2 * r)])
     got = jax.jit(lambda b: wrap_halo(b, axis, r, r, n))(block)
     assert bool(jnp.array_equal(got, want))
+
+
+def test_compiled_plane_pass_wraps_the_planes_it_loads(monkeypatch):
+    """The plane pass's own y / z halo fills (ISSUE 34) as Mosaic compiles
+    them, on acoustic's plane -- 608 x 608 f32, radius 4: sublane tiles 0 and
+    75 (y), lane tiles 0 and 4 with the last one ragged (z), written into the
+    pipeline's own input buffer -- for a ringed reader and one fetched lagged,
+    both written back: every RAW cell equals the step whose exchange sweeps
+    all three axes with ``wrap_halo``.  (The benchmark's wave cells cannot see
+    this: their outer frame is zero, so a halo that was never filled is right
+    by accident.)"""
+    from stencil_tpu.core.radius import Radius
+    from stencil_tpu.domain import DistributedDomain
+    from stencil_tpu.ops import stream as sm
+
+    r = 4
+
+    def kern(views, info):
+        u, b = views["u"], views["b"]
+        acc = 0.5 * u.center()
+        for k in range(1, r + 1):
+            w = 1.0 / (12.0 * k)
+            acc = acc + w * (
+                (u.sh(k, 0, 0) + 0.9 * u.sh(-k, 0, 0))
+                + (u.sh(0, k, 0) + 0.8 * u.sh(0, -k, 0))
+                + (u.sh(0, 0, k) + 0.7 * u.sh(0, 0, -k))
+            )
+        return {
+            "u": acc + 0.125 * (b.sh(0, 3, 0) - b.sh(0, 0, -r)),
+            "b": 0.5 * b.center() + 0.25 * b.sh(0, -1, 0),
+        }
+
+    def run():
+        dd = DistributedDomain(8, 600, 600)
+        dd.set_radius(Radius.constant(r))
+        dd.set_devices(jax.devices()[:1])
+        hs = [dd.add_data(n) for n in ("u", "b")]
+        dd.realize()
+        for i, h in enumerate(hs):
+            dd.init_by_coords(h, lambda x, y, z, i=i: jnp.sin(0.013 * (x + 2 * y + 3 * z) + i))
+        step = dd.make_step(kern, engine="stream", x_radius=r)
+        dd.run_step(step, 3)
+        return step._stream_plan, [dd._curr[h.name] for h in hs]
+
+    plan, got = run()
+    assert plan["route"] == "plane" and plan["pass_wrap_axes"] == "yz", plan
+    assert plan["stages"][0]["passes"][0]["rings"] == ("u",), plan
+    monkeypatch.setattr(sm, "pass_wrap_fills", lambda dd, route: ("", ()))
+    plan_off, want = run()
+    assert plan_off["pass_wrap_axes"] == "", plan_off
+    for name, a, b in zip(("u", "b"), got, want):
+        assert bool(jnp.all(jnp.isfinite(b))) and bool(jnp.array_equal(a, b)), name

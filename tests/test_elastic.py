@@ -132,6 +132,7 @@ def test_the_plan_is_staged_and_the_span_says_so():
     assert sim._step._span_args() == {
         "route": "plane", "x_radius": 4, "grouping": "joint", "streamed": 13,
         "stages": 2, "passes": 2, "exchanged": "6/3", "written": "3/6", "aliased": "11/12",
+        "wrapped": "",  # a plain CPU run: the blend kernels are off (ISSUE 34)
     }
     seen = []
     real = telemetry.span
@@ -145,7 +146,7 @@ def test_the_plan_is_staged_and_the_span_says_so():
         sim.step(2)
     (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
     assert (kw["label"], kw["steps"], kw["stages"]) == ("elastic", 2, 2)
-    assert (kw["exchanged"], kw["written"]) == ("6/3", "3/6")
+    assert (kw["exchanged"], kw["written"], kw["wrapped"]) == ("6/3", "3/6", "")
 
 
 def _real_size_traces():
@@ -288,18 +289,23 @@ def test_each_stage_sends_only_what_it_reads_off_centre():
 
 def test_the_step_program_has_two_stages_of_passes_and_wraps(monkeypatch):
     """The step as the chip runs it (blend kernels on): under ``step.stage.0``
-    one pass and 6 x 3 self-wrap kernels, under ``step.stage.1`` one pass and
-    3 x 3; every pass under ``step.pass`` inside its stage, every pass output
-    aliased onto its input."""
+    one pass and the 6 x wraps of the stresses, under ``step.stage.1`` one
+    pass and the 3 of the velocities -- the y and z wraps ride in the passes
+    (ISSUE 34: 6 x 3 and 3 x 3 before; at 608 x 608 planes the four passes make
+    it 13 Pallas calls a step where it was 31); every pass under ``step.pass``
+    inside its stage, every pass output aliased onto its input."""
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
     sim = _sim("pallas")
-    for k, (wraps, outs) in enumerate(((18, 3), (9, 6))):
+    assert sim._step._stream_plan["pass_wrap_axes"] == "yz"
+    assert sim._step._span_args()["wrapped"] == "yz"
+    for k, (wraps, outs) in enumerate(((6, 3), (3, 6))):
         calls = [(e, s) for e, s in _stage_eqns(sim)[k] if e.primitive.name == "pallas_call"]
         passes = [(e, s) for e, s in calls if e.params.get("name") == tm.KERNEL_STREAM_PLANE_PASS]
         (one,) = passes
         assert tm.SPAN_STEP_PASS in one[1]
         assert len(one[0].outvars) == outs == len(one[0].params["input_output_aliases"])
-        assert sum("exchange." in s for _, s in calls) == wraps == len(calls) - 1
+        assert sum("exchange.x/exchange.x.wrap" in s for _, s in calls) == wraps == len(calls) - 1
+        assert not [s for _, s in calls if "exchange.y" in s or "exchange.z" in s]
 
 
 def test_a_one_stage_step_has_no_stage_scope():

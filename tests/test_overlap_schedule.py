@@ -253,6 +253,9 @@ def test_acoustic_step_carries_its_blocks_in_place(monkeypatch):
     ]
     assert pass_line.lstrip().split(" custom-call(")[0].count("f32[608,608,608]") == 2
     assert "output_to_operand_aliasing={{0}: (1, {}), {1}: (2, {})}, " in pass_line
+    # the pass and the x wrap of ``u``: the y and z wraps ride in the pass (ISSUE 34)
+    calls = [l for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
+    assert len(calls) == 2 and any(l.lstrip().startswith("%blend_planes") for l in calls)
     assert not big_copy.findall(text) and temp == 0
     text_off, temp_off = texts[False]
     assert len(big_copy.findall(text_off)) == 2 and temp_off > 1.8e9
@@ -291,7 +294,9 @@ def test_elastic_step_carries_its_blocks_in_place(monkeypatch):
     calls = [l for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
     passes = [l for l in calls if l.lstrip().startswith("%stream_plane_pass")]
     results = sorted(l.lstrip().split(" custom-call(")[0].count("f32[608,608,608]") for l in passes)
-    assert results == [1, 2, 2, 4] and len(calls) - len(passes) == 27
+    assert plan["pass_wrap_axes"] == "yz"
+    assert results == [1, 2, 2, 4] and len(calls) - len(passes) == 9
+    assert all(l.lstrip().startswith("%blend_planes") for l in calls if l not in passes)
     for l in passes:
         n = l.lstrip().split(" custom-call(")[0].count("f32[608,608,608]")
         aliasing = l[l.index("output_to_operand_aliasing="):].split("}, ")[0]

@@ -376,20 +376,28 @@ def _one_pass(kernel, **kw):
     return jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((3,), jnp.int32), blk, blk)
 
 
-def test_an_off_centre_read_the_pass_was_not_told_of_raises_by_name():
+@pytest.mark.parametrize(
+    "wrap_fills",
+    [(), ((1, 0, 8, 1), (1, 9, 1, 1), (2, 0, 8, 1), (2, 9, 1, 1))],
+    ids=["exchanged", "wrapped-in-the-pass"],
+)
+def test_an_off_centre_read_the_pass_was_not_told_of_raises_by_name(wrap_fills):
     """The pass checks what it is told: a kernel that reads ``c`` off-centre
     in a pass whose ``halo_readers`` leave ``c`` out meets a ``c`` whose halo
     was not exchanged, and the pass says so at trace time -- never a stale
-    read; off-centre ALONG X in a pass that holds no ring for ``c`` likewise."""
+    read; off-centre ALONG X in a pass that holds no ring for ``c`` likewise.
+    The pass that fills the y / z halo itself (ISSUE 34) fills it for the
+    readers alone, so it fails closed the same way."""
 
     def kernel(dx, dz):
         return lambda views, info: {"a": _star(views["a"], 1) * views["c"].sh(dx, 0, dz)}
 
-    _one_pass(kernel(0, 1), halo_readers=("a", "c"), rings=("a",))  # told: fine
+    kw = {"wrap_fills": wrap_fills}
+    _one_pass(kernel(0, 1), halo_readers=("a", "c"), rings=("a",), **kw)  # told: fine
     with pytest.raises(ValueError, match=r"reads 'c' off-centre.*halo of 'c' was not exchanged"):
-        _one_pass(kernel(0, 1), halo_readers=("a",))
+        _one_pass(kernel(0, 1), halo_readers=("a",), **kw)
     with pytest.raises(ValueError, match=r"reads 'c' off-centre along x.*no ring for 'c'"):
-        _one_pass(kernel(1, 0), rings=("a",))
+        _one_pass(kernel(1, 0), rings=("a",), **kw)
 
 
 def test_a_step_traces_its_kernel_once_and_runs_what_that_trace_saw():
@@ -705,3 +713,133 @@ def test_a_fused_plane_step_writes_every_quantity():
         jax.make_jaxpr(step._resilience.built(), static_argnums=1)(dd._curr, 1)
     )
     assert len(call.outvars) == 2
+
+
+# --- the pass wraps the planes it loads (ISSUE 34) ----------------------------
+#
+# On an axis the mesh does not split, the halo of a plane is a copy of cells of
+# that same plane: ``stream_plane_pass(wrap_fills=)`` makes the y and z fills
+# itself, in VMEM, on every loaded plane of every halo reader, and the step's
+# exchange sweeps the other axes only.  Here the pass ITSELF: over blocks whose
+# y / z shell is garbage it must equal, on every raw cell of every writer, the
+# pass over blocks that were swept x -> y -> z.
+
+
+def _self_wrap(a, axis, lo, hi):
+    """The numpy twin of ``halo_blend.wrap_halo``: both halos of ``axis``
+    from the array's own interior, over the full extent of the other axes."""
+    a = a.copy()
+    n = a.shape[axis] - lo - hi
+    cut = lambda s, e: tuple(slice(s, e) if ax == axis else slice(None) for ax in range(3))  # noqa: E731
+    if lo:
+        a[cut(0, lo)] = a[cut(n, n + lo)]
+    if hi:
+        a[cut(lo + n, lo + n + hi)] = a[cut(lo, lo + hi)]
+    return a
+
+
+def _lagged_y_kernel(views, info):
+    """elastic's ``tyy``: ``b`` is differenced along y alone (no ring: fetched
+    lagged) and never written; ``a`` is written and read at the centre."""
+    a, b = views["a"], views["b"]
+    return {"a": 0.5 * a.center() + (b.sh(0, 1, 0) - 0.25 * b.sh(0, -1, 0))}
+
+
+def _lagged_self_kernel(views, info):
+    """A reader with no ring that is also the writer: its pass-through carries
+    the patched centre plane back to HBM."""
+    b = views["b"]
+    return {"b": 0.5 * b.center() + 0.25 * b.sh(0, 0, 1) + 0.125 * b.sh(0, -1, 0)}
+
+
+def _ringed_star(r):
+    from test_stream import star_kernel
+
+    return star_kernel(r)
+
+
+_PASS_WRAP_CASES = [
+    # id, kernel, names, r, lo, hi, readers, rings, writers, axes, pass kwargs
+    pytest.param(_ringed_star(2), ["u"], 2, (2, 2, 2), (2, 2, 2), ("u",), ("u",), ("u",),
+                 "yz", {}, id="ringed-reader-written"),
+    pytest.param(_ringed_star(2), ["u"], 2, (2, 2, 2), (2, 2, 2), ("u",), ("u",), ("u",),
+                 "yz", {"alias": True}, id="ringed-in-place"),
+    pytest.param(_lagged_y_kernel, ["a", "b"], 1, (1, 1, 1), (1, 1, 1), ("b",), (), ("a",),
+                 "yz", {}, id="lagged-reader-y-only"),
+    pytest.param(_lagged_self_kernel, ["b"], 1, (1, 1, 1), (1, 1, 1), ("b",), (), ("b",),
+                 "yz", {}, id="lagged-reader-written"),
+    pytest.param(_ringed_star(1), ["u"], 1, (1, 2, 3), (2, 1, 1), ("u",), ("u",), ("u",),
+                 "yz", {}, id="asymmetric-shells"),
+    pytest.param(_lagged_self_kernel, ["b"], 1, (1, 3, 1), (1, 1, 2), ("b",), (), ("b",),
+                 "yz", {}, id="asymmetric-shells-lagged"),
+    pytest.param(_ringed_star(2), ["u"], 2, (2, 2, 2), (2, 2, 2), ("u",), ("u",), ("u",),
+                 "z", {}, id="z-alone-rides"),
+    pytest.param(_ringed_star(2), ["u"], 2, (2, 2, 2), (2, 2, 2), ("u",), ("u",), ("u",),
+                 "y", {}, id="y-alone-rides"),
+    pytest.param(_ringed_star(1), ["u"], 1, (1, 1, 1), (1, 1, 1), ("u",), ("u",), ("u",),
+                 "yz", {"f32_accumulate": True}, id="bf16-storage"),
+]
+
+
+@pytest.mark.parametrize(
+    "kernel,names,r,lo,hi,readers,rings,writers,axes,kw", _PASS_WRAP_CASES
+)
+def test_the_pass_wraps_the_planes_it_loads(
+    kernel, names, r, lo, hi, readers, rings, writers, axes, kw
+):
+    """Every raw cell of every writer, bit for bit: the pass with
+    ``wrap_fills`` over blocks whose shell on the riding axes was never
+    filled, against the plain pass over blocks swept x -> y -> z -- and the
+    garbage mattered (the plain pass over the unswept blocks differs)."""
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops.stream import stream_plane_pass
+
+    n = 10
+    shape = tuple(n + a + b for a, b in zip(lo, hi))
+    dtype = jnp.bfloat16 if kw.get("f32_accumulate") else jnp.float32
+    rng = np.random.default_rng(34)
+    swept, unswept, fills = [], [], []
+    for axis in (1, 2):
+        if "xyz"[axis] in axes:
+            m = shape[axis] - lo[axis] - hi[axis]
+            fills += [(axis, 0, m, lo[axis]), (axis, lo[axis] + m, lo[axis], hi[axis])]
+    for name in names:
+        a = np.asarray(jnp.asarray(rng.standard_normal(shape), dtype).astype(jnp.float32))
+        if name in readers:
+            a = _self_wrap(a, 0, lo[0], hi[0])  # the x sweep stays in the exchange
+            b = a
+            for axis in (1, 2):
+                if "xyz"[axis] not in axes:  # a sweep that rides the wires came first
+                    a = b = _self_wrap(b, axis, lo[axis], hi[axis])
+            for axis in (1, 2):
+                if "xyz"[axis] in axes:
+                    b = _self_wrap(b, axis, lo[axis], hi[axis])
+        else:
+            b = a
+        unswept.append(jnp.asarray(a, dtype))
+        swept.append(jnp.asarray(b, dtype))
+    origin = jnp.zeros((3,), jnp.int32)
+
+    def run(raws, wrap_fills):
+        return stream_plane_pass(
+            kernel, names, raws, Dim3(*lo), Dim3(*hi), r, origin, Dim3(n, n, n),
+            interpret=True, halo_readers=readers, rings=rings, writers=writers,
+            wrap_fills=wrap_fills, **kw,
+        )
+
+    got = run(unswept, tuple(fills))
+    want = run(swept, ())
+    stale = run(unswept, ())
+    for q, name in enumerate(names):
+        if name not in writers:
+            assert got[q] is unswept[q], name  # an input and nothing else: HBM keeps its shell
+            continue
+        a, b = (np.asarray(v.astype(jnp.float32)) for v in (got[q], want[q]))
+        assert np.isfinite(b).all() and np.array_equal(a, b), name
+    assert any(
+        not np.array_equal(np.asarray(stale[q].astype(jnp.float32)),
+                           np.asarray(want[q].astype(jnp.float32)))
+        for q, name in enumerate(names) if name in writers
+    )

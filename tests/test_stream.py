@@ -438,6 +438,7 @@ def test_stream_separable_per_field_grouping(monkeypatch):
         "overlap": "off", "halo": "array",
         "halo_readers": ("a", "b", "c", "d"),  # the wavefront exchanges every quantity
         "writers": ("a", "b", "c", "d"),  # and writes every one
+        "pass_wrap_axes": "",  # the plane route's alone (ISSUE 34)
     }
     monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
     ref_dd, ref_hs = _mk(24, 24, 24, Radius.constant(1), names, devs)
@@ -507,6 +508,7 @@ def test_stream_depth_cap():
         "overlap": "off", "halo": "array",
         "halo_readers": (),  # and no exchange
         "writers": ("u",),
+        "pass_wrap_axes": "",
     }
     for a, b in outs:  # uncapped wrap vs the XLA ground truth
         np.testing.assert_allclose(a, b, **TOL)
@@ -774,3 +776,199 @@ def test_wavefront_span_counts_its_in_place_passes(nq, want):
     assert plan["alias"] is (want > 0), plan
     args = sim._step._span_args()
     assert (args["streamed"], args["aliased"]) == (nq, want), args
+
+
+# --- the plane pass wraps the planes it loads (ISSUE 34) ----------------------
+#
+# On the plane route's default schedule the y and z sweeps of an axis the mesh
+# does not split leave the exchange and ride in the pass (``plan["pass_wrap_
+# axes"]``, ``ops/stream.py pass_wrap_fills``).  The step as built against the
+# same step with the rule off (the parent's program: every axis swept by the
+# exchange, an unsplit one by ``halo_blend.wrap_halo``), blend kernels on as on
+# the chip: every raw cell of every writer bitwise equal, halo included.
+
+
+def staged_kernels():
+    """A two-stage step in elastic's shape: stage V differences ``s`` along y
+    alone (no ring: fetched lagged) and ``t`` along z and x; stage S reads the
+    ``v`` stage V wrote, halo included, and carries a coefficient."""
+
+    def stage_v(views, info):
+        s, t = views["s"], views["t"]
+        return {
+            "v": views["v"].center()
+            + 0.25 * (s.sh(0, 1, 0) - s.sh(0, -1, 0))
+            + 0.125 * (t.sh(0, 0, 1) - t.sh(-1, 0, 0))
+        }
+
+    def stage_s(views, info):
+        v, c = views["v"], views["c"].center()
+        return {
+            "s": views["s"].center() + 0.25 * (v.sh(1, 0, 0) - v.sh(0, -1, 0)) * c,
+            "t": views["t"].center() + 0.5 * (v.sh(0, 0, 1) - v.sh(0, 0, -1)),
+        }
+
+    return (stage_v, stage_s)
+
+
+def _uneven_shell():
+    """Face widths lo (2, 1, 2), hi (1, 2, 1): every direction as wide as its
+    narrowest face."""
+    from stencil_tpu.core.direction_map import DIRECTIONS_26
+
+    faces = {-1: (2, 1, 2), 1: (1, 2, 1)}
+    return Radius.from_dict(
+        {d: min(faces[s][ax] for ax, s in enumerate(d) if s) for d in DIRECTIONS_26}
+    )
+
+
+_FOUR = (["u", "v", "c", "d"], 2)
+_PASS_WRAP_STEP_CASES = [
+    # id: kernel, names, x_radius, partition, shell, extent, axes that ride in the pass
+    pytest.param(coupled_kernel(2), *_FOUR, (1, 1, 1), None, (16, 16, 16), "yz", id="one-chip"),
+    pytest.param(coupled_kernel(2), *_FOUR, (2, 1, 1), None, (16, 16, 16), "yz", id="mesh-2x1x1"),
+    pytest.param(coupled_kernel(2), *_FOUR, (1, 2, 1), None, (16, 16, 16), "z", id="mesh-1x2x1"),
+    pytest.param(coupled_kernel(2), *_FOUR, (1, 1, 2), None, (16, 16, 16), "y", id="mesh-1x1x2"),
+    pytest.param(coupled_kernel(2), *_FOUR, (2, 2, 2), None, (16, 16, 16), "", id="mesh-2x2x2"),
+    pytest.param(staged_kernels(), ["v", "s", "t", "c"], 1, (1, 1, 1), None, (16, 16, 16), "yz",
+                 id="two-stage"),
+    pytest.param(staged_kernels(), ["v", "s", "t", "c"], 1, (2, 1, 1), None, (16, 16, 16), "yz",
+                 id="two-stage-mesh-2x1x1"),
+    pytest.param(star_kernel(1), ["u"], 1, (1, 1, 1), "uneven", (16, 16, 16), "yz",
+                 id="asymmetric-shells"),
+    pytest.param(star_kernel(1), ["u"], 1, (2, 1, 1), None, (15, 16, 16), "yz",
+                 id="padded-x-over-the-wires"),
+]
+
+
+def _pass_wrap_domain(names, r, partition, shell, extent):
+    dd = DistributedDomain(*extent)
+    dd.set_radius(_uneven_shell() if shell == "uneven" else Radius.constant(r))
+    dd.set_devices(jax.devices()[: int(np.prod(partition))])
+    dd.set_partition(*partition)
+    hs = [dd.add_data(n) for n in names]
+    dd.realize()
+    for i, h in enumerate(hs):
+        dd.init_by_coords(h, lambda x, y, z, i=i: jnp.sin(0.13 * (x + 2 * y + 3 * z) + i))
+    return dd, hs
+
+
+@pytest.mark.parametrize("kernel,names,r,partition,shell,extent,axes", _PASS_WRAP_STEP_CASES)
+def test_plane_step_wraps_its_unsplit_axes_in_the_pass(
+    kernel, names, r, partition, shell, extent, axes, monkeypatch
+):
+    from stencil_tpu.analysis import jaxpr as jx
+    from stencil_tpu.ops import stream as sm
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+
+    def run():
+        dd, hs = _pass_wrap_domain(names, r, partition, shell, extent)
+        plan = dict(sm.plan_stream(dd, r, "plane", False))
+        step = sm._build_stream_step(dd, kernel, r, plan, interpret=True)
+        closed = jax.make_jaxpr(step, static_argnums=1)(dd._curr, 1)
+        swept = {
+            ax
+            for e in jx.iter_eqns(closed)
+            if e.primitive.name in ("pallas_call", "ppermute")
+            for ax in "xyz"
+            if f"exchange.{ax}" in jx.name_stack_str(e)
+        }
+        dd.run_step(step, 3)
+        raws = {h.name: np.asarray(dd._curr[h.name]) for h in hs}
+        return raws, [dd.quantity_to_host(h) for h in hs], plan, swept
+
+    raws, fields, plan, swept = run()
+    assert plan["route"] == "plane" and plan["pass_wrap_axes"] == axes, plan
+    assert swept == set("xyz") - set(axes)  # only the remaining axes are swept
+    monkeypatch.setattr(sm, "pass_wrap_fills", lambda dd, route: ("", ()))
+    raws_off, fields_off, plan_off, swept_off = run()
+    assert plan_off["pass_wrap_axes"] == "" and swept_off == set("xyz")
+    assert plan_off["halo_readers"] == plan["halo_readers"] and plan["halo_readers"]
+    # a quantity some stage both reads off-centre and writes comes back with
+    # its halo as the exchange left it (the pass-through writes the patched
+    # centre plane); one that is read in one stage and written in another
+    # keeps a stale y / z shell in HBM, which nothing reads before the next
+    # exchange refills it: its interior is held instead
+    whole = {
+        nm for st in plan["stages"] for nm in st["readers"]
+        if any(nm in p["writes"] for p in st["passes"])
+    }
+    assert whole or len(plan["stages"]) > 1
+    for i, name in enumerate(names):
+        assert np.isfinite(raws_off[name]).all(), name
+        assert np.array_equal(fields[i], fields_off[i]), name
+        if name in whole:
+            assert np.array_equal(raws[name], raws_off[name]), name
+    dd, hs = _pass_wrap_domain(names, r, partition, shell, extent)
+    dd.run_step(dd.make_step(kernel, overlap=False), 3)
+    for name, a, h in zip(names, fields, hs):
+        np.testing.assert_allclose(a, dd.quantity_to_host(h), err_msg=name, **TOL)
+
+
+#: id: partition, exchange route, stream path, plan overrides, blend kernels,
+#: and what the DEFAULT plane schedule wraps on that domain
+_NO_PASS_WRAP_CASES = [
+    # the packed routes need a wire on y and z, and their own sweeps of an
+    # unsplit axis are not the self-wrap
+    pytest.param((1, 2, 2), "yzpack_xla", "plane", {"halo": "fused", "halo_forced": True},
+                 "1", "", id="fused"),
+    pytest.param((1, 2, 1), None, "plane", {"overlap": "split", "overlap_forced": True},
+                 "1", "z", id="split"),
+    pytest.param((1, 1, 1), None, "plane", {}, "0", "", id="blend-kernels-off"),
+    pytest.param((1, 1, 1), None, "wavefront", {}, "1", "yz", id="wavefront-route"),
+    pytest.param((1, 1, 1), None, "wrap", {}, "1", "yz", id="wrap-route"),
+]
+
+
+@pytest.mark.parametrize("partition,route,path,plan_kw,blend,default", _NO_PASS_WRAP_CASES)
+def test_the_pass_wraps_nothing_where_the_rule_does_not_hold(
+    partition, route, path, plan_kw, blend, default, monkeypatch
+):
+    """``pass_wrap_axes`` is ``""`` -- the program the step had before ISSUE 34
+    -- under ``halo="fused"``, under ``overlap="split"``, with the blend
+    kernels off (a CPU run) and off the plane route; the default plane
+    schedule on the same domain says what the mesh leaves unsplit."""
+    from stencil_tpu.ops import stream as sm
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", blend)
+
+    def built(path, kw):
+        dd = DistributedDomain(16, 16, 16)
+        dd.set_radius(Radius.constant(2 if path == "wavefront" else 1))
+        dd.set_devices(jax.devices()[: int(np.prod(partition))])
+        dd.set_partition(*partition)
+        if route is not None:
+            dd.set_exchange_route(route)
+        dd.add_data("u")
+        dd.realize()
+        plan = dict(sm.plan_stream(dd, 1, path, False), **kw)
+        sm._build_stream_step(dd, star_kernel(1), 1, plan, interpret=True)
+        return plan
+
+    plan = built(path, plan_kw)
+    for key, want in plan_kw.items():  # the variant engaged, it did not degrade
+        assert plan[key] == want, plan
+    assert plan["route"] == path and plan["pass_wrap_axes"] == "", plan
+    assert built("plane", {})["pass_wrap_axes"] == default
+
+
+def test_an_nd_quantity_keeps_every_sweep_in_the_exchange(monkeypatch):
+    """The rule reads the whole domain, as ``_sweep_kind`` reads the blocks of
+    an exchange: one N-D quantity and no axis rides in a pass (the stream
+    engine refuses such a domain anyway: ``plan_stream``)."""
+    from stencil_tpu.ops import stream as sm
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    for components, want in (((), "yz"), ((3,), "")):
+        dd = DistributedDomain(16, 16, 16)
+        dd.set_radius(Radius.constant(1))
+        dd.set_devices(jax.devices()[:1])
+        dd.add_data("u")
+        dd.add_data("w", components=components)
+        dd.realize()
+        axes, fills = sm.pass_wrap_fills(dd, "direct")
+        assert axes == want, (components, axes)
+        assert fills == (
+            ((1, 0, 16, 1), (1, 17, 1, 1), (2, 0, 16, 1), (2, 17, 1, 1)) if want else ()
+        )

@@ -35,6 +35,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
+import weakref
 from functools import partial
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -234,6 +235,11 @@ class DistributedDomain:
         self._exchange_fn = None
         self._exchange_many_fn = None
         self._exchange_count = 0
+        # program -> the static arguments this domain has called it with
+        # (``_dispatch_span_args``): the first call of each is where jax
+        # compiles.  Weak keys: a step the caller dropped is not kept alive
+        self._dispatched = weakref.WeakKeyDictionary()
+        self._dispatched_by_id: dict = {}  # programs that cannot be weak keys
         # z-sweep exchange route (ops/exchange.py EXCHANGE_ROUTES): resolved
         # at realize() — explicit request > STENCIL_EXCHANGE_ROUTE > tuned
         # config > static "direct"; packed-route analytic accounting rides it
@@ -606,8 +612,9 @@ class DistributedDomain:
         # the span opens once the geometry is known, so it can say which
         # shards are padded; it holds what takes the time (allocation, the
         # exchange's build and eager compile)
+        telemetry.watch_jax()  # jax is up by now: its compile events, by phase
         with telemetry.span(
-            tm.SPAN_REALIZE,
+            tm.SPAN_REALIZE, total=tm.PHASE_REALIZE,
             valid_last=",".join("-" if v is None else str(v) for v in self._valid_last),
         ):
             self._allocate_and_plan(allocate)
@@ -667,7 +674,9 @@ class DistributedDomain:
             # executable cache.
             if self._handles:
                 t0 = time.perf_counter()
-                with telemetry.span(tm.EVENT_COMPILE, label="exchange:realize"):
+                with telemetry.span(
+                    tm.EVENT_COMPILE, total=tm.PHASE_COMPILE, label="exchange:realize"
+                ):
                     self._exchange_fn.lower(self._curr).compile()
                 self._record_exchange_compile(t0, "realize")
         else:
@@ -726,8 +735,10 @@ class DistributedDomain:
         return jax.jit(partial(jnp.zeros, shape, dtype=fdt), out_shardings=pin)()
 
     def _record_exchange_compile(self, t0: float, label: str) -> None:
+        # the reference-parity stat (``bin/weak.py`` prints it) and the JSONL /
+        # flight-ring event; the always-live record is the ``domain.compile``
+        # span's total (``setup.span_seconds.compile``)
         self.stats.time_create = time.perf_counter() - t0
-        telemetry.observe(tm.COMPILE_SECONDS, self.stats.time_create)
         telemetry.emit_event(
             tm.EVENT_COMPILE,
             phase="exchange",
@@ -1040,7 +1051,7 @@ class DistributedDomain:
                 # dispatch() pattern) so injected connection drops exercise
                 # the same retry path real ones take
                 inject.maybe_fail("compile", label)
-                with telemetry.span(tm.EVENT_COMPILE, label=label):
+                with telemetry.span(tm.EVENT_COMPILE, total=tm.PHASE_COMPILE, label=label):
                     return fn.lower(self._curr).compile()
 
             execute_with_retry(compile_unit, label=label)
@@ -1293,7 +1304,7 @@ class DistributedDomain:
         fill (a seed's words) then leave the program's text alone, so the
         compile cache serves every later fill instead of compiling a new
         program per value."""
-        with telemetry.span(tm.SPAN_INIT, quantity=h.name):
+        with telemetry.span(tm.SPAN_INIT, total=tm.PHASE_INIT, quantity=h.name):
             fill = self._init_program(h, fn, include_halo, len(args))
             self._curr[h.name] = fill(self._curr[h.name], *args)
 
@@ -1426,6 +1437,24 @@ class DistributedDomain:
                 self._packed_nkernels = kernels * self.num_subdomains()
         return self._exchange_nbytes
 
+    def _dispatch_span_args(self, fn, *static) -> dict:
+        """The set-up account's part of a dispatch span's arguments: phase
+        ``first_dispatch`` (and ``first=1``) the first time this domain calls
+        ``fn`` with these static arguments -- the call in which jax traces,
+        lowers and compiles or loads -- and ``steady`` ever after, for the
+        price of two lookups (``telemetry.dispatch_phase``: the first steady
+        dispatch ends set-up)."""
+        try:
+            seen = self._dispatched.setdefault(fn, set())
+        except TypeError:  # unhashable or not weakly referenceable: by identity, held
+            seen = self._dispatched_by_id.setdefault(id(fn), (fn, set()))[1]
+        first = static not in seen
+        args = {"total": telemetry.dispatch_phase(first)}
+        if first:  # the span says so; a steady span's arguments stay what they were
+            seen.add(static)
+            args["first"] = 1
+        return args
+
     def _account_exchanges(self, n: int) -> None:
         """Counter bookkeeping for ``n`` (possibly fused) halo exchanges —
         counters are always live, so this must stay a dict hit + two int
@@ -1445,6 +1474,7 @@ class DistributedDomain:
             "time_exchange", tm.EXCHANGE_SECONDS, tm.SPAN_EXCHANGE, sync=True,
             route=self._exchange_route, nbytes=self._model_exchange(), count=1,
             wrap_axes=self._wrap_axes, uneven_axes=self._uneven_axes,
+            **self._dispatch_span_args(self._exchange_fn),
         ):
             self._curr = self._watched_call(
                 "exchange", lambda: self._exchange_fn(self._curr)
@@ -1471,6 +1501,7 @@ class DistributedDomain:
             tm.SPAN_EXCHANGE, route=self._exchange_route,
             nbytes=steps * self._model_exchange(), count=steps,
             wrap_axes=self._wrap_axes, uneven_axes=self._uneven_axes,
+            **self._dispatch_span_args(self._exchange_many_fn, steps),
         ):
             self._curr = self._exchange_many_fn(self._curr, steps)
         self._shell_stale = False
@@ -1870,7 +1901,12 @@ class DistributedDomain:
         (``domain.step.*``) and analytic exchange bytes are always counted;
         the enqueue is always a ``domain.step`` span (a profiler annotation
         [label, steps]: on the device's clock under a profiler session, free
-        without one, never a sync).  With ``STENCIL_TELEMETRY`` enabled the
+        without one, never a sync).  The FIRST call of a step with given
+        ``steps`` -- where jax traces, lowers and compiles or loads -- is the
+        set-up account's phase ``first_dispatch``: its span says ``first=1``
+        and its enqueue wall time goes to an always-live total; every later
+        call is ``steady``: marked, not timed (``_dispatch_span_args``;
+        docs/observability.md "Set-up").  With ``STENCIL_TELEMETRY`` enabled the
         dispatch is additionally honest-synced inside that span and a
         per-raw-iteration histogram sample (``domain.step.seconds``) is taken
         — enabling telemetry therefore adds one device sync per dispatch,
@@ -1897,7 +1933,10 @@ class DistributedDomain:
         # the span is the ENQUEUE (a profiler annotation, never a sync);
         # only STENCIL_TELEMETRY's honest timing waits inside it
         plan_args = getattr(step_fn, "_span_args", dict)()  # a stream step's plan
-        with telemetry.span(tm.SPAN_STEP, label=label, steps=raw, **plan_args):
+        with telemetry.span(
+            tm.SPAN_STEP, label=label, steps=raw, **plan_args,
+            **self._dispatch_span_args(step_fn, steps),
+        ):
             self._curr = execute_with_retry(
                 dispatch,
                 label=f"dispatch:{label}",

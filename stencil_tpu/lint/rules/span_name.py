@@ -1,5 +1,5 @@
 """Rule ``span-name``: span labels — ``annotate()`` named scopes and the
-first argument of ``span()``/``record_span()`` — must be SPAN constants
+first argument of ``span()`` — must be SPAN constants
 from ``stencil_tpu/telemetry/names.py`` (``names.ALL_SPANS``).
 
 The general ``telemetry-name`` rule already rejects names absent from the
@@ -34,7 +34,7 @@ from typing import List
 from stencil_tpu.lint.framework import FileContext, Rule, Violation, register
 
 #: telemetry facade calls whose first positional arg is a SPAN label
-SPAN_TAKING_CALLS = {"annotate", "span", "record_span"}
+SPAN_TAKING_CALLS = {"annotate", "span"}
 
 #: module aliases the tree uses for the telemetry facade
 FACADE_ALIASES = {"telemetry"}
@@ -49,7 +49,7 @@ def _span_registry():
 
 
 def _is_span_call(node: ast.Call) -> bool:
-    """``telemetry.annotate/span/record_span(...)``, a bare ``annotate(...)``
+    """``telemetry.annotate/span(...)``, a bare ``annotate(...)``
     (the one verb distinctive enough to match by name — plain ``span``
     collides with too many locals), or ``jax.named_scope(...)`` (in-kernel
     device-timeline scopes)."""

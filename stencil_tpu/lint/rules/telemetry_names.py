@@ -6,8 +6,7 @@ Two checks, over ``stencil_tpu/`` (telemetry internals exempt — they pass
 names through as parameters), ``tests/``, and ``bench.py``:
 
 1. A telemetry API call (``telemetry.inc`` / ``observe`` / ``set_gauge`` /
-   ``emit_event`` / ``span`` / ``record_span`` / ``counter`` / ``gauge`` /
-   ``histogram``) whose first argument is a STRING LITERAL must use a
+   ``emit_event`` / ``span`` / ``counter`` / ``gauge`` / ``histogram``) whose first argument is a STRING LITERAL must use a
    literal registered in ``names.ALL_NAMES`` — a free string silently
    forks the time series across bench rounds.
 2. An attribute reference ``names.X`` / ``tm.X`` (the aliases this tree
@@ -32,7 +31,6 @@ NAME_TAKING_CALLS = {
     "set_gauge",
     "emit_event",
     "span",
-    "record_span",
     "counter",
     "gauge",
     "histogram",
@@ -61,7 +59,7 @@ def _registry():
 def _is_telemetry_call(node: ast.Call) -> bool:
     """``telemetry.<api>(...)`` or a bare ``<api>(...)`` name imported from
     the facade — bare names are matched by name alone, which is safe because
-    the API verbs are distinctive (``emit_event``, ``record_span``, ...) and
+    the API verbs are distinctive (``emit_event``, ``set_gauge``) and
     a false positive only ever asks the author to register a name."""
     f = node.func
     if isinstance(f, ast.Attribute):
@@ -73,7 +71,7 @@ def _is_telemetry_call(node: ast.Call) -> bool:
     if isinstance(f, ast.Name):
         # bare imports: only the unambiguous verbs (plain `span`/`counter`
         # etc. collide with too many local names to match blindly)
-        return f.id in {"emit_event", "record_span", "set_gauge"}
+        return f.id in {"emit_event", "set_gauge"}
     return False
 
 

@@ -146,7 +146,8 @@ class DegradationLadder:
             inject.maybe_fail("compile", f"{self.label}:{self.rung.name}")
             t0 = time.perf_counter()
             with telemetry.span(
-                tm.EVENT_COMPILE, label=f"{self.label}:{self.rung.name}"
+                tm.EVENT_COMPILE, total=tm.PHASE_COMPILE,
+                label=f"{self.label}:{self.rung.name}",
             ):
                 self._impl = self.rung.build()
             dt = time.perf_counter() - t0

@@ -107,7 +107,7 @@ class AOTCache:
         a previous process — AFTER caching, so the refusal can never
         repeat for this key."""
         t0 = self.clock()
-        with telemetry.span(tm.EVENT_COMPILE, label=f"serve:{label}"):
+        with telemetry.span(tm.EVENT_COMPILE, total=tm.PHASE_COMPILE, label=f"serve:{label}"):
             exe = build()
         seconds = self.clock() - t0
         telemetry.observe(tm.SERVE_COMPILE_SECONDS, seconds)

@@ -31,6 +31,12 @@ Design rules (enforced here, asserted by tests):
   ``emit_event`` return after one attribute check, no formatting happens.
   Counters/gauges stay live always (an int add; a post-mortem ``snapshot()``
   after a failed run still counts its retries).
+* **set-up is accounted always** — the few set-up-class spans open with
+  ``total=`` (a ``names.PHASE_*``): their wall time goes to always-live
+  totals and jax's own trace / lower / compile / cache events
+  (``watch_jax``) are counted under the innermost open phase, ``setup.*``
+  until the first steady dispatch of any program and ``run.*`` after it.
+  The steady dispatch path is marked, never timed: no clock read, no sync.
 * **never initialize a jax backend** — rank tags use the fail-closed
   ``logging._rank`` probe; spans enter ``jax.named_scope`` only when jax is
   already imported.
@@ -46,6 +52,8 @@ import collections
 import contextlib
 import json
 import os
+import sys
+import threading
 import time
 from typing import List, Optional
 
@@ -89,6 +97,11 @@ class _Telemetry:
         #: runs whose last events matter most are the ones that die with
         #: telemetry off.  Dumped by the flight recorder's crash report.
         self.ring = collections.deque(maxlen=RING_SIZE)
+        #: ``setup`` until the first steady dispatch of any program, then
+        #: ``run`` (``dispatch_phase``): which half of the phase totals grows
+        self.epoch = names.EPOCH_SETUP
+        #: jax's monitoring listeners are registered (``watch_jax``)
+        self.jax_watched = False
 
     def configure_from_env(self) -> None:
         from stencil_tpu.utils.config import env_bool, env_str
@@ -158,11 +171,13 @@ def disable() -> None:
 
 def reset() -> None:
     """Clear all recorded metrics, spans, and the event ring (counters
-    restart at 0)."""
+    restart at 0, the phase totals with them: the account of a set-up starts
+    over).  jax's listeners stay registered."""
     t = _cfg()
     t.registry.reset()
     t.spans.clear()
     t.ring.clear()
+    t.epoch = names.EPOCH_SETUP
 
 
 # --- metrics -----------------------------------------------------------------
@@ -199,17 +214,49 @@ def snapshot() -> dict:
 # --- spans -------------------------------------------------------------------
 
 
+class _Phase:
+    """One open phase (``span(total=)``): on the per-thread phase stack, so
+    jax's events are charged to it, and -- a timed phase -- its wall time and
+    a count into the always-live totals of the epoch it opened in.  The
+    steady phase has no total: entering it reads no clock."""
+
+    __slots__ = ("t", "phase", "seconds", "count", "t0")
+
+    def __init__(self, t: _Telemetry, phase: str):
+        self.t, self.phase = t, phase
+        self.seconds = names.PHASE_SERIES.get((t.epoch, names.TOTAL_SPAN_SECONDS, phase))
+        self.count = names.PHASE_SERIES.get((t.epoch, names.TOTAL_SPAN_COUNT, phase))
+
+    def __enter__(self):
+        self.t.spans.push_phase(self.phase)
+        if self.seconds is not None:
+            self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        if self.seconds is not None:
+            dur = time.perf_counter() - self.t0
+            self.t.registry.counter(self.seconds).inc(dur)
+            self.t.registry.counter(self.count).inc()
+        self.t.spans.pop_phase()
+
+
+_NO_PHASE = contextlib.nullcontext()
+
+
 @contextlib.contextmanager
-def span(name: str, histogram: Optional[str] = None, **args):
+def span(name: str, histogram: Optional[str] = None, total: Optional[str] = None, **args):
     """Nestable span.  ALWAYS: a ``jax.profiler.TraceAnnotation`` of the
     same name and args (if jax is already up) — a no-op without a profiler
     session, and with one the span sits on the host plane of the profiler's
-    trace, on the device ops' clock.  When telemetry is enabled,
+    trace, on the device ops' clock.  ``total`` (a ``names.PHASE_*``; the
+    set-up-class spans and the dispatches only) makes the span a phase of the
+    set-up account, always live: see ``_Phase``.  When telemetry is enabled,
     additionally: records a Chrome-trace event (nested under the enclosing
     span), optionally observes the duration into ``histogram``, and labels
     the region in HLO/XProf."""
     t = _cfg()
-    with _profiler_annotation(name, args):
+    phase = _NO_PHASE if total is None else _Phase(t, total)
+    with _profiler_annotation(name, args), phase:
         if not t.enabled:
             yield
             return
@@ -228,21 +275,73 @@ def span(name: str, histogram: Optional[str] = None, **args):
                 t.registry.histogram(histogram).observe(dur)
 
 
-def record_span(
-    name: str, t0: float, dur: float, histogram: Optional[str] = None, **args
-) -> None:
-    """Post-hoc span record for call sites that already timed themselves
-    (``t0`` from ``time.perf_counter``, ``dur`` seconds).  No-op disabled.
-    Recorder only: a finished interval cannot be back-dated onto the
-    profiler's clock, so a span that must sit beside the device ops is
-    opened with ``span()``."""
-    t = _cfg()
-    if not t.enabled:
+def dispatch_phase(first: bool) -> str:
+    """The phase of one program dispatch, for ``span(total=)``.  ``first``:
+    the program has not been called with these static arguments before, so
+    jax traces, lowers and compiles or loads inside the call.  Any later
+    call is steady, and the first steady dispatch of any program ENDS
+    set-up: from it on totals and events accumulate under ``run.*`` (a
+    second fill, a tenant admitted late, a re-realize after a reshard)."""
+    if first:
+        return names.PHASE_FIRST_DISPATCH
+    _t.epoch = names.EPOCH_RUN
+    return names.PHASE_STEADY
+
+
+# --- jax's own events, under the program's phases -----------------------------
+
+#: how many jaxpr traces are open on this thread: a jit traced inside a jit
+#: reports its own duration inside the outer one's
+_tracing = threading.local()
+
+
+def _charge(total: str, value) -> None:
+    """Add ``value`` to ``<epoch>.<total>.<innermost open phase>``."""
+    phase = _t.spans.phase() or names.PHASE_OUTSIDE
+    _t.registry.counter(names.PHASE_SERIES[_t.epoch, total, phase]).inc(value)
+
+
+def _on_jax_start(event: str, value, **kwargs) -> None:
+    # jax announces each timed region's start as a scalar of the same name
+    if event == names.JAX_TRACE_EVENT:
+        _tracing.depth = getattr(_tracing, "depth", 0) + 1
+
+
+def _on_jax_duration(event: str, duration: float, **kwargs) -> None:
+    total = names.JAX_DURATION_TOTALS.get(event)
+    if total is None:
         return
-    t.spans.record(name, t0, dur, **args)
-    _sample_track_counters(t, t0 + dur)
-    if histogram is not None:
-        t.registry.histogram(histogram).observe(dur)
+    if event == names.JAX_TRACE_EVENT:
+        _tracing.depth = depth = max(getattr(_tracing, "depth", 0) - 1, 0)
+        if depth:
+            return  # the enclosing trace's duration holds this one
+    _charge(total, duration)
+    if event == names.JAX_BACKEND_COMPILE_EVENT:
+        _charge(names.TOTAL_BACKEND_COMPILES, 1)
+
+
+def _on_jax_event(event: str, **kwargs) -> None:
+    total = names.JAX_EVENT_TOTALS.get(event)
+    if total is not None:
+        _charge(total, 1)
+
+
+def watch_jax() -> bool:
+    """Register, once per process, the ``jax.monitoring`` listeners that fold
+    jax's own trace / lower / backend-compile / persistent-cache events into
+    the always-live phase counters (``names.PHASE_SERIES``).  Only if jax is
+    ALREADY imported -- the facade never imports it; callers that know it is
+    (``realize()``) make sure.  Returns whether the listeners are on."""
+    if _t.jax_watched:
+        return True
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    _t.jax_watched = True
+    jax.monitoring.register_scalar_listener(_on_jax_start)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    jax.monitoring.register_event_listener(_on_jax_event)
+    return True
 
 
 def _sample_track_counters(t: _Telemetry, at: float) -> None:

@@ -2,7 +2,7 @@
 and event name this tree may emit.
 
 Every call into the telemetry facade (``telemetry.inc`` / ``observe`` /
-``set_gauge`` / ``emit_event`` / ``span`` / ``record_span``) must name its
+``set_gauge`` / ``emit_event`` / ``span``) must name its
 series through a constant defined here; the ``telemetry-name`` rule of
 ``stencil_tpu.lint`` (wired as a tier-1 test) rejects free-string names at
 call sites.  One
@@ -162,6 +162,98 @@ EXCHANGE_HOP_BYTES = {
     ("z", "high"): EXCHANGE_HOP_Z_HIGH_BYTES,
 }
 
+# --- set-up accounting (always live; docs/observability.md "Set-up") ---------
+#
+# A span opened with ``telemetry.span(..., total=PHASE_*)`` is a PHASE of the
+# program's start: it goes on a per-thread phase stack, and a timed phase adds
+# its wall time and a count to the always-live totals below -- recorder on or
+# off, profiler session or none.  jax's own monitoring events (trace, lower,
+# backend compile, persistent-cache traffic; ``telemetry.watch_jax``) are
+# folded into counters keyed by the INNERMOST open phase.  Every series is
+# ``<epoch>.<what>.<phase>``: epoch ``setup`` until the first steady dispatch
+# of any program, ``run`` from then on.
+
+#: ``domain.realize``: allocation, the exchange's build and eager compile
+PHASE_REALIZE = "realize"
+#: ``domain.init``: one seeded fill, its jit included
+PHASE_INIT = "init"
+#: ``domain.compile``: an eager compile (exchange, ladder rung, serving AOT)
+PHASE_COMPILE = "compile"
+#: the first call of a program with given static arguments (``domain.step`` /
+#: ``domain.exchange`` carrying ``first=1``): jax traces, lowers and compiles
+#: or loads inside it
+PHASE_FIRST_DISPATCH = "first_dispatch"
+#: every later call of that program: on the phase stack so that a recompile
+#: is charged to it, but NOT timed -- no clock read on the steady path
+PHASE_STEADY = "steady"
+#: no program span open on this thread (a caller's own jits)
+PHASE_OUTSIDE = "outside"
+
+#: the phases a span's wall time is added up for (spans nest -- the eager
+#: exchange compile sits inside ``realize`` -- and each total is inclusive)
+TIMED_PHASES = (PHASE_REALIZE, PHASE_INIT, PHASE_COMPILE, PHASE_FIRST_DISPATCH)
+PHASES = TIMED_PHASES + (PHASE_STEADY, PHASE_OUTSIDE)
+
+EPOCH_SETUP = "setup"
+EPOCH_RUN = "run"
+EPOCHS = (EPOCH_SETUP, EPOCH_RUN)
+
+#: wall seconds / number of the spans opened under a timed phase
+TOTAL_SPAN_SECONDS = "span_seconds"
+TOTAL_SPAN_COUNT = "span_count"
+#: Python tracing + lowering to MLIR (paid on every start, warm or cold);
+#: nested traces (a jit traced inside a jit) count once, at the outermost
+TOTAL_TRACE_SECONDS = "trace_seconds"
+#: backend compile -- in jax 0.9 the event wraps ``compile_or_get_cached``,
+#: so a persistent-cache hit's retrieval is inside it -- and how many
+TOTAL_BACKEND_SECONDS = "backend_seconds"
+TOTAL_BACKEND_COMPILES = "backend_compiles"
+#: reading an executable back from the persistent cache (already inside
+#: ``backend_seconds``: kept apart, never added to it)
+TOTAL_CACHE_RETRIEVAL_SECONDS = "cache_retrieval_seconds"
+TOTAL_CACHE_HITS = "cache_hits"
+TOTAL_CACHE_MISSES = "cache_misses"
+#: compiles that asked the persistent cache at all
+TOTAL_CACHE_REQUESTS = "cache_requests"
+#: jax found the persistent cache switched off (once per check)
+TOTAL_CACHE_DISABLED = "cache_disabled"
+
+#: the two of jax's ``jax.monitoring`` events the listener treats specially:
+#: traces nest, and a backend compile is also counted
+JAX_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+JAX_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: jax's duration events -> the total each is added to
+JAX_DURATION_TOTALS = {
+    JAX_TRACE_EVENT: TOTAL_TRACE_SECONDS,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": TOTAL_TRACE_SECONDS,
+    JAX_BACKEND_COMPILE_EVENT: TOTAL_BACKEND_SECONDS,
+    "/jax/compilation_cache/cache_retrieval_time_sec": TOTAL_CACHE_RETRIEVAL_SECONDS,
+}
+#: jax's plain events -> the total each counts in
+JAX_EVENT_TOTALS = {
+    "/jax/compilation_cache/cache_hits": TOTAL_CACHE_HITS,
+    "/jax/compilation_cache/cache_misses": TOTAL_CACHE_MISSES,
+    "/jax/compilation_cache/compile_requests_use_cache": TOTAL_CACHE_REQUESTS,
+    "/jax/compilation_cache/task_disabled_cache": TOTAL_CACHE_DISABLED,
+}
+
+#: (epoch, total, phase) -> the counter's name: span totals for the timed
+#: phases, jax's for every phase
+PHASE_SERIES = {
+    (epoch, total, phase): f"{epoch}.{total}.{phase}"
+    for epoch in EPOCHS
+    for total, phases in (
+        [(t, TIMED_PHASES) for t in (TOTAL_SPAN_SECONDS, TOTAL_SPAN_COUNT)]
+        + [
+            (t, PHASES)
+            for t in (TOTAL_BACKEND_COMPILES, *JAX_DURATION_TOTALS.values(),
+                      *JAX_EVENT_TOTALS.values())
+        ]
+    )
+    for phase in phases
+}
+
+
 ALL_COUNTERS = frozenset({
     EXCHANGE_COUNT,
     EXCHANGE_BYTES,
@@ -211,6 +303,7 @@ ALL_COUNTERS = frozenset({
     FABRIC_PROBE_RUNS,
     FABRIC_CACHE_HIT,
     FABRIC_CACHE_MISS,
+    *PHASE_SERIES.values(),
 })
 
 # --- gauges (last-value) -----------------------------------------------------
@@ -248,9 +341,6 @@ STEP_SECONDS = "domain.step.seconds"
 EXCHANGE_SECONDS = "domain.exchange.seconds"
 #: wall seconds per ``swap()`` call
 SWAP_SECONDS = "domain.swap.seconds"
-#: exchange trace+compile seconds at ``realize()`` (the CUDA-Graph-capture
-#: analog, DomainStats.time_create)
-COMPILE_SECONDS = "domain.compile.seconds"
 #: degradation-ladder rung build (trace/compile) seconds
 LADDER_BUILD_SECONDS = "resilience.ladder.build_seconds"
 #: wall seconds per checkpoint commit (gather + write + fsync + rename)
@@ -288,7 +378,6 @@ ALL_HISTOGRAMS = frozenset({
     STEP_SECONDS,
     EXCHANGE_SECONDS,
     SWAP_SECONDS,
-    COMPILE_SECONDS,
     LADDER_BUILD_SECONDS,
     CHECKPOINT_SAVE_SECONDS,
     CHECKPOINT_RESTORE_SECONDS,

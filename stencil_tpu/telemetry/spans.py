@@ -110,7 +110,10 @@ def _profiler_annotation(name: str, args: dict):
 
 class SpanRecorder:
     """Thread-safe recorder of completed spans with a per-thread name stack
-    (so a span knows its parent at record time)."""
+    (so a span knows its parent at record time) and a per-thread PHASE stack
+    (so an event knows which phase of the program it fell in).  The name
+    stack moves only while the recorder is on; the phase stack is always
+    live: two list operations per ``span(total=)``."""
 
     def __init__(self):
         self.epoch = time.perf_counter()
@@ -139,6 +142,26 @@ class SpanRecorder:
 
     def pop(self) -> None:
         s = self._stack()
+        if s:
+            s.pop()
+
+    # --- the per-thread phase stack (always live) ------------------------------
+    def _phases(self) -> list:
+        s = getattr(self._tls, "phases", None)
+        if s is None:
+            s = self._tls.phases = []
+        return s
+
+    def phase(self) -> Optional[str]:
+        """The innermost open phase on this thread (None: no program span)."""
+        s = self._phases()
+        return s[-1] if s else None
+
+    def push_phase(self, phase: str) -> None:
+        self._phases().append(phase)
+
+    def pop_phase(self) -> None:
+        s = self._phases()
         if s:
             s.pop()
 

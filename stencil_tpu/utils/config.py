@@ -142,7 +142,11 @@ def apply_compile_cache() -> str:
     to the live config too (the cache initializes lazily at first compile,
     so post-import application is still "before first backend use").  jax's
     own thresholds decide which entries are worth keeping.  Returns the
-    directory in use."""
+    directory in use.
+
+    It is also where the set-up account starts listening to jax's own
+    compile and cache events (``telemetry.watch_jax``: only if jax is
+    already imported; ``realize()`` makes sure later)."""
     path = compile_cache_dir()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.environ["JAX_COMPILATION_CACHE_DIR"] = path
@@ -152,6 +156,9 @@ def apply_compile_cache() -> str:
             import jax
 
             jax.config.update("jax_compilation_cache_dir", path)
+    from stencil_tpu import telemetry
+
+    telemetry.watch_jax()
     return path
 
 

@@ -11,7 +11,8 @@ with telemetry.annotate(tm.SPAN_OVERLAP_INTERIOR):
     pass
 with telemetry.span(tm.SPAN_STEP, histogram=tm.STEP_SECONDS):
     pass
-telemetry.record_span(tm.SPAN_EXCHANGE, 0.0, 0.25)
+with telemetry.span(tm.SPAN_REALIZE, total=tm.PHASE_REALIZE):
+    pass
 
 with jax.named_scope(tm.SPAN_EXCHANGE_Z_LOW):  # a registered literal form
     pass

@@ -98,7 +98,10 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
         assert m["reducer"] in ("named_share", "named_roofline_hbm", "span_percentile", "span_count"), name
         assert m["cells"] == ["elastic-*"] and m["moves"] == "mcells_per_s_chip", name
         assert declared[name]["workloads"] == ["elastic-so8-600.bulk"], name
-    for name in set(declared) - new - plane - staged - (ragged - {"collective_pct.ragged"}):
+    # PR 35's: the set-up account's seven, for every cell (tests/test_bench_setup.py holds them)
+    setup = {n for n in declared if n.startswith("setup_") or n == "steady_compiles"}
+    assert len(setup) == 7
+    for name in set(declared) - new - plane - staged - setup - (ragged - {"collective_pct.ragged"}):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
                                                "trace_roofline_hbm", "trace_idle"), name

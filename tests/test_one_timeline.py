@@ -202,15 +202,30 @@ def test_span_lands_on_the_profilers_host_plane_with_its_args(profiler_session):
 def test_hot_path_opens_spans_and_never_syncs(session, monkeypatch, request):
     """``run_step`` / ``exchange`` / ``swap`` with STENCIL_TELEMETRY unset
     and exchange-stats off: every call is a span, none calls
-    ``block_until_ready`` (the two-deep dispatch pipeline stays two deep)."""
+    ``block_until_ready`` (the two-deep dispatch pipeline stays two deep) and,
+    steady, none reads a clock: the set-up account (PR 35) times the FIRST
+    call of a program and only marks the later ones."""
+    import time
+
     dd = _exchange_domain()
     step = dd.make_step(lambda views, info: {k: v.sh(0, 0, 0) * 1.0 for k, v in views.items()})
-    dd.run_step(step, 2)  # compile outside the counted stretch
+    dd.run_step(step, 4)  # the first dispatch of each program (compile, first=1): outside the counted stretch
     dd.exchange()
     jax.block_until_ready(dd._curr)
     stop = request.getfixturevalue("profiler_session") if session == "live_session" else None
 
-    syncs, opened = [], []
+    syncs, opened, clock_reads = [], [], []
+
+    class CountingClock:  # ``time`` as the program's modules see it
+        def __getattr__(self, name):
+            if name == "perf_counter":
+                clock_reads.append(name)
+            return getattr(time, name)
+
+    import stencil_tpu.domain as domain_module
+
+    monkeypatch.setattr(telemetry, "time", CountingClock())
+    monkeypatch.setattr(domain_module, "time", CountingClock())
     monkeypatch.setattr(DistributedDomain, "block_until_ready", lambda self: syncs.append("dd"))
     real_jax_sync = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready", lambda x: (syncs.append("jax"), real_jax_sync(x))[1])
@@ -226,6 +241,7 @@ def test_hot_path_opens_spans_and_never_syncs(session, monkeypatch, request):
         dd.swap()
     monkeypatch.undo()
     assert syncs == [], syncs
+    assert clock_reads == [], clock_reads
     names = [n for n, _ in opened]
     assert names.count(tm.SPAN_STEP) == 3 and names.count(tm.SPAN_EXCHANGE) == 3 and names.count(tm.SPAN_SWAP) == 6
     step_args = [a for n, a in opened if n == tm.SPAN_STEP]

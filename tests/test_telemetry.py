@@ -200,19 +200,6 @@ class TestSpans:
         assert telemetry.dump_chrome_trace(str(tmp_path / "t.json")) is None
         assert list(tmp_path.iterdir()) == []
 
-    def test_record_span_post_hoc(self, tmp_path):
-        import time
-
-        telemetry.enable(dir=str(tmp_path))
-        t0 = time.perf_counter()
-        telemetry.record_span(
-            names.SPAN_EXCHANGE, t0, 0.25, histogram=names.EXCHANGE_SECONDS
-        )
-        doc = json.loads(open(telemetry.dump_chrome_trace()).read())
-        assert doc["traceEvents"][0]["dur"] == pytest.approx(0.25e6)
-        hist = telemetry.snapshot()["histograms"][names.EXCHANGE_SECONDS]
-        assert hist["count"] == 1 and hist["max"] == 0.25
-
     def test_counter_tracks_in_chrome_trace(self, tmp_path):
         """The metrics registry rides the trace as Chrome counter-track
         ("ph":"C") events sampled at span records — Perfetto shows

@@ -110,6 +110,7 @@ INPLACE_PASSES = {
         "inplace_order_plane_writers_clean.py",
         "inplace_order_plane_lagged_clean.py",
         "inplace_order_plane_wrapped_clean.py",
+        "inplace_order_plane_renamed_clean.py",
     ),
     "stream_wavefront_pass": ("inplace_order_wavefront_clean.py",),
 }

@@ -17,14 +17,19 @@ What it asks of the runtime, unlike ``Jacobi3D`` and ``AstarothSim``:
   distance 4 is read, so the stream engine runs its plane route (one level
   per pass over a ``2r``-deep ring, an exchange every step);
 * two time levels through a contract that knows one: the kernel returns
-  ``u <- u+`` and ``u_prev <- u`` in the same pass;
+  ``u <- u+`` and ``u_prev <- u`` in the same pass, the second as ``u``'s
+  centre plane and nothing else -- which the engine reads off the kernel's
+  jaxpr: it writes ``u+`` into ``u_prev``'s block and hands ``u``'s old
+  array on under the name ``u_prev``, a swap of two handles where Devito
+  rotates its time buffers by index (the kernel stays as written; a
+  ``uc + 0.0`` or a masked copy here would be written as a copy again);
 * model fields ``m`` and ``damp`` that are read and never written, and at
   the centre only: like ``u_prev`` they stay out of the step's exchange
   (the engine exchanges what the kernel reads off-centre, which is ``u``
   alone), and they are inputs of the pass and nothing else (it writes what
-  the kernel returns, ``u`` and ``u_prev``) -- ``ops/stream.py
-  trace_plane_kernel`` learns both from this kernel, docs/acoustic.md says
-  what a step moves;
+  the kernel returns with a value of its own, ``u``) -- ``ops/stream.py
+  trace_plane_kernel`` learns all three from this kernel, docs/acoustic.md
+  says what a step moves;
 * a Dirichlet edge on a periodic runtime: the ``FRAME`` outer cells are
   pinned to zero BY THE KERNEL from ``info.coords()`` -- no model field
   could do it (``u+`` has no coefficient that a zero would null), and no
@@ -170,7 +175,8 @@ class AcousticWave:
         for c, n in zip(info.coords(), (g.x, g.y, g.z)):
             d = jnp.minimum(c - FRAME, (n - FRAME - 1) - c)
             edge = d if edge is None else jnp.minimum(edge, d)
-        # u_prev <- u needs no pin: u is already 0 in the frame
+        # u_prev <- u needs no pin: u is already 0 in the frame -- and must get
+        # none: returned as the centre plane itself it is a rename, not a copy
         return {"u": jnp.where(edge >= 0, new, 0.0), "u_prev": uc}
 
     def step(self, steps: int = 1) -> None:

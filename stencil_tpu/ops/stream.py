@@ -18,7 +18,9 @@ Two routes, chosen by ``make_stream_step``:
   kernel reads off-centre (the others' shells are read by nothing), then
   stream planes, a ``2r``-deep ring (``r`` = the kernel's declared x read
   distance) for every quantity read off-centre ALONG X and a lagged fetch
-  for the others, writing back only the quantities the kernel returns.
+  for the others, writing back only the quantities the kernel returns
+  with a value of their own (one returned as another's centre plane -- a
+  leapfrog's ``u_prev <- u`` -- swaps handles with it instead).
   On a y or z axis the mesh does not split there is nothing to exchange:
   the pass fills that halo of every plane it loads from the plane itself,
   in VMEM (``pass_wrap_fills``), and the exchange sweeps the other axes.
@@ -273,6 +275,9 @@ def stream_plane_pass(
     wrap_fills: Sequence[Tuple[int, int, int, int]] = (),  # (axis, destination,
     # source, width) of the y / z halo fills the pass makes itself, in VMEM
     # (pass_wrap_fills): the self-wrap of an axis the mesh does not split
+    renames: Sequence[Tuple[str, str]] = (),  # ``(p, q)``: writer ``q``'s new
+    # value lands in ``p``'s buffer and ``p`` comes back as raw ``q``
+    # (trace_plane_kernel): a time level renamed instead of copied
 ) -> List[jax.Array]:
     """ONE kernel level over shell-carrying blocks, streaming x-planes with a
     ``2r``-deep ring per quantity read off-centre along x; shell planes and
@@ -336,6 +341,31 @@ def stream_plane_pass(
     quantity stays an output whatever ``writers`` says (the same exception
     ``plan_plane_stages`` makes for the readers).
 
+    With ``renames`` an output the kernel would return as another writer's
+    centre plane, unchanged, is not written at all (``trace_plane_kernel``
+    has the rule and where it does not apply; never with ``fused_shell``).
+    For a pair ``(p, q)`` -- ``u_prev <- u`` -- ``p`` is no writer, and the
+    output of ``q`` is what it always was, cell for cell (the kernel's value
+    inside, ``q``'s own shell planes and in-plane shell ring passed through),
+    but it has its HOME in ``p``'s block: under ``alias`` it aliases raw
+    ``p`` (operand ``1 + p``), not raw ``q``.  The returned list holds that
+    array under ``q`` and ``raws[q]`` ITSELF under ``p``: the two handles
+    swap, and the pass moves one array less than writing ``p`` does
+    (acoustic: reads 4, writes 1).  ``q`` comes back bitwise the array the
+    un-renamed pass returns, shell included; ``p`` on its interior (its
+    shell is now ``q``'s, as exchanged, where it was ``p``'s own stale one:
+    the exchange owns both).  In place stays safe for the same reason as
+    before, now for the pair (raw ``p``, output of ``q``): ``p`` is an
+    operand of the pass whether the kernel reads it or not, fetched lagged
+    (or ringed), so its plane ``j`` is read before grid step ``j + r``,
+    after which the output's plane ``j`` is flushed onto it; raw ``q`` is an
+    input only, nothing is flushed over it (``check_inplace_order`` judges
+    whatever pair the call carries).  A value the kernel returns for ``p``
+    in THIS trace is not looked at: the footprint trace proved it is ``q``'s
+    centre plane.  The caller must hand the handles on permuted -- a loop
+    that carries them pays whole-array copies unless a trip returns them to
+    their places (``_build_stream_step``).
+
     With ``alias`` a writer's output IS its raw block
     (``input_output_aliases`` maps operand ``1 + q`` — operand 0 is
     ``origin`` — to the writer's position among the outputs): a
@@ -378,6 +408,14 @@ def stream_plane_pass(
         wq = [q for q in range(nq) if names[q] in writers]
     if not wq:
         return list(raws)
+    # the quantity whose block a writer's output lives in (aliases, under
+    # ``alias``): its own, or the one whose name its old block takes
+    home = {q: q for q in wq}
+    for p_name, q_name in renames:
+        p, q = names.index(p_name), names.index(q_name)
+        assert fused_shell is None and q in home and p not in home, (renames, writers)
+        assert raws[p].dtype == raws[q].dtype, (p_name, q_name)
+        home[q] = p
     if rings is None or fused_shell is not None:
         ringed = list(range(nq))
     else:
@@ -479,7 +517,7 @@ def stream_plane_pass(
                 info = PlaneInfo(x_g, y_g, z_g, gsize, 1)
                 vals = kernel(views, info)
                 for q, name in enumerate(names):
-                    if name in vals and q not in out_refs:
+                    if name in vals and q not in out_refs and q not in home.values():
                         raise ValueError(
                             f"the kernel returns {name!r}, but its footprint "
                             f"trace did not (it saw {tuple(writers)}), so "
@@ -572,10 +610,10 @@ def stream_plane_pass(
         out_specs=out_specs if len(wq) > 1 else out_specs[0],
         out_shape=out_shape if len(wq) > 1 else out_shape[0],
         # operand 0 is origin; fused-shell side inputs sit after the raws,
-        # so the map is raw-q -> its place among the writers' outputs
-        # whatever rides in
+        # so the map is the raw block a writer's output lives in -> its
+        # place among the writers' outputs, whatever rides in
         input_output_aliases=(
-            {1 + q: k for k, q in enumerate(wq)} if alias else {}
+            {1 + home[q]: k for k, q in enumerate(wq)} if alias else {}
         ),
         scratch_shapes=[
             pltpu.VMEM((2 * r, Y, Z), raws[q].dtype) for q in ringed
@@ -585,6 +623,7 @@ def stream_plane_pass(
     )(*args)
     result = list(raws)  # a non-writer comes back as the array that went in
     for q, o in zip(wq, outs if len(wq) > 1 else [outs]):
+        result[home[q]] = raws[q]  # renamed: the handles swap (a no-op at home)
         result[q] = o
     return result
 
@@ -1220,6 +1259,8 @@ class PlaneTrace:
     x_radius: int
     closed: Optional[object]  # the ClosedJaxpr; None = the trace raised
     kernel: PlaneKernel  # the user's callable (run as is when ``closed`` is None)
+    renames: Tuple[Tuple[str, str], ...] = ()  # ``(p, q)``: output ``p`` IS the
+    # centre plane of ``q``, a writer with a value of its own (``_plane_renames``)
 
     def pruned(self, outputs: Sequence[str]):
         """``(kernel, reads, rings)`` of the pass that writes ``outputs``:
@@ -1267,6 +1308,8 @@ def trace_plane_kernel(
     x_radius: int,
     global_size: Dim3,
     interpret: bool = True,
+    storage: Optional[Sequence] = None,  # per quantity, the dtype its block is
+    # STORED in (the rename rule compares them); None = the planes' own
 ) -> PlaneTrace:
     """The footprint of a PLANE-route kernel: trace it ONCE, abstractly
     (``jax.make_jaxpr``, nothing runs), over ``PlaneView``s that record
@@ -1301,6 +1344,32 @@ def trace_plane_kernel(
     never returns, every raw cell out is the raw cell in, so the step keeps
     the input array and moves nothing — a coefficient or an older time level
     is then read once a step, not read and written.
+
+    Why an output that IS another quantity's centre plane need not be
+    written either (``PlaneTrace.renames``).  A leapfrog scheme returns
+    ``{"u": new, "u_prev": views["u"].center()}``: the second output is, value
+    for value, an array the pass has already loaded under another name.
+    Output ``p`` is a RENAME of ``q`` when its outvar in the jaxpr IS the
+    invar of ``q``'s centre plane (``d == r``; no arithmetic, no ``where``,
+    nothing in between: ``uc + 0.0`` or a masked copy is a value of its own
+    and is written as before), ``q`` is another quantity that the kernel also
+    returns with a value of its own (so after the step nothing else names
+    ``q``'s old array), and ``p`` and ``q`` are stored alike (dtype and plane
+    shape).  The pass then writes ``q``'s new value into ``p``'s buffer and
+    the step hands ``q``'s old array back under the name ``p``
+    (``stream_plane_pass(renames=)``): two handles swap, nothing is copied
+    (acoustic: 6 arrays through HBM a step -> 5).  A source is claimed once
+    (a second ``p2 <- q`` is written as before), and a chain ``p2 <- p <- q``
+    renames ``p <- q`` alone: ``p`` has no value of its own, so ``p2 <- p`` is
+    the copy it was.  ``q`` comes back bitwise what writing ``p`` gives on
+    every raw cell and ``p`` on every interior cell; ``p``'s shell is now
+    ``q``'s as the exchange left it where it was ``p``'s own stale one,
+    which the contract allows: the step marks its shells stale and the
+    exchange owns them.  Applied
+    where the stage runs ONE in-place pass of the plane route's default
+    schedule (``plan_plane_passes(rename=)``); not under ``overlap="split"``
+    (fresh outputs), not under ``halo="fused"`` (every quantity is written),
+    not in a stage cut into several passes, not when the trace failed.
 
     Fail closed: a trace that raises exchanges AND writes every quantity and
     runs the kernel as the user wrote it, every quantity ringed
@@ -1340,6 +1409,9 @@ def trace_plane_kernel(
             "exchanging and writing every quantity"
         )
         return PlaneTrace(names, names, names, r, None, kernel)
+    if storage is None:
+        storage = [p.dtype for p in planes]
+    stored = {nm: (jnp.dtype(d), p.shape) for nm, d, p in zip(names, storage, planes)}
     return PlaneTrace(
         names,
         tuple(nm for nm in names if nm in seen),
@@ -1347,7 +1419,30 @@ def trace_plane_kernel(
         r,
         closed,
         kernel,
+        _plane_renames(closed.jaxpr, names, tuple(returned), r, stored),
     )
+
+
+def _plane_renames(jaxpr, names, writers, x_radius: int, stored: dict):
+    """The ``(p, q)`` of ``trace_plane_kernel``'s rename rule, read off the
+    kernel's jaxpr (invars: three coordinates, then ``2r + 1`` window planes a
+    quantity; outvars: the writers in order): output ``p`` is ``q``'s centre
+    invar itself, ``q`` is a writer whose own output is no quantity's centre
+    plane, ``stored`` (dtype, plane shape) agree, and ``q`` is claimed once."""
+    w = 2 * x_radius + 1
+    centres = [(nm, jaxpr.invars[3 + q * w + x_radius]) for q, nm in enumerate(names)]
+    pure = {}  # output -> the quantity whose centre plane it is, unchanged
+    for p, var in zip(writers, jaxpr.outvars):
+        q = next((nm for nm, centre in centres if centre is var), None)
+        if q is not None:
+            pure[p] = q
+    pairs, claimed = [], set()
+    for p, q in pure.items():
+        if q != p and q in writers and q not in pure and q not in claimed:
+            if stored[p] == stored[q]:
+                claimed.add(q)
+                pairs.append((p, q))
+    return tuple(pairs)
 
 
 def plane_pass_vmem_bytes(
@@ -1366,11 +1461,12 @@ def plane_pass_vmem_bytes(
 
 
 def plan_plane_passes(
-    trace: PlaneTrace, plane_bytes: Dict[str, int], whole: bool = False
+    trace: PlaneTrace, plane_bytes: Dict[str, int], whole: bool = False,
+    rename: bool = False,
 ) -> List[dict]:
     """The passes of one stage over one group: ``[{"writes", "reads",
-    "rings", "vmem_bytes"}, ...]``, each a subset of the kernel's outputs
-    with the quantities THOSE outputs touch (``PlaneTrace.pruned``).
+    "rings", "renames", "vmem_bytes"}, ...]``, each a subset of the kernel's
+    outputs with the quantities THOSE outputs touch (``PlaneTrace.pruned``).
 
     Outputs join the current pass, in the order the kernel returns them,
     while the pass still fits the VMEM budget (``plane_pass_vmem_bytes``
@@ -1392,19 +1488,29 @@ def plan_plane_passes(
 
     ``whole`` keeps the stage in one pass over every quantity, every one
     ringed and written (``halo="fused"``, whose side buffers are
-    per-quantity operands of the pass)."""
+    per-quantity operands of the pass).
+
+    ``rename`` applies the rename rule (``trace_plane_kernel``) where the
+    stage came out as ONE pass: the outputs that are another writer's centre
+    plane leave ``writes`` and the pairs go under ``renames`` (``(p, q)``:
+    ``q``'s new value lands in ``p``'s buffer, so ``p`` stays among the
+    ``reads`` whether the kernel reads it or not).  The caller passes it for
+    the in-place default schedule only (``_build_stream_step``)."""
     budget = _vmem_budget()
 
-    def describe(outputs, whole=False):
+    def describe(outputs, whole=False, renames=()):
         if whole or trace.closed is None:
             reads = rings = writes = trace.names
         else:
             _, reads, rings = trace.pruned(outputs)
             writes = tuple(outputs)
+            homes = set(reads) | {p for p, _ in renames}
+            reads = tuple(nm for nm in trace.names if nm in homes)
         return {
             "writes": writes,
             "reads": reads,
             "rings": rings,
+            "renames": tuple(renames),
             "vmem_bytes": plane_pass_vmem_bytes(
                 plane_bytes, trace.x_radius, reads, rings, writes
             ),
@@ -1450,6 +1556,10 @@ def plan_plane_passes(
                 "of its own"
             )
         written |= set(p["writes"])
+    if rename and len(passes) == 1 and trace.renames:
+        renamed = {p for p, _ in trace.renames}
+        kept = [out for out in trace.writers if out not in renamed]
+        return [describe(kept, renames=trace.renames)]
     return passes
 
 
@@ -1693,19 +1803,22 @@ def _as_stages(kernel) -> Tuple[PlaneKernel, ...]:
 
 
 def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
-                      fused: bool = False) -> List[List[tuple]]:
+                      fused: bool = False, rename: bool = False) -> List[List[tuple]]:
     """Plan a PLANE-route step from its kernels' own footprints and write the
     plan back: ``plan["stages"]`` -- per stage its ``readers`` (the
     quantities its exchange fills) and its ``passes`` (``plan_plane_passes``:
-    writes, reads, rings, modeled VMEM bytes) -- and the step-wide unions
-    ``plan["halo_readers"]`` / ``plan["writers"]``.  Raises ``ValueError``
-    for a step that fits in no pass.  Returns, per stage, what the build
-    runs: ``[(pass kernel, reads, rings, writes), ...]`` (names).
+    writes, reads, rings, renames, modeled VMEM bytes) -- and the step-wide
+    unions ``plan["halo_readers"]`` / ``plan["writers"]`` /
+    ``plan["renamed"]`` (the quantities whose write became a rename).
+    Raises ``ValueError`` for a step that fits in no pass.  Returns, per
+    stage, what the build runs: ``[(pass kernel, reads, rings, writes,
+    renames), ...]`` (names).
 
     Every stage is traced once per group (``trace_plane_kernel``); a function
     of the kernels, as ``_sweep_kind`` is a function of the mesh: no option.
     Under ``fused`` every quantity rides the exchange and every pass is
-    whole (``plan_plane_passes``)."""
+    whole (``plan_plane_passes``); ``rename`` is the build's to pass, where
+    its passes run in place on the default schedule."""
     names = [h.name for h in dd._handles]
     raw = dd.local_spec().raw_size()
     f32_acc = any(dd.field_dtype(h) != h.dtype for h in dd._handles)
@@ -1725,12 +1838,15 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
         for g in _stream_groups(plan, len(names)):
             trace = trace_plane_kernel(
                 stage, [names[q] for q in g], [planes[q] for q in g], x_radius,
-                dd._size, interpret,
+                dd._size, interpret, [dd.field_dtype(dd._handles[q]) for q in g],
             )
             readers |= set(names) if fused else set(trace.readers)
-            for p in plan_plane_passes(trace, plane_bytes, whole=fused):
+            for p in plan_plane_passes(trace, plane_bytes, whole=fused, rename=rename):
                 passes.append(p)
-                runs.append((trace.pruned(p["writes"])[0], p["reads"], p["rings"], p["writes"]))
+                runs.append((
+                    trace.pruned(p["writes"])[0], p["reads"], p["rings"], p["writes"],
+                    p["renames"],
+                ))
         described.append({
             "readers": tuple(nm for nm in names if nm in readers),
             "passes": tuple(passes),
@@ -1740,10 +1856,29 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     for key, of in (
         ("halo_readers", lambda st: st["readers"]),
         ("writers", lambda st: [w for p in st["passes"] for w in p["writes"]]),
+        ("renamed", lambda st: [a for p in st["passes"] for a, _ in p["renames"]]),
     ):
         union = {nm for st in described for nm in of(st)}
         plan[key] = tuple(nm for nm in names if nm in union)
     return built
+
+
+def _carry_period(names: Sequence[str], stages) -> int:
+    """After how many steps a step loop's carry is back in its own buffers:
+    the order of the permutation one step's renames (``plan["stages"]``)
+    make of the quantities' blocks -- 1 with none, 2 for one or more disjoint
+    swaps such as ``u_prev <- u``."""
+    index = {name: q for q, name in enumerate(names)}
+    home = list(range(len(names)))
+    once = list(home)  # once[q]: the buffer q's value is in after a step
+    for st in stages:
+        for p in st["passes"]:
+            for a, b in p["renames"]:
+                once[index[a]], once[index[b]] = once[index[b]], once[index[a]]
+    period, now = 1, once
+    while now != home:
+        period, now = period + 1, [once[b] for b in now]
+    return period
 
 
 def pass_wrap_fills(dd, exch_route: str) -> Tuple[str, tuple]:
@@ -1993,11 +2128,19 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     # mode's side buffers and the split schedule's exterior bands keep the
     # exchange they have
     plan["pass_wrap_axes"], wrap_fills = "", ()
+    # the quantities whose write became a rename (trace_plane_kernel),
+    # written back like the three above (domain.step's ``renamed``): on the
+    # same schedule, where the passes run in place
+    plan["renamed"] = ()
     if plan["route"] == "wrap":
         plan["halo_readers"] = ()
     elif plan["route"] == "plane":
-        stage_runs = plan_plane_stages(dd, stages, x_radius, plan, interpret, fused)
-        if not fused and not split:
+        default = not fused and not split
+        stage_runs = plan_plane_stages(
+            dd, stages, x_radius, plan, interpret, fused,
+            rename=default and _plan_passes_in_place(plan),
+        )
+        if default:
             plan["pass_wrap_axes"], wrap_fills = pass_wrap_fills(dd, exch_route)
     else:
         plan["halo_readers"] = tuple(names)
@@ -2073,7 +2216,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
             """Stage ``k``'s passes in order, each over the quantities it
             touches; a later pass sees what an earlier one wrote."""
             out = list(bs)
-            for pass_kernel, reads, rings, writes in stage_runs[k]:
+            for pass_kernel, reads, rings, writes, renames in stage_runs[k]:
                 g = [index[name] for name in reads]
                 fs = None
                 if fused_bufs is not None:
@@ -2090,6 +2233,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                         interpret=interpret, fused_shell=fs,
                         f32_accumulate=f32_acc, halo_readers=stage_readers[k],
                         writers=writes, rings=rings, wrap_fills=wrap_fills,
+                        renames=renames,
                     )
                 for q, o in zip(g, outs):
                     out[q] = o
@@ -2141,8 +2285,23 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
             def stage(k, bs, origin):
                 return plane_passes(k, exchange_readers(bs, k), origin)
 
+        # A renaming pass hands its blocks on PERMUTED, and a loop body that
+        # returns its carry permuted makes XLA copy whole arrays to put each
+        # value back where the carry lives (three ``copy`` a trip for a
+        # two-array leapfrog on the CPU compiler; PR 28 met the same copy,
+        # 39% of busy).  So a trip runs as many steps as the permutation's
+        # period -- two for ``u_prev <- u`` -- with the handles swapped in
+        # Python between them: the carry comes back in its own places and
+        # every pass writes in place.  The remainder runs unrolled behind the
+        # loop; with ``steps`` no multiple of the period the program's
+        # outputs are permuted against its donated inputs and XLA may copy at
+        # the program's edge, once a DISPATCH: correct, and dearer by up to a
+        # step's traffic -- keep ``steps`` a multiple of the period (1 with no
+        # rename: the loop every other plan had).
+        period = _carry_period(names, plan["stages"])
+
         def per_shard(steps, *blocks):
-            def body(_, bs):
+            def one(bs):
                 origin = origin_of()
                 bs = list(bs)
                 for k in range(len(stages)):
@@ -2150,7 +2309,18 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                         bs = stage(k, bs, origin)
                 return tuple(bs)
 
-            return lax.fori_loop(0, steps, body, tuple(blocks))
+            def body(_, bs):
+                for _ in range(period):
+                    bs = one(bs)
+                return bs
+
+            trips, rem = divmod(steps, period)
+            bs = tuple(blocks)
+            if trips:
+                bs = lax.fori_loop(0, trips, body, bs)
+            for _ in range(rem):
+                bs = one(bs)
+            return bs
 
     else:
         m = plan["m"]
@@ -2357,7 +2527,14 @@ def make_stream_step(
     all of them gives.  The same trace learns which quantities the kernel
     RETURNS (``plan["writers"]``, ``domain.step``'s ``written``): the others
     are inputs of the pass and nothing else, read once a step and never
-    written back; which quantities each output TOUCHES, so that a stage too
+    written back; which returned quantities are RENAMES (``plan["renamed"]``,
+    ``domain.step``'s ``renamed``: an output that is another writer's centre
+    plane takes that writer's old array instead of a copy of it, where the
+    passes run in place on the default schedule; the step loop then runs the
+    permutation's period a trip, and ``steps`` that is no multiple of it --
+    an odd count for one swap -- costs up to three whole-array copies and one
+    block of temporaries at the program's edge, once a dispatch); which
+    quantities each output TOUCHES, so that a stage too
     wide for one pass's VMEM runs as several, each over its own quantities
     (``plan["stages"]``; a step that fits in no pass raises here); and which
     are read off-centre ALONG X — the only ones that keep a VMEM ring.  The
@@ -2616,6 +2793,10 @@ def make_stream_step(
             # quantities the passes write: what the kernel returns on the
             # plane route (plan_plane_stages), every one elsewhere
             "written": len(plan_now.get("writers", ())),
+            # quantities whose write became a rename: an output that is
+            # another writer's centre plane swaps handles with it instead of
+            # being copied (trace_plane_kernel); they are not ``written``
+            "renamed": len(plan_now.get("renamed", ())),
             # the axes whose halo the plane passes fill themselves in VMEM,
             # so that the exchange does not sweep them (pass_wrap_fills): one
             # value for every stage, a function of the mesh and the domain
@@ -2633,6 +2814,7 @@ def make_stream_step(
             args["passes"] = sum(len(st["passes"]) for st in per_stage)
             args["exchanged"] = each(lambda st: len(st["readers"]))
             args["written"] = each(lambda st: sum(len(p["writes"]) for p in st["passes"]))
+            args["renamed"] = each(lambda st: sum(len(p["renames"]) for p in st["passes"]))
             args["aliased"] = each(
                 lambda st: len({q for p in st["passes"] for q in p["reads"]}) if in_place else 0
             )

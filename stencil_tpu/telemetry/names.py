@@ -412,15 +412,20 @@ ALL_HISTOGRAMS = frozenset({
 #: ``halo="fused"``), every one on the wavefront route, 0 on the wrap route,
 #: written = quantities that are outputs of the passes: on the plane route
 #: those the kernel returns (the same trace, ``plan["writers"]``; all of them
-#: under ``halo="fused"``), every one on the other routes, wrapped = the axes
+#: under ``halo="fused"``), every one on the other routes, renamed = the
+#: quantities whose write became a rename: an output that IS another writer's
+#: centre plane (a leapfrog's ``u_prev <- u``) swaps handles with it and is
+#: not ``written`` (``ops/stream.trace_plane_kernel``, ``plan["renamed"]``:
+#: acoustic 1; 0 wherever the passes do not run in place on the plane route's
+#: default schedule), wrapped = the axes
 #: whose halo the plane passes fill themselves in VMEM, so that the step's
 #: exchange does not sweep them (``ops/stream.pass_wrap_fills``: the y / z
 #: axes the mesh does not split, "yz" on one chip, "z" on mesh [2,2,1], ""
 #: off the plane route's default schedule and wherever that axis's sweep is
 #: not the self-wrap); a STAGED step (``make_step`` with a sequence of
-#: kernels) adds stages and passes, and says exchanged / written / aliased
-#: PER STAGE, in order: "6/3", "3/6", "11/12" (``wrapped`` is one value: a
-#: function of the mesh, the same for every stage)]
+#: kernels) adds stages and passes, and says exchanged / written / renamed /
+#: aliased PER STAGE, in order: "6/3", "3/6", "0/0", "11/12" (``wrapped`` is
+#: one value: a function of the mesh, the same for every stage)]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes

@@ -97,11 +97,13 @@ def test_route_is_plane_and_the_span_says_so(blend, wrapped, monkeypatch):
     assert sim._step._span_args() == {
         "route": "plane", "x_radius": 4, "grouping": "joint", "streamed": 4,
         "aliased": 4, "exchanged": 1,  # u alone is read off-centre (ISSUE 30)
-        "written": 2,  # m and damp are never returned: inputs only (ISSUE 32)
+        "written": 1,  # m and damp are never returned: inputs only (ISSUE 32)
+        "renamed": 1,  # and u_prev <- u swaps two handles: nothing to write (ISSUE 36)
         "wrapped": wrapped,
     }
     assert plan["halo_readers"] == ("u",), plan
-    assert plan["writers"] == ("u", "u_prev"), plan
+    assert plan["writers"] == ("u",) and plan["renamed"] == ("u_prev",), plan
+    assert plan["stages"][0]["passes"][0]["renames"] == (("u_prev", "u"),), plan
     assert plan["pass_wrap_axes"] == wrapped, plan
     seen = []
     real = telemetry.span
@@ -115,7 +117,8 @@ def test_route_is_plane_and_the_span_says_so(blend, wrapped, monkeypatch):
         sim.step(2)
     (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
     assert kw["label"] == "acoustic" and kw["steps"] == 2 and kw["route"] == "plane"
-    assert (kw["streamed"], kw["aliased"], kw["exchanged"], kw["written"]) == (4, 4, 1, 2)
+    assert (kw["streamed"], kw["aliased"], kw["exchanged"], kw["written"]) == (4, 4, 1, 1)
+    assert kw["renamed"] == 1
     assert kw["wrapped"] == wrapped
 
 
@@ -141,7 +144,9 @@ def test_the_step_program_exchanges_u_alone(monkeypatch):
     device is a self-wrap): per step ONE ``stream_plane_pass`` and ONE Pallas
     call under an ``exchange.*`` scope, the x wrap of ``u`` -- the y and z
     wraps ride in the pass (ISSUE 34: 4 Pallas calls a step -> 2, no
-    ``blend_slab`` left; three wraps before, twelve before ISSUE 30)."""
+    ``blend_slab`` left; three wraps before, twelve before ISSUE 30).  Five
+    steps trace as THREE: a loop trip of two (``u_prev <- u`` is a rename, so
+    the carry is back in place every second step: ISSUE 36) and one behind it."""
     from stencil_tpu.analysis import jaxpr as jx
 
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
@@ -150,8 +155,10 @@ def test_the_step_program_exchanges_u_alone(monkeypatch):
     calls = [e for e in jx.iter_eqns(closed) if e.primitive.name == "pallas_call"]
     passes = [e for e in calls if e.params.get("name") == tm.KERNEL_STREAM_PLANE_PASS]
     wraps = [jx.name_stack_str(e) for e in calls if "exchange." in jx.name_stack_str(e)]
-    assert len(passes) == 1 and len(calls) == 2, [e.params.get("name") for e in calls]
-    assert wraps == ["exchange.x/exchange.x.wrap/blend_planes"], wraps
+    assert len(passes) == 3 and len(calls) == 6, [e.params.get("name") for e in calls]
+    assert wraps == ["exchange.x/exchange.x.wrap/blend_planes"] * 3, wraps
+    whiles = [e for e in jx.iter_eqns(closed) if e.primitive.name in ("while", "scan")]
+    assert len(whiles) == 1  # two trips of two steps, then the fifth step
     assert not [e for e in jx.iter_eqns(closed) if e.primitive.name == "ppermute"]
     assert sim._step._stream_plan["pass_wrap_axes"] == "yz"
 
@@ -171,23 +178,21 @@ def _plane_pass_aliases(fn, curr):
 
 
 def test_the_step_program_says_the_pass_is_in_place():
-    """The step as built: ONE plane pass over the four raw blocks with TWO
-    outputs, ``u`` and ``u_prev``, each aliased onto its raw block (operand 0
-    is ``origin``) -- ``m`` and ``damp`` are inputs and nothing else (ISSUE
-    32); the same plan forced off carries no alias, and says so in the plan
-    and on the span."""
+    """The step as built: ONE plane pass over the four raw blocks with ONE
+    output, the new ``u``, aliased onto raw ``u_prev`` (operand 2; operand 0
+    is ``origin``): ``u_prev <- u`` is a rename (ISSUE 36), ``m`` and
+    ``damp`` are inputs and nothing else (ISSUE 32); the same plan forced off
+    carries no alias and no rename, and says so in the plan and on the span."""
     from stencil_tpu.ops import stream as sm
 
     sim = _sim("pallas")
     (call,) = _plane_passes(sim._step._resilience.built(), sim.dd._curr)
-    assert len(call.outvars) == 2 and len(call.invars) == 1 + 4, call
-    assert _plane_pass_aliases(sim._step._resilience.built(), sim.dd._curr) == [
-        ((1, 0), (2, 1))
-    ]
+    assert len(call.outvars) == 1 and len(call.invars) == 1 + 4, call
+    assert _plane_pass_aliases(sim._step._resilience.built(), sim.dd._curr) == [((2, 0),)]
     plan = dict(sim._step._stream_plan, alias=False, alias_forced=True)
     off = sm._build_stream_step(sim.dd, sim._kernel, ref.RADIUS, plan, interpret=True)
     assert _plane_pass_aliases(off, sim.dd._curr) == [()]
-    assert plan["alias"] is False
+    assert plan["alias"] is False and plan["renamed"] == () and plan["writers"] == ("u", "u_prev")
     # the split schedule keeps fresh outputs whatever the plan resolves: the
     # interior pass and the exchange both read the pre-exchange blocks
     plan = dict(sim._step._stream_plan, overlap="split", overlap_forced=True)
@@ -196,7 +201,7 @@ def test_the_step_program_says_the_pass_is_in_place():
     assert len(passes) == 7 and set(passes) == {()}, passes  # interior + six bands
     assert {len(e.outvars) for e in _plane_passes(split, sim.dd._curr)} == {2}
     assert plan["overlap"] == "split" and plan["alias"] is True
-    assert sm._plan_passes_in_place(plan) is False
+    assert sm._plan_passes_in_place(plan) is False and plan["renamed"] == ()
 
 
 def test_plane_pass_sits_under_its_scope():
@@ -270,3 +275,228 @@ def test_driver_runs_on_the_cpu(capsys):
     assert rc == 0
     row = capsys.readouterr().out.strip().splitlines()[-1].split(",")
     assert row[0] == "acoustic" and row[3:7] == ["8", "8", "8", "4"]
+
+
+# --- u_prev <- u is a rename (ISSUE 36) ----------------------------------------
+#
+# The kernel returns ``u_prev`` as ``u``'s centre plane, so the pass writes
+# ``u`` alone, into ``u_prev``'s block, and the step swaps the two handles
+# (ops/stream.py trace_plane_kernel).  Held to the same model with the rule
+# off (the parent's program) bit for bit, and to the plain reference within
+# ``ATOL`` (the two compilers round differently, see the header: bitwise
+# against the reference is not to be had on the CPU).
+
+
+def _renames_off(monkeypatch):
+    from test_plane_stencil import _renames_off as off
+
+    off(monkeypatch)
+
+
+def _mesh_sim(mesh):
+    sim = AcousticWave(N, N, N, nbl=NBL, interpret=True, seed_words=WORDS,
+                       devices=jax.devices()[: int(np.prod(mesh))])
+    sim.dd.set_partition(*mesh)
+    sim.realize()
+    return sim
+
+
+@pytest.mark.parametrize("mesh", [(1, 1, 1), (2, 1, 1), (2, 2, 1)], ids=lambda m: "x".join(map(str, m)))
+@pytest.mark.parametrize("steps", [1, 2, 3, 8])
+def test_the_renamed_step_is_bitwise_the_copying_one(steps, mesh, monkeypatch):
+    """``steps`` in one dispatch and again in a second one (an odd count hands
+    the dispatch's outputs on permuted against its donated inputs): every
+    interior cell of ``u`` AND ``u_prev`` bitwise the rule-off program's and
+    within ``ATOL`` of the plain reference; after ``dd.exchange()`` every raw
+    cell of every quantity bitwise too."""
+
+    def run():
+        sim = _mesh_sim(mesh)
+        plan = sim._step._stream_plan
+        seen = []
+        for _ in range(2):
+            sim.step(steps)
+            seen.append({q: sim.field(q) for q in ("u", "u_prev")})
+        sim.dd.exchange()
+        return seen, {q: np.asarray(sim.dd._curr[q]) for q in QUANTITIES}, plan, sim.grid
+
+    seen, raws, plan, grid = run()
+    assert plan["renamed"] == ("u_prev",) and plan["writers"] == ("u",), plan
+    _renames_off(monkeypatch)
+    seen_off, raws_off, plan_off, _ = run()
+    assert plan_off["renamed"] == () and plan_off["writers"] == ("u", "u_prev"), plan_off
+    for k, (got, want) in enumerate(zip(seen, seen_off)):
+        u, u_prev, _ = _reference(grid, (k + 1) * steps)
+        for q, r in (("u", u), ("u_prev", u_prev)):
+            np.testing.assert_array_equal(got[q], want[q], err_msg=f"{q} after dispatch {k}")
+            assert float(np.max(np.abs(got[q] - r))) <= ATOL, (q, k)
+    for q in QUANTITIES:
+        np.testing.assert_array_equal(raws[q], raws_off[q], err_msg=q)
+
+
+def _loop_and_body(closed):
+    """The step loop of a traced stream step and its body's jaxpr, with the
+    body's carried invars and outvars (a static trip count traces as a
+    ``scan``: constants first, then the carry)."""
+    from stencil_tpu.analysis import jaxpr as jx
+
+    (loop,) = [e for e in jx.iter_eqns(closed) if e.primitive.name == "scan"]
+    body = loop.params["jaxpr"].jaxpr
+    return loop, body, body.invars[loop.params["num_consts"]:], body.outvars
+
+
+def _home_of(body, var):
+    """Follow ``var`` back through the in-place Pallas calls that made it (an
+    output IS the operand it aliases) to the value whose buffer it lives in."""
+    producers = {id(o): e for e in body.eqns for o in e.outvars}
+    while id(var) in producers:
+        eqn = producers[id(var)]
+        if eqn.primitive.name != "pallas_call":
+            return None  # a fresh buffer: no home among the carry
+        k = [id(o) for o in eqn.outvars].index(id(var))
+        src = [i for i, o in eqn.params["input_output_aliases"] if o == k]
+        if not src:
+            return None
+        var = eqn.invars[src[0]]
+    return var
+
+
+@pytest.mark.parametrize("steps,trips,behind", [(8, 4, 0), (9, 4, 1), (1, 0, 1)])
+def test_a_loop_trip_returns_every_block_to_its_place(steps, trips, behind, monkeypatch):
+    """The step loop as the chip runs it (blend kernels on: the x wrap is an
+    in-place kernel): a trip holds TWO steps, and followed back through the
+    in-place calls every carried block comes out of the trip in the buffer it
+    went in as -- so XLA has nothing to copy (a body that returned its carry
+    permuted made it copy three whole arrays a trip; PERF.md, PR 36).  An odd
+    count runs its last step behind the loop."""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    sim = _sim("pallas")
+    closed = jax.make_jaxpr(sim._step._resilience.built(), static_argnums=1)(sim.dd._curr, steps)
+    from test_plane_stencil import _pass_calls
+
+    assert len(_pass_calls(closed)) == (2 if trips else 0) + behind
+    if not trips:
+        return
+    loop, body, carried, out = _loop_and_body(closed)
+    assert loop.params["length"] == trips
+    blocks = [(i, o) for i, o in zip(carried, out) if getattr(i.aval, "ndim", 0) == 3]
+    assert len(blocks) == 2  # u and u_prev: m and damp never change and ride as constants
+    for i, o in blocks:
+        assert _home_of(body, o) is i
+
+
+def _leapfrog_program(steps, period=None, monkeypatch=None):
+    """A cellwise two-level leapfrog through the REAL step builder, its plane
+    pass stood in for by plain ``jnp`` over whole blocks -- what the CPU
+    compiler can run in place, as the chip runs the aliased Pallas call (the
+    interpreted call lowers to loops that copy blocks whatever the program
+    around them does, so its CPU HLO says nothing)."""
+    import jax.numpy as jnp
+
+    from test_plane_stencil import _plane_domain
+
+    from stencil_tpu.ops import stream as sm
+
+    def kernel(views, info):
+        u = views["u"].center()
+        return {"u": 1.5 * u - 0.5 * views["v"].center(), "v": u}
+
+    def stand_in(pass_kernel, names, raws, *a, writers=None, renames=(), **kw):
+        views = {nm: sm.PlaneView((b,), None) for nm, b in zip(names, raws)}
+        vals = pass_kernel(views, sm.PlaneInfo(None, None, None, None, 1))  # coordinates unread
+        out = list(raws)
+        for p, q in renames:
+            out[names.index(p)] = raws[names.index(q)]
+        for nm, v in vals.items():
+            out[names.index(nm)] = v
+        return out
+
+    monkeypatch.setattr(sm, "stream_plane_pass", stand_in)
+    if period is not None:
+        monkeypatch.setattr(sm, "_carry_period", lambda names, stages: period)
+    dd, hs = _plane_domain(["u", "v"], 1, 1, extent=(32, 32, 32))
+    plan = dict(sm.plan_stream(dd, 1, "plane", False))
+    step = sm._build_stream_step(dd, kernel, 1, plan, interpret=True)
+    assert plan["renamed"] == ("v",) and plan["halo_readers"] == (), plan
+    want = {h.name: dd.quantity_to_host(h) for h in hs}
+    for _ in range(steps):
+        want = {"u": 1.5 * want["u"] - 0.5 * want["v"], "v": want["u"]}
+    text = step.lower(dd._curr, steps).compile().as_text()
+    dd.run_step(step, steps)
+    for h in hs:
+        np.testing.assert_allclose(dd.quantity_to_host(h), want[h.name], rtol=1e-6, atol=1e-6)
+    return text
+
+
+def _block_copies_in_loops(text, shape="f32[34,34,34]"):
+    """``copy`` ops of a whole block inside the computations a ``while`` runs."""
+    import re
+
+    comps = dict(re.findall(r"\n(?:ENTRY )?%?([\w.\-]+) \([^\n]*\) -> [^\n]*\{\n(.*?)\n\}", text, re.S))
+    bodies = set(re.findall(r"\bwhile\([^\n]*body=%?([\w.\-]+)", text))
+    assert bodies, "the step loop is gone"
+    seen, todo = set(), list(bodies)
+    while todo:  # the body and whatever it calls
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        todo += re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", comps[name])
+    return sum(
+        len(re.findall(rf"= {re.escape(shape)}\S* copy\(", comps[name])) for name in seen
+    )
+
+
+def test_an_even_dispatch_compiles_to_a_loop_without_a_block_copy(monkeypatch):
+    """The compiled (CPU) program of an even ``steps``: no whole-block ``copy``
+    inside the ``while`` body -- and the same step with a trip of ONE step,
+    its carry returned permuted, has them: the check can see what it guards."""
+    assert _block_copies_in_loops(_leapfrog_program(8, monkeypatch=monkeypatch)) == 0
+    assert _block_copies_in_loops(_leapfrog_program(8, 1, monkeypatch)) > 0
+
+
+def test_an_odd_dispatch_is_correct(monkeypatch):
+    """Nine steps: four trips and one step behind the loop, its outputs
+    permuted against the donated inputs -- correct; XLA may copy at the
+    program's edge, once a dispatch (three blocks on the chip's compiler)."""
+    assert _block_copies_in_loops(_leapfrog_program(9, monkeypatch=monkeypatch)) == 0
+
+
+def _fingerprint_of(step, curr):
+    from program_fingerprint import fingerprint
+
+    return fingerprint(jax.make_jaxpr(step, static_argnums=1)(curr, 3))
+
+
+@pytest.mark.parametrize("variant", ["split", "fused", "fresh-output"])
+def test_the_other_schedules_rename_nothing_and_keep_their_programs(variant, monkeypatch):
+    """``overlap="split"`` keeps fresh outputs, ``halo="fused"`` writes every
+    quantity, a plan resolved un-aliased has no block to land in: ``renamed``
+    0, and the traced program is, by its fingerprint, the one the rule-off
+    build traces."""
+    from stencil_tpu.ops import stream as sm
+
+    plan_kw = {
+        "split": {"overlap": "split", "overlap_forced": True},
+        "fused": {"halo": "fused", "halo_forced": True},
+        "fresh-output": {"alias": False, "alias_forced": True},
+    }[variant]
+
+    def build():
+        sim = AcousticWave(N, N, N, nbl=NBL, interpret=True, seed_words=WORDS,
+                           devices=jax.devices()[: 4 if variant == "fused" else 1])
+        if variant == "fused":  # the packed routes need a wire on y and z
+            sim.dd.set_partition(1, 2, 2)
+            sim.dd.set_exchange_route("yzpack_xla")
+        sim.realize()
+        plan = dict(sm.plan_stream(sim.dd, ref.RADIUS, "plane", False), **plan_kw)
+        step = sm._build_stream_step(sim.dd, sim._kernel, ref.RADIUS, plan, interpret=True)
+        return plan, _fingerprint_of(step, sim.dd._curr)
+
+    plan, fp = build()
+    for key, want in plan_kw.items():  # the variant engaged, it did not degrade
+        assert plan[key] == want, plan
+    assert plan["renamed"] == () and "u_prev" in plan["writers"], plan
+    _renames_off(monkeypatch)
+    plan_off, fp_off = build()
+    assert plan_off["renamed"] == () and fp == fp_off

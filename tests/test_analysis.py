@@ -636,6 +636,25 @@ def test_inplace_order_reads_fetch_and_flush_steps():
     assert "flushes block (1, 0, 0) after grid step 1" in msg and "step 2" in msg
 
 
+def test_inplace_order_judges_an_output_aliased_onto_another_quantity():
+    """A rename (ISSUE 36) lands an output on ANOTHER quantity's operand: the
+    contract takes the pair as the call carries it.  The real renaming pass
+    aliases its one output onto operand 2 (raw ``u_prev``; 0 is ``origin``,
+    1 raw ``u``) and is clean; the synthetic one whose target is fetched two
+    planes behind an un-lagged write fires on that operand."""
+    from stencil_tpu.analysis import kernels
+
+    art = _load(os.path.join(FIXTURE_DIR, "inplace_order_plane_renamed_clean.py"))
+    (rep,) = kernels.kernel_reports(art.closed)
+    assert {o: a.index for o, a in rep.aliases.items()} == {0: 2} and len(rep.outputs) == 1
+    assert not kernels.check_inplace_order(art)
+    (msg,) = kernels.check_inplace_order(
+        _load(os.path.join(FIXTURE_DIR, "inplace_order_renamed_fire.py"))
+    )
+    assert "output 0 aliases in[1]" in msg
+    assert "flushes block (1, 0, 0) after grid step 1" in msg and "step 3" in msg
+
+
 # --- tier-2: the real CLI end to end -----------------------------------------
 
 

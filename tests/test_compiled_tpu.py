@@ -324,3 +324,49 @@ def test_compiled_plane_pass_wraps_the_planes_it_loads(monkeypatch):
     assert plan_off["pass_wrap_axes"] == "", plan_off
     for name, a, b in zip(("u", "b"), got, want):
         assert bool(jnp.all(jnp.isfinite(b))) and bool(jnp.array_equal(a, b)), name
+
+
+@pytest.mark.parametrize("steps", [8, 9])
+def test_compiled_acoustic_step_renames_u_prev(steps, monkeypatch):
+    """The acoustic cell's step as Mosaic and XLA compile it, 600^3 (raw
+    608^3): ``u_prev <- u`` is a rename (ISSUE 36) -- the pass writes ``u``
+    alone, into ``u_prev``'s block, and the loop runs two steps a trip -- and
+    every interior cell of ``u`` AND ``u_prev`` is bitwise what the same model
+    gives with the rule off (both written, the parent's program), after an
+    even dispatch (8: four trips, every block back in place) and an odd one
+    (9: the last step behind the loop, the outputs permuted against the
+    donated inputs).  In place is what CPU interpret mode cannot show: there
+    an aliased call runs functionally."""
+    import dataclasses
+    import gc
+
+    from stencil_tpu.models.acoustic import AcousticWave
+    from stencil_tpu.ops import stream as sm
+
+    def run():
+        sim = AcousticWave(600, 600, 600, devices=jax.devices()[:1],
+                           seed_words=(0x1234ABCD, 77, 0xDEADBEEF, 2024))
+        sim.realize()
+        sim.step(steps)
+        plan = sim._step._stream_plan
+        fields = [sim.field(q) for q in ("u", "u_prev")]  # on the host: 0.86 GB each
+        # a domain holds 7.6 GB here (two slots of four blocks) and is kept
+        # alive by the cycles of its own closures: free it before the next one
+        for slot in (sim.dd._curr, sim.dd._next or {}):
+            for block in slot.values():
+                block.delete()
+        del sim
+        gc.collect()
+        return plan, fields
+
+    plan, got = run()
+    assert plan["renamed"] == ("u_prev",) and plan["writers"] == ("u",), plan
+    real = sm.trace_plane_kernel
+    monkeypatch.setattr(
+        sm, "trace_plane_kernel", lambda *a: dataclasses.replace(real(*a), renames=())
+    )
+    plan_off, want = run()
+    assert plan_off["renamed"] == () and plan_off["writers"] == ("u", "u_prev"), plan_off
+    for name, a, b in zip(("u", "u_prev"), got, want):
+        assert np.isfinite(b).all() and float(np.max(np.abs(b))) > 0.01, name
+        assert np.array_equal(a, b), name

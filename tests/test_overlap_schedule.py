@@ -218,13 +218,17 @@ def test_no_overlap_step_schedule_serializes():
 # worker that runs it)
 def test_acoustic_step_carries_its_blocks_in_place(monkeypatch):
     """The acoustic cell's step (600^3, four quantities, plane route) as the
-    chip's compiler leaves it: the pass's custom call has TWO results, ``u``
-    and ``u_prev`` (``m`` and ``damp`` are operands only: ISSUE 32), aliased
-    onto their operands, and the ``while`` body holds NO whole-array copy —
-    un-aliased, XLA copies every written 608^3 block every step to put the
-    fresh result where the loop's carry lives (PERF.md §6, PR 28: 11.5 of
-    29.35 ms when all four were written).  The check ISSUEs 28 and 32 ask
-    for before any chip call."""
+    chip's compiler leaves it: the pass's custom call has ONE result, the new
+    ``u`` (``m`` and ``damp`` are operands only: ISSUE 32; ``u_prev <- u`` is
+    a rename: ISSUE 36), aliased onto raw ``u_prev`` (operand 2), the
+    ``while`` body holds TWO steps and NO whole-array copy, and nothing is
+    temporary — un-aliased, XLA copies every written 608^3 block every step
+    to put the fresh result where the loop's carry lives (PERF.md §6, PR 28:
+    11.5 of 29.35 ms when all four were written), and so it would for a body
+    that returned its carry permuted.  Nine steps run the ninth behind the
+    loop: the program's outputs are permuted against its donated inputs and
+    three copies (one block of temporaries) come back at its edge, once a
+    dispatch.  The check ISSUEs 28, 32 and 36 ask for before any chip call."""
     from stencil_tpu.models.acoustic import RADIUS, AcousticWave
     from stencil_tpu.ops import halo_blend
     from stencil_tpu.ops import stream as sm
@@ -235,29 +239,42 @@ def test_acoustic_step_carries_its_blocks_in_place(monkeypatch):
     jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
     try:
         texts = {}
-        for alias in (None, False):
+        for alias, steps in ((None, 8), (None, 9), (False, 8)):
             sim = AcousticWave(600, 600, 600, devices=devices[:1], seed_words=None)
             sim.dd.realize(allocate=False)
             plan = sm.plan_stream(sim.dd, RADIUS, "auto", False)
             if alias is not None:
                 plan = dict(plan, alias=alias, alias_forced=True)
             step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
-            compiled = step.lower(sim.dd.abstract_arrays(), 8).compile()
-            texts[alias] = (compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes)
+            compiled = step.lower(sim.dd.abstract_arrays(), steps).compile()
+            texts[alias, steps] = (
+                compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes, plan
+            )
     finally:
         jax.config.update("jax_enable_x64", x64_was)
     big_copy = re.compile(r"=\s+f32\[608,608,608\]\S*\s+copy\(")
-    text, temp = texts[None]
-    (pass_line,) = [
-        l for l in text.splitlines() if "stream_plane_pass" in l and "custom-call(" in l
-    ]
-    assert pass_line.lstrip().split(" custom-call(")[0].count("f32[608,608,608]") == 2
-    assert "output_to_operand_aliasing={{0}: (1, {}), {1}: (2, {})}, " in pass_line
-    # the pass and the x wrap of ``u``: the y and z wraps ride in the pass (ISSUE 34)
-    calls = [l for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
-    assert len(calls) == 2 and any(l.lstrip().startswith("%blend_planes") for l in calls)
+
+    def custom_calls(text, name=""):
+        return [
+            l for l in text.splitlines()
+            if "custom-call(" in l and "tpu_custom_call" in l and l.lstrip().startswith("%" + name)
+        ]
+
+    text, temp, plan = texts[None, 8]
+    assert plan["renamed"] == ("u_prev",) and plan["writers"] == ("u",), plan
+    passes = custom_calls(text, "stream_plane_pass")
+    assert len(passes) == 2  # a trip of the loop is two steps
+    for line in passes:
+        assert line.lstrip().split(" custom-call(")[0].count("f32[608,608,608]") == 1
+        assert "output_to_operand_aliasing={{}: (2, {})}, " in line
+    # the passes and the x wraps of ``u``: the y and z wraps ride in the pass (ISSUE 34)
+    assert len(custom_calls(text)) == 4 and len(custom_calls(text, "blend_planes")) == 2
     assert not big_copy.findall(text) and temp == 0
-    text_off, temp_off = texts[False]
+    text_odd, temp_odd, _ = texts[None, 9]
+    assert len(custom_calls(text_odd, "stream_plane_pass")) == 3
+    assert len(big_copy.findall(text_odd)) == 3 and 0.9e9 < temp_odd < 1.0e9
+    text_off, temp_off, plan_off = texts[False, 8]
+    assert plan_off["renamed"] == () and plan_off["writers"] == ("u", "u_prev"), plan_off
     assert len(big_copy.findall(text_off)) == 2 and temp_off > 1.8e9
 
 

@@ -318,7 +318,10 @@ def test_a_kernel_that_reads_nothing_off_centre_exchanges_nothing():
     eqns = list(jx.iter_eqns(closed))
     assert not [e for e in eqns if "exchange." in jx.name_stack_str(e)]
     assert not [e for e in eqns if e.primitive.name == "ppermute"]
-    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 1
+    # one pass a step, and nothing else: ``v <- u`` is a rename (ISSUE 36), so
+    # a trip of the step loop holds the permutation's period, two steps
+    assert step._span_args()["renamed"] == 1
+    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 2
 
 
 def test_a_footprint_trace_that_raises_exchanges_everything(monkeypatch):
@@ -843,3 +846,280 @@ def test_the_pass_wraps_the_planes_it_loads(
                            np.asarray(want[q].astype(jnp.float32)))
         for q, name in enumerate(names) if name in writers
     )
+
+
+# --- a time level renamed, not copied (ISSUE 36) ------------------------------
+#
+# An output that IS another writer's centre plane -- a leapfrog scheme's
+# ``u_prev <- u`` -- is not written: the pass lands ``u``'s new value in
+# ``u_prev``'s block and the step hands ``u``'s old array on under the name
+# ``u_prev`` (ops/stream.py trace_plane_kernel, stream_plane_pass(renames=)).
+# The rule reads the kernel's jaxpr and fails closed on anything but the
+# centre invar itself.
+
+
+def _leapfrog(second):
+    """``u`` takes a star of itself against the older level ``v``; ``second``
+    says what ``v`` takes (and may add or drop outputs)."""
+
+    def kernel(views, info):
+        u, v = views["u"], views["v"]
+        out = {"u": _star(u, 1) - 0.5 * v.center()}
+        out.update(second(views, info))
+        return out
+
+    return kernel
+
+
+def _masked_copy(views, info):
+    import jax.numpy as jnp
+
+    return {"v": jnp.where(info.coords()[1] >= 2, views["u"].center(), 0.0)}
+
+
+def _acoustic_case():
+    from stencil_tpu.models.acoustic import QUANTITIES, AcousticWave
+
+    sim = AcousticWave(24, 24, 24, nbl=4, interpret=True, seed_words=None)
+    return sim._kernel, QUANTITIES, 4
+
+
+def _elastic_case(stage):
+    from stencil_tpu.models import elastic_reference as eref
+    from stencil_tpu.models.elastic import ElasticWave
+
+    sim = ElasticWave(24, 24, 24, nbl=4, interpret=True)
+    return getattr(sim, stage), eref.QUANTITIES, 4
+
+
+def _two_levels(second, names=("u", "v")):
+    """() -> (kernel, names, r) of a ``_leapfrog`` over ``names`` at radius 1."""
+    return lambda: (_leapfrog(second), list(names), 1)
+
+
+_UVW = ("u", "v", "w")
+_RENAME_CASES = [
+    # () -> (kernel, names, r), storage dtypes (None: the planes'), renames
+    pytest.param(_acoustic_case, None, (("u_prev", "u"),), id="acoustic"),
+    pytest.param(_two_levels(lambda vs, _: {"v": vs["u"].center()}), None, (("v", "u"),),
+                 id="leapfrog"),
+    pytest.param(_two_levels(lambda vs, _: {"v": vs["u"].sh(0, 0, 0)}), None, (("v", "u"),),
+                 id="leapfrog-sh000"),
+    pytest.param(_two_levels(lambda vs, _: {"v": vs["u"].center() + 0.0}), None, (),
+                 id="plus-zero"),
+    pytest.param(_two_levels(_masked_copy), None, (), id="masked-copy"),
+    pytest.param(_two_levels(lambda vs, _: {"v": vs["u"].sh(1, 0, 0)}), None, (),
+                 id="off-centre-plane"),
+    # ``u`` keeps naming its array: nothing is free to take another name
+    pytest.param(lambda: (lambda vs, _: {"v": vs["u"].center()}, ["u", "v"], 1), None, (),
+                 id="source-not-written"),
+    pytest.param(_two_levels(lambda vs, _: {"v": vs["u"].center()}), ("float32", "bfloat16"), (),
+                 id="mismatched-storage"),
+    pytest.param(lambda: _elastic_case("_stage_v"), None, (), id="elastic-stage-v"),
+    pytest.param(lambda: _elastic_case("_stage_t"), None, (), id="elastic-stage-t"),
+    # w <- v <- u: ``v`` has no value of its own, so ``w <- v`` stays a copy
+    pytest.param(_two_levels(lambda vs, _: {"v": vs["u"].center(), "w": vs["v"].center()}, _UVW),
+                 None, (("v", "u"),), id="chain"),
+    pytest.param(lambda: (lambda vs, _: {"u": vs["v"].center(), "v": vs["u"].center()},
+                          ["u", "v"], 1), None, (), id="swap"),
+    pytest.param(_two_levels(lambda vs, _: {"v": vs["u"].center(), "w": vs["u"].center()}, _UVW),
+                 None, (("v", "u"),), id="source-claimed-once"),
+    pytest.param(_two_levels(lambda vs, _: {"v": vs["v"].center()}), None, (), id="itself"),
+]
+
+
+@pytest.mark.parametrize("case,storage,renames", _RENAME_CASES)
+def test_the_footprint_trace_reports_a_rename_and_nothing_like_one(case, storage, renames):
+    import jax
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops import stream as sm
+
+    kernel, names, r = case()
+    plane = jax.ShapeDtypeStruct((16 + 2 * r, 16 + 2 * r), jnp.float32)
+    trace = sm.trace_plane_kernel(
+        kernel, list(names), [plane] * len(names), r, Dim3(16, 16, 16), True, storage
+    )
+    assert trace.closed is not None and trace.renames == renames, trace
+
+
+def test_a_trace_that_raises_renames_nothing():
+    import jax
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops import stream as sm
+
+    plane = jax.ShapeDtypeStruct((18, 18), jnp.float32)
+    trace = sm.trace_plane_kernel(_raising_kernel, ["u", "v"], [plane] * 2, 1, Dim3(16, 16, 16))
+    assert trace.closed is None and trace.renames == ()
+
+
+def _renames_off(monkeypatch):
+    """The rule off: the parent's program, every returned quantity written."""
+    import dataclasses
+
+    from stencil_tpu.ops import stream as sm
+
+    real = sm.trace_plane_kernel
+    monkeypatch.setattr(
+        sm, "trace_plane_kernel", lambda *a: dataclasses.replace(real(*a), renames=())
+    )
+
+
+@pytest.mark.parametrize("alias", [False, True], ids=["fresh", "aliased"])
+def test_a_renaming_pass_swaps_two_handles_and_writes_one_array(alias):
+    """``stream_plane_pass(renames=)`` itself, ``coupled_kernel``'s four
+    quantities: TWO outputs where the plain pass has three (``v`` is not
+    written), ``u``'s output aliased onto raw ``v`` (operand ``1 + 1``),
+    ``v`` handed back as the very array ``u`` went in as, and every quantity
+    equal to the plain pass's -- ``u`` and ``d`` on every raw cell, ``v`` on
+    its interior (its shell is now ``u``'s)."""
+    import jax
+    import jax.numpy as jnp
+
+    from test_stream import coupled_kernel
+
+    from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops.stream import stream_plane_pass
+
+    r, n = 2, 12
+    names = ["u", "v", "c", "d"]
+    rng = np.random.default_rng(36)
+    raws = [jnp.asarray(rng.standard_normal((n + 2 * r,) * 3), jnp.float32) for _ in names]
+    origin = jnp.zeros((3,), jnp.int32)
+
+    def run(writers, renames):
+        def fn(origin, *raws):
+            return stream_plane_pass(
+                coupled_kernel(r), names, list(raws), Dim3(r, r, r), Dim3(r, r, r), r,
+                origin, Dim3(n, n, n), alias=alias, interpret=True, writers=writers,
+                rings=("u",), renames=renames,
+            )
+
+        (call,) = _pass_calls(jax.make_jaxpr(fn)(origin, *raws))
+        return call, fn(origin, *raws)
+
+    call, got = run(("u", "d"), (("v", "u"),))
+    plain_call, want = run(("u", "v", "d"), ())
+    assert (len(call.outvars), len(plain_call.outvars)) == (2, 3)
+    assert _alias_pairs(call) == (((2, 0), (4, 1)) if alias else ())
+    assert _alias_pairs(plain_call) == (((1, 0), (2, 1), (4, 2)) if alias else ())
+    assert got[1] is raws[0] and got[2] is raws[2]
+    inner = (slice(r, -r),) * 3
+    for name, a, b in zip(names, got, want):
+        cells = inner if name == "v" else ...
+        assert np.array_equal(np.asarray(a)[cells], np.asarray(b)[cells]), name
+    assert np.any(np.asarray(got[0]) != np.asarray(raws[0]))
+
+
+def test_a_rename_the_pass_cannot_make_is_refused():
+    """The source must be a writer and the target must not be one: the pass
+    asserts what ``plan_plane_passes`` guarantees."""
+    with pytest.raises(AssertionError):
+        _one_pass(lambda vs, _: {"a": vs["a"].center() * 0.5}, writers=("a",),
+                  renames=(("a", "c"),))
+
+
+_RENAME_STEP_CASES = [
+    # id, devices, plan overrides, renamed, steps of the one dispatch
+    pytest.param(1, {}, ("v",), 4, id="in-place-even"),
+    pytest.param(1, {}, ("v",), 3, id="in-place-odd"),
+    pytest.param(8, {}, ("v",), 5, id="mesh-2x2x2-odd"),
+    pytest.param(1, {"alias": False, "alias_forced": True}, (), 3, id="fresh-output"),
+    pytest.param(8, SPLIT, (), 3, id="split"),
+]
+
+
+@pytest.mark.parametrize("n_dev,plan_kw,renamed,steps", _RENAME_STEP_CASES)
+def test_plane_route_renames_a_time_level_where_its_passes_run_in_place(
+    n_dev, plan_kw, renamed, steps, monkeypatch
+):
+    """The step as built, rule on against rule off: ``plan["renamed"]`` and
+    the passes' ``renames`` say where it engaged (in place on the default
+    schedule; not with fresh outputs, not under ``overlap="split"``), the
+    renamed quantity leaves ``writers``, and every interior cell of every
+    quantity is bitwise the same after an even and after an odd number of
+    steps -- as is every raw cell once both sides have exchanged."""
+    from test_stream import coupled_kernel
+
+    names, r = ["u", "v", "c", "d"], 2
+
+    def run():
+        dd, hs = _plane_domain(names, r, n_dev)
+        step, plan = _plane_step(dd, coupled_kernel(r), r, plan_kw)
+        dd.run_step(step, steps)
+        fields = [dd.quantity_to_host(h) for h in hs]
+        dd.exchange()
+        return fields, {h.name: np.asarray(dd._curr[h.name]) for h in hs}, plan
+
+    fields, raws, plan = run()
+    for key, want in plan_kw.items():
+        assert plan[key] == want, plan
+    assert plan["renamed"] == renamed, plan
+    assert plan["writers"] == tuple(nm for nm in ("u", "v", "d") if nm not in renamed), plan
+    (p,) = plan["stages"][0]["passes"]
+    assert p["renames"] == ((("v", "u"),) if renamed else ()) and "v" in p["reads"], p
+    _renames_off(monkeypatch)
+    fields_off, raws_off, plan_off = run()
+    assert plan_off["renamed"] == () and plan_off["writers"] == ("u", "v", "d"), plan_off
+    for i, name in enumerate(names):
+        assert np.array_equal(fields[i], fields_off[i]), name
+        assert np.array_equal(raws[name], raws_off[name]), name
+
+
+def test_a_chain_renames_its_head_and_copies_its_tail():
+    """``w <- v <- u``: ``v <- u`` is a rename, ``w <- v`` the copy it was (it
+    is read from ``v``'s block before ``u``'s new value is flushed onto it),
+    and the step equals the XLA engine on every quantity after 1, 2 and 3
+    steps."""
+    kernel = _leapfrog(lambda vs, _: {"v": vs["u"].center(), "w": vs["v"].center()})
+    names = ["u", "v", "w"]
+    dd, hs = _plane_domain(names, 1, 1)
+    step, plan = _plane_step(dd, kernel, 1, {})
+    assert plan["renamed"] == ("v",) and plan["writers"] == ("u", "w"), plan
+    ref_dd, ref_hs = _plane_domain(names, 1, 1)
+    ref = ref_dd.make_step(kernel, overlap=False)
+    for steps in (1, 2, 3):
+        dd.run_step(step, steps)
+        ref_dd.run_step(ref, steps)
+        for h, g in zip(hs, ref_hs):
+            assert np.array_equal(dd.quantity_to_host(h), ref_dd.quantity_to_host(g)), (h.name, steps)
+
+
+def test_a_stage_cut_into_passes_renames_nothing(monkeypatch):
+    """The rule needs ONE pass that holds both quantities: under a VMEM
+    budget that cuts the stage in two, ``v`` is written as before.  (The older
+    level comes first and the new one does not read it, so the cut is legal:
+    a leapfrog whose new level reads the old one fits one pass or none.)"""
+
+    def kernel(views, info):
+        return {"v": views["u"].center(), "u": _star(views["u"], 2) * views["c"].center()}
+
+    names, r = ["v", "u", "c"], 2  # outputs join the passes in the domain's order
+    dd, _ = _plane_domain(names, r, 1)
+    _, whole = _plane_step(dd, kernel, r, {})
+    (p,) = whole["stages"][0]["passes"]
+    assert p["renames"] == (("v", "u"),) and whole["writers"] == ("u",), whole
+    monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(p["vmem_bytes"] - 1))
+    dd, _ = _plane_domain(names, r, 1)
+    _, cut = _plane_step(dd, kernel, r, {})
+    passes = cut["stages"][0]["passes"]
+    assert [q["writes"] for q in passes] == [("v",), ("u",)], passes
+    assert all(q["renames"] == () for q in passes), passes
+    assert cut["renamed"] == () and cut["writers"] == ("v", "u"), cut
+
+
+def test_the_carry_period_is_the_order_of_the_steps_permutation():
+    from stencil_tpu.ops.stream import _carry_period
+
+    def stages(*passes):
+        return tuple({"passes": tuple({"renames": r} for r in st)} for st in passes)
+
+    names = list("abcd")
+    assert _carry_period(names, stages([()])) == 1
+    assert _carry_period(names, stages([(("b", "a"),)])) == 2
+    assert _carry_period(names, stages([(("b", "a"), ("d", "c"))])) == 2
+    # two stages that pass one block on: a cycle of three
+    assert _carry_period(names, stages([(("b", "a"),)], [(("c", "b"),)])) == 3

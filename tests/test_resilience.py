@@ -487,7 +487,7 @@ class TestLadderEngines:
             "route": "wavefront", "m": 3, "z_slabs": True, "grouping": "joint",
             "alias": False,  # one field on the wavefront route: fresh outputs
             "overlap": "off", "halo": "array", "halo_readers": ("u",),
-            "writers": ("u",), "pass_wrap_axes": "",
+            "writers": ("u",), "pass_wrap_axes": "", "renamed": (),
         }
         inject.set_plan("execute:vmem_oom:stream*2")
         dd.run_step(step, 4)

@@ -439,6 +439,7 @@ def test_stream_separable_per_field_grouping(monkeypatch):
         "halo_readers": ("a", "b", "c", "d"),  # the wavefront exchanges every quantity
         "writers": ("a", "b", "c", "d"),  # and writes every one
         "pass_wrap_axes": "",  # the plane route's alone (ISSUE 34)
+        "renamed": (),  # as is the rename of a time level (ISSUE 36)
     }
     monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
     ref_dd, ref_hs = _mk(24, 24, 24, Radius.constant(1), names, devs)
@@ -509,6 +510,7 @@ def test_stream_depth_cap():
         "halo_readers": (),  # and no exchange
         "writers": ("u",),
         "pass_wrap_axes": "",
+        "renamed": (),
     }
     for a, b in outs:  # uncapped wrap vs the XLA ground truth
         np.testing.assert_allclose(a, b, **TOL)

@@ -3,8 +3,10 @@
 Parity target: Devito's ``benchmarks/user/benchmark.py -P acoustic -so 8 -d X
 Y Z`` (``examples/seismic/acoustic``: ``iso_stencil``, kernel ``OT2``, a
 ``--nbl``-point damping layer) — the forward propagator of seismic modelling.
-``x y z`` are the PHYSICAL extents, as Devito's ``-d``; the run adds the
-sponge and the 4-cell zero frame on every side (docs/acoustic.md).  No source
+``x y z`` are the PHYSICAL extents, as Devito's ``-d``, and need not be equal
+(``1112 1112 512`` on four chips is the benchmark's decomposed shot: mesh
+2,2,1, printed on stderr beside the domain); the run adds the sponge and the
+4-cell zero frame on every side (docs/acoustic.md).  No source
 and no receivers: a seeded wave packet inside the physical region stands for
 the shot.  One CSV row, like the other drivers, plus Devito's own figure of
 merit (GPts/s over the whole padded grid):
@@ -64,6 +66,16 @@ def run(argv, name: str, model, steps: int) -> int:
     )
     _common.apply_numerics(args, sim.dd)
     sim.realize()
+    # the mesh the partitioner picked for this (maybe non-cubic) extent, and
+    # what a step sends over it: Devito's 2 x 2 x 1 for 1112 1112 512 on four
+    # chips, "xy" wired and z wrapped in the pass (docs/acoustic.md)
+    mesh = ",".join(str(int(d)) for d in sim.dd.mesh_dim())
+    plan = getattr(sim._step, "_span_args", dict)()
+    print(
+        f"mesh: {mesh} wired={plan.get('wired', '')!r} "
+        f"wrapped={plan.get('wrapped', '')!r} wire_bytes={plan.get('wire_bytes', 0)}",
+        file=sys.stderr,
+    )
 
     iter_time = Statistics()
 

@@ -50,6 +50,7 @@ big array never sees a halo write at all.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -496,6 +497,47 @@ def wrap_axes(
             route, dtypes, all_3d,
         ) == "wrap"
     )
+
+
+def wire_plan(
+    mesh_shape: Tuple[int, int, int],
+    radius: Radius,
+    raw_spatial: Tuple[int, int, int],
+    dtypes,
+    valid_last: Optional[Tuple[Optional[int], Optional[int], Optional[int]]] = None,
+    route: str = "direct",
+    axes: Tuple[int, ...] = (0, 1, 2),
+) -> Tuple[str, int]:
+    """``(wired, nbytes)`` of one ``halo_exchange_multi`` over blocks of
+    ``dtypes``: the mesh axes (a substring of ``"xyz"``) among ``axes`` whose
+    sweep sends its slabs to ANOTHER shard -- the mesh splits the axis, so
+    ``_sweep_kind`` cannot pick the self-wrap and both directions are
+    ``ppermute``s -- and the bytes one shard receives over them: per axis the
+    messages the sweep kind forms (the sliced slabs of ``direct``, ``(r_lo +
+    r_hi)`` x the raw cross-section; the packed buffers of ``ypack`` /
+    ``zpack``), every quantity.  ``("", 0)`` on one device.  What
+    ``domain.step`` reports as ``wired`` / ``wire_bytes``."""
+    itemsizes = [jnp.dtype(dt).itemsize for dt in dtypes]
+    wired, nbytes = "", 0
+    for a in axes:
+        r_lo, r_hi = radius.axis(a, -1), radius.axis(a, +1)
+        if mesh_shape[a] == 1 or r_lo + r_hi == 0:
+            continue
+        kind = _sweep_kind(
+            a, r_lo, r_hi, mesh_shape[a], raw_spatial[a],
+            valid_last[a] if valid_last is not None else None,
+            route, dtypes, True,  # block rank matters to the self-wrap alone
+        )
+        assert kind != "wrap", (a, mesh_shape)  # a split axis has a neighbour
+        wired += MESH_AXES[a]
+        if kind == "zpack":
+            nbytes += zpack_message_stats(raw_spatial, r_lo, r_hi, itemsizes)[0]
+        elif kind == "ypack":
+            nbytes += ypack_message_stats(raw_spatial, r_lo, r_hi, itemsizes)[0]
+        else:
+            face = math.prod(raw_spatial) // raw_spatial[a]
+            nbytes += (r_lo + r_hi) * face * sum(itemsizes)
+    return wired, nbytes
 
 
 def uneven_axes(

@@ -1933,6 +1933,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     from stencil_tpu.ops.exchange import (
         fused_shell_exchange,
         halo_exchange_multi,
+        wire_plan,
     )
     from stencil_tpu.parallel.mesh import MESH_AXES
 
@@ -2132,6 +2133,11 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     # written back like the three above (domain.step's ``renamed``): on the
     # same schedule, where the passes run in place
     plan["renamed"] = ()
+    # the axes the step's exchange sends over wires and the bytes a shard
+    # receives over them a step, where the exchange is the plane route's
+    # swept one (set below; no other schedule says)
+    plan.pop("wired", None)
+    plan.pop("wire_bytes", None)
     if plan["route"] == "wrap":
         plan["halo_readers"] = ()
     elif plan["route"] == "plane":
@@ -2195,6 +2201,24 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
         swept_axes = tuple(
             a for a in range(3) if MESH_AXES[a] not in plan["pass_wrap_axes"]
         )
+        if not fused:
+            # what the sweeps that are left send to ANOTHER shard, written
+            # back like the wrapped axes (domain.step's ``wired`` and
+            # ``wire_bytes``): per stage the message plan of the exchange
+            # below (ops/exchange.py wire_plan, i.e. ``_sweep_kind``), the
+            # axes joined and the bytes summed over the stages of a step
+            per_stage = [
+                wire_plan(
+                    mesh_shape, shell, (raw.x, raw.y, raw.z),
+                    [dd.field_dtype(dd._handles[index[name]]) for name in readers],
+                    valid_last=valid_last, route=exch_route, axes=swept_axes,
+                )
+                for readers in stage_readers if readers
+            ]
+            plan["wired"] = "".join(
+                ax for ax in MESH_AXES if any(ax in w for w, _ in per_stage)
+            )
+            plan["wire_bytes"] = sum(b for _, b in per_stage)
 
         def exchange_readers(bs, k):
             """``bs`` with the shells of stage ``k``'s halo readers filled --
@@ -2802,6 +2826,12 @@ def make_stream_step(
             # value for every stage, a function of the mesh and the domain
             "wrapped": plan_now.get("pass_wrap_axes", ""),
         }
+        if "wired" in plan_now:
+            # the axes whose sweep of the step's exchange crosses to another
+            # shard, and the bytes one shard receives over them a step, all
+            # stages (ops/exchange.py wire_plan): "" and 0 on one device
+            args["wired"] = plan_now["wired"]
+            args["wire_bytes"] = plan_now["wire_bytes"]
         per_stage = plan_now.get("stages", ())
         if len(per_stage) > 1:
             # a staged step says the three PER STAGE, in order ("6/3"): each

@@ -422,10 +422,16 @@ ALL_HISTOGRAMS = frozenset({
 #: exchange does not sweep them (``ops/stream.pass_wrap_fills``: the y / z
 #: axes the mesh does not split, "yz" on one chip, "z" on mesh [2,2,1], ""
 #: off the plane route's default schedule and wherever that axis's sweep is
-#: not the self-wrap); a STAGED step (``make_step`` with a sequence of
-#: kernels) adds stages and passes, and says exchanged / written / renamed /
-#: aliased PER STAGE, in order: "6/3", "3/6", "0/0", "11/12" (``wrapped`` is
-#: one value: a function of the mesh, the same for every stage)]
+#: not the self-wrap), and on the plane route's swept exchange wired = the
+#: axes whose sweep sends its slabs to ANOTHER shard and wire_bytes = the
+#: bytes one shard receives over them a step, every stage
+#: (``ops/exchange.wire_plan``, read off what ``_sweep_kind`` decides:
+#: acoustic on mesh [2,2,1] "xy" and 23658496 = four radius-4 faces of
+#: ``u``'s 608^3 block, "" and 0 on one device); a STAGED step (``make_step``
+#: with a sequence of kernels) adds stages and passes, and says exchanged /
+#: written / renamed / aliased PER STAGE, in order: "6/3", "3/6", "0/0",
+#: "11/12" (``wrapped``, ``wired`` and ``wire_bytes`` are one value each: the
+#: first two functions of the mesh, the last summed over the stages)]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes

@@ -170,6 +170,20 @@ def _acoustic():
     return _trace_step(s.dd, s._step)
 
 
+def _acoustic_x4():
+    import jax
+
+    from stencil_tpu.models.acoustic import AcousticWave
+
+    # x = y = 2z as the cell, the mesh the partitioner picks for it
+    s = AcousticWave(48, 48, 24, nbl=4, interpret=True, devices=jax.devices()[:4])
+    s.realize()
+    args = s._step._span_args()
+    assert tuple(s.dd.mesh_dim()) == (2, 2, 1), s.dd.mesh_dim()
+    assert (args["route"], args["wired"], args["wrapped"]) == ("plane", "xy", "z"), args
+    return _trace_step(s.dd, s._step)
+
+
 def _elastic():
     import jax
 
@@ -190,6 +204,7 @@ MODEL_PROGRAMS = {
     "model:weak-r3-512x4/exchange-direct": _weak_exchange,
     "model:acoustic-so8-600/plane-r4": _acoustic,
     "model:elastic-so8-600/plane-r4": _elastic,
+    "model:acoustic-so8-1200x4/plane-r4": _acoustic_x4,
 }
 
 

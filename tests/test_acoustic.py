@@ -84,13 +84,23 @@ def test_one_chip_sweeps_are_self_wraps_at_radius_4(monkeypatch):
     assert err_u <= ATOL and err_prev <= ATOL, (err_u, err_prev)
 
 
-@pytest.mark.parametrize("blend,wrapped", [("0", ""), ("1", "yz")], ids=["cpu", "as-on-the-chip"])
-def test_route_is_plane_and_the_span_says_so(blend, wrapped, monkeypatch):
+@pytest.mark.parametrize(
+    "blend,mesh,wrapped,wired",
+    [("0", (1, 1, 1), "", ""), ("1", (1, 1, 1), "yz", ""), ("1", (2, 2, 1), "z", "xy")],
+    ids=["cpu", "as-on-the-chip", "as-on-four-chips"],
+)
+def test_route_is_plane_and_the_span_says_so(blend, mesh, wrapped, wired, monkeypatch):
     """``wrapped`` (ISSUE 34): with the blend kernels on, as on the chip, the
     y and z self-wraps of the one device ride in the pass; a plain CPU run
-    keeps the program it had."""
+    keeps the program it had.  ``wired`` / ``wire_bytes`` (ISSUE 37): on mesh
+    [2,2,1] the x and y sweeps of ``u`` cross to another shard, four radius-4
+    faces of the raw block a step, and the pass wraps z alone; on one device
+    nothing crosses a wire."""
     monkeypatch.setenv("STENCIL_HALO_BLEND", blend)
-    sim = _sim("pallas")
+    sim = _sim("pallas") if mesh == (1, 1, 1) else _shot()
+    assert tuple(sim.dd.mesh_dim()) == mesh
+    raw = N + 2 * ref.RADIUS
+    wire_bytes = len(wired) * 2 * ref.RADIUS * raw * raw * 4
     plan = sim._step._stream_plan
     assert plan["route"] == "plane" and plan["m"] == 1 and plan["grouping"] == "joint", plan
     assert plan["alias"] is True, plan  # the plane route writes in place (ISSUE 28)
@@ -100,6 +110,7 @@ def test_route_is_plane_and_the_span_says_so(blend, wrapped, monkeypatch):
         "written": 1,  # m and damp are never returned: inputs only (ISSUE 32)
         "renamed": 1,  # and u_prev <- u swaps two handles: nothing to write (ISSUE 36)
         "wrapped": wrapped,
+        "wired": wired, "wire_bytes": wire_bytes,  # what crosses to another shard (ISSUE 37)
     }
     assert plan["halo_readers"] == ("u",), plan
     assert plan["writers"] == ("u",) and plan["renamed"] == ("u_prev",), plan
@@ -120,6 +131,7 @@ def test_route_is_plane_and_the_span_says_so(blend, wrapped, monkeypatch):
     assert (kw["streamed"], kw["aliased"], kw["exchanged"], kw["written"]) == (4, 4, 1, 1)
     assert kw["renamed"] == 1
     assert kw["wrapped"] == wrapped
+    assert (kw["wired"], kw["wire_bytes"]) == (wired, wire_bytes)
 
 
 @pytest.mark.parametrize("devices", [1, 2, 8])
@@ -161,6 +173,89 @@ def test_the_step_program_exchanges_u_alone(monkeypatch):
     assert len(whiles) == 1  # two trips of two steps, then the fifth step
     assert not [e for e in jx.iter_eqns(closed) if e.primitive.name == "ppermute"]
     assert sim._step._stream_plan["pass_wrap_axes"] == "yz"
+
+
+# --- the shot decomposed over a mesh (ISSUE 37) ---------------------------------
+#
+# The benchmark's four-chip cell is 1200 x 1200 x 600 on mesh [2,2,1]: x = y =
+# 2z, the x and y seams through the middle of the wave packet, z whole.  Here
+# the same shape at 48 x 48 x 24, on the mesh the partitioner picks for it.
+
+SHOT = (2 * N, 2 * N, N)
+
+
+def _shot(impl="pallas", **kw):
+    sim = AcousticWave(*SHOT, nbl=NBL, kernel_impl=impl, interpret=True,
+                       devices=jax.devices()[:4], seed_words=WORDS, **kw)
+    sim.realize()
+    assert tuple(sim.dd.mesh_dim()) == (2, 2, 1), sim.dd.mesh_dim()  # nobody asked for it
+    return sim
+
+
+@pytest.mark.parametrize("dispatches", [1, 2])
+@pytest.mark.parametrize("blend", ["0", "1"], ids=["cpu", "as-on-the-chip"])
+def test_the_decomposed_shot_matches_the_reference(blend, dispatches, monkeypatch):
+    """The non-cubic shot on mesh [2,2,1] against the plain reference on the
+    whole array, every cell of ``u`` and ``u_prev``, at ``ATOL`` (the header
+    says why that and not bitwise) -- with the sweeps as the CPU has them and
+    as the chip has them (blends on: the pass fills z itself, beside a y halo
+    that arrived from another shard)."""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", blend)
+    sim = _shot()
+    assert sim._step._stream_plan["pass_wrap_axes"] == ("z" if blend == "1" else "")
+    err_u, err_prev = _errors(sim, dispatches)
+    assert err_u <= ATOL and err_prev <= ATOL, (err_u, err_prev)
+
+
+def test_the_decomposed_shot_in_bf16_storage_fails_the_tolerance():
+    sim = _shot(storage_dtype="bf16")
+    assert sim.dd.storage_dtype() == "bf16"
+    err_u, _ = _errors(sim, 1)
+    assert err_u > 100 * ATOL, err_u
+
+
+@pytest.mark.parametrize("blend", ["0", "1"], ids=["cpu", "as-on-the-chip"])
+def test_the_decomposed_shot_is_bitwise_the_xla_engine(blend, monkeypatch):
+    """Three dispatches on, every interior cell of every quantity bitwise the
+    XLA slice engine's on the same mesh (which exchanges all four, on all
+    three axes)."""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", blend)
+    sims = [_shot(impl) for impl in ("pallas", "jnp")]
+    for sim in sims:
+        for _ in range(3):
+            sim.step(DISPATCH)
+    for q in QUANTITIES:
+        a, b = (sim.field(q) for sim in sims)
+        assert np.any(a != 0.0), q
+        np.testing.assert_array_equal(a, b, err_msg=q)
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_the_wires_carry_four_faces_of_u_and_nothing_else(devices, monkeypatch):
+    """The step as the chip runs it: on mesh [2,2,1] the ``ppermute``s of one
+    step are four -- ``exchange.x.low`` / ``.high``, ``exchange.y.low`` /
+    ``.high`` -- and their cells are exactly ONE quantity's four radius-4
+    faces of the raw block, which is what the plan and the span report as
+    ``wire_bytes``; the z sweep is gone into the pass.  On one device nothing
+    is sent at all."""
+    from stencil_tpu.analysis import jaxpr as jx
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    sim = _shot() if devices == 4 else _sim("pallas")
+    closed = jax.make_jaxpr(sim._step._resilience.built(), static_argnums=1)(sim.dd._curr, 1)
+    sends = [e for e in jx.iter_eqns(closed) if e.primitive.name == "ppermute"]
+    cells = sum(int(np.prod(v.aval.shape)) for e in sends for v in e.invars)
+    args = sim._step._span_args()
+    assert args["wire_bytes"] == 4 * cells
+    raw = N + 2 * ref.RADIUS
+    if devices == 1:
+        assert not sends and (args["wired"], args["wrapped"]) == ("", "yz"), args
+        return
+    assert cells == 4 * ref.RADIUS * raw * raw
+    assert sorted(jx.name_stack_str(e).split("/")[-1] for e in sends) == [
+        tm.exchange_direction_span(a, side) for a in "xy" for side in ("high", "low")]
+    assert (args["wired"], args["wrapped"], args["exchanged"], args["renamed"]) == ("xy", "z", 1, 1)
+    assert not [e for e in jx.iter_eqns(closed) if "exchange.z" in jx.name_stack_str(e)]
 
 
 def _plane_passes(fn, curr):
@@ -268,13 +363,21 @@ def test_fill_takes_the_seed_as_an_argument():
     np.testing.assert_allclose(sim.field("u"), want, rtol=0, atol=1e-6)
 
 
-def test_driver_runs_on_the_cpu(capsys):
+@pytest.mark.parametrize("extent", [("8", "8", "8"), ("32", "32", "8")], ids=["cubic", "x=y=2z"])
+def test_driver_runs_on_the_cpu(extent, capsys):
+    """``stencil-acoustic`` takes the PHYSICAL extents, equal or not, and says
+    on stderr which mesh the partitioner gave it and what a step wires."""
     from stencil_tpu.bin import acoustic
 
-    rc = acoustic.main(["8", "8", "8", "--nbl", "4", "--iters", "1", "--steps", "2"])
+    rc = acoustic.main([*extent, "--nbl", "4", "--iters", "1", "--steps", "2"])
     assert rc == 0
-    row = capsys.readouterr().out.strip().splitlines()[-1].split(",")
-    assert row[0] == "acoustic" and row[3:7] == ["8", "8", "8", "4"]
+    io = capsys.readouterr()
+    row = io.out.strip().splitlines()[-1].split(",")
+    assert row[0] == "acoustic" and row[3:7] == [*extent, "4"]
+    (said,) = [l for l in io.err.splitlines() if l.startswith("mesh: ")]
+    devices = int(row[2])
+    mesh = [int(d) for d in said.split()[1].split(",")]
+    assert int(np.prod(mesh)) == devices and "wired=" in said and "wrapped=" in said, said
 
 
 # --- u_prev <- u is a rename (ISSUE 36) ----------------------------------------

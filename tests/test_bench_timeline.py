@@ -72,7 +72,8 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             m = json.load(f)
         assert m["reducer"] in ("named_share", "named_roofline_hbm") and m["cells"] == ["acoustic-*"], name
-        assert declared[name]["workloads"] == ["acoustic-so8-600.bulk"], name
+        # the cells the pattern matches, in the order they joined the benchmark (PR 37 the second)
+        assert declared[name]["workloads"] == ["acoustic-so8-600.bulk", "acoustic-so8-1200x4.bulk"], name
     # PR 31's: the ragged weak cell's shares, by pattern too
     ragged = {
         "exchange_z_pct.ragged", "collective_pct.ragged", "kernel_named_pct.ragged", "enqueue_ms_p90.ragged",
@@ -101,7 +102,17 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
     # PR 35's: the set-up account's seven, for every cell (tests/test_bench_setup.py holds them)
     setup = {n for n in declared if n.startswith("setup_") or n == "steady_compiles"}
     assert len(setup) == 7
-    for name in set(declared) - new - plane - staged - setup - (ragged - {"collective_pct.ragged"}):
+    # PR 37's: the plane step across chips, by pattern (tests/test_bench_acoustic_x4.py holds them)
+    wired = {n for n in declared if n.endswith(".wired")}
+    assert len(wired) == 6
+    for name in wired:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["reducer"] in ("trace_share", "named_share", "span_percentile", "span_count"), name
+        assert m["cells"] == ["acoustic-so8-1200x4*"] and m["moves"] == "mcells_per_s_chip", name
+        assert declared[name]["workloads"] == ["acoustic-so8-1200x4.bulk"], name
+    wired -= {"collective_pct.wired"}  # a trace_share: it reads opcodes, as PR 24's do
+    for name in set(declared) - new - plane - staged - setup - wired - (ragged - {"collective_pct.ragged"}):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
                                                "trace_roofline_hbm", "trace_idle"), name

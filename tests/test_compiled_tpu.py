@@ -370,3 +370,46 @@ def test_compiled_acoustic_step_renames_u_prev(steps, monkeypatch):
     for name, a, b in zip(("u", "u_prev"), got, want):
         assert np.isfinite(b).all() and float(np.max(np.abs(b))) > 0.01, name
         assert np.array_equal(a, b), name
+
+
+def test_compiled_acoustic_step_across_four_chips():
+    """The acoustic shot decomposed over four chips (1200 x 1200 x 600 on mesh
+    [2,2,1], 600^3 a chip: ISSUE 37), run by hand on a four-chip host: the
+    compiled plane step -- ``u``'s radius-4 x and y halos over ICI every step,
+    the z wrap inside the pass, ``u_prev <- u`` a rename inside a loop body
+    that holds collectives -- against the XLA slice engine on the same mesh,
+    bitwise on EVERY RAW CELL of ``u`` and ``u_prev``, halos included (after
+    ``dd.exchange()``: the plane step leaves the shells of what it does not
+    read stale), after an even dispatch of 8 steps and again after an odd one
+    of 9 behind it; ``m`` and ``damp`` raw at the end.  The wave cells'
+    ``correct`` compares interiors; this holds the wires to every shell cell."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs the four chips of one host")
+    from stencil_tpu.models.acoustic import QUANTITIES, AcousticWave
+
+    def run(impl):
+        sim = AcousticWave(1200, 1200, 600, devices=jax.devices()[:4], kernel_impl=impl,
+                           seed_words=(0x1234ABCD, 77, 0xDEADBEEF, 2024))
+        sim.realize()
+        assert tuple(sim.dd.mesh_dim()) == (2, 2, 1), sim.dd.mesh_dim()
+        seen = []
+        for steps, names in ((8, ("u", "u_prev")), (9, QUANTITIES)):
+            sim.step(steps)
+            sim.dd.exchange()
+            seen.append({q: np.asarray(sim.dd._curr[q]) for q in names})  # 3.6 GB each, on the host
+        plan = getattr(sim._step, "_stream_plan", None)
+        args = getattr(sim._step, "_span_args", dict)()
+        for slot in (sim.dd._curr, sim.dd._next or {}):
+            for block in slot.values():
+                block.delete()
+        return plan, args, seen
+
+    plan, args, got = run("pallas")
+    assert plan["route"] == "plane" and plan["pass_wrap_axes"] == "z", plan
+    assert plan["renamed"] == ("u_prev",) and plan["halo_readers"] == ("u",), plan
+    assert (args["wired"], args["wire_bytes"]) == ("xy", 2 * 2 * 4 * 608 * 608 * 4), args
+    _, _, want = run("jnp")
+    for k, (a, b) in enumerate(zip(got, want)):
+        for q in b:
+            assert np.isfinite(b[q]).all() and float(np.max(np.abs(b[q]))) > 1e-4, (q, k)
+            assert np.array_equal(a[q], b[q]), (q, k, float(np.max(np.abs(a[q] - b[q]))))

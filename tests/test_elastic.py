@@ -81,6 +81,13 @@ def test_model_matches_the_reference_on_a_2x2x1_mesh(impl):
     global coordinates on every shard."""
     sim = _sim(impl, devices=jax.devices()[:4], partition=(2, 2, 1))
     assert tuple(sim.dd.mesh_dim()) == (2, 2, 1)
+    if impl == "pallas":
+        # both stages' messages: 6 stresses, then 3 velocities, four radius-4
+        # faces of the raw block each (ISSUE 37)
+        raw = sim.dd.local_spec().raw_size()
+        args = sim._step._span_args()
+        assert (args["wired"], args["exchanged"]) == ("xy", "6/3"), args
+        assert args["wire_bytes"] == (6 + 3) * 2 * 4 * (raw.y * raw.z + raw.x * raw.z) * 4, args
     errs = _errors(sim, 1)
     assert max(errs.values()) <= ATOL, errs
 
@@ -134,6 +141,7 @@ def test_the_plan_is_staged_and_the_span_says_so():
         "stages": 2, "passes": 2, "exchanged": "6/3", "written": "3/6", "aliased": "11/12",
         "renamed": "0/0",  # every output is masked by the frame: none is a centre plane (ISSUE 36)
         "wrapped": "",  # a plain CPU run: the blend kernels are off (ISSUE 34)
+        "wired": "", "wire_bytes": 0,  # one device: nothing crosses to another shard (ISSUE 37)
     }
     seen = []
     real = telemetry.span

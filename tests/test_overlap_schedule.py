@@ -279,6 +279,59 @@ def test_acoustic_step_carries_its_blocks_in_place(monkeypatch):
 
 
 @pytest.mark.slow  # tier-2 with its siblings: a real-TPU-compiler AOT
+# compile at the benchmark's size for all four chips (5 s alone)
+def test_acoustic_step_over_four_chips_carries_its_blocks_in_place(monkeypatch):
+    """The four-chip acoustic cell's 8-step program (1200 x 1200 x 600 on mesh
+    [2,2,1], 600^3 a chip: ISSUE 37) as the chip's compiler leaves it: the
+    rename survives a loop body that holds collectives -- the ``while`` body
+    is two steps, each FOUR ``collective-permute``s (x low / high, then y low
+    / high, of ``u`` alone), two ``blend_planes``, two ``blend_slab`` and one
+    ``stream_plane_pass`` whose one result aliases raw ``u_prev``; no ``copy``
+    of ``f32[608,608,608]`` anywhere and nothing temporary (the messages live
+    in the loop's own buffers); the only copies are the x message's relayouts,
+    ``f32[1,2432,608]``.  The check ISSUE 37 asks for before any chip call."""
+    from stencil_tpu.models.acoustic import RADIUS, AcousticWave
+    from stencil_tpu.ops import halo_blend
+    from stencil_tpu.ops import stream as sm
+
+    devices = _topology_devices()
+    monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        sim = AcousticWave(1200, 1200, 600, devices=devices, seed_words=None)
+        sim.dd.realize(allocate=False)
+        assert tuple(sim.dd.mesh_dim()) == (2, 2, 1)  # the partitioner's own pick
+        plan = sm.plan_stream(sim.dd, RADIUS, "auto", False)
+        step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
+        compiled = step.lower(sim.dd.abstract_arrays(), 8).compile()
+        text, temp = compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    assert (plan["route"], plan["pass_wrap_axes"], plan["wired"]) == ("plane", "z", "xy"), plan
+    assert plan["wire_bytes"] == 2 * 2 * 4 * 608 * 608 * 4
+    assert plan["renamed"] == ("u_prev",) and plan["halo_readers"] == ("u",), plan
+
+    def custom_calls(name):
+        return [
+            l for l in text.splitlines()
+            if "custom-call(" in l and "tpu_custom_call" in l and l.lstrip().startswith("%" + name)
+        ]
+
+    passes = custom_calls("stream_plane_pass")
+    assert len(passes) == 2  # a trip of the loop is two steps
+    for line in passes:
+        assert line.lstrip().split(" custom-call(")[0].count("f32[608,608,608]") == 1
+        assert "output_to_operand_aliasing={{}: (2, {})}, " in line
+    assert len(custom_calls("blend_planes")) == 4 and len(custom_calls("blend_slab")) == 4
+    assert len(custom_calls("")) == 10  # and no other kernel
+    assert len(re.findall(r"=.*collective-permute-start\(", text)) == 8
+    assert not re.findall(r"=\s+f32\[608,608,608\]\S*\s+copy\(", text) and temp == 0
+    copied = set(re.findall(r"=\s+(f32\[[\d,]+\])\S*\s+copy\(", text))
+    assert copied <= {"f32[1,2432,608]"}, copied
+
+
+@pytest.mark.slow  # tier-2 with its siblings: a real-TPU-compiler AOT
 # compile at the benchmark's size (12 s alone)
 def test_elastic_step_carries_its_blocks_in_place(monkeypatch):
     """The elastic cell's step (600^3, thirteen quantities, two stages) as

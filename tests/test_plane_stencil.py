@@ -300,6 +300,26 @@ def test_plane_route_exchanges_only_what_the_kernel_reads(
     assert wires_all > 0 and wires * len(names) == wires_all * len(readers), (wires, wires_all)
 
 
+@pytest.mark.parametrize("n_dev", [1, 2, 4, 8])
+def test_the_plan_says_what_crosses_a_wire(n_dev):
+    """``wired`` / ``wire_bytes`` (ISSUE 37) against the traced program, with
+    the sweeps as the chip has them (blend kernels on: an unsplit axis wraps
+    and sends nothing): the axes the plan names are the axes the mesh splits,
+    and the bytes it states are the ``ppermute`` operands of one step -- the
+    two readers' slabs of three quantities, both sides of every wired axis."""
+    from stencil_tpu.analysis.programs import tpu_shaped_trace
+    from stencil_tpu.parallel.mesh import MESH_AXES
+
+    with tpu_shaped_trace():
+        dd, _ = _plane_domain(["a", "b", "c"], 2, n_dev, extent=(32, 32, 16))
+        step, plan = _plane_step(dd, two_of_three_kernel(2), 2, {})
+        cells = _ppermute_cells(step, dd._curr)
+    split = "".join(a for a, m in zip(MESH_AXES, dd.mesh_dim()) if m > 1)
+    assert plan["halo_readers"] == ("a", "b") and plan["wired"] == split, plan
+    assert plan["wire_bytes"] == 4 * cells and (cells > 0) == (n_dev > 1), (plan, cells)
+    assert not set(plan["wired"]) & set(plan["pass_wrap_axes"]), plan
+
+
 def test_a_kernel_that_reads_nothing_off_centre_exchanges_nothing():
     """``exchanged`` 0 through ``make_step`` and no ``exchange.*`` scope (nor
     any collective) anywhere in the step's program."""

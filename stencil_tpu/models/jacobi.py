@@ -30,6 +30,46 @@ COLD_TEMP = 0.0
 HOT_TEMP = 1.0
 
 
+def _macros_per_trip(in_place: bool) -> int:
+    """After how many macros a macro loop's carry is back in its own buffer:
+    1 where the kernel writes in place, 2 where it writes a fresh result
+    (``_macro_loop``)."""
+    return 1 if in_place else 2
+
+
+def _macro_loop(macro, macros: int, carry, per_trip: int):
+    """``macros`` applications of ``macro`` to ``carry`` as one ``fori_loop``
+    of ``per_trip`` macros a trip (``_macros_per_trip``: as many as it takes
+    for the carry to be back in its own buffers), the handles passed on in
+    Python between them, what is left over unrolled behind the loop.
+
+    A ``while`` wants its carry back in the buffer it came in.  With ONE
+    fresh-result kernel call a trip, result and operand are alive together
+    and cannot share a buffer, so XLA copies a whole block every trip to put
+    the result where the carry lives (10.4% of ``jacobi3d-512.bulk``'s busy
+    time, 7.2% of its four-chip twin: PERF.md, PR 38).  The SECOND result of
+    a trip is born after the trip's operand has died and takes its buffer:
+    the carry comes home and nothing is copied (``ops/stream.py
+    _carry_period`` is the same count for the plane route's renames).  With
+    an odd ``macros`` the last result flows into the program's edge (the
+    ``dynamic_update_slice`` / ``pad`` of the dispatch), which has no carry
+    to honour: XLA may copy there, once a DISPATCH -- dispatch an even count
+    of macros."""
+    from jax import lax
+
+    def trip(_, c):
+        for _ in range(per_trip):
+            c = macro(c)
+        return c
+
+    trips, behind = divmod(macros, per_trip)
+    if trips:
+        carry = lax.fori_loop(0, trips, trip, carry)
+    for _ in range(behind):
+        carry = macro(carry)
+    return carry
+
+
 class Jacobi3D:
     def __init__(
         self,
@@ -323,7 +363,19 @@ class Jacobi3D:
         after the z ppermute, each slab is extended with rows from the y
         neighbors and then planes from the x neighbors (two hops carry the
         xyz-corner cells from the diagonal blocks), mirroring the sweep
-        order of the in-array exchange."""
+        order of the in-array exchange.
+
+        The macro loop (``_macro_loop``) runs ``_macros_per_trip`` macros a
+        ``fori_loop`` trip: TWO while the kernel writes a fresh result (the
+        default: in place serialises the deep-m pipeline, see ``alias``
+        below), so that the second result lands in the buffer the trip's
+        operand died in and the carry is back in its own place -- with one a
+        trip XLA copied the whole block every macro (7.2% of
+        ``jacobi3d-512x4.bulk``); ONE where ``alias`` resolves true, the loop
+        that was always there.  The kernels themselves stay un-aliased: an
+        ``input_output_aliases`` would bring the serialisation back, and the
+        benchmark's ``pallas_hbm_pct`` reads only calls whose result aliases
+        no operand.  ``domain.step`` says ``macros_per_trip``."""
         from functools import partial
 
         import jax
@@ -388,6 +440,7 @@ class Jacobi3D:
                 alias = bool(tuned["alias"])
             else:
                 alias = False
+        per_trip = _macros_per_trip(alias)
         self._marks_shell_stale = True
         self._pallas_path = "wavefront"
         self._wavefront_z_slabs = z_slab_mode
@@ -458,8 +511,8 @@ class Jacobi3D:
                     )
 
                 macros, rem = divmod(steps, depth_run)
-                b = lax.fori_loop(
-                    0, macros, lambda _, b: macro_plain(depth_run, b), raw_block
+                b = _macro_loop(
+                    partial(macro_plain, depth_run), macros, raw_block, per_trip
                 )
                 if rem:
                     b = macro_plain(rem, b)
@@ -495,8 +548,8 @@ class Jacobi3D:
                 )  # drop the z-shell columns from the streamed array
                 carry = (b0, prime_z_slabs(raw_block, Zr, m))
                 macros, rem = divmod(steps, depth_run)
-                carry = lax.fori_loop(
-                    0, macros, lambda _, c: macro_ring(depth_run, c), carry
+                carry = _macro_loop(
+                    partial(macro_ring, depth_run), macros, carry, per_trip
                 )
                 if rem:
                     carry = macro_ring(rem, carry)
@@ -527,7 +580,7 @@ class Jacobi3D:
                 prime_z_slabs(raw_block, Zr, m),
             )
             macros, rem = divmod(steps, depth_run)
-            carry = lax.fori_loop(0, macros, lambda _, c: macro(depth_run, c), carry)
+            carry = _macro_loop(partial(macro, depth_run), macros, carry, per_trip)
             if rem:
                 carry = macro(rem, carry)
             return carry[0][:, :, :Zr]
@@ -546,6 +599,7 @@ class Jacobi3D:
             )
             return {name: fn(curr[name])}
 
+        step._span_args = lambda: {"macros_per_trip": per_trip}
         return step
 
     def _make_pallas_step(self):
@@ -572,6 +626,18 @@ class Jacobi3D:
           constraint.
         * ``shell`` — fallback (uneven/padded sizes, or shards with < 2
           x-planes): the general shell-carrying exchange + plane kernel.
+
+        The wrap route's macro loop (``_macro_loop``) runs TWO k-level
+        ``jacobi_wrap_step`` calls a ``fori_loop`` trip: the kernel writes a
+        fresh result (in place, the replay at its wrap-around would re-read
+        planes it has already overwritten), and the second result of a trip
+        takes the buffer the trip's operand died in, so the carry is back in
+        its own place -- with one a trip XLA copied the whole block every
+        macro (10.4% of ``jacobi3d-512.bulk``).  The kernel stays un-aliased, onto its input
+        or anything else: the benchmark's ``pallas_hbm_pct`` reads only calls
+        whose result aliases no operand.  An odd macro runs behind the loop,
+        before the ``steps % k`` remainder; ``domain.step`` says
+        ``macros_per_trip``.
         """
         from functools import partial
 
@@ -631,6 +697,7 @@ class Jacobi3D:
             )
             self._wrap_k = k
             f32_acc = dd.field_dtype(self.h) != self.h.dtype
+            per_trip = _macros_per_trip(False)  # the wrap kernel never writes in place
 
             @partial(jax.jit, static_argnums=1, donate_argnums=0)
             def step(curr, steps: int = 1):
@@ -643,15 +710,13 @@ class Jacobi3D:
                 # level's arithmetic is identical to a k=1 pass, so any
                 # (blocked, remainder) split is bit-exact vs k=1.
                 blocked, rem = divmod(steps, k)
-                if blocked:
-                    block = lax.fori_loop(
-                        0,
-                        blocked,
-                        lambda _, b: jacobi_wrap_step(
-                            b, interpret=interpret, k=k, f32_accumulate=f32_acc
-                        ),
-                        block,
-                    )
+                block = _macro_loop(
+                    partial(
+                        jacobi_wrap_step, interpret=interpret, k=k,
+                        f32_accumulate=f32_acc,
+                    ),
+                    blocked, block, per_trip,
+                )
                 if rem:
                     # one k=rem wavefront (rem < k <= X//2 so always valid);
                     # bit-exact and one HBM pass instead of rem
@@ -661,6 +726,7 @@ class Jacobi3D:
                 # stencil-lint: disable=sliver-dus whole-interior write-back into the shell-carrying array after the k-loop — block spans the full interior, not a y/z sliver
                 return {name: lax.dynamic_update_slice(arr, block, (lo.x, lo.y, lo.z))}
 
+            step._span_args = lambda: {"macros_per_trip": per_trip}
             return step
         if want in ("auto", "slab") and (
             all(v is None for v in dd._valid_last)

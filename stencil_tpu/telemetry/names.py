@@ -431,7 +431,11 @@ ALL_HISTOGRAMS = frozenset({
 #: with a sequence of kernels) adds stages and passes, and says exchanged /
 #: written / renamed / aliased PER STAGE, in order: "6/3", "3/6", "0/0",
 #: "11/12" (``wrapped``, ``wired`` and ``wire_bytes`` are one value each: the
-#: first two functions of the mesh, the last summed over the stages)]
+#: first two functions of the mesh, the last summed over the stages); a
+#: ``Jacobi3D`` wrap or wavefront step adds macros_per_trip = the macros one
+#: trip of its device-side macro loop runs, as many as it takes for the carry
+#: to be back in its own buffer (``models/jacobi._macro_loop``): 2 where the
+#: kernel writes a fresh result, 1 where it writes in place (``alias``)]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes

@@ -108,10 +108,12 @@ def _jacobi_wrap():
 
     from stencil_tpu.models.jacobi import Jacobi3D
 
+    # depth 3: MODEL_STEPS is one trip of the macro loop (two macros: ISSUE
+    # 38) and a remainder, so the fingerprint holds the loop
     m = Jacobi3D(16, 16, 16, kernel_impl="pallas", interpret=True,
-                 devices=jax.devices()[:1])
+                 devices=jax.devices()[:1], temporal_k=3)
     m.realize()
-    assert m._pallas_path == "wrap", m._pallas_path
+    assert m._pallas_path == "wrap" and m._wrap_k == 3, m._pallas_path
     return _trace_step(m.dd, m._step)
 
 
@@ -121,10 +123,10 @@ def _jacobi_zring():
     from stencil_tpu.models.jacobi import Jacobi3D
 
     m = Jacobi3D(32, 32, 128, kernel_impl="pallas", interpret=True,
-                 devices=jax.devices()[:4])
+                 devices=jax.devices()[:4], temporal_k=3)  # a trip and a remainder, as above
     m.dd.set_partition(2, 2, 1)
     m.realize()
-    assert m._pallas_path == "wavefront" and m._wavefront_z_ring
+    assert m._pallas_path == "wavefront" and m._wavefront_z_ring and m._wavefront_m == 3
     return _trace_step(m.dd, m._step)
 
 

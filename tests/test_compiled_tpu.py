@@ -413,3 +413,44 @@ def test_compiled_acoustic_step_across_four_chips():
         for q in b:
             assert np.isfinite(b[q]).all() and float(np.max(np.abs(b[q]))) > 1e-4, (q, k)
             assert np.array_equal(a[q], b[q]), (q, k, float(np.max(np.abs(a[q] - b[q]))))
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_compiled_jacobi_macro_loop_is_bitwise_one_a_trip(chips, monkeypatch):
+    """Both jacobi cells of the benchmark as Mosaic and XLA compile them, run
+    by hand (one chip: 512^3, wrap route, k=16; a four-chip host: 1024 x 1024
+    x 512 on mesh [2,2,1], z-ring wavefront, depth 16): the macro loop runs
+    TWO macros a trip so that the fresh result lands in the carry's own buffer
+    (ISSUE 38), and every cell is bitwise what the same model gives with ONE
+    macro a trip (the parent's program, a whole-block copy a trip) -- after the
+    cell's own dispatch (256 / 160 steps: 16 / 10 macros, whole trips) and
+    again after an odd one behind it (17 / 11 macros and a remainder of 5: the
+    last macro and the remainder run behind the loop).  Whose buffer a result
+    takes is what CPU interpret mode cannot show."""
+    if len(jax.devices()) < chips:
+        pytest.skip("needs the four chips of one host")
+    from stencil_tpu.models import jacobi as jm
+    from stencil_tpu.models.jacobi import Jacobi3D
+
+    size, macros = ((512, 512, 512), 16) if chips == 1 else ((1024, 1024, 512), 10)
+
+    def run():
+        sim = Jacobi3D(*size, devices=jax.devices()[:chips], kernel_impl="pallas")
+        sim.realize()
+        depth = sim._wrap_k if chips == 1 else sim._wavefront_m
+        assert (sim._pallas_path, depth) == ("wrap" if chips == 1 else "wavefront", 16)
+        assert chips == 1 or (sim._wavefront_z_ring and tuple(sim.dd.mesh_dim()) == (2, 2, 1))
+        seen = []
+        for steps in (macros * 16, (macros + 1) * 16 + 5):
+            sim.step(steps)
+            seen.append(sim.temperature())
+        return sim._step._span_args(), seen
+
+    args, got = run()
+    assert args == {"macros_per_trip": 2}
+    monkeypatch.setattr(jm, "_macros_per_trip", lambda in_place: 1)
+    args_one, want = run()
+    assert args_one == {"macros_per_trip": 1}
+    for a, b in zip(got, want):
+        assert np.isfinite(b).all() and 0.0 <= b.min() < 0.4 and 0.6 < b.max() <= 1.0
+        assert np.array_equal(a, b), float(np.max(np.abs(a - b)))

@@ -331,6 +331,108 @@ def test_acoustic_step_over_four_chips_carries_its_blocks_in_place(monkeypatch):
     assert copied <= {"f32[1,2432,608]"}, copied
 
 
+def _jacobi_cell_step(chips, devices):
+    """The step of a jacobi cell of the benchmark, built for described
+    devices: ``Jacobi3D.realize()`` without the allocation and the fill."""
+    from stencil_tpu.models.jacobi import Jacobi3D
+
+    if chips == 1:
+        sim = Jacobi3D(512, 512, 512, devices=devices[:1], kernel_impl="pallas")
+        sim._wavefront_m = 0
+        sim._resolve_storage()
+        sim.dd.realize(allocate=False)
+        return sim, sim._make_pallas_step()
+    sim = Jacobi3D(1024, 1024, 512, devices=devices, kernel_impl="pallas")
+    sim._resolve_storage()
+    sim._wavefront_m = sim._plan_wavefront()
+    sim.dd.set_halo_multiplier(sim._wavefront_m)
+    sim.dd.realize(allocate=False)
+    return sim, sim._make_wavefront_step()
+
+
+@pytest.mark.slow  # tier-2 with its siblings: real-TPU-compiler AOT compiles
+# at the benchmark's sizes, six of them (15-40 s each)
+@pytest.mark.parametrize("chips", [1, 4])
+def test_jacobi_macro_loop_carries_its_block_in_place(chips, monkeypatch):
+    """Both jacobi cells' dispatch as the chip's compiler leaves it (ISSUE 38):
+    256 steps of the wrap route for a described v5e, 160 steps of the z-ring
+    wavefront for a described v5e:2x2.  The ``while`` body holds TWO stencil
+    calls -- the second result takes the buffer the trip's operand died in, so
+    the carry is back in its own place -- and NO ``copy`` of the whole block
+    (``f32[512,512,512]`` / ``f32[544,544,512]``), the stencil call aliases no
+    operand (``pallas_hbm_pct`` reads only fresh results), and nothing is
+    temporary that the one-a-trip loop did not hold: that control has one
+    stencil call and one whole-block copy a trip (10.4% / 7.2% of the cells'
+    busy time: PERF.md, PR 38).  An odd macro count (17 / 11) runs its last
+    macro behind the loop: no copy either, but on four chips 0.9 GB more of
+    temporaries at the program's edge -- dispatch an even count."""
+    from stencil_tpu.models import jacobi as jm
+
+    devices = _topology_devices()
+    shape, kernel, k, macros = {
+        1: ("f32[512,512,512]", "jacobi_wrap_step", 16, 16),
+        4: ("f32[544,544,512]", "jacobi_zring_wavefront_step", 16, 10),
+    }[chips]
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        got = {}
+        for per_trip, count in ((None, macros), (None, macros + 1), (1, macros)):
+            with monkeypatch.context() as mp:
+                if per_trip is not None:
+                    mp.setattr(jm, "_macros_per_trip", lambda in_place: per_trip)
+                sim, step = _jacobi_cell_step(chips, devices)
+                assert (sim._pallas_path, getattr(sim, "_wrap_k", sim._wavefront_m)) == (
+                    "wrap" if chips == 1 else "wavefront", k)
+                assert chips == 1 or (sim._wavefront_z_ring and tuple(sim.dd.mesh_dim()) == (2, 2, 1))
+                compiled = step.lower(sim.dd.abstract_arrays(), count * k).compile()
+            got[per_trip, count] = (
+                compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes,
+                step._span_args(),
+            )
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    big_copy = re.compile(rf"=\s+{re.escape(shape)}\S*\s+copy\(")
+
+    def stencil_calls(text):
+        return [
+            l for l in text.splitlines()
+            if "custom-call(" in l and "tpu_custom_call" in l and l.lstrip().startswith("%" + kernel)
+        ]
+
+    def in_the_loop(text):
+        """Lines of the computations a ``while`` runs as its body."""
+        lines = text.splitlines()
+        bodies = set(re.findall(r"\bwhile\([^\n]*body=%?([\w.\-]+)", text))
+        assert bodies, "the macro loop is gone"
+        out = []
+        for i, l in enumerate(lines):
+            m = re.match(r"%?([\w.\-]+) \(", l.lstrip())
+            if m and m.group(1) in bodies and l.rstrip().endswith("{"):
+                out += lines[i:_computation_block(lines, i + 1)[1]]
+        return "\n".join(out)
+
+    text, temp, args = got[None, macros]
+    assert args == {"macros_per_trip": 2}
+    assert len(stencil_calls(text)) == 2 == len(stencil_calls(in_the_loop(text)))
+    assert not big_copy.findall(text)
+    assert not any("output_to_operand_aliasing" in l for l in stencil_calls(text))
+    text_one, temp_one, args_one = got[1, macros]
+    assert args_one == {"macros_per_trip": 1}
+    assert len(stencil_calls(text_one)) == 1
+    assert len(big_copy.findall(in_the_loop(text_one))) == 1
+    # the same two blocks taking turns; on four chips a second pair of z-slab buffers
+    assert temp_one <= temp <= temp_one * 1.002, (temp, temp_one)
+    if chips == 4:
+        assert len(re.findall(r"=.*collective-permute-start\(", text)) == 2 * len(
+            re.findall(r"=.*collective-permute-start\(", text_one)
+        )
+    text_odd, temp_odd, _ = got[None, macros + 1]
+    assert len(stencil_calls(text_odd)) == 3 and len(stencil_calls(in_the_loop(text_odd))) == 2
+    assert not big_copy.findall(text_odd)
+    assert temp_odd <= (temp * 1.002 if chips == 1 else temp + 1.0e9), (temp_odd, temp)
+
+
 @pytest.mark.slow  # tier-2 with its siblings: a real-TPU-compiler AOT
 # compile at the benchmark's size (12 s alone)
 def test_elastic_step_carries_its_blocks_in_place(monkeypatch):

@@ -91,7 +91,11 @@ def check_vmem(dd, plan: dict, budget: Optional[int] = None) -> Optional[str]:
                 f"against the {cap / 1e6:.1f} MB budget"
             )
         return None
-    raw = dd.local_spec().raw_size()
+    # the planes the pass streams, as plan_stream models them: the wrap
+    # route works on the bare interiors (the periodic boundary is folded into
+    # its index maps), the wavefront route on the raw, shell-carrying planes
+    spec = dd.local_spec()
+    planes = spec.sz if route == "wrap" else spec.raw_size()
     itemsizes: List[int] = [dd.field_dtype(h).itemsize for h in dd._handles]
     ring_sizes: List[int] = [h.dtype.itemsize for h in dd._handles]
     if plan.get("grouping") == "per-field" and len(itemsizes) > 1:
@@ -99,8 +103,8 @@ def check_vmem(dd, plan: dict, budget: Optional[int] = None) -> Optional[str]:
         ring_sizes = [max(ring_sizes)]
     est = stream_plan_vmem_bytes(
         m,
-        raw.y,
-        raw.z,
+        planes.y,
+        planes.z,
         itemsizes,
         z_slabs=bool(plan.get("z_slabs")),
         ring_itemsizes=ring_sizes,

@@ -24,50 +24,12 @@ from jax import shard_map
 from stencil_tpu.core.dim3 import Dim3
 from stencil_tpu.core.radius import Radius
 from stencil_tpu.domain import DistributedDomain
+from stencil_tpu.ops.stream import macro_loop as _macro_loop
+from stencil_tpu.ops.stream import macros_per_trip as _macros_per_trip
 from stencil_tpu.utils.config import MethodFlags, PlacementStrategy
 
 COLD_TEMP = 0.0
 HOT_TEMP = 1.0
-
-
-def _macros_per_trip(in_place: bool) -> int:
-    """After how many macros a macro loop's carry is back in its own buffer:
-    1 where the kernel writes in place, 2 where it writes a fresh result
-    (``_macro_loop``)."""
-    return 1 if in_place else 2
-
-
-def _macro_loop(macro, macros: int, carry, per_trip: int):
-    """``macros`` applications of ``macro`` to ``carry`` as one ``fori_loop``
-    of ``per_trip`` macros a trip (``_macros_per_trip``: as many as it takes
-    for the carry to be back in its own buffers), the handles passed on in
-    Python between them, what is left over unrolled behind the loop.
-
-    A ``while`` wants its carry back in the buffer it came in.  With ONE
-    fresh-result kernel call a trip, result and operand are alive together
-    and cannot share a buffer, so XLA copies a whole block every trip to put
-    the result where the carry lives (10.4% of ``jacobi3d-512.bulk``'s busy
-    time, 7.2% of its four-chip twin: PERF.md, PR 38).  The SECOND result of
-    a trip is born after the trip's operand has died and takes its buffer:
-    the carry comes home and nothing is copied (``ops/stream.py
-    _carry_period`` is the same count for the plane route's renames).  With
-    an odd ``macros`` the last result flows into the program's edge (the
-    ``dynamic_update_slice`` / ``pad`` of the dispatch), which has no carry
-    to honour: XLA may copy there, once a DISPATCH -- dispatch an even count
-    of macros."""
-    from jax import lax
-
-    def trip(_, c):
-        for _ in range(per_trip):
-            c = macro(c)
-        return c
-
-    trips, behind = divmod(macros, per_trip)
-    if trips:
-        carry = lax.fori_loop(0, trips, trip, carry)
-    for _ in range(behind):
-        carry = macro(carry)
-    return carry
 
 
 class Jacobi3D:

@@ -153,10 +153,11 @@ class PlaneView:
     offsets are in-plane rotates.  Rotate wraparound at the plane edges only
     contaminates shell cells the validity contract already sacrifices.
 
-    ``off_centre`` is called, at trace time, on every read with a non-zero
-    offset (``center()`` and ``sh(0, 0, 0)`` never call it): the plane
-    route's footprint trace records the quantity there, and its pass raises
-    there for a quantity whose halo was not filled (``trace_plane_kernel``).
+    ``off_centre(dx, dy, dz)`` is called, at trace time, on every read with
+    a non-zero offset (``center()`` and ``sh(0, 0, 0)`` never call it): the
+    footprint trace records the quantity and the offset there, and the plane
+    pass raises there for a quantity whose halo was not filled
+    (``trace_plane_kernel``).
     A window plane may be ``None``: the pass holds no ring for a quantity
     its kernel reads at ``dx == 0`` only, and ``no_ring`` is called on a read
     of such a plane (it raises, naming the quantity).
@@ -179,7 +180,7 @@ class PlaneView:
             (dx, dy, dz), self._r,
         )
         if self._off_centre is not None and (dx or dy or dz):
-            self._off_centre()
+            self._off_centre(dx, dy, dz)
         v = self._window[self._r + dx]
         if v is None:
             self._no_ring()
@@ -442,7 +443,7 @@ def stream_plane_pass(
         if halo_readers is None or name in halo_readers:
             return None
 
-        def fail():
+        def fail(*offset):
             raise ValueError(
                 f"the kernel reads {name!r} off-centre, but its footprint "
                 f"trace did not (it saw {tuple(halo_readers)}), so the halo "
@@ -1261,6 +1262,8 @@ class PlaneTrace:
     kernel: PlaneKernel  # the user's callable (run as is when ``closed`` is None)
     renames: Tuple[Tuple[str, str], ...] = ()  # ``(p, q)``: output ``p`` IS the
     # centre plane of ``q``, a writer with a value of its own (``_plane_renames``)
+    offsets: Tuple[Tuple[str, tuple], ...] = ()  # per reader, the ``(dx, dy, dz)``
+    # it is read at off-centre (``footprint_counts``)
 
     def pruned(self, outputs: Sequence[str]):
         """``(kernel, reads, rings)`` of the pass that writes ``outputs``:
@@ -1379,16 +1382,19 @@ def trace_plane_kernel(
     trace time, on an off-centre read or a returned name it was not told of
     (``stream_plane_pass(halo_readers=, writers=, rings=)``)."""
     names = tuple(names)
-    seen, returned = set(), []
+    seen, returned = {}, []  # seen: reader -> the offsets it is read at
     roll = _make_roll(interpret)
     r, w = x_radius, 2 * x_radius + 1
     Y, Z = planes[0].shape
+
+    def note(nm, dx, dy, dz):
+        seen.setdefault(nm, set()).add((dx, dy, dz))
 
     def footprint(x_g, y_g, z_g, *vs):
         info = PlaneInfo(x_g, y_g, z_g, global_size, 1)
         vals = kernel(
             {
-                nm: PlaneView(tuple(vs[q * w : (q + 1) * w]), roll, partial(seen.add, nm))
+                nm: PlaneView(tuple(vs[q * w : (q + 1) * w]), roll, partial(note, nm))
                 for q, nm in enumerate(names)
             },
             info,
@@ -1420,7 +1426,35 @@ def trace_plane_kernel(
         closed,
         kernel,
         _plane_renames(closed.jaxpr, names, tuple(returned), r, stored),
+        tuple((nm, tuple(sorted(seen[nm]))) for nm in names if nm in seen),
     )
+
+
+def footprint_counts(traces: Sequence[PlaneTrace]) -> Optional[dict]:
+    """What a step's kernels read off-centre, counted from their footprint
+    traces (every stage, every group): ``offcentre`` -- the quantities read at
+    a non-zero offset; ``diagonal`` -- those of them read at an offset with
+    two or more non-zero components (an EDGE or corner halo: only the full
+    x, then y, then z sweep order fills it); ``read_sides`` -- the distinct
+    (quantity, axis, side) triples read, where an exchange that serves a
+    reader at all serves six.  None where a trace raised (nothing is known).
+    D3Q19 lattice Boltzmann: 18, 12, 30; a 7-point star: 1, 0, 6."""
+    if any(t.closed is None for t in traces):
+        return None
+    offsets = {}
+    for t in traces:
+        for nm, offs in t.offsets:
+            offsets.setdefault(nm, set()).update(offs)
+    sides = {
+        (nm, a, o[a] > 0) for nm, offs in offsets.items() for o in offs for a in range(3) if o[a]
+    }
+    return {
+        "offcentre": len(offsets),
+        "diagonal": sum(
+            any(sum(1 for c in o if c) >= 2 for o in offs) for offs in offsets.values()
+        ),
+        "read_sides": len(sides),
+    }
 
 
 def _plane_renames(jaxpr, names, writers, x_radius: int, stored: dict):
@@ -1832,7 +1866,7 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
         h.name: _padded_plane_bytes(raw.y, raw.z, dd.field_dtype(h).itemsize)
         for h in dd._handles
     }
-    described, built = [], []
+    described, built, traces = [], [], []
     for stage in _as_stages(kernel):
         readers, passes, runs = set(), [], []
         for g in _stream_groups(plan, len(names)):
@@ -1840,6 +1874,7 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
                 stage, [names[q] for q in g], [planes[q] for q in g], x_radius,
                 dd._size, interpret, [dd.field_dtype(dd._handles[q]) for q in g],
             )
+            traces.append(trace)
             readers |= set(names) if fused else set(trace.readers)
             for p in plan_plane_passes(trace, plane_bytes, whole=fused, rename=rename):
                 passes.append(p)
@@ -1853,6 +1888,7 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
         })
         built.append(runs)
     plan["stages"] = tuple(described)
+    plan["footprint"] = footprint_counts(traces)
     for key, of in (
         ("halo_readers", lambda st: st["readers"]),
         ("writers", lambda st: [w for p in st["passes"] for w in p["writes"]]),
@@ -1861,6 +1897,49 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
         union = {nm for st in described for nm in of(st)}
         plan[key] = tuple(nm for nm in names if nm in union)
     return built
+
+
+def macros_per_trip(in_place: bool) -> int:
+    """After how many macros a macro loop's carry is back in its own buffer:
+    1 where the kernel writes in place, 2 where it writes a fresh result
+    (``macro_loop``)."""
+    return 1 if in_place else 2
+
+
+def macro_loop(macro, macros: int, carry, per_trip: int):
+    """``macros`` applications of ``macro`` to ``carry`` as one ``fori_loop``
+    of ``per_trip`` macros a trip (``macros_per_trip``: as many as it takes
+    for the carry to be back in its own buffers), the handles passed on in
+    Python between them, what is left over unrolled behind the loop.  The
+    loop of ``models/jacobi.py``'s bespoke kernels and of the stream engine's
+    wrap route.
+
+    A ``while`` wants its carry back in the buffer it came in.  With ONE
+    fresh-result kernel call a trip, result and operand are alive together
+    and cannot share a buffer, so XLA copies a whole block every trip to put
+    the result where the carry lives (10.4% of ``jacobi3d-512.bulk``'s busy
+    time, 7.2% of its four-chip twin: PERF.md, PR 38; nineteen such copies a
+    trip, as much traffic as the pass itself, in the wrap route's LBM step
+    cross-compiled at 256^3: PERF.md, PR 39).  The SECOND result of
+    a trip is born after the trip's operand has died and takes its buffer:
+    the carry comes home and nothing is copied (``_carry_period`` is the
+    same count for the plane route's renames).  With
+    an odd ``macros`` the last result flows into the program's edge (the
+    ``dynamic_update_slice`` / ``pad`` of the dispatch), which has no carry
+    to honour: XLA may copy there, once a DISPATCH -- dispatch an even count
+    of macros."""
+
+    def trip(_, c):
+        for _ in range(per_trip):
+            c = macro(c)
+        return c
+
+    trips, behind = divmod(macros, per_trip)
+    if trips:
+        carry = lax.fori_loop(0, trips, trip, carry)
+    for _ in range(behind):
+        carry = macro(carry)
+    return carry
 
 
 def _carry_period(names: Sequence[str], stages) -> int:
@@ -2138,9 +2217,8 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     # swept one (set below; no other schedule says)
     plan.pop("wired", None)
     plan.pop("wire_bytes", None)
-    if plan["route"] == "wrap":
-        plan["halo_readers"] = ()
-    elif plan["route"] == "plane":
+    plan.pop("macros_per_trip", None)
+    if plan["route"] == "plane":
         default = not fused and not split
         stage_runs = plan_plane_stages(
             dd, stages, x_radius, plan, interpret, fused,
@@ -2149,10 +2227,26 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
         if default:
             plan["pass_wrap_axes"], wrap_fills = pass_wrap_fills(dd, exch_route)
     else:
-        plan["halo_readers"] = tuple(names)
+        plan["halo_readers"] = () if plan["route"] == "wrap" else tuple(names)
+        # what the kernel reads off-centre, as the plane route's planner
+        # learns it (plan_plane_stages): one abstract trace a group, which
+        # decides nothing here -- domain.step's ``offcentre`` / ``diagonal`` /
+        # ``read_sides`` say it beside what the route serves
+        plane = jax.ShapeDtypeStruct((raw.y, raw.z), jnp.float32)
+        plan["footprint"] = footprint_counts([
+            trace_plane_kernel(
+                kernel, [names[q] for q in g], [plane] * len(g), x_radius, gsize, interpret
+            )
+            for g in groups
+        ])
 
     if plan["route"] == "wrap":
         k = plan["m"]
+        # the wrap pass writes fresh results: two macros a trip bring the
+        # loop's carry home (macro_loop), written back like alias (domain.
+        # step's ``macros_per_trip``); ``steps`` an even count of macros
+        # keeps the dispatch's edge free of copies too
+        per_trip = plan["macros_per_trip"] = macros_per_trip(False)
 
         def per_shard(steps, *blocks_raw):
             bs = tuple(
@@ -2174,7 +2268,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                 return tuple(out)
 
             blocked, rem = divmod(steps, k)
-            bs = lax.fori_loop(0, blocked, lambda _, b: one(k, b), bs)
+            bs = macro_loop(partial(one, k), blocked, bs, per_trip)
             if rem:
                 bs = one(rem, bs)
             return tuple(
@@ -2825,7 +2919,21 @@ def make_stream_step(
             # so that the exchange does not sweep them (pass_wrap_fills): one
             # value for every stage, a function of the mesh and the domain
             "wrapped": plan_now.get("pass_wrap_axes", ""),
+            # how many quantities the step carries, and what its kernels READ
+            # of them off-centre (footprint_counts: quantities read at a
+            # non-zero offset, those of them read at a diagonal one, and the
+            # distinct (quantity, axis, side) triples read; None each where a
+            # footprint trace raised) beside what the route SERVES: every
+            # exchanged quantity's halo is filled -- by the exchange's sweeps
+            # or the pass's own fills -- on all six sides
+            "quantities": nq,
+            **(plan_now.get("footprint") or dict.fromkeys(("offcentre", "diagonal", "read_sides"))),
+            "exchanged_sides": 6 * len(plan_now.get("halo_readers", ())),
         }
+        if "macros_per_trip" in plan_now:
+            # the wrap route: macros a trip of its device-side loop, as many
+            # as bring the fresh-result pass's carry home (macro_loop)
+            args["macros_per_trip"] = plan_now["macros_per_trip"]
         if "wired" in plan_now:
             # the axes whose sweep of the step's exchange crosses to another
             # shard, and the bytes one shard receives over them a step, all

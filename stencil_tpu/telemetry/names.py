@@ -434,8 +434,18 @@ ALL_HISTOGRAMS = frozenset({
 #: first two functions of the mesh, the last summed over the stages); a
 #: ``Jacobi3D`` wrap or wavefront step adds macros_per_trip = the macros one
 #: trip of its device-side macro loop runs, as many as it takes for the carry
-#: to be back in its own buffer (``models/jacobi._macro_loop``): 2 where the
-#: kernel writes a fresh result, 1 where it writes in place (``alias``)]
+#: to be back in its own buffer (``ops/stream.macro_loop``): 2 where the
+#: kernel writes a fresh result, 1 where it writes in place (``alias``) -- and
+#: so does a stream-engine step on the wrap route (2: ``stream_wrap_pass``
+#: writes fresh results); every stream-engine step says what its kernels READ
+#: beside what the route SERVES: quantities = the quantities it carries,
+#: offcentre = those read at a non-zero offset, diagonal = those of them read
+#: at an offset with two or more non-zero components (an edge or corner halo),
+#: read_sides = the distinct (quantity, axis, side) triples read
+#: (``ops/stream.footprint_counts`` over one abstract trace of each kernel;
+#: None each where it raised) and exchanged_sides = six for every quantity
+#: whose halo the route fills (D3Q19 lattice Boltzmann: 19, 18, 12, 30 and 0 on
+#: the wrap route, 108 on the plane route)]
 SPAN_STEP = "domain.step"
 #: one ``exchange()`` / ``exchange_many()`` call [route, nbytes = analytic
 #: bytes of the call, count = exchanges in it, wrap_axes = the mesh axes

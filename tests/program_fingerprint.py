@@ -198,6 +198,24 @@ def _elastic():
     return _trace_step(s.dd, s._step)
 
 
+def _lbm():
+    import jax
+
+    from stencil_tpu.models.lbm import LatticeBoltzmann
+
+    # 16^3 plans depth 8: cap it at the cell's 2, so that MODEL_STEPS is one
+    # trip of the wrap route's macro loop (two macros), a macro behind it and
+    # a remainder
+    s = LatticeBoltzmann(16, 16, 16, interpret=True, devices=jax.devices()[:1])
+    s.realize()
+    s._step = s.dd.make_step(s._kernel, engine="stream", x_radius=1, interpret=True,
+                             stream_depth=2)
+    args = s._step._span_args()
+    assert (args["route"], args["macros_per_trip"], args["diagonal"]) == ("wrap", 2, 12), args
+    assert s._step._stream_plan["m"] == 2
+    return _trace_step(s.dd, s._step)
+
+
 #: label -> builder of the ClosedJaxpr, at a CPU size under interpret
 MODEL_PROGRAMS = {
     "model:jacobi3d-512/wrap": _jacobi_wrap,
@@ -207,6 +225,7 @@ MODEL_PROGRAMS = {
     "model:acoustic-so8-600/plane-r4": _acoustic,
     "model:elastic-so8-600/plane-r4": _elastic,
     "model:acoustic-so8-1200x4/plane-r4": _acoustic_x4,
+    "model:lbm-d3q19-256/wrap-m2": _lbm,
 }
 
 

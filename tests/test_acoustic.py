@@ -111,6 +111,9 @@ def test_route_is_plane_and_the_span_says_so(blend, mesh, wrapped, wired, monkey
         "renamed": 1,  # and u_prev <- u swaps two handles: nothing to write (ISSUE 36)
         "wrapped": wrapped,
         "wired": wired, "wire_bytes": wire_bytes,  # what crosses to another shard (ISSUE 37)
+        # what the kernel reads against what the exchange serves (ISSUE 39): u
+        # alone, along the axes only, on all six sides
+        "quantities": 4, "offcentre": 1, "diagonal": 0, "read_sides": 6, "exchanged_sides": 6,
     }
     assert plan["halo_readers"] == ("u",), plan
     assert plan["writers"] == ("u",) and plan["renamed"] == ("u_prev",), plan

@@ -112,7 +112,17 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
         assert m["cells"] == ["acoustic-so8-1200x4*"] and m["moves"] == "mcells_per_s_chip", name
         assert declared[name]["workloads"] == ["acoustic-so8-1200x4.bulk"], name
     wired -= {"collective_pct.wired"}  # a trace_share: it reads opcodes, as PR 24's do
-    for name in set(declared) - new - plane - staged - setup - wired - (ragged - {"collective_pct.ragged"}):
+    # PR 39's: the joint lattice-Boltzmann step's shares, their files listing the cell by name
+    # (tests/test_bench_lbm.py holds them)
+    lbm = {n for n in declared if n.endswith(".lbm") or n == "lbm_pass_hbm_pct"}
+    assert len(lbm) == 7
+    for name in lbm:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["reducer"] in ("named_share", "named_roofline_hbm", "span_percentile", "span_count"), name
+        assert m["cells"] == ["lbm-d3q19-256.bulk"] == declared[name]["workloads"], name
+        assert m["moves"] == "mcells_per_s_chip", name
+    for name in set(declared) - new - plane - staged - setup - wired - lbm - (ragged - {"collective_pct.ragged"}):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
                                                "trace_roofline_hbm", "trace_idle"), name

@@ -454,3 +454,45 @@ def test_compiled_jacobi_macro_loop_is_bitwise_one_a_trip(chips, monkeypatch):
     for a, b in zip(got, want):
         assert np.isfinite(b).all() and 0.0 <= b.min() < 0.4 and 0.6 < b.max() <= 1.0
         assert np.array_equal(a, b), float(np.max(np.abs(a - b)))
+
+
+def test_compiled_lbm_step_matches_the_xla_engine():
+    """The lattice-Boltzmann cell's step as Mosaic compiles it, 256^3 x 19
+    (ISSUE 39): the route ``auto`` takes on one chip (wrap, two macros a trip)
+    against the XLA slice engine running the same kernel, on every cell of all
+    nineteen populations after a dispatch of whole trips, a macro behind the
+    loop and a remainder -- the wrap folded into the index maps and rotates
+    must serve every diagonal read.  Within ``max_abs_err`` of the benchmark's
+    configuration (Mosaic and XLA round apart here, as on the CPU)."""
+    import gc
+    import json
+    import os
+
+    from stencil_tpu.models.lbm import LatticeBoltzmann
+    from stencil_tpu.models.lbm_reference import NAMES
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "lbm-d3q19-256.json")) as f:
+        config = json.load(f)
+    steps = 5 * config["expect"]["depth"] + 1  # two trips, a macro behind them, a step more
+
+    def run(impl):
+        sim = LatticeBoltzmann(256, 256, 256, devices=jax.devices()[:1], kernel_impl=impl,
+                               seed_words=(0x1234ABCD, 77, 0xDEADBEEF, 2024))
+        sim.realize()
+        sim.step(steps)
+        said = getattr(sim._step, "_span_args", dict)()
+        fields = [sim.field(q) for q in NAMES]  # on the host: 67 MB each
+        for slot in (sim.dd._curr, sim.dd._next or {}):
+            for block in slot.values():
+                block.delete()
+        del sim
+        gc.collect()
+        return said, fields
+
+    said, got = run("pallas")
+    assert (said["route"], said["macros_per_trip"], said["diagonal"]) == (config["expect"]["route"], 2, 12)
+    _, want = run("jnp")
+    worst = max(float(np.abs(a - b).max()) for a, b in zip(got, want))
+    assert np.isfinite(worst) and worst <= config["limits"]["max_abs_err"], worst
+    assert min(float(np.abs(a - b).max()) for a, b in zip(got[1:], got[:-1])) > 1e-3  # no two alike

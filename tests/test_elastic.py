@@ -142,6 +142,10 @@ def test_the_plan_is_staged_and_the_span_says_so():
         "renamed": "0/0",  # every output is masked by the frame: none is a centre plane (ISSUE 36)
         "wrapped": "",  # a plain CPU run: the blend kernels are off (ISSUE 34)
         "wired": "", "wire_bytes": 0,  # one device: nothing crosses to another shard (ISSUE 37)
+        # what the two kernels read against what the exchanges serve (ISSUE 39):
+        # nine wavefields, along the axes only
+        "quantities": 13, "offcentre": 9, "diagonal": 0, "read_sides": 36,  # 9 (stress, axis) + 9 (velocity, axis) pairs, both sides
+        "exchanged_sides": 54,
     }
     seen = []
     real = telemetry.span

@@ -474,3 +474,58 @@ def test_elastic_step_carries_its_blocks_in_place(monkeypatch):
         aliasing = l[l.index("output_to_operand_aliasing="):].split("}, ")[0]
         assert aliasing.count("(") == n, aliasing
     assert not re.findall(r"=\s+f32\[608,608,608\]\S*\s+copy\(", text) and temp == 0
+
+
+@pytest.mark.slow  # tier-2 with its siblings: real-TPU-compiler AOT compiles
+# at the benchmark's size, three of them (5-6 s each)
+def test_lbm_macro_loop_carries_its_blocks_in_place(monkeypatch):
+    """The lattice-Boltzmann cell's dispatch as the chip's compiler leaves it
+    (ISSUE 39): 256^3 x 19 on the stream engine's wrap route at depth 2 for a
+    described v5e.  The ``while`` body holds TWO ``stream_wrap_pass`` calls --
+    every second result takes the buffer the trip's operand died in -- and NO
+    ``copy`` of ``f32[256,256,256]``; the one-a-trip control has one call and
+    NINETEEN such copies a trip (2.55 GB, as much as the pass moves).  Nothing
+    is temporary that the control did not hold, and an odd macro count runs
+    its last macro behind the loop without a copy."""
+    from stencil_tpu.models.lbm import RADIUS, LatticeBoltzmann
+    from stencil_tpu.ops import stream as sm
+
+    devices = _topology_devices()
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        got = {}
+        for per_trip, macros in ((None, 4), (None, 3), (1, 4)):
+            with monkeypatch.context() as mp:
+                if per_trip is not None:
+                    mp.setattr(sm, "macros_per_trip", lambda in_place: per_trip)
+                sim = LatticeBoltzmann(256, 256, 256, devices=devices[:1], seed_words=None)
+                sim.dd.realize(allocate=False)
+                plan = sm.plan_stream(sim.dd, RADIUS, "auto", False)
+                assert (plan["route"], plan["m"], plan["grouping"]) == ("wrap", 2, "joint"), plan
+                step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
+                compiled = step.lower(sim.dd.abstract_arrays(), macros * plan["m"]).compile()
+            got[per_trip, macros] = (
+                compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes, plan,
+            )
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    big_copy = re.compile(r"=\s+f32\[256,256,256\]\S*\s+copy\(")
+
+    def passes(text):
+        return [
+            l for l in text.splitlines()
+            if "custom-call(" in l and "tpu_custom_call" in l and l.lstrip().startswith("%stream_wrap_pass")
+        ]
+
+    text, temp, plan = got[None, 4]
+    assert plan["macros_per_trip"] == 2 and plan["footprint"]["diagonal"] == 12
+    assert len(passes(text)) == 2 and not big_copy.findall(text)
+    assert not any("output_to_operand_aliasing" in l for l in passes(text))
+    text_one, temp_one, plan_one = got[1, 4]
+    assert plan_one["macros_per_trip"] == 1
+    assert len(passes(text_one)) == 1 and len(big_copy.findall(text_one)) == 19
+    assert temp <= temp_one  # two sets of nineteen blocks taking turns, either way
+    text_odd, temp_odd, _ = got[None, 3]
+    assert len(passes(text_odd)) == 3 and not big_copy.findall(text_odd)
+    assert temp_odd <= temp * 1.002

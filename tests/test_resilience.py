@@ -488,6 +488,8 @@ class TestLadderEngines:
             "alias": False,  # one field on the wavefront route: fresh outputs
             "overlap": "off", "halo": "array", "halo_readers": ("u",),
             "writers": ("u",), "pass_wrap_axes": "", "renamed": (),
+            # what the kernel reads, traced for the span alone here (ISSUE 39)
+            "footprint": {"offcentre": 1, "diagonal": 0, "read_sides": 6},
         }
         inject.set_plan("execute:vmem_oom:stream*2")
         dd.run_step(step, 4)

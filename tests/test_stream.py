@@ -440,6 +440,8 @@ def test_stream_separable_per_field_grouping(monkeypatch):
         "writers": ("a", "b", "c", "d"),  # and writes every one
         "pass_wrap_axes": "",  # the plane route's alone (ISSUE 34)
         "renamed": (),  # as is the rename of a time level (ISSUE 36)
+        # what the kernel reads, one trace a group, for the span alone (ISSUE 39)
+        "footprint": {"offcentre": 4, "diagonal": 0, "read_sides": 24},
     }
     monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
     ref_dd, ref_hs = _mk(24, 24, 24, Radius.constant(1), names, devs)
@@ -511,6 +513,9 @@ def test_stream_depth_cap():
         "writers": ("u",),
         "pass_wrap_axes": "",
         "renamed": (),
+        # the 27-point kernel reads every edge and corner (ISSUE 39)
+        "footprint": {"offcentre": 1, "diagonal": 1, "read_sides": 6},
+        "macros_per_trip": 2,  # the wrap pass writes fresh results (ISSUE 39)
     }
     for a, b in outs:  # uncapped wrap vs the XLA ground truth
         np.testing.assert_allclose(a, b, **TOL)

@@ -320,7 +320,8 @@ class Jacobi3D:
         (8,128)-tile columns (~a full-domain pass per exchange, probe12d),
         so the z-shell lives in a separate z-major (Xr, 2m, Yr) packed slab
         array (rows [0,m) = low halo, [m,2m) = high) that the kernel
-        consumes (VMEM column patching via one small per-plane transpose)
+        consumes (VMEM column patching via one small per-plane transpose,
+        inside the lane tiles that hold the halo: ``patch_z_halo``)
         and emits (next macro's outgoing slabs).  Corner data propagates on the slabs themselves:
         after the z ppermute, each slab is extended with rows from the y
         neighbors and then planes from the x neighbors (two hops carry the
@@ -351,6 +352,7 @@ class Jacobi3D:
             jacobi_zring_wavefront_step,
             pack_d2,
             yz_dist2_plane,
+            z_halo_patch_form,
             zring_dist2_plane,
         )
         from stencil_tpu.ops.stream import (
@@ -561,7 +563,14 @@ class Jacobi3D:
             )
             return {name: fn(curr[name])}
 
-        step._span_args = lambda: {"macros_per_trip": per_trip}
+        span_args = {"macros_per_trip": per_trip}
+        if z_slab_mode:
+            # where the kernel patches its z halo, read off the working
+            # plane's width as the kernel's own helper reads it
+            span_args["z_halo_patch"] = z_halo_patch_form(
+                _ZRING_OFF + n.z if z_ring_mode else Zp, m
+            )
+        step._span_args = lambda: dict(span_args)
         return step
 
     def _make_pallas_step(self):

@@ -384,6 +384,76 @@ def _make_roll(interpret: bool):
     return roll
 
 
+#: lanes of one vector register: the unit the z-halo patch works in
+_LANES = 128
+
+
+def z_halo_patch_form(width: int, s: int) -> str:
+    """Which form ``patch_z_halo`` takes on a working plane ``width`` lanes
+    wide under an ``s``-wide z halo, read off the static shapes alone:
+    ``"tile"`` where the plane is whole lane tiles and the slab's ``2s``
+    columns fit one, ``"plane"`` otherwise (an unpadded shell plane).
+    ``domain.step`` says it as ``z_halo_patch``."""
+    return "tile" if width % _LANES == 0 and 2 * s <= _LANES else "plane"
+
+
+def patch_z_halo(plane, zst, s: int, lo_at: int, hi_at: int, roll):
+    """``plane`` (Yr, W) with lanes [lo_at, lo_at + s) <- ``zst[:, :s]`` and
+    lanes [hi_at, hi_at + s) <- ``zst[:, s:2s]`` -- the z-halo patch of the
+    z-slab wavefront kernels, ``zst`` (Yr, 2s) being the streamed slab block
+    after its one small transpose.  Every other lane keeps its value.
+
+    On a lane-aligned plane (``z_halo_patch_form`` says ``"tile"``) only the
+    128-lane tiles that hold a halo lane are touched.  The slab's ``2s``
+    columns sit in lanes [0, 2s) of one tile after the transpose; ONE lane
+    rotate of that tile per distinct shift puts them at their lanes modulo
+    128 (a halo that straddles a multiple of 128 lands in both tiles from the
+    same rotate), one masked select per (halo, tile) merges them into the
+    tile sliced out at a multiple of 128, and the tiles go back by a
+    lane-aligned concatenate.
+
+    The whole-plane form this replaces took one column of ``zst`` at a time,
+    broadcast it along the lanes and selected it into EVERY vreg of the plane,
+    ``2s`` times: 2.07 of the z-ring kernel's 20.72 ms a 16-level macro and
+    0.50 of the engine's 3.69 ms a 3-level field pass (TPU v5 lite, PERF.md
+    PR 40).  The same ``2s`` selects confined to the halo's tile gave nothing
+    back (20.81 ms): it is the per-column broadcasts that cost, and the
+    rotate makes none.  A plane that is not whole lane tiles keeps the
+    whole-plane form: it has no tile to slice out at a multiple of 128."""
+    Yr, W = plane.shape
+    halos = ((lo_at, 0), (hi_at, s))  # (first lane, first slab column)
+    if z_halo_patch_form(W, s) == "plane":
+        col = jax.lax.broadcasted_iota(jnp.int32, (Yr, W), 1)
+        for j in range(s):
+            for at, j0 in halos:
+                plane = jnp.where(col == at + j, zst[:, j0 + j][:, None], plane)
+        return plane
+    src = jnp.pad(zst, ((0, 0), (0, _LANES - 2 * s)))  # one (Yr, 128) tile
+    lane = jax.lax.broadcasted_iota(jnp.int32, (Yr, _LANES), 1)
+    rolled, tiles = {}, {}
+    for at, j0 in halos:
+        amt = (at - j0) % _LANES  # slab column j0 + j -> lane (at + j) % 128
+        if amt not in rolled:
+            # (the compiled rotate hands narrow floats back as f32)
+            rolled[amt] = (roll(src, amt, 1) if amt else src).astype(plane.dtype)
+        for t in range(at // _LANES, (at + s - 1) // _LANES + 1):
+            a = max(at - t * _LANES, 0)
+            b = min(at + s - t * _LANES, _LANES)
+            tile = tiles.get(t)
+            if tile is None:
+                tile = plane[:, t * _LANES : (t + 1) * _LANES]
+            tiles[t] = jnp.where((lane >= a) & (lane < b), rolled[amt], tile)
+    pieces, done = [], 0
+    for t in sorted(tiles):
+        if t * _LANES > done:
+            pieces.append(plane[:, done : t * _LANES])
+        pieces.append(tiles[t])
+        done = (t + 1) * _LANES
+    if done < W:
+        pieces.append(plane[:, done:])
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
+
+
 def jacobi_wrap_step(
     block: jax.Array,
     interpret: bool = False,
@@ -494,10 +564,14 @@ def jacobi_shell_wavefront_step(
     # what fit 516^2 planes under Mosaic's old 16 MB default, kept reachable
     # via STENCIL_VMEM_LIMIT_BYTES).  The kernel transposes
     # the small block in VMEM, patches the z columns of every streamed
-    # plane, and, when set, ALSO emits the next macro step's outgoing slabs
-    # in the same layout, returning (out, z_out) with z_out rows [0, s) =
-    # my top interior cols [Zr-2s, Zr-s) (the -z-bound message) and
-    # [s, 2s) = my bottom interior cols [s, 2s) (the +z-bound message).
+    # plane -- inside the lane tiles that hold them, tile 0 for the low halo
+    # and the one or two tiles over [z_valid - s, z_valid) for the high one,
+    # where the plane is whole lane tiles; over the whole plane where it is
+    # not (``patch_z_halo``) -- and, when set, ALSO emits the next macro
+    # step's outgoing slabs in the same layout, returning (out, z_out) with
+    # z_out rows [0, s) = my top interior cols [Zr-2s, Zr-s) (the -z-bound
+    # message) and [s, 2s) = my bottom interior cols [s, 2s) (the +z-bound
+    # message).
     z_valid: int = None,  # logical z extent of the raw planes (shell incl.);
     # columns [z_valid, Zr) are DEAD LANE PADDING that rounds the plane width
     # up to a 128 multiple.  Ragged lane extents cripple the plane DMA
@@ -566,14 +640,10 @@ def jacobi_shell_wavefront_step(
         if z_slabs is not None:
             # patch the z-shell columns in VMEM — they are never stored in
             # the big array.  One small (2s, Yr) -> (Yr, 2s) transpose per
-            # plane turns the z-major block into the column vectors needed.
+            # plane turns the z-major block into the columns patch_z_halo
+            # moves to their lanes.
             zst = jnp.swapaxes(zs_ref[0], 0, 1).astype(acc_dtype)  # (Yr, 2s)
-            col = jax.lax.broadcasted_iota(jnp.int32, (Yr, Zr), 1)
-            for j in range(s_off):
-                vals = jnp.where(col == j, zst[:, j][:, None], vals)
-                vals = jnp.where(
-                    col == zv - s_off + j, zst[:, s_off + j][:, None], vals
-                )
+            vals = patch_z_halo(vals, zst, s_off, 0, zv - s_off, roll)
         for s in range(1, m + 1):
             prev = ring[s - 1, i % 2]  # level-(s-1) plane i-s-1
             cent = ring[s - 1, (i + 1) % 2]  # level-(s-1) plane i-s
@@ -689,20 +759,32 @@ def jacobi_zring_wavefront_step(
     """``m`` Jacobi levels per pass with the z halo in a RING-layout VMEM
     working plane — the deep-wavefront path that streams NO z padding.
 
-    probe24: at 512^3 m=16 the macro is ~82% kernel pass, and the pass costs
-    exactly the wrap kernel x the padded-array ratio (544^2 x 640 / 512^3 =
-    1.41).  The z share of that ratio is pure waste: in z-slab mode the
-    in-array z-shell columns are never read (the kernel patches halos from
-    the slab buffers), yet they force either ragged-lane DMA (~30% slower,
-    probe22) or 640-wide lane padding.  Here HBM stores only the Zi
-    interior columns; each streamed (Yr, Zi) plane is staged into a
-    (Yr, Zi + 128) working plane at lane offset 128 whose LANE WRAP is
-    periodic-consistent by construction:
+    In z-slab mode the in-array z-shell columns are never read (the kernel
+    patches halos from the slab buffers), yet they force either ragged-lane
+    DMA (~30% slower, probe22) or 640-wide lane padding.  Here HBM stores
+    only the Zi interior columns; each streamed (Yr, Zi) plane is staged
+    into a (Yr, Zi + 128) working plane at lane offset 128 whose LANE WRAP
+    is periodic-consistent by construction:
 
-        lanes [0, s)            hi halo  (z = Zi .. Zi+s)
-        lanes [s, 128 - s)      dead
-        lanes [128 - s, 128)    lo halo  (z = -s .. 0)
-        lanes [128, 128 + Zi)   interior (z = c - 128)
+        lanes [0, s)            hi halo  (z = Zi .. Zi+s)   } the RING TILE,
+        lanes [s, 128 - s)      dead (zero)                 } built on its own
+        lanes [128 - s, 128)    lo halo  (z = -s .. 0)      } by patch_z_halo
+        lanes [128, 128 + Zi)   interior (z = c - 128): the streamed plane
+
+    The ring tile is one (Yr, 128) tile made from the slab block alone --
+    its one small transpose, ONE lane rotate by -s, two masked selects
+    (``patch_z_halo`` on a zero tile) -- and concatenated, lane-aligned, in
+    front of the interior plane: no operation of the staging touches the
+    interior's vregs.
+
+    What the pass costs against ``jacobi_wrap_step`` on the same 512^3 of
+    interior (TPU v5 lite, PERF.md PR 40): both make 544 grid steps a
+    16-level macro, and a level works on 544 x 640 = 340 vregs here (the
+    16-deep y shell, the ring tile) against 512 x 512 = 256 there: x 1.328
+    in level work, 13.2 -> 17.5 ms.  On top of that came the staging --
+    until ISSUE 40 ``2s`` lane-broadcasts of a slab column, each selected
+    into the WHOLE plane: 2.07 ms a macro -- and what the slab blocks' two
+    DMAs a grid step and the emit cost (~1.1 ms).
 
     ``roll(plane, -1)`` brings lane 0 (hi halo z=Zi) to lane 127+Zi
     (interior z=Zi-1) — its true +z neighbor; ``roll(plane, +1)`` brings
@@ -732,14 +814,14 @@ def jacobi_zring_wavefront_step(
     def kernel(origin_ref, in_ref, d2_ref, zs_ref, out_ref, zout_ref, ring):
         i = pl.program_id(0)
         d2v = d2_ref[...]
-        # stage the interior plane at lane offset OFF and patch the halo
-        # segments from the slab block (one small transpose per plane)
-        vals = jnp.pad(in_ref[0].astype(acc_dtype), ((0, 0), (OFF, 0)))
+        # the ring tile from the slab block alone (one small transpose per
+        # plane; lo halo at lanes [OFF - s, OFF), hi halo at [0, s), zeros
+        # between), then the interior plane behind it at lane offset OFF
         zst = jnp.swapaxes(zs_ref[0], 0, 1).astype(acc_dtype)  # (Yr, 2s)
-        col = jax.lax.broadcasted_iota(jnp.int32, (Yr, W), 1)
-        for j in range(s_off):
-            vals = jnp.where(col == OFF - s_off + j, zst[:, j][:, None], vals)
-            vals = jnp.where(col == j, zst[:, s_off + j][:, None], vals)
+        ring_tile = patch_z_halo(
+            jnp.zeros((Yr, OFF), acc_dtype), zst, s_off, OFF - s_off, 0, roll
+        )
+        vals = jnp.concatenate([ring_tile, in_ref[0].astype(acc_dtype)], axis=1)
         for s in range(1, m + 1):
             prev = ring[s - 1, i % 2]
             cent = ring[s - 1, (i + 1) % 2]

@@ -126,6 +126,8 @@ from stencil_tpu.ops.jacobi_pallas import (
     _vmem_budget,
     _VMEM_STACK_MARGIN,
     _WRAP_MAX_K,
+    patch_z_halo,
+    z_halo_patch_form,
 )
 
 
@@ -652,7 +654,11 @@ def stream_wavefront_pass(
     its docstring for the shrinking-validity contamination argument, the
     z-slab layout, and the lane-padding rationale; all carry over verbatim).
     Returns the advanced blocks, plus per-quantity outgoing z slabs when
-    ``z_slabs`` is given.
+    ``z_slabs`` is given.  In that form each level-0 plane gets its z halo
+    from the slab block through ``jacobi_pallas.patch_z_halo``: on the
+    lane-padded plane inside the lane tiles that hold the halo lanes -- tile
+    0 for [0, s), the one or two tiles over [z_valid - s, z_valid) -- and
+    nowhere else (``domain.step`` says ``z_halo_patch: "tile"``).
 
     With ``fused_shell`` the blocks' shell cells are STALE and every axis's
     fresh halos ride as side inputs (``fused_shell_exchange``): each
@@ -711,17 +717,10 @@ def stream_wavefront_pass(
                 )
         if z_slabs is not None:
             # patch the z-shell columns in VMEM — never stored in the big
-            # array (see jacobi_shell_wavefront_step)
-            col = lax.broadcasted_iota(jnp.int32, (Yr, Zr), 1)
+            # array (see jacobi_shell_wavefront_step) — in their lane tiles
             for q in range(nq):
                 zst = up(jnp.swapaxes(zs_refs[q][0], 0, 1))  # (Yr, 2s)
-                v = vals[q]
-                for j in range(s_off):
-                    v = jnp.where(col == j, zst[:, j][:, None], v)
-                    v = jnp.where(
-                        col == zv - s_off + j, zst[:, s_off + j][:, None], v
-                    )
-                vals[q] = v
+                vals[q] = patch_z_halo(vals[q], zst, s_off, 0, zv - s_off, roll)
         for s in range(1, m + 1):
             prevs = [rings[q][s - 1, i % 2] for q in range(nq)]
             cents = [rings[q][s - 1, (i + 1) % 2] for q in range(nq)]
@@ -2218,6 +2217,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     plan.pop("wired", None)
     plan.pop("wire_bytes", None)
     plan.pop("macros_per_trip", None)
+    plan.pop("z_halo_patch", None)
     if plan["route"] == "plane":
         default = not fused and not split
         stage_runs = plan_plane_stages(
@@ -2446,6 +2446,12 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
         z_slab_mode = plan["z_slabs"]
         Xr, Yr, Zr = raw.x, raw.y, raw.z
         Zp = lane_pad_width(Zr) if z_slab_mode else Zr
+        if z_slab_mode:
+            # where the pass patches its z halo, read off the working plane's
+            # shape as the kernel's own helper reads it (patch_z_halo),
+            # written back like macros_per_trip (domain.step's
+            # ``z_halo_patch``): "tile" on the lane-padded plane
+            plan["z_halo_patch"] = z_halo_patch_form(Zp, s)
         yext, xext = make_slab_extenders(Xr, Yr, s, mesh_shape)
 
         def wavefront_groups(bs, depth, origin, zs=None, fused_bufs=None):
@@ -2934,6 +2940,10 @@ def make_stream_step(
             # the wrap route: macros a trip of its device-side loop, as many
             # as bring the fresh-result pass's carry home (macro_loop)
             args["macros_per_trip"] = plan_now["macros_per_trip"]
+        if "z_halo_patch" in plan_now:
+            # the z-slab wavefront: whether the pass patches its z halo in
+            # the lane tiles that hold it or over the whole plane
+            args["z_halo_patch"] = plan_now["z_halo_patch"]
         if "wired" in plan_now:
             # the axes whose sweep of the step's exchange crosses to another
             # shard, and the bytes one shard receives over them a step, all

@@ -437,7 +437,15 @@ ALL_HISTOGRAMS = frozenset({
 #: to be back in its own buffer (``ops/stream.macro_loop``): 2 where the
 #: kernel writes a fresh result, 1 where it writes in place (``alias``) -- and
 #: so does a stream-engine step on the wrap route (2: ``stream_wrap_pass``
-#: writes fresh results); every stream-engine step says what its kernels READ
+#: writes fresh results); a z-slab wavefront step (``Jacobi3D``'s z-ring and
+#: lane-padded shell kernels, the stream engine's wavefront route with
+#: ``z_slabs``) adds z_halo_patch = where its kernel patches the z halo into
+#: the working plane: "tile" = inside the 128-lane tiles that hold the halo
+#: lanes, on a plane that is whole lane tiles, "plane" = compare + select over
+#: the whole plane, on one that is not (``ops/jacobi_pallas.patch_z_halo``,
+#: read off the static shapes by ``z_halo_patch_form``: "tile" in
+#: ``jacobi3d-512x4.bulk`` and ``astaroth-8q-512.bulk``; no other step says
+#: it); every stream-engine step says what its kernels READ
 #: beside what the route SERVES: quantities = the quantities it carries,
 #: offcentre = those read at a non-zero offset, diagonal = those of them read
 #: at an offset with two or more non-zero components (an edge or corner halo),

@@ -446,11 +446,13 @@ def test_compiled_jacobi_macro_loop_is_bitwise_one_a_trip(chips, monkeypatch):
             seen.append(sim.temperature())
         return sim._step._span_args(), seen
 
+    # the z-ring step also says where its kernel patches the z halo (ISSUE 40)
+    patch = {} if chips == 1 else {"z_halo_patch": "tile"}
     args, got = run()
-    assert args == {"macros_per_trip": 2}
+    assert args == {"macros_per_trip": 2, **patch}
     monkeypatch.setattr(jm, "_macros_per_trip", lambda in_place: 1)
     args_one, want = run()
-    assert args_one == {"macros_per_trip": 1}
+    assert args_one == {"macros_per_trip": 1, **patch}
     for a, b in zip(got, want):
         assert np.isfinite(b).all() and 0.0 <= b.min() < 0.4 and 0.6 < b.max() <= 1.0
         assert np.array_equal(a, b), float(np.max(np.abs(a - b)))

@@ -30,6 +30,11 @@ CASES = [("wrap", (1, 1, 1))] + [
 ]
 
 
+# what ``domain.step`` says beside ``macros_per_trip``: the z-slab routes patch
+# their z halo inside its lane tiles on these lane-aligned planes (ISSUE 40)
+Z_HALO_PATCH = {"ring": {"z_halo_patch": "tile"}, "zslab": {"z_halo_patch": "tile"}}
+
+
 def _seeded(x, y, z):
     return (jnp.sin(12.9898 * x + 78.233 * y + 37.719 * z) * 0.5 + 0.5).astype(jnp.float32)
 
@@ -83,7 +88,7 @@ def test_two_macros_a_trip_is_bitwise_one_a_trip(route, mesh, macros, rem, monke
     steps = macros * K + rem
     two = _build(route, mesh, monkeypatch)
     one = _build(route, mesh, monkeypatch, per_trip=1)
-    assert two._step._span_args() == {"macros_per_trip": 2}
+    assert two._step._span_args() == {"macros_per_trip": 2, **Z_HALO_PATCH.get(route, {})}
     seeded = _raw(two)
     np.testing.assert_array_equal(seeded, _raw(one))
     for _ in range(2):
@@ -139,7 +144,9 @@ def test_an_in_place_kernel_keeps_the_parents_program(route, mesh, monkeypatch):
 
     def traced(alias):
         sim = _build(route, mesh, monkeypatch, wavefront_alias=alias)
-        assert sim._step._span_args() == {"macros_per_trip": 1 if alias else 2}
+        assert sim._step._span_args() == {
+            "macros_per_trip": 1 if alias else 2, **Z_HALO_PATCH.get(route, {})
+        }
         return fingerprint(jax.make_jaxpr(sim._step, static_argnums=1)(sim.dd._curr, 3 * K + 1))
 
     ours, fresh = traced(True), traced(False)
@@ -200,11 +207,13 @@ def test_an_odd_count_keeps_the_loop_clean(monkeypatch):
     ("wrap", (1, 1, 1), None, 2),
     ("ring", (2, 2, 1), None, 2),
     ("ring", (2, 2, 1), True, 1),
+    ("zslab", (2, 1, 1), None, 2),
     ("plain", (2, 1, 1), True, 1),
 ])
 def test_the_step_span_says_the_macros_a_trip(route, mesh, alias, want, monkeypatch):
     """``domain.step`` carries ``macros_per_trip`` (registered under
-    ``SPAN_STEP``): the counter that says the mechanism engaged."""
+    ``SPAN_STEP``): the counter that says the mechanism engaged -- and, on
+    the z-slab routes alone, ``z_halo_patch`` (ISSUE 40)."""
     kw = {} if alias is None else {"wavefront_alias": alias}
     sim = _build(route, mesh, monkeypatch, **kw)
     seen = []
@@ -218,6 +227,7 @@ def test_the_step_span_says_the_macros_a_trip(route, mesh, alias, want, monkeypa
     sim.step(2 * K)
     (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
     assert kw["label"] == "jacobi" and kw["steps"] == 2 * K and kw["macros_per_trip"] == want
+    assert kw.get("z_halo_patch") == Z_HALO_PATCH.get(route, {}).get("z_halo_patch")
 
 
 def test_the_counter_is_registered_and_the_names_lint_passes():
@@ -225,5 +235,6 @@ def test_the_counter_is_registered_and_the_names_lint_passes():
 
     from stencil_tpu import lint
 
-    assert "macros_per_trip" in inspect.getsource(tm).split('SPAN_STEP = "domain.step"')[0]
+    registered = inspect.getsource(tm).split('SPAN_STEP = "domain.step"')[0]
+    assert "macros_per_trip" in registered and "z_halo_patch" in registered
     assert lint.run_lint(select=["telemetry-name"]) == []

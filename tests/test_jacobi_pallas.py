@@ -7,6 +7,7 @@ multi-device halos, and uneven padding.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -279,3 +280,160 @@ def test_wrap_fast_path_matches_jnp_single_device():
     a.step(5)
     b.step(5)
     np.testing.assert_allclose(a.temperature(), b.temperature(), rtol=1e-6)
+
+
+# -- ISSUE 40: the z-halo patch works inside the lane tiles that hold the halo --
+
+
+def parent_patch_z_halo(plane, zst, s, lo_at, hi_at, roll=None):
+    """The staging all three z-slab kernels made before ISSUE 40, kept here as
+    the oracle: ``2s`` compare + select pairs over the WHOLE plane."""
+    col = jax.lax.broadcasted_iota(jnp.int32, plane.shape, 1)
+    for j in range(s):
+        plane = jnp.where(col == lo_at + j, zst[:, j][:, None], plane)
+        plane = jnp.where(col == hi_at + j, zst[:, s + j][:, None], plane)
+    return plane
+
+
+def _plane_and_slab(yr, width, s, seed=0):
+    """A plane and a transposed slab block with no value in common, so a
+    column that lands in the wrong lane -- or nowhere -- shows."""
+    rng = np.random.default_rng(seed)
+    plane = jnp.asarray(rng.random((yr, width), dtype=np.float32))
+    zst = jnp.asarray(10.0 + rng.random((yr, 2 * s), dtype=np.float32))
+    return plane, zst
+
+
+@pytest.mark.parametrize("zi", [128, 512])
+@pytest.mark.parametrize("s", [1, 3, 16, 64])
+def test_patch_z_halo_builds_the_ring_tile(s, zi):
+    """The z-ring working plane -- a (Yr, 128) ring tile built on its own in
+    front of the interior lanes -- is, bitwise and in every lane, the parent's
+    whole-plane pad + ``2s`` selects: hi halo in lanes [0, s), lo halo in
+    [128 - s, 128), the dead lanes between them zero."""
+    from stencil_tpu.ops import jacobi_pallas as jp
+
+    interior, zst = _plane_and_slab(24, zi, s, seed=s + zi)
+    want = parent_patch_z_halo(
+        jnp.pad(interior, ((0, 0), (jp._ZRING_OFF, 0))), zst, s, jp._ZRING_OFF - s, 0
+    )
+    assert jp.z_halo_patch_form(jp._ZRING_OFF, s) == "tile"
+    tile = jp.patch_z_halo(
+        jnp.zeros((24, jp._ZRING_OFF), jnp.float32), zst, s, jp._ZRING_OFF - s, 0,
+        jp._make_roll(True),
+    )
+    got = jnp.concatenate([tile, interior], axis=1)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got[:, :s]), np.asarray(zst[:, s:]))
+    np.testing.assert_array_equal(np.asarray(got[:, 128 - s : 128]), np.asarray(zst[:, :s]))
+
+
+@pytest.mark.parametrize("zv", [518, 514, 640], ids=["one-tile", "straddles-512", "last-lanes"])
+@pytest.mark.parametrize("s", [1, 3, 16, 64])
+def test_patch_z_halo_patches_a_lane_padded_plane_in_its_tiles(s, zv):
+    """The shell-layout plane (lo halo in lanes [0, s), hi halo in
+    [zv - s, zv), dead lanes behind) on a 640-wide plane: the hi halo inside
+    ONE lane tile (zv = 518: lanes 515..517 of tile 4 at s = 3), STRADDLING a
+    multiple of 128 (zv = 514: lanes 511..513) and at the plane's very end --
+    bitwise the parent's whole-plane selects, every untouched lane as it was."""
+    from stencil_tpu.ops import jacobi_pallas as jp
+
+    plane, zst = _plane_and_slab(22, 640, s, seed=7 * s + zv)
+    assert jp.z_halo_patch_form(640, s) == "tile"
+    got = jp.patch_z_halo(plane, zst, s, 0, zv - s, jp._make_roll(True))
+    want = parent_patch_z_halo(plane, zst, s, 0, zv - s)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got[:, zv - s : zv]), np.asarray(zst[:, s:]))
+
+
+@pytest.mark.parametrize("width,s", [(132, 2), (26, 1), (518, 3), (640, 65)],
+                         ids=["132x2", "26x1", "518x3", "slab-wider-than-a-tile"])
+def test_patch_z_halo_keeps_the_whole_plane_form_off_the_lane_tiling(width, s, monkeypatch):
+    """A plane that is not whole lane tiles (the shell kernel on an unpadded
+    block), or a slab block wider than one tile, takes the parent's
+    whole-plane form: the rule reads the static shapes alone, and that form
+    never slices a tile or rotates."""
+    from stencil_tpu.ops import jacobi_pallas as jp
+
+    plane, zst = _plane_and_slab(20, width, s, seed=width)
+    assert jp.z_halo_patch_form(width, s) == "plane"
+
+    def no_roll(*a):
+        raise AssertionError("the whole-plane form rotates nothing")
+
+    got = jp.patch_z_halo(plane, zst, s, 0, width - s, no_roll)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(parent_patch_z_halo(plane, zst, s, 0, width - s))
+    )
+
+
+def _zring_case(m, s, seed=3):
+    """Operands of ``jacobi_zring_wavefront_step`` on a (2s + 8)^2 x 128
+    shard of a 2 x 2 x 1 mesh, the spheres inside the domain."""
+    from stencil_tpu.ops import jacobi_pallas as jp
+
+    n, zi = 2 * s + 8, 128
+    xr = yr = n + 2 * s
+    gsize = (2 * n, 2 * n, zi)
+    rng = np.random.default_rng(seed)
+    raw = jnp.asarray(rng.random((xr, yr, zi), dtype=np.float32))
+    zs = jnp.asarray(rng.random((xr, 2 * s, yr), dtype=np.float32))
+    origin = jnp.array([n, 0, 0], jnp.int32)
+    d2 = jp.pack_d2(jp.zring_dist2_plane(origin[1] - s, origin[2], s, yr, zi, gsize), gsize)
+    return raw, origin, d2, gsize, zs
+
+
+@pytest.mark.parametrize("m,s", [(1, 1), (3, 3), (16, 16), (2, 5)], ids=["m1", "m3", "m16", "m2-of-shell-5"])
+def test_zring_kernel_returns_the_parents_bytes(m, s, monkeypatch):
+    """``jacobi_zring_wavefront_step`` with the tile-form patch returns, on
+    every plane it writes, the block AND the outgoing slabs it returned with
+    the parent's whole-plane staging (the oracle patched in for the helper)."""
+    from stencil_tpu.ops import jacobi_pallas as jp
+
+    raw, origin, d2, gsize, zs = _zring_case(m, s)
+
+    def run():
+        out, zout = jp.jacobi_zring_wavefront_step(
+            raw, m, origin, d2, gsize, zs, interior_offset=s, interpret=True
+        )
+        written = raw.shape[0] - m  # the un-aliased call leaves the last m planes unwritten
+        return np.asarray(out[:written]), np.asarray(zout[:written])
+
+    ours = run()
+    monkeypatch.setattr(jp, "patch_z_halo", parent_patch_z_halo)
+    parents = run()
+    np.testing.assert_array_equal(ours[0], parents[0])
+    np.testing.assert_array_equal(ours[1], parents[1])
+    assert not np.array_equal(ours[0], np.asarray(raw[: ours[0].shape[0]]))
+
+
+@pytest.mark.parametrize("zr,form", [(128, "tile"), (28, "plane")], ids=["lane-padded", "unpadded"])
+def test_shell_kernel_returns_the_parents_bytes(zr, form, monkeypatch):
+    """``jacobi_shell_wavefront_step`` in z-slab mode, on a lane-padded plane
+    (the tile form) and on an unpadded one (the whole-plane form), against the
+    parent's staging: the block and the outgoing slabs, bitwise."""
+    from stencil_tpu.ops import jacobi_pallas as jp
+
+    m = s = 3
+    n, zv = 22, 28
+    xr = yr = n + 2 * s
+    gsize = (2 * n, 2 * n, n)
+    rng = np.random.default_rng(11)
+    raw = jnp.asarray(rng.random((xr, yr, zr), dtype=np.float32))
+    zs = jnp.asarray(rng.random((xr, 2 * s, yr), dtype=np.float32))
+    origin = jnp.array([0, n, 0], jnp.int32)
+    d2 = jp.pack_d2(jp.yz_dist2_plane(origin[1] - s, origin[2] - s, (yr, zr), gsize), gsize)
+    assert jp.z_halo_patch_form(zr, s) == form
+
+    def run():
+        out, zout = jp.jacobi_shell_wavefront_step(
+            raw, m, origin, d2, gsize, interior_offset=s, interpret=True, alias=False,
+            z_slabs=zs, z_valid=zv,
+        )
+        return np.asarray(out[: xr - m]), np.asarray(zout[: xr - m])
+
+    ours = run()
+    monkeypatch.setattr(jp, "patch_z_halo", parent_patch_z_halo)
+    parents = run()
+    np.testing.assert_array_equal(ours[0], parents[0])
+    np.testing.assert_array_equal(ours[1], parents[1])

@@ -412,13 +412,15 @@ def test_jacobi_macro_loop_carries_its_block_in_place(chips, monkeypatch):
                 out += lines[i:_computation_block(lines, i + 1)[1]]
         return "\n".join(out)
 
+    # the z-ring step also says where its kernel patches the z halo (ISSUE 40)
+    patch = {} if chips == 1 else {"z_halo_patch": "tile"}
     text, temp, args = got[None, macros]
-    assert args == {"macros_per_trip": 2}
+    assert args == {"macros_per_trip": 2, **patch}
     assert len(stencil_calls(text)) == 2 == len(stencil_calls(in_the_loop(text)))
     assert not big_copy.findall(text)
     assert not any("output_to_operand_aliasing" in l for l in stencil_calls(text))
     text_one, temp_one, args_one = got[1, macros]
-    assert args_one == {"macros_per_trip": 1}
+    assert args_one == {"macros_per_trip": 1, **patch}
     assert len(stencil_calls(text_one)) == 1
     assert len(big_copy.findall(in_the_loop(text_one))) == 1
     # the same two blocks taking turns; on four chips a second pair of z-slab buffers
@@ -431,6 +433,61 @@ def test_jacobi_macro_loop_carries_its_block_in_place(chips, monkeypatch):
     assert len(stencil_calls(text_odd)) == 3 and len(stencil_calls(in_the_loop(text_odd))) == 2
     assert not big_copy.findall(text_odd)
     assert temp_odd <= (temp * 1.002 if chips == 1 else temp + 1.0e9), (temp_odd, temp)
+
+
+@pytest.mark.slow  # tier-2 with its siblings: real-TPU-compiler AOT compiles
+# of single kernels at the cells' plane sizes, a few seconds each
+@pytest.mark.parametrize("kernel,zv", [("stream", 518), ("stream", 514), ("shell", 544)],
+                         ids=["astaroth-518", "straddles-512", "shell-544"])
+def test_the_tile_form_z_halo_patch_lowers_for_the_chip(kernel, zv):
+    """What interpret mode cannot show (ISSUE 40): Mosaic takes the tile-form
+    z-halo patch -- the (Yr, 2s) -> (Yr, 128) lane pad, the lane rotate, the
+    tile sliced out at a multiple of 128 -- on astaroth's 518-row planes (off
+    the sublane tile: the rotate takes its slice + concatenate form), with the
+    hi halo inside one lane tile and straddling lane 512, and on the shell
+    kernel's lane-padded (544, 640) plane.  Few planes and one level: the
+    patch is the same at any depth, the compile stays short."""
+    from jax.sharding import SingleDeviceSharding
+
+    from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops import jacobi_pallas as jp
+    from stencil_tpu.ops import stream as sm
+
+    one = SingleDeviceSharding(_topology_devices()[0])
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        if kernel == "stream":
+            s, xr, yr = 3, 16, 518
+            assert sm.z_halo_patch_form(640, s) == "tile"
+
+            def run(origin, raw, zs):
+                return sm.stream_wavefront_pass(
+                    _jacobi_kernel, ["q"], [raw], 1, s, origin, Dim3(1024, 1024, 512),
+                    z_slabs=[zs], z_valid=zv,
+                )
+
+            args = (shaped((3,), jnp.int32), shaped((xr, yr, 640)), shaped((xr, 2 * s, yr)))
+        else:
+            s, xr, yr = 16, 40, 544
+            assert jp.z_halo_patch_form(640, s) == "tile"
+
+            def run(origin, raw, zs, d2):
+                return jp.jacobi_shell_wavefront_step(
+                    raw, 1, origin, d2, (1024, 1024, 512), interior_offset=s,
+                    z_slabs=zs, z_valid=zv,
+                )
+
+            args = (shaped((3,), jnp.int32), shaped((xr, yr, 640)), shaped((xr, 2 * s, yr)),
+                    shaped((yr, 640), jnp.int32))
+        text = jax.jit(run).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    assert "tpu_custom_call" in text
 
 
 @pytest.mark.slow  # tier-2 with its siblings: a real-TPU-compiler AOT

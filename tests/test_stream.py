@@ -442,7 +442,11 @@ def test_stream_separable_per_field_grouping(monkeypatch):
         "renamed": (),  # as is the rename of a time level (ISSUE 36)
         # what the kernel reads, one trace a group, for the span alone (ISSUE 39)
         "footprint": {"offcentre": 4, "diagonal": 0, "read_sides": 24},
+        # the z-slab pass patches its z halo in the lane tiles that hold it,
+        # on the lane-padded plane (ISSUE 40)
+        "z_halo_patch": "tile",
     }
+    assert step._span_args()["z_halo_patch"] == "tile"
     monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
     ref_dd, ref_hs = _mk(24, 24, 24, Radius.constant(1), names, devs)
     ref = ref_dd.make_step(mean6_kernel, overlap=False)
@@ -979,3 +983,42 @@ def test_an_nd_quantity_keeps_every_sweep_in_the_exchange(monkeypatch):
         assert fills == (
             ((1, 0, 16, 1), (1, 17, 1, 1), (2, 0, 16, 1), (2, 17, 1, 1)) if want else ()
         )
+
+
+@pytest.mark.parametrize("zp,zv,form", [(128, 30, "tile"), (256, 130, "tile"), (30, 30, "plane")],
+                         ids=["lane-padded", "hi-halo-straddles-128", "unpadded"])
+def test_wavefront_pass_z_slab_patch_returns_the_parents_bytes(zp, zv, form, monkeypatch):
+    """``stream_wavefront_pass`` in z-slab form, m = 3, two fields: with the
+    z halo patched inside its lane tiles (ISSUE 40) the pass returns, on every
+    plane it writes, the blocks and the outgoing slabs it returned with the
+    parent's whole-plane selects (the oracle of tests/test_jacobi_pallas.py
+    patched in for the helper) -- on a lane-padded plane, on one whose hi halo
+    straddles a multiple of 128 (lanes 127..129), and on an unpadded one,
+    which keeps the whole-plane form."""
+    import stencil_tpu.ops.stream as sm
+    from stencil_tpu.core.dim3 import Dim3
+    from test_jacobi_pallas import parent_patch_z_halo
+
+    m = s = 3
+    xr = yr = 24
+    names = ["a", "b"]
+    rng = np.random.default_rng(zp + zv)
+    raws = [jnp.asarray(rng.random((xr, yr, zp), dtype=np.float32)) for _ in names]
+    slabs = [jnp.asarray(rng.random((xr, 2 * s, yr), dtype=np.float32)) for _ in names]
+    origin = jnp.array([18, 0, 0], jnp.int32)
+    assert sm.z_halo_patch_form(zp, s) == form
+
+    def run():
+        outs, zouts = sm.stream_wavefront_pass(
+            mean6_kernel, names, raws, m, s, origin, Dim3(36, 36, zv - 2 * s),
+            z_slabs=slabs, z_valid=zv, interpret=True,
+        )
+        return [np.asarray(o[: xr - m]) for o in list(outs) + list(zouts)]
+
+    ours = run()
+    monkeypatch.setattr(sm, "patch_z_halo", parent_patch_z_halo)
+    parents = run()
+    assert len(ours) == 4
+    for a, b in zip(ours, parents):
+        np.testing.assert_array_equal(a, b)
+    assert not np.array_equal(ours[0], np.asarray(raws[0][: xr - m]))

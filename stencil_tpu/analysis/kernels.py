@@ -36,7 +36,14 @@ classes into static verdicts:
   (``ops/pack.py lane_pad``) round their minor extent up to 128 and never
   visit the dead pad columns, so a trailing minor-dim run of uncovered
   blocks shorter than one lane tile — on an output whose minor extent is
-  a 128-multiple — is tolerated too.  Any other gap fires.
+  a 128-multiple — is tolerated too.  Any other gap fires.  A BOUNDARY
+  block — wider than the array in a dim, as the z-slab wavefront pass
+  streams a raw ``(Xr, Yr, Zr)`` block through ``(1, Yr, Zp)`` windows,
+  ``Zp`` the next 128-multiple (the DMA moves ``Zr`` lanes, the rest of the
+  window is VMEM only) — covers the ARRAY's extent in that dim, not its
+  own: blocks are counted ``ceil(array / block)`` and a block's cells are
+  clamped to the array (:func:`_block_box`), so one such block is the whole
+  dim and in-place order is judged on the cells that exist.
 * **In-place order** (:func:`check_inplace_order`, contract
   ``inplace-order``).  An output that aliases an input
   (``input_output_aliases``) shares its HBM buffer, so on a sequential
@@ -80,6 +87,11 @@ classes into static verdicts:
     on v5e (partial-tile transfers cost bandwidth, not legality —
     PERF_NOTES "HBM ragged-edge tax").  Only a grid of multi-row windows
     whose extent is off the granule has no representable tiled layout.
+    A BOUNDARY block (extent past the array's, one window) is legal where
+    that extent is on the granule — Mosaic clamps the transfer to the
+    array — and has no layout where it is not: the lowering wants the last
+    two block dims "divisible by 8 and 128 respectively, or be equal to
+    the respective dimensions of the overall array".
   - int64 grid index arithmetic (``jax_enable_x64``) — Mosaic index
     arithmetic is 32-bit ("failed to legalize").  Config legs are scoped
     to where the config is the KERNEL's fault: the traced contract fires
@@ -600,7 +612,23 @@ def _window_faults(rep: KernelReport) -> List[str]:
                     f"({sub}, {LANE_GRANULE}) {use.dtype} tile grid "
                     "('invalid offsets in tiling target')"
                 )
+            elif _off_granule_boundary(shape[d], use.array_shape[d], gran):
+                out.append(
+                    f"{rep.label}: {use.role}[{use.index}] streams the "
+                    f"{name} dim's {use.array_shape[d]} cells through a "
+                    f"boundary block of extent {shape[d]} — a block wider "
+                    f"than the array must be whole ({sub}, {LANE_GRANULE}) "
+                    f"{use.dtype} tiles ('divisible by 8 and 128 "
+                    "respectively, or be equal to the respective dimensions "
+                    "of the overall array')"
+                )
     return out
+
+
+def _off_granule_boundary(block: int, array: int, gran: int) -> bool:
+    """A block wider than the array in this dim whose extent is not whole
+    tiles: neither of the two forms the lowering takes (module docstring)."""
+    return block > array and block % gran != 0
 
 
 def _index_faults(rep: KernelReport) -> List[str]:
@@ -665,8 +693,10 @@ def check_kernel_legal(dd, plan: dict) -> Optional[str]:
     rotate every resident plane; narrow floats upcast inside
     ``_make_roll``, 8-byte and narrow integer dtypes cannot), and the
     blocked-window offset granule over the pass's block layout (all three
-    stream passes stream single-window ``(1, Y, Z)``-family blocks today,
-    so this leg guards future geometries rather than current ones).
+    stream passes stream single-window ``(1, Y, Z)``-family blocks today —
+    the z-slab wavefront a BOUNDARY one, ``lane_pad_width(Z)`` lanes over
+    the raw block's ``Z``, whole lane tiles by construction — so this leg
+    guards future geometries rather than current ones).
     """
     route = plan.get("route")
     if route not in ("wrap", "wavefront", "plane"):
@@ -702,7 +732,12 @@ def check_kernel_legal(dd, plan: dict) -> Optional[str]:
     # z-slab message blocks when the plan carries them
     layouts = [((1, raw.y, raw.z), (raw.x, raw.y, raw.z))]
     if plan.get("z_slabs"):
-        layouts.append(((1, 2 * m, raw.y), (raw.x, 2 * m, raw.y)))
+        from stencil_tpu.ops.stream import lane_pad_width
+
+        layouts = [
+            ((1, raw.y, lane_pad_width(raw.z)), (raw.x, raw.y, raw.z)),
+            ((1, 2 * m, raw.y), (raw.x, 2 * m, raw.y)),
+        ]
     for h in dd._handles:
         itemsize = dd.field_dtype(h).itemsize
         sub = sublane_granule(itemsize)
@@ -718,5 +753,13 @@ def check_kernel_legal(dd, plan: dict) -> Optional[str]:
                         f"{nb} windows of extent {block[d]} — sub-granule "
                         "window offsets ('invalid offsets in tiling "
                         "target')"
+                    )
+                if _off_granule_boundary(block[d], array[d], gran):
+                    return (
+                        f"plan {route}[m={m}] streams the {name} dim's "
+                        f"{array[d]} cells through a boundary block of "
+                        f"extent {block[d]} — not whole tiles ('divisible "
+                        "by 8 and 128 respectively, or be equal to the "
+                        "respective dimensions of the overall array')"
                     )
     return None

@@ -229,6 +229,24 @@ def _yz_coord_planes(origin_ref, Yr, Zr, off_y, off_z, gsize):
     return y_g, z_g
 
 
+def _zero_lane_pad(plane, valid: int):
+    """``plane`` (Yr, Zp) with lanes [valid, Zp) set to zero -- the dead lanes
+    of a boundary block, which hold whatever the VMEM buffer held.  ``Zp`` is
+    ``lane_pad_width(valid)``, so they all sit in the LAST lane tile: one
+    select there (the tile sliced out at a multiple of 128, as
+    ``patch_z_halo`` takes its own), the other tiles untouched.  The zeros
+    are what the ``jnp.pad`` this replaces stored, so nothing non-finite
+    reaches a level, the emit or the stored shell."""
+    Yr, Zp = plane.shape
+    if Zp == valid:
+        return plane
+    at = Zp - 128
+    assert at <= valid < Zp, (valid, Zp)
+    lane = lax.broadcasted_iota(jnp.int32, (Yr, 128), 1)
+    last = jnp.where(lane < valid - at, plane[:, at:], jnp.zeros((), plane.dtype))
+    return jnp.concatenate([plane[:, :at], last], axis=1) if at else last
+
+
 def _fused_plane_patch(v, xplane, yst, zst, t, lo_y, hi_y, lo_z, hi_z):
     """Patch one level-0 VMEM plane from the fused shell buffers, replaying
     the exchange's sweep order x -> y -> z: replace the whole plane when
@@ -640,7 +658,6 @@ def stream_wavefront_pass(
     origin: jax.Array,
     global_size: Dim3,
     z_slabs: Sequence[jax.Array] = None,  # per-q (Xr, 2s, Yr) z-major slabs
-    z_valid: int = None,  # logical plane width; [z_valid, Zr) is lane padding
     alias: bool = False,
     interpret: bool = False,
     f32_accumulate: bool = False,  # bf16-storage variant: upcast at load,
@@ -654,11 +671,18 @@ def stream_wavefront_pass(
     its docstring for the shrinking-validity contamination argument, the
     z-slab layout, and the lane-padding rationale; all carry over verbatim).
     Returns the advanced blocks, plus per-quantity outgoing z slabs when
-    ``z_slabs`` is given.  In that form each level-0 plane gets its z halo
-    from the slab block through ``jacobi_pallas.patch_z_halo``: on the
-    lane-padded plane inside the lane tiles that hold the halo lanes -- tile
-    0 for [0, s), the one or two tiles over [z_valid - s, z_valid) -- and
-    nowhere else (``domain.step`` says ``z_halo_patch: "tile"``).
+    ``z_slabs`` is given.  In that form the blocks stay the domain's raw
+    ``(Xr, Yr, Zr)`` ones and the LANE PADDING LIVES IN VMEM ONLY: every
+    quantity streams through ``(1, Yr, Zp)`` blocks, ``Zp =
+    lane_pad_width(Zr)`` -- a boundary block in the minor dimension, so the
+    DMA brings ``Zr`` lanes into a ``Zp``-lane plane and writes ``Zr`` back
+    -- and lanes [Zr, Zp) of each level-0 plane, whatever the VMEM block
+    held, are set to zero (``domain.step`` says ``lane_pad: "vmem"``;
+    ``"none"`` where ``Zr`` is whole lane tiles already).  Each level-0 plane
+    gets its z halo from the slab block through
+    ``jacobi_pallas.patch_z_halo``: on the lane-padded plane inside the lane
+    tiles that hold the halo lanes -- tile 0 for [0, s), the one or two tiles
+    over [Zr - s, Zr) -- and nowhere else (``z_halo_patch: "tile"``).
 
     With ``fused_shell`` the blocks' shell cells are STALE and every axis's
     fresh halos ride as side inputs (``fused_shell_exchange``): each
@@ -671,8 +695,9 @@ def stream_wavefront_pass(
 
     nq = len(names)
     Xr, Yr, Zr = raws[0].shape
-    zv = Zr if z_valid is None else z_valid
-    assert 1 <= m <= s_off and 2 * s_off < min(Xr, Yr, zv), (m, s_off, zv)
+    # the working plane's width: whole lane tiles in the z-slab form
+    Zp = lane_pad_width(Zr) if z_slabs is not None else Zr
+    assert 1 <= m <= s_off and 2 * s_off < min(Xr, Yr, Zr), (m, s_off, Zr)
     assert z_slabs is None or fused_shell is None
     gsize = global_size
     assert 2 * s_off < gsize.x, (s_off, gsize)  # non-negative lax.rem operand
@@ -702,7 +727,7 @@ def stream_wavefront_pass(
         i = pl.program_id(0)
         # level-0 raw plane i per quantity (upcast once under f32_accumulate)
         vals = [up(ref[0]) for ref in in_refs]
-        y_g, z_g = _yz_coord_planes(origin_ref, Yr, Zr, s_off, s_off, gsize)
+        y_g, z_g = _yz_coord_planes(origin_ref, Yr, Zp, s_off, s_off, gsize)
         if fused_shell is not None:
             # level-0 VMEM patch (module docstring; _fused_plane_patch —
             # upcast once under f32_accumulate, like the raw planes)
@@ -720,7 +745,9 @@ def stream_wavefront_pass(
             # array (see jacobi_shell_wavefront_step) — in their lane tiles
             for q in range(nq):
                 zst = up(jnp.swapaxes(zs_refs[q][0], 0, 1))  # (Yr, 2s)
-                vals[q] = patch_z_halo(vals[q], zst, s_off, 0, zv - s_off, roll)
+                vals[q] = patch_z_halo(
+                    _zero_lane_pad(vals[q], Zr), zst, s_off, 0, Zr - s_off, roll
+                )
         for s in range(1, m + 1):
             prevs = [rings[q][s - 1, i % 2] for q in range(nq)]
             cents = [rings[q][s - 1, (i + 1) % 2] for q in range(nq)]
@@ -748,7 +775,7 @@ def stream_wavefront_pass(
             if zout_refs is not None:
                 emit = jnp.concatenate(
                     [
-                        vals[q][:, zv - 2 * s_off : zv - s_off],
+                        vals[q][:, Zr - 2 * s_off : Zr - s_off],
                         vals[q][:, s_off : 2 * s_off],
                     ],
                     axis=1,
@@ -757,9 +784,9 @@ def stream_wavefront_pass(
 
     out_idx = lambda i: (jnp.maximum(i - m, 0), 0, 0)
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + [
-        pl.BlockSpec((1, Yr, Zr), lambda i: (i, 0, 0)) for _ in range(nq)
+        pl.BlockSpec((1, Yr, Zp), lambda i: (i, 0, 0)) for _ in range(nq)
     ]
-    out_specs: list = [pl.BlockSpec((1, Yr, Zr), out_idx) for _ in range(nq)]
+    out_specs: list = [pl.BlockSpec((1, Yr, Zp), out_idx) for _ in range(nq)]
     out_shape: list = [
         jax.ShapeDtypeStruct((Xr, Yr, Zr), b.dtype) for b in raws
     ]
@@ -818,7 +845,7 @@ def stream_wavefront_pass(
         out_shape=tuple(out_shape),
         input_output_aliases=aliases,
         scratch_shapes=[
-            pltpu.VMEM((m, 2, Yr, Zr), acc) for acc in acc_dtypes
+            pltpu.VMEM((m, 2, Yr, Zp), acc) for acc in acc_dtypes
         ],
         interpret=interpret,
         **_tpu_compiler_params(interpret),
@@ -2218,6 +2245,7 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
     plan.pop("wire_bytes", None)
     plan.pop("macros_per_trip", None)
     plan.pop("z_halo_patch", None)
+    plan.pop("lane_pad", None)
     if plan["route"] == "plane":
         default = not fused and not split
         stage_runs = plan_plane_stages(
@@ -2445,13 +2473,16 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
         s = lo.x
         z_slab_mode = plan["z_slabs"]
         Xr, Yr, Zr = raw.x, raw.y, raw.z
-        Zp = lane_pad_width(Zr) if z_slab_mode else Zr
         if z_slab_mode:
             # where the pass patches its z halo, read off the working plane's
             # shape as the kernel's own helper reads it (patch_z_halo),
             # written back like macros_per_trip (domain.step's
-            # ``z_halo_patch``): "tile" on the lane-padded plane
-            plan["z_halo_patch"] = z_halo_patch_form(Zp, s)
+            # ``z_halo_patch``): "tile" on the lane-padded plane -- which the
+            # pass makes in VMEM from the raw block where ``Zr`` is not whole
+            # lane tiles (``lane_pad``), so the step carries the domain's own
+            # blocks and pads or cuts nothing
+            plan["z_halo_patch"] = z_halo_patch_form(lane_pad_width(Zr), s)
+            plan["lane_pad"] = "vmem" if Zr % 128 else "none"
         yext, xext = make_slab_extenders(Xr, Yr, s, mesh_shape)
 
         def wavefront_groups(bs, depth, origin, zs=None, fused_bufs=None):
@@ -2471,7 +2502,6 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                     kernel, [names[q] for q in g], [bs[q] for q in g],
                     depth, s, origin, gsize,
                     z_slabs=[zs[q] for q in g] if zs is not None else None,
-                    z_valid=Zr if zs is not None else None,
                     alias=alias,
                     interpret=interpret,
                     fused_shell=fs,
@@ -2581,18 +2611,15 @@ def _build_stream_step(dd, kernel, x_radius, plan, interpret, donate=True):
                 outs, zouts = wavefront_groups(bs, depth, origin, zs)
                 return tuple(outs), tuple(zouts)
 
-            # prime slabs from the blocks' interior z boundaries, lane-pad
-            bs = tuple(
-                jnp.pad(b, ((0, 0), (0, 0), (0, Zp - Zr))) for b in blocks
-            )
+            # prime slabs from the blocks' interior z boundaries
             zouts = tuple(prime_z_slabs(b, Zr, s) for b in blocks)
             macros, rem = divmod(steps, m)
             carry = lax.fori_loop(
-                0, macros, lambda _, c: macro(m, c), (bs, zouts)
+                0, macros, lambda _, c: macro(m, c), (tuple(blocks), zouts)
             )
             if rem:
                 carry = macro(rem, carry)
-            return tuple(b[:, :, :Zr] for b in carry[0])
+            return carry[0]
 
     donate_kw = {"donate_argnums": 0} if donate else {}
 
@@ -2944,6 +2971,9 @@ def make_stream_step(
             # the z-slab wavefront: whether the pass patches its z halo in
             # the lane tiles that hold it or over the whole plane
             args["z_halo_patch"] = plan_now["z_halo_patch"]
+            # ... and where its lane padding lives: "vmem" (the pass widens
+            # the raw block's plane itself) or "none" (nothing to pad)
+            args["lane_pad"] = plan_now["lane_pad"]
         if "wired" in plan_now:
             # the axes whose sweep of the step's exchange crosses to another
             # shard, and the bytes one shard receives over them a step, all

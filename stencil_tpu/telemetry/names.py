@@ -445,7 +445,13 @@ ALL_HISTOGRAMS = frozenset({
 #: the whole plane, on one that is not (``ops/jacobi_pallas.patch_z_halo``,
 #: read off the static shapes by ``z_halo_patch_form``: "tile" in
 #: ``jacobi3d-512x4.bulk`` and ``astaroth-8q-512.bulk``; no other step says
-#: it); every stream-engine step says what its kernels READ
+#: it), and the stream engine's z-slab wavefront step adds lane_pad = where
+#: the working plane's lane padding lives: "vmem" = the step carries the
+#: domain's raw blocks and ``stream_wavefront_pass`` widens each plane to whole
+#: lane tiles itself, through a boundary block (``astaroth-8q-512.bulk``: 518
+#: lanes in a 640-lane plane), "none" = the raw z extent is a multiple of 128
+#: already (read off ``Zr % 128``; the step pads and cuts nothing in HBM
+#: either way); every stream-engine step says what its kernels READ
 #: beside what the route SERVES: quantities = the quantities it carries,
 #: offcentre = those read at a non-zero offset, diagonal = those of them read
 #: at an offset with two or more non-zero components (an edge or corner halo),

@@ -655,6 +655,53 @@ def test_inplace_order_judges_an_output_aliased_onto_another_quantity():
     assert "flushes block (1, 0, 0) after grid step 1" in msg and "step 3" in msg
 
 
+def test_a_boundary_block_counts_the_arrays_extent():
+    """A block wider than the array in the minor dim (ISSUE 41), whole lane
+    tiles: ONE block covers the dim -- its cells are the array's 200, not its
+    own 256 -- so the clean fixture, in place and a plane behind its reads, is
+    in order and covered, and every kernel contract is quiet; the same windows
+    at 250 lanes fire ``tiling-legal`` alone, naming the boundary block."""
+    from stencil_tpu.analysis import kernels
+
+    art = _load(os.path.join(FIXTURE_DIR, "tiling_legal_boundary_clean.py"))
+    (rep,) = kernels.kernel_reports(art.closed)
+    (use,) = rep.inputs
+    assert (use.block_shape, use.array_shape, use.nblocks) == ((1, 16, 256), (4, 16, 200), (4, 1, 1))
+    assert kernels._block_box(use, (2, 0, 0)) == ((2, 3), (0, 16), (0, 200))
+    assert rep.aliases
+    shape_contracts = ("kernel-coverage", "inplace-order", "kernel-race", "tiling-legal")
+    for contract in shape_contracts:
+        assert not analysis.check(art, contract=contract), contract
+    fire = _load(os.path.join(FIXTURE_DIR, "tiling_legal_boundary_fire.py"))
+    findings = [f for c in shape_contracts for f in analysis.check(fire, contract=c)]
+    assert len(findings) == 2  # the operand's window and the result's
+    for finding in findings:
+        assert finding.contract == "tiling-legal"
+        assert "boundary block of extent 250" in finding.render() and "200 cells" in finding.render()
+
+
+@pytest.mark.parametrize("z,want", [(10, None), (122, None)], ids=["boundary-16-in-128", "whole-tiles-128"])
+def test_check_kernel_legal_takes_the_z_slab_boundary_block(z, want):
+    """The plan surface models the z-slab wavefront's window as the pass
+    builds it -- ``lane_pad_width(Zr)`` lanes over the raw block's ``Zr`` --
+    and finds it legal; a window that were neither the array's extent nor
+    whole lane tiles would not be."""
+    from stencil_tpu.analysis import kernels
+    from stencil_tpu.core.radius import Radius
+    from stencil_tpu.domain import DistributedDomain
+
+    dd = DistributedDomain(16, 16, z)
+    dd.set_radius(Radius.constant(3))
+    dd.set_devices(jax.devices()[:4])
+    dd.set_partition(2, 2, 1)
+    dd.add_data("q")
+    dd.realize(allocate=False)
+    plan = {"route": "wavefront", "m": 3, "z_slabs": True, "grouping": "joint"}
+    assert kernels.check_kernel_legal(dd, plan) is want
+    assert kernels._off_granule_boundary(250, 200, 128) and not kernels._off_granule_boundary(256, 200, 128)
+    assert not kernels._off_granule_boundary(200, 200, 128)
+
+
 # --- tier-2: the real CLI end to end -----------------------------------------
 
 

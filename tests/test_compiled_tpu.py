@@ -135,6 +135,61 @@ def test_compiled_astaroth_schedules_match():
         np.testing.assert_allclose(a.field(i), b.field(i), rtol=0, atol=1e-6)
 
 
+@pytest.mark.parametrize("storage", ["native", "bf16"])
+def test_compiled_zslab_step_carries_the_raw_blocks(storage, monkeypatch):
+    """The z-slab wavefront step compiled by Mosaic (ISSUE 41): the raw
+    ``(70, 70, 70)`` blocks stream through ``(1, 70, 128)`` boundary blocks, in
+    place, their dead lanes zeroed in VMEM -- bitwise, on every plane the
+    passes own (all rows, all lanes: the z-shell lanes beside the dead ones
+    included), the step that pads in HBM and runs the plain pass
+    (``tests/test_lane_pad_vmem.py hbm_padded_pass``), after two dispatches
+    of two macros and a remainder, and within the interior the XLA engine's
+    per-step result.  A dead lane left as the VMEM buffer held it shows in
+    lane 69 after one level."""
+    import test_lane_pad_vmem as lp
+    from stencil_tpu.core.radius import Radius
+    from stencil_tpu.domain import DistributedDomain
+    from stencil_tpu.ops import stream as sm
+
+    names = ("a", "b", "c", "d")  # four: the wavefront's static rule runs in place
+
+    def two_dispatches(engine):
+        dd = DistributedDomain(64, 64, 64)
+        dd.set_radius(Radius.constant(3 if engine == "stream" else 1))
+        dd.set_devices(jax.devices()[:1])
+        if engine == "stream":
+            dd.set_storage(storage)
+        hs = [dd.add_data(nm) for nm in names]
+        dd.realize()
+        for i, h in enumerate(hs):
+            dd.init_by_coords(h, lambda x, y, z, i=i: jnp.sin(0.13 * (x + 2 * y + 3 * z) + i))
+        if engine == "stream":
+            step = dd.make_step(_mean6, engine="stream", x_radius=1, stream_path="wavefront")
+            plan = step._stream_plan
+            assert (plan["route"], plan["m"], plan["z_slabs"], plan["alias"], plan["lane_pad"]) == (
+                "wavefront", 3, True, True, "vmem"), plan
+        else:
+            step = dd.make_step(_mean6, overlap=False)
+        for _ in range(2):
+            dd.run_step(step, 7)
+        if engine == "stream":
+            assert step._resilience.descents == [], step._resilience.descents
+        raws = [np.asarray(dd._curr[nm].astype(jnp.float32)) for nm in names]
+        return raws, [dd.quantity_to_host(h) for h in hs]
+
+    ours, fields = two_dispatches("stream")
+    monkeypatch.setattr(sm, "stream_wavefront_pass", lp.hbm_padded_pass(sm.stream_wavefront_pass))
+    padded, _ = two_dispatches("stream")
+    for a, b in zip(ours, padded):
+        assert a.shape == (70, 70, 70)
+        np.testing.assert_array_equal(a[3:-3], b[3:-3])
+        assert np.isfinite(a[3:-3]).all()
+    if storage == "native":
+        _, want = two_dispatches("xla")
+        for a, b in zip(fields, want):
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
+
+
 # --- census: one compiled case per non-default axis value ---------------------
 #
 # Twins of the interpret-mode suites (test_exchange_routes, test_overlap_split,

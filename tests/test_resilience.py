@@ -491,13 +491,16 @@ class TestLadderEngines:
             # what the kernel reads, traced for the span alone here (ISSUE 39)
             "footprint": {"offcentre": 1, "diagonal": 0, "read_sides": 6},
             # the z-slab pass patches its z halo in the lane tiles that hold
-            # it, on the lane-padded plane (ISSUE 40)
+            # it, on the lane-padded plane (ISSUE 40), made in VMEM from the
+            # raw block (ISSUE 41)
             "z_halo_patch": "tile",
+            "lane_pad": "vmem",
         }
         inject.set_plan("execute:vmem_oom:stream*2")
         dd.run_step(step, 4)
         assert step._stream_plan["route"] == "plane"
-        assert "z_halo_patch" not in step._stream_plan  # the z-slab wavefront's alone
+        # the z-slab wavefront's alone
+        assert "z_halo_patch" not in step._stream_plan and "lane_pad" not in step._stream_plan
         # a depth descent re-plans: the plane rung resolves its OWN alias
         # (in place) instead of inheriting the wavefront rung's
         assert step._stream_plan["alias"] is True

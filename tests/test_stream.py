@@ -443,10 +443,13 @@ def test_stream_separable_per_field_grouping(monkeypatch):
         # what the kernel reads, one trace a group, for the span alone (ISSUE 39)
         "footprint": {"offcentre": 4, "diagonal": 0, "read_sides": 24},
         # the z-slab pass patches its z halo in the lane tiles that hold it,
-        # on the lane-padded plane (ISSUE 40)
+        # on the lane-padded plane (ISSUE 40), which it makes in VMEM from the
+        # 30-lane raw block (ISSUE 41)
         "z_halo_patch": "tile",
+        "lane_pad": "vmem",
     }
     assert step._span_args()["z_halo_patch"] == "tile"
+    assert step._span_args()["lane_pad"] == "vmem"
     monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
     ref_dd, ref_hs = _mk(24, 24, 24, Radius.constant(1), names, devs)
     ref = ref_dd.make_step(mean6_kernel, overlap=False)
@@ -985,16 +988,16 @@ def test_an_nd_quantity_keeps_every_sweep_in_the_exchange(monkeypatch):
         )
 
 
-@pytest.mark.parametrize("zp,zv,form", [(128, 30, "tile"), (256, 130, "tile"), (30, 30, "plane")],
-                         ids=["lane-padded", "hi-halo-straddles-128", "unpadded"])
-def test_wavefront_pass_z_slab_patch_returns_the_parents_bytes(zp, zv, form, monkeypatch):
+@pytest.mark.parametrize("zr", [30, 130, 128], ids=["lane-padded", "hi-halo-straddles-128", "whole-tiles"])
+def test_wavefront_pass_z_slab_patch_returns_the_parents_bytes(zr, monkeypatch):
     """``stream_wavefront_pass`` in z-slab form, m = 3, two fields: with the
     z halo patched inside its lane tiles (ISSUE 40) the pass returns, on every
     plane it writes, the blocks and the outgoing slabs it returned with the
     parent's whole-plane selects (the oracle of tests/test_jacobi_pallas.py
-    patched in for the helper) -- on a lane-padded plane, on one whose hi halo
-    straddles a multiple of 128 (lanes 127..129), and on an unpadded one,
-    which keeps the whole-plane form."""
+    patched in for the helper) -- on a raw block the pass pads to one lane
+    tile in VMEM (ISSUE 41), on one whose hi halo straddles a multiple of 128
+    (lanes 127..129 of a 256-lane plane), and on one that is whole lane tiles
+    as it stands."""
     import stencil_tpu.ops.stream as sm
     from stencil_tpu.core.dim3 import Dim3
     from test_jacobi_pallas import parent_patch_z_halo
@@ -1002,17 +1005,18 @@ def test_wavefront_pass_z_slab_patch_returns_the_parents_bytes(zp, zv, form, mon
     m = s = 3
     xr = yr = 24
     names = ["a", "b"]
-    rng = np.random.default_rng(zp + zv)
-    raws = [jnp.asarray(rng.random((xr, yr, zp), dtype=np.float32)) for _ in names]
+    rng = np.random.default_rng(zr)
+    raws = [jnp.asarray(rng.random((xr, yr, zr), dtype=np.float32)) for _ in names]
     slabs = [jnp.asarray(rng.random((xr, 2 * s, yr), dtype=np.float32)) for _ in names]
     origin = jnp.array([18, 0, 0], jnp.int32)
-    assert sm.z_halo_patch_form(zp, s) == form
+    assert sm.z_halo_patch_form(sm.lane_pad_width(zr), s) == "tile"
 
     def run():
         outs, zouts = sm.stream_wavefront_pass(
-            mean6_kernel, names, raws, m, s, origin, Dim3(36, 36, zv - 2 * s),
-            z_slabs=slabs, z_valid=zv, interpret=True,
+            mean6_kernel, names, raws, m, s, origin, Dim3(36, 36, zr - 2 * s),
+            z_slabs=slabs, interpret=True,
         )
+        assert all(o.shape == (xr, yr, zr) for o in outs)
         return [np.asarray(o[: xr - m]) for o in list(outs) + list(zouts)]
 
     ours = run()

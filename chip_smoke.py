@@ -278,7 +278,7 @@ def leg_user_mean6(devices, shape, interpret: bool = False, name="A user-mean6")
     import jax.numpy as jnp
 
     from stencil_tpu import DistributedDomain
-    from stencil_tpu.ops.stream import plan_stream
+    from stencil_tpu.ops.stream_plan import plan_stream
 
     before = _counters()
     leg = {"leg": name, "device": _device_report(devices), "global": list(shape)}

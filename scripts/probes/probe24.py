@@ -12,8 +12,8 @@ from stencil_tpu.ops.exchange import halo_exchange_shard
 from stencil_tpu.ops.jacobi_pallas import (
     jacobi_shell_wavefront_step, pack_d2, yz_dist2_plane)
 from stencil_tpu.ops.stream import (
-    lane_pad_width, make_slab_extenders, permute_and_extend_z_slabs,
-    prime_z_slabs)
+    make_slab_extenders, permute_and_extend_z_slabs, prime_z_slabs)
+from stencil_tpu.ops.stream_pass import lane_pad_width
 from stencil_tpu.parallel.mesh import MESH_AXES
 
 def main():

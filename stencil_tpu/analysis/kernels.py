@@ -732,7 +732,7 @@ def check_kernel_legal(dd, plan: dict) -> Optional[str]:
     # z-slab message blocks when the plan carries them
     layouts = [((1, raw.y, raw.z), (raw.x, raw.y, raw.z))]
     if plan.get("z_slabs"):
-        from stencil_tpu.ops.stream import lane_pad_width
+        from stencil_tpu.ops.stream_pass import lane_pad_width
 
         layouts = [
             ((1, raw.y, lane_pad_width(raw.z)), (raw.x, raw.y, raw.z)),

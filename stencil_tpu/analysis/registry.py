@@ -36,11 +36,11 @@ CANONICAL_AXES = {
         ),
     },
     "STREAM_OVERLAP": {
-        "module": "stencil_tpu/ops/stream.py",
+        "module": "stencil_tpu/ops/stream_plan.py",
         "covered": ("off", "split"),
     },
     "STREAM_HALO": {
-        "module": "stencil_tpu/ops/stream.py",
+        "module": "stencil_tpu/ops/stream_plan.py",
         "covered": ("array", "fused"),
     },
     "STORAGE_DTYPES": {
@@ -88,7 +88,7 @@ PALLAS_KERNELS = {
         "mean6_shell_wavefront_step",
         "mean6_plane_step",
     ),
-    "stencil_tpu/ops/stream.py": (
+    "stencil_tpu/ops/stream_pass.py": (
         "stream_plane_pass",
         "stream_wavefront_pass",
         "stream_wrap_pass",

@@ -1,8 +1,8 @@
 """Static VMEM verdicts — the analytic footprint re-derived where the
 compiler would otherwise discover it by failing.
 
-Two entry points over the SAME models the planners use
-(``ops/jacobi_pallas.wavefront_vmem_bytes`` / ``ops/stream.stream_vmem_fits``):
+Two entry points over the ONE model the stream planners use
+(``ops/stream_plan.py stream_plan_vmem_bytes`` / ``stream_vmem_bytes``):
 
 * :func:`check_vmem` — pre-build: a stream PLAN against a realized domain.
   ``tune/space.stream_space`` consults it to prefilter candidates before
@@ -20,66 +20,25 @@ fit question (a malformed plan is the caller's bug and does raise).
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import Optional
 
-
-def stream_plan_vmem_bytes(
-    m: int,
-    plane_y: int,
-    plane_z: int,
-    itemsizes: Sequence[int],
-    z_slabs: bool = False,
-    ring_itemsizes: Optional[Sequence[int]] = None,
-    fused: bool = False,
-) -> int:
-    """Modeled VMEM block bytes of a stream plan (stack margin excluded —
-    compare against :func:`budget_and_margin`).  The generic-engine model
-    (``stream_vmem_fits``'s accounting) plus, under ``halo="fused"``, the
-    double-buffered fused-shell side blocks: per field, one (1, y, z) x-slab
-    plane plus the (1, 2m, z) y and (1, 2m, y) z message blocks per grid
-    step."""
-    from stencil_tpu.ops.jacobi_pallas import _padded_plane_bytes
-
-    ring = list(itemsizes) if ring_itemsizes is None else list(ring_itemsizes)
-    est = 0
-    for it, rit in zip(itemsizes, ring):
-        est += 2 * m * _padded_plane_bytes(plane_y, plane_z, rit)
-        est += 4 * _padded_plane_bytes(plane_y, plane_z, it)
-        if z_slabs:
-            est += 4 * _padded_plane_bytes(2 * m, plane_y, it)
-        if fused:
-            est += 2 * _padded_plane_bytes(plane_y, plane_z, it)
-            est += 2 * _padded_plane_bytes(2 * m, plane_z, it)
-            est += 2 * _padded_plane_bytes(2 * m, plane_y, it)
-    return est
-
-
-def budget_and_margin(n_fields: int, budget: Optional[int] = None):
-    """(requested scoped-VMEM budget bytes, per-plan stack margin) — the
-    calibrated numbers the planners gate on (``STENCIL_VMEM_LIMIT_BYTES``
-    validated read unless ``budget`` overrides)."""
-    from stencil_tpu.ops.jacobi_pallas import _VMEM_STACK_MARGIN, _vmem_budget
-
-    return (budget if budget is not None else _vmem_budget(),
-            _VMEM_STACK_MARGIN * max(1, n_fields))
+from stencil_tpu.ops.jacobi_pallas import _vmem_budget
+from stencil_tpu.ops.stream_plan import stack_margin, stream_plan_vmem_bytes, stream_vmem_bytes
 
 
 def check_vmem(dd, plan: dict, budget: Optional[int] = None) -> Optional[str]:
     """Does this stream plan's modeled footprint fit the scoped-VMEM budget
     on this realized domain?  ``None`` = fits; otherwise a reason string
-    naming the estimate and the budget.  The per-field itemsizes honor the
-    storage axis (bf16 buffers stream 2 B planes but carry f32 level
-    rings — the ``f32_accumulate`` contract)."""
+    naming the estimate and the budget (``budget`` overrides the validated
+    ``STENCIL_VMEM_LIMIT_BYTES`` read).  The planes and itemsizes are the
+    planner's own (``stream_plan_vmem_bytes``)."""
     route = plan.get("route")
     if route not in ("wrap", "wavefront", "plane"):
         raise ValueError(f"not a stream plan: {plan!r}")
-    m = int(plan.get("m", 1))
+    cap = budget if budget is not None else _vmem_budget()
     if route == "plane" and plan.get("stages"):
-        # a planned plane step: its passes carry their own modeled bytes
-        # (ops/stream.py plan_plane_passes, stack margin included)
-        from stencil_tpu.ops.jacobi_pallas import _vmem_budget
-
-        cap = budget if budget is not None else _vmem_budget()
+        # a resolved plane step: its passes carry their own modeled bytes
+        # (ops/stream_plan.py plan_plane_passes, stack margin included)
         worst = max(
             (p for st in plan["stages"] for p in st["passes"]),
             key=lambda p: p["vmem_bytes"], default=None,
@@ -91,30 +50,11 @@ def check_vmem(dd, plan: dict, budget: Optional[int] = None) -> Optional[str]:
                 f"against the {cap / 1e6:.1f} MB budget"
             )
         return None
-    # the planes the pass streams, as plan_stream models them: the wrap
-    # route works on the bare interiors (the periodic boundary is folded into
-    # its index maps), the wavefront route on the raw, shell-carrying planes
-    spec = dd.local_spec()
-    planes = spec.sz if route == "wrap" else spec.raw_size()
-    itemsizes: List[int] = [dd.field_dtype(h).itemsize for h in dd._handles]
-    ring_sizes: List[int] = [h.dtype.itemsize for h in dd._handles]
-    if plan.get("grouping") == "per-field" and len(itemsizes) > 1:
-        itemsizes = [max(itemsizes)]
-        ring_sizes = [max(ring_sizes)]
-    est = stream_plan_vmem_bytes(
-        m,
-        planes.y,
-        planes.z,
-        itemsizes,
-        z_slabs=bool(plan.get("z_slabs")),
-        ring_itemsizes=ring_sizes,
-        fused=plan.get("halo") == "fused",
-    )
-    cap, margin = budget_and_margin(len(itemsizes), budget)
+    est, margin = stream_plan_vmem_bytes(dd, plan)
     if est + margin > cap:
         tags = ",fused" if plan.get("halo") == "fused" else ""
         return (
-            f"plan {plan.get('route')}[m={m}{tags}] models "
+            f"plan {route}[m={plan.get('m', 1)}{tags}] models "
             f"{est / 1e6:.1f} MB of VMEM blocks (+{margin / 1e6:.1f} MB "
             f"stack) against the {cap / 1e6:.1f} MB budget"
         )
@@ -124,8 +64,9 @@ def check_vmem(dd, plan: dict, budget: Optional[int] = None) -> Optional[str]:
 def check_traced(art, budget: Optional[int] = None) -> Optional[str]:
     """The ``vmem-budget`` contract's core: re-derive the footprint from the
     TRACED program — depth from the plan, plane dims and itemsizes from the
-    3-D operands of the pallas calls actually in the jaxpr — and gate it
-    against the budget.  ``None`` when it fits, or when the artifact has no
+    3-D operands of the pallas calls actually in the jaxpr, never from the
+    planner's choice of planes: only the arithmetic is shared
+    (``stream_vmem_bytes``) — and gate it against the budget.  ``None`` when it fits, or when the artifact has no
     stream plan / no pallas calls to model."""
     from stencil_tpu.analysis import jaxpr as jx
 
@@ -166,18 +107,14 @@ def check_traced(art, budget: Optional[int] = None) -> Optional[str]:
     if best is None:
         return None
     _, (py, pz), itemsizes, ring_itemsizes = best
-    est = stream_plan_vmem_bytes(
-        int(plan.get("m", 1)),
-        py,
-        pz,
-        itemsizes,
-        z_slabs=bool(plan.get("z_slabs")),
-        ring_itemsizes=ring_itemsizes,
-        fused=plan.get("halo") == "fused",
+    est = stream_vmem_bytes(
+        int(plan.get("m", 1)), py, pz, itemsizes, z_slabs=bool(plan.get("z_slabs")),
+        ring_itemsizes=ring_itemsizes, fused=plan.get("halo") == "fused",
     )
-    cap, margin = budget_and_margin(
-        len(itemsizes), budget if budget is not None else art.vmem_budget
-    )
+    margin = stack_margin(len(itemsizes))
+    cap = budget if budget is not None else art.vmem_budget
+    if cap is None:
+        cap = _vmem_budget()
     if est + margin > cap:
         return (
             f"traced pallas planes ({py}, {pz}) at depth m="

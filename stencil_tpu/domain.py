@@ -1635,12 +1635,12 @@ class DistributedDomain:
         # (auto maximizes it — the right call for bandwidth-bound kernels,
         # wrong for compute-heavy ones, whose VPU work scales with depth)
         stream_overlap: str = "auto",  # stream engine: split-step overlap
-        # schedule (ops/stream.py STREAM_OVERLAP): "split" dispatches the
+        # schedule (ops/stream_plan.py STREAM_OVERLAP): "split" dispatches the
         # interior pass with no data dependency on the shell ppermutes and
         # recomputes the boundary bands from fresh halos afterward —
         # bitwise-identical to "off"; "auto" resolves env > tuned > off
         stream_halo: str = "auto",  # stream engine: halo consumption mode
-        # (ops/stream.py STREAM_HALO): "fused" lands the packed yzpack_*
+        # (ops/stream_plan.py STREAM_HALO): "fused" lands the packed yzpack_*
         # exchange messages directly in the pass's level-0 VMEM planes (no
         # big-array halo write at all) — bitwise-identical to "array";
         # "auto" resolves env > tuned > array (docs/tuning.md "Fused halo

@@ -27,7 +27,7 @@ What it asks of the runtime, unlike ``Jacobi3D`` and ``AstarothSim``:
   the centre only: like ``u_prev`` they stay out of the step's exchange
   (the engine exchanges what the kernel reads off-centre, which is ``u``
   alone), and they are inputs of the pass and nothing else (it writes what
-  the kernel returns with a value of its own, ``u``) -- ``ops/stream.py
+  the kernel returns with a value of its own, ``u``) -- ``ops/stream_plan.py
   trace_plane_kernel`` learns all three from this kernel, docs/acoustic.md
   says what a step moves;
 * a Dirichlet edge on a periodic runtime: the ``FRAME`` outer cells are

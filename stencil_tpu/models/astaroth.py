@@ -69,7 +69,7 @@ class AstarothSim:
         # engine's split-step overlap schedule (ops/stream.py
         # STREAM_OVERLAP; "auto" = env > tuned > static off)
         stream_halo: str = "auto",  # pallas engine only: the stream
-        # engine's halo consumption mode (ops/stream.py STREAM_HALO;
+        # engine's halo consumption mode (ops/stream_plan.py STREAM_HALO;
         # "fused" lands the packed yzpack_* messages directly in the
         # pass's VMEM planes; "auto" = env > tuned > static array)
         exchange_route: str = None,  # pin the halo exchange's y/z-sweep

@@ -18,7 +18,7 @@ What it asks of the runtime, beyond what ``AcousticWave`` asks:
   coupled ones are all that fit ONE plane pass at 608 x 608: the stream
   engine forms each stage's passes from the kernel's own footprint, a pass
   carrying only the quantities its outputs touch and a VMEM ring only for
-  those read off-centre along x (``ops/stream.py plan_plane_passes``);
+  those read off-centre along x (``ops/stream_plan.py plan_plane_passes``);
 * a time step of TWO stages: stage T reads the velocities stage V has just
   written, off-centre, so the step exchanges between its stages --
   ``make_step`` takes the stages in order and every stage gets the exchange

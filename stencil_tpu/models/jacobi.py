@@ -25,7 +25,7 @@ from stencil_tpu.core.dim3 import Dim3
 from stencil_tpu.core.radius import Radius
 from stencil_tpu.domain import DistributedDomain
 from stencil_tpu.ops.stream import macro_loop as _macro_loop
-from stencil_tpu.ops.stream import macros_per_trip as _macros_per_trip
+from stencil_tpu.ops.stream_plan import macros_per_trip as _macros_per_trip
 from stencil_tpu.utils.config import MethodFlags, PlacementStrategy
 
 COLD_TEMP = 0.0
@@ -217,7 +217,7 @@ class Jacobi3D:
         the valid-width exchange places each halo contiguously after the
         valid cells, so the wavefront's shrinking-validity and
         wrapped-coordinate arguments hold unchanged at the dynamic positions
-        (see ``ops/stream.plan_stream``); the z-slab form's static interior
+        (see ``ops/stream_plan.plan_stream``); the z-slab form's static interior
         emit slices keep it even-shard-only, and the depth is capped by the
         smallest VALID extent (partition.hpp:83-114 parity: remainders run
         at full speed)."""
@@ -355,8 +355,8 @@ class Jacobi3D:
             z_halo_patch_form,
             zring_dist2_plane,
         )
+        from stencil_tpu.ops.stream_pass import lane_pad_width
         from stencil_tpu.ops.stream import (
-            lane_pad_width,
             make_slab_extenders,
             permute_and_extend_z_slabs,
             prime_z_slabs,

@@ -1,0 +1,867 @@
+"""What the stream engine runs on the chip: the three streaming passes.
+
+In the reference, the stencil kernel is USER code: apps write plain CUDA
+through ``Accessor`` (accessor.hpp:13-40, jacobi3d.cu:65-108,
+astaroth_sim.cu:65-83) and the GPU cache hierarchy gives every such kernel
+operand reuse for free.  The TPU analog of that cache reuse is an explicit
+VMEM plane ring.  The passes here run the SAME ``StepKernel`` signature that
+``make_step``'s XLA route runs — ``views[name].sh(dx,dy,dz)`` reads plus
+``info.coords()`` — but stream x-planes through VMEM so each HBM plane is
+read once per pass instead of once per shifted operand (the XLA slice
+formulation re-reads the block ~6x, measured 5-7.5 Gcells/s at 512^3 vs
+~40+ for the streamed form).
+
+* ``stream_plane_pass`` — ONE level per pass over shell-carrying blocks, any
+  per-axis shell widths and any ``r >= 1`` (the kernel's x read distance).
+* ``stream_wavefront_pass`` — ``m`` levels per pass over an ``s``-wide-shell
+  shard (``m <= s // r``, ``r == 1`` only): each HBM plane is read and
+  written once per ``m`` iterations (~``8/m`` B/cell), the temporal blocking
+  that makes the flagship paths beat the bandwidth roofline; plain or in the
+  z-slab form (z halos never touch the tiled array).
+* ``stream_wrap_pass`` — ``k`` levels over the whole single-device domain
+  with the periodic wrap folded into the index maps: no shell, no exchange.
+
+The passes are bit-compatible with the XLA route: both call the user kernel
+with the same per-cell arithmetic, so outputs agree exactly (modulo compiler
+excess precision, which the interpret-mode tests pin).
+
+**Fused unpack→blend** (``fused_shell=``; the plan's ``halo="fused"``,
+``ops/stream_plan.py`` has its gates): under the packed ``yzpack_*``
+exchange routes the macro's unpack step is redundant — the received shell
+messages are blended into the big array only so the pass can read them
+back out one plane later.  ``fused_shell_exchange`` (ops/exchange.py)
+returns the received per-axis shell BUFFERS (corner-patched on the small
+buffers in the exchange's sweep order), and the plane and wavefront passes
+consume them as side inputs — each level-0 plane is patched in VMEM
+(x-shell planes replaced from the x slabs, then y rows from the
+sublane-major y buffer, then z columns from the lane-major z buffer,
+replaying the x→y→z sweep order) before any kernel level runs.  The big
+array is NEVER written with halo data: no blend kernels, no halo DUS, no
+unpack kernels — the generalization of the z-slab wavefront's bespoke
+zero-big-array-halo scheme to every axis.  Because the patched level-0
+planes are bitwise equal to the unfused post-exchange planes, every pass
+output — interior AND shell — is bitwise-identical to ``halo="array"``.
+
+This module imports nothing of ``ops/stream_plan.py`` or ``ops/stream.py``:
+what is decided and what is built sit above it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from stencil_tpu.core.dim3 import Dim3
+from stencil_tpu.telemetry import names as tm
+from stencil_tpu.ops.jacobi_pallas import (
+    _make_roll,
+    _tpu_compiler_params,
+    patch_z_halo,
+)
+
+
+class PlaneView:
+    """Resident-plane window for one quantity inside a streaming kernel.
+
+    ``sh(dx, dy, dz)`` mirrors ``ShardView.sh`` (the reference's
+    ``src[o + Dim3(dx,dy,dz)]`` Accessor read, accessor.hpp:27-40): the
+    x offset selects one of the ``2r+1`` VMEM-resident planes, the y/z
+    offsets are in-plane rotates.  Rotate wraparound at the plane edges only
+    contaminates shell cells the validity contract already sacrifices.
+
+    ``off_centre(dx, dy, dz)`` is called, at trace time, on every read with
+    a non-zero offset (``center()`` and ``sh(0, 0, 0)`` never call it): the
+    footprint trace records the quantity and the offset there, and the plane
+    pass raises there for a quantity whose halo was not filled
+    (``trace_plane_kernel``).
+    A window plane may be ``None``: the pass holds no ring for a quantity
+    its kernel reads at ``dx == 0`` only, and ``no_ring`` is called on a read
+    of such a plane (it raises, naming the quantity).
+    """
+
+    def __init__(self, window: Tuple[jax.Array, ...], roll, off_centre=None,
+                 no_ring=None):
+        self._window = window
+        self._r = (len(window) - 1) // 2
+        self._roll = roll
+        self._off_centre = off_centre
+        self._no_ring = no_ring
+
+    def sh(self, dx: int = 0, dy: int = 0, dz: int = 0) -> jax.Array:
+        # ALL axes are bounded by the declared read radius: an in-plane
+        # shift beyond it would wrap opposite-edge values into cells the
+        # validity contract counts as correct — silently wrong results, so
+        # fail at trace time instead
+        assert all(-self._r <= d <= self._r for d in (dx, dy, dz)), (
+            (dx, dy, dz), self._r,
+        )
+        if self._off_centre is not None and (dx or dy or dz):
+            self._off_centre(dx, dy, dz)
+        v = self._window[self._r + dx]
+        if v is None:
+            self._no_ring()
+        if dy:
+            v = self._roll(v, -dy, 0)
+        if dz:
+            v = self._roll(v, -dz, 1)
+        return v
+
+    def center(self) -> jax.Array:
+        return self._window[self._r]
+
+
+@dataclasses.dataclass
+class PlaneInfo:
+    """Traced per-plane context handed to streaming kernels.  ``coords``
+    returns broadcast-compatible pieces — x a scalar (the whole plane shares
+    one global x), y a column, z a row — so kernels written against
+    ``BlockInfo.coords()`` broadcasting run unchanged."""
+
+    x_global: jax.Array  # int32 scalar: wrapped global x of the output plane
+    y_global: jax.Array  # (Y, 1) int32 wrapped global y
+    z_global: jax.Array  # (1, Z) int32 wrapped global z
+    global_size: Dim3
+    level: int  # wavefront level (1-based); 1 on the plane route
+
+    def coords(self):
+        return self.x_global, self.y_global, self.z_global
+
+
+#: a streaming kernel is just a StepKernel evaluated on planes
+PlaneKernel = Callable[[Dict[str, PlaneView], PlaneInfo], Dict[str, jax.Array]]
+
+
+def lane_pad_width(z: int) -> int:
+    """Plane width rounded up to a 128 multiple — ragged lane extents stream
+    ~30% slower (probe22), so z-slab wavefronts pad with dead columns."""
+    return -(-z // 128) * 128
+
+
+def _yz_coord_planes(origin_ref, Yr, Zr, off_y, off_z, gsize):
+    """Wrapped global y/z coordinates of the raw plane, as a (Yr, 1) column
+    and a (1, Zr) row (2D iotas — Mosaic has no 1D iota)."""
+    y = lax.broadcasted_iota(jnp.int32, (Yr, 1), 0)
+    z = lax.broadcasted_iota(jnp.int32, (1, Zr), 1)
+    gy, gz = jnp.int32(gsize.y), jnp.int32(gsize.z)
+    # + gsize keeps lax.rem's operand non-negative (origin - shell >= -shell)
+    y_g = lax.rem(origin_ref[1] + gy + y - jnp.int32(off_y), gy)
+    z_g = lax.rem(origin_ref[2] + gz + z - jnp.int32(off_z), gz)
+    return y_g, z_g
+
+
+def _zero_lane_pad(plane, valid: int):
+    """``plane`` (Yr, Zp) with lanes [valid, Zp) set to zero -- the dead lanes
+    of a boundary block, which hold whatever the VMEM buffer held.  ``Zp`` is
+    ``lane_pad_width(valid)``, so they all sit in the LAST lane tile: one
+    select there (the tile sliced out at a multiple of 128, as
+    ``patch_z_halo`` takes its own), the other tiles untouched.  The zeros
+    are what the ``jnp.pad`` this replaces stored, so nothing non-finite
+    reaches a level, the emit or the stored shell."""
+    Yr, Zp = plane.shape
+    if Zp == valid:
+        return plane
+    at = Zp - 128
+    assert at <= valid < Zp, (valid, Zp)
+    lane = lax.broadcasted_iota(jnp.int32, (Yr, 128), 1)
+    last = jnp.where(lane < valid - at, plane[:, at:], jnp.zeros((), plane.dtype))
+    return jnp.concatenate([plane[:, :at], last], axis=1) if at else last
+
+
+def _fused_plane_patch(v, xplane, yst, zst, t, lo_y, hi_y, lo_z, hi_z):
+    """Patch one level-0 VMEM plane from the fused shell buffers, replaying
+    the exchange's sweep order x -> y -> z: replace the whole plane when
+    this is an x-shell position (``t`` is the threshold-iota row bound —
+    the plane height at shell positions, 0 otherwise: the broadcast-compare
+    pattern the dynamic blend kernels use), then land the y rows from the
+    sublane-major buffer and the z columns from the lane-major one.
+    Shared by the plane and wavefront passes (``fused_shell`` mode)."""
+    Y, Z = v.shape
+    rowv = lax.broadcasted_iota(jnp.int32, (Y, Z), 0)
+    colv = lax.broadcasted_iota(jnp.int32, (Y, Z), 1)
+    v = jnp.where(rowv < t, xplane, v)
+    for j in range(lo_y):
+        v = jnp.where(rowv == j, yst[j][None, :], v)
+    for j in range(hi_y):
+        v = jnp.where(rowv == Y - hi_y + j, yst[lo_y + j][None, :], v)
+    for j in range(lo_z):
+        v = jnp.where(colv == j, zst[j][:, None], v)
+    for j in range(hi_z):
+        v = jnp.where(colv == Z - hi_z + j, zst[lo_z + j][:, None], v)
+    return v
+
+
+def stream_plane_pass(
+    kernel: PlaneKernel,
+    names: Sequence[str],
+    raws: Sequence[jax.Array],  # per-quantity (X, Y, Z) shell-carrying blocks
+    lo: Dim3,
+    hi: Dim3,  # shell widths (allocation minus interior)
+    x_radius: int,  # kernel x read distance r; ring depth is 2r
+    origin: jax.Array,  # (3,) int32 global coords of the interior start
+    global_size: Dim3,
+    alias: bool = False,  # out q aliases raw q (in place; see below)
+    interpret: bool = False,
+    f32_accumulate: bool = False,  # bf16-storage variant: planes upcast to
+    # f32 for the kernel, one downcast at the interior store (pass-through
+    # shell planes keep their storage bytes bit-exact)
+    fused_shell=None,  # (xbufs, ybufs, zbufs) per quantity — the packed
+    # halo messages land in the level-0 planes in VMEM instead of having
+    # been unpacked into the blocks (halo="fused"; see module docstring)
+    halo_readers: Optional[Sequence[str]] = None,  # the quantities whose
+    # shell was filled (trace_plane_kernel); None = every one
+    writers: Optional[Sequence[str]] = None,  # the quantities the kernel
+    # returns (trace_plane_kernel): the pass's only outputs; None = every one
+    rings: Optional[Sequence[str]] = None,  # the quantities the kernel reads
+    # at dx != 0 (PlaneTrace.pruned): the only ones with a ring; None = all
+    wrap_fills: Sequence[Tuple[int, int, int, int]] = (),  # (axis, destination,
+    # source, width) of the y / z halo fills the pass makes itself, in VMEM
+    # (pass_wrap_fills): the self-wrap of an axis the mesh does not split
+    renames: Sequence[Tuple[str, str]] = (),  # ``(p, q)``: writer ``q``'s new
+    # value lands in ``p``'s buffer and ``p`` comes back as raw ``q``
+    # (trace_plane_kernel): a time level renamed instead of copied
+) -> List[jax.Array]:
+    """ONE kernel level over shell-carrying blocks, streaming x-planes with a
+    ``2r``-deep ring per quantity read off-centre along x; shell planes and
+    the in-plane shell ring pass through unchanged (the exchange owns halo
+    cells).  Generalizes ``mean6_plane_step``/``jacobi_plane_step`` to user
+    kernels, any field count, and any ``r >= 1``.
+
+    A quantity outside ``rings`` is read at ``dx == 0`` only -- a coefficient,
+    an older time level, a quantity differenced along y or z alone -- and
+    needs no window along x: its plane is FETCHED LAGGED, at the output
+    plane ``clip(i - r, 0, X - 1)`` instead of ``min(i, X - 1)``, so the
+    fetched block IS the centre plane, and it has no ring scratch and no
+    push.  (VMEM per such quantity: two pipeline planes instead of ``2r +
+    2`` -- what lets a pass carry nine quantities at 608 x 608, ``plan_plane
+    _passes``.)  In place stays safe: a lagged input's plane ``j`` is fetched
+    before grid step ``j + r`` and the aliased output's plane ``j`` is
+    flushed after it, and no later fetch goes back (``check_inplace_order``
+    proves it from the block maps, as for the ringed form below).  Not under
+    ``fused_shell`` (the patch replays the sweep on the plane fetched at
+    ``i``): every quantity keeps its ring there.
+
+    With ``fused_shell`` the blocks' shell cells are STALE and the fresh
+    halos ride as side inputs (``fused_shell_exchange``'s buffers): every
+    loaded plane is patched in VMEM — x-shell planes replaced from the x
+    slabs, then y rows, then z columns, replaying the exchange's sweep
+    order — before it feeds the ring, the kernel, or the pass-through, so
+    the pass is bitwise-identical to running over exchanged blocks.
+
+    With ``wrap_fills`` the y / z shell of the blocks is STALE on the axes
+    the fills name and there is no message at all: on an axis the mesh does
+    not split the halo of a plane is a copy of cells of that same plane, so
+    every loaded plane of every halo reader -- ringed or fetched lagged,
+    x-shell planes included -- has its halo rows (y) and then its halo
+    columns (z) copied from its own interior, each over the full extent of
+    the other axis, before it feeds the ring, the kernel or the
+    pass-through.  The step's exchange then sweeps the remaining axes only
+    (x always: in place, the pass has overwritten the source planes of the
+    high x shell long before it reaches it), and after that sweep the fills
+    replay the exchange's order x -> y -> z cell for cell: every window is
+    bitwise the one the kernel saw over exchanged blocks, and a writer that
+    is also a reader leaves the same raw array in HBM, halo included (the
+    pass-through writes the patched centre plane).  A reader no pass writes
+    keeps a stale y / z shell in HBM, which the contract allows (the
+    exchange owns halo cells and refills them before every read).  The
+    copies are made in the pipeline's own input buffer, on the few sublane
+    rows and the two lane tiles that hold the four ranges (as
+    ``halo_blend.wrap_halo``'s shuffle does in its scratch): no VMEM of
+    their own, and idempotent, so a plane the pipeline does not refetch is
+    patched again to the same cells.  Not with ``fused_shell``.
+
+    Returns one array per quantity, but only the ``writers`` are OUTPUTS of
+    the Pallas call: every quantity is an input with its ring and its view,
+    and a quantity the kernel never returns is nothing else — its every raw
+    cell, shell included, would be written back as it was read, so the pass
+    returns ``raws[q]`` itself and moves a plane in where it moved one in and
+    one out (acoustic: ``m`` and ``damp``, 8 arrays through HBM a step -> 6).
+    A kernel that returns a name outside ``writers`` in THIS trace raises and
+    names it (its values would otherwise be dropped silently); with no
+    writer at all there is no call to make.  Not under ``fused_shell``:
+    there the written planes are where the fresh shell lands, so every
+    quantity stays an output whatever ``writers`` says (the same exception
+    ``plan_plane_stages`` makes for the readers).
+
+    With ``renames`` an output the kernel would return as another writer's
+    centre plane, unchanged, is not written at all (``trace_plane_kernel``
+    has the rule and where it does not apply; never with ``fused_shell``).
+    For a pair ``(p, q)`` -- ``u_prev <- u`` -- ``p`` is no writer, and the
+    output of ``q`` is what it always was, cell for cell (the kernel's value
+    inside, ``q``'s own shell planes and in-plane shell ring passed through),
+    but it has its HOME in ``p``'s block: under ``alias`` it aliases raw
+    ``p`` (operand ``1 + p``), not raw ``q``.  The returned list holds that
+    array under ``q`` and ``raws[q]`` ITSELF under ``p``: the two handles
+    swap, and the pass moves one array less than writing ``p`` does
+    (acoustic: reads 4, writes 1).  ``q`` comes back bitwise the array the
+    un-renamed pass returns, shell included; ``p`` on its interior (its
+    shell is now ``q``'s, as exchanged, where it was ``p``'s own stale one:
+    the exchange owns both).  In place stays safe for the same reason as
+    before, now for the pair (raw ``p``, output of ``q``): ``p`` is an
+    operand of the pass whether the kernel reads it or not, fetched lagged
+    (or ringed), so its plane ``j`` is read before grid step ``j + r``,
+    after which the output's plane ``j`` is flushed onto it; raw ``q`` is an
+    input only, nothing is flushed over it (``check_inplace_order`` judges
+    whatever pair the call carries).  A value the kernel returns for ``p``
+    in THIS trace is not looked at: the footprint trace proved it is ``q``'s
+    centre plane.  The caller must hand the handles on permuted -- a loop
+    that carries them pays whole-array copies unless a trip returns them to
+    their places (``ops/stream.py _build_plane_step``).
+
+    With ``alias`` a writer's output IS its raw block
+    (``input_output_aliases`` maps operand ``1 + q`` — operand 0 is
+    ``origin`` — to the writer's position among the outputs): a
+    step loop that carries its blocks in place then needs no whole-array
+    copy per quantity per step to put a fresh result where the carry lives.
+    In place is safe because writes trail reads by ``r >= 1`` planes on the
+    sequential grid ``(X + r,)``: step ``i`` fetches in plane ``min(i, X-1)``
+    and holds out plane ``clip(i - r, 0, X-1)``.  The out plane flushed
+    after step ``i`` is ``i - r <= i - 1``; every in plane fetched after
+    step ``i`` is ``>= i + 1`` (or the clamped ``X-1``, which is written
+    last, after the final step).  Out plane 0 is held for steps ``0..r``
+    and flushed once, after plane 0 was read at step 0.  All the kernel
+    needs of planes ``i-2r..i`` sits in the VMEM rings by the time plane
+    ``i - r`` is written.  The ``inplace-order`` contract
+    (``analysis/kernels.py check_inplace_order``) proves this from the
+    traced block maps; CPU interpret mode runs an aliased call
+    functionally and cannot.
+
+    With ``halo_readers`` the shells of the OTHER quantities are stale (the
+    step exchanged only what the kernel's footprint trace saw read
+    off-centre): an off-centre ``sh`` on one of them in THIS trace raises
+    and names it, so a kernel that traces differently the second time can
+    never read a stale cell silently."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nq = len(names)
+    X, Y, Z = raws[0].shape
+    r = x_radius
+    assert r >= 1 and lo.x >= r and hi.x >= r, (r, lo, hi)
+    assert lo.y >= r and hi.y >= r and lo.z >= r and hi.z >= r, (r, lo, hi)
+    y0, y1 = lo.y, Y - hi.y
+    z0, z1 = lo.z, Z - hi.z
+    roll = _make_roll(interpret)
+    gsize = global_size
+    up = (lambda v: v.astype(jnp.float32)) if f32_accumulate else (lambda v: v)
+    if writers is None or fused_shell is not None:
+        wq = list(range(nq))
+    else:
+        wq = [q for q in range(nq) if names[q] in writers]
+    if not wq:
+        return list(raws)
+    # the quantity whose block a writer's output lives in (aliases, under
+    # ``alias``): its own, or the one whose name its old block takes
+    home = {q: q for q in wq}
+    for p_name, q_name in renames:
+        p, q = names.index(p_name), names.index(q_name)
+        assert fused_shell is None and q in home and p not in home, (renames, writers)
+        assert raws[p].dtype == raws[q].dtype, (p_name, q_name)
+        home[q] = p
+    if rings is None or fused_shell is not None:
+        ringed = list(range(nq))
+    else:
+        ringed = [q for q in range(nq) if names[q] in rings]
+    assert not wrap_fills or fused_shell is None
+    assert all(a in (1, 2) for a, _, _, _ in wrap_fills), wrap_fills
+    wrapped = [
+        q for q in range(nq)
+        if wrap_fills and (halo_readers is None or names[q] in halo_readers)
+    ]
+
+    def no_ring(name):
+        def fail():
+            raise ValueError(
+                f"the kernel reads {name!r} off-centre along x, but its "
+                f"footprint trace did not (it saw {tuple(rings)}), so the pass "
+                f"holds no ring for {name!r}: a kernel must read the same "
+                "offsets every time it is traced"
+            )
+
+        return fail
+
+    def stale_read(name):
+        if halo_readers is None or name in halo_readers:
+            return None
+
+        def fail(*offset):
+            raise ValueError(
+                f"the kernel reads {name!r} off-centre, but its footprint "
+                f"trace did not (it saw {tuple(halo_readers)}), so the halo "
+                f"of {name!r} was not exchanged: a kernel must read the same "
+                "offsets every time it is traced"
+            )
+
+        return fail
+
+    def body(origin_ref, *refs):
+        in_refs = refs[:nq]
+        if fused_shell is not None:
+            xs_refs = refs[nq : 2 * nq]
+            ys_refs = refs[2 * nq : 3 * nq]
+            zs_refs = refs[3 * nq : 4 * nq]
+            refs = refs[:nq] + refs[4 * nq :]
+        out_refs = dict(zip(wq, refs[nq : nq + len(wq)]))  # writers only
+        ring_refs = dict(zip(ringed, refs[nq + len(wq) :]))  # x readers only
+        i = pl.program_id(0)
+        for q in wrapped:
+            for axis, dst, src, w in wrap_fills:  # y before z
+                if axis == 1:
+                    in_refs[q][0, dst : dst + w, :] = in_refs[q][0, src : src + w, :]
+                else:
+                    in_refs[q][0, :, dst : dst + w] = in_refs[q][0, :, src : src + w]
+        curs = [ref[0] for ref in in_refs]
+        if fused_shell is not None:
+            # level-0 VMEM patch (module docstring; _fused_plane_patch)
+            ip = jnp.minimum(i, X - 1)  # the replayed last-plane refetches
+            t = jnp.where(
+                jnp.logical_or(ip < lo.x, ip >= X - hi.x),
+                jnp.int32(Y),
+                jnp.int32(0),
+            )
+            for q in range(nq):
+                curs[q] = _fused_plane_patch(
+                    curs[q], xs_refs[q][0], ys_refs[q][0], zs_refs[q][0],
+                    t, lo.y, hi.y, lo.z, hi.z,
+                )
+
+        y_g, z_g = _yz_coord_planes(origin_ref, Y, Z, lo.y, lo.z, gsize)
+
+        # output plane j = i - r; window is raw planes j-r .. j+r
+        j = i - r
+        in_window = jnp.logical_and(j >= lo.x, j <= X - hi.x - 1)
+
+        def plane(q, t):  # raw plane i - t for quantity q (t in [0, 2r])
+            if q not in ring_refs:  # fetched lagged: the centre plane alone
+                return curs[q] if t == r else None
+            return curs[q] if t == 0 else ring_refs[q][(i - t) % (2 * r)]
+
+        def window(q):
+            return tuple(
+                None if (v := plane(q, 2 * r - d)) is None else up(v)
+                for d in range(2 * r + 1)
+            )
+
+        @pl.when(jnp.logical_and(i >= 1, i <= X + r - 1))
+        def _():
+            @pl.when(in_window)
+            def _():
+                views = {
+                    names[q]: PlaneView(
+                        window(q), roll, stale_read(names[q]), no_ring(names[q])
+                    )
+                    for q in range(nq)
+                }
+                x_g = lax.rem(
+                    origin_ref[0] + jnp.int32(gsize.x) + j - jnp.int32(lo.x),
+                    jnp.int32(gsize.x),
+                )
+                info = PlaneInfo(x_g, y_g, z_g, gsize, 1)
+                vals = kernel(views, info)
+                for q, name in enumerate(names):
+                    if name in vals and q not in out_refs and q not in home.values():
+                        raise ValueError(
+                            f"the kernel returns {name!r}, but its footprint "
+                            f"trace did not (it saw {tuple(writers)}), so "
+                            f"{name!r} is not an output of the pass: a kernel "
+                            "must return the same names every time it is traced"
+                        )
+                for q, out in out_refs.items():
+                    cent = plane(q, r)
+                    out[0] = cent  # keep the y/z shell ring
+                    if names[q] in vals:
+                        out[0, y0:y1, z0:z1] = vals[names[q]][
+                            y0:y1, z0:z1
+                        ].astype(cent.dtype)
+
+            @pl.when(jnp.logical_not(in_window))
+            def _():
+                for q, out in out_refs.items():
+                    # shell plane j = i - r passes through from the ring
+                    # (slot is garbage for i < r, where plane j < 0 doesn't
+                    # exist — those writes land on out plane 0, which step
+                    # i == r rewrites with the real pass-through)
+                    out[0] = plane(q, r)
+
+        @pl.when(i == 0)
+        def _():
+            for q, out in out_refs.items():
+                out[0] = curs[q]  # first plane passes through
+
+        # push the fetched plane (skip replayed last-plane refetches)
+        if ring_refs:
+
+            @pl.when(i <= X - 1)
+            def _():
+                for q, ring in ring_refs.items():
+                    ring[i % (2 * r)] = curs[q]
+
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + [
+        pl.BlockSpec((1, Y, Z), lambda i: (jnp.minimum(i, X - 1), 0, 0))
+        if q in ringed
+        else pl.BlockSpec((1, Y, Z), lambda i: (jnp.clip(i - r, 0, X - 1), 0, 0))
+        for q in range(nq)
+    ]
+    args = [origin.astype(jnp.int32), *raws]
+    if fused_shell is not None:
+        xs_list, ys_list, zs_list = fused_shell
+        assert all(b.shape == (lo.x + hi.x, Y, Z) for b in xs_list)
+        assert all(b.shape == (X, lo.y + hi.y, Z) for b in ys_list)
+        assert all(b.shape == (X, lo.z + hi.z, Y) for b in zs_list)
+
+        def xidx(i):
+            # the x slab plane for shell positions; the long interior
+            # stretch clamps to slot 0 (a constant index — no refetch)
+            ip = jnp.minimum(i, X - 1)
+            return (
+                jnp.where(
+                    ip < lo.x,
+                    ip,
+                    jnp.where(ip >= X - hi.x, lo.x + ip - (X - hi.x), 0),
+                ),
+                0,
+                0,
+            )
+
+        in_specs += [pl.BlockSpec((1, Y, Z), xidx) for _ in range(nq)]
+        in_specs += [
+            pl.BlockSpec(
+                (1, lo.y + hi.y, Z), lambda i: (jnp.minimum(i, X - 1), 0, 0)
+            )
+            for _ in range(nq)
+        ]
+        in_specs += [
+            pl.BlockSpec(
+                (1, lo.z + hi.z, Y), lambda i: (jnp.minimum(i, X - 1), 0, 0)
+            )
+            for _ in range(nq)
+        ]
+        args += list(xs_list) + list(ys_list) + list(zs_list)
+    out_specs = tuple(
+        pl.BlockSpec((1, Y, Z), lambda i: (jnp.clip(i - r, 0, X - 1), 0, 0))
+        for _ in wq
+    )
+    out_shape = tuple(
+        jax.ShapeDtypeStruct((X, Y, Z), raws[q].dtype) for q in wq
+    )
+    outs = pl.pallas_call(
+        body,
+        name=tm.KERNEL_STREAM_PLANE_PASS,
+        grid=(X + r,),
+        in_specs=in_specs,
+        out_specs=out_specs if len(wq) > 1 else out_specs[0],
+        out_shape=out_shape if len(wq) > 1 else out_shape[0],
+        # operand 0 is origin; fused-shell side inputs sit after the raws,
+        # so the map is the raw block a writer's output lives in -> its
+        # place among the writers' outputs, whatever rides in
+        input_output_aliases=(
+            {1 + home[q]: k for k, q in enumerate(wq)} if alias else {}
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((2 * r, Y, Z), raws[q].dtype) for q in ringed
+        ],
+        interpret=interpret,
+        **_tpu_compiler_params(interpret),
+    )(*args)
+    result = list(raws)  # a non-writer comes back as the array that went in
+    for q, o in zip(wq, outs if len(wq) > 1 else [outs]):
+        result[home[q]] = raws[q]  # renamed: the handles swap (a no-op at home)
+        result[q] = o
+    return result
+
+
+def stream_wavefront_pass(
+    kernel: PlaneKernel,
+    names: Sequence[str],
+    raws: Sequence[jax.Array],  # per-quantity (Xr, Yr, Zr) FILLED-shell blocks
+    m: int,  # levels to advance (<= shell width)
+    s_off: int,  # shell width (raw index of the interior start)
+    origin: jax.Array,
+    global_size: Dim3,
+    z_slabs: Sequence[jax.Array] = None,  # per-q (Xr, 2s, Yr) z-major slabs
+    alias: bool = False,
+    interpret: bool = False,
+    f32_accumulate: bool = False,  # bf16-storage variant: upcast at load,
+    # f32 level rings + arithmetic, one downcast at the final store/emit
+    fused_shell=None,  # (xbufs, ybufs, zbufs) per quantity — the packed
+    # halo messages land in the level-0 planes in VMEM (halo="fused");
+    # mutually exclusive with z_slabs (the bespoke z-only scheme)
+):
+    """``m`` kernel levels in ONE pass over ``s_off``-shell-carrying shards —
+    the user-kernel generalization of ``jacobi_shell_wavefront_step`` (see
+    its docstring for the shrinking-validity contamination argument, the
+    z-slab layout, and the lane-padding rationale; all carry over verbatim).
+    Returns the advanced blocks, plus per-quantity outgoing z slabs when
+    ``z_slabs`` is given.  In that form the blocks stay the domain's raw
+    ``(Xr, Yr, Zr)`` ones and the LANE PADDING LIVES IN VMEM ONLY: every
+    quantity streams through ``(1, Yr, Zp)`` blocks, ``Zp =
+    lane_pad_width(Zr)`` -- a boundary block in the minor dimension, so the
+    DMA brings ``Zr`` lanes into a ``Zp``-lane plane and writes ``Zr`` back
+    -- and lanes [Zr, Zp) of each level-0 plane, whatever the VMEM block
+    held, are set to zero (``domain.step`` says ``lane_pad: "vmem"``;
+    ``"none"`` where ``Zr`` is whole lane tiles already).  Each level-0 plane
+    gets its z halo from the slab block through
+    ``jacobi_pallas.patch_z_halo``: on the lane-padded plane inside the lane
+    tiles that hold the halo lanes -- tile 0 for [0, s), the one or two tiles
+    over [Zr - s, Zr) -- and nowhere else (``z_halo_patch: "tile"``).
+
+    With ``fused_shell`` the blocks' shell cells are STALE and every axis's
+    fresh halos ride as side inputs (``fused_shell_exchange``): each
+    level-0 plane is patched in VMEM — x-shell planes replaced, then y
+    rows, then z columns (the exchange's sweep order) — so the level chain
+    sees exactly the planes an in-array exchange would have produced and
+    the pass output is bitwise-identical to the unfused form."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nq = len(names)
+    Xr, Yr, Zr = raws[0].shape
+    # the working plane's width: whole lane tiles in the z-slab form
+    Zp = lane_pad_width(Zr) if z_slabs is not None else Zr
+    assert 1 <= m <= s_off and 2 * s_off < min(Xr, Yr, Zr), (m, s_off, Zr)
+    assert z_slabs is None or fused_shell is None
+    gsize = global_size
+    assert 2 * s_off < gsize.x, (s_off, gsize)  # non-negative lax.rem operand
+    roll = _make_roll(interpret)
+    acc_dtypes = [
+        jnp.float32 if f32_accumulate else b.dtype for b in raws
+    ]
+    up = (lambda v: v.astype(jnp.float32)) if f32_accumulate else (lambda v: v)
+
+    def body(origin_ref, *refs):
+        in_refs = refs[:nq]
+        refs = refs[nq:]
+        if fused_shell is not None:
+            xs_refs = refs[:nq]
+            ys_refs = refs[nq : 2 * nq]
+            zsf_refs = refs[2 * nq : 3 * nq]
+            refs = refs[3 * nq :]
+        if z_slabs is not None:
+            zs_refs = refs[:nq]
+            out_refs = refs[nq : 2 * nq]
+            zout_refs = refs[2 * nq : 3 * nq]
+            rings = refs[3 * nq :]
+        else:
+            out_refs = refs[:nq]
+            zout_refs = None
+            rings = refs[nq :]
+        i = pl.program_id(0)
+        # level-0 raw plane i per quantity (upcast once under f32_accumulate)
+        vals = [up(ref[0]) for ref in in_refs]
+        y_g, z_g = _yz_coord_planes(origin_ref, Yr, Zp, s_off, s_off, gsize)
+        if fused_shell is not None:
+            # level-0 VMEM patch (module docstring; _fused_plane_patch —
+            # upcast once under f32_accumulate, like the raw planes)
+            s = s_off
+            t = jnp.where(
+                jnp.logical_or(i < s, i >= Xr - s), jnp.int32(Yr), jnp.int32(0)
+            )
+            for q in range(nq):
+                vals[q] = _fused_plane_patch(
+                    vals[q], up(xs_refs[q][0]), up(ys_refs[q][0]),
+                    up(zsf_refs[q][0]), t, s, s, s, s,
+                )
+        if z_slabs is not None:
+            # patch the z-shell columns in VMEM — never stored in the big
+            # array (see jacobi_shell_wavefront_step) — in their lane tiles
+            for q in range(nq):
+                zst = up(jnp.swapaxes(zs_refs[q][0], 0, 1))  # (Yr, 2s)
+                vals[q] = patch_z_halo(
+                    _zero_lane_pad(vals[q], Zr), zst, s_off, 0, Zr - s_off, roll
+                )
+        for s in range(1, m + 1):
+            prevs = [rings[q][s - 1, i % 2] for q in range(nq)]
+            cents = [rings[q][s - 1, (i + 1) % 2] for q in range(nq)]
+            for q in range(nq):
+                rings[q][s - 1, i % 2] = vals[q]  # push plane i-s+1
+            views = {
+                names[q]: PlaneView((prevs[q], cents[q], vals[q]), roll)
+                for q in range(nq)
+            }
+            x_g = lax.rem(
+                origin_ref[0] + jnp.int32(gsize.x) + i - jnp.int32(s + s_off),
+                jnp.int32(gsize.x),
+            )
+            info = PlaneInfo(x_g, y_g, z_g, gsize, s)
+            new = kernel(views, info)
+            vals = [
+                new[names[q]].astype(cents[q].dtype)
+                if names[q] in new
+                else cents[q]
+                for q in range(nq)
+            ]
+        for q in range(nq):
+            # level-m plane i-m (the one f32_accumulate downcast)
+            out_refs[q][0] = vals[q].astype(raws[q].dtype)
+            if zout_refs is not None:
+                emit = jnp.concatenate(
+                    [
+                        vals[q][:, Zr - 2 * s_off : Zr - s_off],
+                        vals[q][:, s_off : 2 * s_off],
+                    ],
+                    axis=1,
+                ).astype(raws[q].dtype)  # (Yr, 2s)
+                zout_refs[q][0] = jnp.swapaxes(emit, 0, 1)
+
+    out_idx = lambda i: (jnp.maximum(i - m, 0), 0, 0)
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + [
+        pl.BlockSpec((1, Yr, Zp), lambda i: (i, 0, 0)) for _ in range(nq)
+    ]
+    out_specs: list = [pl.BlockSpec((1, Yr, Zp), out_idx) for _ in range(nq)]
+    out_shape: list = [
+        jax.ShapeDtypeStruct((Xr, Yr, Zr), b.dtype) for b in raws
+    ]
+    args = [origin.astype(jnp.int32), *raws]
+    if fused_shell is not None:
+        xs_list, ys_list, zs_list = fused_shell
+        s = s_off
+        assert all(b.shape == (2 * s, Yr, Zr) for b in xs_list)
+        assert all(b.shape == (Xr, 2 * s, Zr) for b in ys_list)
+        assert all(b.shape == (Xr, 2 * s, Yr) for b in zs_list)
+
+        def xidx(i):
+            # x slab slot for shell planes; interior clamps to a constant
+            # slot 0 (no refetch over the long middle stretch)
+            return (
+                jnp.where(
+                    i < s, i, jnp.where(i >= Xr - s, s + i - (Xr - s), 0)
+                ),
+                0,
+                0,
+            )
+
+        in_specs += [pl.BlockSpec((1, Yr, Zr), xidx) for _ in range(nq)]
+        in_specs += [
+            pl.BlockSpec((1, 2 * s, Zr), lambda i: (i, 0, 0))
+            for _ in range(nq)
+        ]
+        in_specs += [
+            pl.BlockSpec((1, 2 * s, Yr), lambda i: (i, 0, 0))
+            for _ in range(nq)
+        ]
+        args += list(xs_list) + list(ys_list) + list(zs_list)
+    if z_slabs is not None:
+        for q in range(nq):
+            assert z_slabs[q].shape == (Xr, 2 * s_off, Yr), z_slabs[q].shape
+        in_specs += [
+            pl.BlockSpec((1, 2 * s_off, Yr), lambda i: (i, 0, 0))
+            for _ in range(nq)
+        ]
+        out_specs += [pl.BlockSpec((1, 2 * s_off, Yr), out_idx) for _ in range(nq)]
+        out_shape += [
+            jax.ShapeDtypeStruct((Xr, 2 * s_off, Yr), b.dtype) for b in raws
+        ]
+        args += list(z_slabs)
+    # in-place safe: out plane max(i - m, 0) trails in plane i by m >= 1
+    # (the inplace-order contract, analysis/kernels.py, proves it from the
+    # block maps).  Band-matrix inputs sit between the raws and the slabs,
+    # so the alias map stays raw-q -> out-q regardless.
+    aliases = {1 + q: q for q in range(nq)} if alias else {}
+    outs = pl.pallas_call(
+        body,
+        name=tm.KERNEL_STREAM_WAVEFRONT_PASS,
+        grid=(Xr,),
+        in_specs=in_specs,
+        out_specs=tuple(out_specs),
+        out_shape=tuple(out_shape),
+        input_output_aliases=aliases,
+        scratch_shapes=[
+            pltpu.VMEM((m, 2, Yr, Zp), acc) for acc in acc_dtypes
+        ],
+        interpret=interpret,
+        **_tpu_compiler_params(interpret),
+    )(*args)
+    outs = list(outs)
+    if z_slabs is not None:
+        return outs[:nq], outs[nq:]
+    return outs, None
+
+
+def stream_wrap_pass(
+    kernel: PlaneKernel,
+    names: Sequence[str],
+    blocks: Sequence[jax.Array],  # per-quantity BARE (X, Y, Z) interiors
+    k: int,  # temporal depth (1 <= k <= X//2)
+    origin: jax.Array,  # (3,) int32 — global coords of the block start
+    global_size: Dim3,
+    interpret: bool = False,
+    f32_accumulate: bool = False,  # bf16-storage variant (see
+    # stream_wavefront_pass)
+) -> List[jax.Array]:
+    """``k`` kernel levels over the WHOLE (single-device) domain with the
+    periodic wrap folded in — the user-kernel generalization of
+    ``jacobi_wrap_step`` (see its docstring: the x-wrap rides the modular
+    block index map with a ``2k``-step replay closing every level's ring;
+    the y/z wrap is the natural roll wraparound on exact-sized planes).
+    No shell, no exchange, ~8/k HBM bytes per cell per iteration."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nq = len(names)
+    X, Y, Z = blocks[0].shape
+    assert 1 <= k <= X // 2, (k, X)
+    roll = _make_roll(interpret)
+    gsize = global_size
+    acc_dtypes = [
+        jnp.float32 if f32_accumulate else b.dtype for b in blocks
+    ]
+    up = (lambda v: v.astype(jnp.float32)) if f32_accumulate else (lambda v: v)
+
+    def body(origin_ref, *refs):
+        in_refs = refs[:nq]
+        refs = refs[nq:]
+        out_refs = refs[:nq]
+        rings = refs[nq:]
+        i = pl.program_id(0)
+        vals = [up(ref[0]) for ref in in_refs]  # level-0 plane i (mod X)
+        y_g, z_g = _yz_coord_planes(origin_ref, Y, Z, 0, 0, gsize)
+        for s in range(1, k + 1):
+            prevs = [rings[q][s - 1, i % 2] for q in range(nq)]
+            cents = [rings[q][s - 1, (i + 1) % 2] for q in range(nq)]
+            for q in range(nq):
+                rings[q][s - 1, i % 2] = vals[q]
+            views = {
+                names[q]: PlaneView((prevs[q], cents[q], vals[q]), roll)
+                for q in range(nq)
+            }
+            x_g = lax.rem(
+                origin_ref[0] + jnp.int32(gsize.x) + i - jnp.int32(s),
+                jnp.int32(gsize.x),
+            )
+            info = PlaneInfo(x_g, y_g, z_g, gsize, s)
+            new = kernel(views, info)
+            vals = [
+                new[names[q]].astype(cents[q].dtype)
+                if names[q] in new
+                else cents[q]
+                for q in range(nq)
+            ]
+        for q in range(nq):
+            # level-k plane (i - k) % X (the one f32_accumulate downcast)
+            out_refs[q][0] = vals[q].astype(blocks[q].dtype)
+
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + [
+        pl.BlockSpec((1, Y, Z), lambda i: (i % X, 0, 0)) for _ in range(nq)
+    ]
+    args = [origin.astype(jnp.int32), *blocks]
+    outs = pl.pallas_call(
+        body,
+        name=tm.KERNEL_STREAM_WRAP_PASS,
+        grid=(X + 2 * k,),
+        in_specs=in_specs,
+        out_specs=tuple(
+            pl.BlockSpec((1, Y, Z), lambda i: ((i - k) % X, 0, 0))
+            for _ in range(nq)
+        ),
+        out_shape=tuple(
+            jax.ShapeDtypeStruct((X, Y, Z), b.dtype) for b in blocks
+        ),
+        scratch_shapes=[pltpu.VMEM((k, 2, Y, Z), acc) for acc in acc_dtypes],
+        interpret=interpret,
+        **_tpu_compiler_params(interpret),
+    )(*args)
+    # out_shape is always a tuple, so pallas returns a tuple even for nq=1
+    return list(outs)

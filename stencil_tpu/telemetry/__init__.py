@@ -13,8 +13,7 @@ same visibility is one process-local facade:
   ``jax.profiler.TraceAnnotation`` (so a profiler trace holds them beside the
   device ops), and with telemetry on wall-clock spans dumped as Chrome
   trace-event JSON (``chrome://tracing`` / Perfetto); also home of the
-  ``annotate``/``trace`` jax wrappers that used to live in
-  ``utils/profiling.py``.
+  ``annotate``/``trace`` jax wrappers.
 * **events** (``events.py``) — rank-tagged JSONL event log for the signals
   a program must consume (retries, ladder descents, divergence trips).
 

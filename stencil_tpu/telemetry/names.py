@@ -405,21 +405,21 @@ ALL_HISTOGRAMS = frozenset({
 #: the enqueue of one ``run_step`` dispatch [label, steps = raw iterations;
 #: a stream-engine step adds the plan it ran: route, x_radius, grouping,
 #: streamed = quantities in the pass, aliased = quantities the passes carry in
-#: place (all or none: ``ops/stream._plan_passes_in_place``; a written one's
+#: place (all or none: ``ops/stream_plan._plan_passes_in_place``; a written one's
 #: output aliases its input, an unwritten one is its input), exchanged
 #: = quantities riding the step's halo exchange: on the plane route those the
-#: kernel reads off-centre (``ops/stream.trace_plane_kernel``; all of them under
+#: kernel reads off-centre (``ops/stream_plan.trace_plane_kernel``; all of them under
 #: ``halo="fused"``), every one on the wavefront route, 0 on the wrap route,
 #: written = quantities that are outputs of the passes: on the plane route
 #: those the kernel returns (the same trace, ``plan["writers"]``; all of them
 #: under ``halo="fused"``), every one on the other routes, renamed = the
 #: quantities whose write became a rename: an output that IS another writer's
 #: centre plane (a leapfrog's ``u_prev <- u``) swaps handles with it and is
-#: not ``written`` (``ops/stream.trace_plane_kernel``, ``plan["renamed"]``:
+#: not ``written`` (``ops/stream_plan.trace_plane_kernel``, ``plan["renamed"]``:
 #: acoustic 1; 0 wherever the passes do not run in place on the plane route's
 #: default schedule), wrapped = the axes
 #: whose halo the plane passes fill themselves in VMEM, so that the step's
-#: exchange does not sweep them (``ops/stream.pass_wrap_fills``: the y / z
+#: exchange does not sweep them (``ops/stream_plan.pass_wrap_fills``: the y / z
 #: axes the mesh does not split, "yz" on one chip, "z" on mesh [2,2,1], ""
 #: off the plane route's default schedule and wherever that axis's sweep is
 #: not the self-wrap), and on the plane route's swept exchange wired = the
@@ -456,7 +456,7 @@ ALL_HISTOGRAMS = frozenset({
 #: offcentre = those read at a non-zero offset, diagonal = those of them read
 #: at an offset with two or more non-zero components (an edge or corner halo),
 #: read_sides = the distinct (quantity, axis, side) triples read
-#: (``ops/stream.footprint_counts`` over one abstract trace of each kernel;
+#: (``ops/stream_plan.footprint_counts`` over one abstract trace of each kernel;
 #: None each where it raised) and exchanged_sides = six for every quantity
 #: whose halo the route fills (D3Q19 lattice Boltzmann: 19, 18, 12, 30 and 0 on
 #: the wrap route, 108 on the plane route)]

@@ -1,9 +1,8 @@
 """Nestable wall-clock span tracer + Chrome trace-event dump.
 
-Subsumes ``utils/profiling.py``: ``annotate`` (the NVTX-range analog —
-``jax.named_scope`` labels the region in compiled HLO and XProf timelines)
-and ``trace`` (a ``jax.profiler`` capture) live here now, alongside the
-host-side span recorder.
+``annotate`` (the NVTX-range analog — ``jax.named_scope`` labels the region
+in compiled HLO and XProf timelines) and ``trace`` (a ``jax.profiler``
+capture) live here, alongside the host-side span recorder.
 
 Every span is also a ``jax.profiler.TraceAnnotation`` (``_profiler_annotation``):
 under a profiler session it sits in the profiler's own trace beside the device

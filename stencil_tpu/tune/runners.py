@@ -277,7 +277,8 @@ def autotune_stream(
     same process via the cache."""
     import jax
 
-    from stencil_tpu.ops.stream import _build_stream_step, plan_stream
+    from stencil_tpu.ops.stream import _build_stream_step
+    from stencil_tpu.ops.stream_plan import plan_stream, resolve_stream_plan
 
     key = dd.tune_key("stream")
     with tune.disabled():
@@ -302,7 +303,9 @@ def autotune_stream(
             # and for the fused-halo A/B under STENCIL_STREAM_HALO
             plan["halo_forced"] = True
         step = _build_stream_step(
-            dd, kernel, x_radius, plan, interpret, donate=False
+            dd, kernel, x_radius,
+            resolve_stream_plan(dd, kernel, x_radius, plan, interpret),
+            interpret, donate=False,
         )
 
         def run(n):

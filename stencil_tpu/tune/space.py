@@ -12,7 +12,7 @@ Axes (ISSUE: the constants PERF_NOTES.md says to re-qualify per chip):
 * **stream route** (wrap/plane/wavefront) and grouping — the generic
   engine's plan axes.
 * **overlap** (off/split) — the stream engine's split-step schedule
-  (ops/stream.py ``STREAM_OVERLAP``): dispatch the interior pass with no
+  (ops/stream_plan.py ``STREAM_OVERLAP``): dispatch the interior pass with no
   ppermute dependency and recompute the boundary bands afterward, so the
   collectives hide behind the VPU work at the cost of ~``6·3w``-wide band
   recomputes; ``off`` is the static fallback, and the win flips with the
@@ -26,7 +26,7 @@ Axes (ISSUE: the constants PERF_NOTES.md says to re-qualify per chip):
   halo storage (PERF_NOTES "Thin z-region access" / "Thin y-region
   access").
 * **halo consumption** (array/fused) — the stream engine's fused
-  unpack→blend mode (ops/stream.py ``STREAM_HALO``): under ``fused`` the
+  unpack→blend mode (ops/stream_plan.py ``STREAM_HALO``): under ``fused`` the
   packed ``yzpack_*`` messages land directly in the pass's level-0 VMEM
   working planes and the big array never sees a halo write; ``array`` is
   the static fallback — the win trades the saved unpack/blend dispatches
@@ -244,13 +244,13 @@ def stream_space(dd, x_radius: int, separable: bool,
     plane route as the m=1 structural baseline, and the split-step overlap
     A/B (``overlap ∈ {off, split}``, ops/stream.py — the interior pass
     dispatched with no ppermute dependency).
-    Every candidate is a plan dict ``_build_stream_step`` accepts verbatim
+    Every candidate is a request ``resolve_stream_plan`` accepts verbatim
     (+ ``alias``/``overlap``).
 
     Every candidate carries explicit ``overlap`` and ``halo`` fields
     ("off"/"array" unless it IS that axis's twin) so persisted winners
     record the axes — while older entries WITHOUT the fields stay
-    consultable (absent = the static off/array, ops/stream.py
+    consultable (absent = the static off/array, ops/stream_plan.py
     ``_overlap_request`` / ``_halo_request``); no cache schema bump.  The
     split twin of a z-slab wavefront re-plans to the plain form
     (``plain_wavefront_plan``): split needs z halos in the big array for
@@ -259,7 +259,7 @@ def stream_space(dd, x_radius: int, separable: bool,
     docs/tuning.md "Fused halo consumption") re-plans the same way and is
     structurally prefiltered unless the domain's resolved exchange route
     packs the y shell (``fused_halo_ineligible``)."""
-    from stencil_tpu.ops.stream import (
+    from stencil_tpu.ops.stream_plan import (
         fused_halo_ineligible,
         plain_wavefront_plan,
         plan_stream,

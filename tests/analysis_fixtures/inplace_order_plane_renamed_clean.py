@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from stencil_tpu import analysis
 from stencil_tpu.core.dim3 import Dim3
-from stencil_tpu.ops.stream import stream_plane_pass
+from stencil_tpu.ops.stream_pass import stream_plane_pass
 
 R = 4
 

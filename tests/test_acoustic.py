@@ -21,6 +21,8 @@ from stencil_tpu import telemetry
 from stencil_tpu.models import acoustic_reference as ref
 from stencil_tpu.models.acoustic import QUANTITIES, AcousticWave
 from stencil_tpu.telemetry import names as tm
+from stencil_tpu.ops import stream_plan as sp
+from stencil_tpu.ops import stream_pass as spass
 
 N, NBL, DISPATCH = 24, 4, 3
 ATOL = 2e-6
@@ -287,19 +289,25 @@ def test_the_step_program_says_the_pass_is_in_place():
     (call,) = _plane_passes(sim._step._resilience.built(), sim.dd._curr)
     assert len(call.outvars) == 1 and len(call.invars) == 1 + 4, call
     assert _plane_pass_aliases(sim._step._resilience.built(), sim.dd._curr) == [((2, 0),)]
-    plan = dict(sim._step._stream_plan, alias=False, alias_forced=True)
+    plan = sp.resolve_stream_plan(
+        sim.dd, sim._kernel, ref.RADIUS,
+        dict(sim._step._stream_plan, alias=False, alias_forced=True), True,
+    )
     off = sm._build_stream_step(sim.dd, sim._kernel, ref.RADIUS, plan, interpret=True)
     assert _plane_pass_aliases(off, sim.dd._curr) == [()]
     assert plan["alias"] is False and plan["renamed"] == () and plan["writers"] == ("u", "u_prev")
     # the split schedule keeps fresh outputs whatever the plan resolves: the
     # interior pass and the exchange both read the pre-exchange blocks
-    plan = dict(sim._step._stream_plan, overlap="split", overlap_forced=True)
+    plan = sp.resolve_stream_plan(
+        sim.dd, sim._kernel, ref.RADIUS,
+        dict(sim._step._stream_plan, overlap="split", overlap_forced=True), True,
+    )
     split = sm._build_stream_step(sim.dd, sim._kernel, ref.RADIUS, plan, interpret=True)
     passes = _plane_pass_aliases(split, sim.dd._curr)
     assert len(passes) == 7 and set(passes) == {()}, passes  # interior + six bands
     assert {len(e.outvars) for e in _plane_passes(split, sim.dd._curr)} == {2}
     assert plan["overlap"] == "split" and plan["alias"] is True
-    assert sm._plan_passes_in_place(plan) is False and plan["renamed"] == ()
+    assert sp._plan_passes_in_place(plan) is False and plan["renamed"] == ()
 
 
 def test_plane_pass_sits_under_its_scope():
@@ -387,7 +395,7 @@ def test_driver_runs_on_the_cpu(extent, capsys):
 #
 # The kernel returns ``u_prev`` as ``u``'s centre plane, so the pass writes
 # ``u`` alone, into ``u_prev``'s block, and the step swaps the two handles
-# (ops/stream.py trace_plane_kernel).  Held to the same model with the rule
+# (ops/stream_plan.py trace_plane_kernel).  Held to the same model with the rule
 # off (the parent's program) bit for bit, and to the plain reference within
 # ``ATOL`` (the two compilers round differently, see the header: bitwise
 # against the reference is not to be had on the CPU).
@@ -508,8 +516,8 @@ def _leapfrog_program(steps, period=None, monkeypatch=None):
         return {"u": 1.5 * u - 0.5 * views["v"].center(), "v": u}
 
     def stand_in(pass_kernel, names, raws, *a, writers=None, renames=(), **kw):
-        views = {nm: sm.PlaneView((b,), None) for nm, b in zip(names, raws)}
-        vals = pass_kernel(views, sm.PlaneInfo(None, None, None, None, 1))  # coordinates unread
+        views = {nm: spass.PlaneView((b,), None) for nm, b in zip(names, raws)}
+        vals = pass_kernel(views, spass.PlaneInfo(None, None, None, None, 1))  # coordinates unread
         out = list(raws)
         for p, q in renames:
             out[names.index(p)] = raws[names.index(q)]
@@ -519,9 +527,9 @@ def _leapfrog_program(steps, period=None, monkeypatch=None):
 
     monkeypatch.setattr(sm, "stream_plane_pass", stand_in)
     if period is not None:
-        monkeypatch.setattr(sm, "_carry_period", lambda names, stages: period)
+        monkeypatch.setattr(sp, "_carry_period", lambda names, stages: period)
     dd, hs = _plane_domain(["u", "v"], 1, 1, extent=(32, 32, 32))
-    plan = dict(sm.plan_stream(dd, 1, "plane", False))
+    plan = sp.resolve_stream_plan(dd, kernel, 1, sp.plan_stream(dd, 1, "plane", False), True)
     step = sm._build_stream_step(dd, kernel, 1, plan, interpret=True)
     assert plan["renamed"] == ("v",) and plan["halo_readers"] == (), plan
     want = {h.name: dd.quantity_to_host(h) for h in hs}
@@ -595,7 +603,10 @@ def test_the_other_schedules_rename_nothing_and_keep_their_programs(variant, mon
             sim.dd.set_partition(1, 2, 2)
             sim.dd.set_exchange_route("yzpack_xla")
         sim.realize()
-        plan = dict(sm.plan_stream(sim.dd, ref.RADIUS, "plane", False), **plan_kw)
+        plan = sp.resolve_stream_plan(
+            sim.dd, sim._kernel, ref.RADIUS,
+            dict(sp.plan_stream(sim.dd, ref.RADIUS, "plane", False), **plan_kw), True,
+        )
         step = sm._build_stream_step(sim.dd, sim._kernel, ref.RADIUS, plan, interpret=True)
         return plan, _fingerprint_of(step, sim.dd._curr)
 

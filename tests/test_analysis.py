@@ -28,6 +28,7 @@ from stencil_tpu.analysis import programs as aprog
 from stencil_tpu.analysis import registry as aregistry
 from stencil_tpu.analysis import vmem as avmem
 from stencil_tpu.analysis.cli import main as analysis_main
+from stencil_tpu.ops import stream_plan as sp
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 FIXTURE_DIR = os.path.join(HERE, "analysis_fixtures")
@@ -346,20 +347,12 @@ def _fused_straddling_budget(dd, static_plan):
     space but rejects the fused-halo twin (whose side-buffer blocks the
     stream planner's depth gate never modeled) — computed from the same
     model, so the pin cannot rot with recalibration."""
-    from stencil_tpu.ops.stream import plain_wavefront_plan
+    from stencil_tpu.ops.stream_plan import plain_wavefront_plan, stream_plan_vmem_bytes
 
-    raw = dd.local_spec().raw_size()
-    sizes = [dd.field_dtype(h).itemsize for h in dd._handles]
-    e_static = avmem.stream_plan_vmem_bytes(
-        static_plan["m"], raw.y, raw.z, sizes,
-        z_slabs=bool(static_plan.get("z_slabs")),
-    )
+    e_static, margin = stream_plan_vmem_bytes(dd, static_plan)
     plain = plain_wavefront_plan(dd, static_plan) or static_plan
-    e_fused = avmem.stream_plan_vmem_bytes(
-        plain["m"], raw.y, raw.z, sizes, fused=True
-    )
+    e_fused, _ = stream_plan_vmem_bytes(dd, dict(plain, halo="fused"))
     assert e_fused > e_static
-    _, margin = avmem.budget_and_margin(len(sizes))
     return (e_static + e_fused) // 2 + margin
 
 
@@ -375,7 +368,7 @@ def test_pruned_candidate_never_compiles(monkeypatch, tune_dir):
 
     dd = _mk_dd(exchange_route="yzpack_xla")  # the fused twin is eligible
     with tune.disabled():
-        static_plan = sm.plan_stream(dd, 1, "auto", False)
+        static_plan = sp.plan_stream(dd, 1, "auto", False)
     budget = _fused_straddling_budget(dd, static_plan)
     built_plans = []
     real_build = sm._build_stream_step
@@ -480,7 +473,7 @@ def test_stream_space_prunes_illegal_kernel_statically(monkeypatch, tune_dir):
     defense."""
     from stencil_tpu import tune
     from stencil_tpu.analysis import kernels as akern
-    from stencil_tpu.ops.stream import plan_stream
+    from stencil_tpu.ops.stream_plan import plan_stream
     from stencil_tpu.tune import space
 
     dd = _mk_dd()
@@ -516,7 +509,7 @@ def test_illegal_candidate_never_compiles(monkeypatch, tune_dir):
 
     dd = _mk_dd()
     with tune.disabled():
-        static_plan = sm.plan_stream(dd, 1, "auto", False)
+        static_plan = sp.plan_stream(dd, 1, "auto", False)
     built_plans = []
     real_build = sm._build_stream_step
 

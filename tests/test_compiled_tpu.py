@@ -149,7 +149,7 @@ def test_compiled_zslab_step_carries_the_raw_blocks(storage, monkeypatch):
     import test_lane_pad_vmem as lp
     from stencil_tpu.core.radius import Radius
     from stencil_tpu.domain import DistributedDomain
-    from stencil_tpu.ops import stream as sm
+    from stencil_tpu.ops import stream as sm, stream_pass as spass
 
     names = ("a", "b", "c", "d")  # four: the wavefront's static rule runs in place
 
@@ -178,7 +178,7 @@ def test_compiled_zslab_step_carries_the_raw_blocks(storage, monkeypatch):
         return raws, [dd.quantity_to_host(h) for h in hs]
 
     ours, fields = two_dispatches("stream")
-    monkeypatch.setattr(sm, "stream_wavefront_pass", lp.hbm_padded_pass(sm.stream_wavefront_pass))
+    monkeypatch.setattr(sm, "stream_wavefront_pass", lp.hbm_padded_pass(spass.stream_wavefront_pass))
     padded, _ = two_dispatches("stream")
     for a, b in zip(ours, padded):
         assert a.shape == (70, 70, 70)
@@ -340,7 +340,7 @@ def test_compiled_plane_pass_wraps_the_planes_it_loads(monkeypatch):
     by accident.)"""
     from stencil_tpu.core.radius import Radius
     from stencil_tpu.domain import DistributedDomain
-    from stencil_tpu.ops import stream as sm
+    from stencil_tpu.ops import stream_plan as sp
 
     r = 4
 
@@ -374,7 +374,7 @@ def test_compiled_plane_pass_wraps_the_planes_it_loads(monkeypatch):
     plan, got = run()
     assert plan["route"] == "plane" and plan["pass_wrap_axes"] == "yz", plan
     assert plan["stages"][0]["passes"][0]["rings"] == ("u",), plan
-    monkeypatch.setattr(sm, "pass_wrap_fills", lambda dd, route: ("", ()))
+    monkeypatch.setattr(sp, "pass_wrap_fills", lambda dd, route: ("", ()))
     plan_off, want = run()
     assert plan_off["pass_wrap_axes"] == "", plan_off
     for name, a, b in zip(("u", "b"), got, want):
@@ -396,7 +396,7 @@ def test_compiled_acoustic_step_renames_u_prev(steps, monkeypatch):
     import gc
 
     from stencil_tpu.models.acoustic import AcousticWave
-    from stencil_tpu.ops import stream as sm
+    from stencil_tpu.ops import stream_plan as sp
 
     def run():
         sim = AcousticWave(600, 600, 600, devices=jax.devices()[:1],
@@ -416,9 +416,9 @@ def test_compiled_acoustic_step_renames_u_prev(steps, monkeypatch):
 
     plan, got = run()
     assert plan["renamed"] == ("u_prev",) and plan["writers"] == ("u",), plan
-    real = sm.trace_plane_kernel
+    real = sp.trace_plane_kernel
     monkeypatch.setattr(
-        sm, "trace_plane_kernel", lambda *a: dataclasses.replace(real(*a), renames=())
+        sp, "trace_plane_kernel", lambda *a: dataclasses.replace(real(*a), renames=())
     )
     plan_off, want = run()
     assert plan_off["renamed"] == () and plan_off["writers"] == ("u", "u_prev"), plan_off

@@ -24,6 +24,7 @@ from stencil_tpu.core.dim3 import Dim3
 from stencil_tpu.models import elastic_reference as ref
 from stencil_tpu.models.elastic import ElasticWave
 from stencil_tpu.ops import stream as sm
+from stencil_tpu.ops import stream_plan as sp
 from stencil_tpu.telemetry import names as tm
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -167,7 +168,7 @@ def _real_size_traces():
     sim = ElasticWave(N, N, N, nbl=NBL, interpret=True, devices=jax.devices()[:1])
     plane = jax.ShapeDtypeStruct((608, 608), jnp.float32)
     return [
-        sm.trace_plane_kernel(stage, ref.QUANTITIES, [plane] * 13, 4, Dim3(600, 600, 600), False)
+        sp.trace_plane_kernel(stage, ref.QUANTITIES, [plane] * 13, 4, Dim3(600, 600, 600), False)
         for stage in (sim._stage_v, sim._stage_t)
     ]
 
@@ -179,17 +180,17 @@ def test_the_plan_at_the_benchmark_size_fits_the_vmem_model_pass_by_pass():
     from stencil_tpu.ops.jacobi_pallas import _padded_plane_bytes, _vmem_budget
 
     plane_bytes = {q: _padded_plane_bytes(608, 608, 4) for q in ref.QUANTITIES}
-    passes = [p for t in _real_size_traces() for p in sm.plan_plane_passes(t, plane_bytes)]
+    passes = [p for t in _real_size_traces() for p in sp.plan_plane_passes(t, plane_bytes)]
     assert [p["writes"] for p in passes] == [
         ("vx", "vy"), ("vz",), ("txx", "tyy", "tzz", "txy"), ("txz", "tyz"),
     ]
     assert [p["rings"] for p in passes] == [("txx", "txy"), ("txz",), ("vx", "vy"), ("vz",)]
     for p in passes:
         assert p["vmem_bytes"] <= _vmem_budget(), p
-        assert p["vmem_bytes"] == sm.plane_pass_vmem_bytes(
+        assert p["vmem_bytes"] == sp.plane_pass_vmem_bytes(
             plane_bytes, 4, p["reads"], p["rings"], p["writes"])
     # every quantity whole in one pass would not fit
-    whole = sm.plane_pass_vmem_bytes(plane_bytes, 4, ref.QUANTITIES, ref.QUANTITIES, ref.WAVEFIELDS)
+    whole = sp.plane_pass_vmem_bytes(plane_bytes, 4, ref.QUANTITIES, ref.QUANTITIES, ref.WAVEFIELDS)
     assert whole > 2 * _vmem_budget()
     with open(os.path.join(ROOT, "benchmark", "configs", "elastic-so8-600.json")) as f:
         stated = json.load(f)["passes"]
@@ -217,13 +218,13 @@ def test_a_step_that_fits_in_no_pass_raises_at_plan_time(monkeypatch):
 
     names = [f"q{i}" for i in range(14)]
     plane_bytes = {q: 1_556_480 for q in names}  # a 608 x 640 f32 plane
-    trace = sm.trace_plane_kernel(
+    trace = sp.trace_plane_kernel(
         _fourteen_kernel, names, [jax.ShapeDtypeStruct((608, 608), jnp.float32)] * 14, 1,
         Dim3(600, 600, 600), True,
     )
     with pytest.raises(ValueError, match=r"writes \('q0',\) reads 14 quantities .*14 of them "
                        r"off-centre along x.* bytes of VMEM.*fits no pass"):
-        sm.plan_plane_passes(trace, plane_bytes)
+        sp.plan_plane_passes(trace, plane_bytes)
     # ... and through make_step, on a domain whose budget is that tight
     monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", "200000")
     dd, _ = _mk(16, 16, 16, Radius.constant(1), names, jax.devices()[:1])
@@ -354,8 +355,8 @@ def test_acoustic_drops_the_rings_nothing_reads_and_is_bitwise_unchanged(monkeyp
     assert (p["rings"], p["writes"], p["reads"]) == (("u",), ("u",), QUANTITIES)
     assert p["renames"] == (("u_prev", "u"),)
     monkeypatch.setattr(
-        sm, "trace_plane_kernel",
-        lambda kernel, names, *a: sm.PlaneTrace(
+        sp, "trace_plane_kernel",
+        lambda kernel, names, *a: sp.PlaneTrace(
             tuple(names), ("u",), ("u", "u_prev"), 4, None, kernel
         ),
     )

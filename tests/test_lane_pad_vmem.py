@@ -22,6 +22,7 @@ from stencil_tpu.analysis import jaxpr as jx
 from stencil_tpu.core.radius import Radius
 from stencil_tpu.domain import DistributedDomain
 from stencil_tpu.ops import stream as sm
+from stencil_tpu.ops import stream_pass as spass
 from stencil_tpu.telemetry import names as tm
 
 NAMES = ("a", "b")
@@ -80,7 +81,7 @@ def hbm_padded_pass(real):
         if z_slabs is None:
             return real(kernel, names, raws, m, s, origin, gsize, alias=alias, **kw)
         Xr, Yr, Zr = raws[0].shape
-        pad = sm.lane_pad_width(Zr) - Zr
+        pad = spass.lane_pad_width(Zr) - Zr
         padded = []
         for b, zs in zip(raws, z_slabs):
             zst = jnp.swapaxes(zs, 1, 2)  # (Xr, Yr, 2s)
@@ -134,7 +135,7 @@ def test_the_raw_block_step_is_bitwise_the_hbm_padded_one(geometry, storage, mac
         return states
 
     ours = two_dispatches()  # traced before the reference is patched in
-    monkeypatch.setattr(sm, "stream_wavefront_pass", hbm_padded_pass(sm.stream_wavefront_pass))
+    monkeypatch.setattr(sm, "stream_wavefront_pass", hbm_padded_pass(spass.stream_wavefront_pass))
     ref = two_dispatches()
     for got, want in zip(ours, ref):
         for a, b in zip(got, want):
@@ -183,7 +184,7 @@ def test_the_traced_step_pads_and_cuts_no_block(mesh, monkeypatch):
                   for bm in gm.block_mappings]
         assert blocks.count(((raw.x, raw.y, raw.z), (1, raw.y, 128))) == 2 * len(NAMES)
         assert {pair[1] for pair in e.params["input_output_aliases"]} == set(range(len(NAMES)))
-    monkeypatch.setattr(sm, "stream_wavefront_pass", hbm_padded_pass(sm.stream_wavefront_pass))
+    monkeypatch.setattr(sm, "stream_wavefront_pass", hbm_padded_pass(spass.stream_wavefront_pass))
     padded = jax.make_jaxpr(_step(dd, 3), static_argnums=1)(dd._curr, 7)
     assert any(op == "pad" for op in _whole_block_ops(padded, raw))
 

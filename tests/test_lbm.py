@@ -17,7 +17,7 @@ import pytest
 from stencil_tpu import telemetry
 from stencil_tpu.models import lbm_reference as ref
 from stencil_tpu.models.lbm import RADIUS, LatticeBoltzmann, population_bounds
-from stencil_tpu.ops import stream as sm
+from stencil_tpu.ops import stream_plan as sp
 from stencil_tpu.telemetry import names as tm
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -237,7 +237,7 @@ def test_the_plan_at_the_benchmarks_size_is_the_configurations():
     config = _config()
     sim = LatticeBoltzmann(*config["global_extent"], devices=jax.devices()[:1], seed_words=None)
     sim.dd.realize(allocate=False)
-    plan = sm.plan_stream(sim.dd, RADIUS, "auto", False)
+    plan = sp.plan_stream(sim.dd, RADIUS, "auto", False)
     expect = config["expect"]
     assert (plan["route"], plan["m"], plan["grouping"]) == (expect["route"], expect["depth"], "joint")
     assert config["dispatch"]["bulk"] % (2 * plan["m"]) == 0  # whole trips of the macro loop
@@ -252,7 +252,7 @@ def test_the_plan_at_the_benchmarks_size_is_the_configurations():
     assert analysis.check_vmem(sim.dd, plan) is None
     deeper = analysis.check_vmem(sim.dd, {**plan, "m": plan["m"] + 1})
     assert deeper is not None and f"wrap[m={plan['m'] + 1}]" in deeper
-    assert sm.plan_stream(sim.dd, RADIUS, "auto", False, max_m=plan["m"] + 1)["m"] == plan["m"]
+    assert sp.plan_stream(sim.dd, RADIUS, "auto", False, max_m=plan["m"] + 1)["m"] == plan["m"]
 
 
 @pytest.mark.parametrize("path, mesh, exchanged", [

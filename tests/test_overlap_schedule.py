@@ -24,6 +24,8 @@ from jax.sharding import Mesh
 from stencil_tpu.core.radius import Radius
 from stencil_tpu.domain import DistributedDomain
 from stencil_tpu.parallel.mesh import MESH_AXES
+from stencil_tpu.ops import stream_plan as sp
+from stencil_tpu.ops import stream_pass as spass
 
 # Mosaic lowering of the split-step macro (interior pass + six band passes
 # in one fori_loop body) recurses deeper than CPython's default 1000 frames
@@ -155,10 +157,10 @@ def test_stream_split_step_schedule_straddles_interior():
         def kernel(views, info):
             return _jacobi_kernel(views, info)
 
-        plan = {
+        plan = sp.resolve_stream_plan(dd, kernel, 1, {
             "route": "wavefront", "m": 2, "z_slabs": False,
             "grouping": "joint", "overlap": "split", "overlap_forced": True,
-        }
+        }, False)
         step = sm._build_stream_step(dd, kernel, 1, plan, interpret=False,
                                      donate=False)
         text = step.lower(dd.abstract_arrays(), 1).compile().as_text()
@@ -242,9 +244,10 @@ def test_acoustic_step_carries_its_blocks_in_place(monkeypatch):
         for alias, steps in ((None, 8), (None, 9), (False, 8)):
             sim = AcousticWave(600, 600, 600, devices=devices[:1], seed_words=None)
             sim.dd.realize(allocate=False)
-            plan = sm.plan_stream(sim.dd, RADIUS, "auto", False)
+            plan = sp.plan_stream(sim.dd, RADIUS, "auto", False)
             if alias is not None:
                 plan = dict(plan, alias=alias, alias_forced=True)
+            plan = sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, plan, False)
             step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
             compiled = step.lower(sim.dd.abstract_arrays(), steps).compile()
             texts[alias, steps] = (
@@ -302,7 +305,8 @@ def test_acoustic_step_over_four_chips_carries_its_blocks_in_place(monkeypatch):
         sim = AcousticWave(1200, 1200, 600, devices=devices, seed_words=None)
         sim.dd.realize(allocate=False)
         assert tuple(sim.dd.mesh_dim()) == (2, 2, 1)  # the partitioner's own pick
-        plan = sm.plan_stream(sim.dd, RADIUS, "auto", False)
+        plan = sp.plan_stream(sim.dd, RADIUS, "auto", False)
+        plan = sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, plan, False)
         step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
         compiled = step.lower(sim.dd.abstract_arrays(), 8).compile()
         text, temp = compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
@@ -453,7 +457,6 @@ def test_the_tile_form_z_halo_patch_lowers_for_the_chip(kernel, zv):
 
     from stencil_tpu.core.dim3 import Dim3
     from stencil_tpu.ops import jacobi_pallas as jp
-    from stencil_tpu.ops import stream as sm
 
     one = SingleDeviceSharding(_topology_devices()[0])
 
@@ -465,13 +468,13 @@ def test_the_tile_form_z_halo_patch_lowers_for_the_chip(kernel, zv):
     try:
         if kernel == "stream":
             s, xr, yr = 3, 16, 518
-            assert sm.z_halo_patch_form(sm.lane_pad_width(zv), s) == "tile"
+            assert jp.z_halo_patch_form(spass.lane_pad_width(zv), s) == "tile"
 
             # the raw block as the domain stores it, in place as astaroth's
             # eight run: the pass makes the 640-lane plane in VMEM from a
             # boundary block (ISSUE 41), and Mosaic takes that with the alias
             def run(origin, raw, zs):
-                return sm.stream_wavefront_pass(
+                return spass.stream_wavefront_pass(
                     _jacobi_kernel, ["q"], [raw], 1, s, origin, Dim3(1024, 1024, 512),
                     z_slabs=[zs], alias=True,
                 )
@@ -519,10 +522,10 @@ def test_elastic_step_carries_its_blocks_in_place(monkeypatch):
     try:
         sim = ElasticWave(600, 600, 600, devices=devices[:1], seed_words=None)
         sim.dd.realize(allocate=False)
-        plan = sm.plan_stream(sim.dd, RADIUS, "plane", False)
-        step = sm._build_stream_step(
-            sim.dd, (sim._stage_v, sim._stage_t), RADIUS, plan, interpret=False
-        )
+        stages = (sim._stage_v, sim._stage_t)
+        plan = sp.plan_stream(sim.dd, RADIUS, "plane", False)
+        plan = sp.resolve_stream_plan(sim.dd, stages, RADIUS, plan, False)
+        step = sm._build_stream_step(sim.dd, stages, RADIUS, plan, interpret=False)
         compiled = step.lower(sim.dd.abstract_arrays(), 4).compile()
         text, temp = compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
     finally:
@@ -563,11 +566,12 @@ def test_lbm_macro_loop_carries_its_blocks_in_place(monkeypatch):
         for per_trip, macros in ((None, 4), (None, 3), (1, 4)):
             with monkeypatch.context() as mp:
                 if per_trip is not None:
-                    mp.setattr(sm, "macros_per_trip", lambda in_place: per_trip)
+                    mp.setattr(sp, "macros_per_trip", lambda in_place: per_trip)
                 sim = LatticeBoltzmann(256, 256, 256, devices=devices[:1], seed_words=None)
                 sim.dd.realize(allocate=False)
-                plan = sm.plan_stream(sim.dd, RADIUS, "auto", False)
+                plan = sp.plan_stream(sim.dd, RADIUS, "auto", False)
                 assert (plan["route"], plan["m"], plan["grouping"]) == ("wrap", 2, "joint"), plan
+                plan = sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, plan, False)
                 step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
                 compiled = step.lower(sim.dd.abstract_arrays(), macros * plan["m"]).compile()
             got[per_trip, macros] = (

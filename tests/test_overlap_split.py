@@ -22,6 +22,7 @@ from stencil_tpu import telemetry, tune
 from stencil_tpu.core.radius import Radius
 from stencil_tpu.domain import DistributedDomain
 from stencil_tpu.ops import stream as sm
+from stencil_tpu.ops import stream_plan as sp
 from stencil_tpu.telemetry import names as tm
 from stencil_tpu.tune import space as tune_space
 from stencil_tpu.tune.runners import autotune_stream
@@ -228,7 +229,7 @@ def test_split_structural_guard_on_zslab_plan():
     plain form first — plain_wavefront_plan)."""
     plan = {"route": "wavefront", "m": 2, "z_slabs": True, "grouping": "joint",
             "overlap": "split", "overlap_forced": True}
-    val, source = sm._resolve_stream_overlap(plan)
+    val, source = sp._resolve_stream_overlap(plan)
     assert val == "off" and source == "explicit/degraded"
 
 
@@ -238,7 +239,7 @@ def test_split_replans_zslab_to_plain_form():
     for the exchange it overlaps)."""
     dd, _ = _mk(mult=2)
     with tune.disabled():
-        static = sm.plan_stream(dd, 1, "auto", False)
+        static = sp.plan_stream(dd, 1, "auto", False)
     assert static["route"] == "wavefront" and static["z_slabs"]
     step = dd.make_step(mean6_kernel, engine="stream", interpret=True,
                         stream_overlap="split")
@@ -297,7 +298,7 @@ def test_ladder_steps_split_down_to_off(monkeypatch):
 def test_stream_space_grows_split_candidates(tune_dir):
     dd, _ = _mk(mult=2)
     with tune.disabled():
-        static = sm.plan_stream(dd, 1, "auto", False)
+        static = sp.plan_stream(dd, 1, "auto", False)
     cands, _ = tune_space.stream_space(dd, 1, False, static)
     assert all("overlap" in c for c in cands)
     split_cands = [c for c in cands if c["overlap"] == "split"]

@@ -7,6 +7,8 @@ import pytest
 from ulp import assert_reassociation_close
 
 from stencil_tpu.models.astaroth import AstarothSim
+from stencil_tpu.ops import stream_plan as sp
+from stencil_tpu.ops import stream_pass as spass
 
 
 @pytest.mark.parametrize("size", [(28, 28, 28), (15, 14, 13)])
@@ -145,7 +147,7 @@ def test_mean6_kernel_axes_variants():
 # --- exchange only what the kernel reads (ISSUE 30) ---------------------------
 #
 # On the plane route the step exchanges the quantities the kernel reads
-# off-centre and no others (ops/stream.py trace_plane_kernel).  Every case
+# off-centre and no others (ops/stream_plan.py trace_plane_kernel).  Every case
 # runs the same kernel three ways -- the XLA slice engine (which exchanges
 # everything), the plane route as built, and the plane route with the rule
 # switched off (the parent's program: every quantity exchanged) -- and holds
@@ -211,18 +213,18 @@ def _plane_step(dd, kernel, r, plan_kw):
     plan it resolved."""
     from stencil_tpu.ops import stream as sm
 
-    plan = dict(sm.plan_stream(dd, r, "plane", False), **plan_kw)
+    request = dict(sp.plan_stream(dd, r, "plane", False), **plan_kw)
+    plan = sp.resolve_stream_plan(dd, kernel, r, request, True)
     return sm._build_stream_step(dd, kernel, r, plan, interpret=True), plan
 
 
 def _exchange_everything(monkeypatch):
     """The rules off: the trace every build makes fails closed, so every
     quantity is exchanged, ringed and written (PR 29's program)."""
-    from stencil_tpu.ops import stream as sm
 
     monkeypatch.setattr(
-        sm, "trace_plane_kernel",
-        lambda kernel, names, planes, r, *a: sm.PlaneTrace(
+        sp, "trace_plane_kernel",
+        lambda kernel, names, planes, r, *a: sp.PlaneTrace(
             tuple(names), tuple(names), tuple(names), r, None, kernel
         ),
     )
@@ -349,7 +351,7 @@ def test_a_footprint_trace_that_raises_exchanges_everything(monkeypatch):
     rides the exchange as before -- and the step still runs and is right."""
     from stencil_tpu.ops import stream as sm
 
-    real = sm.stream_plane_pass
+    real = spass.stream_plane_pass
     in_pass = []
 
     def spy(*a, **kw):
@@ -385,7 +387,7 @@ def _one_pass(kernel, **kw):
     import jax.numpy as jnp
 
     from stencil_tpu.core.dim3 import Dim3
-    from stencil_tpu.ops.stream import stream_plane_pass
+    from stencil_tpu.ops.stream_pass import stream_plane_pass
 
     r, n = 1, 8
     blk = jax.ShapeDtypeStruct((n + 2 * r,) * 3, jnp.float32)
@@ -456,7 +458,7 @@ def test_a_step_traces_its_kernel_once_and_runs_what_that_trace_saw():
 # --- write only what the kernel writes (ISSUE 32) -----------------------------
 #
 # The same trace learns which quantities the kernel RETURNS; the others are
-# inputs of the plane pass and nothing else (ops/stream.py trace_plane_kernel,
+# inputs of the plane pass and nothing else (ops/stream_plan.py trace_plane_kernel,
 # stream_plane_pass(writers=)).  A pass with the rule off wrote every such
 # quantity back cell for cell, so the two must agree on every RAW cell of
 # every quantity, shell included.
@@ -500,11 +502,10 @@ def _footprint(kernel, names, r, groups=None):
     import jax.numpy as jnp
 
     from stencil_tpu.core.dim3 import Dim3
-    from stencil_tpu.ops import stream as sm
 
     plane = jax.ShapeDtypeStruct((16 + 2 * r, 16 + 2 * r), jnp.float32)
     traces = [  # one trace per group, as plan_plane_stages makes them
-        sm.trace_plane_kernel(kernel, [names[q] for q in g], [plane] * len(g), r, Dim3(16, 16, 16))
+        sp.trace_plane_kernel(kernel, [names[q] for q in g], [plane] * len(g), r, Dim3(16, 16, 16))
         for g in groups or [list(range(len(names)))]
     ]
     return tuple(
@@ -560,7 +561,7 @@ def test_the_pass_has_one_output_per_writer_and_is_bitwise_the_full_pass(alias):
     import jax.numpy as jnp
 
     from stencil_tpu.core.dim3 import Dim3
-    from stencil_tpu.ops.stream import stream_plane_pass
+    from stencil_tpu.ops.stream_pass import stream_plane_pass
 
     r, n = 2, 12
     names = list("dabc")  # the writers sit at positions 1 and 2
@@ -596,11 +597,9 @@ def _write_everything(monkeypatch):
     stay as the trace found them)."""
     import dataclasses
 
-    from stencil_tpu.ops import stream as sm
-
-    real = sm.trace_plane_kernel
+    real = sp.trace_plane_kernel
     monkeypatch.setattr(
-        sm, "trace_plane_kernel",
+        sp, "trace_plane_kernel",
         lambda kernel, names, *a: dataclasses.replace(
             real(kernel, names, *a), writers=tuple(names), closed=None
         ),
@@ -631,8 +630,6 @@ def test_plane_route_writes_only_what_the_kernel_returns(
     as the step left them), the interior equal to the XLA engine's."""
     import jax
 
-    from stencil_tpu.ops import stream as sm
-
     r = 2
 
     def run_plane():
@@ -648,7 +645,7 @@ def test_plane_route_writes_only_what_the_kernel_returns(
     raws, fields, plan, calls = run_plane()
     assert plan["writers"] == ("a", "b"), plan
     assert [len(e.outvars) for e in calls] == outputs
-    in_place = sm._plan_passes_in_place(plan)
+    in_place = sp._plan_passes_in_place(plan)
     for e in calls:
         assert len(_alias_pairs(e)) == (len(e.outvars) if in_place else 0)
     _write_everything(monkeypatch)
@@ -696,7 +693,7 @@ def test_fused_shell_keeps_every_quantity_an_output():
     import jax.numpy as jnp
 
     from stencil_tpu.core.dim3 import Dim3
-    from stencil_tpu.ops.stream import stream_plane_pass
+    from stencil_tpu.ops.stream_pass import stream_plane_pass
 
     r, n = 1, 8
     raw = n + 2 * r
@@ -817,7 +814,7 @@ def test_the_pass_wraps_the_planes_it_loads(
     import jax.numpy as jnp
 
     from stencil_tpu.core.dim3 import Dim3
-    from stencil_tpu.ops.stream import stream_plane_pass
+    from stencil_tpu.ops.stream_pass import stream_plane_pass
 
     n = 10
     shape = tuple(n + a + b for a, b in zip(lo, hi))
@@ -873,7 +870,7 @@ def test_the_pass_wraps_the_planes_it_loads(
 # An output that IS another writer's centre plane -- a leapfrog scheme's
 # ``u_prev <- u`` -- is not written: the pass lands ``u``'s new value in
 # ``u_prev``'s block and the step hands ``u``'s old array on under the name
-# ``u_prev`` (ops/stream.py trace_plane_kernel, stream_plane_pass(renames=)).
+# ``u_prev`` (ops/stream_plan.py trace_plane_kernel, stream_plane_pass(renames=)).
 # The rule reads the kernel's jaxpr and fails closed on anything but the
 # centre invar itself.
 
@@ -954,11 +951,10 @@ def test_the_footprint_trace_reports_a_rename_and_nothing_like_one(case, storage
     import jax.numpy as jnp
 
     from stencil_tpu.core.dim3 import Dim3
-    from stencil_tpu.ops import stream as sm
 
     kernel, names, r = case()
     plane = jax.ShapeDtypeStruct((16 + 2 * r, 16 + 2 * r), jnp.float32)
-    trace = sm.trace_plane_kernel(
+    trace = sp.trace_plane_kernel(
         kernel, list(names), [plane] * len(names), r, Dim3(16, 16, 16), True, storage
     )
     assert trace.closed is not None and trace.renames == renames, trace
@@ -969,10 +965,9 @@ def test_a_trace_that_raises_renames_nothing():
     import jax.numpy as jnp
 
     from stencil_tpu.core.dim3 import Dim3
-    from stencil_tpu.ops import stream as sm
 
     plane = jax.ShapeDtypeStruct((18, 18), jnp.float32)
-    trace = sm.trace_plane_kernel(_raising_kernel, ["u", "v"], [plane] * 2, 1, Dim3(16, 16, 16))
+    trace = sp.trace_plane_kernel(_raising_kernel, ["u", "v"], [plane] * 2, 1, Dim3(16, 16, 16))
     assert trace.closed is None and trace.renames == ()
 
 
@@ -980,11 +975,9 @@ def _renames_off(monkeypatch):
     """The rule off: the parent's program, every returned quantity written."""
     import dataclasses
 
-    from stencil_tpu.ops import stream as sm
-
-    real = sm.trace_plane_kernel
+    real = sp.trace_plane_kernel
     monkeypatch.setattr(
-        sm, "trace_plane_kernel", lambda *a: dataclasses.replace(real(*a), renames=())
+        sp, "trace_plane_kernel", lambda *a: dataclasses.replace(real(*a), renames=())
     )
 
 
@@ -1002,7 +995,7 @@ def test_a_renaming_pass_swaps_two_handles_and_writes_one_array(alias):
     from test_stream import coupled_kernel
 
     from stencil_tpu.core.dim3 import Dim3
-    from stencil_tpu.ops.stream import stream_plane_pass
+    from stencil_tpu.ops.stream_pass import stream_plane_pass
 
     r, n = 2, 12
     names = ["u", "v", "c", "d"]
@@ -1132,7 +1125,7 @@ def test_a_stage_cut_into_passes_renames_nothing(monkeypatch):
 
 
 def test_the_carry_period_is_the_order_of_the_steps_permutation():
-    from stencil_tpu.ops.stream import _carry_period
+    from stencil_tpu.ops.stream_plan import _carry_period
 
     def stages(*passes):
         return tuple({"passes": tuple({"renames": r} for r in st)} for st in passes)

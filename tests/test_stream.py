@@ -22,6 +22,8 @@ from stencil_tpu.core.radius import Radius
 from stencil_tpu.domain import DistributedDomain
 from stencil_tpu.models.astaroth import AstarothSim
 from stencil_tpu.models.jacobi import Jacobi3D
+from stencil_tpu.ops import stream_plan as sp
+from stencil_tpu.ops import stream_pass as spass
 
 TOL = dict(rtol=1e-6, atol=1e-6)
 
@@ -420,7 +422,6 @@ def test_stream_padded_plane_route():
 def test_stream_separable_per_field_grouping(monkeypatch):
     """When many fields jointly blow the VMEM model, a separable kernel
     streams per-field at FULL wavefront depth instead of a shallower m."""
-    import stencil_tpu.ops.stream as sm
 
     devs = jax.devices()[:8]
     r3 = Radius.constant(3)
@@ -759,16 +760,16 @@ def test_plane_pass_in_place_is_bitwise_the_fresh_pass(r, nq, n_dev, extent, pla
             dd.init_by_coords(
                 h, lambda x, y, z, i=i: jnp.sin(0.13 * (x + 2 * y + 3 * z) + i)
             )
-        plan = dict(
-            sm.plan_stream(dd, r, "plane", False),
+        plan = sp.resolve_stream_plan(dd, kernel, r, dict(
+            sp.plan_stream(dd, r, "plane", False),
             alias=alias, alias_forced=True, **plan_kw,
-        )
+        ), True)
         step = sm._build_stream_step(dd, kernel, r, plan, interpret=True)
         dd.run_step(step, 3)
         plans.append(plan)
         fields.append([dd.quantity_to_host(h) for h in hs])
     assert [p["route"] for p in plans] == ["plane", "plane"]
-    assert [p["alias"] for p in plans] == [True, False]  # written back as resolved
+    assert [p["alias"] for p in plans] == [True, False]  # as resolved
     for key in ("overlap", "halo"):
         if key in plan_kw:  # the variant engaged, it did not degrade
             assert plans[0][key] == plans[1][key] == plan_kw[key]
@@ -796,7 +797,7 @@ def test_wavefront_span_counts_its_in_place_passes(nq, want):
 #
 # On the plane route's default schedule the y and z sweeps of an axis the mesh
 # does not split leave the exchange and ride in the pass (``plan["pass_wrap_
-# axes"]``, ``ops/stream.py pass_wrap_fills``).  The step as built against the
+# axes"]``, ``ops/stream_plan.py pass_wrap_fills``).  The step as built against the
 # same step with the rule off (the parent's program: every axis swept by the
 # exchange, an unsplit one by ``halo_blend.wrap_halo``), blend kernels on as on
 # the chip: every raw cell of every writer bitwise equal, halo included.
@@ -878,7 +879,7 @@ def test_plane_step_wraps_its_unsplit_axes_in_the_pass(
 
     def run():
         dd, hs = _pass_wrap_domain(names, r, partition, shell, extent)
-        plan = dict(sm.plan_stream(dd, r, "plane", False))
+        plan = sp.resolve_stream_plan(dd, kernel, r, sp.plan_stream(dd, r, "plane", False), True)
         step = sm._build_stream_step(dd, kernel, r, plan, interpret=True)
         closed = jax.make_jaxpr(step, static_argnums=1)(dd._curr, 1)
         swept = {
@@ -895,7 +896,7 @@ def test_plane_step_wraps_its_unsplit_axes_in_the_pass(
     raws, fields, plan, swept = run()
     assert plan["route"] == "plane" and plan["pass_wrap_axes"] == axes, plan
     assert swept == set("xyz") - set(axes)  # only the remaining axes are swept
-    monkeypatch.setattr(sm, "pass_wrap_fills", lambda dd, route: ("", ()))
+    monkeypatch.setattr(sp, "pass_wrap_fills", lambda dd, route: ("", ()))
     raws_off, fields_off, plan_off, swept_off = run()
     assert plan_off["pass_wrap_axes"] == "" and swept_off == set("xyz")
     assert plan_off["halo_readers"] == plan["halo_readers"] and plan["halo_readers"]
@@ -943,7 +944,6 @@ def test_the_pass_wraps_nothing_where_the_rule_does_not_hold(
     -- under ``halo="fused"``, under ``overlap="split"``, with the blend
     kernels off (a CPU run) and off the plane route; the default plane
     schedule on the same domain says what the mesh leaves unsplit."""
-    from stencil_tpu.ops import stream as sm
 
     monkeypatch.setenv("STENCIL_HALO_BLEND", blend)
 
@@ -956,9 +956,8 @@ def test_the_pass_wraps_nothing_where_the_rule_does_not_hold(
             dd.set_exchange_route(route)
         dd.add_data("u")
         dd.realize()
-        plan = dict(sm.plan_stream(dd, 1, path, False), **kw)
-        sm._build_stream_step(dd, star_kernel(1), 1, plan, interpret=True)
-        return plan
+        request = dict(sp.plan_stream(dd, 1, path, False), **kw)
+        return sp.resolve_stream_plan(dd, star_kernel(1), 1, request, True)
 
     plan = built(path, plan_kw)
     for key, want in plan_kw.items():  # the variant engaged, it did not degrade
@@ -971,7 +970,6 @@ def test_an_nd_quantity_keeps_every_sweep_in_the_exchange(monkeypatch):
     """The rule reads the whole domain, as ``_sweep_kind`` reads the blocks of
     an exchange: one N-D quantity and no axis rides in a pass (the stream
     engine refuses such a domain anyway: ``plan_stream``)."""
-    from stencil_tpu.ops import stream as sm
 
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
     for components, want in (((), "yz"), ((3,), "")):
@@ -981,7 +979,7 @@ def test_an_nd_quantity_keeps_every_sweep_in_the_exchange(monkeypatch):
         dd.add_data("u")
         dd.add_data("w", components=components)
         dd.realize()
-        axes, fills = sm.pass_wrap_fills(dd, "direct")
+        axes, fills = sp.pass_wrap_fills(dd, "direct")
         assert axes == want, (components, axes)
         assert fills == (
             ((1, 0, 16, 1), (1, 17, 1, 1), (2, 0, 16, 1), (2, 17, 1, 1)) if want else ()
@@ -998,8 +996,8 @@ def test_wavefront_pass_z_slab_patch_returns_the_parents_bytes(zr, monkeypatch):
     tile in VMEM (ISSUE 41), on one whose hi halo straddles a multiple of 128
     (lanes 127..129 of a 256-lane plane), and on one that is whole lane tiles
     as it stands."""
-    import stencil_tpu.ops.stream as sm
     from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops.jacobi_pallas import z_halo_patch_form
     from test_jacobi_pallas import parent_patch_z_halo
 
     m = s = 3
@@ -1009,10 +1007,10 @@ def test_wavefront_pass_z_slab_patch_returns_the_parents_bytes(zr, monkeypatch):
     raws = [jnp.asarray(rng.random((xr, yr, zr), dtype=np.float32)) for _ in names]
     slabs = [jnp.asarray(rng.random((xr, 2 * s, yr), dtype=np.float32)) for _ in names]
     origin = jnp.array([18, 0, 0], jnp.int32)
-    assert sm.z_halo_patch_form(sm.lane_pad_width(zr), s) == "tile"
+    assert z_halo_patch_form(spass.lane_pad_width(zr), s) == "tile"
 
     def run():
-        outs, zouts = sm.stream_wavefront_pass(
+        outs, zouts = spass.stream_wavefront_pass(
             mean6_kernel, names, raws, m, s, origin, Dim3(36, 36, zr - 2 * s),
             z_slabs=slabs, interpret=True,
         )
@@ -1020,7 +1018,7 @@ def test_wavefront_pass_z_slab_patch_returns_the_parents_bytes(zr, monkeypatch):
         return [np.asarray(o[: xr - m]) for o in list(outs) + list(zouts)]
 
     ours = run()
-    monkeypatch.setattr(sm, "patch_z_halo", parent_patch_z_halo)
+    monkeypatch.setattr(spass, "patch_z_halo", parent_patch_z_halo)
     parents = run()
     assert len(ours) == 4
     for a, b in zip(ours, parents):

@@ -1,4 +1,4 @@
-"""Tier-1: the stream engine's fused unpack→blend mode (ops/stream.py
+"""Tier-1: the stream engine's fused unpack→blend mode (ops/stream_plan.py
 ``STREAM_HALO``; docs/tuning.md "Fused halo consumption").
 
 The tentpole claims, in-process on the fake 8-chip CPU mesh (interpret-mode
@@ -29,6 +29,7 @@ from stencil_tpu.analysis.programs import tpu_shaped_trace
 from stencil_tpu.core.radius import Radius
 from stencil_tpu.domain import DistributedDomain
 from stencil_tpu.ops import stream as sm
+from stencil_tpu.ops import stream_plan as sp
 from stencil_tpu.telemetry import names as tm
 from stencil_tpu.tune import space as tune_space
 from stencil_tpu.tune.runners import autotune_stream
@@ -241,7 +242,7 @@ def test_fused_replans_zslab_to_plain_form():
     the split path's rule, shared."""
     dd, _ = _mk(mult=2)
     with tune.disabled():
-        static = sm.plan_stream(dd, 1, "auto", False)
+        static = sp.plan_stream(dd, 1, "auto", False)
     assert static["route"] == "wavefront" and static["z_slabs"]
     step = dd.make_step(mean6_kernel, engine="stream", interpret=True,
                         stream_halo="fused")
@@ -300,7 +301,7 @@ def test_ladder_steps_fused_down_to_array(monkeypatch):
 def test_stream_space_grows_fused_twin_only_with_ypack_route(tune_dir):
     dd, _ = _mk(mult=2)
     with tune.disabled():
-        static = sm.plan_stream(dd, 1, "auto", False)
+        static = sp.plan_stream(dd, 1, "auto", False)
     cands, _ = tune_space.stream_space(dd, 1, False, static)
     assert all("halo" in c for c in cands)
     fused_cands = [c for c in cands if c["halo"] == "fused"]
@@ -308,7 +309,7 @@ def test_stream_space_grows_fused_twin_only_with_ypack_route(tune_dir):
     # a z-only exchange route cannot feed the fused consumer: prefiltered
     dd2, _ = _mk(mult=2, route="zpack_xla")
     with tune.disabled():
-        static2 = sm.plan_stream(dd2, 1, "auto", False)
+        static2 = sp.plan_stream(dd2, 1, "auto", False)
     cands2, pre2 = tune_space.stream_space(dd2, 1, False, static2)
     assert not [c for c in cands2 if c["halo"] == "fused"]
     assert pre2 >= 1

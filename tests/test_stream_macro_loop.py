@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from stencil_tpu import DistributedDomain, Radius
-from stencil_tpu.ops import stream as sm
+from stencil_tpu.ops import stream_plan as sp
 
 M = 2  # temporal depth of every build here: a macro is two raw steps
 N = 8
@@ -50,7 +50,7 @@ def _seeded(q):
 
 def _build(kernel, nq, monkeypatch, per_trip=None):
     if per_trip is not None:
-        monkeypatch.setattr(sm, "macros_per_trip", lambda in_place: per_trip)
+        monkeypatch.setattr(sp, "macros_per_trip", lambda in_place: per_trip)
     dd = DistributedDomain(N, N, N)
     dd.set_radius(Radius.constant(1))
     dd.set_devices(jax.devices()[:1])

@@ -241,7 +241,7 @@ def test_search_vmem_oom_prunes_deeper_wavefront_style_candidates():
 
 
 def test_stream_alias_resolution_precedence(monkeypatch):
-    from stencil_tpu.ops.stream import _resolve_stream_alias
+    from stencil_tpu.ops.stream_plan import _resolve_stream_alias
 
     monkeypatch.delenv("STENCIL_STREAM_ALIAS", raising=False)
     # static rule: >= 4 fields alias
@@ -426,7 +426,7 @@ def test_jacobi_wavefront_plan_consults_cache(tune_dir):
 def test_plan_stream_consults_and_validates(tune_dir):
     from stencil_tpu.domain import DistributedDomain
     from stencil_tpu.core.radius import Radius
-    from stencil_tpu.ops.stream import plan_stream
+    from stencil_tpu.ops.stream_plan import plan_stream
 
     dd = DistributedDomain(16, 16, 16)
     dd.set_radius(Radius.constant(1))
@@ -455,7 +455,7 @@ def _consult_stream(fields):
     the tuned plan, the static plan)."""
     from stencil_tpu.core.radius import Radius
     from stencil_tpu.domain import DistributedDomain
-    from stencil_tpu.ops.stream import plan_stream
+    from stencil_tpu.ops.stream_plan import plan_stream
 
     dd = DistributedDomain(16, 16, 16)
     dd.set_radius(Radius.constant(1))

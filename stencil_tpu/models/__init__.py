@@ -11,6 +11,11 @@
   ``examples/seismic/elastic``; Minimod), velocity-stress on a staggered
   grid: thirteen quantities, two stages a time step, beside its plain
   reference ``elastic_reference``.
+* ``lbm`` -- D3Q19 BGK lattice Boltzmann on a periodic box (FluidX3D's
+  benchmark), beside its plain reference ``lbm_reference``.
+* ``astaroth_mhd`` -- Astaroth's real MHD step (``astaroth`` is its proxy):
+  eight fields at sixth order, three Runge-Kutta substeps a step, beside its
+  plain reference ``astaroth_mhd_reference``.
 """
 
 from stencil_tpu.models.jacobi import Jacobi3D

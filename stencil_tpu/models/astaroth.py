@@ -1,5 +1,10 @@
 """Astaroth MHD proxy: radius-3, sin-wave field, 6-neighbor averaging.
 
+Of the two Astaroth models THIS is the proxy (the reference's stand-in with
+Astaroth's communication volume and a cheap kernel); the real right-hand side
+-- the MHD equations at sixth order, three Runge-Kutta substeps a step -- is
+``models/astaroth_mhd.py AstarothMHD`` (docs/astaroth-mhd.md).
+
 Parity target: reference bin/astaroth_sim.cu — a proxy for the Astaroth
 magnetohydrodynamics code used to study compute/communication overlap:
 

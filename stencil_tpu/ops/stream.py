@@ -954,6 +954,10 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         # the wrap route: macros a trip of its device-side loop, as many
         # as bring the fresh-result pass's carry home (macro_loop)
         args["macros_per_trip"] = plan["macros_per_trip"]
+    if "steps_per_trip" in plan:
+        # the plane route: steps a trip of its step loop, as many as bring
+        # the renamed handles home (_carry_period)
+        args["steps_per_trip"] = plan["steps_per_trip"]
     if "z_halo_patch" in plan:
         # the z-slab wavefront: whether the pass patches its z halo in
         # the lane tiles that hold it or over the whole plane

@@ -1083,11 +1083,15 @@ class ResolvedPlan(Mapping):
     # writes, renames), ...]`` (``plan_plane_stages``); () elsewhere
     wrap_fills: tuple  # the y / z halo fills the plane passes make themselves
     # (``pass_wrap_fills``), the how of ``plan["pass_wrap_axes"]``
-    period: int  # steps after which the plane route's step loop has its carry
-    # back in its own buffers (``_carry_period``)
     exchange_route: str  # the domain's realize-resolved exchange route
     overlap_source: str  # who chose ``overlap`` / ``halo`` (``step.overlap`` /
     halo_source: str  # ``step.halo`` events)
+
+    @property
+    def period(self) -> int:
+        """Steps after which the plane route's step loop has its carry back in
+        its own buffers (``plan["steps_per_trip"]``; 1 off the plane route)."""
+        return self.plan.get("steps_per_trip", 1)
 
     def __getitem__(self, key):
         return self.plan[key]
@@ -1208,11 +1212,14 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
         # blocks and pads or cuts nothing
         plan["z_halo_patch"] = z_halo_patch_form(lane_pad_width(raw.z), dd._shell_radius.lo().x)
         plan["lane_pad"] = "vmem" if raw.z % 128 else "none"
-    return ResolvedPlan(
-        plan, stage_runs, wrap_fills,
-        _carry_period(names, plan["stages"]) if route == "plane" else 1,
-        exch_route, overlap_source, halo_source,
-    )
+    if route == "plane":
+        # the steps one trip of the step loop runs (domain.step's
+        # ``steps_per_trip``, the build's ``ResolvedPlan.period``): the period
+        # of the permutation a step's renames make of the blocks -- acoustic's
+        # one swap 2, the MHD step's three swaps of each of eight pairs (an odd
+        # count) 2, elastic's none 1
+        plan["steps_per_trip"] = _carry_period(names, plan["stages"])
+    return ResolvedPlan(plan, stage_runs, wrap_fills, exch_route, overlap_source, halo_source)
 
 
 def swept_axes(plan: Mapping) -> Tuple[int, ...]:

@@ -437,7 +437,13 @@ ALL_HISTOGRAMS = frozenset({
 #: to be back in its own buffer (``ops/stream.macro_loop``): 2 where the
 #: kernel writes a fresh result, 1 where it writes in place (``alias``) -- and
 #: so does a stream-engine step on the wrap route (2: ``stream_wrap_pass``
-#: writes fresh results); a z-slab wavefront step (``Jacobi3D``'s z-ring and
+#: writes fresh results); a stream-engine step on the PLANE route says
+#: steps_per_trip = the steps one trip of its step loop runs, as many as bring
+#: the handles its renames swap back to their own buffers
+#: (``ops/stream_plan._carry_period``): 1 with no rename (elastic), 2 for
+#: acoustic's one swap a step and for Astaroth's MHD step, whose three stages
+#: each swap all eight ``(q_prev, q)`` pairs (``renamed`` "8/8/8", an odd
+#: count of swaps a step; dispatch a multiple of it); a z-slab wavefront step (``Jacobi3D``'s z-ring and
 #: lane-padded shell kernels, the stream engine's wavefront route with
 #: ``z_slabs``) adds z_halo_patch = where its kernel patches the z halo into
 #: the working plane: "tile" = inside the 128-lane tiles that hold the halo

@@ -216,6 +216,19 @@ def _lbm():
     return _trace_step(s.dd, s._step)
 
 
+def _astaroth_mhd():
+    import jax
+
+    from stencil_tpu.models.astaroth_mhd import AstarothMHD
+
+    s = AstarothMHD(16, 16, 16, interpret=True, devices=jax.devices()[:1], seed_words=None)
+    s.realize()
+    args = s._step._span_args()
+    assert (args["route"], args["stages"], args["renamed"], args["steps_per_trip"]) == (
+        "plane", 3, "8/8/8", 2), args
+    return _trace_step(s.dd, s._step)
+
+
 #: label -> builder of the ClosedJaxpr, at a CPU size under interpret
 MODEL_PROGRAMS = {
     "model:jacobi3d-512/wrap": _jacobi_wrap,
@@ -226,6 +239,7 @@ MODEL_PROGRAMS = {
     "model:elastic-so8-600/plane-r4": _elastic,
     "model:acoustic-so8-1200x4/plane-r4": _acoustic_x4,
     "model:lbm-d3q19-256/wrap-m2": _lbm,
+    "model:astaroth-mhd-256/plane-r3": _astaroth_mhd,
 }
 
 

@@ -116,6 +116,7 @@ def test_route_is_plane_and_the_span_says_so(blend, mesh, wrapped, wired, monkey
         # what the kernel reads against what the exchange serves (ISSUE 39): u
         # alone, along the axes only, on all six sides
         "quantities": 4, "offcentre": 1, "diagonal": 0, "read_sides": 6, "exchanged_sides": 6,
+        "steps_per_trip": 2,  # one swap a step: the handles are home after two (ISSUE 44 says it)
     }
     assert plan["halo_readers"] == ("u",), plan
     assert plan["writers"] == ("u",) and plan["renamed"] == ("u_prev",), plan

@@ -1,0 +1,401 @@
+"""``AstarothMHD`` (Astaroth's compressible MHD step: eight fields and their
+eight second buffers, sixth-order differences at full radius 3 with in-plane
+diagonals, three Runge-Kutta substeps a step) against the plain reference
+``models/astaroth_mhd_reference.py``: every cell of all sixteen quantities on
+the plane route and on the XLA slice engine, after odd and even step counts
+(three renames a step: the carry's period is two steps), on meshes where an
+edge halo crosses two wires; an edge halo or a plane corner left unfilled
+comes out wrong; every single term switched off is seen; the difference
+operators are sixth order and the integrator third; the plan at the
+benchmark's size; the ``domain.step`` span's account."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from stencil_tpu import telemetry
+from stencil_tpu.models import astaroth_mhd_reference as ref
+from stencil_tpu.models.astaroth_mhd import RADIUS, AstarothMHD
+from stencil_tpu.ops import stream_plan as sp
+from stencil_tpu.telemetry import names as tm
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+WORDS = (0x1234, 0xBEEF, 0x5EED, 0xC0FFEE)
+#: the program and the reference run the same ``substep``; the compilers round apart
+TOL = 1e-6
+N = 16
+
+
+def _config():
+    with open(os.path.join(ROOT, "benchmark", "configs", "astaroth-mhd-256.json")) as f:
+        return json.load(f)
+
+
+def _setup(n=N, **kw):
+    """Two whole waves an axis at most: 16 cells hold them at sixth order."""
+    return ref.MhdSetup((n, n, n), max_waves=2, **kw)
+
+
+def _sim(mesh=(1, 1, 1), impl="pallas", **kw):
+    sim = AstarothMHD(N, N, N, setup=_setup(), interpret=True, seed_words=None, kernel_impl=impl,
+                      devices=jax.devices()[: int(np.prod(mesh))], **kw)
+    sim.dd.set_partition(*mesh)
+    sim.realize()
+    return sim
+
+
+_SIMS = {}
+
+
+def _shared(mesh=(1, 1, 1), impl="pallas"):
+    """One realized 16^3 model a (mesh, engine), shared by the cases that load
+    their own state into it: building and compiling it is most of a case's
+    time."""
+    if (mesh, impl) not in _SIMS:
+        _SIMS[mesh, impl] = _sim(mesh, impl)
+    return _SIMS[mesh, impl]
+
+
+_STATE = {}
+
+
+def _state(words=WORDS):
+    if words not in _STATE:
+        _STATE[words] = ref.global_fields(_setup(), np.asarray(words, dtype=np.uint32))
+    return _STATE[words]
+
+
+def _load(sim, state):
+    for name in ref.QUANTITIES:
+        sim.dd.set_quantity(sim.handles[name], np.asarray(state[name]))
+
+
+def _errors(sim, want) -> dict:
+    return {q: float(np.abs(sim.field(q) - np.asarray(want[q])).max()) for q in ref.QUANTITIES}
+
+
+# --- the program against the reference -------------------------------------------------
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3, 4])
+def test_plane_route_matches_the_reference(steps):
+    """One device, ONE dispatch of ``steps`` time steps: 3 is a trip of two and
+    a step behind the loop (the handles come back permuted), 4 is two trips;
+    every cell of the eight fields and of the eight second buffers."""
+    sim = _shared()
+    _load(sim, _state())
+    sim.step(steps)
+    plan = sim._step._stream_plan
+    assert (plan["route"], plan["steps_per_trip"]) == ("plane", 2)
+    assert max(_errors(sim, ref.steps(sim.setup, _state(), steps)).values()) < TOL
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3, 4])
+def test_the_xla_engine_runs_the_same_kernels(steps):
+    """The XLA slice engine, a step a dispatch (it has no carry to bring home)."""
+    sim = _shared(impl="jnp")
+    _load(sim, _state())
+    for _ in range(steps):
+        sim.step(1)
+    assert max(_errors(sim, ref.steps(sim.setup, _state(), steps)).values()) < TOL
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3])
+@pytest.mark.parametrize("mesh", [(2, 1, 1), (2, 2, 1), (2, 2, 2)])
+def test_model_matches_the_reference_across_devices(mesh, steps):
+    """CPU meshes: the mixed differences read the x-y, x-z and y-z EDGE halos
+    at radius 3, which on [2,2,2] cross two wires each: the x, then y, then z
+    sweeps must have carried them.  A step a dispatch: every second one starts
+    from permuted handles."""
+    sim = _shared(mesh=mesh)
+    _load(sim, _state())
+    for _ in range(steps):
+        sim.step(1)
+    assert tuple(sim.dd.mesh_dim()) == mesh
+    assert max(_errors(sim, ref.steps(sim.setup, _state(), steps)).values()) < TOL
+
+
+def test_the_seeded_state_matches_the_reference_and_takes_the_seed_as_an_argument():
+    """The seeded plane waves through ``fill(args=)``: one compiled fill a
+    quantity serves every seed (``init_by_coords(args=)``), every field
+    nowhere constant, each ``*_prev`` its field."""
+    sim = _shared()
+    fields = ref.seeded_fields(sim.setup)
+    c = (np.arange(4)[:, None, None], np.arange(4)[None, :, None], np.arange(4)[None, None, :])
+    seeds = [np.asarray(WORDS, dtype=np.uint32), np.asarray(WORDS, dtype=np.uint32) + 5]
+    assert len({jax.jit(fields["uy"]).lower(*c, w).as_text() for w in seeds}) == 1
+    sim.fill(fields, (seeds[1],))
+    want = ref.global_fields(sim.setup, seeds[1])
+    assert max(_errors(sim, want).values()) < 5e-7
+    other = _state()
+    for q in ref.FIELDS:
+        a = np.asarray(want[q])
+        assert float(np.abs(a - np.asarray(other[q])).max()) > 1e-3  # another seed, another state
+        rest = sim.setup.lnrho0 if q == "lnrho" else 0.0
+        assert float(np.abs(a - rest).max()) <= sim.setup.amplitude * (1 + 1e-6)
+        for axis in range(3):  # constant along no line
+            assert float(np.abs(np.diff(a, axis=axis)).max(axis=axis).min()) > 0.0
+        np.testing.assert_array_equal(a, np.asarray(want[q + "_prev"]))
+
+
+def test_bf16_storage_fails_the_tolerance():
+    sim = _sim(storage_dtype="bf16")
+    _load(sim, _state())
+    sim.step(1)
+    assert sim.dd.storage_dtype() == "bf16"
+    assert max(_errors(sim, ref.steps(sim.setup, _state(), 1)).values()) > 100 * TOL
+
+
+@pytest.mark.parametrize("which", ["edge_xy", "corner_yz"])
+def test_an_unfilled_edge_halo_or_plane_corner_comes_out_wrong(which, monkeypatch):
+    """Mesh [2,2,2]: a sweep carries the halo of the axes swept before it
+    along, which is what fills the edges.  ``edge_xy``: the x halo planes' y
+    halo rows put back to what they held before the y sweep; ``corner_yz``: the
+    y halo rows' z halo columns -- the corner of every x-plane the pass loads
+    -- put back after the z sweep.  Every face halo is still filled, and the
+    mixed differences of the second and third substeps come out wrong in the
+    fields that read that diagonal (``grad div``: ``ux, uy`` in the x-y plane,
+    ``uy, uz`` in the y-z plane, and the potentials likewise)."""
+    from stencil_tpu.ops import exchange as ex
+
+    real = ex._axis_sweep
+    swept, other = (1, 0) if which == "edge_xy" else (2, 1)
+
+    def faces_only(blocks, axis, r_lo, r_hi, *rest):
+        before = list(blocks)  # the sweep writes its results into the list it is given
+        out = real(blocks, axis, r_lo, r_hi, *rest)
+        if axis != swept:
+            return out
+        stale = []
+        for new, old in zip(out, before):
+            for a in (slice(0, RADIUS), slice(-RADIUS, None)):  # the earlier axis's halo ...
+                for b in (slice(0, r_lo), slice(new.shape[axis] - r_hi, None)):  # ... of this one's
+                    at = [slice(None)] * 3
+                    at[other], at[axis] = a, b
+                    new = new.at[tuple(at)].set(old[tuple(at)])
+            stale.append(new)
+        return stale
+
+    monkeypatch.setattr(ex, "_axis_sweep", faces_only)
+    sim = _sim(mesh=(2, 2, 2))
+    _load(sim, _state())  # with its shell filled: the FIRST substep's edges are right as loaded
+    sim.step(1)
+    errs = _errors(sim, ref.steps(sim.setup, _state(), 1))
+    a_read, a_spared = (("ax", "ay"), "az") if which == "edge_xy" else (("ay", "az"), "ax")
+    assert all(errs[q] > 50 * TOL for q in ref.VELOCITY), errs  # coupled through div u at once
+    # the potentials couple through B alone: the one whose grad div reads no
+    # diagonal of that plane is still right after one step
+    assert all(errs[q] > 5 * TOL for q in a_read) and errs[a_spared] < TOL, errs
+
+
+# --- every term is seen -------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("term", ["nu", "eta", "chi", "zeta", "lorentz", "pressure", "advection"])
+def test_every_single_term_moves_the_state_far_beyond_the_limit(term):
+    """The benchmark's coefficients, fixed ``dt`` and dispatch, its seeded
+    state, on the same box at 24^3 (the terms are those of the low modes: what
+    they add up to over the dispatch's TIME is the same on any grid that
+    resolves them): the update with ONE term switched off against the full one
+    differs by more than 100 times the cell's ``max_abs_err`` after one
+    dispatch's worth of steps, so a program that skipped it would not be
+    ``correct``.  The update is ``astaroth_mhd_reference.substep``, the one the
+    program's kernels run (held to each other above), on whole arrays: a
+    seventh of the compiles the program would take (the benchmark's rehearsal
+    switches a term off in the program itself, tests/test_bench_mhd.py)."""
+    config = _config()
+    s = config["setup"]
+    full = ref.MhdSetup(
+        (24, 24, 24), nu=s["nu"], eta=s["eta"], chi=s["chi"], zeta=s["zeta"], gamma=s["gamma"],
+        cp=s["cp"], cs0=s["cs0"], mu0=s["mu0"], lnrho0=s["lnrho0"], lnT0=s["lnT0"], box=s["box"],
+        dt=s["dt"], amplitude=s["amplitude"], modes=s["modes"], max_waves=s["max_waves"],
+    )
+    off = {term: 0.0} if term in ("nu", "eta", "chi", "zeta") else {"off": (term,)}
+    steps = config["dispatch"]["bulk"]
+    state = ref.global_fields(full, np.asarray(WORDS, dtype=np.uint32))
+    want = ref.steps(full, state, steps)
+    got = ref.steps(dataclasses.replace(full, **off), state, steps)
+    worst = max(float(jnp.abs(got[q] - want[q]).max()) for q in ref.QUANTITIES)
+    assert worst > 100 * config["limits"]["max_abs_err"], (term, worst)
+    assert all(bool(jnp.isfinite(want[q]).all()) for q in ref.QUANTITIES)
+
+
+def test_unknown_terms_are_refused():
+    with pytest.raises(ValueError, match="unknown terms"):
+        ref.MhdSetup((8, 8, 8), off=("gravity",))
+
+
+# --- the operators and the integrator ---------------------------------------------------
+
+
+def _sine_error(which: str, n: int) -> float:
+    """max error of one difference of ``sin(2x + y - z + 0.3)`` on ``n^3``
+    cells of the 2 pi box, in float64."""
+    h = 2.0 * np.pi / n
+    x = np.arange(n) * h
+    arg = 2.0 * x[:, None, None] + x[None, :, None] - x[None, None, :] + 0.3
+    f = {"f": np.sin(arg)}
+    taps = ref.Taps(lambda q, dx, dy, dz: np.roll(f[q], (-dx, -dy, -dz), (0, 1, 2)))
+    if which == "first":
+        return float(np.abs(ref.der1(taps, "f", 0, 1.0 / h) - 2.0 * np.cos(arg)).max())
+    if which == "second":
+        return float(np.abs(ref.der2(taps, "f", 1, 1.0 / h) + np.sin(arg)).max())
+    mixed = ref.der_mixed(taps, "f", 0, 2, 1.0 / h, 1.0 / h)  # d_x d_z = +2 sin
+    return float(np.abs(mixed - 2.0 * np.sin(arg)).max())
+
+
+@pytest.mark.parametrize("which", ["first", "second", "mixed"])
+def test_the_differences_are_sixth_order(which):
+    coarse, fine = _sine_error(which, 32), _sine_error(which, 64)
+    assert fine < 1e-5 and 45.0 < coarse / fine < 80.0, (coarse, fine)  # 2^6 = 64
+
+
+def test_the_two_buffer_runge_kutta_is_third_order():
+    """``y' = -y`` over one time unit in Astaroth's two-buffer form: halving
+    the step cuts the error eightfold, and the second buffer holds the value
+    before the last substep."""
+
+    def integrate(steps):
+        h, cur, prev = 1.0 / steps, 1.0, 1.0
+        for _ in range(steps):
+            for s, (ratio, beta) in enumerate(ref.COEFFS):
+                cur, prev = ref.two_buffer(cur, prev if s else None, h * -cur, ratio, beta), cur
+        return cur, prev
+
+    errors = [abs(integrate(n)[0] - np.exp(-1.0)) for n in (10, 20, 40)]
+    assert 7.0 < errors[0] / errors[1] < 9.0 and 7.0 < errors[1] / errors[2] < 9.0, errors
+    cur, prev = integrate(10)
+    assert prev != cur and abs(prev - cur) < 0.1
+    assert ref.ALPHA[0] == 0.0 and abs(sum(ref.BETA[s] for s in range(3)) - 1.8041666) < 1e-6
+
+
+# --- the plan ---------------------------------------------------------------------------
+
+
+def test_the_plan_at_the_benchmarks_size_is_the_configurations(monkeypatch):
+    """Plan only, nothing allocated: 256^3 x 16 on one described chip (the
+    blend kernels on, as on the chip): the plane route, three stages of ONE
+    pass each (16 read, 8 ringed, 8 written, 8 renamed), the eight ``*_prev``
+    in no message, the y and z halos filled in the pass, two steps a trip --
+    and the planner's VMEM model and ``check_vmem`` of one verdict."""
+    from stencil_tpu import analysis
+    from stencil_tpu.ops import halo_blend
+    from stencil_tpu.ops.jacobi_pallas import _vmem_budget
+    from stencil_tpu.ops.stream import stream_span_args
+
+    monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
+    config = _config()
+    sim = AstarothMHD(*config["global_extent"], devices=jax.devices()[:1], seed_words=None)
+    sim.dd.realize(allocate=False)
+    stages = tuple(sim._substep(s) for s in range(ref.SUBSTEPS))
+    request = sp.plan_stream(sim.dd, RADIUS, "auto", False)
+    assert request["route"] == "plane"  # x_radius 3 admits no other
+    plan = sp.resolve_stream_plan(sim.dd, stages, RADIUS, request, False)
+    expect = config["expect"]
+    assert "depth" not in expect  # a pinned depth would shut temporal blocking out
+    assert (plan["route"], len(plan["stages"]), len(plan["renamed"])) == (
+        expect["route"], expect["stages"], expect["renamed"])
+    assert len(sim.dd._handles) == expect["quantities"] == config["quantities"] == 16
+    for st in plan["stages"]:
+        assert st["readers"] == ref.FIELDS  # the *_prev ride in no message
+        (p,) = st["passes"]
+        assert (len(p["reads"]), len(p["rings"]), len(p["writes"])) == (16, 8, 8)
+        assert (config["pass"]["reads"], config["pass"]["writes"]) == (16, 8)
+        assert p["renames"] == tuple((q + "_prev", q) for q in ref.FIELDS)
+        # 16 x 2 + 8 x 2 + 8 x 6 = 96 planes of 264 x 384 f32 + sixteen margins
+        assert p["vmem_bytes"] == 96 * 264 * 384 * 4 + 16 * sp._VMEM_STACK_MARGIN <= _vmem_budget()
+    assert plan["halo_readers"] == ref.FIELDS and plan["writers"] == ref.FIELDS
+    assert (plan["pass_wrap_axes"], plan["steps_per_trip"], plan.period) == ("yz", 2, 2)
+    assert config["dispatch"]["bulk"] % plan["steps_per_trip"] == 0  # whole trips: no edge copy
+    assert analysis.check_vmem(sim.dd, plan.plan) is None
+    said = stream_span_args(plan.plan, RADIUS, 16)
+    assert (said["exchanged_sides"], said["read_sides"], said["wrapped"]) == (48, 48, "yz")
+
+
+def test_the_span_says_what_a_staged_renaming_step_does():
+    """``domain.step``: three stages, eight renames and eight exchanged in
+    each, sixteen quantities of which eight are read off-centre, six of them
+    diagonally; 48 sides read of the 48 served (of 16 x 6: the mask's work)."""
+    sim = _shared()
+    _load(sim, _state())
+    seen = []
+    real = telemetry.span
+
+    def spy(name, *a, **kw):
+        seen.append((name, kw))
+        return real(name, *a, **kw)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(telemetry, "span", spy)
+        sim.step(2)
+    (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
+    assert (kw["label"], kw["steps"], kw["route"], kw["x_radius"]) == ("astaroth-mhd", 2, "plane", 3)
+    assert (kw["stages"], kw["passes"], kw["steps_per_trip"]) == (3, 3, 2)
+    assert (kw["renamed"], kw["exchanged"], kw["written"], kw["aliased"]) == (
+        "8/8/8", "8/8/8", "8/8/8", "16/16/16")
+    assert (kw["quantities"], kw["offcentre"], kw["diagonal"]) == (16, 8, 6)
+    assert (kw["read_sides"], kw["exchanged_sides"]) == (48, 48)
+    said = {k: v for k, v in kw.items() if k not in ("first", "total")}  # a first call's marks
+    assert said == {"label": "astaroth-mhd", "steps": 2, **sim._step._span_args()}
+
+
+def test_the_counter_is_registered_and_the_names_lint_passes():
+    import inspect
+
+    from stencil_tpu import lint
+
+    registered = inspect.getsource(tm).split('SPAN_STEP = "domain.step"')[0]
+    assert "steps_per_trip" in registered
+    assert lint.run_lint(select=["telemetry-name"]) == []
+    with open(os.path.join(ROOT, "docs", "observability.md")) as f:
+        assert "`steps_per_trip`" in f.read()
+
+
+def test_the_step_loop_brings_every_carry_home():
+    """``_carry_period``: three stages that each swap all eight pairs is an odd
+    count of swaps a step -- period 2; an even count would be 1."""
+    names = list(ref.QUANTITIES)
+    stage = {"passes": ({"renames": tuple((q + "_prev", q) for q in ref.FIELDS)},)}
+    assert sp._carry_period(names, [stage] * 3) == 2
+    assert sp._carry_period(names, [stage] * 2) == 1
+    assert sp._carry_period(names, []) == 1
+
+
+def test_rebuild_keeps_the_plan_and_bad_arguments_are_refused():
+    sim = _shared()
+    sim.rebuild_after_reshard()
+    plan = sim._step._stream_plan
+    assert (plan["route"], len(plan["stages"]), plan["steps_per_trip"]) == ("plane", 3, 2)
+    _load(sim, _state())
+    sim.step(2)
+    assert max(_errors(sim, ref.steps(sim.setup, _state(), 2)).values()) < TOL
+    with pytest.raises(ValueError, match="the set-up is for"):
+        AstarothMHD(8, 8, 8, setup=_setup())
+    with pytest.raises(ValueError, match="unknown kernel_impl"):
+        AstarothMHD(8, 8, 8, kernel_impl="cuda")
+
+
+# --- the driver -------------------------------------------------------------------------
+
+
+def test_driver_runs_on_the_cpu(capsys, tmp_path):
+    """``stencil-astaroth-mhd`` takes the box, prints the cell's figure of merit,
+    says on stderr what the planner made of the three substeps and writes its
+    metrics where it is told."""
+    from stencil_tpu.bin import astaroth_mhd
+
+    out = tmp_path / "metrics.json"
+    rc = astaroth_mhd.main(["16", "16", "16", "--iters", "1", "--steps", "1",
+                            "--metrics-out", str(out)])
+    assert rc == 0
+    io = capsys.readouterr()
+    row = io.out.strip().splitlines()[-1].split(",")
+    assert row[0] == "astaroth_mhd" and row[3:6] == ["16", "16", "16"] and float(row[-1]) > 0
+    (said,) = [l for l in io.err.splitlines() if l.startswith("mesh: ")]
+    assert "route='plane'" in said and "stages=3" in said and "renamed=8/8/8" in said, said
+    assert out.exists() and out.stat().st_size > 0

@@ -122,7 +122,18 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
         assert m["reducer"] in ("named_share", "named_roofline_hbm", "span_percentile", "span_count"), name
         assert m["cells"] == ["lbm-d3q19-256.bulk"] == declared[name]["workloads"], name
         assert m["moves"] == "mcells_per_s_chip", name
-    for name in set(declared) - new - plane - staged - setup - wired - lbm - (ragged - {"collective_pct.ragged"}):
+    # PR 44's: the MHD step's shares and its two rooflines, their files listing the cell by name
+    # (tests/test_bench_mhd.py holds them)
+    mhd = {n for n in declared if n.endswith(".mhd") or n.startswith("mhd_pass_")}
+    assert len(mhd) == 8
+    for name in mhd:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["reducer"] in ("named_share", "named_roofline_hbm", "named_roofline_flops",
+                                "span_percentile", "span_count"), name
+        assert m["cells"] == ["astaroth-mhd-256.bulk"] == declared[name]["workloads"], name
+        assert m["moves"] == "mcells_per_s_chip", name
+    for name in set(declared) - new - plane - staged - setup - wired - lbm - mhd - (ragged - {"collective_pct.ragged"}):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
                                                "trace_roofline_hbm", "trace_idle"), name

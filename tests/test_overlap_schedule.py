@@ -544,6 +544,50 @@ def test_elastic_step_carries_its_blocks_in_place(monkeypatch):
     assert not re.findall(r"=\s+f32\[608,608,608\]\S*\s+copy\(", text) and temp == 0
 
 
+@pytest.mark.slow  # tier-2 with its siblings: one real-TPU-compiler AOT
+# compile at the benchmark's size (40 s alone: six passes of ~840 operations
+# on 99-vreg planes)
+def test_mhd_step_carries_its_blocks_in_place(monkeypatch):
+    """The MHD cell's dispatch (256^3, sixteen quantities, three stages) as the
+    chip's compiler leaves it (ISSUE 44): the ``while`` body holds TWO steps --
+    SIX ``stream_plane_pass`` custom calls of eight results, each result aliased
+    onto a ``*_prev`` operand, the renamed handles home again after the trip --
+    and 48 ``blend_planes`` x wraps (eight fields a substep; the y and z halos
+    are the pass's own fills), NO ``copy`` of a block and no temporary: a step
+    holds its sixteen arrays and nothing else.  Mosaic takes the whole-stage
+    pass (86.9 MB by the planner's model, 96 planes and sixteen margins)."""
+    from stencil_tpu.models.astaroth_mhd import RADIUS, AstarothMHD
+    from stencil_tpu.ops import halo_blend
+    from stencil_tpu.ops import stream as sm
+
+    devices = _topology_devices()
+    monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        sim = AstarothMHD(256, 256, 256, devices=devices[:1], seed_words=None)
+        sim.dd.realize(allocate=False)
+        stages = tuple(sim._substep(s) for s in range(3))
+        plan = sp.plan_stream(sim.dd, RADIUS, "auto", False)
+        plan = sp.resolve_stream_plan(sim.dd, stages, RADIUS, plan, False)
+        step = sm._build_stream_step(sim.dd, stages, RADIUS, plan, interpret=False)
+        compiled = step.lower(sim.dd.abstract_arrays(), 4).compile()
+        text, temp = compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    assert (plan["route"], plan["pass_wrap_axes"], plan["steps_per_trip"]) == ("plane", "yz", 2)
+    assert [len(p["renames"]) for st in plan["stages"] for p in st["passes"]] == [8, 8, 8]
+    calls = [l for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
+    passes = [l for l in calls if l.lstrip().startswith("%stream_plane_pass")]
+    assert len(passes) == 6 and len(calls) - len(passes) == 48
+    assert all(l.lstrip().startswith("%blend_planes") for l in calls if l not in passes)
+    for l in passes:
+        assert l.lstrip().split(" custom-call(")[0].count("f32[262,262,262]") == 8
+        aliasing = l[l.index("output_to_operand_aliasing="):].split("}, ")[0]
+        assert aliasing.count("(") == 8, aliasing
+    assert not re.findall(r"=\s+f32\[262,262,262\]\S*\s+copy\(", text) and temp == 0
+
+
 @pytest.mark.slow  # tier-2 with its siblings: real-TPU-compiler AOT compiles
 # at the benchmark's size, three of them (5-6 s each)
 def test_lbm_macro_loop_carries_its_blocks_in_place(monkeypatch):

@@ -398,7 +398,7 @@ def _build_plane_step(g, stages, x_radius, plan):
                     interpret=g.interpret, fused_shell=_group_bufs(fused_bufs, grp),
                     f32_accumulate=g.f32_acc, halo_readers=stage_readers[k],
                     writers=writes, rings=rings, wrap_fills=plan.wrap_fills,
-                    renames=renames,
+                    renames=renames, window=plan["plane_window"],
                 )
             for q, o in zip(grp, outs):
                 out[q] = o
@@ -958,6 +958,11 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         # the plane route: steps a trip of its step loop, as many as bring
         # the renamed handles home (_carry_period)
         args["steps_per_trip"] = plan["steps_per_trip"]
+        # ... and the plane its passes work on: the block's "interior" where
+        # they fill both in-plane halos themselves and it is whole vector
+        # tiles (every in-plane shift one rotate, its wraparound the halo),
+        # the "raw" shell-carrying plane elsewhere (plane_window_form)
+        args["plane_window"] = plan["plane_window"]
     if "z_halo_patch" in plan:
         # the z-slab wavefront: whether the pass patches its z halo in
         # the lane tiles that hold it or over the whole plane

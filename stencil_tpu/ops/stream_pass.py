@@ -70,8 +70,15 @@ class PlaneView:
     ``sh(dx, dy, dz)`` mirrors ``ShardView.sh`` (the reference's
     ``src[o + Dim3(dx,dy,dz)]`` Accessor read, accessor.hpp:27-40): the
     x offset selects one of the ``2r+1`` VMEM-resident planes, the y/z
-    offsets are in-plane rotates.  Rotate wraparound at the plane edges only
-    contaminates shell cells the validity contract already sacrifices.
+    offsets are in-plane rotates.  What the rotate's wraparound brings in at
+    the plane's edges depends on the plane.  On a RAW, shell-carrying plane
+    (the wavefront pass, the plane pass's ``"raw"`` window) it only
+    contaminates shell cells the validity contract already sacrifices.  On a
+    BARE interior that is the whole periodic extent of both in-plane axes
+    (the wrap pass; the plane pass's ``"interior"`` window, which holds it
+    rotated -- all the same to a rotate) the wraparound IS the halo: the cell
+    a shift reads past an edge is the periodic neighbour, on the y and z
+    faces and in the y-z corner alike, and no cell is sacrificed.
 
     ``off_centre(dx, dy, dz)`` is called, at trace time, on every read with
     a non-zero offset (``center()`` and ``sh(0, 0, 0)`` never call it): the
@@ -139,6 +146,39 @@ def lane_pad_width(z: int) -> int:
     """Plane width rounded up to a 128 multiple — ragged lane extents stream
     ~30% slower (probe22), so z-slab wavefronts pad with dead columns."""
     return -(-z // 128) * 128
+
+
+def plane_window_form(wrap_fills, lo: Dim3, hi: Dim3, plane: Tuple[int, int], dtypes) -> str:
+    """The working plane of ``stream_plane_pass`` over ``plane = (Y, Z)`` raw
+    planes stored as ``dtypes``, read off what the pass is told and the static
+    shapes alone: ``"interior"`` where the pass makes BOTH in-plane halo fills
+    itself, each the self-wrap of the block's whole interior (``wrap_fills``
+    as ``pass_wrap_fills`` gives them where the mesh splits neither y nor z),
+    AND that interior is whole vector tiles of every stored dtype (8 sublanes
+    of f32, 16 of a 2-byte dtype, by 128 lanes); ``"raw"`` everywhere else.
+    ``domain.step`` says it as ``plane_window``."""
+    yi, zi = plane[0] - lo.y - hi.y, plane[1] - lo.z - hi.z
+    self_wrap = (
+        (1, 0, yi, lo.y), (1, lo.y + yi, lo.y, hi.y),
+        (2, 0, zi, lo.z), (2, lo.z + zi, lo.z, hi.z),
+    )
+    sublanes = max(max(8, 32 // jnp.dtype(d).itemsize) for d in dtypes)
+    whole = yi % sublanes == 0 and zi % 128 == 0
+    # ... and no narrower than the shell it stands in for (the rows and lanes
+    # past the window repeat its first ``lo + hi``)
+    whole = whole and yi >= lo.y + hi.y > 0 and zi >= lo.z + hi.z > 0
+    return "interior" if whole and tuple(wrap_fills) == self_wrap else "raw"
+
+
+def _wrap_fill(ref, fills):
+    """The y, then z, halo fills ``(axis, destination, source, width)`` of the
+    ``(1, Y, Z)`` block ``ref``, made in place, each over the full extent of
+    the other axis (so the later z fill completes the y-z corner)."""
+    for axis, dst, src, w in fills:
+        if axis == 1:
+            ref[0, dst : dst + w, :] = ref[0, src : src + w, :]
+        else:
+            ref[0, :, dst : dst + w] = ref[0, :, src : src + w]
 
 
 def _yz_coord_planes(origin_ref, Yr, Zr, off_y, off_z, gsize):
@@ -223,6 +263,9 @@ def stream_plane_pass(
     renames: Sequence[Tuple[str, str]] = (),  # ``(p, q)``: writer ``q``'s new
     # value lands in ``p``'s buffer and ``p`` comes back as raw ``q``
     # (trace_plane_kernel): a time level renamed instead of copied
+    window: str = "raw",  # the working plane (plane_window_form): the "raw"
+    # plane, or the block's "interior" (rotated onto the block's aligned
+    # corner) where the fills are its own self-wrap
 ) -> List[jax.Array]:
     """ONE kernel level over shell-carrying blocks, streaming x-planes with a
     ``2r``-deep ring per quantity read off-centre along x; shell planes and
@@ -272,6 +315,46 @@ def stream_plane_pass(
     ``halo_blend.wrap_halo``'s shuffle does in its scratch): no VMEM of
     their own, and idempotent, so a plane the pipeline does not refetch is
     patched again to the same cells.  Not with ``fused_shell``.
+
+    With ``window="interior"`` (``plane_window_form``: the fills are the
+    self-wrap of the block's WHOLE interior on both in-plane axes, and that
+    interior is whole vector tiles) the pass needs no halo around its working
+    plane at all: a halo cell of a loaded plane is a copy of an interior cell
+    of that plane, and a rotate of the bare ``(Yi, Zi)`` interior wraps around
+    to exactly that cell.  A rotate's wraparound cares nothing for where the
+    plane starts, so the working plane is the interior ROTATED by ``(lo.y,
+    lo.z)``, which needs no unaligned access: once the two LOW halos of a
+    fetched block hold their wrap (the first fill of each axis, made in the
+    pipeline's input buffer as above, for EVERY quantity -- a centre read
+    reads those rows and lanes too), the block's aligned corner ``[0:Yi,
+    0:Zi]`` is that plane -- raw row ``k`` is interior row ``k - lo.y`` for
+    ``k >= lo.y`` and the low halo, interior row ``Yi - lo.y + k``, below.
+    The rings hold ``(2r, Yi, Zi)`` such planes; the kernel's windows are such
+    planes and ``info.coords()`` their cells' own coordinates; every in-plane
+    shift is one native rotate (``_make_roll``'s aligned branch) whose
+    wraparound supplies the y, z and y-z corner reads, on interior and
+    x-shell planes alike (the x-y / x-z edge halos of a diagonal read).  No
+    read changes value and the kernel evaluates the same operations in the
+    same order on the cells the raw window keeps: interiors are bitwise the
+    raw window's.  What an OUTPUT block holds outside them: each stored plane
+    -- the kernel's values, or an x-shell plane passed through from the ring
+    or the lagged block -- goes onto the block's aligned corner whole
+    (interior cells and low halos at once), and the rows and lanes past it,
+    which repeat the plane's first ``lo + hi``, are copied behind it: the y /
+    z shell of the stored plane is REBUILT from the stored interior, in the
+    pipeline's output buffer.  An x-shell plane of a halo reader is then
+    bitwise what the raw window writes; an interior plane's shell is the wrap
+    of the NEW interior where the raw window keeps the wrap of the plane as
+    loaded -- so after the step's x sweep the block is, halo included, what a
+    full x -> y -> z exchange of the new state gives.  Either is the
+    exchange's to own (it refills halo cells before every read), and nothing
+    of the step reads a y / z halo cell of HBM.  The block maps, the aliases,
+    the lagged fetches and every guard below are the raw window's
+    (``check_inplace_order`` judges the same maps).  Everywhere else the
+    window is ``"raw"`` and the pass is, operation for operation, the one
+    above.  (Cutting the interior itself out of the block, at ``[lo.y:,
+    lo.z:]``, costs a sublane and a lane shift of every vreg of every plane
+    in and out: 2.4 ms of a 9.6 ms MHD pass, PERF.md PR 45.)
 
     Returns one array per quantity, but only the ``writers`` are OUTPUTS of
     the Pallas call: every quantity is an input with its ring and its view,
@@ -371,6 +454,15 @@ def stream_plane_pass(
         q for q in range(nq)
         if wrap_fills and (halo_readers is None or names[q] in halo_readers)
     ]
+    assert window in ("raw", "interior"), window
+    interior = window == "interior"
+    assert not interior or fused_shell is None
+    assert not interior or plane_window_form(
+        wrap_fills, lo, hi, (Y, Z), [b.dtype for b in raws]
+    ) == "interior", (wrap_fills, lo, hi, (Y, Z))
+    # the working plane: what the rings hold and the kernel's windows are
+    Yw, Zw = (y1 - y0, z1 - z0) if interior else (Y, Z)
+    low_fills = [f for f in wrap_fills if f[1] == 0]  # the fills of the LOW halos
 
     def no_ring(name):
         def fail():
@@ -407,13 +499,17 @@ def stream_plane_pass(
         out_refs = dict(zip(wq, refs[nq : nq + len(wq)]))  # writers only
         ring_refs = dict(zip(ringed, refs[nq + len(wq) :]))  # x readers only
         i = pl.program_id(0)
-        for q in wrapped:
-            for axis, dst, src, w in wrap_fills:  # y before z
-                if axis == 1:
-                    in_refs[q][0, dst : dst + w, :] = in_refs[q][0, src : src + w, :]
-                else:
-                    in_refs[q][0, :, dst : dst + w] = in_refs[q][0, :, src : src + w]
-        curs = [ref[0] for ref in in_refs]
+        if interior:
+            # every quantity, read off-centre or not: with its LOW halos
+            # filled, the block's aligned (Yw, Zw) corner IS the interior,
+            # rotated by (lo.y, lo.z) -- whole tiles, nothing shifted
+            for ref in in_refs:
+                _wrap_fill(ref, low_fills)  # y before z
+            curs = [ref[0, :Yw, :Zw] for ref in in_refs]
+        else:
+            for q in wrapped:
+                _wrap_fill(in_refs[q], wrap_fills)  # y before z
+            curs = [ref[0] for ref in in_refs]
         if fused_shell is not None:
             # level-0 VMEM patch (module docstring; _fused_plane_patch)
             ip = jnp.minimum(i, X - 1)  # the replayed last-plane refetches
@@ -428,7 +524,21 @@ def stream_plane_pass(
                     t, lo.y, hi.y, lo.z, hi.z,
                 )
 
-        y_g, z_g = _yz_coord_planes(origin_ref, Y, Z, lo.y, lo.z, gsize)
+        # (row / lane ``k`` of either window is raw row / lane ``k``)
+        y_g, z_g = _yz_coord_planes(origin_ref, Yw, Zw, lo.y, lo.z, gsize)
+
+        def put(out, v):
+            """The working plane ``v`` into the output block: the raw plane
+            whole; the rotated interior onto the block's aligned corner --
+            its interior cells and its low halos at once --, then the raw
+            rows and lanes past it, which repeat the plane's first ``lo +
+            hi``: the y / z shell of the STORED plane, whole."""
+            if not interior:
+                out[0] = v
+                return
+            out[0, :Yw, :Zw] = v
+            out[0, Yw:, :Zw] = v[: Y - Yw, :]
+            out[0, :, Zw:] = out[0, :, : Z - Zw]  # every row: the y-z corner too
 
         # output plane j = i - r; window is raw planes j-r .. j+r
         j = i - r
@@ -471,6 +581,9 @@ def stream_plane_pass(
                         )
                 for q, out in out_refs.items():
                     cent = plane(q, r)
+                    if interior:  # the kernel's values are the plane, whole
+                        put(out, vals[names[q]].astype(cent.dtype) if names[q] in vals else cent)
+                        continue
                     out[0] = cent  # keep the y/z shell ring
                     if names[q] in vals:
                         out[0, y0:y1, z0:z1] = vals[names[q]][
@@ -484,12 +597,12 @@ def stream_plane_pass(
                     # (slot is garbage for i < r, where plane j < 0 doesn't
                     # exist — those writes land on out plane 0, which step
                     # i == r rewrites with the real pass-through)
-                    out[0] = plane(q, r)
+                    put(out, plane(q, r))
 
         @pl.when(i == 0)
         def _():
             for q, out in out_refs.items():
-                out[0] = curs[q]  # first plane passes through
+                put(out, curs[q])  # first plane passes through
 
         # push the fetched plane (skip replayed last-plane refetches)
         if ring_refs:
@@ -561,7 +674,7 @@ def stream_plane_pass(
             {1 + home[q]: k for k, q in enumerate(wq)} if alias else {}
         ),
         scratch_shapes=[
-            pltpu.VMEM((2 * r, Y, Z), raws[q].dtype) for q in ringed
+            pltpu.VMEM((2 * r, Yw, Zw), raws[q].dtype) for q in ringed
         ],
         interpret=interpret,
         **_tpu_compiler_params(interpret),

@@ -17,7 +17,10 @@ Routes (``plan_stream`` chooses; ``ops/stream_pass.py`` has the passes):
   value of their own (one returned as another's centre plane -- a leapfrog's
   ``u_prev <- u`` -- swaps handles with it instead).  On a y or z axis the
   mesh does not split the pass fills that halo itself, in VMEM
-  (``pass_wrap_fills``), and the exchange sweeps the other axes.  A step may
+  (``pass_wrap_fills``), and the exchange sweeps the other axes; where it
+  fills BOTH and the interior is whole vector tiles the pass works on the
+  interior plane alone and the rotates' wraparound is the halo
+  (``plane_window``, ``stream_pass.plane_window_form``).  A step may
   be several STAGES (a sequence of kernels, each behind its own exchange)
   and a stage several PASSES, each over the quantities its outputs touch:
   all planned from one abstract trace of each kernel (``plan_plane_stages``).
@@ -65,7 +68,13 @@ from stencil_tpu.ops.jacobi_pallas import (
     _WRAP_MAX_K,
     z_halo_patch_form,
 )
-from stencil_tpu.ops.stream_pass import PlaneInfo, PlaneKernel, PlaneView, lane_pad_width
+from stencil_tpu.ops.stream_pass import (
+    PlaneInfo,
+    PlaneKernel,
+    PlaneView,
+    lane_pad_width,
+    plane_window_form,
+)
 from stencil_tpu.parallel.mesh import MESH_AXES
 
 
@@ -626,23 +635,28 @@ def _plane_renames(jaxpr, names, writers, x_radius: int, stored: dict):
 
 
 def plane_pass_vmem_bytes(
-    plane_bytes: Dict[str, int], x_radius: int, reads, rings, writes
+    plane_bytes: Dict[str, int], x_radius: int, reads, rings, writes,
+    ring_bytes: Optional[Dict[str, int]] = None,
 ) -> int:
     """VMEM model of one plane pass, ``stream_vmem_fits``' accounting cut to
     what the pass holds: two pipeline planes per quantity read, two more per
     quantity written, a ``2r``-deep ring per quantity read at ``dx != 0``,
     and the per-quantity stack margin (the kernel's roll / select
-    temporaries).  ``plane_bytes`` is the tile-padded plane of each quantity
-    at its STORAGE itemsize (the plane pass rings hold raw planes)."""
+    temporaries).  ``plane_bytes`` is the tile-padded RAW plane of each
+    quantity at its STORAGE itemsize -- what the pipeline moves -- and
+    ``ring_bytes`` the plane its ring holds, where that is another: the
+    block's interior on the interior window (``plane_window_form``; the
+    rings hold storage-dtype working planes).  None = the raw plane."""
+    ring_bytes = plane_bytes if ring_bytes is None else ring_bytes
     est = sum(2 * plane_bytes[q] for q in reads)
     est += sum(2 * plane_bytes[q] for q in writes)
-    est += sum(2 * x_radius * plane_bytes[q] for q in rings)
+    est += sum(2 * x_radius * ring_bytes[q] for q in rings)
     return est + _VMEM_STACK_MARGIN * len(reads)
 
 
 def plan_plane_passes(
     trace: PlaneTrace, plane_bytes: Dict[str, int], whole: bool = False,
-    rename: bool = False,
+    rename: bool = False, ring_bytes: Optional[Dict[str, int]] = None,
 ) -> List[dict]:
     """The passes of one stage over one group: ``[{"writes", "reads",
     "rings", "renames", "vmem_bytes"}, ...]``, each a subset of the kernel's
@@ -675,7 +689,8 @@ def plan_plane_passes(
     plane leave ``writes`` and the pairs go under ``renames`` (``(p, q)``:
     ``q``'s new value lands in ``p``'s buffer, so ``p`` stays among the
     ``reads`` whether the kernel reads it or not).  The caller passes it for
-    the in-place default schedule only (``resolve_stream_plan``)."""
+    the in-place default schedule only (``resolve_stream_plan``).
+    ``ring_bytes`` is ``plane_pass_vmem_bytes``' own."""
     budget = _vmem_budget()
 
     def describe(outputs, whole=False, renames=()):
@@ -692,7 +707,7 @@ def plan_plane_passes(
             "rings": rings,
             "renames": tuple(renames),
             "vmem_bytes": plane_pass_vmem_bytes(
-                plane_bytes, trace.x_radius, reads, rings, writes
+                plane_bytes, trace.x_radius, reads, rings, writes, ring_bytes
             ),
         }
 
@@ -929,7 +944,8 @@ def _as_stages(kernel) -> Tuple[PlaneKernel, ...]:
 
 
 def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
-                      fused: bool = False, rename: bool = False) -> Tuple[dict, tuple]:
+                      fused: bool = False, rename: bool = False,
+                      window: str = "raw") -> Tuple[dict, tuple]:
     """Plan a PLANE-route step from its kernels' own footprints: ``(keys,
     runs)``.  ``keys`` is what the plan says of it: ``stages`` -- per stage
     its ``readers`` (the quantities its exchange fills) and its ``passes``
@@ -945,20 +961,27 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     of the kernels, as ``_sweep_kind`` is a function of the mesh: no option.
     Under ``fused`` every quantity rides the exchange and every pass is
     whole (``plan_plane_passes``); ``rename`` is for the in-place default
-    schedule (``resolve_stream_plan``)."""
+    schedule (``resolve_stream_plan``).  ``window`` is the passes' working
+    plane (``plane_window_form``): the kernels are traced over planes of ITS
+    shape -- the rotate a shift lowers to is chosen there, at trace time
+    (``_make_roll``) -- and the rings are priced at it."""
     names = [h.name for h in dd._handles]
     raw = dd.local_spec().raw_size()
+    work = dd.local_spec().sz if window == "interior" else raw
     f32_acc = any(dd.field_dtype(h) != h.dtype for h in dd._handles)
     planes = [
         jax.ShapeDtypeStruct(
-            (raw.y, raw.z), jnp.float32 if f32_acc else dd.field_dtype(h)
+            (work.y, work.z), jnp.float32 if f32_acc else dd.field_dtype(h)
         )
         for h in dd._handles
     ]
-    plane_bytes = {
-        h.name: _padded_plane_bytes(raw.y, raw.z, dd.field_dtype(h).itemsize)
-        for h in dd._handles
-    }
+    plane_bytes, ring_bytes = (
+        {
+            h.name: _padded_plane_bytes(of.y, of.z, dd.field_dtype(h).itemsize)
+            for h in dd._handles
+        }
+        for of in (raw, work)
+    )
     described, built, traces = [], [], []
     for stage in _as_stages(kernel):
         readers, passes, runs = set(), [], []
@@ -969,7 +992,9 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
             )
             traces.append(trace)
             readers |= set(names) if fused else set(trace.readers)
-            for p in plan_plane_passes(trace, plane_bytes, whole=fused, rename=rename):
+            for p in plan_plane_passes(
+                trace, plane_bytes, whole=fused, rename=rename, ring_bytes=ring_bytes
+            ):
                 passes.append(p)
                 runs.append((
                     trace.pruned(p["writes"])[0], p["reads"], p["rings"], p["writes"],
@@ -1160,13 +1185,22 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
     stage_runs, wrap_fills = (), ()
     if route == "plane":
         default = not fused and not split
-        keys, stage_runs = plan_plane_stages(
-            dd, stages, x_radius, plan, interpret, fused,
-            rename=default and _plan_passes_in_place(plan),
-        )
-        plan.update(keys)
         if default:
             plan["pass_wrap_axes"], wrap_fills = pass_wrap_fills(dd, exch_route)
+        # the passes' working plane (domain.step's ``plane_window``): the
+        # block's interior where the fills above are its whole self-wrap on
+        # both axes and it is whole vector tiles, the raw plane elsewhere --
+        # read off the fills and the block's static shape alone
+        shell = dd._shell_radius
+        plan["plane_window"] = plane_window_form(
+            wrap_fills, shell.lo(), shell.hi(), (raw.y, raw.z),
+            [dd.field_dtype(h) for h in dd._handles],
+        )
+        keys, stage_runs = plan_plane_stages(
+            dd, stages, x_radius, plan, interpret, fused,
+            rename=default and _plan_passes_in_place(plan), window=plan["plane_window"],
+        )
+        plan.update(keys)
     else:
         plan["halo_readers"] = () if route == "wrap" else tuple(names)
         # what the kernel reads off-centre, as the plane route's planner

@@ -443,7 +443,16 @@ ALL_HISTOGRAMS = frozenset({
 #: (``ops/stream_plan._carry_period``): 1 with no rename (elastic), 2 for
 #: acoustic's one swap a step and for Astaroth's MHD step, whose three stages
 #: each swap all eight ``(q_prev, q)`` pairs (``renamed`` "8/8/8", an odd
-#: count of swaps a step; dispatch a multiple of it); a z-slab wavefront step (``Jacobi3D``'s z-ring and
+#: count of swaps a step; dispatch a multiple of it), and plane_window = the
+#: plane its passes work on: "interior" where they make BOTH in-plane halo
+#: fills themselves (``wrapped`` "yz"), each the self-wrap of the block's whole
+#: interior, and that interior is whole vector tiles -- the working plane is
+#: the block's aligned corner, the interior rotated by the low shell widths,
+#: every in-plane shift is one native rotate whose wraparound is the halo
+#: --, "raw" = the shell-carrying plane, everywhere
+#: else (``ops/stream_pass.plane_window_form``, read off the fills and the
+#: block's static shape: "interior" in ``astaroth-mhd-256.bulk``, "raw" in the
+#: three 600-extent plane cells); a z-slab wavefront step (``Jacobi3D``'s z-ring and
 #: lane-padded shell kernels, the stream engine's wavefront route with
 #: ``z_slabs``) adds z_halo_patch = where its kernel patches the z halo into
 #: the working plane: "tile" = inside the 128-lane tiles that hold the halo

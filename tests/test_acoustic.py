@@ -117,6 +117,9 @@ def test_route_is_plane_and_the_span_says_so(blend, mesh, wrapped, wired, monkey
         # alone, along the axes only, on all six sides
         "quantities": 4, "offcentre": 1, "diagonal": 0, "read_sides": 6, "exchanged_sides": 6,
         "steps_per_trip": 2,  # one swap a step: the handles are home after two (ISSUE 44 says it)
+        # 24 + 2 x 8 = 40 cells of interior a side here, 600 in the cell (4 x 128
+        # + 88 lanes): no whole vector tile, the passes keep the raw plane (ISSUE 45)
+        "plane_window": "raw",
     }
     assert plan["halo_readers"] == ("u",), plan
     assert plan["writers"] == ("u",) and plan["renamed"] == ("u_prev",), plan

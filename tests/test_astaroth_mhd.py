@@ -143,6 +143,33 @@ def test_the_seeded_state_matches_the_reference_and_takes_the_seed_as_an_argumen
         np.testing.assert_array_equal(a, np.asarray(want[q + "_prev"]))
 
 
+def test_the_interior_window_matches_the_reference(monkeypatch):
+    """A box whose y-z interior is whole vector tiles (16 x 128) on one device,
+    the blend kernels on as on the chip, so the pass fills both in-plane halos
+    itself (ISSUE 45): the passes work on the INTERIOR plane -- no halo in the
+    kernel's windows at all, the rotates' wraparound supplies the y, z and y-z
+    corner reads on interior and x-shell planes alike (the x-y and x-z edge
+    halos of the mixed differences) -- and every cell of all sixteen
+    quantities matches the reference after a trip of two steps and one behind
+    the loop.  The box is periodic and nowhere zero: a wrong wrap shows."""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    shape = (8, 16, 128)
+    setup = ref.MhdSetup(shape, max_waves=2)
+    sim = AstarothMHD(*shape, setup=setup, interpret=True, seed_words=None,
+                      devices=jax.devices()[:1])
+    sim.realize()
+    state = ref.global_fields(setup, np.asarray(WORDS, dtype=np.uint32))
+    _load(sim, state)
+    sim.step(3)
+    said = sim._step._span_args()
+    assert (said["route"], said["wrapped"], said["plane_window"]) == ("plane", "yz", "interior")
+    assert (said["renamed"], said["steps_per_trip"]) == ("8/8/8", 2)
+    want = ref.steps(setup, state, 3)
+    moved = min(float(jnp.abs(want[q] - state[q]).max()) for q in ref.FIELDS)
+    assert moved > 100 * TOL, moved  # every field advanced: the comparison sees the step
+    assert max(_errors(sim, want).values()) < TOL
+
+
 def test_bf16_storage_fails_the_tolerance():
     sim = _sim(storage_dtype="bf16")
     _load(sim, _state())
@@ -307,14 +334,19 @@ def test_the_plan_at_the_benchmarks_size_is_the_configurations(monkeypatch):
         assert (len(p["reads"]), len(p["rings"]), len(p["writes"])) == (16, 8, 8)
         assert (config["pass"]["reads"], config["pass"]["writes"]) == (16, 8)
         assert p["renames"] == tuple((q + "_prev", q) for q in ref.FIELDS)
-        # 16 x 2 + 8 x 2 + 8 x 6 = 96 planes of 264 x 384 f32 + sixteen margins
-        assert p["vmem_bytes"] == 96 * 264 * 384 * 4 + 16 * sp._VMEM_STACK_MARGIN <= _vmem_budget()
+        # 16 x 2 + 8 x 2 = 48 pipeline planes of the raw 262 x 262 block (264 x
+        # 384 f32 as tiled), 8 x 6 = 48 ring planes of its 256 x 256 interior
+        # (the interior window: ISSUE 45) + sixteen margins
+        assert p["vmem_bytes"] == (
+            48 * 264 * 384 * 4 + 48 * 256 * 256 * 4 + 16 * sp._VMEM_STACK_MARGIN) <= _vmem_budget()
     assert plan["halo_readers"] == ref.FIELDS and plan["writers"] == ref.FIELDS
     assert (plan["pass_wrap_axes"], plan["steps_per_trip"], plan.period) == ("yz", 2, 2)
+    assert plan["plane_window"] == "interior"  # 256 = 32 x 8 sublanes = 2 x 128 lanes
     assert config["dispatch"]["bulk"] % plan["steps_per_trip"] == 0  # whole trips: no edge copy
     assert analysis.check_vmem(sim.dd, plan.plan) is None
     said = stream_span_args(plan.plan, RADIUS, 16)
     assert (said["exchanged_sides"], said["read_sides"], said["wrapped"]) == (48, 48, "yz")
+    assert said["plane_window"] == "interior"
 
 
 def test_the_span_says_what_a_staged_renaming_step_does():
@@ -340,6 +372,8 @@ def test_the_span_says_what_a_staged_renaming_step_does():
         "8/8/8", "8/8/8", "8/8/8", "16/16/16")
     assert (kw["quantities"], kw["offcentre"], kw["diagonal"]) == (16, 8, 6)
     assert (kw["read_sides"], kw["exchanged_sides"]) == (48, 48)
+    assert kw["plane_window"] == "raw"  # 16 lanes of interior: no whole tile (and a CPU run
+    # without the blend kernels fills no halo in the pass: ``wrapped`` "")
     said = {k: v for k, v in kw.items() if k not in ("first", "total")}  # a first call's marks
     assert said == {"label": "astaroth-mhd", "steps": 2, **sim._step._span_args()}
 
@@ -350,10 +384,11 @@ def test_the_counter_is_registered_and_the_names_lint_passes():
     from stencil_tpu import lint
 
     registered = inspect.getsource(tm).split('SPAN_STEP = "domain.step"')[0]
-    assert "steps_per_trip" in registered
+    assert "steps_per_trip" in registered and "plane_window" in registered
     assert lint.run_lint(select=["telemetry-name"]) == []
     with open(os.path.join(ROOT, "docs", "observability.md")) as f:
-        assert "`steps_per_trip`" in f.read()
+        said = f.read()
+    assert "`steps_per_trip`" in said and "`plane_window`" in said
 
 
 def test_the_step_loop_brings_every_carry_home():

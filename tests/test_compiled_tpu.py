@@ -555,20 +555,28 @@ def test_compiled_lbm_step_matches_the_xla_engine():
     assert min(float(np.abs(a - b).max()) for a, b in zip(got[1:], got[:-1])) > 1e-3  # no two alike
 
 
-def test_compiled_mhd_step_matches_the_xla_engine():
-    """Astaroth's MHD step as Mosaic compiles it (ISSUE 44), at a small size
-    whose raw plane is whole tiles (64 x 128): the plane route -- three
+@pytest.mark.parametrize("shape,window", [
+    pytest.param((32, 58, 122), "raw", id="raw-planes-of-whole-tiles"),
+    pytest.param((32, 64, 128), "interior", id="interior-of-whole-tiles"),
+])
+def test_compiled_mhd_step_matches_the_xla_engine(shape, window):
+    """Astaroth's MHD step as Mosaic compiles it (ISSUE 44), at two small
+    sizes: one whose RAW plane is whole tiles (64 x 128: the raw window, every
+    in-plane shift a rotate of the shell-carrying plane) and one whose
+    INTERIOR is (64 x 128 of a 70 x 134 plane: the interior window of ISSUE
+    45 -- the block's aligned corner, the interior rotated by the shell, the
+    rotates' wraparound the halo, the stored planes' shell rebuilt behind
+    them).  The plane route -- three
     stages, eight renames each, two steps a trip, the y / z fills made in the
     pass -- against the XLA slice engine running the same kernels over swept
     exchanges, on every cell of all sixteen quantities after an even and an
     odd count of steps (the odd one runs a step behind the loop and hands the
     handles on permuted).  The box is periodic and nowhere zero: an unfilled
-    edge halo or plane corner would show."""
+    edge halo or plane corner, or a wrong wrap, would show."""
     from stencil_tpu.models.astaroth_mhd import AstarothMHD
     from stencil_tpu.models.astaroth_mhd_reference import QUANTITIES, MhdSetup
 
-    shape = (32, 58, 122)
-    setup = MhdSetup(shape)  # dt from the finest spacing, 2 pi / 122
+    setup = MhdSetup(shape)  # dt from the finest spacing, 2 pi / 122 or / 128
 
     def run(impl):
         sim = AstarothMHD(*shape, setup=setup, devices=jax.devices()[:1], kernel_impl=impl,
@@ -583,9 +591,69 @@ def test_compiled_mhd_step_matches_the_xla_engine():
     said, got = run("pallas")
     assert (said["route"], said["stages"], said["renamed"], said["steps_per_trip"], said["wrapped"]) == (
         "plane", 3, "8/8/8", 2, "yz"), said
+    assert said["plane_window"] == window, said
     _, want = run("jnp")
     for a, b in zip(got, want):
         worst = max(float(np.abs(x - y).max()) for x, y in zip(a, b))
         assert np.isfinite(worst) and worst <= 3e-6, worst  # the cell's own limit
     moved = min(float(np.abs(x - y).max()) for x, y in zip(got[0], got[1]))
     assert moved > 1e-4, moved  # every quantity advanced between the two readings
+
+
+@pytest.mark.parametrize("storage", ["native", "bf16"])
+def test_compiled_interior_window_is_bitwise_the_raw_plane_pass(storage, monkeypatch):
+    """The plane pass on its interior window (ISSUE 45) as Mosaic compiles it,
+    against the SAME step built on the raw window (``plane_window_form``
+    patched to say "raw": the parent's program): a radius-3 kernel with y-z,
+    x-y and x-z diagonal reads, products of fields, a lagged quantity and a
+    rename, on a 16 x 64 x 256 box (70 x 262 raw planes: two lane tiles and a
+    ragged third, as the MHD cell's), f32 and bf16 storage.  Mosaic contracts
+    nothing, so every cell of every quantity is BITWISE equal after an even
+    and an odd count of steps -- the kernel evaluates the same operations in
+    the same order on the cells the raw window keeps."""
+    from stencil_tpu.core.radius import Radius
+    from stencil_tpu.domain import DistributedDomain
+    from stencil_tpu.ops import stream_plan as sp
+
+    r = 3
+
+    def kern(views, info):
+        u, c = views["u"], views["c"]
+        _, y, z = info.coords()
+        acc = 0.3 * u.center() + 1e-3 * jnp.sin(0.1 * (y + 2 * z).astype(jnp.float32))
+        for k in (1, 2, 3):
+            acc = acc + (0.1 / k) * (
+                (u.sh(k, 0, 0) - 0.9 * u.sh(-k, 0, 0))
+                + (u.sh(0, k, k) - 0.8 * u.sh(0, -k, k)) * c.sh(0, k, 0)
+                + (u.sh(k, -k, 0) - 0.7 * u.sh(-k, k, 0))
+                + (u.sh(-k, 0, k) - 0.6 * u.sh(k, 0, -k)) * c.sh(0, 0, -k)
+            )
+        return {"u": acc + 0.5 * views["p"].center(), "p": u.center()}
+
+    def run():
+        dd = DistributedDomain(16, 64, 256)
+        dd.set_radius(Radius.constant(r))
+        dd.set_devices(jax.devices()[:1])
+        if storage != "native":
+            dd.set_storage(storage)
+        hs = [dd.add_data(n) for n in ("u", "c", "p")]
+        dd.realize()
+        for i, h in enumerate(hs):
+            dd.init_by_coords(h, lambda x, y, z, i=i: jnp.sin(0.13 * (x + 2 * y + 3 * z) + i))
+        step = dd.make_step(kern, engine="stream", x_radius=r)
+        got = []
+        for n in (4, 3):
+            dd.run_step(step, n)
+            got.append([np.asarray(dd.quantity_to_host(h), np.float32) for h in hs])
+        return step._stream_plan, got
+
+    plan, got = run()
+    assert (plan["route"], plan["pass_wrap_axes"], plan["plane_window"]) == ("plane", "yz", "interior")
+    assert plan["renamed"] == ("p",) and plan["stages"][0]["passes"][0]["rings"] == ("u",), plan
+    monkeypatch.setattr(sp, "plane_window_form", lambda *a: "raw")
+    plan_raw, want = run()
+    assert plan_raw["plane_window"] == "raw"
+    for a, b in zip(got, want):
+        for name, x, y in zip(("u", "c", "p"), a, b):
+            assert np.isfinite(y).all() and np.array_equal(x, y), name
+    assert float(np.abs(got[0][0] - got[1][0]).max()) > 1e-3  # the state moved

@@ -148,6 +148,7 @@ def test_the_plan_is_staged_and_the_span_says_so():
         "quantities": 13, "offcentre": 9, "diagonal": 0, "read_sides": 36,  # 9 (stress, axis) + 9 (velocity, axis) pairs, both sides
         "exchanged_sides": 54,
         "steps_per_trip": 1,  # no rename: every trip of the step loop is one step (ISSUE 44)
+        "plane_window": "raw",  # 24 cells of interior a side: no whole vector tile (ISSUE 45)
     }
     seen = []
     real = telemetry.span

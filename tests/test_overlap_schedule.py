@@ -555,7 +555,11 @@ def test_mhd_step_carries_its_blocks_in_place(monkeypatch):
     and 48 ``blend_planes`` x wraps (eight fields a substep; the y and z halos
     are the pass's own fills), NO ``copy`` of a block and no temporary: a step
     holds its sixteen arrays and nothing else.  Mosaic takes the whole-stage
-    pass (86.9 MB by the planner's model, 96 planes and sixteen margins)."""
+    pass on its INTERIOR window (ISSUE 45: the aligned 256 x 256 corner of
+    each 262 x 262 block, sliced loads and stores of a ref at whole tiles and
+    the few rows and lanes of the fills beside them; 80.0 MB by the planner's
+    model -- 48 raw pipeline planes, 48 interior ring planes and sixteen
+    margins, 86.9 MB on the raw window)."""
     from stencil_tpu.models.astaroth_mhd import RADIUS, AstarothMHD
     from stencil_tpu.ops import halo_blend
     from stencil_tpu.ops import stream as sm
@@ -576,6 +580,7 @@ def test_mhd_step_carries_its_blocks_in_place(monkeypatch):
     finally:
         jax.config.update("jax_enable_x64", x64_was)
     assert (plan["route"], plan["pass_wrap_axes"], plan["steps_per_trip"]) == ("plane", "yz", 2)
+    assert plan["plane_window"] == "interior"
     assert [len(p["renames"]) for st in plan["stages"] for p in st["passes"]] == [8, 8, 8]
     calls = [l for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
     passes = [l for l in calls if l.lstrip().startswith("%stream_plane_pass")]

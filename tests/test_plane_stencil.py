@@ -1136,3 +1136,205 @@ def test_the_carry_period_is_the_order_of_the_steps_permutation():
     assert _carry_period(names, stages([(("b", "a"), ("d", "c"))])) == 2
     # two stages that pass one block on: a cycle of three
     assert _carry_period(names, stages([(("b", "a"),)], [(("c", "b"),)])) == 3
+
+
+# --- the pass works on the aligned interior plane (ISSUE 45) ------------------
+#
+# Where the pass makes BOTH in-plane halo fills itself and each is the
+# self-wrap of the block's whole interior, the halo of a plane is what a rotate
+# of its interior wraps around to: ``stream_plane_pass(window="interior")`` works
+# on the bare interior of every fetched plane (held rotated by the low shell
+# widths: the block's aligned corner, no unaligned access), holds such planes in
+# its rings and hands the kernel such windows (whole vector tiles: every
+# in-plane shift one native rotate).  The kernel's values are the ones the
+# raw-plane pass computes in the cells it keeps, in the same order.
+
+
+def _diagonal_r3_kernel(views, info):
+    """Radius 3 read at full distance on every axis and on the y-z, x-y and
+    x-z diagonals (the MHD step's mixed differences); ``c`` along y alone (no
+    ring: fetched lagged), ``p`` at the centre; the cell's own coordinates
+    enter; ``p <- u`` as the centre plane itself.  Every weight is a power of
+    two, so each product is exact and the sum rounds the same whether or not
+    the CPU compiler contracts a multiply into the add behind it -- which it
+    decides per fusion, and fuses planes of another shape otherwise (on the
+    chip Mosaic contracts nothing)."""
+    u, c = views["u"], views["c"]
+    _, y, z = info.coords()
+    acc = 0.25 * u.center() + 2.0**-10 * (y + 2 * z).astype(u.center().dtype)
+    for k in (1, 2, 3):
+        acc = acc + 2.0**-k * (
+            (u.sh(k, 0, 0) - 0.5 * u.sh(-k, 0, 0))
+            + (u.sh(0, k, k) - 0.25 * u.sh(0, -k, k))
+            + (u.sh(k, -k, 0) - 0.125 * u.sh(-k, k, 0))
+            + (u.sh(-k, 0, k) - 0.0625 * u.sh(k, 0, -k))
+            + (0.5 * c.sh(0, k, 0) - c.sh(0, -k, 0))
+        )
+    return {"u": acc + views["p"].center(), "p": u.center()}
+
+
+def _whole_self_wrap(n, lo, hi):
+    return tuple(
+        (a, d, s, w)
+        for a in (1, 2)
+        for d, s, w in ((0, n[a], lo[a]), (lo[a] + n[a], lo[a], hi[a]))
+    )
+
+
+@pytest.mark.parametrize("interior,lo,hi,fills,storage,want", [
+    pytest.param((16, 128), (3, 3, 3), (3, 3, 3), "yz", "float32", "interior", id="whole-tiles"),
+    pytest.param((256, 256), (3, 3, 3), (3, 3, 3), "yz", "float32", "interior", id="mhd-256"),
+    pytest.param((8, 128), (1, 2, 3), (2, 1, 1), "yz", "float32", "interior", id="uneven-shell"),
+    pytest.param((16, 100), (3, 3, 3), (3, 3, 3), "yz", "float32", "raw", id="ragged-lanes"),
+    pytest.param((600, 600), (4, 4, 4), (4, 4, 4), "yz", "float32", "raw", id="acoustic-600"),
+    pytest.param((12, 128), (3, 3, 3), (3, 3, 3), "yz", "float32", "raw", id="ragged-sublanes"),
+    pytest.param((16, 128), (3, 3, 3), (3, 3, 3), "z", "float32", "raw", id="y-split"),
+    pytest.param((16, 128), (3, 3, 3), (3, 3, 3), "", "float32", "raw", id="no-fill"),
+    pytest.param((8, 128), (3, 3, 3), (3, 3, 3), "yz", "bfloat16", "raw", id="bf16-half-a-tile"),
+    pytest.param((16, 128), (3, 3, 3), (3, 3, 3), "yz", "bfloat16", "interior", id="bf16-whole-tiles"),
+])
+def test_the_plane_window_is_read_off_the_fills_and_the_shape(interior, lo, hi, fills, storage, want):
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+
+    n = (0,) + interior
+    given = tuple(f for f in _whole_self_wrap(n, lo, hi) if "xyz"[f[0]] in fills)
+    plane = tuple(n[a] + lo[a] + hi[a] for a in (1, 2))
+    dtypes = [jnp.float32, jnp.dtype(storage)]
+    assert spass.plane_window_form(given, Dim3(*lo), Dim3(*hi), plane, dtypes) == want
+    # a fill of fewer cells than the interior (a ragged last shard) is no self-wrap of it
+    short = tuple((a, d - (d > 0), s - (d == 0), w) for a, d, s, w in given)
+    assert spass.plane_window_form(short, Dim3(*lo), Dim3(*hi), plane, dtypes) == "raw"
+
+
+_R3 = ((3, 3, 3), (3, 3, 3))
+_INTERIOR_WINDOW_CASES = [
+    pytest.param({}, (), _R3, id="plain"),
+    pytest.param({"alias": True}, (), _R3, id="in-place"),
+    pytest.param({}, (("p", "u"),), _R3, id="renamed"),
+    pytest.param({"alias": True}, (("p", "u"),), _R3, id="renamed-in-place"),
+    pytest.param({"f32_accumulate": True}, (), _R3, id="bf16-storage"),
+    pytest.param({"f32_accumulate": True, "alias": True}, (("p", "u"),), _R3,
+                 id="bf16-renamed-in-place"),
+    pytest.param({"alias": True}, (("p", "u"),), ((3, 4, 3), (4, 3, 5)), id="uneven-shell"),
+]
+
+
+@pytest.mark.parametrize("kw,renames,shell", _INTERIOR_WINDOW_CASES)
+def test_the_interior_window_pass_is_bitwise_the_raw_plane_pass(kw, renames, shell):
+    """The same blocks through both windows: every interior cell of every
+    quantity bitwise equal, the x-shell planes of an output that is a halo
+    reader equal on every raw cell (their interiors pass through, their y / z
+    shell is the same fill), and on the interior window the y / z shell of EVERY stored plane
+    is the self-wrap of the plane as stored -- where the raw window keeps the
+    fills of the plane as loaded.  A quantity the pass does not write comes
+    back as the array that went in."""
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+
+    r, n, names = 3, (6, 16, 128), ["u", "c", "p"]
+    lo, hi = shell
+    shape = tuple(m + a + b for m, a, b in zip(n, lo, hi))
+    dtype = jnp.bfloat16 if kw.get("f32_accumulate") else jnp.float32
+    rng = np.random.default_rng(45)
+    raws = [jnp.asarray(rng.standard_normal(shape), dtype) for _ in names]
+    fills = _whole_self_wrap(n, lo, hi)
+    writers = ("u",) if renames else ("u", "p")
+
+    def run(window):
+        return spass.stream_plane_pass(
+            _diagonal_r3_kernel, names, raws, Dim3(*lo), Dim3(*hi), r,
+            jnp.asarray([5, 0, 0], jnp.int32), Dim3(64, n[1], n[2]), interpret=True,
+            halo_readers=("u", "c"), rings=("u",), writers=writers, wrap_fills=fills,
+            renames=renames, window=window, **kw,
+        )
+
+    got, want = run("interior"), run("raw")
+    inner = tuple(slice(a, a + m) for a, m in zip(lo, n))
+    as_np = lambda v: np.asarray(v.astype(jnp.float32))  # noqa: E731
+    for q, name in enumerate(names):
+        a, b = as_np(got[q]), as_np(want[q])
+        assert np.isfinite(b[inner]).all() and np.array_equal(a[inner], b[inner]), name
+        if name == "c":
+            assert got[q] is raws[q] and want[q] is raws[q]
+        elif name == "p" and renames:
+            assert got[q] is raws[0] and want[q] is raws[0]  # the handles swapped
+        else:
+            # a halo reader's x-shell planes are filled alike by both; one the
+            # raw window does not fill keeps its loaded shell there
+            yz = (slice(None),) + (inner[1:] if name == "p" else (slice(None),) * 2)
+            for x_shell in (slice(0, lo[0]), slice(lo[0] + n[0], None)):
+                assert np.array_equal(a[x_shell][yz], b[x_shell][yz]), name
+            assert np.array_equal(
+                a, _self_wrap(_self_wrap(a, 1, lo[1], hi[1]), 2, lo[2], hi[2])), name
+            assert not np.array_equal(a, b), name  # the raw window's shell is the OLD plane's
+
+
+@pytest.mark.parametrize("extent,partition,window", [
+    pytest.param((8, 16, 128), (1, 1, 1), "interior", id="whole-tiles"),
+    pytest.param((8, 16, 100), (1, 1, 1), "raw", id="ragged-lanes"),
+    pytest.param((8, 32, 128), (1, 2, 1), "raw", id="y-split"),
+])
+def test_a_plane_step_takes_the_interior_window_where_it_wraps_onto_itself(
+    extent, partition, window, monkeypatch
+):
+    """The step as built, blend kernels on as on the chip: ``plan["plane_
+    window"]`` follows the fills and the block's shape; where it says "raw"
+    the traced program IS the one built with the rule off (the parent's), and
+    where it says "interior" the program differs -- its rings hold interior
+    planes -- and every cell of every quantity is bitwise what the raw-plane
+    program gives after an even and an odd count of steps.  ``inplace-order`` holds on the program either way."""
+    import jax
+
+    from program_fingerprint import fingerprint_text
+    from test_stream import _pass_wrap_domain
+
+    from stencil_tpu import analysis
+    from stencil_tpu.analysis import jaxpr as jx
+    from stencil_tpu.ops import stream as sm
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    names, r = ["u", "c", "p"], 3
+
+    def run():
+        dd, hs = _pass_wrap_domain(names, r, partition, None, extent)
+        request = sp.plan_stream(dd, r, "plane", False)
+        plan = sp.resolve_stream_plan(dd, _diagonal_r3_kernel, r, request, True)
+        step = sm._build_stream_step(dd, _diagonal_r3_kernel, r, plan, interpret=True)
+        closed = jax.make_jaxpr(step, static_argnums=1)(dd._curr, 2)
+        fields = []
+        for steps in (2, 3):
+            dd.run_step(step, steps)
+            fields.append([dd.quantity_to_host(h) for h in hs])
+        return plan, closed, fields
+
+    plan, closed, fields = run()
+    assert plan["plane_window"] == window, plan
+    assert plan["pass_wrap_axes"] == ("yz" if partition == (1, 1, 1) else "z"), plan
+    assert plan["renamed"] == ("p",) and plan["steps_per_trip"] == 2, plan
+    assert sm.stream_span_args(plan, r, len(names))["plane_window"] == window
+    art = analysis.ProgramArtifact(
+        label="test:plane-window", kind="step", closed=closed, plan=dict(plan.plan),
+        n_devices=int(np.prod(partition)),
+    )
+    for contract in ("inplace-order", "tiling-legal", "vmem-budget"):
+        found = analysis.check(art, contract=contract)
+        assert not found, "\n".join(f.render() for f in found)
+    monkeypatch.setattr(sp, "plane_window_form", lambda *a: "raw")
+    plan_raw, closed_raw, fields_raw = run()
+    assert plan_raw["plane_window"] == "raw"
+    same = fingerprint_text(closed) == fingerprint_text(closed_raw)
+    assert same == (window == "raw")
+    rings = {  # the planes the passes' rings hold
+        tuple(sc.shape[-2:])
+        for e in jx.iter_eqns(closed)
+        if e.primitive.name == "pallas_call" and "stream_plane_pass" in str(e.params.get("name"))
+        for sc in e.params["grid_mapping"].scratch_avals
+    }
+    raw = tuple(m + 2 * r for m in np.asarray(extent[1:]) // np.asarray(partition[1:]))
+    assert rings == ({tuple(extent[1:])} if window == "interior" else {raw}), rings
+    for a, b in zip(fields, fields_raw):
+        for name, x, y in zip(names, a, b):
+            assert np.isfinite(y).all() and np.array_equal(x, y), name

@@ -12,6 +12,7 @@ import os
 import types
 
 import jax
+import numpy as np
 import pytest
 
 from stencil_tpu import DistributedDomain, Radius
@@ -204,3 +205,156 @@ def test_the_builder_is_a_dispatch_and_writes_no_plan_key():
         and target.value.id.startswith("plan")
     ]
     assert not writes, writes
+
+
+# --- the plane pass's working plane (ISSUE 45) --------------------------------
+
+
+def _benchmark_plane_model(cell):
+    """The model of a benchmark plane cell at its REAL extent, nothing
+    allocated, and the stages of its step."""
+    if cell == "astaroth-mhd-256":
+        from stencil_tpu.models.astaroth_mhd import RADIUS, AstarothMHD
+
+        sim = AstarothMHD(256, 256, 256, devices=jax.devices()[:1], seed_words=None)
+        stages = lambda: tuple(sim._substep(s) for s in range(3))  # noqa: E731
+    elif cell == "elastic-so8-600":
+        from stencil_tpu.models.elastic import RADIUS, ElasticWave
+
+        sim = ElasticWave(600, 600, 600, devices=jax.devices()[:1], seed_words=None)
+        stages = lambda: (sim._stage_v, sim._stage_t)  # noqa: E731
+    else:
+        from stencil_tpu.models.acoustic import RADIUS, AcousticWave
+
+        extent, n_dev = ((1200, 1200, 600), 4) if cell.endswith("x4") else ((600, 600, 600), 1)
+        sim = AcousticWave(*extent, devices=jax.devices()[:n_dev], seed_words=None)
+        stages = lambda: sim._kernel  # noqa: E731
+    sim.dd.realize(allocate=False)
+    return sim, stages(), RADIUS
+
+
+@pytest.mark.parametrize("cell,wrapped,window", [
+    ("astaroth-mhd-256", "yz", "interior"),  # 256 = 32 x 8 sublanes = 2 x 128 lanes
+    ("acoustic-so8-600", "yz", "raw"),  # 600 = 4 x 128 + 88 lanes
+    ("elastic-so8-600", "yz", "raw"),
+    ("acoustic-so8-1200x4", "z", "raw"),  # mesh [2, 2, 1] splits y
+])
+def test_the_benchmarks_plane_cells_resolve_their_window(cell, wrapped, window, monkeypatch):
+    """``plan["plane_window"]`` of the four benchmark plane configurations at
+    their real extents, the blend kernels on as on the chip: from the fills
+    the plan resolved and the block's static shape alone -- no option, no
+    model's name -- and ``domain.step`` says it beside ``wrapped``."""
+    from stencil_tpu.ops import halo_blend
+
+    monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
+    sim, stages, r = _benchmark_plane_model(cell)
+    plan = sp.resolve_stream_plan(sim.dd, stages, r, sp.plan_stream(sim.dd, r, "plane", False), False)
+    assert (plan["pass_wrap_axes"], plan["plane_window"]) == (wrapped, window), plan.plan
+    raw, n = sim.dd.local_spec().raw_size(), sim.dd.local_spec().sz
+    shell = sim.dd._shell_radius
+    assert plan["plane_window"] == spass.plane_window_form(
+        plan.wrap_fills, shell.lo(), shell.hi(), (raw.y, raw.z),
+        [sim.dd.field_dtype(h) for h in sim.dd._handles],
+    )
+    said = sm.stream_span_args(plan, r, len(sim.dd._handles))
+    assert (said["wrapped"], said["plane_window"]) == (wrapped, window)
+    # the rings are priced at the plane they hold: the interior's where the
+    # window is the interior, the raw plane's elsewhere (the one VMEM model)
+    pad = sp._padded_plane_bytes
+    held = (n.y, n.z) if window == "interior" else (raw.y, raw.z)
+    for st in plan["stages"]:
+        for p in st["passes"]:
+            assert p["vmem_bytes"] == (
+                2 * (len(p["reads"]) + len(p["writes"])) * pad(raw.y, raw.z, 4)
+                + 2 * r * len(p["rings"]) * pad(*held, 4)
+                + sp._VMEM_STACK_MARGIN * len(p["reads"])
+            ), p
+    # a request that turns the schedule off the default one keeps the raw plane
+    split = dict(sp.plan_stream(sim.dd, r, "plane", False), overlap="split", overlap_forced=True)
+    if cell == "astaroth-mhd-256":
+        assert sp.resolve_stream_plan(sim.dd, stages, r, split, False)["plane_window"] == "raw"
+
+
+def _lag_kernel(views, info):
+    u, c = views["u"], views["c"]
+    new = 0.5 * u.center() + 0.25 * (u.sh(2, 0, 0) + u.sh(0, -2, 2)) + c.sh(0, 1, 0)
+    return {"u": new, "p": u.center()}
+
+
+@pytest.mark.parametrize("window", ["interior", "raw"])
+@pytest.mark.parametrize("storage", ["native", "bf16"])
+def test_the_vmem_model_is_what_the_pass_allocates(window, storage, monkeypatch):
+    """``plane_pass_vmem_bytes`` against the traced Pallas call of the pass, in
+    both forms and both storages: two tile-padded buffers a pipelined block
+    (every operand, every result), the scratch as allocated, the stack margin
+    a quantity read -- the pass allocates what the one model charges, ring
+    planes of the interior included."""
+    from stencil_tpu.analysis import jaxpr as jx
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    if window == "raw":
+        monkeypatch.setattr(sp, "plane_window_form", lambda *a: "raw")
+    dd = DistributedDomain(8, 16, 128)
+    dd.set_radius(Radius.constant(2))
+    dd.set_devices(jax.devices()[:1])
+    if storage != "native":
+        dd.set_storage(storage)
+    for name in ("u", "c", "p"):
+        dd.add_data(name)
+    dd.realize()
+    plan = sp.resolve_stream_plan(dd, _lag_kernel, 2, sp.plan_stream(dd, 2, "plane", False), True)
+    assert (plan["pass_wrap_axes"], plan["plane_window"]) == ("yz", window)
+    (p,) = plan["stages"][0]["passes"]
+    assert (p["reads"], p["rings"], p["writes"], p["renames"]) == (
+        ("u", "c", "p"), ("u",), ("u",), (("p", "u"),))
+    step = sm._build_stream_step(dd, _lag_kernel, 2, plan, interpret=True)
+    closed = jax.make_jaxpr(step, static_argnums=1)(dd._curr, 1)
+    (call,) = [
+        e for e in jx.iter_eqns(closed)
+        if e.primitive.name == "pallas_call" and "stream_plane_pass" in str(e.params.get("name"))
+    ]
+    gm = call.params["grid_mapping"]
+    pad = lambda shape, dtype: sp._padded_plane_bytes(  # noqa: E731
+        shape[-2], shape[-1], dtype.itemsize) * int(np.prod(shape[:-2]))
+    blocks = [bm for bm in gm.block_mappings if len(bm.block_shape) == 3]  # not ``origin``
+    assert len(blocks) == len(p["reads"]) + len(p["writes"])
+    allocated = sum(
+        2 * pad(tuple(int(getattr(b, "block_size", 1)) for b in bm.block_shape), bm.array_aval.dtype)
+        for bm in blocks
+    )
+    allocated += sum(pad(sc.shape, sc.dtype) for sc in gm.scratch_avals)
+    assert p["vmem_bytes"] == allocated + sp._VMEM_STACK_MARGIN * len(p["reads"])
+    (ring,) = gm.scratch_avals
+    assert ring.shape == ((4, 16, 128) if window == "interior" else (4, 20, 132))
+    assert ring.dtype == dd.field_dtype(dd._handles[0])  # the rings hold STORED planes
+
+
+def test_the_step_span_carries_the_plane_window(monkeypatch):
+    """``domain.step`` says ``plane_window`` on every dispatch of a plane
+    step, beside ``wrapped``; a step off the plane route does not say it."""
+    from stencil_tpu import telemetry
+    from stencil_tpu.telemetry import names as tm
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    seen, real = [], telemetry.span
+
+    def spy(name, *a, **kw):
+        seen.append((name, kw))
+        return real(name, *a, **kw)
+
+    monkeypatch.setattr(telemetry, "span", spy)
+    for extent, path, want in (((8, 16, 128), "plane", "interior"), ((8, 16, 96), "plane", "raw"),
+                               ((8, 16, 128), "wrap", None)):
+        dd = DistributedDomain(*extent)
+        dd.set_radius(Radius.constant(1))
+        dd.set_devices(jax.devices()[:1])
+        dd.add_data("u")
+        dd.add_data("v")
+        dd.realize()
+        step = dd.make_step(_mean2, engine="stream", stream_path=path, interpret=True)
+        del seen[:]
+        dd.run_step(step, 2)
+        dd.run_step(step, 2)
+        said = [kw for name, kw in seen if name == tm.SPAN_STEP]
+        assert len(said) == 2 and all(kw.get("plane_window") == want for kw in said), said
+        assert all((kw["wrapped"] == "yz") == (path == "plane") for kw in said), said

@@ -65,7 +65,7 @@ def main(argv=None) -> int:
     print(
         f"mesh: {mesh} route={plan.get('route')!r} stages={plan.get('stages')} "
         f"renamed={plan.get('renamed')} wrapped={plan.get('wrapped')!r} "
-        f"plane_window={plan.get('plane_window')!r} "
+        f"plane_window={plan.get('plane_window')!r} plane_strip={plan.get('plane_strip')} "
         f"read_sides={plan.get('read_sides')} exchanged_sides={plan.get('exchanged_sides')}",
         file=sys.stderr,
     )

@@ -389,7 +389,7 @@ def _build_plane_step(g, stages, x_radius, plan):
         """Stage ``k``'s passes in order, each over the quantities it
         touches; a later pass sees what an earlier one wrote."""
         out = list(bs)
-        for pass_kernel, reads, rings, writes, renames in plan.stage_runs[k]:
+        for pass_kernel, reads, rings, writes, renames, prerotated in plan.stage_runs[k]:
             grp = [index[name] for name in reads]
             with scope():
                 outs = stream_plane_pass(
@@ -399,6 +399,7 @@ def _build_plane_step(g, stages, x_radius, plan):
                     f32_accumulate=g.f32_acc, halo_readers=stage_readers[k],
                     writers=writes, rings=rings, wrap_fills=plan.wrap_fills,
                     renames=renames, window=plan["plane_window"],
+                    strip=plan["plane_strip"], prerotated=prerotated,
                 )
             for q, o in zip(grp, outs):
                 out[q] = o
@@ -963,6 +964,10 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         # tiles (every in-plane shift one rotate, its wraparound the halo),
         # the "raw" shell-carrying plane elsewhere (plane_window_form)
         args["plane_window"] = plan["plane_window"]
+        # ... and the rows of it their kernel is evaluated over at a time: a
+        # y shift is then the address of a tile and a value a few vregs; 0 =
+        # over the plane whole (plane_strip_rows, plan_plane_stages)
+        args["plane_strip"] = plan["plane_strip"]
     if "z_halo_patch" in plan:
         # the z-slab wavefront: whether the pass patches its z halo in
         # the lane tiles that hold it or over the whole plane

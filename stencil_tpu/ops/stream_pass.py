@@ -121,6 +121,36 @@ class PlaneView:
         return self._window[self._r]
 
 
+class StripView(PlaneView):
+    """A ``PlaneView`` over ONE STRIP of the working plane (the plane pass's
+    strip form, ``plane_strip_rows``): ``read(dx, dy, dz)`` hands the ``(S, Z)``
+    strip of window plane ``dx`` shifted by ``(dy, dz)`` -- in the pass a read
+    of the plane's tiles ``dy`` further on (an address, no rotate) and a lane
+    rotate for ``dz``, made in the loop or read from a plane rotated once for
+    all its readers; in the footprint trace an input of its own -- or ``None``
+    for a plane the pass holds no ring for."""
+
+    def __init__(self, read, x_radius: int, off_centre=None, no_ring=None):
+        self._read = read
+        self._r = x_radius
+        self._off_centre = off_centre
+        self._no_ring = no_ring
+
+    def sh(self, dx: int = 0, dy: int = 0, dz: int = 0) -> jax.Array:
+        assert all(-self._r <= d <= self._r for d in (dx, dy, dz)), (
+            (dx, dy, dz), self._r,
+        )
+        if self._off_centre is not None and (dx or dy or dz):
+            self._off_centre(dx, dy, dz)
+        v = self._read(dx, dy, dz)
+        if v is None:
+            self._no_ring()
+        return v
+
+    def center(self) -> jax.Array:
+        return self._read(0, 0, 0)
+
+
 @dataclasses.dataclass
 class PlaneInfo:
     """Traced per-plane context handed to streaming kernels.  ``coords``
@@ -148,6 +178,53 @@ def lane_pad_width(z: int) -> int:
     return -(-z // 128) * 128
 
 
+class _ReadAtUse:
+    """``planes[q]`` reads the ``(rows, lanes)`` corner of block ``q`` where it
+    is asked for."""
+
+    def __init__(self, refs, rows: int, lanes: int):
+        self._refs, self._rows, self._lanes = refs, rows, lanes
+
+    def __getitem__(self, q):
+        return self._refs[q][0, : self._rows, : self._lanes]
+
+
+def sublane_tile(dtypes) -> int:
+    """Rows of a vector tile of the narrowest of ``dtypes``: 8 of f32, 16 of a
+    2-byte dtype."""
+    return max(max(8, 32 // jnp.dtype(d).itemsize) for d in dtypes)
+
+
+#: vregs of f32 a value of the strip form's kernel is cut to (``plane_strip_
+#: rows``)
+_STRIP_VREGS = 4
+
+
+def plane_strip_rows(window: str, plane: Tuple[int, int], dtypes, x_radius: int) -> int:
+    """The rows ``S`` of a strip of ``stream_plane_pass``'s strip form over the
+    ``plane = (Yw, Zw)`` working plane, 0 where the pass evaluates its kernel
+    over the plane whole: read off the window, the plane and the read distance
+    alone.  The strip form exists on the ``"interior"`` window only (there the
+    wraparound is the halo on both in-plane axes and the plane is whole tiles)
+    and where the plane holds ``x_radius`` or more tiles of rows (a y shift
+    then wraps around the tiles once at most).  A strip is ``G`` of the
+    plane's ``K = Yw / T`` tiles (``T`` the sublane tile of the stored
+    dtypes), ``S = G T`` rows: ``G`` divides ``K`` and makes a value of the
+    kernel ``_STRIP_VREGS`` vregs (``G x Zw / 128``) or, on a plane too wide
+    for that, one tile.  ``domain.step`` says it as ``plane_strip``."""
+    if window != "interior":
+        return 0
+    yw, zw = plane
+    tile = sublane_tile(dtypes)
+    tiles = yw // tile
+    if tiles < x_radius:
+        return 0
+    group = max(1, min(tiles, _STRIP_VREGS * 128 // zw))
+    while tiles % group:
+        group -= 1
+    return group * tile
+
+
 def plane_window_form(wrap_fills, lo: Dim3, hi: Dim3, plane: Tuple[int, int], dtypes) -> str:
     """The working plane of ``stream_plane_pass`` over ``plane = (Y, Z)`` raw
     planes stored as ``dtypes``, read off what the pass is told and the static
@@ -162,8 +239,7 @@ def plane_window_form(wrap_fills, lo: Dim3, hi: Dim3, plane: Tuple[int, int], dt
         (1, 0, yi, lo.y), (1, lo.y + yi, lo.y, hi.y),
         (2, 0, zi, lo.z), (2, lo.z + zi, lo.z, hi.z),
     )
-    sublanes = max(max(8, 32 // jnp.dtype(d).itemsize) for d in dtypes)
-    whole = yi % sublanes == 0 and zi % 128 == 0
+    whole = yi % sublane_tile(dtypes) == 0 and zi % 128 == 0
     # ... and no narrower than the shell it stands in for (the rows and lanes
     # past the window repeat its first ``lo + hi``)
     whole = whole and yi >= lo.y + hi.y > 0 and zi >= lo.z + hi.z > 0
@@ -181,10 +257,11 @@ def _wrap_fill(ref, fills):
             ref[0, :, dst : dst + w] = ref[0, :, src : src + w]
 
 
-def _yz_coord_planes(origin_ref, Yr, Zr, off_y, off_z, gsize):
+def _yz_coord_planes(origin_ref, Yr, Zr, off_y, off_z, gsize, rows=None):
     """Wrapped global y/z coordinates of the raw plane, as a (Yr, 1) column
-    and a (1, Zr) row (2D iotas — Mosaic has no 1D iota)."""
-    y = lax.broadcasted_iota(jnp.int32, (Yr, 1), 0)
+    and a (1, Zr) row (2D iotas — Mosaic has no 1D iota); with ``rows`` (a
+    (Yr, 1) int32 column) of those rows of it, for a strip."""
+    y = lax.broadcasted_iota(jnp.int32, (Yr, 1), 0) if rows is None else rows
     z = lax.broadcasted_iota(jnp.int32, (1, Zr), 1)
     gy, gz = jnp.int32(gsize.y), jnp.int32(gsize.z)
     # + gsize keeps lax.rem's operand non-negative (origin - shell >= -shell)
@@ -266,6 +343,11 @@ def stream_plane_pass(
     window: str = "raw",  # the working plane (plane_window_form): the "raw"
     # plane, or the block's "interior" (rotated onto the block's aligned
     # corner) where the fills are its own self-wrap
+    strip: int = 0,  # rows of a strip the kernel is evaluated over at a time
+    # (plane_strip_rows; the interior window only); 0 = over the plane whole
+    prerotated: Sequence[Tuple[str, int, int]] = (),  # the strip form: the
+    # ``(quantity, dx, dz)`` whose plane is rotated by ``dz`` lanes ONCE a grid
+    # step, for every strip and every ``dy`` that reads it (shared_rotations)
 ) -> List[jax.Array]:
     """ONE kernel level over shell-carrying blocks, streaming x-planes with a
     ``2r``-deep ring per quantity read off-centre along x; shell planes and
@@ -355,6 +437,43 @@ def stream_plane_pass(
     above.  (Cutting the interior itself out of the block, at ``[lo.y:,
     lo.z:]``, costs a sublane and a lane shift of every vreg of every plane
     in and out: 2.4 ms of a 9.6 ms MHD pass, PERF.md PR 45.)
+
+    With ``strip = S > 0`` (``plane_strip_rows``: the interior window only)
+    the kernel is not evaluated over the working plane whole but a STRIP of it
+    at a time: inside a grid step a loop runs over the plane's strips,
+    ``kernel(views, info)`` is traced ONCE, over ``StripView``s, and a value of
+    the kernel is ``S / 8 x Zw / 128`` vregs and not the whole register file
+    (64 at 256 x 256), so a chain of operations stays in registers.  The
+    strips are cut so that a y shift is an ADDRESS.  The pass holds every plane
+    as TILES, ``(K, T, Zw)`` with ``T`` the rows of a sublane tile and ``K = Yw
+    / T``: tile ``k`` holds row ``s K + k`` of the working plane at sublane
+    ``s`` (``to_tiles``: one transposition of the tiles' two leading
+    dimensions a plane, as it is pushed), so the plane's next row is the next
+    TILE's same sublane.  A strip is ``G = S / T`` consecutive tiles; ``sh(dx,
+    dy, dz)`` reads tiles ``k + dy .. + G`` of window plane ``dx`` -- whole
+    aligned tiles at a traced index of a leading dimension: no sublane rotate,
+    no select -- and makes the one lane rotate for ``dz``, whose wraparound
+    over the ``Zw`` whole lanes is the z halo exactly as on the whole plane.
+    The y wraparound comes from ``r`` MARGIN tiles a side: tile ``K + m`` is
+    tile ``m`` one sublane up (row ``s K + K + m`` is row ``(s + 1) K + m``,
+    and the last sublane wraps onto row ``m``: the periodic neighbour), tile
+    ``-m`` tile ``K - m`` one sublane down -- ``2r`` one-tile sublane rotates a
+    plane.  Every ringed quantity holds ``2r + 1`` such planes and is pushed
+    BEFORE the strips run (they read the newest plane there too); every other
+    quantity holds its centre plane, put in before the strips.  The kernel's
+    values are gathered as tiles in a staging plane a writer and go to the
+    output block through ``put`` once a plane, transposed back.
+    ``info.coords()`` hands the strip's own ``y_global`` rows.  ``prerotated``
+    names the ``(quantity, dx, dz)`` whose plane is rotated by ``dz`` lanes
+    ONCE a grid step, tiles and margins, into a plane of its own
+    (``stream_plan.shared_rotations``: those that two or more ``dy`` read): a
+    read of it at any ``dy`` is then tiles of that plane, where the loop would
+    rotate the strip of each ``dy`` anew -- the lane rotates bound the loop
+    (PERF.md PR 46).  The grid, the block maps, the aliases, the renames, the
+    lagged fetches, the rings' x logic and the x-shell pass-through are the
+    whole-plane form's, and so is every value: the same operations in the same
+    order on every cell, bitwise.  With ``strip = 0`` the pass is, operation
+    for operation, the one above.
 
     Returns one array per quantity, but only the ``writers`` are OUTPUTS of
     the Pallas call: every quantity is an input with its ring and its view,
@@ -463,6 +582,20 @@ def stream_plane_pass(
     # the working plane: what the rings hold and the kernel's windows are
     Yw, Zw = (y1 - y0, z1 - z0) if interior else (Y, Z)
     low_fills = [f for f in wrap_fills if f[1] == 0]  # the fills of the LOW halos
+    # the strip form: every quantity's planes sit in the scratch as ``K + 2r``
+    # TILES of ``T`` rows -- tile ``k`` holds rows ``k, K + k, 2K + k, ...`` of
+    # the working plane, one a sublane, between ``r`` margin tiles a side --,
+    # a ringed quantity ``2r + 1`` planes deep (the newest is pushed BEFORE
+    # the strips read it), every other one plane; and a writer's strips are
+    # gathered in a staging plane of tiles before they go out
+    T = sublane_tile([b.dtype for b in raws])
+    K, G = Yw // T, strip // T
+    assert not strip or (interior and strip == G * T and K % G == 0 and r <= K), (
+        strip, window, Yw, T)
+    depth = 2 * r + 1 if strip else 2 * r
+    held = list(range(nq)) if strip else ringed
+    pre = [(names.index(nm), dx, dz) for nm, dx, dz in prerotated] if strip else []
+    assert all(dz and (q in ringed or not dx) for q, dx, dz in pre), (prerotated, rings)
 
     def no_ring(name):
         def fail():
@@ -497,7 +630,10 @@ def stream_plane_pass(
             zs_refs = refs[3 * nq : 4 * nq]
             refs = refs[:nq] + refs[4 * nq :]
         out_refs = dict(zip(wq, refs[nq : nq + len(wq)]))  # writers only
-        ring_refs = dict(zip(ringed, refs[nq + len(wq) :]))  # x readers only
+        ring_refs = dict(zip(held, refs[nq + len(wq) :]))  # x readers only (the
+        # strip form: everyone, as tiles)
+        stage_refs = dict(zip(wq, refs[nq + len(wq) + len(held) :]))  # the strip form
+        pre_refs = dict(zip(pre, refs[nq + 2 * len(wq) + len(held) :]))
         i = pl.program_id(0)
         if interior:
             # every quantity, read off-centre or not: with its LOW halos
@@ -505,7 +641,11 @@ def stream_plane_pass(
             # rotated by (lo.y, lo.z) -- whole tiles, nothing shifted
             for ref in in_refs:
                 _wrap_fill(ref, low_fills)  # y before z
-            curs = [ref[0, :Yw, :Zw] for ref in in_refs]
+            # (the strip form reads a plane where it is used: loaded here it
+            # would be held, spilled, across every region of the body below)
+            curs = _ReadAtUse(in_refs, Yw, Zw)
+            if not strip:
+                curs = [curs[q] for q in range(nq)]
         else:
             for q in wrapped:
                 _wrap_fill(in_refs[q], wrap_fills)  # y before z
@@ -544,10 +684,74 @@ def stream_plane_pass(
         j = i - r
         in_window = jnp.logical_and(j >= lo.x, j <= X - hi.x - 1)
 
+        def to_tiles(v):
+            """The working plane ``(Yw, Zw)`` as its ``K`` tiles ``(K, T, Zw)``:
+            tile ``k`` holds row ``s K + k`` at sublane ``s``, so the plane's
+            next row is the NEXT TILE's same sublane -- a y shift of a tile is
+            another tile, an address and no rotate."""
+            return jnp.swapaxes(v.reshape(T, K, Zw), 0, 1)
+
+        def from_tiles(d):
+            return jnp.swapaxes(d, 0, 1).reshape(Yw, Zw)
+
         def plane(q, t):  # raw plane i - t for quantity q (t in [0, 2r])
-            if q not in ring_refs:  # fetched lagged: the centre plane alone
+            if q not in ringed:  # fetched lagged: the centre plane alone
                 return curs[q] if t == r else None
+            if strip:  # the tiles between the margins
+                return curs[q] if t == 0 else from_tiles(ring_refs[q][(i - t) % depth, r : r + K])
             return curs[q] if t == 0 else ring_refs[q][(i - t) % (2 * r)]
+
+        def push_tiles(q, slot, margins):
+            """The working plane of ``q`` into ``ring[slot]`` as tiles; with
+            ``margins`` between the ``r`` tiles a side that continue it past its
+            ends: tile ``K + m`` is tile ``m`` a sublane UP (row ``s K + K + m``
+            is row ``(s + 1) K + m``; the last sublane wraps to row ``m`` of the
+            plane: the y wraparound) and tile ``-m`` tile ``K - m`` a sublane
+            down."""
+            ring_refs[q][slot, r : r + K] = to_tiles(curs[q])
+            if margins:
+                wrap_margins(ring_refs[q], (slot,))
+
+        def wrap_margins(ref, at):
+            """The ``r`` margin tiles a side of the plane of tiles ``ref[at]``,
+            from its own tiles (read back: the plane is not held live)."""
+            low, high = ref[(*at, slice(K, K + r))], ref[(*at, slice(r, 2 * r))]
+            ref[(*at, slice(0, r))] = roll(low, 1, 1).astype(ref.dtype)
+            ref[(*at, slice(r + K, K + 2 * r))] = roll(high, -1, 1).astype(ref.dtype)
+
+        if strip and ringed:  # FIRST: the strips read the newest plane there too
+
+            @pl.when(i <= X - 1)  # (skip replayed last-plane refetches)
+            def _():
+                newest = i % depth
+                for q in ringed:
+                    push_tiles(q, newest, True)
+
+        def strip_reader(q, slots, first):
+            """``read(dx, dy, dz)`` of quantity ``q``'s ``StripView`` for one
+            strip: ``slots[dx]`` the ring slot of window plane ``dx``,
+            ``first[dy]`` the first of the strip's ``G`` tiles ``dy`` on (the y
+            shift is an address) -- traced scalars, made once for all reads."""
+
+            def tiles(ref, at, dy):
+                if G == 1:
+                    return up(ref[(*at, first[dy])])
+                return up(ref[(*at, pl.ds(first[dy], G))].reshape(strip, Zw))
+
+            unrotated = {}  # (dx, dy) -> the strip as read, for every dz of it
+
+            def read(dx, dy, dz):
+                if q not in ringed and dx:
+                    return None
+                if (q, dx, dz) in pre_refs:  # rotated once, for every strip and dy
+                    return tiles(pre_refs[q, dx, dz], (), dy)
+                if (dx, dy) not in unrotated:
+                    at = (slots[dx] if q in ringed else 0,)
+                    unrotated[dx, dy] = tiles(ring_refs[q], at, dy)
+                v = unrotated[dx, dy]
+                return roll(v, -dz, 1) if dz else v
+
+            return read
 
         def window(q):
             return tuple(
@@ -555,30 +759,88 @@ def stream_plane_pass(
                 for d in range(2 * r + 1)
             )
 
+        def unknown_output(vals):
+            for q, name in enumerate(names):
+                if name in vals and q not in out_refs and q not in home.values():
+                    raise ValueError(
+                        f"the kernel returns {name!r}, but its footprint "
+                        f"trace did not (it saw {tuple(writers)}), so "
+                        f"{name!r} is not an output of the pass: a kernel "
+                        "must return the same names every time it is traced"
+                    )
+
+        def x_global():
+            return lax.rem(
+                origin_ref[0] + jnp.int32(gsize.x) + j - jnp.int32(lo.x),
+                jnp.int32(gsize.x),
+            )
+
+        def strips():
+            """The window's output plane a strip at a time: the kernel is traced
+            ONCE, over ``StripView``s, inside a loop over the ``K / G`` strips,
+            its values are gathered as tiles and go to the output block whole."""
+
+            for q in held:
+                if q not in ringed:  # fetched lagged: the centre plane
+                    push_tiles(q, 0, halo_readers is None or names[q] in halo_readers)
+
+            # the planes of ``prerotated``, for all their readers: every tile of
+            # a plane in one rotate, its margins as push_tiles makes them
+            for (q, dx, dz), rotated in pre_refs.items():
+                slot = (j + dx) % depth if q in ringed else 0
+                tiles = roll(up(ring_refs[q][slot, r : r + K]), -dz, 2)
+                rotated[r : r + K] = tiles.astype(rotated.dtype)
+                wrap_margins(rotated, ())
+            x_g = x_global()
+            # row ``s K + g`` of the plane at row ``g T + s`` of a strip
+            f = lax.broadcasted_iota(jnp.int32, (strip, 1), 0)
+            rows0 = (f % T) * K + f // T
+
+            def one(k, carry):
+                k0 = k * G
+                first = {dy: k0 + (r + dy) for dy in range(-r, r + 1)}
+                # (made INSIDE the loop: carried in from outside, the seven
+                # scalars cost the MHD loop 10% -- PERF.md PR 46, call 7)
+                slots = {dx: (j + dx) % depth for dx in range(-r, r + 1)}
+                views = {
+                    names[q]: StripView(
+                        strip_reader(q, slots, first), r, stale_read(names[q]),
+                        no_ring(names[q]),
+                    )
+                    for q in range(nq)
+                }
+                y_s, _ = _yz_coord_planes(origin_ref, strip, Zw, lo.y, lo.z, gsize, rows0 + k0)
+                vals = kernel(views, PlaneInfo(x_g, y_s, z_g, gsize, 1))
+                unknown_output(vals)
+                for q, stage in stage_refs.items():
+                    v = vals[names[q]] if names[q] in vals else views[names[q]].center()
+                    v = v.astype(stage.dtype)
+                    if G == 1:
+                        stage[k0] = v
+                    else:
+                        stage[pl.ds(k0, G)] = v.reshape(G, T, Zw)
+                return carry
+
+            lax.fori_loop(0, K // G, one, 0)
+            for q, out in out_refs.items():
+                put(out, from_tiles(stage_refs[q][...]))
+
         @pl.when(jnp.logical_and(i >= 1, i <= X + r - 1))
         def _():
             @pl.when(in_window)
             def _():
+                if strip:
+                    strips()
+                    return
                 views = {
                     names[q]: PlaneView(
                         window(q), roll, stale_read(names[q]), no_ring(names[q])
                     )
                     for q in range(nq)
                 }
-                x_g = lax.rem(
-                    origin_ref[0] + jnp.int32(gsize.x) + j - jnp.int32(lo.x),
-                    jnp.int32(gsize.x),
-                )
-                info = PlaneInfo(x_g, y_g, z_g, gsize, 1)
+                info = PlaneInfo(x_global(), y_g, z_g, gsize, 1)
                 vals = kernel(views, info)
-                for q, name in enumerate(names):
-                    if name in vals and q not in out_refs and q not in home.values():
-                        raise ValueError(
-                            f"the kernel returns {name!r}, but its footprint "
-                            f"trace did not (it saw {tuple(writers)}), so "
-                            f"{name!r} is not an output of the pass: a kernel "
-                            "must return the same names every time it is traced"
-                        )
+                unknown_output(vals)
                 for q, out in out_refs.items():
                     cent = plane(q, r)
                     if interior:  # the kernel's values are the plane, whole
@@ -605,7 +867,7 @@ def stream_plane_pass(
                 put(out, curs[q])  # first plane passes through
 
         # push the fetched plane (skip replayed last-plane refetches)
-        if ring_refs:
+        if ring_refs and not strip:
 
             @pl.when(i <= X - 1)
             def _():
@@ -675,6 +937,11 @@ def stream_plane_pass(
         ),
         scratch_shapes=[
             pltpu.VMEM((2 * r, Yw, Zw), raws[q].dtype) for q in ringed
+        ] if not strip else [
+            pltpu.VMEM((depth if q in ringed else 1, K + 2 * r, T, Zw), raws[q].dtype)
+            for q in held
+        ] + [pltpu.VMEM((K, T, Zw), raws[q].dtype) for q in wq] + [
+            pltpu.VMEM((K + 2 * r, T, Zw), raws[q].dtype) for q, _, _ in pre
         ],
         interpret=interpret,
         **_tpu_compiler_params(interpret),

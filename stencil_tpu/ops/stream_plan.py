@@ -20,7 +20,9 @@ Routes (``plan_stream`` chooses; ``ops/stream_pass.py`` has the passes):
   (``pass_wrap_fills``), and the exchange sweeps the other axes; where it
   fills BOTH and the interior is whole vector tiles the pass works on the
   interior plane alone and the rotates' wraparound is the halo
-  (``plane_window``, ``stream_pass.plane_window_form``).  A step may
+  (``plane_window``, ``stream_pass.plane_window_form``), a heavy kernel
+  evaluated a strip at a time (``plane_strip``, ``stream_pass.plane_strip_
+  rows``, ``_STRIP_MIN_OPS``).  A step may
   be several STAGES (a sequence of kernels, each behind its own exchange)
   and a stage several PASSES, each over the quantities its outputs touch:
   all planned from one abstract trace of each kernel (``plan_plane_stages``).
@@ -72,8 +74,11 @@ from stencil_tpu.ops.stream_pass import (
     PlaneInfo,
     PlaneKernel,
     PlaneView,
+    StripView,
     lane_pad_width,
+    plane_strip_rows,
     plane_window_form,
+    sublane_tile,
 )
 from stencil_tpu.parallel.mesh import MESH_AXES
 
@@ -419,43 +424,56 @@ class PlaneTrace:
     # centre plane of ``q``, a writer with a value of its own (``_plane_renames``)
     offsets: Tuple[Tuple[str, tuple], ...] = ()  # per reader, the ``(dx, dy, dz)``
     # it is read at off-centre (``footprint_counts``)
+    strip: int = 0  # rows of the strip the kernel was traced over (``stream_
+    # pass.plane_strip_rows``): the jaxpr then takes ``(2r + 1)^3`` strips a
+    # quantity, one for every ``(dx, dy, dz)``, and holds no shift at all; 0 =
+    # ``2r + 1`` whole planes, the in-plane shifts rotates inside it
+
+    def plane_offsets(self) -> Tuple[Tuple[int, int, int], ...]:
+        """The ``(dx, dy, dz)`` of each of a quantity's inputs of the jaxpr, in
+        order."""
+        span = range(-self.x_radius, self.x_radius + 1)
+        plane = span if self.strip else (0,)
+        return tuple((dx, dy, dz) for dx in span for dy in plane for dz in plane)
 
     def pruned(self, outputs: Sequence[str]):
-        """``(kernel, reads, rings)`` of the pass that writes ``outputs``:
-        the kernel with everything those outputs do not need cut away
-        (``dce_jaxpr``), the quantities it still reads (the outputs
-        themselves included: the pass carries their shell through), and the
-        ones among them it reads at ``dx != 0``.  No second trace of the
+        """``(kernel, reads, rings, offsets)`` of the pass that writes
+        ``outputs``: the kernel with everything those outputs do not need cut
+        away (``dce_jaxpr``), the quantities it still reads (the outputs
+        themselves included: the pass carries their shell through), the ones
+        among them it reads at ``dx != 0``, and the ``(quantity, (dx, dy,
+        dz))`` inputs of the jaxpr it still takes.  No second trace of the
         user's callable is made: what the footprint saw IS what runs."""
         if self.closed is None:  # fail closed: the whole kernel, every ring
-            return self.kernel, self.names, self.names
+            return self.kernel, self.names, self.names, ()
         from jax.extend import core as jex
         from jax.interpreters import partial_eval as pe
 
-        r, w = self.x_radius, 2 * self.x_radius + 1
+        offsets = self.plane_offsets()
         kept = [nm for nm in self.writers if nm in outputs]
         jaxpr, used = pe.dce_jaxpr(
             self.closed.jaxpr, [nm in outputs for nm in self.writers], instantiate=False
         )
         run = jex.jaxpr_as_fun(jex.ClosedJaxpr(jaxpr, self.closed.consts))
         planes = [
-            (nm, d)
+            (nm, off)
             for q, nm in enumerate(self.names)
-            for d in range(w)
-            if used[3 + q * w + d]
+            for k, off in enumerate(offsets)
+            if used[3 + q * len(offsets) + k]
         ]
 
         def kernel(views, info):
             args = [c for c, u in zip(info.coords(), used[:3]) if u]
-            args += [views[nm].sh(d - r, 0, 0) for nm, d in planes]
+            args += [views[nm].sh(*off) for nm, off in planes]
             return dict(zip(kept, run(*args)))
 
         touched = {nm for nm, _ in planes} | set(kept)
-        ringed = {nm for nm, d in planes if d != r}
+        ringed = {nm for nm, off in planes if off[0]}
         return (
             kernel,
             tuple(nm for nm in self.names if nm in touched),
             tuple(nm for nm in self.names if nm in ringed),
+            tuple(planes),
         )
 
 
@@ -468,6 +486,8 @@ def trace_plane_kernel(
     interpret: bool = True,
     storage: Optional[Sequence] = None,  # per quantity, the dtype its block is
     # STORED in (the rename rule compares them); None = the planes' own
+    strip: int = 0,  # the pass's strip form (``plane_strip_rows``): ``planes``
+    # are then the ``(S, Z)`` strips the kernel is evaluated over
 ) -> PlaneTrace:
     """The footprint of a PLANE-route kernel: trace it ONCE, abstractly
     (``jax.make_jaxpr``, nothing runs), over ``PlaneView``s that record
@@ -478,6 +498,10 @@ def trace_plane_kernel(
     (``PlaneTrace.pruned``).  A function of the kernel, as ``_sweep_kind``
     is a function of the mesh: no option, no plan value a user sets.
     ``interpret`` picks the rotate the passes will lower (``_make_roll``).
+    With ``strip`` the kernel is traced as the pass's strip form will run it,
+    over ``StripView``s: every ``(dx, dy, dz)`` a quantity is read at is an
+    input of the jaxpr of its own and the jaxpr holds no shift -- the pass
+    makes each where and as often as it chooses (``shared_rotations``).
 
     Why the others keep a stale shell and the result is the same.  The plane
     pass is ONE level and writes interior cells only (shell planes and the
@@ -541,17 +565,22 @@ def trace_plane_kernel(
     roll = _make_roll(interpret)
     r, w = x_radius, 2 * x_radius + 1
     Y, Z = planes[0].shape
+    per = w**3 if strip else w  # inputs a quantity (``PlaneTrace.plane_offsets``)
 
     def note(nm, dx, dy, dz):
         seen.setdefault(nm, set()).add((dx, dy, dz))
 
+    def view(nm, mine):
+        if not strip:
+            return PlaneView(tuple(mine), roll, partial(note, nm))
+        return StripView(
+            lambda dx, dy, dz: mine[((r + dx) * w + r + dy) * w + r + dz], r, partial(note, nm)
+        )
+
     def footprint(x_g, y_g, z_g, *vs):
         info = PlaneInfo(x_g, y_g, z_g, global_size, 1)
         vals = kernel(
-            {
-                nm: PlaneView(tuple(vs[q * w : (q + 1) * w]), roll, partial(note, nm))
-                for q, nm in enumerate(names)
-            },
+            {nm: view(nm, vs[q * per : (q + 1) * per]) for q, nm in enumerate(names)},
             info,
         )
         returned[:] = [nm for nm in names if nm in vals]
@@ -560,7 +589,7 @@ def trace_plane_kernel(
     i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
     try:
         closed = jax.make_jaxpr(footprint)(
-            i32(()), i32((Y, 1)), i32((1, Z)), *[p for p in planes for _ in range(w)]
+            i32(()), i32((Y, 1)), i32((1, Z)), *[p for p in planes for _ in range(per)]
         )
     except Exception as exc:  # noqa: BLE001 — whatever the user's kernel raises
         from stencil_tpu.utils.logging import log_warn
@@ -569,7 +598,7 @@ def trace_plane_kernel(
             f"the stream kernel's footprint trace raised ({exc!r}); "
             "exchanging and writing every quantity"
         )
-        return PlaneTrace(names, names, names, r, None, kernel)
+        return PlaneTrace(names, names, names, r, None, kernel, strip=strip)
     if storage is None:
         storage = [p.dtype for p in planes]
     stored = {nm: (jnp.dtype(d), p.shape) for nm, d, p in zip(names, storage, planes)}
@@ -580,8 +609,9 @@ def trace_plane_kernel(
         r,
         closed,
         kernel,
-        _plane_renames(closed.jaxpr, names, tuple(returned), r, stored),
+        _plane_renames(closed.jaxpr, names, tuple(returned), per, stored),
         tuple((nm, tuple(sorted(seen[nm]))) for nm in names if nm in seen),
+        strip,
     )
 
 
@@ -612,14 +642,15 @@ def footprint_counts(traces: Sequence[PlaneTrace]) -> Optional[dict]:
     }
 
 
-def _plane_renames(jaxpr, names, writers, x_radius: int, stored: dict):
+def _plane_renames(jaxpr, names, writers, per: int, stored: dict):
     """The ``(p, q)`` of ``trace_plane_kernel``'s rename rule, read off the
-    kernel's jaxpr (invars: three coordinates, then ``2r + 1`` window planes a
-    quantity; outvars: the writers in order): output ``p`` is ``q``'s centre
-    invar itself, ``q`` is a writer whose own output is no quantity's centre
-    plane, ``stored`` (dtype, plane shape) agree, and ``q`` is claimed once."""
-    w = 2 * x_radius + 1
-    centres = [(nm, jaxpr.invars[3 + q * w + x_radius]) for q, nm in enumerate(names)]
+    kernel's jaxpr (invars: three coordinates, then ``per`` inputs a quantity
+    -- its ``2r + 1`` window planes, or in the strip form a strip for every
+    ``(dx, dy, dz)`` --, the centre in the middle; outvars: the writers in order):
+    output ``p`` is ``q``'s centre invar itself, ``q`` is a writer whose own
+    output is no quantity's centre plane, ``stored`` (dtype, plane shape)
+    agree, and ``q`` is claimed once."""
+    centres = [(nm, jaxpr.invars[3 + q * per + per // 2]) for q, nm in enumerate(names)]
     pure = {}  # output -> the quantity whose centre plane it is, unchanged
     for p, var in zip(writers, jaxpr.outvars):
         q = next((nm for nm, centre in centres if centre is var), None)
@@ -634,9 +665,34 @@ def _plane_renames(jaxpr, names, writers, x_radius: int, stored: dict):
     return tuple(pairs)
 
 
+#: operations a cell (equations of the kernel's strip-form jaxpr) from which a
+#: step's passes take the strip form: Devito's acoustic update (52) runs 3-10%
+#: SLOWER in it on a periodic 256^3 box, Astaroth's MHD substep (823 / 847)
+#: faster (PERF.md PR 46)
+_STRIP_MIN_OPS = 512
+
+
+def shared_rotations(offsets) -> Tuple[Tuple[str, int, int], ...]:
+    """The ``(quantity, dx, dz)`` of a strip-form pass whose plane is worth
+    rotating ONCE a grid step (``stream_plane_pass(prerotated=)``): those among
+    the ``(quantity, (dx, dy, dz))`` reads ``offsets`` (``PlaneTrace.pruned``)
+    that two or more ``dy`` share -- a strip's y shift is an address, so every
+    one of them then reads the ONE rotated plane where the loop would rotate
+    each strip of each ``dy`` anew.  (Astaroth's MHD step: the y-z mixed
+    differences read ``(0, +-k, +-k)`` beside the ``(0, 0, +-k)`` of the
+    differences along z: 24 planes of four fields, a third of its 144 lane
+    rotates a cell.)"""
+    rows = {}
+    for nm, (dx, dy, dz) in offsets:
+        if dz:
+            rows.setdefault((nm, dx, dz), set()).add(dy)
+    return tuple(key for key, dys in rows.items() if len(dys) > 1)
+
+
 def plane_pass_vmem_bytes(
     plane_bytes: Dict[str, int], x_radius: int, reads, rings, writes,
     ring_bytes: Optional[Dict[str, int]] = None,
+    stage_bytes: Optional[Dict[str, int]] = None, prerotated=(),
 ) -> int:
     """VMEM model of one plane pass, ``stream_vmem_fits``' accounting cut to
     what the pass holds: two pipeline planes per quantity read, two more per
@@ -646,21 +702,36 @@ def plane_pass_vmem_bytes(
     quantity at its STORAGE itemsize -- what the pipeline moves -- and
     ``ring_bytes`` the plane its ring holds, where that is another: the
     block's interior on the interior window (``plane_window_form``; the
-    rings hold storage-dtype working planes).  None = the raw plane."""
+    rings hold storage-dtype working planes).  None = the raw plane.
+    ``stage_bytes`` says the pass runs its strip form (``stream_pass.plane_
+    strip_rows``): ``ring_bytes`` is then the working plane as tiles between
+    its margin tiles, a ring holds ``2r + 1`` of them (the newest plane is
+    pushed before the strips read it), every other quantity read holds one,
+    every quantity written a staging plane of ``stage_bytes``, and every
+    ``(quantity, dx, dz)`` of ``prerotated`` (``shared_rotations``) one more
+    plane of ``ring_bytes``.  None = the kernel runs over the plane whole."""
     ring_bytes = plane_bytes if ring_bytes is None else ring_bytes
     est = sum(2 * plane_bytes[q] for q in reads)
     est += sum(2 * plane_bytes[q] for q in writes)
-    est += sum(2 * x_radius * ring_bytes[q] for q in rings)
+    if stage_bytes is None:
+        est += sum(2 * x_radius * ring_bytes[q] for q in rings)
+    else:
+        est += sum((2 * x_radius + 1 if q in rings else 1) * ring_bytes[q] for q in reads)
+        est += sum(stage_bytes[q] for q in writes)
+        est += sum(ring_bytes[q] for q, _, _ in prerotated)
     return est + _VMEM_STACK_MARGIN * len(reads)
 
 
 def plan_plane_passes(
     trace: PlaneTrace, plane_bytes: Dict[str, int], whole: bool = False,
     rename: bool = False, ring_bytes: Optional[Dict[str, int]] = None,
+    stage_bytes: Optional[Dict[str, int]] = None,
 ) -> List[dict]:
     """The passes of one stage over one group: ``[{"writes", "reads",
-    "rings", "renames", "vmem_bytes"}, ...]``, each a subset of the kernel's
-    outputs with the quantities THOSE outputs touch (``PlaneTrace.pruned``).
+    "rings", "renames", "prerotated", "vmem_bytes"}, ...]``, each a subset of
+    the kernel's outputs with the quantities THOSE outputs touch
+    (``PlaneTrace.pruned``); ``prerotated`` is the strip form's
+    ``shared_rotations``, () where they do not fit beside the rest.
 
     Outputs join the current pass, in the order the kernel returns them,
     while the pass still fits the VMEM budget (``plane_pass_vmem_bytes``
@@ -690,25 +761,35 @@ def plan_plane_passes(
     ``q``'s new value lands in ``p``'s buffer, so ``p`` stays among the
     ``reads`` whether the kernel reads it or not).  The caller passes it for
     the in-place default schedule only (``resolve_stream_plan``).
-    ``ring_bytes`` is ``plane_pass_vmem_bytes``' own."""
+    ``ring_bytes`` and ``stage_bytes`` are ``plane_pass_vmem_bytes``' own."""
     budget = _vmem_budget()
 
     def describe(outputs, whole=False, renames=()):
+        prerotated = ()
         if whole or trace.closed is None:
             reads = rings = writes = trace.names
         else:
-            _, reads, rings = trace.pruned(outputs)
+            _, reads, rings, offsets = trace.pruned(outputs)
             writes = tuple(outputs)
             homes = set(reads) | {p for p, _ in renames}
             reads = tuple(nm for nm in trace.names if nm in homes)
+            prerotated = shared_rotations(offsets) if trace.strip else ()
+
+        def priced(prerotated):
+            return plane_pass_vmem_bytes(
+                plane_bytes, trace.x_radius, reads, rings, writes, ring_bytes, stage_bytes,
+                prerotated,
+            )
+
+        if priced(prerotated) > budget:  # no room: the loop rotates every strip itself
+            prerotated = ()
         return {
             "writes": writes,
             "reads": reads,
             "rings": rings,
             "renames": tuple(renames),
-            "vmem_bytes": plane_pass_vmem_bytes(
-                plane_bytes, trace.x_radius, reads, rings, writes, ring_bytes
-            ),
+            "prerotated": prerotated,
+            "vmem_bytes": priced(prerotated),
         }
 
     def refuse(p):
@@ -945,7 +1026,7 @@ def _as_stages(kernel) -> Tuple[PlaneKernel, ...]:
 
 def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
                       fused: bool = False, rename: bool = False,
-                      window: str = "raw") -> Tuple[dict, tuple]:
+                      window: str = "raw", strip: int = 0) -> Tuple[dict, tuple]:
     """Plan a PLANE-route step from its kernels' own footprints: ``(keys,
     runs)``.  ``keys`` is what the plan says of it: ``stages`` -- per stage
     its ``readers`` (the quantities its exchange fills) and its ``passes``
@@ -953,7 +1034,7 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     bytes) --, ``footprint`` (``footprint_counts``) and the step-wide unions
     ``halo_readers`` / ``writers`` / ``renamed`` (the quantities whose write
     became a rename).  ``runs`` is, per stage, what the build runs: ``[(pass
-    kernel, reads, rings, writes, renames), ...]`` (names).  Raises
+    kernel, reads, rings, writes, renames, prerotated), ...]`` (names).  Raises
     ``ValueError`` for a step that fits in no pass.  Of ``plan`` only the
     grouping is read.
 
@@ -964,48 +1045,75 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     schedule (``resolve_stream_plan``).  ``window`` is the passes' working
     plane (``plane_window_form``): the kernels are traced over planes of ITS
     shape -- the rotate a shift lowers to is chosen there, at trace time
-    (``_make_roll``) -- and the rings are priced at it."""
+    (``_make_roll``) -- and the rings are priced at it.  ``strip`` is the rows
+    of a strip of the passes' strip form as the plane allows it
+    (``plane_strip_rows``; 0 = the plane whole): the kernels are traced over
+    such strips and the rings priced as the tiles they then hold -- unless the
+    heaviest of the step's kernels makes fewer than ``_STRIP_MIN_OPS``
+    operations a cell (the equations of its jaxpr, which in the strip form
+    holds no shift): the step is then planned over whole planes, and
+    ``keys["plane_strip"]`` says 0."""
     names = [h.name for h in dd._handles]
     raw = dd.local_spec().raw_size()
     work = dd.local_spec().sz if window == "interior" else raw
     f32_acc = any(dd.field_dtype(h) != h.dtype for h in dd._handles)
     planes = [
         jax.ShapeDtypeStruct(
-            (work.y, work.z), jnp.float32 if f32_acc else dd.field_dtype(h)
+            (strip or work.y, work.z), jnp.float32 if f32_acc else dd.field_dtype(h)
         )
         for h in dd._handles
     ]
-    plane_bytes, ring_bytes = (
+    # what a ring holds of a plane: in the strip form its tiles between
+    # ``x_radius`` margin tiles a side, and a writer's staging plane beside
+    # (``stream_plane_pass``)
+    margins = 2 * x_radius * sublane_tile([dd.field_dtype(h) for h in dd._handles]) if strip else 0
+    plane_bytes, stage_bytes, ring_bytes = (
         {
-            h.name: _padded_plane_bytes(of.y, of.z, dd.field_dtype(h).itemsize)
+            h.name: _padded_plane_bytes(y, of.z, dd.field_dtype(h).itemsize)
             for h in dd._handles
         }
-        for of in (raw, work)
+        for y, of in ((raw.y, raw), (work.y, work), (work.y + margins, work))
     )
-    described, built, traces = [], [], []
-    for stage in _as_stages(kernel):
-        readers, passes, runs = set(), [], []
-        for g in _stream_groups(plan, len(names)):
-            trace = trace_plane_kernel(
+    groups = _stream_groups(plan, len(names))
+    traced = [  # per stage, per group: traced before anything is planned
+        [
+            trace_plane_kernel(
                 stage, [names[q] for q in g], [planes[q] for q in g], x_radius,
-                dd._size, interpret, [dd.field_dtype(dd._handles[q]) for q in g],
+                dd._size, interpret, [dd.field_dtype(dd._handles[q]) for q in g], strip,
             )
-            traces.append(trace)
+            for g in groups
+        ]
+        for stage in _as_stages(kernel)
+    ]
+    traces = [t for of_stage in traced for t in of_stage]
+    if strip and max(
+        (len(t.closed.jaxpr.eqns) for t in traces if t.closed is not None), default=0
+    ) < _STRIP_MIN_OPS:
+        # a LIGHT kernel is bound by the planes it streams, and the strip form's
+        # tiles cost it more than its few values in registers save: whole planes
+        return plan_plane_stages(dd, kernel, x_radius, plan, interpret, fused, rename, window)
+    described, built = [], []
+    for of_stage in traced:
+        readers, passes, runs = set(), [], []
+        for trace in of_stage:
             readers |= set(names) if fused else set(trace.readers)
             for p in plan_plane_passes(
-                trace, plane_bytes, whole=fused, rename=rename, ring_bytes=ring_bytes
+                trace, plane_bytes, whole=fused, rename=rename, ring_bytes=ring_bytes,
+                stage_bytes=stage_bytes if strip else None,
             ):
                 passes.append(p)
                 runs.append((
                     trace.pruned(p["writes"])[0], p["reads"], p["rings"], p["writes"],
-                    p["renames"],
+                    p["renames"], p["prerotated"],
                 ))
         described.append({
             "readers": tuple(nm for nm in names if nm in readers),
             "passes": tuple(passes),
         })
         built.append(runs)
-    keys = {"stages": tuple(described), "footprint": footprint_counts(traces)}
+    keys = {
+        "stages": tuple(described), "footprint": footprint_counts(traces), "plane_strip": strip,
+    }
     for key, of in (
         ("halo_readers", lambda st: st["readers"]),
         ("writers", lambda st: [w for p in st["passes"] for w in p["writes"]]),
@@ -1105,7 +1213,7 @@ class ResolvedPlan(Mapping):
 
     plan: Mapping
     stage_runs: tuple  # plane route: per stage ``[(pass kernel, reads, rings,
-    # writes, renames), ...]`` (``plan_plane_stages``); () elsewhere
+    # writes, renames, prerotated), ...]`` (``plan_plane_stages``); () elsewhere
     wrap_fills: tuple  # the y / z halo fills the plane passes make themselves
     # (``pass_wrap_fills``), the how of ``plan["pass_wrap_axes"]``
     exchange_route: str  # the domain's realize-resolved exchange route
@@ -1196,9 +1304,18 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
             wrap_fills, shell.lo(), shell.hi(), (raw.y, raw.z),
             [dd.field_dtype(h) for h in dd._handles],
         )
+        # ... and the rows of it their kernel is evaluated over at a time
+        # (domain.step's ``plane_strip``; 0 = the plane whole): what the plane
+        # allows, and the kernels' own weight decides (plan_plane_stages)
+        strip = plane_strip_rows(
+            plan["plane_window"], (raw.y - shell.lo().y - shell.hi().y,
+                                   raw.z - shell.lo().z - shell.hi().z),
+            [dd.field_dtype(h) for h in dd._handles], x_radius,
+        )
         keys, stage_runs = plan_plane_stages(
             dd, stages, x_radius, plan, interpret, fused,
             rename=default and _plan_passes_in_place(plan), window=plan["plane_window"],
+            strip=strip,
         )
         plan.update(keys)
     else:

@@ -452,7 +452,17 @@ ALL_HISTOGRAMS = frozenset({
 #: --, "raw" = the shell-carrying plane, everywhere
 #: else (``ops/stream_pass.plane_window_form``, read off the fills and the
 #: block's static shape: "interior" in ``astaroth-mhd-256.bulk``, "raw" in the
-#: three 600-extent plane cells); a z-slab wavefront step (``Jacobi3D``'s z-ring and
+#: three 600-extent plane cells) and plane_strip = the rows ``S`` of that plane
+#: its passes evaluate their kernel over at a time -- a loop over the plane's
+#: strips inside a grid step, the planes held as tiles whose next row is the
+#: next tile (a y shift an address and no rotate), a value of the kernel ``S /
+#: 8 x Zw / 128`` vregs and not a whole plane's -- on the interior window and
+#: for a kernel of ``_STRIP_MIN_OPS`` or more operations a cell, 0 = the kernel
+#: runs over the plane whole (``ops/stream_pass.plane_strip_rows`` and
+#: ``ops/stream_plan.plan_plane_stages``, read off the window, the plane and
+#: the kernels' traces: 16 in ``astaroth-mhd-256.bulk``, 0 in the three
+#: 600-extent plane cells); a
+#: z-slab wavefront step (``Jacobi3D``'s z-ring and
 #: lane-padded shell kernels, the stream engine's wavefront route with
 #: ``z_slabs``) adds z_halo_patch = where its kernel patches the z halo into
 #: the working plane: "tile" = inside the 128-lane tiles that hold the halo

@@ -223,12 +223,13 @@ def _astaroth_mhd():
 
     # a box whose y-z interior is whole vector tiles, as the cell's 256 x 256
     # is: the passes take the interior window (ISSUE 45), the cell's program
-    s = AstarothMHD(8, 16, 128, interpret=True, devices=jax.devices()[:1], seed_words=None)
+    s = AstarothMHD(8, 32, 128, interpret=True, devices=jax.devices()[:1], seed_words=None)
     s.realize()
     args = s._step._span_args()
     assert (args["route"], args["stages"], args["renamed"], args["steps_per_trip"]) == (
         "plane", 3, "8/8/8", 2), args
     assert (args["wrapped"], args["plane_window"]) == ("yz", "interior"), args
+    assert args["plane_strip"] == 32, args  # ... in its strip form (ISSUE 46: one strip of four tiles)
     return _trace_step(s.dd, s._step)
 
 

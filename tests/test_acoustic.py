@@ -120,6 +120,7 @@ def test_route_is_plane_and_the_span_says_so(blend, mesh, wrapped, wired, monkey
         # 24 + 2 x 8 = 40 cells of interior a side here, 600 in the cell (4 x 128
         # + 88 lanes): no whole vector tile, the passes keep the raw plane (ISSUE 45)
         "plane_window": "raw",
+        "plane_strip": 0,  # ... and their kernel runs over it whole (ISSUE 46)
     }
     assert plan["halo_readers"] == ("u",), plan
     assert plan["writers"] == ("u",) and plan["renamed"] == ("u_prev",), plan

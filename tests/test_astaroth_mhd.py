@@ -28,6 +28,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORDS = (0x1234, 0xBEEF, 0x5EED, 0xC0FFEE)
 #: the program and the reference run the same ``substep``; the compilers round apart
 TOL = 1e-6
+#: rows of a strip of the cell's passes: 4 vregs a value on 256-lane planes (ISSUE 46)
+MHD_STRIP = 16
 N = 16
 
 
@@ -144,7 +146,7 @@ def test_the_seeded_state_matches_the_reference_and_takes_the_seed_as_an_argumen
 
 
 def test_the_interior_window_matches_the_reference(monkeypatch):
-    """A box whose y-z interior is whole vector tiles (16 x 128) on one device,
+    """A box whose y-z interior is whole vector tiles (32 x 128) on one device,
     the blend kernels on as on the chip, so the pass fills both in-plane halos
     itself (ISSUE 45): the passes work on the INTERIOR plane -- no halo in the
     kernel's windows at all, the rotates' wraparound supplies the y, z and y-z
@@ -153,7 +155,7 @@ def test_the_interior_window_matches_the_reference(monkeypatch):
     quantities matches the reference after a trip of two steps and one behind
     the loop.  The box is periodic and nowhere zero: a wrong wrap shows."""
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
-    shape = (8, 16, 128)
+    shape = (8, 32, 128)
     setup = ref.MhdSetup(shape, max_waves=2)
     sim = AstarothMHD(*shape, setup=setup, interpret=True, seed_words=None,
                       devices=jax.devices()[:1])
@@ -163,6 +165,9 @@ def test_the_interior_window_matches_the_reference(monkeypatch):
     sim.step(3)
     said = sim._step._span_args()
     assert (said["route"], said["wrapped"], said["plane_window"]) == ("plane", "yz", "interior")
+    # ... in its strip form (ISSUE 46): the kernel over ONE strip of the plane's
+    # four tiles, every y shift a read of another tile, the margins' wrap included
+    assert said["plane_strip"] == 32
     assert (said["renamed"], said["steps_per_trip"]) == ("8/8/8", 2)
     want = ref.steps(setup, state, 3)
     moved = min(float(jnp.abs(want[q] - state[q]).max()) for q in ref.FIELDS)
@@ -335,18 +340,27 @@ def test_the_plan_at_the_benchmarks_size_is_the_configurations(monkeypatch):
         assert (config["pass"]["reads"], config["pass"]["writes"]) == (16, 8)
         assert p["renames"] == tuple((q + "_prev", q) for q in ref.FIELDS)
         # 16 x 2 + 8 x 2 = 48 pipeline planes of the raw 262 x 262 block (264 x
-        # 384 f32 as tiled), 8 x 6 = 48 ring planes of its 256 x 256 interior
-        # (the interior window: ISSUE 45) + sixteen margins
+        # 384 f32 as tiled); its 256 x 256 interior (the interior window: ISSUE
+        # 45) as 32 tiles between three margin tiles a side (the strip form:
+        # ISSUE 46), 8 x 7 ring planes (the newest is pushed before it is read)
+        # + 8 of the *_prev + the 24 planes rotated once a grid step (uy, uz,
+        # ay, az by +-1..3 lanes: the z shifts their y-z mixed differences
+        # share with their differences along z); eight staging planes of
+        # tiles; sixteen stack margins: 97.0 MB, ONE pass a stage still
         assert p["vmem_bytes"] == (
-            48 * 264 * 384 * 4 + 48 * 256 * 256 * 4 + 16 * sp._VMEM_STACK_MARGIN) <= _vmem_budget()
+            48 * 264 * 384 * 4 + (64 + 24) * 304 * 256 * 4 + 8 * 256 * 256 * 4
+            + 16 * sp._VMEM_STACK_MARGIN) <= _vmem_budget()
+        assert sorted(p["prerotated"]) == sorted(
+            (q, 0, dz) for q in ("uy", "uz", "ay", "az") for dz in (-3, -2, -1, 1, 2, 3))
     assert plan["halo_readers"] == ref.FIELDS and plan["writers"] == ref.FIELDS
     assert (plan["pass_wrap_axes"], plan["steps_per_trip"], plan.period) == ("yz", 2, 2)
     assert plan["plane_window"] == "interior"  # 256 = 32 x 8 sublanes = 2 x 128 lanes
+    assert plan["plane_strip"] == MHD_STRIP  # 16 strips of two tiles, 4 vregs a value (ISSUE 46)
     assert config["dispatch"]["bulk"] % plan["steps_per_trip"] == 0  # whole trips: no edge copy
     assert analysis.check_vmem(sim.dd, plan.plan) is None
     said = stream_span_args(plan.plan, RADIUS, 16)
     assert (said["exchanged_sides"], said["read_sides"], said["wrapped"]) == (48, 48, "yz")
-    assert said["plane_window"] == "interior"
+    assert (said["plane_window"], said["plane_strip"]) == ("interior", MHD_STRIP)
 
 
 def test_the_span_says_what_a_staged_renaming_step_does():
@@ -374,6 +388,7 @@ def test_the_span_says_what_a_staged_renaming_step_does():
     assert (kw["read_sides"], kw["exchanged_sides"]) == (48, 48)
     assert kw["plane_window"] == "raw"  # 16 lanes of interior: no whole tile (and a CPU run
     # without the blend kernels fills no halo in the pass: ``wrapped`` "")
+    assert kw["plane_strip"] == 0  # ... and the raw window's kernel runs over the plane whole
     said = {k: v for k, v in kw.items() if k not in ("first", "total")}  # a first call's marks
     assert said == {"label": "astaroth-mhd", "steps": 2, **sim._step._span_args()}
 
@@ -385,10 +400,11 @@ def test_the_counter_is_registered_and_the_names_lint_passes():
 
     registered = inspect.getsource(tm).split('SPAN_STEP = "domain.step"')[0]
     assert "steps_per_trip" in registered and "plane_window" in registered
+    assert "plane_strip" in registered
     assert lint.run_lint(select=["telemetry-name"]) == []
     with open(os.path.join(ROOT, "docs", "observability.md")) as f:
         said = f.read()
-    assert "`steps_per_trip`" in said and "`plane_window`" in said
+    assert "`steps_per_trip`" in said and "`plane_window`" in said and "`plane_strip`" in said
 
 
 def test_the_step_loop_brings_every_carry_home():

@@ -592,6 +592,11 @@ def test_compiled_mhd_step_matches_the_xla_engine(shape, window):
     assert (said["route"], said["stages"], said["renamed"], said["steps_per_trip"], said["wrapped"]) == (
         "plane", 3, "8/8/8", 2, "yz"), said
     assert said["plane_window"] == window, said
+    # the interior window evaluates the kernel a row strip at a time (ISSUE
+    # 46: two strips of four of the 64 x 128 plane's eight tiles, every y shift
+    # a read of another tile, the margin tiles' wrap included), the raw one
+    # over the plane whole
+    assert said["plane_strip"] == (32 if window == "interior" else 0), said
     _, want = run("jnp")
     for a, b in zip(got, want):
         worst = max(float(np.abs(x - y).max()) for x, y in zip(a, b))
@@ -610,7 +615,12 @@ def test_compiled_interior_window_is_bitwise_the_raw_plane_pass(storage, monkeyp
     ragged third, as the MHD cell's), f32 and bf16 storage.  Mosaic contracts
     nothing, so every cell of every quantity is BITWISE equal after an even
     and an odd count of steps -- the kernel evaluates the same operations in
-    the same order on the cells the raw window keeps."""
+    the same order on the cells the raw window keeps.  The interior window
+    runs its STRIP form (ISSUE 46: strips of two tiles -- 16 rows of f32, 32 of
+    bf16 --, the rings' planes as tiles between their margin tiles, ``c`` a
+    lagged halo reader); the same step with the kernel over the plane WHOLE
+    (``plane_strip_rows`` patched to 0: the parent's interior form) is held to
+    the same bits."""
     from stencil_tpu.core.radius import Radius
     from stencil_tpu.domain import DistributedDomain
     from stencil_tpu.ops import stream_plan as sp
@@ -647,13 +657,20 @@ def test_compiled_interior_window_is_bitwise_the_raw_plane_pass(storage, monkeyp
             got.append([np.asarray(dd.quantity_to_host(h), np.float32) for h in hs])
         return step._stream_plan, got
 
+    monkeypatch.setattr(sp, "_STRIP_MIN_OPS", 0)  # (a light kernel: whole planes by the planner)
     plan, got = run()
     assert (plan["route"], plan["pass_wrap_axes"], plan["plane_window"]) == ("plane", "yz", "interior")
     assert plan["renamed"] == ("p",) and plan["stages"][0]["passes"][0]["rings"] == ("u",), plan
+    assert plan["plane_strip"] == (16 if storage == "native" else 32), plan
+    with monkeypatch.context() as mp:
+        mp.setattr(sp, "plane_strip_rows", lambda *a: 0)
+        plan_whole, whole = run()
+    assert (plan_whole["plane_window"], plan_whole["plane_strip"]) == ("interior", 0)
     monkeypatch.setattr(sp, "plane_window_form", lambda *a: "raw")
     plan_raw, want = run()
-    assert plan_raw["plane_window"] == "raw"
-    for a, b in zip(got, want):
-        for name, x, y in zip(("u", "c", "p"), a, b):
+    assert (plan_raw["plane_window"], plan_raw["plane_strip"]) == ("raw", 0)
+    for a, b, c in zip(got, want, whole):
+        for name, x, y, w in zip(("u", "c", "p"), a, b, c):
             assert np.isfinite(y).all() and np.array_equal(x, y), name
+            assert np.array_equal(x, w), name
     assert float(np.abs(got[0][0] - got[1][0]).max()) > 1e-3  # the state moved

@@ -149,6 +149,7 @@ def test_the_plan_is_staged_and_the_span_says_so():
         "exchanged_sides": 54,
         "steps_per_trip": 1,  # no rename: every trip of the step loop is one step (ISSUE 44)
         "plane_window": "raw",  # 24 cells of interior a side: no whole vector tile (ISSUE 45)
+        "plane_strip": 0,  # ... so the kernels run over the plane whole (ISSUE 46)
     }
     seen = []
     real = telemetry.span

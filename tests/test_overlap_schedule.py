@@ -545,8 +545,8 @@ def test_elastic_step_carries_its_blocks_in_place(monkeypatch):
 
 
 @pytest.mark.slow  # tier-2 with its siblings: one real-TPU-compiler AOT
-# compile at the benchmark's size (40 s alone: six passes of ~840 operations
-# on 99-vreg planes)
+# compile at the benchmark's size (10 s alone since ISSUE 46: six passes of
+# ~840 operations on four-vreg strips; 40 s on 99-vreg planes)
 def test_mhd_step_carries_its_blocks_in_place(monkeypatch):
     """The MHD cell's dispatch (256^3, sixteen quantities, three stages) as the
     chip's compiler leaves it (ISSUE 44): the ``while`` body holds TWO steps --
@@ -581,6 +581,12 @@ def test_mhd_step_carries_its_blocks_in_place(monkeypatch):
         jax.config.update("jax_enable_x64", x64_was)
     assert (plan["route"], plan["pass_wrap_axes"], plan["steps_per_trip"]) == ("plane", "yz", 2)
     assert plan["plane_window"] == "interior"
+    # ... in its strip form (ISSUE 46): Mosaic takes the planes as tiles (a
+    # transposition of a tile's two leading dimensions a plane), the loop over
+    # sixteen strips of two tiles read at a traced index of a leading
+    # dimension, and the 24 whole-plane lane rotates; 97.0 MB by the model
+    assert plan["plane_strip"] == 16
+    assert [len(p["prerotated"]) for st in plan["stages"] for p in st["passes"]] == [24, 24, 24]
     assert [len(p["renames"]) for st in plan["stages"] for p in st["passes"]] == [8, 8, 8]
     calls = [l for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
     passes = [l for l in calls if l.lstrip().startswith("%stream_plane_pass")]

@@ -1208,6 +1208,79 @@ def test_the_plane_window_is_read_off_the_fills_and_the_shape(interior, lo, hi, 
     assert spass.plane_window_form(short, Dim3(*lo), Dim3(*hi), plane, dtypes) == "raw"
 
 
+@pytest.mark.parametrize("window,plane,storage,r,want", [
+    pytest.param("interior", (256, 256), "float32", 3, 16, id="mhd-256"),  # 16 strips of two tiles
+    pytest.param("interior", (64, 128), "float32", 3, 32, id="four-vregs-a-value"),
+    pytest.param("interior", (32, 128), "float32", 3, 32, id="one-strip"),
+    pytest.param("interior", (24, 128), "float32", 3, 24, id="three-tiles"),
+    pytest.param("interior", (16, 128), "float32", 3, 0, id="fewer-tiles-than-the-read-distance"),
+    pytest.param("interior", (16, 128), "float32", 2, 16, id="as-many"),
+    pytest.param("interior", (256, 1024), "float32", 3, 8, id="too-wide-one-tile"),
+    pytest.param("interior", (40, 128), "float32", 4, 8, id="five-tiles-one-at-a-time"),
+    pytest.param("interior", (256, 256), "bfloat16", 3, 32, id="bf16-16-row-tiles"),
+    pytest.param("interior", (32, 128), "bfloat16", 3, 0, id="bf16-two-tiles"),
+    pytest.param("raw", (256, 256), "float32", 3, 0, id="raw-window"),
+])
+def test_the_strip_is_read_off_the_window_and_the_plane(window, plane, storage, r, want):
+    """``plane_strip_rows``: whole tiles of the stored dtype that divide the
+    plane, four vregs a value where the plane allows; none off the interior
+    window, nor where a y shift would wrap around the tiles more than once."""
+    import jax.numpy as jnp
+
+    assert spass.plane_strip_rows(window, plane, [jnp.float32, jnp.dtype(storage)], r) == want
+
+
+def test_the_shared_rotations_are_those_two_rows_read():
+    """``shared_rotations``: a plane is rotated once a grid step where two or
+    more ``dy`` read it at the same ``(dx, dz)`` -- the y-z diagonals beside
+    the reads along z --, and nowhere else."""
+    reads = [("u", (0, 0, 1)), ("u", (0, 2, 1)), ("u", (0, 0, -1)), ("u", (1, 0, 1)),
+             ("u", (1, 0, 0)), ("u", (1, 1, 0)), ("c", (0, -1, 2)), ("c", (0, 1, 2)),
+             ("c", (0, 1, 0)), ("c", (0, -1, 0))]
+    assert sp.shared_rotations(reads) == (("u", 0, 1), ("c", 0, 2))
+    assert sp.shared_rotations([]) == ()
+
+
+def test_the_strip_form_fails_closed_by_name():
+    """The strip form checks what it is told as the whole-plane form does, at
+    trace time and by name (ISSUE 46): a read off-centre of a quantity outside
+    ``halo_readers`` (it holds no margin plane: the rows a y offset would read
+    are not there), a read along x of one outside ``rings``, a returned name
+    outside ``writers`` -- never a stale read, never a dropped result."""
+    import jax
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+
+    r, n = 1, (4, 8, 128)
+    blk = jax.ShapeDtypeStruct(tuple(m + 2 * r for m in n), jnp.float32)
+    fills = _whole_self_wrap(n, (r,) * 3, (r,) * 3)
+
+    def one_pass(kernel, **kw):
+        def fn(origin, a, c):
+            return spass.stream_plane_pass(
+                kernel, ["a", "c"], [a, c], Dim3(r, r, r), Dim3(r, r, r), r, origin,
+                Dim3(*n), interpret=True, wrap_fills=fills, window="interior", strip=8, **kw,
+            )
+
+        return jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((3,), jnp.int32), blk, blk)
+
+    def reads(dx, dy):
+        return lambda views, info: {"a": _star(views["a"], 1) * views["c"].sh(dx, dy, 0)}
+
+    one_pass(reads(0, 1), halo_readers=("a", "c"), rings=("a",), writers=("a",))  # told: fine
+    with pytest.raises(ValueError, match=r"reads 'c' off-centre.*halo of 'c' was not exchanged"):
+        one_pass(reads(0, 1), halo_readers=("a",), rings=("a",), writers=("a",))
+    with pytest.raises(ValueError, match=r"reads 'c' off-centre along x.*no ring for 'c'"):
+        one_pass(reads(1, 0), rings=("a",), writers=("a",))
+
+    def returns_c(views, info):
+        return {"a": _star(views["a"], 1), "c": views["c"].center() + 1.0}
+
+    with pytest.raises(ValueError, match=r"returns 'c'.*'c' is not an output of the pass"):
+        one_pass(returns_c, writers=("a",))
+
+
 _R3 = ((3, 3, 3), (3, 3, 3))
 _INTERIOR_WINDOW_CASES = [
     pytest.param({}, (), _R3, id="plain"),
@@ -1221,39 +1294,64 @@ _INTERIOR_WINDOW_CASES = [
 ]
 
 
+@pytest.mark.parametrize("strips", [0, 1, 2], ids=["whole", "one-strip", "two-strips"])
 @pytest.mark.parametrize("kw,renames,shell", _INTERIOR_WINDOW_CASES)
-def test_the_interior_window_pass_is_bitwise_the_raw_plane_pass(kw, renames, shell):
+def test_the_interior_window_pass_is_bitwise_the_raw_plane_pass(kw, renames, shell, strips):
     """The same blocks through both windows: every interior cell of every
     quantity bitwise equal, the x-shell planes of an output that is a halo
     reader equal on every raw cell (their interiors pass through, their y / z
     shell is the same fill), and on the interior window the y / z shell of EVERY stored plane
     is the self-wrap of the plane as stored -- where the raw window keeps the
     fills of the plane as loaded.  A quantity the pass does not write comes
-    back as the array that went in."""
+    back as the array that went in.
+
+    ``strips``: the interior window's STRIP form (ISSUE 46), the kernel
+    evaluated a strip at a time over the planes' TILES (tile ``k`` holds rows
+    ``k, K + k, ...``: a y shift of a strip is another tile, read at its
+    address) -- on a plane of four tiles that is ONE strip and one of TWO, so
+    every shifted read goes through the margin tiles and their one-sublane
+    wrap: bitwise the whole-plane interior-window pass on EVERY raw cell, and
+    so the raw-window pass as above -- renames, the lagged ``c`` (read along
+    y) and ``p`` (read at the centre), x-shell planes, the y-z, x-y and x-z
+    diagonals at radius 3, the cells' own coordinates, f32 and bf16 storage;
+    on the plane of two strips with the planes two ``dy`` share ROTATED ONCE a
+    grid step and read at their tiles (``prerotated``), on the other every
+    strip rotating its own."""
     import jax.numpy as jnp
 
     from stencil_tpu.core.dim3 import Dim3
 
-    r, n, names = 3, (6, 16, 128), ["u", "c", "p"]
+    dtype = jnp.bfloat16 if kw.get("f32_accumulate") else jnp.float32
+    rows = 4 * spass.sublane_tile([dtype])  # four tiles of rows a plane
+    strip = rows // strips if strips else 0
+    r, n, names = 3, (6, rows if strips else 16, 128), ["u", "c", "p"]
     lo, hi = shell
     shape = tuple(m + a + b for m, a, b in zip(n, lo, hi))
-    dtype = jnp.bfloat16 if kw.get("f32_accumulate") else jnp.float32
     rng = np.random.default_rng(45)
     raws = [jnp.asarray(rng.standard_normal(shape), dtype) for _ in names]
     fills = _whole_self_wrap(n, lo, hi)
     writers = ("u",) if renames else ("u", "p")
 
-    def run(window):
+    # the planes rotated ONCE a grid step for all their readers, on the plane of
+    # two strips: ``u``'s centre plane (its y-z diagonals share the shift), a
+    # ring plane off-centre along x, and the lagged ``c``
+    shared = (("u", 0, 1), ("u", 0, 2), ("u", 0, 3), ("u", -2, 2), ("c", 0, -1))
+
+    def run(window, strip=0):
         return spass.stream_plane_pass(
             _diagonal_r3_kernel, names, raws, Dim3(*lo), Dim3(*hi), r,
             jnp.asarray([5, 0, 0], jnp.int32), Dim3(64, n[1], n[2]), interpret=True,
             halo_readers=("u", "c"), rings=("u",), writers=writers, wrap_fills=fills,
-            renames=renames, window=window, **kw,
+            renames=renames, window=window, strip=strip,
+            prerotated=shared if strips == 2 and strip else (), **kw,
         )
 
-    got, want = run("interior"), run("raw")
+    got, want = run("interior", strip), run("raw")
     inner = tuple(slice(a, a + m) for a, m in zip(lo, n))
     as_np = lambda v: np.asarray(v.astype(jnp.float32))  # noqa: E731
+    if strip:
+        for a, b in zip(got, run("interior")):  # the whole-plane form, every raw cell
+            assert np.array_equal(as_np(a), as_np(b))
     for q, name in enumerate(names):
         a, b = as_np(got[q]), as_np(want[q])
         assert np.isfinite(b[inner]).all() and np.array_equal(a[inner], b[inner]), name
@@ -1272,13 +1370,14 @@ def test_the_interior_window_pass_is_bitwise_the_raw_plane_pass(kw, renames, she
             assert not np.array_equal(a, b), name  # the raw window's shell is the OLD plane's
 
 
-@pytest.mark.parametrize("extent,partition,window", [
-    pytest.param((8, 16, 128), (1, 1, 1), "interior", id="whole-tiles"),
-    pytest.param((8, 16, 100), (1, 1, 1), "raw", id="ragged-lanes"),
-    pytest.param((8, 32, 128), (1, 2, 1), "raw", id="y-split"),
+@pytest.mark.parametrize("extent,partition,window,strip", [
+    pytest.param((8, 16, 128), (1, 1, 1), "interior", 0, id="whole-tiles"),
+    pytest.param((8, 32, 128), (1, 1, 1), "interior", 32, id="whole-tiles-in-strips"),
+    pytest.param((8, 16, 100), (1, 1, 1), "raw", 0, id="ragged-lanes"),
+    pytest.param((8, 32, 128), (1, 2, 1), "raw", 0, id="y-split"),
 ])
 def test_a_plane_step_takes_the_interior_window_where_it_wraps_onto_itself(
-    extent, partition, window, monkeypatch
+    extent, partition, window, strip, monkeypatch
 ):
     """The step as built, blend kernels on as on the chip: ``plan["plane_
     window"]`` follows the fills and the block's shape; where it says "raw"
@@ -1297,6 +1396,8 @@ def test_a_plane_step_takes_the_interior_window_where_it_wraps_onto_itself(
 
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
     names, r = ["u", "c", "p"], 3
+    if strip:  # (this kernel is a light one: the planner would keep it over whole planes)
+        monkeypatch.setattr(sp, "_STRIP_MIN_OPS", 0)
 
     def run():
         dd, hs = _pass_wrap_domain(names, r, partition, None, extent)
@@ -1327,14 +1428,23 @@ def test_a_plane_step_takes_the_interior_window_where_it_wraps_onto_itself(
     assert plan_raw["plane_window"] == "raw"
     same = fingerprint_text(closed) == fingerprint_text(closed_raw)
     assert same == (window == "raw")
-    rings = {  # the planes the passes' rings hold
-        tuple(sc.shape[-2:])
+    rings = {  # the planes the passes' rings hold (the strip form: what the passes hold)
+        tuple(sc.shape) if strip else tuple(sc.shape[-2:])
         for e in jx.iter_eqns(closed)
         if e.primitive.name == "pallas_call" and "stream_plane_pass" in str(e.params.get("name"))
         for sc in e.params["grid_mapping"].scratch_avals
     }
     raw = tuple(m + 2 * r for m in np.asarray(extent[1:]) // np.asarray(partition[1:]))
-    assert rings == ({tuple(extent[1:])} if window == "interior" else {raw}), rings
+    # (the strip form, ISSUE 46: every quantity's plane as its four tiles between
+    # three margin tiles a side -- ``u`` seven planes deep, ``c`` and ``p`` one
+    # --, ``u``'s staging plane of tiles, and ``u`` rotated once a grid step
+    # for the z shifts its y-z diagonals share)
+    assert plan["plane_strip"] == strip, plan
+    assert sm.stream_span_args(plan, r, len(names))["plane_strip"] == strip
+    (p,) = plan["stages"][0]["passes"]
+    assert set(p["prerotated"]) == ({("u", 0, k) for k in (1, 2, 3)} if strip else set())
+    tiles = {(7, 10, 8, 128), (1, 10, 8, 128), (4, 8, 128), (10, 8, 128)}
+    assert rings == (tiles if strip else {tuple(extent[1:])} if window == "interior" else {raw}), rings
     for a, b in zip(fields, fields_raw):
         for name, x, y in zip(names, a, b):
             assert np.isfinite(y).all() and np.array_equal(x, y), name

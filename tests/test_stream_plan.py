@@ -12,6 +12,7 @@ import os
 import types
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -233,6 +234,11 @@ def _benchmark_plane_model(cell):
     return sim, stages(), RADIUS
 
 
+#: rows of a strip of the MHD cell's passes (256 x 256 interior planes of f32:
+#: ``stream_pass._STRIP_VREGS`` vregs a value)
+MHD_STRIP = 16
+
+
 @pytest.mark.parametrize("cell,wrapped,window", [
     ("astaroth-mhd-256", "yz", "interior"),  # 256 = 32 x 8 sublanes = 2 x 128 lanes
     ("acoustic-so8-600", "yz", "raw"),  # 600 = 4 x 128 + 88 lanes
@@ -258,17 +264,31 @@ def test_the_benchmarks_plane_cells_resolve_their_window(cell, wrapped, window, 
     )
     said = sm.stream_span_args(plan, r, len(sim.dd._handles))
     assert (said["wrapped"], said["plane_window"]) == (wrapped, window)
-    # the rings are priced at the plane they hold: the interior's where the
-    # window is the interior, the raw plane's elsewhere (the one VMEM model)
+    # ... and the rows of it the kernel is evaluated over at a time (ISSUE 46):
+    # strips of MHD_STRIP rows on the interior window, the plane whole on the raw one
+    strip = MHD_STRIP if window == "interior" else 0
+    assert plan["plane_strip"] == said["plane_strip"] == strip
+    assert strip == spass.plane_strip_rows(window, (n.y, n.z), [jnp.float32], r)
+    # the rings are priced at the plane they hold: the raw plane's on the raw
+    # window; on the interior window, in the strip form, the interior as tiles
+    # between r margin tiles a side -- 2r + 1 deep for a ringed quantity (the
+    # newest plane is pushed before the strips read it), one plane for every
+    # other --, a staging plane of tiles a writer and the planes rotated once
+    # for all their readers (the one VMEM model)
     pad = sp._padded_plane_bytes
-    held = (n.y, n.z) if window == "interior" else (raw.y, raw.z)
     for st in plan["stages"]:
         for p in st["passes"]:
-            assert p["vmem_bytes"] == (
-                2 * (len(p["reads"]) + len(p["writes"])) * pad(raw.y, raw.z, 4)
-                + 2 * r * len(p["rings"]) * pad(*held, 4)
-                + sp._VMEM_STACK_MARGIN * len(p["reads"])
-            ), p
+            blocks = 2 * (len(p["reads"]) + len(p["writes"])) * pad(raw.y, raw.z, 4)
+            if strip:
+                # ... and the 24 planes rotated once a grid step: the four fields
+                # whose y-z mixed differences share the z shifts of their
+                # differences along z (``shared_rotations``)
+                assert len(p["prerotated"]) == 24 and {dx for _, dx, _ in p["prerotated"]} == {0}
+                held = ((2 * r + 1) * len(p["rings"]) + len(p["reads"]) - len(p["rings"]) + 24) * pad(
+                    n.y + 2 * r * 8, n.z, 4) + len(p["writes"]) * pad(n.y, n.z, 4)
+            else:
+                held = 2 * r * len(p["rings"]) * pad(raw.y, raw.z, 4)
+            assert p["vmem_bytes"] == blocks + held + sp._VMEM_STACK_MARGIN * len(p["reads"]), p
     # a request that turns the schedule off the default one keeps the raw plane
     split = dict(sp.plan_stream(sim.dd, r, "plane", False), overlap="split", overlap_forced=True)
     if cell == "astaroth-mhd-256":
@@ -281,7 +301,7 @@ def _lag_kernel(views, info):
     return {"u": new, "p": u.center()}
 
 
-@pytest.mark.parametrize("window", ["interior", "raw"])
+@pytest.mark.parametrize("window", ["interior", "interior-in-strips", "raw"])
 @pytest.mark.parametrize("storage", ["native", "bf16"])
 def test_the_vmem_model_is_what_the_pass_allocates(window, storage, monkeypatch):
     """``plane_pass_vmem_bytes`` against the traced Pallas call of the pass, in
@@ -294,7 +314,10 @@ def test_the_vmem_model_is_what_the_pass_allocates(window, storage, monkeypatch)
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
     if window == "raw":
         monkeypatch.setattr(sp, "plane_window_form", lambda *a: "raw")
-    dd = DistributedDomain(8, 16, 128)
+    window, strips = window.split("-")[0], window.endswith("strips")
+    if strips:  # (a light kernel: the planner keeps it over whole planes)
+        monkeypatch.setattr(sp, "_STRIP_MIN_OPS", 0)
+    dd = DistributedDomain(8, 16 if storage == "native" else 32, 128)
     dd.set_radius(Radius.constant(2))
     dd.set_devices(jax.devices()[:1])
     if storage != "native":
@@ -324,8 +347,20 @@ def test_the_vmem_model_is_what_the_pass_allocates(window, storage, monkeypatch)
     )
     allocated += sum(pad(sc.shape, sc.dtype) for sc in gm.scratch_avals)
     assert p["vmem_bytes"] == allocated + sp._VMEM_STACK_MARGIN * len(p["reads"])
-    (ring,) = gm.scratch_avals
-    assert ring.shape == ((4, 16, 128) if window == "interior" else (4, 20, 132))
+    rows = 16 if storage == "native" else 32  # two sublane tiles of the stored dtype
+    if not strips:
+        assert plan["plane_strip"] == 0
+        (ring,) = gm.scratch_avals
+        assert ring.shape == ((4, rows + 4, 132) if window == "raw" else (4, rows, 128))
+    else:
+        # the strip form (ISSUE 46): every quantity's interior as its two tiles
+        # between two margin tiles a side -- ``u`` ringed 2r + 1 deep, ``c`` and
+        # ``p`` one plane -- and the writer's staging plane of tiles
+        assert plan["plane_strip"] == rows and p["prerotated"] == ()
+        ring, *lagged, stage = gm.scratch_avals
+        assert [sc.shape for sc in (ring, *lagged, stage)] == [
+            (5, 6, rows // 2, 128), (1, 6, rows // 2, 128), (1, 6, rows // 2, 128),
+            (2, rows // 2, 128)]
     assert ring.dtype == dd.field_dtype(dd._handles[0])  # the rings hold STORED planes
 
 
@@ -343,8 +378,10 @@ def test_the_step_span_carries_the_plane_window(monkeypatch):
         return real(name, *a, **kw)
 
     monkeypatch.setattr(telemetry, "span", spy)
-    for extent, path, want in (((8, 16, 128), "plane", "interior"), ((8, 16, 96), "plane", "raw"),
-                               ((8, 16, 128), "wrap", None)):
+    monkeypatch.setattr(sp, "_STRIP_MIN_OPS", 0)  # (``_mean2`` is a light kernel)
+    for extent, path, want, strip in (
+            ((8, 16, 128), "plane", "interior", 16), ((8, 16, 96), "plane", "raw", 0),
+            ((8, 16, 128), "wrap", None, None)):
         dd = DistributedDomain(*extent)
         dd.set_radius(Radius.constant(1))
         dd.set_devices(jax.devices()[:1])
@@ -357,4 +394,5 @@ def test_the_step_span_carries_the_plane_window(monkeypatch):
         dd.run_step(step, 2)
         said = [kw for name, kw in seen if name == tm.SPAN_STEP]
         assert len(said) == 2 and all(kw.get("plane_window") == want for kw in said), said
+        assert all(kw.get("plane_strip") == strip for kw in said), said  # (ISSUE 46)
         assert all((kw["wrapped"] == "yz") == (path == "plane") for kw in said), said

@@ -4,9 +4,12 @@ Parity target: Astaroth's standard solver (``acc-runtime/samples/mhd_modular/
 mhdsolver.ac``: the Pencil Code's continuity, momentum, induction and entropy
 equations, sixth-order differences, Williamson's RK3; 256^3 a device in its
 scaling study, arXiv:2103.01597) — the step ``bin/astaroth_sim.py`` runs a
-proxy of.  ``x y z`` is the box, of side 2 pi; a seeded superposition of plane
-waves stands for the source's random or file data, and the time step is fixed
-(docs/astaroth-mhd.md).  One CSV row, like the other drivers, with the
+proxy of.  ``x y z`` is the grid, on a UNIFORM cell: its shortest axis spans
+2 pi and the box grows with the others, as the source's weak scaling grows it
+(``512 512 256`` on four devices: 256^3 a device on mesh 2,2,1, the box 4 pi x
+4 pi x 2 pi, the cell and the time step those of ``256 256 256``); a seeded
+superposition of plane waves stands for the source's random or file data, and
+the time step is fixed (docs/astaroth-mhd.md).  One CSV row, like the other drivers, with the
 figure of merit the proxy's cell reports (million cell updates a second over
 the eight evolved fields, a time step = three substeps):
 
@@ -16,6 +19,7 @@ the eight evolved fields, a time step = three substeps):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
 
@@ -24,7 +28,7 @@ import jax
 from stencil_tpu.bin import _common
 from stencil_tpu.core.radius import Radius
 from stencil_tpu.models.astaroth_mhd import RADIUS, AstarothMHD
-from stencil_tpu.models.astaroth_mhd_reference import FIELDS, dt_of
+from stencil_tpu.models.astaroth_mhd_reference import FIELDS, MhdSetup, dt_of
 from stencil_tpu.utils.statistics import Statistics
 
 
@@ -54,8 +58,13 @@ def main(argv=None) -> int:
     x, y, z = _common.fit_to_mesh(args.x, args.y, args.z, Radius.constant(RADIUS))
     print(f"domain: {x},{y},{z}", file=sys.stderr)
     words = [int(w) for w in jax.random.bits(jax.random.key(args.seed), (4,), "uint32")]
+    setup = MhdSetup((x, y, z))
+    if not x == y == z:  # one cell on every axis: the shortest spans the cube's side
+        cell = setup.box / min(x, y, z)
+        setup = dataclasses.replace(setup, box=(cell * x, cell * y, cell * z))
     sim = AstarothMHD(
-        x, y, z, kernel_impl=args.kernel_impl, interpret=args.interpret, seed_words=words
+        x, y, z, setup=setup, kernel_impl=args.kernel_impl, interpret=args.interpret,
+        seed_words=words,
     )
     _common.apply_numerics(args, sim.dd)
     sim.realize()
@@ -63,8 +72,9 @@ def main(argv=None) -> int:
     mesh = ",".join(str(int(d)) for d in sim.dd.mesh_dim())
     plan = getattr(sim._step, "_span_args", dict)()
     print(
-        f"mesh: {mesh} route={plan.get('route')!r} stages={plan.get('stages')} "
-        f"renamed={plan.get('renamed')} wrapped={plan.get('wrapped')!r} "
+        f"mesh: {mesh} wired={plan.get('wired', '')!r} wrapped={plan.get('wrapped', '')!r} "
+        f"wire_bytes={plan.get('wire_bytes', 0)} wired_edges={plan.get('wired_edges', '')!r} "
+        f"route={plan.get('route')!r} stages={plan.get('stages')} renamed={plan.get('renamed')} "
         f"plane_window={plan.get('plane_window')!r} plane_strip={plan.get('plane_strip')} "
         f"read_sides={plan.get('read_sides')} exchanged_sides={plan.get('exchanged_sides')}",
         file=sys.stderr,

@@ -75,6 +75,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+from typing import Union
 
 #: the evolved fields, then the second buffer of each (the previous substep's
 #: value); the quantities of a domain, in the order they are added
@@ -102,8 +103,10 @@ TERMS = ("advection", "pressure", "lorentz")
 
 @dataclasses.dataclass(frozen=True)
 class MhdSetup:
-    """The numbers of one set-up: a periodic box of side ``box`` on every axis,
-    ``shape`` cells, code units (``cs0 = 1``)."""
+    """The numbers of one set-up: a periodic box of side ``box`` on every axis
+    (one float), or of sides ``box[a]`` (a triple: a weak-scaled run keeps the
+    CELL and grows the box with the grid), ``shape`` cells, code units
+    (``cs0 = 1``)."""
 
     shape: tuple
     nu: float = 5e-3  # kinematic viscosity
@@ -116,7 +119,7 @@ class MhdSetup:
     mu0: float = 1.0
     lnrho0: float = 0.0
     lnT0: float = math.log(1.5)  # cs0^2 / ((gamma - 1) cp)
-    box: float = 2.0 * math.pi
+    box: Union[float, tuple] = 2.0 * math.pi  # one side, or a side an axis
     courant: float = 0.3  # of dx / (cs0 + |u|max)
     dt: float = None  # the fixed time step; None = ``dt_of`` this set-up
     amplitude: float = 0.05  # bound on every seeded field (Mach 0.05 a component)
@@ -128,10 +131,19 @@ class MhdSetup:
         unknown = set(self.off) - set(TERMS)
         if unknown:
             raise ValueError(f"unknown terms {sorted(unknown)}; there are {TERMS}")
+        if not isinstance(self.box, (int, float)):
+            if len(self.box) != 3:
+                raise ValueError(f"box is one side or one an axis, not {self.box!r}")
+            object.__setattr__(self, "box", tuple(float(b) for b in self.box))  # hashable
+
+    @property
+    def sides(self) -> tuple:
+        """The box's side along each axis."""
+        return self.box if isinstance(self.box, tuple) else (self.box,) * 3
 
     @property
     def spacing(self) -> tuple:
-        return tuple(self.box / n for n in self.shape)
+        return tuple(b / n for b, n in zip(self.sides, self.shape))
 
 
 def dt_of(setup: MhdSetup) -> float:

@@ -981,6 +981,10 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         # stages (ops/exchange.py wire_plan): "" and 0 on one device
         args["wired"] = plan["wired"]
         args["wire_bytes"] = plan["wire_bytes"]
+        # ... and the pairs of those axes whose EDGE halo the kernels read
+        # (a diagonal offset across both): it reaches a shard over two wires
+        # in turn ("xy"; several "/"-joined; "" where no edge is read so)
+        args["wired_edges"] = "/".join(plan["wired_edges"])
     per_stage = plan.get("stages", ())
     if len(per_stage) > 1:
         # a staged step says the three PER STAGE, in order ("6/3"): each
@@ -997,6 +1001,8 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         args["aliased"] = each(
             lambda st: len({q for p in st["passes"] for q in p["reads"]}) if in_place else 0
         )
+        if "wired" in plan:  # the bytes over the wires, stage by stage
+            args["wire_bytes_by_stage"] = "/".join(str(b) for b in plan["wire_bytes_by_stage"])
     return args
 
 

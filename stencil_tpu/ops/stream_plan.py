@@ -642,6 +642,20 @@ def footprint_counts(traces: Sequence[PlaneTrace]) -> Optional[dict]:
     }
 
 
+def edge_reads(traces: Sequence[PlaneTrace]) -> Tuple[str, ...]:
+    """The axis pairs (``"xy"``, ``"xz"``, ``"yz"``, in that order) some
+    quantity is read across at an offset non-zero on BOTH axes: the edge
+    halos a step's kernels need (``PlaneTrace.offsets``; a trace that raised
+    says nothing).  Astaroth's MHD step: all three, the mixed differences'
+    diagonals; a star: none."""
+    offs = [o for t in traces for _, of_reader in t.offsets for o in of_reader]
+    return tuple(
+        MESH_AXES[a] + MESH_AXES[b]
+        for a, b in ((0, 1), (0, 2), (1, 2))
+        if any(o[a] and o[b] for o in offs)
+    )
+
+
 def _plane_renames(jaxpr, names, writers, per: int, stored: dict):
     """The ``(p, q)`` of ``trace_plane_kernel``'s rename rule, read off the
     kernel's jaxpr (invars: three coordinates, then ``per`` inputs a quantity
@@ -1113,6 +1127,7 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
         built.append(runs)
     keys = {
         "stages": tuple(described), "footprint": footprint_counts(traces), "plane_strip": strip,
+        "edge_reads": edge_reads(traces),
     }
     for key, of in (
         ("halo_readers", lambda st: st["readers"]),
@@ -1340,8 +1355,8 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
         # what the sweeps the passes leave send to ANOTHER shard (domain.step's
         # ``wired`` and ``wire_bytes``): per stage the message plan of its
         # exchange (ops/exchange.py wire_plan, i.e. ``_sweep_kind``), the axes
-        # joined and the bytes summed over the stages of a step.  No other
-        # schedule says.
+        # joined, the bytes stage by stage (``wire_bytes_by_stage``) and summed
+        # over the stages of a step.  No other schedule says.
         dtype_of = {h.name: dd.field_dtype(h) for h in dd._handles}
         per_stage = [
             wire_plan(
@@ -1349,11 +1364,19 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
                 (raw.x, raw.y, raw.z), [dtype_of[name] for name in st["readers"]],
                 valid_last=dd._valid_last, route=exch_route,
                 axes=swept_axes(plan),
-            )
-            for st in plan["stages"] if st["readers"]
+            ) if st["readers"] else ("", 0)  # a stage that exchanges nothing
+            for st in plan["stages"]
         ]
         plan["wired"] = "".join(ax for ax in MESH_AXES if any(ax in w for w, _ in per_stage))
-        plan["wire_bytes"] = sum(b for _, b in per_stage)
+        plan["wire_bytes_by_stage"] = tuple(b for _, b in per_stage)
+        plan["wire_bytes"] = sum(plan["wire_bytes_by_stage"])
+        # ... and the pairs of wired axes a kernel reads DIAGONALLY across
+        # (``wired_edges``): that edge halo is the diagonal neighbour's, and
+        # reaches the shard over two wires in turn, the later sweep carrying
+        # what the earlier one received
+        plan["wired_edges"] = tuple(
+            pair for pair in plan["edge_reads"] if all(ax in plan["wired"] for ax in pair)
+        )
     if route == "wavefront" and plan["z_slabs"]:
         # where the pass patches its z halo, read off the working plane's
         # shape as the kernel's own helper reads it (patch_z_halo; domain.

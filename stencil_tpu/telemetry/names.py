@@ -431,7 +431,16 @@ ALL_HISTOGRAMS = frozenset({
 #: with a sequence of kernels) adds stages and passes, and says exchanged /
 #: written / renamed / aliased PER STAGE, in order: "6/3", "3/6", "0/0",
 #: "11/12" (``wrapped``, ``wired`` and ``wire_bytes`` are one value each: the
-#: first two functions of the mesh, the last summed over the stages); a
+#: first two functions of the mesh, the last summed over the stages) and, where
+#: it says ``wired``, wire_bytes_by_stage = those bytes stage by stage
+#: ("26359296/26359296/26359296" for Astaroth's MHD step on mesh [2,2,1]: three
+#: exchanges of the same eight fields; a stage that exchanges nothing 0); every
+#: step that says ``wired`` says wired_edges = the pairs of wired axes its
+#: kernels read DIAGONALLY across (``ops/stream_plan.edge_reads`` over
+#: ``PlaneTrace.offsets``, "/"-joined): that EDGE halo is the diagonal
+#: neighbour's and reaches the shard over two wires in turn, the later sweep
+#: carrying what the earlier one received -- "xy" for the MHD step's mixed
+#: differences on [2,2,1], "" on one device, on [2,1,1] and for a star; a
 #: ``Jacobi3D`` wrap or wavefront step adds macros_per_trip = the macros one
 #: trip of its device-side macro loop runs, as many as it takes for the carry
 #: to be back in its own buffer (``ops/stream.macro_loop``): 2 where the

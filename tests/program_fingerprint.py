@@ -233,6 +233,28 @@ def _astaroth_mhd():
     return _trace_step(s.dd, s._step)
 
 
+def _astaroth_mhd_x4():
+    import math
+
+    import jax
+
+    from stencil_tpu.models.astaroth_mhd import AstarothMHD
+    from stencil_tpu.models.astaroth_mhd_reference import MhdSetup
+
+    # x = y = 2z as the cell, on ITS cell (the box grown with the grid: a side
+    # an axis), the mesh the partitioner picks for it
+    shape = (32, 32, 16)
+    setup = MhdSetup(shape, box=tuple(2.0 * math.pi * n / 16 for n in shape))
+    s = AstarothMHD(*shape, setup=setup, interpret=True, devices=jax.devices()[:4], seed_words=None)
+    s.realize()
+    args = s._step._span_args()
+    assert tuple(s.dd.mesh_dim()) == (2, 2, 1), s.dd.mesh_dim()
+    assert (args["route"], args["wired"], args["wrapped"], args["wired_edges"]) == (
+        "plane", "xy", "z", "xy"), args
+    assert (args["plane_window"], args["plane_strip"]) == ("raw", 0), args  # y arrives over a wire
+    return _trace_step(s.dd, s._step)
+
+
 #: label -> builder of the ClosedJaxpr, at a CPU size under interpret
 MODEL_PROGRAMS = {
     "model:jacobi3d-512/wrap": _jacobi_wrap,
@@ -244,6 +266,7 @@ MODEL_PROGRAMS = {
     "model:acoustic-so8-1200x4/plane-r4": _acoustic_x4,
     "model:lbm-d3q19-256/wrap-m2": _lbm,
     "model:astaroth-mhd-256/plane-r3": _astaroth_mhd,
+    "model:astaroth-mhd-256x4/plane-r3": _astaroth_mhd_x4,
 }
 
 

@@ -122,6 +122,58 @@ def test_model_matches_the_reference_across_devices(mesh, steps):
     assert max(_errors(sim, ref.steps(sim.setup, _state(), steps)).values()) < TOL
 
 
+#: a weak-scaled grid as ``astaroth-mhd-256x4`` has it: x = y = 2z cells on ONE
+#: cell, so the box has a side of its own an axis (4 pi x 4 pi x 2 pi at N = 16)
+WIDE = (2 * N, 2 * N, N)
+
+
+def _wide_setup():
+    return ref.MhdSetup(WIDE, box=tuple(2.0 * np.pi * n / N for n in WIDE), max_waves=2)
+
+
+def _wide_sim(mesh, impl):
+    if ("wide", mesh, impl) not in _SIMS:
+        sim = AstarothMHD(*WIDE, setup=_wide_setup(), interpret=True, seed_words=None,
+                          kernel_impl=impl, devices=jax.devices()[: int(np.prod(mesh))])
+        sim.dd.set_partition(*mesh)
+        sim.realize()
+        _SIMS["wide", mesh, impl] = sim
+    return _SIMS["wide", mesh, impl]
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3, 4])
+@pytest.mark.parametrize("mesh,impl", [((2, 2, 1), "pallas"), ((2, 2, 2), "pallas"), ((2, 2, 1), "jnp")])
+def test_a_box_with_a_side_of_its_own_an_axis_matches_the_reference(mesh, impl, steps):
+    """32 x 32 x 16 cells on a uniform cell (ISSUE 47: weak scaling keeps the
+    CELL, the box grows with the grid), plane route and XLA slice engine, on
+    the meshes where the x-y edge halo crosses two wires: every cell of all
+    sixteen quantities after 1-4 time steps, a step a dispatch."""
+    setup = _wide_setup()
+    assert setup.spacing == (2.0 * np.pi / N,) * 3 and ref.dt_of(setup) == ref.dt_of(_setup())
+    sim = _wide_sim(mesh, impl)
+    state = ref.global_fields(setup, np.asarray(WORDS, dtype=np.uint32))
+    _load(sim, state)
+    for _ in range(steps):
+        sim.step(1)
+    assert tuple(sim.dd.mesh_dim()) == mesh
+    assert max(_errors(sim, ref.steps(setup, state, steps)).values()) < TOL
+
+
+def test_a_box_is_one_side_or_three():
+    """A float keeps its meaning (every axis that side: the one-chip cell's
+    set-up, its program and its fingerprint do not move); a triple is a side
+    an axis, hashable as the float is (``_substeps`` caches on the set-up)."""
+    cube = ref.MhdSetup((8, 16, 32))
+    assert cube.box == 2.0 * np.pi and cube.sides == (cube.box,) * 3
+    assert cube.spacing == (cube.box / 8, cube.box / 16, cube.box / 32)
+    wide = ref.MhdSetup((8, 16, 32), box=[1.0, 2, 4.0])
+    assert wide.box == wide.sides == (1.0, 2.0, 4.0) and wide.spacing == (0.125,) * 3
+    assert hash(wide) == hash(ref.MhdSetup((8, 16, 32), box=(1.0, 2.0, 4.0)))
+    assert ref.dt_of(wide) == wide.courant * 0.125 / (wide.cs0 + np.sqrt(3.0) * wide.amplitude)
+    with pytest.raises(ValueError, match="one side or one an axis"):
+        ref.MhdSetup((8, 8, 8), box=(1.0, 2.0))
+
+
 def test_the_seeded_state_matches_the_reference_and_takes_the_seed_as_an_argument():
     """The seeded plane waves through ``fill(args=)``: one compiled fill a
     quantity serves every seed (``init_by_coords(args=)``), every field
@@ -393,6 +445,65 @@ def test_the_span_says_what_a_staged_renaming_step_does():
     assert said == {"label": "astaroth-mhd", "steps": 2, **sim._step._span_args()}
 
 
+def _stage_sends(sim, steps=1):
+    """Per stage and swept axis, the bytes of the ``ppermute`` equations of the
+    traced step under ``step.stage.<k>/.../exchange.<axis>``."""
+    from stencil_tpu.analysis import jaxpr as jx
+
+    closed = jax.make_jaxpr(sim._step._resilience.built(), static_argnums=1)(sim.dd._curr, steps)
+    sent = {}
+    for e in jx.iter_eqns(closed):
+        if e.primitive.name != "ppermute":
+            continue
+        stack = jx.name_stack_str(e)
+        (k,) = [k for k in range(ref.SUBSTEPS) if tm.step_stage_span(k) in stack.split("/")]
+        (axis,) = [a for a in "xyz" if tm.exchange_axis_span(a) in stack.split("/")]
+        nbytes = sum(int(np.prod(v.aval.shape)) * v.aval.dtype.itemsize for v in e.invars)
+        sent[k, axis] = sent.get((k, axis), 0) + nbytes
+    return sent
+
+
+@pytest.mark.parametrize("chip_sweeps", [False, True])
+def test_the_span_says_what_crosses_the_wires_stage_by_stage(chip_sweeps, monkeypatch):
+    """``domain.step`` on mesh [2,2,1] (ISSUE 47): ``wired`` "xy", ``wrapped``
+    "z" with the sweeps as the chip has them ("" as the CPU has them), the
+    raw window and no strip beside a y halo that arrives over a wire,
+    ``wire_bytes`` = the bytes of the traced ``ppermute``s, which sit under
+    ``step.stage.<k>/exchange.x|y`` -- three equal stages, as
+    ``wire_bytes_by_stage`` says --, and ``wired_edges`` "xy": the mixed
+    differences read ``sh(+-k, +-k, 0)``, an edge that reaches a shard over
+    two wires in turn."""
+    if chip_sweeps:
+        monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+        sim = AstarothMHD(*WIDE, setup=_wide_setup(), interpret=True, seed_words=None,
+                          devices=jax.devices()[:4])
+        sim.realize()  # the partitioner's own pick
+    else:
+        sim = _wide_sim((2, 2, 1), "pallas")
+    assert tuple(sim.dd.mesh_dim()) == (2, 2, 1)
+    said = sim._step._span_args()
+    raw = N + 2 * RADIUS
+    stage = 2 * 8 * 2 * RADIUS * raw * raw * 4  # two axes, eight fields, six planes of the raw block
+    assert (said["wired"], said["wrapped"]) == ("xy", "z" if chip_sweeps else "")
+    assert (said["plane_window"], said["plane_strip"], said["exchanged"]) == ("raw", 0, "8/8/8")
+    assert said["wire_bytes"] == 3 * stage == 557_568
+    assert said["wire_bytes_by_stage"] == "/".join([str(stage)] * 3)
+    assert said["wired_edges"] == "xy"
+    sent = _stage_sends(sim)  # every send inside its stage, under its sweep's scope
+    # (the CPU's sweeps also "send" the unsplit z axis's wrap to the shard itself: no wire)
+    assert sorted(sent) == [(k, a) for k in range(3) for a in ("xy" if chip_sweeps else "xyz")]
+    assert [sum(sent[k, a] for a in "xy") for k in range(3)] == [stage] * 3
+    assert sum(v for (_, a), v in sent.items() if a in "xy") == said["wire_bytes"]
+    # one split axis, or none: no edge crosses two wires
+    line = _shared(mesh=(2, 1, 1))._step._span_args()
+    assert (line["wired"], line["wired_edges"]) == ("x", "")
+    assert line["wire_bytes_by_stage"] == "/".join([str(stage // 2)] * 3)
+    alone = _shared()._step._span_args()
+    assert (alone["wired"], alone["wired_edges"], alone["wire_bytes_by_stage"]) == ("", "", "0/0/0")
+    # ... and all three pairs where all three axes are split
+    assert _shared(mesh=(2, 2, 2))._step._span_args()["wired_edges"] == "xy/xz/yz"
+
+
 def test_the_counter_is_registered_and_the_names_lint_passes():
     import inspect
 
@@ -401,10 +512,12 @@ def test_the_counter_is_registered_and_the_names_lint_passes():
     registered = inspect.getsource(tm).split('SPAN_STEP = "domain.step"')[0]
     assert "steps_per_trip" in registered and "plane_window" in registered
     assert "plane_strip" in registered
+    assert "wire_bytes_by_stage" in registered and "wired_edges" in registered
     assert lint.run_lint(select=["telemetry-name"]) == []
     with open(os.path.join(ROOT, "docs", "observability.md")) as f:
         said = f.read()
     assert "`steps_per_trip`" in said and "`plane_window`" in said and "`plane_strip`" in said
+    assert "`wire_bytes_by_stage`" in said and "`wired_edges`" in said
 
 
 def test_the_step_loop_brings_every_carry_home():
@@ -450,3 +563,22 @@ def test_driver_runs_on_the_cpu(capsys, tmp_path):
     (said,) = [l for l in io.err.splitlines() if l.startswith("mesh: ")]
     assert "route='plane'" in said and "stages=3" in said and "renamed=8/8/8" in said, said
     assert out.exists() and out.stat().st_size > 0
+    # the host's eight devices: every axis split, every edge over two wires
+    assert said.startswith("mesh: 2,2,2 wired='xyz' ") and "wired_edges='xy/xz/yz'" in said, said
+
+
+def test_driver_keeps_the_cell_on_a_weak_scaled_grid(capsys, monkeypatch):
+    """``stencil-astaroth-mhd 32 32 16`` on four devices: the partitioner's
+    own mesh 2,2,1, the CELL of ``16 16 16`` (so its time step), and what
+    crosses the wires on stderr as ``stencil-acoustic`` says it."""
+    from stencil_tpu.bin import astaroth_mhd
+
+    monkeypatch.setattr(jax, "devices", lambda *a, real=jax.devices: real(*a)[:4])
+    rc = astaroth_mhd.main(["32", "32", "16", "--iters", "1", "--steps", "2"])
+    assert rc == 0
+    io = capsys.readouterr()
+    row = io.out.strip().splitlines()[-1].split(",")
+    assert row[3:6] == ["32", "32", "16"] and float(row[-1]) > 0
+    assert abs(float(row[6]) - ref.dt_of(_setup())) < 1e-15  # the cube's time step
+    (said,) = [l for l in io.err.splitlines() if l.startswith("mesh: ")]
+    assert said.startswith("mesh: 2,2,1 wired='xy' wrapped='' wire_bytes=557568 wired_edges='xy'"), said

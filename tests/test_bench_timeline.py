@@ -124,7 +124,7 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
         assert m["moves"] == "mcells_per_s_chip", name
     # PR 44's: the MHD step's shares and its two rooflines, their files listing the cell by name
     # (tests/test_bench_mhd.py holds them)
-    mhd = {n for n in declared if n.endswith(".mhd") or n.startswith("mhd_pass_")}
+    mhd = {n for n in declared if n.endswith(".mhd") or n in ("mhd_pass_hbm_pct", "mhd_pass_flops_pct")}
     assert len(mhd) == 8
     for name in mhd:
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
@@ -133,7 +133,20 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
                                 "span_percentile", "span_count"), name
         assert m["cells"] == ["astaroth-mhd-256.bulk"] == declared[name]["workloads"], name
         assert m["moves"] == "mcells_per_s_chip", name
-    for name in set(declared) - new - plane - staged - setup - wired - lbm - mhd - (ragged - {"collective_pct.ragged"}):
+    # PR 47's: the MHD step across chips, their files listing the cell by name
+    # (tests/test_bench_mhd_x4.py holds them)
+    mhdx4 = {n for n in declared if n.endswith(".mhdx4")}
+    assert len(mhdx4) == 12
+    for name in mhdx4:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["reducer"] in ("trace_share", "named_share", "named_roofline_hbm", "named_roofline_flops",
+                                "span_percentile", "span_count"), name
+        assert m["cells"] == ["astaroth-mhd-256x4.bulk"] == declared[name]["workloads"], name
+        assert m["moves"] == "mcells_per_s_chip", name
+    mhdx4 -= {"collective_pct.mhdx4"}  # a trace_share: it reads opcodes, as PR 24's do
+    for name in (set(declared) - new - plane - staged - setup - wired - lbm - mhd - mhdx4
+                 - (ragged - {"collective_pct.ragged"})):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
                                                "trace_roofline_hbm", "trace_idle"), name

@@ -605,6 +605,56 @@ def test_compiled_mhd_step_matches_the_xla_engine(shape, window):
     assert moved > 1e-4, moved  # every quantity advanced between the two readings
 
 
+def test_compiled_mhd_step_across_four_chips():
+    """Astaroth's MHD step decomposed over four chips (ISSUE 47), run by hand on
+    a four-chip host at a small size whose raw planes are whole tiles (32 x 58 x
+    122 a chip, 64 x 116 x 122 on mesh [2,2,1], a uniform cell): the compiled
+    plane step -- three staged exchanges a time step over ICI, the eight
+    fields' radius-3 x and y halos and the x-y edge halo that crosses two wires
+    in turn, the z wrap inside the pass, eight renames a stage inside a loop
+    body that holds collectives -- against the XLA slice engine on the same
+    mesh, on EVERY RAW CELL of all sixteen quantities, shells included (after
+    ``dd.exchange()``: the plane step leaves the shells of what it does not
+    read stale), after an even dispatch of 4 steps and an odd one of 3 behind
+    it.  The cell's ``correct`` compares interiors; this holds the wires to
+    every shell cell, the four shard edges among them."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs the four chips of one host")
+    from stencil_tpu.models.astaroth_mhd import AstarothMHD
+    from stencil_tpu.models.astaroth_mhd_reference import QUANTITIES, MhdSetup
+
+    shape = (64, 116, 122)
+    cell = 2.0 * np.pi / 122
+    setup = MhdSetup(shape, box=tuple(cell * n for n in shape))
+
+    def run(impl):
+        sim = AstarothMHD(*shape, setup=setup, devices=jax.devices()[:4], kernel_impl=impl,
+                          seed_words=(0x1234ABCD, 77, 0xDEADBEEF, 2024))
+        sim.dd.set_partition(2, 2, 1)
+        sim.realize()
+        seen = []
+        for n in (4, 3):
+            sim.step(n)
+            sim.dd.exchange()
+            seen.append({q: np.asarray(sim.dd._curr[q]) for q in QUANTITIES})
+        return getattr(sim._step, "_span_args", dict)(), seen
+
+    said, got = run("pallas")
+    assert (said["route"], said["stages"], said["renamed"], said["steps_per_trip"]) == (
+        "plane", 3, "8/8/8", 2), said
+    assert (said["wired"], said["wrapped"], said["wired_edges"]) == ("xy", "z", "xy"), said
+    assert (said["plane_window"], said["plane_strip"]) == ("raw", 0), said
+    stage = 8 * 6 * (64 + 38) * 128 * 4  # eight fields, six x planes and six y rows of the raw 38 x 64 x 128 block
+    assert said["wire_bytes_by_stage"] == "/".join([str(stage)] * 3), said
+    _, want = run("jnp")
+    for a, b in zip(got, want):
+        assert a["ux"].shape == (2 * 38, 2 * 64, 128)  # every shard's raw block, shells included
+        worst = max(float(np.abs(a[q] - b[q]).max()) for q in QUANTITIES)
+        assert np.isfinite(worst) and worst <= 3e-6, worst  # the cell's own limit
+    moved = min(float(np.abs(got[0][q] - got[1][q]).max()) for q in QUANTITIES)
+    assert moved > 1e-4, moved  # every quantity advanced between the two readings
+
+
 @pytest.mark.parametrize("storage", ["native", "bf16"])
 def test_compiled_interior_window_is_bitwise_the_raw_plane_pass(storage, monkeypatch):
     """The plane pass on its interior window (ISSUE 45) as Mosaic compiles it,

@@ -599,6 +599,68 @@ def test_mhd_step_carries_its_blocks_in_place(monkeypatch):
     assert not re.findall(r"=\s+f32\[262,262,262\]\S*\s+copy\(", text) and temp == 0
 
 
+@pytest.mark.slow  # tier-2 with its siblings: one real-TPU-compiler AOT
+# compile at the benchmark's size for all four chips (40 s alone: six passes of
+# ~840 operations over whole 99-vreg planes, the raw window)
+def test_mhd_step_over_four_chips_carries_its_blocks_in_place(monkeypatch):
+    """The four-chip MHD cell's 8-step program (512 x 512 x 256 on mesh [2,2,1],
+    256^3 a chip: ISSUE 47) as the chip's compiler leaves it: eight renames a
+    stage survive a loop body that holds collectives -- the ``while`` body is
+    two steps = SIX ``stream_plane_pass`` of eight aliased results and the
+    collectives of SIX exchanges, 24 ``collective-permute``s (x low / high,
+    then y low / high, the eight fields of a direction in ONE message), 96
+    ``blend_planes`` and 96 ``blend_slab`` --, no ``copy`` of a block (the
+    copies are the messages' relayouts) and nothing temporary.  Beside a y
+    halo that arrives over a wire the passes work on the RAW window, whole
+    planes: PR 45's interior window and PR 46's strips are the one-chip
+    cell's (ROADMAP X11).  The check ISSUE 47 asks for before any chip call."""
+    from stencil_tpu.models.astaroth_mhd import RADIUS, AstarothMHD
+    from stencil_tpu.models.astaroth_mhd_reference import MhdSetup
+    from stencil_tpu.ops import halo_blend
+    from stencil_tpu.ops import stream as sm
+
+    devices = _topology_devices()
+    monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        shape = (512, 512, 256)
+        cell = MhdSetup((256,) * 3).box / 256  # the one-chip cell's: the box grows with the grid
+        setup = MhdSetup(shape, box=tuple(cell * n for n in shape))
+        sim = AstarothMHD(*shape, setup=setup, devices=devices, seed_words=None)
+        sim.dd.realize(allocate=False)
+        assert tuple(sim.dd.mesh_dim()) == (2, 2, 1)  # the partitioner's own pick
+        stages = tuple(sim._substep(s) for s in range(3))
+        plan = sp.plan_stream(sim.dd, RADIUS, "auto", False)
+        plan = sp.resolve_stream_plan(sim.dd, stages, RADIUS, plan, False)
+        step = sm._build_stream_step(sim.dd, stages, RADIUS, plan, interpret=False)
+        compiled = step.lower(sim.dd.abstract_arrays(), 8).compile()
+        text, temp = compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    assert (plan["route"], plan["pass_wrap_axes"], plan["wired"], plan["steps_per_trip"]) == (
+        "plane", "z", "xy", 2), plan
+    assert (plan["plane_window"], plan["plane_strip"]) == ("raw", 0)
+    stage_bytes = 2 * 8 * 6 * 262 * 262 * 4
+    assert plan["wire_bytes_by_stage"] == (stage_bytes,) * 3 and plan["wire_bytes"] == 79_077_888
+    assert plan["wired_edges"] == ("xy",)
+    assert [len(p["renames"]) for st in plan["stages"] for p in st["passes"]] == [8, 8, 8]
+    calls = [l.lstrip() for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
+    passes = [l for l in calls if l.startswith("%stream_plane_pass")]
+    assert len(passes) == 6  # a trip of the loop is two steps of three stages
+    for l in passes:
+        assert l.split(" custom-call(")[0].count("f32[262,262,262]") == 8
+        aliasing = l[l.index("output_to_operand_aliasing="):].split("}, ")[0]
+        assert aliasing.count("(") == 8, aliasing
+    assert len([l for l in calls if l.startswith("%blend_planes")]) == 96
+    assert len([l for l in calls if l.startswith("%blend_slab")]) == 96
+    assert len(calls) == 6 + 96 + 96  # and no other kernel
+    assert len(re.findall(r"=.*collective-permute-start\(", text)) == 24
+    assert not re.findall(r"=\s+f32\[262,262,262\]\S*\s+copy\(", text) and temp == 0
+    copied = set(re.findall(r"=\s+(f32\[[\d,]+\])\S*\s+copy\(", text))
+    assert copied <= {"f32[1,262,3,262]", "f32[1,1,786,262]", "f32[8,262,3,262]", "f32[8,1,786,262]"}, copied
+
+
 @pytest.mark.slow  # tier-2 with its siblings: real-TPU-compiler AOT compiles
 # at the benchmark's size, three of them (5-6 s each)
 def test_lbm_macro_loop_carries_its_blocks_in_place(monkeypatch):

@@ -962,7 +962,9 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         # ... and the plane its passes work on: the block's "interior" where
         # they fill both in-plane halos themselves and it is whole vector
         # tiles (every in-plane shift one rotate, its wraparound the halo),
-        # the "raw" shell-carrying plane elsewhere (plane_window_form)
+        # "interior-z" where they fill z alone beside a split y (the
+        # neighbours' y halo rows ride in the strip form's margin tiles), the
+        # "raw" shell-carrying plane elsewhere (plane_window_form)
         args["plane_window"] = plan["plane_window"]
         # ... and the rows of it their kernel is evaluated over at a time: a
         # y shift is then the address of a tile and a value a few vregs; 0 =

@@ -204,15 +204,17 @@ def plane_strip_rows(window: str, plane: Tuple[int, int], dtypes, x_radius: int)
     """The rows ``S`` of a strip of ``stream_plane_pass``'s strip form over the
     ``plane = (Yw, Zw)`` working plane, 0 where the pass evaluates its kernel
     over the plane whole: read off the window, the plane and the read distance
-    alone.  The strip form exists on the ``"interior"`` window only (there the
-    wraparound is the halo on both in-plane axes and the plane is whole tiles)
-    and where the plane holds ``x_radius`` or more tiles of rows (a y shift
-    then wraps around the tiles once at most).  A strip is ``G`` of the
+    alone.  The strip form exists on the two ALIGNED windows only,
+    ``"interior"`` and ``"interior-z"`` (``plane_window_form``: there the
+    working plane is whole tiles, and the rotate's wraparound the halo on the
+    axes the pass fills itself; a raw plane -- a split z, ragged lanes or rows
+    -- is neither), and where the plane holds ``x_radius`` or more tiles of rows
+    (a y shift then wraps around the tiles once at most).  A strip is ``G`` of the
     plane's ``K = Yw / T`` tiles (``T`` the sublane tile of the stored
     dtypes), ``S = G T`` rows: ``G`` divides ``K`` and makes a value of the
     kernel ``_STRIP_VREGS`` vregs (``G x Zw / 128``) or, on a plane too wide
     for that, one tile.  ``domain.step`` says it as ``plane_strip``."""
-    if window != "interior":
+    if window == "raw":
         return 0
     yw, zw = plane
     tile = sublane_tile(dtypes)
@@ -228,22 +230,35 @@ def plane_strip_rows(window: str, plane: Tuple[int, int], dtypes, x_radius: int)
 def plane_window_form(wrap_fills, lo: Dim3, hi: Dim3, plane: Tuple[int, int], dtypes) -> str:
     """The working plane of ``stream_plane_pass`` over ``plane = (Y, Z)`` raw
     planes stored as ``dtypes``, read off what the pass is told and the static
-    shapes alone: ``"interior"`` where the pass makes BOTH in-plane halo fills
+    shapes alone.  ``"interior"`` where the pass makes BOTH in-plane halo fills
     itself, each the self-wrap of the block's whole interior (``wrap_fills``
     as ``pass_wrap_fills`` gives them where the mesh splits neither y nor z),
     AND that interior is whole vector tiles of every stored dtype (8 sublanes
-    of f32, 16 of a 2-byte dtype, by 128 lanes); ``"raw"`` everywhere else.
-    ``domain.step`` says it as ``plane_window``."""
+    of f32, 16 of a 2-byte dtype, by 128 lanes).  ``"interior-z"`` where the
+    fills are the z self-wrap ALONE (the mesh splits y: the y halo is a
+    neighbour's rows, real data that arrived over a wire), the interior is
+    whole tiles as above, and the y shell is one the pass's tile layout
+    carries: ``lo.y + hi.y`` rows that fit one sublane tile (they ride as the
+    last sublane of that many margin tiles) and are no more than the plane has
+    tiles.  ``"raw"`` everywhere else: a z the mesh splits (the z halo is then
+    no rotate's wraparound), an interior of ragged lanes or rows (600: nothing
+    of it is whole tiles, and cutting a plane at an unaligned row or lane costs
+    more than the dead lanes do), a y shell wider than a tile, a plane of fewer
+    tiles than its y shell has rows.  ``domain.step`` says it as
+    ``plane_window``."""
     yi, zi = plane[0] - lo.y - hi.y, plane[1] - lo.z - hi.z
-    self_wrap = (
-        (1, 0, yi, lo.y), (1, lo.y + yi, lo.y, hi.y),
-        (2, 0, zi, lo.z), (2, lo.z + zi, lo.z, hi.z),
-    )
-    whole = yi % sublane_tile(dtypes) == 0 and zi % 128 == 0
+    wrap_y = ((1, 0, yi, lo.y), (1, lo.y + yi, lo.y, hi.y))
+    wrap_z = ((2, 0, zi, lo.z), (2, lo.z + zi, lo.z, hi.z))
+    tile = sublane_tile(dtypes)
+    whole = yi % tile == 0 and zi % 128 == 0
     # ... and no narrower than the shell it stands in for (the rows and lanes
     # past the window repeat its first ``lo + hi``)
-    whole = whole and yi >= lo.y + hi.y > 0 and zi >= lo.z + hi.z > 0
-    return "interior" if whole and tuple(wrap_fills) == self_wrap else "raw"
+    whole = whole and zi >= lo.z + hi.z > 0
+    if whole and tuple(wrap_fills) == wrap_y + wrap_z and yi >= lo.y + hi.y > 0:
+        return "interior"
+    if whole and tuple(wrap_fills) == wrap_z and 0 < lo.y + hi.y <= min(tile, yi // tile):
+        return "interior-z"
+    return "raw"
 
 
 def _wrap_fill(ref, fills):
@@ -342,9 +357,10 @@ def stream_plane_pass(
     # (trace_plane_kernel): a time level renamed instead of copied
     window: str = "raw",  # the working plane (plane_window_form): the "raw"
     # plane, or the block's "interior" (rotated onto the block's aligned
-    # corner) where the fills are its own self-wrap
+    # corner) where the fills are its own self-wrap, or "interior-z" where
+    # they are its z self-wrap alone and the y halo rows are a neighbour's
     strip: int = 0,  # rows of a strip the kernel is evaluated over at a time
-    # (plane_strip_rows; the interior window only); 0 = over the plane whole
+    # (plane_strip_rows; the two aligned windows only); 0 = over the plane whole
     prerotated: Sequence[Tuple[str, int, int]] = (),  # the strip form: the
     # ``(quantity, dx, dz)`` whose plane is rotated by ``dz`` lanes ONCE a grid
     # step, for every strip and every ``dy`` that reads it (shared_rotations)
@@ -438,7 +454,7 @@ def stream_plane_pass(
     lo.z:]``, costs a sublane and a lane shift of every vreg of every plane
     in and out: 2.4 ms of a 9.6 ms MHD pass, PERF.md PR 45.)
 
-    With ``strip = S > 0`` (``plane_strip_rows``: the interior window only)
+    With ``strip = S > 0`` (``plane_strip_rows``: the two aligned windows only)
     the kernel is not evaluated over the working plane whole but a STRIP of it
     at a time: inside a grid step a loop runs over the plane's strips,
     ``kernel(views, info)`` is traced ONCE, over ``StripView``s, and a value of
@@ -474,6 +490,53 @@ def stream_plane_pass(
     whole-plane form's, and so is every value: the same operations in the same
     order on every cell, bitwise.  With ``strip = 0`` the pass is, operation
     for operation, the one above.
+
+    With ``window="interior-z"`` (``plane_window_form``: the fills are the z
+    self-wrap of the block's whole z interior ALONE, because the mesh splits
+    y; the interior is whole vector tiles; the strip form only) the y halo of a
+    loaded plane is no copy of cells of that plane but a neighbour's rows,
+    which the step's exchange has put into the block, and the working plane is
+    aligned all the same.  Along z nothing changes: the LOW z fill is made in
+    the pipeline's input buffer for every quantity over EVERY row, the y halo
+    rows included (the y-z corner of the x -> y -> z sweep order: the y halo
+    has arrived first), lanes ``[0, Zi)`` of the block are then the z interior
+    rotated by ``lo.z``, and a ``dz`` shift is one native lane rotate whose
+    wraparound is the z halo.  Along y NOTHING IS CUT at an unaligned row: the
+    ``K = Yi / T`` tiles are taken over RAW rows ``[0, Yi)`` -- rows ``[0,
+    lo.y)`` of them the low halo, real cells of the extended plane, the rest
+    interior rows ``[0, Yi - lo.y)`` -- by the same transposition, and sit at
+    tiles ``[0, K)`` of a scratch plane.  Behind them stand ``M = lo.y + hi.y``
+    margin tiles: tile ``K + m`` is tile ``m`` one sublane up, as the interior
+    window's high margin is, EXCEPT its last sublane, which is raw row ``Yi +
+    m`` -- one of the block's tail rows ``[Yi, Y)``, its last ``lo.y`` interior
+    rows and its high halo, copied there row by row from their static,
+    tile-aligned place -- where the interior window has the periodic wrap onto
+    row ``m``.  Tile ``k`` then holds raw rows ``s K + k`` for every ``k`` in
+    ``[0, K + M)``, so tiles ``[lo.y, lo.y + K)`` hold every interior row
+    exactly once; the strips run over THOSE ``K`` tiles (the interior window's
+    trip count and loop body, ``first[dy]`` counted from ``lo.y`` where it
+    counts from ``r`` there) and read tiles ``[lo.y - r, lo.y + K + r)``, all
+    there.  No low margin is made, and with ``lo.y = hi.y = r`` the scratch
+    shapes, and so the VMEM model, are the interior window's.  A plane of
+    ``prerotated`` is rotated tiles and margins at once (the margins hold rows
+    of their own).  Outputs mirror the inputs (``put_carried``): the staged
+    tiles below ``K`` go out as they stand; the ``lo.y`` past it go one sublane
+    down onto tiles ``[0, lo.y)``, whose sublane 0 -- a low y halo row -- passes
+    through from the centre plane; what that shift drops off their last
+    sublane are the tail rows ``Yi + m``, and the high halo rows behind those
+    pass through from the centre plane too; the z shell of the stored plane is
+    rebuilt from its first ``lo.z + hi.z`` lanes over every row, as on the
+    interior window.  So a stored plane holds the kernel's values on its
+    interior, the centre plane's y halo rows (bitwise the raw window's: the
+    exchange owns them) and a z shell that is the wrap of what was stored.
+    Pass-through planes (x-shell planes, which hold the x neighbour's cells,
+    and plane 0) carry their tail rows along (``tail_rows``).  No read changes
+    value and the kernel evaluates the same operations in the same order:
+    interiors are bitwise the raw window's.  What the window cannot carry
+    stays ``"raw"`` (``plane_window_form``): a y shell of more rows than a
+    sublane tile or than the plane has tiles, a z the mesh splits (its halo is
+    no wraparound of the block's own lanes), ragged lanes or rows (the three
+    600-extent cells).
 
     Returns one array per quantity, but only the ``writers`` are OUTPUTS of
     the Pallas call: every quantity is an input with its ring and its view,
@@ -573,14 +636,17 @@ def stream_plane_pass(
         q for q in range(nq)
         if wrap_fills and (halo_readers is None or names[q] in halo_readers)
     ]
-    assert window in ("raw", "interior"), window
+    assert window in ("raw", "interior", "interior-z"), window
     interior = window == "interior"
-    assert not interior or fused_shell is None
-    assert not interior or plane_window_form(
+    carried = window == "interior-z"  # the y halo rows ride in the tiles
+    aligned = interior or carried
+    assert not aligned or fused_shell is None
+    assert not aligned or plane_window_form(
         wrap_fills, lo, hi, (Y, Z), [b.dtype for b in raws]
-    ) == "interior", (wrap_fills, lo, hi, (Y, Z))
+    ) == window, (wrap_fills, lo, hi, (Y, Z))
+    assert strip or not carried, window  # (the strip form only)
     # the working plane: what the rings hold and the kernel's windows are
-    Yw, Zw = (y1 - y0, z1 - z0) if interior else (Y, Z)
+    Yw, Zw = (y1 - y0, z1 - z0) if aligned else (Y, Z)
     low_fills = [f for f in wrap_fills if f[1] == 0]  # the fills of the LOW halos
     # the strip form: every quantity's planes sit in the scratch as ``K + 2r``
     # TILES of ``T`` rows -- tile ``k`` holds rows ``k, K + k, 2K + k, ...`` of
@@ -590,8 +656,15 @@ def stream_plane_pass(
     # gathered in a staging plane of tiles before they go out
     T = sublane_tile([b.dtype for b in raws])
     K, G = Yw // T, strip // T
-    assert not strip or (interior and strip == G * T and K % G == 0 and r <= K), (
+    assert not strip or (aligned and strip == G * T and K % G == 0 and r <= K), (
         strip, window, Yw, T)
+    # where the plane's ``K`` tiles sit among a scratch plane's ``KT``, and the
+    # tile the strips' first output tile is: between ``r`` margin tiles a side,
+    # every tile an output -- or, where the y halo rows ride in the tiles, from
+    # tile 0 on, ``M = lo.y + hi.y`` margin tiles behind them, the outputs the
+    # ``K`` tiles from ``lo.y`` on (tile ``k`` holds RAW rows ``s K + k``)
+    M = lo.y + hi.y
+    t0, base, KT = (0, lo.y, K + M) if carried else (r, r, K + 2 * r)
     depth = 2 * r + 1 if strip else 2 * r
     held = list(range(nq)) if strip else ringed
     pre = [(names.index(nm), dx, dz) for nm, dx, dz in prerotated] if strip else []
@@ -635,10 +708,12 @@ def stream_plane_pass(
         stage_refs = dict(zip(wq, refs[nq + len(wq) + len(held) :]))  # the strip form
         pre_refs = dict(zip(pre, refs[nq + 2 * len(wq) + len(held) :]))
         i = pl.program_id(0)
-        if interior:
+        if aligned:
             # every quantity, read off-centre or not: with its LOW halos
             # filled, the block's aligned (Yw, Zw) corner IS the interior,
-            # rotated by (lo.y, lo.z) -- whole tiles, nothing shifted
+            # rotated by (lo.y, lo.z) -- whole tiles, nothing shifted (beside a
+            # split y: rotated by lo.z alone, rows [0, Yw) of the plane the
+            # neighbours' halo rows extend)
             for ref in in_refs:
                 _wrap_fill(ref, low_fills)  # y before z
             # (the strip form reads a plane where it is used: loaded here it
@@ -667,18 +742,36 @@ def stream_plane_pass(
         # (row / lane ``k`` of either window is raw row / lane ``k``)
         y_g, z_g = _yz_coord_planes(origin_ref, Yw, Zw, lo.y, lo.z, gsize)
 
-        def put(out, v):
+        def put(out, v, tail=None):
             """The working plane ``v`` into the output block: the raw plane
             whole; the rotated interior onto the block's aligned corner --
             its interior cells and its low halos at once --, then the raw
             rows and lanes past it, which repeat the plane's first ``lo +
-            hi``: the y / z shell of the STORED plane, whole."""
-            if not interior:
+            hi``: the y / z shell of the STORED plane, whole.  Beside a split
+            y the rows past it are no repeat but the plane's own ``tail``
+            rows (``tail_rows``), one at a time."""
+            if not aligned:
                 out[0] = v
                 return
             out[0, :Yw, :Zw] = v
-            out[0, Yw:, :Zw] = v[: Y - Yw, :]
+            if carried:
+                for m, row in enumerate(tail):
+                    out[0, Yw + m : Yw + m + 1, :Zw] = row
+            else:
+                out[0, Yw:, :Zw] = v[: Y - Yw, :]
             out[0, :, Zw:] = out[0, :, : Z - Zw]  # every row: the y-z corner too
+
+        def tail_rows(q, t):
+            """Beside a split y: raw rows ``[Yw, Y)`` of quantity ``q``'s raw
+            plane ``i - t`` on the working plane's lanes -- its last ``lo.y``
+            interior rows and its high y halo -- as ``(1, Zw)`` rows, read from
+            the fetched block or from the last sublane of the ring plane's
+            margin tiles (``tail_margins``); None on the other windows."""
+            if not carried:
+                return None
+            if t == 0 or q not in ringed:  # (fetched lagged: the centre plane)
+                return [in_refs[q][0, Yw + m : Yw + m + 1, :Zw] for m in range(M)]
+            return [ring_refs[q][(i - t) % depth, K + m, T - 1 : T] for m in range(M)]
 
         # output plane j = i - r; window is raw planes j-r .. j+r
         j = i - r
@@ -698,7 +791,7 @@ def stream_plane_pass(
             if q not in ringed:  # fetched lagged: the centre plane alone
                 return curs[q] if t == r else None
             if strip:  # the tiles between the margins
-                return curs[q] if t == 0 else from_tiles(ring_refs[q][(i - t) % depth, r : r + K])
+                return curs[q] if t == 0 else from_tiles(ring_refs[q][(i - t) % depth, t0 : t0 + K])
             return curs[q] if t == 0 else ring_refs[q][(i - t) % (2 * r)]
 
         def push_tiles(q, slot, margins):
@@ -707,9 +800,13 @@ def stream_plane_pass(
             ends: tile ``K + m`` is tile ``m`` a sublane UP (row ``s K + K + m``
             is row ``(s + 1) K + m``; the last sublane wraps to row ``m`` of the
             plane: the y wraparound) and tile ``-m`` tile ``K - m`` a sublane
-            down."""
-            ring_refs[q][slot, r : r + K] = to_tiles(curs[q])
-            if margins:
+            down.  Beside a split y the margins are the plane's own next rows
+            (``tail_margins``), whoever reads it: its last ``lo.y`` interior rows
+            are among them."""
+            ring_refs[q][slot, t0 : t0 + K] = to_tiles(curs[q])
+            if carried:
+                tail_margins(ring_refs[q], slot, in_refs[q])
+            elif margins:
                 wrap_margins(ring_refs[q], (slot,))
 
         def wrap_margins(ref, at):
@@ -718,6 +815,17 @@ def stream_plane_pass(
             low, high = ref[(*at, slice(K, K + r))], ref[(*at, slice(r, 2 * r))]
             ref[(*at, slice(0, r))] = roll(low, 1, 1).astype(ref.dtype)
             ref[(*at, slice(r + K, K + 2 * r))] = roll(high, -1, 1).astype(ref.dtype)
+
+        def tail_margins(ref, slot, block):
+            """Beside a split y: the ``M`` margin tiles BEHIND the plane of tiles
+            ``ref[slot]``, which holds raw rows ``[0, Yw)`` of ``block``: tile ``K
+            + m`` is tile ``m`` a sublane up, as above, but its last sublane is
+            raw row ``Yw + m`` of the block -- the plane goes on where the
+            periodic one wraps around -- so tiles ``[lo.y, lo.y + K)`` hold the
+            interior rows, each once, and tiles ``[0, K + M)`` every raw row."""
+            ref[slot, K : K + M] = roll(ref[slot, :M], -1, 1).astype(ref.dtype)
+            for m in range(M):
+                ref[slot, K + m, T - 1 : T] = block[0, Yw + m : Yw + m + 1, :Zw]
 
         if strip and ringed:  # FIRST: the strips read the newest plane there too
 
@@ -788,6 +896,9 @@ def stream_plane_pass(
             # a plane in one rotate, its margins as push_tiles makes them
             for (q, dx, dz), rotated in pre_refs.items():
                 slot = (j + dx) % depth if q in ringed else 0
+                if carried:  # the margins hold rows of their own: rotated with the rest
+                    rotated[...] = roll(up(ring_refs[q][slot]), -dz, 2).astype(rotated.dtype)
+                    continue
                 tiles = roll(up(ring_refs[q][slot, r : r + K]), -dz, 2)
                 rotated[r : r + K] = tiles.astype(rotated.dtype)
                 wrap_margins(rotated, ())
@@ -795,10 +906,12 @@ def stream_plane_pass(
             # row ``s K + g`` of the plane at row ``g T + s`` of a strip
             f = lax.broadcasted_iota(jnp.int32, (strip, 1), 0)
             rows0 = (f % T) * K + f // T
+            if base != t0:  # (output tile ``k`` holds raw rows ``s K + lo.y + k``)
+                rows0 = rows0 + (base - t0)
 
             def one(k, carry):
                 k0 = k * G
-                first = {dy: k0 + (r + dy) for dy in range(-r, r + 1)}
+                first = {dy: k0 + (base + dy) for dy in range(-r, r + 1)}
                 # (made INSIDE the loop: carried in from outside, the seven
                 # scalars cost the MHD loop 10% -- PERF.md PR 46, call 7)
                 slots = {dx: (j + dx) % depth for dx in range(-r, r + 1)}
@@ -823,7 +936,29 @@ def stream_plane_pass(
 
             lax.fori_loop(0, K // G, one, 0)
             for q, out in out_refs.items():
-                put(out, from_tiles(stage_refs[q][...]))
+                if carried:
+                    put_carried(out, q)
+                else:
+                    put(out, from_tiles(stage_refs[q][...]))
+
+        def put_carried(out, q):
+            """Beside a split y: the staged tiles of writer ``q`` -- tiles
+            ``[lo.y, lo.y + K)`` of the plane of tiles -- into the output block.
+            Those below ``K`` go out as they stand; the ``lo.y`` past it go one
+            sublane DOWN onto tiles ``[0, lo.y)`` (row ``s K + K + m`` is row ``(s
+            + 1) K + m``), where sublane 0, a low y halo row, passes through
+            from the centre plane; what falls off their last sublane are the
+            block's tail rows ``Yw + m``, and the high y halo rows behind those
+            pass through from the centre plane too."""
+            stage, centre = stage_refs[q], ring_refs[q]
+            slot = j % depth if q in ringed else 0  # the centre plane's
+            down = roll(stage[K - lo.y :], 1, 1).astype(stage.dtype)  # (lo.y, T, Zw)
+            sublane = lax.broadcasted_iota(jnp.int32, down.shape, 1)
+            head = jnp.where(sublane == 0, centre[slot, : lo.y], down)
+            tail = [down[m, 0:1] for m in range(lo.y)] + [
+                centre[slot, K + m, T - 1 : T] for m in range(lo.y, M)
+            ]
+            put(out, from_tiles(jnp.concatenate([head, stage[: K - lo.y]], axis=0)), tail)
 
         @pl.when(jnp.logical_and(i >= 1, i <= X + r - 1))
         def _():
@@ -859,12 +994,12 @@ def stream_plane_pass(
                     # (slot is garbage for i < r, where plane j < 0 doesn't
                     # exist — those writes land on out plane 0, which step
                     # i == r rewrites with the real pass-through)
-                    put(out, plane(q, r))
+                    put(out, plane(q, r), tail_rows(q, r))
 
         @pl.when(i == 0)
         def _():
             for q, out in out_refs.items():
-                put(out, curs[q])  # first plane passes through
+                put(out, curs[q], tail_rows(q, 0))  # first plane passes through
 
         # push the fetched plane (skip replayed last-plane refetches)
         if ring_refs and not strip:
@@ -938,10 +1073,10 @@ def stream_plane_pass(
         scratch_shapes=[
             pltpu.VMEM((2 * r, Yw, Zw), raws[q].dtype) for q in ringed
         ] if not strip else [
-            pltpu.VMEM((depth if q in ringed else 1, K + 2 * r, T, Zw), raws[q].dtype)
+            pltpu.VMEM((depth if q in ringed else 1, KT, T, Zw), raws[q].dtype)
             for q in held
         ] + [pltpu.VMEM((K, T, Zw), raws[q].dtype) for q in wq] + [
-            pltpu.VMEM((K + 2 * r, T, Zw), raws[q].dtype) for q, _, _ in pre
+            pltpu.VMEM((KT, T, Zw), raws[q].dtype) for q, _, _ in pre
         ],
         interpret=interpret,
         **_tpu_compiler_params(interpret),

@@ -22,7 +22,9 @@ Routes (``plan_stream`` chooses; ``ops/stream_pass.py`` has the passes):
   interior plane alone and the rotates' wraparound is the halo
   (``plane_window``, ``stream_pass.plane_window_form``), a heavy kernel
   evaluated a strip at a time (``plane_strip``, ``stream_pass.plane_strip_
-  rows``, ``_STRIP_MIN_OPS``).  A step may
+  rows``, ``_STRIP_MIN_OPS``); where it fills z alone, beside a y the mesh
+  splits, a heavy kernel's pass takes the aligned window all the same
+  (``"interior-z"``: the strip form's tiles carry the y halo rows).  A step may
   be several STAGES (a sequence of kernels, each behind its own exchange)
   and a stage several PASSES, each over the quantities its outputs touch:
   all planned from one abstract trace of each kernel (``plan_plane_stages``).
@@ -1059,7 +1061,11 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     schedule (``resolve_stream_plan``).  ``window`` is the passes' working
     plane (``plane_window_form``): the kernels are traced over planes of ITS
     shape -- the rotate a shift lowers to is chosen there, at trace time
-    (``_make_roll``) -- and the rings are priced at it.  ``strip`` is the rows
+    (``_make_roll``) -- and the rings are priced at it; ``keys["plane_window"]``
+    says the one the step is planned on, which is ``"raw"`` where
+    ``"interior-z"`` was asked for and no strip form comes of it (that window
+    has no whole-plane form: a light kernel beside a split y has no cell and
+    gets no code).  ``strip`` is the rows
     of a strip of the passes' strip form as the plane allows it
     (``plane_strip_rows``; 0 = the plane whole): the kernels are traced over
     such strips and the rings priced as the tiles they then hold -- unless the
@@ -1069,7 +1075,9 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     ``keys["plane_strip"]`` says 0."""
     names = [h.name for h in dd._handles]
     raw = dd.local_spec().raw_size()
-    work = dd.local_spec().sz if window == "interior" else raw
+    if window == "interior-z" and not strip:
+        window = "raw"  # beside a split y the aligned window has a strip form only
+    work = raw if window == "raw" else dd.local_spec().sz
     f32_acc = any(dd.field_dtype(h) != h.dtype for h in dd._handles)
     planes = [
         jax.ShapeDtypeStruct(
@@ -1080,7 +1088,9 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     # what a ring holds of a plane: in the strip form its tiles between
     # ``x_radius`` margin tiles a side, and a writer's staging plane beside
     # (``stream_plane_pass``)
-    margins = 2 * x_radius * sublane_tile([dd.field_dtype(h) for h in dd._handles]) if strip else 0
+    shell = dd._shell_radius
+    margins = (shell.lo().y + shell.hi().y) if window == "interior-z" else 2 * x_radius
+    margins = margins * sublane_tile([dd.field_dtype(h) for h in dd._handles]) if strip else 0
     plane_bytes, stage_bytes, ring_bytes = (
         {
             h.name: _padded_plane_bytes(y, of.z, dd.field_dtype(h).itemsize)
@@ -1127,7 +1137,7 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
         built.append(runs)
     keys = {
         "stages": tuple(described), "footprint": footprint_counts(traces), "plane_strip": strip,
-        "edge_reads": edge_reads(traces),
+        "edge_reads": edge_reads(traces), "plane_window": window,
     }
     for key, of in (
         ("halo_readers", lambda st: st["readers"]),
@@ -1312,8 +1322,11 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
             plan["pass_wrap_axes"], wrap_fills = pass_wrap_fills(dd, exch_route)
         # the passes' working plane (domain.step's ``plane_window``): the
         # block's interior where the fills above are its whole self-wrap on
-        # both axes and it is whole vector tiles, the raw plane elsewhere --
-        # read off the fills and the block's static shape alone
+        # both axes and it is whole vector tiles, the same aligned corner
+        # beside the neighbours' y halo rows where they are its z self-wrap
+        # alone, the raw plane elsewhere -- read off the fills and the
+        # block's static shape alone (plan_plane_stages has the last word: a
+        # window that exists in strips only is "raw" for a light kernel)
         shell = dd._shell_radius
         plan["plane_window"] = plane_window_form(
             wrap_fills, shell.lo(), shell.hi(), (raw.y, raw.z),

@@ -458,18 +458,24 @@ ALL_HISTOGRAMS = frozenset({
 #: interior, and that interior is whole vector tiles -- the working plane is
 #: the block's aligned corner, the interior rotated by the low shell widths,
 #: every in-plane shift is one native rotate whose wraparound is the halo
-#: --, "raw" = the shell-carrying plane, everywhere
-#: else (``ops/stream_pass.plane_window_form``, read off the fills and the
-#: block's static shape: "interior" in ``astaroth-mhd-256.bulk``, "raw" in the
-#: three 600-extent plane cells) and plane_strip = the rows ``S`` of that plane
+#: --, "interior-z" where they make the z fill alone (``wrapped`` "z": the
+#: mesh splits y) beside an interior of whole vector tiles -- the z halo is the
+#: lane rotates' wraparound as above, and the neighbours' y halo rows ride in
+#: the margin tiles of the strip form's tile layout, no plane cut at an
+#: unaligned row --, "raw" = the shell-carrying plane, everywhere
+#: else: a z the mesh splits, ragged lanes or rows, a light kernel beside a
+#: split y (``ops/stream_pass.plane_window_form``, read off the fills and the
+#: block's static shape: "interior" in ``astaroth-mhd-256.bulk``, "interior-z"
+#: in ``astaroth-mhd-256x4.bulk``, "raw" in the three 600-extent plane cells)
+#: and plane_strip = the rows ``S`` of that plane
 #: its passes evaluate their kernel over at a time -- a loop over the plane's
 #: strips inside a grid step, the planes held as tiles whose next row is the
 #: next tile (a y shift an address and no rotate), a value of the kernel ``S /
-#: 8 x Zw / 128`` vregs and not a whole plane's -- on the interior window and
-#: for a kernel of ``_STRIP_MIN_OPS`` or more operations a cell, 0 = the kernel
-#: runs over the plane whole (``ops/stream_pass.plane_strip_rows`` and
+#: 8 x Zw / 128`` vregs and not a whole plane's -- on the two aligned windows
+#: and for a kernel of ``_STRIP_MIN_OPS`` or more operations a cell, 0 = the
+#: kernel runs over the plane whole (``ops/stream_pass.plane_strip_rows`` and
 #: ``ops/stream_plan.plan_plane_stages``, read off the window, the plane and
-#: the kernels' traces: 16 in ``astaroth-mhd-256.bulk``, 0 in the three
+#: the kernels' traces: 16 in both ``astaroth-mhd-256`` cells, 0 in the three
 #: 600-extent plane cells); a
 #: z-slab wavefront step (``Jacobi3D``'s z-ring and
 #: lane-padded shell kernels, the stream engine's wavefront route with

@@ -241,17 +241,22 @@ def _astaroth_mhd_x4():
     from stencil_tpu.models.astaroth_mhd import AstarothMHD
     from stencil_tpu.models.astaroth_mhd_reference import MhdSetup
 
-    # x = y = 2z as the cell, on ITS cell (the box grown with the grid: a side
-    # an axis), the mesh the partitioner picks for it
-    shape = (32, 32, 16)
-    setup = MhdSetup(shape, box=tuple(2.0 * math.pi * n / 16 for n in shape))
+    # on mesh [2,2,1] as the cell, on a uniform cell (the box grown with the grid:
+    # a side an axis), at a shard that IS the cell's program in small (ISSUE 48):
+    # 8 x 64 x 128 -- an interior of whole tiles, eight tiles of rows for its
+    # six-row y shell, as the cell's 256 x 256 --, so the passes take the aligned
+    # window beside the split y, in strips
+    shape = (16, 128, 128)
+    setup = MhdSetup(shape, box=tuple(2.0 * math.pi * n / 128 for n in shape))
     s = AstarothMHD(*shape, setup=setup, interpret=True, devices=jax.devices()[:4], seed_words=None)
+    s.dd.set_partition(2, 2, 1)
     s.realize()
     args = s._step._span_args()
     assert tuple(s.dd.mesh_dim()) == (2, 2, 1), s.dd.mesh_dim()
     assert (args["route"], args["wired"], args["wrapped"], args["wired_edges"]) == (
         "plane", "xy", "z", "xy"), args
-    assert (args["plane_window"], args["plane_strip"]) == ("raw", 0), args  # y arrives over a wire
+    # y arrives over a wire, z is the rotates' wraparound: two strips of four tiles
+    assert (args["plane_window"], args["plane_strip"]) == ("interior-z", 32), args
     return _trace_step(s.dd, s._step)
 
 

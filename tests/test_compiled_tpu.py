@@ -655,6 +655,23 @@ def test_compiled_mhd_step_across_four_chips():
     assert moved > 1e-4, moved  # every quantity advanced between the two readings
 
 
+def _diagonal_r3_kernel(views, info):
+    """Radius 3 at full distance on every axis and on the y-z, x-y and x-z
+    diagonals, products of fields, the cells' own coordinates; ``c`` read along
+    y and z alone (fetched lagged), ``p`` at the centre, ``p <- u`` a rename."""
+    u, c = views["u"], views["c"]
+    _, y, z = info.coords()
+    acc = 0.3 * u.center() + 1e-3 * jnp.sin(0.1 * (y + 2 * z).astype(jnp.float32))
+    for k in (1, 2, 3):
+        acc = acc + (0.1 / k) * (
+            (u.sh(k, 0, 0) - 0.9 * u.sh(-k, 0, 0))
+            + (u.sh(0, k, k) - 0.8 * u.sh(0, -k, k)) * c.sh(0, k, 0)
+            + (u.sh(k, -k, 0) - 0.7 * u.sh(-k, k, 0))
+            + (u.sh(-k, 0, k) - 0.6 * u.sh(k, 0, -k)) * c.sh(0, 0, -k)
+        )
+    return {"u": acc + 0.5 * views["p"].center(), "p": u.center()}
+
+
 @pytest.mark.parametrize("storage", ["native", "bf16"])
 def test_compiled_interior_window_is_bitwise_the_raw_plane_pass(storage, monkeypatch):
     """The plane pass on its interior window (ISSUE 45) as Mosaic compiles it,
@@ -676,19 +693,7 @@ def test_compiled_interior_window_is_bitwise_the_raw_plane_pass(storage, monkeyp
     from stencil_tpu.ops import stream_plan as sp
 
     r = 3
-
-    def kern(views, info):
-        u, c = views["u"], views["c"]
-        _, y, z = info.coords()
-        acc = 0.3 * u.center() + 1e-3 * jnp.sin(0.1 * (y + 2 * z).astype(jnp.float32))
-        for k in (1, 2, 3):
-            acc = acc + (0.1 / k) * (
-                (u.sh(k, 0, 0) - 0.9 * u.sh(-k, 0, 0))
-                + (u.sh(0, k, k) - 0.8 * u.sh(0, -k, k)) * c.sh(0, k, 0)
-                + (u.sh(k, -k, 0) - 0.7 * u.sh(-k, k, 0))
-                + (u.sh(-k, 0, k) - 0.6 * u.sh(k, 0, -k)) * c.sh(0, 0, -k)
-            )
-        return {"u": acc + 0.5 * views["p"].center(), "p": u.center()}
+    kern = _diagonal_r3_kernel
 
     def run():
         dd = DistributedDomain(16, 64, 256)
@@ -724,3 +729,108 @@ def test_compiled_interior_window_is_bitwise_the_raw_plane_pass(storage, monkeyp
             assert np.isfinite(y).all() and np.array_equal(x, y), name
             assert np.array_equal(x, w), name
     assert float(np.abs(got[0][0] - got[1][0]).max()) > 1e-3  # the state moved
+
+
+@pytest.mark.parametrize("storage", ["native", "bf16"])
+def test_compiled_window_beside_a_split_y_is_bitwise_the_raw_plane_pass(storage):
+    """The plane pass on the aligned window beside a SPLIT y (ISSUE 48:
+    ``window="interior-z"``, the strip form) as Mosaic compiles it, ONE pass on
+    one chip against the same pass on the raw window: the blocks' y halo rows
+    hold data of their own (a neighbour's rows: random numbers, not the plane's
+    wrap), the pass is handed the z fills alone.  16 x 128 x 256 blocks (134 x
+    262 raw planes: sixteen tiles of f32 rows, eight of bf16; two lane tiles and
+    a ragged third, as the MHD cell's), strips of two tiles, the planes two
+    ``dy`` share rotated once a grid step.  Mosaic contracts nothing: every
+    interior cell BITWISE equal, the y halo rows of every stored plane and the
+    x-shell planes too (they pass through), and the stored z shell the
+    self-wrap of the stored plane."""
+    from stencil_tpu.core.dim3 import Dim3
+    from stencil_tpu.ops import stream_pass as spass
+
+    r, n, names = 3, (16, 128, 256), ["u", "c", "p"]
+    dtype = jnp.float32 if storage == "native" else jnp.bfloat16
+    shape = tuple(m + 2 * r for m in n)
+    rng = np.random.default_rng(48)
+    raws = [jnp.asarray(rng.standard_normal(shape), dtype) for _ in names]
+    fills = ((2, 0, n[2], r), (2, r + n[2], r, r))
+    lo = hi = Dim3(r, r, r)
+    assert spass.plane_window_form(fills, lo, hi, shape[1:], [dtype]) == "interior-z"
+    strip = spass.plane_strip_rows("interior-z", n[1:], [dtype], r)
+    assert strip == 2 * spass.sublane_tile([dtype])
+
+    def run(window, strip):
+        def fn(origin, *blocks):
+            return spass.stream_plane_pass(
+                _diagonal_r3_kernel, names, list(blocks), lo, hi, r, origin, Dim3(64, 4 * n[1], n[2]),
+                f32_accumulate=storage != "native", halo_readers=("u", "c"), rings=("u",),
+                writers=("u",), wrap_fills=fills, renames=(("p", "u"),), window=window, strip=strip,
+                prerotated=(("u", 0, 1), ("u", 0, 2), ("u", 0, 3), ("c", 0, -1)) if strip else (),
+            )
+
+        out = jax.jit(fn)(jnp.asarray([5, 7, 0], jnp.int32), *raws)
+        return [np.asarray(o.astype(jnp.float32)) for o in out]
+
+    got, want = run("interior-z", strip), run("raw", 0)
+    inner = tuple(slice(r, r + m) for m in n)
+    for name, a, b in zip(names, got, want):
+        assert np.isfinite(b).all() and np.array_equal(a[inner], b[inner]), name
+    u, u_raw = got[0], want[0]
+    assert np.array_equal(got[2], np.asarray(raws[0].astype(jnp.float32)))  # ``p`` is the old ``u``
+    for y_halo in (slice(0, r), slice(r + n[1], None)):
+        assert np.array_equal(u[:, y_halo], u_raw[:, y_halo])
+    for x_shell in (slice(0, r), slice(r + n[0], None)):
+        assert np.array_equal(u[x_shell], u_raw[x_shell])
+    assert np.array_equal(u[..., :r], u[..., n[2] : n[2] + r])  # the stored plane's own z wrap
+    assert np.array_equal(u[..., r + n[2] :], u[..., r : 2 * r])
+    assert not np.array_equal(u[inner], np.asarray(raws[0].astype(jnp.float32))[inner])
+
+
+def test_compiled_mhd_step_beside_a_split_y_across_four_chips(monkeypatch):
+    """Astaroth's MHD step on mesh [2,2,1] at a shard the aligned window beside
+    a split y takes (ISSUE 48; 16 x 64 x 128 a chip, 32 x 128 x 128 on a uniform
+    cell: interiors of whole tiles, eight tiles of rows for a six-row y shell),
+    run by hand on a four-chip host: the compiled plane step -- the z halo the
+    rotates' wraparound, the neighbours' y halo rows riding in the margin tiles,
+    the strips of four tiles -- against the XLA slice engine on every interior
+    cell of all sixteen quantities after an even dispatch and an odd one, and
+    BITWISE the same step built on the raw window (``plane_window_form``
+    patched to "raw": the parent's program)."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs the four chips of one host")
+    from stencil_tpu.models.astaroth_mhd import AstarothMHD
+    from stencil_tpu.models.astaroth_mhd_reference import QUANTITIES, MhdSetup
+    from stencil_tpu.ops import stream_plan as sp
+
+    shape = (32, 128, 128)
+    cell = 2.0 * np.pi / 128
+    setup = MhdSetup(shape, box=tuple(cell * n for n in shape))
+
+    def run(impl):
+        sim = AstarothMHD(*shape, setup=setup, devices=jax.devices()[:4], kernel_impl=impl,
+                          seed_words=(0x1234ABCD, 77, 0xDEADBEEF, 2024))
+        sim.dd.set_partition(2, 2, 1)
+        sim.realize()
+        seen = []
+        for n in (4, 3):
+            sim.step(n)
+            seen.append({q: sim.field(q) for q in QUANTITIES})
+        return getattr(sim._step, "_span_args", dict)(), seen
+
+    said, got = run("pallas")
+    assert (said["route"], said["stages"], said["renamed"], said["steps_per_trip"]) == (
+        "plane", 3, "8/8/8", 2), said
+    assert (said["wired"], said["wrapped"], said["wired_edges"]) == ("xy", "z", "xy"), said
+    assert (said["plane_window"], said["plane_strip"]) == ("interior-z", 32), said
+    _, want = run("jnp")
+    for a, b in zip(got, want):
+        worst = max(float(np.abs(a[q] - b[q]).max()) for q in QUANTITIES)
+        assert np.isfinite(worst) and worst <= 3e-6, worst  # the cell's own limit
+    moved = min(float(np.abs(got[0][q] - got[1][q]).max()) for q in QUANTITIES)
+    assert moved > 1e-4, moved  # every quantity advanced between the two readings
+    monkeypatch.setattr(sp, "plane_window_form", lambda *a: "raw")
+    said_raw, raw = run("pallas")
+    assert (said_raw["plane_window"], said_raw["plane_strip"]) == ("raw", 0), said_raw
+    for a, b in zip(got, raw):
+        for q in QUANTITIES:
+            assert np.array_equal(a[q], b[q]), q
+

@@ -640,7 +640,11 @@ def test_mhd_step_over_four_chips_carries_its_blocks_in_place(monkeypatch):
         jax.config.update("jax_enable_x64", x64_was)
     assert (plan["route"], plan["pass_wrap_axes"], plan["wired"], plan["steps_per_trip"]) == (
         "plane", "z", "xy", 2), plan
-    assert (plan["plane_window"], plan["plane_strip"]) == ("raw", 0)
+    # beside the y halo that arrives over a wire the passes take the aligned
+    # window in its strip form (ISSUE 48: the z halo the rotates' wraparound, the
+    # y halo rows in the margin tiles; the twin's scratch shapes, 97.0 MB)
+    assert (plan["plane_window"], plan["plane_strip"]) == ("interior-z", 16)
+    assert [len(p["prerotated"]) for st in plan["stages"] for p in st["passes"]] == [24, 24, 24]
     stage_bytes = 2 * 8 * 6 * 262 * 262 * 4
     assert plan["wire_bytes_by_stage"] == (stage_bytes,) * 3 and plan["wire_bytes"] == 79_077_888
     assert plan["wired_edges"] == ("xy",)

@@ -1192,6 +1192,20 @@ def _whole_self_wrap(n, lo, hi):
     pytest.param((16, 128), (3, 3, 3), (3, 3, 3), "", "float32", "raw", id="no-fill"),
     pytest.param((8, 128), (3, 3, 3), (3, 3, 3), "yz", "bfloat16", "raw", id="bf16-half-a-tile"),
     pytest.param((16, 128), (3, 3, 3), (3, 3, 3), "yz", "bfloat16", "interior", id="bf16-whole-tiles"),
+    # beside a split y (ISSUE 48): the z self-wrap alone, whole tiles, and a y
+    # shell that rides in the tiles -- no wider than a tile, no more rows than
+    # the plane has tiles
+    pytest.param((64, 128), (3, 3, 3), (3, 3, 3), "z", "float32", "interior-z", id="y-split-whole-tiles"),
+    pytest.param((256, 256), (3, 3, 3), (3, 3, 3), "z", "float32", "interior-z", id="mhd-256x4"),
+    pytest.param((64, 128), (3, 4, 3), (4, 3, 5), "z", "float32", "interior-z", id="y-split-uneven-shell"),
+    pytest.param((128, 128), (3, 3, 3), (3, 3, 3), "z", "bfloat16", "interior-z", id="y-split-bf16"),
+    pytest.param((64, 128), (3, 3, 3), (3, 3, 3), "y", "float32", "raw", id="z-split"),
+    pytest.param((64, 100), (3, 3, 3), (3, 3, 3), "z", "float32", "raw", id="y-split-ragged-lanes"),
+    pytest.param((600, 600), (4, 4, 4), (4, 4, 4), "z", "float32", "raw", id="acoustic-1200x4"),
+    pytest.param((60, 128), (3, 3, 3), (3, 3, 3), "z", "float32", "raw", id="y-split-ragged-sublanes"),
+    pytest.param((128, 128), (3, 5, 3), (3, 4, 3), "z", "float32", "raw", id="y-shell-wider-than-a-tile"),
+    pytest.param((40, 128), (3, 3, 3), (3, 3, 3), "z", "float32", "raw", id="y-split-fewer-tiles-than-shell-rows"),
+    pytest.param((64, 128), (3, 3, 3), (3, 3, 3), "z", "bfloat16", "raw", id="y-split-bf16-four-tiles"),
 ])
 def test_the_plane_window_is_read_off_the_fills_and_the_shape(interior, lo, hi, fills, storage, want):
     import jax.numpy as jnp
@@ -1220,11 +1234,14 @@ def test_the_plane_window_is_read_off_the_fills_and_the_shape(interior, lo, hi, 
     pytest.param("interior", (256, 256), "bfloat16", 3, 32, id="bf16-16-row-tiles"),
     pytest.param("interior", (32, 128), "bfloat16", 3, 0, id="bf16-two-tiles"),
     pytest.param("raw", (256, 256), "float32", 3, 0, id="raw-window"),
+    pytest.param("interior-z", (256, 256), "float32", 3, 16, id="mhd-256x4"),  # the twin's strips
+    pytest.param("interior-z", (64, 128), "float32", 3, 32, id="y-split-four-vregs-a-value"),
+    pytest.param("interior-z", (16, 128), "float32", 3, 0, id="y-split-fewer-tiles-than-the-read-distance"),
 ])
 def test_the_strip_is_read_off_the_window_and_the_plane(window, plane, storage, r, want):
     """``plane_strip_rows``: whole tiles of the stored dtype that divide the
-    plane, four vregs a value where the plane allows; none off the interior
-    window, nor where a y shift would wrap around the tiles more than once."""
+    plane, four vregs a value where the plane allows; none off the two aligned
+    windows, nor where a y shift would wrap around the tiles more than once."""
     import jax.numpy as jnp
 
     assert spass.plane_strip_rows(window, plane, [jnp.float32, jnp.dtype(storage)], r) == want
@@ -1370,11 +1387,85 @@ def test_the_interior_window_pass_is_bitwise_the_raw_plane_pass(kw, renames, she
             assert not np.array_equal(a, b), name  # the raw window's shell is the OLD plane's
 
 
+@pytest.mark.parametrize("strips", [1, 4], ids=["one-strip", "four-strips"])
+@pytest.mark.parametrize("kw,renames,shell", _INTERIOR_WINDOW_CASES)
+def test_the_window_beside_a_split_y_is_bitwise_the_raw_plane_pass(kw, renames, shell, strips):
+    """The pass beside a SPLIT y (ISSUE 48: ``window="interior-z"``, the strip
+    form only): it is handed the z fills alone, and the y halo rows of every
+    block hold data of their own -- a neighbour's rows, here random numbers
+    that are NOT the plane's periodic wrap (the same blocks through a pass that
+    wraps y give other interiors: a form that wrapped y would fail here).  The
+    planes are tiles over RAW rows ``[0, Yi)``, the ``lo.y + hi.y`` margin
+    tiles behind them carry the block's tail rows at their last sublane, and
+    the strips run over tiles ``[lo.y, lo.y + K)``: every interior cell of
+    every quantity bitwise the raw window's; the x-shell planes of an output
+    that is a halo reader equal on every raw cell; the y halo ROWS of every
+    stored plane the raw window's (the centre plane's, passed through); and
+    the z shell of every stored plane the self-wrap of the plane as stored.
+    Renames, the lagged ``c`` (read along y) and ``p`` (read at the centre),
+    in place, f32 and bf16 storage, an uneven shell, the y-z / x-y / x-z
+    diagonals at radius 3, the cells' own coordinates; on the plane of four
+    strips (two tiles each, the benchmark's) with the planes two ``dy`` share
+    rotated ONCE a grid step, margins and all (``prerotated``)."""
+    import jax.numpy as jnp
+
+    from stencil_tpu.core.dim3 import Dim3
+
+    dtype = jnp.bfloat16 if kw.get("f32_accumulate") else jnp.float32
+    rows = 8 * spass.sublane_tile([dtype])  # eight tiles of rows a plane
+    r, n, names = 3, (6, rows, 128), ["u", "c", "p"]
+    lo, hi = shell
+    shape = tuple(m + a + b for m, a, b in zip(n, lo, hi))
+    rng = np.random.default_rng(48)
+    raws = [jnp.asarray(rng.standard_normal(shape), dtype) for _ in names]
+    fills = tuple(f for f in _whole_self_wrap(n, lo, hi) if f[0] == 2)
+    writers = ("u",) if renames else ("u", "p")
+    shared = (("u", 0, 1), ("u", 0, 2), ("u", 0, 3), ("u", -2, 2), ("c", 0, -1))
+
+    def run(window, strip=0, fills=fills):
+        return spass.stream_plane_pass(
+            _diagonal_r3_kernel, names, raws, Dim3(*lo), Dim3(*hi), r,
+            jnp.asarray([5, 7, 0], jnp.int32), Dim3(64, 4 * n[1], n[2]), interpret=True,
+            halo_readers=("u", "c"), rings=("u",), writers=writers, wrap_fills=fills,
+            renames=renames, window=window, strip=strip,
+            prerotated=shared if strips > 1 and strip else (), **kw,
+        )
+
+    assert spass.plane_window_form(
+        fills, Dim3(*lo), Dim3(*hi), shape[1:], [dtype]) == "interior-z"
+    got, want = run("interior-z", rows // strips), run("raw")
+    wrapped = run("raw", fills=_whole_self_wrap(n, lo, hi))  # what wrapping y would give
+    inner = tuple(slice(a, a + m) for a, m in zip(lo, n))
+    as_np = lambda v: np.asarray(v.astype(jnp.float32))  # noqa: E731
+    assert not np.array_equal(as_np(wrapped[0])[inner], as_np(want[0])[inner])
+    for q, name in enumerate(names):
+        a, b = as_np(got[q]), as_np(want[q])
+        assert np.isfinite(b).all() and np.array_equal(a[inner], b[inner]), name
+        if name == "c":
+            assert got[q] is raws[q] and want[q] is raws[q]
+        elif name == "p" and renames:
+            assert got[q] is raws[0] and want[q] is raws[0]  # the handles swapped
+        else:
+            # the y halo rows pass through from the centre plane, as the raw
+            # window's do (one the raw window does not z-fill keeps its loaded
+            # z shell there: compare on the interior lanes)
+            lanes = inner[2] if name == "p" else slice(None)
+            for y_halo in (slice(0, lo[1]), slice(lo[1] + n[1], None)):
+                assert np.array_equal(a[:, y_halo, lanes], b[:, y_halo, lanes]), name
+            # a halo reader's x-shell planes are filled alike by both
+            yz = (slice(None),) + (inner[1:] if name == "p" else (slice(None),) * 2)
+            for x_shell in (slice(0, lo[0]), slice(lo[0] + n[0], None)):
+                assert np.array_equal(a[x_shell][yz], b[x_shell][yz]), name
+            assert np.array_equal(a, _self_wrap(a, 2, lo[2], hi[2])), name
+            assert not np.array_equal(a, b), name  # the raw window's z shell is the OLD plane's
+
+
 @pytest.mark.parametrize("extent,partition,window,strip", [
     pytest.param((8, 16, 128), (1, 1, 1), "interior", 0, id="whole-tiles"),
     pytest.param((8, 32, 128), (1, 1, 1), "interior", 32, id="whole-tiles-in-strips"),
     pytest.param((8, 16, 100), (1, 1, 1), "raw", 0, id="ragged-lanes"),
     pytest.param((8, 32, 128), (1, 2, 1), "raw", 0, id="y-split"),
+    pytest.param((8, 128, 128), (1, 2, 1), "interior-z", 32, id="y-split-whole-tiles-in-strips"),
 ])
 def test_a_plane_step_takes_the_interior_window_where_it_wraps_onto_itself(
     extent, partition, window, strip, monkeypatch
@@ -1384,7 +1475,11 @@ def test_a_plane_step_takes_the_interior_window_where_it_wraps_onto_itself(
     the traced program IS the one built with the rule off (the parent's), and
     where it says "interior" the program differs -- its rings hold interior
     planes -- and every cell of every quantity is bitwise what the raw-plane
-    program gives after an even and an odd count of steps.  ``inplace-order`` holds on the program either way."""
+    program gives after an even and an odd count of steps.  ``inplace-order`` holds on the program either way.
+    Beside a SPLIT y (ISSUE 48) a shard of whole tiles -- eight tiles of rows
+    for its six-row y shell -- takes the ``"interior-z"`` window in strips: the
+    y halo arrives over the mesh, the z halo is the rotates' wraparound, and
+    every cell is bitwise the raw-plane program's all the same."""
     import jax
 
     from program_fingerprint import fingerprint_text
@@ -1443,8 +1538,11 @@ def test_a_plane_step_takes_the_interior_window_where_it_wraps_onto_itself(
     assert sm.stream_span_args(plan, r, len(names))["plane_strip"] == strip
     (p,) = plan["stages"][0]["passes"]
     assert set(p["prerotated"]) == ({("u", 0, k) for k in (1, 2, 3)} if strip else set())
-    tiles = {(7, 10, 8, 128), (1, 10, 8, 128), (4, 8, 128), (10, 8, 128)}
+    # (beside a split y: the shard's eight tiles before six margin tiles)
+    held, plane_tiles = (14, 8) if window == "interior-z" else (10, 4)
+    tiles = {(7, held, 8, 128), (1, held, 8, 128), (plane_tiles, 8, 128), (held, 8, 128)}
     assert rings == (tiles if strip else {tuple(extent[1:])} if window == "interior" else {raw}), rings
+    assert (window == "raw") == (plan["plane_window"] == plan_raw["plane_window"])
     for a, b in zip(fields, fields_raw):
         for name, x, y in zip(names, a, b):
             assert np.isfinite(y).all() and np.array_equal(x, y), name

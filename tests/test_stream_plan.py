@@ -214,10 +214,15 @@ def test_the_builder_is_a_dispatch_and_writes_no_plan_key():
 def _benchmark_plane_model(cell):
     """The model of a benchmark plane cell at its REAL extent, nothing
     allocated, and the stages of its step."""
-    if cell == "astaroth-mhd-256":
+    if cell.startswith("astaroth-mhd-256"):
         from stencil_tpu.models.astaroth_mhd import RADIUS, AstarothMHD
+        from stencil_tpu.models.astaroth_mhd_reference import MhdSetup
 
-        sim = AstarothMHD(256, 256, 256, devices=jax.devices()[:1], seed_words=None)
+        # (the four-chip cell: 256^3 a device on the one-chip cell's uniform spacing)
+        shape, n_dev = ((512, 512, 256), 4) if cell.endswith("x4") else ((256,) * 3, 1)
+        box = tuple(MhdSetup((256,) * 3).box / 256 * n for n in shape)
+        sim = AstarothMHD(*shape, setup=MhdSetup(shape, box=box), devices=jax.devices()[:n_dev],
+                          seed_words=None)
         stages = lambda: tuple(sim._substep(s) for s in range(3))  # noqa: E731
     elif cell == "elastic-so8-600":
         from stencil_tpu.models.elastic import RADIUS, ElasticWave
@@ -244,9 +249,12 @@ MHD_STRIP = 16
     ("acoustic-so8-600", "yz", "raw"),  # 600 = 4 x 128 + 88 lanes
     ("elastic-so8-600", "yz", "raw"),
     ("acoustic-so8-1200x4", "z", "raw"),  # mesh [2, 2, 1] splits y
+    # ... and so it does here, beside whole tiles (ISSUE 48): the z halo the rotates'
+    # wraparound, the neighbours' y halo rows in the tiles
+    ("astaroth-mhd-256x4", "z", "interior-z"),
 ])
 def test_the_benchmarks_plane_cells_resolve_their_window(cell, wrapped, window, monkeypatch):
-    """``plan["plane_window"]`` of the four benchmark plane configurations at
+    """``plan["plane_window"]`` of the five benchmark plane configurations at
     their real extents, the blend kernels on as on the chip: from the fills
     the plan resolved and the block's static shape alone -- no option, no
     model's name -- and ``domain.step`` says it beside ``wrapped``."""
@@ -254,6 +262,7 @@ def test_the_benchmarks_plane_cells_resolve_their_window(cell, wrapped, window, 
 
     monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
     sim, stages, r = _benchmark_plane_model(cell)
+    assert tuple(sim.dd.mesh_dim()) == ((2, 2, 1) if cell.endswith("x4") else (1, 1, 1))
     plan = sp.resolve_stream_plan(sim.dd, stages, r, sp.plan_stream(sim.dd, r, "plane", False), False)
     assert (plan["pass_wrap_axes"], plan["plane_window"]) == (wrapped, window), plan.plan
     raw, n = sim.dd.local_spec().raw_size(), sim.dd.local_spec().sz
@@ -265,13 +274,15 @@ def test_the_benchmarks_plane_cells_resolve_their_window(cell, wrapped, window, 
     said = sm.stream_span_args(plan, r, len(sim.dd._handles))
     assert (said["wrapped"], said["plane_window"]) == (wrapped, window)
     # ... and the rows of it the kernel is evaluated over at a time (ISSUE 46):
-    # strips of MHD_STRIP rows on the interior window, the plane whole on the raw one
-    strip = MHD_STRIP if window == "interior" else 0
+    # strips of MHD_STRIP rows on the two aligned windows, the plane whole on the raw one
+    strip = 0 if window == "raw" else MHD_STRIP
     assert plan["plane_strip"] == said["plane_strip"] == strip
     assert strip == spass.plane_strip_rows(window, (n.y, n.z), [jnp.float32], r)
     # the rings are priced at the plane they hold: the raw plane's on the raw
     # window; on the interior window, in the strip form, the interior as tiles
-    # between r margin tiles a side -- 2r + 1 deep for a ringed quantity (the
+    # between r margin tiles a side (beside a split y: before the lo.y + hi.y
+    # margin tiles that carry the y halo rows, as many here: the twin's shapes
+    # and the twin's 96,955,392 B a pass) -- 2r + 1 deep for a ringed quantity (the
     # newest plane is pushed before the strips read it), one plane for every
     # other --, a staging plane of tiles a writer and the planes rotated once
     # for all their readers (the one VMEM model)
@@ -289,9 +300,11 @@ def test_the_benchmarks_plane_cells_resolve_their_window(cell, wrapped, window, 
             else:
                 held = 2 * r * len(p["rings"]) * pad(raw.y, raw.z, 4)
             assert p["vmem_bytes"] == blocks + held + sp._VMEM_STACK_MARGIN * len(p["reads"]), p
+            if strip:
+                assert p["vmem_bytes"] == 96_955_392, p
     # a request that turns the schedule off the default one keeps the raw plane
     split = dict(sp.plan_stream(sim.dd, r, "plane", False), overlap="split", overlap_forced=True)
-    if cell == "astaroth-mhd-256":
+    if cell.startswith("astaroth-mhd-256"):
         assert sp.resolve_stream_plan(sim.dd, stages, r, split, False)["plane_window"] == "raw"
 
 
@@ -301,32 +314,38 @@ def _lag_kernel(views, info):
     return {"u": new, "p": u.center()}
 
 
-@pytest.mark.parametrize("window", ["interior", "interior-in-strips", "raw"])
+@pytest.mark.parametrize("window", ["interior", "interior-in-strips", "raw", "interior-z-in-strips"])
 @pytest.mark.parametrize("storage", ["native", "bf16"])
 def test_the_vmem_model_is_what_the_pass_allocates(window, storage, monkeypatch):
     """``plane_pass_vmem_bytes`` against the traced Pallas call of the pass, in
     both forms and both storages: two tile-padded buffers a pipelined block
     (every operand, every result), the scratch as allocated, the stack margin
     a quantity read -- the pass allocates what the one model charges, ring
-    planes of the interior included."""
+    planes of the interior included; beside a split y (ISSUE 48, mesh [1,2,1])
+    the tiles of raw rows ``[0, Yi)`` before the ``lo.y + hi.y`` margin tiles
+    that carry the y halo rows."""
     from stencil_tpu.analysis import jaxpr as jx
 
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
     if window == "raw":
         monkeypatch.setattr(sp, "plane_window_form", lambda *a: "raw")
-    window, strips = window.split("-")[0], window.endswith("strips")
+    window, strips = window.split("-in-")[0], window.endswith("strips")
     if strips:  # (a light kernel: the planner keeps it over whole planes)
         monkeypatch.setattr(sp, "_STRIP_MIN_OPS", 0)
-    dd = DistributedDomain(8, 16 if storage == "native" else 32, 128)
+    split_y = window == "interior-z"  # four tiles of rows a shard: as many as its y shell has rows
+    rows = (2 if split_y else 1) * (16 if storage == "native" else 32)
+    dd = DistributedDomain(8, 2 * rows if split_y else rows, 128)
     dd.set_radius(Radius.constant(2))
-    dd.set_devices(jax.devices()[:1])
+    dd.set_devices(jax.devices()[: 2 if split_y else 1])
+    if split_y:
+        dd.set_partition(1, 2, 1)
     if storage != "native":
         dd.set_storage(storage)
     for name in ("u", "c", "p"):
         dd.add_data(name)
     dd.realize()
     plan = sp.resolve_stream_plan(dd, _lag_kernel, 2, sp.plan_stream(dd, 2, "plane", False), True)
-    assert (plan["pass_wrap_axes"], plan["plane_window"]) == ("yz", window)
+    assert (plan["pass_wrap_axes"], plan["plane_window"]) == ("z" if split_y else "yz", window)
     (p,) = plan["stages"][0]["passes"]
     assert (p["reads"], p["rings"], p["writes"], p["renames"]) == (
         ("u", "c", "p"), ("u",), ("u",), (("p", "u"),))
@@ -347,8 +366,15 @@ def test_the_vmem_model_is_what_the_pass_allocates(window, storage, monkeypatch)
     )
     allocated += sum(pad(sc.shape, sc.dtype) for sc in gm.scratch_avals)
     assert p["vmem_bytes"] == allocated + sp._VMEM_STACK_MARGIN * len(p["reads"])
-    rows = 16 if storage == "native" else 32  # two sublane tiles of the stored dtype
-    if not strips:
+    tile = rows // (4 if split_y else 2)  # a sublane tile of the stored dtype
+    if split_y:
+        # the shard's four tiles of raw rows [0, Yi) and the four margin tiles
+        # behind them, whose last sublane is a tail row of the block
+        assert plan["plane_strip"] == rows and p["prerotated"] == ()
+        ring, *lagged, stage = gm.scratch_avals
+        assert [sc.shape for sc in (ring, *lagged, stage)] == [
+            (5, 8, tile, 128), (1, 8, tile, 128), (1, 8, tile, 128), (4, tile, 128)]
+    elif not strips:
         assert plan["plane_strip"] == 0
         (ring,) = gm.scratch_avals
         assert ring.shape == ((4, rows + 4, 132) if window == "raw" else (4, rows, 128))
@@ -381,10 +407,13 @@ def test_the_step_span_carries_the_plane_window(monkeypatch):
     monkeypatch.setattr(sp, "_STRIP_MIN_OPS", 0)  # (``_mean2`` is a light kernel)
     for extent, path, want, strip in (
             ((8, 16, 128), "plane", "interior", 16), ((8, 16, 96), "plane", "raw", 0),
+            ((8, 32, 128), "plane", "interior-z", 16),  # mesh [1,2,1]: y arrives over a wire (ISSUE 48)
             ((8, 16, 128), "wrap", None, None)):
         dd = DistributedDomain(*extent)
         dd.set_radius(Radius.constant(1))
-        dd.set_devices(jax.devices()[:1])
+        dd.set_devices(jax.devices()[: 2 if want == "interior-z" else 1])
+        if want == "interior-z":
+            dd.set_partition(1, 2, 1)
         dd.add_data("u")
         dd.add_data("v")
         dd.realize()
@@ -395,4 +424,5 @@ def test_the_step_span_carries_the_plane_window(monkeypatch):
         said = [kw for name, kw in seen if name == tm.SPAN_STEP]
         assert len(said) == 2 and all(kw.get("plane_window") == want for kw in said), said
         assert all(kw.get("plane_strip") == strip for kw in said), said  # (ISSUE 46)
-        assert all((kw["wrapped"] == "yz") == (path == "plane") for kw in said), said
+        wrapped = {"interior-z": "z", None: ""}.get(want, "yz")
+        assert all(kw["wrapped"] == wrapped for kw in said), said

@@ -283,6 +283,19 @@ def add_stream_halo_flag(p: argparse.ArgumentParser) -> None:
     )
 
 
+def step_hops_str(step) -> str:
+    """``x.low:N/x.high:N/...``: the bytes one shard receives over each wired
+    hop per exchanging unit of ``step`` (a step; a wavefront's macro) -- the
+    step's own ``WireAccount`` (``ops/exchange.py``), which ``run_step``
+    counts ``exchange.hop.*.bytes`` from and whose sum a raw step the step's
+    span says as ``wire_bytes``: the drivers print the counters' source.  ""
+    for a step that declares none, or wires nothing."""
+    account = getattr(step, "_wire_account", None)
+    if account is None:
+        return ""
+    return "/".join(f"{axis}.{side}:{nb}" for (axis, side), nb in sorted(account().hops.items()))
+
+
 def add_numerics_flag(p: argparse.ArgumentParser) -> None:
     """``--numerics-every``: the numerics observatory's snapshot cadence
     (docs/observability.md "Numerics observatory").  Every N raw steps ONE

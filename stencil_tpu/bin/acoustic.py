@@ -73,7 +73,8 @@ def run(argv, name: str, model, steps: int) -> int:
     plan = getattr(sim._step, "_span_args", dict)()
     print(
         f"mesh: {mesh} wired={plan.get('wired', '')!r} "
-        f"wrapped={plan.get('wrapped', '')!r} wire_bytes={plan.get('wire_bytes', 0)}",
+        f"wrapped={plan.get('wrapped', '')!r} wire_bytes={plan.get('wire_bytes', 0)} "
+        f"hops={_common.step_hops_str(sim._step)!r}",
         file=sys.stderr,
     )
 

@@ -154,9 +154,11 @@ def overlap_domain_size(args, mesh, devices, weak_scale: bool):
 
 def _hop_table(dd, s_exch: float) -> list:
     """The per-hop attribution table every per-mesh artifact carries: the
-    ANALYTIC decomposition of the exchange bytes over each mesh hop
-    (``DistributedDomain.exchange_hop_bytes``; hops on unsplit axes report
-    0), with the measured per-exchange time apportioned by byte share.
+    bytes ``exchange()`` sends over each mesh hop by its message plan
+    (``DistributedDomain.exchange_hop_bytes`` = ``ops/exchange.py exchange_account``,
+    the function behind the ``exchange.hop.*.bytes`` counters and a step's
+    ``wire_bytes``; hops on unsplit axes report 0), with the measured
+    per-exchange time apportioned by byte share.
     Tagged ``source: "analytic"`` — a profiler trace upgrades these to
     measured per-direction device time (``scripts/perf_report.py``)."""
     hop_bytes = dd.exchange_hop_bytes()

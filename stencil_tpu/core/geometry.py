@@ -218,32 +218,6 @@ def sweep_bytes(spec: LocalSpec, itemsizes) -> int:
     return total
 
 
-#: receive-side direction name per (axis index, sign): ``low`` receives the
-#: -1 neighbor's slab (ops/exchange.py ``_shift_from_low``), ``high`` the +1
-#: neighbor's — the vocabulary of the ``exchange.<axis>.<side>`` spans
-HOP_SIDES = ((-1, "low"), (+1, "high"))
-
-
-def sweep_hop_bytes(spec: LocalSpec, itemsizes) -> dict:
-    """``sweep_bytes`` decomposed per mesh hop: bytes one subdomain receives
-    per exchange over each (axis index, side) message of the 3-axis-sweep
-    implementation, keyed ``(axis, side)`` with side in ``low``/``high``.
-    Values sum to ``sweep_bytes`` — the honest per-LINK traffic model for
-    the comms roofline (edge/corner data transits once per participating
-    axis, so the sum exceeds the 26-message ``exchange_bytes``)."""
-    raw = spec.raw_size()
-    r = spec.radius
-    itemsize_sum = sum(int(s) for s in itemsizes)
-    out = {}
-    for axis in range(3):
-        others = [raw[b] for b in range(3) if b != axis]
-        plane = others[0] * others[1]
-        for sign, side in HOP_SIDES:
-            # the slab received on ``side`` has that side's halo width
-            out[(axis, side)] = itemsize_sum * plane * r.axis(axis, sign)
-    return out
-
-
 def ripple_value(p: Dim3) -> float:
     """The analytic test field from the reference's exchange tests
     (test_exchange.cu:14-38): ``x + ripple[x%4] + y + ripple[y%4] + z +

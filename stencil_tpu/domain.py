@@ -253,8 +253,6 @@ class DistributedDomain:
         # field allocation, exchange byte accounting, and the packed z-shell
         # messages all follow ``field_dtype``
         self._storage = "native"
-        self._packed_nbytes = 0
-        self._packed_nkernels = 0
         self._halo_mult = 1
         self._shell_stale = False
         self._shell_radius: Optional[Radius] = None
@@ -283,11 +281,12 @@ class DistributedDomain:
         # STENCIL_WATCHDOG_S at first dispatch, or installed programmatically
         self._watchdog = None
         self._watchdog_resolved = False
-        # analytic bytes per exchange (exchange_bytes_total), computed once
-        # per realize() for the telemetry counters; the per-hop decomposition
-        # (exchange_hop_bytes) is cached beside it as (counter, bytes) pairs
+        # analytic bytes per exchange (exchange_bytes_total: the span's
+        # ``nbytes`` and the gauge), computed once per realize(); the
+        # exchange's own account of its wires and packed sweeps (what the
+        # counters read) is cached beside it
         self._exchange_nbytes: Optional[int] = None
-        self._hop_nbytes: Optional[List[Tuple[str, int]]] = None
+        self._wires = None
 
     def set_watchdog(self, wd) -> None:
         """Install (or clear, with ``None``) a dispatch watchdog
@@ -878,8 +877,6 @@ class DistributedDomain:
         # analytic byte models recompute lazily
         self._exchange_many_fn = None
         self._exchange_nbytes = None
-        self._hop_nbytes = None
-        self._packed_nbytes = self._packed_nkernels = 0
         self._shell_stale = False
         if self._numerics is not None:
             # the stats program closes over the OLD mesh/spec; the engine's
@@ -929,8 +926,6 @@ class DistributedDomain:
         self._exchange_fn = None
         self._exchange_many_fn = None
         self._exchange_nbytes = None
-        self._hop_nbytes = None
-        self._packed_nbytes = self._packed_nkernels = 0
         self._shell_stale = False
         if self._numerics is not None:
             self._numerics.on_mesh_change()
@@ -1371,8 +1366,11 @@ class DistributedDomain:
 
     def _model_exchange(self) -> int:
         """Analytic bytes of ONE exchange via ``exchange_bytes_total``
-        (src/stencil.cu:6-25), modeled once with its per-hop and packed-route
-        decompositions and cached — the hot path is a None check."""
+        (src/stencil.cu:6-25: every shell cell, the self-filled ones too --
+        the ``domain.exchange`` span's ``nbytes`` and the gauge), modeled once
+        beside the exchange's own account of its wires and packed sweeps
+        (``self._wires``: what the counters read) and cached -- the hot path
+        is a None check."""
         if self._exchange_nbytes is None:
             self._exchange_nbytes = (
                 self.exchange_bytes_total() if self._handles else 0
@@ -1380,61 +1378,7 @@ class DistributedDomain:
             telemetry.set_gauge(
                 tm.EXCHANGE_BYTES_PER_EXCHANGE, self._exchange_nbytes
             )
-            # per-hop decomposition for the comms roofline: modeled once,
-            # then the hot path is one inc per TRAFFICKED hop (size-1 mesh
-            # axes are dropped here — their counters stay seeded at 0)
-            self._hop_nbytes = [
-                (tm.EXCHANGE_HOP_BYTES[(axis, side)], nb)
-                for (axis, side), nb in sorted(
-                    self.exchange_hop_bytes().items()
-                )
-                if nb
-            ] if self._handles else []
-            if self._handles and self._exchange_route != "direct":
-                # analytic packed-route traffic (like the bytes model above:
-                # modeled once, an int multiply on the hot path).  Each
-                # sweep counts only when it can actually engage — a yzpack
-                # route over an uneven z still packs (and counts) its y
-                # sweep, and vice versa.
-                from stencil_tpu.ops.exchange import (
-                    Y_PACK_ROUTES,
-                    ypack_message_stats,
-                    ypack_supported,
-                    zpack_message_stats,
-                    zpack_supported,
-                )
-
-                raw = self._spec.raw_size()
-                shell = self._shell_radius
-                itemsizes = [
-                    self.field_dtype(h).itemsize
-                    for h in self._handles
-                    for _ in range(h.cell_count())
-                ]
-                dtypes = [self.field_dtype(h) for h in self._handles]
-                nbytes = kernels = 0
-                if zpack_supported(dtypes, self._valid_last):
-                    nb, nk = zpack_message_stats(
-                        (raw.x, raw.y, raw.z),
-                        shell.axis(2, -1),
-                        shell.axis(2, +1),
-                        itemsizes,
-                    )
-                    nbytes += nb
-                    kernels += nk
-                if self._exchange_route in Y_PACK_ROUTES and ypack_supported(
-                    dtypes, self._valid_last
-                ):
-                    nb, nk = ypack_message_stats(
-                        (raw.x, raw.y, raw.z),
-                        shell.axis(1, -1),
-                        shell.axis(1, +1),
-                        itemsizes,
-                    )
-                    nbytes += nb
-                    kernels += nk
-                self._packed_nbytes = nbytes * self.num_subdomains()
-                self._packed_nkernels = kernels * self.num_subdomains()
+            self._wires = self._exchange_account()
         return self._exchange_nbytes
 
     def _dispatch_span_args(self, fn, *static) -> dict:
@@ -1456,24 +1400,41 @@ class DistributedDomain:
         return args
 
     def _account_exchanges(self, n: int) -> None:
-        """Counter bookkeeping for ``n`` (possibly fused) halo exchanges —
-        counters are always live, so this must stay a dict hit + two int
-        adds on the hot path."""
-        telemetry.inc(tm.EXCHANGE_COUNT, n)
-        telemetry.inc(tm.EXCHANGE_BYTES, n * self._model_exchange())
-        for counter, nb in self._hop_nbytes:
-            telemetry.inc(counter, n * nb)
-        if self._packed_nkernels:
-            telemetry.inc(tm.EXCHANGE_PACKED_BYTES, n * self._packed_nbytes)
-            telemetry.inc(tm.EXCHANGE_PACKED_KERNELS, n * self._packed_nkernels)
+        """Counter bookkeeping for ``n`` (possibly fused) ``exchange()``s of
+        every quantity at the shell radius -- the domain-wide model: what
+        ``exchange()`` / ``exchange_many()`` run, and the FALLBACK for a
+        caller's own step callable that declares no account of its wires
+        (``run_step``)."""
+        self._model_exchange()
+        self._account_wires(self._wires, n)
+
+    def _account_wires(self, account, raw_steps: int) -> None:
+        """Counter bookkeeping for a dispatch of ``raw_steps`` of whatever
+        declared what it exchanges (``ops/exchange.py WireAccount``, from the
+        message plan that is run: a built step's, or ``exchange()``'s own):
+        its count of exchanges, the bytes of its hops summed over subdomains
+        -- ``domain.exchange.bytes`` their sum, so one chip counts exchanges
+        and no bytes -- and what its packed sweeps move.  Counters are always
+        live: an int multiply and one ``inc`` per trafficked hop."""
+        units = account.units(raw_steps)
+        scale = units * self.num_subdomains()
+        telemetry.inc(tm.EXCHANGE_COUNT, units * account.exchanges)
+        telemetry.inc(tm.EXCHANGE_BYTES, scale * sum(account.hops.values()))
+        for hop, nb in account.hops.items():
+            telemetry.inc(tm.EXCHANGE_HOP_BYTES[hop], scale * nb)
+        if account.packed[1]:
+            telemetry.inc(tm.EXCHANGE_PACKED_BYTES, scale * account.packed[0])
+            telemetry.inc(tm.EXCHANGE_PACKED_KERNELS, scale * account.packed[1])
 
     def exchange(self) -> None:
         """Fill every quantity's halo shell (src/stencil.cu:670-864)."""
         assert self._realized
+        nbytes = self._model_exchange()
         with self._phase_timer(
             "time_exchange", tm.EXCHANGE_SECONDS, tm.SPAN_EXCHANGE, sync=True,
-            route=self._exchange_route, nbytes=self._model_exchange(), count=1,
+            route=self._exchange_route, nbytes=nbytes, count=1,
             wrap_axes=self._wrap_axes, uneven_axes=self._uneven_axes,
+            wire_bytes=self._wires.said()[1],
             **self._dispatch_span_args(self._exchange_fn),
         ):
             self._curr = self._watched_call(
@@ -1497,10 +1458,12 @@ class DistributedDomain:
                 return lax.fori_loop(0, s, lambda _, a: inner(a), arrays)
 
             self._exchange_many_fn = many
+        nbytes = steps * self._model_exchange()
         with telemetry.span(
             tm.SPAN_EXCHANGE, route=self._exchange_route,
-            nbytes=steps * self._model_exchange(), count=steps,
+            nbytes=nbytes, count=steps,
             wrap_axes=self._wrap_axes, uneven_axes=self._uneven_axes,
+            wire_bytes=self._wires.said()[1],
             **self._dispatch_span_args(self._exchange_many_fn, steps),
         ):
             self._curr = self._exchange_many_fn(self._curr, steps)
@@ -1540,32 +1503,37 @@ class DistributedDomain:
         return per_dom * self.num_subdomains()
 
     def exchange_hop_bytes(self) -> Dict[Tuple[str, str], int]:
-        """Analytic bytes-per-exchange over each mesh hop, keyed
-        ``(mesh axis name, side)`` with side in ``low``/``high`` — the
-        per-direction decomposition of the sweep traffic
-        (core/geometry.py ``sweep_hop_bytes``) summed across subdomains.
-        Hops on mesh axes of size 1 report 0: their ppermute self-wraps
-        (the periodic boundary inside one chip), so no fabric traffic.
-        Feeds the ``exchange.hop.*.bytes`` counters and the per-hop table
-        in the weak-scaling artifacts (docs/observability.md "Fabric
+        """Bytes ONE ``exchange()`` sends over each mesh hop, keyed
+        ``(mesh axis name, side)`` with side in ``low``/``high``, summed
+        across subdomains: the message plan of the exchange this domain
+        runs (``ops/exchange.py exchange_account`` -- every quantity, the shell
+        radius, the realize-resolved route, so a packed sweep counts its
+        padded buffer), the function a built step's own account is made of.
+        Hops on mesh axes of size 1 report 0: the sweep self-wraps inside
+        the chip, no fabric traffic.  Feeds the ``exchange.hop.*.bytes``
+        counters of ``exchange()`` / ``exchange_many()`` and the per-hop
+        table in the weak-scaling artifacts (docs/observability.md "Fabric
         observatory")."""
-        from stencil_tpu.core.geometry import sweep_hop_bytes
-
-        per_dom = sweep_hop_bytes(
-            self._spec,
-            [
-                self.field_dtype(h).itemsize * h.cell_count()
-                for h in self._handles
-            ],
-        )
-        n_sub = self.num_subdomains()
-        shape = dict(self.mesh.shape) if self.mesh is not None else {}
+        per_dom, n_sub = self._exchange_account().hops, self.num_subdomains()
         return {
-            (MESH_AXES[axis], side): (
-                nb * n_sub if shape.get(MESH_AXES[axis], 1) > 1 else 0
-            )
-            for (axis, side), nb in per_dom.items()
+            (axis, side): per_dom.get((axis, side), 0) * n_sub
+            for axis in MESH_AXES for side in ("low", "high")
         }
+
+    def _exchange_account(self):
+        """The account of one ``exchange()`` (``ops/exchange.py
+        exchange_account``): the bytes ONE shard receives over each wired hop,
+        and what its packed sweeps move."""
+        from stencil_tpu.ops.exchange import exchange_account
+
+        raw = self._spec.raw_size()
+        return exchange_account(
+            tuple(self.mesh.shape[a] for a in MESH_AXES) if self.mesh is not None else (1, 1, 1),
+            self._shell_radius, (raw.x, raw.y, raw.z),
+            [self.field_dtype(h) for h in self._handles],
+            valid_last=self._valid_last, route=self._exchange_route,
+            cells=[h.cell_count() for h in self._handles],
+        )
 
     def write_plan(self, prefix: str = "plan", link_model=None) -> str:
         """Dump the communication plan — the analog of the reference's
@@ -1867,6 +1835,13 @@ class DistributedDomain:
         # `mult` raw iterations — consumers that count raw steps (the
         # divergence sentinel) read this factor off the step
         step._raw_steps_per_call = mult
+        # what a macro exchanges: every quantity at the shell radius on the
+        # domain's route, once a stage (``run_step``'s counters)
+        from stencil_tpu.ops.exchange import sum_accounts
+
+        account = sum_accounts([self._exchange_account()] * len(stages), every=mult)
+        step._wire_account = lambda: account
+        step._span_args = lambda: dict(zip(("wired", "wire_bytes"), account.said()))
         return step
 
     def run_step(self, step_fn, steps: int = 1, label: str = None) -> None:
@@ -1950,11 +1925,16 @@ class DistributedDomain:
             telemetry.observe(tm.STEP_SECONDS, dt / max(raw, 1))
         telemetry.inc(tm.STEP_DISPATCHES)
         telemetry.inc(tm.STEP_ITERATIONS, raw)
-        # analytic exchange traffic of the fused step: one exchange per macro
-        # (= raw iterations / halo multiplier) at exchange_bytes_total bytes —
-        # the modeled bytes, not a measured count (exchange-free single-device
-        # routes are still attributed their modeled halo traffic)
-        self._account_exchanges(max(raw // max(self._halo_mult, 1), 1))
+        # the wires of the fused step: from the account its builder declared
+        # of the message plan it runs (``step._wire_account``).  FALLBACK for
+        # a caller's own step callable that declares nothing: the domain-wide
+        # model, one exchange of every quantity per macro (= raw iterations /
+        # halo multiplier) -- modeled bytes, not this step's
+        account = getattr(step_fn, "_wire_account", None)
+        if account is not None:
+            self._account_wires(account(), raw)
+        else:
+            self._account_exchanges(max(raw // max(self._halo_mult, 1), 1))
         # streaming-engine steps advance interiors only; the carried shell
         # goes stale and raw readback must re-exchange first
         if getattr(step_fn, "_marks_shell_stale", False):

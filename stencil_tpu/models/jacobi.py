@@ -563,6 +563,23 @@ class Jacobi3D:
             )
             return {name: fn(curr[name])}
 
+        # what a macro sends over wires (``run_step``'s counters, and the
+        # span's ``wired`` / ``wire_bytes`` a raw step): the m-wide shell of
+        # the block as the macro exchanges it -- the raw block whole, or its
+        # x and y sweeps (in ring mode over the z interior alone) beside the
+        # z-slab buffers' permutes
+        from stencil_tpu.ops.exchange import WireAccount, exchange_account, sum_hops, z_slab_hops
+
+        dtype = dd.field_dtype(self.h)
+        if z_slab_mode:
+            hops = sum_hops(
+                exchange_account(mesh_shape, shell, (Xr, Yr, n.z if z_ring_mode else Zp),
+                                 [dtype], axes=(0, 1)).hops,
+                z_slab_hops(mesh_shape, Xr, Yr, m, [dtype.itemsize]),
+            )
+        else:
+            hops = exchange_account(
+                mesh_shape, shell, (Xr, Yr, Zr), [dtype], valid_last=dd._valid_last).hops
         span_args = {"macros_per_trip": per_trip}
         if z_slab_mode:
             # where the kernel patches its z halo, read off the working
@@ -570,8 +587,7 @@ class Jacobi3D:
             span_args["z_halo_patch"] = z_halo_patch_form(
                 _ZRING_OFF + n.z if z_ring_mode else Zp, m
             )
-        step._span_args = lambda: dict(span_args)
-        return step
+        return self._declare_wires(step, WireAccount(1, hops, depth_run), **span_args)
 
     def _make_pallas_step(self):
         """Fused exchange + plane-streaming pallas kernel (ops/jacobi_pallas):
@@ -616,7 +632,7 @@ class Jacobi3D:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from stencil_tpu.ops.exchange import halo_exchange_shard
+        from stencil_tpu.ops.exchange import WireAccount, exchange_account, halo_exchange_shard
         from stencil_tpu.ops.jacobi_pallas import (
             choose_temporal_k,
             jacobi_plane_step,
@@ -697,8 +713,8 @@ class Jacobi3D:
                 # stencil-lint: disable=sliver-dus whole-interior write-back into the shell-carrying array after the k-loop — block spans the full interior, not a y/z sliver
                 return {name: lax.dynamic_update_slice(arr, block, (lo.x, lo.y, lo.z))}
 
-            step._span_args = lambda: {"macros_per_trip": per_trip}
-            return step
+            # one chip: no exchange, no wire
+            return self._declare_wires(step, WireAccount(0, {}), macros_per_trip=per_trip)
         if want in ("auto", "slab") and (
             all(v is None for v in dd._valid_last)
             and dd.local_spec().sz.x >= 2
@@ -747,6 +763,20 @@ class Jacobi3D:
             )
             return {name: fn(curr[name])}
 
+        raw = dd.local_spec().raw_size()
+        return self._declare_wires(step, exchange_account(
+            mesh_shape, shell, (raw.x, raw.y, raw.z), [dd.field_dtype(self.h)],
+            valid_last=valid_last,
+        ))
+
+    @staticmethod
+    def _declare_wires(step, account, **span_args):
+        """``step`` with its account of the wires (``run_step``'s counters)
+        and what its ``domain.step`` span says: ``span_args`` and the
+        account's ``wired`` / ``wire_bytes``."""
+        span_args["wired"], span_args["wire_bytes"] = account.said()
+        step._wire_account = lambda: account
+        step._span_args = lambda: dict(span_args)
         return step
 
     def _make_slab_step(self):
@@ -765,7 +795,7 @@ class Jacobi3D:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from stencil_tpu.ops.exchange import _shift_from_high, _shift_from_low
+        from stencil_tpu.ops.exchange import WireAccount, _shift_from_high, _shift_from_low
         from stencil_tpu.telemetry import names as tm
         from stencil_tpu.ops.jacobi_pallas import jacobi_slab_step, yz_dist2_plane
         from stencil_tpu.parallel.mesh import MESH_AXES
@@ -831,7 +861,14 @@ class Jacobi3D:
             )
             return {name: fn(curr[name])}
 
-        return step
+        # six bare faces of the interior a step, one each way an axis
+        isz = dd.field_dtype(self.h).itemsize
+        faces = {"x": n.y * n.z, "y": n.x * n.z, "z": n.x * n.y}
+        return self._declare_wires(step, WireAccount(1, {
+            (MESH_AXES[a], side): faces[MESH_AXES[a]] * isz
+            for a in range(3) if mesh_shape[a] > 1
+            for side in ("low", "high")
+        }))
 
     def _kernel(self, views, info):
         size = info.global_size
@@ -1016,7 +1053,6 @@ class Jacobi3D:
         # the analytic exchange-bytes cache and the compiled exchange were
         # built over the narrow buffers; drop both so they re-derive
         dd._exchange_nbytes = None
-        dd._packed_nbytes = dd._packed_nkernels = 0
         dd._exchange_many_fn = None
 
     def temperature(self) -> np.ndarray:

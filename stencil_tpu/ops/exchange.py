@@ -52,7 +52,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Mapping, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -499,7 +499,25 @@ def wrap_axes(
     )
 
 
-def wire_plan(
+def _sweeps(mesh_shape, radius, raw_spatial, dtypes, valid_last, route, axes):
+    """``(axis, sweep kind, r_lo, r_hi)`` of every sweep of one
+    ``halo_exchange_multi`` that has a halo, the kind as ``_sweep_kind`` picks
+    it (block rank matters to the self-wrap alone, which is neither a wire nor
+    a packed sweep)."""
+    for a in axes:
+        r_lo, r_hi = radius.axis(a, -1), radius.axis(a, +1)
+        if r_lo + r_hi:
+            yield a, _sweep_kind(
+                a, r_lo, r_hi, mesh_shape[a], raw_spatial[a],
+                valid_last[a] if valid_last is not None else None,
+                route, dtypes, True,
+            ), r_lo, r_hi
+
+
+_PACKED_STATS = {"zpack": zpack_message_stats, "ypack": ypack_message_stats}
+
+
+def exchange_account(
     mesh_shape: Tuple[int, int, int],
     radius: Radius,
     raw_spatial: Tuple[int, int, int],
@@ -507,37 +525,121 @@ def wire_plan(
     valid_last: Optional[Tuple[Optional[int], Optional[int], Optional[int]]] = None,
     route: str = "direct",
     axes: Tuple[int, ...] = (0, 1, 2),
-) -> Tuple[str, int]:
-    """``(wired, nbytes)`` of one ``halo_exchange_multi`` over blocks of
-    ``dtypes``: the mesh axes (a substring of ``"xyz"``) among ``axes`` whose
-    sweep sends its slabs to ANOTHER shard -- the mesh splits the axis, so
-    ``_sweep_kind`` cannot pick the self-wrap and both directions are
-    ``ppermute``s -- and the bytes one shard receives over them: per axis the
-    messages the sweep kind forms (the sliced slabs of ``direct``, ``(r_lo +
-    r_hi)`` x the raw cross-section; the packed buffers of ``ypack`` /
-    ``zpack``), every quantity.  ``("", 0)`` on one device.  What
-    ``domain.step`` reports as ``wired`` / ``wire_bytes``."""
-    itemsizes = [jnp.dtype(dt).itemsize for dt in dtypes]
-    wired, nbytes = "", 0
-    for a in axes:
-        r_lo, r_hi = radius.axis(a, -1), radius.axis(a, +1)
-        if mesh_shape[a] == 1 or r_lo + r_hi == 0:
+    cells=None,
+) -> "WireAccount":
+    """The account of ONE ``halo_exchange_multi`` of blocks of ``dtypes``
+    (``cells``: the 3D slices each block holds, its leading dims multiplied; 1
+    each when None), read off ``_sweep_kind`` sweep by sweep:
+
+    * ``hops`` -- ``{(mesh axis, "low" | "high"): bytes ONE shard receives}``
+      over wires: a hop is there where the mesh splits the axis among ``axes``
+      -- so the sweep cannot be the self-wrap and the direction is a
+      ``ppermute`` to ANOTHER shard -- and the side has a halo.  The bytes are
+      those of the message the sweep kind forms: the sliced slab of ``direct``
+      (the side's halo width x the raw cross-section), the packed buffer of
+      ``ypack`` / ``zpack`` with its lane padding, every quantity.  ``{}`` on
+      one device.
+    * ``packed`` -- ``(bytes, kernels)`` one shard moves through the packed
+      sweeps' pack and unpack kernels (``z/ypack_message_stats``), split axis
+      or not: a packed sweep packs its own wrap too.
+
+    The ONE account of the wires: the step builders' ``WireAccount``s and
+    ``DistributedDomain``'s own (``exchange()``, ``exchange_hop_bytes``) are
+    made of it, and ``tests/test_wire_account.py`` holds it to the ``ppermute``
+    operands of the traced programs."""
+    itemsizes = [
+        jnp.dtype(dt).itemsize * k for dt, k in zip(dtypes, cells or [1] * len(dtypes))
+    ]
+    hops: Dict[Tuple[str, str], int] = {}
+    packed_bytes = packed_kernels = 0
+    for a, kind, r_lo, r_hi in _sweeps(
+        mesh_shape, radius, raw_spatial, dtypes, valid_last, route, axes
+    ):
+        if kind in _PACKED_STATS:
+            nbytes, kernels = _PACKED_STATS[kind](raw_spatial, r_lo, r_hi, itemsizes)
+            packed_bytes += nbytes
+            packed_kernels += kernels
+        if mesh_shape[a] == 1:
             continue
-        kind = _sweep_kind(
-            a, r_lo, r_hi, mesh_shape[a], raw_spatial[a],
-            valid_last[a] if valid_last is not None else None,
-            route, dtypes, True,  # block rank matters to the self-wrap alone
-        )
         assert kind != "wrap", (a, mesh_shape)  # a split axis has a neighbour
-        wired += MESH_AXES[a]
-        if kind == "zpack":
-            nbytes += zpack_message_stats(raw_spatial, r_lo, r_hi, itemsizes)[0]
-        elif kind == "ypack":
-            nbytes += ypack_message_stats(raw_spatial, r_lo, r_hi, itemsizes)[0]
-        else:
-            face = math.prod(raw_spatial) // raw_spatial[a]
-            nbytes += (r_lo + r_hi) * face * sum(itemsizes)
-    return wired, nbytes
+        face = math.prod(raw_spatial) // raw_spatial[a]
+        for side, lo, hi in (("low", r_lo, 0), ("high", 0, r_hi)):
+            if kind in _PACKED_STATS:
+                nbytes = _PACKED_STATS[kind](raw_spatial, lo, hi, itemsizes)[0]
+            else:
+                nbytes = (lo + hi) * face * sum(itemsizes)
+            if nbytes:
+                hops[(MESH_AXES[a], side)] = nbytes
+    return WireAccount(1, hops, 1, (packed_bytes, packed_kernels))
+
+
+class WireAccount(NamedTuple):
+    """What a built step sends over wires, declared by its builder from the
+    message plan it resolved: ``exchanges`` halo exchanges, ``hops`` --
+    ``{(mesh axis, side): bytes ONE shard receives}`` -- and ``packed`` --
+    ``(bytes, kernels)`` one shard's packed sweeps move --, both summed over
+    those exchanges, per ``every`` raw steps (a macro's depth; a dispatch
+    whose steps are no multiple runs one more, shallower macro behind a whole
+    exchange).  ``DistributedDomain.run_step`` counts ``domain.exchange.*``,
+    ``exchange.hop.*.bytes`` and ``exchange.packed.*`` from it and the
+    ``domain.step`` span says ``wired`` / ``wire_bytes`` of it, so span and
+    counter cannot differ."""
+
+    exchanges: int
+    hops: Mapping[Tuple[str, str], int]
+    every: int = 1
+    packed: Tuple[int, int] = (0, 0)
+
+    def units(self, raw_steps: int) -> int:
+        """The exchanging units (steps, macros) a dispatch of ``raw_steps``
+        runs."""
+        return -(-raw_steps // self.every)
+
+    def said(self) -> Tuple[str, int]:
+        """``(wired, wire_bytes)`` as a span says them: the axes with a hop,
+        and the bytes one shard receives over all hops a RAW step (a macro's
+        bytes over its depth, whole where the depth divides them)."""
+        axes = {axis for axis, _ in self.hops}
+        return (
+            "".join(a for a in MESH_AXES if a in axes),
+            sum(self.hops.values()) // self.every,
+        )
+
+
+def sum_accounts(accounts, every: int = 1) -> WireAccount:
+    """The exchanges of one unit (a step's stages, a macro's one) as one
+    account: counts, hops and packed traffic added up."""
+    accounts = list(accounts)
+    return WireAccount(
+        sum(a.exchanges for a in accounts),
+        sum_hops(*(a.hops for a in accounts)),
+        every,
+        (sum(a.packed[0] for a in accounts), sum(a.packed[1] for a in accounts)),
+    )
+
+
+def z_slab_hops(mesh_shape: Tuple[int, int, int], Xr: int, Yr: int, s: int,
+                itemsizes) -> Dict[Tuple[str, str], int]:
+    """The hops of one macro's z-slab permutes (``ops/stream.py
+    permute_and_extend_z_slabs``), one z-major ``(Xr, 2s, Yr)`` slab buffer a
+    quantity: its two ``(Xr, s, Yr)`` halves over z, each then extended by
+    ``(Xr, s, s)`` rows from either y neighbour and ``(s, s, Yr)`` planes from
+    either x neighbour.  As ``exchange_account``'s hops: split axes only."""
+    cells = {"x": 2 * s * s * Yr, "y": 2 * Xr * s * s, "z": Xr * s * Yr}
+    return {
+        (MESH_AXES[a], side): cells[MESH_AXES[a]] * sum(itemsizes)
+        for a in range(3) if mesh_shape[a] > 1
+        for side in ("low", "high")
+    }
+
+
+def sum_hops(*plans: Mapping[Tuple[str, str], int]) -> Dict[Tuple[str, str], int]:
+    """Several exchanges' hops (``exchange_account``) added hop by hop."""
+    out: Dict[Tuple[str, str], int] = {}
+    for hops in plans:
+        for hop, nbytes in hops.items():
+            out[hop] = out.get(hop, 0) + nbytes
+    return out
 
 
 def uneven_axes(

@@ -908,6 +908,8 @@ def make_stream_step(
     # what this step's ``domain.step`` span says of the plan it runs NOW (the
     # ladder may have moved it)
     step._span_args = lambda: stream_span_args(step._stream_plan, x_radius, len(dd._handles))
+    # ... and of the wires its exchanges cross (``run_step``'s counters)
+    step._wire_account = lambda: step._stream_plan["wire_account"]
     step._resilience = ladder
     step._resilience_label = "stream"
     return step
@@ -977,12 +979,13 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         # ... and where its lane padding lives: "vmem" (the pass widens
         # the raw block's plane itself) or "none" (nothing to pad)
         args["lane_pad"] = plan["lane_pad"]
-    if "wired" in plan:
-        # the axes whose sweep of the step's exchange crosses to another
-        # shard, and the bytes one shard receives over them a step, all
-        # stages (ops/exchange.py wire_plan): "" and 0 on one device
-        args["wired"] = plan["wired"]
-        args["wire_bytes"] = plan["wire_bytes"]
+    # the axes whose sweep of the step's exchanges crosses to another shard,
+    # and the bytes one shard receives over them a raw step, all stages (the
+    # plan's ``wire_account``, which ``run_step`` counts the wires from; ops/
+    # exchange.py exchange_account): "" and 0 on one device
+    args["wired"] = plan["wired"]
+    args["wire_bytes"] = plan["wire_bytes"]
+    if "wired_edges" in plan:
         # ... and the pairs of those axes whose EDGE halo the kernels read
         # (a diagonal offset across both): it reaches a shard over two wires
         # in turn ("xy"; several "/"-joined; "" where no edge is read so)
@@ -1003,7 +1006,7 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         args["aliased"] = each(
             lambda st: len({q for p in st["passes"] for q in p["reads"]}) if in_place else 0
         )
-        if "wired" in plan:  # the bytes over the wires, stage by stage
+        if "wire_bytes_by_stage" in plan:  # the bytes over the wires, stage by stage
             args["wire_bytes_by_stage"] = "/".join(str(b) for b in plan["wire_bytes_by_stage"])
     return args
 

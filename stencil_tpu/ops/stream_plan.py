@@ -1271,7 +1271,7 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
     ``interpret`` picks the rotate the traces use (``trace_plane_kernel``).
     The one abstract trace a group of each stage's kernel is made here, once.
     Raises ``ValueError`` for a plane step that fits in no pass."""
-    from stencil_tpu.ops.exchange import wire_plan
+    from stencil_tpu.ops.exchange import sum_accounts
 
     names = [h.name for h in dd._handles]
     raw = dd.local_spec().raw_size()
@@ -1364,29 +1364,25 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
         # the wrap pass writes fresh results: two macros a trip bring the
         # loop's carry home (macro_loop; domain.step's ``macros_per_trip``)
         plan["macros_per_trip"] = macros_per_trip(False)
+    # what the step's exchanges send to ANOTHER shard, hop by hop, and pack
+    # (ops/exchange.py ``exchange_account``, i.e. ``_sweep_kind``: the message
+    # plan that is run): ``domain.run_step`` counts the wires from it and
+    # ``domain.step`` says ``wired`` / ``wire_bytes`` of it, a raw step
+    stage_wires = _stage_wires(dd, plan, (raw.x, raw.y, raw.z), exch_route)
+    plan["wire_account"] = sum_accounts(
+        (st for st in stage_wires if st is not None),
+        every=plan["m"] if route == "wavefront" else 1,
+    )
+    plan["wired"], plan["wire_bytes"] = plan["wire_account"].said()
     if route == "plane" and not fused:
-        # what the sweeps the passes leave send to ANOTHER shard (domain.step's
-        # ``wired`` and ``wire_bytes``): per stage the message plan of its
-        # exchange (ops/exchange.py wire_plan, i.e. ``_sweep_kind``), the axes
-        # joined, the bytes stage by stage (``wire_bytes_by_stage``) and summed
-        # over the stages of a step.  No other schedule says.
-        dtype_of = {h.name: dd.field_dtype(h) for h in dd._handles}
-        per_stage = [
-            wire_plan(
-                tuple(dd.mesh.shape[a] for a in MESH_AXES), dd._shell_radius,
-                (raw.x, raw.y, raw.z), [dtype_of[name] for name in st["readers"]],
-                valid_last=dd._valid_last, route=exch_route,
-                axes=swept_axes(plan),
-            ) if st["readers"] else ("", 0)  # a stage that exchanges nothing
-            for st in plan["stages"]
-        ]
-        plan["wired"] = "".join(ax for ax in MESH_AXES if any(ax in w for w, _ in per_stage))
-        plan["wire_bytes_by_stage"] = tuple(b for _, b in per_stage)
-        plan["wire_bytes"] = sum(plan["wire_bytes_by_stage"])
-        # ... and the pairs of wired axes a kernel reads DIAGONALLY across
+        # ... the bytes stage by stage (``wire_bytes_by_stage``), and the
+        # pairs of wired axes a kernel reads DIAGONALLY across
         # (``wired_edges``): that edge halo is the diagonal neighbour's, and
         # reaches the shard over two wires in turn, the later sweep carrying
-        # what the earlier one received
+        # what the earlier one received.  No other schedule says.
+        plan["wire_bytes_by_stage"] = tuple(
+            sum(st.hops.values()) if st else 0 for st in stage_wires
+        )
         plan["wired_edges"] = tuple(
             pair for pair in plan["edge_reads"] if all(ax in plan["wired"] for ax in pair)
         )
@@ -1407,6 +1403,45 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
         # count) 2, elastic's none 1
         plan["steps_per_trip"] = _carry_period(names, plan["stages"])
     return ResolvedPlan(plan, stage_runs, wrap_fills, exch_route, overlap_source, halo_source)
+
+
+def _stage_wires(dd, plan: Mapping, raw_spatial, exch_route: str) -> list:
+    """Per exchange of ONE unit of a resolved plan -- a stage of a plane step,
+    a wavefront macro -- its account (``exchange_account``), None for a stage
+    that exchanges nothing; ``[]`` on the wrap route.  Each entry mirrors the
+    call the route's builder makes (``ops/stream.py``)."""
+    from stencil_tpu.ops.exchange import WireAccount, exchange_account, sum_hops, z_slab_hops
+
+    mesh_shape = tuple(dd.mesh.shape[a] for a in MESH_AXES)
+    dtype_of = {h.name: dd.field_dtype(h) for h in dd._handles}
+    everyone = list(dtype_of.values())
+    shell = dd._shell_radius
+    if plan["route"] == "wrap":
+        return []
+    if plan["halo"] == "fused":  # fused_shell_exchange: every block, all three axes
+        n = len(plan["stages"]) if plan["route"] == "plane" else 1
+        return [exchange_account(mesh_shape, shell, raw_spatial, everyone, route=exch_route)] * n
+    if plan["route"] == "plane":
+        return [
+            exchange_account(
+                mesh_shape, shell, raw_spatial, [dtype_of[name] for name in st["readers"]],
+                valid_last=dd._valid_last, route=exch_route, axes=swept_axes(plan),
+            ) if st["readers"] else None
+            for st in plan["stages"]
+        ]
+    if plan["z_slabs"]:
+        # x and y in the array on the direct route, z as slab buffers that the
+        # y and x neighbours extend (permute_and_extend_z_slabs)
+        return [WireAccount(1, sum_hops(
+            exchange_account(mesh_shape, shell, raw_spatial, everyone, axes=(0, 1)).hops,
+            z_slab_hops(
+                mesh_shape, raw_spatial[0], raw_spatial[1], shell.lo().x,
+                [jnp.dtype(dt).itemsize for dt in everyone],
+            ),
+        ))]
+    return [exchange_account(
+        mesh_shape, shell, raw_spatial, everyone, valid_last=dd._valid_last, route=exch_route,
+    )]
 
 
 def swept_axes(plan: Mapping) -> Tuple[int, ...]:

@@ -18,11 +18,20 @@ from __future__ import annotations
 
 # --- counters (monotonic; always recorded, snapshot seeds all of these) -----
 
-#: halo exchanges accounted (direct ``exchange()``/``exchange_many()`` calls
-#: plus one per fused-step exchange inside ``run_step`` dispatches)
+#: halo exchanges accounted: direct ``exchange()``/``exchange_many()`` calls,
+#: plus those a ``run_step`` dispatch makes by the step's OWN account
+#: (``ops/exchange.WireAccount``, declared by its builder from the message
+#: plan it runs: three a time step of the staged MHD step, one a macro of a
+#: wavefront, none on a wrap route; a caller's own step callable that
+#: declares nothing falls back on one a macro)
 EXCHANGE_COUNT = "domain.exchange.count"
-#: analytic bytes moved by those exchanges (``exchange_bytes_total`` per
-#: exchange — the reference's exchange_bytes_for_method accounting)
+#: bytes those exchanges send over WIRES, summed over subdomains: always the
+#: sum of the ``exchange.hop.*.bytes`` counters, 0 on one chip -- a ``run_step``
+#: dispatch's from its declared account, a direct call's (and the undeclared
+#: step's fallback) from the domain's own.  The analytic
+#: ``exchange_bytes_total`` (every shell cell, the reference's
+#: exchange_bytes_for_method accounting) is the ``domain.exchange`` span's
+#: ``nbytes`` and the gauge ``EXCHANGE_BYTES_PER_EXCHANGE``
 EXCHANGE_BYTES = "domain.exchange.bytes"
 #: ``run_step`` dispatches (device-side loops of many raw iterations)
 STEP_DISPATCHES = "domain.step.dispatches"
@@ -52,9 +61,11 @@ TUNE_TRIALS = "tune.trials"
 TUNE_PRUNED = "tune.pruned"
 #: winning configs selected (and persisted) by a completed search
 TUNE_SELECTED = "tune.selected"
-#: analytic bytes moved through packed z-shell message buffers (the
-#: ``zpack_*`` exchange routes; 0 under ``direct`` — ops/exchange.py
-#: ``zpack_message_stats``)
+#: analytic bytes moved through packed shell message buffers (the ``zpack_*``
+#: / ``yzpack_*`` exchange routes; 0 under ``direct`` — ops/exchange.py
+#: ``z/ypack_message_stats``), of the exchanges ``EXCHANGE_COUNT`` counts and
+#: from the same account (``WireAccount.packed``: the quantities a step's
+#: exchange carries)
 EXCHANGE_PACKED_BYTES = "exchange.packed.bytes"
 #: analytic pack+unpack kernel launches of those packed exchanges
 EXCHANGE_PACKED_KERNELS = "exchange.packed.kernels"
@@ -131,11 +142,16 @@ SERVE_BATCH_FALLBACKS = "serve.batch.fallbacks"
 SERVE_BATCH_DISPATCHES = "serve.batch.dispatches"
 #: successful sub-slice packed cycles (same role for the bin-packer)
 SERVE_SUBSLICE_DISPATCHES = "serve.subslice.dispatches"
-#: analytic bytes moved per exchange over ONE mesh hop — one counter per
+#: bytes received over ONE mesh hop, summed over subdomains — one counter per
 #: (axis, direction) so the comms roofline can price each link of the
-#: realized mesh (the per-direction decomposition of ``domain.exchange.bytes``
-#: — ``DistributedDomain.exchange_hop_bytes``); 0 on axes the mesh does not
-#: split
+#: realized mesh: the messages of the plan that is RUN (``ops/exchange.
+#: exchange_account``, read off ``_sweep_kind``; a packed sweep counts its
+#: padded buffer) — for ``exchange()`` every quantity at the shell radius
+#: (``DistributedDomain.exchange_hop_bytes``), for a ``run_step`` dispatch the
+#: quantities, axes, radius and exchanges a step its builder declared
+#: (``WireAccount``); held hop by hop to the ``ppermute`` operands of the
+#: traced programs by ``tests/test_wire_account.py``; 0 on axes the mesh does
+#: not split
 EXCHANGE_HOP_X_LOW_BYTES = "exchange.hop.x.low.bytes"
 EXCHANGE_HOP_X_HIGH_BYTES = "exchange.hop.x.high.bytes"
 EXCHANGE_HOP_Y_LOW_BYTES = "exchange.hop.y.low.bytes"
@@ -213,10 +229,6 @@ TOTAL_BACKEND_COMPILES = "backend_compiles"
 TOTAL_CACHE_RETRIEVAL_SECONDS = "cache_retrieval_seconds"
 TOTAL_CACHE_HITS = "cache_hits"
 TOTAL_CACHE_MISSES = "cache_misses"
-#: compiles that asked the persistent cache at all
-TOTAL_CACHE_REQUESTS = "cache_requests"
-#: jax found the persistent cache switched off (once per check)
-TOTAL_CACHE_DISABLED = "cache_disabled"
 
 #: the two of jax's ``jax.monitoring`` events the listener treats specially:
 #: traces nest, and a backend compile is also counted
@@ -233,8 +245,6 @@ JAX_DURATION_TOTALS = {
 JAX_EVENT_TOTALS = {
     "/jax/compilation_cache/cache_hits": TOTAL_CACHE_HITS,
     "/jax/compilation_cache/cache_misses": TOTAL_CACHE_MISSES,
-    "/jax/compilation_cache/compile_requests_use_cache": TOTAL_CACHE_REQUESTS,
-    "/jax/compilation_cache/task_disabled_cache": TOTAL_CACHE_DISABLED,
 }
 
 #: (epoch, total, phase) -> the counter's name: span totals for the timed
@@ -422,20 +432,22 @@ ALL_HISTOGRAMS = frozenset({
 #: exchange does not sweep them (``ops/stream_plan.pass_wrap_fills``: the y / z
 #: axes the mesh does not split, "yz" on one chip, "z" on mesh [2,2,1], ""
 #: off the plane route's default schedule and wherever that axis's sweep is
-#: not the self-wrap), and on the plane route's swept exchange wired = the
-#: axes whose sweep sends its slabs to ANOTHER shard and wire_bytes = the
-#: bytes one shard receives over them a step, every stage
-#: (``ops/exchange.wire_plan``, read off what ``_sweep_kind`` decides:
-#: acoustic on mesh [2,2,1] "xy" and 23658496 = four radius-4 faces of
-#: ``u``'s 608^3 block, "" and 0 on one device); a STAGED step (``make_step``
+#: not the self-wrap), wired = the axes over which the step's exchanges send
+#: to ANOTHER shard and wire_bytes = the bytes one shard receives over them a
+#: RAW step, every stage -- the step's ``WireAccount``, which ``run_step``
+#: counts ``exchange.hop.*.bytes`` from (``ops/exchange.exchange_account``, read off
+#: what ``_sweep_kind`` decides: acoustic on mesh [2,2,1] "xy" and 23658496 =
+#: four radius-4 faces of ``u``'s 608^3 block, "" and 0 on one device; a
+#: wavefront's macro bytes over its depth; the bespoke ``Jacobi3D`` steps say
+#: both too); a STAGED step (``make_step``
 #: with a sequence of kernels) adds stages and passes, and says exchanged /
 #: written / renamed / aliased PER STAGE, in order: "6/3", "3/6", "0/0",
 #: "11/12" (``wrapped``, ``wired`` and ``wire_bytes`` are one value each: the
-#: first two functions of the mesh, the last summed over the stages) and, where
-#: it says ``wired``, wire_bytes_by_stage = those bytes stage by stage
+#: first two functions of the mesh, the last summed over the stages) and, on
+#: the plane route's swept exchange, wire_bytes_by_stage = those bytes stage by stage
 #: ("26359296/26359296/26359296" for Astaroth's MHD step on mesh [2,2,1]: three
 #: exchanges of the same eight fields; a stage that exchanges nothing 0); every
-#: step that says ``wired`` says wired_edges = the pairs of wired axes its
+#: plane step on that schedule says wired_edges = the pairs of wired axes its
 #: kernels read DIAGONALLY across (``ops/stream_plan.edge_reads`` over
 #: ``PlaneTrace.offsets``, "/"-joined): that EDGE halo is the diagonal
 #: neighbour's and reaches the shard over two wires in turn, the later sweep
@@ -506,7 +518,9 @@ SPAN_STEP = "domain.step"
 #: whose sweep is the self-wrap kernel, e.g. "z" on mesh [2,2,1], uneven_axes
 #: = the mesh axes swept at per-shard traced offsets because the mesh does
 #: not divide the extent (``ops/exchange.uneven_axes``), e.g. "xy" for 1191^3
-#: on that mesh, "" on every aligned extent]
+#: on that mesh, "" on every aligned extent, wire_bytes = the bytes one shard
+#: receives over wires per exchange (``domain.step``'s meaning; the sum of
+#: ``exchange_hop_bytes`` a subdomain)]
 SPAN_EXCHANGE = "domain.exchange"
 SPAN_SWAP = "domain.swap"
 #: ``realize()`` once the geometry is known: allocation, exchange build +

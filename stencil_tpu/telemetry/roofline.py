@@ -50,6 +50,12 @@ PHASE_BYTES_COUNTERS = {
     "pack": names.EXCHANGE_PACKED_BYTES,
 }
 
+#: the phases whose counter is HBM traffic, read against the HBM roofline.
+#: ``exchange``'s is bytes over WIRES (the sum of the ``exchange.hop.*``
+#: counters, 0 on one chip): its ceiling is the link, which the comms
+#: table's ``% of link`` reads it against
+HBM_PHASES = ("pack",)
+
 
 def peaks_for(chip: Optional[str],
               measured_hbm_gbps: Optional[float] = None) -> dict:
@@ -89,8 +95,8 @@ def roofline_report(
     ``attribution`` is ``{phase: {"device_us": ..., "events": ...}}``
     (``telemetry.device.attribute_device_time``; a host-span fallback uses
     the same shape with ``source="host"``).  Phases carrying an analytic
-    bytes counter report achieved GB/s and their fraction of the HBM
-    roofline; scope phases with no counter (interior/exterior) report time
+    bytes counter report achieved GB/s and, where the bytes are HBM traffic
+    (``HBM_PHASES``), their fraction of the HBM roofline; scope phases with no counter (interior/exterior) report time
     and their share of total device time — the overlap-efficiency inputs.
 
     ``counters_scope`` records what window the counters cover, because the
@@ -127,7 +133,7 @@ def roofline_report(
                 entry["bytes"] = int(b)
                 if s > 0:
                     entry["gbps"] = round(b / s / 1e9, 3)
-                    if peaks["hbm_gbps"]:
+                    if peaks["hbm_gbps"] and phase in HBM_PHASES:
                         entry["frac_of_roofline"] = round(
                             entry["gbps"] / peaks["hbm_gbps"], 4
                         )
@@ -186,9 +192,9 @@ def comms_roofline(
       over a profiler trace: collective-permute device time per registered
       ``exchange.<axis>.<side>`` scope, plus the coverage fraction of the
       whole exchange family;
-    * ``snapshot`` — the analytic ``exchange.hop.<axis>.<side>.bytes``
-      counters (``DistributedDomain`` decomposes ``domain.exchange.bytes``
-      per hop);
+    * ``snapshot`` — the ``exchange.hop.<axis>.<side>.bytes`` counters
+      (the wires of the message plans that ran; ``domain.exchange.bytes``
+      is their sum);
     * ``fabric_model`` — ``telemetry.fabric.link_model`` output (optional:
       without it, achieved rates report with null probed ceilings).
 

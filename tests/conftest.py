@@ -43,3 +43,26 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", _platform)
 jax.config.update("jax_enable_x64", os.environ["JAX_ENABLE_X64"] != "0")
+
+import sys  # noqa: E402
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(scope="session", autouse=True)
+def _trace_root_of_this_process():
+    """The benchmark's rehearsals write their traces under ``window.OUT`` and
+    ``harness/timeline.py`` reads "the newest ``*.xplane.pb``" under
+    ``TRACE_ROOT``: in the checkout that is ONE directory for every xdist
+    worker, and a traced rehearsal has read another worker's trace (ROADMAP
+    M7 (viii): ``test_selftest_elastic[a]``, ``test_bench_lbm``).  A worker is
+    a process and runs its tests in turn, so a root per process is a root per
+    run; the benchmark's own files stay as they are."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.harness import timeline, window
+
+    out = tempfile.mkdtemp(prefix="stencil_bench_out_")
+    window.OUT, timeline.TRACE_ROOT = out, os.path.join(out, "trace")
+    yield

@@ -145,7 +145,10 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
         assert m["cells"] == ["astaroth-mhd-256x4.bulk"] == declared[name]["workloads"], name
         assert m["moves"] == "mcells_per_s_chip", name
     mhdx4 -= {"collective_pct.mhdx4"}  # a trace_share: it reads opcodes, as PR 24's do
-    for name in (set(declared) - new - plane - staged - setup - wired - lbm - mhd - mhdx4
+    # PR 49's: the wires' own intervals (tests/test_bench_wires.py holds them)
+    wires = {n for n in declared if n.startswith("wire_")}
+    assert len(wires) == 10
+    for name in (set(declared) - new - plane - staged - setup - wired - lbm - mhd - mhdx4 - wires
                  - (ragged - {"collective_pct.ragged"})):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",

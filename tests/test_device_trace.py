@@ -218,7 +218,8 @@ class TestRoofline:
         # 6291456 B over 640 us of collective time
         assert ex["bytes"] == 6_291_456
         assert ex["gbps"] == pytest.approx(6_291_456 / 640e-6 / 1e9, rel=1e-3)
-        assert ex["frac_of_roofline"] == pytest.approx(ex["gbps"] / 819.0, rel=1e-2)
+        # bytes over wires: the link is their ceiling (the comms table), not HBM
+        assert ex["frac_of_roofline"] is None
         assert r["phases"][names.SPAN_OVERLAP_INTERIOR]["share_of_device"] > 0.5
         assert r["total_device_ms"] == pytest.approx(2.90)
         assert r["source"] == "device"

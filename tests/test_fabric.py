@@ -76,7 +76,7 @@ class TestProbe:
         loads it warm — ZERO device work (the probe-run counter does not
         move)."""
         mesh = _mesh222()
-        doc = fabric.ensure(mesh, nbytes=4096, reps=1)
+        doc = fabric.ensure(mesh, nbytes=1 << 20, reps=1)
         assert doc["bench"] == "fabric_probe"
         assert doc["topology"] == [2, 2, 2] and doc["n_devices"] == 8
         assert doc["protocol"]["edges"] == 24 and len(doc["links"]) == 48
@@ -98,7 +98,7 @@ class TestProbe:
         assert snap["counters"][names.FABRIC_CACHE_MISS] == 1
         assert snap["counters"][names.FABRIC_CACHE_HIT] == 0
 
-        doc2 = fabric.ensure(mesh, nbytes=4096, reps=1)
+        doc2 = fabric.ensure(mesh, nbytes=1 << 20, reps=1)
         assert doc2["links"] == doc["links"]
         snap = telemetry.snapshot()
         assert snap["counters"][names.FABRIC_PROBE_RUNS] == 24  # no device work
@@ -166,7 +166,7 @@ class TestProbe:
 class TestLinkModel:
     def test_link_model_and_summary_shapes(self):
         mesh = _mesh222()
-        doc = fabric.ensure(mesh, nbytes=4096, reps=1)
+        doc = fabric.ensure(mesh, nbytes=1 << 20, reps=1)
         model = fabric.link_model(doc)
         assert set(model["axes"]) == {"x", "y", "z"}
         for sides in model["axes"].values():

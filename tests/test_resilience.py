@@ -495,7 +495,11 @@ class TestLadderEngines:
             # raw block (ISSUE 41)
             "z_halo_patch": "tile",
             "lane_pad": "vmem",
+            # what a macro sends over the wires of mesh [2,2,2] (ISSUE 49)
+            "wire_account": step._stream_plan["wire_account"],
+            "wired": "xyz", "wire_bytes": step._stream_plan["wire_account"].said()[1],
         }
+        assert step._stream_plan["wire_account"].every == 3
         inject.set_plan("execute:vmem_oom:stream*2")
         dd.run_step(step, 4)
         assert step._stream_plan["route"] == "plane"

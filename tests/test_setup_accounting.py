@@ -209,7 +209,7 @@ def test_a_warm_persistent_cache_reads_hits_and_no_misses(tmp_path):
             _fresh_jit(3.75)(x).block_until_ready()
         c = _counters()
         return {t: c[_series(t, tm.PHASE_INIT)] for t in (
-            tm.TOTAL_BACKEND_COMPILES, tm.TOTAL_CACHE_REQUESTS, tm.TOTAL_CACHE_HITS,
+            tm.TOTAL_BACKEND_COMPILES, tm.TOTAL_CACHE_HITS,
             tm.TOTAL_CACHE_MISSES, tm.TOTAL_CACHE_RETRIEVAL_SECONDS)}
 
     try:
@@ -225,9 +225,9 @@ def test_a_warm_persistent_cache_reads_hits_and_no_misses(tmp_path):
         for k, v in saved.items():
             jax.config.update(k, v)
         compilation_cache.reset_cache()
-    assert cold[tm.TOTAL_BACKEND_COMPILES] == 1 and cold[tm.TOTAL_CACHE_REQUESTS] == 1, cold
+    assert cold[tm.TOTAL_BACKEND_COMPILES] == 1, cold
     assert cold[tm.TOTAL_CACHE_MISSES] == 1 and cold[tm.TOTAL_CACHE_HITS] == 0, cold
-    assert warm[tm.TOTAL_BACKEND_COMPILES] == 1 and warm[tm.TOTAL_CACHE_REQUESTS] == 1, warm
+    assert warm[tm.TOTAL_BACKEND_COMPILES] == 1, warm
     assert warm[tm.TOTAL_CACHE_HITS] == 1 and warm[tm.TOTAL_CACHE_MISSES] == 0, warm
     assert warm[tm.TOTAL_CACHE_RETRIEVAL_SECONDS] > 0 == cold[tm.TOTAL_CACHE_RETRIEVAL_SECONDS]
     # what ``setup_cold_compiles`` reads: compiles the cache did not serve

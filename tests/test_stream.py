@@ -448,6 +448,10 @@ def test_stream_separable_per_field_grouping(monkeypatch):
         # 30-lane raw block (ISSUE 41)
         "z_halo_patch": "tile",
         "lane_pad": "vmem",
+        # the account of the wires a macro crosses (ISSUE 49: tests/test_wire_account.py)
+        "wire_account": step._stream_plan["wire_account"],
+        "wired": step._stream_plan["wire_account"].said()[0],
+        "wire_bytes": step._stream_plan["wire_account"].said()[1],
     }
     assert step._span_args()["z_halo_patch"] == "tile"
     assert step._span_args()["lane_pad"] == "vmem"
@@ -524,6 +528,8 @@ def test_stream_depth_cap():
         # the 27-point kernel reads every edge and corner (ISSUE 39)
         "footprint": {"offcentre": 1, "diagonal": 1, "read_sides": 6},
         "macros_per_trip": 2,  # the wrap pass writes fresh results (ISSUE 39)
+        # no exchange, no wire (ISSUE 49)
+        "wire_account": (0, {}, 1, (0, 0)), "wired": "", "wire_bytes": 0,
     }
     for a, b in outs:  # uncapped wrap vs the XLA ground truth
         np.testing.assert_allclose(a, b, **TOL)

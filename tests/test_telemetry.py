@@ -519,18 +519,21 @@ class TestResilienceIntegration:
 class TestDomainAccounting:
     def test_exchange_bytes_and_timing_single_path(self, tmp_path):
         """``exchange()``/``swap()`` feed the reference-parity DomainStats
-        AND the telemetry histograms from one timing path, and the analytic
-        byte counters match ``exchange_bytes_total``."""
+        AND the telemetry histograms from one timing path; the byte counter
+        is the wires of the exchange's message plan (the sum of its hops, as
+        for a step: ISSUE 49), the gauge the analytic ``exchange_bytes_total``."""
         telemetry.enable(dir=str(tmp_path))
         dd, _ = _mk_domain(["u", "v"], jax.devices()[:8])
-        per = dd.exchange_bytes_total()
+        per = sum(dd.exchange_hop_bytes().values())
         dd.exchange()
         dd.swap()
         dd.exchange_many(3)
         snap = telemetry.snapshot()
         assert snap["counters"][names.EXCHANGE_COUNT] == 4
-        assert snap["counters"][names.EXCHANGE_BYTES] == 4 * per
-        assert snap["gauges"][names.EXCHANGE_BYTES_PER_EXCHANGE] == per
+        assert snap["counters"][names.EXCHANGE_BYTES] == 4 * per > 0
+        assert snap["counters"][names.EXCHANGE_BYTES] == sum(
+            snap["counters"][c] for c in names.EXCHANGE_HOP_BYTES.values())
+        assert snap["gauges"][names.EXCHANGE_BYTES_PER_EXCHANGE] == dd.exchange_bytes_total()
         assert snap["histograms"][names.EXCHANGE_SECONDS]["count"] == 1
         assert snap["histograms"][names.SWAP_SECONDS]["count"] == 1
         # telemetry timing populated DomainStats without enable_exchange_stats
@@ -559,12 +562,12 @@ class TestDomainAccounting:
         telemetry.enable(dir=str(tmp_path))
         dd, _ = _mk_domain(["u"], jax.devices()[:8], mult=2)
         step = dd.make_step(mean6_kernel, overlap=False)
-        per = dd.exchange_bytes_total()
+        per = sum(dd.exchange_hop_bytes().values())  # the wires of one exchange (ISSUE 49)
         dd.run_step(step, 3)  # 3 macros = 6 raw iterations, 3 exchanges
         snap = telemetry.snapshot()
         assert snap["counters"][names.STEP_ITERATIONS] == 6
         assert snap["counters"][names.EXCHANGE_COUNT] == 3
-        assert snap["counters"][names.EXCHANGE_BYTES] == 3 * per
+        assert snap["counters"][names.EXCHANGE_BYTES] == 3 * per > 0
 
 
 # --- drivers and bench -------------------------------------------------------
@@ -650,7 +653,7 @@ def test_bench_json_grows_telemetry_section(tmp_path):
     tel = artifact["telemetry"]
     assert tel["histograms"][names.STEP_SECONDS]["count"] > 0
     assert tel["histograms"][names.STEP_SECONDS]["min"] > 0
-    assert tel["counters"][names.EXCHANGE_BYTES] > 0
+    assert tel["counters"][names.EXCHANGE_BYTES] == 0  # one chip: a step's own account has no wire (ISSUE 49)
     assert tel["counters"][names.STEP_ITERATIONS] > 0
     # resilience counters present (zero on a clean run) — the diffable part
     assert tel["counters"][names.RETRY_ATTEMPTS] == 0

@@ -8,8 +8,9 @@ walker or a source-level heuristic the tracer can defeat:
   property IS a dataflow property (arxiv 2401.16677 makes the same point:
   overlap is what the compiler's dependency graph permits).  Replaces the
   hand-rolled taint pass ``tests/test_overlap_structural.py`` carried.
-* ``exchange-structure``  — the fused ≤6-permute one-message-per-direction
-  exchange (packer.cuh:52-69's collapse) must survive every route and any
+* ``exchange-structure``  — the fused exchange (packer.cuh:52-69's
+  collapse): one FACE message a direction (≤6) plus at most one corner relay
+  a direction of a jointly swept axis (≤2) must survive every route and any
   quantity count.
 * ``sliver-dus``          — the thin-z relayout trap (PERF_NOTES "Thin
   z-region access") checked on the traced program, where the source rule
@@ -75,6 +76,7 @@ walker or a source-level heuristic the tracer can defeat:
 
 from __future__ import annotations
 
+import math
 from typing import List
 
 from stencil_tpu.analysis.framework import (
@@ -90,9 +92,12 @@ from stencil_tpu.analysis.framework import (
 #: tile the DUS is guaranteed partial-tile relayout bait.
 SLIVER_Z_LIMIT = 8
 
-#: the fused-exchange bound: ≤ 2 ppermutes per axis sweep, ≤ 6 total,
-#: regardless of quantity count (SURVEY.md §7 "26-neighbor exchange")
+#: the fused-exchange bound: ≤ 2 FACE ppermutes per axis sweep, ≤ 6 total,
+#: regardless of quantity count (SURVEY.md §7 "26-neighbor exchange") ...
 MAX_PERMUTES = 6
+#: ... and, where two wired axes sweep jointly (ops/exchange.py
+#: ``_sweep_groups``), one corner RELAY behind each face of the second axis
+MAX_RELAYS = 2
 
 
 def _exchanging(art: ProgramArtifact) -> bool:
@@ -219,9 +224,10 @@ class OverlapIndependence(Contract):
 class ExchangeStructure(Contract):
     name = "exchange-structure"
     why = (
-        "every exchange route traces to <=6 ppermutes, one fused message "
-        "per direction, independent of the quantity count (the reference's "
-        "packed-buffer collapse, packer.cuh:52-69)"
+        "every exchange route traces to one fused FACE message per direction "
+        "(<=6 ppermutes) plus at most one smaller corner relay per direction "
+        "of ONE jointly swept axis (<=2), independent of the quantity count "
+        "(the reference's packed-buffer collapse, packer.cuh:52-69)"
     )
 
     def applies_to(self, art: ProgramArtifact) -> bool:
@@ -235,37 +241,69 @@ class ExchangeStructure(Contract):
         return art.kind in ("step", "fn") and not (art.plan or {}).get("z_slabs")
 
     def check(self, art: ProgramArtifact) -> List[Finding]:
-        from collections import Counter
-
         from stencil_tpu.analysis import jaxpr as jx
+
+        def is_permute(e) -> bool:
+            return e.primitive.name == "ppermute"
+
+        def nbytes(e) -> int:
+            return sum(
+                math.prod(v.aval.shape) * v.aval.dtype.itemsize for v in e.invars
+            )
 
         out: List[Finding] = []
         saw_any = False
         for j in jx.walk(getattr(art.closed, "jaxpr", art.closed)):
-            pps = [e for e in j.eqns if e.primitive.name == "ppermute"]
-            if not pps:
+            if not any(is_permute(e) for e in j.eqns):
                 continue
             saw_any = True
-            if len(pps) > MAX_PERMUTES:
+            # a permute whose operand derives from an earlier permute's result
+            # is ``tainted``: what a relay of RECEIVED corners must be
+            by_scope: dict = {}
+            for row in jx.taint_rows(j, source=is_permute, watch=is_permute):
+                by_scope.setdefault(row.scopes, []).append(row)
+            faces, relayed = 0, []
+            for ns, rows in by_scope.items():
+                rows = sorted(rows, key=lambda r: -nbytes(r.eqn))
+                relay = rows[1] if len(rows) == 2 else None
+                if len(rows) > 2 or (
+                    relay is not None
+                    and not (relay.tainted and nbytes(relay.eqn) < nbytes(rows[0].eqn))
+                ):
+                    out.append(
+                        art.finding(
+                            self.name,
+                            f"{len(rows)} ppermutes under one direction scope "
+                            f"({ns!r}) and the extra is no corner relay (a "
+                            "smaller message of cells another permute "
+                            "received): the per-quantity messages did not "
+                            "fuse into one buffer per direction",
+                        )
+                    )
+                    faces += len(rows)
+                    continue
+                faces += 1
+                if relay is not None:
+                    relayed.append(ns)
+            if faces > MAX_PERMUTES:
                 out.append(
                     art.finding(
                         self.name,
-                        f"one traced exchange issues {len(pps)} ppermutes "
+                        f"one traced exchange issues {faces} face ppermutes "
                         f"(> {MAX_PERMUTES}): the per-direction fusion is "
                         "broken",
                     )
                 )
-            scopes = Counter(jx.name_stack_str(e) for e in pps)
-            for ns, n in scopes.items():
-                if n > 1:
-                    out.append(
-                        art.finding(
-                            self.name,
-                            f"{n} ppermutes under one direction scope "
-                            f"({ns!r}): the per-quantity messages did not "
-                            "fuse into one buffer per direction",
-                        )
+            # ``.../exchange.y.low`` -> ``.../exchange.y``: the relays of one
+            # exchange all complete the halo of ONE axis, the pair's second
+            if len(relayed) > MAX_RELAYS or len({ns.rpartition(".")[0] for ns in relayed}) > 1:
+                out.append(
+                    art.finding(
+                        self.name,
+                        f"corner relays under {sorted(relayed)}: more than one "
+                        f"a direction of ONE jointly swept axis (<= {MAX_RELAYS})",
                     )
+                )
         if art.kind == "exchange" and not saw_any:
             out.append(
                 art.finding(

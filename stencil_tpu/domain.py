@@ -1425,6 +1425,8 @@ class DistributedDomain:
         if account.packed[1]:
             telemetry.inc(tm.EXCHANGE_PACKED_BYTES, scale * account.packed[0])
             telemetry.inc(tm.EXCHANGE_PACKED_KERNELS, scale * account.packed[1])
+        if account.joint[1]:
+            telemetry.inc(tm.EXCHANGE_JOINT_SWEEPS, scale * account.joint[1])
 
     def exchange(self) -> None:
         """Fill every quantity's halo shell (src/stencil.cu:670-864)."""
@@ -1434,7 +1436,7 @@ class DistributedDomain:
             "time_exchange", tm.EXCHANGE_SECONDS, tm.SPAN_EXCHANGE, sync=True,
             route=self._exchange_route, nbytes=nbytes, count=1,
             wrap_axes=self._wrap_axes, uneven_axes=self._uneven_axes,
-            wire_bytes=self._wires.said()[1],
+            wire_bytes=self._wires.said()[1], joint=self._wires.joint[0],
             **self._dispatch_span_args(self._exchange_fn),
         ):
             self._curr = self._watched_call(
@@ -1463,7 +1465,7 @@ class DistributedDomain:
             tm.SPAN_EXCHANGE, route=self._exchange_route,
             nbytes=nbytes, count=steps,
             wrap_axes=self._wrap_axes, uneven_axes=self._uneven_axes,
-            wire_bytes=self._wires.said()[1],
+            wire_bytes=self._wires.said()[1], joint=self._wires.joint[0],
             **self._dispatch_span_args(self._exchange_many_fn, steps),
         ):
             self._curr = self._exchange_many_fn(self._curr, steps)
@@ -1841,7 +1843,7 @@ class DistributedDomain:
 
         account = sum_accounts([self._exchange_account()] * len(stages), every=mult)
         step._wire_account = lambda: account
-        step._span_args = lambda: dict(zip(("wired", "wire_bytes"), account.said()))
+        step._span_args = account.span_args
         return step
 
     def run_step(self, step_fn, steps: int = 1, label: str = None) -> None:

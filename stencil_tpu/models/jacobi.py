@@ -572,14 +572,13 @@ class Jacobi3D:
 
         dtype = dd.field_dtype(self.h)
         if z_slab_mode:
-            hops = sum_hops(
-                exchange_account(mesh_shape, shell, (Xr, Yr, n.z if z_ring_mode else Zp),
-                                 [dtype], axes=(0, 1)).hops,
-                z_slab_hops(mesh_shape, Xr, Yr, m, [dtype.itemsize]),
-            )
+            swept = exchange_account(mesh_shape, shell, (Xr, Yr, n.z if z_ring_mode else Zp),
+                                     [dtype], axes=(0, 1))
+            hops = sum_hops(swept.hops, z_slab_hops(mesh_shape, Xr, Yr, m, [dtype.itemsize]))
         else:
-            hops = exchange_account(
-                mesh_shape, shell, (Xr, Yr, Zr), [dtype], valid_last=dd._valid_last).hops
+            swept = exchange_account(
+                mesh_shape, shell, (Xr, Yr, Zr), [dtype], valid_last=dd._valid_last)
+            hops = swept.hops
         span_args = {"macros_per_trip": per_trip}
         if z_slab_mode:
             # where the kernel patches its z halo, read off the working
@@ -587,7 +586,8 @@ class Jacobi3D:
             span_args["z_halo_patch"] = z_halo_patch_form(
                 _ZRING_OFF + n.z if z_ring_mode else Zp, m
             )
-        return self._declare_wires(step, WireAccount(1, hops, depth_run), **span_args)
+        return self._declare_wires(
+            step, WireAccount(1, hops, depth_run, joint=swept.joint), **span_args)
 
     def _make_pallas_step(self):
         """Fused exchange + plane-streaming pallas kernel (ops/jacobi_pallas):
@@ -773,8 +773,8 @@ class Jacobi3D:
     def _declare_wires(step, account, **span_args):
         """``step`` with its account of the wires (``run_step``'s counters)
         and what its ``domain.step`` span says: ``span_args`` and the
-        account's ``wired`` / ``wire_bytes``."""
-        span_args["wired"], span_args["wire_bytes"] = account.said()
+        account's ``wired`` / ``wire_bytes`` / ``joint``."""
+        span_args.update(account.span_args())
         step._wire_account = lambda: account
         step._span_args = lambda: dict(span_args)
         return step

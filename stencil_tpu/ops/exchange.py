@@ -12,8 +12,19 @@ Design: each shard is a *shell-carrying* block — interior of size ``n`` plus
 exchange runs **three axis sweeps** (x, then y, then z).  Each sweep sends
 slabs spanning the *full* extent of the other axes — including their already-
 filled halos — so edge and corner data propagate without dedicated diagonal
-messages: 26 neighbor messages collapse into <=6 ppermutes (SURVEY.md §7
+messages: 26 neighbor messages collapse into <=6 face ppermutes (SURVEY.md §7
 "26-neighbor exchange").
+
+Two WIRED sweeps that follow each other fly JOINTLY (``_sweep_groups``; x and
+y on mesh [2,2,1]): a chip's x and y neighbours sit on different ICI ports, and
+all the y slabs need of the x sweep is their corner columns.  So both axes'
+faces are cut from the blocks as they enter and all four messages are sent
+before any is used; the corner strips — the x slabs a shard RECEIVED, on the y
+slabs' rows — follow the y faces to the same neighbours as one small fused
+message a direction (``_relay_corners``: <=2 more ppermutes, under the y
+direction scopes) and overwrite the stale columns of the received y slabs
+before they are blended.  The halos are bitwise those of the sweeps run in
+turn, and a sweep that flies alone is the same code with a group of one.
 
 The ``-dir`` extent convention holds by construction: the slab sent in
 direction ``+a`` has width ``radius(-a)`` (the receiver's ``-a`` halo width),
@@ -198,66 +209,89 @@ def _shift_from_high(x, axis_name: str, n: int):
         return lax.ppermute(x, axis_name, [(k, (k - 1) % n) for k in range(n)])
 
 
-def _fused_shift(slabs: List[jax.Array], shift_fn, name: str, n_dev: int) -> List[jax.Array]:
-    """ppermute several quantities' slabs as ONE fused message.
+class _Stacked(NamedTuple):
+    """Several quantities' slabs as ONE message (``_stack``): ``bufs`` -- per
+    dtype, in first-seen order, the slabs of that dtype concatenated along a
+    flattened leading (quantity / batch) axis --, ``idxs`` -- which slabs each
+    buffer holds -- and the slabs' own ``shapes``.  A single slab is its own
+    buffer, unreshaped."""
 
-    The reference packs all quantities of one neighbor into a single aligned
+    bufs: List[jax.Array]
+    idxs: List[List[int]]
+    shapes: List[Tuple[int, ...]]
+
+
+def _stack(slabs: List[jax.Array]) -> _Stacked:
+    """The reference packs all quantities of one neighbor into a single aligned
     buffer so message count is independent of field count (packer.cuh:52-69,
-    146-160).  Here: same-dtype slabs stack along a flattened leading axis
-    (one collective-permute carries the stack); mixed dtypes additionally
-    fuse byte-wise via ``bitcast_convert_type`` — one buffer per direction,
-    exactly the reference's byte-packed layout.  Returns received slabs in
-    the original order/shapes.
-    """
+    146-160).  Here: same-dtype slabs stack along a flattened leading axis."""
+    shapes = [s.shape for s in slabs]
     if len(slabs) == 1:
-        return [shift_fn(slabs[0], name, n_dev)]
+        return _Stacked(list(slabs), [[0]], shapes)
     # flatten leading (quantity/batch) dims so same-dtype slabs concatenate
     flat = [s.reshape((-1,) + s.shape[-3:]) for s in slabs]
     groups: Dict[object, List[int]] = {}
     for i, s in enumerate(flat):
         groups.setdefault(s.dtype, []).append(i)
-    bufs = [
-        (dt, idxs, jnp.concatenate([flat[i] for i in idxs], axis=0))
-        for dt, idxs in groups.items()
-    ]
-    if len(bufs) == 1:
-        dt, idxs, buf = bufs[0]
-        bufs = [(dt, idxs, shift_fn(buf, name, n_dev))]
-    else:
-        # mixed dtypes: one byte buffer per direction (packer.cuh:52-69)
-        def to_bytes(v):
-            if v.dtype == jnp.bool_:
-                return v.reshape(-1).astype(jnp.uint8)  # lossless 0/1
-            if v.dtype.itemsize > 1:
-                return lax.bitcast_convert_type(v.reshape(-1), jnp.uint8).reshape(-1)
-            return lax.bitcast_convert_type(v.reshape(-1), jnp.uint8)
+    return _Stacked(
+        [jnp.concatenate([flat[i] for i in idxs], axis=0) for idxs in groups.values()],
+        list(groups.values()), shapes,
+    )
 
-        def from_bytes(p, dt):
-            if dt == jnp.bool_:
-                return p.astype(jnp.bool_)
-            if jnp.dtype(dt).itemsize > 1:
-                return lax.bitcast_convert_type(
-                    p.reshape(-1, jnp.dtype(dt).itemsize), dt
-                )
-            return lax.bitcast_convert_type(p, dt)
 
-        fused = jnp.concatenate([to_bytes(buf) for _, _, buf in bufs])
-        recv_bytes = shift_fn(fused, name, n_dev)
-        recv_parts, off = [], 0
-        for dt, _, buf in bufs:
-            nbytes = buf.size * buf.dtype.itemsize
-            p = recv_bytes[off : off + nbytes]
-            off += nbytes
-            recv_parts.append(from_bytes(p, dt).reshape(buf.shape))
-        bufs = [(dt, idxs, rp) for (dt, idxs, _), rp in zip(bufs, recv_parts)]
-    out: List[Optional[jax.Array]] = [None] * len(slabs)
-    for _, idxs, rbuf in bufs:
+def _unstack(st: _Stacked) -> List[jax.Array]:
+    """The slabs of a message, in their original order and shapes."""
+    if len(st.shapes) == 1:
+        return [st.bufs[0].reshape(st.shapes[0])]
+    out: List[Optional[jax.Array]] = [None] * len(st.shapes)
+    for buf, idxs in zip(st.bufs, st.idxs):
         off = 0
         for i in idxs:
-            k = flat[i].shape[0]
-            out[i] = rbuf[off : off + k].reshape(slabs[i].shape)
+            k = math.prod(st.shapes[i][:-3])
+            out[i] = buf[off : off + k].reshape(st.shapes[i])
             off += k
     return out  # type: ignore[return-value]
+
+
+def _shift_bufs(bufs: List[jax.Array], shift_fn, name: str, n_dev: int) -> List[jax.Array]:
+    """ppermute a message's buffers (``_Stacked.bufs``) as ONE
+    collective-permute: one dtype's buffer as it is; mixed dtypes additionally
+    fuse byte-wise via ``bitcast_convert_type`` — one buffer per direction,
+    exactly the reference's byte-packed layout."""
+    if len(bufs) == 1:
+        return [shift_fn(bufs[0], name, n_dev)]
+
+    # mixed dtypes: one byte buffer per direction (packer.cuh:52-69)
+    def to_bytes(v):
+        if v.dtype == jnp.bool_:
+            return v.reshape(-1).astype(jnp.uint8)  # lossless 0/1
+        if v.dtype.itemsize > 1:
+            return lax.bitcast_convert_type(v.reshape(-1), jnp.uint8).reshape(-1)
+        return lax.bitcast_convert_type(v.reshape(-1), jnp.uint8)
+
+    def from_bytes(p, dt):
+        if dt == jnp.bool_:
+            return p.astype(jnp.bool_)
+        if jnp.dtype(dt).itemsize > 1:
+            return lax.bitcast_convert_type(
+                p.reshape(-1, jnp.dtype(dt).itemsize), dt
+            )
+        return lax.bitcast_convert_type(p, dt)
+
+    recv_bytes = shift_fn(jnp.concatenate([to_bytes(buf) for buf in bufs]), name, n_dev)
+    recv, off = [], 0
+    for buf in bufs:
+        nbytes = buf.size * buf.dtype.itemsize
+        recv.append(from_bytes(recv_bytes[off : off + nbytes], buf.dtype).reshape(buf.shape))
+        off += nbytes
+    return recv
+
+
+def _fused_shift(slabs: List[jax.Array], shift_fn, name: str, n_dev: int) -> List[jax.Array]:
+    """ppermute several quantities' slabs as ONE fused message (``_stack``,
+    ``_shift_bufs``); returns received slabs in the original order/shapes."""
+    st = _stack(slabs)
+    return _unstack(st._replace(bufs=_shift_bufs(st.bufs, shift_fn, name, n_dev)))
 
 
 def _zpack_sweep(
@@ -499,19 +533,71 @@ def wrap_axes(
     )
 
 
-def _sweeps(mesh_shape, radius, raw_spatial, dtypes, valid_last, route, axes):
-    """``(axis, sweep kind, r_lo, r_hi)`` of every sweep of one
-    ``halo_exchange_multi`` that has a halo, the kind as ``_sweep_kind`` picks
-    it (block rank matters to the self-wrap alone, which is neither a wire nor
-    a packed sweep)."""
+class _Sweep(NamedTuple):
+    """One axis sweep of an exchange: the mesh ``axis``, the ``kind``
+    ``_sweep_kind`` picks for it, the halo widths of its two sides, the mesh
+    extent ``n_dev`` and the raw extent ``size`` on that axis, and ``v_last``,
+    the last shard's valid interior cells (None = even)."""
+
+    axis: int
+    kind: str
+    r_lo: int
+    r_hi: int
+    n_dev: int
+    size: int
+    v_last: Optional[int]
+
+    @property
+    def wired(self) -> bool:
+        """Cut, ``ppermute`` to ANOTHER shard, blend: the kind that can fly
+        beside another of its kind."""
+        return self.kind == "direct" and self.n_dev > 1
+
+
+def _sweeps(mesh_shape, radius, raw_spatial, dtypes, valid_last, route, axes, all_3d=True):
+    """The ``_Sweep`` of every axis of one ``halo_exchange_multi`` that has a
+    halo, in sweep order, the kind as ``_sweep_kind`` picks it (block rank,
+    ``all_3d``, matters to the self-wrap alone, which is neither a wire nor a
+    packed sweep)."""
     for a in axes:
         r_lo, r_hi = radius.axis(a, -1), radius.axis(a, +1)
         if r_lo + r_hi:
-            yield a, _sweep_kind(
-                a, r_lo, r_hi, mesh_shape[a], raw_spatial[a],
-                valid_last[a] if valid_last is not None else None,
-                route, dtypes, True,
-            ), r_lo, r_hi
+            v_last = valid_last[a] if valid_last is not None else None
+            yield _Sweep(
+                a,
+                _sweep_kind(
+                    a, r_lo, r_hi, mesh_shape[a], raw_spatial[a], v_last, route,
+                    dtypes, all_3d,
+                ),
+                r_lo, r_hi, mesh_shape[a], raw_spatial[a], v_last,
+            )
+
+
+def _sweep_groups(sweeps) -> List[List[_Sweep]]:
+    """The sweeps of one exchange in the groups that fly together: the first
+    two WIRED sweeps that follow each other with no other sweep between them
+    are one group of two -- both axes' faces cut from the blocks as they enter
+    and sent at once, the corner strips relayed behind them (``_sweep_group``)
+    -- and every other sweep is a group of one, in sweep order.  x and y on
+    mesh [2,2,1] and [2,2,2] (z after the pair, its slabs carrying the pair's
+    finished halos), y and z on [1,2,2]; on [2,1,2] the unsplit y lies between
+    the wires and every sweep flies alone, as do the packed kinds."""
+    sweeps = list(sweeps)
+    for i in range(len(sweeps) - 1):
+        if sweeps[i].wired and sweeps[i + 1].wired:
+            return (
+                [[s] for s in sweeps[:i]] + [sweeps[i : i + 2]]
+                + [[s] for s in sweeps[i + 2 :]]
+            )
+    return [[s] for s in sweeps]
+
+
+def _relay_cells(first: _Sweep, second: _Sweep, raw_spatial, r_second: int) -> int:
+    """The cells a quantity's corner relay carries to one side of ``second``
+    (halo width ``r_second`` there): both received slabs of ``first`` on that
+    side's slab rows, over the whole raw extent of the third axis."""
+    (third,) = {0, 1, 2} - {first.axis, second.axis}
+    return (first.r_lo + first.r_hi) * r_second * raw_spatial[third]
 
 
 _PACKED_STATS = {"zpack": zpack_message_stats, "ypack": ypack_message_stats}
@@ -537,8 +623,11 @@ def exchange_account(
       ``ppermute`` to ANOTHER shard -- and the side has a halo.  The bytes are
       those of the message the sweep kind forms: the sliced slab of ``direct``
       (the side's halo width x the raw cross-section), the packed buffer of
-      ``ypack`` / ``zpack`` with its lane padding, every quantity.  ``{}`` on
-      one device.
+      ``ypack`` / ``zpack`` with its lane padding, every quantity; the second
+      axis of a jointly swept pair (``_sweep_groups``) adds the corner relay
+      that follows its face (``_relay_cells``).  ``{}`` on one device.
+    * ``joint`` -- ``(the pair of axes whose sweeps fly jointly, 1)``,
+      ``("", 0)`` where every sweep flies alone.
     * ``packed`` -- ``(bytes, kernels)`` one shard moves through the packed
       sweeps' pack and unpack kernels (``z/ypack_message_stats``), split axis
       or not: a packed sweep packs its own wrap too.
@@ -552,25 +641,31 @@ def exchange_account(
     ]
     hops: Dict[Tuple[str, str], int] = {}
     packed_bytes = packed_kernels = 0
-    for a, kind, r_lo, r_hi in _sweeps(
-        mesh_shape, radius, raw_spatial, dtypes, valid_last, route, axes
+    joint = ""
+    for group in _sweep_groups(
+        _sweeps(mesh_shape, radius, raw_spatial, dtypes, valid_last, route, axes)
     ):
-        if kind in _PACKED_STATS:
-            nbytes, kernels = _PACKED_STATS[kind](raw_spatial, r_lo, r_hi, itemsizes)
-            packed_bytes += nbytes
-            packed_kernels += kernels
-        if mesh_shape[a] == 1:
-            continue
-        assert kind != "wrap", (a, mesh_shape)  # a split axis has a neighbour
-        face = math.prod(raw_spatial) // raw_spatial[a]
-        for side, lo, hi in (("low", r_lo, 0), ("high", 0, r_hi)):
-            if kind in _PACKED_STATS:
-                nbytes = _PACKED_STATS[kind](raw_spatial, lo, hi, itemsizes)[0]
-            else:
-                nbytes = (lo + hi) * face * sum(itemsizes)
-            if nbytes:
-                hops[(MESH_AXES[a], side)] = nbytes
-    return WireAccount(1, hops, 1, (packed_bytes, packed_kernels))
+        for s in group:
+            if s.kind in _PACKED_STATS:
+                nbytes, kernels = _PACKED_STATS[s.kind](raw_spatial, s.r_lo, s.r_hi, itemsizes)
+                packed_bytes += nbytes
+                packed_kernels += kernels
+            if s.n_dev == 1:
+                continue
+            assert s.kind != "wrap", (s.axis, mesh_shape)  # a split axis has a neighbour
+            face = math.prod(raw_spatial) // raw_spatial[s.axis]
+            for side, lo, hi in (("low", s.r_lo, 0), ("high", 0, s.r_hi)):
+                if s.kind in _PACKED_STATS:
+                    nbytes = _PACKED_STATS[s.kind](raw_spatial, lo, hi, itemsizes)[0]
+                else:
+                    nbytes = (lo + hi) * face * sum(itemsizes)
+                    if s is not group[0]:  # the corner relay behind the face
+                        nbytes += _relay_cells(group[0], s, raw_spatial, lo + hi) * sum(itemsizes)
+                if nbytes:
+                    hops[(MESH_AXES[s.axis], side)] = nbytes
+        if len(group) == 2:
+            joint = "".join(MESH_AXES[s.axis] for s in group)
+    return WireAccount(1, hops, 1, (packed_bytes, packed_kernels), (joint, 1 if joint else 0))
 
 
 class WireAccount(NamedTuple):
@@ -580,15 +675,20 @@ class WireAccount(NamedTuple):
     ``(bytes, kernels)`` one shard's packed sweeps move --, both summed over
     those exchanges, per ``every`` raw steps (a macro's depth; a dispatch
     whose steps are no multiple runs one more, shallower macro behind a whole
-    exchange).  ``DistributedDomain.run_step`` counts ``domain.exchange.*``,
-    ``exchange.hop.*.bytes`` and ``exchange.packed.*`` from it and the
-    ``domain.step`` span says ``wired`` / ``wire_bytes`` of it, so span and
+    exchange); ``joint`` -- ``(axes, sweeps)``: the mesh axes whose sweeps
+    fly jointly in those exchanges (``_sweep_groups``; "" where every sweep
+    flies alone) and how many joint sweeps a unit runs.
+    ``DistributedDomain.run_step`` counts ``domain.exchange.*``,
+    ``exchange.hop.*.bytes``, ``exchange.packed.*`` and
+    ``exchange.joint.sweeps`` from it and the ``domain.step`` span says
+    ``wired`` / ``wire_bytes`` / ``joint`` of it (``span_args``), so span and
     counter cannot differ."""
 
     exchanges: int
     hops: Mapping[Tuple[str, str], int]
     every: int = 1
     packed: Tuple[int, int] = (0, 0)
+    joint: Tuple[str, int] = ("", 0)
 
     def units(self, raw_steps: int) -> int:
         """The exchanging units (steps, macros) a dispatch of ``raw_steps``
@@ -605,6 +705,13 @@ class WireAccount(NamedTuple):
             sum(self.hops.values()) // self.every,
         )
 
+    def span_args(self) -> Dict[str, object]:
+        """What a ``domain.step`` / ``domain.exchange`` span says of this
+        account: ``wired`` and ``wire_bytes`` (``said``), and ``joint``, the
+        axes whose sweeps fly jointly."""
+        wired, wire_bytes = self.said()
+        return {"wired": wired, "wire_bytes": wire_bytes, "joint": self.joint[0]}
+
 
 def sum_accounts(accounts, every: int = 1) -> WireAccount:
     """The exchanges of one unit (a step's stages, a macro's one) as one
@@ -615,6 +722,10 @@ def sum_accounts(accounts, every: int = 1) -> WireAccount:
         sum_hops(*(a.hops for a in accounts)),
         every,
         (sum(a.packed[0] for a in accounts), sum(a.packed[1] for a in accounts)),
+        (
+            "".join(x for x in MESH_AXES if any(x in a.joint[0] for a in accounts)),
+            sum(a.joint[1] for a in accounts),
+        ),
     )
 
 
@@ -665,95 +776,158 @@ def uneven_axes(
     )
 
 
-def _axis_sweep(
-    blocks: List[jax.Array],
-    axis: int,
-    r_lo: int,
-    r_hi: int,
-    name: str,
-    n_dev: int,
-    size: int,
-    v_last: Optional[int],
-    route: str,
-) -> List[jax.Array]:
-    """One axis sweep of ``halo_exchange_multi`` (which enters the
-    ``exchange.<axis>`` scope around it): ``size`` is the raw extent on this
-    axis, ``v_last`` the last shard's valid interior cells (None = even)."""
-    from stencil_tpu.ops import halo_blend
-
-    n_pad = size - r_lo - r_hi  # per-shard (padded) interior width
-    uneven = v_last is not None and v_last != n_pad
-    interp = halo_blend.interpret_mode()
-    kind = _sweep_kind(
-        axis, r_lo, r_hi, n_dev, size, v_last, route,
-        [b.dtype for b in blocks], all(b.ndim == 3 for b in blocks),
-    )
-    if kind == "ypack":
-        return _ypack_sweep(blocks, r_lo, r_hi, n_pad, name, n_dev, route)
-    if kind == "zpack":
-        return _zpack_sweep(blocks, r_lo, r_hi, n_pad, name, n_dev, route)
-    if kind == "wrap":
-        # one shard is the last shard: its valid width is static
-        n_last = n_pad if v_last is None else v_last
-        with jax.named_scope(tm.exchange_wrap_span(name)):
-            return [
-                halo_blend.wrap_halo(b, axis, r_lo, r_hi, n_last, interpret=interp)
-                for b in blocks
-            ]
-
-    def axslice(b, lo, hi):
+def _cut(b: jax.Array, axis: int, start, width: int) -> jax.Array:
+    """``width`` cells of ``b`` from ``start`` along spatial ``axis`` (of its
+    last three dims): a static slice where ``start`` is a Python int, a
+    ``lax.dynamic_slice`` where it is a traced per-shard offset (an uneven
+    axis)."""
+    d = b.ndim - 3 + axis
+    if isinstance(start, int):
         idx = [slice(None)] * b.ndim
-        idx[b.ndim - 3 + axis] = slice(lo, hi)
-        return tuple(idx)
+        idx[d] = slice(start, start + width)
+        return b[tuple(idx)]
+    starts = [jnp.int32(0)] * b.ndim
+    starts[d] = start
+    sizes = list(b.shape)
+    sizes[d] = width
+    return lax.dynamic_slice(b, tuple(starts), tuple(sizes))
 
-    def dyn_starts(b, start):
-        s = [jnp.int32(0)] * b.ndim
-        s[b.ndim - 3 + axis] = start
-        return tuple(s)
 
-    def slab_sizes(b, w):
-        s = list(b.shape)
-        s[b.ndim - 3 + axis] = w
-        return tuple(s)
+def _put(b: jax.Array, part: jax.Array, axis: int, start) -> jax.Array:
+    """``b`` with ``part`` written from ``start`` (as ``_cut`` takes it) along
+    spatial ``axis``.  The no-kernel halo write (CPU, N-D quantities, exotic
+    dtypes), and the corner patch of a received SLAB -- never the kernels'
+    way into a big array."""
+    d = b.ndim - 3 + axis
+    if isinstance(start, int):
+        idx = [slice(None)] * b.ndim
+        idx[d] = slice(start, start + part.shape[d])
+        return b.at[tuple(idx)].set(part)
+    starts = [jnp.int32(0)] * b.ndim
+    starts[d] = start
+    # stencil-lint: disable=sliver-dus the no-kernel path at a traced offset, or a received slab's corner columns
+    return lax.dynamic_update_slice(b, part, tuple(starts))
 
-    if uneven:
-        idx = lax.axis_index(name)
-        n_valid = jnp.where(idx == n_dev - 1, v_last, n_pad).astype(jnp.int32)
 
-    def through_permute(slabs, shift_fn):
-        if axis != 0:
-            return _fused_shift(slabs, shift_fn, name, n_dev)
+class _Flight(NamedTuple):
+    """A wired sweep whose faces are sent: the axis ``name``, ``n_valid`` --
+    this shard's valid interior cells, a Python int on an even axis and a
+    traced one on an uneven axis --, and the received messages, every
+    quantity's slab stacked (``_Stacked``): ``lo`` for the halo at
+    ``[0, r_lo)`` and ``hi`` for the one right behind the valid cells (None
+    where that side has no halo)."""
+
+    sweep: _Sweep
+    name: str
+    n_valid: object
+    lo: Optional[_Stacked]
+    hi: Optional[_Stacked]
+
+
+def _send_faces(blocks: List[jax.Array], s: _Sweep, name: str, flat: bool) -> _Flight:
+    """Cut the two face slabs of every block along ``s.axis`` -- full raw
+    extent on the other axes -- and send each direction's as ONE fused
+    message, its first two spatial dims merged where ``flat``."""
+    n_pad = s.size - s.r_lo - s.r_hi  # per-shard (padded) interior width
+    n_valid = n_pad
+    if s.v_last is not None and s.v_last != n_pad:
+        n_valid = jnp.where(
+            lax.axis_index(name) == s.n_dev - 1, s.v_last, n_pad
+        ).astype(jnp.int32)
+
+    def through_permute(slabs, shift_fn) -> _Stacked:
         # axis-0 slabs (r, Y, Z) travel as (1, r*Y, Z): the slice is
         # contiguous, and the 2D-spatial buffer keeps XLA's layout
         # assignment from giving the permute operand a transposed layout
         # whose feeder is a full-domain relayout copy (seen as a ~3 ms
-        # {2,1,0}->{2,0,1} copy per macro step in the wavefront loop)
-        shapes = [s.shape for s in slabs]
-        flat = [
-            s.reshape(s.shape[:-3] + (1, s.shape[-3] * s.shape[-2], s.shape[-1]))
-            for s in slabs
-        ]
-        out = _fused_shift(flat, shift_fn, name, n_dev)
-        return [o.reshape(sh) for o, sh in zip(out, shapes)]
+        # {2,1,0}->{2,0,1} copy per macro step in the wavefront loop).  So
+        # do the slabs of a sweep that flies behind another, (X, r, Z) as
+        # (1, X*r, Z): cut from the blocks as a loop carries them, not from
+        # a blend's result, they draw the same copy
+        st = _stack(slabs if not flat else [
+            b.reshape(b.shape[:-3] + (1, b.shape[-3] * b.shape[-2], b.shape[-1]))
+            for b in slabs
+        ])
+        bufs = _shift_bufs(st.bufs, shift_fn, name, s.n_dev)
+        if flat:
+            spatial = slabs[0].shape[-3:]
+            bufs = [buf.reshape(buf.shape[:-3] + spatial) for buf in bufs]
+        return _Stacked(bufs, st.idxs, [b.shape for b in slabs])
 
-    lo_recv = hi_recv = None
-    if r_lo > 0:
+    lo = hi = None
+    if s.r_lo > 0:
         # my low halo [0, r_lo) <- -axis neighbor's top slab of VALID
         # interior, width r_lo (message traveling +axis has extent
         # radius(-axis)).  Uneven: top r_lo rows of my valid interior,
         # [n_valid, n_valid + r_lo) in allocation coords.
-        slabs = [
-            lax.dynamic_slice(b, dyn_starts(b, n_valid), slab_sizes(b, r_lo))
-            if uneven
-            else b[axslice(b, n_pad, r_lo + n_pad)]
-            for b in blocks
-        ]
-        lo_recv = through_permute(slabs, _shift_from_low)
-    if r_hi > 0:
+        lo = through_permute(
+            [_cut(b, s.axis, n_valid, s.r_lo) for b in blocks], _shift_from_low
+        )
+    if s.r_hi > 0:
         # my high halo <- +axis neighbor's interior bottom slab, width
         # r_hi, written right after MY valid cells
-        slabs = [b[axslice(b, r_lo, r_lo + r_hi)] for b in blocks]
-        hi_recv = through_permute(slabs, _shift_from_high)
+        hi = through_permute(
+            [_cut(b, s.axis, s.r_lo, s.r_hi) for b in blocks], _shift_from_high
+        )
+    return _Flight(s, name, n_valid, lo, hi)
+
+
+def _relay_corners(first: _Flight, second: _Flight) -> _Flight:
+    """``second`` with the corner columns of its received slabs made what a
+    sweep AFTER ``first`` would have sent.  Its faces were cut from the blocks
+    as they entered the exchange, so on ``first``'s halo columns they carry
+    stale cells; what belongs there is ``first``'s RECEIVED slabs on the face's
+    rows -- they need the slabs to have arrived, not the blend.  Those strips,
+    ``(r_lo + r_hi of first) x (the side's width of second) x the third axis``
+    a quantity, follow the face to the same neighbour as one small fused
+    message a direction and overwrite the columns of the message it received
+    -- cut, sent and written on the stacked buffers, every quantity at once."""
+    a1, a2 = first.sweep.axis, second.sweep.axis
+    # first's received messages, and where each lies along a1 in a raw block --
+    # and so in a slab of second
+    lands = [
+        (recv, start)
+        for recv, start in (
+            (first.lo, 0), (first.hi, first.sweep.r_lo + first.n_valid)
+        )
+        if recv is not None
+    ]
+
+    def relay(face: _Stacked, row0, width: int, shift_fn) -> _Stacked:
+        strips = [
+            jnp.concatenate(
+                [_cut(recv.bufs[g], a2, row0, width) for recv, _ in lands],
+                axis=buf.ndim - 3 + a1,
+            )
+            for g, buf in enumerate(face.bufs)
+        ]
+        strips = _shift_bufs(strips, shift_fn, second.name, second.sweep.n_dev)
+        bufs = []
+        for buf, strip in zip(face.bufs, strips):
+            at = 0
+            for recv, start in lands:
+                w = recv.bufs[0].shape[-3 + a1]
+                buf = _put(buf, _cut(strip, a1, at, w), a1, start)
+                at += w
+            bufs.append(buf)
+        return face._replace(bufs=bufs)
+
+    s2 = second.sweep
+    lo, hi = second.lo, second.hi
+    if lo is not None:  # the rows ``_send_faces`` cut for each side
+        lo = relay(lo, second.n_valid, s2.r_lo, _shift_from_low)
+    if hi is not None:
+        hi = relay(hi, s2.r_lo, s2.r_hi, _shift_from_high)
+    return second._replace(lo=lo, hi=hi)
+
+
+def _blend_faces(blocks: List[jax.Array], f: _Flight) -> List[jax.Array]:
+    """The received slabs of one flight written into the halos of the
+    blocks."""
+    from stencil_tpu.ops import halo_blend
+
+    axis = f.sweep.axis
+    interp = halo_blend.interpret_mode()
     # y/z halo writes go through tile-local pallas blend kernels where
     # possible: plain DUS slivers on those axes bait XLA's layout
     # assignment into transposing the whole array (two full-domain
@@ -761,34 +935,80 @@ def _axis_sweep(
     blend = halo_blend.enabled() and all(
         b.ndim == 3 and halo_blend.supports(b.dtype) for b in blocks
     )
+    lo = _unstack(f.lo) if f.lo is not None else None
+    hi = _unstack(f.hi) if f.hi is not None else None
+    hi_at = f.sweep.r_lo + f.n_valid  # right behind MY valid cells
+    out = []
     for j, b in enumerate(blocks):
-        if lo_recv is not None:
+        if lo is not None:
             # the low halo sits at 0 even on padded axes, so the static
             # kernel serves both cases
             if blend:
-                b = halo_blend.blend_slab(b, lo_recv[j], axis, 0, interpret=interp)
+                b = halo_blend.blend_slab(b, lo[j], axis, 0, interpret=interp)
             else:
-                b = b.at[axslice(b, 0, r_lo)].set(lo_recv[j])
-        if hi_recv is not None:
-            if uneven and blend:
+                b = _put(b, lo[j], axis, 0)
+        if hi is not None:
+            if not blend:
+                b = _put(b, hi[j], axis, hi_at)
+            elif isinstance(hi_at, int):
+                b = halo_blend.blend_slab(b, hi[j], axis, hi_at, interpret=interp)
+            else:
                 # every axis, x included: a traced x-plane DUS is no relayout
                 # bait but compiles to a whole-array fusion with a fresh
                 # result (halo_blend.blend_slab_dynamic)
                 b = halo_blend.blend_slab_dynamic(
-                    b, hi_recv[j], axis, r_lo + n_valid, interpret=interp
+                    b, hi[j], axis, hi_at, interpret=interp
                 )
-            elif uneven:
-                # stencil-lint: disable=sliver-dus the no-kernel path (CPU, N-D quantities, exotic dtypes) at a traced offset
-                b = lax.dynamic_update_slice(
-                    b, hi_recv[j], dyn_starts(b, r_lo + n_valid)
-                )
-            elif blend:
-                b = halo_blend.blend_slab(
-                    b, hi_recv[j], axis, r_lo + n_pad, interpret=interp
-                )
-            else:
-                b = b.at[axslice(b, r_lo + n_pad, size)].set(hi_recv[j])
-        blocks[j] = b
+        out.append(b)
+    return out
+
+
+def _sweep_group(
+    blocks: List[jax.Array],
+    group: Sequence[_Sweep],
+    axis_names: Sequence[str],
+    route: str,
+) -> List[jax.Array]:
+    """One group of ``_sweep_groups`` run on ``blocks``: a packed sweep or a
+    self-wrap, or the ``direct`` sweeps that fly together -- every axis's
+    faces cut from the blocks AS THEY ENTER and sent, so no face message of
+    one axis waits for another's; for a pair the corner strips relayed behind
+    the second axis's faces (``_relay_corners``); then the blends in sweep
+    order.  A group of one is the single-axis sweep: cut, send, blend.  Every
+    instruction sits under the ``exchange.<axis>`` scope of the axis whose
+    halo it completes -- slab cut / pack, the wires (the per-direction scopes
+    nest inside), relay, unpack / blend carry it in their HLO op_name, so a
+    trace tells the exchange from step glue."""
+    from stencil_tpu.ops import halo_blend
+
+    names = [axis_names[s.axis] for s in group]
+    scopes = [partial(jax.named_scope, tm.exchange_axis_span(n)) for n in names]
+    s = group[0]
+    if s.kind != "direct":
+        (name,), n_pad = names, s.size - s.r_lo - s.r_hi
+        with scopes[0]():
+            if s.kind == "ypack":
+                return _ypack_sweep(blocks, s.r_lo, s.r_hi, n_pad, name, s.n_dev, route)
+            if s.kind == "zpack":
+                return _zpack_sweep(blocks, s.r_lo, s.r_hi, n_pad, name, s.n_dev, route)
+            # the self-wrap: one shard is the last shard, its valid width is static
+            n_last = n_pad if s.v_last is None else s.v_last
+            interp = halo_blend.interpret_mode()
+            with jax.named_scope(tm.exchange_wrap_span(name)):
+                return [
+                    halo_blend.wrap_halo(b, s.axis, s.r_lo, s.r_hi, n_last, interpret=interp)
+                    for b in blocks
+                ]
+    flights = []
+    for s, name, scope in zip(group, names, scopes):
+        with scope():
+            flights.append(_send_faces(blocks, s, name, flat=s.axis == 0 or bool(flights)))
+    if len(flights) == 2:
+        with scopes[1]():
+            flights[1] = _relay_corners(*flights)
+    for f, scope in zip(flights, scopes):
+        with scope():
+            blocks = _blend_faces(blocks, f)
     return blocks
 
 
@@ -801,15 +1021,24 @@ def halo_exchange_multi(
     axes: Tuple[int, ...] = (0, 1, 2),
     route: str = "direct",
 ) -> List[jax.Array]:
-    """Fill the halo shells of several shell-carrying shards JOINTLY —
-    ≤ 2 ppermutes per axis sweep (≤ 6 total) no matter how many quantities,
-    the reference's fused multi-quantity buffers (packer.cuh:52-69).  Must run
-    inside ``shard_map`` over a mesh with ``axis_names``.
+    """Fill the halo shells of several shell-carrying shards JOINTLY — ONE
+    face ppermute a direction of every axis sweep (≤ 6), plus one small corner
+    relay a direction of the second axis of a jointly swept pair (≤ 2), no
+    matter how many quantities: the reference's fused multi-quantity buffers
+    (packer.cuh:52-69).  Must run inside ``shard_map`` over a mesh with
+    ``axis_names``.
 
     Each block's spatial extent is its LAST three dims (leading batch/
     quantity dims ride along inside the fused message); every block must
     share the same spatial shape ``interior + r_lo + r_hi`` per axis, with
     the interior at ``[r_lo, r_lo + n)``.
+
+    The sweeps run in the order of ``axes``, and the first two WIRED ones that
+    follow each other fly jointly (``_sweep_groups``): a chip's x and y
+    neighbours sit on different ICI ports, and all a y slab needs of the x
+    sweep is its corner columns.  The halos are bitwise those of sweeps run
+    strictly in turn; nothing selects the form but how many axes of this
+    exchange are wired.
 
     ``valid_last`` supports uneven global sizes via pad-and-mask (the
     reference's +-1-cell remainders, partition.hpp:83-114): entry ``a`` is the
@@ -839,21 +1068,12 @@ def halo_exchange_multi(
             "all quantities must share one spatial (last-3-dims) shape; got "
             f"{[b.shape for b in blocks]}"
         )
-    for axis in axes:
-        r_lo = radius.axis(axis, -1)  # my low-side halo width
-        r_hi = radius.axis(axis, +1)  # my high-side halo width
-        if r_lo == 0 and r_hi == 0:
-            continue
-        name = axis_names[axis]
-        # the whole sweep -- slab cut / pack, the wire, unpack / blend -- sits
-        # under ONE registered scope: every instruction the exchange adds
-        # carries ``exchange.<axis>`` in its HLO op_name (the per-direction
-        # wire scopes nest inside), so a trace tells it from step glue
-        with jax.named_scope(tm.exchange_axis_span(name)):
-            blocks = _axis_sweep(
-                blocks, axis, r_lo, r_hi, name, mesh_shape[axis], spatial[axis],
-                valid_last[axis] if valid_last is not None else None, route,
-            )
+    sweeps = _sweeps(
+        mesh_shape, radius, spatial, [b.dtype for b in blocks], valid_last, route,
+        axes, all(b.ndim == 3 for b in blocks),
+    )
+    for group in _sweep_groups(sweeps):
+        blocks = _sweep_group(blocks, group, axis_names, route)
     return blocks
 
 

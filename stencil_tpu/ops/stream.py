@@ -982,9 +982,11 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
     # the axes whose sweep of the step's exchanges crosses to another shard,
     # and the bytes one shard receives over them a raw step, all stages (the
     # plan's ``wire_account``, which ``run_step`` counts the wires from; ops/
-    # exchange.py exchange_account): "" and 0 on one device
+    # exchange.py exchange_account): "" and 0 on one device; and the pair of
+    # them whose sweeps fly jointly ("" where the sweeps run in turn)
     args["wired"] = plan["wired"]
     args["wire_bytes"] = plan["wire_bytes"]
+    args["joint"] = plan["joint"]
     if "wired_edges" in plan:
         # ... and the pairs of those axes whose EDGE halo the kernels read
         # (a diagonal offset across both): it reaches a shard over two wires

@@ -1367,13 +1367,13 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
     # what the step's exchanges send to ANOTHER shard, hop by hop, and pack
     # (ops/exchange.py ``exchange_account``, i.e. ``_sweep_kind``: the message
     # plan that is run): ``domain.run_step`` counts the wires from it and
-    # ``domain.step`` says ``wired`` / ``wire_bytes`` of it, a raw step
+    # ``domain.step`` says ``wired`` / ``wire_bytes`` / ``joint`` of it, a raw step
     stage_wires = _stage_wires(dd, plan, (raw.x, raw.y, raw.z), exch_route)
     plan["wire_account"] = sum_accounts(
         (st for st in stage_wires if st is not None),
         every=plan["m"] if route == "wavefront" else 1,
     )
-    plan["wired"], plan["wire_bytes"] = plan["wire_account"].said()
+    plan.update(plan["wire_account"].span_args())  # wired, wire_bytes, joint
     if route == "plane" and not fused:
         # ... the bytes stage by stage (``wire_bytes_by_stage``), and the
         # pairs of wired axes a kernel reads DIAGONALLY across
@@ -1432,13 +1432,14 @@ def _stage_wires(dd, plan: Mapping, raw_spatial, exch_route: str) -> list:
     if plan["z_slabs"]:
         # x and y in the array on the direct route, z as slab buffers that the
         # y and x neighbours extend (permute_and_extend_z_slabs)
+        swept = exchange_account(mesh_shape, shell, raw_spatial, everyone, axes=(0, 1))
         return [WireAccount(1, sum_hops(
-            exchange_account(mesh_shape, shell, raw_spatial, everyone, axes=(0, 1)).hops,
+            swept.hops,
             z_slab_hops(
                 mesh_shape, raw_spatial[0], raw_spatial[1], shell.lo().x,
                 [jnp.dtype(dt).itemsize for dt in everyone],
             ),
-        ))]
+        ), joint=swept.joint)]
     return [exchange_account(
         mesh_shape, shell, raw_spatial, everyone, valid_last=dd._valid_last, route=exch_route,
     )]

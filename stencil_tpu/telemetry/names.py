@@ -69,6 +69,13 @@ TUNE_SELECTED = "tune.selected"
 EXCHANGE_PACKED_BYTES = "exchange.packed.bytes"
 #: analytic pack+unpack kernel launches of those packed exchanges
 EXCHANGE_PACKED_KERNELS = "exchange.packed.kernels"
+#: JOINT sweeps dispatched, summed over subdomains: sweeps of a halo exchange in
+#: which two wired axes' faces were cut from the entering blocks and sent at
+#: once, the corner strips relayed behind them (ops/exchange.py
+#: ``_sweep_groups``; one an exchange on mesh [2,2,1], none where fewer than
+#: two wired ``direct`` sweeps follow each other) -- of the exchanges
+#: ``EXCHANGE_COUNT`` counts and from the same account (``WireAccount.joint``)
+EXCHANGE_JOINT_SWEEPS = "exchange.joint.sweeps"
 #: analytic boundary-band cells RECOMPUTED by the split-step exterior passes
 #: (``overlap=split`` on the stream engine, ops/stream.py): the redundant
 #: surface work the overlapped schedule pays to free the interior pass from
@@ -269,6 +276,7 @@ ALL_COUNTERS = frozenset({
     EXCHANGE_BYTES,
     EXCHANGE_PACKED_BYTES,
     EXCHANGE_PACKED_KERNELS,
+    EXCHANGE_JOINT_SWEEPS,
     STEP_DISPATCHES,
     STEP_ITERATIONS,
     RETRY_ATTEMPTS,
@@ -439,7 +447,11 @@ ALL_HISTOGRAMS = frozenset({
 #: what ``_sweep_kind`` decides: acoustic on mesh [2,2,1] "xy" and 23658496 =
 #: four radius-4 faces of ``u``'s 608^3 block, "" and 0 on one device; a
 #: wavefront's macro bytes over its depth; the bespoke ``Jacobi3D`` steps say
-#: both too); a STAGED step (``make_step``
+#: both too), joint = the pair of wired axes whose sweeps of those exchanges
+#: fly jointly -- both axes' faces sent at once, the corner strips relayed
+#: behind them (``ops/exchange._sweep_groups``): "xy" on mesh [2,2,1], "" where
+#: the sweeps run in turn (fewer than two wired ``direct`` sweeps in a row: one
+#: device, [2,1,1], [2,1,2], the packed kinds); a STAGED step (``make_step``
 #: with a sequence of kernels) adds stages and passes, and says exchanged /
 #: written / renamed / aliased PER STAGE, in order: "6/3", "3/6", "0/0",
 #: "11/12" (``wrapped``, ``wired`` and ``wire_bytes`` are one value each: the
@@ -520,7 +532,8 @@ SPAN_STEP = "domain.step"
 #: not divide the extent (``ops/exchange.uneven_axes``), e.g. "xy" for 1191^3
 #: on that mesh, "" on every aligned extent, wire_bytes = the bytes one shard
 #: receives over wires per exchange (``domain.step``'s meaning; the sum of
-#: ``exchange_hop_bytes`` a subdomain)]
+#: ``exchange_hop_bytes`` a subdomain), joint = the axes whose sweeps fly
+#: jointly (``domain.step``'s meaning)]
 SPAN_EXCHANGE = "domain.exchange"
 SPAN_SWAP = "domain.swap"
 #: ``realize()`` once the geometry is known: allocation, exchange build +

@@ -1,6 +1,8 @@
 # analysis-fixture: contract=exchange-structure expect=clean
 """The sanctioned fused exchange: both quantities stack into ONE buffer per
-direction, ≤6 permutes total regardless of field count."""
+direction, ≤6 face permutes total regardless of field count -- and, the x and
+y sweeps flying jointly, one smaller corner relay behind each y face, of cells
+the x permutes received."""
 
 import jax
 import jax.numpy as jnp
@@ -21,14 +23,25 @@ def build():
 
     def body(q0, q1):
         fused = jnp.concatenate([q0, q1], axis=0)
+        # the pair's four faces, every one cut from the blocks as they entered
+        got = {}
         for name, perm in (
             (tm.SPAN_EXCHANGE_X_LOW, fwd),
             (tm.SPAN_EXCHANGE_X_HIGH, rev),
             (tm.SPAN_EXCHANGE_Y_LOW, fwd),
             (tm.SPAN_EXCHANGE_Y_HIGH, rev),
-            (tm.SPAN_EXCHANGE_Z_LOW, fwd),
-            (tm.SPAN_EXCHANGE_Z_HIGH, rev),
         ):
+            with jax.named_scope(name):
+                got[name] = lax.ppermute(fused, "x", perm)
+        # the corner relays: a strip of what x RECEIVED, behind each y face
+        corners = jnp.concatenate(
+            [got[tm.SPAN_EXCHANGE_X_LOW][:, :2], got[tm.SPAN_EXCHANGE_X_HIGH][:, :2]], axis=1
+        )
+        for name, perm in ((tm.SPAN_EXCHANGE_Y_LOW, fwd), (tm.SPAN_EXCHANGE_Y_HIGH, rev)):
+            with jax.named_scope(name):
+                got[name] = got[name].at[:, :4].set(lax.ppermute(corners, "x", perm))
+        fused = sum(got.values())
+        for name, perm in ((tm.SPAN_EXCHANGE_Z_LOW, fwd), (tm.SPAN_EXCHANGE_Z_HIGH, rev)):
             with jax.named_scope(name):
                 fused = lax.ppermute(fused, "x", perm)
         k = q0.shape[0]

@@ -1,7 +1,8 @@
 # analysis-fixture: contract=exchange-structure expect=fire
 """A broken exchange: per-quantity ppermutes (two messages per direction
-scope — the fusion packer.cuh:52-69 collapses is gone) and more than six
-permutes in one traced exchange."""
+scope — the fusion packer.cuh:52-69 collapses is gone; the second is as large
+as the first, so it is no corner relay of a joint sweep) and more than six
+face permutes in one traced exchange."""
 
 import jax
 import jax.numpy as jnp

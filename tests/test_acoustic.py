@@ -103,6 +103,11 @@ def test_route_is_plane_and_the_span_says_so(blend, mesh, wrapped, wired, monkey
     assert tuple(sim.dd.mesh_dim()) == mesh
     raw = N + 2 * ref.RADIUS
     wire_bytes = len(wired) * 2 * ref.RADIUS * raw * raw * 4
+    # two wired sweeps in a row fly jointly (ISSUE 50): behind each y face the
+    # corner relay, both x halos on its four rows
+    joint = wired if len(wired) == 2 else ""
+    if joint:
+        wire_bytes += 2 * (2 * ref.RADIUS) * ref.RADIUS * raw * 4
     plan = sim._step._stream_plan
     assert plan["route"] == "plane" and plan["m"] == 1 and plan["grouping"] == "joint", plan
     assert plan["alias"] is True, plan  # the plane route writes in place (ISSUE 28)
@@ -113,6 +118,7 @@ def test_route_is_plane_and_the_span_says_so(blend, mesh, wrapped, wired, monkey
         "renamed": 1,  # and u_prev <- u swaps two handles: nothing to write (ISSUE 36)
         "wrapped": wrapped,
         "wired": wired, "wire_bytes": wire_bytes,  # what crosses to another shard (ISSUE 37)
+        "joint": joint,
         # what the kernel reads against what the exchange serves (ISSUE 39): u
         # alone, along the axes only, on all six sides
         "quantities": 4, "offcentre": 1, "diagonal": 0, "read_sides": 6, "exchanged_sides": 6,
@@ -142,7 +148,7 @@ def test_route_is_plane_and_the_span_says_so(blend, mesh, wrapped, wired, monkey
     assert (kw["streamed"], kw["aliased"], kw["exchanged"], kw["written"]) == (4, 4, 1, 1)
     assert kw["renamed"] == 1
     assert kw["wrapped"] == wrapped
-    assert (kw["wired"], kw["wire_bytes"]) == (wired, wire_bytes)
+    assert (kw["wired"], kw["wire_bytes"], kw["joint"]) == (wired, wire_bytes, joint)
 
 
 @pytest.mark.parametrize("devices", [1, 2, 8])
@@ -243,12 +249,14 @@ def test_the_decomposed_shot_is_bitwise_the_xla_engine(blend, monkeypatch):
 
 @pytest.mark.parametrize("devices", [1, 4])
 def test_the_wires_carry_four_faces_of_u_and_nothing_else(devices, monkeypatch):
-    """The step as the chip runs it: on mesh [2,2,1] the ``ppermute``s of one
-    step are four -- ``exchange.x.low`` / ``.high``, ``exchange.y.low`` /
-    ``.high`` -- and their cells are exactly ONE quantity's four radius-4
-    faces of the raw block, which is what the plan and the span report as
-    ``wire_bytes``; the z sweep is gone into the pass.  On one device nothing
-    is sent at all."""
+    """The step as the chip runs it: on mesh [2,2,1] the FACE ``ppermute``s of
+    one step are four -- ``exchange.x.low`` / ``.high``, ``exchange.y.low`` /
+    ``.high`` --, behind each y face flies the corner relay of the joint x-y
+    sweep (ISSUE 50: both x halos on the face's four rows), and their cells
+    are exactly ONE quantity's four radius-4 faces of the raw block and those
+    two strips, which is what the plan and the span report as ``wire_bytes``;
+    the z sweep is gone into the pass.  On one device nothing is sent at
+    all."""
     from stencil_tpu.analysis import jaxpr as jx
 
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
@@ -262,9 +270,11 @@ def test_the_wires_carry_four_faces_of_u_and_nothing_else(devices, monkeypatch):
     if devices == 1:
         assert not sends and (args["wired"], args["wrapped"]) == ("", "yz"), args
         return
-    assert cells == 4 * ref.RADIUS * raw * raw
-    assert sorted(jx.name_stack_str(e).split("/")[-1] for e in sends) == [
-        tm.exchange_direction_span(a, side) for a in "xy" for side in ("high", "low")]
+    assert cells == 4 * ref.RADIUS * raw * raw + 2 * (2 * ref.RADIUS) * ref.RADIUS * raw
+    assert sorted(jx.name_stack_str(e).split("/")[-1] for e in sends) == sorted(
+        [tm.exchange_direction_span(a, side) for a in "xy" for side in ("high", "low")]
+        + [tm.exchange_direction_span("y", side) for side in ("high", "low")])
+    assert args["joint"] == "xy"
     assert (args["wired"], args["wrapped"], args["exchanged"], args["renamed"]) == ("xy", "z", 1, 1)
     assert not [e for e in jx.iter_eqns(closed) if "exchange.z" in jx.name_stack_str(e)]
 

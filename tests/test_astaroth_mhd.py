@@ -239,7 +239,8 @@ def test_bf16_storage_fails_the_tolerance():
 def test_an_unfilled_edge_halo_or_plane_corner_comes_out_wrong(which, monkeypatch):
     """Mesh [2,2,2]: a sweep carries the halo of the axes swept before it
     along, which is what fills the edges.  ``edge_xy``: the x halo planes' y
-    halo rows put back to what they held before the y sweep; ``corner_yz``: the
+    halo rows left as the y faces carried them, the corner relay of the joint
+    x-y sweep taken out; ``corner_yz``: the
     y halo rows' z halo columns -- the corner of every x-plane the pass loads
     -- put back after the z sweep.  Every face halo is still filled, and the
     mixed differences of the second and third substeps come out wrong in the
@@ -247,25 +248,29 @@ def test_an_unfilled_edge_halo_or_plane_corner_comes_out_wrong(which, monkeypatc
     ``uy, uz`` in the y-z plane, and the potentials likewise)."""
     from stencil_tpu.ops import exchange as ex
 
-    real = ex._axis_sweep
-    swept, other = (1, 0) if which == "edge_xy" else (2, 1)
+    if which == "edge_xy":
+        # x and y fly jointly on this mesh: the y faces are cut before the x
+        # halo is in, and the relay is what puts the x halo planes' y halo rows
+        # right -- without it they hold what the neighbour's stale halo held
+        monkeypatch.setattr(ex, "_relay_corners", lambda first, second: second)
+    else:
+        real = ex._sweep_group
 
-    def faces_only(blocks, axis, r_lo, r_hi, *rest):
-        before = list(blocks)  # the sweep writes its results into the list it is given
-        out = real(blocks, axis, r_lo, r_hi, *rest)
-        if axis != swept:
-            return out
-        stale = []
-        for new, old in zip(out, before):
-            for a in (slice(0, RADIUS), slice(-RADIUS, None)):  # the earlier axis's halo ...
-                for b in (slice(0, r_lo), slice(new.shape[axis] - r_hi, None)):  # ... of this one's
-                    at = [slice(None)] * 3
-                    at[other], at[axis] = a, b
-                    new = new.at[tuple(at)].set(old[tuple(at)])
-            stale.append(new)
-        return stale
+        def faces_only(blocks, group, *rest):
+            before = list(blocks)
+            out = real(blocks, group, *rest)
+            if [s.axis for s in group] != [2]:  # z sweeps alone, behind the pair
+                return out
+            (s,) = group
+            stale = []
+            for new, old in zip(out, before):
+                for a in (slice(0, RADIUS), slice(-RADIUS, None)):  # the y halo rows' ...
+                    for b in (slice(0, s.r_lo), slice(new.shape[2] - s.r_hi, None)):  # ... z halo columns
+                        new = new.at[:, a, b].set(old[:, a, b])
+                stale.append(new)
+            return stale
 
-    monkeypatch.setattr(ex, "_axis_sweep", faces_only)
+        monkeypatch.setattr(ex, "_sweep_group", faces_only)
     sim = _sim(mesh=(2, 2, 2))
     _load(sim, _state())  # with its shell filled: the FIRST substep's edges are right as loaded
     sim.step(1)
@@ -483,10 +488,12 @@ def test_the_span_says_what_crosses_the_wires_stage_by_stage(chip_sweeps, monkey
     assert tuple(sim.dd.mesh_dim()) == (2, 2, 1)
     said = sim._step._span_args()
     raw = N + 2 * RADIUS
-    stage = 2 * 8 * 2 * RADIUS * raw * raw * 4  # two axes, eight fields, six planes of the raw block
+    faces = 2 * 8 * 2 * RADIUS * raw * raw * 4  # two axes, eight fields, six planes of the raw block
+    # x and y fly jointly: behind each y face the corner relay, both x halos on its three rows
+    stage = faces + 2 * 8 * 2 * RADIUS * RADIUS * raw * 4
     assert (said["wired"], said["wrapped"]) == ("xy", "z" if chip_sweeps else "")
     assert (said["plane_window"], said["plane_strip"], said["exchanged"]) == ("raw", 0, "8/8/8")
-    assert said["wire_bytes"] == 3 * stage == 557_568
+    assert said["wire_bytes"] == 3 * stage == 557_568 + 76_032 and said["joint"] == "xy"
     assert said["wire_bytes_by_stage"] == "/".join([str(stage)] * 3)
     assert said["wired_edges"] == "xy"
     sent = _stage_sends(sim)  # every send inside its stage, under its sweep's scope
@@ -497,7 +504,7 @@ def test_the_span_says_what_crosses_the_wires_stage_by_stage(chip_sweeps, monkey
     # one split axis, or none: no edge crosses two wires
     line = _shared(mesh=(2, 1, 1))._step._span_args()
     assert (line["wired"], line["wired_edges"]) == ("x", "")
-    assert line["wire_bytes_by_stage"] == "/".join([str(stage // 2)] * 3)
+    assert line["wire_bytes_by_stage"] == "/".join([str(faces // 2)] * 3) and line["joint"] == ""
     alone = _shared()._step._span_args()
     assert (alone["wired"], alone["wired_edges"], alone["wire_bytes_by_stage"]) == ("", "", "0/0/0")
     # ... and all three pairs where all three axes are split
@@ -581,4 +588,4 @@ def test_driver_keeps_the_cell_on_a_weak_scaled_grid(capsys, monkeypatch):
     assert row[3:6] == ["32", "32", "16"] and float(row[-1]) > 0
     assert abs(float(row[6]) - ref.dt_of(_setup())) < 1e-15  # the cube's time step
     (said,) = [l for l in io.err.splitlines() if l.startswith("mesh: ")]
-    assert said.startswith("mesh: 2,2,1 wired='xy' wrapped='' wire_bytes=557568 wired_edges='xy'"), said
+    assert said.startswith("mesh: 2,2,1 wired='xy' wrapped='' wire_bytes=633600 wired_edges='xy'"), said

@@ -52,7 +52,8 @@ def test_rehearsal_is_sound(capsys):
     ran = plan["ran"]
     assert (ran["mesh"], ran["route"], ran["depth"], ran["descents"]) == ([2, 2, 1], "plane", 1, 0), ran
     # the program's own word for what crossed a wire: four radius-4 faces of u's raw block
-    assert (ran["wired"], ran["wire_bytes"]) == ("xy", 2 * 2 * 4 * 24 * 24 * 4), ran
+    # and, behind each y face, the corner relay of the joint x-y sweep (both x halos' four rows)
+    assert (ran["wired"], ran["wire_bytes"]) == ("xy", (2 * 2 * 4 * 24 * 24 + 2 * 8 * 4 * 24) * 4), ran
     assert checks["max_abs_err"]["value"] <= 1e-6  # far inside the cell's limit on the CPU
     assert checks["frame_nonzero_cells"]["value"] == 0 and checks["window_state_bad_cells"]["value"] == 0
 

@@ -75,8 +75,10 @@ def test_the_rehearsed_cell_comes_out_sound(trace):
     assert (ran["quantities"], ran["stages"], ran["passes"], ran["renamed"]) == (16, 3, 3, 8)
     assert (ran["renamed_by_stage"], ran["exchanged"], ran["steps_per_trip"]) == ("8/8/8", "8/8/8", 2)
     # the program's own word for what crossed a wire: three exchanges of eight
-    # fields' radius-3 x and y faces of the raw 22^3 block
-    assert (ran["wired"], ran["wire_bytes"]) == ("xy", 3 * 2 * 8 * 6 * 22 * 22 * 4), ran
+    # fields' radius-3 x and y faces of the raw 22^3 block, and behind each y
+    # face the corner relay of the joint x-y sweep (both x halos' three rows)
+    stage_bytes = (2 * 8 * 6 * 22 * 22 + 2 * 8 * 6 * 3 * 22) * 4
+    assert (ran["wired"], ran["wire_bytes"]) == ("xy", 3 * stage_bytes), ran
     assert checks["max_abs_err"]["value"] <= 2e-7 and checks["window_state_bad_cells"]["value"] == 0
     want = {"mcells_per_s_chip", "setup_s"} if not trace else {"compiles_in_window.mhdx4", "compile_s"}
     assert want <= set(line["rehearsal"]["would_report"])
@@ -85,8 +87,8 @@ def test_the_rehearsed_cell_comes_out_sound(trace):
 
         spans = [h[3] for h in timeline.host_spans(timeline.load(), "domain.step")]
         assert spans and all(
-            (a["stages"], a["wired"], a["wired_edges"], a["wire_bytes_by_stage"], a["steps"]) == (
-                3, "xy", "xy", "/".join([str(2 * 8 * 6 * 22 * 22 * 4)] * 3), DISPATCH)
+            (a["stages"], a["wired"], a["joint"], a["wired_edges"], a["wire_bytes_by_stage"], a["steps"]) == (
+                3, "xy", "xy", "xy", "/".join([str(stage_bytes)] * 3), DISPATCH)
             for a in spans
         ), spans[:2]
 
@@ -110,16 +112,17 @@ def test_a_step_whose_third_stage_skips_its_y_sweep_is_not_correct(monkeypatch):
     box that is nowhere zero."""
     from stencil_tpu.ops import exchange
 
-    real = exchange._axis_sweep
+    real = exchange._sweep_group
     y_sweeps = itertools.count()
 
-    def third_stage_skips_y(blocks, axis, *rest):
-        if axis == 1 and next(y_sweeps) % 3 == 2:  # stages trace in order, three a step
-            return list(blocks)
-        return real(blocks, axis, *rest)
+    def third_stage_skips_y(blocks, group, *rest):
+        # x and y fly as one group on this mesh; the group cut to x is the x sweep alone
+        if [s.axis for s in group] == [0, 1] and next(y_sweeps) % 3 == 2:  # stages trace in order, three a step
+            group = group[:1]
+        return real(blocks, group, *rest)
 
     def patch(cell):
-        monkeypatch.setattr(exchange, "_axis_sweep", third_stage_skips_y)
+        monkeypatch.setattr(exchange, "_sweep_group", third_stage_skips_y)
         cell.sim.rebuild_after_reshard()
 
     line, checks, plan = _rehearse(patch=patch, seed=2**31 + 247, dispatch_size=6)

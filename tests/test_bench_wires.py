@@ -268,8 +268,11 @@ def test_a_rehearsal_has_no_device_plane_and_reports_none_of_the_five(tmp_path, 
     assert tl["workload"] == opts.workload and tl["devices"] == {}
     spans = [h[3] for h in tl["host"] if h[0] == "domain.exchange"]
     raw = 8 + 2 * 3  # radius 3 around 8^3 a chip; x and y wired on mesh [2,2,1], four f32 quantities
-    assert spans and {int(a["wire_bytes"]) for a in spans} == {2 * 2 * 3 * raw * raw * 4 * 4}
-    assert tw.said_bytes(tl) == (2 * 2 * 3 * raw * raw * 4 * 4, 1)
+    # four faces and, the pair flying jointly, the two corner relays behind the y faces
+    nbytes = (2 * 2 * 3 * raw * raw + 2 * 6 * 3 * raw) * 4 * 4
+    assert spans and {int(a["wire_bytes"]) for a in spans} == {nbytes}
+    assert {a["joint"] for a in spans} == {"xy"}
+    assert tw.said_bytes(tl) == (nbytes, 1)
 
 
 def test_the_ten_metrics_are_declared_for_the_four_chip_cells_alone():

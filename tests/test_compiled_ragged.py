@@ -85,7 +85,9 @@ def test_the_exchange_holds_no_whole_array_transient(ragged):
     compiled = fn.lower(dd.abstract_arrays()).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == mem.alias_size_in_bytes == 4 * ARRAY  # in place
-    assert mem.temp_size_in_bytes < ARRAY // 8, mem.temp_size_in_bytes  # slabs: 75 MB
+    # slabs: 296 MB, sent and received of BOTH axes at once since the x and y
+    # sweeps fly jointly (ISSUE 50; 75 MB when they ran in turn) -- a sixth of one array
+    assert mem.temp_size_in_bytes < ARRAY // 6, mem.temp_size_in_bytes
     text = compiled.as_text()
     assert _whole_array_ops(text) == []
     assert "{1,2,0" not in text.split("ENTRY")[1].split("\n")[0]  # row-major in, row-major out

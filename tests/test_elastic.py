@@ -84,11 +84,13 @@ def test_model_matches_the_reference_on_a_2x2x1_mesh(impl):
     assert tuple(sim.dd.mesh_dim()) == (2, 2, 1)
     if impl == "pallas":
         # both stages' messages: 6 stresses, then 3 velocities, four radius-4
-        # faces of the raw block each (ISSUE 37)
+        # faces of the raw block each (ISSUE 37) and, behind each y face, the
+        # corner relay of the joint x-y sweep: both x halos on its four rows (ISSUE 50)
         raw = sim.dd.local_spec().raw_size()
         args = sim._step._span_args()
-        assert (args["wired"], args["exchanged"]) == ("xy", "6/3"), args
-        assert args["wire_bytes"] == (6 + 3) * 2 * 4 * (raw.y * raw.z + raw.x * raw.z) * 4, args
+        assert (args["wired"], args["joint"], args["exchanged"]) == ("xy", "xy", "6/3"), args
+        cells = 2 * 4 * (raw.y * raw.z + raw.x * raw.z) + 2 * 8 * 4 * raw.z
+        assert args["wire_bytes"] == (6 + 3) * cells * 4, args
     errs = _errors(sim, 1)
     assert max(errs.values()) <= ATOL, errs
 
@@ -142,7 +144,7 @@ def test_the_plan_is_staged_and_the_span_says_so():
         "stages": 2, "passes": 2, "exchanged": "6/3", "written": "3/6", "aliased": "11/12",
         "renamed": "0/0",  # every output is masked by the frame: none is a centre plane (ISSUE 36)
         "wrapped": "",  # a plain CPU run: the blend kernels are off (ISSUE 34)
-        "wired": "", "wire_bytes": 0,  # one device: nothing crosses to another shard (ISSUE 37)
+        "wired": "", "wire_bytes": 0, "joint": "",  # one device: nothing crosses to another shard (ISSUE 37)
         # what the two kernels read against what the exchanges serve (ISSUE 39):
         # nine wavefields, along the axes only
         "quantities": 13, "offcentre": 9, "diagonal": 0, "read_sides": 36,  # 9 (stress, axis) + 9 (velocity, axis) pairs, both sides

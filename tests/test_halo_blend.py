@@ -236,8 +236,8 @@ def test_uneven_unsplit_axis_wraps_at_static_offset(monkeypatch):
 
     rng = np.random.default_rng(11)
     block = jnp.asarray(rng.random((6, 9, 24)), jnp.float32)
-    blocks = exchange._axis_sweep(
-        [block], 2, 2, 2, "z", 1, 24, 17, "direct"
+    blocks = exchange._sweep_group(
+        [block], [exchange._Sweep(2, "wrap", 2, 2, 1, 24, 17)], "xyz", "direct"
     )
     want = np.asarray(block).copy()
     want[:, :, 0:2] = want[:, :, 17:19]

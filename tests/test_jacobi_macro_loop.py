@@ -80,8 +80,9 @@ def _raw(sim):
 
 def _said(sim) -> dict:
     """The step's span arguments but the wires' (``wired`` / ``wire_bytes``,
-    ISSUE 49: ``tests/test_wire_account.py`` holds those to the program)."""
-    return {k: v for k, v in sim._step._span_args().items() if k not in ("wired", "wire_bytes")}
+    ISSUE 49, and ``joint``, ISSUE 50: ``tests/test_wire_account.py`` holds
+    those to the program)."""
+    return {k: v for k, v in sim._step._span_args().items() if k not in ("wired", "wire_bytes", "joint")}
 
 
 @pytest.mark.parametrize("macros,rem", [(m, r) for m in range(6) for r in (0, 1) if m or r])

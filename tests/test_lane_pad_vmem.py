@@ -226,7 +226,14 @@ def _kernels_and_outer_ops(closed) -> str:
 #: whole-program fingerprint cannot hold: the parent traced ``jnp.pad(b, 0)``
 #: as one zero-width ``pad`` equation a quantity (XLA folds it away), which
 #: went with the pad, and every name behind it in the printed jaxpr shifts.
-PARENT_WHOLE_TILES_PROGRAM = "73383a415d5ef4ba466e3161822616f5f12a22f358159d1bcd5ae1bf69e53dbf"
+#: Re-recorded in PR 50 (it read 73383a41...9e53dbf from ISSUE 41 to PR 49): the
+#: step's shell exchange on mesh [2,2,1] sweeps x and y JOINTLY since then
+#: (``ops/exchange.py _sweep_groups``: both axes' faces sent at once, two corner
+#: relays) -- equations OUTSIDE the kernels, which this hash holds too; the
+#: kernels' part is as it was (hashed apart on both trees: 0b2b7b36...13feb226;
+#: ``tests/data/program_fingerprints.json`` also tells
+#: the two apart: the one-chip wavefront programs hold).
+PARENT_WHOLE_TILES_PROGRAM = "f945b1d7d34bdd5e1c8ad5cc1007676cb1f7c7d1f4814f0a83af245bb9bba2be"
 
 
 def _whole_tiles_program():

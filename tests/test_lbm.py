@@ -125,27 +125,15 @@ def test_bf16_storage_fails_the_tolerance():
 
 def test_an_unfilled_edge_halo_comes_out_wrong(monkeypatch):
     """Mesh [2,2,1]: the y sweep carries the x halo planes along, which is what
-    fills the x-y EDGE halo.  With that edge put back to what it held before
-    the sweep -- every face halo still filled -- the second step's diagonal
+    fills the x-y EDGE halo.  With that edge left as the y faces carried it
+    (the joint sweep's corner relay taken out: the neighbour's stale halo) --
+    every face halo still filled -- the second step's diagonal
     reads in the xy plane find the first step's cells and come out wrong."""
     from stencil_tpu.ops import exchange as ex
 
-    real = ex._axis_sweep
-
-    def faces_only(blocks, axis, r_lo, r_hi, *rest):
-        before = list(blocks)  # the sweep writes its results into the list it is given
-        out = real(blocks, axis, r_lo, r_hi, *rest)
-        if axis != 1:
-            return out
-        stale = []
-        for new, old in zip(out, before):
-            for xs in (slice(0, 1), slice(-1, None)):  # the x halo planes' y halo rows
-                for ys in (slice(0, r_lo), slice(new.shape[1] - r_hi, None)):
-                    new = new.at[xs, ys, :].set(old[xs, ys, :])
-            stale.append(new)
-        return stale
-
-    monkeypatch.setattr(ex, "_axis_sweep", faces_only)
+    # x and y fly jointly on this mesh: the y faces are cut before the x halo
+    # is in, and the corner relay is what carries the x halo planes along
+    monkeypatch.setattr(ex, "_relay_corners", lambda first, second: second)
     sim = _sim(mesh=(2, 2, 1))
     state = _random_state(sim.setup.shape, 11)
     _load(sim, state)  # with its shell filled: the FIRST step's edges are right as loaded

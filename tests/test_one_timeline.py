@@ -245,13 +245,14 @@ def test_hot_path_opens_spans_and_never_syncs(session, monkeypatch, request):
     names = [n for n, _ in opened]
     assert names.count(tm.SPAN_STEP) == 3 and names.count(tm.SPAN_EXCHANGE) == 3 and names.count(tm.SPAN_SWAP) == 6
     step_args = [a for n, a in opened if n == tm.SPAN_STEP]
-    wires = {"wired": "xy", "wire_bytes": sum(dd.exchange_hop_bytes().values()) // dd.num_subdomains()}
+    wires = {"wired": "xy", "wire_bytes": sum(dd.exchange_hop_bytes().values()) // dd.num_subdomains(),
+             "joint": "xy"}  # x and y fly jointly on this mesh (ISSUE 50)
     assert all(a == {"label": "unit", "steps": 4, **wires} for a in step_args), step_args  # the xla
     # engine's own account of its wires (ISSUE 49): one exchange of every quantity a step
     exchange_args = [a for n, a in opened if n == tm.SPAN_EXCHANGE]
     assert all(
         a == {"route": "direct", "nbytes": dd.exchange_bytes_total(), "count": 1, "wrap_axes": "",
-              "uneven_axes": "", "wire_bytes": wires["wire_bytes"]}
+              "uneven_axes": "", "wire_bytes": wires["wire_bytes"], "joint": "xy"}
         for a in exchange_args
     ), exchange_args  # wrap_axes "": on the CPU the blend kernels are off, so z self-ppermutes
     if stop is not None:

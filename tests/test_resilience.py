@@ -498,6 +498,7 @@ class TestLadderEngines:
             # what a macro sends over the wires of mesh [2,2,2] (ISSUE 49)
             "wire_account": step._stream_plan["wire_account"],
             "wired": "xyz", "wire_bytes": step._stream_plan["wire_account"].said()[1],
+            "joint": "xy",  # x and y fly jointly, z behind the pair (ISSUE 50)
         }
         assert step._stream_plan["wire_account"].every == 3
         inject.set_plan("execute:vmem_oom:stream*2")

@@ -452,6 +452,8 @@ def test_stream_separable_per_field_grouping(monkeypatch):
         "wire_account": step._stream_plan["wire_account"],
         "wired": step._stream_plan["wire_account"].said()[0],
         "wire_bytes": step._stream_plan["wire_account"].said()[1],
+        # ... and the pair of wired axes whose sweeps fly jointly (ISSUE 50)
+        "joint": step._stream_plan["wire_account"].joint[0],
     }
     assert step._span_args()["z_halo_patch"] == "tile"
     assert step._span_args()["lane_pad"] == "vmem"
@@ -529,7 +531,7 @@ def test_stream_depth_cap():
         "footprint": {"offcentre": 1, "diagonal": 1, "read_sides": 6},
         "macros_per_trip": 2,  # the wrap pass writes fresh results (ISSUE 39)
         # no exchange, no wire (ISSUE 49)
-        "wire_account": (0, {}, 1, (0, 0)), "wired": "", "wire_bytes": 0,
+        "wire_account": (0, {}, 1, (0, 0), ("", 0)), "wired": "", "wire_bytes": 0, "joint": "",
     }
     for a, b in outs:  # uncapped wrap vs the XLA ground truth
         np.testing.assert_allclose(a, b, **TOL)

@@ -224,6 +224,18 @@ CASES = {
 }
 #: the axes whose sweep a case's exchange PACKS (every other case packs none)
 PACKED = {"astaroth-fused[2,2,2]": "yz", "xla-zpack[2,2,2]": "z"}
+#: the pair of wired axes a case's exchanges sweep JOINTLY: x and y wherever
+#: both are split and ``halo_exchange_multi`` sweeps them (the relay behind the
+#: y faces is in ``traced_hops`` like any other ``ppermute``); every other
+#: case's sweeps run in turn -- one wired axis, the fused shell exchange, the
+#: bespoke slab step's own permutes
+JOINT = {case: "xy" for case in (
+    "acoustic-plane[2,2,1]", "elastic-staged[2,2,1]", "mhd-staged[2,2,1]", "lbm[2,2,1]",
+    "astaroth-zslabs[2,2,1]", "astaroth-split[2,2,2]", "astaroth-per-step[2,2,1]",
+    "jacobi-zring[2,2,1]", "jacobi-zslab[2,2,1]", "jacobi-shell[2,2,1]", "jacobi-ragged[2,2,1]",
+    "jacobi-jnp[2,2,1]",
+    "xla-mult2[2,2,1]", "xla-mult2[2,2,2]", "xla-zpack[2,2,2]",
+)}
 
 
 def packed_counters() -> tuple:
@@ -241,6 +253,7 @@ def test_the_hop_counters_move_by_the_bytes_of_the_steps_ppermutes(case):
     before, packed = hop_counters(), packed_counters()
     exchanges = telemetry.snapshot()["counters"][tm.EXCHANGE_COUNT]
     total = telemetry.snapshot()["counters"][tm.EXCHANGE_BYTES]
+    joint_sweeps = telemetry.snapshot()["counters"][tm.EXCHANGE_JOINT_SWEEPS]
     (said,) = spied_spans(dispatch, tm.SPAN_STEP)
     n_sub = dd.num_subdomains()
     # hop by hop, and nothing on an axis the mesh does not split
@@ -254,6 +267,11 @@ def test_the_hop_counters_move_by_the_bytes_of_the_steps_ppermutes(case):
     assert said["wired"] == "".join(a for a in MESH_AXES if any(axis == a for axis, _ in sent))
     if raw % account.every == 0:  # whole macros: the per-step figure is exact
         assert said["wire_bytes"] * raw == sum(sent.values()), (said, sent)
+    # the sweeps that flew jointly: said on the span, counted from the same account
+    assert said["joint"] == account.joint[0] == JOINT.get(case, "")
+    assert after[tm.EXCHANGE_JOINT_SWEEPS] - joint_sweeps == (
+        account.units(raw) * account.joint[1] * n_sub)
+    assert (account.joint[1] > 0) == bool(account.joint[0])
     # a packed sweep on a split axis packs the messages it sends, two kernels
     # (pack, unpack) a quantity a side; no other sweep packs anything
     axes = PACKED.get(case, "")
@@ -265,15 +283,20 @@ def test_the_hop_counters_move_by_the_bytes_of_the_steps_ppermutes(case):
 
 
 def test_the_scratch_case_of_the_issue_reads_a_quarter_of_what_it_read():
-    """``AcousticWave(48, 48, 32, nbl=4)`` on mesh [2,2,1], two steps: 81,920 B
-    a shard a step on the span, 163,840 B a hop over four shards and both
-    steps -- the domain-wide model charged four quantities, 655,360 a hop."""
+    """``AcousticWave(48, 48, 32, nbl=4)`` on mesh [2,2,1], two steps: four
+    faces of 20,480 B and, behind each y face, the corner relay of the joint
+    x-y sweep (both x halos' four y rows, 8 x 4 x 40 cells: 5,120 B) a shard a
+    step on the span; 163,840 B an x hop and 204,800 B a y hop over four shards
+    and both steps -- the domain-wide model charged four quantities, 655,360 a
+    hop."""
     dd, step, _, dispatch = acoustic((2, 2, 1), 2)
     before = hop_counters()
     dispatch()
-    assert step._span_args()["wire_bytes"] == 81_920
+    assert step._span_args()["wire_bytes"] == 4 * 20_480 + 2 * 5_120
+    assert step._span_args()["joint"] == "xy"
     assert moved(before, hop_counters()) == {
-        (axis, side): 163_840 for axis in "xy" for side in ("low", "high")
+        (axis, side): 163_840 + (40_960 if axis == "y" else 0)
+        for axis in "xy" for side in ("low", "high")
     }
 
 
@@ -299,6 +322,12 @@ def _exchange_domain(extent, mesh, route=None, components=0):
     ("vector-field[2,1,1]", (16, 16, 16), (2, 1, 1), {"components": 3}),
     ("zpack[2,2,2]", (16, 16, 16), (2, 2, 2), {"route": "zpack_xla"}),
     ("yzpack[2,2,2]", (16, 16, 16), (2, 2, 2), {"route": "yzpack_xla"}),
+    # the joint sweep's relay is a hop with bytes: y and z behind an unsplit x,
+    # four shards along x, a ragged pair; x and z with y between them fly in turn
+    ("even[1,2,2]", (16, 16, 16), (1, 2, 2), {"joint": "yz"}),
+    ("even[4,2,1]", (32, 16, 16), (4, 2, 1), {}),
+    ("ragged[4,2,1]", (33, 21, 16), (4, 2, 1), {}),
+    ("even[2,1,2]", (16, 16, 16), (2, 1, 2), {"joint": ""}),
 ])
 def test_exchange_counts_its_own_ppermutes(case, extent, mesh, kw):
     """``dd.exchange()`` and ``exchange_many()``, even and ragged: the hop
@@ -306,15 +335,22 @@ def test_exchange_counts_its_own_ppermutes(case, extent, mesh, kw):
     per-shard sum, ``exchange_hop_bytes()`` (the drivers' table) the same
     numbers with the unsplit hops at 0.  On the packed routes the padded
     message is what travels and what is counted."""
+    # x and y fly jointly wherever both are split and neither is packed
+    joint = kw.pop("joint", "" if mesh[:2] != (2, 2) and mesh[:2] != (4, 2)
+                   or kw.get("route") == "yzpack_xla" else "xy")
     dd = _exchange_domain(extent, mesh, **kw)
     assert (dd.exchange_route() == kw.get("route", dd.exchange_route())), dd.exchange_route()
     sent = traced_hops(jax.make_jaxpr(dd._exchange_fn)(dd._curr), mesh)
     n_sub = dd.num_subdomains()
     assert dd.exchange_hop_bytes() == {hop: sent.get(hop, 0) * n_sub for hop in HOPS}
     before, packed, total = hop_counters(), packed_counters(), telemetry.snapshot()["counters"][tm.EXCHANGE_BYTES]
+    joint_sweeps = telemetry.snapshot()["counters"][tm.EXCHANGE_JOINT_SWEEPS]
     (said,) = spied_spans(dd.exchange, tm.SPAN_EXCHANGE)
     assert moved(before, hop_counters()) == {hop: nb * n_sub for hop, nb in sent.items()}
     assert said["wire_bytes"] == sum(sent.values()) and said["count"] == 1
+    assert said["joint"] == joint
+    assert telemetry.snapshot()["counters"][tm.EXCHANGE_JOINT_SWEEPS] - joint_sweeps == (
+        n_sub if joint else 0)
     # ``domain.exchange.bytes`` is the sum of the hops here as for a step; the
     # analytic figure (every shell cell) is the span's ``nbytes``
     assert telemetry.snapshot()["counters"][tm.EXCHANGE_BYTES] - total == sum(sent.values()) * n_sub
@@ -357,3 +393,14 @@ def test_an_account_says_the_axes_and_the_sum_of_its_hops():
     both = ex.sum_accounts([one, one], every=3)
     assert (both.exchanges, both.every, both.packed) == (2, 3, (2 * one.packed[0], 8))
     assert both.hops == {hop: 2 * nb for hop, nb in one.hops.items()}
+    assert one.joint == ("", 0) == both.joint and one.span_args()["joint"] == ""
+    # two wired sweeps in a row: the second axis's hops carry the corner relay,
+    # both received slabs of the first on the side's rows, the third axis whole
+    pair = ex.exchange_account((2, 2, 1), radius, (20, 20, 20), [jnp.float32, jnp.float64])
+    face, relay = 2 * 20 * 20 * 12, (2 + 2) * 2 * 20 * 12
+    assert pair.hops == {("x", "low"): face, ("x", "high"): face,
+                         ("y", "low"): face + relay, ("y", "high"): face + relay}
+    assert pair.joint == ("xy", 1) and pair.span_args() == {
+        "wired": "xy", "wire_bytes": 4 * face + 2 * relay, "joint": "xy"}
+    assert ex.sum_accounts([pair, one, pair]).joint == ("xy", 2)
+    assert ex.exchange_account((1, 2, 2), radius, (20, 20, 20), [jnp.float32]).joint == ("yz", 1)

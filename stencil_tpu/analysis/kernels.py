@@ -692,11 +692,14 @@ def check_kernel_legal(dd, plan: dict) -> Optional[str]:
     arithmetic under x64, rotate operand width (the streaming kernels
     rotate every resident plane; narrow floats upcast inside
     ``_make_roll``, 8-byte and narrow integer dtypes cannot), and the
-    blocked-window offset granule over the pass's block layout (all three
-    stream passes stream single-window ``(1, Y, Z)``-family blocks today —
-    the z-slab wavefront a BOUNDARY one, ``lane_pad_width(Z)`` lanes over
-    the raw block's ``Z``, whole lane tiles by construction — so this leg
-    guards future geometries rather than current ones).
+    blocked-window offset granule over the pass's block layout (the stream
+    passes stream single-window ``(1, Y, Z)``-family blocks — the z-slab
+    wavefront a BOUNDARY one, ``lane_pad_width(Z)`` lanes over the raw
+    block's ``Z``, whole lane tiles by construction — and a plane pass on
+    planes too large for VMEM ``(1, tile_rows, Z)`` Y TILES of them, which
+    the resolved plan names (``plan["stages"]``): several windows along the
+    sublane dim, so a ``tile_rows`` that is not whole sublane tiles of the
+    stored dtype is refused here).
     """
     route = plan.get("route")
     if route not in ("wrap", "wavefront", "plane"):
@@ -731,6 +734,16 @@ def check_kernel_legal(dd, plan: dict) -> Optional[str]:
     # operand family — one x-plane window over the raw block, plus the
     # z-slab message blocks when the plan carries them
     layouts = [((1, raw.y, raw.z), (raw.x, raw.y, raw.z))]
+    if plan.get("tile_rows"):
+        # a resolved plane plan whose passes move Y TILES of a plane says so
+        # (ops/stream_plan.py plan_plane_passes): the blocks are the plan's
+        # own, read and not re-derived -- one layout a distinct tile
+        layouts = [
+            ((1, rows, raw.z), (raw.x, raw.y, raw.z))
+            for rows in sorted({
+                p["tile_rows"] or raw.y for st in plan["stages"] for p in st["passes"]
+            })
+        ]
     if plan.get("z_slabs"):
         from stencil_tpu.ops.stream_pass import lane_pad_width
 

@@ -90,6 +90,7 @@ PALLAS_KERNELS = {
     ),
     "stencil_tpu/ops/stream_pass.py": (
         "stream_plane_pass",
+        "stream_plane_pass_tiled",
         "stream_wavefront_pass",
         "stream_wrap_pass",
     ),
@@ -111,6 +112,7 @@ INPLACE_PASSES = {
         "inplace_order_plane_lagged_clean.py",
         "inplace_order_plane_wrapped_clean.py",
         "inplace_order_plane_renamed_clean.py",
+        "inplace_order_plane_tiled_clean.py",
     ),
     "stream_wavefront_pass": ("inplace_order_wavefront_clean.py",),
 }

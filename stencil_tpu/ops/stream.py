@@ -59,6 +59,7 @@ from stencil_tpu.telemetry import names as tm
 from stencil_tpu.ops.stream_pass import (
     PlaneKernel,
     stream_plane_pass,
+    stream_plane_pass_tiled,
     stream_wavefront_pass,
     stream_wrap_pass,
 )
@@ -389,18 +390,24 @@ def _build_plane_step(g, stages, x_radius, plan):
         """Stage ``k``'s passes in order, each over the quantities it
         touches; a later pass sees what an earlier one wrote."""
         out = list(bs)
-        for pass_kernel, reads, rings, writes, renames, prerotated in plan.stage_runs[k]:
+        for pass_kernel, reads, rings, writes, renames, prerotated, tile_rows in plan.stage_runs[k]:
             grp = [index[name] for name in reads]
             with scope():
-                outs = stream_plane_pass(
-                    pass_kernel, reads, [out[q] for q in grp],
-                    lo, hi, x_radius, origin, g.gsize, alias=alias,
-                    interpret=g.interpret, fused_shell=_group_bufs(fused_bufs, grp),
-                    f32_accumulate=g.f32_acc, halo_readers=stage_readers[k],
-                    writers=writes, rings=rings, wrap_fills=plan.wrap_fills,
-                    renames=renames, window=plan["plane_window"],
-                    strip=plan["plane_strip"], prerotated=prerotated,
+                shared = dict(
+                    alias=alias, interpret=g.interpret, f32_accumulate=g.f32_acc,
+                    halo_readers=stage_readers[k], writers=writes, rings=rings,
+                    wrap_fills=plan.wrap_fills, strip=plan["plane_strip"],
                 )
+                args = (
+                    pass_kernel, reads, [out[q] for q in grp], lo, hi, x_radius, origin, g.gsize,
+                )
+                if tile_rows:  # planes that fit VMEM in y tiles only
+                    outs = stream_plane_pass_tiled(*args, tile_rows=tile_rows, **shared)
+                else:
+                    outs = stream_plane_pass(
+                        *args, fused_shell=_group_bufs(fused_bufs, grp), renames=renames,
+                        window=plan["plane_window"], prerotated=prerotated, **shared,
+                    )
             for q, o in zip(grp, outs):
                 out[q] = o
         return out
@@ -972,6 +979,11 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         # y shift is then the address of a tile and a value a few vregs; 0 =
         # over the plane whole (plane_strip_rows, plan_plane_stages)
         args["plane_strip"] = plan["plane_strip"]
+        # ... and the rows of the Y TILES its pipeline moves of a plane that
+        # fits VMEM whole in no pass, and how many a plane is: 0 and 1 where
+        # the passes move whole planes (plan_plane_passes)
+        args["tile_rows"] = plan["tile_rows"]
+        args["y_tiles"] = plan["y_tiles"]
     if "z_halo_patch" in plan:
         # the z-slab wavefront: whether the pass patches its z halo in
         # the lane tiles that hold it or over the whole plane

@@ -12,7 +12,9 @@ formulation re-reads the block ~6x, measured 5-7.5 Gcells/s at 512^3 vs
 ~40+ for the streamed form).
 
 * ``stream_plane_pass`` — ONE level per pass over shell-carrying blocks, any
-  per-axis shell widths and any ``r >= 1`` (the kernel's x read distance).
+  per-axis shell widths and any ``r >= 1`` (the kernel's x read distance);
+  ``stream_plane_pass_tiled`` is its interior-window strip form for planes
+  too large to stream whole: the pipeline moves y tiles of them.
 * ``stream_wavefront_pass`` — ``m`` levels per pass over an ``s``-wide-shell
   shard (``m <= s // r``, ``r == 1`` only): each HBM plane is read and
   written once per ``m`` iterations (~``8/m`` B/cell), the temporal blocking
@@ -324,6 +326,50 @@ def _fused_plane_patch(v, xplane, yst, zst, t, lo_y, hi_y, lo_z, hi_z):
     for j in range(hi_z):
         v = jnp.where(colv == Z - hi_z + j, zst[lo_z + j][:, None], v)
     return v
+
+
+def _told_guards(names, halo_readers, rings, writers, outputs):
+    """``(no_ring, stale_read, unknown_output)`` of a plane pass: what raises, at
+    trace time and by name, where the kernel reads or returns what the pass was
+    not told of (``trace_plane_kernel`` has the rule).  ``outputs`` are the
+    indices of the quantities the pass has an output block for."""
+
+    def no_ring(name):
+        def fail():
+            raise ValueError(
+                f"the kernel reads {name!r} off-centre along x, but its "
+                f"footprint trace did not (it saw {tuple(rings)}), so the pass "
+                f"holds no ring for {name!r}: a kernel must read the same "
+                "offsets every time it is traced"
+            )
+
+        return fail
+
+    def stale_read(name):
+        if halo_readers is None or name in halo_readers:
+            return None
+
+        def fail(*offset):
+            raise ValueError(
+                f"the kernel reads {name!r} off-centre, but its footprint "
+                f"trace did not (it saw {tuple(halo_readers)}), so the halo "
+                f"of {name!r} was not exchanged: a kernel must read the same "
+                "offsets every time it is traced"
+            )
+
+        return fail
+
+    def unknown_output(vals):
+        for q, name in enumerate(names):
+            if name in vals and q not in outputs:
+                raise ValueError(
+                    f"the kernel returns {name!r}, but its footprint "
+                    f"trace did not (it saw {tuple(writers)}), so "
+                    f"{name!r} is not an output of the pass: a kernel "
+                    "must return the same names every time it is traced"
+                )
+
+    return no_ring, stale_read, unknown_output
 
 
 def stream_plane_pass(
@@ -670,30 +716,8 @@ def stream_plane_pass(
     pre = [(names.index(nm), dx, dz) for nm, dx, dz in prerotated] if strip else []
     assert all(dz and (q in ringed or not dx) for q, dx, dz in pre), (prerotated, rings)
 
-    def no_ring(name):
-        def fail():
-            raise ValueError(
-                f"the kernel reads {name!r} off-centre along x, but its "
-                f"footprint trace did not (it saw {tuple(rings)}), so the pass "
-                f"holds no ring for {name!r}: a kernel must read the same "
-                "offsets every time it is traced"
-            )
-
-        return fail
-
-    def stale_read(name):
-        if halo_readers is None or name in halo_readers:
-            return None
-
-        def fail(*offset):
-            raise ValueError(
-                f"the kernel reads {name!r} off-centre, but its footprint "
-                f"trace did not (it saw {tuple(halo_readers)}), so the halo "
-                f"of {name!r} was not exchanged: a kernel must read the same "
-                "offsets every time it is traced"
-            )
-
-        return fail
+    no_ring, stale_read, unknown_output = _told_guards(
+        names, halo_readers, rings, writers, set(wq) | set(home.values()))
 
     def body(origin_ref, *refs):
         in_refs = refs[:nq]
@@ -866,16 +890,6 @@ def stream_plane_pass(
                 None if (v := plane(q, 2 * r - d)) is None else up(v)
                 for d in range(2 * r + 1)
             )
-
-        def unknown_output(vals):
-            for q, name in enumerate(names):
-                if name in vals and q not in out_refs and q not in home.values():
-                    raise ValueError(
-                        f"the kernel returns {name!r}, but its footprint "
-                        f"trace did not (it saw {tuple(writers)}), so "
-                        f"{name!r} is not an output of the pass: a kernel "
-                        "must return the same names every time it is traced"
-                    )
 
         def x_global():
             return lax.rem(
@@ -1084,6 +1098,278 @@ def stream_plane_pass(
     result = list(raws)  # a non-writer comes back as the array that went in
     for q, o in zip(wq, outs if len(wq) > 1 else [outs]):
         result[home[q]] = raws[q]  # renamed: the handles swap (a no-op at home)
+        result[q] = o
+    return result
+
+
+def stream_plane_pass_tiled(
+    kernel: PlaneKernel,
+    names: Sequence[str],
+    raws: Sequence[jax.Array],  # per-quantity (X, Y, Z) shell-carrying blocks
+    lo: Dim3,
+    hi: Dim3,
+    x_radius: int,
+    origin: jax.Array,
+    global_size: Dim3,
+    tile_rows: int,  # rows ``Yt`` of a y tile of the working plane: what the
+    # pipeline moves (``stream_plan.plan_plane_passes`` chooses it)
+    strip: int,  # rows of a strip of the kernel (``plane_strip_rows``)
+    alias: bool = False,
+    interpret: bool = False,
+    f32_accumulate: bool = False,
+    halo_readers: Optional[Sequence[str]] = None,
+    writers: Optional[Sequence[str]] = None,
+    rings: Optional[Sequence[str]] = None,
+    wrap_fills: Sequence[Tuple[int, int, int, int]] = (),
+) -> List[jax.Array]:
+    """``stream_plane_pass`` on its ``"interior"`` window in the strip form,
+    for planes whose pipeline blocks do not fit VMEM whole: the pipeline moves
+    ``(1, Yt, Z)`` Y TILES of a raw plane, ``NT = Yw / Yt`` of them and the
+    block's tail rows a plane, on the grid ``(X + r + 1, NT + 1)`` -- x planes
+    outer, y tiles inner -- and the planes the kernel reads are whole in VMEM
+    scratch only.  Same kernel name, same values: every cell is bitwise the
+    whole-plane form's (``tests/test_plane_tiles.py``).
+
+    What it holds.  Every quantity's working planes as TILES, one y tile after
+    the other: y tile ``t`` is a small plane of its own, ``Kt = Yt / T`` tiles
+    between ``r`` margin tiles a side (``stream_plane_pass``'s layout over
+    ``Yt`` rows: tile ``k`` holds rows ``t Yt + s Kt + k``, one a sublane, so a
+    y shift of a strip is another tile's address).  Its margins continue it
+    into its NEIGHBOURS: the high margin tile ``m`` is tile ``m`` a sublane up
+    with the next y tile's row ``m`` at its last sublane, the low one mirrors
+    it, and the last y tile's neighbour is the first -- the periodic wrap
+    (``link``), made as each tile lands.  A quantity read at ``dx != 0`` holds
+    ``2r + 2`` such planes, every other TWO: a plane lands tile by tile WHILE
+    the strips read the planes before it, so the window lags one plane more
+    than the whole-plane form's (output plane ``j = i - r - 1`` at x step
+    ``i``) and the newest slot is never read.
+
+    A grid step ``(i, t)``.  In: the block's TAIL rows first (``t = 0``: raw rows
+    ``[Yw, Y)``, whose first ``lo.y`` are the low y halo's wrap -- kept in a
+    one-tile stash), then y tiles ``0 .. NT - 1``; each has its low z halo
+    filled in the pipeline's buffer as ever, y tile 0 its low y halo rows from
+    the stash (the y-z corner: the stash row had its z fill first), and goes
+    into the scratch as tiles.  Out: y tile ``t`` of plane ``j`` -- the strips of
+    that tile (the whole-plane form's loop over ``Kt / G`` strips, reading the
+    ``2r + 1`` complete planes around ``j``), gathered in a one-tile staging
+    block, or an x-shell plane's tile passed through -- onto the block's
+    aligned corner, the z shell rebuilt behind it; the tail rows last (``t =
+    NT``: the first rows of y tile 0, stashed).  So a stored plane is, raw cell
+    for raw cell, what the whole-plane interior window stores.
+
+    In place (``alias``) is safe by planes: x step ``i`` fetches planes ``i``
+    (ringed) and ``i - r`` (fetched lagged) and flushes plane ``i - r - 1``.
+    The maps stand still where a plane index is clamped -- the out map until
+    plane 0's real tiles come (x step ``r + 1``), the in maps once past plane
+    ``X - 1`` -- so nothing is flushed before it is computed and nothing
+    refetched after it was overwritten (``check_inplace_order`` judges the
+    maps).  Not built: ``fused_shell``, ``renames``, ``prerotated``, any other
+    window (``plan_plane_passes`` does not tile those)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nq = len(names)
+    X, Y, Z = raws[0].shape
+    r = x_radius
+    dtypes = [b.dtype for b in raws]
+    assert plane_window_form(wrap_fills, lo, hi, (Y, Z), dtypes) == "interior", (
+        wrap_fills, lo, hi, (Y, Z))
+    Yw, Zw = Y - lo.y - hi.y, Z - lo.z - hi.z
+    T = sublane_tile(dtypes)
+    Yt, NT, tail = tile_rows, Yw // tile_rows, Y - Yw
+    Kt, G = Yt // T, strip // T
+    KT = Kt + 2 * r  # a y tile's tiles, margins included
+    assert NT * Yt == Yw and Kt * T == Yt and G and G * T == strip and Kt % G == 0, (
+        tile_rows, strip, Yw, T)
+    assert Kt >= r and 0 < tail <= T and lo.x >= r and hi.x >= r, (tile_rows, r, lo, hi)
+    roll = _make_roll(interpret)
+    gsize = global_size
+    up = (lambda v: v.astype(jnp.float32)) if f32_accumulate else (lambda v: v)
+    wq = list(range(nq)) if writers is None else [q for q in range(nq) if names[q] in writers]
+    if not wq:
+        return list(raws)
+    ringed = list(range(nq)) if rings is None else [q for q in range(nq) if names[q] in rings]
+    depth = {q: 2 * r + 2 if q in ringed else 2 for q in range(nq)}
+    low_z = [f for f in wrap_fills if f[0] == 2 and f[1] == 0]
+    no_ring, stale_read, unknown_output = _told_guards(names, halo_readers, rings, writers, set(wq))
+
+    def body(origin_ref, *refs):
+        in_refs = refs[:nq]
+        out_refs = dict(zip(wq, refs[nq : nq + len(wq)]))
+        scratch = refs[nq + len(wq) :]
+        held, stash_in = scratch[:nq], scratch[nq : 2 * nq]
+        stage = dict(zip(wq, scratch[2 * nq : 2 * nq + len(wq)]))
+        stash_out = dict(zip(wq, scratch[2 * nq + len(wq) :]))
+        i, t = pl.program_id(0), pl.program_id(1)
+        j = i - r - 1  # the output plane; its window is raw planes j - r .. j + r
+
+        def link(ref, slot, before, after):
+            """Y tile ``after`` continues y tile ``before`` of the plane
+            ``ref[slot]``: the last sublane of ``before``'s high margin tiles is
+            ``after``'s first rows, sublane 0 of ``after``'s low margin tiles
+            ``before``'s last."""
+            for m in range(r):
+                ref[slot, before * KT + r + Kt + m, T - 1 : T] = ref[slot, after * KT + r + m, 0:1]
+                ref[slot, after * KT + m, 0:1] = ref[slot, before * KT + Kt + m, T - 1 : T]
+
+        def land(q, y):
+            """Y tile ``y`` of the fetched plane of ``q`` into its slot, as tiles
+            between its margins."""
+            ref, block, slot = held[q], in_refs[q], i % depth[q]
+
+            @pl.when(y == 0)  # the low y halo: the block's own tail rows
+            def _():
+                block[0, : lo.y, :Zw] = stash_in[q][: lo.y]
+
+            own = jnp.swapaxes(block[0, :, :Zw].reshape(T, Kt, Zw), 0, 1)  # (Kt, T, Zw)
+            ref[slot, pl.ds(y * KT + r, Kt)] = own
+            ref[slot, pl.ds(y * KT, r)] = roll(own[Kt - r :], 1, 1).astype(ref.dtype)
+            ref[slot, pl.ds(y * KT + r + Kt, r)] = roll(own[:r], -1, 1).astype(ref.dtype)
+            if NT > 1:
+
+                @pl.when(y >= 1)
+                def _():
+                    link(ref, slot, y - 1, y)
+
+            @pl.when(y == NT - 1)  # the periodic wrap: the first tile follows the last
+            def _():
+                link(ref, slot, NT - 1, 0)
+
+        for q in range(nq):
+            _wrap_fill(in_refs[q], low_z)  # every row of the tile, the tail's too
+
+            @pl.when(i <= X - 1 + (0 if q in ringed else r))  # (not a clamped refetch)
+            def _(q=q):
+                @pl.when(t == 0)
+                def _():
+                    stash_in[q][...] = in_refs[q][0, :T, :Zw]
+
+                @pl.when(t >= 1)
+                def _():
+                    land(q, t - 1)
+
+        def put(out, v, rows):
+            """``rows`` working rows ``v`` onto the output block's aligned
+            corner, the z shell of the stored rows rebuilt behind them."""
+            out[0, :rows, :Zw] = v
+            out[0, :rows, Zw:] = out[0, :rows, : Z - Zw]
+
+        def slot_of(q, dx=0):
+            """Where plane ``j + dx`` of ``q`` sits: a ringed plane ``p`` landed in
+            slot ``p % depth``, one fetched lagged during the x step before."""
+            return (j + dx) % depth[q] if q in ringed else (i - 1) % 2
+
+        def strips(y):
+            """Y tile ``y`` of the output plane, a strip at a time into the
+            staging tile (``stream_plane_pass``'s loop over one y tile)."""
+            x_g = lax.rem(
+                origin_ref[0] + jnp.int32(gsize.x) + j - jnp.int32(lo.x), jnp.int32(gsize.x)
+            )
+            _, z_g = _yz_coord_planes(origin_ref, T, Zw, lo.y, lo.z, gsize)
+            f = lax.broadcasted_iota(jnp.int32, (strip, 1), 0)
+            rows0 = y * Yt + (f % T) * Kt + f // T  # row of the plane at a strip's row
+
+            def one(k, carry):
+                k0 = k * G
+                first = {dy: y * KT + (k0 + r + dy) for dy in range(-r, r + 1)}
+
+                def reader(q):
+                    unrotated = {}
+
+                    def read(dx, dy, dz):
+                        if q not in ringed and dx:
+                            return None
+                        if (dx, dy) not in unrotated:
+                            if G == 1:
+                                v = held[q][slot_of(q, dx), first[dy]]
+                            else:
+                                v = held[q][slot_of(q, dx), pl.ds(first[dy], G)].reshape(strip, Zw)
+                            unrotated[dx, dy] = up(v)
+                        v = unrotated[dx, dy]
+                        return roll(v, -dz, 1) if dz else v
+
+                    return read
+
+                views = {
+                    names[q]: StripView(reader(q), r, stale_read(names[q]), no_ring(names[q]))
+                    for q in range(nq)
+                }
+                y_s, _ = _yz_coord_planes(origin_ref, strip, Zw, lo.y, lo.z, gsize, rows0 + k0)
+                vals = kernel(views, PlaneInfo(x_g, y_s, z_g, gsize, 1))
+                unknown_output(vals)
+                for q, staged in stage.items():
+                    v = vals[names[q]] if names[q] in vals else views[names[q]].center()
+                    v = v.astype(staged.dtype)
+                    if G == 1:
+                        staged[k0] = v
+                    else:
+                        staged[pl.ds(k0, G)] = v.reshape(G, T, Zw)
+                return carry
+
+            lax.fori_loop(0, Kt // G, one, 0)
+
+        @pl.when(jnp.logical_and(i >= r + 1, t <= NT - 1))
+        def _():
+            in_window = jnp.logical_and(j >= lo.x, j <= X - hi.x - 1)
+
+            @pl.when(in_window)
+            def _():
+                strips(t)
+
+            for q, out in out_refs.items():
+
+                @pl.when(in_window)
+                def _(q=q, out=out):
+                    put(out, jnp.swapaxes(stage[q][...], 0, 1).reshape(Yt, Zw), Yt)
+
+                @pl.when(jnp.logical_not(in_window))  # an x-shell plane passes through
+                def _(q=q, out=out):
+                    centre = held[q][slot_of(q), pl.ds(t * KT + r, Kt)]
+                    put(out, jnp.swapaxes(centre, 0, 1).reshape(Yt, Zw), Yt)
+
+                @pl.when(t == 0)  # the stored plane's first rows: its tail rows too
+                def _(q=q, out=out):
+                    stash_out[q][...] = out[0, :T, :Zw]
+
+        @pl.when(jnp.logical_and(i >= r + 1, t == NT))
+        def _():
+            for q, out in out_refs.items():
+                put(out, stash_out[q][...], T)
+
+    def in_map(lag):
+        def index(i, t):
+            live = i - lag <= X - 1  # past the last plane the map stands still
+            return (
+                jnp.clip(i - lag, 0, X - 1),
+                jnp.where(live, (t + NT) % (NT + 1), NT - 1),  # the tail, then the tiles
+                0,
+            )
+
+        return index
+
+    def out_map(i, t):
+        # (until plane 0's own tiles come the map stands still: nothing is flushed)
+        return (jnp.clip(i - r - 1, 0, X - 1), jnp.where(i >= r + 1, t, 0), 0)
+
+    outs = pl.pallas_call(
+        body,
+        name=tm.KERNEL_STREAM_PLANE_PASS,
+        grid=(X + r + 1, NT + 1),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [
+            pl.BlockSpec((1, Yt, Z), in_map(0 if q in ringed else r)) for q in range(nq)
+        ],
+        out_specs=tuple(pl.BlockSpec((1, Yt, Z), out_map) for _ in wq),
+        out_shape=tuple(jax.ShapeDtypeStruct((X, Y, Z), raws[q].dtype) for q in wq),
+        input_output_aliases={1 + q: k for k, q in enumerate(wq)} if alias else {},
+        scratch_shapes=[pltpu.VMEM((depth[q], NT * KT, T, Zw), raws[q].dtype) for q in range(nq)]
+        + [pltpu.VMEM((T, Zw), raws[q].dtype) for q in range(nq)]
+        + [pltpu.VMEM((Kt, T, Zw), raws[q].dtype) for q in wq]
+        + [pltpu.VMEM((T, Zw), raws[q].dtype) for q in wq],
+        interpret=interpret,
+        **_tpu_compiler_params(interpret),
+    )(origin.astype(jnp.int32), *raws)
+    result = list(raws)  # a non-writer comes back as the array that went in
+    for q, o in zip(wq, outs):
         result[q] = o
     return result
 

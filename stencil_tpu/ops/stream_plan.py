@@ -28,6 +28,10 @@ Routes (``plan_stream`` chooses; ``ops/stream_pass.py`` has the passes):
   be several STAGES (a sequence of kernels, each behind its own exchange)
   and a stage several PASSES, each over the quantities its outputs touch:
   all planned from one abstract trace of each kernel (``plan_plane_stages``).
+  A pass that cannot be cut further and fits VMEM with whole planes in no
+  form moves Y TILES of them (``plan_plane_passes``, ``tile_rows``;
+  ``stream_pass.stream_plane_pass_tiled``): the interior window's strip
+  form only, and only where the planner would otherwise raise.
 * **wavefront** — ``m`` levels per pass over an ``s``-wide-shell shard
   (``m <= s // r``, ``r == 1`` only), plain or in the z-slab form.
 * **wrap** — a single subdomain, the periodic boundary folded into the pass.
@@ -58,7 +62,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -709,6 +713,7 @@ def plane_pass_vmem_bytes(
     plane_bytes: Dict[str, int], x_radius: int, reads, rings, writes,
     ring_bytes: Optional[Dict[str, int]] = None,
     stage_bytes: Optional[Dict[str, int]] = None, prerotated=(),
+    y_tiles: int = 0, stash_bytes: Optional[Dict[str, int]] = None,
 ) -> int:
     """VMEM model of one plane pass, ``stream_vmem_fits``' accounting cut to
     what the pass holds: two pipeline planes per quantity read, two more per
@@ -725,10 +730,24 @@ def plane_pass_vmem_bytes(
     pushed before the strips read it), every other quantity read holds one,
     every quantity written a staging plane of ``stage_bytes``, and every
     ``(quantity, dx, dz)`` of ``prerotated`` (``shared_rotations``) one more
-    plane of ``ring_bytes``.  None = the kernel runs over the plane whole."""
+    plane of ``ring_bytes``.  None = the kernel runs over the plane whole.
+    ``y_tiles = NT > 0`` says the pipeline moves Y TILES of a plane
+    (``stream_pass.stream_plane_pass_tiled``): ``plane_bytes``, ``ring_bytes``
+    and ``stage_bytes`` are then ONE y tile's -- the raw rows a block moves, the
+    tile's tiles between its own margins, the staging tile -- a quantity read at
+    ``dx != 0`` holds ``2r + 2`` planes of ``NT`` such tiles (a plane lands
+    while the strips read the ``2r + 1`` before it), every other two, every
+    quantity read and every one written one more tile of ``stash_bytes`` (the
+    block's tail rows), and the margin is ONE ``_VMEM_STACK_MARGIN``: a value
+    of this form is a strip or a y tile, never a plane a quantity."""
     ring_bytes = plane_bytes if ring_bytes is None else ring_bytes
     est = sum(2 * plane_bytes[q] for q in reads)
     est += sum(2 * plane_bytes[q] for q in writes)
+    if y_tiles:
+        est += sum((2 * x_radius + 2 if q in rings else 2) * y_tiles * ring_bytes[q] for q in reads)
+        est += sum(stage_bytes[q] + stash_bytes[q] for q in writes)
+        est += sum(stash_bytes[q] for q in reads)
+        return est + _VMEM_STACK_MARGIN
     if stage_bytes is None:
         est += sum(2 * x_radius * ring_bytes[q] for q in rings)
     else:
@@ -738,13 +757,34 @@ def plane_pass_vmem_bytes(
     return est + _VMEM_STACK_MARGIN * len(reads)
 
 
+class FitsNoPass(ValueError):
+    """A plane pass that cannot be cut further -- one output alone, or a stage
+    whose passes would read what an earlier one wrote in place -- fits the VMEM
+    budget in no form the planner has (``plan_plane_passes``)."""
+
+
+@dataclasses.dataclass(frozen=True)
+class PlaneTiling:
+    """The y tiles ``plan_plane_passes`` may cut a pass's planes into
+    (``plan_plane_stages`` makes it): ``rows`` the candidates, largest first --
+    every divisor of the working plane's rows that is whole strips, the plane
+    itself first, one strip last --, ``bytes_of(rows)`` the ``(plane_bytes,
+    ring_bytes, stage_bytes, stash_bytes)`` of ``plane_pass_vmem_bytes(y_tiles=)``
+    for one of them, and, where ``rows`` is empty, ``why`` the step has no
+    tiled form."""
+
+    rows: Tuple[int, ...] = ()
+    bytes_of: Optional[Callable[[int], tuple]] = None
+    why: str = ""
+
+
 def plan_plane_passes(
     trace: PlaneTrace, plane_bytes: Dict[str, int], whole: bool = False,
     rename: bool = False, ring_bytes: Optional[Dict[str, int]] = None,
-    stage_bytes: Optional[Dict[str, int]] = None,
+    stage_bytes: Optional[Dict[str, int]] = None, tiling: PlaneTiling = PlaneTiling(),
 ) -> List[dict]:
     """The passes of one stage over one group: ``[{"writes", "reads",
-    "rings", "renames", "prerotated", "vmem_bytes"}, ...]``, each a subset of
+    "rings", "renames", "prerotated", "tile_rows", "vmem_bytes"}, ...]``, each a subset of
     the kernel's outputs with the quantities THOSE outputs touch
     (``PlaneTrace.pruned``); ``prerotated`` is the strip form's
     ``shared_rotations``, () where they do not fit beside the rest.
@@ -761,6 +801,21 @@ def plan_plane_passes(
     smaller exists and no restructuring of the kernel helps, so it is built
     whatever the model says (an over-tight ``STENCIL_VMEM_LIMIT_BYTES``
     degrades to it and never crashes; the model errs on the safe side).
+
+    Y TILES (``tiling``; ``"tile_rows"``, 0 = whole planes).  Where an output
+    fits no pass of whole planes ALONE -- the one place this function used to
+    raise: a kernel that couples many quantities in every output, D3Q19's
+    nineteen at 512 x 512 -- the pass moves y tiles of its planes instead
+    (``stream_pass.stream_plane_pass_tiled``): the largest ``tiling.rows`` the
+    one model fits (``plane_pass_vmem_bytes(y_tiles=)``), and the outputs
+    behind it join that pass while ANY tile fits (fewer passes move fewer
+    arrays; a smaller tile moves the same bytes); and a stage whose
+    whole-plane passes CLASH (the next paragraph's refusal) is planned as one
+    tiled pass over all its outputs where that fits.  Nothing that fits whole
+    planes is ever tiled, so no plan that resolved before tiles existed
+    changes.  A tiled pass takes no rename and no ``prerotated`` plane.  It
+    raises ``FitsNoPass`` where no tile fits either, or the step has no tiled
+    form (``tiling.why``), and says what was tried.
 
     Passes run one after the other ON THE SAME ARRAYS (in place), while a
     kernel means all its outputs to come from the values it was called with:
@@ -805,18 +860,46 @@ def plan_plane_passes(
             "rings": rings,
             "renames": tuple(renames),
             "prerotated": prerotated,
+            "tile_rows": 0,
             "vmem_bytes": priced(prerotated),
         }
+
+    def tiled(outputs):
+        """The pass that writes ``outputs`` over the largest y tile that fits, or
+        None."""
+        p = describe(outputs)
+        for rows in tiling.rows:
+            of_tile, of_ring, of_stage, of_stash = tiling.bytes_of(rows)
+            est = plane_pass_vmem_bytes(
+                of_tile, trace.x_radius, p["reads"], p["rings"], p["writes"], of_ring, of_stage,
+                y_tiles=tiling.rows[0] // rows, stash_bytes=of_stash,
+            )
+            if est <= budget:
+                return dict(p, prerotated=(), tile_rows=rows, vmem_bytes=est)
+        return None
+
+    def fit(outputs, in_tiles):
+        p = tiled(outputs) if in_tiles else describe(outputs)
+        return p if p is not None and p["vmem_bytes"] <= budget else None
 
     def refuse(p):
         if len(p["reads"]) == 1:
             return  # the floor: one quantity, nothing to split
-        raise ValueError(
+        if whole or trace.closed is None:
+            tried = "a pass that carries every quantity whole has no tiled form"
+        elif tiling.rows:
+            tried = (
+                f"y tiles of its planes from {tiling.rows[0]} rows down to one strip of "
+                f"{tiling.rows[-1]} fit none either"
+            )
+        else:
+            tried = tiling.why or "the step has no tiled form"
+        raise FitsNoPass(
             f"the plane pass that writes {p['writes']} reads {len(p['reads'])} "
             f"quantities {p['reads']}, {len(p['rings'])} of them off-centre "
             f"along x {p['rings']}: {p['vmem_bytes']} bytes of VMEM by the "
-            f"model against a budget of {budget} -- it fits no pass; split "
-            "the kernel into stages that touch fewer quantities each"
+            f"model against a budget of {budget} -- it fits no pass ({tried}); "
+            "split the kernel into stages that touch fewer quantities each"
         )
 
     if not trace.writers:
@@ -826,21 +909,31 @@ def plan_plane_passes(
         if p["vmem_bytes"] > budget:
             refuse(p)
         return [p]
-    passes, current = [], []
+    passes, current, in_tiles = [], [], False
     for out in trace.writers:
-        p = describe(current + [out])
-        if current and p["vmem_bytes"] > budget:  # close the pass, open the next
-            passes.append(describe(current))
-            current, p = [], describe([out])
-        if p["vmem_bytes"] > budget:
-            refuse(p)  # alone and too wide: raises, unless it is the floor
+        p = fit(current + [out], in_tiles)
+        if current and p is None:  # close the pass, open the next
+            passes.append(fit(current, in_tiles))
+            current, in_tiles, p = [], False, fit([out], False)
+        if p is None:  # alone and too wide for whole planes: y tiles of them
+            alone = describe([out])
+            in_tiles = len(alone["reads"]) > 1 and tiled([out]) is not None
+            if not in_tiles:
+                refuse(alone)  # raises, unless it is the floor
         current.append(out)
-    passes.append(describe(current))
+    passes.append(fit(current, in_tiles) or describe(current))
     written = set()
     for p in passes:
         clash = written & (set(p["reads"]) - set(p["writes"]))
+        if clash and not any(q["tile_rows"] for q in passes):
+            # the other place this function used to raise: in y tiles the
+            # stage may be ONE pass after all (D3Q19 at 512 x 512 stored as
+            # bf16: eight outputs fit whole planes, the ninth reads them)
+            joint = tiled(list(trace.writers))
+            if joint is not None:
+                return [joint]
         if clash:
-            raise ValueError(
+            raise FitsNoPass(
                 f"the plane pass that writes {p['writes']} reads "
                 f"{tuple(sorted(clash))}, which an earlier pass of the same "
                 "stage has already written in place: the stage does not fit "
@@ -848,7 +941,7 @@ def plan_plane_passes(
                 "of its own"
             )
         written |= set(p["writes"])
-    if rename and len(passes) == 1 and trace.renames:
+    if rename and len(passes) == 1 and trace.renames and not passes[0]["tile_rows"]:
         renamed = {p for p, _ in trace.renames}
         kept = [out for out in trace.writers if out not in renamed]
         return [describe(kept, renames=trace.renames)]
@@ -1050,8 +1143,8 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     bytes) --, ``footprint`` (``footprint_counts``) and the step-wide unions
     ``halo_readers`` / ``writers`` / ``renamed`` (the quantities whose write
     became a rename).  ``runs`` is, per stage, what the build runs: ``[(pass
-    kernel, reads, rings, writes, renames, prerotated), ...]`` (names).  Raises
-    ``ValueError`` for a step that fits in no pass.  Of ``plan`` only the
+    kernel, reads, rings, writes, renames, prerotated, tile_rows), ...]`` (names).
+    Raises ``FitsNoPass`` (a ``ValueError``) for a step that fits in no pass.  Of ``plan`` only the
     grouping is read.
 
     Every stage is traced once per group (``trace_plane_kernel``); a function
@@ -1091,12 +1184,14 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     shell = dd._shell_radius
     margins = (shell.lo().y + shell.hi().y) if window == "interior-z" else 2 * x_radius
     margins = margins * sublane_tile([dd.field_dtype(h) for h in dd._handles]) if strip else 0
-    plane_bytes, stage_bytes, ring_bytes = (
-        {
-            h.name: _padded_plane_bytes(y, of.z, dd.field_dtype(h).itemsize)
+    def padded(rows, of):  # per quantity, ``rows`` tile-padded rows of a plane of ``of``
+        return {
+            h.name: _padded_plane_bytes(rows, of.z, dd.field_dtype(h).itemsize)
             for h in dd._handles
         }
-        for y, of in ((raw.y, raw), (work.y, work), (work.y + margins, work))
+
+    plane_bytes, stage_bytes, ring_bytes = (
+        padded(raw.y, raw), padded(work.y, work), padded(work.y + margins, work)
     )
     groups = _stream_groups(plan, len(names))
     traced = [  # per stage, per group: traced before anything is planned
@@ -1115,7 +1210,36 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     ) < _STRIP_MIN_OPS:
         # a LIGHT kernel is bound by the planes it streams, and the strip form's
         # tiles cost it more than its few values in registers save: whole planes
-        return plan_plane_stages(dd, kernel, x_radius, plan, interpret, fused, rename, window)
+        # -- unless no pass holds them whole: the strip form can move y tiles
+        try:
+            return plan_plane_stages(dd, kernel, x_radius, plan, interpret, fused, rename, window)
+        except FitsNoPass:
+            if window != "interior" or fused:
+                raise
+    # the y tiles a pass that fits no whole planes may move instead
+    # (plan_plane_passes): built for ONE form, the interior window's strip form
+    if window == "interior" and strip and not fused:
+        tile = sublane_tile([dd.field_dtype(h) for h in dd._handles])
+        tiling = PlaneTiling(
+            tuple(
+                work.y // n for n in range(1, work.y // strip + 1)
+                if work.y % n == 0 and work.y // n % strip == 0 and work.y // n >= x_radius * tile
+            ),
+            # of ONE y tile: the raw rows a block moves, its tiles between its
+            # own margins, the staging tile, a one-tile stash of tail rows
+            lambda rows: (
+                padded(rows, raw), padded(rows + 2 * x_radius * tile, work),
+                padded(rows, work), padded(tile, work),
+            ),
+        )
+    else:
+        tiling = PlaneTiling(why=(
+            "y tiles of a plane are built for the strip form on the 'interior' window "
+            "alone (y and z unsplit, an interior of whole vector tiles); this step's "
+            f"passes work on the {window!r} window" + (
+                ", whole planes" if not strip else "") + (
+                " under halo='fused'" if fused else "")
+        ))
     described, built = [], []
     for of_stage in traced:
         readers, passes, runs = set(), [], []
@@ -1123,21 +1247,27 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
             readers |= set(names) if fused else set(trace.readers)
             for p in plan_plane_passes(
                 trace, plane_bytes, whole=fused, rename=rename, ring_bytes=ring_bytes,
-                stage_bytes=stage_bytes if strip else None,
+                stage_bytes=stage_bytes if strip else None, tiling=tiling,
             ):
                 passes.append(p)
                 runs.append((
                     trace.pruned(p["writes"])[0], p["reads"], p["rings"], p["writes"],
-                    p["renames"], p["prerotated"],
+                    p["renames"], p["prerotated"], p["tile_rows"],
                 ))
         described.append({
             "readers": tuple(nm for nm in names if nm in readers),
             "passes": tuple(passes),
         })
         built.append(runs)
+    # the rows of the y tiles its passes move (the smallest, of a step whose
+    # passes differ) and how many of them a plane is: 0 and 1 over whole planes
+    tile_rows = min(
+        (p["tile_rows"] for st in described for p in st["passes"] if p["tile_rows"]), default=0
+    )
     keys = {
         "stages": tuple(described), "footprint": footprint_counts(traces), "plane_strip": strip,
         "edge_reads": edge_reads(traces), "plane_window": window,
+        "tile_rows": tile_rows, "y_tiles": work.y // tile_rows if tile_rows else 1,
     }
     for key, of in (
         ("halo_readers", lambda st: st["readers"]),
@@ -1238,7 +1368,8 @@ class ResolvedPlan(Mapping):
 
     plan: Mapping
     stage_runs: tuple  # plane route: per stage ``[(pass kernel, reads, rings,
-    # writes, renames, prerotated), ...]`` (``plan_plane_stages``); () elsewhere
+    # writes, renames, prerotated, tile_rows), ...]`` (``plan_plane_stages``); ()
+    # elsewhere
     wrap_fills: tuple  # the y / z halo fills the plane passes make themselves
     # (``pass_wrap_fills``), the how of ``plan["pass_wrap_axes"]``
     exchange_route: str  # the domain's realize-resolved exchange route

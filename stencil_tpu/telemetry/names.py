@@ -500,7 +500,13 @@ ALL_HISTOGRAMS = frozenset({
 #: kernel runs over the plane whole (``ops/stream_pass.plane_strip_rows`` and
 #: ``ops/stream_plan.plan_plane_stages``, read off the window, the plane and
 #: the kernels' traces: 16 in both ``astaroth-mhd-256`` cells, 0 in the three
-#: 600-extent plane cells); a
+#: 600-extent plane cells) and tile_rows / y_tiles = the rows of the Y TILES its
+#: pipeline moves of a plane, and how many a plane is, where a pass that cannot
+#: be cut further fits VMEM with whole planes in no form -- the planes the
+#: kernel reads are then whole in VMEM scratch only, ``ops/stream_pass.
+#: stream_plane_pass_tiled``; 0 and 1 = the passes move whole planes, every
+#: cell but ``lbm-d3q19-512.bulk`` (128 and 4; ``ops/stream_plan.
+#: plan_plane_passes``, read off the one VMEM model); a
 #: z-slab wavefront step (``Jacobi3D``'s z-ring and
 #: lane-padded shell kernels, the stream engine's wavefront route with
 #: ``z_slabs``) adds z_halo_patch = where its kernel patches the z halo into

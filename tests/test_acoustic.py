@@ -127,6 +127,7 @@ def test_route_is_plane_and_the_span_says_so(blend, mesh, wrapped, wired, monkey
         # + 88 lanes): no whole vector tile, the passes keep the raw plane (ISSUE 45)
         "plane_window": "raw",
         "plane_strip": 0,  # ... and their kernel runs over it whole (ISSUE 46)
+        "tile_rows": 0, "y_tiles": 1,  # ... and their pipeline moves whole planes (ISSUE 51)
         "wired_edges": "",  # a star reads no edge: nothing crosses two wires in turn (ISSUE 47)
     }
     assert plan["halo_readers"] == ("u",), plan

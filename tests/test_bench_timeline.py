@@ -148,7 +148,17 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
     # PR 49's: the wires' own intervals (tests/test_bench_wires.py holds them)
     wires = {n for n in declared if n.startswith("wire_")}
     assert len(wires) == 10
-    for name in (set(declared) - new - plane - staged - setup - wired - lbm - mhd - mhdx4 - wires
+    # PR 51's: the card-filling lattice-Boltzmann cell's shares, the 256 cell's readers under
+    # a suffix of its own (tests/test_bench_lbm512.py holds them)
+    lbm512 = {n for n in declared if n.endswith(".lbm512")}
+    assert len(lbm512) == 7
+    for name in lbm512:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["reducer"] in ("named_share", "named_roofline_hbm", "span_percentile", "span_count"), name
+        assert m["cells"] == ["lbm-d3q19-512.bulk"] == declared[name]["workloads"], name
+        assert m["moves"] == "mcells_per_s_chip", name
+    for name in (set(declared) - new - plane - staged - setup - wired - lbm - lbm512 - mhd - mhdx4 - wires
                  - (ragged - {"collective_pct.ragged"})):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",

@@ -555,6 +555,46 @@ def test_compiled_lbm_step_matches_the_xla_engine():
     assert min(float(np.abs(a - b).max()) for a, b in zip(got[1:], got[:-1])) > 1e-3  # no two alike
 
 
+@pytest.mark.parametrize("budget,y_tiles", [(50e6, 2), (36e6, 4)])
+def test_compiled_lbm_step_in_y_tiles_is_bitwise_the_wrap_route(budget, y_tiles, monkeypatch):
+    """The plane pass over Y TILES of a plane as Mosaic compiles it (ISSUE 51),
+    at 256^3 x 19: the PLANNER's budget tightened (the compiler keeps its own)
+    and the wrap route taken away, so that ``LatticeBoltzmann``'s normal path
+    answers with the tiled pass, the plane in two and in four -- every cell of
+    all nineteen populations bitwise the wrap route's after 8 steps (my chip
+    runs, PR 51: passed, 78 s; 6.1-6.3 ms a step by call 2's probe).  The 512^3 box itself is the
+    benchmark's cell ``lbm-d3q19-512.bulk``: it fills the chip."""
+    import gc
+
+    from stencil_tpu.models.lbm import LatticeBoltzmann
+    from stencil_tpu.models.lbm_reference import NAMES
+    from stencil_tpu.ops import stream_plan as sp
+
+    def run():
+        sim = LatticeBoltzmann(256, 256, 256, devices=jax.devices()[:1],
+                               seed_words=(0x1234ABCD, 77, 0xDEADBEEF, 2024))
+        sim.realize()
+        sim.step(8)
+        plan = dict(sim._step._stream_plan)
+        fields = [sim.field(q) for q in NAMES]
+        for slot in (sim.dd._curr, sim.dd._next or {}):
+            for block in slot.values():
+                block.delete()
+        del sim
+        gc.collect()
+        return plan, fields
+
+    plan, want = run()
+    assert plan["route"] == "wrap"
+    monkeypatch.setattr(sp, "_deepest_fit", lambda *a, **k: None)  # no wrap depth: the plane route
+    monkeypatch.setattr(sp, "_vmem_budget", lambda: int(budget))
+    plan, got = run()
+    assert (plan["route"], plan["plane_window"], plan["y_tiles"]) == ("plane", "interior", y_tiles)
+    assert plan["tile_rows"] == 256 // y_tiles and plan["alias"]
+    for q, a, b in zip(NAMES, got, want):
+        assert np.array_equal(a, b), (q, float(np.abs(a - b).max()))
+
+
 @pytest.mark.parametrize("shape,window", [
     pytest.param((32, 58, 122), "raw", id="raw-planes-of-whole-tiles"),
     pytest.param((32, 64, 128), "interior", id="interior-of-whole-tiles"),

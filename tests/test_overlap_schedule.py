@@ -730,3 +730,42 @@ def test_lbm_macro_loop_carries_its_blocks_in_place(monkeypatch):
     text_odd, temp_odd, _ = got[None, 3]
     assert len(passes(text_odd)) == 3 and not big_copy.findall(text_odd)
     assert temp_odd <= temp * 1.002
+
+
+@pytest.mark.slow  # tier-2 with its siblings: a real-TPU-compiler AOT compile
+# at the benchmark's size (6 s)
+def test_lbm_512_step_runs_in_place_in_y_tiles(monkeypatch):
+    """The card-filling lattice-Boltzmann cell's dispatch as the chip's compiler
+    leaves it (ISSUE 51): 512^3 x 19 through the normal planner for a described
+    v5e -- ONE ``stream_plane_pass`` custom call of nineteen results, every one
+    aliased onto its own operand, eighteen ``blend_planes`` x wraps, no ``copy``
+    of a block and NOTHING temporary beside 13.0 GB of arguments: Mosaic takes
+    the 101.9 MB of VMEM the model prices (y tiles of 128 rows)."""
+    from stencil_tpu.models.lbm import RADIUS, LatticeBoltzmann
+    from stencil_tpu.ops import halo_blend
+    from stencil_tpu.ops import stream as sm
+
+    devices = _topology_devices()
+    monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        sim = LatticeBoltzmann(512, 512, 512, devices=devices[:1], seed_words=None)
+        sim.dd.realize(allocate=False)
+        plan = sp.plan_stream(sim.dd, RADIUS, "auto", False)
+        plan = sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, plan, False)
+        step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
+        compiled = step.lower(sim.dd.abstract_arrays(), 8).compile()
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    assert (plan["route"], plan["plane_window"], plan["tile_rows"], plan["y_tiles"]) == (
+        "plane", "interior", 128, 4), plan
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    calls = [l.lstrip() for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
+    passes = [l for l in calls if l.startswith("%stream_plane_pass")]
+    assert len(passes) == 1 and len([l for l in calls if l.startswith("%blend_planes")]) == 18
+    assert passes[0].split(" custom-call(")[0].count("f32[514,514,514]") == 19
+    for k in range(19):
+        assert f"{{{k}}}: ({k + 1}, {{}})" in passes[0], k  # result k IS operand 1 + k
+    assert not re.findall(r"=\s+f32\[514,514,514\]\S*\s+copy\(", text)
+    assert memory.temp_size_in_bytes == 0 and memory.argument_size_in_bytes == 13_000_499_200 + 0

@@ -1,0 +1,352 @@
+"""The plane pass whose pipeline blocks are Y TILES of a plane (ISSUE 51:
+``ops/stream_pass.py stream_plane_pass_tiled``, ``ops/stream_plan.py
+plan_plane_passes`` / ``PlaneTiling``): the pass bitwise the whole-plane strip
+form on every raw cell over several tile counts, one among them; the planner
+gives tiles exactly where it used to raise, prices them with the one model and
+allocates what it priced; D3Q19 at 512 x 512 x nineteen lands under the budget;
+the lattice-Boltzmann model through tiles against its plain reference; the
+in-place order of the tiled maps; the forms that keep the refusal say so."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from stencil_tpu import analysis
+from stencil_tpu.core.dim3 import Dim3
+from stencil_tpu.models import lbm_reference as ref
+from stencil_tpu.models.lbm import RADIUS, LatticeBoltzmann
+from stencil_tpu.ops import stream as sm
+from stencil_tpu.ops import stream_pass as spass
+from stencil_tpu.ops import stream_plan as sp
+
+#: the model's margin for a whole-plane pass of nineteen: nothing under it fits
+#: whole planes, and the wrap route's m = 1 neither (``stack_margin``)
+MARGIN_19 = 19 * sp._VMEM_STACK_MARGIN
+
+
+def _fills(n, lo, hi):
+    return tuple(
+        (a, d, s, w) for a in (1, 2)
+        for d, s, w in ((0, n[a], lo[a]), (lo[a] + n[a], lo[a], hi[a]))
+    )
+
+
+def _kernel(r):
+    """``u`` ringed and read on every kind of diagonal, ``c`` read along y and z
+    alone (fetched lagged, a halo reader), ``p`` at the centre; the cells' own
+    coordinates; two outputs."""
+
+    def kernel(views, info):
+        u, c = views["u"], views["c"]
+        x, y, z = info.coords()
+        new = 0.5 * u.center() + 0.125 * (
+            u.sh(r, r, 0) - u.sh(-r, 0, r) + u.sh(0, -r, -r) + u.sh(r, 0, 0) - u.sh(-1, 1, -1)
+        ) + c.sh(0, r, 1) + 1e-3 * (x + 2 * y + 3 * z).astype(jnp.float32)
+        return {"u": new, "p": 2.0 * u.center() + views["p"].center()}
+
+    return kernel
+
+
+_PASS_CASES = [
+    pytest.param(1, {}, ((1, 1, 1), (1, 1, 1)), id="r1"),
+    pytest.param(1, {"alias": True}, ((1, 1, 1), (1, 1, 1)), id="r1-in-place"),
+    pytest.param(1, {"f32_accumulate": True}, ((1, 1, 1), (1, 1, 1)), id="r1-bf16-storage"),
+    pytest.param(2, {"alias": True}, ((2, 3, 2), (3, 2, 4)), id="r2-uneven-shell"),
+]
+
+
+@pytest.mark.parametrize("y_tiles", [1, 2, 4])
+@pytest.mark.parametrize("r,kw,shell", _PASS_CASES)
+def test_the_tiled_pass_is_bitwise_the_whole_plane_pass(r, kw, shell, y_tiles):
+    """The same blocks through ``stream_plane_pass``'s strip form on the interior
+    window and through the tiled pass: EVERY raw cell of every quantity bitwise
+    equal -- interiors, x-shell planes passed through, the rebuilt y / z shell,
+    the tail rows -- with the plane moved whole (one y tile), in two and in four:
+    every shifted read then crosses a margin tile that a NEIGHBOUR tile filled,
+    and the first tile's low rows come from the block's tail.  A quantity the
+    pass does not write comes back as the array that went in."""
+    dtype = jnp.bfloat16 if kw.get("f32_accumulate") else jnp.float32
+    tile = spass.sublane_tile([dtype])
+    lo, hi = shell
+    n, names = (5, 8 * tile, 128), ["u", "c", "p"]
+    strip = 2 * tile  # four strips a plane, one or more a y tile
+    shape = tuple(m + a + b for m, a, b in zip(n, lo, hi))
+    rng = np.random.default_rng(51)
+    raws = [jnp.asarray(rng.standard_normal(shape), dtype) for _ in names]
+    common = dict(
+        interpret=True, halo_readers=("u", "c"), rings=("u",), writers=("u", "p"),
+        wrap_fills=_fills(n, lo, hi), **kw,
+    )
+    args = (_kernel(r), names, raws, Dim3(*lo), Dim3(*hi), r,
+            jnp.asarray([5, 3, 7], jnp.int32), Dim3(64, n[1], n[2]))
+    want = spass.stream_plane_pass(*args, window="interior", strip=strip, **common)
+    got = spass.stream_plane_pass_tiled(
+        *args, tile_rows=n[1] // y_tiles, strip=strip, **common)
+    for name, a, b in zip(names, got, want):
+        a, b = (np.asarray(v.astype(jnp.float32)) for v in (a, b))
+        assert np.isfinite(b).all() and np.array_equal(a, b), name
+    assert got[1] is raws[1]
+
+
+def test_the_tiled_pass_fails_closed_by_name():
+    """It checks what it is told as the whole-plane form does (``_told_guards``)."""
+    r, n = 1, (4, 16, 128)
+    blk = jax.ShapeDtypeStruct(tuple(m + 2 * r for m in n), jnp.float32)
+
+    def one_pass(kernel, **kw):
+        def fn(origin, a, c):
+            return spass.stream_plane_pass_tiled(
+                kernel, ["a", "c"], [a, c], Dim3(r, r, r), Dim3(r, r, r), r, origin, Dim3(*n),
+                tile_rows=8, strip=8, interpret=True, wrap_fills=_fills(n, (r,) * 3, (r,) * 3),
+                **kw,
+            )
+
+        return jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((3,), jnp.int32), blk, blk)
+
+    def reads(dx, dy):
+        return lambda views, info: {"a": views["a"].sh(1, 0, 1) * views["c"].sh(dx, dy, 0)}
+
+    one_pass(reads(0, 1), halo_readers=("a", "c"), rings=("a",), writers=("a",))  # told: fine
+    with pytest.raises(ValueError, match=r"reads 'c' off-centre.*halo of 'c' was not exchanged"):
+        one_pass(reads(0, 1), halo_readers=("a",), rings=("a",), writers=("a",))
+    with pytest.raises(ValueError, match=r"reads 'c' off-centre along x.*no ring for 'c'"):
+        one_pass(reads(1, 0), rings=("a",), writers=("a",))
+    with pytest.raises(ValueError, match=r"returns 'c'.*'c' is not an output of the pass"):
+        one_pass(lambda v, i: {"a": v["a"].center(), "c": v["c"].center()}, writers=("a",))
+
+
+# --- the planner ----------------------------------------------------------------------
+
+
+def _lbm(shape, monkeypatch, budget=None, mesh=(1, 1, 1), **kw):
+    """A lattice-Boltzmann model realized WITHOUT arrays (the real size costs
+    nothing), the pass's own fills engaged as on the chip."""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    if budget is not None:
+        monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(int(budget)))
+    sim = LatticeBoltzmann(*shape, seed_words=None, interpret=True,
+                           devices=jax.devices()[: int(np.prod(mesh))], **kw)
+    sim.dd.set_partition(*mesh)
+    sim.dd.realize(allocate=False)
+    return sim
+
+
+def _resolve(sim, **request):
+    req = dict(sp.plan_stream(sim.dd, RADIUS, "auto", False), **request)
+    return sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, req, True)
+
+
+def _tiled_bytes(n_y, n_z, rows, itemsize=4, tile=8, r=1):
+    """``plane_pass_vmem_bytes(y_tiles=)`` for D3Q19 by hand: 19 read and 19
+    written, 10 ringed."""
+    pad = sp._padded_plane_bytes
+    nt = n_y // rows
+    pipeline = 2 * 38 * pad(rows, n_z + 2 * r, itemsize)
+    held = (10 * (2 * r + 2) + 9 * 2) * nt * pad(rows + 2 * r * tile, n_z, itemsize)
+    staged = 19 * pad(rows, n_z, itemsize) + 38 * pad(tile, n_z, itemsize)
+    return pipeline + held + staged + sp._VMEM_STACK_MARGIN
+
+
+def test_the_card_filling_box_plans_in_y_tiles_under_the_budget(monkeypatch):
+    """``LatticeBoltzmann(512, 512, 512)`` on one device, every axis ``auto``:
+    the wrap route finds no depth, radius 1 rules the wavefront out, whole
+    512 x 512 planes of nineteen coupled populations fit no pass -- and the
+    planner answers with ONE in-place pass over y tiles of 128 rows, four a
+    plane, 101.9 MB by the model against 104.9; the box the benchmark already
+    had keeps its wrap route."""
+    sim = _lbm((512,) * 3, monkeypatch)
+    request = sp.plan_stream(sim.dd, RADIUS, "auto", False)
+    assert (request["route"], request["m"]) == ("plane", 1)
+    plan = sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, request, True)
+    (p,) = plan["stages"][0]["passes"]
+    assert (len(p["writes"]), len(p["reads"]), len(p["rings"])) == (19, 19, 10)
+    assert (plan["plane_window"], plan["plane_strip"], plan["pass_wrap_axes"]) == ("interior", 8, "yz")
+    assert (plan["tile_rows"], plan["y_tiles"], p["tile_rows"]) == (128, 4, 128)
+    assert p["vmem_bytes"] == _tiled_bytes(512, 512, 128) == 101_926_592 < sp._vmem_budget()
+    assert _tiled_bytes(512, 512, 256) > sp._vmem_budget()  # the largest that fits
+    assert plan["alias"] and not p["renames"] and not p["prerotated"]
+    args = sm.stream_span_args(plan, RADIUS, 19)
+    assert (args["tile_rows"], args["y_tiles"], args["aliased"]) == (128, 4, 19)
+    assert analysis.check_vmem(sim.dd, plan.plan) is None
+    small = _lbm((256,) * 3, monkeypatch)
+    assert sp.plan_stream(small.dd, RADIUS, "auto", False) == {
+        "route": "wrap", "m": 2, "z_slabs": False, "grouping": "joint"}
+
+
+@pytest.mark.parametrize("slack,rows", [(5_000_000, 64), (0, 64), (-25_000_000, 0)])
+def test_a_tighter_budget_takes_a_smaller_tile_or_says_what_it_tried(slack, rows, monkeypatch):
+    """``STENCIL_VMEM_LIMIT_BYTES`` under the default: the largest tile the one
+    model fits (at 512 lanes that is 64 rows and no smaller one: every y tile
+    brings two margin tiles a plane), and where none holds even ONE output's
+    nineteen reads down to one strip the refusal names what was tried before it
+    asks for stages."""
+    budget = _tiled_bytes(512, 512, 64) + slack
+    assert budget < _tiled_bytes(512, 512, 128)
+    sim = _lbm((512,) * 3, monkeypatch, budget=budget)
+    if rows:
+        plan = _resolve(sim)
+        assert (plan["tile_rows"], plan["y_tiles"]) == (rows, 512 // rows)
+        assert plan["stages"][0]["passes"][0]["vmem_bytes"] == _tiled_bytes(512, 512, rows)
+        return
+    with pytest.raises(sp.FitsNoPass, match=(
+            r"fits no pass \(y tiles of its planes from 512 rows down to one strip of 8 "
+            r"fit none either\); split the kernel into stages")):
+        _resolve(sim)
+
+
+def test_a_pass_that_fits_whole_planes_is_never_tiled(monkeypatch):
+    """Tiles are the answer only where whole planes raise: at 256^3 the plane
+    route's pass of nineteen fits whole and stays as it was."""
+    sim = _lbm((256,) * 3, monkeypatch)
+    plan = sp.resolve_stream_plan(
+        sim.dd, sim._kernel, RADIUS, sp.plan_stream(sim.dd, RADIUS, "plane", False), True)
+    (p,) = plan["stages"][0]["passes"]
+    assert (plan["tile_rows"], plan["y_tiles"], p["tile_rows"], plan["plane_strip"]) == (0, 1, 0, 0)
+    assert MARGIN_19 < p["vmem_bytes"] <= sp._vmem_budget()
+
+
+@pytest.mark.parametrize("case,why", [
+    ("ragged", r"passes work on the 'raw' window, whole planes\)"),
+    ("split-y", r"passes work on the 'raw' window, whole planes\)"),
+    ("fused", r"a pass that carries every quantity whole has no tiled form\)"),
+])
+def test_the_forms_that_keep_the_refusal_say_so(case, why, monkeypatch):
+    """One form, not three: ragged lanes (the raw window), a y the mesh splits
+    (a light kernel beside it plans over raw planes) and ``halo="fused"`` keep
+    the refusal they had, and its message says that tiles were not on offer."""
+    if case == "ragged":
+        sim, request = _lbm((512, 512, 600), monkeypatch), {}
+    elif case == "split-y":
+        sim, request = _lbm((512, 1024, 512), monkeypatch, mesh=(1, 2, 1)), {}
+    else:
+        sim = _lbm((512,) * 3, monkeypatch)
+        request = {"halo": "fused", "halo_forced": True}
+        monkeypatch.setattr(sp, "fused_halo_ineligible", lambda *a: None)
+    with pytest.raises(sp.FitsNoPass, match=r"fits no pass \(.*" + why):
+        _resolve(sim, route="plane", m=1, **request)
+
+
+def test_the_legality_prefilter_reads_the_tile_from_the_plan(monkeypatch):
+    """``check_kernel_legal`` judges the blocks the plan names: y tiles of whole
+    sublane tiles pass, a tile that is not is refused by its rows."""
+    sim = _lbm((512,) * 3, monkeypatch)
+    plan = dict(_resolve(sim).plan)
+    monkeypatch.setattr("stencil_tpu.analysis.kernels._mosaic_target", lambda: False)
+    assert analysis.check_kernel_legal(sim.dd, plan) is None
+
+    def with_rows(rows):
+        stages = tuple(
+            {**st, "passes": tuple({**p, "tile_rows": rows} for p in st["passes"])}
+            for st in plan["stages"]
+        )
+        return {**plan, "tile_rows": rows, "stages": stages}
+
+    assert analysis.check_kernel_legal(sim.dd, with_rows(64)) is None
+    assert "windows of extent 100" in analysis.check_kernel_legal(sim.dd, with_rows(100))
+
+
+# --- the model through tiles, and what the pass allocates -------------------------------
+
+
+def _small_lbm(monkeypatch, rows):
+    """``LatticeBoltzmann(6, 64, 256)`` under a budget that the y tile of ``rows``
+    rows just fits: no wrap depth, no whole-plane pass, no larger tile."""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    budget = _tiled_bytes(64, 256, rows)
+    assert budget < MARGIN_19
+    monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(budget))
+    sim = LatticeBoltzmann(6, 64, 256, interpret=True, seed_words=None,
+                           devices=jax.devices()[:1])
+    sim.realize()
+    return sim
+
+
+@pytest.mark.parametrize("rows", [64, 32, 16])
+def test_the_model_through_y_tiles_matches_the_reference(rows, monkeypatch):
+    """The lattice-Boltzmann model through its normal path, ``make_step(engine=
+    "stream")`` with every axis ``auto``, the budget tightened until the planner
+    answers with y tiles (one, two and four a plane): seeded random populations,
+    every cell of all nineteen against the plain reference after three steps,
+    to the tolerance the other routes are held to; and what the pass allocates
+    is what the model priced."""
+    from stencil_tpu.analysis import jaxpr as jx
+
+    sim = _small_lbm(monkeypatch, rows)
+    plan = sim._step._stream_plan
+    assert (plan["route"], plan["plane_window"], plan["plane_strip"]) == ("plane", "interior", 16)
+    assert (plan["tile_rows"], plan["y_tiles"]) == (rows, 64 // rows)
+    rng = np.random.default_rng(rows)
+    state = [np.float32(w) * rng.uniform(0.6, 1.4, sim.setup.shape).astype(np.float32) for w in ref.W]
+    for name, a in zip(ref.NAMES, state):
+        sim.dd.set_quantity(sim.handles[name], a)
+    sim.step(3)
+    want = ref.steps(sim.setup, state, 3)
+    worst = max(float(np.abs(sim.field(q) - np.asarray(w)).max()) for q, w in zip(ref.NAMES, want))
+    assert worst < 2e-6, worst
+    assert not sim._step._resilience.descents
+    # the traced call: two tile-padded buffers a pipelined block, the scratch as allocated
+    resolved = _resolve(sim)
+    step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, resolved, interpret=True)
+    closed = jax.make_jaxpr(step, static_argnums=1)(sim.dd._curr, 1)
+    (call,) = [
+        e for e in jx.iter_eqns(closed)
+        if e.primitive.name == "pallas_call" and "stream_plane_pass" in str(e.params.get("name"))
+    ]
+    gm = call.params["grid_mapping"]
+    assert tuple(gm.grid) == (8 + RADIUS + 1, 64 // rows + 1)
+    pad = lambda shape, dtype: sp._padded_plane_bytes(  # noqa: E731
+        shape[-2], shape[-1], dtype.itemsize) * int(np.prod(shape[:-2]))
+    blocks = [bm for bm in gm.block_mappings if len(bm.block_shape) == 3]
+    allocated = sum(
+        2 * pad(tuple(int(getattr(b, "block_size", 1)) for b in bm.block_shape), bm.array_aval.dtype)
+        for bm in blocks
+    ) + sum(pad(sc.shape, sc.dtype) for sc in gm.scratch_avals)
+    (p,) = resolved["stages"][0]["passes"]
+    assert len(blocks) == 38 and p["vmem_bytes"] == allocated + sp._VMEM_STACK_MARGIN
+
+
+# --- in place -----------------------------------------------------------------------------
+
+
+def test_the_inplace_order_contract_judges_the_tiled_maps(monkeypatch):
+    """``check_inplace_order`` on the traced tiled pass, in place: its two aliased
+    pairs are in order as built; with the maps NOT standing still where a plane
+    index is clamped -- the out map cycling over plane 0's tiles before their own
+    values exist, the in maps refetching the last plane's tiles while it is being
+    overwritten -- the contract names the hazard."""
+    import importlib.util
+    import os
+
+    from stencil_tpu.analysis import kernels
+
+    path = os.path.join(os.path.dirname(__file__), "analysis_fixtures",
+                        "inplace_order_plane_tiled_clean.py")
+    spec = importlib.util.spec_from_file_location("tiled_fixture", path)
+    fixture = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fixture)
+    art = fixture.build()
+    (rep,) = kernels.kernel_reports(art.closed)
+    assert not rep.parallel_dims and {o: a.index for o, a in rep.aliases.items()} == {0: 1, 1: 2}
+    assert len(rep.outputs[0].footprint) == (8 + 2) * (32 // 8 + 1)  # every grid step judged
+    assert not kernels.check_inplace_order(art)
+    kernels.reset_report_cache()
+    real = jnp.where
+    monkeypatch.setattr(spass.jnp, "where", lambda cond, a, b: a if np.ndim(a) == 0 else real(cond, a, b))
+    found = kernels.check_inplace_order(fixture.build())
+    assert found and "in place the kernel reads its own result" in found[0]
+
+
+def test_a_stage_whose_whole_plane_passes_clash_takes_one_tiled_pass(monkeypatch):
+    """The other place the planner used to raise: stored as bf16 the 512^3 box
+    fits eight outputs a whole-plane pass, and the ninth reads what the first
+    pass wrote in place.  In y tiles the stage is ONE pass after all -- here the
+    plane as one tile of 512 rows (the benchmark's bf16 control runs this)."""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    sim = LatticeBoltzmann(512, 512, 512, seed_words=None, interpret=True, devices=jax.devices()[:1])
+    sim.dd.set_storage("bf16")
+    sim.dd.realize(allocate=False)
+    plan = _resolve(sim)
+    (p,) = plan["stages"][0]["passes"]
+    assert (plan["plane_strip"], plan["tile_rows"], plan["y_tiles"]) == (16, 512, 1)
+    assert len(p["writes"]) == 19 and p["vmem_bytes"] == _tiled_bytes(512, 512, 512, 2, 16) == 95_700_672

@@ -287,8 +287,12 @@ def test_the_benchmarks_plane_cells_resolve_their_window(cell, wrapped, window, 
     # other --, a staging plane of tiles a writer and the planes rotated once
     # for all their readers (the one VMEM model)
     pad = sp._padded_plane_bytes
+    # every pass of the five moves WHOLE planes (ISSUE 51: y tiles are the
+    # planner's answer only where it used to raise)
+    assert (plan["tile_rows"], plan["y_tiles"], said["tile_rows"], said["y_tiles"]) == (0, 1, 0, 1)
     for st in plan["stages"]:
         for p in st["passes"]:
+            assert p["tile_rows"] == 0
             blocks = 2 * (len(p["reads"]) + len(p["writes"])) * pad(raw.y, raw.z, 4)
             if strip:
                 # ... and the 24 planes rotated once a grid step: the four fields

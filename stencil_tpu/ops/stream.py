@@ -150,10 +150,12 @@ def macro_loop(macro, macros: int, carry, per_trip: int):
     a trip is born after the trip's operand has died and takes its buffer:
     the carry comes home and nothing is copied (``_carry_period`` is the
     same count for the plane route's renames).  With
-    an odd ``macros`` the last result flows into the program's edge (the
-    ``dynamic_update_slice`` / ``pad`` of the dispatch), which has no carry
-    to honour: XLA may copy there, once a DISPATCH -- dispatch an even count
-    of macros."""
+    an odd ``macros`` the last result flows into what follows the loop --
+    on the wrap route the dispatch's edge CALL, the ``stream_wrap_pass`` that
+    writes the raw blocks, which takes a fresh operand as it comes; in
+    ``models/jacobi.py`` and under ``edges: "xla"`` the ``dynamic_update_slice``
+    / ``pad`` of the dispatch, which has no carry to honour: XLA may copy
+    there, once a DISPATCH -- dispatch an even count of macros."""
 
     def trip(_, c):
         for _ in range(per_trip):
@@ -316,28 +318,39 @@ def _build_wrap_step(g, stages, x_radius, plan):
     names, lo, n = g.names, g.lo, g.n
     groups = _stream_groups(plan, len(names))
 
+    def one(depth, bs, raw_in=False, raw_out=None):
+        origin = _origin_of(g)
+        out = list(bs)
+        for grp in groups:
+            outs = stream_wrap_pass(
+                kernel, [names[q] for q in grp], [bs[q] for q in grp],
+                depth, origin, g.gsize, interpret=g.interpret,
+                f32_accumulate=g.f32_acc, interior=(lo, n), raw_in=raw_in,
+                raw_out=raw_out and [raw_out[q] for q in grp],
+            )
+            for q, o in zip(grp, outs):
+                out[q] = o
+        return tuple(out)
+
     def per_shard(steps, *blocks_raw):
+        blocked, rem = divmod(steps, k)
+        calls = blocked + bool(rem)
+        if plan["edges"] == "raw" and calls >= 2:
+            # the dispatch carries the raw blocks at its two edges: its first
+            # call reads them, its last one writes them, in place (the blocks
+            # are the step's donated operand), and the calls between run bare
+            # in the loop -- ``steps`` an even count of macros keeps the loop
+            # whole trips.  A dispatch of ONE call would read the blocks it
+            # writes: it takes the cut and the write-back below
+            bs = one(k, blocks_raw, raw_in=True)
+            bs = macro_loop(partial(one, k), calls - 2, bs, per_trip)
+            return one(rem or k, bs, raw_out=blocks_raw)
         bs = tuple(
             lax.slice(b, (lo.x, lo.y, lo.z), (lo.x + n.x, lo.y + n.y, lo.z + n.z))
             for b in blocks_raw
         )
-
-        def one(depth, bs):
-            origin = _origin_of(g)
-            out = list(bs)
-            for grp in groups:
-                outs = stream_wrap_pass(
-                    kernel, [names[q] for q in grp], [bs[q] for q in grp],
-                    depth, origin, g.gsize, interpret=g.interpret,
-                    f32_accumulate=g.f32_acc,
-                )
-                for q, o in zip(grp, outs):
-                    out[q] = o
-            return tuple(out)
-
         # ``steps`` an even count of macros keeps the dispatch's edge free
         # of copies too (macro_loop)
-        blocked, rem = divmod(steps, k)
         bs = macro_loop(partial(one, k), blocked, bs, per_trip)
         if rem:
             bs = one(rem, bs)
@@ -807,6 +820,8 @@ def make_stream_step(
         suffix = ",split" if plan["overlap"] == "split" else ""
         if plan["halo"] == "fused":
             suffix += ",fused"
+        if plan.get("edges") == "raw":
+            suffix += ",raw"
         return Rung(
             name=f"{plan['route']}[m={plan['m']}{suffix}]",
             build=lambda: _build_stream_step(
@@ -840,6 +855,16 @@ def make_stream_step(
                 "down to overlap=off at the same depth"
             )
             return rung_for(dict(request, overlap="off", overlap_forced=True))
+        if plan_now.get("edges") == "raw":
+            # the wrap route's edge forms hold two larger pipeline planes a
+            # quantity than the bare pass the depth was chosen for: drop them
+            # at the SAME depth before any depth descent
+            log_warn(
+                f"raw-block edges on wrap[m={plan_now['m']}] exceeded the "
+                f"compiler's capability ({cls.value}); stepping down to the XLA "
+                "cut and write-back at the same depth"
+            )
+            return rung_for(dict(request, edges="xla", edges_forced=True))
         if plan_now["route"] not in ("wavefront", "wrap") or plan_now["m"] <= 1:
             return None  # plane route is the bottom rung — propagate
         new_max = plan_now["m"] - 1
@@ -856,6 +881,8 @@ def make_stream_step(
             plan_stream(dd, x_radius, path, separable, max_m=new_max),
             overlap=plan_now["overlap"], overlap_forced=True,
             halo=plan_now["halo"], halo_forced=True,
+            # (nor the wrap route's raw-block edges: "xla" by now)
+            **({"edges": "xla", "edges_forced": True} if "edges" in plan_now else {}),
         ))
 
     # static prefilters on real backends: a rung the VMEM model
@@ -964,6 +991,10 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         # the wrap route: macros a trip of its device-side loop, as many
         # as bring the fresh-result pass's carry home (macro_loop)
         args["macros_per_trip"] = plan["macros_per_trip"]
+        # ... and where a dispatch's two edges live: "raw" = its first pass
+        # reads the domain's raw blocks and its last one writes them, "xla" =
+        # a slice and a dynamic_update_slice a quantity (wrap_edge_form)
+        args["edges"] = plan["edges"]
     if "steps_per_trip" in plan:
         # the plane route: steps a trip of its step loop, as many as bring
         # the renamed handles home (_carry_period)

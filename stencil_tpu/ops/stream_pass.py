@@ -1581,6 +1581,30 @@ def stream_wavefront_pass(
     return outs, None
 
 
+def _periodic_margin(v, axis: int, ext: int, lo: int, roll):
+    """``v`` grown by ``ext`` cells along ``axis`` so that cell ``lo + j`` of the
+    result is cell ``j`` of ``v`` and the cells around it -- ``lo`` before, ``ext
+    - lo`` behind -- are ``v``'s PERIODIC IMAGE: what a self-wrap exchange writes
+    into a shell that wide.  The margin is built behind ``v`` (its first cells,
+    and in the last ``lo`` places its last ones: one select over ``ext`` cells)
+    and one rotate by ``lo`` carries those round to the front.  ``ext <= n``."""
+    n = v.shape[axis]
+    assert 0 <= lo <= ext <= n, (lo, ext, n)
+    head = lax.slice_in_dim(v, 0, ext, axis=axis)
+    last = lax.slice_in_dim(v, n - ext, n, axis=axis)
+    at = lax.broadcasted_iota(jnp.int32, head.shape, axis)
+    v = jnp.concatenate([v, jnp.where(at < ext - lo, head, last)], axis=axis)
+    return roll(v, lo, axis) if lo else v
+
+
+def wrap_edge_plane(raw_plane: Tuple[int, int], dtypes) -> Tuple[int, int]:
+    """The ``(Yb, Zb)`` block ``stream_wrap_pass``'s edge forms move of a
+    ``(Yr, Zr)`` raw plane: whole vector tiles of every stored dtype, a
+    boundary block in both dims (the DMA moves ``Yr`` x ``Zr``)."""
+    tile = sublane_tile(dtypes)
+    return -(-raw_plane[0] // tile) * tile, lane_pad_width(raw_plane[1])
+
+
 def stream_wrap_pass(
     kernel: PlaneKernel,
     names: Sequence[str],
@@ -1591,18 +1615,51 @@ def stream_wrap_pass(
     interpret: bool = False,
     f32_accumulate: bool = False,  # bf16-storage variant (see
     # stream_wavefront_pass)
+    interior: Tuple[Dim3, Dim3] = None,  # the edge forms: (lo, n), where the
+    # bare interior sits in a RAW block and how large it is
+    raw_in: bool = False,  # ``blocks`` are the domain's raw blocks
+    raw_out: Sequence[jax.Array] = None,  # the raw blocks the results land in
+    # (consumed: each is its output's buffer)
 ) -> List[jax.Array]:
     """``k`` kernel levels over the WHOLE (single-device) domain with the
     periodic wrap folded in — the user-kernel generalization of
     ``jacobi_wrap_step`` (see its docstring: the x-wrap rides the modular
     block index map with a ``2k``-step replay closing every level's ring;
     the y/z wrap is the natural roll wraparound on exact-sized planes).
-    No shell, no exchange, ~8/k HBM bytes per cell per iteration."""
+    No shell, no exchange, ~8/k HBM bytes per cell per iteration.
+
+    The two EDGE FORMS let a dispatch carry the domain's raw ``(Xr, Yr, Zr)``
+    blocks at its two ends and nothing cut or landed by XLA between
+    (``ops/stream.py _build_wrap_step``; ``domain.step`` says ``edges:
+    "raw"``).  Both move a raw plane as ONE boundary block of whole tiles
+    (``wrap_edge_plane``) at the x index ``lo.x +`` the bare one; the levels
+    between work on the bare ``(Y, Z)`` plane exactly as above, so every
+    interior value is the bare form's to the bit.
+
+    * ``raw_in``: the level-0 plane is the ``[lo.y : lo.y + Y, lo.z : lo.z + Z]``
+      window of the block -- two rotates on the whole-tile plane and an
+      aligned cut.
+    * ``raw_out``: the level-``k`` plane is placed at ``(lo.y, lo.z)`` of the
+      whole-tile plane with its periodic image around it
+      (``_periodic_margin``: on this route the domain is one periodic device,
+      so that image is what ``exchange()`` writes there) and written into the
+      ``raw_out`` block, which the output ALIASES and the kernel never reads
+      (``pl.ANY``): the x halo planes, visited by no grid step, keep what the
+      operand held.  Never both on the SAME buffers: a replay step would read
+      a plane an earlier step has written.
+
+    They exist where the interior is whole vector tiles no narrower than the
+    margin (``ops/stream_plan.py wrap_edge_form``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     nq = len(names)
-    X, Y, Z = blocks[0].shape
+    if interior is None:
+        assert not raw_in and raw_out is None
+        lo, (X, Y, Z) = Dim3(0, 0, 0), blocks[0].shape
+    else:
+        lo, (X, Y, Z) = interior
+    raw_shape = blocks[0].shape if raw_in else (raw_out[0].shape if raw_out else None)
     assert 1 <= k <= X // 2, (k, X)
     roll = _make_roll(interpret)
     gsize = global_size
@@ -1610,14 +1667,24 @@ def stream_wrap_pass(
         jnp.float32 if f32_accumulate else b.dtype for b in blocks
     ]
     up = (lambda v: v.astype(jnp.float32)) if f32_accumulate else (lambda v: v)
+    if raw_shape is not None:
+        Yb, Zb = wrap_edge_plane(raw_shape[1:], [b.dtype for b in blocks])
+
+    def level0(ref, acc):  # level-0 plane i (mod X), bare
+        v = up(ref[0])
+        if raw_in:
+            for axis, at in ((0, lo.y), (1, lo.z)):
+                v = roll(v, -at, axis) if at else v
+            v = v[:Y, :Z].astype(acc)  # (a narrow float comes back from a rotate as f32)
+        return v
 
     def body(origin_ref, *refs):
         in_refs = refs[:nq]
-        refs = refs[nq:]
+        refs = refs[nq * (2 if raw_out else 1):]  # past the aliased operands
         out_refs = refs[:nq]
         rings = refs[nq:]
         i = pl.program_id(0)
-        vals = [up(ref[0]) for ref in in_refs]  # level-0 plane i (mod X)
+        vals = [level0(ref, acc) for ref, acc in zip(in_refs, acc_dtypes)]
         y_g, z_g = _yz_coord_planes(origin_ref, Y, Z, 0, 0, gsize)
         for s in range(1, k + 1):
             prevs = [rings[q][s - 1, i % 2] for q in range(nq)]
@@ -1641,25 +1708,40 @@ def stream_wrap_pass(
                 for q in range(nq)
             ]
         for q in range(nq):
+            v = vals[q]
+            if raw_out:
+                v = _periodic_margin(v, 0, Yb - Y, lo.y, roll)
+                v = _periodic_margin(v, 1, Zb - Z, lo.z, roll)
             # level-k plane (i - k) % X (the one f32_accumulate downcast)
-            out_refs[q][0] = vals[q].astype(blocks[q].dtype)
+            out_refs[q][0] = v.astype(blocks[q].dtype)
 
+    bare, edge = (1, Y, Z), (1, Yb, Zb) if raw_shape is not None else None
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + [
-        pl.BlockSpec((1, Y, Z), lambda i: (i % X, 0, 0)) for _ in range(nq)
+        pl.BlockSpec(edge, lambda i: (lo.x + i % X, 0, 0)) if raw_in
+        else pl.BlockSpec(bare, lambda i: (i % X, 0, 0))
+        for _ in range(nq)
     ]
     args = [origin.astype(jnp.int32), *blocks]
+    aliases = {}
+    if raw_out:
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY) for _ in range(nq)]
+        args += list(raw_out)
+        aliases = {1 + nq + q: q for q in range(nq)}
     outs = pl.pallas_call(
         body,
         name=tm.KERNEL_STREAM_WRAP_PASS,
         grid=(X + 2 * k,),
         in_specs=in_specs,
         out_specs=tuple(
-            pl.BlockSpec((1, Y, Z), lambda i: ((i - k) % X, 0, 0))
+            pl.BlockSpec(edge, lambda i: (lo.x + (i - k) % X, 0, 0)) if raw_out
+            else pl.BlockSpec(bare, lambda i: ((i - k) % X, 0, 0))
             for _ in range(nq)
         ),
         out_shape=tuple(
-            jax.ShapeDtypeStruct((X, Y, Z), b.dtype) for b in blocks
+            jax.ShapeDtypeStruct(raw_shape if raw_out else (X, Y, Z), b.dtype)
+            for b in blocks
         ),
+        input_output_aliases=aliases,
         scratch_shapes=[pltpu.VMEM((k, 2, Y, Z), acc) for acc in acc_dtypes],
         interpret=interpret,
         **_tpu_compiler_params(interpret),

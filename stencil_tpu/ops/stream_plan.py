@@ -85,6 +85,7 @@ from stencil_tpu.ops.stream_pass import (
     plane_strip_rows,
     plane_window_form,
     sublane_tile,
+    wrap_edge_plane,
 )
 from stencil_tpu.parallel.mesh import MESH_AXES
 
@@ -1286,6 +1287,38 @@ def macros_per_trip(in_place: bool) -> int:
     return 1 if in_place else 2
 
 
+def wrap_edge_form(dd, plan: Mapping) -> str:
+    """Where the edges of a wrap-route dispatch live: ``"raw"`` = its first
+    pass reads the domain's raw blocks and its last one writes them
+    (``stream_wrap_pass``'s edge forms), ``"xla"`` = ``lax.slice`` cuts the
+    bare interiors out before the passes and ``dynamic_update_slice`` lands
+    them behind (nineteen of each, a tenth of ``lbm-d3q19-256.bulk``'s busy
+    time: PERF.md, PR 52).  Read off the block's static shape and the VMEM
+    model AFTER the depth is chosen, for the bare form as ever: ``"raw"`` where
+    the y-z interior is whole vector tiles of every stored dtype, no narrower
+    than the margin that rounds a raw plane up to whole tiles
+    (``wrap_edge_plane``), and the bare form's bytes plus the edge forms' two
+    larger pipeline planes a quantity still fit the budget -- so a kernel
+    that fits bare only keeps its depth and the XLA edges.  ``domain.step``
+    says it as ``edges``; no option."""
+    spec = dd.local_spec()
+    n, raw = spec.sz, spec.raw_size()
+    dtypes = [dd.field_dtype(h) for h in dd._handles]
+    yb, zb = wrap_edge_plane((raw.y, raw.z), dtypes)
+    whole = n.y % sublane_tile(dtypes) == 0 and n.z % 128 == 0
+    if not (whole and yb <= 2 * n.y and zb <= 2 * n.z):
+        return "xla"
+    itemsizes = [jnp.dtype(d).itemsize for d in dtypes]
+    if plan.get("grouping") == "per-field":
+        itemsizes = [max(itemsizes)]
+    wider = sum(
+        2 * (_padded_plane_bytes(raw.y, raw.z, it) - _padded_plane_bytes(n.y, n.z, it))
+        for it in itemsizes
+    )
+    est, margin = stream_plan_vmem_bytes(dd, plan)
+    return "raw" if est + wider + margin <= _vmem_budget() else "xla"
+
+
 def _carry_period(names: Sequence[str], stages) -> int:
     """After how many steps a step loop's carry is back in its own buffers:
     the order of the permutation one step's renames (``plan["stages"]``)
@@ -1354,7 +1387,7 @@ def pass_wrap_fills(dd, exch_route: str) -> Tuple[str, tuple]:
 #: brings none of its old resolution along
 REQUEST_KEYS = (
     "route", "m", "z_slabs", "grouping", "alias", "alias_forced",
-    "overlap", "overlap_forced", "halo", "halo_forced",
+    "overlap", "overlap_forced", "halo", "halo_forced", "edges", "edges_forced",
 )
 
 
@@ -1495,6 +1528,13 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
         # the wrap pass writes fresh results: two macros a trip bring the
         # loop's carry home (macro_loop; domain.step's ``macros_per_trip``)
         plan["macros_per_trip"] = macros_per_trip(False)
+        # ... and where a dispatch's two edges live (domain.step's ``edges``):
+        # in the first and the last pass where the raw blocks' planes are
+        # theirs to move, else in XLA's cut and write-back -- as after a
+        # compile reject of the edge forms (the ladder's step down at the
+        # same depth)
+        if not plan.get("edges_forced"):
+            plan["edges"] = wrap_edge_form(dd, plan)
     # what the step's exchanges send to ANOTHER shard, hop by hop, and pack
     # (ops/exchange.py ``exchange_account``, i.e. ``_sweep_kind``: the message
     # plan that is run): ``domain.run_step`` counts the wires from it and

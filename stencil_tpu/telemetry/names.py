@@ -470,7 +470,14 @@ ALL_HISTOGRAMS = frozenset({
 #: to be back in its own buffer (``ops/stream.macro_loop``): 2 where the
 #: kernel writes a fresh result, 1 where it writes in place (``alias``) -- and
 #: so does a stream-engine step on the wrap route (2: ``stream_wrap_pass``
-#: writes fresh results); a stream-engine step on the PLANE route says
+#: writes fresh results), which says beside it edges = where a dispatch's two
+#: edges live: "raw" = its first pass reads the domain's raw blocks and its
+#: last one writes them, in place (the pass's edge forms), "xla" = a
+#: ``lax.slice`` and a ``dynamic_update_slice`` a quantity around the passes --
+#: read off the block's static shape and the VMEM model AFTER the depth is
+#: chosen (``ops/stream_plan.wrap_edge_form``), "raw" in ``lbm-d3q19-256.bulk``;
+#: a dispatch of ONE call keeps the cut and the write-back whatever it says; a
+#: stream-engine step on the PLANE route says
 #: steps_per_trip = the steps one trip of its step loop runs, as many as bring
 #: the handles its renames swap back to their own buffers
 #: (``ops/stream_plan._carry_period``): 1 with no rename (elastic), 2 for

@@ -203,16 +203,18 @@ def _lbm():
 
     from stencil_tpu.models.lbm import LatticeBoltzmann
 
-    # 16^3 plans depth 8: cap it at the cell's 2, so that MODEL_STEPS is one
-    # trip of the wrap route's macro loop (two macros), a macro behind it and
-    # a remainder
-    s = LatticeBoltzmann(16, 16, 16, interpret=True, devices=jax.devices()[:1])
+    # a box whose y-z interior is whole vector tiles, as the cell's 256 x 256
+    # is, so that the dispatch carries the raw blocks at its edges (ISSUE 52);
+    # it plans depth 8: cap it at the cell's 2, so that MODEL_STEPS is the edge
+    # call that reads the raw blocks, one trip of the wrap route's macro loop
+    # (two bare macros) and the remainder as the edge call that writes them
+    s = LatticeBoltzmann(16, 8, 128, interpret=True, devices=jax.devices()[:1])
     s.realize()
     s._step = s.dd.make_step(s._kernel, engine="stream", x_radius=1, interpret=True,
                              stream_depth=2)
     args = s._step._span_args()
     assert (args["route"], args["macros_per_trip"], args["diagonal"]) == ("wrap", 2, 12), args
-    assert s._step._stream_plan["m"] == 2
+    assert s._step._stream_plan["m"] == 2 and args["edges"] == "raw", args
     return _trace_step(s.dd, s._step)
 
 

@@ -673,6 +673,27 @@ def test_a_boundary_block_counts_the_arrays_extent():
         assert "boundary block of extent 250" in finding.render() and "200 cells" in finding.render()
 
 
+@pytest.mark.parametrize("fixture,aliased", [
+    ("tiling_legal_wrap_edges_clean.py", False), ("kernel_coverage_wrap_edges_clean.py", True),
+], ids=["raw-in", "raw-out"])
+def test_the_wrap_pass_edge_forms_are_quiet_on_every_kernel_contract(fixture, aliased):
+    """``stream_wrap_pass``'s edge forms (ISSUE 52) move a raw ``(10, 10, 130)``
+    plane as ONE ``(1, 16, 256)`` boundary block, wider than the array in both
+    minor dims: counted on the array's extent, legal on the granule; the
+    ``raw_out`` form's x halo planes are covered by its alias alone, onto an
+    operand no block map reads."""
+    from stencil_tpu.analysis import kernels
+
+    art = _load(os.path.join(FIXTURE_DIR, fixture))
+    (rep,) = kernels.kernel_reports(art.closed)
+    raw = (10, 10, 130)  # (the aliased operand, in ``pl.ANY``, reads as one block of the whole array)
+    edge = [u for u in list(rep.inputs) + list(rep.outputs) if u.array_shape == raw != u.block_shape]
+    assert len(edge) == 1 and all((u.block_shape, u.nblocks) == ((1, 16, 256), (10, 1, 1)) for u in edge)
+    assert bool(rep.aliases) == aliased
+    for contract in ("kernel-coverage", "inplace-order", "kernel-race", "tiling-legal"):
+        assert not analysis.check(art, contract=contract), contract
+
+
 @pytest.mark.parametrize("z,want", [(10, None), (122, None)], ids=["boundary-16-in-128", "whole-tiles-128"])
 def test_check_kernel_legal_takes_the_z_slab_boundary_block(z, want):
     """The plan surface models the z-slab wavefront's window as the pass

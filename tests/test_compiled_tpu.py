@@ -555,6 +555,51 @@ def test_compiled_lbm_step_matches_the_xla_engine():
     assert min(float(np.abs(a - b).max()) for a, b in zip(got[1:], got[:-1])) > 1e-3  # no two alike
 
 
+def test_compiled_wrap_step_carries_the_raw_blocks_at_its_edges(monkeypatch):
+    """The wrap route's edge forms as Mosaic compiles them (ISSUE 52), 256^3 x
+    19, the benchmark's cell: after 8 steps (raw in, one trip of two bare
+    macros, raw out -- aliased onto the step's donated blocks) every interior
+    cell of all nineteen populations BITWISE the parent's formulation
+    (``wrap_edge_form`` patched to ``"xla"``: ``lax.slice``, bare passes,
+    ``dynamic_update_slice``); the y and z shell of every interior x plane the
+    periodic image of that plane, and the x halo planes what the blocks held
+    before the step."""
+    import gc
+
+    from stencil_tpu.models.lbm import LatticeBoltzmann
+    from stencil_tpu.models.lbm_reference import NAMES
+    from stencil_tpu.ops import stream_plan as sp
+
+    def run(edges):
+        with monkeypatch.context() as mp:
+            if edges == "xla":
+                mp.setattr(sp, "wrap_edge_form", lambda dd, plan: "xla")
+            sim = LatticeBoltzmann(256, 256, 256, devices=jax.devices()[:1],
+                                   seed_words=(0x1234ABCD, 77, 0xDEADBEEF, 2024))
+            sim.realize()
+            before = [(np.asarray(sim.dd._curr[q][0]), np.asarray(sim.dd._curr[q][-1])) for q in NAMES]
+            sim.step(8)
+            said = sim._step._span_args()
+            assert (said["route"], said["edges"], sim._step._stream_plan["m"]) == ("wrap", edges, 2), said
+            assert not sim._step._resilience.descents
+            raws = [np.asarray(sim.dd._curr[q]) for q in NAMES]  # on the host: 69 MB each
+        for slot in (sim.dd._curr, sim.dd._next or {}):
+            for block in slot.values():
+                block.delete()
+        del sim
+        gc.collect()
+        return before, raws
+
+    before, got = run("raw")
+    _, want = run("xla")
+    for q, (lo_plane, hi_plane), a, b in zip(NAMES, before, got, want):
+        inner = a[1:-1, 1:-1, 1:-1]
+        assert np.array_equal(inner, b[1:-1, 1:-1, 1:-1]), (q, float(np.abs(inner - b[1:-1, 1:-1, 1:-1]).max()))
+        assert np.array_equal(a[1:-1], np.pad(inner, ((0, 0), (1, 1), (1, 1)), mode="wrap")), q
+        assert np.array_equal(a[0], lo_plane) and np.array_equal(a[-1], hi_plane), q
+    assert min(float(np.abs(a - b).max()) for a, b in zip(got[1:], got[:-1])) > 1e-3  # no two alike
+
+
 @pytest.mark.parametrize("budget,y_tiles", [(50e6, 2), (36e6, 4)])
 def test_compiled_lbm_step_in_y_tiles_is_bitwise_the_wrap_route(budget, y_tiles, monkeypatch):
     """The plane pass over Y TILES of a plane as Mosaic compiles it (ISSUE 51),

@@ -680,13 +680,19 @@ def test_mhd_step_over_four_chips_carries_its_blocks_in_place(monkeypatch):
 # at the benchmark's size, three of them (5-6 s each)
 def test_lbm_macro_loop_carries_its_blocks_in_place(monkeypatch):
     """The lattice-Boltzmann cell's dispatch as the chip's compiler leaves it
-    (ISSUE 39): 256^3 x 19 on the stream engine's wrap route at depth 2 for a
-    described v5e.  The ``while`` body holds TWO ``stream_wrap_pass`` calls --
-    every second result takes the buffer the trip's operand died in -- and NO
-    ``copy`` of ``f32[256,256,256]``; the one-a-trip control has one call and
-    NINETEEN such copies a trip (2.55 GB, as much as the pass moves).  Nothing
-    is temporary that the control did not hold, and an odd macro count runs
-    its last macro behind the loop without a copy."""
+    (ISSUE 39, ISSUE 52): 256^3 x 19 on the stream engine's wrap route at
+    depth 2 for a described v5e.  The dispatch carries the domain's RAW blocks
+    at its two edges: its first ``stream_wrap_pass`` reads the nineteen
+    ``f32[258,258,258]`` blocks, its last one writes them, every result aliased
+    onto the step's own operand, and the program holds NO
+    ``dynamic-update-slice``, NO ``slice`` and no ``copy`` of a block.  Between
+    them the ``while`` body holds TWO bare calls -- every second result takes
+    the buffer the trip's operand died in --; the one-a-trip control has one
+    call and NINETEEN copies of ``f32[256,256,256]`` a trip (2.55 GB, as much
+    as the pass moves).  Nothing is temporary that the control did not hold, an
+    odd macro count runs its last bare macro behind the loop without a copy,
+    and with the edge forms taken away (``wrap_edge_form`` patched to the
+    parent's) the cut and the write-back are back, nineteen of each."""
     from stencil_tpu.models.lbm import RADIUS, LatticeBoltzmann
     from stencil_tpu.ops import stream as sm
 
@@ -695,23 +701,27 @@ def test_lbm_macro_loop_carries_its_blocks_in_place(monkeypatch):
     jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
     try:
         got = {}
-        for per_trip, macros in ((None, 4), (None, 3), (1, 4)):
+        for per_trip, macros, edges in ((None, 6, "raw"), (None, 7, "raw"), (1, 6, "raw"), (None, 6, "xla")):
             with monkeypatch.context() as mp:
                 if per_trip is not None:
                     mp.setattr(sp, "macros_per_trip", lambda in_place: per_trip)
+                if edges == "xla":
+                    mp.setattr(sp, "wrap_edge_form", lambda dd, plan: "xla")
                 sim = LatticeBoltzmann(256, 256, 256, devices=devices[:1], seed_words=None)
                 sim.dd.realize(allocate=False)
                 plan = sp.plan_stream(sim.dd, RADIUS, "auto", False)
                 assert (plan["route"], plan["m"], plan["grouping"]) == ("wrap", 2, "joint"), plan
                 plan = sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, plan, False)
+                assert (plan["m"], plan["edges"]) == (2, edges), plan
                 step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
                 compiled = step.lower(sim.dd.abstract_arrays(), macros * plan["m"]).compile()
-            got[per_trip, macros] = (
+            got[per_trip, macros, edges] = (
                 compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes, plan,
             )
     finally:
         jax.config.update("jax_enable_x64", x64_was)
-    big_copy = re.compile(r"=\s+f32\[256,256,256\]\S*\s+copy\(")
+    big_copy = re.compile(r"=\s+f32\[25[68],25[68],25[68]\]\S*\s+copy\(")
+    edge_ops = re.compile(r"=\s+f32\[25[68],25[68],25[68]\]\S*\s+(?:dynamic-update-slice|slice)\(")
 
     def passes(text):
         return [
@@ -719,17 +729,33 @@ def test_lbm_macro_loop_carries_its_blocks_in_place(monkeypatch):
             if "custom-call(" in l and "tpu_custom_call" in l and l.lstrip().startswith("%stream_wrap_pass")
         ]
 
-    text, temp, plan = got[None, 4]
+    def results(line, shape):
+        return line.split(" custom-call(")[0].count(shape)
+
+    def aliased(line):
+        if "output_to_operand_aliasing=" not in line:
+            return 0
+        return line[line.index("output_to_operand_aliasing="):].split("}, ")[0].count("(")
+
+    text, temp, plan = got[None, 6, "raw"]
     assert plan["macros_per_trip"] == 2 and plan["footprint"]["diagonal"] == 12
-    assert len(passes(text)) == 2 and not big_copy.findall(text)
-    assert not any("output_to_operand_aliasing" in l for l in passes(text))
-    text_one, temp_one, plan_one = got[1, 4]
+    # raw in, two in the ``while`` (two trips of them), raw out: no other kernel
+    calls = passes(text)
+    assert len(calls) == 4 and not big_copy.findall(text) and not edge_ops.findall(text)
+    assert sorted(results(l, "f32[258,258,258]") for l in calls) == [0, 0, 0, 19]
+    assert sorted(aliased(l) for l in calls) == [0, 0, 0, 19]
+    assert all(results(l, "f32[256,256,256]") == 19 for l in calls if not aliased(l))
+    text_one, temp_one, plan_one = got[1, 6, "raw"]
     assert plan_one["macros_per_trip"] == 1
-    assert len(passes(text_one)) == 1 and len(big_copy.findall(text_one)) == 19
+    assert len(passes(text_one)) == 3 and len(big_copy.findall(text_one)) == 19
     assert temp <= temp_one  # two sets of nineteen blocks taking turns, either way
-    text_odd, temp_odd, _ = got[None, 3]
-    assert len(passes(text_odd)) == 3 and not big_copy.findall(text_odd)
+    text_odd, temp_odd, _ = got[None, 7, "raw"]
+    assert len(passes(text_odd)) == 5 and not big_copy.findall(text_odd) and not edge_ops.findall(text_odd)
     assert temp_odd <= temp * 1.002
+    # the parent's edges: the whole loop between a cut and a write-back
+    text_xla, temp_xla, _ = got[None, 6, "xla"]
+    assert len(passes(text_xla)) == 2 and not any(aliased(l) for l in passes(text_xla))
+    assert len(edge_ops.findall(text_xla)) == 38 and temp <= temp_xla * 1.002
 
 
 @pytest.mark.slow  # tier-2 with its siblings: a real-TPU-compiler AOT compile

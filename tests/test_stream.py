@@ -530,6 +530,7 @@ def test_stream_depth_cap():
         # the 27-point kernel reads every edge and corner (ISSUE 39)
         "footprint": {"offcentre": 1, "diagonal": 1, "read_sides": 6},
         "macros_per_trip": 2,  # the wrap pass writes fresh results (ISSUE 39)
+        "edges": "xla",  # a 16 x 16 interior is no whole vector tile (ISSUE 52)
         # no exchange, no wire (ISSUE 49)
         "wire_account": (0, {}, 1, (0, 0), ("", 0)), "wired": "", "wire_bytes": 0, "joint": "",
     }

@@ -59,15 +59,17 @@ def main(argv=None) -> int:
     _common.apply_numerics(args, sim.dd)
     sim.realize()
     # the route the planner took and what the kernel reads against what the
-    # route serves (docs/lbm.md): wrap on one device, plane on a mesh -- and on
-    # one device at a box whose planes fit VMEM in y tiles only (512^3)
+    # route serves (docs/lbm.md): wrap on one device, plane on a mesh -- and at
+    # a box whose planes fit VMEM in y tiles only (512^3 a device), on one device
+    # or beside a split y (``plane_window`` "interior" / "interior-z")
     mesh = ",".join(str(int(d)) for d in sim.dd.mesh_dim())
     plan = getattr(sim._step, "_span_args", dict)()
     print(
         f"mesh: {mesh} route={plan.get('route')!r} depth={getattr(sim._step, '_stream_plan', {}).get('m')} "
         f"read_sides={plan.get('read_sides')} exchanged_sides={plan.get('exchanged_sides')} "
         f"aliased={plan.get('aliased')} tile_rows={plan.get('tile_rows', 0)} "
-        f"y_tiles={plan.get('y_tiles', 1)}",
+        f"y_tiles={plan.get('y_tiles', 1)} plane_window={plan.get('plane_window')!r} "
+        f"wired={plan.get('wired', '')!r} wire_bytes={plan.get('wire_bytes', 0)}",
         file=sys.stderr,
     )
 

@@ -1122,40 +1122,60 @@ def stream_plane_pass_tiled(
     rings: Optional[Sequence[str]] = None,
     wrap_fills: Sequence[Tuple[int, int, int, int]] = (),
 ) -> List[jax.Array]:
-    """``stream_plane_pass`` on its ``"interior"`` window in the strip form,
-    for planes whose pipeline blocks do not fit VMEM whole: the pipeline moves
-    ``(1, Yt, Z)`` Y TILES of a raw plane, ``NT = Yw / Yt`` of them and the
+    """``stream_plane_pass`` on one of its two ALIGNED windows in the strip
+    form, for planes whose pipeline blocks do not fit VMEM whole: the pipeline
+    moves ``(1, Yt, Z)`` Y TILES of a raw plane, ``NT = Yw / Yt`` of them and the
     block's tail rows a plane, on the grid ``(X + r + 1, NT + 1)`` -- x planes
     outer, y tiles inner -- and the planes the kernel reads are whole in VMEM
     scratch only.  Same kernel name, same values: every cell is bitwise the
-    whole-plane form's (``tests/test_plane_tiles.py``).
+    whole-plane form's (``tests/test_plane_tiles.py``).  The window is read off
+    what the pass is told (``plane_window_form``), and decides ONE thing: how
+    the two ENDS of a plane's y tiles are closed.
 
     What it holds.  Every quantity's working planes as TILES, one y tile after
     the other: y tile ``t`` is a small plane of its own, ``Kt = Yt / T`` tiles
-    between ``r`` margin tiles a side (``stream_plane_pass``'s layout over
-    ``Yt`` rows: tile ``k`` holds rows ``t Yt + s Kt + k``, one a sublane, so a
-    y shift of a strip is another tile's address).  Its margins continue it
-    into its NEIGHBOURS: the high margin tile ``m`` is tile ``m`` a sublane up
-    with the next y tile's row ``m`` at its last sublane, the low one mirrors
-    it, and the last y tile's neighbour is the first -- the periodic wrap
+    between its margin tiles (``stream_plane_pass``'s layout over ``Yt`` rows:
+    tile ``k`` holds raw rows ``t Yt + s Kt + k``, one a sublane, so a y shift of
+    a strip is another tile's address).  Its margins continue it into its
+    NEIGHBOURS: the high margin tile ``m`` is tile ``m`` a sublane up with the
+    next y tile's row ``m`` at its last sublane, the low one mirrors it
     (``link``), made as each tile lands.  A quantity read at ``dx != 0`` holds
     ``2r + 2`` such planes, every other TWO: a plane lands tile by tile WHILE
     the strips read the planes before it, so the window lags one plane more
     than the whole-plane form's (output plane ``j = i - r - 1`` at x step
     ``i``) and the newest slot is never read.
 
+    The ends, on ``"interior"`` (the pass fills y and z itself): ``r`` margin
+    tiles a side, and the last y tile's neighbour is the first -- the periodic
+    wrap, one more ``link``.  The block's tail rows ``[Yw, Y)`` begin with the
+    low y halo's wrap, so y tile 0 takes its rows ``[0, lo.y)`` from them, and
+    the stored plane's tail rows are the first rows of its y tile 0.
+
+    The ends, on ``"interior-z"`` (the mesh splits y: the rows outside the
+    interior are a NEIGHBOUR's, put into the block by the step's exchange):
+    nothing links the last y tile to the first.  The layout is ``stream_plane_
+    pass``'s carried one a y tile: tiles over raw rows from tile 0 on -- the
+    low y halo rows are y tile 0's own first rows, real cells --, no low
+    margin, ``M = lo.y + hi.y`` high margin tiles, the LAST y tile's filled
+    from the block's tail rows (its last ``lo.y`` interior rows and the high y
+    halo; their z fill made first: the y-z corner of the x -> y -> z order).  The
+    strips of y tile ``t`` compute the ``Kt`` tiles from ``lo.y`` on, raw rows
+    ``[t Yt + lo.y, (t + 1) Yt + lo.y)``: every interior row once.  Out, the
+    staged tiles go one sublane down as ``put_carried`` has it: the last
+    ``lo.y`` rows a y tile computes belong to the NEXT block and wait in the
+    stash (for the tail block after the last), the first block's rows ``[0,
+    lo.y)`` and the tail's high halo rows pass through from the centre plane.
+
     A grid step ``(i, t)``.  In: the block's TAIL rows first (``t = 0``: raw rows
-    ``[Yw, Y)``, whose first ``lo.y`` are the low y halo's wrap -- kept in a
-    one-tile stash), then y tiles ``0 .. NT - 1``; each has its low z halo
-    filled in the pipeline's buffer as ever, y tile 0 its low y halo rows from
-    the stash (the y-z corner: the stash row had its z fill first), and goes
-    into the scratch as tiles.  Out: y tile ``t`` of plane ``j`` -- the strips of
+    ``[Yw, Y)``, kept in a one-tile stash), then y tiles ``0 .. NT - 1``; each
+    has its low z halo filled in the pipeline's buffer as ever and goes into
+    the scratch as tiles.  Out: y tile ``t`` of plane ``j`` -- the strips of
     that tile (the whole-plane form's loop over ``Kt / G`` strips, reading the
     ``2r + 1`` complete planes around ``j``), gathered in a one-tile staging
     block, or an x-shell plane's tile passed through -- onto the block's
     aligned corner, the z shell rebuilt behind it; the tail rows last (``t =
-    NT``: the first rows of y tile 0, stashed).  So a stored plane is, raw cell
-    for raw cell, what the whole-plane interior window stores.
+    NT``).  So a stored plane is, raw cell for raw cell, what the whole-plane
+    form on the same window stores.
 
     In place (``alias``) is safe by planes: x step ``i`` fetches planes ``i``
     (ringed) and ``i - r`` (fetched lagged) and flushes plane ``i - r - 1``.
@@ -1163,8 +1183,9 @@ def stream_plane_pass_tiled(
     plane 0's real tiles come (x step ``r + 1``), the in maps once past plane
     ``X - 1`` -- so nothing is flushed before it is computed and nothing
     refetched after it was overwritten (``check_inplace_order`` judges the
-    maps).  Not built: ``fused_shell``, ``renames``, ``prerotated``, any other
-    window (``plan_plane_passes`` does not tile those)."""
+    maps, which are the same on both windows).  Not built: ``fused_shell``,
+    ``renames``, ``prerotated``, the raw window -- ragged lanes or rows, a z the
+    mesh splits -- (``plan_plane_passes`` does not tile those)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1172,16 +1193,21 @@ def stream_plane_pass_tiled(
     X, Y, Z = raws[0].shape
     r = x_radius
     dtypes = [b.dtype for b in raws]
-    assert plane_window_form(wrap_fills, lo, hi, (Y, Z), dtypes) == "interior", (
-        wrap_fills, lo, hi, (Y, Z))
+    window = plane_window_form(wrap_fills, lo, hi, (Y, Z), dtypes)
+    assert window in ("interior", "interior-z"), (window, wrap_fills, lo, hi, (Y, Z))
+    carried = window == "interior-z"  # the y halo rows are a neighbour's
     Yw, Zw = Y - lo.y - hi.y, Z - lo.z - hi.z
     T = sublane_tile(dtypes)
-    Yt, NT, tail = tile_rows, Yw // tile_rows, Y - Yw
+    Yt, NT, M = tile_rows, Yw // tile_rows, Y - Yw
     Kt, G = Yt // T, strip // T
-    KT = Kt + 2 * r  # a y tile's tiles, margins included
+    # a y tile's margin tiles below and above its own ``Kt``, and the tile its
+    # strips' first output tile is
+    below, above, base = (0, M, lo.y) if carried else (r, r, r)
+    KT = below + Kt + above
     assert NT * Yt == Yw and Kt * T == Yt and G and G * T == strip and Kt % G == 0, (
         tile_rows, strip, Yw, T)
-    assert Kt >= r and 0 < tail <= T and lo.x >= r and hi.x >= r, (tile_rows, r, lo, hi)
+    assert Kt >= max(below, above) and 0 < M <= T and lo.x >= r and hi.x >= r, (
+        tile_rows, r, lo, hi)
     roll = _make_roll(interpret)
     gsize = global_size
     up = (lambda v: v.astype(jnp.float32)) if f32_accumulate else (lambda v: v)
@@ -1208,32 +1234,41 @@ def stream_plane_pass_tiled(
             ``ref[slot]``: the last sublane of ``before``'s high margin tiles is
             ``after``'s first rows, sublane 0 of ``after``'s low margin tiles
             ``before``'s last."""
-            for m in range(r):
-                ref[slot, before * KT + r + Kt + m, T - 1 : T] = ref[slot, after * KT + r + m, 0:1]
+            for m in range(above):
+                ref[slot, before * KT + below + Kt + m, T - 1 : T] = (
+                    ref[slot, after * KT + below + m, 0:1])
+            for m in range(below):
                 ref[slot, after * KT + m, 0:1] = ref[slot, before * KT + Kt + m, T - 1 : T]
 
         def land(q, y):
             """Y tile ``y`` of the fetched plane of ``q`` into its slot, as tiles
             between its margins."""
             ref, block, slot = held[q], in_refs[q], i % depth[q]
+            if not carried:
 
-            @pl.when(y == 0)  # the low y halo: the block's own tail rows
-            def _():
-                block[0, : lo.y, :Zw] = stash_in[q][: lo.y]
+                @pl.when(y == 0)  # the low y halo: the block's own tail rows
+                def _():
+                    block[0, : lo.y, :Zw] = stash_in[q][: lo.y]
 
             own = jnp.swapaxes(block[0, :, :Zw].reshape(T, Kt, Zw), 0, 1)  # (Kt, T, Zw)
-            ref[slot, pl.ds(y * KT + r, Kt)] = own
-            ref[slot, pl.ds(y * KT, r)] = roll(own[Kt - r :], 1, 1).astype(ref.dtype)
-            ref[slot, pl.ds(y * KT + r + Kt, r)] = roll(own[:r], -1, 1).astype(ref.dtype)
+            ref[slot, pl.ds(y * KT + below, Kt)] = own
+            if below:
+                ref[slot, pl.ds(y * KT, below)] = roll(own[Kt - below :], 1, 1).astype(ref.dtype)
+            ref[slot, pl.ds(y * KT + below + Kt, above)] = (
+                roll(own[:above], -1, 1).astype(ref.dtype))
             if NT > 1:
 
                 @pl.when(y >= 1)
                 def _():
                     link(ref, slot, y - 1, y)
 
-            @pl.when(y == NT - 1)  # the periodic wrap: the first tile follows the last
+            @pl.when(y == NT - 1)
             def _():
-                link(ref, slot, NT - 1, 0)
+                if carried:  # the plane goes on into the block's tail rows
+                    for m in range(M):
+                        ref[slot, (NT - 1) * KT + Kt + m, T - 1 : T] = stash_in[q][m : m + 1]
+                else:  # the periodic wrap: the first tile follows the last
+                    link(ref, slot, NT - 1, 0)
 
         for q in range(nq):
             _wrap_fill(in_refs[q], low_z)  # every row of the tile, the tail's too
@@ -1248,16 +1283,50 @@ def stream_plane_pass_tiled(
                 def _():
                     land(q, t - 1)
 
-        def put(out, v, rows):
+        def from_tiles(d):  # a y tile's ``Kt`` tiles as its ``Yt`` rows
+            return jnp.swapaxes(d, 0, 1).reshape(Yt, Zw)
+
+        def put(out, v, rows, patch=None):
             """``rows`` working rows ``v`` onto the output block's aligned
-            corner, the z shell of the stored rows rebuilt behind them."""
+            corner (``patch()`` then mends rows of it), the z shell of the
+            stored rows rebuilt behind them."""
             out[0, :rows, :Zw] = v
+            if patch is not None:
+                patch()
             out[0, :rows, Zw:] = out[0, :rows, : Z - Zw]
 
         def slot_of(q, dx=0):
             """Where plane ``j + dx`` of ``q`` sits: a ringed plane ``p`` landed in
             slot ``p % depth``, one fetched lagged during the x step before."""
             return (j + dx) % depth[q] if q in ringed else (i - 1) % 2
+
+        def tail_of_centre(q, m):
+            """Beside a split y: raw row ``Yw + m`` of the centre plane, where
+            ``land`` put it."""
+            return held[q][slot_of(q), (NT - 1) * KT + Kt + m, T - 1 : T]
+
+        def put_carried(out, q):
+            """Beside a split y: the staged tiles of writer ``q`` -- tiles ``[lo.y,
+            lo.y + Kt)`` of y tile ``t`` -- into output block ``t``, one sublane
+            DOWN as ``stream_plane_pass``'s ``put_carried`` has it.  The block's
+            rows ``[0, lo.y)`` are what the y tile BEFORE staged last (stashed) --
+            before the first, the low y halo rows of the centre plane, passed
+            through --, and what falls off this tile's last sublane waits in the
+            stash for the next block."""
+            staged, stash = stage[q], stash_out[q]
+            down = roll(staged[Kt - lo.y :], 1, 1).astype(staged.dtype)  # (lo.y, T, Zw)
+
+            @pl.when(t == 0)
+            def _():
+                for m in range(lo.y):
+                    stash[m : m + 1] = held[q][slot_of(q), m, 0:1]
+
+            def patch():
+                for m in range(lo.y):
+                    out[0, m : m + 1, :Zw] = stash[m : m + 1]
+                    stash[m : m + 1] = staged[Kt - lo.y + m, T - 1 : T]
+
+            put(out, from_tiles(jnp.concatenate([down, staged[: Kt - lo.y]], axis=0)), Yt, patch)
 
         def strips(y):
             """Y tile ``y`` of the output plane, a strip at a time into the
@@ -1267,11 +1336,13 @@ def stream_plane_pass_tiled(
             )
             _, z_g = _yz_coord_planes(origin_ref, T, Zw, lo.y, lo.z, gsize)
             f = lax.broadcasted_iota(jnp.int32, (strip, 1), 0)
-            rows0 = y * Yt + (f % T) * Kt + f // T  # row of the plane at a strip's row
+            # raw row of the plane at a strip's row (output tile ``k`` holds raw
+            # rows ``y Yt + s Kt + k + base - below``)
+            rows0 = y * Yt + (f % T) * Kt + f // T + (base - below)
 
             def one(k, carry):
                 k0 = k * G
-                first = {dy: y * KT + (k0 + r + dy) for dy in range(-r, r + 1)}
+                first = {dy: y * KT + (k0 + base + dy) for dy in range(-r, r + 1)}
 
                 def reader(q):
                     unrotated = {}
@@ -1308,9 +1379,12 @@ def stream_plane_pass_tiled(
 
             lax.fori_loop(0, Kt // G, one, 0)
 
+        def interior_plane():  # is the output plane one the kernel computes?
+            return jnp.logical_and(j >= lo.x, j <= X - hi.x - 1)
+
         @pl.when(jnp.logical_and(i >= r + 1, t <= NT - 1))
         def _():
-            in_window = jnp.logical_and(j >= lo.x, j <= X - hi.x - 1)
+            in_window = interior_plane()
 
             @pl.when(in_window)
             def _():
@@ -1320,20 +1394,36 @@ def stream_plane_pass_tiled(
 
                 @pl.when(in_window)
                 def _(q=q, out=out):
-                    put(out, jnp.swapaxes(stage[q][...], 0, 1).reshape(Yt, Zw), Yt)
+                    if carried:
+                        put_carried(out, q)
+                    else:
+                        put(out, from_tiles(stage[q][...]), Yt)
 
                 @pl.when(jnp.logical_not(in_window))  # an x-shell plane passes through
                 def _(q=q, out=out):
-                    centre = held[q][slot_of(q), pl.ds(t * KT + r, Kt)]
-                    put(out, jnp.swapaxes(centre, 0, 1).reshape(Yt, Zw), Yt)
+                    put(out, from_tiles(held[q][slot_of(q), pl.ds(t * KT + below, Kt)]), Yt)
 
-                @pl.when(t == 0)  # the stored plane's first rows: its tail rows too
-                def _(q=q, out=out):
-                    stash_out[q][...] = out[0, :T, :Zw]
+                if not carried:
+
+                    @pl.when(t == 0)  # the stored plane's first rows: its tail rows too
+                    def _(q=q, out=out):
+                        stash_out[q][...] = out[0, :T, :Zw]
 
         @pl.when(jnp.logical_and(i >= r + 1, t == NT))
         def _():
             for q, out in out_refs.items():
+                if carried:
+                    # the stash holds the last y tile's last ``lo.y`` rows; the high
+                    # y halo rows, and every tail row of an x-shell plane, pass
+                    # through from the centre plane
+                    for m in range(lo.y, M):
+                        stash_out[q][m : m + 1] = tail_of_centre(q, m)
+
+                    @pl.when(jnp.logical_not(interior_plane()))
+                    def _(q=q):
+                        for m in range(lo.y):
+                            stash_out[q][m : m + 1] = tail_of_centre(q, m)
+
                 put(out, stash_out[q][...], T)
 
     def in_map(lag):

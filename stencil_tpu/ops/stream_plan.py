@@ -30,8 +30,10 @@ Routes (``plan_stream`` chooses; ``ops/stream_pass.py`` has the passes):
   all planned from one abstract trace of each kernel (``plan_plane_stages``).
   A pass that cannot be cut further and fits VMEM with whole planes in no
   form moves Y TILES of them (``plan_plane_passes``, ``tile_rows``;
-  ``stream_pass.stream_plane_pass_tiled``): the interior window's strip
-  form only, and only where the planner would otherwise raise.
+  ``stream_pass.stream_plane_pass_tiled``): the strip form on either aligned
+  window -- a light kernel falls to it too --, and only where the planner
+  would otherwise raise; the raw window (ragged lanes or rows, a split z) and
+  ``halo="fused"`` keep that refusal.
 * **wavefront** — ``m`` levels per pass over an ``s``-wide-shell shard
   (``m <= s // r``, ``r == 1`` only), plain or in the z-slab form.
 * **wrap** — a single subdomain, the periodic boundary folded into the pass.
@@ -1158,15 +1160,18 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     (``_make_roll``) -- and the rings are priced at it; ``keys["plane_window"]``
     says the one the step is planned on, which is ``"raw"`` where
     ``"interior-z"`` was asked for and no strip form comes of it (that window
-    has no whole-plane form: a light kernel beside a split y has no cell and
-    gets no code).  ``strip`` is the rows
+    has no whole-plane form: a light kernel beside a split y runs over whole
+    raw planes while they fit a pass).  ``strip`` is the rows
     of a strip of the passes' strip form as the plane allows it
     (``plane_strip_rows``; 0 = the plane whole): the kernels are traced over
     such strips and the rings priced as the tiles they then hold -- unless the
     heaviest of the step's kernels makes fewer than ``_STRIP_MIN_OPS``
     operations a cell (the equations of its jaxpr, which in the strip form
     holds no shift): the step is then planned over whole planes, and
-    ``keys["plane_strip"]`` says 0."""
+    ``keys["plane_strip"]`` says 0 -- unless whole planes fit no pass
+    (``FitsNoPass``): the strip form, on either aligned window, can move y tiles
+    of them (``PlaneTiling``; D3Q19 at 512 x 512, on one chip and beside a split
+    y), and the step is planned in it after all."""
     names = [h.name for h in dd._handles]
     raw = dd.local_spec().raw_size()
     if window == "interior-z" and not strip:
@@ -1183,8 +1188,9 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
     # ``x_radius`` margin tiles a side, and a writer's staging plane beside
     # (``stream_plane_pass``)
     shell = dd._shell_radius
-    margins = (shell.lo().y + shell.hi().y) if window == "interior-z" else 2 * x_radius
-    margins = margins * sublane_tile([dd.field_dtype(h) for h in dd._handles]) if strip else 0
+    tile = sublane_tile([dd.field_dtype(h) for h in dd._handles])
+    margin_tiles = (shell.lo().y + shell.hi().y) if window == "interior-z" else 2 * x_radius
+    margins = margin_tiles * tile if strip else 0
     def padded(rows, of):  # per quantity, ``rows`` tile-padded rows of a plane of ``of``
         return {
             h.name: _padded_plane_bytes(rows, of.z, dd.field_dtype(h).itemsize)
@@ -1212,31 +1218,36 @@ def plan_plane_stages(dd, kernel, x_radius: int, plan: dict, interpret: bool,
         # a LIGHT kernel is bound by the planes it streams, and the strip form's
         # tiles cost it more than its few values in registers save: whole planes
         # -- unless no pass holds them whole: the strip form can move y tiles
+        # (a strip form exists on the two aligned windows alone)
         try:
             return plan_plane_stages(dd, kernel, x_radius, plan, interpret, fused, rename, window)
         except FitsNoPass:
-            if window != "interior" or fused:
+            if fused:
                 raise
     # the y tiles a pass that fits no whole planes may move instead
-    # (plan_plane_passes): built for ONE form, the interior window's strip form
-    if window == "interior" and strip and not fused:
-        tile = sublane_tile([dd.field_dtype(h) for h in dd._handles])
+    # (plan_plane_passes): built for the strip form, on either aligned window
+    if strip and not fused:
+        # a y tile holds at least the tiles its margins are cut from: ``r`` a
+        # side on the interior window, the y shell's ``lo.y + hi.y`` behind it
+        # beside a split y (``stream_plane_pass_tiled``)
+        least = (margin_tiles if window == "interior-z" else x_radius) * tile
         tiling = PlaneTiling(
             tuple(
                 work.y // n for n in range(1, work.y // strip + 1)
-                if work.y % n == 0 and work.y // n % strip == 0 and work.y // n >= x_radius * tile
+                if work.y % n == 0 and work.y // n % strip == 0 and work.y // n >= least
             ),
             # of ONE y tile: the raw rows a block moves, its tiles between its
             # own margins, the staging tile, a one-tile stash of tail rows
             lambda rows: (
-                padded(rows, raw), padded(rows + 2 * x_radius * tile, work),
+                padded(rows, raw), padded(rows + margins, work),
                 padded(rows, work), padded(tile, work),
             ),
         )
     else:
         tiling = PlaneTiling(why=(
-            "y tiles of a plane are built for the strip form on the 'interior' window "
-            "alone (y and z unsplit, an interior of whole vector tiles); this step's "
+            "y tiles of a plane are built for the strip form on the two aligned windows "
+            "alone ('interior': y and z unsplit; 'interior-z': z unsplit beside a split y; "
+            "an interior of whole vector tiles either way); this step's "
             f"passes work on the {window!r} window" + (
                 ", whole planes" if not strip else "") + (
                 " under halo='fused'" if fused else "")

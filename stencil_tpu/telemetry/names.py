@@ -495,25 +495,31 @@ ALL_HISTOGRAMS = frozenset({
 #: the margin tiles of the strip form's tile layout, no plane cut at an
 #: unaligned row --, "raw" = the shell-carrying plane, everywhere
 #: else: a z the mesh splits, ragged lanes or rows, a light kernel beside a
-#: split y (``ops/stream_pass.plane_window_form``, read off the fills and the
-#: block's static shape: "interior" in ``astaroth-mhd-256.bulk``, "interior-z"
-#: in ``astaroth-mhd-256x4.bulk``, "raw" in the three 600-extent plane cells)
+#: split y whose whole raw planes fit a pass (``ops/stream_pass.
+#: plane_window_form``, read off the fills and the block's static shape:
+#: "interior" in ``astaroth-mhd-256.bulk`` and ``lbm-d3q19-512.bulk``,
+#: "interior-z" in ``astaroth-mhd-256x4.bulk`` and ``lbm-d3q19-512x4.bulk``,
+#: "raw" in the three 600-extent plane cells)
 #: and plane_strip = the rows ``S`` of that plane
 #: its passes evaluate their kernel over at a time -- a loop over the plane's
 #: strips inside a grid step, the planes held as tiles whose next row is the
 #: next tile (a y shift an address and no rotate), a value of the kernel ``S /
 #: 8 x Zw / 128`` vregs and not a whole plane's -- on the two aligned windows
-#: and for a kernel of ``_STRIP_MIN_OPS`` or more operations a cell, 0 = the
-#: kernel runs over the plane whole (``ops/stream_pass.plane_strip_rows`` and
-#: ``ops/stream_plan.plan_plane_stages``, read off the window, the plane and
-#: the kernels' traces: 16 in both ``astaroth-mhd-256`` cells, 0 in the three
-#: 600-extent plane cells) and tile_rows / y_tiles = the rows of the Y TILES its
-#: pipeline moves of a plane, and how many a plane is, where a pass that cannot
-#: be cut further fits VMEM with whole planes in no form -- the planes the
-#: kernel reads are then whole in VMEM scratch only, ``ops/stream_pass.
-#: stream_plane_pass_tiled``; 0 and 1 = the passes move whole planes, every
-#: cell but ``lbm-d3q19-512.bulk`` (128 and 4; ``ops/stream_plan.
-#: plan_plane_passes``, read off the one VMEM model); a
+#: and for a kernel of ``_STRIP_MIN_OPS`` or more operations a cell -- or a
+#: lighter one whose whole planes fit no pass, so that it moves y tiles --, 0 =
+#: the kernel runs over the plane whole (``ops/stream_pass.plane_strip_rows``
+#: and ``ops/stream_plan.plan_plane_stages``, read off the window, the plane
+#: and the kernels' traces: 16 in both ``astaroth-mhd-256`` cells, 8 in both
+#: ``lbm-d3q19-512`` cells, 0 in the three 600-extent plane cells) and
+#: tile_rows / y_tiles = the rows of the Y TILES its pipeline moves of a plane,
+#: and how many a plane is, where a pass that cannot be cut further fits VMEM
+#: with whole planes in no form -- the planes the kernel reads are then whole
+#: in VMEM scratch only, ``ops/stream_pass.stream_plane_pass_tiled``, on either
+#: aligned window (beside a split y the two ends of a plane's tiles are the
+#: block's own halo rows, a neighbour's cells, and no wrap); 0 and 1 = the
+#: passes move whole planes, every cell but ``lbm-d3q19-512.bulk`` and
+#: ``lbm-d3q19-512x4.bulk`` (128 and 4; ``ops/stream_plan.plan_plane_passes``,
+#: read off the one VMEM model); a
 #: z-slab wavefront step (``Jacobi3D``'s z-ring and
 #: lane-padded shell kernels, the stream engine's wavefront route with
 #: ``z_slabs``) adds z_halo_patch = where its kernel patches the z halo into

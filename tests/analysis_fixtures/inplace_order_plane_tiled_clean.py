@@ -10,7 +10,10 @@ and flushed at x step ``j + 2``.  Where a plane index is clamped the maps stand
 still -- the out map through x steps ``0 .. 1`` (nothing is flushed onto plane 0
 before its own tiles come), the in maps past plane ``X - 1`` (nothing is
 refetched from a plane the pass has begun to overwrite) -- which is what the
-contract would catch a tiled map without."""
+contract would catch a tiled map without.  ``build(split_y=True)`` is the same
+pass beside a split y (ISSUE 53: the fills are the z self-wrap alone, y tiles of
+16 rows -- a y tile holds the ``lo.y + hi.y`` tiles its margins are cut from):
+the maps are the same functions, and the contract judges them the same."""
 
 import jax
 import jax.numpy as jnp
@@ -31,15 +34,17 @@ def _kernel(views, info):
     }
 
 
-def build():
+def build(split_y: bool = False):
     fills = tuple(
-        (axis, d, s, R) for axis in (1, 2) for d, s in ((0, N[axis]), (R + N[axis], R))
+        (axis, d, s, R) for axis in ((2,) if split_y else (1, 2))
+        for d, s in ((0, N[axis]), (R + N[axis], R))
     )
+    rows = 16 if split_y else 8
 
     def step(origin, u, c):
         return stream_plane_pass_tiled(
             _kernel, ["u", "c"], [u, c], Dim3(R, R, R), Dim3(R, R, R), R,
-            origin, Dim3(*N), tile_rows=8, strip=8, alias=True, interpret=True,
+            origin, Dim3(*N), tile_rows=rows, strip=8, alias=True, interpret=True,
             halo_readers=("u", "c"), writers=("u", "c"), rings=("u",), wrap_fills=fills,
         )
 

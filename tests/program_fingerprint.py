@@ -262,6 +262,35 @@ def _astaroth_mhd_x4():
     return _trace_step(s.dd, s._step)
 
 
+def _lbm_x4():
+    import jax
+
+    from stencil_tpu.models.lbm import LatticeBoltzmann
+
+    # on mesh [2,2,1] as the cell, at a shard that IS the cell's program in small
+    # (ISSUE 53): 4 x 32 x 256 -- an interior of whole tiles beside a split y --
+    # under a VMEM budget that, like 104.9 MB at 512 x 512, holds the nineteen
+    # planes in y tiles only (16 rows, two a plane; tests/test_lbm.py has the
+    # arithmetic), so the pass is the tiled one on the "interior-z" window
+    was = os.environ.get("STENCIL_VMEM_LIMIT_BYTES")
+    os.environ["STENCIL_VMEM_LIMIT_BYTES"] = "9291456"
+    try:
+        s = LatticeBoltzmann(8, 64, 256, interpret=True, devices=jax.devices()[:4], seed_words=None)
+        s.dd.set_partition(2, 2, 1)
+        s.realize()
+        args = s._step._span_args()
+        assert (args["route"], args["wired"], args["wrapped"], args["wired_edges"]) == (
+            "plane", "xy", "z", "xy"), args
+        assert (args["plane_window"], args["tile_rows"], args["y_tiles"], args["aliased"]) == (
+            "interior-z", 16, 2, 19), args
+        return _trace_step(s.dd, s._step)
+    finally:
+        if was is None:
+            del os.environ["STENCIL_VMEM_LIMIT_BYTES"]
+        else:
+            os.environ["STENCIL_VMEM_LIMIT_BYTES"] = was
+
+
 #: label -> builder of the ClosedJaxpr, at a CPU size under interpret
 MODEL_PROGRAMS = {
     "model:jacobi3d-512/wrap": _jacobi_wrap,
@@ -274,6 +303,7 @@ MODEL_PROGRAMS = {
     "model:lbm-d3q19-256/wrap-m2": _lbm,
     "model:astaroth-mhd-256/plane-r3": _astaroth_mhd,
     "model:astaroth-mhd-256x4/plane-r3": _astaroth_mhd_x4,
+    "model:lbm-d3q19-512x4/plane-y-tiles": _lbm_x4,
 }
 
 

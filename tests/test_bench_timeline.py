@@ -158,8 +158,20 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
         assert m["reducer"] in ("named_share", "named_roofline_hbm", "span_percentile", "span_count"), name
         assert m["cells"] == ["lbm-d3q19-512.bulk"] == declared[name]["workloads"], name
         assert m["moves"] == "mcells_per_s_chip", name
-    for name in (set(declared) - new - plane - staged - setup - wired - lbm - lbm512 - mhd - mhdx4 - wires
-                 - (ragged - {"collective_pct.ragged"})):
+    # PR 53's: the four-chip lattice-Boltzmann cell's shares, the .lbm512 and .mhdx4 readers
+    # under a suffix of its own (tests/test_bench_lbm512x4.py holds them)
+    lbm512x4 = {n for n in declared if n.endswith(".lbm512x4")}
+    assert len(lbm512x4) == 11
+    for name in lbm512x4:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["reducer"] in ("trace_share", "named_share", "named_roofline_hbm", "span_percentile",
+                                "span_count"), name
+        assert m["cells"] == ["lbm-d3q19-512x4.bulk"] == declared[name]["workloads"], name
+        assert m["moves"] == "mcells_per_s_chip", name
+    lbm512x4 -= {"collective_pct.lbm512x4"}  # a trace_share: it reads opcodes, as PR 24's do
+    for name in (set(declared) - new - plane - staged - setup - wired - lbm - lbm512 - lbm512x4 - mhd - mhdx4
+                 - wires - (ragged - {"collective_pct.ragged"})):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
                                                "trace_roofline_hbm", "trace_idle"), name

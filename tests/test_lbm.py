@@ -4,7 +4,9 @@ cell of all nineteen populations on every route the planner may take and on
 meshes where every diagonal read crosses a shard edge somewhere; an edge halo
 left unfilled comes out wrong; the reference itself conserves mass and
 momentum and damps a shear wave at the viscosity it was given; the plan at the
-benchmark's size; the ``domain.step`` span's account of what the kernel reads."""
+benchmark's size; the ``domain.step`` span's account of what the kernel reads;
+and (ISSUE 53) the same through Y TILES of planes beside a y the mesh splits,
+the form ``lbm-d3q19-512x4`` runs on four chips."""
 
 import json
 import os
@@ -47,6 +49,28 @@ def _shared(mesh=(1, 1, 1), path=None):
     if (mesh, path) not in _SIMS:
         _SIMS[mesh, path] = _sim(mesh=mesh, path=path)
     return _SIMS[mesh, path]
+
+
+#: a VMEM budget that the 16-row y tile of a 32 x 256 plane of nineteen just
+#: fits (``plane_pass_vmem_bytes(y_tiles=2)``; tests/test_plane_tiles.py
+#: ``_tiled_bytes(32, 256, 16)``): no whole-plane pass, no larger tile
+_TILED_BUDGET = 9_291_456
+
+
+def _tiled_sim(mesh, monkeypatch):
+    """A model whose shards are 4 x 32 x 256 -- an interior of whole vector tiles
+    beside a y the mesh splits -- under ``_TILED_BUDGET``: the planner answers
+    with two y tiles a plane on the ``"interior-z"`` window."""
+    monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+    monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(_TILED_BUDGET))
+    sim = LatticeBoltzmann(4 * mesh[0], 32 * mesh[1], 256, interpret=True, seed_words=None,
+                           devices=jax.devices()[: int(np.prod(mesh))])
+    sim.dd.set_partition(*mesh)
+    sim.realize()
+    plan = sim._step._stream_plan
+    assert (plan["route"], plan["plane_window"], plan["tile_rows"], plan["y_tiles"]) == (
+        "plane", "interior-z", 16, 2), plan
+    return sim
 
 
 def _random_state(shape, seed):
@@ -97,6 +121,35 @@ def test_model_matches_the_reference_across_devices(mesh, steps):
     assert _worst(sim, ref.steps(sim.setup, state, steps)) < TOL
 
 
+@pytest.mark.parametrize("mesh", [(2, 2, 1), (1, 2, 1)])
+def test_model_through_y_tiles_beside_a_split_y_matches_the_reference(mesh, monkeypatch):
+    """ISSUE 53: planes too large for the (tightened) budget beside a y the mesh
+    splits -- the pass moves y tiles whose two ENDS are the block's own rows, a
+    neighbour's cells that came over the y wire (on [2,2,1] the x-y edge over
+    both).  Seeded random populations, every cell of all nineteen after three
+    steps; and ``domain.step`` says the window, the tile and the wires."""
+    sim = _tiled_sim(mesh, monkeypatch)
+    state = _random_state(sim.setup.shape, 13)
+    _load(sim, state)
+    seen = []
+    real = telemetry.span
+
+    def spy(name, *a, **kw):
+        seen.append((name, kw))
+        return real(name, *a, **kw)
+
+    monkeypatch.setattr(telemetry, "span", spy)
+    sim.step(3)
+    want = ref.steps(sim.setup, state, 3)
+    assert float(np.abs(np.asarray(want[1]) - state[1]).max()) > 1e-3  # (the state moved)
+    assert _worst(sim, want) < TOL
+    assert not sim._step._resilience.descents
+    (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
+    assert (kw["plane_window"], kw["tile_rows"], kw["y_tiles"], kw["aliased"]) == ("interior-z", 16, 2, 19)
+    assert (kw["wired"], kw["wrapped"]) == ("xy" if mesh[0] > 1 else "y", "z")
+    assert (kw["read_sides"], kw["exchanged"], kw["exchanged_sides"]) == (30, 18, 108)
+
+
 @pytest.mark.parametrize("mesh", [(1, 1, 1), (2, 2, 1)])
 def test_the_seeded_state_matches_the_reference(mesh):
     """The seeded Taylor-Green state through ``fill(args=)``: the fills and a
@@ -123,18 +176,20 @@ def test_bf16_storage_fails_the_tolerance():
     assert _worst(sim, want) > 100 * TOL
 
 
-def test_an_unfilled_edge_halo_comes_out_wrong(monkeypatch):
+@pytest.mark.parametrize("tiled", [False, True], ids=["whole-planes", "y-tiles"])
+def test_an_unfilled_edge_halo_comes_out_wrong(tiled, monkeypatch):
     """Mesh [2,2,1]: the y sweep carries the x halo planes along, which is what
     fills the x-y EDGE halo.  With that edge left as the y faces carried it
     (the joint sweep's corner relay taken out: the neighbour's stale halo) --
     every face halo still filled -- the second step's diagonal
-    reads in the xy plane find the first step's cells and come out wrong."""
+    reads in the xy plane find the first step's cells and come out wrong;
+    through y tiles (ISSUE 53) as over whole planes."""
     from stencil_tpu.ops import exchange as ex
 
     # x and y fly jointly on this mesh: the y faces are cut before the x halo
     # is in, and the corner relay is what carries the x halo planes along
     monkeypatch.setattr(ex, "_relay_corners", lambda first, second: second)
-    sim = _sim(mesh=(2, 2, 1))
+    sim = _tiled_sim((2, 2, 1), monkeypatch) if tiled else _sim(mesh=(2, 2, 1))
     state = _random_state(sim.setup.shape, 11)
     _load(sim, state)  # with its shell filled: the FIRST step's edges are right as loaded
     sim.step(2)

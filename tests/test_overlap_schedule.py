@@ -795,3 +795,52 @@ def test_lbm_512_step_runs_in_place_in_y_tiles(monkeypatch):
         assert f"{{{k}}}: ({k + 1}, {{}})" in passes[0], k  # result k IS operand 1 + k
     assert not re.findall(r"=\s+f32\[514,514,514\]\S*\s+copy\(", text)
     assert memory.temp_size_in_bytes == 0 and memory.argument_size_in_bytes == 13_000_499_200 + 0
+
+
+@pytest.mark.slow  # tier-2 with its siblings: a real-TPU-compiler AOT compile
+# at the benchmark's size (10 s)
+def test_lbm_512x4_step_runs_in_place_beside_a_split_y(monkeypatch):
+    """The four-chip lattice-Boltzmann cell's dispatch as the chip's compiler
+    leaves it (ISSUE 53): 1024 x 1024 x 512 on mesh [2,2,1] through the normal
+    planner for a described v5e:2x2 -- ONE ``stream_plane_pass`` custom call of
+    nineteen results, every one aliased onto its own operand (y tiles of 128
+    rows on the ``"interior-z"`` window: Mosaic takes the 101.9 MB the model
+    prices), the joint x-y exchange of the eighteen moving populations as six
+    ``collective-permute``s (four faces, two corner relays) with 36 + 36 blends,
+    no ``copy`` of a block, and beside 13.0 GB of arguments a chip only the
+    messages: nothing of a block's size (0.68 GB) is temporary."""
+    from stencil_tpu.models.lbm import RADIUS, LatticeBoltzmann
+    from stencil_tpu.ops import halo_blend
+    from stencil_tpu.ops import stream as sm
+
+    devices = _topology_devices()
+    monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        sim = LatticeBoltzmann(1024, 1024, 512, devices=devices, seed_words=None)
+        sim.dd.realize(allocate=False)
+        assert tuple(sim.dd.mesh_dim()) == (2, 2, 1)  # the partitioner's own pick
+        plan = sp.plan_stream(sim.dd, RADIUS, "auto", False)
+        plan = sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, plan, False)
+        step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
+        compiled = step.lower(sim.dd.abstract_arrays(), 6).compile()
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    assert (plan["route"], plan["plane_window"], plan["tile_rows"], plan["y_tiles"]) == (
+        "plane", "interior-z", 128, 4), plan
+    assert (plan["wired"], plan["joint"], plan["wired_edges"], plan["wire_bytes"]) == (
+        "xy", "xy", ("xy",), (4 * 18 * 514 * 514 + 2 * 18 * 2 * 514) * 4), plan
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    calls = [l.lstrip() for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
+    passes = [l for l in calls if l.startswith("%stream_plane_pass")]
+    assert len(passes) == 1 and len(calls) == 1 + 36 + 36
+    assert len([l for l in calls if l.startswith("%blend_planes")]) == 36
+    assert len([l for l in calls if l.startswith("%blend_slab")]) == 36
+    assert passes[0].split(" custom-call(")[0].count("f32[514,514,514]") == 19
+    for k in range(19):
+        assert f"{{{k}}}: ({k + 1}, {{}})" in passes[0], k  # result k IS operand 1 + k
+    assert len(re.findall(r"=.*collective-permute-start\(", text)) == 6
+    assert not re.findall(r"=\s+f32\[514,514,514\]\S*\s+copy\(", text)
+    assert memory.argument_size_in_bytes == 13_000_499_200
+    assert memory.temp_size_in_bytes < 514 * 520 * 640 * 4 // 4  # messages: no quarter of a block

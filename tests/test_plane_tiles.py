@@ -5,7 +5,10 @@ form on every raw cell over several tile counts, one among them; the planner
 gives tiles exactly where it used to raise, prices them with the one model and
 allocates what it priced; D3Q19 at 512 x 512 x nineteen lands under the budget;
 the lattice-Boltzmann model through tiles against its plain reference; the
-in-place order of the tiled maps; the forms that keep the refusal say so."""
+in-place order of the tiled maps; the forms that keep the refusal say so.
+Beside a SPLIT y (ISSUE 53: the ``"interior-z"`` window, the rows outside the
+interior a neighbour's) the same pass closes a plane's ends on the block's own
+rows: bitwise the whole-plane carried form, planned where the planner raised."""
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +84,41 @@ def test_the_tiled_pass_is_bitwise_the_whole_plane_pass(r, kw, shell, y_tiles):
     args = (_kernel(r), names, raws, Dim3(*lo), Dim3(*hi), r,
             jnp.asarray([5, 3, 7], jnp.int32), Dim3(64, n[1], n[2]))
     want = spass.stream_plane_pass(*args, window="interior", strip=strip, **common)
+    got = spass.stream_plane_pass_tiled(
+        *args, tile_rows=n[1] // y_tiles, strip=strip, **common)
+    for name, a, b in zip(names, got, want):
+        a, b = (np.asarray(v.astype(jnp.float32)) for v in (a, b))
+        assert np.isfinite(b).all() and np.array_equal(a, b), name
+    assert got[1] is raws[1]
+
+
+@pytest.mark.parametrize("y_tiles", [2, 4])
+@pytest.mark.parametrize("r,kw,shell", _PASS_CASES)
+def test_the_tiled_pass_beside_a_split_y_is_bitwise_the_carried_form(r, kw, shell, y_tiles):
+    """The ``"interior-z"`` window (the fills are the z self-wrap alone: the mesh
+    splits y): the same blocks through ``stream_plane_pass``'s carried strip form
+    and through the tiled pass, EVERY raw cell bitwise equal -- interiors, the y
+    halo rows passed through, the rebuilt z shell, the tail rows, x-shell planes.
+    The blocks' y shells are random, no wrap of their own interior: a ``link``
+    of the last y tile to the first left in, or a tail row taken from the wrong
+    place, shows in the rows beside either end."""
+    dtype = jnp.bfloat16 if kw.get("f32_accumulate") else jnp.float32
+    tile = spass.sublane_tile([dtype])
+    lo, hi = shell
+    # (a y tile holds at least the ``lo.y + hi.y`` tiles its margins are cut from)
+    tiles = 8 if (lo[1] + hi[1]) * y_tiles <= 8 else 24
+    n, names = (5, tiles * tile, 128), ["u", "c", "p"]
+    strip = 2 * tile
+    shape = tuple(m + a + b for m, a, b in zip(n, lo, hi))
+    rng = np.random.default_rng(53)
+    raws = [jnp.asarray(rng.standard_normal(shape), dtype) for _ in names]
+    common = dict(
+        interpret=True, halo_readers=("u", "c"), rings=("u",), writers=("u", "p"),
+        wrap_fills=tuple(f for f in _fills(n, lo, hi) if f[0] == 2), **kw,
+    )
+    args = (_kernel(r), names, raws, Dim3(*lo), Dim3(*hi), r,
+            jnp.asarray([5, 3, 7], jnp.int32), Dim3(64, 2 * n[1], n[2]))
+    want = spass.stream_plane_pass(*args, window="interior-z", strip=strip, **common)
     got = spass.stream_plane_pass_tiled(
         *args, tile_rows=n[1] // y_tiles, strip=strip, **common)
     for name, a, b in zip(names, got, want):
@@ -208,23 +246,52 @@ def test_a_pass_that_fits_whole_planes_is_never_tiled(monkeypatch):
 
 @pytest.mark.parametrize("case,why", [
     ("ragged", r"passes work on the 'raw' window, whole planes\)"),
-    ("split-y", r"passes work on the 'raw' window, whole planes\)"),
     ("fused", r"a pass that carries every quantity whole has no tiled form\)"),
 ])
 def test_the_forms_that_keep_the_refusal_say_so(case, why, monkeypatch):
-    """One form, not three: ragged lanes (the raw window), a y the mesh splits
-    (a light kernel beside it plans over raw planes) and ``halo="fused"`` keep
-    the refusal they had, and its message says that tiles were not on offer."""
+    """One more form, not three: ragged lanes (the raw window) and
+    ``halo="fused"`` keep the refusal they had, and its message says that tiles
+    were not on offer (a y the mesh splits has them since ISSUE 53: the next
+    test)."""
     if case == "ragged":
         sim, request = _lbm((512, 512, 600), monkeypatch), {}
-    elif case == "split-y":
-        sim, request = _lbm((512, 1024, 512), monkeypatch, mesh=(1, 2, 1)), {}
     else:
         sim = _lbm((512,) * 3, monkeypatch)
         request = {"halo": "fused", "halo_forced": True}
         monkeypatch.setattr(sp, "fused_halo_ineligible", lambda *a: None)
     with pytest.raises(sp.FitsNoPass, match=r"fits no pass \(.*" + why):
         _resolve(sim, route="plane", m=1, **request)
+
+
+@pytest.mark.parametrize("shape,mesh,wired", [
+    ((512, 1024, 512), (1, 2, 1), "y"),
+    ((1024, 1024, 512), (2, 2, 1), "xy"),  # FluidX3D's 4-GPU line: lbm-d3q19-512x4
+])
+def test_the_box_beside_a_split_y_plans_in_y_tiles(shape, mesh, wired, monkeypatch):
+    """512^3 a device beside a y the mesh splits, every axis ``auto``: the light
+    kernel's whole raw planes fit no pass, and the planner -- which raised here
+    until ISSUE 53 -- answers with y tiles on the ``"interior-z"`` window, at the
+    one-chip cell's price (``lo.y + hi.y`` margin tiles are its ``2 r``); the
+    legality prefilter and the VMEM verdict take the plan; a tighter budget takes
+    the smaller tile."""
+    sim = _lbm(shape, monkeypatch, mesh=mesh)
+    request = sp.plan_stream(sim.dd, RADIUS, "auto", False)
+    assert (request["route"], request["m"]) == ("plane", 1)
+    plan = sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, request, True)
+    (p,) = plan["stages"][0]["passes"]
+    assert (plan["plane_window"], plan["plane_strip"], plan["pass_wrap_axes"]) == ("interior-z", 8, "z")
+    assert (plan["tile_rows"], plan["y_tiles"], p["tile_rows"]) == (128, 4, 128)
+    assert 512 % p["tile_rows"] == 0 and p["vmem_bytes"] == _tiled_bytes(512, 512, 128) < sp._vmem_budget()
+    assert plan["alias"] and plan["wired"] == wired and not p["renames"] and not p["prerotated"]
+    args = sm.stream_span_args(plan, RADIUS, 19)
+    assert (args["plane_window"], args["tile_rows"], args["y_tiles"], args["aliased"]) == (
+        "interior-z", 128, 4, 19)
+    assert (args["read_sides"], args["exchanged"], args["exchanged_sides"]) == (30, 18, 108)
+    assert analysis.check_vmem(sim.dd, plan.plan) is None
+    monkeypatch.setattr("stencil_tpu.analysis.kernels._mosaic_target", lambda: False)
+    assert analysis.check_kernel_legal(sim.dd, dict(plan.plan)) is None
+    monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(_tiled_bytes(512, 512, 64)))
+    assert _resolve(sim)["tile_rows"] == 64
 
 
 def test_the_legality_prefilter_reads_the_tile_from_the_plan(monkeypatch):
@@ -309,7 +376,8 @@ def test_the_model_through_y_tiles_matches_the_reference(rows, monkeypatch):
 # --- in place -----------------------------------------------------------------------------
 
 
-def test_the_inplace_order_contract_judges_the_tiled_maps(monkeypatch):
+@pytest.mark.parametrize("split_y", [False, True], ids=["interior", "split-y"])
+def test_the_inplace_order_contract_judges_the_tiled_maps(split_y, monkeypatch):
     """``check_inplace_order`` on the traced tiled pass, in place: its two aliased
     pairs are in order as built; with the maps NOT standing still where a plane
     index is clamped -- the out map cycling over plane 0's tiles before their own
@@ -325,15 +393,16 @@ def test_the_inplace_order_contract_judges_the_tiled_maps(monkeypatch):
     spec = importlib.util.spec_from_file_location("tiled_fixture", path)
     fixture = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(fixture)
-    art = fixture.build()
+    art = fixture.build(split_y)
     (rep,) = kernels.kernel_reports(art.closed)
     assert not rep.parallel_dims and {o: a.index for o, a in rep.aliases.items()} == {0: 1, 1: 2}
-    assert len(rep.outputs[0].footprint) == (8 + 2) * (32 // 8 + 1)  # every grid step judged
+    rows = 16 if split_y else 8
+    assert len(rep.outputs[0].footprint) == (8 + 2) * (32 // rows + 1)  # every grid step judged
     assert not kernels.check_inplace_order(art)
     kernels.reset_report_cache()
     real = jnp.where
     monkeypatch.setattr(spass.jnp, "where", lambda cond, a, b: a if np.ndim(a) == 0 else real(cond, a, b))
-    found = kernels.check_inplace_order(fixture.build())
+    found = kernels.check_inplace_order(fixture.build(split_y))
     assert found and "in place the kernel reads its own result" in found[0]
 
 

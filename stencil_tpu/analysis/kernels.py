@@ -699,7 +699,9 @@ def check_kernel_legal(dd, plan: dict) -> Optional[str]:
     planes too large for VMEM ``(1, tile_rows, Z)`` Y TILES of them, which
     the resolved plan names (``plan["stages"]``): several windows along the
     sublane dim, so a ``tile_rows`` that is not whole sublane tiles of the
-    stored dtype is refused here).
+    stored dtype is refused here; with ``plan["plane_lanes"]`` "window" the
+    ``(1, tile_rows, Zw)`` blocks on the side of a call that faces another
+    call of the dispatch too).
     """
     route = plan.get("route")
     if route not in ("wrap", "wavefront", "plane"):
@@ -744,6 +746,11 @@ def check_kernel_legal(dd, plan: dict) -> Optional[str]:
                 p["tile_rows"] or raw.y for st in plan["stages"] for p in st["passes"]
             })
         ]
+        if plan.get("plane_lanes") == "window":
+            # ... and towards another call of the dispatch the aligned window's
+            # lane tiles alone, the lane dim in two windows (plane_lanes_form)
+            zw = dd.local_spec().sz.z
+            layouts += [((1, block[1], zw), array) for block, array in layouts]
     if plan.get("z_slabs"):
         from stencil_tpu.ops.stream_pass import lane_pad_width
 

@@ -69,6 +69,7 @@ def main(argv=None) -> int:
         f"read_sides={plan.get('read_sides')} exchanged_sides={plan.get('exchanged_sides')} "
         f"aliased={plan.get('aliased')} tile_rows={plan.get('tile_rows', 0)} "
         f"y_tiles={plan.get('y_tiles', 1)} plane_window={plan.get('plane_window')!r} "
+        f"plane_lanes={plan.get('plane_lanes')!r} "
         f"wired={plan.get('wired', '')!r} wire_bytes={plan.get('wire_bytes', 0)}",
         file=sys.stderr,
     )

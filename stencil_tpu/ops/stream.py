@@ -399,9 +399,10 @@ def _build_plane_step(g, stages, x_radius, plan):
 
     def plane_passes(k, bs, origin, fused_bufs=None, lo=lo, hi=hi,
                      alias=in_place,
-                     scope=partial(telemetry.annotate, tm.SPAN_STEP_PASS)):
+                     scope=partial(telemetry.annotate, tm.SPAN_STEP_PASS), **lanes):
         """Stage ``k``'s passes in order, each over the quantities it
-        touches; a later pass sees what an earlier one wrote."""
+        touches; a later pass sees what an earlier one wrote.  ``lanes`` is
+        the tiled pass's ``shell_in`` / ``shell_out`` (``per_shard``)."""
         out = list(bs)
         for pass_kernel, reads, rings, writes, renames, prerotated, tile_rows in plan.stage_runs[k]:
             grp = [index[name] for name in reads]
@@ -415,7 +416,7 @@ def _build_plane_step(g, stages, x_radius, plan):
                     pass_kernel, reads, [out[q] for q in grp], lo, hi, x_radius, origin, g.gsize,
                 )
                 if tile_rows:  # planes that fit VMEM in y tiles only
-                    outs = stream_plane_pass_tiled(*args, tile_rows=tile_rows, **shared)
+                    outs = stream_plane_pass_tiled(*args, tile_rows=tile_rows, **lanes, **shared)
                 else:
                     outs = stream_plane_pass(
                         *args, fused_shell=_group_bufs(fused_bufs, grp), renames=renames,
@@ -468,8 +469,8 @@ def _build_plane_step(g, stages, x_radius, plan):
 
     else:
 
-        def stage(k, bs, origin):
-            return plane_passes(k, exchange_readers(bs, k), origin)
+        def stage(k, bs, origin, **lanes):
+            return plane_passes(k, exchange_readers(bs, k), origin, **lanes)
 
     # A renaming pass hands its blocks on PERMUTED, and a loop body that
     # returns its carry permuted makes XLA copy whole arrays to put each
@@ -487,13 +488,34 @@ def _build_plane_step(g, stages, x_radius, plan):
     period = plan.period
 
     def per_shard(steps, *blocks):
-        def one(bs):
+        def one(bs, **lanes):
             origin = _origin_of(g)
             bs = list(bs)
             for k in range(len(stages)):
                 with stage_scope(k):
-                    bs = stage(k, bs, origin)
+                    bs = stage(k, bs, origin, **lanes)
             return tuple(bs)
+
+        if plan["plane_lanes"] == "window" and steps >= 2:
+            # the lane tile behind the aligned window moves one way a call
+            # (``stream_plan.plane_lanes_form``): the dispatch's first call reads
+            # whole raw planes and makes the fills -- the step still assumes
+            # nothing of the halo at entry -- and writes the window's lane tiles
+            # alone; every later call reads those alone and writes whole planes,
+            # the z shell rebuilt, so the last leaves every raw cell as whole
+            # calls do.  TWO forms, not three (first / narrow both ways between
+            # / last): each traced form of the pass and each traced exchange is
+            # set-up time, and with a third the one-chip cell read ``setup_s``
+            # +26.5% against a bound of 25% (PERF.md §6, PR 54).  The x wrap and
+            # a mesh's wires go on moving whole planes and rows: after the first
+            # call the stale shell lanes ride along and nobody reads them.  A
+            # dispatch of ONE call takes today's form.  Only the default
+            # schedule's ``stage`` takes the forms (fused side buffers and split
+            # exterior bands would read the stale lanes)
+            assert period == 1 and plan["halo"] != "fused" and plan["overlap"] != "split", (
+                plan["steps_per_trip"], plan["halo"], plan["overlap"])
+            bs = one(tuple(blocks), shell_out=False)
+            return lax.fori_loop(0, steps - 1, lambda _, b: one(b, shell_in=False), bs)
 
         def body(_, bs):
             for _ in range(period):
@@ -1015,6 +1037,12 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         # the passes move whole planes (plan_plane_passes)
         args["tile_rows"] = plan["tile_rows"]
         args["y_tiles"] = plan["y_tiles"]
+        # ... and the lane tiles of a plane they move towards another call of
+        # the dispatch: "window" = the aligned window's alone, the z shell
+        # read by the dispatch's first call and written by every later one
+        # (the span's ``steps`` says how many calls: one step is one whole
+        # call), "raw" = whole raw planes both ways (plane_lanes_form)
+        args["plane_lanes"] = plan["plane_lanes"]
     if "z_halo_patch" in plan:
         # the z-slab wavefront: whether the pass patches its z halo in
         # the lane tiles that hold it or over the whole plane

@@ -1121,6 +1121,8 @@ def stream_plane_pass_tiled(
     writers: Optional[Sequence[str]] = None,
     rings: Optional[Sequence[str]] = None,
     wrap_fills: Sequence[Tuple[int, int, int, int]] = (),
+    shell_in: bool = True,
+    shell_out: bool = True,
 ) -> List[jax.Array]:
     """``stream_plane_pass`` on one of its two ALIGNED windows in the strip
     form, for planes whose pipeline blocks do not fit VMEM whole: the pipeline
@@ -1185,7 +1187,24 @@ def stream_plane_pass_tiled(
     refetched after it was overwritten (``check_inplace_order`` judges the
     maps, which are the same on both windows).  Not built: ``fused_shell``,
     ``renames``, ``prerotated``, the raw window -- ragged lanes or rows, a z the
-    mesh splits -- (``plan_plane_passes`` does not tile those)."""
+    mesh splits -- (``plan_plane_passes`` does not tile those).
+
+    The lanes behind the window.  Lanes ``[Zw, Z)`` of a raw row hold copies
+    of the window's first lanes: this pass leaves them behind every row it
+    stores, and only its own low z fill reads them back -- which after a call
+    of this same pass changes nothing, lane 0 was computed as a cell of the
+    window.  So a call BETWEEN two calls of one dispatch neither needs nor owes
+    them, and of a 514-lane f32 row they are a fifth (8,128) tile with two live
+    lanes.  ``shell_in=False``: the in blocks are ``(1, Yt, Zw)``, the aligned
+    lane tiles alone, and nothing fills the low z halo (the caller says every
+    block's lane 0 is already the window's cell: each was last written by this
+    pass); ``shell_out=False`` (in place only): the out blocks are ``(1, Yt,
+    Zw)`` and no z shell is rebuilt -- lanes ``[Zw, Z)`` of the aliased block
+    keep what they held, stale.  Both true is the program as it was.  The maps,
+    the grid and everything of y are the same in every form (``ops/stream.py
+    _build_plane_step`` runs a dispatch's first call ``(True, False)`` and
+    every later one ``(False, True)``; ``stream_plan.plane_lanes_form`` says
+    where)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1195,6 +1214,7 @@ def stream_plane_pass_tiled(
     dtypes = [b.dtype for b in raws]
     window = plane_window_form(wrap_fills, lo, hi, (Y, Z), dtypes)
     assert window in ("interior", "interior-z"), (window, wrap_fills, lo, hi, (Y, Z))
+    assert shell_out or alias, "the lanes a narrow call does not write are the aliased block's"
     carried = window == "interior-z"  # the y halo rows are a neighbour's
     Yw, Zw = Y - lo.y - hi.y, Z - lo.z - hi.z
     T = sublane_tile(dtypes)
@@ -1240,48 +1260,62 @@ def stream_plane_pass_tiled(
             for m in range(below):
                 ref[slot, after * KT + m, 0:1] = ref[slot, before * KT + Kt + m, T - 1 : T]
 
-        def land(q, y):
-            """Y tile ``y`` of the fetched plane of ``q`` into its slot, as tiles
-            between its margins."""
-            ref, block, slot = held[q], in_refs[q], i % depth[q]
+        def land(qs, y):
+            """Y tile ``y`` of the fetched planes of the quantities ``qs`` into
+            their slots, as tiles between their margins (one region a
+            condition, every quantity inside it: a region a quantity is
+            nineteen times the trace); ``qs`` share a ring depth."""
+            slot = i % depth[qs[0]]
             if not carried:
 
                 @pl.when(y == 0)  # the low y halo: the block's own tail rows
                 def _():
-                    block[0, : lo.y, :Zw] = stash_in[q][: lo.y]
+                    for q in qs:
+                        in_refs[q][0, : lo.y, :Zw] = stash_in[q][: lo.y]
 
-            own = jnp.swapaxes(block[0, :, :Zw].reshape(T, Kt, Zw), 0, 1)  # (Kt, T, Zw)
-            ref[slot, pl.ds(y * KT + below, Kt)] = own
-            if below:
-                ref[slot, pl.ds(y * KT, below)] = roll(own[Kt - below :], 1, 1).astype(ref.dtype)
-            ref[slot, pl.ds(y * KT + below + Kt, above)] = (
-                roll(own[:above], -1, 1).astype(ref.dtype))
+            for q in qs:
+                ref = held[q]
+                own = jnp.swapaxes(in_refs[q][0, :, :Zw].reshape(T, Kt, Zw), 0, 1)  # (Kt, T, Zw)
+                ref[slot, pl.ds(y * KT + below, Kt)] = own
+                if below:
+                    ref[slot, pl.ds(y * KT, below)] = (
+                        roll(own[Kt - below :], 1, 1).astype(ref.dtype))
+                ref[slot, pl.ds(y * KT + below + Kt, above)] = (
+                    roll(own[:above], -1, 1).astype(ref.dtype))
             if NT > 1:
 
                 @pl.when(y >= 1)
                 def _():
-                    link(ref, slot, y - 1, y)
+                    for q in qs:
+                        link(held[q], slot, y - 1, y)
 
             @pl.when(y == NT - 1)
             def _():
-                if carried:  # the plane goes on into the block's tail rows
-                    for m in range(M):
-                        ref[slot, (NT - 1) * KT + Kt + m, T - 1 : T] = stash_in[q][m : m + 1]
-                else:  # the periodic wrap: the first tile follows the last
-                    link(ref, slot, NT - 1, 0)
+                for q in qs:
+                    if carried:  # the plane goes on into the block's tail rows
+                        for m in range(M):
+                            held[q][slot, (NT - 1) * KT + Kt + m, T - 1 : T] = (
+                                stash_in[q][m : m + 1])
+                    else:  # the periodic wrap: the first tile follows the last
+                        link(held[q], slot, NT - 1, 0)
 
-        for q in range(nq):
-            _wrap_fill(in_refs[q], low_z)  # every row of the tile, the tail's too
+        if shell_in:
+            for q in range(nq):
+                _wrap_fill(in_refs[q], low_z)  # every row of the tile, the tail's too
+        for lag, qs in ((0, ringed), (r, [q for q in range(nq) if q not in ringed])):
+            if not qs:
+                continue
 
-            @pl.when(i <= X - 1 + (0 if q in ringed else r))  # (not a clamped refetch)
-            def _(q=q):
+            @pl.when(i <= X - 1 + lag)  # (not a clamped refetch)
+            def _(qs=qs):
                 @pl.when(t == 0)
                 def _():
-                    stash_in[q][...] = in_refs[q][0, :T, :Zw]
+                    for q in qs:
+                        stash_in[q][...] = in_refs[q][0, :T, :Zw]
 
                 @pl.when(t >= 1)
                 def _():
-                    land(q, t - 1)
+                    land(qs, t - 1)
 
         def from_tiles(d):  # a y tile's ``Kt`` tiles as its ``Yt`` rows
             return jnp.swapaxes(d, 0, 1).reshape(Yt, Zw)
@@ -1293,7 +1327,8 @@ def stream_plane_pass_tiled(
             out[0, :rows, :Zw] = v
             if patch is not None:
                 patch()
-            out[0, :rows, Zw:] = out[0, :rows, : Z - Zw]
+            if shell_out:
+                out[0, :rows, Zw:] = out[0, :rows, : Z - Zw]
 
         def slot_of(q, dx=0):
             """Where plane ``j + dx`` of ``q`` sits: a ringed plane ``p`` landed in
@@ -1305,28 +1340,31 @@ def stream_plane_pass_tiled(
             ``land`` put it."""
             return held[q][slot_of(q), (NT - 1) * KT + Kt + m, T - 1 : T]
 
-        def put_carried(out, q):
-            """Beside a split y: the staged tiles of writer ``q`` -- tiles ``[lo.y,
+        def put_carried():
+            """Beside a split y: the staged tiles of every writer -- tiles ``[lo.y,
             lo.y + Kt)`` of y tile ``t`` -- into output block ``t``, one sublane
             DOWN as ``stream_plane_pass``'s ``put_carried`` has it.  The block's
             rows ``[0, lo.y)`` are what the y tile BEFORE staged last (stashed) --
             before the first, the low y halo rows of the centre plane, passed
             through --, and what falls off this tile's last sublane waits in the
             stash for the next block."""
-            staged, stash = stage[q], stash_out[q]
-            down = roll(staged[Kt - lo.y :], 1, 1).astype(staged.dtype)  # (lo.y, T, Zw)
 
             @pl.when(t == 0)
             def _():
-                for m in range(lo.y):
-                    stash[m : m + 1] = held[q][slot_of(q), m, 0:1]
+                for q in out_refs:
+                    for m in range(lo.y):
+                        stash_out[q][m : m + 1] = held[q][slot_of(q), m, 0:1]
 
-            def patch():
-                for m in range(lo.y):
-                    out[0, m : m + 1, :Zw] = stash[m : m + 1]
-                    stash[m : m + 1] = staged[Kt - lo.y + m, T - 1 : T]
+            for q, out in out_refs.items():
+                staged, stash = stage[q], stash_out[q]
+                down = roll(staged[Kt - lo.y :], 1, 1).astype(staged.dtype)  # (lo.y, T, Zw)
 
-            put(out, from_tiles(jnp.concatenate([down, staged[: Kt - lo.y]], axis=0)), Yt, patch)
+                def patch():  # (``put`` calls it at once)
+                    for m in range(lo.y):
+                        out[0, m : m + 1, :Zw] = stash[m : m + 1]
+                        stash[m : m + 1] = staged[Kt - lo.y + m, T - 1 : T]
+
+                put(out, from_tiles(jnp.concatenate([down, staged[: Kt - lo.y]], axis=0)), Yt, patch)
 
         def strips(y):
             """Y tile ``y`` of the output plane, a strip at a time into the
@@ -1386,44 +1424,45 @@ def stream_plane_pass_tiled(
         def _():
             in_window = interior_plane()
 
+            # (one region a condition, every writer inside it: see ``land``)
             @pl.when(in_window)
             def _():
                 strips(t)
-
-            for q, out in out_refs.items():
-
-                @pl.when(in_window)
-                def _(q=q, out=out):
-                    if carried:
-                        put_carried(out, q)
-                    else:
+                if carried:
+                    put_carried()
+                else:
+                    for q, out in out_refs.items():
                         put(out, from_tiles(stage[q][...]), Yt)
 
-                @pl.when(jnp.logical_not(in_window))  # an x-shell plane passes through
-                def _(q=q, out=out):
+            @pl.when(jnp.logical_not(in_window))  # an x-shell plane passes through
+            def _():
+                for q, out in out_refs.items():
                     put(out, from_tiles(held[q][slot_of(q), pl.ds(t * KT + below, Kt)]), Yt)
 
-                if not carried:
+            if not carried:
 
-                    @pl.when(t == 0)  # the stored plane's first rows: its tail rows too
-                    def _(q=q, out=out):
+                @pl.when(t == 0)  # the stored plane's first rows: its tail rows too
+                def _():
+                    for q, out in out_refs.items():
                         stash_out[q][...] = out[0, :T, :Zw]
 
         @pl.when(jnp.logical_and(i >= r + 1, t == NT))
         def _():
-            for q, out in out_refs.items():
-                if carried:
-                    # the stash holds the last y tile's last ``lo.y`` rows; the high
-                    # y halo rows, and every tail row of an x-shell plane, pass
-                    # through from the centre plane
+            if carried:
+                # the stash holds the last y tile's last ``lo.y`` rows; the high
+                # y halo rows, and every tail row of an x-shell plane, pass
+                # through from the centre plane
+                for q in out_refs:
                     for m in range(lo.y, M):
                         stash_out[q][m : m + 1] = tail_of_centre(q, m)
 
-                    @pl.when(jnp.logical_not(interior_plane()))
-                    def _(q=q):
+                @pl.when(jnp.logical_not(interior_plane()))
+                def _():
+                    for q in out_refs:
                         for m in range(lo.y):
                             stash_out[q][m : m + 1] = tail_of_centre(q, m)
 
+            for q, out in out_refs.items():
                 put(out, stash_out[q][...], T)
 
     def in_map(lag):
@@ -1446,9 +1485,10 @@ def stream_plane_pass_tiled(
         name=tm.KERNEL_STREAM_PLANE_PASS,
         grid=(X + r + 1, NT + 1),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [
-            pl.BlockSpec((1, Yt, Z), in_map(0 if q in ringed else r)) for q in range(nq)
+            pl.BlockSpec((1, Yt, Z if shell_in else Zw), in_map(0 if q in ringed else r))
+            for q in range(nq)
         ],
-        out_specs=tuple(pl.BlockSpec((1, Yt, Z), out_map) for _ in wq),
+        out_specs=tuple(pl.BlockSpec((1, Yt, Z if shell_out else Zw), out_map) for _ in wq),
         out_shape=tuple(jax.ShapeDtypeStruct((X, Y, Z), raws[q].dtype) for q in wq),
         input_output_aliases={1 + q: k for k, q in enumerate(wq)} if alias else {},
         scratch_shapes=[pltpu.VMEM((depth[q], NT * KT, T, Zw), raws[q].dtype) for q in range(nq)]

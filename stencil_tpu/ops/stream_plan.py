@@ -33,7 +33,10 @@ Routes (``plan_stream`` chooses; ``ops/stream_pass.py`` has the passes):
   ``stream_pass.stream_plane_pass_tiled``): the strip form on either aligned
   window -- a light kernel falls to it too --, and only where the planner
   would otherwise raise; the raw window (ragged lanes or rows, a split z) and
-  ``halo="fused"`` keep that refusal.
+  ``halo="fused"`` keep that refusal.  Inside a DISPATCH such a pass moves
+  the lane tile behind the aligned window one way a call, where the step is
+  that one pass, in place, writing all it reads (``plane_lanes``,
+  ``plane_lanes_form``).
 * **wavefront** — ``m`` levels per pass over an ``s``-wide-shell shard
   (``m <= s // r``, ``r == 1`` only), plain or in the z-slab form.
 * **wrap** — a single subdomain, the periodic boundary folded into the pass.
@@ -1330,6 +1333,46 @@ def wrap_edge_form(dd, plan: Mapping) -> str:
     return "raw" if est + wider + margin <= _vmem_budget() else "xla"
 
 
+def plane_lanes_form(plan: Mapping) -> str:
+    """Which lane tiles of a raw plane the passes of a plane-route DISPATCH
+    move on the side that faces another call of it: ``"window"`` = the aligned
+    working window ``[0, Zw)`` alone -- the dispatch's first call reads whole
+    raw planes and makes today's fills but writes the window's lane tiles
+    alone, every later call reads those alone, fills nothing, and writes
+    whole planes with the z shell rebuilt (``stream_plane_pass_tiled(
+    shell_in=, shell_out=)``, ``ops/stream.py _build_plane_step``) --,
+    ``"raw"`` = every call moves whole raw planes both ways.  On either
+    aligned window the lanes ``[Zw, Z)`` hold copies of the window's first
+    lanes that a call of the pass leaves behind and only its own low z fill
+    reads back: between two calls of one dispatch they carry nothing, and of
+    a 514-lane f32 plane they are a fifth (8,128) tile of every row the
+    pipeline moves (D3Q19 at 512^3: 26.0 GB a call whole, 23.4 with one side
+    narrow, 20.8 with both -- which would take a third form of the pass, and
+    of the exchange beside it, to trace: set-up time the cells do not have;
+    PERF.md, PR 54).
+
+    Read off the resolved plan alone: ONE pass a step (a later pass of the
+    dispatch's first step would fill its low z halo from lanes an earlier
+    one left stale), moved in y tiles (``tile_rows``: the tiled pass is the
+    one that has the forms; on an aligned window, whose z fills are the
+    pass's own), in place (the lanes a narrow call does not write keep what
+    the aliased block held), writing every quantity it reads (a read-only
+    operand's low z halo is filled nowhere but in the pipeline's buffer), on
+    the default schedule (``halo="fused"`` side buffers and the split
+    schedule's exterior bands are cut from whole raw blocks: they would read
+    the stale lanes, and their stages take no forms).  ``domain.step`` says
+    it as ``plane_lanes``; no option."""
+    passes = [p for st in plan["stages"] for p in st["passes"]]
+    if len(passes) != 1 or not passes[0]["tile_rows"]:
+        return "raw"
+    if plan["halo"] == "fused" or plan["overlap"] == "split":
+        return "raw"
+    (p,) = passes
+    aligned = plan["plane_window"] != "raw" and "z" in plan["pass_wrap_axes"]
+    whole = set(p["reads"]) <= set(p["writes"]) and not p["renames"]
+    return "window" if aligned and whole and _plan_passes_in_place(plan) else "raw"
+
+
 def _carry_period(names: Sequence[str], stages) -> int:
     """After how many steps a step loop's carry is back in its own buffers:
     the order of the permutation one step's renames (``plan["stages"]``)
@@ -1521,6 +1564,9 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
             strip=strip,
         )
         plan.update(keys)
+        # ... and the lane tiles of a plane they move between a dispatch's two
+        # edges (domain.step's ``plane_lanes``)
+        plan["plane_lanes"] = plane_lanes_form(plan)
     else:
         plan["halo_readers"] = () if route == "wrap" else tuple(names)
         # what the kernel reads off-centre, as the plane route's planner

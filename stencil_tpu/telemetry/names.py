@@ -519,7 +519,16 @@ ALL_HISTOGRAMS = frozenset({
 #: block's own halo rows, a neighbour's cells, and no wrap); 0 and 1 = the
 #: passes move whole planes, every cell but ``lbm-d3q19-512.bulk`` and
 #: ``lbm-d3q19-512x4.bulk`` (128 and 4; ``ops/stream_plan.plan_plane_passes``,
-#: read off the one VMEM model); a
+#: read off the one VMEM model) and plane_lanes = the lane tiles of a raw plane
+#: the passes move on the side that faces another call of the dispatch:
+#: "window" = the aligned window's alone -- the dispatch's first call reads
+#: whole raw planes and makes the fills but writes ``(1, Yt, Zw)`` blocks, every
+#: later call reads such blocks and writes whole planes with the z shell
+#: rebuilt (``steps`` says how many calls: a dispatch of ONE step is one whole
+#: call) --, "raw" = whole raw planes both ways every call: every cell but the
+#: two ``lbm-d3q19-512`` ones
+#: (``ops/stream_plan.plane_lanes_form``, read off the resolved plan: one tiled
+#: pass a step, in place, that writes every quantity it reads); a
 #: z-slab wavefront step (``Jacobi3D``'s z-ring and
 #: lane-padded shell kernels, the stream engine's wavefront route with
 #: ``z_slabs``) adds z_halo_patch = where its kernel patches the z halo into

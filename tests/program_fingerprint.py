@@ -262,33 +262,49 @@ def _astaroth_mhd_x4():
     return _trace_step(s.dd, s._step)
 
 
-def _lbm_x4():
+def _lbm_tiled(shape, mesh, budget, said):
+    """A lattice-Boltzmann model under a VMEM budget that, like 104.9 MB at 512 x
+    512, holds the nineteen planes in y tiles only: the pass is the tiled one,
+    and a dispatch of two steps or more moves the lane tile behind the window
+    one way a call (ISSUE 54: ``plane_lanes`` "window" -- two forms of the pass,
+    the first call's raw in / window out, the loop's window in / raw out)."""
     import jax
 
     from stencil_tpu.models.lbm import LatticeBoltzmann
 
-    # on mesh [2,2,1] as the cell, at a shard that IS the cell's program in small
-    # (ISSUE 53): 4 x 32 x 256 -- an interior of whole tiles beside a split y --
-    # under a VMEM budget that, like 104.9 MB at 512 x 512, holds the nineteen
-    # planes in y tiles only (16 rows, two a plane; tests/test_lbm.py has the
-    # arithmetic), so the pass is the tiled one on the "interior-z" window
     was = os.environ.get("STENCIL_VMEM_LIMIT_BYTES")
-    os.environ["STENCIL_VMEM_LIMIT_BYTES"] = "9291456"
+    os.environ["STENCIL_VMEM_LIMIT_BYTES"] = str(budget)
     try:
-        s = LatticeBoltzmann(8, 64, 256, interpret=True, devices=jax.devices()[:4], seed_words=None)
-        s.dd.set_partition(2, 2, 1)
+        s = LatticeBoltzmann(
+            *shape, interpret=True, devices=jax.devices()[: mesh[0] * mesh[1]], seed_words=None)
+        s.dd.set_partition(*mesh)
         s.realize()
         args = s._step._span_args()
-        assert (args["route"], args["wired"], args["wrapped"], args["wired_edges"]) == (
-            "plane", "xy", "z", "xy"), args
+        assert (args["route"], args["wired"], args["wrapped"]) == ("plane",) + said[:2], args
+        assert args["wired_edges"] == said[0], args
         assert (args["plane_window"], args["tile_rows"], args["y_tiles"], args["aliased"]) == (
-            "interior-z", 16, 2, 19), args
+            said[2:] + (2, 19)), args
+        assert args["plane_lanes"] == "window", args
         return _trace_step(s.dd, s._step)
     finally:
         if was is None:
             del os.environ["STENCIL_VMEM_LIMIT_BYTES"]
         else:
             os.environ["STENCIL_VMEM_LIMIT_BYTES"] = was
+
+
+def _lbm_512():
+    # one device, 6 x 64 x 256: y and z the pass's own fills, two y tiles of 32
+    # rows (tests/test_plane_tiles.py ``_tiled_bytes(64, 256, 32)``)
+    return _lbm_tiled((6, 64, 256), (1, 1, 1), 13371072, ("", "yz", "interior", 32))
+
+
+def _lbm_x4():
+    # on mesh [2,2,1] as the cell, at a shard that IS the cell's program in small
+    # (ISSUE 53): 4 x 32 x 256 -- an interior of whole tiles beside a split y --,
+    # 16 rows a y tile, two a plane (tests/test_lbm.py has the arithmetic), so
+    # the pass is the tiled one on the "interior-z" window
+    return _lbm_tiled((8, 64, 256), (2, 2, 1), 9291456, ("xy", "z", "interior-z", 16))
 
 
 #: label -> builder of the ClosedJaxpr, at a CPU size under interpret
@@ -304,6 +320,7 @@ MODEL_PROGRAMS = {
     "model:astaroth-mhd-256/plane-r3": _astaroth_mhd,
     "model:astaroth-mhd-256x4/plane-r3": _astaroth_mhd_x4,
     "model:lbm-d3q19-512x4/plane-y-tiles": _lbm_x4,
+    "model:lbm-d3q19-512/plane-y-tiles": _lbm_512,
 }
 
 

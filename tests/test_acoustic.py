@@ -128,6 +128,7 @@ def test_route_is_plane_and_the_span_says_so(blend, mesh, wrapped, wired, monkey
         "plane_window": "raw",
         "plane_strip": 0,  # ... and their kernel runs over it whole (ISSUE 46)
         "tile_rows": 0, "y_tiles": 1,  # ... and their pipeline moves whole planes (ISSUE 51)
+        "plane_lanes": "raw",  # ... every call of a dispatch (ISSUE 54)
         "wired_edges": "",  # a star reads no edge: nothing crosses two wires in turn (ISSUE 47)
     }
     assert plan["halo_readers"] == ("u",), plan

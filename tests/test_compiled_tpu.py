@@ -640,6 +640,48 @@ def test_compiled_lbm_step_in_y_tiles_is_bitwise_the_wrap_route(budget, y_tiles,
         assert np.array_equal(a, b), (q, float(np.abs(a - b).max()))
 
 
+def test_compiled_lbm_dispatch_with_the_z_shell_at_its_edges_is_bitwise_whole_calls(monkeypatch):
+    """The tiled pass's two edge forms as Mosaic compiles them (ISSUE 54), at 256^3
+    x 19 with the plane in four y tiles (the planner's budget tightened as above):
+    a dispatch whose first call reads whole raw planes and writes ``(1, 64, 256)``
+    blocks of the 258-lane rows, whose later calls read such blocks and write the z
+    shell back, against the same program with every call whole
+    (``plane_lanes_form`` patched to "raw": the parent's) -- EVERY raw cell of all
+    nineteen populations bitwise equal after a dispatch of 6 steps and one of 3
+    behind it, the z shell, the tail rows and the x-halo planes included."""
+    import gc
+
+    from stencil_tpu.models.lbm import LatticeBoltzmann
+    from stencil_tpu.models.lbm_reference import NAMES
+    from stencil_tpu.ops import stream_plan as sp
+
+    monkeypatch.setattr(sp, "_deepest_fit", lambda *a, **k: None)  # no wrap depth: the plane route
+    monkeypatch.setattr(sp, "_vmem_budget", lambda: int(36e6))
+
+    def run():
+        sim = LatticeBoltzmann(256, 256, 256, devices=jax.devices()[:1],
+                               seed_words=(0x1234ABCD, 77, 0xDEADBEEF, 2024))
+        sim.realize()
+        sim.step(6)
+        sim.step(3)
+        plan = dict(sim._step._stream_plan)
+        raws = [np.asarray(sim.dd._curr[q]) for q in NAMES]
+        for slot in (sim.dd._curr, sim.dd._next or {}):
+            for block in slot.values():
+                block.delete()
+        del sim
+        gc.collect()
+        return plan, raws
+
+    plan, got = run()
+    assert (plan["route"], plan["y_tiles"], plan["plane_lanes"]) == ("plane", 4, "window")
+    monkeypatch.setattr(sp, "plane_lanes_form", lambda plan: "raw")
+    plan, want = run()
+    assert (plan["route"], plan["y_tiles"], plan["plane_lanes"]) == ("plane", 4, "raw")
+    for q, a, b in zip(NAMES, got, want):
+        assert a.shape == (258, 258, 258) and np.array_equal(a, b), (q, np.argwhere(a != b)[:4])
+
+
 @pytest.mark.parametrize("shape,window", [
     pytest.param((32, 58, 122), "raw", id="raw-planes-of-whole-tiles"),
     pytest.param((32, 64, 128), "interior", id="interior-of-whole-tiles"),

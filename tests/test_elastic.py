@@ -153,6 +153,7 @@ def test_the_plan_is_staged_and_the_span_says_so():
         "plane_window": "raw",  # 24 cells of interior a side: no whole vector tile (ISSUE 45)
         "plane_strip": 0,  # ... so the kernels run over the plane whole (ISSUE 46)
         "tile_rows": 0, "y_tiles": 1,  # ... and their pipeline moves whole planes (ISSUE 51)
+        "plane_lanes": "raw",  # ... every call of a dispatch (ISSUE 54)
         "wired_edges": "", "wire_bytes_by_stage": "0/0",  # one device: no wire (ISSUE 47)
     }
     seen = []

@@ -763,10 +763,13 @@ def test_lbm_macro_loop_carries_its_blocks_in_place(monkeypatch):
 def test_lbm_512_step_runs_in_place_in_y_tiles(monkeypatch):
     """The card-filling lattice-Boltzmann cell's dispatch as the chip's compiler
     leaves it (ISSUE 51): 512^3 x 19 through the normal planner for a described
-    v5e -- ONE ``stream_plane_pass`` custom call of nineteen results, every one
-    aliased onto its own operand, eighteen ``blend_planes`` x wraps, no ``copy``
+    v5e -- TWO ``stream_plane_pass`` custom calls (ISSUE 54: the dispatch's first
+    call raw in / window out, the loop's window in / raw out, the lane tile behind
+    the window moved one way a call) of nineteen results each, every one aliased
+    onto its own operand, eighteen ``blend_planes`` x wraps a call, no ``copy``
     of a block and NOTHING temporary beside 13.0 GB of arguments: Mosaic takes
-    the 101.9 MB of VMEM the model prices (y tiles of 128 rows)."""
+    the 101.9 MB of VMEM the model prices (y tiles of 128 rows) and the
+    ``(1, 128, 512)`` blocks of a 514-lane array."""
     from stencil_tpu.models.lbm import RADIUS, LatticeBoltzmann
     from stencil_tpu.ops import halo_blend
     from stencil_tpu.ops import stream as sm
@@ -786,13 +789,15 @@ def test_lbm_512_step_runs_in_place_in_y_tiles(monkeypatch):
         jax.config.update("jax_enable_x64", x64_was)
     assert (plan["route"], plan["plane_window"], plan["tile_rows"], plan["y_tiles"]) == (
         "plane", "interior", 128, 4), plan
+    assert plan["plane_lanes"] == "window", plan
     text, memory = compiled.as_text(), compiled.memory_analysis()
     calls = [l.lstrip() for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
     passes = [l for l in calls if l.startswith("%stream_plane_pass")]
-    assert len(passes) == 1 and len([l for l in calls if l.startswith("%blend_planes")]) == 18
-    assert passes[0].split(" custom-call(")[0].count("f32[514,514,514]") == 19
-    for k in range(19):
-        assert f"{{{k}}}: ({k + 1}, {{}})" in passes[0], k  # result k IS operand 1 + k
+    assert len(passes) == 2 and len([l for l in calls if l.startswith("%blend_planes")]) == 2 * 18
+    for call in passes:
+        assert call.split(" custom-call(")[0].count("f32[514,514,514]") == 19
+        for k in range(19):
+            assert f"{{{k}}}: ({k + 1}, {{}})" in call, k  # result k IS operand 1 + k
     assert not re.findall(r"=\s+f32\[514,514,514\]\S*\s+copy\(", text)
     assert memory.temp_size_in_bytes == 0 and memory.argument_size_in_bytes == 13_000_499_200 + 0
 
@@ -802,13 +807,14 @@ def test_lbm_512_step_runs_in_place_in_y_tiles(monkeypatch):
 def test_lbm_512x4_step_runs_in_place_beside_a_split_y(monkeypatch):
     """The four-chip lattice-Boltzmann cell's dispatch as the chip's compiler
     leaves it (ISSUE 53): 1024 x 1024 x 512 on mesh [2,2,1] through the normal
-    planner for a described v5e:2x2 -- ONE ``stream_plane_pass`` custom call of
-    nineteen results, every one aliased onto its own operand (y tiles of 128
+    planner for a described v5e:2x2 -- TWO ``stream_plane_pass`` custom calls
+    (ISSUE 54: the dispatch's first call and the loop's call behind it) of
+    nineteen results each, every one aliased onto its own operand (y tiles of 128
     rows on the ``"interior-z"`` window: Mosaic takes the 101.9 MB the model
-    prices), the joint x-y exchange of the eighteen moving populations as six
-    ``collective-permute``s (four faces, two corner relays) with 36 + 36 blends,
-    no ``copy`` of a block, and beside 13.0 GB of arguments a chip only the
-    messages: nothing of a block's size (0.68 GB) is temporary."""
+    prices), before each the joint x-y exchange of the eighteen moving
+    populations as six ``collective-permute``s (four faces, two corner relays)
+    with 36 + 36 blends, no ``copy`` of a block, and beside 13.0 GB of arguments
+    a chip only the messages: nothing of a block's size (0.68 GB) is temporary."""
     from stencil_tpu.models.lbm import RADIUS, LatticeBoltzmann
     from stencil_tpu.ops import halo_blend
     from stencil_tpu.ops import stream as sm
@@ -833,14 +839,16 @@ def test_lbm_512x4_step_runs_in_place_beside_a_split_y(monkeypatch):
         "xy", "xy", ("xy",), (4 * 18 * 514 * 514 + 2 * 18 * 2 * 514) * 4), plan
     text, memory = compiled.as_text(), compiled.memory_analysis()
     calls = [l.lstrip() for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
+    assert plan["plane_lanes"] == "window", plan
     passes = [l for l in calls if l.startswith("%stream_plane_pass")]
-    assert len(passes) == 1 and len(calls) == 1 + 36 + 36
-    assert len([l for l in calls if l.startswith("%blend_planes")]) == 36
-    assert len([l for l in calls if l.startswith("%blend_slab")]) == 36
-    assert passes[0].split(" custom-call(")[0].count("f32[514,514,514]") == 19
-    for k in range(19):
-        assert f"{{{k}}}: ({k + 1}, {{}})" in passes[0], k  # result k IS operand 1 + k
-    assert len(re.findall(r"=.*collective-permute-start\(", text)) == 6
+    assert len(passes) == 2 and len(calls) == 2 * (1 + 36 + 36)
+    assert len([l for l in calls if l.startswith("%blend_planes")]) == 2 * 36
+    assert len([l for l in calls if l.startswith("%blend_slab")]) == 2 * 36
+    for call in passes:
+        assert call.split(" custom-call(")[0].count("f32[514,514,514]") == 19
+        for k in range(19):
+            assert f"{{{k}}}: ({k + 1}, {{}})" in call, k  # result k IS operand 1 + k
+    assert len(re.findall(r"=.*collective-permute-start\(", text)) == 2 * 6
     assert not re.findall(r"=\s+f32\[514,514,514\]\S*\s+copy\(", text)
     assert memory.argument_size_in_bytes == 13_000_499_200
     assert memory.temp_size_in_bytes < 514 * 520 * 640 * 4 // 4  # messages: no quarter of a block

@@ -10,6 +10,8 @@ Beside a SPLIT y (ISSUE 53: the ``"interior-z"`` window, the rows outside the
 interior a neighbour's) the same pass closes a plane's ends on the block's own
 rows: bitwise the whole-plane carried form, planned where the planner raised."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -419,3 +421,219 @@ def test_a_stage_whose_whole_plane_passes_clash_takes_one_tiled_pass(monkeypatch
     (p,) = plan["stages"][0]["passes"]
     assert (plan["plane_strip"], plan["tile_rows"], plan["y_tiles"]) == (16, 512, 1)
     assert len(p["writes"]) == 19 and p["vmem_bytes"] == _tiled_bytes(512, 512, 512, 2, 16) == 95_700_672
+
+
+# --- the lanes behind the window: a dispatch's two edges (ISSUE 54) ------------------------
+
+#: per window: the realized model, its resolved plan and the two traced programs
+_LANES = {}
+
+
+def _lanes_case(window, monkeypatch):
+    """A model the planner tiles on ``window`` -- one device (6 x 64 x 256, two y
+    tiles of 32 rows) or mesh [2,2,1] on four CPU devices (shards of 4 x 32 x 256,
+    two y tiles of 16 rows beside the split y) --, its resolved plan (``plane_
+    lanes`` "window") and two programs of it, un-donated: the dispatch as built,
+    and the parent's, every call moving whole raw planes."""
+    if window not in _LANES:
+        monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+        mesh, shape, rows = {
+            "interior": ((1, 1, 1), (6, 64, 256), 32),
+            "interior-z": ((2, 2, 1), (8, 64, 256), 16),
+        }[window]
+        monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(_tiled_bytes(shape[1] // mesh[1], 256, rows)))
+        sim = LatticeBoltzmann(*shape, interpret=True, seed_words=None,
+                               devices=jax.devices()[: int(np.prod(mesh))])
+        sim.dd.set_partition(*mesh)
+        sim.realize()
+        plan = _resolve(sim)
+        assert (plan["plane_window"], plan["tile_rows"], plan["y_tiles"]) == (window, rows, 2)
+        assert plan["plane_lanes"] == "window"
+        raw = dataclasses.replace(plan, plan={**plan.plan, "plane_lanes": "raw"})
+        build = lambda p: sm._build_stream_step(  # noqa: E731
+            sim.dd, sim._kernel, RADIUS, p, interpret=True, donate=False)
+        _LANES[window] = (sim, plan, build(plan), build(raw))
+    return _LANES[window]
+
+
+def _seeded_blocks(sim, seed, garbage=False):
+    """The model's raw blocks with seeded random populations in every interior
+    cell; the shell what ``set_quantity`` leaves (zeros), or with ``garbage``
+    large random numbers in every shell cell of every shard."""
+    rng = np.random.default_rng(seed)
+    for name, w in zip(ref.NAMES, ref.W):
+        sim.dd.set_quantity(
+            sim.handles[name], np.float32(w) * rng.uniform(0.6, 1.4, sim.setup.shape).astype(np.float32))
+    blocks = dict(sim.dd._curr)
+    if garbage:
+        n, lo = sim.dd.local_spec().sz, sim.dd.local_spec().radius.lo()
+        raw = sim.dd.local_spec().raw_size()
+        inside = [
+            (np.arange(g) % r >= a) & (np.arange(g) % r < a + m)
+            for g, r, a, m in zip(blocks["f0"].shape, raw, lo, n)
+        ]
+        interior = inside[0][:, None, None] & inside[1][None, :, None] & inside[2][None, None, :]
+        for name, b in blocks.items():
+            junk = rng.uniform(-1e3, 1e3, b.shape).astype(np.float32)
+            blocks[name] = jax.device_put(np.where(interior, np.asarray(b), junk), b.sharding)
+    return blocks
+
+
+def _same_raw_cells(got, want):
+    for name in ref.NAMES:
+        a, b = np.asarray(got[name]), np.asarray(want[name])
+        assert np.isfinite(b).all() and np.array_equal(a, b), (
+            name, np.argwhere(a != b)[:4].tolist())
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3, 6])
+@pytest.mark.parametrize("window", ["interior", "interior-z"])
+def test_a_dispatch_that_carries_the_z_shell_at_its_edges_is_bitwise_whole_calls(
+        window, steps, monkeypatch):
+    """Dispatches of 1, 2, 3 and 6 steps through the dispatch structure -- first
+    call raw in / window out, every later call window in / raw out, the lane tile
+    behind the window moved one way a call -- against the parent's program, every call
+    moving whole raw planes: EVERY raw cell of all nineteen populations bitwise
+    equal after the dispatch, the z shell, the tail rows and the x-halo planes
+    included; on four devices the y halo rows a neighbour sent too."""
+    sim, _, lanes, whole = _lanes_case(window, monkeypatch)
+    blocks = _seeded_blocks(sim, 54 + steps)
+    want = whole(blocks, steps)
+    assert not np.array_equal(np.asarray(want["f1"]), np.asarray(blocks["f1"]))  # (the state moved)
+    _same_raw_cells(lanes(blocks, steps), want)
+
+
+@pytest.mark.parametrize("window", ["interior", "interior-z"])
+def test_the_first_call_of_a_dispatch_assumes_nothing_of_the_shell(window, monkeypatch):
+    """Blocks whose SHELL is garbage at entry, only the interior filled: the
+    dispatch's first call still makes every fill the parent's makes (that is what
+    the narrow calls behind it rest on), so three steps leave every raw cell as
+    whole calls leave it -- and what they leave in the interior does not depend
+    on the garbage."""
+    sim, _, lanes, whole = _lanes_case(window, monkeypatch)
+    blocks = _seeded_blocks(sim, 7, garbage=True)
+    got = lanes(blocks, 3)
+    _same_raw_cells(got, whole(blocks, 3))
+    clean = lanes(_seeded_blocks(sim, 7), 3)
+    for name in ("f0", "f5", "f18"):
+        sim.dd._curr = dict(got)
+        a = sim.field(name)
+        sim.dd._curr = dict(clean)
+        assert np.array_equal(a, sim.field(name)), name
+
+
+def _pass_blocks(closed):
+    """Per ``stream_plane_pass`` call of a traced program (a loop body once),
+    sorted: the last dimension of its in blocks and of its out blocks."""
+    from stencil_tpu.analysis import jaxpr as jx
+
+    said = []
+    for e in jx.iter_eqns(closed):
+        if e.primitive.name != "pallas_call" or "stream_plane_pass" not in str(e.params.get("name")):
+            continue
+        gm = e.params["grid_mapping"]
+        lanes = [
+            int(getattr(bm.block_shape[-1], "block_size", bm.block_shape[-1]))
+            for bm in gm.block_mappings if len(bm.block_shape) == 3
+        ]
+        (lanes_in,), (lanes_out,) = set(lanes[: gm.num_inputs - 1]), set(lanes[gm.num_inputs - 1:])
+        said.append((lanes_in, lanes_out))
+    return sorted(said)
+
+
+@pytest.mark.parametrize("window", ["interior", "interior-z"])
+@pytest.mark.parametrize("steps,forms", [
+    (1, ["ZZ"]), (2, ["ZW", "WZ"]), (3, ["ZW", "WZ"]), (6, ["ZW", "WZ"]),
+])
+def test_a_call_moves_the_lane_tile_behind_the_window_one_way(
+        window, steps, forms, monkeypatch):
+    """Bytes counted, never time: the traced dispatch holds one call a form --
+    the loop body once --: the first call's out blocks and every later call's in
+    blocks end in ``Zw`` = 256 lanes where the parent's end in ``Z`` = 258 on
+    both sides; the first call's in blocks and the later calls' out blocks are
+    whole rows.  A dispatch of ONE step is one whole call; the parent's program
+    of six is one whole call in its loop."""
+    sim, _, lanes, whole = _lanes_case(window, monkeypatch)
+    width = {"Z": 258, "W": 256}
+    closed = jax.make_jaxpr(lanes, static_argnums=1)(sim.dd._curr, steps)
+    assert _pass_blocks(closed) == sorted((width[f[0]], width[f[1]]) for f in forms)
+    closed = jax.make_jaxpr(whole, static_argnums=1)(sim.dd._curr, steps)
+    assert _pass_blocks(closed) == [(width["Z"], width["Z"])]
+
+
+def _one_pass_plan(**over):
+    p = {"tile_rows": 128, "reads": ("a", "b"), "writes": ("a", "b"), "renames": ()}
+    plan = {"route": "plane", "alias": True, "overlap": "off", "halo": "array",
+            "plane_window": "interior",
+            "pass_wrap_axes": "yz", "stages": ({"passes": (p,)},)}
+    for key, v in over.items():
+        (p if key in p else plan)[key] = v
+    return plan
+
+
+@pytest.mark.parametrize("over,lanes", [
+    ({}, "window"),
+    ({"plane_window": "interior-z", "pass_wrap_axes": "z"}, "window"),
+    ({"tile_rows": 0}, "raw"),  # whole planes: stream_plane_pass has no such forms
+    ({"alias": False}, "raw"),  # the lanes a narrow call leaves are the aliased block's
+    ({"overlap": "split"}, "raw"),  # (the split schedule keeps fresh outputs)
+    # ... and its exterior bands, like the fused side buffers, are cut from whole
+    # raw blocks: whatever the fills say, only the default schedule takes the forms
+    ({"overlap": "split", "alias": False}, "raw"),
+    ({"halo": "fused"}, "raw"),
+    ({"writes": ("a",)}, "raw"),  # a read-only operand's low z halo is filled in VMEM only
+    ({"plane_window": "raw", "pass_wrap_axes": ""}, "raw"),
+    ({"renames": (("a", "b"),)}, "raw"),
+])
+def test_who_takes_the_window_lanes_is_read_off_the_plan(over, lanes):
+    assert sp.plane_lanes_form(_one_pass_plan(**over)) == lanes
+
+
+def test_a_step_of_several_passes_keeps_whole_calls():
+    """A later pass of the dispatch's first step would fill its low z halo from
+    lanes an earlier pass left stale: a step of two passes, or of two stages,
+    moves whole raw planes every call."""
+    plan = _one_pass_plan()
+    (p,) = plan["stages"][0]["passes"]
+    assert sp.plane_lanes_form({**plan, "stages": ({"passes": (p, p)},)}) == "raw"
+    assert sp.plane_lanes_form({**plan, "stages": plan["stages"] * 2}) == "raw"
+
+
+@pytest.mark.parametrize("shape,mesh,kw,said", [
+    ((512,) * 3, (1, 1, 1), {}, ("interior", 128, "window")),
+    ((1024, 1024, 512), (2, 2, 1), {}, ("interior-z", 128, "window")),
+    ((256,) * 3, (1, 1, 1), {"route": "plane", "m": 1}, ("interior", 0, "raw")),
+])
+def test_domain_step_says_the_lanes_of_the_plan(shape, mesh, kw, said, monkeypatch):
+    """``domain.step`` carries ``plane_lanes`` beside ``tile_rows`` / ``y_tiles``,
+    as the resolved plan has it: both card-filling boxes take the window's lanes,
+    a box whose planes fit a pass whole keeps its program."""
+    sim = _lbm(shape, monkeypatch, mesh=mesh)
+    plan = _resolve(sim, **kw)
+    args = sm.stream_span_args(plan, RADIUS, 19)
+    assert (plan["plane_window"], plan["tile_rows"], plan["plane_lanes"]) == said
+    assert (args["plane_window"], args["tile_rows"], args["plane_lanes"]) == said
+
+
+def test_a_dispatch_says_its_lanes_on_the_span(monkeypatch):
+    """The span a dispatch of the model opens, held to the plan it ran."""
+    from stencil_tpu import telemetry
+    from stencil_tpu.telemetry import names as tm
+
+    sim = _small_lbm(monkeypatch, 32)
+    seen = []
+    real = telemetry.span
+
+    def spy(name, *a, **kw):
+        seen.append((name, kw))
+        return real(name, *a, **kw)
+
+    monkeypatch.setattr(telemetry, "span", spy)
+    _seeded_blocks(sim, 3)
+    sim.step(2)
+    (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
+    plan = sim._step._stream_plan
+    assert (kw["steps"], kw["plane_lanes"]) == (2, "window")
+    assert (kw["plane_lanes"], kw["tile_rows"], kw["y_tiles"]) == (
+        plan["plane_lanes"], plan["tile_rows"], plan["y_tiles"])
+    assert not sim._step._resilience.descents

@@ -1,17 +1,23 @@
-"""Rule ``slow-marker``: tier-1 time-budget discipline for tests.
+"""Rule ``slow-marker``: one class of expensive tier-1 test, found by its shape.
 
-ROADMAP records tier-1 clipping its 870 s timeout when heavyweight tests
-landed unmarked; PR 3 had to evacuate two AOT proofs (481 s for one) to
-tier-2 to restore headroom.  The expensive class is mechanical to spot: a
-test that spawns a fresh interpreter (``sys.executable`` / ``subprocess``)
-pays import+backend cold start per run, and a test that invokes
-``bench.py`` runs a full measurement protocol.  Such tests must carry
-``@pytest.mark.slow`` (tier-2) — or a suppression stating why the spawn is
-cheap (e.g. logging's jax-free ``python -c`` children).
+What the rule sees: a test that spawns a fresh interpreter (``sys.executable``
+/ ``subprocess``) pays import and backend cold start every run, and a test
+that invokes ``bench.py`` runs a whole measurement protocol.  Such tests must
+carry ``@pytest.mark.slow`` (tier-2) -- or a suppression stating why the spawn
+is cheap (e.g. logging's jax-free ``python -c`` children).  Detection is
+transitive over same-file helpers: a test calling a module helper that spawns
+is as expensive as spawning inline.  Docstrings are ignored (mentioning
+bench.py is not running it).
 
-Detection is transitive over same-file helpers: a test calling a module
-helper that spawns is as expensive as spawning inline.  Docstrings are
-ignored (mentioning bench.py is not running it).
+What it does NOT see is where tier-1's time went once the spawns were gone: an
+in-process test that traces and lowers interpreted ``pallas_call``s costs
+seconds a call, and no syntax says how many programs a test traces (a
+``parametrize`` over a static ``steps`` is a program a case).  That cost is
+measured, not linted: ``scripts/tier1_times.py <junit.xml>`` prints the
+test-seconds a file and the longest cases of the driver's run and exits 1 over
+the budget ROADMAP D13 states (the run's limit is 1470 s over six workers under
+``--dist loadfile``; the 870 s in this rule's message is the serial limit of
+the rounds that wrote it).
 """
 
 from __future__ import annotations

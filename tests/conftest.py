@@ -43,6 +43,15 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", _platform)
 jax.config.update("jax_enable_x64", os.environ["JAX_ENABLE_X64"] != "0")
+# ...and it keeps EVERY executable, not only those that took a second to
+# compile (jax's default): the suite compiles the same small programs over and
+# over -- a fill a quantity a domain, the control side of the bitwise cases --
+# and a hit costs a tenth of the compile (``tests/test_lane_pad_vmem.py`` alone:
+# 119 s -> 95 s; ROADMAP D13).  ``benchmark/harness/window.py`` sets the same two
+# for its runs, process-wide, so a worker that had run one rehearsal worked this
+# way already: now every test does, whatever ran before it.
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 import sys  # noqa: E402
 

@@ -86,6 +86,16 @@ def fingerprint_text(closed) -> str:
     return _SPACE.sub(" ", text)
 
 
+def step_loop_and_text(closed) -> tuple:
+    """A traced step program as (the trips of its ONE step loop, ``fingerprint_
+    text`` with that number taken out): two dispatches that differ in their step
+    count alone are the same text and another count -- held so, a test runs the
+    longer one as dispatches of the shorter and lowers nothing more."""
+    text = fingerprint_text(closed)
+    (loop,) = re.finditer(r"\} length=(\d+) linear", text)
+    return int(loop.group(1)), text[: loop.start(1)] + text[loop.end(1):]
+
+
 def fingerprint(closed) -> str:
     return hashlib.sha256(fingerprint_text(closed).encode()).hexdigest()
 

@@ -5,11 +5,14 @@ diagonals, three Runge-Kutta substeps a step) against the plain reference
 the plane route and on the XLA slice engine, after odd and even step counts
 (three renames a step: the carry's period is two steps), on meshes where an
 edge halo crosses two wires; an edge halo or a plane corner left unfilled
-comes out wrong; every single term switched off is seen; the difference
-operators are sixth order and the integrator third; the plan at the
-benchmark's size; the ``domain.step`` span's account."""
+comes out wrong; the
+plan at the benchmark's size; the ``domain.step`` span's account.  The
+non-cubic box, the wires' account and the drivers are in
+``tests/test_mhd_wide_box.py``, the reference's own physics in
+``tests/test_mhd_reference.py``, the window beside a split y in
+``tests/test_mhd_split_y.py``: a file is one worker's, and this model's
+programs cost 25 s of lowering each (ROADMAP D13: a file under 400 s)."""
 
-import dataclasses
 import json
 import os
 
@@ -86,12 +89,26 @@ def _errors(sim, want) -> dict:
 
 @pytest.mark.parametrize("steps", [1, 2, 3, 4])
 def test_plane_route_matches_the_reference(steps):
-    """One device, ONE dispatch of ``steps`` time steps: 3 is a trip of two and
-    a step behind the loop (the handles come back permuted), 4 is two trips;
-    every cell of the eight fields and of the eight second buffers."""
+    """One device, ``steps`` time steps: 1 is a step behind an empty loop (the
+    handles come back permuted), 2 a trip of two, 3 a trip and a step behind
+    the loop, each ONE dispatch; every cell of the eight fields and of the
+    eight second buffers.  4 is two trips -- the program of 2 with ``length=2``
+    on its loop and nothing else changed, which the traced programs are held to
+    (tracing lowers nothing: ISSUE 55) -- and runs as two dispatches of that
+    trip, the second from the handles the first brought home."""
     sim = _shared()
     _load(sim, _state())
-    sim.step(steps)
+    if steps == 4:
+        from program_fingerprint import step_loop_and_text
+
+        built = sim._step._resilience.built()
+        (trips, of_two), (trips4, of_four) = (
+            step_loop_and_text(jax.make_jaxpr(built, static_argnums=1)(sim.dd._curr, n)) for n in (2, 4))
+        assert (trips, trips4) == (1, 2) and of_four == of_two
+        sim.step(2)
+        sim.step(2)
+    else:
+        sim.step(steps)
     plan = sim._step._stream_plan
     assert (plan["route"], plan["steps_per_trip"]) == ("plane", 2)
     assert max(_errors(sim, ref.steps(sim.setup, _state(), steps)).values()) < TOL
@@ -120,58 +137,6 @@ def test_model_matches_the_reference_across_devices(mesh, steps):
         sim.step(1)
     assert tuple(sim.dd.mesh_dim()) == mesh
     assert max(_errors(sim, ref.steps(sim.setup, _state(), steps)).values()) < TOL
-
-
-#: a weak-scaled grid as ``astaroth-mhd-256x4`` has it: x = y = 2z cells on ONE
-#: cell, so the box has a side of its own an axis (4 pi x 4 pi x 2 pi at N = 16)
-WIDE = (2 * N, 2 * N, N)
-
-
-def _wide_setup():
-    return ref.MhdSetup(WIDE, box=tuple(2.0 * np.pi * n / N for n in WIDE), max_waves=2)
-
-
-def _wide_sim(mesh, impl):
-    if ("wide", mesh, impl) not in _SIMS:
-        sim = AstarothMHD(*WIDE, setup=_wide_setup(), interpret=True, seed_words=None,
-                          kernel_impl=impl, devices=jax.devices()[: int(np.prod(mesh))])
-        sim.dd.set_partition(*mesh)
-        sim.realize()
-        _SIMS["wide", mesh, impl] = sim
-    return _SIMS["wide", mesh, impl]
-
-
-@pytest.mark.parametrize("steps", [1, 2, 3, 4])
-@pytest.mark.parametrize("mesh,impl", [((2, 2, 1), "pallas"), ((2, 2, 2), "pallas"), ((2, 2, 1), "jnp")])
-def test_a_box_with_a_side_of_its_own_an_axis_matches_the_reference(mesh, impl, steps):
-    """32 x 32 x 16 cells on a uniform cell (ISSUE 47: weak scaling keeps the
-    CELL, the box grows with the grid), plane route and XLA slice engine, on
-    the meshes where the x-y edge halo crosses two wires: every cell of all
-    sixteen quantities after 1-4 time steps, a step a dispatch."""
-    setup = _wide_setup()
-    assert setup.spacing == (2.0 * np.pi / N,) * 3 and ref.dt_of(setup) == ref.dt_of(_setup())
-    sim = _wide_sim(mesh, impl)
-    state = ref.global_fields(setup, np.asarray(WORDS, dtype=np.uint32))
-    _load(sim, state)
-    for _ in range(steps):
-        sim.step(1)
-    assert tuple(sim.dd.mesh_dim()) == mesh
-    assert max(_errors(sim, ref.steps(setup, state, steps)).values()) < TOL
-
-
-def test_a_box_is_one_side_or_three():
-    """A float keeps its meaning (every axis that side: the one-chip cell's
-    set-up, its program and its fingerprint do not move); a triple is a side
-    an axis, hashable as the float is (``_substeps`` caches on the set-up)."""
-    cube = ref.MhdSetup((8, 16, 32))
-    assert cube.box == 2.0 * np.pi and cube.sides == (cube.box,) * 3
-    assert cube.spacing == (cube.box / 8, cube.box / 16, cube.box / 32)
-    wide = ref.MhdSetup((8, 16, 32), box=[1.0, 2, 4.0])
-    assert wide.box == wide.sides == (1.0, 2.0, 4.0) and wide.spacing == (0.125,) * 3
-    assert hash(wide) == hash(ref.MhdSetup((8, 16, 32), box=(1.0, 2.0, 4.0)))
-    assert ref.dt_of(wide) == wide.courant * 0.125 / (wide.cs0 + np.sqrt(3.0) * wide.amplitude)
-    with pytest.raises(ValueError, match="one side or one an axis"):
-        ref.MhdSetup((8, 8, 8), box=(1.0, 2.0))
 
 
 def test_the_seeded_state_matches_the_reference_and_takes_the_seed_as_an_argument():
@@ -204,24 +169,28 @@ def test_the_interior_window_matches_the_reference(monkeypatch):
     kernel's windows at all, the rotates' wraparound supplies the y, z and y-z
     corner reads on interior and x-shell planes alike (the x-y and x-z edge
     halos of the mixed differences) -- and every cell of all sixteen
-    quantities matches the reference after a trip of two steps and one behind
-    the loop.  The box is periodic and nowhere zero: a wrong wrap shows."""
+    quantities matches the reference after ONE time step of three times the
+    Courant number (the time three steps covered until ISSUE 55; a dispatch of
+    three is ``test_plane_route_matches_the_reference[3]``'s: each strip-form
+    call costs 15 s of lowering, a step holds three, and the window is the
+    same in every one).  The box is periodic and nowhere zero: a wrong wrap
+    shows; 8 planes are the fewest a radius-3 ring takes."""
     monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
     shape = (8, 32, 128)
-    setup = ref.MhdSetup(shape, max_waves=2)
+    setup = ref.MhdSetup(shape, max_waves=2, courant=0.9)
     sim = AstarothMHD(*shape, setup=setup, interpret=True, seed_words=None,
                       devices=jax.devices()[:1])
     sim.realize()
     state = ref.global_fields(setup, np.asarray(WORDS, dtype=np.uint32))
     _load(sim, state)
-    sim.step(3)
+    sim.step(1)
     said = sim._step._span_args()
     assert (said["route"], said["wrapped"], said["plane_window"]) == ("plane", "yz", "interior")
     # ... in its strip form (ISSUE 46): the kernel over ONE strip of the plane's
     # four tiles, every y shift a read of another tile, the margins' wrap included
     assert said["plane_strip"] == 32
     assert (said["renamed"], said["steps_per_trip"]) == ("8/8/8", 2)
-    want = ref.steps(setup, state, 3)
+    want = ref.steps(setup, state, 1)
     moved = min(float(jnp.abs(want[q] - state[q]).max()) for q in ref.FIELDS)
     assert moved > 100 * TOL, moved  # every field advanced: the comparison sees the step
     assert max(_errors(sim, want).values()) < TOL
@@ -280,87 +249,6 @@ def test_an_unfilled_edge_halo_or_plane_corner_comes_out_wrong(which, monkeypatc
     # the potentials couple through B alone: the one whose grad div reads no
     # diagonal of that plane is still right after one step
     assert all(errs[q] > 5 * TOL for q in a_read) and errs[a_spared] < TOL, errs
-
-
-# --- every term is seen -------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("term", ["nu", "eta", "chi", "zeta", "lorentz", "pressure", "advection"])
-def test_every_single_term_moves_the_state_far_beyond_the_limit(term):
-    """The benchmark's coefficients, fixed ``dt`` and dispatch, its seeded
-    state, on the same box at 24^3 (the terms are those of the low modes: what
-    they add up to over the dispatch's TIME is the same on any grid that
-    resolves them): the update with ONE term switched off against the full one
-    differs by more than 100 times the cell's ``max_abs_err`` after one
-    dispatch's worth of steps, so a program that skipped it would not be
-    ``correct``.  The update is ``astaroth_mhd_reference.substep``, the one the
-    program's kernels run (held to each other above), on whole arrays: a
-    seventh of the compiles the program would take (the benchmark's rehearsal
-    switches a term off in the program itself, tests/test_bench_mhd.py)."""
-    config = _config()
-    s = config["setup"]
-    full = ref.MhdSetup(
-        (24, 24, 24), nu=s["nu"], eta=s["eta"], chi=s["chi"], zeta=s["zeta"], gamma=s["gamma"],
-        cp=s["cp"], cs0=s["cs0"], mu0=s["mu0"], lnrho0=s["lnrho0"], lnT0=s["lnT0"], box=s["box"],
-        dt=s["dt"], amplitude=s["amplitude"], modes=s["modes"], max_waves=s["max_waves"],
-    )
-    off = {term: 0.0} if term in ("nu", "eta", "chi", "zeta") else {"off": (term,)}
-    steps = config["dispatch"]["bulk"]
-    state = ref.global_fields(full, np.asarray(WORDS, dtype=np.uint32))
-    want = ref.steps(full, state, steps)
-    got = ref.steps(dataclasses.replace(full, **off), state, steps)
-    worst = max(float(jnp.abs(got[q] - want[q]).max()) for q in ref.QUANTITIES)
-    assert worst > 100 * config["limits"]["max_abs_err"], (term, worst)
-    assert all(bool(jnp.isfinite(want[q]).all()) for q in ref.QUANTITIES)
-
-
-def test_unknown_terms_are_refused():
-    with pytest.raises(ValueError, match="unknown terms"):
-        ref.MhdSetup((8, 8, 8), off=("gravity",))
-
-
-# --- the operators and the integrator ---------------------------------------------------
-
-
-def _sine_error(which: str, n: int) -> float:
-    """max error of one difference of ``sin(2x + y - z + 0.3)`` on ``n^3``
-    cells of the 2 pi box, in float64."""
-    h = 2.0 * np.pi / n
-    x = np.arange(n) * h
-    arg = 2.0 * x[:, None, None] + x[None, :, None] - x[None, None, :] + 0.3
-    f = {"f": np.sin(arg)}
-    taps = ref.Taps(lambda q, dx, dy, dz: np.roll(f[q], (-dx, -dy, -dz), (0, 1, 2)))
-    if which == "first":
-        return float(np.abs(ref.der1(taps, "f", 0, 1.0 / h) - 2.0 * np.cos(arg)).max())
-    if which == "second":
-        return float(np.abs(ref.der2(taps, "f", 1, 1.0 / h) + np.sin(arg)).max())
-    mixed = ref.der_mixed(taps, "f", 0, 2, 1.0 / h, 1.0 / h)  # d_x d_z = +2 sin
-    return float(np.abs(mixed - 2.0 * np.sin(arg)).max())
-
-
-@pytest.mark.parametrize("which", ["first", "second", "mixed"])
-def test_the_differences_are_sixth_order(which):
-    coarse, fine = _sine_error(which, 32), _sine_error(which, 64)
-    assert fine < 1e-5 and 45.0 < coarse / fine < 80.0, (coarse, fine)  # 2^6 = 64
-
-
-def test_the_two_buffer_runge_kutta_is_third_order():
-    """``y' = -y`` over one time unit in Astaroth's two-buffer form: halving
-    the step cuts the error eightfold, and the second buffer holds the value
-    before the last substep."""
-
-    def integrate(steps):
-        h, cur, prev = 1.0 / steps, 1.0, 1.0
-        for _ in range(steps):
-            for s, (ratio, beta) in enumerate(ref.COEFFS):
-                cur, prev = ref.two_buffer(cur, prev if s else None, h * -cur, ratio, beta), cur
-        return cur, prev
-
-    errors = [abs(integrate(n)[0] - np.exp(-1.0)) for n in (10, 20, 40)]
-    assert 7.0 < errors[0] / errors[1] < 9.0 and 7.0 < errors[1] / errors[2] < 9.0, errors
-    cur, prev = integrate(10)
-    assert prev != cur and abs(prev - cur) < 0.1
-    assert ref.ALPHA[0] == 0.0 and abs(sum(ref.BETA[s] for s in range(3)) - 1.8041666) < 1e-6
 
 
 # --- the plan ---------------------------------------------------------------------------
@@ -450,67 +338,6 @@ def test_the_span_says_what_a_staged_renaming_step_does():
     assert said == {"label": "astaroth-mhd", "steps": 2, **sim._step._span_args()}
 
 
-def _stage_sends(sim, steps=1):
-    """Per stage and swept axis, the bytes of the ``ppermute`` equations of the
-    traced step under ``step.stage.<k>/.../exchange.<axis>``."""
-    from stencil_tpu.analysis import jaxpr as jx
-
-    closed = jax.make_jaxpr(sim._step._resilience.built(), static_argnums=1)(sim.dd._curr, steps)
-    sent = {}
-    for e in jx.iter_eqns(closed):
-        if e.primitive.name != "ppermute":
-            continue
-        stack = jx.name_stack_str(e)
-        (k,) = [k for k in range(ref.SUBSTEPS) if tm.step_stage_span(k) in stack.split("/")]
-        (axis,) = [a for a in "xyz" if tm.exchange_axis_span(a) in stack.split("/")]
-        nbytes = sum(int(np.prod(v.aval.shape)) * v.aval.dtype.itemsize for v in e.invars)
-        sent[k, axis] = sent.get((k, axis), 0) + nbytes
-    return sent
-
-
-@pytest.mark.parametrize("chip_sweeps", [False, True])
-def test_the_span_says_what_crosses_the_wires_stage_by_stage(chip_sweeps, monkeypatch):
-    """``domain.step`` on mesh [2,2,1] (ISSUE 47): ``wired`` "xy", ``wrapped``
-    "z" with the sweeps as the chip has them ("" as the CPU has them), the
-    raw window and no strip beside a y halo that arrives over a wire,
-    ``wire_bytes`` = the bytes of the traced ``ppermute``s, which sit under
-    ``step.stage.<k>/exchange.x|y`` -- three equal stages, as
-    ``wire_bytes_by_stage`` says --, and ``wired_edges`` "xy": the mixed
-    differences read ``sh(+-k, +-k, 0)``, an edge that reaches a shard over
-    two wires in turn."""
-    if chip_sweeps:
-        monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
-        sim = AstarothMHD(*WIDE, setup=_wide_setup(), interpret=True, seed_words=None,
-                          devices=jax.devices()[:4])
-        sim.realize()  # the partitioner's own pick
-    else:
-        sim = _wide_sim((2, 2, 1), "pallas")
-    assert tuple(sim.dd.mesh_dim()) == (2, 2, 1)
-    said = sim._step._span_args()
-    raw = N + 2 * RADIUS
-    faces = 2 * 8 * 2 * RADIUS * raw * raw * 4  # two axes, eight fields, six planes of the raw block
-    # x and y fly jointly: behind each y face the corner relay, both x halos on its three rows
-    stage = faces + 2 * 8 * 2 * RADIUS * RADIUS * raw * 4
-    assert (said["wired"], said["wrapped"]) == ("xy", "z" if chip_sweeps else "")
-    assert (said["plane_window"], said["plane_strip"], said["exchanged"]) == ("raw", 0, "8/8/8")
-    assert said["wire_bytes"] == 3 * stage == 557_568 + 76_032 and said["joint"] == "xy"
-    assert said["wire_bytes_by_stage"] == "/".join([str(stage)] * 3)
-    assert said["wired_edges"] == "xy"
-    sent = _stage_sends(sim)  # every send inside its stage, under its sweep's scope
-    # (the CPU's sweeps also "send" the unsplit z axis's wrap to the shard itself: no wire)
-    assert sorted(sent) == [(k, a) for k in range(3) for a in ("xy" if chip_sweeps else "xyz")]
-    assert [sum(sent[k, a] for a in "xy") for k in range(3)] == [stage] * 3
-    assert sum(v for (_, a), v in sent.items() if a in "xy") == said["wire_bytes"]
-    # one split axis, or none: no edge crosses two wires
-    line = _shared(mesh=(2, 1, 1))._step._span_args()
-    assert (line["wired"], line["wired_edges"]) == ("x", "")
-    assert line["wire_bytes_by_stage"] == "/".join([str(faces // 2)] * 3) and line["joint"] == ""
-    alone = _shared()._step._span_args()
-    assert (alone["wired"], alone["wired_edges"], alone["wire_bytes_by_stage"]) == ("", "", "0/0/0")
-    # ... and all three pairs where all three axes are split
-    assert _shared(mesh=(2, 2, 2))._step._span_args()["wired_edges"] == "xy/xz/yz"
-
-
 def test_the_counter_is_registered_and_the_names_lint_passes():
     import inspect
 
@@ -543,49 +370,9 @@ def test_rebuild_keeps_the_plan_and_bad_arguments_are_refused():
     plan = sim._step._stream_plan
     assert (plan["route"], len(plan["stages"]), plan["steps_per_trip"]) == ("plane", 3, 2)
     _load(sim, _state())
-    sim.step(2)
-    assert max(_errors(sim, ref.steps(sim.setup, _state(), 2)).values()) < TOL
+    sim.step(1)  # (the rebuilt step's one program: three calls to lower, not six)
+    assert max(_errors(sim, ref.steps(sim.setup, _state(), 1)).values()) < TOL
     with pytest.raises(ValueError, match="the set-up is for"):
         AstarothMHD(8, 8, 8, setup=_setup())
     with pytest.raises(ValueError, match="unknown kernel_impl"):
         AstarothMHD(8, 8, 8, kernel_impl="cuda")
-
-
-# --- the driver -------------------------------------------------------------------------
-
-
-def test_driver_runs_on_the_cpu(capsys, tmp_path):
-    """``stencil-astaroth-mhd`` takes the box, prints the cell's figure of merit,
-    says on stderr what the planner made of the three substeps and writes its
-    metrics where it is told."""
-    from stencil_tpu.bin import astaroth_mhd
-
-    out = tmp_path / "metrics.json"
-    rc = astaroth_mhd.main(["16", "16", "16", "--iters", "1", "--steps", "1",
-                            "--metrics-out", str(out)])
-    assert rc == 0
-    io = capsys.readouterr()
-    row = io.out.strip().splitlines()[-1].split(",")
-    assert row[0] == "astaroth_mhd" and row[3:6] == ["16", "16", "16"] and float(row[-1]) > 0
-    (said,) = [l for l in io.err.splitlines() if l.startswith("mesh: ")]
-    assert "route='plane'" in said and "stages=3" in said and "renamed=8/8/8" in said, said
-    assert out.exists() and out.stat().st_size > 0
-    # the host's eight devices: every axis split, every edge over two wires
-    assert said.startswith("mesh: 2,2,2 wired='xyz' ") and "wired_edges='xy/xz/yz'" in said, said
-
-
-def test_driver_keeps_the_cell_on_a_weak_scaled_grid(capsys, monkeypatch):
-    """``stencil-astaroth-mhd 32 32 16`` on four devices: the partitioner's
-    own mesh 2,2,1, the CELL of ``16 16 16`` (so its time step), and what
-    crosses the wires on stderr as ``stencil-acoustic`` says it."""
-    from stencil_tpu.bin import astaroth_mhd
-
-    monkeypatch.setattr(jax, "devices", lambda *a, real=jax.devices: real(*a)[:4])
-    rc = astaroth_mhd.main(["32", "32", "16", "--iters", "1", "--steps", "2"])
-    assert rc == 0
-    io = capsys.readouterr()
-    row = io.out.strip().splitlines()[-1].split(",")
-    assert row[3:6] == ["32", "32", "16"] and float(row[-1]) > 0
-    assert abs(float(row[6]) - ref.dt_of(_setup())) < 1e-15  # the cube's time step
-    (said,) = [l for l in io.err.splitlines() if l.startswith("mesh: ")]
-    assert said.startswith("mesh: 2,2,1 wired='xy' wrapped='' wire_bytes=633600 wired_edges='xy'"), said

@@ -38,7 +38,10 @@ def _config(name="lbm-d3q19-512"):
 
 
 def _rehearse(patch=None, **flags):
-    """One rehearsal in process: (last line, checks by name, plan line)."""
+    """One rehearsal in process: (last line, checks by name, plan line).  The
+    runs share one built cell a storage (``rehearsal_cells``)."""
+    from rehearsal_cells import shared_build
+
     from benchmark.harness import window
 
     opts = types.SimpleNamespace(
@@ -46,7 +49,7 @@ def _rehearse(patch=None, **flags):
         describe_trace=False, also_verify=[], rehearse=N, dispatch_size=DISPATCH)
     vars(opts).update(flags)
     out = io.StringIO()
-    with contextlib.redirect_stdout(out):
+    with contextlib.redirect_stdout(out), shared_build("benchmark.factories.lbm_slab"):
         rc = window.run(opts, time.perf_counter(), patch=patch)
     assert rc == 0
     lines = [json.loads(x) for x in out.getvalue().splitlines() if x.startswith("{")]
@@ -76,9 +79,9 @@ def test_the_bf16_control_is_not_correct():
     assert checks["max_abs_err"]["value"] > 20 * checks["max_abs_err"]["limit"]
 
 
-def test_a_frozen_dispatch_is_not_correct():
-    def freeze(cell):  # the step returns its state unchanged
-        cell.dispatch = lambda n: None
+def test_a_frozen_dispatch_is_not_correct(monkeypatch):
+    def freeze(cell):  # the step returns its state unchanged (on the shared cell: undone behind the test)
+        monkeypatch.setattr(cell, "dispatch", lambda n: None, raising=False)
 
     line, checks, _ = _rehearse(patch=freeze, seed=7)
     bad = [n for n, c in checks.items() if not c["ok"]]

@@ -36,8 +36,12 @@ def _config():
         return json.load(f)
 
 
-def _rehearse(patch=None, **flags):
-    """One rehearsal in process: (last line, checks by name, plan line)."""
+def _rehearse(patch=None, own_cell=False, **flags):
+    """One rehearsal in process: (last line, checks by name, plan line).  The
+    runs share one built cell a storage (``rehearsal_cells``) but the one that
+    asks for its ``own_cell`` to break."""
+    from rehearsal_cells import shared_build
+
     from benchmark.harness import window
 
     opts = types.SimpleNamespace(
@@ -45,7 +49,8 @@ def _rehearse(patch=None, **flags):
         describe_trace=False, also_verify=[], rehearse=N, dispatch_size=DISPATCH)
     vars(opts).update(flags)
     out = io.StringIO()
-    with contextlib.redirect_stdout(out):
+    shared = contextlib.nullcontext() if own_cell else shared_build("benchmark.factories.mhd")
+    with contextlib.redirect_stdout(out), shared:
         rc = window.run(opts, time.perf_counter(), patch=patch)
     assert rc == 0
     lines = [json.loads(x) for x in out.getvalue().splitlines() if x.startswith("{")]
@@ -88,9 +93,9 @@ def test_the_bf16_control_is_not_correct():
     assert checks["max_abs_err"]["value"] > 20 * checks["max_abs_err"]["limit"]
 
 
-def test_a_frozen_dispatch_is_not_correct():
-    def freeze(cell):  # the step returns its state unchanged
-        cell.dispatch = lambda n: None
+def test_a_frozen_dispatch_is_not_correct(monkeypatch):
+    def freeze(cell):  # the step returns its state unchanged (on the shared cell: undone behind the test)
+        monkeypatch.setattr(cell, "dispatch", lambda n: None, raising=False)
 
     line, checks, _ = _rehearse(patch=freeze, seed=7)
     bad = [n for n, c in checks.items() if not c["ok"]]
@@ -105,7 +110,7 @@ def test_a_program_that_skips_a_term_is_not_correct():
         cell.sim.setup = dataclasses.replace(cell.sim.setup, nu=0.0)
         cell.sim.rebuild_after_reshard()  # the step, rebuilt over the changed set-up
 
-    line, checks, _ = _rehearse(patch=inviscid, seed=11)
+    line, checks, _ = _rehearse(patch=inviscid, own_cell=True, seed=11)
     assert line["rehearsal"]["checks_ok"] is False
     # two steps of the cell's eight a dispatch: a quarter of the time the term has there
     assert checks["max_abs_err"]["value"] > 20 * checks["max_abs_err"]["limit"], checks

@@ -47,8 +47,12 @@ def _bench():
         return json.load(f)
 
 
-def _rehearse(patch=None, **flags):
-    """One rehearsal in process: (last line, checks by name, plan line)."""
+def _rehearse(patch=None, own_cell=False, **flags):
+    """One rehearsal in process: (last line, checks by name, plan line).  The
+    runs share one built cell a storage (``rehearsal_cells``) but the one that
+    asks for its ``own_cell`` to break."""
+    from rehearsal_cells import shared_build
+
     from benchmark.harness import window
 
     opts = types.SimpleNamespace(
@@ -56,7 +60,8 @@ def _rehearse(patch=None, **flags):
         describe_trace=False, also_verify=[], rehearse=N, dispatch_size=DISPATCH)
     vars(opts).update(flags)
     out = io.StringIO()
-    with contextlib.redirect_stdout(out):
+    shared = contextlib.nullcontext() if own_cell else shared_build("benchmark.factories.mhd_x4")
+    with contextlib.redirect_stdout(out), shared:
         rc = window.run(opts, time.perf_counter(), patch=patch)
     assert rc == 0
     lines = [json.loads(x) for x in out.getvalue().splitlines() if x.startswith("{")]
@@ -125,7 +130,7 @@ def test_a_step_whose_third_stage_skips_its_y_sweep_is_not_correct(monkeypatch):
         monkeypatch.setattr(exchange, "_sweep_group", third_stage_skips_y)
         cell.sim.rebuild_after_reshard()
 
-    line, checks, plan = _rehearse(patch=patch, seed=2**31 + 247, dispatch_size=6)
+    line, checks, plan = _rehearse(own_cell=True, patch=patch, seed=2**31 + 247, dispatch_size=6)
     assert next(y_sweeps) >= 3  # the patched sweep was traced
     assert plan["ran"]["route"] == "plane" and line["failed"] == 0
     assert line["rehearsal"]["checks_ok"] is False

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import seeded_blocks
+
 from stencil_tpu import telemetry
 from stencil_tpu.core.dim3 import Dim3
 from stencil_tpu.models import elastic_reference as ref
@@ -33,12 +35,29 @@ ATOL = 5e-6
 WORDS = np.asarray([0x1234ABCD, 77, 0xDEADBEEF, 2024], dtype=np.uint32)
 
 
-def _sim(impl="pallas", devices=None, partition=None, **kw):
-    sim = ElasticWave(N, N, N, nbl=NBL, kernel_impl=impl, interpret=True,
-                      devices=devices or jax.devices()[:1], seed_words=WORDS, **kw)
-    if partition:
-        sim.dd.set_partition(*partition)
-    sim.realize()
+_BUILT = {}
+
+
+def _sim(impl="pallas", devices=None, partition=None, fresh=False, **kw):
+    """One realized model a configuration -- engine, devices, partition, options
+    and the ``STENCIL_HALO_BLEND`` / ``STENCIL_VMEM_LIMIT_BYTES`` of the moment
+    (the plan reads them) --, its seeded blocks put back for the case that asks
+    (ISSUE 55: a model's programs, one a dispatch size, are most of a case's
+    time; ``fresh`` for the case that looks at what a NEW domain allocates)."""
+    devices = devices or jax.devices()[:1]
+    key = (impl, len(devices), partition, tuple(sorted(kw.items())),
+           os.environ.get("STENCIL_HALO_BLEND"), os.environ.get("STENCIL_VMEM_LIMIT_BYTES"))
+    if fresh or key not in _BUILT:
+        sim = ElasticWave(N, N, N, nbl=NBL, kernel_impl=impl, interpret=True,
+                          devices=devices, seed_words=WORDS, **kw)
+        if partition:
+            sim.dd.set_partition(*partition)
+        sim.realize()
+        if fresh:
+            return sim
+        _BUILT[key] = (sim, seeded_blocks.snapshot(sim.dd))
+    sim, blocks = _BUILT[key]
+    seeded_blocks.restore(sim.dd, blocks)
     return sim
 
 
@@ -428,7 +447,7 @@ def test_the_next_slot_is_allocated_on_first_use_where_two_do_not_fit(monkeypatc
     from stencil_tpu.domain import DistributedDomain
 
     monkeypatch.setattr(DistributedDomain, "_both_slots_fit", lambda self: False)
-    sim = _sim("pallas")
+    sim = _sim("pallas", fresh=True)
     assert sim.dd._next == {} and len(sim.dd._curr) == 13
     sim.step(1)
     assert sim.dd._next == {}  # a built step carries curr in place

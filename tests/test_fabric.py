@@ -24,6 +24,13 @@ from stencil_tpu.telemetry import fabric, names
 from stencil_tpu.telemetry.ledger import entries_from_artifact
 
 
+#: every probe's payload.  A link is ``nbytes / seconds`` rounded to three
+#: decimals of a GB/s on the host's own clock: at 4 KiB one 8 ms stall of a
+#: loaded worker reads 0.000 (PR 45's and PR 49's rc 1; ROADMAP D11), at 1 MiB
+#: a stall of a whole second still reads 0.001.  No tier-1 probe runs smaller.
+PAYLOAD = 1 << 20
+
+
 def _mesh222():
     return mesh_from_grid(np.array(jax.devices()[:8]).reshape(2, 2, 2))
 
@@ -76,7 +83,7 @@ class TestProbe:
         loads it warm — ZERO device work (the probe-run counter does not
         move)."""
         mesh = _mesh222()
-        doc = fabric.ensure(mesh, nbytes=1 << 20, reps=1)
+        doc = fabric.ensure(mesh, nbytes=PAYLOAD, reps=1)
         assert doc["bench"] == "fabric_probe"
         assert doc["topology"] == [2, 2, 2] and doc["n_devices"] == 8
         assert doc["protocol"]["edges"] == 24 and len(doc["links"]) == 48
@@ -98,7 +105,7 @@ class TestProbe:
         assert snap["counters"][names.FABRIC_CACHE_MISS] == 1
         assert snap["counters"][names.FABRIC_CACHE_HIT] == 0
 
-        doc2 = fabric.ensure(mesh, nbytes=1 << 20, reps=1)
+        doc2 = fabric.ensure(mesh, nbytes=PAYLOAD, reps=1)
         assert doc2["links"] == doc["links"]
         snap = telemetry.snapshot()
         assert snap["counters"][names.FABRIC_PROBE_RUNS] == 24  # no device work
@@ -112,15 +119,15 @@ class TestProbe:
 
     def test_payload_is_part_of_the_key(self):
         mesh = _mesh222()
-        fabric.ensure(mesh, nbytes=4096, reps=1)
-        fabric.ensure(mesh, nbytes=8192, reps=1)  # different fact: re-probe
+        fabric.ensure(mesh, nbytes=PAYLOAD, reps=1)
+        fabric.ensure(mesh, nbytes=2 * PAYLOAD, reps=1)  # different fact: re-probe
         snap = telemetry.snapshot()
         assert snap["counters"][names.FABRIC_CACHE_MISS] == 2
 
     def test_force_reprobes(self):
         mesh = _mesh222()
-        fabric.ensure(mesh, nbytes=4096, reps=1)
-        fabric.ensure(mesh, nbytes=4096, reps=1, force=True)
+        fabric.ensure(mesh, nbytes=PAYLOAD, reps=1)
+        fabric.ensure(mesh, nbytes=PAYLOAD, reps=1, force=True)
         snap = telemetry.snapshot()
         assert snap["counters"][names.FABRIC_PROBE_RUNS] == 48
         assert snap["counters"][names.FABRIC_CACHE_HIT] == 0
@@ -129,8 +136,8 @@ class TestProbe:
         """The tune-cache pattern verbatim: corrupt file -> warn + miss;
         schema/toolchain mismatch -> info + miss; never a crash."""
         mesh = _mesh222()
-        doc = fabric.ensure(mesh, nbytes=4096, reps=1)
-        key = fabric.probe_key((2, 2, 2), doc["chip"], 4096, None)
+        doc = fabric.ensure(mesh, nbytes=PAYLOAD, reps=1)
+        key = fabric.probe_key((2, 2, 2), doc["chip"], PAYLOAD, None)
         path = fabric.path_for(key)
         assert os.path.exists(path)
 
@@ -166,7 +173,7 @@ class TestProbe:
 class TestLinkModel:
     def test_link_model_and_summary_shapes(self):
         mesh = _mesh222()
-        doc = fabric.ensure(mesh, nbytes=1 << 20, reps=1)
+        doc = fabric.ensure(mesh, nbytes=PAYLOAD, reps=1)
         model = fabric.link_model(doc)
         assert set(model["axes"]) == {"x", "y", "z"}
         for sides in model["axes"].values():
@@ -188,17 +195,15 @@ class TestLinkModel:
         """``link_model(mesh)`` — the placement/tuner entry — goes through
         ensure(): warm after one probe, zero further device work."""
         mesh = _mesh222()
-        fabric.ensure(mesh, nbytes=4096, reps=1)
-        model = fabric.link_model(mesh, nbytes=4096, reps=1)
+        fabric.ensure(mesh, nbytes=PAYLOAD, reps=1)
+        model = fabric.link_model(mesh, nbytes=PAYLOAD, reps=1)
         assert set(model["axes"]) == {"x", "y", "z"}
         snap = telemetry.snapshot()
         assert snap["counters"][names.FABRIC_PROBE_RUNS] == 24
 
     def test_ledger_ingests_probe_artifact(self, tmp_path):
         mesh = _mesh222()
-        # 1 MiB, not 4 KiB: links round to 3 decimals of a GB/s, and one
-        # 8 ms stall of a loaded worker reads a 4 KiB message as 0.000
-        doc = fabric.ensure(mesh, nbytes=1 << 20, reps=1)
+        doc = fabric.ensure(mesh, nbytes=PAYLOAD, reps=1)
         path = tmp_path / "fabric.json"
         path.write_text(json.dumps(doc))
         entries = entries_from_artifact(str(path))
@@ -218,7 +223,7 @@ class TestCli:
         cache = str(tmp_path / "cache")
         out = str(tmp_path / "fabric.json")
         rc = main([
-            "--grid", "2", "2", "2", "--nbytes", "4096", "--reps", "1",
+            "--grid", "2", "2", "2", "--nbytes", str(PAYLOAD), "--reps", "1",
             "--cache", cache, "--out", out,
         ])
         assert rc == 0
@@ -228,7 +233,7 @@ class TestCli:
         assert doc["bench"] == "fabric_probe"
         # warm second run prints from the cache (and --json round-trips)
         rc = main([
-            "--grid", "2", "2", "2", "--nbytes", "4096", "--reps", "1",
+            "--grid", "2", "2", "2", "--nbytes", str(PAYLOAD), "--reps", "1",
             "--cache", cache, "--json",
         ])
         assert rc == 0
@@ -248,7 +253,7 @@ class TestCli:
 class TestStatusSurface:
     def test_fabric_lines_render_matrix_and_callout(self):
         mesh = _mesh222()
-        doc = fabric.ensure(mesh, nbytes=4096, reps=1)
+        doc = fabric.ensure(mesh, nbytes=PAYLOAD, reps=1)
         from stencil_tpu.status import _fabric_lines
 
         lines = _fabric_lines(fabric.summary(doc))
@@ -267,7 +272,7 @@ class TestStatusSurface:
         from stencil_tpu.telemetry.flight import FlightRecorder, read_status
 
         mesh = _mesh222()
-        doc = fabric.ensure(mesh, nbytes=4096, reps=1)
+        doc = fabric.ensure(mesh, nbytes=PAYLOAD, reps=1)
         fr = FlightRecorder(str(tmp_path), label="weak-scaling")
         fr.state["fabric"] = fabric.summary(doc)
         fr.heartbeat(1, 3, stage="mesh 2x2x2")
@@ -290,7 +295,7 @@ def test_live_probe_on_real_mesh():
     from stencil_tpu.parallel.mesh import make_mesh
 
     mesh, _ = make_mesh((128, 128, 128), Radius.constant(1))
-    doc = fabric.ensure(mesh, nbytes=1 << 20, reps=2)
+    doc = fabric.ensure(mesh, nbytes=PAYLOAD, reps=2)
     n = doc["n_devices"]
     m = doc["matrix"]
     assert len(m) == n
@@ -299,5 +304,5 @@ def test_live_probe_on_real_mesh():
             assert (m[i][j] > 0) == (m[j][i] > 0)
     if doc["protocol"]["edges"]:
         assert all(l["gbps"] > 0 for l in doc["links"])
-        doc2 = fabric.ensure(mesh, nbytes=1 << 20, reps=2)
+        doc2 = fabric.ensure(mesh, nbytes=PAYLOAD, reps=2)
         assert doc2["links"] == doc["links"]

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import seeded_blocks
+
 from stencil_tpu import telemetry
 from stencil_tpu.models import jacobi as jm
 from stencil_tpu.models.jacobi import Jacobi3D
@@ -85,24 +87,81 @@ def _said(sim) -> dict:
     return {k: v for k, v in sim._step._span_args().items() if k not in ("wired", "wire_bytes", "joint")}
 
 
+_BUILT = {}
+
+
+def _shared(route, mesh, monkeypatch, per_trip=None):
+    """One realized build a (route, mesh, macros a trip) for every case, its
+    seeded raw blocks put back for the case that asks: a case then traces the
+    one program of ITS step count."""
+    key = (route, mesh, per_trip)
+    if key not in _BUILT:
+        with pytest.MonkeyPatch.context() as mp:
+            sim = _build(route, mesh, mp, per_trip=per_trip)
+        _BUILT[key] = (sim, seeded_blocks.snapshot(sim.dd))
+    if route == "plain":  # (the route's switch, as ``_build`` had it while it built)
+        monkeypatch.setenv("STENCIL_Z_SLABS", "0")
+    sim, blocks = _BUILT[key]
+    seeded_blocks.restore(sim.dd, blocks)
+    return sim
+
+
 @pytest.mark.parametrize("macros,rem", [(m, r) for m in range(6) for r in (0, 1) if m or r])
 @pytest.mark.parametrize("route,mesh", CASES, ids=[f"{r}-{'x'.join(map(str, m))}" for r, m in CASES])
 def test_two_macros_a_trip_is_bitwise_one_a_trip(route, mesh, macros, rem, monkeypatch):
     """Every raw cell (shell included) after one dispatch of ``macros`` whole
     macros and ``rem`` steps more, and after a second such dispatch, against
-    the same build with one macro a trip: trips, the odd macro behind the
-    loop and the remainder run the same kernel calls in the same order."""
+    the same kernels with no loop around them at all: the control build (one
+    macro a trip) dispatched a macro at a time and then the remainder, so its
+    two programs a (route, mesh) serve every step count (ISSUE 55; it ran
+    ``steps`` in one dispatch before, a program a case, and on the z-slab
+    route it still does).  Trips, the odd macro behind the loop and the
+    remainder run the same kernel calls in the same order.  Three of the
+    eleven counts run as two dispatches (``_as_a_second_trip``)."""
     steps = macros * K + rem
-    two = _build(route, mesh, monkeypatch)
-    one = _build(route, mesh, monkeypatch, per_trip=1)
+    two = _shared(route, mesh, monkeypatch)
+    one = _shared(route, mesh, monkeypatch, per_trip=1)
     assert _said(two) == {"macros_per_trip": 2, **Z_HALO_PATCH.get(route, {})}
+    assert _said(one) == {"macros_per_trip": 1, **Z_HALO_PATCH.get(route, {})}
     seeded = _raw(two)
     np.testing.assert_array_equal(seeded, _raw(one))
+    dispatches = _as_a_second_trip(two, macros, rem) or (steps,)
     for _ in range(2):
-        two.step(steps)
-        one.step(steps)
+        for n in dispatches:
+            two.step(n)
+        if route == "zslab":
+            # its z halo rides in the slab carry: the block's z-shell columns hold
+            # what the kernels left there since the DISPATCH began, so the control
+            # must begin and end where the dispatch does (a program a dispatch)
+            for n in dispatches:
+                one.step(n)
+        else:
+            for _ in range(macros):
+                one.step(K)
+            if rem:
+                one.step(rem)
         np.testing.assert_array_equal(_raw(two), _raw(one))
     assert not np.array_equal(_raw(two), seeded)
+
+
+def _as_a_second_trip(sim, macros, rem):
+    """``(4, 0)``, ``(4, 1)`` and ``(5, 0)`` are the programs of ``(2, 0)``,
+    ``(2, 1)`` and ``(3, 0)`` with TWO trips on the loop and nothing else
+    changed: the traced programs are held to exactly that (tracing lowers
+    nothing), and the case runs as two dispatches whose programs those cases
+    lower anyway -- one trip, then what is left.  ``(5, 1)`` stays ONE dispatch:
+    two trips, a macro behind the loop and the remainder.  None for every other
+    count."""
+    from program_fingerprint import step_loop_and_text
+
+    if macros < 4 or (macros, rem) == (5, 1):
+        return None
+    rest = (macros - 2) * K + rem
+    (trips, text), (trips_rest, text_rest) = (
+        step_loop_and_text(jax.make_jaxpr(sim._step, static_argnums=1)(sim.dd._curr, n))
+        for n in (macros * K + rem, rest))
+    assert (trips, trips_rest) == (2, 1) and text == text_rest
+    return (2 * K, rest)
 
 
 def _stencil_calls(jaxpr):

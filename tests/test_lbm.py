@@ -94,15 +94,26 @@ def _worst(sim, want):
 # --- the program against the reference -------------------------------------------------
 
 
+def _advance(sim, steps):
+    """``steps`` time steps in the dispatches whose programs the other cases of
+    the (mesh, route) trace anyway (a program is 5 s of lowering nineteen
+    coupled populations: ISSUE 55): 1, 2 and 7 are ONE dispatch each -- a
+    remainder alone, a macro alone, and a trip of the macro loop with a macro
+    and the remainder behind it --, 3 is the dispatch of 2 and then the dispatch
+    of 1 from the blocks it left, shell and all."""
+    for n in {3: (2, 1)}.get(steps, (steps,)):
+        sim.step(n)
+
+
 @pytest.mark.parametrize("steps", [1, 2, 3, 7])
 @pytest.mark.parametrize("path", [None, "wrap", "plane"])
 def test_model_matches_the_reference_on_every_route(path, steps):
     """One device: the route ``auto`` picks (wrap) and each route forced, from
-    seeded random populations, every cell of all nineteen."""
+    seeded random populations, every cell of all nineteen (``_advance``)."""
     sim = _shared(path=path)
     state = _random_state(sim.setup.shape, 7)
     _load(sim, state)
-    sim.step(steps)
+    _advance(sim, steps)
     assert sim._step._stream_plan["route"] == (path or "wrap")
     assert _worst(sim, ref.steps(sim.setup, state, steps)) < TOL
 
@@ -112,11 +123,11 @@ def test_model_matches_the_reference_on_every_route(path, steps):
 def test_model_matches_the_reference_across_devices(mesh, steps):
     """CPU meshes: every diagonal read crosses a shard edge somewhere, so the
     x, then y, then z sweeps (or the pass's own fills on an unsplit axis)
-    must have left the EDGE halos filled."""
+    must have left the EDGE halos filled (``_advance``)."""
     sim = _shared(mesh=mesh)
     state = _random_state(sim.setup.shape, 11)
     _load(sim, state)
-    sim.step(steps)
+    _advance(sim, steps)
     assert tuple(sim.dd.mesh_dim()) == mesh
     assert _worst(sim, ref.steps(sim.setup, state, steps)) < TOL
 
@@ -153,8 +164,12 @@ def test_model_through_y_tiles_beside_a_split_y_matches_the_reference(mesh, monk
 @pytest.mark.parametrize("mesh", [(1, 1, 1), (2, 2, 1)])
 def test_the_seeded_state_matches_the_reference(mesh):
     """The seeded Taylor-Green state through ``fill(args=)``: the fills and a
-    dispatch of several macros and a remainder."""
-    sim = _sim(n=24, mesh=mesh, words=WORDS)
+    dispatch of several macros and a remainder, on the shared 16^3 model of the
+    mesh (24^3 and a model of its own until ISSUE 55: the fills and the program
+    are the same at either size, and the seven-step dispatch is the one
+    ``test_model_matches_the_reference_*[7]`` traces)."""
+    sim = _shared(mesh=mesh)
+    sim.fill(ref.seeded_fields(sim.setup), (np.asarray(WORDS, dtype=np.uint32),))
     want = ref.global_fields(sim.setup, WORDS)
     assert _worst(sim, want) < 5e-7
     sim.step(7)
@@ -223,7 +238,9 @@ def test_mass_and_momentum_are_conserved(which):
     else:
         sim = _shared()
         _load(sim, state)
-        sim.step(50)
+        for _ in range(7):  # (the seven-step dispatch the cases above trace, and one step)
+            sim.step(7)
+        sim.step(1)
         after = [sim.field(name) for name in ref.NAMES]
     mass, mom = _totals(after)
     assert abs(mass - mass0) / mass0 < 1e-6

@@ -177,14 +177,14 @@ def _resolve(sim, **request):
     return sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, req, True)
 
 
-def _tiled_bytes(n_y, n_z, rows, itemsize=4, tile=8, r=1):
-    """``plane_pass_vmem_bytes(y_tiles=)`` for D3Q19 by hand: 19 read and 19
-    written, 10 ringed."""
+def _tiled_bytes(n_y, n_z, rows, itemsize=4, tile=8, r=1, reads=19, rings=10, writes=19):
+    """``plane_pass_vmem_bytes(y_tiles=)`` by hand; D3Q19's counts where none are
+    given: 19 read and 19 written, 10 ringed."""
     pad = sp._padded_plane_bytes
     nt = n_y // rows
-    pipeline = 2 * 38 * pad(rows, n_z + 2 * r, itemsize)
-    held = (10 * (2 * r + 2) + 9 * 2) * nt * pad(rows + 2 * r * tile, n_z, itemsize)
-    staged = 19 * pad(rows, n_z, itemsize) + 38 * pad(tile, n_z, itemsize)
+    pipeline = 2 * (reads + writes) * pad(rows, n_z + 2 * r, itemsize)
+    held = (rings * (2 * r + 2) + (reads - rings) * 2) * nt * pad(rows + 2 * r * tile, n_z, itemsize)
+    staged = writes * pad(rows, n_z, itemsize) + (reads + writes) * pad(tile, n_z, itemsize)
     return pipeline + held + staged + sp._VMEM_STACK_MARGIN
 
 
@@ -318,6 +318,11 @@ def test_the_legality_prefilter_reads_the_tile_from_the_plan(monkeypatch):
 # --- the model through tiles, and what the pass allocates -------------------------------
 
 
+#: per tile: the realized model (one build, one traced dispatch a tile for every
+#: test that drives it)
+_SMALL = {}
+
+
 def _small_lbm(monkeypatch, rows):
     """``LatticeBoltzmann(6, 64, 256)`` under a budget that the y tile of ``rows``
     rows just fits: no wrap depth, no whole-plane pass, no larger tile."""
@@ -325,10 +330,20 @@ def _small_lbm(monkeypatch, rows):
     budget = _tiled_bytes(64, 256, rows)
     assert budget < MARGIN_19
     monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(budget))
-    sim = LatticeBoltzmann(6, 64, 256, interpret=True, seed_words=None,
-                           devices=jax.devices()[:1])
-    sim.realize()
-    return sim
+    if rows not in _SMALL:
+        sim = LatticeBoltzmann(6, 64, 256, interpret=True, seed_words=None,
+                               devices=jax.devices()[:1])
+        sim.realize()
+        _SMALL[rows] = sim
+    return _SMALL[rows]
+
+
+def _seed_populations(sim, seed):
+    rng = np.random.default_rng(seed)
+    state = [np.float32(w) * rng.uniform(0.6, 1.4, sim.setup.shape).astype(np.float32) for w in ref.W]
+    for name, a in zip(ref.NAMES, state):
+        sim.dd.set_quantity(sim.handles[name], a)
+    return state
 
 
 @pytest.mark.parametrize("rows", [64, 32, 16])
@@ -345,10 +360,7 @@ def test_the_model_through_y_tiles_matches_the_reference(rows, monkeypatch):
     plan = sim._step._stream_plan
     assert (plan["route"], plan["plane_window"], plan["plane_strip"]) == ("plane", "interior", 16)
     assert (plan["tile_rows"], plan["y_tiles"]) == (rows, 64 // rows)
-    rng = np.random.default_rng(rows)
-    state = [np.float32(w) * rng.uniform(0.6, 1.4, sim.setup.shape).astype(np.float32) for w in ref.W]
-    for name, a in zip(ref.NAMES, state):
-        sim.dd.set_quantity(sim.handles[name], a)
+    state = _seed_populations(sim, rows)
     sim.step(3)
     want = ref.steps(sim.setup, state, 3)
     worst = max(float(np.abs(sim.field(q) - np.asarray(w)).max()) for q, w in zip(ref.NAMES, want))
@@ -424,53 +436,83 @@ def test_a_stage_whose_whole_plane_passes_clash_takes_one_tiled_pass(monkeypatch
 
 
 # --- the lanes behind the window: a dispatch's two edges (ISSUE 54) ------------------------
+#
+# The forms are the pass's, indifferent to the kernel it runs: these cases drive
+# them with this file's three-quantity kernel (ISSUE 55: nineteen coupled
+# populations cost 45 s of lowering a case and showed nothing more).  The model's
+# own kernel goes through the same dispatch in ``test_the_model_through_y_tiles_
+# matches_the_reference`` (three steps: a first and a later call) and, beside a
+# split y, in ``tests/test_lbm.py``.
 
-#: per window: the realized model, its resolved plan and the two traced programs
+_LANE_NAMES = ("u", "c", "p")
+
+
+def _coupled_kernel(views, info):
+    """``_kernel(1)`` with ``c`` written too: a dispatch takes the window's lanes
+    only where its pass writes every quantity it reads (``plane_lanes_form``).
+    ``u`` is ringed and read on every kind of diagonal, both ways along z (the
+    lanes behind the window are its low z fill's) and along y (across a y tile's
+    margins), ``c`` along y and z, ``p`` at the centre."""
+    out = _kernel(1)(views, info)
+    return {**out, "c": 0.5 * views["c"].center() + 0.25 * views["u"].sh(0, -1, 1)}
+
+
+#: per window: the realized domain, its handles, its resolved plan and the two programs
 _LANES = {}
 
 
-def _lanes_case(window, monkeypatch):
-    """A model the planner tiles on ``window`` -- one device (6 x 64 x 256, two y
-    tiles of 32 rows) or mesh [2,2,1] on four CPU devices (shards of 4 x 32 x 256,
-    two y tiles of 16 rows beside the split y) --, its resolved plan (``plane_
-    lanes`` "window") and two programs of it, un-donated: the dispatch as built,
-    and the parent's, every call moving whole raw planes."""
+def _lanes_case(window):
+    """A domain of three quantities the planner tiles on ``window`` -- one device
+    (6 x 64 x 256, two y tiles of 32 rows) or mesh [2,2,1] on four CPU devices
+    (shards of 4 x 32 x 256, two y tiles of 16 rows beside the split y): the
+    smallest shards with two y tiles of whole strips and a z interior of whole
+    lane tiles --, its resolved plan (``plane_lanes`` "window") and two programs
+    of it, un-donated: the dispatch as built, and the parent's, every call
+    moving whole raw planes."""
     if window not in _LANES:
-        monkeypatch.setenv("STENCIL_HALO_BLEND", "1")
+        from stencil_tpu.core.radius import Radius
+        from stencil_tpu.domain import DistributedDomain
+
         mesh, shape, rows = {
             "interior": ((1, 1, 1), (6, 64, 256), 32),
             "interior-z": ((2, 2, 1), (8, 64, 256), 16),
         }[window]
-        monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(_tiled_bytes(shape[1] // mesh[1], 256, rows)))
-        sim = LatticeBoltzmann(*shape, interpret=True, seed_words=None,
-                               devices=jax.devices()[: int(np.prod(mesh))])
-        sim.dd.set_partition(*mesh)
-        sim.realize()
-        plan = _resolve(sim)
-        assert (plan["plane_window"], plan["tile_rows"], plan["y_tiles"]) == (window, rows, 2)
-        assert plan["plane_lanes"] == "window"
-        raw = dataclasses.replace(plan, plan={**plan.plan, "plane_lanes": "raw"})
-        build = lambda p: sm._build_stream_step(  # noqa: E731
-            sim.dd, sim._kernel, RADIUS, p, interpret=True, donate=False)
-        _LANES[window] = (sim, plan, build(plan), build(raw))
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("STENCIL_HALO_BLEND", "1")
+            mp.setenv("STENCIL_VMEM_LIMIT_BYTES", str(_tiled_bytes(
+                shape[1] // mesh[1], 256, rows, reads=3, rings=1, writes=3)))
+            dd = DistributedDomain(*shape)
+            dd.set_radius(Radius.constant(1))
+            dd.set_devices(jax.devices()[: int(np.prod(mesh))])
+            dd.set_partition(*mesh)
+            hs = [dd.add_data(name, dtype=jnp.float32) for name in _LANE_NAMES]
+            dd.realize()
+            plan = sp.resolve_stream_plan(
+                dd, _coupled_kernel, 1, sp.plan_stream(dd, 1, "plane", False), True)
+            assert (plan["plane_window"], plan["tile_rows"], plan["y_tiles"]) == (window, rows, 2)
+            assert plan["plane_lanes"] == "window" and len(plan["stages"][0]["passes"]) == 1
+            raw = dataclasses.replace(plan, plan={**plan.plan, "plane_lanes": "raw"})
+            build = lambda p: sm._build_stream_step(  # noqa: E731
+                dd, _coupled_kernel, 1, p, interpret=True, donate=False)
+            _LANES[window] = (dd, hs, plan, build(plan), build(raw))
     return _LANES[window]
 
 
-def _seeded_blocks(sim, seed, garbage=False):
-    """The model's raw blocks with seeded random populations in every interior
-    cell; the shell what ``set_quantity`` leaves (zeros), or with ``garbage``
-    large random numbers in every shell cell of every shard."""
+def _seeded_blocks(dd, handles, seed, garbage=False):
+    """The domain's raw blocks with seeded random values in every interior cell;
+    the shell what ``set_quantity`` leaves (zeros), or with ``garbage`` large
+    random numbers in every shell cell of every shard."""
     rng = np.random.default_rng(seed)
-    for name, w in zip(ref.NAMES, ref.W):
-        sim.dd.set_quantity(
-            sim.handles[name], np.float32(w) * rng.uniform(0.6, 1.4, sim.setup.shape).astype(np.float32))
-    blocks = dict(sim.dd._curr)
+    size = dd._size
+    for h in handles:
+        dd.set_quantity(h, rng.uniform(0.6, 1.4, (size.x, size.y, size.z)).astype(np.float32))
+    blocks = dict(dd._curr)
     if garbage:
-        n, lo = sim.dd.local_spec().sz, sim.dd.local_spec().radius.lo()
-        raw = sim.dd.local_spec().raw_size()
+        n, lo = dd.local_spec().sz, dd.local_spec().radius.lo()
+        raw = dd.local_spec().raw_size()
         inside = [
             (np.arange(g) % r >= a) & (np.arange(g) % r < a + m)
-            for g, r, a, m in zip(blocks["f0"].shape, raw, lo, n)
+            for g, r, a, m in zip(blocks[handles[0].name].shape, raw, lo, n)
         ]
         interior = inside[0][:, None, None] & inside[1][None, :, None] & inside[2][None, None, :]
         for name, b in blocks.items():
@@ -480,7 +522,7 @@ def _seeded_blocks(sim, seed, garbage=False):
 
 
 def _same_raw_cells(got, want):
-    for name in ref.NAMES:
+    for name in want:
         a, b = np.asarray(got[name]), np.asarray(want[name])
         assert np.isfinite(b).all() and np.array_equal(a, b), (
             name, np.argwhere(a != b)[:4].tolist())
@@ -488,38 +530,37 @@ def _same_raw_cells(got, want):
 
 @pytest.mark.parametrize("steps", [1, 2, 3, 6])
 @pytest.mark.parametrize("window", ["interior", "interior-z"])
-def test_a_dispatch_that_carries_the_z_shell_at_its_edges_is_bitwise_whole_calls(
-        window, steps, monkeypatch):
+def test_a_dispatch_that_carries_the_z_shell_at_its_edges_is_bitwise_whole_calls(window, steps):
     """Dispatches of 1, 2, 3 and 6 steps through the dispatch structure -- first
     call raw in / window out, every later call window in / raw out, the lane tile
     behind the window moved one way a call -- against the parent's program, every call
-    moving whole raw planes: EVERY raw cell of all nineteen populations bitwise
-    equal after the dispatch, the z shell, the tail rows and the x-halo planes
-    included; on four devices the y halo rows a neighbour sent too."""
-    sim, _, lanes, whole = _lanes_case(window, monkeypatch)
-    blocks = _seeded_blocks(sim, 54 + steps)
+    moving whole raw planes: EVERY raw cell of every quantity bitwise equal after
+    the dispatch, the z shell, the tail rows and the x-halo planes included; on
+    four devices the y halo rows a neighbour sent too."""
+    dd, hs, _, lanes, whole = _lanes_case(window)
+    blocks = _seeded_blocks(dd, hs, 54 + steps)
     want = whole(blocks, steps)
-    assert not np.array_equal(np.asarray(want["f1"]), np.asarray(blocks["f1"]))  # (the state moved)
+    assert not np.array_equal(np.asarray(want["u"]), np.asarray(blocks["u"]))  # (the state moved)
     _same_raw_cells(lanes(blocks, steps), want)
 
 
 @pytest.mark.parametrize("window", ["interior", "interior-z"])
-def test_the_first_call_of_a_dispatch_assumes_nothing_of_the_shell(window, monkeypatch):
+def test_the_first_call_of_a_dispatch_assumes_nothing_of_the_shell(window):
     """Blocks whose SHELL is garbage at entry, only the interior filled: the
     dispatch's first call still makes every fill the parent's makes (that is what
     the narrow calls behind it rest on), so three steps leave every raw cell as
     whole calls leave it -- and what they leave in the interior does not depend
     on the garbage."""
-    sim, _, lanes, whole = _lanes_case(window, monkeypatch)
-    blocks = _seeded_blocks(sim, 7, garbage=True)
+    dd, hs, _, lanes, whole = _lanes_case(window)
+    blocks = _seeded_blocks(dd, hs, 7, garbage=True)
     got = lanes(blocks, 3)
     _same_raw_cells(got, whole(blocks, 3))
-    clean = lanes(_seeded_blocks(sim, 7), 3)
-    for name in ("f0", "f5", "f18"):
-        sim.dd._curr = dict(got)
-        a = sim.field(name)
-        sim.dd._curr = dict(clean)
-        assert np.array_equal(a, sim.field(name)), name
+    clean = lanes(_seeded_blocks(dd, hs, 7), 3)
+    for h in hs:
+        dd._curr = dict(got)
+        a = dd.quantity_to_host(h)
+        dd._curr = dict(clean)
+        assert np.array_equal(a, dd.quantity_to_host(h)), h.name
 
 
 def _pass_blocks(closed):
@@ -545,19 +586,18 @@ def _pass_blocks(closed):
 @pytest.mark.parametrize("steps,forms", [
     (1, ["ZZ"]), (2, ["ZW", "WZ"]), (3, ["ZW", "WZ"]), (6, ["ZW", "WZ"]),
 ])
-def test_a_call_moves_the_lane_tile_behind_the_window_one_way(
-        window, steps, forms, monkeypatch):
+def test_a_call_moves_the_lane_tile_behind_the_window_one_way(window, steps, forms):
     """Bytes counted, never time: the traced dispatch holds one call a form --
     the loop body once --: the first call's out blocks and every later call's in
     blocks end in ``Zw`` = 256 lanes where the parent's end in ``Z`` = 258 on
     both sides; the first call's in blocks and the later calls' out blocks are
     whole rows.  A dispatch of ONE step is one whole call; the parent's program
     of six is one whole call in its loop."""
-    sim, _, lanes, whole = _lanes_case(window, monkeypatch)
+    dd, _, _, lanes, whole = _lanes_case(window)
     width = {"Z": 258, "W": 256}
-    closed = jax.make_jaxpr(lanes, static_argnums=1)(sim.dd._curr, steps)
+    closed = jax.make_jaxpr(lanes, static_argnums=1)(dd._curr, steps)
     assert _pass_blocks(closed) == sorted((width[f[0]], width[f[1]]) for f in forms)
-    closed = jax.make_jaxpr(whole, static_argnums=1)(sim.dd._curr, steps)
+    closed = jax.make_jaxpr(whole, static_argnums=1)(dd._curr, steps)
     assert _pass_blocks(closed) == [(width["Z"], width["Z"])]
 
 
@@ -616,7 +656,9 @@ def test_domain_step_says_the_lanes_of_the_plan(shape, mesh, kw, said, monkeypat
 
 
 def test_a_dispatch_says_its_lanes_on_the_span(monkeypatch):
-    """The span a dispatch of the model opens, held to the plan it ran."""
+    """The span a dispatch of the model opens, held to the plan it ran (the
+    model and the three-step dispatch ``test_the_model_through_y_tiles_matches_
+    the_reference[32]`` built: nothing is traced again)."""
     from stencil_tpu import telemetry
     from stencil_tpu.telemetry import names as tm
 
@@ -629,11 +671,11 @@ def test_a_dispatch_says_its_lanes_on_the_span(monkeypatch):
         return real(name, *a, **kw)
 
     monkeypatch.setattr(telemetry, "span", spy)
-    _seeded_blocks(sim, 3)
-    sim.step(2)
+    _seed_populations(sim, 3)
+    sim.step(3)
     (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
     plan = sim._step._stream_plan
-    assert (kw["steps"], kw["plane_lanes"]) == (2, "window")
+    assert (kw["steps"], kw["plane_lanes"]) == (3, "window")
     assert (kw["plane_lanes"], kw["tile_rows"], kw["y_tiles"]) == (
         plan["plane_lanes"], plan["tile_rows"], plan["y_tiles"])
     assert not sim._step._resilience.descents

@@ -568,7 +568,13 @@ class Jacobi3D:
         # the block as the macro exchanges it -- the raw block whole, or its
         # x and y sweeps (in ring mode over the z interior alone) beside the
         # z-slab buffers' permutes
-        from stencil_tpu.ops.exchange import WireAccount, exchange_account, sum_hops, z_slab_hops
+        from stencil_tpu.ops.exchange import (
+            WireAccount,
+            exchange_account,
+            slab_wrap_axes,
+            sum_hops,
+            z_slab_hops,
+        )
 
         dtype = dd.field_dtype(self.h)
         if z_slab_mode:
@@ -586,6 +592,9 @@ class Jacobi3D:
             span_args["z_halo_patch"] = z_halo_patch_form(
                 _ZRING_OFF + n.z if z_ring_mode else Zp, m
             )
+            # ... and the axes on which a macro's slab extension is the
+            # self-wrap kernel, nothing sent to oneself
+            span_args["slab_wrap"] = slab_wrap_axes(mesh_shape, Xr, Yr, m, [dtype])
         return self._declare_wires(
             step, WireAccount(1, hops, depth_run, joint=swept.joint), **span_args)
 

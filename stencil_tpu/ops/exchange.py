@@ -37,7 +37,12 @@ halos, no slab cut, no message and no unpack, like the reference's same-GPU
 ``PeerAccessSender`` copy kernels (tx_cuda.cuh:39-104).  Where the blend
 kernels are off (CPU, ``STENCIL_HALO_BLEND=0``) or cannot engage (N-D blocks,
 exotic dtypes) such an axis still ppermutes to itself, which is the same
-periodic boundary by the general path.
+periodic boundary by the general path.  The z-slab routes' slab BUFFERS
+(``ops/stream.py permute_and_extend_z_slabs``: z-major ``(Xr, 2s, Yr)``, the z
+halo kept out of the big array) go by the same rule since PR 56
+(``slab_wrap_axes``): on an unsplit z the outgoing buffer is the incoming one,
+on an unsplit y or x ``wrap_halo`` fills the buffer's own shell in place, and
+only a split axis cuts halves, sends and lands them.
 
 The y and z sweeps have selectable ROUTES (``EXCHANGE_ROUTES``, a tuner
 axis — docs/tuning.md "Exchange routes"): ``direct`` sends the thin sliver
@@ -735,13 +740,35 @@ def z_slab_hops(mesh_shape: Tuple[int, int, int], Xr: int, Yr: int, s: int,
     permute_and_extend_z_slabs``), one z-major ``(Xr, 2s, Yr)`` slab buffer a
     quantity: its two ``(Xr, s, Yr)`` halves over z, each then extended by
     ``(Xr, s, s)`` rows from either y neighbour and ``(s, s, Yr)`` planes from
-    either x neighbour.  As ``exchange_account``'s hops: split axes only."""
+    either x neighbour.  As ``exchange_account``'s hops: split axes only (an
+    unsplit axis sends to itself, or, where ``slab_wrap_axes`` names it,
+    nothing at all)."""
     cells = {"x": 2 * s * s * Yr, "y": 2 * Xr * s * s, "z": Xr * s * Yr}
     return {
         (MESH_AXES[a], side): cells[MESH_AXES[a]] * sum(itemsizes)
         for a in range(3) if mesh_shape[a] > 1
         for side in ("low", "high")
     }
+
+
+def slab_wrap_axes(mesh_shape: Tuple[int, int, int], Xr: int, Yr: int, s: int, dtypes) -> str:
+    """The mesh axes (a substring of ``"xyz"``) on which one macro's z-slab
+    extension (``ops/stream.py permute_and_extend_z_slabs``) is a SELF-WRAP:
+    on z the outgoing ``(Xr, 2s, Yr)`` buffer is the incoming one, on y and x
+    ``halo_blend.wrap_halo`` fills the buffer's own shell in place -- what
+    ``domain.step`` says as ``slab_wrap``.  ``_sweep_kind``'s answer for a 3-D
+    block of the buffer's extents on the ``direct`` route: the mesh does not
+    split the axis, the dtype's tile geometry is known and the blend kernels
+    are on; "" wherever they are not (the CPU default), and every slab hop is
+    then a ``ppermute``, to oneself on an unsplit axis."""
+    # (extent, halo width) of the buffer along x, y and z; along z it IS the
+    # halo, the two directions' s planes side by side, and holds none to fill
+    shells = ((Xr, s), (Yr, s), (0, 0))
+    return "".join(
+        MESH_AXES[a]
+        for a, (size, r) in enumerate(shells)
+        if _sweep_kind(a, r, r, mesh_shape[a], size, None, "direct", dtypes, True) == "wrap"
+    )
 
 
 def sum_hops(*plans: Mapping[Tuple[str, str], int]) -> Dict[Tuple[str, str], int]:

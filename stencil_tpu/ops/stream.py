@@ -94,11 +94,15 @@ def prime_z_slabs(block: jax.Array, Zr: int, s: int) -> jax.Array:
 
 
 def make_slab_extenders(Xr: int, Yr: int, s: int, mesh_shape, axis_names=None):
-    """(yext, xext) for z-major slab buffers: after the z ppermute, each slab
+    """(yext, xext) for z-major slab buffers, the WIRED form: each slab half
     is extended with rows from the y neighbors and then planes from the x
     neighbors — two hops that carry the xyz-corner cells from the diagonal
     blocks, mirroring the in-array exchange's sweep order.  Shared by the
-    generic engine and the bespoke jacobi wavefront."""
+    generic engine and the bespoke jacobi wavefront.
+    ``permute_and_extend_z_slabs`` calls them on the axes the mesh splits, and
+    on every axis where the blend kernels cannot engage; an axis the mesh does
+    not split takes ``halo_blend.wrap_halo`` there instead (``ops/exchange.py
+    slab_wrap_axes``)."""
     from stencil_tpu.ops.exchange import _shift_from_high, _shift_from_low
 
     names = MESH_AXES if axis_names is None else axis_names
@@ -120,16 +124,58 @@ def make_slab_extenders(Xr: int, Yr: int, s: int, mesh_shape, axis_names=None):
 
 def permute_and_extend_z_slabs(zout, s: int, mesh_shape, yext, xext):
     """One macro's incoming z-slab buffer from the previous macro's outgoing
-    one: ppermute the two direction halves along z, then extend with y- and
+    one: send the two direction halves along z, then extend with y- and
     x-neighbor content (corner propagation).  This IS the z sweep of the
     z-slab routes: all of it sits under the ``exchange.z`` scope (the y/x
-    extension hops nest their own direction scopes inside)."""
-    from stencil_tpu.ops.exchange import _shift_from_high, _shift_from_low
+    extension hops nest their own direction or self-wrap scopes inside).
+
+    Each axis takes the form the in-array exchange gives it
+    (``ops/exchange.py slab_wrap_axes``, ``_sweep_kind``'s rule; ``domain.step``
+    says ``slab_wrap``).  Where the mesh SPLITS it, or the blend kernels cannot
+    engage: the ``ppermute``s, on the two ``(Xr, s, Yr)`` halves (``yext`` /
+    ``xext`` land what they receive with ``.at[].set``, which XLA runs as a
+    copy of the whole half: 67 us a call at ``(518, 3, 518)``, PERF.md §6 PR
+    56).  Where it does not: nothing is sent to oneself.  On z the outgoing
+    buffer ``[(-z)-bound | (+z)-bound]`` IS the incoming one, uncut; on y and
+    x the halo of the slab is the slab's own interior, whatever the middle
+    (z-side) axis holds, so ``halo_blend.wrap_halo`` fills it in the WHOLE
+    buffer, both halves at once, in place -- ``zout`` is consumed as its only
+    operand.  The order stays y then x (the x halo planes carry the y-extended
+    rows); halves are cut, and put together again, only between an axis of one
+    form and an axis of the other."""
+    from stencil_tpu.ops import halo_blend
+    from stencil_tpu.ops.exchange import _shift_from_high, _shift_from_low, slab_wrap_axes
+
+    Xr, _, Yr = zout.shape
+    wraps = slab_wrap_axes(mesh_shape, Xr, Yr, s, [zout.dtype])
+
+    def halves(v):
+        return v if isinstance(v, tuple) else (v[:, 0:s, :], v[:, s : 2 * s, :])
+
+    def wrapped(v, name, axis, raw):
+        whole = jnp.concatenate(v, axis=1) if isinstance(v, tuple) else v
+        with jax.named_scope(tm.exchange_wrap_span(name)):
+            return halo_blend.wrap_halo(
+                whole, axis, s, s, raw - 2 * s, interpret=halo_blend.interpret_mode()
+            )
 
     with telemetry.annotate(tm.SPAN_EXCHANGE_Z):
-        zlo = _shift_from_low(zout[:, 0:s, :], MESH_AXES[2], mesh_shape[2])
-        zhi = _shift_from_high(zout[:, s : 2 * s, :], MESH_AXES[2], mesh_shape[2])
-        return jnp.concatenate([xext(yext(zlo)), xext(yext(zhi))], axis=1)
+        v = zout
+        if "z" not in wraps:
+            v = (
+                _shift_from_low(zout[:, 0:s, :], MESH_AXES[2], mesh_shape[2]),
+                _shift_from_high(zout[:, s : 2 * s, :], MESH_AXES[2], mesh_shape[2]),
+            )
+        if "y" not in wraps and "x" not in wraps:
+            # half by half, as the program without self-wraps always was
+            v = tuple(xext(yext(h)) for h in halves(v))
+        else:
+            for name, axis, raw, ext in (("y", 2, Yr, yext), ("x", 0, Xr, xext)):
+                if name in wraps:
+                    v = wrapped(v, name, axis, raw)
+                else:
+                    v = tuple(ext(h) for h in halves(v))
+        return jnp.concatenate(v, axis=1) if isinstance(v, tuple) else v
 
 
 def macro_loop(macro, macros: int, carry, per_trip: int):
@@ -1050,6 +1096,9 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         # ... and where its lane padding lives: "vmem" (the pass widens
         # the raw block's plane itself) or "none" (nothing to pad)
         args["lane_pad"] = plan["lane_pad"]
+        # ... and the axes on which its slab extension sends nothing to
+        # itself: the self-wrap kernel, in place (slab_wrap_axes)
+        args["slab_wrap"] = plan["slab_wrap"]
     # the axes whose sweep of the step's exchanges crosses to another shard,
     # and the bytes one shard receives over them a raw step, all stages (the
     # plan's ``wire_account``, which ``run_step`` counts the wires from; ops/

@@ -1489,7 +1489,7 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
     ``interpret`` picks the rotate the traces use (``trace_plane_kernel``).
     The one abstract trace a group of each stage's kernel is made here, once.
     Raises ``ValueError`` for a plane step that fits in no pass."""
-    from stencil_tpu.ops.exchange import sum_accounts
+    from stencil_tpu.ops.exchange import slab_wrap_axes, sum_accounts
 
     names = [h.name for h in dd._handles]
     raw = dd.local_spec().raw_size()
@@ -1623,6 +1623,13 @@ def resolve_stream_plan(dd, kernel, x_radius: int, request: Mapping, interpret: 
         # blocks and pads or cuts nothing
         plan["z_halo_patch"] = z_halo_patch_form(lane_pad_width(raw.z), dd._shell_radius.lo().x)
         plan["lane_pad"] = "vmem" if raw.z % 128 else "none"
+        # ... and the axes on which a macro's slab extension is the self-wrap,
+        # nothing sent to oneself (domain.step's ``slab_wrap``): a function of
+        # the mesh, the dtypes and the backend, as ``wrapped`` is
+        plan["slab_wrap"] = slab_wrap_axes(
+            tuple(dd.mesh.shape[a] for a in MESH_AXES), raw.x, raw.y, dd._shell_radius.lo().x,
+            [dd.field_dtype(h) for h in dd._handles],
+        )
     if route == "plane":
         # the steps one trip of the step loop runs (domain.step's
         # ``steps_per_trip``, the build's ``ResolvedPlan.period``): the period

@@ -543,7 +543,15 @@ ALL_HISTOGRAMS = frozenset({
 #: lane tiles itself, through a boundary block (``astaroth-8q-512.bulk``: 518
 #: lanes in a 640-lane plane), "none" = the raw z extent is a multiple of 128
 #: already (read off ``Zr % 128``; the step pads and cuts nothing in HBM
-#: either way); every stream-engine step says what its kernels READ
+#: either way); every z-slab wavefront step says slab_wrap = the axes on which
+#: a macro's slab extension sends nothing to itself -- on z the outgoing slab
+#: buffer is the incoming one, on y and x the self-wrap kernel fills the
+#: buffer's shell in place, no ``ppermute`` to oneself and no
+#: ``dynamic_update_slice`` (``ops/exchange.slab_wrap_axes``, ``_sweep_kind``'s
+#: rule read off the mesh, the dtypes and the backend, as ``wrapped`` is: "xyz"
+#: in ``astaroth-8q-512.bulk``, "z" on mesh [2,2,1], "" wherever the blend
+#: kernels cannot engage, the CPU's default among them; the axes ``wired``
+#: names are never in it); every stream-engine step says what its kernels READ
 #: beside what the route SERVES: quantities = the quantities it carries,
 #: offcentre = those read at a non-zero offset, diagonal = those of them read
 #: at an offset with two or more non-zero components (an edge or corner halo),

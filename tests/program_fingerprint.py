@@ -137,6 +137,10 @@ def _jacobi_zring():
     m.dd.set_partition(2, 2, 1)
     m.realize()
     assert m._pallas_path == "wavefront" and m._wavefront_z_ring and m._wavefront_m == 3
+    # x and y are wired; on z the outgoing slab buffer is the incoming one (ISSUE 56:
+    # ``program_fingerprint`` traces every model with the blend kernels on)
+    args = m._step._span_args()
+    assert (args["wired"], args["slab_wrap"]) == ("xy", "z"), args
     return _trace_step(m.dd, m._step)
 
 
@@ -151,6 +155,9 @@ def _astaroth():
     s.realize()
     plan = s._step._stream_plan
     assert (plan["route"], plan["m"]) == ("wavefront", 3), plan
+    # every slab extension is the self-wrap kernel, nothing is sent (ISSUE 56)
+    args = s._step._span_args()
+    assert (args["wired"], args["slab_wrap"]) == ("", "xyz"), args
     return _trace_step(s.dd, s._step)
 
 

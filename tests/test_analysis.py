@@ -716,6 +716,32 @@ def test_check_kernel_legal_takes_the_z_slab_boundary_block(z, want):
     assert not kernels._off_granule_boundary(200, 200, 128)
 
 
+@pytest.mark.parametrize("build,wires", [
+    (pfp._astaroth, ()), (pfp._jacobi_zring, ("x", "y"))], ids=["astaroth-1x1x1", "jacobi-zring-2x2x1"])
+def test_the_z_slab_extension_sends_nothing_to_itself(build, wires):
+    """The z sweep of the two z-slab cells' programs, traced with the blend
+    kernels on as the chip has them (ISSUE 56): under an ``exchange.z`` scope
+    the one-device program holds no ``ppermute`` and no ``.at[].set`` (a
+    ``scatter`` here, a whole-half ``dynamic-update-slice`` on the chip) -- its
+    slab extension is two self-wrap kernels a quantity a macro, under the
+    ``exchange.<axis>.wrap`` scopes --, and the mesh-[2,2,1] one holds them for
+    the axes the mesh splits alone, one landing a received piece: nothing is
+    sent over z, to oneself."""
+    with aprog.tpu_shaped_trace():
+        closed = build()
+    under_z = [e for e in jx.iter_eqns(closed) if "exchange.z" in jx.name_stack_str(e).split("/")]
+    sent = [e.params["axis_name"] for e in under_z if e.primitive.name == "ppermute"]
+    landed = [e for e in under_z if e.primitive.name in ("scatter", "dynamic_update_slice")]
+    wraps = [e for e in under_z if e.primitive.name == "pallas_call"]
+    assert {a for axes in sent for a in axes} == set(wires), sent
+    assert len(landed) == len(sent)
+    if wires:
+        assert not wraps and all(sent.count((a,)) == len(sent) // len(wires) for a in wires)
+    else:
+        scopes = [s for e in wraps for s in jx.name_stack_str(e).split("/") if s.endswith(".wrap")]
+        assert wraps and scopes.count("exchange.y.wrap") == scopes.count("exchange.x.wrap") == len(wraps) // 2
+
+
 # --- tier-2: the real CLI end to end -----------------------------------------
 
 

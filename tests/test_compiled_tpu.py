@@ -329,6 +329,32 @@ def test_compiled_self_wrap_518(axis):
     assert bool(jnp.array_equal(got, want))
 
 
+@pytest.mark.parametrize("shape,s", [((518, 6, 518), 3), ((544, 32, 544), 16)],
+                         ids=["astaroth-518x6x518", "jacobi-x4-544x32x544"])
+def test_compiled_self_wrap_of_a_z_slab_buffer(shape, s):
+    """The self-wrap kernel on a z-major ``(Xr, 2s, Yr)`` slab buffer, as
+    ``permute_and_extend_z_slabs`` calls it on an axis the mesh does not split
+    (ISSUE 56): the y extension (axis 2, lane tiles 0 and 4) and then the x
+    extension (axis 0, ``2s`` planes) at the astaroth cell's own buffer -- a
+    SIX-row sublane dim, under the 8-row tile, which interpret mode cannot
+    vouch for -- and at the jacobi x4 cell's, against the numpy twin of
+    ``wrap_halo`` (``tests/test_plane_stencil.py _self_wrap``) on every cell."""
+    from test_plane_stencil import _self_wrap
+
+    from stencil_tpu.ops.halo_blend import wrap_halo
+
+    rng = np.random.default_rng(56)
+    host = rng.standard_normal(shape).astype(np.float32)
+    want = _self_wrap(_self_wrap(host, 2, s, s), 0, s, s)
+
+    @jax.jit
+    def both(b):
+        b = wrap_halo(b, 2, s, s, shape[2] - 2 * s)
+        return wrap_halo(b, 0, s, s, shape[0] - 2 * s)
+
+    np.testing.assert_array_equal(np.asarray(both(jnp.asarray(host))), want)
+
+
 def test_compiled_plane_pass_wraps_the_planes_it_loads(monkeypatch):
     """The plane pass's own y / z halo fills (ISSUE 34) as Mosaic compiles
     them, on acoustic's plane -- 608 x 608 f32, radius 4: sublane tiles 0 and
@@ -502,12 +528,17 @@ def test_compiled_jacobi_macro_loop_is_bitwise_one_a_trip(chips, monkeypatch):
         return sim._step._span_args(), seen
 
     # the z-ring step also says where its kernel patches the z halo (ISSUE 40)
-    patch = {} if chips == 1 else {"z_halo_patch": "tile"}
+    # and that over z its slab buffers send nothing to themselves (ISSUE 56)
+    patch = {} if chips == 1 else {"z_halo_patch": "tile", "slab_wrap": "z"}
+
+    def said(args):  # but the wires' (PR 49, ISSUE 50: tests/test_wire_account.py)
+        return {k: v for k, v in args.items() if k not in ("wired", "wire_bytes", "joint")}
+
     args, got = run()
-    assert args == {"macros_per_trip": 2, **patch}
+    assert said(args) == {"macros_per_trip": 2, **patch}
     monkeypatch.setattr(jm, "_macros_per_trip", lambda in_place: 1)
     args_one, want = run()
-    assert args_one == {"macros_per_trip": 1, **patch}
+    assert said(args_one) == {"macros_per_trip": 1, **patch}
     for a, b in zip(got, want):
         assert np.isfinite(b).all() and 0.0 <= b.min() < 0.4 and 0.6 < b.max() <= 1.0
         assert np.array_equal(a, b), float(np.max(np.abs(a - b)))

@@ -33,8 +33,11 @@ CASES = [("wrap", (1, 1, 1))] + [
 
 
 # what ``domain.step`` says beside ``macros_per_trip``: the z-slab routes patch
-# their z halo inside its lane tiles on these lane-aligned planes (ISSUE 40)
-Z_HALO_PATCH = {"ring": {"z_halo_patch": "tile"}, "zslab": {"z_halo_patch": "tile"}}
+# their z halo inside its lane tiles on these lane-aligned planes (ISSUE 40),
+# and no slab extension of theirs is a self-wrap with the blend kernels off
+# (ISSUE 56: tests/test_slab_step.py has them on)
+Z_HALO_PATCH = {"ring": {"z_halo_patch": "tile", "slab_wrap": ""},
+                "zslab": {"z_halo_patch": "tile", "slab_wrap": ""}}
 
 
 def _seeded(x, y, z):

@@ -422,7 +422,8 @@ def test_jacobi_macro_loop_carries_its_block_in_place(chips, monkeypatch):
     # ... and every step what it sends over wires (PR 49) and which sweeps fly jointly (ISSUE 50):
     # the 16-wide shell's faces, the z slabs' extensions and the two corner relays behind the y faces
     patch = {"wired": "", "wire_bytes": 0, "joint": ""} if chips == 1 else {
-        "z_halo_patch": "tile", "wired": "xy", "wire_bytes": 4_734_976 + 2 * 32 * 16 * 512 * 4 // 16,
+        # (the blend kernels are off where the default backend is the CPU: no slab self-wrap, ISSUE 56)
+        "z_halo_patch": "tile", "slab_wrap": "", "wired": "xy", "wire_bytes": 4_734_976 + 2 * 32 * 16 * 512 * 4 // 16,
         "joint": "xy"}
     text, temp, args = got[None, macros]
     assert args == {"macros_per_trip": 2, **patch}

@@ -495,6 +495,7 @@ class TestLadderEngines:
             # raw block (ISSUE 41)
             "z_halo_patch": "tile",
             "lane_pad": "vmem",
+            "slab_wrap": "",  # every axis of mesh [2,2,2] is wired (ISSUE 56)
             # what a macro sends over the wires of mesh [2,2,2] (ISSUE 49)
             "wire_account": step._stream_plan["wire_account"],
             "wired": "xyz", "wire_bytes": step._stream_plan["wire_account"].said()[1],
@@ -505,7 +506,7 @@ class TestLadderEngines:
         dd.run_step(step, 4)
         assert step._stream_plan["route"] == "plane"
         # the z-slab wavefront's alone
-        assert "z_halo_patch" not in step._stream_plan and "lane_pad" not in step._stream_plan
+        assert not {"z_halo_patch", "lane_pad", "slab_wrap"} & set(step._stream_plan)
         # a depth descent re-plans: the plane rung resolves its OWN alias
         # (in place) instead of inheriting the wavefront rung's
         assert step._stream_plan["alias"] is True

@@ -151,3 +151,92 @@ def test_wavefront_macro_hlo_permute_count(monkeypatch):
         "collective-permute-start("
     )
     assert n_permutes == 16, n_permutes
+
+
+# --- the z-slab buffers' self-wrap (ISSUE 56) ---------------------------------
+#
+# On an axis the mesh does not split, a macro's slab extension is the in-place
+# self-wrap kernel (``ops/stream.py permute_and_extend_z_slabs``, ``ops/
+# exchange.py slab_wrap_axes``) where the blend kernels engage -- and the
+# ``ppermute`` to oneself + ``.at[].set`` where they do not, the CPU's default.
+# The two are one arithmetic: held bitwise here, mesh by mesh.
+
+
+def _zslab_step(mesh, blend, monkeypatch, nq=2):
+    """A realized 16^3 domain on ``mesh`` with a three-deep shell and its
+    stream-engine z-slab wavefront step of the light ``mean6_kernel``, built
+    with the blend kernels forced ``blend`` ("0" / "1"; interpreted)."""
+    from test_stream import mean6_kernel
+
+    from stencil_tpu.core.radius import Radius
+    from stencil_tpu.domain import DistributedDomain
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", blend)
+    dd = DistributedDomain(16, 16, 16)
+    dd.set_radius(Radius.constant(1))
+    dd.set_devices(jax.devices()[: mesh[0] * mesh[1] * mesh[2]])
+    dd.set_partition(*mesh)
+    dd.set_halo_multiplier(3)
+    hs = [dd.add_data(f"q{i}") for i in range(nq)]
+    dd.realize()
+    for i, h in enumerate(hs):
+        dd.init_by_coords(h, lambda x, y, z, i=i: jnp.sin(0.13 * (x + 2 * y + 3 * z) + i))
+    step = dd.make_step(mean6_kernel, engine="stream", interpret=True, stream_path="wavefront")
+    plan = step._stream_plan
+    assert (plan["route"], plan["m"], plan["z_slabs"]) == ("wavefront", 3, True), plan
+    return dd, hs, step
+
+
+@pytest.mark.parametrize("mesh,wraps", [
+    ((1, 1, 1), "xyz"), ((2, 1, 1), "yz"), ((1, 2, 1), "xz"), ((2, 2, 1), "z")],
+    ids=["1x1x1", "2x1x1", "1x2x1", "2x2x1"])
+def test_zslab_self_wrap_is_bitwise_the_permute_path(mesh, wraps, monkeypatch):
+    """Two macros and a remainder of the z-slab wavefront step, two
+    quantities: with the blend kernels on, the slab extension of every unsplit
+    axis is the self-wrap kernel (``slab_wrap`` on the plan and the span), and
+    every interior cell is bitwise the blend-off program's, whose every slab
+    hop is a ``ppermute`` (``slab_wrap`` "")."""
+    got = {}
+    for blend, said in (("0", ""), ("1", wraps)):
+        dd, hs, step = _zslab_step(mesh, blend, monkeypatch)
+        assert step._stream_plan["slab_wrap"] == said == step._span_args()["slab_wrap"]
+        assert not set(said) & set(step._span_args()["wired"])
+        dd.run_step(step, 7)
+        got[blend] = [dd.quantity_to_host(h) for h in hs]
+    for off, on in zip(got["0"], got["1"]):
+        np.testing.assert_array_equal(off, on)
+
+
+@pytest.mark.parametrize("dtype,blend", [
+    (jnp.float32, "1"), (jnp.bfloat16, "1"), (jnp.float32, "0")],
+    ids=["f32-wrap", "bf16-wrap", "f32-permute"])
+def test_permute_and_extend_z_slabs_matches_the_numpy_self_wrap(dtype, blend, monkeypatch):
+    """The function alone on one device, on a seeded ``(Xr, 2s, Yr)`` buffer
+    whose ``2s`` = 6 rows are under the 8- (f32) and the 16-row (bf16) sublane
+    tile, its y halo and the cells it copies in lane tiles 0 and 2:
+    ``xext(yext(.))`` of both halves is the numpy twin of ``wrap_halo``
+    (``tests/test_plane_stencil.py _self_wrap``) along y, then along x, on
+    every cell -- by the self-wrap kernels, and by the ``ppermute``s to oneself
+    that the blend-off program keeps."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from test_plane_stencil import _self_wrap
+
+    from stencil_tpu.analysis import jaxpr as jx
+    from stencil_tpu.ops.stream import make_slab_extenders, permute_and_extend_z_slabs
+    from stencil_tpu.parallel.mesh import MESH_AXES
+
+    monkeypatch.setenv("STENCIL_HALO_BLEND", blend)
+    Xr, s, Yr = 20, 3, 262
+    host = np.random.default_rng(56).standard_normal((Xr, 2 * s, Yr)).astype(np.float32)
+    S = jnp.asarray(host).astype(dtype)
+    want = _self_wrap(_self_wrap(np.asarray(S), 2, s, s), 0, s, s)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1), MESH_AXES)
+    yext, xext = make_slab_extenders(Xr, Yr, s, (1, 1, 1))
+    fn = jax.jit(jax.shard_map(
+        lambda z: permute_and_extend_z_slabs(z, s, (1, 1, 1), yext, xext),
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
+    ))
+    prims = {e.primitive.name for e in jx.iter_eqns(jax.make_jaxpr(fn)(S))}
+    assert ("ppermute" in prims) == (blend == "0") == ("pallas_call" not in prims), prims
+    np.testing.assert_array_equal(np.asarray(fn(S)), want)
+

@@ -448,6 +448,9 @@ def test_stream_separable_per_field_grouping(monkeypatch):
         # 30-lane raw block (ISSUE 41)
         "z_halo_patch": "tile",
         "lane_pad": "vmem",
+        # no slab extension is a self-wrap on mesh [2,2,2], nor anywhere with
+        # the blend kernels off (ISSUE 56)
+        "slab_wrap": "",
         # the account of the wires a macro crosses (ISSUE 49: tests/test_wire_account.py)
         "wire_account": step._stream_plan["wire_account"],
         "wired": step._stream_plan["wire_account"].said()[0],
@@ -457,6 +460,7 @@ def test_stream_separable_per_field_grouping(monkeypatch):
     }
     assert step._span_args()["z_halo_patch"] == "tile"
     assert step._span_args()["lane_pad"] == "vmem"
+    assert step._span_args()["slab_wrap"] == ""
     monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
     ref_dd, ref_hs = _mk(24, 24, 24, Radius.constant(1), names, devs)
     ref = ref_dd.make_step(mean6_kernel, overlap=False)

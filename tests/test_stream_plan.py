@@ -107,7 +107,8 @@ def test_resolving_leaves_the_request_as_it_was(dom, extra):
     assert request == before
     assert plan.plan is not request and set(request) < set(plan)
     assert all(plan[key] == request[key] for key in ("route", "m", "z_slabs", "grouping"))
-    stale = dict(plan.plan, wired="xyz", wire_bytes=-1, macros_per_trip=7, lane_pad="hbm")
+    stale = dict(plan.plan, wired="xyz", wire_bytes=-1, macros_per_trip=7, lane_pad="hbm",
+                 slab_wrap="q")
     again = sp.resolve_stream_plan(dd, _mean2, 1, stale, True)
     assert again.plan == plan.plan
 

@@ -265,6 +265,9 @@ def test_the_hop_counters_move_by_the_bytes_of_the_steps_ppermutes(case):
     raw = said["steps"]
     assert after[tm.EXCHANGE_COUNT] - exchanges == account.units(raw) * account.exchanges > 0
     assert said["wired"] == "".join(a for a in MESH_AXES if any(axis == a for axis, _ in sent))
+    # a z-slab step also says on which axes its slab extension is the self-wrap kernel: on
+    # none with the blend kernels off, as here (ISSUE 56; on: tests/test_slab_step.py)
+    assert said.get("slab_wrap") == ("" if "z_halo_patch" in said else None)
     if raw % account.every == 0:  # whole macros: the per-step figure is exact
         assert said["wire_bytes"] * raw == sum(sent.values()), (said, sent)
     # the sweeps that flew jointly: said on the span, counted from the same account

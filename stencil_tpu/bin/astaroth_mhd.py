@@ -76,6 +76,7 @@ def main(argv=None) -> int:
         f"wire_bytes={plan.get('wire_bytes', 0)} wired_edges={plan.get('wired_edges', '')!r} "
         f"route={plan.get('route')!r} stages={plan.get('stages')} renamed={plan.get('renamed')} "
         f"plane_window={plan.get('plane_window')!r} plane_strip={plan.get('plane_strip')} "
+        f"passes={plan.get('passes')} passes_by_stage={plan.get('passes_by_stage', '')!r} "
         f"read_sides={plan.get('read_sides')} exchanged_sides={plan.get('exchanged_sides')} "
         f"hops={_common.step_hops_str(sim._step)!r}",
         file=sys.stderr,

@@ -146,13 +146,37 @@ class MhdSetup:
         return tuple(b / n for b, n in zip(self.sides, self.shape))
 
 
+#: Williamson's RK3 is stable on the negative real axis down to -2.51, and the
+#: sixth-order second difference reaches -6.04 / dx^2 an axis (272 / 45)
+RK3_REAL_BOUND = 2.51
+D2_SPECTRAL_RADIUS = 272.0 / 45.0
+#: the share of that bound the fixed step takes of the stiffest diffusion
+DIFFUSIVE_SHARE = 0.8
+
+
 def dt_of(setup: MhdSetup) -> float:
-    """The fixed time step: ``courant`` times the smallest ``dx`` over ``cs0 +
+    """The fixed time step, by Astaroth's own rule the smaller of the advective
+    and the diffusive limit: ``courant`` times the smallest ``dx`` over ``cs0 +
     |u|max``, ``|u|max = sqrt(3) amplitude`` the seeded state's bound (the
-    Alfven speed of the seeded field stays under a third of ``cs0``)."""
+    Alfven speed of the seeded field stays under a third of ``cs0``) -- and
+    ``DIFFUSIVE_SHARE`` of RK3's bound over the stiffest of the three diffusion
+    operators on that ``dx``: ``nu lap + (nu/3 + zeta) grad div`` of the
+    momentum, ``gamma chi lap`` of the entropy, ``eta lap`` of the induction
+    equation.  The advective limit goes as ``dx`` and the diffusive as ``dx^2``:
+    on a 2 pi box with the default coefficients the first binds up to some 280
+    cells an axis (every grid the tests and the 256^3 cells run: there the
+    viscous number reads 1.81 of 2.51), the second beyond (512^3: 1.876e-3 where
+    ``courant`` alone gives 3.388e-3, at which the viscous number reads 3.62)."""
     if setup.dt is not None:
         return float(setup.dt)
-    return setup.courant * min(setup.spacing) / (setup.cs0 + math.sqrt(3.0) * setup.amplitude)
+    dx = min(setup.spacing)
+    advective = setup.courant * dx / (setup.cs0 + math.sqrt(3.0) * setup.amplitude)
+    stiffest = D2_SPECTRAL_RADIUS * max(
+        3.0 * setup.nu + setup.nu / 3.0 + setup.zeta, 3.0 * setup.gamma * setup.chi, 3.0 * setup.eta
+    )
+    if stiffest <= 0.0:  # (the ablation tests switch every diffusion off)
+        return advective
+    return min(advective, DIFFUSIVE_SHARE * RK3_REAL_BOUND * dx * dx / stiffest)
 
 
 # --- reads and differences ------------------------------------------------------------

@@ -447,29 +447,44 @@ def _build_plane_step(g, stages, x_radius, plan):
                      alias=in_place,
                      scope=partial(telemetry.annotate, tm.SPAN_STEP_PASS), **lanes):
         """Stage ``k``'s passes in order, each over the quantities it
-        touches; a later pass sees what an earlier one wrote.  ``lanes`` is
-        the tiled pass's ``shell_in`` / ``shell_out`` (``per_shard``)."""
+        touches.  Every pass reads the blocks the STAGE was handed: the
+        planner lets no pass read a block an earlier one of the stage has
+        written (``plan_plane_passes``), and a renaming pass leaves its new
+        ``q`` in ``p``'s block, so a later pass that reads ``q`` still finds the
+        stage's entry value under its name.  What the passes wrote, and the
+        handles their renames swap, take their places once the last has run.
+        ``lanes`` is the tiled pass's ``shell_in`` / ``shell_out``
+        (``per_shard``)."""
         out = list(bs)
-        for pass_kernel, reads, rings, writes, renames, prerotated, tile_rows in plan.stage_runs[k]:
+        runs = plan.stage_runs[k]
+        # a stage of several passes that hand blocks on renamed: each under a
+        # scope of its own, so that a device trace splits the stage by pass
+        numbered = len(runs) > 1 and any(p["renames"] for p in plan["stages"][k]["passes"])
+        for i, (pass_kernel, reads, rings, writes, renames, prerotated, tile_rows) in enumerate(runs):
             grp = [index[name] for name in reads]
-            with scope():
+            with contextlib.ExitStack() as scopes:
+                if numbered:
+                    scopes.enter_context(telemetry.annotate(tm.stage_pass_span(i)))
+                scopes.enter_context(scope())
                 shared = dict(
                     alias=alias, interpret=g.interpret, f32_accumulate=g.f32_acc,
                     halo_readers=stage_readers[k], writers=writes, rings=rings,
-                    wrap_fills=plan.wrap_fills, strip=plan["plane_strip"],
+                    wrap_fills=plan.wrap_fills, strip=plan["plane_strip"], renames=renames,
                 )
                 args = (
-                    pass_kernel, reads, [out[q] for q in grp], lo, hi, x_radius, origin, g.gsize,
+                    pass_kernel, reads, [bs[q] for q in grp], lo, hi, x_radius, origin, g.gsize,
                 )
                 if tile_rows:  # planes that fit VMEM in y tiles only
                     outs = stream_plane_pass_tiled(*args, tile_rows=tile_rows, **lanes, **shared)
                 else:
                     outs = stream_plane_pass(
-                        *args, fused_shell=_group_bufs(fused_bufs, grp), renames=renames,
+                        *args, fused_shell=_group_bufs(fused_bufs, grp),
                         window=plan["plane_window"], prerotated=prerotated, **shared,
                     )
-            for q, o in zip(grp, outs):
-                out[q] = o
+            moved = set(writes) | {p for p, _ in renames}
+            for q, name, o in zip(grp, reads, outs):
+                if name in moved:
+                    out[q] = o
         return out
 
     if plan["halo"] == "fused":
@@ -1130,6 +1145,22 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
         )
         if "wire_bytes_by_stage" in plan:  # the bytes over the wires, stage by stage
             args["wire_bytes_by_stage"] = "/".join(str(b) for b in plan["wire_bytes_by_stage"])
+    if any(len(st["passes"]) > 1 for st in per_stage):
+        # a stage of SEVERAL passes says each of them, in the order it runs
+        # them: the quantities the pass writes, reads and holds a ring of, the
+        # rows of its y tiles and how many a plane is (0 and 1: whole planes),
+        # the writes that land in another quantity's block -- "w2-r10-g8-t256-
+        # y2-n2", the passes of a stage "+"-joined, the stages "/"-joined
+        # (``tile_rows`` / ``y_tiles`` above say the step's smallest tile)
+        rows = plan["tile_rows"] * plan["y_tiles"]  # the working plane's
+        args["passes_by_stage"] = "/".join(
+            "+".join(
+                f"w{len(p['writes'])}-r{len(p['reads'])}-g{len(p['rings'])}-t{p['tile_rows']}"
+                f"-y{rows // p['tile_rows'] if p['tile_rows'] else 1}-n{len(p['renames'])}"
+                for p in st["passes"]
+            )
+            for st in per_stage
+        )
     return args
 
 

@@ -328,6 +328,19 @@ def _fused_plane_patch(v, xplane, yst, zst, t, lo_y, hi_y, lo_z, hi_z):
     return v
 
 
+def _output_homes(names, raws, writers, renames) -> Dict[int, int]:
+    """Per writer (an index into ``names``) the quantity whose BLOCK its output
+    lives in -- aliases, under ``alias`` --: its own, or for a pair ``(p, q)`` of
+    ``renames`` raw ``p`` for writer ``q`` (``stream_plane_pass`` has the rule)."""
+    home = {q: q for q in writers}
+    for p_name, q_name in renames:
+        p, q = names.index(p_name), names.index(q_name)
+        assert q in home and p not in home, (renames, [names[w] for w in writers])
+        assert raws[p].dtype == raws[q].dtype, (p_name, q_name)
+        home[q] = p
+    return home
+
+
 def _told_guards(names, halo_readers, rings, writers, outputs):
     """``(no_ring, stale_read, unknown_output)`` of a plane pass: what raises, at
     trace time and by name, where the kernel reads or returns what the pass was
@@ -620,7 +633,9 @@ def stream_plane_pass(
     in THIS trace is not looked at: the footprint trace proved it is ``q``'s
     centre plane.  The caller must hand the handles on permuted -- a loop
     that carries them pays whole-array copies unless a trip returns them to
-    their places (``ops/stream.py _build_plane_step``).
+    their places (``ops/stream.py _build_plane_step``) -- and a STAGE of several
+    passes hands every pass the stage's entry blocks and swaps when its last
+    pass has run: a later pass reads the old ``q`` under ``q``'s name.
 
     With ``alias`` a writer's output IS its raw block
     (``input_output_aliases`` maps operand ``1 + q`` — operand 0 is
@@ -664,14 +679,8 @@ def stream_plane_pass(
         wq = [q for q in range(nq) if names[q] in writers]
     if not wq:
         return list(raws)
-    # the quantity whose block a writer's output lives in (aliases, under
-    # ``alias``): its own, or the one whose name its old block takes
-    home = {q: q for q in wq}
-    for p_name, q_name in renames:
-        p, q = names.index(p_name), names.index(q_name)
-        assert fused_shell is None and q in home and p not in home, (renames, writers)
-        assert raws[p].dtype == raws[q].dtype, (p_name, q_name)
-        home[q] = p
+    assert fused_shell is None or not renames, renames
+    home = _output_homes(names, raws, wq, renames)
     if rings is None or fused_shell is not None:
         ringed = list(range(nq))
     else:
@@ -1123,6 +1132,8 @@ def stream_plane_pass_tiled(
     wrap_fills: Sequence[Tuple[int, int, int, int]] = (),
     shell_in: bool = True,
     shell_out: bool = True,
+    renames: Sequence[Tuple[str, str]] = (),  # ``(p, q)``: writer ``q``'s new
+    # value lands in ``p``'s block (``stream_plane_pass`` has the rule)
 ) -> List[jax.Array]:
     """``stream_plane_pass`` on one of its two ALIGNED windows in the strip
     form, for planes whose pipeline blocks do not fit VMEM whole: the pipeline
@@ -1185,9 +1196,15 @@ def stream_plane_pass_tiled(
     plane 0's real tiles come (x step ``r + 1``), the in maps once past plane
     ``X - 1`` -- so nothing is flushed before it is computed and nothing
     refetched after it was overwritten (``check_inplace_order`` judges the
-    maps, which are the same on both windows).  Not built: ``fused_shell``,
-    ``renames``, ``prerotated``, the raw window -- ragged lanes or rows, a z the
-    mesh splits -- (``plan_plane_passes`` does not tile those).
+    maps, which are the same on both windows).  With ``renames`` the output of
+    a writer ``q`` has its home in ``p``'s block, as in ``stream_plane_pass``:
+    cell for cell what it always was (the kernel's values, ``q``'s own x-shell
+    planes and tail rows passed through), aliased onto raw ``p`` -- an operand
+    of the pass whether the kernel reads it or not, fetched lagged, so its
+    plane ``i - r`` is read before plane ``i - r - 1`` is flushed over it -- and
+    the returned list holds ``raws[q]`` ITSELF under ``p``.  Not built:
+    ``fused_shell``, ``prerotated``, the raw window -- ragged lanes or rows, a z
+    the mesh splits -- (``plan_plane_passes`` does not tile those).
 
     The lanes behind the window.  Lanes ``[Zw, Z)`` of a raw row hold copies
     of the window's first lanes: this pass leaves them behind every row it
@@ -1234,10 +1251,12 @@ def stream_plane_pass_tiled(
     wq = list(range(nq)) if writers is None else [q for q in range(nq) if names[q] in writers]
     if not wq:
         return list(raws)
+    home = _output_homes(names, raws, wq, renames)
     ringed = list(range(nq)) if rings is None else [q for q in range(nq) if names[q] in rings]
     depth = {q: 2 * r + 2 if q in ringed else 2 for q in range(nq)}
     low_z = [f for f in wrap_fills if f[0] == 2 and f[1] == 0]
-    no_ring, stale_read, unknown_output = _told_guards(names, halo_readers, rings, writers, set(wq))
+    no_ring, stale_read, unknown_output = _told_guards(
+        names, halo_readers, rings, writers, set(wq) | set(home.values()))
 
     def body(origin_ref, *refs):
         in_refs = refs[:nq]
@@ -1490,7 +1509,7 @@ def stream_plane_pass_tiled(
         ],
         out_specs=tuple(pl.BlockSpec((1, Yt, Z if shell_out else Zw), out_map) for _ in wq),
         out_shape=tuple(jax.ShapeDtypeStruct((X, Y, Z), raws[q].dtype) for q in wq),
-        input_output_aliases={1 + q: k for k, q in enumerate(wq)} if alias else {},
+        input_output_aliases={1 + home[q]: k for k, q in enumerate(wq)} if alias else {},
         scratch_shapes=[pltpu.VMEM((depth[q], NT * KT, T, Zw), raws[q].dtype) for q in range(nq)]
         + [pltpu.VMEM((T, Zw), raws[q].dtype) for q in range(nq)]
         + [pltpu.VMEM((Kt, T, Zw), raws[q].dtype) for q in wq]
@@ -1500,6 +1519,7 @@ def stream_plane_pass_tiled(
     )(origin.astype(jnp.int32), *raws)
     result = list(raws)  # a non-writer comes back as the array that went in
     for q, o in zip(wq, outs):
+        result[home[q]] = raws[q]  # renamed: the handles swap (a no-op at home)
         result[q] = o
     return result
 

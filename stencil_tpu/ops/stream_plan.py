@@ -560,10 +560,11 @@ def trace_plane_kernel(
     ``q``'s as the exchange left it where it was ``p``'s own stale one,
     which the contract allows: the step marks its shells stale and the
     exchange owns them.  Applied
-    where the stage runs ONE in-place pass of the plane route's default
-    schedule (``plan_plane_passes(rename=)``); not under ``overlap="split"``
-    (fresh outputs), not under ``halo="fused"`` (every quantity is written),
-    not in a stage cut into several passes, not when the trace failed.
+    to the in-place passes of the plane route's default schedule, pass by
+    pass where a stage is cut into several (``plan_plane_passes(rename=)``:
+    the handles swap when the stage's last pass has run); not under
+    ``overlap="split"`` (fresh outputs), not under ``halo="fused"`` (every
+    quantity is written), not when the trace failed.
 
     Fail closed: a trace that raises exchanges AND writes every quantity and
     runs the kernel as the user wrote it, every quantity ringed
@@ -765,7 +766,7 @@ def plane_pass_vmem_bytes(
 
 class FitsNoPass(ValueError):
     """A plane pass that cannot be cut further -- one output alone, or a stage
-    whose passes would read what an earlier one wrote in place -- fits the VMEM
+    whose passes would read a block an earlier one wrote -- fits the VMEM
     budget in no form the planner has (``plan_plane_passes``)."""
 
 
@@ -819,35 +820,54 @@ def plan_plane_passes(
     whole-plane passes CLASH (the next paragraph's refusal) is planned as one
     tiled pass over all its outputs where that fits.  Nothing that fits whole
     planes is ever tiled, so no plan that resolved before tiles existed
-    changes.  A tiled pass takes no rename and no ``prerotated`` plane.  It
-    raises ``FitsNoPass`` where no tile fits either, or the step has no tiled
-    form (``tiling.why``), and says what was tried.
+    changes.  A tiled pass takes renames as a whole-plane pass does, and no
+    ``prerotated`` plane.  It raises ``FitsNoPass`` where no tile fits either,
+    or the step has no tiled form (``tiling.why``), and says what was tried.
 
     Passes run one after the other ON THE SAME ARRAYS (in place), while a
     kernel means all its outputs to come from the values it was called with:
-    a pass that reads what an EARLIER pass of the stage has written would
-    read the new value.  That raises too (make it a stage of its own).
+    a pass that reads a BLOCK an earlier pass of the stage has written would
+    read the new value.  That raises too (make it a stage of its own), and
+    says which block.  The check reads blocks, not names: a pass writes an
+    output into the output's own block, or, renamed, into another
+    quantity's -- and then the stage's entry value still stands under the
+    output's name for every later pass to read.
 
     ``whole`` keeps the stage in one pass over every quantity, every one
     ringed and written (``halo="fused"``, whose side buffers are
     per-quantity operands of the pass).
 
-    ``rename`` applies the rename rule (``trace_plane_kernel``) where the
-    stage came out as ONE pass: the outputs that are another writer's centre
-    plane leave ``writes`` and the pairs go under ``renames`` (``(p, q)``:
-    ``q``'s new value lands in ``p``'s buffer, so ``p`` stays among the
-    ``reads`` whether the kernel reads it or not).  The caller passes it for
-    the in-place default schedule only (``resolve_stream_plan``).
+    ``rename`` applies the rename rule (``trace_plane_kernel``), pass by
+    pass: the outputs that are another writer's centre plane are written by
+    no pass, and the pair ``(p, q)`` goes under the ``renames`` of the pass
+    that computes ``q``, tiled or whole -- ``q``'s new value lands in ``p``'s
+    buffer, so ``p`` stays among that pass's ``reads`` whether the kernel reads
+    it or not.  In a stage of several passes that is what keeps the passes
+    apart: Astaroth's two-buffer Runge-Kutta at 512 x 512 writes every new
+    field into its ``*_prev`` block, so no pass reads a block another has
+    written, where written in place the second pass would clash with the
+    first.  The build swaps the handles once, when the stage's last pass has
+    run (``ops/stream.py _build_plane_step``); ``_carry_period`` walks every
+    pass's pairs.  The caller passes it for the in-place default schedule only
+    (``resolve_stream_plan``).
     ``ring_bytes`` and ``stage_bytes`` are ``plane_pass_vmem_bytes``' own."""
     budget = _vmem_budget()
+    # the rename rule, pass by pass: ``home[q]`` is the quantity whose block
+    # the new ``q`` lands in, and the outputs that are such a ``q``'s centre
+    # plane are written by no pass
+    home = {}
+    if rename and not whole and trace.closed is not None:
+        home = {q: p for p, q in trace.renames}
+    outputs = [out for out in trace.writers if out not in home.values()]
 
-    def describe(outputs, whole=False, renames=()):
-        prerotated = ()
+    def describe(outputs, whole=False):
+        prerotated, renames = (), ()
         if whole or trace.closed is None:
             reads = rings = writes = trace.names
         else:
             _, reads, rings, offsets = trace.pruned(outputs)
             writes = tuple(outputs)
+            renames = tuple((p, q) for p, q in trace.renames if home and q in writes)
             homes = set(reads) | {p for p, _ in renames}
             reads = tuple(nm for nm in trace.names if nm in homes)
             prerotated = shared_rotations(offsets) if trace.strip else ()
@@ -864,7 +884,7 @@ def plan_plane_passes(
             "writes": writes,
             "reads": reads,
             "rings": rings,
-            "renames": tuple(renames),
+            "renames": renames,
             "prerotated": prerotated,
             "tile_rows": 0,
             "vmem_bytes": priced(prerotated),
@@ -888,6 +908,12 @@ def plan_plane_passes(
         p = tiled(outputs) if in_tiles else describe(outputs)
         return p if p is not None and p["vmem_bytes"] <= budget else None
 
+    def blocks(p):
+        """The quantities whose BLOCKS the pass ``p`` writes: an output's own,
+        or the one its rename lands it in."""
+        landed = {q: at for at, q in p["renames"]}
+        return tuple(landed.get(q, q) for q in p["writes"])
+
     def refuse(p):
         if len(p["reads"]) == 1:
             return  # the floor: one quantity, nothing to split
@@ -900,8 +926,9 @@ def plan_plane_passes(
             )
         else:
             tried = tiling.why or "the step has no tiled form"
+        into = f" (into the blocks of {blocks(p)}: renamed)" if p["renames"] else ""
         raise FitsNoPass(
-            f"the plane pass that writes {p['writes']} reads {len(p['reads'])} "
+            f"the plane pass that writes {p['writes']}{into} reads {len(p['reads'])} "
             f"quantities {p['reads']}, {len(p['rings'])} of them off-centre "
             f"along x {p['rings']}: {p['vmem_bytes']} bytes of VMEM by the "
             f"model against a budget of {budget} -- it fits no pass ({tried}); "
@@ -916,7 +943,7 @@ def plan_plane_passes(
             refuse(p)
         return [p]
     passes, current, in_tiles = [], [], False
-    for out in trace.writers:
+    for out in outputs:
         p = fit(current + [out], in_tiles)
         if current and p is None:  # close the pass, open the next
             passes.append(fit(current, in_tiles))
@@ -928,29 +955,25 @@ def plan_plane_passes(
                 refuse(alone)  # raises, unless it is the floor
         current.append(out)
     passes.append(fit(current, in_tiles) or describe(current))
-    written = set()
+    written = set()  # the blocks the stage's passes have written so far
     for p in passes:
-        clash = written & (set(p["reads"]) - set(p["writes"]))
+        clash = written & set(p["reads"])
         if clash and not any(q["tile_rows"] for q in passes):
             # the other place this function used to raise: in y tiles the
             # stage may be ONE pass after all (D3Q19 at 512 x 512 stored as
             # bf16: eight outputs fit whole planes, the ninth reads them)
-            joint = tiled(list(trace.writers))
+            joint = tiled(outputs)
             if joint is not None:
                 return [joint]
         if clash:
             raise FitsNoPass(
-                f"the plane pass that writes {p['writes']} reads "
-                f"{tuple(sorted(clash))}, which an earlier pass of the same "
-                "stage has already written in place: the stage does not fit "
-                "one pass and cannot be split; make the later update a stage "
-                "of its own"
+                f"the plane pass that writes {p['writes']} (into the blocks of "
+                f"{blocks(p)}) reads {tuple(sorted(clash))}, whose block an earlier "
+                "pass of the same stage has already written in place: the stage "
+                "does not fit one pass and cannot be split; make the later update "
+                "a stage of its own"
             )
-        written |= set(p["writes"])
-    if rename and len(passes) == 1 and trace.renames and not passes[0]["tile_rows"]:
-        renamed = {p for p, _ in trace.renames}
-        kept = [out for out in trace.writers if out not in renamed]
-        return [describe(kept, renames=trace.renames)]
+        written |= set(blocks(p))
     return passes
 
 

@@ -458,7 +458,14 @@ ALL_HISTOGRAMS = frozenset({
 #: first two functions of the mesh, the last summed over the stages) and, on
 #: the plane route's swept exchange, wire_bytes_by_stage = those bytes stage by stage
 #: ("26359296/26359296/26359296" for Astaroth's MHD step on mesh [2,2,1]: three
-#: exchanges of the same eight fields; a stage that exchanges nothing 0); every
+#: exchanges of the same eight fields; a stage that exchanges nothing 0); a step
+#: with a stage of SEVERAL passes adds passes_by_stage = every pass in the order
+#: its stage runs it -- the quantities it writes, reads and holds a ring of, the
+#: rows of its y tiles and how many a plane is (0 and 1: whole planes), its
+#: writes that land in another quantity's block --, "w2-r10-g8-t256-y2-n2", the
+#: passes of a stage "+"-joined and the stages "/"-joined
+#: (``ops/stream.stream_span_args``; ``astaroth-mhd-512.bulk``: four passes a
+#: substep, ``tile_rows`` / ``y_tiles`` beside it the step's smallest tile); every
 #: plane step on that schedule says wired_edges = the pairs of wired axes its
 #: kernels read DIAGONALLY across (``ops/stream_plan.edge_reads`` over
 #: ``PlaneTrace.offsets``, "/"-joined): that EDGE halo is the diagonal
@@ -598,6 +605,13 @@ SPAN_STEP_PASS = "step.pass"
 #: scope around the stage's exchange sweeps and its passes, ``step.stage.0``,
 #: ``step.stage.1``, ... (``step_stage_span``); a one-stage step has none
 SPAN_STEP_STAGE = "step.stage"
+#: one PASS of a stage that runs several and hands its blocks on renamed
+#: (Astaroth's MHD substep at 512^3: four passes, eight renames): a
+#: DEVICE-timeline scope below the stage's, around the pass's own ``step.pass``,
+#: ``step.stage.<k>/pass.0``, ``.../pass.1``, ... in the order the stage runs them
+#: (``stage_pass_span``), so that a trace splits a stage by pass; a stage of one
+#: pass, and one that renames nothing, has none
+SPAN_STAGE_PASS = "pass"
 #: the redistribution collective schedule (parallel/redistribute.py): a
 #: named scope entered around the per-round slice/permute/blend body, so
 #: device-time attribution can price a live mesh transition
@@ -658,6 +672,11 @@ def step_stage_span(k: int) -> str:
     return f"{SPAN_STEP_STAGE}.{int(k)}"
 
 
+def stage_pass_span(i: int) -> str:
+    """The device scope of pass ``i`` of a stage of several renaming passes."""
+    return f"{SPAN_STAGE_PASS}.{int(i)}"
+
+
 def exchange_direction_span(axis: str, side: str) -> str:
     """The registered span name for one exchange hop (axis in x/y/z, side in
     low/high).  In-kernel scopes must come through here (or the constants
@@ -698,6 +717,7 @@ ALL_SPANS = frozenset({
     SPAN_OVERLAP_EXTERIOR,
     SPAN_STEP_PASS,
     SPAN_STEP_STAGE,
+    SPAN_STAGE_PASS,
     SPAN_RESHARD,
     SPAN_EXCHANGE_X_LOW,
     SPAN_EXCHANGE_X_HIGH,

@@ -279,6 +279,34 @@ def _astaroth_mhd_x4():
     return _trace_step(s.dd, s._step)
 
 
+def _astaroth_mhd_512():
+    """The MHD model under a VMEM budget that, like 104.9 MB at 512 x 512, holds
+    no substep in one pass: every stage is two y-tiled passes that write their
+    new fields into the ``*_prev`` blocks (ISSUE 57: renames pass by pass, the
+    handles swapped at the stage's end, each pass under ``pass.<i>``) --
+    7,128,768 B each by the model at 8 x 32 x 128 (tests/test_stage_passes.py)."""
+    import jax
+
+    from stencil_tpu.models.astaroth_mhd import AstarothMHD
+
+    was = os.environ.get("STENCIL_VMEM_LIMIT_BYTES")
+    os.environ["STENCIL_VMEM_LIMIT_BYTES"] = "7150000"
+    try:
+        s = AstarothMHD(8, 32, 128, interpret=True, devices=jax.devices()[:1], seed_words=None)
+        s.realize()
+        args = s._step._span_args()
+        assert (args["route"], args["stages"], args["passes"], args["renamed"]) == (
+            "plane", 3, 6, "8/8/8"), args
+        assert args["passes_by_stage"] == "/".join(["w4-r12-g8-t32-y1-n4+w4-r12-g8-t32-y1-n4"] * 3), args
+        assert (args["plane_window"], args["plane_lanes"], args["steps_per_trip"]) == ("interior", "raw", 2), args
+        return _trace_step(s.dd, s._step)
+    finally:
+        if was is None:
+            del os.environ["STENCIL_VMEM_LIMIT_BYTES"]
+        else:
+            os.environ["STENCIL_VMEM_LIMIT_BYTES"] = was
+
+
 def _lbm_tiled(shape, mesh, budget, said):
     """A lattice-Boltzmann model under a VMEM budget that, like 104.9 MB at 512 x
     512, holds the nineteen planes in y tiles only: the pass is the tiled one,
@@ -338,6 +366,7 @@ MODEL_PROGRAMS = {
     "model:astaroth-mhd-256x4/plane-r3": _astaroth_mhd_x4,
     "model:lbm-d3q19-512x4/plane-y-tiles": _lbm_x4,
     "model:lbm-d3q19-512/plane-y-tiles": _lbm_512,
+    "model:astaroth-mhd-512/plane-y-tiles-renamed": _astaroth_mhd_512,
 }
 
 

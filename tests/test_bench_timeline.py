@@ -170,8 +170,20 @@ def test_new_metrics_are_declared_and_read_names_not_shapes():
         assert m["cells"] == ["lbm-d3q19-512x4.bulk"] == declared[name]["workloads"], name
         assert m["moves"] == "mcells_per_s_chip", name
     lbm512x4 -= {"collective_pct.lbm512x4"}  # a trace_share: it reads opcodes, as PR 24's do
+    # PR 57's: the card-filling MHD cell's shares, the .mhd readers under a suffix of its own, the
+    # two rooflines over a time step's work and the four passes by scope (tests/test_bench_mhd512.py
+    # holds them)
+    mhd512 = {n for n in declared if ".mhd512" in n}
+    assert len(mhd512) == 12
+    for name in mhd512:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
+            m = json.load(f)
+        assert m["reducer"] in ("named_share", "named_roofline_hbm", "named_roofline_flops",
+                                "span_percentile", "span_count"), name
+        assert m["cells"] == ["astaroth-mhd-512.bulk"] == declared[name]["workloads"], name
+        assert m["moves"] == "mcells_per_s_chip", name
     for name in (set(declared) - new - plane - staged - setup - wired - lbm - lbm512 - lbm512x4 - mhd - mhdx4
-                 - wires - (ragged - {"collective_pct.ragged"})):
+                 - mhd512 - wires - (ragged - {"collective_pct.ragged"})):
         with open(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")) as f:
             assert json.load(f)["reducer"] in ("host_clock", "host_percentile", "trace_share",
                                                "trace_roofline_hbm", "trace_idle"), name

@@ -261,24 +261,39 @@ def test_a_step_that_fits_in_no_pass_raises_at_plan_time(monkeypatch):
 
 def test_a_stage_whose_passes_would_read_their_own_writes_raises(monkeypatch):
     """Passes run in place one after the other: a stage that does not fit one
-    pass and whose later output reads an earlier one's quantity cannot be
-    split (``b <- a`` after ``a`` was advanced would read the NEW ``a``)."""
+    pass and whose later output reads a quantity an earlier pass has written
+    INTO ITS OWN BLOCK cannot be split (``b <- 2 a`` after ``a`` was advanced
+    would read the NEW ``a``), and the refusal says which block.  Where the
+    later output is the earlier one's centre plane itself -- a leapfrog's
+    ``b <- a`` -- nothing clashes since ISSUE 57: the new ``a`` lands in ``b``'s
+    block (a rename), one pass writes and nothing else reads it."""
     from test_stream import _mk
 
     from stencil_tpu.core.radius import Radius
 
-    def leapfrog(views, info):
+    def advance(views):
         a = views["a"]
-        return {"a": 0.5 * (a.sh(1, 0, 0) + a.sh(-1, 0, 0)), "b": a.center()}
+        return 0.5 * (a.sh(1, 0, 0) + a.sh(-1, 0, 0))
+
+    def scaled(views, info):
+        return {"a": advance(views), "b": 2.0 * views["a"].center()}
+
+    def leapfrog(views, info):
+        return {"a": advance(views), "b": views["a"].center()}
 
     dd, hs = _mk(16, 16, 16, Radius.constant(1), ["a", "b"], jax.devices()[:1])
-    step = dd.make_step(leapfrog, engine="stream", stream_path="plane", interpret=True)
-    assert len(step._stream_plan["stages"][0]["passes"]) == 1  # fits: one pass, as acoustic
+    step = dd.make_step(scaled, engine="stream", stream_path="plane", interpret=True)
+    assert len(step._stream_plan["stages"][0]["passes"]) == 1  # fits: one pass
     # 24 x 128-lane planes of 12,288 B and 3 MB of stack a quantity read: a
     # alone 3.07 MB, b alone 6.07 MB, the two jointly 6.12 MB
     monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", "6100000")
-    with pytest.raises(ValueError, match=r"reads \('a',\), which an earlier pass.*stage of its own"):
-        dd.make_step(leapfrog, engine="stream", stream_path="plane", interpret=True)
+    with pytest.raises(ValueError, match=(
+            r"writes \('b',\) \(into the blocks of \('b',\)\) reads \('a',\), whose block an "
+            r"earlier pass.*stage of its own")):
+        dd.make_step(scaled, engine="stream", stream_path="plane", interpret=True)
+    (p,) = dd.make_step(
+        leapfrog, engine="stream", stream_path="plane", interpret=True)._stream_plan["stages"][0]["passes"]
+    assert (p["writes"], p["reads"], p["renames"]) == (("a",), ("a", "b"), (("b", "a"),))
 
 
 def test_passes_split_under_a_tight_budget_and_stay_bitwise(monkeypatch):

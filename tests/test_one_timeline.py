@@ -308,6 +308,7 @@ def test_every_registered_name_has_a_call_site(group):
         ("exchange_axis_span", tm.EXCHANGE_AXIS_SPANS),
         ("exchange_wrap_span", tm.EXCHANGE_WRAP_SPANS),
         ("step_stage_span", {"k": tm.SPAN_STEP_STAGE}),  # step.stage.<k>
+        ("stage_pass_span", {"i": tm.SPAN_STAGE_PASS}),  # pass.<i>
     ):
         for value in table.values():
             through_helper[value] = helper

@@ -608,6 +608,60 @@ def test_mhd_step_carries_its_blocks_in_place(monkeypatch):
 
 
 @pytest.mark.slow  # tier-2 with its siblings: one real-TPU-compiler AOT
+# compile at the benchmark's size (30 s alone: twelve passes of four kinds)
+def test_mhd_512_step_runs_in_place_in_renaming_passes(monkeypatch):
+    """The card-filling MHD cell's dispatch as the chip's compiler leaves it
+    (ISSUE 57): 512^3 x 16 through the normal planner for a described v5e -- two
+    time steps are 24 ``stream_plane_pass`` custom calls, FOUR a substep (one over
+    whole planes, three over y tiles of 256 rows at radius 3: Mosaic takes the
+    104.6 MB of VMEM the model prices for the ``ux uy`` pass), of 1, 2, 4 and 1
+    results, EVERY result aliased onto the ``*_prev`` operand of its field (the
+    pass's last operands: a rename, in a tiled pass as in a whole one), 48
+    ``blend_planes`` x wraps, no ``copy`` of a block -- the handles swapped at a
+    stage's end cost XLA nothing -- and NOTHING temporary beside 11.03 GB of
+    arguments."""
+    from stencil_tpu.models.astaroth_mhd import RADIUS, AstarothMHD
+    from stencil_tpu.ops import halo_blend
+    from stencil_tpu.ops import stream as sm
+
+    devices = _topology_devices()
+    monkeypatch.setattr(halo_blend, "pallas_interpret", lambda: False)
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)  # Mosaic index arithmetic is 32-bit
+    try:
+        sim = AstarothMHD(512, 512, 512, devices=devices[:1], seed_words=None)
+        sim.dd.realize(allocate=False)
+        stages = tuple(sim._substep(s) for s in range(3))
+        plan = sp.plan_stream(sim.dd, RADIUS, "auto", False)
+        plan = sp.resolve_stream_plan(sim.dd, stages, RADIUS, plan, False)
+        step = sm._build_stream_step(sim.dd, stages, RADIUS, plan, interpret=False)
+        compiled = step.lower(sim.dd.abstract_arrays(), 2).compile()
+        text, memory = compiled.as_text(), compiled.memory_analysis()
+    finally:
+        jax.config.update("jax_enable_x64", x64_was)
+    assert (plan["route"], plan["plane_window"], plan["plane_strip"], plan["steps_per_trip"]) == (
+        "plane", "interior", 8, 2), plan
+    said = [(len(p["writes"]), len(p["reads"]), p["tile_rows"], len(p["renames"]))
+            for p in plan["stages"][0]["passes"]]
+    assert said == [(1, 5, 0, 1), (2, 10, 256, 2), (4, 12, 256, 4), (1, 9, 256, 1)], said
+    calls = [l.lstrip() for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
+    passes = [l for l in calls if l.startswith("%stream_plane_pass")]
+    assert len(passes) == 24 and len([l for l in calls if l.startswith("%blend_planes")]) == 48
+    shapes = {(1, 5): 0, (2, 10): 0, (4, 12): 0, (1, 9): 0}
+    for call in passes:
+        results = call.split(" custom-call(")[0].count("f32[518,518,518]")
+        operands = call.split(" custom-call(")[1].split("), custom_call_target")[0].count("%") - 1
+        shapes[results, operands] += 1
+        aliasing = call[call.index("output_to_operand_aliasing="):].split("}, frontend")[0]
+        # result k IS one of the pass's LAST operands (operand 0 is the origin): its field's *_prev
+        got = sorted(int(m) for m in re.findall(r"\((\d+), \{\}\)", aliasing))
+        assert got == list(range(operands - results + 1, operands + 1)), (aliasing, operands)
+    assert shapes == {(1, 5): 6, (2, 10): 6, (4, 12): 6, (1, 9): 6}, shapes
+    assert not re.findall(r"=\s+f32\[518,518,518\]\S*\s+copy\(", text)
+    assert memory.temp_size_in_bytes == 0 and memory.argument_size_in_bytes == 11_032_985_600 + 0
+
+
+@pytest.mark.slow  # tier-2 with its siblings: one real-TPU-compiler AOT
 # compile at the benchmark's size for all four chips (40 s alone: six passes of
 # ~840 operations over whole 99-vreg planes, the raw window)
 def test_mhd_step_over_four_chips_carries_its_blocks_in_place(monkeypatch):

@@ -1101,27 +1101,44 @@ def test_a_chain_renames_its_head_and_copies_its_tail():
             assert np.array_equal(dd.quantity_to_host(h), ref_dd.quantity_to_host(g)), (h.name, steps)
 
 
-def test_a_stage_cut_into_passes_renames_nothing(monkeypatch):
-    """The rule needs ONE pass that holds both quantities: under a VMEM
-    budget that cuts the stage in two, ``v`` is written as before.  (The older
-    level comes first and the new one does not read it, so the cut is legal:
-    a leapfrog whose new level reads the old one fits one pass or none.)"""
+def test_a_stage_cut_into_passes_renames_pass_by_pass(monkeypatch):
+    """The rule applies pass by pass (ISSUE 57; it needed ONE pass that holds
+    both quantities before): the renamed output is written by no pass, so under
+    a VMEM budget one byte short of the renaming pass the stage does not fall
+    back to copying ``v`` -- nothing is left to cut, and the planner says what
+    does not fit.  With a second output that reads other quantities the stage
+    is cut in two, and the pass that computes ``u`` lands it in ``v``'s block."""
 
     def kernel(views, info):
         return {"v": views["u"].center(), "u": _star(views["u"], 2) * views["c"].center()}
 
-    names, r = ["v", "u", "c"], 2  # outputs join the passes in the domain's order
+    names, r = ["v", "u", "c"], 2
     dd, _ = _plane_domain(names, r, 1)
     _, whole = _plane_step(dd, kernel, r, {})
     (p,) = whole["stages"][0]["passes"]
     assert p["renames"] == (("v", "u"),) and whole["writers"] == ("u",), whole
     monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(p["vmem_bytes"] - 1))
     dd, _ = _plane_domain(names, r, 1)
-    _, cut = _plane_step(dd, kernel, r, {})
+    with pytest.raises(ValueError, match=(
+            r"writes \('u',\) \(into the blocks of \('v',\): renamed\) reads 3 quantities "
+            r"\('v', 'u', 'c'\).*fits no pass")):
+        _plane_step(dd, kernel, r, {})
+    monkeypatch.delenv("STENCIL_VMEM_LIMIT_BYTES")
+
+    def two(views, info):
+        return {**kernel(views, info), "w": 2.0 * views["w"].center() + views["c"].sh(0, 1, 0)}
+
+    names = ["v", "u", "c", "w"]
+    dd, _ = _plane_domain(names, r, 1)
+    _, joint = _plane_step(dd, two, r, {})
+    (p,) = joint["stages"][0]["passes"]
+    assert p["writes"] == ("u", "w") and p["renames"] == (("v", "u"),), joint
+    monkeypatch.setenv("STENCIL_VMEM_LIMIT_BYTES", str(p["vmem_bytes"] - 1))
+    dd, _ = _plane_domain(names, r, 1)
+    _, cut = _plane_step(dd, two, r, {})
     passes = cut["stages"][0]["passes"]
-    assert [q["writes"] for q in passes] == [("v",), ("u",)], passes
-    assert all(q["renames"] == () for q in passes), passes
-    assert cut["renamed"] == () and cut["writers"] == ("v", "u"), cut
+    assert [(q["writes"], q["renames"]) for q in passes] == [(("u",), (("v", "u"),)), (("w",), ())], passes
+    assert cut["renamed"] == ("v",) and cut["writers"] == ("u", "w") and cut["steps_per_trip"] == 2, cut
 
 
 def test_the_carry_period_is_the_order_of_the_steps_permutation():

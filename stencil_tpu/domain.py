@@ -1910,6 +1910,8 @@ class DistributedDomain:
         # the span is the ENQUEUE (a profiler annotation, never a sync);
         # only STENCIL_TELEMETRY's honest timing waits inside it
         plan_args = getattr(step_fn, "_span_args", dict)()  # a stream step's plan
+        if hasattr(step_fn, "_dispatch_args"):  # ... and what THIS dispatch runs of it
+            plan_args.update(step_fn._dispatch_args(steps))
         with telemetry.span(
             tm.SPAN_STEP, label=label, steps=raw, **plan_args,
             **self._dispatch_span_args(step_fn, steps),

@@ -70,6 +70,7 @@ from stencil_tpu.ops.stream_plan import (
     _plan_passes_in_place,
     _stream_groups,
     plain_wavefront_plan,
+    plane_lane_forms,
     plan_stream,
     resolve_stream_plan,
     swept_axes,
@@ -487,6 +488,13 @@ def _build_plane_step(g, stages, x_radius, plan):
                     out[q] = o
         return out
 
+    if plan["plane_lanes"] == "window":
+        # the lane forms of a dispatch (``per_shard``) differ in the pass's
+        # blocks and nowhere in the step's exchange: one trace and one lowering
+        # of it serve them all (set-up time; XLA inlines the call).  Only here:
+        # every other plan traces one form, and keeps its program byte for byte
+        exchange_readers = jax.jit(exchange_readers, static_argnums=1)
+
     if plan["halo"] == "fused":
 
         def stage(k, bs, origin):
@@ -558,25 +566,35 @@ def _build_plane_step(g, stages, x_radius, plan):
             return tuple(bs)
 
         if plan["plane_lanes"] == "window" and steps >= 2:
-            # the lane tile behind the aligned window moves one way a call
-            # (``stream_plan.plane_lanes_form``): the dispatch's first call reads
-            # whole raw planes and makes the fills -- the step still assumes
-            # nothing of the halo at entry -- and writes the window's lane tiles
-            # alone; every later call reads those alone and writes whole planes,
-            # the z shell rebuilt, so the last leaves every raw cell as whole
-            # calls do.  TWO forms, not three (first / narrow both ways between
-            # / last): each traced form of the pass and each traced exchange is
-            # set-up time, and with a third the one-chip cell read ``setup_s``
-            # +26.5% against a bound of 25% (PERF.md §6, PR 54).  The x wrap and
-            # a mesh's wires go on moving whole planes and rows: after the first
-            # call the stale shell lanes ride along and nobody reads them.  A
-            # dispatch of ONE call takes today's form.  Only the default
-            # schedule's ``stage`` takes the forms (fused side buffers and split
-            # exterior bands would read the stale lanes)
+            # the lane tile behind the aligned window moves only at the
+            # dispatch's two edges (``stream_plan.plane_lanes_form``), in the
+            # forms ``plane_lane_forms`` lists: the first call reads whole raw
+            # planes and makes the fills -- the step still assumes nothing of
+            # the halo at entry -- and writes the window's lane tiles alone;
+            # the calls between, the loop, move the window's lane tiles both
+            # ways, fill nothing and rebuild no z shell; the last reads those
+            # alone and writes whole planes, the z shell rebuilt, so the
+            # dispatch leaves every raw cell as whole calls do.  The x wrap
+            # and a mesh's wires go on moving whole planes and rows: after the
+            # first call the stale shell lanes ride along and nobody reads
+            # them.  Each traced form of the pass is set-up time: a dispatch of
+            # two traces no middle form, of ONE takes the whole call below
+            # (the third form read ``setup_s`` +26.5% against the one-form
+            # program of PR 54, over a 25% bound; against the two-form one it
+            # costs +2.8 s of trace and lowering, +10% of the one-chip cell's
+            # ``setup_s``, for +8% of its rate: PERF.md §6, PR 58).  Only the default
+            # schedule's ``stage`` takes the forms (fused side buffers and
+            # split exterior bands would read the stale lanes)
             assert period == 1 and plan["halo"] != "fused" and plan["overlap"] != "split", (
                 plan["steps_per_trip"], plan["halo"], plan["overlap"])
-            bs = one(tuple(blocks), shell_out=False)
-            return lax.fori_loop(0, steps - 1, lambda _, b: one(b, shell_in=False), bs)
+            bs = tuple(blocks)
+            for (shell_in, shell_out), calls in plane_lane_forms(plan, steps):
+                call = partial(one, shell_in=shell_in, shell_out=shell_out)
+                if shell_in or shell_out:  # an edge: once
+                    bs = call(bs)
+                else:
+                    bs = lax.fori_loop(0, calls, lambda _, b: call(b), bs)
+            return bs
 
         def body(_, bs):
             for _ in range(period):
@@ -1025,6 +1043,8 @@ def make_stream_step(
     # what this step's ``domain.step`` span says of the plan it runs NOW (the
     # ladder may have moved it)
     step._span_args = lambda: stream_span_args(step._stream_plan, x_radius, len(dd._handles))
+    # ... and of the dispatch of ``steps`` it is about to run of it
+    step._dispatch_args = lambda steps: stream_dispatch_args(step._stream_plan, steps)
     # ... and of the wires its exchanges cross (``run_step``'s counters)
     step._wire_account = lambda: step._stream_plan["wire_account"]
     step._resilience = ladder
@@ -1162,6 +1182,18 @@ def stream_span_args(plan, x_radius: int, nq: int) -> dict:
             for st in per_stage
         )
     return args
+
+
+def stream_dispatch_args(plan, steps: int) -> dict:
+    """What ``domain.step`` says of ONE dispatch of ``steps`` beside the plan's
+    ``stream_span_args``: where the passes take the lane forms (``plane_lanes``
+    "window"), narrow_calls = the calls of THIS dispatch that moved the aligned
+    window's lane tiles both ways -- counted off the same list ``per_shard``
+    runs (``plane_lane_forms``): ``steps - 2``, 0 for a dispatch of one or two.
+    Nothing elsewhere."""
+    if plan.get("plane_lanes") != "window":
+        return {}
+    return {"narrow_calls": dict(plane_lane_forms(plan, steps)).get((False, False), 0)}
 
 
 # --- batched dispatch (serve/pack.py) ----------------------------------------

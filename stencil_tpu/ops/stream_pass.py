@@ -1218,10 +1218,17 @@ def stream_plane_pass_tiled(
     pass); ``shell_out=False`` (in place only): the out blocks are ``(1, Yt,
     Zw)`` and no z shell is rebuilt -- lanes ``[Zw, Z)`` of the aliased block
     keep what they held, stale.  Both true is the program as it was.  The maps,
-    the grid and everything of y are the same in every form (``ops/stream.py
-    _build_plane_step`` runs a dispatch's first call ``(True, False)`` and
-    every later one ``(False, True)``; ``stream_plan.plane_lanes_form`` says
-    where)."""
+    the grid and everything of y are the same in every form.  ``ops/stream.py
+    _build_plane_step`` runs a dispatch's first call ``(True, False)``, the
+    ``steps - 2`` calls between ``(False, False)`` in its loop and the last
+    ``(False, True)``, so the dispatch leaves every raw cell as whole calls do
+    (``stream_plan.plane_lane_forms`` lists them, ``plane_lanes_form`` says
+    where; a dispatch of one step is one whole call, of two the two edge forms).
+    At D3Q19 512^3 on one v5e a call reads 39.2 ms whole, 35.2 / 35.8 with one
+    side narrow, 31.3 narrow both ways in the loop (PERF.md §6, PRs 54 and
+    58), and every form traced is one more trace, lowering and Mosaic compile
+    of the pass in ``setup_s``: +2.8 s for the third with the compile cache
+    warm, +10% of that cell's set-up (PERF.md §6, PR 58)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

@@ -1361,18 +1361,22 @@ def plane_lanes_form(plan: Mapping) -> str:
     move on the side that faces another call of it: ``"window"`` = the aligned
     working window ``[0, Zw)`` alone -- the dispatch's first call reads whole
     raw planes and makes today's fills but writes the window's lane tiles
-    alone, every later call reads those alone, fills nothing, and writes
-    whole planes with the z shell rebuilt (``stream_plane_pass_tiled(
-    shell_in=, shell_out=)``, ``ops/stream.py _build_plane_step``) --,
-    ``"raw"`` = every call moves whole raw planes both ways.  On either
-    aligned window the lanes ``[Zw, Z)`` hold copies of the window's first
-    lanes that a call of the pass leaves behind and only its own low z fill
-    reads back: between two calls of one dispatch they carry nothing, and of
-    a 514-lane f32 plane they are a fifth (8,128) tile of every row the
-    pipeline moves (D3Q19 at 512^3: 26.0 GB a call whole, 23.4 with one side
-    narrow, 20.8 with both -- which would take a third form of the pass, and
-    of the exchange beside it, to trace: set-up time the cells do not have;
-    PERF.md, PR 54).
+    alone, every call between the first and the last reads and writes those
+    alone, fills nothing and rebuilds no z shell, and the last reads them and
+    writes whole planes with the z shell rebuilt (``plane_lane_forms``;
+    ``stream_plane_pass_tiled(shell_in=, shell_out=)``, ``ops/stream.py
+    _build_plane_step``) --, ``"raw"`` = every call moves whole raw planes
+    both ways.  On either aligned window the lanes ``[Zw, Z)`` hold copies of
+    the window's first lanes that a call of the pass leaves behind and only
+    its own low z fill reads back: between two calls of one dispatch they
+    carry nothing, and of a 514-lane f32 plane they are a fifth (8,128) tile
+    of every row the pipeline moves (D3Q19 at 512^3: 26.0 GB a call whole,
+    23.4 with one side narrow, 20.8 with both: 39.2 / 35.2-35.8 / 32.0 ms a
+    call on one v5e, PERF.md §6 PR 54; a 6-step dispatch runs 1 + 4 + 1 of
+    them: 32.7 ms a step where two forms read 35.3, PERF.md §6 PR 58).  Each
+    form is one more trace, lowering and Mosaic compile of the pass at set-up:
+    the third cost the one-chip cell +2.8 s of its first dispatch with the
+    compile cache warm, +10% of ``setup_s`` (as measured, PR 58).
 
     Read off the resolved plan alone: ONE pass a step (a later pass of the
     dispatch's first step would fill its low z halo from lanes an earlier
@@ -1394,6 +1398,22 @@ def plane_lanes_form(plan: Mapping) -> str:
     aligned = plan["plane_window"] != "raw" and "z" in plan["pass_wrap_axes"]
     whole = set(p["reads"]) <= set(p["writes"]) and not p["renames"]
     return "window" if aligned and whole and _plan_passes_in_place(plan) else "raw"
+
+
+def plane_lane_forms(plan: Mapping, steps: int) -> Tuple[Tuple[Tuple[bool, bool], int], ...]:
+    """The calls of a plane-route dispatch of ``steps`` steps, in order, as
+    runs ``((shell_in, shell_out), calls)`` of the tiled pass's lane forms
+    (``plane_lanes_form``): with ``plane_lanes`` "window" the first call
+    ``(True, False)``, the ``steps - 2`` between ``(False, False)``, the last
+    ``(False, True)``; a dispatch of ONE step, like every call of a "raw"
+    plan, is whole both ways.  ``steps`` is static, so a dispatch of two holds
+    no middle run and traces no third form.  ``_build_plane_step`` runs what
+    this lists, and ``domain.step``'s ``narrow_calls`` counts its ``(False,
+    False)`` calls."""
+    if plan["plane_lanes"] != "window" or steps < 2:
+        return (((True, True), steps),)
+    runs = (((True, False), 1), ((False, False), steps - 2), ((False, True), 1))
+    return tuple(run for run in runs if run[1])
 
 
 def _carry_period(names: Sequence[str], stages) -> int:

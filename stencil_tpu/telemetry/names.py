@@ -529,13 +529,17 @@ ALL_HISTOGRAMS = frozenset({
 #: read off the one VMEM model) and plane_lanes = the lane tiles of a raw plane
 #: the passes move on the side that faces another call of the dispatch:
 #: "window" = the aligned window's alone -- the dispatch's first call reads
-#: whole raw planes and makes the fills but writes ``(1, Yt, Zw)`` blocks, every
-#: later call reads such blocks and writes whole planes with the z shell
-#: rebuilt (``steps`` says how many calls: a dispatch of ONE step is one whole
-#: call) --, "raw" = whole raw planes both ways every call: every cell but the
-#: two ``lbm-d3q19-512`` ones
+#: whole raw planes and makes the fills but writes ``(1, Yt, Zw)`` blocks, the
+#: calls between read and write such blocks alone, and the last reads them and
+#: writes whole planes with the z shell rebuilt (a dispatch of ONE step is one
+#: whole call, of two the two edge calls) --, "raw" = whole raw planes both
+#: ways every call: every cell but the two ``lbm-d3q19-512`` ones
 #: (``ops/stream_plan.plane_lanes_form``, read off the resolved plan: one tiled
-#: pass a step, in place, that writes every quantity it reads); a
+#: pass a step, in place, that writes every quantity it reads), and beside
+#: "window" narrow_calls = the calls of THIS dispatch that moved the window's
+#: lane tiles both ways, ``steps - 2`` and 0 for a dispatch of one or two
+#: (``ops/stream.stream_dispatch_args``, off the list the dispatch runs,
+#: ``ops/stream_plan.plane_lane_forms``; no "raw" span carries it); a
 #: z-slab wavefront step (``Jacobi3D``'s z-ring and
 #: lane-padded shell kernels, the stream engine's wavefront route with
 #: ``z_slabs``) adds z_halo_patch = where its kernel patches the z halo into

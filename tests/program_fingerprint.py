@@ -310,9 +310,10 @@ def _astaroth_mhd_512():
 def _lbm_tiled(shape, mesh, budget, said):
     """A lattice-Boltzmann model under a VMEM budget that, like 104.9 MB at 512 x
     512, holds the nineteen planes in y tiles only: the pass is the tiled one,
-    and a dispatch of two steps or more moves the lane tile behind the window
-    one way a call (ISSUE 54: ``plane_lanes`` "window" -- two forms of the pass,
-    the first call's raw in / window out, the loop's window in / raw out)."""
+    and a dispatch moves the lane tile behind the window at its two edges alone
+    (ISSUES 54, 58: ``plane_lanes`` "window" -- three forms of the pass, the first
+    call's raw in / window out, the loop's window in / window out, the last
+    call's window in / raw out, behind ONE traced exchange)."""
     import jax
 
     from stencil_tpu.models.lbm import LatticeBoltzmann

@@ -672,11 +672,12 @@ def test_compiled_lbm_step_in_y_tiles_is_bitwise_the_wrap_route(budget, y_tiles,
 
 
 def test_compiled_lbm_dispatch_with_the_z_shell_at_its_edges_is_bitwise_whole_calls(monkeypatch):
-    """The tiled pass's two edge forms as Mosaic compiles them (ISSUE 54), at 256^3
-    x 19 with the plane in four y tiles (the planner's budget tightened as above):
-    a dispatch whose first call reads whole raw planes and writes ``(1, 64, 256)``
-    blocks of the 258-lane rows, whose later calls read such blocks and write the z
-    shell back, against the same program with every call whole
+    """The tiled pass's three lane forms as Mosaic compiles them (ISSUES 54, 58), at
+    256^3 x 19 with the plane in four y tiles (the planner's budget tightened as
+    above): a dispatch whose first call reads whole raw planes and writes ``(1, 64,
+    256)`` blocks of the 258-lane rows, whose calls between (four of six steps, one
+    of three) read and write such blocks alone, and whose last call reads them and
+    writes the z shell back, against the same program with every call whole
     (``plane_lanes_form`` patched to "raw": the parent's) -- EVERY raw cell of all
     nineteen populations bitwise equal after a dispatch of 6 steps and one of 3
     behind it, the z shell, the tail rows and the x-halo planes included."""

@@ -818,13 +818,15 @@ def test_lbm_macro_loop_carries_its_blocks_in_place(monkeypatch):
 def test_lbm_512_step_runs_in_place_in_y_tiles(monkeypatch):
     """The card-filling lattice-Boltzmann cell's dispatch as the chip's compiler
     leaves it (ISSUE 51): 512^3 x 19 through the normal planner for a described
-    v5e -- TWO ``stream_plane_pass`` custom calls (ISSUE 54: the dispatch's first
-    call raw in / window out, the loop's window in / raw out, the lane tile behind
-    the window moved one way a call) of nineteen results each, every one aliased
+    v5e -- THREE ``stream_plane_pass`` custom calls (ISSUES 54, 58: the dispatch's
+    first call raw in / window out, the loop's window in / window out, the last
+    call's window in / raw out: the lane tile behind the window moved at the
+    dispatch's two edges alone) of nineteen results each, every one aliased
     onto its own operand, eighteen ``blend_planes`` x wraps a call, no ``copy``
     of a block and NOTHING temporary beside 13.0 GB of arguments: Mosaic takes
     the 101.9 MB of VMEM the model prices (y tiles of 128 rows) and the
-    ``(1, 128, 512)`` blocks of a 514-lane array."""
+    ``(1, 128, 512)`` blocks of a 514-lane array on BOTH sides of one call.  A
+    dispatch of two steps holds the two edge forms and no third."""
     from stencil_tpu.models.lbm import RADIUS, LatticeBoltzmann
     from stencil_tpu.ops import halo_blend
     from stencil_tpu.ops import stream as sm
@@ -840,15 +842,19 @@ def test_lbm_512_step_runs_in_place_in_y_tiles(monkeypatch):
         plan = sp.resolve_stream_plan(sim.dd, sim._kernel, RADIUS, plan, False)
         step = sm._build_stream_step(sim.dd, sim._kernel, RADIUS, plan, interpret=False)
         compiled = step.lower(sim.dd.abstract_arrays(), 8).compile()
+        two = step.lower(sim.dd.abstract_arrays(), 2).compile().as_text()
     finally:
         jax.config.update("jax_enable_x64", x64_was)
     assert (plan["route"], plan["plane_window"], plan["tile_rows"], plan["y_tiles"]) == (
         "plane", "interior", 128, 4), plan
     assert plan["plane_lanes"] == "window", plan
+    assert sp.plane_lane_forms(plan, 8) == (
+        ((True, False), 1), ((False, False), 6), ((False, True), 1))
     text, memory = compiled.as_text(), compiled.memory_analysis()
     calls = [l.lstrip() for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
     passes = [l for l in calls if l.startswith("%stream_plane_pass")]
-    assert len(passes) == 2 and len([l for l in calls if l.startswith("%blend_planes")]) == 2 * 18
+    assert len(passes) == 3 and len([l for l in calls if l.startswith("%blend_planes")]) == 3 * 18
+    assert len(re.findall(r"^\s*%stream_plane_pass\S* = .*custom-call\(", two, re.M)) == 2
     for call in passes:
         assert call.split(" custom-call(")[0].count("f32[514,514,514]") == 19
         for k in range(19):
@@ -862,8 +868,9 @@ def test_lbm_512_step_runs_in_place_in_y_tiles(monkeypatch):
 def test_lbm_512x4_step_runs_in_place_beside_a_split_y(monkeypatch):
     """The four-chip lattice-Boltzmann cell's dispatch as the chip's compiler
     leaves it (ISSUE 53): 1024 x 1024 x 512 on mesh [2,2,1] through the normal
-    planner for a described v5e:2x2 -- TWO ``stream_plane_pass`` custom calls
-    (ISSUE 54: the dispatch's first call and the loop's call behind it) of
+    planner for a described v5e:2x2 -- THREE ``stream_plane_pass`` custom calls
+    (ISSUES 54, 58: the dispatch's first call, the loop's call narrow both ways,
+    the last call) of
     nineteen results each, every one aliased onto its own operand (y tiles of 128
     rows on the ``"interior-z"`` window: Mosaic takes the 101.9 MB the model
     prices), before each the joint x-y exchange of the eighteen moving
@@ -896,14 +903,14 @@ def test_lbm_512x4_step_runs_in_place_beside_a_split_y(monkeypatch):
     calls = [l.lstrip() for l in text.splitlines() if "custom-call(" in l and "tpu_custom_call" in l]
     assert plan["plane_lanes"] == "window", plan
     passes = [l for l in calls if l.startswith("%stream_plane_pass")]
-    assert len(passes) == 2 and len(calls) == 2 * (1 + 36 + 36)
-    assert len([l for l in calls if l.startswith("%blend_planes")]) == 2 * 36
-    assert len([l for l in calls if l.startswith("%blend_slab")]) == 2 * 36
+    assert len(passes) == 3 and len(calls) == 3 * (1 + 36 + 36)
+    assert len([l for l in calls if l.startswith("%blend_planes")]) == 3 * 36
+    assert len([l for l in calls if l.startswith("%blend_slab")]) == 3 * 36
     for call in passes:
         assert call.split(" custom-call(")[0].count("f32[514,514,514]") == 19
         for k in range(19):
             assert f"{{{k}}}: ({k + 1}, {{}})" in call, k  # result k IS operand 1 + k
-    assert len(re.findall(r"=.*collective-permute-start\(", text)) == 2 * 6
+    assert len(re.findall(r"=.*collective-permute-start\(", text)) == 3 * 6
     assert not re.findall(r"=\s+f32\[514,514,514\]\S*\s+copy\(", text)
     assert memory.argument_size_in_bytes == 13_000_499_200
     assert memory.temp_size_in_bytes < 514 * 520 * 640 * 4 // 4  # messages: no quarter of a block

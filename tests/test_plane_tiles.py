@@ -531,12 +531,14 @@ def _same_raw_cells(got, want):
 @pytest.mark.parametrize("steps", [1, 2, 3, 6])
 @pytest.mark.parametrize("window", ["interior", "interior-z"])
 def test_a_dispatch_that_carries_the_z_shell_at_its_edges_is_bitwise_whole_calls(window, steps):
-    """Dispatches of 1, 2, 3 and 6 steps through the dispatch structure -- first
-    call raw in / window out, every later call window in / raw out, the lane tile
-    behind the window moved one way a call -- against the parent's program, every call
-    moving whole raw planes: EVERY raw cell of every quantity bitwise equal after
-    the dispatch, the z shell, the tail rows and the x-halo planes included; on
-    four devices the y halo rows a neighbour sent too."""
+    """Dispatches of 1, 2, 3 and 6 steps through the dispatch structure's THREE
+    forms -- first call raw in / window out, the calls between (one of three
+    steps, four of six, none of two) window in / window out, the last window in
+    / raw out: the lane tile behind the window moves at the dispatch's two edges
+    alone -- against the parent's program, every call moving whole raw planes:
+    EVERY raw cell of every quantity bitwise equal after the dispatch, the z
+    shell, the tail rows and the x-halo planes included; on four devices the y
+    halo rows a neighbour sent too."""
     dd, hs, _, lanes, whole = _lanes_case(window)
     blocks = _seeded_blocks(dd, hs, 54 + steps)
     want = whole(blocks, steps)
@@ -544,18 +546,20 @@ def test_a_dispatch_that_carries_the_z_shell_at_its_edges_is_bitwise_whole_calls
     _same_raw_cells(lanes(blocks, steps), want)
 
 
+@pytest.mark.parametrize("steps", [3, 6])
 @pytest.mark.parametrize("window", ["interior", "interior-z"])
-def test_the_first_call_of_a_dispatch_assumes_nothing_of_the_shell(window):
+def test_the_first_call_of_a_dispatch_assumes_nothing_of_the_shell(window, steps):
     """Blocks whose SHELL is garbage at entry, only the interior filled: the
     dispatch's first call still makes every fill the parent's makes (that is what
     the narrow calls behind it rest on), so three steps leave every raw cell as
     whole calls leave it -- and what they leave in the interior does not depend
-    on the garbage."""
+    on the garbage.  Six steps likewise: the middle form, which fills nothing and
+    leaves lanes ``[Zw, Z)`` stale, runs four times behind that shell."""
     dd, hs, _, lanes, whole = _lanes_case(window)
     blocks = _seeded_blocks(dd, hs, 7, garbage=True)
-    got = lanes(blocks, 3)
-    _same_raw_cells(got, whole(blocks, 3))
-    clean = lanes(_seeded_blocks(dd, hs, 7), 3)
+    got = lanes(blocks, steps)
+    _same_raw_cells(got, whole(blocks, steps))
+    clean = lanes(_seeded_blocks(dd, hs, 7), steps)
     for h in hs:
         dd._curr = dict(got)
         a = dd.quantity_to_host(h)
@@ -584,21 +588,49 @@ def _pass_blocks(closed):
 
 @pytest.mark.parametrize("window", ["interior", "interior-z"])
 @pytest.mark.parametrize("steps,forms", [
-    (1, ["ZZ"]), (2, ["ZW", "WZ"]), (3, ["ZW", "WZ"]), (6, ["ZW", "WZ"]),
+    (1, ["ZZ"]), (2, ["ZW", "WZ"]), (3, ["ZW", "WW", "WZ"]), (6, ["ZW", "WW", "WZ"]),
 ])
 def test_a_call_moves_the_lane_tile_behind_the_window_one_way(window, steps, forms):
     """Bytes counted, never time: the traced dispatch holds one call a form --
     the loop body once --: the first call's out blocks and every later call's in
     blocks end in ``Zw`` = 256 lanes where the parent's end in ``Z`` = 258 on
-    both sides; the first call's in blocks and the later calls' out blocks are
-    whole rows.  A dispatch of ONE step is one whole call; the parent's program
-    of six is one whole call in its loop."""
+    both sides, and so do the out blocks of every call but the last; the first
+    call's in blocks and the last call's out blocks are whole rows.  A dispatch
+    of ONE step is one whole call, of two holds no middle form; the parent's
+    program of six is one whole call in its loop."""
     dd, _, _, lanes, whole = _lanes_case(window)
     width = {"Z": 258, "W": 256}
     closed = jax.make_jaxpr(lanes, static_argnums=1)(dd._curr, steps)
     assert _pass_blocks(closed) == sorted((width[f[0]], width[f[1]]) for f in forms)
     closed = jax.make_jaxpr(whole, static_argnums=1)(dd._curr, steps)
     assert _pass_blocks(closed) == [(width["Z"], width["Z"])]
+
+
+_T, _F = True, False
+
+
+@pytest.mark.parametrize("window", ["interior", "interior-z"])
+@pytest.mark.parametrize("steps,forms", [
+    (1, [(_T, _T)]), (2, [(_T, _F), (_F, _T)]),
+    (3, [(_T, _F), (_F, _F), (_F, _T)]), (6, [(_T, _F), (_F, _F), (_F, _T)]),
+])
+def test_a_dispatch_traces_one_call_a_lane_form(window, steps, forms, monkeypatch):
+    """The ``(shell_in, shell_out)`` of the calls a dispatch TRACES, in order:
+    one whole call for one step; first and last for two, no middle form; first,
+    middle, last for three and for six alike -- the loop traces its body once,
+    whatever its trips.  The pass is stubbed (it hands its blocks back), so this
+    traces the dispatch's structure and the step's exchange, no Pallas body."""
+    dd, _, _, lanes, _ = _lanes_case(window)
+    seen = []
+
+    def spy(kernel, names, raws, *a, shell_in=True, shell_out=True, **kw):
+        seen.append((shell_in, shell_out))
+        return list(raws)
+
+    monkeypatch.setattr(sm, "stream_plane_pass_tiled", spy)
+    # (a fresh function: jax would answer ``lanes``' own from its trace cache)
+    jax.make_jaxpr(lambda curr: lanes.__wrapped__(curr, steps))(dd._curr)
+    assert seen == forms
 
 
 def _one_pass_plan(**over):
@@ -655,14 +687,11 @@ def test_domain_step_says_the_lanes_of_the_plan(shape, mesh, kw, said, monkeypat
     assert (args["plane_window"], args["tile_rows"], args["plane_lanes"]) == said
 
 
-def test_a_dispatch_says_its_lanes_on_the_span(monkeypatch):
-    """The span a dispatch of the model opens, held to the plan it ran (the
-    model and the three-step dispatch ``test_the_model_through_y_tiles_matches_
-    the_reference[32]`` built: nothing is traced again)."""
+def _step_span_args(monkeypatch, dispatch):
+    """The arguments of the one ``domain.step`` span that ``dispatch()`` opens."""
     from stencil_tpu import telemetry
     from stencil_tpu.telemetry import names as tm
 
-    sim = _small_lbm(monkeypatch, 32)
     seen = []
     real = telemetry.span
 
@@ -670,12 +699,47 @@ def test_a_dispatch_says_its_lanes_on_the_span(monkeypatch):
         seen.append((name, kw))
         return real(name, *a, **kw)
 
-    monkeypatch.setattr(telemetry, "span", spy)
-    _seed_populations(sim, 3)
-    sim.step(3)
+    with monkeypatch.context() as mp:
+        mp.setattr(telemetry, "span", spy)
+        dispatch()
     (kw,) = [kw for name, kw in seen if name == tm.SPAN_STEP]
+    return kw
+
+
+def test_a_dispatch_says_its_lanes_on_the_span(monkeypatch):
+    """The span a dispatch of the model opens, held to the plan it ran (the
+    model and the three-step dispatch ``test_the_model_through_y_tiles_matches_
+    the_reference[32]`` built: nothing is traced again)."""
+    sim = _small_lbm(monkeypatch, 32)
+    _seed_populations(sim, 3)
+    kw = _step_span_args(monkeypatch, lambda: sim.step(3))
     plan = sim._step._stream_plan
-    assert (kw["steps"], kw["plane_lanes"]) == (3, "window")
+    assert (kw["steps"], kw["plane_lanes"], kw["narrow_calls"]) == (3, "window", 1)
     assert (kw["plane_lanes"], kw["tile_rows"], kw["y_tiles"]) == (
         plan["plane_lanes"], plan["tile_rows"], plan["y_tiles"])
     assert not sim._step._resilience.descents
+
+
+@pytest.mark.parametrize("lanes,steps,narrow", [
+    ("window", 1, 0), ("window", 2, 0), ("window", 3, 1), ("window", 6, 4), ("raw", 6, None),
+])
+def test_domain_step_counts_the_calls_that_moved_the_window_both_ways(lanes, steps, narrow, monkeypatch):
+    """``narrow_calls`` on ``domain.step`` beside ``plane_lanes`` and ``steps``:
+    the calls of THAT dispatch between its two edge calls, ``steps - 2`` and 0 for
+    a dispatch of one or two -- off the list the dispatch runs (``plane_lane_
+    forms``) --, and no such key where ``plane_lanes`` is "raw".  The dispatch
+    itself is idle here (the model's plan and span hooks on a step that hands the
+    blocks back): nothing is traced."""
+    sim = _small_lbm(monkeypatch, 32)
+    plan = {**sim._step._stream_plan, "plane_lanes": lanes}
+    assert sim._step._stream_plan["plane_lanes"] == "window"
+    forms = dict(sp.plane_lane_forms(plan, steps))
+    assert sum(forms.values()) == steps and forms.get((False, False), 0) == (narrow or 0)
+
+    def idle(curr, steps):
+        return curr
+
+    idle._span_args = lambda: sm.stream_span_args(plan, RADIUS, 19)
+    idle._dispatch_args = lambda steps: sm.stream_dispatch_args(plan, steps)
+    kw = _step_span_args(monkeypatch, lambda: sim.dd.run_step(idle, steps))
+    assert (kw["steps"], kw["plane_lanes"], kw.get("narrow_calls")) == (steps, lanes, narrow)
